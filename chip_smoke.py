@@ -1,0 +1,718 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the served stack starts on the chip.
+
+Drives the normal served path once, through the entry points the README's
+quick start uses, at the full width and depth of one model the repo supports
+(registry preset qwen3-8b, int8 weights, random from --seed):
+
+  kernels   each Pallas kernel that compiles for v5e, run NON-interpreted at
+            the qwen3-8b head shape against ops/attention.py's XLA path
+  serve     `python -m ...engine.server` and `python -m ...router.app` as two
+            OS processes; through the router: one chat completion, one
+            streamed (SSE, every chunk parsed), four concurrent completions,
+            one request repeating an earlier prompt's first >=1,024 tokens
+            (the engine's prefix-hit counter must rise); /metrics of both
+            parse; zero mid-traffic compiles and zero failed builds after
+            warm-up; both processes stop on SIGTERM with exit 0
+
+`--chips 4` runs ONLY the four-chip comparison: the same engine at tp=1 on
+the first device, then, after it has exited, at --tensor-parallel-size 4 —
+same prompts, greedy, logprobs on.
+
+This process never imports JAX: a chip belongs to one process at a time, so
+every chip-holding phase is a child, and each child has exited before the
+next starts. JAX_PLATFORMS is not set here; whatever platform the children
+find is REPORTED, and anything but `tpu` fails the run. One JSON line per
+phase; the last line of stdout is only
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+and the exit code is 0 only when that line says ok. Nothing here catches a
+failure without recording it as one.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import re
+import signal
+import socket
+import subprocess
+import sys
+import threading
+import time
+import traceback
+import urllib.error
+import urllib.request
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+OUT_DIR = os.path.join(REPO, "chiprun_out", "chip_smoke")
+# compilation included; the driver allows 1200 s
+DEADLINE_S = 1140.0
+BLOCK_SIZE = 32
+# bf16 attention outputs of O(1) magnitude (queries are scaled so the softmax
+# is peaked): both paths accumulate in f32 but round at different points — the
+# XLA path feeds bf16 probabilities to the MXU, the kernel f32 ones — so an
+# element may differ by a few bf16 ulps (2^-8 relative each). Allowed per
+# element: KERNEL_ATOL + KERNEL_RTOL * |reference|, i.e. 4 ulps and a floor.
+KERNEL_ATOL = 1e-2
+KERNEL_RTOL = 2.0 ** -6
+# tp=4 reorders every 4096-long bf16 reduction of arm (a) into four partial
+# sums + a psum; on first-token logprobs (|lp| ~ ln V ~ 12) that is a few
+# hundredths of a nat. Judged on tokens present in both top-8 lists.
+LOGPROB_ATOL = 0.25
+MIN_SHARED_TOP8 = 4
+SPREAD_TOL = 0.10  # per-device bytes within 10% of equal under tp=4
+
+
+def engine_flags(model: str, seed: int, tp: int = 1,
+                 warmup: bool = True) -> list[str]:
+    """The §1 engine: full published widths and depth, int8 weights so 8B
+    fits one 16 GB chip, a bf16 pool of 32-token pages at max_model_len 4096
+    so `auto` selects the Pallas decode kernel on a TPU, and a program set
+    bounded to one prefill bucket x one decode bucket x one context width so
+    a cold run compiles little (coarse warm-up covers every key traffic can
+    ask for: rows 1/2/4 x chunk 512, batch 4 x windows 1/2/4/8)."""
+    flags = [
+        "--model", model, "--quantization", "int8", "--seed", str(seed),
+        "--block-size", str(BLOCK_SIZE), "--max-model-len", "4096",
+        "--num-blocks", "768",
+        "--max-num-seqs", "4", "--max-num-batched-tokens", "512",
+        "--prefill-buckets", "512", "--decode-buckets", "4",
+        "--width-floor-blocks", "128",
+        "--tensor-parallel-size", str(tp),
+    ]
+    if warmup:
+        flags += ["--warmup", "--warmup-scope", "coarse"]
+    return flags
+
+
+# -- plumbing ----------------------------------------------------------------
+
+_T0 = time.monotonic()
+
+
+def remaining() -> float:
+    return DEADLINE_S - (time.monotonic() - _T0)
+
+
+def emit(obj: dict) -> None:
+    print(json.dumps(obj), flush=True)
+
+
+class Failures:
+    """Every caught failure lands here; the exit code is non-zero if any."""
+
+    def __init__(self) -> None:
+        self.items: list[str] = []
+
+    def add(self, what: str) -> None:
+        self.items.append(what)
+
+    def check(self, cond: bool, what: str) -> bool:
+        if not cond:
+            self.add(what)
+        return bool(cond)
+
+
+def free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def http(method: str, url: str, body: dict | None = None,
+         timeout: float = 300.0):
+    data = None if body is None else json.dumps(body).encode()
+    req = urllib.request.Request(
+        url, data=data, method=method,
+        headers={"Content-Type": "application/json"},
+    )
+    return urllib.request.urlopen(req, timeout=timeout)
+
+
+def http_json(method: str, url: str, body: dict | None = None,
+              timeout: float = 300.0) -> dict:
+    try:
+        with http(method, url, body, timeout) as r:
+            return json.loads(r.read())
+    except urllib.error.HTTPError as e:
+        raise RuntimeError(
+            f"{method} {url} -> HTTP {e.code}: {e.read()[:400]!r}"
+        ) from e
+
+
+def log_tail(path: str, n: int = 1500) -> str:
+    try:
+        with open(path, "rb") as f:
+            f.seek(0, os.SEEK_END)
+            f.seek(max(0, f.tell() - n))
+            return f.read().decode(errors="replace")
+    except OSError as e:
+        return f"<no log: {e}>"
+
+
+class Child:
+    """One OS process of the stack, its log, and its orderly stop."""
+
+    def __init__(self, name: str, argv: list[str]):
+        os.makedirs(OUT_DIR, exist_ok=True)
+        self.name = name
+        self.log_path = os.path.join(OUT_DIR, f"{name}.log")
+        self._log = open(self.log_path, "w")
+        self.proc = subprocess.Popen(
+            [sys.executable, *argv], cwd=REPO,
+            stdout=self._log, stderr=subprocess.STDOUT,
+        )
+
+    def wait_healthy(self, url: str, timeout_s: float) -> float:
+        t0 = time.monotonic()
+        while time.monotonic() - t0 < timeout_s:
+            if self.proc.poll() is not None:
+                raise RuntimeError(
+                    f"{self.name} exited rc={self.proc.returncode} before "
+                    f"healthy: {log_tail(self.log_path)}"
+                )
+            try:
+                with http("GET", url + "/health", timeout=2) as r:
+                    if r.status == 200:
+                        return time.monotonic() - t0
+            except (urllib.error.URLError, OSError):
+                pass  # not listening yet; the loop's deadline decides
+            time.sleep(1.0)
+        raise TimeoutError(
+            f"{self.name} not healthy after {timeout_s:.0f}s: "
+            f"{log_tail(self.log_path)}"
+        )
+
+    def stop(self, fails: Failures, grace_s: float = 60.0) -> int | None:
+        """SIGTERM, wait, and hold the process to exit code 0. A process
+        that needs SIGKILL is a failure — and is killed all the same, so
+        nothing outlives the smoke."""
+        if self.proc.poll() is None:
+            self.proc.send_signal(signal.SIGTERM)
+            try:
+                self.proc.wait(timeout=grace_s)
+            except subprocess.TimeoutExpired:
+                self.proc.kill()
+                self.proc.wait(timeout=30)
+                fails.add(f"{self.name} ignored SIGTERM for {grace_s:.0f}s")
+        self._log.close()
+        rc = self.proc.returncode
+        fails.check(rc == 0, f"{self.name} exited rc={rc}: "
+                             f"{log_tail(self.log_path, 600)}")
+        return rc
+
+
+_SAMPLE = re.compile(
+    r'^([a-zA-Z_:][a-zA-Z0-9_:]*'
+    r'(?:\{(?:[^"{}]|"(?:[^"\\]|\\.)*")*\})?)'
+    r' (\S+)(?: -?[0-9]+)?$'
+)
+
+
+def parse_metrics(text: str) -> dict[str, float]:
+    """Prometheus text exposition -> {series-with-labels: value}; a line
+    that is neither a comment nor a sample raises (float() takes +Inf and
+    NaN, and raises on anything that is not a number)."""
+    out: dict[str, float] = {}
+    for line in text.splitlines():
+        if not line or line.startswith("#"):
+            continue
+        m = _SAMPLE.match(line)
+        if m is None:
+            raise ValueError(f"unparseable metrics line: {line[:200]!r}")
+        out[m.group(1)] = float(m.group(2))
+    return out
+
+
+def scrape(url: str) -> dict[str, float]:
+    with http("GET", url + "/metrics") as r:
+        return parse_metrics(r.read().decode())
+
+
+def metric_sum(samples: dict[str, float], name: str) -> float:
+    return sum(v for k, v in samples.items()
+               if k == name or k.startswith(name + "{"))
+
+
+def filler(n: int, salt: int) -> str:
+    """n printable ASCII chars — n tokens under the byte tokenizer the
+    presets serve with (no vocabulary files, no network)."""
+    words = ("lorem", "ipsum", "dolor", "sit", "amet", "tpu", "page", "kv")
+    out, i = [], salt
+    while sum(len(w) + 1 for w in out) < n:
+        out.append(f"{words[i % len(words)]}{i * 7919 % 1000}")
+        i += 1
+    return " ".join(out)[:n]
+
+
+# -- device report -----------------------------------------------------------
+
+def check_device(dev: dict, fails: Failures, want_count: int,
+                 want_decode: str | None) -> None:
+    """Hold the engine's own statement of what it runs on (GET
+    /debug/programs "device") to the chip: the platform is asserted HERE,
+    not inferred from an exit code."""
+    fails.check(dev["platform"] == "tpu",
+                f"engine runs on platform {dev['platform']!r}, not 'tpu'")
+    fails.check(dev["count"] == want_count,
+                f"engine sees {dev['count']} devices, expected {want_count}")
+    att = dev["attention"]
+    for phase in ("decode", "prefill"):
+        fails.check(att[phase]["resolved"] != "pallas_interpret",
+                    f"{phase} attention resolved to interpret mode")
+    if want_decode is not None and dev["platform"] == "tpu":
+        fails.check(
+            att["decode"]["resolved"] == want_decode,
+            f"decode attention resolved to {att['decode']['resolved']!r}, "
+            f"expected {want_decode!r}",
+        )
+
+
+def device_summary(dev: dict | None) -> dict | None:
+    if dev is None:
+        return None
+    return {"platform": dev["platform"], "kind": dev["kind"],
+            "count": dev["count"]}
+
+
+# -- requests ----------------------------------------------------------------
+
+def chat_plain(url: str, model: str) -> dict:
+    body = {
+        "model": model, "max_tokens": 32, "temperature": 0.0,
+        "ignore_eos": True,
+        "messages": [{"role": "user", "content": "Say hello to the chip."}],
+    }
+    out = http_json("POST", url + "/v1/chat/completions", body)
+    text = out["choices"][0]["message"]["content"]
+    if not isinstance(text, str):
+        raise RuntimeError(f"chat content is not text: {out!r}"[:400])
+    tokens = out["usage"]["completion_tokens"]
+    if tokens != 32:
+        raise RuntimeError(f"asked 32 tokens, got {tokens}")
+    return {"request": "chat", "tokens": tokens}
+
+
+def chat_streamed(url: str, model: str) -> dict:
+    want = 80
+    body = {
+        "model": model, "max_tokens": want, "temperature": 0.0,
+        "ignore_eos": True, "stream": True,
+        "stream_options": {"include_usage": True},
+        "messages": [{"role": "user", "content": "Stream me a story."}],
+    }
+    chunks = tokens = 0
+    done = False
+    with http("POST", url + "/v1/chat/completions", body) as resp:
+        if resp.status != 200:
+            raise RuntimeError(f"stream -> HTTP {resp.status}")
+        for raw in resp:
+            line = raw.decode().strip()
+            if not line:
+                continue
+            if not line.startswith("data: "):
+                raise RuntimeError(f"not an SSE data line: {line[:200]!r}")
+            payload = line[len("data: "):]
+            if payload == "[DONE]":
+                done = True
+                break
+            chunk = json.loads(payload)  # every chunk must parse
+            if chunk.get("error"):
+                raise RuntimeError(f"stream error chunk: {chunk!r}"[:400])
+            chunks += 1
+            if chunk.get("usage"):
+                tokens = chunk["usage"]["completion_tokens"]
+    if not done:
+        raise RuntimeError("stream ended without [DONE]")
+    # tokens, not text: random weights over a 151,936-entry vocabulary
+    # mostly sample ids the byte tokenizer renders as nothing
+    if tokens < 64 or chunks < 2:
+        raise RuntimeError(
+            f"stream returned {tokens} tokens in {chunks} chunks"
+        )
+    return {"request": "chat_stream", "tokens": tokens, "chunks": chunks}
+
+
+def completion(url: str, model: str, prompt: str, max_tokens: int,
+               logprobs: int | None = None) -> dict:
+    body = {
+        "model": model, "prompt": prompt, "max_tokens": max_tokens,
+        "temperature": 0.0, "ignore_eos": True,
+    }
+    if logprobs is not None:
+        body["logprobs"] = logprobs
+    out = http_json("POST", url + "/v1/completions", body)
+    tokens = out["usage"]["completion_tokens"]
+    if tokens != max_tokens:
+        raise RuntimeError(f"asked {max_tokens} tokens, got {tokens}")
+    return out
+
+
+def concurrent_completions(url: str, model: str, prompts: list[str]) -> list:
+    results: list = [None] * len(prompts)
+
+    def one(i: int) -> None:
+        try:
+            out = completion(url, model, prompts[i], 32)
+            results[i] = {"request": f"completion_{i}",
+                          "tokens": out["usage"]["completion_tokens"],
+                          "prompt_tokens": out["usage"]["prompt_tokens"]}
+        except Exception as e:  # re-raised below, from the parent thread
+            results[i] = e
+
+    threads = [threading.Thread(target=one, args=(i,))
+               for i in range(len(prompts))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=600)
+    for r in results:
+        if r is None:
+            raise RuntimeError("a concurrent completion never returned")
+        if isinstance(r, Exception):
+            raise r
+    return results
+
+
+# -- phases ------------------------------------------------------------------
+
+def phase_kernels(args, fails: Failures) -> None:
+    """Chip-holding child #1; it has exited before the engine starts."""
+    t0 = time.monotonic()
+    proc = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), "--child", "kernels",
+         "--seed", str(args.seed)],
+        cwd=REPO, capture_output=True, text=True,
+        timeout=max(30.0, min(400.0, remaining() - 600.0)),
+    )
+    lines = [ln for ln in proc.stdout.splitlines() if ln.startswith("{")]
+    report = json.loads(lines[-1]) if lines else {}
+    report.update(phase="kernels", rc=proc.returncode,
+                  seconds=round(time.monotonic() - t0, 1))
+    if proc.returncode != 0 or not report.get("ok"):
+        report["ok"] = False
+        report["stderr_tail"] = proc.stderr[-1200:]
+        fails.add(f"kernels: rc={proc.returncode} "
+                  f"{report.get('error') or report.get('kernels')}")
+    emit(report)
+
+
+def phase_serve(args, fails: Failures) -> dict | None:
+    """Engine + router as two processes, the seven requests, the checks."""
+    device = None
+    engine = router = None
+    engine_port, router_port = free_port(), free_port()
+    engine_url = f"http://127.0.0.1:{engine_port}"
+    url = f"http://127.0.0.1:{router_port}"
+    try:
+        engine = Child("engine", [
+            "-m", "vllm_production_stack_tpu.engine.server",
+            "--port", str(engine_port), "--host", "127.0.0.1",
+            *engine_flags(args.model, args.seed),
+        ])
+        boot_s = engine.wait_healthy(engine_url, max(60.0, remaining() - 240))
+        programs = http_json("GET", engine_url + "/debug/programs")
+        device = dev = programs["device"]
+        check_device(dev, fails, want_count=1, want_decode="pallas")
+        emit({
+            "phase": "engine_up", "ok": dev["platform"] == "tpu",
+            "model": args.model, "quantization": "int8",
+            "engine_pid": engine.proc.pid,
+            "boot_to_healthy_s": round(boot_s, 1),
+            "warmup_programs": len(programs["programs"]),
+            "warmup_compile_wall_s": round(sum(
+                p["compile_wall_s"] for p in programs["programs"]), 1),
+            "device": dev,
+        })
+
+        router = Child("router", [
+            "-m", "vllm_production_stack_tpu.router.app",
+            "--port", str(router_port), "--host", "127.0.0.1",
+            "--service-discovery", "static",
+            "--static-backends", engine_url,
+            "--static-models", args.model,
+        ])
+        router.wait_healthy(url, min(120.0, max(30.0, remaining() - 200)))
+
+        served: list[dict] = []
+        served.append(chat_plain(url, args.model))
+        served.append(chat_streamed(url, args.model))
+        # four at once; the first carries the >=1,024-token prefix the last
+        # request of the smoke repeats
+        shared = filler(1280, salt=1)
+        prompts = [shared + " first tail"] + [
+            filler(200 + 60 * i, salt=100 + i) for i in range(1, 4)
+        ]
+        served += concurrent_completions(url, args.model, prompts)
+        before = scrape(engine_url)
+        out = completion(url, args.model, shared + " a different tail", 16)
+        served.append({"request": "prefix_repeat",
+                       "tokens": out["usage"]["completion_tokens"],
+                       "prompt_tokens": out["usage"]["prompt_tokens"]})
+        engine_metrics, router_metrics = scrape(engine_url), scrape(url)
+        hit_blocks = (
+            metric_sum(engine_metrics, "tpu:hbm_prefix_cache_hits_total")
+            - metric_sum(before, "tpu:hbm_prefix_cache_hits_total")
+        )
+        hit = fails.check(
+            hit_blocks * BLOCK_SIZE >= 1024,
+            f"prefix repeat hit {hit_blocks:.0f} blocks "
+            f"({hit_blocks * BLOCK_SIZE:.0f} tokens), expected >=1024 tokens",
+        )
+        emit({"phase": "requests", "ok": hit, "via": "router",
+              "router_pid": router.proc.pid, "served": served,
+              "prefix_hit_blocks": hit_blocks,
+              "engine_metric_samples": len(engine_metrics),
+              "router_metric_samples": len(router_metrics)})
+
+        programs = http_json("GET", engine_url + "/debug/programs")
+        mid = sum(v for k, v in programs["compiles"].items()
+                  if k.endswith("/mid_traffic"))
+        failed = programs["failed_builds"]["total"]
+        fails.check(mid == 0, f"{mid} mid-traffic compiles after warm-up: "
+                              f"{programs['compiles']}")
+        fails.check(failed == 0, f"{failed} failed program builds: "
+                                 f"{programs['failed_builds']['last']}")
+        dev = programs["device"]
+        emit({"phase": "after_traffic", "ok": mid == 0 and failed == 0,
+              "mid_traffic_compiles": mid, "failed_builds": failed,
+              "compiles": programs["compiles"],
+              "program_cache": programs["cache"],
+              "kvhash": dev["kvhash"], "attention": dev["attention"],
+              "hbm": dev["devices"]})
+    except Exception as e:
+        fails.add(f"serve: {type(e).__name__}: {e}"[:1500])
+        emit({"phase": "serve", "ok": False,
+              "error": f"{type(e).__name__}: {e}"[:1500],
+              "trace": traceback.format_exc()[-800:]})
+    finally:
+        codes = {}
+        for child in (router, engine):
+            if child is not None:
+                codes[child.name] = child.stop(fails)
+        emit({"phase": "shutdown", "signal": "SIGTERM", "exit_codes": codes,
+              "ok": bool(codes) and all(c == 0 for c in codes.values())})
+    return device
+
+
+def run_arm(args, fails: Failures, tp: int, prompts: list[str]) -> dict:
+    """One arm of the four-chip comparison: the §1 engine at `tp`, asked
+    directly (the router path is the one-chip run's business), greedy with
+    logprobs. The engine has exited when this returns."""
+    name = f"tp{tp}"
+    port = free_port()
+    url = f"http://127.0.0.1:{port}"
+    arm: dict = {"tp": tp, "device": None, "outputs": []}
+    # no warm-up: logprobs programs are their own static variants, so the
+    # requests compile exactly what they use
+    engine = Child(f"engine_{name}", [
+        "-m", "vllm_production_stack_tpu.engine.server",
+        "--port", str(port), "--host", "127.0.0.1",
+        *engine_flags(args.model, args.seed, tp=tp, warmup=False),
+    ])
+    try:
+        boot_s = engine.wait_healthy(url, max(60.0, remaining() - 120))
+        arm["device"] = http_json("GET", url + "/debug/programs")["device"]
+        check_device(arm["device"], fails, want_count=4,
+                     want_decode="pallas" if tp == 1 else None)
+        for prompt in prompts:
+            out = completion(url, args.model, prompt, 16, logprobs=8)
+            lp = out["choices"][0]["logprobs"]
+            arm["outputs"].append({
+                "tokens": lp["tokens"],
+                "first_top8": lp["top_logprobs"][0],
+            })
+        dev = http_json("GET", url + "/debug/programs")["device"]
+        arm["device"] = dev
+        ids = dev["mesh_device_ids"]
+        fails.check(len(set(ids)) == tp,
+                    f"{name}: mesh device ids {ids}, expected {tp} distinct")
+        emit({"phase": f"arm_{name}", "ok": True,
+              "boot_to_healthy_s": round(boot_s, 1),
+              "served": len(arm["outputs"]), "mesh_device_ids": ids,
+              "attention": dev["attention"], "hbm": dev["devices"]})
+    except Exception as e:
+        fails.add(f"arm {name}: {type(e).__name__}: {e}"[:1500])
+        emit({"phase": f"arm_{name}", "ok": False,
+              "error": f"{type(e).__name__}: {e}"[:1500]})
+    finally:
+        engine.stop(fails)
+    return arm
+
+
+def phase_four_chips(args, fails: Failures) -> dict | None:
+    prompts = [filler(300 + 150 * i, salt=40 + i) for i in range(4)]
+    one = run_arm(args, fails, 1, prompts)  # exits before tp=4 starts
+    four = run_arm(args, fails, 4, prompts)
+    report: dict = {"phase": "tp1_vs_tp4", "logprob_atol": LOGPROB_ATOL,
+                    "min_shared_top8": MIN_SHARED_TOP8,
+                    "spread_tol": SPREAD_TOL}
+    ok = fails.check(
+        len(one["outputs"]) == len(prompts)
+        and len(four["outputs"]) == len(prompts),
+        "an arm did not serve every request",
+    )
+    if ok:
+        worst, shared_min, prefixes = 0.0, 8, []
+        for a, b in zip(one["outputs"], four["outputs"]):
+            common = set(a["first_top8"]) & set(b["first_top8"])
+            shared_min = min(shared_min, len(common))
+            for tok in common:
+                worst = max(worst,
+                            abs(a["first_top8"][tok] - b["first_top8"][tok]))
+            n = 0
+            for x, y in zip(a["tokens"], b["tokens"]):
+                if x != y:
+                    break
+                n += 1
+            prefixes.append(n)  # printed, not judged: flat random logits
+        report.update(first_token_max_abs_logprob_diff=round(worst, 4),
+                      first_token_min_shared_top8=shared_min,
+                      common_greedy_prefix_lens=prefixes)
+        fails.check(shared_min >= MIN_SHARED_TOP8,
+                    f"tp=4 shares only {shared_min}/8 first-token top "
+                    "logprob tokens with tp=1")
+        fails.check(worst <= LOGPROB_ATOL,
+                    f"tp=4 first-token logprobs differ from tp=1 by {worst}")
+    dev = four["device"]
+    if dev is not None:
+        used = [d.get("bytes_in_use") for d in dev["devices"]]
+        report["tp4_bytes_in_use"] = used
+        if fails.check(all(u is not None for u in used) and len(used) == 4,
+                       f"tp=4 per-device bytes not reported: {used}"):
+            mean = sum(used) / len(used)
+            spread = max(abs(u - mean) for u in used) / mean
+            report["tp4_spread"] = round(spread, 4)
+            fails.check(spread <= SPREAD_TOL,
+                        f"tp=4 weight+KV bytes spread {spread:.3f} over "
+                        f"devices {dev['mesh_device_ids']}: {used}")
+    report["ok"] = not fails.items
+    emit(report)
+    return dev or one["device"]
+
+
+# -- the kernels child (the only code here that imports JAX) -----------------
+
+def child_kernels(seed: int) -> int:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    sys.path.insert(0, REPO)
+    from vllm_production_stack_tpu.ops.attention import (
+        causal_page_mask, paged_attention_with_staged, paged_attention_xla,
+    )
+    from vllm_production_stack_tpu.ops.paged_attention_pallas import (
+        paged_decode_attention, paged_prefill_attention,
+    )
+    from vllm_production_stack_tpu.utils.compile_cache import (
+        configure_compile_cache,
+    )
+
+    dev = jax.devices()[0]
+    report: dict = {"platform": dev.platform, "kind": dev.device_kind,
+                    "atol": KERNEL_ATOL, "rtol": KERNEL_RTOL, "kernels": {}}
+    if dev.platform != "tpu":
+        # a Mosaic kernel runs nowhere else, and interpret mode proves
+        # nothing about the chip
+        report.update(ok=False, error=f"platform is {dev.platform!r}: "
+                                      "kernels not run")
+        emit(report)
+        return 1
+    configure_compile_cache()
+    nh, kvh, d = 32, 8, 128  # qwen3-8b
+    bs, num_blocks, nb, w = BLOCK_SIZE, 1024, 128, 8
+    scale = d ** -0.5
+    rng = np.random.RandomState(seed)
+
+    def arr(*shape, gain=1.0):
+        return jnp.asarray(gain * rng.randn(*shape), jnp.bfloat16)
+
+    def tables(b):  # read-only checks: rows may share pages
+        return jnp.asarray(rng.randint(1, num_blocks, size=(b, nb)), jnp.int32)
+
+    def diff(got, want) -> dict:
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        err = np.abs(got - want)
+        allowed = KERNEL_ATOL + KERNEL_RTOL * np.abs(want)
+        return {
+            "max_abs_diff": float(np.max(err)),
+            "ref_abs_max": float(np.max(np.abs(want))),
+            "ok": bool(np.all(np.isfinite(got)) and np.all(err <= allowed)),
+        }
+
+    kv = arr(2, num_blocks, bs, kvh, d)
+    for name, pool in (("decode", kv),
+                       ("decode_fp8_pool", kv.astype(jnp.float8_e4m3fn))):
+        b, step_k = 8, 3
+        q, tb = arr(b, 1, nh, d, gain=3.0), tables(b)
+        hist = jnp.asarray(rng.randint(1, nb * bs, size=b), jnp.int32)
+        sk, sv = arr(w, b, kvh, d), arr(w, b, kvh, d)
+        got = paged_decode_attention(
+            q[:, 0], pool, tb, hist, sk, sv, jnp.int32(step_k), scale=scale,
+        )
+        want = paged_attention_with_staged(
+            q, pool, tb, jnp.arange(nb * bs)[None, :] < hist[:, None],
+            sk, sv, jnp.arange(w) <= step_k, scale=scale,
+        )[:, 0]
+        report["kernels"][name] = diff(got, want)
+
+    b, t = 2, 512
+    q, tb = arr(b, t, nh, d, gain=3.0), tables(b)
+    start = jnp.asarray([1024, 0], jnp.int32)  # a continued and a first chunk
+    ctx = start + t
+    got = paged_prefill_attention(q, kv, tb, ctx, start, scale=scale)
+    positions = start[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+    want = paged_attention_xla(
+        q, kv, tb, causal_page_mask(positions, ctx, nb * bs), scale=scale,
+    )
+    report["kernels"]["prefill"] = diff(got, want)
+
+    report["ok"] = all(k["ok"] for k in report["kernels"].values())
+    emit(report)
+    return 0 if report["ok"] else 1
+
+
+# -- entry -------------------------------------------------------------------
+
+def main() -> int:
+    p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    p.add_argument("--chips", type=int, default=1, choices=[1, 4],
+                   help="4: run only the tp=1 vs tp=4 comparison")
+    p.add_argument("--model", default="qwen3-8b",
+                   help="registry preset (tests pass tiny-llama)")
+    p.add_argument("--seed", type=int, default=0)
+    p.add_argument("--child", default=None, choices=["kernels"],
+                   help=argparse.SUPPRESS)
+    args = p.parse_args()
+    if args.child == "kernels":
+        return child_kernels(args.seed)
+
+    fails = Failures()
+    device = None
+    try:
+        if args.chips == 4:
+            device = phase_four_chips(args, fails)
+        else:
+            phase_kernels(args, fails)
+            device = phase_serve(args, fails)
+    except Exception as e:  # recorded, reported, and the exit code says so
+        fails.add(f"{type(e).__name__}: {e}"[:1500])
+        emit({"phase": "smoke", "ok": False,
+              "trace": traceback.format_exc()[-1500:]})
+    summary = device_summary(device)
+    if summary is None or summary["platform"] != "tpu":
+        fails.add(f"no TPU reported by the engine: {summary}")
+    if fails.items:
+        emit({"phase": "failures", "failures": fails.items,
+              "elapsed_s": round(time.monotonic() - _T0, 1)})
+    else:
+        emit({"phase": "done", "elapsed_s": round(time.monotonic() - _T0, 1)})
+    emit({"ok": not fails.items, "device": summary})
+    return 0 if not fails.items else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,0 +1,165 @@
+"""The Pallas kernels a CLI choice can reach, compiled by the installed TPU
+compiler for a DESCRIBED v5e:2x2 topology — no chip attached, nothing runs.
+
+Interpret mode (tests/test_pallas_attention.py) pins the math but not what
+Mosaic accepts: the prefill kernel passed every interpret test while the v5e
+compiler refused it at real head shapes (20.25 MiB of scoped VMEM against a
+16 MiB limit). These compiles are the guard: real head shapes, real page
+sizes, both pool dtypes, and the shard_map placement tp>1 uses. A compile
+that passes is not a chip run — chip_smoke.py is.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from vllm_production_stack_tpu.ops import paged_attention_pallas as pk
+from vllm_production_stack_tpu.parallel import mesh as mesh_lib
+
+# (num_heads, num_kv_heads, head_dim) of the registry presets
+HEADS = {"qwen3-8b": (32, 8, 128), "llama-1b": (32, 8, 64)}
+POOL_BLOCKS = 1024
+CTX = 4096  # the context 'auto' starts choosing the decode kernel at
+BATCH, WINDOW, CHUNK = 8, 8, 512
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """The described topology, with the persistent compilation cache off
+    around the module: a compile for an absent chip is written to the cache
+    but cannot be read back, and the next one warns about it."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"v5e:2x2 topology cannot be described here: {e!r}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _decode_args(sds, model, block, kv_dtype, specs):
+    nh, kvh, d = HEADS[model]
+    nb = CTX // block
+    q, pool, b2, b1, staged, rep = specs
+    return (
+        sds((BATCH, nh, d), jnp.bfloat16, q),
+        sds((2, POOL_BLOCKS, block, kvh, d), kv_dtype, pool),
+        sds((BATCH, nb), jnp.int32, b2),
+        sds((BATCH,), jnp.int32, b1),
+        sds((WINDOW, BATCH, kvh, d), jnp.bfloat16, staged),
+        sds((WINDOW, BATCH, kvh, d), jnp.bfloat16, staged),
+        sds((), jnp.int32, rep),
+    )
+
+
+def _prefill_args(sds, model, block, kv_dtype, specs):
+    nh, kvh, d = HEADS[model]
+    nb = CTX // block
+    q, pool, b2, b1 = specs
+    return (
+        sds((1, CHUNK, nh, d), jnp.bfloat16, q),
+        sds((2, POOL_BLOCKS, block, kvh, d), kv_dtype, pool),
+        sds((1, nb), jnp.int32, b2),
+        sds((1,), jnp.int32, b1),
+        sds((1,), jnp.int32, b1),
+    )
+
+
+def _one_chip(topo):
+    """An sds(shape, dtype, spec) that ignores spec: all on the first chip."""
+    one = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype, _spec: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one
+    )
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("model,block,kv_dtype", [
+    ("qwen3-8b", 32, "bfloat16"),  # chip_smoke.py's served configuration
+    ("qwen3-8b", 16, "bfloat16"),
+    ("qwen3-8b", 32, "float8_e4m3fn"),
+    ("llama-1b", 32, "bfloat16"),
+    ("llama-1b", 16, "float8_e4m3fn"),
+])
+def test_decode_kernel_compiles_for_v5e(topo, model, block, kv_dtype):
+    args = _decode_args(
+        _one_chip(topo), model, block, jnp.dtype(kv_dtype), (None,) * 6
+    )
+    scale = HEADS[model][2] ** -0.5
+    _assert_kernel(jax.jit(
+        lambda *a: pk.paged_decode_attention(*a, scale=scale)
+    ).lower(*args).compile())
+
+
+@pytest.mark.parametrize("model,block,kv_dtype", [
+    ("qwen3-8b", 32, "bfloat16"),
+    ("qwen3-8b", 16, "float8_e4m3fn"),
+    ("llama-1b", 32, "bfloat16"),
+    ("llama-1b", 16, "bfloat16"),
+])
+def test_prefill_kernel_compiles_for_v5e(topo, model, block, kv_dtype):
+    args = _prefill_args(
+        _one_chip(topo), model, block, jnp.dtype(kv_dtype), (None,) * 4
+    )
+    scale = HEADS[model][2] ** -0.5
+    _assert_kernel(jax.jit(
+        lambda *a: pk.paged_prefill_attention(*a, scale=scale)
+    ).lower(*args).compile())
+
+
+def test_sharded_kernels_compile_for_four_v5e_chips(topo):
+    """--tensor-parallel-size 4 with explicit pallas backends: shard_map
+    places one kernel per chip over its own kvh/tp heads."""
+    mesh = mesh_lib.make_mesh(tensor_parallel_size=4, devices=topo.devices)
+    dp, tp = mesh_lib.DP_AXIS, mesh_lib.TP_AXIS
+
+    def sds(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, spec)
+        )
+
+    pool = P(None, None, None, tp, None)
+    scale = HEADS["qwen3-8b"][2] ** -0.5
+    dargs = _decode_args(sds, "qwen3-8b", 32, jnp.bfloat16, (
+        P(dp, tp, None), pool, P(dp, None), P(dp),
+        P(None, dp, tp, None), P(),
+    ))
+    _assert_kernel(jax.jit(
+        lambda *a: pk.paged_decode_attention_sharded(mesh, *a, scale=scale)
+    ).lower(*dargs).compile())
+    pargs = _prefill_args(sds, "qwen3-8b", 32, jnp.bfloat16, (
+        P(dp, None, tp, None), pool, P(dp, None), P(dp),
+    ))
+    _assert_kernel(jax.jit(
+        lambda *a: pk.paged_prefill_attention_sharded(mesh, *a, scale=scale)
+    ).lower(*pargs).compile())
+
+
+@pytest.mark.parametrize("nh,d,itemsize,want", [
+    (32, 128, 2, 128),  # qwen3-8b / llama-8b: 256 rows was 20.25 MiB
+    (32, 64, 2, 128),   # llama-1b: D=64 lane-pads to the same footprint
+    (8, 128, 2, 256),   # a tp=4 shard keeps the full tile
+    (64, 128, 2, 64),
+])
+def test_prefill_query_tile_fits_scoped_vmem(nh, d, itemsize, want):
+    tt = pk._prefill_q_tile(1024, nh, d, itemsize)
+    assert tt == want
+    lanes = -(-d // 128) * 128
+    resident = nh * tt * (4 * itemsize * lanes + 4 * lanes + 2 * 4 * 128)
+    assert resident <= pk.PREFILL_VMEM_BUDGET
+    assert pk._prefill_q_tile(64, nh, d, itemsize) == min(64, want)

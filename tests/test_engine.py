@@ -304,7 +304,7 @@ def test_compile_fallback_pads_up_to_warm_program():
     """A first-seen (rows x chunk x width) program key must NOT compile on
     the hot path when a compiled program dominates it: the runner pads up
     (identical results) and backgrounds the exact compile — the structural
-    fix for the live-serving compile-stall collapse (ROUND3.md)."""
+    fix for the live-serving compile-stall collapse."""
     import numpy as np
 
     from vllm_production_stack_tpu.engine.config import (

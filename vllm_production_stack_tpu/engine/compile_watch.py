@@ -105,6 +105,10 @@ class CompileWatch:
         self.cache_hits = 0
         self.cache_misses = 0
         self.storms_total = 0
+        # builds that raised (a background compile logs and drops its
+        # failure; the count keeps it visible) + the most recent one
+        self.failed_builds = 0
+        self.last_failure: dict | None = None
         # sliding window of (t, shape_str) mid-traffic builds
         self._storm_events: deque = deque()
         self._in_storm = False  # edge flag: one report per episode
@@ -180,6 +184,23 @@ class CompileWatch:
                 )
         if storm_hit:
             self._check_storm(now)
+
+    def record_failure(
+        self, phase: str, key: tuple, trigger: str, exc: BaseException,
+        *, role: str = "target",
+    ) -> None:
+        """A program build raised instead of landing."""
+        if not self.enabled:
+            return
+        with self._lock:
+            self.failed_builds += 1
+            self.last_failure = {
+                "key": _key_str(key),
+                "phase": phase,
+                "role": role,
+                "trigger": trigger,
+                "error": f"{type(exc).__name__}: {exc}"[:500],
+            }
 
     def record_dispatch(self, served_key: tuple, hit: bool,
                         role: str = "target") -> None:
@@ -290,6 +311,10 @@ class CompileWatch:
                 "programs": programs,
                 "capacity": self.capacity,
                 "compiles": dict(self.compiles),
+                "failed_builds": {
+                    "total": self.failed_builds,
+                    "last": self.last_failure,
+                },
                 "cache": {
                     "hits": self.cache_hits,
                     "misses": self.cache_misses,

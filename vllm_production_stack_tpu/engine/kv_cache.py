@@ -352,7 +352,7 @@ class KVBlockPool:
             return []
         try:
             # one dispatch for the whole fetched run — per-block uploads
-            # cost a device round trip each on high-RTT links. THIS is the
+            # cost a device dispatch each. THIS is the
             # dequant-on-adopt boundary: encoded blocks decode here, right
             # before the device upload.
             self.host_tier.upload_many(

@@ -387,7 +387,7 @@ class KVTransfer:
             )
         # allocate + upload the whole group in ONE device dispatch
         # (upload_blocks): per-block uploads cost a dispatch round trip
-        # each, which dominates PD transfer on high-RTT device links.
+        # each, which dominates PD transfer.
         # Staging/commit bookkeeping is the pool's shared definition
         # (kv_cache.stage_adoption — also used by the device path)
         by_hash = {h: d for h, d in zip(hashes, blocks)}

@@ -17,7 +17,9 @@ from .config import CacheConfig, ModelConfig, ParallelConfig
 
 logger = init_logger(__name__)
 
-DEFAULT_HBM_BYTES = 16 * 1024**3  # v5e-class chip
+# stand-in device memory for the CPU backend, which reports none — tests
+# and rehearsals only; an accelerator always states its own bytes_limit
+CPU_ASSUMED_HBM_BYTES = 16 * 1024**3
 # XLA workspace + fragmentation + activation headroom per device
 RESERVE_BYTES = 1024**3
 # extra pool capacity beyond live-sequence needs, kept as LRU prefix-cache room
@@ -115,13 +117,46 @@ def headroom_budget(
 
 
 def device_hbm_bytes() -> int:
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-        if stats and "bytes_limit" in stats:
-            return int(stats["bytes_limit"])
-    except Exception:
-        pass
-    return DEFAULT_HBM_BYTES
+    """Memory of one local device, as the device reports it. Only the CPU
+    backend (no memory_stats) gets the stand-in: a KV pool sized for a chip
+    that is not there would serve, slower, and say nothing."""
+    dev = jax.local_devices()[0]
+    stats = dev.memory_stats()
+    if stats and "bytes_limit" in stats:
+        return int(stats["bytes_limit"])
+    if dev.platform == "cpu":
+        return CPU_ASSUMED_HBM_BYTES
+    raise RuntimeError(
+        f"{dev.platform} device {dev.id} ({dev.device_kind}) reports no "
+        f"bytes_limit (memory_stats() = {stats!r}); refusing to size the KV "
+        "pool from an assumed HBM size"
+    )
+
+
+def device_report(mesh) -> dict:
+    """What this process runs on, as JAX reports it: platform, kind and
+    count of all devices, plus the ids and live memory of the devices in the
+    engine's mesh (memory keys are absent on backends that report none)."""
+    devices = jax.devices()
+    per_device = []
+    for dev in mesh.devices.flat:
+        stats = dev.memory_stats() or {}
+        per_device.append({
+            "id": dev.id,
+            **{
+                k: int(stats[k])
+                for k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+                if k in stats
+            },
+        })
+    return {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+        "mesh_shape": {k: int(v) for k, v in mesh.shape.items()},
+        "mesh_device_ids": [d["id"] for d in per_device],
+        "devices": per_device,
+    }
 
 
 def derive_num_blocks(

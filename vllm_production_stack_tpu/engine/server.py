@@ -1686,7 +1686,8 @@ class EngineServer:
         "GET /debug/flight": "flight-recorder ring + heartbeat table + "
                              "watchdog state (docs/37)",
         "GET /debug/programs": "XLA program inventory: compile walls, "
-                               "dispatch counts, storm state (docs/42)",
+                               "dispatch counts, storm state, and the "
+                               "device they were compiled for (docs/42)",
         "POST /debug/postmortem": "write (or return) a redacted postmortem "
                                   "JSON black box now (docs/37)",
         "POST /debug/profile/start": "start an xprof device capture "
@@ -1770,8 +1771,23 @@ class EngineServer:
         last-used age and HBM footprint, plus cache hit/miss totals and
         the storm detector's state (docs/42-compile-telemetry.md). The
         storm runbook starts here: find the mid_traffic entry, read its
-        key, fix the bucket ladder that let the shape through."""
-        return web.json_response(self.engine.compile_watch.debug_payload())
+        key, fix the bucket ladder that let the shape through. The
+        "device" section says what those programs were compiled FOR:
+        platform, kind, count, the mesh's device ids and live per-device
+        memory, the attention backends as resolved, and whether the native
+        kvhash library loaded — an engine that came up on the CPU serves
+        exactly like one on the chip, only slower, so it says so here."""
+        from ..utils.native import kvhash_backend
+
+        body = self.engine.compile_watch.debug_payload()
+        body["device"] = {
+            **self.engine.runner.device_report(),
+            # off the loop: the first ask may build csrc/kvhash.cpp
+            "kvhash": await asyncio.get_running_loop().run_in_executor(
+                None, kvhash_backend
+            ),
+        }
+        return web.json_response(body)
 
     async def debug_postmortem(self, request: web.Request) -> web.Response:
         """POST /debug/postmortem: dump the black box NOW. With
@@ -2554,9 +2570,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "blocks — lower = tighter KV gathers but more "
                         "compiled programs (see SchedulerConfig)")
     p.add_argument("--decode-window", type=int, default=8,
-                   help="decode iterations fused into one device dispatch; "
-                        "raise on high-RTT links (remote chips) — dispatch "
-                        "overhead amortizes over window x batch tokens, at "
+                   help="decode iterations fused into one device dispatch: "
+                        "dispatch overhead amortizes over window x batch "
+                        "tokens, at "
                         "the cost of up to window-1 discarded tokens past a "
                         "stop condition")
     p.add_argument("--tensor-parallel-size", type=int, default=1)
@@ -2671,13 +2687,14 @@ def build_parser() -> argparse.ArgumentParser:
                         "JAX_COORDINATOR_ADDRESS/JAX_NUM_PROCESSES/"
                         "JAX_PROCESS_ID env the multi-host statefulset "
                         "exports; auto = initialize iff >1 process named")
-    p.add_argument("--compilation-cache-dir",
-                   default="/tmp/vllm-tpu-xla-cache",
+    p.add_argument("--compilation-cache-dir", default=None,
                    help="persistent XLA compilation cache: --warmup costs "
                         "its 20-40s-per-program compiles ONCE per "
                         "(model, bucket-set); every later boot reloads "
-                        "them in seconds. In k8s, mount a PVC here "
-                        "(empty string disables)")
+                        "them in seconds. In k8s, mount a PVC here. "
+                        "Default: a fixed .jax_cache directory in the "
+                        "checkout; ignored when JAX_COMPILATION_CACHE_DIR "
+                        "is set (empty string disables)")
     return p
 
 
@@ -2789,19 +2806,13 @@ def main(argv: list[str] | None = None) -> None:
     # this, jax.devices() spans every host in the slice and the engine's
     # mesh/pjit shardings cover them
     from ..parallel.distributed import maybe_initialize
+    from ..utils.compile_cache import configure_compile_cache
     from ..utils.system import raise_fd_limit
 
     raise_fd_limit()
     maybe_initialize(args.distributed)
-    if args.compilation_cache_dir:
-        import jax
-
-        jax.config.update(
-            "jax_compilation_cache_dir", args.compilation_cache_dir
-        )
-        # the serving program set is all multi-second compiles; cache
-        # everything that costs more than a second
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    cache_dir = configure_compile_cache(args.compilation_cache_dir)
+    logger.info("persistent compile cache: %s", cache_dir or "off")
     config = engine_config_from_args(args)
     logger.info("starting engine for model=%s on %s:%d",
                 args.model, args.host, args.port)

@@ -438,8 +438,8 @@ THREAD_NAME_VALUES = (
 # watchdog trips, by kind (closed set):
 #   stale_heartbeat  a registered loop stopped beating while busy
 #   unresolved_step  a device step was dispatched and never resolved (the
-#                    collective-stall / wedged-tunnel shape: the host is
-#                    alive, the device work never comes back)
+#                    collective-stall shape: the host is alive, the
+#                    device work never comes back)
 # Counted once per stall EPISODE (a 10-minute wedge is one trip, not 600).
 ENGINE_STEP_STALLS = "tpu:engine_step_stalls_total"
 STALL_KIND_VALUES = ("stale_heartbeat", "unresolved_step")

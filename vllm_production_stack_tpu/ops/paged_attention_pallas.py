@@ -255,10 +255,25 @@ def _hist_kernel(
 # Paged PREFILL (chunked-prefill-aware) flash attention
 # --------------------------------------------------------------------------
 
-# query-tile length: q rows resident in VMEM across the page stream. 256
-# keeps (nh, Tt, D) q + f32 (nh*Tt, D) acc under ~3 MB for llama head
-# shapes, leaving room for double-buffered page DMAs
+# query-tile length: q rows resident in VMEM across the page stream, capped
+# so that every head's tile state fits the 16 MiB scoped-VMEM limit the v5e
+# compiler enforces (tests/test_chip_compile.py). Per (head, row) the kernel
+# holds the double-buffered q and out tiles, the f32 acc, and the (rows, 1)
+# m/l scratch — which is lane-padded to 128, so it costs as much as acc at
+# D=128. The budget leaves the rest of the limit to the page double-buffers
+# and the per-head (Tt, bs) score temporaries.
 PREFILL_Q_TILE = 256
+PREFILL_VMEM_BUDGET = 10 * 2**20
+
+
+def _prefill_q_tile(t: int, nh: int, d: int, itemsize: int) -> int:
+    lanes = -(-d // 128) * 128
+    per_row = 4 * itemsize * lanes + 4 * lanes + 2 * 4 * 128
+    rows = PREFILL_VMEM_BUDGET // (nh * per_row)
+    tt = min(t, PREFILL_Q_TILE)
+    while tt > 8 and tt > rows:
+        tt //= 2
+    return tt
 
 
 def _prefill_kernel(
@@ -379,7 +394,7 @@ def paged_prefill_attention(
     b, t, nh, d = q.shape
     kvh, bs = kv.shape[3], kv.shape[2]
     nb = block_tables.shape[1]
-    tt = min(t, PREFILL_Q_TILE)
+    tt = _prefill_q_tile(t, nh, d, q.dtype.itemsize)
     assert t % tt == 0, (t, tt)  # T is a power-of-two bucket
 
     # head-major q so the kernel's per-head slices are static 2D views
@@ -433,7 +448,6 @@ def paged_prefill_attention_sharded(
     placement as paged_decode_attention_sharded: prefill attention is
     embarrassingly parallel over (row, head) once KV pages are head-sharded
     (kv_cache_spec's layout), so no collective is needed."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.mesh import DP_AXIS, TP_AXIS
@@ -445,7 +459,7 @@ def paged_prefill_attention_sharded(
             f"pallas prefill under tp={tp} needs heads divisible by tp "
             f"(num_heads={nh}, num_kv_heads={kvh})"
         )
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             paged_prefill_attention, scale=scale, interpret=interpret
         ),
@@ -458,7 +472,7 @@ def paged_prefill_attention_sharded(
             P(DP_AXIS),  # chunk_start
         ),
         out_specs=P(DP_AXIS, None, TP_AXIS, None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, kv, block_tables, context_lens, chunk_start)
 
@@ -553,7 +567,6 @@ def paged_decode_attention_sharded(
     once KV pages are head-sharded, which is exactly kv_cache_spec's layout
     (parallel/sharding.py — 'each chip only ever touches its own heads'
     pages')."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.mesh import DP_AXIS, TP_AXIS
@@ -565,7 +578,7 @@ def paged_decode_attention_sharded(
             f"pallas under tp={tp} needs heads divisible by tp "
             f"(num_heads={nh}, num_kv_heads={kvh})"
         )
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             paged_decode_attention, scale=scale, interpret=interpret
         ),
@@ -580,7 +593,7 @@ def paged_decode_attention_sharded(
             P(),  # step_k scalar
         ),
         out_specs=P(DP_AXIS, TP_AXIS, None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, kv, block_tables, hist_len, staged_k, staged_v, step_k)
 

@@ -36,14 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.6 promotes shard_map to the top level (check_vma kwarg)
-    _shard_map = jax.shard_map
-    _SHARD_MAP_CHECK_KW = "check_vma"
-except AttributeError:  # 0.4/0.5: experimental module, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_CHECK_KW = "check_rep"
-
 from .mesh import DP_AXIS, SP_AXIS, TP_AXIS
 
 NEG_INF = -1e30
@@ -171,10 +163,10 @@ def ring_attention(
             hspec, hspec, P(DP_AXIS),
         )
         args = (q, k, v, q_pos, q_pos, kv_valid, hist_k, hist_v, hist_len)
-    return _shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=qspec,
-        **{_SHARD_MAP_CHECK_KW: False},
+        check_vma=False,
     )(*args)

@@ -206,6 +206,11 @@ async def black_hole() -> tuple[asyncio.AbstractServer, int]:
                 pass
         except Exception:
             pass
+        finally:
+            # the peer is gone: release the transport, or the caller's
+            # server.wait_closed() waits for it forever (Python >= 3.12.1
+            # waits for every accepted connection to close)
+            writer.close()
 
     server = await asyncio.start_server(swallow, "127.0.0.1", 0)
     port = server.sockets[0].getsockname()[1]

@@ -119,6 +119,12 @@ def _load_kvhash() -> ctypes.CDLL | None:
         return _KVHASH
 
 
+def kvhash_backend() -> str:
+    """'native' when csrc/kvhash.cpp built and loaded (building it now if
+    nothing asked yet), 'python' when the fallback loop serves."""
+    return "native" if _load_kvhash() is not None else "python"
+
+
 def chain_hashes_native(
     parent: int, token_ids, block_size: int
 ) -> list[int] | None:
