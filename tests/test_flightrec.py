@@ -58,7 +58,7 @@ def test_ring_bounds_and_sequence():
     fr = FlightRecorder(capacity=4)
     for i in range(10):
         seq = fr.dispatch("decode", rows=2, tokens=8, waiting=i)
-        fr.resolve(seq, accepted=8)
+        fr.resolve(seq)
     snap = fr.snapshot()
     assert len(snap) == 4  # bounded: oldest dropped
     assert fr.records_total == 20

@@ -1,0 +1,304 @@
+"""The step timeline (engine/flightrec.py PHASES): one phase boundary with
+three sinks — cumulative /debug/timing totals, `step:<phase>` annotations in
+an xprof capture, stamps on the flight records — the device-starvation
+accounting by cause, and the named scopes inside the jitted steps
+(docs/14-tracing.md, docs/37-flight-recorder.md)."""
+
+import asyncio
+import glob
+
+import pytest
+
+from vllm_production_stack_tpu.engine.async_engine import AsyncEngine
+from vllm_production_stack_tpu.engine.config import EngineConfig
+from vllm_production_stack_tpu.engine.engine import LLMEngine
+from vllm_production_stack_tpu.engine.flightrec import (
+    DISPATCH_PHASES,
+    GAP_AFTER,
+    PHASES,
+    FlightRecorder,
+    Watchdog,
+    ThreadRegistry,
+)
+from vllm_production_stack_tpu.engine.request import SamplingParams
+
+# every key /debug/timing's `engine` group served before this timeline
+# existed (bench.py, bench_northstar.py, the chip benchmark's readers and
+# the tests read them)
+PARENT_KEYS = {
+    "sched_s", "post_s", "prefill_s", "prefill_n", "prefill_tokens",
+    "decode_s", "decode_n", "decode_tokens", "dispatch_s", "sync_s",
+    "overlap_s", "step_wall_s", "rollback_n",
+}
+STEP_PHASES = ("sched", "build", "h2d", "enqueue", "sync", "post")
+# the scopes a captured program's HLO metadata names (docs/14-tracing.md)
+LAYER_SCOPES = {"attn_qkv", "kv_stage", "attn", "attn_out", "mlp"}
+PREFILL_SCOPES = LAYER_SCOPES | {"embed", "final_norm", "lm_head", "sample"}
+DECODE_SCOPES = PREFILL_SCOPES | {"kv_commit", "gather_pages"}
+
+
+def _engine(**kw) -> LLMEngine:
+    return LLMEngine(EngineConfig.tiny().replace(**kw))
+
+
+def _greedy(n: int) -> SamplingParams:
+    return SamplingParams(max_tokens=n, temperature=0.0, ignore_eos=True)
+
+
+@pytest.fixture(scope="module")
+def ran():
+    """A pipelined and a serial engine after the same small workload."""
+    out = {}
+    for name, on in (("pipelined", True), ("serial", False)):
+        eng = _engine(async_scheduling=on)
+        eng.generate([[1, 2, 3, 4, 5], list(range(7, 30))], _greedy(24))
+        out[name] = eng
+    return out
+
+
+@pytest.mark.parametrize("key", sorted(PARENT_KEYS))
+def test_every_parent_timing_key_is_still_served(ran, key):
+    assert key in ran["pipelined"].timing
+
+
+@pytest.mark.parametrize("loop", ["pipelined", "serial"])
+def test_phase_totals_reconcile(ran, loop):
+    t = ran[loop].timing
+    assert t["dispatch_n"] == t["prefill_n"] + t["decode_n"] > 0
+    assert sum(t[p + "_s"] for p in DISPATCH_PHASES) == pytest.approx(
+        t["dispatch_s"], rel=1e-9)
+    assert all(t[p + "_s"] > 0 for p in STEP_PHASES)
+    assert sum(t["starved_" + p + "_s"] for p in PHASES) == pytest.approx(
+        t["starved_s"], rel=1e-9)
+    assert sum(t["starved_after_" + k + "_n"] for k in GAP_AFTER) \
+        == t["starved_n"] > 0
+    # the phases of a step call cover it: what runs between two spans is a
+    # few Python statements
+    covered = sum(t[p + "_s"] for p in STEP_PHASES)
+    assert covered == pytest.approx(t["step_wall_s"], rel=0.01)
+    assert 0 < t["starved_s"] < t["clock_s"]
+
+
+def test_serial_loop_starves_after_every_dispatch(ran):
+    t = ran["serial"].timing
+    # the first dispatch ends the gap open since `start`; each later one
+    # ends the gap its predecessor's resolve opened
+    assert t["starved_n"] == t["dispatch_n"]
+    assert t["starved_after_start_n"] == 1
+    assert t["starved_after_decode_n"] == t["decode_n"] - 1 \
+        or t["starved_after_decode_n"] == t["decode_n"]
+
+
+def _delta(eng, before):
+    now = eng.timing
+    return {k: now[k] - before[k] for k in now}
+
+
+def test_prefill_drains_the_pipeline_and_chained_decodes_do_not():
+    """decode -> decode -> prefill -> decode: exactly one gap, after the
+    prefill, and it sits on the flight record of the decode that ended it."""
+    eng = _engine(async_scheduling=True)
+    eng.add_request("a", prompt_token_ids=[1, 2, 3, 4, 5, 6],
+                    sampling=_greedy(200))
+    while eng.timing["decode_n"] < 2:  # a's prefill, then chained windows
+        eng.step()
+    before = eng.timing
+    for _ in range(3):  # decode -> decode: the queue never empties
+        eng.step()
+    d = _delta(eng, before)
+    assert d["decode_n"] == 3 and d["prefill_n"] == 0
+    assert d["starved_n"] == 0 and d["starved_s"] == 0
+
+    before = eng.timing
+    eng.add_request("b", prompt_token_ids=[9, 8, 7, 6, 5],
+                    sampling=_greedy(200))
+    while _delta(eng, before)["prefill_n"] == 0:
+        eng.step()  # b's prefill chunk, resolved in the same call
+    while _delta(eng, before)["decode_n"] < 3:
+        eng.step()
+    d = _delta(eng, before)
+    assert d["prefill_n"] == 1
+    assert d["starved_n"] == 1
+    assert d["starved_after_prefill_n"] == 1
+    assert d["starved_after_decode_n"] == 0 == d["rollback_n"]
+    assert d["starved_s"] > 0
+    assert d["starved_s"] == pytest.approx(
+        sum(d["starved_" + p + "_s"] for p in PHASES), rel=1e-9)
+    gaps = [r for r in eng.flightrec.snapshot()
+            if r["event"] == "dispatch" and r["gap"]]
+    last = gaps[-1]
+    assert last["kind"] == "decode" and last["gap"]["after"] == "prefill"
+    assert last["gap"]["ms"] == pytest.approx(
+        sum(last["gap"]["phases_ms"].values()), abs=0.01)
+    assert set(last["gap"]["phases_ms"]) <= set(PHASES)
+    # the stamps: name, start, end on the monotonic clock, and the seq
+    names = {p[0] for p in last["phases"]}
+    assert {"sched", "build", "h2d", "enqueue"} <= names
+    for name, start, end, seq in last["phases"]:
+        assert start <= end <= last["mono_ns"]
+        if name in DISPATCH_PHASES:
+            assert seq == last["seq"]
+
+
+def test_rolled_back_dispatch_does_not_empty_the_queue():
+    rec = FlightRecorder()
+    a = rec.dispatch("decode", rows=1, tokens=4)
+    b = rec.dispatch("decode", rows=1, tokens=4)  # chained behind a
+    n0 = rec.timing()["starved_n"]  # the gap since `start` ended at a
+    rec.resolve(a)
+    rec.discard(b)  # the device still runs b
+    assert rec.outstanding_age_s() is None  # no stall candidate...
+    c = rec.dispatch("decode", rows=1, tokens=4)
+    assert rec.timing()["starved_n"] == n0  # ...and no gap ended at c
+    with rec.span("post"):
+        rec.resolve(c)  # completes b too: now the queue is empty
+    d = rec.dispatch("prefill", rows=1, tokens=8)
+    t = rec.timing()
+    assert t["starved_n"] == n0 + 1 and t["starved_after_decode_n"] == 1
+    assert rec.snapshot()[-1]["gap"]["after"] == "decode"
+    rec.resolve(d)
+
+
+def test_engine_rollback_opens_no_gap():
+    """A stop token inside a decode window discards the speculative window
+    dispatched behind it; the next decode is enqueued behind the discarded
+    one, so the device's queue never emptied."""
+    short = stop_at = None
+    for seed in range(1, 16):  # a greedy stream that does not degenerate
+        short = [(seed * 37 + i * 11) % 200 + 1 for i in range(7)]
+        ref = _engine().generate([short], _greedy(24))[0]["token_ids"]
+        cand = [t for t in ref[3:] if ref.index(t) >= 3]
+        if cand:
+            stop_at = cand[0]
+            break
+    assert stop_at is not None, "no non-degenerate greedy stream found"
+    eng = _engine(async_scheduling=True)
+    eng.add_request("long", prompt_token_ids=[1, 2, 3, 4],
+                    sampling=_greedy(64))
+    eng.add_request("short", prompt_token_ids=short,
+                    sampling=SamplingParams(max_tokens=24, temperature=0.0,
+                                            stop_token_ids=(stop_at,)))
+    while eng.has_unfinished():
+        eng.step()
+    t = eng.timing
+    assert t["rollback_n"] >= 1 and t["decode_n"] > 2
+    assert t["starved_after_decode_n"] == 0
+
+
+def test_quiet_loop_counts_a_trailing_rollback_as_emptied():
+    rec = FlightRecorder(enabled=False)
+    a = rec.dispatch("decode", rows=1, tokens=4)
+    b = rec.dispatch("decode", rows=1, tokens=4)
+    rec.resolve(a)
+    rec.discard(b)
+    rec.quiet()  # nothing left to run: b will never be resolved
+    with rec.span("idle"):
+        pass
+    rec.dispatch("prefill", rows=1, tokens=8)
+    t = rec.timing()
+    assert t["starved_after_decode_n"] == 1
+    assert t["starved_idle_s"] > 0 and t["starved_idle_s"] == t["starved_s"]
+
+
+def test_recording_off_keeps_counters_and_watchdog_cursor():
+    eng = _engine(flight_recording=False)
+    eng.generate([[1, 2, 3, 4, 5]], _greedy(12))
+    t = eng.timing
+    assert t["dispatch_n"] > 0 and t["starved_n"] > 0 and t["starved_s"] > 0
+    assert all(t[p + "_s"] > 0 for p in STEP_PHASES)
+    assert eng.flightrec.snapshot() == [] and not eng.flightrec._stamps
+    rec = eng.flightrec
+    rec.dispatch("decode", rows=1, tokens=4)
+    assert rec.outstanding_age_s()[1] == "decode"
+    dog = Watchdog(ThreadRegistry(), rec, stall_after_s=-1.0)
+    report = dog.check()
+    assert report["findings"][0]["kind"] == "unresolved_step"
+
+
+def test_async_loop_phases_and_loop_group():
+    eng = _engine()
+    async_engine = AsyncEngine(eng)
+
+    async def go():
+        async_engine.start(asyncio.get_running_loop())
+        try:
+            async for _ in async_engine.generate(
+                    prompt_token_ids=[1, 2, 3, 4], sampling=_greedy(12)):
+                pass
+            await asyncio.sleep(0.1)  # a few idle rounds
+        finally:
+            async_engine.shutdown()
+
+    asyncio.run(go())
+    t = eng.timing
+    assert all(t[p + "_s"] > 0 for p in PHASES), t
+    assert t["starved_idle_s"] > 0
+    loop = async_engine.loop_snapshot()
+    assert set(loop) == {"steps", "busy_s", "idle_s", "submits", "submit_s"}
+    assert loop["steps"] > 0 and loop["submits"] == 1
+    assert loop["idle_s"] == t["idle_s"]
+
+
+def test_capture_holds_step_spans_and_clock_pair(tmp_path):
+    """A capture on the CPU backend: `step:*` events with their seq on the
+    step thread's line, and the clock pair's own event."""
+    import jax
+    from jax.profiler import ProfileData
+
+    eng = _engine()
+    async_engine = AsyncEngine(eng)
+
+    async def go():
+        async_engine.start(asyncio.get_running_loop())
+        try:
+            async for _ in async_engine.generate(
+                    prompt_token_ids=[1, 2, 3], sampling=_greedy(4)):
+                pass  # compiled before the capture starts
+            jax.profiler.start_trace(str(tmp_path))
+            pair = eng.flightrec.clock_pair()
+            async for _ in async_engine.generate(
+                    prompt_token_ids=[4, 5, 6, 7], sampling=_greedy(12)):
+                pass
+            await asyncio.sleep(0.05)
+            jax.profiler.stop_trace()
+            return pair
+        finally:
+            async_engine.shutdown()
+
+    pair = asyncio.run(go())
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[-1]
+    host = next(p for p in ProfileData.from_file(path).planes
+                if p.name == "/host:CPU")
+    step_line = next(ln for ln in host.lines if ln.name == "engine-step")
+    seen = {}
+    for ev in step_line.events:
+        if ev.name.startswith("step:"):
+            seen.setdefault(ev.name[5:], dict(ev.stats))
+    assert set(seen) == set(PHASES)
+    assert all(stats["seq"] > 0 for stats in seen.values())
+    assert seen["sync"]["kind"] in ("prefill", "decode")
+    marks = [dict(ev.stats) for ln in host.lines for ev in ln.events
+             if ev.name == "clock_pair"]
+    assert marks == [pair]
+
+
+def _scopes(hlo: str) -> set[str]:
+    import re
+
+    found = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', hlo):
+        found.update(op_name.split("/"))
+    return found
+
+
+def test_programs_name_their_scopes():
+    """The documented scope names are in the compiled programs' HLO
+    metadata (what the device planes' name-scope line shows)."""
+    runner = _engine().runner
+    nb = runner._width_bucket(runner.max_blocks)
+    pre = ("prefill", 2, 32, nb, False, False, False)
+    dec = ("decode", 4, nb, 4, False, False, None)
+    for key in (pre, dec):
+        assert runner._compile_key_now(key, "warmup")
+    assert PREFILL_SCOPES <= _scopes(runner._aot_exec[pre].as_text())
+    assert DECODE_SCOPES <= _scopes(runner._aot_exec[dec].as_text())

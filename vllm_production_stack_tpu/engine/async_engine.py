@@ -18,6 +18,7 @@ from collections import deque
 from collections.abc import AsyncIterator
 
 from .engine import LLMEngine
+from .flightrec import FlightRecorder
 from .request import RequestOutput, SamplingParams
 
 logger = logging.getLogger(__name__)
@@ -41,6 +42,18 @@ def _same_request(rid: str, parent: str) -> bool:
     """True when `rid` is `parent` itself or one of its n>1 sibling choice
     ids (server._choice_rids derivation: parent, parent-1, parent-2, …)."""
     return rid == parent or rid.startswith(parent + "-")
+
+
+def _name_os_thread(name: str) -> None:
+    """Give the calling thread its name at the OS level too (Python 3.12
+    names threads only inside the interpreter): a profiler capture and
+    `top -H` label the thread's line by it. Best effort, Linux only."""
+    try:
+        with open(f"/proc/self/task/{threading.get_native_id()}/comm",
+                  "w") as f:
+            f.write(name[:15])
+    except OSError:
+        pass
 
 
 class AsyncEngine:
@@ -85,13 +98,15 @@ class AsyncEngine:
         # postmortem dumper — the dying step thread writes its own black
         # box before the /health flip is even scraped)
         self.on_fatal = None
-        # served-stack profiling (exposed via /debug/timing): where the step
-        # thread's wall time goes, and how long submissions wait on the
-        # engine lock behind it
-        self.loop_timing = {
-            "steps": 0, "busy_s": 0.0, "idle_s": 0.0,
-            "submits": 0, "submit_s": 0.0,  # tokenize+validate+queue time
-        }
+        # the step thread's timeline (flightrec.PHASES): this loop's admit,
+        # emit and idle phases land beside the engine's own. A test double
+        # without a flight recorder gets a private one.
+        self._clock = getattr(engine, "flightrec", None) or FlightRecorder(
+            enabled=False
+        )
+        # the asyncio side of /debug/timing's `loop` group:
+        # tokenize+validate+queue time of submissions
+        self.loop_timing = {"submits": 0, "submit_s": 0.0}
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -169,29 +184,41 @@ class AsyncEngine:
     # better (no pod churn, warm compile caches survive)
     MAX_CONSECUTIVE_STEP_FAILURES = 3
 
+    def loop_snapshot(self) -> dict:
+        """/debug/timing's `loop` group. busy_s: iterations that ran a step
+        (the lock, admissions, step()); idle_s: parked on the wake event."""
+        t = self._clock.timing()
+        return {
+            "steps": t["steps"], "busy_s": t["admit_s"] + t["step_wall_s"],
+            "idle_s": t["idle_s"], **self.loop_timing,
+        }
+
     def _step_loop(self) -> None:
-        lt = self.loop_timing
+        _name_os_thread("engine-step")
+        clock = self._clock
         hb = self._heartbeat
         failures = 0
         while not self._stop:
-            t0 = time.perf_counter()
             if hb is not None:
                 # every iteration, idle path included: staleness then means
                 # "wedged inside a step", never "no traffic"
                 hb.beat()
             try:
-                with self._lock:
-                    self._drain_pending()
+                with clock.span("admit"):
+                    self._lock.acquire()
+                try:
+                    with clock.span("admit"):
+                        self._drain_pending()
                     has_work = (
                         not self.engine.is_sleeping and self.engine.has_unfinished()
                     )
                     outputs = self.engine.step() if has_work else []
+                finally:
+                    self._lock.release()
                 failures = 0
             except Exception as e:
                 failures += 1
-                fr = getattr(self.engine, "flightrec", None)
-                if fr is not None:
-                    fr.fault(str(e))
+                clock.fault(str(e))
                 if failures >= self.MAX_CONSECUTIVE_STEP_FAILURES:
                     # persistent fault: surface to /health, fail everything
                     logger.exception(
@@ -220,16 +247,17 @@ class AsyncEngine:
                     self._fail_all(e)
                     return
                 continue
+            if outputs:
+                with clock.span("emit", seq=clock.done_seq):
+                    for out in outputs:
+                        self._dispatch(out)
             if has_work:
-                lt["steps"] += 1
-                lt["busy_s"] += time.perf_counter() - t0
-            for out in outputs:
-                self._dispatch(out)
-            if not has_work:
-                t1 = time.perf_counter()
-                self._wake.wait(timeout=0.02)
-                self._wake.clear()
-                lt["idle_s"] += time.perf_counter() - t1
+                clock.count("steps")
+            else:
+                clock.quiet()
+                with clock.span("idle"):
+                    self._wake.wait(timeout=0.02)
+                    self._wake.clear()
 
     def _drain_pending(self) -> None:
         """Admit queued submissions (caller holds the engine lock). The

@@ -8,6 +8,7 @@ scraper contract expects (SURVEY §5 metrics contract).
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import logging
 import threading
@@ -125,13 +126,13 @@ class _InflightStep:
 
     work: DecodeWork | VerifyWork
     handle: StepHandle
-    # set once the handle's results were synced to the host — a step that
-    # faults before this must be restored as the in-flight step
-    resolved: bool = False
-    # flight-recorder dispatch seq (docs/37-flight-recorder.md): the
-    # resolve/discard record names the dispatch it closes, and the
-    # watchdog's unresolved-step detection keys off the open cursor
-    rec_seq: int = 0
+
+
+def _work_kind(work) -> str:
+    """The flight recorder's name for a dispatch (flightrec.GAP_AFTER)."""
+    if isinstance(work, PrefillWork):
+        return "prefill"
+    return "verify" if isinstance(work, VerifyWork) else "decode"
 
 
 class LLMEngine:
@@ -227,6 +228,8 @@ class LLMEngine:
             capacity=config.flight_records, enabled=config.flight_recording
         )
         self.threads = ThreadRegistry()
+        # the runner's build/h2d/enqueue/sync spans land on the same timeline
+        self.runner.clock = self.flightrec
         # step quiescence lock: anything that mutates runner.kv_caches or
         # pool bookkeeping off the step thread (the device-collective peer
         # pull donates + reassigns the cache arrays) takes this around the
@@ -497,20 +500,6 @@ class LLMEngine:
         # mid-decode deadline expiry separately — stats() sums them)
         self.shed_requests = 0
         self.deadline_admission_rejects = 0
-        # step-phase wall-time decomposition (served-stack profiling; the
-        # async server exposes this via /debug/timing). dispatch_s = host
-        # time building + enqueueing device work; sync_s = host time
-        # blocked in the per-step D2H result transfer; overlap_s = host
-        # time that ran while a device step was in flight (the pipeline's
-        # win); step_wall_s = total step() wall; rollback_n = speculative
-        # steps discarded because a stop/finish/abort invalidated them.
-        self.timing: dict[str, float | int] = {
-            "sched_s": 0.0, "post_s": 0.0,
-            "prefill_s": 0.0, "prefill_n": 0, "prefill_tokens": 0,
-            "decode_s": 0.0, "decode_n": 0, "decode_tokens": 0,
-            "dispatch_s": 0.0, "sync_s": 0.0,
-            "overlap_s": 0.0, "step_wall_s": 0.0, "rollback_n": 0,
-        }
         # two-deep pipelined step loop (config.async_scheduling): dispatch
         # step N+1 against speculatively-advanced scheduler state before
         # step N's tokens reach the host. Speculative decoding COMPOSES
@@ -548,6 +537,16 @@ class LLMEngine:
         # silently mark lossy KV as byte-identical to locally computed KV.
 
     # -- structured output -------------------------------------------------
+
+    @property
+    def timing(self) -> dict[str, float | int]:
+        """Step-phase wall-time decomposition (/debug/timing → `engine`):
+        the flight recorder's cumulative totals (flightrec.PHASES, the
+        starvation counters, and the keys that predate them: dispatch_s =
+        build + h2d + enqueue, overlap_s = host time that ran while a device
+        step was in flight, step_wall_s = total step() wall, rollback_n =
+        speculative steps discarded)."""
+        return self.flightrec.timing()
 
     @property
     def grammar_cache(self):
@@ -1090,8 +1089,9 @@ class LLMEngine:
         estimate for the process's whole life (a 3 tok/s "observed rate"
         right after boot made the admission gate shed everything). 0.0
         before the first decode resolves (callers treat as "unknown")."""
-        dec_s = float(self.timing["decode_s"])
-        dec_t = float(self.timing["decode_tokens"])
+        timing = self.timing
+        dec_s = float(timing["decode_s"])
+        dec_t = float(timing["decode_tokens"])
         if dec_s > 0.0 and dec_t > 0:
             return dec_t / dec_s
         return 0.0
@@ -1253,13 +1253,14 @@ class LLMEngine:
         return self._step_pipelined()
 
     def _step_pipelined(self) -> list[RequestOutput]:
-        t_enter = time.perf_counter()
         outputs: list[RequestOutput] = []
         inflight, self._inflight = self._inflight, None
         try:
-            return self._step_pipelined_inner(inflight, outputs, t_enter)
+            with self.flightrec.wall("step_wall_s"):
+                self._step_pipelined_inner(inflight, outputs)
+            return outputs
         except Exception:
-            if inflight is not None and not inflight.resolved:
+            if inflight is not None and not inflight.handle.resolved:
                 # the fault hit before the previous step was resolved (e.g.
                 # a transient dispatch failure) — put it back so its
                 # results aren't stranded; the next step (or the async
@@ -1267,66 +1268,114 @@ class LLMEngine:
                 self._inflight = inflight
             raise
 
+    def _schedule(self, outputs: list[RequestOutput], inflight=None):
+        """schedule() as the `sched` phase. Requests the scheduler
+        terminated outside a step (impossible-fit re-admission abort,
+        expired deadline) still need a terminal output or streaming
+        clients would hang forever."""
+        with self.flightrec.span("sched"):
+            work = self.scheduler.schedule(inflight=inflight)
+            for req in self.scheduler.take_finished_externally():
+                outputs.append(
+                    self._make_output(
+                        req, [], "", self._finish_reason(req) or "abort"
+                    )
+                )
+        return work
+
+    def _dispatch(self, work, prev: StepHandle | None = None):
+        """Build, copy and enqueue one program: the `build` phase, with the
+        runner's `h2d` and `enqueue` inside it. The flight record is cut
+        when the enqueue has returned — the device has work from there."""
+        kind = _work_kind(work)
+        with self.flightrec.span("build", kind=kind):
+            handle = self.runner.execute_async(work, prev=prev)
+            if kind != "prefill":
+                # rows advance speculatively by their fed length (full
+                # acceptance): the next step can chain off this handle
+                self.scheduler.begin_speculative(work)
+        handle.seq, handle.kind = self._rec_dispatch(work, kind), kind
+        return handle
+
+    def _resolve(self, work, handle: StepHandle,
+                 outputs: list[RequestOutput]) -> None:
+        """One dispatch's single host sync (`sync`, inside the handle),
+        then its results reconciled into the scheduler and emitted
+        (`post`). prefill_s / decode_s: the host's wait per dispatch."""
+        rec, kind = self.flightrec, handle.kind
+        key = "prefill" if kind == "prefill" else "decode"
+        with rec.span("post", handle.seq, kind):
+            with rec.wall(key + "_s"):
+                rows = handle.resolve()
+                rec.resolve(handle.seq)
+            rec.count(key + "_n")
+            if kind != "prefill":
+                self.scheduler.end_speculative(work)
+            results = self.scheduler.postprocess(work, rows)
+            if kind == "prefill":
+                # chunk tokens PROCESSED (mid-prompt chunks emit none)
+                rec.count(
+                    "prefill_tokens", sum(len(t) for t in work.token_ids)
+                )
+                self._meter_prefill(work)
+            else:
+                # tokens actually ACCEPTED — exact for both the fused
+                # window (mid-window stops discard the tail) and
+                # spec-decode verify (1..k+1 accepted per row)
+                accepted = sum(len(t) for _, t in results)
+                rec.count("decode_tokens", accepted)
+                self._meter_decode(work, accepted)
+            self._emit_results(results, handle.logprob_rows, outputs)
+
+    def _execute_sync(self, work, outputs: list[RequestOutput]) -> None:
+        """Dispatch and resolve at once: here the host's wait per dispatch
+        (prefill_s / decode_s) takes in the dispatch too."""
+        key = "prefill_s" if isinstance(work, PrefillWork) else "decode_s"
+        with self.flightrec.wall(key):
+            handle = self._dispatch(work)
+        self._resolve(work, handle, outputs)
+
+    def _rollback(self, nxt: _InflightStep) -> None:
+        self.scheduler.rollback_speculative(nxt.work)
+        nxt.handle.discard()
+        self.flightrec.discard(nxt.handle.seq)
+        self._ledger_rollback(nxt.work)
+
     def _step_pipelined_inner(
         self,
         inflight: _InflightStep | None,
         outputs: list[RequestOutput],
-        t_enter: float,
-    ) -> list[RequestOutput]:
-        t0 = time.perf_counter()
-        work = self.scheduler.schedule(
-            inflight=inflight.work if inflight else None
-        )
-        t1 = time.perf_counter()
-        self.timing["sched_s"] += t1 - t0
-        # requests the scheduler terminated outside a step (impossible-fit
-        # re-admission abort, expired deadline) still need a terminal
-        # output or streaming clients would hang forever
-        for req in self.scheduler.take_finished_externally():
-            outputs.append(
-                self._make_output(
-                    req, [], "", self._finish_reason(req) or "abort"
-                )
+    ) -> None:
+        rec = self.flightrec
+        # everything up to the resolve runs while the previous step is
+        # still executing on device — the overlap the pipeline buys
+        with rec.wall("overlap_s") if inflight else contextlib.nullcontext():
+            work = self._schedule(
+                outputs, inflight=inflight.work if inflight else None
             )
-        nxt: _InflightStep | None = None
-        pre_handle: StepHandle | None = None
-        pre_seq = 0
-        if isinstance(work, (DecodeWork, VerifyWork)):
-            # a verify dispatch pipelines exactly like a decode window: its
-            # rows advance speculatively by their fed length (full
-            # acceptance) and the next step can chain a decode window off
-            # the handle's on-device bonus-token vector
-            handle = self.runner.execute_async(
-                work, prev=inflight.handle if inflight else None
-            )
-            self.scheduler.begin_speculative(work)
-            self.timing["dispatch_s"] += time.perf_counter() - t1
-            nxt = _InflightStep(
-                work=work, handle=handle, rec_seq=self._rec_dispatch(work)
-            )
-        elif isinstance(work, PrefillWork):
-            # dispatched before resolving the in-flight decode so the host
-            # array building overlaps device execution; resolved below in
-            # this same call (prefill outputs are never speculated on)
-            pre_handle = self.runner.execute_async(work)
-            self.timing["dispatch_s"] += time.perf_counter() - t1
-            pre_seq = self._rec_dispatch(work)
+            nxt: _InflightStep | None = None
+            pre_handle: StepHandle | None = None
+            if isinstance(work, (DecodeWork, VerifyWork)):
+                # a verify dispatch pipelines exactly like a decode window
+                nxt = _InflightStep(work=work, handle=self._dispatch(
+                    work, prev=inflight.handle if inflight else None
+                ))
+            elif isinstance(work, PrefillWork):
+                # dispatched before resolving the in-flight decode so the
+                # host array building overlaps device execution; resolved
+                # below in this same call (prefill outputs are never
+                # speculated on)
+                pre_handle = self._dispatch(work)
         if inflight is not None:
-            # everything since step entry ran while the previous step was
-            # still executing on device — the overlap the pipeline buys
-            self.timing["overlap_s"] += time.perf_counter() - t_enter
             try:
-                self._resolve_decode(inflight, outputs)
+                self._resolve(inflight.work, inflight.handle, outputs)
             except Exception:
                 # the previous step's resolve faulted AFTER nxt was
                 # dispatched: roll nxt back too, or its speculative window
                 # would leak (rows stuck one window ahead with their
                 # sampled tokens silently dropped)
                 if nxt is not None:
-                    self.scheduler.rollback_speculative(nxt.work)
-                    nxt.handle.discard()
-                    self.flightrec.discard(nxt.rec_seq)
-                    self._ledger_rollback(nxt.work)
+                    self._rollback(nxt)
                 raise
             if nxt is not None and not self.scheduler.speculation_valid(
                 nxt.work
@@ -1335,11 +1384,8 @@ class LLMEngine:
                 # dispatch: discard it wholesale. The serial re-dispatch on
                 # the next call reproduces the exact token stream (RNG is
                 # rewound by discard()).
-                self.scheduler.rollback_speculative(nxt.work)
-                nxt.handle.discard()
-                self.flightrec.discard(nxt.rec_seq)
-                self.timing["rollback_n"] += 1
-                self._ledger_rollback(nxt.work)
+                self._rollback(nxt)
+                rec.count("rollback_n")
                 nxt = None
         if work is None and inflight is not None and nxt is None:
             # a priority stall: the scheduler declined to chain because a
@@ -1347,17 +1393,9 @@ class LLMEngine:
             # victims are resolved now — re-schedule IN THIS CALL so the
             # preempt-and-admit happens a full loop round-trip sooner
             # (the realtime arrival's TTFT is the point of the stall).
-            t4 = time.perf_counter()
-            work2 = self.scheduler.schedule()
-            self.timing["sched_s"] += time.perf_counter() - t4
-            for req in self.scheduler.take_finished_externally():
-                outputs.append(
-                    self._make_output(
-                        req, [], "", self._finish_reason(req) or "abort"
-                    )
-                )
+            work2 = self._schedule(outputs)
             if work2 is not None:
-                self._execute_sync(work2, outputs, time.perf_counter())
+                self._execute_sync(work2, outputs)
         if (
             work is None
             and inflight is None
@@ -1371,122 +1409,41 @@ class LLMEngine:
             # the planner priced
             time.sleep(0.001)
         if pre_handle is not None:
-            t2 = time.perf_counter()
-            rows = pre_handle.resolve()
-            self.flightrec.resolve(pre_seq)
-            t3 = time.perf_counter()
-            self.timing["sync_s"] += pre_handle.sync_s
-            self.timing["prefill_s"] += t3 - t2
-            self.timing["prefill_n"] += 1
-            self.timing["prefill_tokens"] += sum(
-                len(t) for t in work.token_ids
-            )
-            results = self.scheduler.postprocess(work, rows)
-            self._meter_prefill(work)
-            self._emit_results(results, pre_handle.logprob_rows, outputs)
-            self.timing["post_s"] += time.perf_counter() - t3
+            self._resolve(work, pre_handle, outputs)
         self._inflight = nxt
-        self.timing["step_wall_s"] += time.perf_counter() - t_enter
-        self._drop_finished(outputs)
-        return outputs
-
-    def _resolve_decode(
-        self, inflight: _InflightStep, outputs: list[RequestOutput]
-    ) -> None:
-        """Resolve the in-flight decode step — the decode hot path's single
-        host sync — then reconcile its real results into the scheduler."""
-        work, handle = inflight.work, inflight.handle
-        t0 = time.perf_counter()
-        rows = handle.resolve()
-        inflight.resolved = True
-        self.flightrec.resolve(inflight.rec_seq)
-        t1 = time.perf_counter()
-        self.timing["sync_s"] += handle.sync_s
-        self.timing["decode_s"] += t1 - t0
-        self.timing["decode_n"] += 1
-        self.scheduler.end_speculative(work)
-        results = self.scheduler.postprocess(work, rows)
-        accepted = sum(len(t) for _, t in results)
-        self.timing["decode_tokens"] += accepted
-        self._meter_decode(work, accepted)
-        self._emit_results(results, handle.logprob_rows, outputs)
-        self.timing["post_s"] += time.perf_counter() - t1
+        with rec.span("post"):
+            self._drop_finished(outputs)
 
     def _step_sync(self) -> list[RequestOutput]:
         """The serial fallback loop: schedule → execute → sync →
         postprocess, one step per call (async_scheduling=False)."""
-        t0 = time.perf_counter()
-        work = self.scheduler.schedule()
-        t1 = time.perf_counter()
-        self.timing["sched_s"] += t1 - t0
         outputs: list[RequestOutput] = []
-        # requests the scheduler terminated outside a step still need a
-        # terminal output or streaming clients would hang forever
-        for req in self.scheduler.take_finished_externally():
-            outputs.append(
-                self._make_output(
-                    req, [], "", self._finish_reason(req) or "abort"
-                )
-            )
-        if work is None:
-            if self.scheduler.hydration_parked():
+        with self.flightrec.wall("step_wall_s"):
+            work = self._schedule(outputs)
+            if work is not None:
+                self._execute_sync(work, outputs)
+            elif self.scheduler.hydration_parked():
                 time.sleep(0.001)  # see the pipelined loop's parked note
-            self._drop_finished(outputs)
-            return outputs
-        self._execute_sync(work, outputs, t1)
-        self.timing["step_wall_s"] += time.perf_counter() - t0
-        self._drop_finished(outputs)
+            with self.flightrec.span("post"):
+                self._drop_finished(outputs)
         return outputs
-
-    def _execute_sync(self, work, outputs: list[RequestOutput], t1: float):
-        seq = self._rec_dispatch(work)
-        sampled = self.runner.execute(work)
-        self.flightrec.resolve(seq)
-        t2 = time.perf_counter()
-        kind = "prefill" if isinstance(work, PrefillWork) else "decode"
-        self.timing[kind + "_s"] += t2 - t1
-        self.timing[kind + "_n"] += 1
-        self.timing["sync_s"] += self.runner.last_sync_s
-        self.timing["dispatch_s"] += max(
-            0.0, (t2 - t1) - self.runner.last_sync_s
-        )
-        lp_rows = self.runner.last_logprobs  # parallel to sampled rows
-        results = self.scheduler.postprocess(work, sampled)
-        self.timing[kind + "_tokens"] += (
-            # chunk tokens PROCESSED (mid-prompt chunks emit none)
-            sum(len(t) for t in work.token_ids)
-            if kind == "prefill"
-            # tokens actually ACCEPTED — exact for both the fused window
-            # (mid-window stops discard the tail) and spec-decode verify
-            # (1..k+1 accepted per row)
-            else sum(len(toks) for _, toks in results)
-        )
-        if kind == "prefill":
-            self._meter_prefill(work)
-        else:
-            self._meter_decode(work, sum(len(toks) for _, toks in results))
-        self._emit_results(results, lp_rows, outputs)
-        self.timing["post_s"] += time.perf_counter() - t2
 
     # -- flight recorder (docs/37-flight-recorder.md) ----------------------
 
-    def _rec_dispatch(self, work) -> int:
+    def _rec_dispatch(self, work, kind: str) -> int:
         """One black-box record per device dispatch: batch shape + phase,
         the scheduler's decision summary, and queue/pool depths — what the
         engine was doing right before it (maybe) stopped doing anything.
-        Also opens the dispatch/resolve cursor the watchdog's
-        unresolved-step detection keys off (tracked even with recording
-        disabled)."""
+        Also advances the dispatch/resolve cursor the watchdog's
+        unresolved-step detection and the starvation accounting key off
+        (tracked even with recording disabled)."""
         sched = self.scheduler
-        if isinstance(work, PrefillWork):
-            kind, window = "prefill", 0
-            tokens = sum(len(t) for t in work.token_ids)
-        elif isinstance(work, VerifyWork):
-            kind, window = "verify", 0
-            tokens = sum(len(t) for t in work.token_ids)
-        else:
-            kind, window = "decode", work.window
+        if kind == "decode":
+            window = work.window
             tokens = work.window * len(work.requests)
+        else:
+            window = 0
+            tokens = sum(len(t) for t in work.token_ids)
         if self.flightrec.enabled:
             # the O(batch) queue/pool summary is only worth computing
             # when a record will actually be written; the liveness
@@ -1966,6 +1923,11 @@ class LLMEngine:
 
     # -- stats / control ---------------------------------------------------
 
+    def _overlap_frac(self) -> float:
+        timing = self.timing
+        wall = timing["step_wall_s"]
+        return timing["overlap_s"] / wall if wall > 0 else 0.0
+
     def stats(self) -> EngineStatsSnapshot:
         pool = self.scheduler.pool
         tenants, waits = self.scheduler.accounting.snapshot(drain_waits=True)
@@ -1995,11 +1957,7 @@ class LLMEngine:
                 self.deadline_admission_rejects
                 + self.scheduler.deadline_expired_total
             ),
-            step_overlap_frac=(
-                self.timing["overlap_s"] / self.timing["step_wall_s"]
-                if self.timing["step_wall_s"] > 0
-                else 0.0
-            ),
+            step_overlap_frac=self._overlap_frac(),
             spec_draft_tokens=self.scheduler.spec_proposed_tokens,
             spec_accepted_tokens=self.scheduler.spec_accepted_tokens,
             spec_proposed_by=dict(self.scheduler.spec_proposed_by),

@@ -83,15 +83,116 @@ DEFAULT_STALL_AFTER_S = 120.0
 DEFAULT_BG_COMPILE_STALL_S = 900.0
 
 
+# -- the step timeline ---------------------------------------------------
+# Phases of the step thread, in the order one loop iteration meets them.
+# Each is a `step:<phase>` TraceAnnotation in an xprof capture, a cumulative
+# `<phase>_s` in /debug/timing and (ring on) a stamp on the flight record.
+# A stable, documented list (docs/14-tracing.md), like the kernels' names.
+PHASES = (
+    "admit",  # engine lock + _drain_pending (queued submissions -> scheduler)
+    "sched",  # Scheduler.schedule() and what it terminated on the way
+    "build",  # host arrays of one dispatch: tokens, block tables, masks
+    "h2d",  # the device_put calls of that dispatch
+    "enqueue",  # the jitted call (a first-seen shape compiles here)
+    "sync",  # blocked in the step's one device_get
+    "post",  # rows -> scheduler.postprocess, metering, detokenize, outputs
+    "emit",  # handing the outputs to the asyncio side
+    "idle",  # nothing to run: parked on the wake event
+)
+_SPAN_NAMES = {p: "step:" + p for p in PHASES}  # nothing formatted per span
+DISPATCH_PHASES = ("build", "h2d", "enqueue")  # dispatch_s is their sum
+# what emptied the device's queue before a starvation gap
+GAP_AFTER = ("start", "prefill", "decode", "verify")
+
+_TRACE_ANNOTATION = None
+
+
+def _trace_annotation():
+    """jax.profiler.TraceAnnotation, resolved on the first span: this module
+    is imported by the router and the KV controller, which never import JAX.
+    Without a live capture one annotation costs well under a microsecond and
+    its name and arguments are never formatted."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation as cls
+        except ImportError:  # a jax-free process still gets the totals
+            import contextlib
+
+            def cls(name, **kwargs):
+                return contextlib.nullcontext()
+        _TRACE_ANNOTATION = cls
+    return _TRACE_ANNOTATION
+
+
+class _Span:
+    """The one phase boundary (FlightRecorder.span / .wall). A phase span
+    owns the step thread's clock until it exits or an inner phase takes
+    over, so the phases partition the thread's time: an outer span is
+    credited only with its own slices. A wall span (phase None) adds its
+    whole length to one key and takes part in nothing else."""
+
+    __slots__ = ("rec", "phase", "key", "seq", "kind", "t0", "t_run",
+                 "outer", "ann")
+
+    def __init__(self, rec, phase, key, seq, kind):
+        self.rec, self.phase, self.key = rec, phase, key
+        self.seq, self.kind = seq, kind
+
+    def __enter__(self):
+        rec = self.rec
+        self.t0 = self.t_run = now = time.monotonic_ns()
+        if self.phase is None:
+            return self
+        if self.seq is None:
+            self.seq = rec._seq + 1  # the dispatch this leads to
+        self.outer = rec._cur
+        if self.outer is not None:
+            self.outer._credit(now)
+        rec._cur = self
+        self.ann = _trace_annotation()(
+            self.key, seq=self.seq, kind=self.kind)
+        self.ann.__enter__()
+        return self
+
+    def _credit(self, now: int) -> None:
+        """Close this span's running slice at `now`."""
+        rec = self.rec
+        d = now - self.t_run
+        self.t_run = now
+        rec._tot[self.phase + "_s"] += d
+        if rec._gap_after is not None:
+            rec._tot["starved_" + self.phase + "_s"] += d
+            rec._gap[self.phase] = rec._gap.get(self.phase, 0) + d
+
+    def __exit__(self, *exc):
+        now = time.monotonic_ns()
+        rec = self.rec
+        if self.phase is None:
+            rec._tot[self.key] += now - self.t0
+            return False
+        self.ann.__exit__(*exc)
+        self._credit(now)
+        rec._cur = self.outer
+        if self.outer is not None:
+            self.outer.t_run = now
+        if rec.enabled:
+            rec._stamps.append((self.phase, self.t0, now, self.seq))
+        return False
+
+
 class FlightRecorder:
-    """Bounded ring of structured step records (the black box).
+    """Bounded ring of structured step records (the black box), and the
+    step thread's timeline: phase spans, cumulative totals, and the
+    device-starvation accounting (docs/37-flight-recorder.md).
 
     Appended from the step thread; snapshotted by the watchdog, the
     postmortem dumper, and GET /debug/flight. One small lock guards the
     ring (an append is a dict build + deque append — microseconds against
-    a millisecond-scale step). The dispatch/resolve cursor is tracked
-    even when recording is disabled: the watchdog's unresolved-step
-    detection must survive ``--flight-recording false``.
+    a millisecond-scale step). The dispatch/resolve cursor, the totals and
+    the starvation counters are kept even when recording is disabled: the
+    watchdog's unresolved-step detection and /debug/timing must survive
+    ``--flight-recording false``; only the ring and its stamps follow it.
     """
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY, enabled: bool = True):
@@ -99,12 +200,107 @@ class FlightRecorder:
         self.capacity = capacity
         self._lock = threading.Lock()
         self._ring: deque[dict] = deque(maxlen=capacity)
-        self._seq = 0
-        # the ONE outstanding dispatched-but-unresolved device step (the
-        # pipeline is two-deep: at most one step is in flight between
-        # step() calls). (seq, monotonic dispatch time, kind) or None.
-        self._outstanding: tuple[int, float, str] | None = None
         self.records_total = 0
+        # the cursor. Programs run in order on the device, so a resolve of
+        # seq n completes every seq <= n; `_enq == _done` means the device's
+        # queue is empty from the host's side. `_open` holds the dispatches
+        # the host still expects an answer from (seq -> (monotonic time,
+        # kind)): a rolled-back step leaves it at once (it is no stall
+        # candidate) but stays enqueued until a later step resolves.
+        self._seq = 0  # == highest seq enqueued
+        self._done = 0  # highest seq known complete
+        self._open: dict[int, tuple[float, str]] = {}
+        self._discarded_kind = "decode"
+        # -- timeline (step thread only; readers see whole ints) ----------
+        self._t_origin = time.monotonic_ns()
+        # nanoseconds under `*_s` keys, counts under the others; timing()
+        # serves seconds. Every key /debug/timing ever had lives here.
+        self._tot: dict[str, int] = dict.fromkeys((
+            *(p + "_s" for p in PHASES),
+            "prefill_s", "prefill_n", "prefill_tokens",
+            "decode_s", "decode_n", "decode_tokens",
+            "overlap_s", "step_wall_s", "rollback_n", "steps",
+            "dispatch_n", "starved_n",
+            *("starved_" + p + "_s" for p in PHASES),
+            *("starved_after_" + k + "_n" for k in GAP_AFTER),
+        ), 0)
+        self._cur: _Span | None = None
+        # a starvation gap is open while `_gap_after` names what emptied
+        # the device's queue; `_gap` splits it over the phases run inside
+        self._gap_after: str | None = "start"
+        self._gap: dict[str, int] = {}
+        # phase stamps since the last ring record (bounded: an idle loop
+        # spins admit/idle with no record to carry them)
+        self._stamps: deque[tuple] = deque(maxlen=64)
+
+    # -- the timeline (step thread) ----------------------------------------
+
+    def span(self, phase: str, seq: int | None = None, kind: str = ""):
+        """``with rec.span("sched"):`` — one phase of PHASES. `seq` names
+        the dispatch that caused it (default: the next one)."""
+        return _Span(self, phase, _SPAN_NAMES[phase], seq, kind)
+
+    def wall(self, key: str):
+        """``with rec.wall("step_wall_s"):`` — a region's whole length
+        added to one cumulative key (regions overlap phases freely)."""
+        return _Span(self, None, key, 0, "")
+
+    def count(self, key: str, n: int = 1) -> None:
+        self._tot[key] += n
+
+    @property
+    def done_seq(self) -> int:
+        """Highest dispatch seq known complete on the device."""
+        return self._done
+
+    def _flip_gap(self, now: int, after: str | None) -> dict | None:
+        """Open (`after` = what emptied the queue) or close (None) the
+        starvation gap at `now`, splitting the running phase at the edge.
+        Closing returns the gap for the flight record."""
+        if (after is None) == (self._gap_after is None):
+            return None
+        if self._cur is not None:
+            self._cur._credit(now)
+        closed = None
+        if after is None:
+            self._tot["starved_n"] += 1
+            self._tot["starved_after_" + self._gap_after + "_n"] += 1
+            closed = {
+                "after": self._gap_after,
+                "ms": round(sum(self._gap.values()) * 1e-6, 3),
+                "phases_ms": {p: round(d * 1e-6, 3)
+                              for p, d in self._gap.items()},
+            }
+            self._gap = {}
+        self._gap_after = after
+        return closed
+
+    def timing(self) -> dict:
+        """The cumulative totals as /debug/timing serves them (seconds and
+        counts; monotonic — readers subtract two snapshots). `dispatch_s`
+        and `starved_s` are sums of their parts; `clock_s` is the step
+        thread's clock at this snapshot, the denominator of a share."""
+        tot = self._tot
+        out = {k: (v * 1e-9 if k.endswith("_s") else v)
+               for k, v in tot.items()}
+        out["dispatch_s"] = sum(tot[p + "_s"] for p in DISPATCH_PHASES) * 1e-9
+        out["starved_s"] = sum(
+            tot["starved_" + p + "_s"] for p in PHASES) * 1e-9
+        out["clock_s"] = (time.monotonic_ns() - self._t_origin) * 1e-9
+        return out
+
+    @staticmethod
+    def clock_pair() -> dict:
+        """One reading of both host clocks, also left in a live xprof
+        capture as a `clock_pair` event that carries them: the flight
+        records and the benchmark client (monotonic), the request spine
+        (wall clock) and the capture (its own zero) can then be laid on one
+        axis offline."""
+        pair = {"monotonic_ns": time.monotonic_ns(),
+                "time_ns": time.time_ns()}
+        with _trace_annotation()("clock_pair", **pair):
+            pass
+        return pair
 
     # -- step-loop recording (step thread) ---------------------------------
 
@@ -113,48 +309,88 @@ class FlightRecorder:
             return
         fields["event"] = event
         fields["t"] = time.time()
+        fields["mono_ns"] = time.monotonic_ns()
         with self._lock:
             self._ring.append(fields)
             self.records_total += 1
+
+    def _take_stamps(self) -> list:
+        """[phase, start, end (monotonic ns), seq] of every phase span that
+        ended since the last dispatch/resolve record."""
+        stamps = list(self._stamps)
+        self._stamps.clear()
+        return stamps
 
     def dispatch(
         self, kind: str, rows: int, tokens: int,
         waiting: int = 0, running: int = 0, pool_usage: float = 0.0,
         window: int = 0,
     ) -> int:
-        """One device dispatch (decode window / verify / prefill chunk).
-        Returns the dispatch seq the matching resolve()/discard() names."""
+        """One device dispatch (decode window / verify / prefill chunk),
+        called when its enqueue has returned: the device has work again, so
+        an open starvation gap ends here. Returns the dispatch seq the
+        matching resolve()/discard() names."""
+        now = time.monotonic_ns()
         with self._lock:
             self._seq += 1
             seq = self._seq
-            self._outstanding = (seq, time.monotonic(), kind)
-        self._append("dispatch", {
-            "seq": seq, "kind": kind, "rows": rows, "tokens": tokens,
-            "window": window, "waiting": waiting, "running": running,
-            "pool_usage": round(pool_usage, 4),
-        })
+            self._open[seq] = (now * 1e-9, kind)
+        self._tot["dispatch_n"] += 1
+        gap = self._flip_gap(now, None)
+        if self.enabled:
+            self._append("dispatch", {
+                "seq": seq, "kind": kind, "rows": rows, "tokens": tokens,
+                "window": window, "waiting": waiting, "running": running,
+                "pool_usage": round(pool_usage, 4),
+                "gap": gap, "phases": self._take_stamps(),
+            })
         return seq
 
-    def resolve(self, seq: int, accepted: int = 0) -> None:
-        """The dispatch's results were synced to the host — the step is no
-        longer a stall candidate."""
+    def resolve(self, seq: int) -> None:
+        """The dispatch's results were synced to the host — it, and every
+        earlier one, is no longer a stall candidate. If nothing later is
+        enqueued the device starves from here on."""
+        now = time.monotonic_ns()
         with self._lock:
-            if self._outstanding is not None and self._outstanding[0] <= seq:
-                self._outstanding = None
-        self._append("resolve", {"seq": seq, "accepted": accepted})
+            kind = self._open.get(seq, (0.0, "start"))[1]
+            for s in [s for s in self._open if s <= seq]:
+                del self._open[s]
+            self._done = max(self._done, seq)
+            emptied = self._done >= self._seq
+        if emptied:
+            self._flip_gap(now, kind)
+        if self.enabled:
+            self._append("resolve", {
+                "seq": seq, "phases": self._take_stamps(),
+            })
 
     def discard(self, seq: int) -> None:
         """A dispatched pipeline step was rolled back (speculation
-        invalidated) — discarded work is resolved work for liveness."""
+        invalidated) — discarded work is resolved work for liveness. The
+        device still runs it, so its queue is not empty: it counts as
+        complete when a later step resolves (or the loop goes quiet)."""
         with self._lock:
-            if self._outstanding is not None and self._outstanding[0] <= seq:
-                self._outstanding = None
+            gone = self._open.pop(seq, None)
+        if gone is not None:
+            self._discarded_kind = gone[1]
         self._append("rollback", {"seq": seq})
+
+    def quiet(self) -> None:
+        """The loop found nothing to run. What is still enqueued was
+        rolled back and nobody will resolve it: count the queue as empty
+        from here (early by at most that one step)."""
+        with self._lock:
+            if self._open or self._done >= self._seq:
+                return
+            self._done = self._seq
+        self._flip_gap(time.monotonic_ns(), self._discarded_kind)
 
     def fault(self, message: str) -> None:
         """A step-loop exception (transient or fatal)."""
         with self._lock:
-            self._outstanding = None  # the step loop abandoned it
+            self._open.clear()  # the step loop abandoned it
+            self._done = self._seq
+        self._flip_gap(time.monotonic_ns(), "start")
         self._append("fault", {"message": str(message)[:500]})
 
     def note(self, event: str, **fields) -> None:
@@ -164,13 +400,13 @@ class FlightRecorder:
     # -- reading (watchdog / exporter / debug) -----------------------------
 
     def outstanding_age_s(self) -> tuple[float, str] | None:
-        """(seconds since dispatch, kind) of the unresolved device step,
-        or None when nothing is in flight."""
+        """(seconds since dispatch, kind) of the oldest unresolved device
+        step, or None when nothing is in flight."""
         with self._lock:
-            out = self._outstanding
+            out = self._open[min(self._open)] if self._open else None
         if out is None:
             return None
-        return time.monotonic() - out[1], out[2]
+        return time.monotonic() - out[0], out[1]
 
     def snapshot(self, last: int | None = None) -> list[dict]:
         with self._lock:

@@ -38,6 +38,7 @@ from ..parallel.sharding import (
     lora_param_specs,
 )
 from .compile_watch import CompileWatch, program_memory_bytes
+from .flightrec import FlightRecorder
 from .config import EngineConfig
 from .sampling import (
     SUPPRESS_IDS, apply_grammar_mask, greedy_argmax, sample,
@@ -128,7 +129,9 @@ class StepHandle:
         self.rng_before = rng_before
         self._postproc = postproc
         self.logprob_rows: list | None = None
-        self.sync_s = 0.0  # host time blocked in the D2H sync
+        # the flight recorder's dispatch seq and kind (the engine sets them
+        # when the enqueue has returned): the `sync` span carries them
+        self.seq, self.kind = 0, ""
         self._rows: list[list[int]] | None = None
         # verify handles: (B_pad,) device vector of each row's full-
         # acceptance bonus token — the chain source for a decode window
@@ -145,17 +148,20 @@ class StepHandle:
         covering tokens + every logprob array — and build the per-request
         token rows. Idempotent: the transfer happens once."""
         if self._rows is None:
-            t0 = time.perf_counter()
-            if self.lp_arrays is not None:
-                got = jax.device_get((self.tokens, *self.lp_arrays))
-                mat = np.asarray(got[0])
-                lp = tuple(np.asarray(x) for x in got[1:])
-            else:
-                mat = np.asarray(jax.device_get(self.tokens))
-                lp = None
-            self.sync_s = time.perf_counter() - t0
+            with self.runner.clock.span("sync", self.seq, self.kind):
+                if self.lp_arrays is not None:
+                    got = jax.device_get((self.tokens, *self.lp_arrays))
+                    mat = np.asarray(got[0])
+                    lp = tuple(np.asarray(x) for x in got[1:])
+                else:
+                    mat = np.asarray(jax.device_get(self.tokens))
+                    lp = None
             self._rows, self.logprob_rows = self._postproc(mat, lp)
         return self._rows
+
+    @property
+    def resolved(self) -> bool:
+        return self._rows is not None
 
     def discard(self) -> None:
         """Roll back this dispatch (speculation invalidated): rewind the
@@ -320,9 +326,10 @@ class ModelRunner:
         # when the dispatched batch requested them; None otherwise. Read by
         # LLMEngine.step right after execute().
         self.last_logprobs: list | None = None
-        # host time the last execute() spent blocked in its D2H sync (the
-        # engine folds this into the timing decomposition's sync_s)
-        self.last_sync_s = 0.0
+        # the step timeline (flightrec.PHASES): this runner's h2d, enqueue
+        # and sync spans. The engine hands in its flight recorder; a runner
+        # on its own (tests, the draft proposer) keeps a private one
+        self.clock = FlightRecorder(enabled=False)
         # async pipeline: splice a chained row's input token from the
         # previous step's device-resident output matrix (last window
         # column), falling back to the host-provided token where
@@ -666,19 +673,24 @@ class ModelRunner:
             flat = hidden.reshape(-1, hidden.shape[-1])
             picked = flat[sample_rows]  # (num_samples, h)
             logits = llama.compute_logits(cfg, params, picked)
-            if want_grammar:
-                # masked logits flow into logprobs too: the reported
-                # distribution is the constrained one actually sampled from
-                logits = apply_grammar_mask(logits, grammar_mask)
-            if want_min_tokens:
-                logits = suppress_stop_tokens(
-                    logits, counts, min_toks, stop_ids
+            with jax.named_scope("sample"):
+                if want_grammar:
+                    # masked logits flow into logprobs too: the reported
+                    # distribution is the constrained one actually sampled
+                    # from
+                    logits = apply_grammar_mask(logits, grammar_mask)
+                if want_min_tokens:
+                    logits = suppress_stop_tokens(
+                        logits, counts, min_toks, stop_ids
+                    )
+                tokens = sample(
+                    logits, temperature, top_p, top_k, rng, seeds, has_seed,
+                    counts,
                 )
-            tokens = sample(
-                logits, temperature, top_p, top_k, rng, seeds, has_seed, counts
-            )
-            if want_logprobs:
-                return kv_caches, tokens, _collect_logprobs(logits, tokens)
+                if want_logprobs:
+                    return (
+                        kv_caches, tokens, _collect_logprobs(logits, tokens)
+                    )
             return kv_caches, tokens
 
         return step_fn
@@ -736,17 +748,21 @@ class ModelRunner:
             flat = hidden.reshape(-1, hidden.shape[-1])
             picked = flat[sample_rows]
             logits = llama.compute_logits(cfg, params, picked)
-            if want_grammar:
-                logits = apply_grammar_mask(logits, grammar_mask)
-            if want_min_tokens:
-                logits = suppress_stop_tokens(
-                    logits, counts, min_toks, stop_ids
+            with jax.named_scope("sample"):
+                if want_grammar:
+                    logits = apply_grammar_mask(logits, grammar_mask)
+                if want_min_tokens:
+                    logits = suppress_stop_tokens(
+                        logits, counts, min_toks, stop_ids
+                    )
+                tokens = sample(
+                    logits, temperature, top_p, top_k, rng, seeds, has_seed,
+                    counts,
                 )
-            tokens = sample(
-                logits, temperature, top_p, top_k, rng, seeds, has_seed, counts
-            )
-            if want_logprobs:
-                return kv_caches, tokens, _collect_logprobs(logits, tokens)
+                if want_logprobs:
+                    return (
+                        kv_caches, tokens, _collect_logprobs(logits, tokens)
+                    )
             return kv_caches, tokens
 
         return sp_step_fn
@@ -821,14 +837,13 @@ class ModelRunner:
                 self._attention_backend == "xla"
                 and self._hoist_bytes(b, s_ctx) <= self._hoist_budget
             )
-            hists = (
-                tuple(
-                    gather_pages(kv_caches[i], block_tables)
-                    for i in range(cfg.num_layers)
-                )
-                if hoist
-                else None
-            )
+            hists = None
+            if hoist:
+                with jax.named_scope("gather_pages"):
+                    hists = tuple(
+                        gather_pages(kv_caches[i], block_tables)
+                        for i in range(cfg.num_layers)
+                    )
 
             if want_grammar:
                 has_gr = gr_idx >= 0  # (B,)
@@ -856,51 +871,55 @@ class ModelRunner:
                     mesh=self.mesh,
                 )
                 logits = llama.compute_logits(cfg, params, hidden)
-                if want_grammar:
-                    # the automaton advances ON DEVICE: mask from the current
-                    # state's class row, sample, then step the state — so a
-                    # constrained row accepts the whole window like any other
-                    # row instead of bailing after one host-masked token
-                    dest_c = gr_class_dest[g, gstate]  # (B, C)
-                    allowed = jnp.take_along_axis(
-                        dest_c >= 0, tclass, axis=1
-                    )  # (B, V)
-                    # EOS is not a grammar byte: allowed exactly in
-                    # accepting states (empty-content tokens hold dest -1
-                    # everywhere, so BOS/PAD stay rejected)
-                    allowed = allowed.at[:, gr_eos[0]].set(
-                        gr_accepting[g, gstate]
+                with jax.named_scope("sample"):
+                    if want_grammar:
+                        # the automaton advances ON DEVICE: mask from the
+                        # current state's class row, sample, then step the
+                        # state — so a constrained row accepts the whole
+                        # window like any other row instead of bailing after
+                        # one host-masked token
+                        dest_c = gr_class_dest[g, gstate]  # (B, C)
+                        allowed = jnp.take_along_axis(
+                            dest_c >= 0, tclass, axis=1
+                        )  # (B, V)
+                        # EOS is not a grammar byte: allowed exactly in
+                        # accepting states (empty-content tokens hold dest -1
+                        # everywhere, so BOS/PAD stay rejected)
+                        allowed = allowed.at[:, gr_eos[0]].set(
+                            gr_accepting[g, gstate]
+                        )
+                        allowed = allowed | ~has_gr[:, None]
+                        logits = apply_grammar_mask(logits, allowed)
+                    if want_min_tokens:
+                        logits = suppress_stop_tokens(
+                            logits, counts0 + k, min_toks, stop_ids
+                        )
+                    toks = sample(
+                        logits, temperature, top_p, top_k,
+                        jax.random.fold_in(base_key, k),
+                        seeds, has_seed, counts0 + k,
                     )
-                    allowed = allowed | ~has_gr[:, None]
-                    logits = apply_grammar_mask(logits, allowed)
-                if want_min_tokens:
-                    logits = suppress_stop_tokens(
-                        logits, counts0 + k, min_toks, stop_ids
-                    )
-                toks = sample(
-                    logits, temperature, top_p, top_k,
-                    jax.random.fold_in(base_key, k),
-                    seeds, has_seed, counts0 + k,
-                )
-                if want_logprobs:
-                    chosen, top_lp, top_id = _collect_logprobs(logits, toks)
-                    lp_out = lp_out.at[:, k].set(chosen)
-                    top_lp_out = top_lp_out.at[:, k].set(top_lp)
-                    top_id_out = top_id_out.at[:, k].set(top_id)
-                if want_grammar:
-                    tcls = jnp.take_along_axis(tclass, toks[:, None], axis=1)
-                    nxt = jnp.take_along_axis(dest_c, tcls, axis=1)[:, 0]
-                    gstate = jnp.where(
-                        has_gr, jnp.where(nxt >= 0, nxt, dead), gstate
-                    )
+                    if want_logprobs:
+                        chosen, top_lp, top_id = _collect_logprobs(
+                            logits, toks)
+                        lp_out = lp_out.at[:, k].set(chosen)
+                        top_lp_out = top_lp_out.at[:, k].set(top_lp)
+                        top_id_out = top_id_out.at[:, k].set(top_id)
+                    if want_grammar:
+                        tcls = jnp.take_along_axis(
+                            tclass, toks[:, None], axis=1)
+                        nxt = jnp.take_along_axis(dest_c, tcls, axis=1)[:, 0]
+                        gstate = jnp.where(
+                            has_gr, jnp.where(nxt >= 0, nxt, dead), gstate
+                        )
+                        return (
+                            staged, toks, out.at[:, k].set(toks),
+                            lp_out, top_lp_out, top_id_out, gstate,
+                        )
                     return (
                         staged, toks, out.at[:, k].set(toks),
-                        lp_out, top_lp_out, top_id_out, gstate,
+                        lp_out, top_lp_out, top_id_out,
                     )
-                return (
-                    staged, toks, out.at[:, k].set(toks),
-                    lp_out, top_lp_out, top_id_out,
-                )
 
             if want_grammar:
                 (staged, _, out, lp_out, top_lp_out, top_id_out,
@@ -986,18 +1005,20 @@ class ModelRunner:
             logits = llama.compute_logits(
                 cfg, params, hidden.reshape(-1, hidden.shape[-1])
             )
-            if want_grammar:
-                logits = apply_grammar_mask(
-                    logits, grammar_mask.reshape(-1, grammar_mask.shape[-1])
-                )
-            toks = greedy_argmax(logits)
-            mat = toks.reshape(hidden.shape[0], hidden.shape[1])
-            # row i's bonus token under full acceptance sits at its last
-            # real fed column — the chain source for a dispatched-on-top
-            # decode window
-            nxt = jnp.take_along_axis(
-                mat, jnp.maximum(chunk_lens - 1, 0)[:, None], axis=1
-            )[:, 0]
+            with jax.named_scope("sample"):
+                if want_grammar:
+                    logits = apply_grammar_mask(
+                        logits,
+                        grammar_mask.reshape(-1, grammar_mask.shape[-1]),
+                    )
+                toks = greedy_argmax(logits)
+                mat = toks.reshape(hidden.shape[0], hidden.shape[1])
+                # row i's bonus token under full acceptance sits at its last
+                # real fed column — the chain source for a dispatched-on-top
+                # decode window
+                nxt = jnp.take_along_axis(
+                    mat, jnp.maximum(chunk_lens - 1, 0)[:, None], axis=1
+                )[:, 0]
             return kv_caches, mat, nxt
 
         return verify_fn
@@ -1086,7 +1107,19 @@ class ModelRunner:
         # verify draws no RNG (pure argmax): rng_before == rng after, so a
         # discard()'s rewind is a no-op — recorded anyway for uniformity
         rng_before = self._rng
-        toks_dev = self._put(token_ids, self._batch2)
+        clock = self.clock
+        with clock.span("h2d", kind="verify"):
+            toks_dev = self._put(token_ids, self._batch2)
+            dyn_args = (
+                self._put(positions, self._batch2),
+                self._put(block_tables, self._batch2),
+                self._put(context_lens, self._batch1),
+                self._put(chunk_lens, self._batch1),
+                self._put(write_ids, self._batch2),
+                self._put(start_off, self._batch1),
+                self._put(lora_idx, self._batch1) if self._use_lora else None,
+            )
+            gm_dev = self._put(grammar_mask, self._batch1) if want_gr else None
         if work.chain_rows and any(c >= 0 for c in work.chain_rows):
             if prev is None or prev.chain_vec is None:
                 raise RuntimeError(
@@ -1095,26 +1128,17 @@ class ModelRunner:
                 )
             idx = np.full(b_pad, -1, np.int32)
             idx[: len(work.chain_rows)] = work.chain_rows
-            toks_dev = self._chain_verify_fn(
-                prev.chain_vec, toks_dev, self._put(idx, self._batch1)
+            with clock.span("h2d", kind="verify"):
+                idx_dev = self._put(idx, self._batch1)
+            with clock.span("enqueue", kind="verify"):
+                toks_dev = self._chain_verify_fn(
+                    prev.chain_vec, toks_dev, idx_dev
+                )
+        with clock.span("enqueue", kind="verify"):
+            self.kv_caches, toks, nxt = self._verify_fn(
+                self.params, self.lora_params, self.kv_caches, toks_dev,
+                *dyn_args, grammar_mask=gm_dev, want_grammar=want_gr,
             )
-        self.kv_caches, toks, nxt = self._verify_fn(
-            self.params,
-            self.lora_params,
-            self.kv_caches,
-            toks_dev,
-            self._put(positions, self._batch2),
-            self._put(block_tables, self._batch2),
-            self._put(context_lens, self._batch1),
-            self._put(chunk_lens, self._batch1),
-            self._put(write_ids, self._batch2),
-            self._put(start_off, self._batch1),
-            self._put(lora_idx, self._batch1) if self._use_lora else None,
-            grammar_mask=(
-                self._put(grammar_mask, self._batch1) if want_gr else None
-            ),
-            want_grammar=want_gr,
-        )
         if new_program:
             self._verify_keys.add(vkey)
             self._watch_sync_compile(
@@ -1140,9 +1164,7 @@ class ModelRunner:
 
     def _execute_verify(self, work: VerifyWork) -> list[list[int]]:
         handle = self._dispatch_verify(work)
-        rows = handle.resolve()
-        self.last_sync_s = handle.sync_s
-        return rows
+        return handle.resolve()
 
     # -- public API --------------------------------------------------------
 
@@ -1156,7 +1178,6 @@ class ModelRunner:
         handle = self.execute_async(work)
         rows = handle.resolve()
         self.last_logprobs = handle.logprob_rows
-        self.last_sync_s = handle.sync_s
         return rows
 
     def execute_async(
@@ -1279,19 +1300,21 @@ class ModelRunner:
                 ):
                     req.grammar.sync(req.output_token_ids)
                     grammar_mask[i] = req.grammar.mask()
-            grammar_mask = self._put(grammar_mask, self._batch2)
+            with self.clock.span("h2d", kind="prefill"):
+                grammar_mask = self._put(grammar_mask, self._batch2)
         elif use_gr:
             # a grammar-enabled program serving an unconstrained batch
             # (shape dominance): all-ones mask is the identity, cached per
             # batch bucket like the zero stop arrays
             grammar_mask = self._ones_mask_cache.get(b_pad)
             if grammar_mask is None:
-                grammar_mask = self._put(
-                    np.ones(
-                        (b_pad, self.config.model.vocab_size), dtype=bool
-                    ),
-                    self._batch2,
-                )
+                with self.clock.span("h2d", kind="prefill"):
+                    grammar_mask = self._put(
+                        np.ones(
+                            (b_pad, self.config.model.vocab_size), dtype=bool
+                        ),
+                        self._batch2,
+                    )
                 self._ones_mask_cache[b_pad] = grammar_mask
         tokens_dev, lp_dev, rng_before = self._run(
             token_ids, positions, block_tables,
@@ -1385,12 +1408,13 @@ class ModelRunner:
         self._watch_dispatch(exact_key, aot_key)
         _, b_pad, nb, _w, _lp, use_mt, use_gkey = aot_key
 
+        clock = self.clock
         first_tokens = np.zeros(b_pad, np.int32)
         first_tokens[:b] = work.token_ids
-        ft = self._put(first_tokens, self._batch1)
         chain = work.chain_rows
         idx_dev = None
-        if any(c >= 0 for c in chain):
+        chained = any(c >= 0 for c in chain)
+        if chained:
             # chained rows read their input token straight from the
             # previous (still in-flight) step's device output — the
             # D2H→H2D round trip the pipeline eliminates
@@ -1401,11 +1425,6 @@ class ModelRunner:
                 )
             idx = np.full(b_pad, -1, np.int32)
             idx[: len(chain)] = chain
-            idx_dev = self._put(idx, self._batch1)
-            if prev.chain_vec is not None:  # previous step was a verify
-                ft = self._chain_vec_fn(prev.chain_vec, ft, idx_dev)
-            else:
-                ft = self._chain_fn(prev.tokens, ft, idx_dev)
         positions0 = np.zeros(b_pad, np.int32)
         positions0[:b] = work.positions
         block_tables = self._block_table_array(
@@ -1431,21 +1450,32 @@ class ModelRunner:
         for i, req in enumerate(work.requests):
             lora_idx[i] = req.lora_index
         min_toks, stop_ids_arr = self._stop_id_arrays(work.requests, b_pad)
-        dyn_args = (
-            ft,
-            self._put(positions0, self._batch1),
-            self._put(block_tables, self._batch2),
-            self._put(lora_idx, self._batch1) if self._use_lora else None,
-            self._put(np.asarray(temps, np.float32), self._batch1),
-            self._put(np.asarray(top_ps, np.float32), self._batch1),
-            self._put(np.asarray(top_ks, np.int32), self._batch1),
-            self._put(step_key, self._rep),
-            self._put(seed_vals, self._batch1),
-            self._put(has_seed, self._batch1),
-            self._put(np.asarray(counts, np.int32), self._batch1),
-            self._put(min_toks, self._batch1),
-            self._put(stop_ids_arr, self._batch2),
-        )
+        with clock.span("h2d", kind="decode"):
+            ft = self._put(first_tokens, self._batch1)
+            if chained:
+                idx_dev = self._put(idx, self._batch1)
+        if chained:
+            with clock.span("enqueue", kind="decode"):
+                if prev.chain_vec is not None:  # previous step was a verify
+                    ft = self._chain_vec_fn(prev.chain_vec, ft, idx_dev)
+                else:
+                    ft = self._chain_fn(prev.tokens, ft, idx_dev)
+        with clock.span("h2d", kind="decode"):
+            dyn_args = (
+                ft,
+                self._put(positions0, self._batch1),
+                self._put(block_tables, self._batch2),
+                self._put(lora_idx, self._batch1) if self._use_lora else None,
+                self._put(np.asarray(temps, np.float32), self._batch1),
+                self._put(np.asarray(top_ps, np.float32), self._batch1),
+                self._put(np.asarray(top_ks, np.int32), self._batch1),
+                self._put(step_key, self._rep),
+                self._put(seed_vals, self._batch1),
+                self._put(has_seed, self._batch1),
+                self._put(np.asarray(counts, np.int32), self._batch1),
+                self._put(min_toks, self._batch1),
+                self._put(stop_ids_arr, self._batch2),
+            )
         if want_gr:
             tc_dev, cd_dev, acc_dev = self._grammar_device_tables(
                 grammars, use_gkey
@@ -1470,7 +1500,14 @@ class ModelRunner:
                         if req.grammar.state >= 0
                         else use_gkey[1] - 1
                     )
-            gs_dev = self._put(gs0, self._batch1)
+            with clock.span("h2d", kind="decode"):
+                gs_dev = self._put(gs0, self._batch1)
+                gr_idx_dev = self._put(gr_idx, self._batch1)
+                if self._gr_eos_dev is None:
+                    self._gr_eos_dev = self._put(
+                        np.asarray([grammars[0].eos_token_id], np.int32),
+                        self._rep,
+                    )
             if grammar_chains:
                 if prev is None or prev.grammar_states is None:
                     raise RuntimeError(
@@ -1482,39 +1519,35 @@ class ModelRunner:
                 # the in-flight step's post-window state, others keep the
                 # host value (non-grammar chained rows gather junk their
                 # gr_idx = -1 makes inert)
-                gs_dev = self._chain_vec_fn(
-                    prev.grammar_states, gs_dev, idx_dev
-                )
-            if self._gr_eos_dev is None:
-                self._gr_eos_dev = self._put(
-                    np.asarray([grammars[0].eos_token_id], np.int32),
-                    self._rep,
-                )
+                with clock.span("enqueue", kind="decode"):
+                    gs_dev = self._chain_vec_fn(
+                        prev.grammar_states, gs_dev, idx_dev
+                    )
             dyn_args = dyn_args + (
-                tc_dev, cd_dev, acc_dev,
-                self._put(gr_idx, self._batch1),
-                gs_dev,
+                tc_dev, cd_dev, acc_dev, gr_idx_dev, gs_dev,
                 self._gr_eos_dev,
             )
         aot = self._aot_exec.get(aot_key)
         if aot is not None:
-            result = aot(
-                self.params, self.lora_params, self.kv_caches, *dyn_args
-            )
+            with clock.span("enqueue", kind="decode"):
+                result = aot(
+                    self.params, self.lora_params, self.kv_caches, *dyn_args
+                )
         else:
             with self._bg_lock:
                 new_program = aot_key not in self._compiled_keys
             t0 = time.perf_counter()
-            result = self._decode_window_fn(
-                self.params,
-                self.lora_params,
-                self.kv_caches,
-                *dyn_args,
-                window=work.window,
-                want_logprobs=want_lp,
-                want_min_tokens=use_mt,
-                want_grammar=want_gr,
-            )
+            with clock.span("enqueue", kind="decode"):
+                result = self._decode_window_fn(
+                    self.params,
+                    self.lora_params,
+                    self.kv_caches,
+                    *dyn_args,
+                    window=work.window,
+                    want_logprobs=want_lp,
+                    want_min_tokens=use_mt,
+                    want_grammar=want_gr,
+                )
             self._note_compiled(aot_key)
             if new_program:
                 self._watch_sync_compile(
@@ -1586,36 +1619,39 @@ class ModelRunner:
         )
         # sp shards the chunk axis; dp-only meshes leave T unsharded
         tok_sh = self._seq2 if self._sp > 1 else self._batch2
-        dyn_args = (
-            self._put(token_ids, tok_sh),
-            self._put(positions, tok_sh),
-            self._put(block_tables, self._batch2),
-            # (B*T,) for the sp path (B divisible by dp); (1,) placeholder
-            # (replicated) for the paged path
-            self._put(slots, self._batch1 if self._sp > 1 else self._rep),
-            self._put(context_lens, self._batch1),
-            self._put(chunk_lens, self._batch1),
-            self._put(write_ids, self._batch2),
-            self._put(start_off, self._batch1),
-            self._put(lora_idx, self._batch1) if self._use_lora else None,
-            self._put(sample_rows, self._batch1),
-            self._put(np.asarray(temps, np.float32), self._batch1),
-            self._put(np.asarray(top_ps, np.float32), self._batch1),
-            self._put(np.asarray(top_ks, np.int32), self._batch1),
-            self._put(step_key, self._rep),
-            self._put(seed_vals, self._batch1),
-            self._put(has_seed, self._batch1),
-            self._put(np.asarray(counts, np.int32), self._batch1),
-            self._put(min_toks, self._batch1),
-            self._put(stop_ids_arr, self._batch2),
-        )
+        clock = self.clock
+        with clock.span("h2d", kind="prefill"):
+            dyn_args = (
+                self._put(token_ids, tok_sh),
+                self._put(positions, tok_sh),
+                self._put(block_tables, self._batch2),
+                # (B*T,) for the sp path (B divisible by dp); (1,)
+                # placeholder (replicated) for the paged path
+                self._put(slots, self._batch1 if self._sp > 1 else self._rep),
+                self._put(context_lens, self._batch1),
+                self._put(chunk_lens, self._batch1),
+                self._put(write_ids, self._batch2),
+                self._put(start_off, self._batch1),
+                self._put(lora_idx, self._batch1) if self._use_lora else None,
+                self._put(sample_rows, self._batch1),
+                self._put(np.asarray(temps, np.float32), self._batch1),
+                self._put(np.asarray(top_ps, np.float32), self._batch1),
+                self._put(np.asarray(top_ks, np.int32), self._batch1),
+                self._put(step_key, self._rep),
+                self._put(seed_vals, self._batch1),
+                self._put(has_seed, self._batch1),
+                self._put(np.asarray(counts, np.int32), self._batch1),
+                self._put(min_toks, self._batch1),
+                self._put(stop_ids_arr, self._batch2),
+            )
         if want_grammar:
             dyn_args = dyn_args + (grammar_mask,)  # already device-resident
         aot = self._aot_exec.get(aot_key) if aot_key is not None else None
         if aot is not None:
-            result = aot(
-                self.params, self.lora_params, self.kv_caches, *dyn_args
-            )
+            with clock.span("enqueue", kind="prefill"):
+                result = aot(
+                    self.params, self.lora_params, self.kv_caches, *dyn_args
+                )
         else:
             # a first-ever key means this _step_fn call traces+compiles
             # synchronously — the stall CompileWatch attributes to the
@@ -1626,15 +1662,16 @@ class ModelRunner:
                 with self._bg_lock:
                     new_program = aot_key not in self._compiled_keys
             t0 = time.perf_counter()
-            result = self._step_fn(
-                self.params,
-                self.lora_params,
-                self.kv_caches,
-                *dyn_args,
-                want_logprobs=want_logprobs,
-                want_min_tokens=want_min_tokens,
-                want_grammar=want_grammar,
-            )
+            with clock.span("enqueue", kind="prefill"):
+                result = self._step_fn(
+                    self.params,
+                    self.lora_params,
+                    self.kv_caches,
+                    *dyn_args,
+                    want_logprobs=want_logprobs,
+                    want_min_tokens=want_min_tokens,
+                    want_grammar=want_grammar,
+                )
             if aot_key is not None:
                 self._note_compiled(aot_key)
                 if new_program:
@@ -1676,11 +1713,12 @@ class ModelRunner:
             tc[j] = g.token_class
             cd[j, : g.n_states, : g.n_classes] = g.class_dest
             acc[j, : g.n_states] = g.accepting
-        out = (
-            self._put(tc, self._rep),
-            self._put(cd, self._rep),
-            self._put(acc, self._rep),
-        )
+        with self.clock.span("h2d", kind="decode"):
+            out = (
+                self._put(tc, self._rep),
+                self._put(cd, self._rep),
+                self._put(acc, self._rep),
+            )
         # bounded: distinct (batch composition, pads) combos churn during
         # warmup then stabilize; evict oldest past a small cap
         if len(self._grammar_tables_cache) >= 32:

@@ -1677,8 +1677,9 @@ class EngineServer:
     # number six+ now and were discoverable only by reading this file)
     DEBUG_ENDPOINTS = {
         "GET /debug": "this index",
-        "GET /debug/timing": "step-thread wall-time decomposition, "
-                             "submit-lock waits, program-cache state",
+        "GET /debug/timing": "step-thread phase totals, device-starvation "
+                             "gaps by cause, submit waits, program-cache "
+                             "state (docs/37)",
         "GET /debug/hydration": "compute-or-load planner live inputs + "
                                 "decision counters (docs/31)",
         "GET /debug/requests": "tracing-spine timelines; ?rid= one full "
@@ -1719,7 +1720,7 @@ class EngineServer:
                 "kv_hydration": eng.config.kv_hydration,
             },
             "timing": dict(eng.timing),
-            "loop_timing": dict(self.async_engine.loop_timing),
+            "loop_timing": self.async_engine.loop_snapshot(),
         }
         if self.watchdog is not None:
             ctx["watchdog"] = self.watchdog.snapshot()
@@ -1806,7 +1807,9 @@ class EngineServer:
         """On-demand xprof capture on a live engine: wraps
         jax.profiler.start_trace so a slow phase seen in /debug/requests
         or /debug/timing can be drilled into at the device level without
-        restarting the pod. Load the dump in XProf/TensorBoard."""
+        restarting the pod. Load the dump in XProf/TensorBoard: the step
+        thread's `step:<phase>` spans sit on the `engine-step` line, the
+        jitted steps' scopes on the device's name-scope line."""
         body = {}
         if request.can_read_body:
             try:
@@ -1827,8 +1830,16 @@ class EngineServer:
         except Exception as e:  # another tracer already active, bad dir...
             return error(409, f"profiler refused to start: {e}", "conflict")
         self._profile_dir = log_dir
+        # one reading of both host clocks at the capture's start, in the
+        # reply and in the flight ring: the flight records' monotonic
+        # stamps, the spine's wall clock and the capture's `step:*` spans
+        # can then be laid on one axis offline (docs/14-tracing.md)
+        clocks = self.engine.flightrec.clock_pair()
+        self.engine.flightrec.note("profile_start", dir=log_dir, **clocks)
         logger.info("xprof capture started -> %s", log_dir)
-        return web.json_response({"status": "profiling", "dir": log_dir})
+        return web.json_response(
+            {"status": "profiling", "dir": log_dir, "clocks": clocks}
+        )
 
     async def debug_profile_stop(self, request: web.Request) -> web.Response:
         if self._profile_dir is None:
@@ -1869,7 +1880,7 @@ class EngineServer:
             }
         return web.json_response({
             "engine": dict(eng.timing),
-            "loop": dict(self.async_engine.loop_timing),
+            "loop": self.async_engine.loop_snapshot(),
             "spec": spec,
             "programs": {
                 "compile_fallbacks": eng.runner.compile_fallbacks,

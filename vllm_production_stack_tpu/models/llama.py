@@ -264,56 +264,61 @@ def _layer_body(
             return _mm(xin, w)
 
     res = x
-    if not cfg.post_norms_only:
-        x = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps,
-                     cfg.rms_norm_add_one)
     ap = lp["attn"]
-    q = proj(x, ap["wq"], "q_proj")
-    k = proj(x, ap["wk"], "k_proj")
-    v = proj(x, ap["wv"], "v_proj")
-    if cfg.attention_bias:
-        q, k, v = q + ap["bq"], k + ap["bk"], v + ap["bv"]
-    if cfg.qk_norm_flat:
-        # olmo2: RMSNorm over the whole flat projection, pre-reshape
-        q = rms_norm(q, ap["q_norm"], cfg.rms_norm_eps,
-                     scale_f32=cfg.norm_scale_f32)
-        k = rms_norm(k, ap["k_norm"], cfg.rms_norm_eps,
-                     scale_f32=cfg.norm_scale_f32)
-    q = q.reshape(b, t, nh, hd)
-    k = k.reshape(b, t, nkv, hd)
-    if cfg.qk_norm:
-        # qwen3: per-head RMSNorm on q/k BEFORE rope (HF Qwen3Attention)
-        q = rms_norm(q, ap["q_norm"], cfg.rms_norm_eps)
-        k = rms_norm(k, ap["k_norm"], cfg.rms_norm_eps)
-    q = apply_rope(q, positions, cfg.rope_theta, scaling=cfg.rope_scaling)
-    k = apply_rope(k, positions, cfg.rope_theta, scaling=cfg.rope_scaling)
-    v = v.reshape(b, t, nkv, hd)
+    with jax.named_scope("attn_qkv"):
+        if not cfg.post_norms_only:
+            x = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps,
+                         cfg.rms_norm_add_one)
+        q = proj(x, ap["wq"], "q_proj")
+        k = proj(x, ap["wk"], "k_proj")
+        v = proj(x, ap["wv"], "v_proj")
+        if cfg.attention_bias:
+            q, k, v = q + ap["bq"], k + ap["bk"], v + ap["bv"]
+        if cfg.qk_norm_flat:
+            # olmo2: RMSNorm over the whole flat projection, pre-reshape
+            q = rms_norm(q, ap["q_norm"], cfg.rms_norm_eps,
+                         scale_f32=cfg.norm_scale_f32)
+            k = rms_norm(k, ap["k_norm"], cfg.rms_norm_eps,
+                         scale_f32=cfg.norm_scale_f32)
+        q = q.reshape(b, t, nh, hd)
+        k = k.reshape(b, t, nkv, hd)
+        if cfg.qk_norm:
+            # qwen3: per-head RMSNorm on q/k BEFORE rope (HF Qwen3Attention)
+            q = rms_norm(q, ap["q_norm"], cfg.rms_norm_eps)
+            k = rms_norm(k, ap["k_norm"], cfg.rms_norm_eps)
+        q = apply_rope(q, positions, cfg.rope_theta, scaling=cfg.rope_scaling)
+        k = apply_rope(k, positions, cfg.rope_theta, scaling=cfg.rope_scaling)
+        v = v.reshape(b, t, nkv, hd)
 
+    # `attend` names its own parts: kv_stage (this step's K/V into the
+    # pool or the window's staging buffer) and attn
     attn = attend(q, k, v).reshape(b, t, nh * hd)
-    attn_out = proj(attn, ap["wo"], "o_proj")
-    if cfg.sandwich_norms or cfg.post_norms_only:
-        # Gemma-2 / OLMo-2: norm the attention OUTPUT before the residual
-        attn_out = rms_norm(attn_out, lp["attn_out_norm"],
-                            cfg.rms_norm_eps, cfg.rms_norm_add_one,
-                 scale_f32=cfg.norm_scale_f32)
-    x = res + attn_out
+    with jax.named_scope("attn_out"):
+        attn_out = proj(attn, ap["wo"], "o_proj")
+        if cfg.sandwich_norms or cfg.post_norms_only:
+            # Gemma-2 / OLMo-2: norm the attention OUTPUT before the residual
+            attn_out = rms_norm(attn_out, lp["attn_out_norm"],
+                                cfg.rms_norm_eps, cfg.rms_norm_add_one,
+                     scale_f32=cfg.norm_scale_f32)
+        x = res + attn_out
 
     res = x
-    if not cfg.post_norms_only:
-        x = rms_norm(x, lp["post_attn_norm"], cfg.rms_norm_eps,
-                     cfg.rms_norm_add_one)
-    if "moe" in lp:
-        return res + _moe_mlp(cfg, lp["moe"], x)
-    mp = lp["mlp"]
-    inner = _activation(cfg)(proj(x, mp["gate"], "gate_proj")) * proj(
-        x, mp["up"], "up_proj"
-    )
-    mlp_out = proj(inner, mp["down"], "down_proj")
-    if cfg.sandwich_norms or cfg.post_norms_only:
-        mlp_out = rms_norm(mlp_out, lp["ffw_out_norm"],
-                           cfg.rms_norm_eps, cfg.rms_norm_add_one,
-                 scale_f32=cfg.norm_scale_f32)
-    return res + mlp_out
+    with jax.named_scope("mlp"):
+        if not cfg.post_norms_only:
+            x = rms_norm(x, lp["post_attn_norm"], cfg.rms_norm_eps,
+                         cfg.rms_norm_add_one)
+        if "moe" in lp:
+            return res + _moe_mlp(cfg, lp["moe"], x)
+        mp = lp["mlp"]
+        inner = _activation(cfg)(proj(x, mp["gate"], "gate_proj")) * proj(
+            x, mp["up"], "up_proj"
+        )
+        mlp_out = proj(inner, mp["down"], "down_proj")
+        if cfg.sandwich_norms or cfg.post_norms_only:
+            mlp_out = rms_norm(mlp_out, lp["ffw_out_norm"],
+                               cfg.rms_norm_eps, cfg.rms_norm_add_one,
+                     scale_f32=cfg.norm_scale_f32)
+        return res + mlp_out
 
 
 def _moe_mlp(cfg: ModelConfig, mp: dict, x: jax.Array) -> jax.Array:
@@ -382,35 +387,37 @@ def _layer(
 
     def attend(q, k, v):
         nonlocal kv_layer
-        if write_blocks is not None:
-            kv_layer = write_kv_pages_blockwise(
-                kv_layer, k, v, write_blocks["ids"],
-                write_blocks["start_off"], write_blocks["chunk_lens"],
-            )
-        else:
-            kv_layer = write_kv_pages(
-                kv_layer, k.reshape(b * t, nkv, hd),
-                v.reshape(b * t, nkv, hd), slot_mapping,
-            )
-        if pallas_prefill is not None:
-            mesh = pallas_prefill["mesh"]
-            if mesh is not None and mesh.size > 1:
-                return paged_prefill_attention_sharded(
-                    mesh, q, kv_layer, block_tables,
+        with jax.named_scope("kv_stage"):
+            if write_blocks is not None:
+                kv_layer = write_kv_pages_blockwise(
+                    kv_layer, k, v, write_blocks["ids"],
+                    write_blocks["start_off"], write_blocks["chunk_lens"],
+                )
+            else:
+                kv_layer = write_kv_pages(
+                    kv_layer, k.reshape(b * t, nkv, hd),
+                    v.reshape(b * t, nkv, hd), slot_mapping,
+                )
+        with jax.named_scope("attn"):
+            if pallas_prefill is not None:
+                mesh = pallas_prefill["mesh"]
+                if mesh is not None and mesh.size > 1:
+                    return paged_prefill_attention_sharded(
+                        mesh, q, kv_layer, block_tables,
+                        pallas_prefill["context_lens"],
+                        pallas_prefill["chunk_start"], scale=cfg.attn_scale,
+                        interpret=pallas_prefill["interpret"],
+                    )
+                return paged_prefill_attention(
+                    q, kv_layer, block_tables,
                     pallas_prefill["context_lens"],
                     pallas_prefill["chunk_start"], scale=cfg.attn_scale,
                     interpret=pallas_prefill["interpret"],
                 )
-            return paged_prefill_attention(
-                q, kv_layer, block_tables,
-                pallas_prefill["context_lens"],
-                pallas_prefill["chunk_start"], scale=cfg.attn_scale,
-                interpret=pallas_prefill["interpret"],
+            return paged_attention_xla(
+                q, kv_layer, block_tables, mask, scale=cfg.attn_scale,
+                softcap=cfg.attn_logit_softcap,
             )
-        return paged_attention_xla(
-            q, kv_layer, block_tables, mask, scale=cfg.attn_scale,
-            softcap=cfg.attn_logit_softcap,
-        )
 
     x = _layer_body(cfg, lp, x, positions, attend, lora, lora_idx)
     return x, kv_layer
@@ -439,7 +446,8 @@ def forward(
 ) -> tuple[jax.Array, jax.Array]:
     """One model step over a token batch. Prefill is (B=1, T=chunk); decode is
     (B=batch, T=1). Returns (hidden (B,T,h), updated kv_caches)."""
-    x = _embed(cfg, params, token_ids)
+    with jax.named_scope("embed"):
+        x = _embed(cfg, params, token_ids)
     if backend.startswith("pallas"):
         # the kernel masks from scalars alone — the scheduler feeds chunks
         # with contiguous positions (scheduler.py: range(start, start+len)),
@@ -485,8 +493,9 @@ def forward(
             pallas_prefill,
         )
         new_kv.append(layer_kv)
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, cfg.rms_norm_add_one,
-                 scale_f32=cfg.norm_scale_f32)
+    with jax.named_scope("final_norm"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
+                     cfg.rms_norm_add_one, scale_f32=cfg.norm_scale_f32)
     return x, tuple(new_kv)
 
 
@@ -526,7 +535,8 @@ def decode_window_step(
     contiguous per-layer (hist_k, hist_v) and the pool is not touched here
     (ops/attention.py:attention_with_hist). Returns (hidden (B, h), staged')."""
     window = staged.shape[2]
-    x = _embed(cfg, params, token_ids)[:, None]  # (B, 1, h)
+    with jax.named_scope("embed"):
+        x = _embed(cfg, params, token_ids)[:, None]  # (B, 1, h)
     # staged slot w is attendable once written: w <= k
     staged_mask = jnp.arange(window, dtype=jnp.int32) <= step_k
     if backend == "xla":
@@ -553,55 +563,61 @@ def decode_window_step(
 
         def attend(q, k, v, i=i):
             nonlocal staged
-            staged = staged.at[i, 0, step_k].set(k[:, 0].astype(staged.dtype))
-            staged = staged.at[i, 1, step_k].set(v[:, 0].astype(staged.dtype))
-            if backend == "xla":
-                h_mask = (
-                    hist_mask_local
-                    if cfg.layer_sliding(i)
-                    else hist_mask
-                )
-                if hists is not None:
-                    return attention_with_hist(
-                        q, hists[i][0], hists[i][1], h_mask,
+            with jax.named_scope("kv_stage"):
+                staged = staged.at[i, 0, step_k].set(
+                    k[:, 0].astype(staged.dtype))
+                staged = staged.at[i, 1, step_k].set(
+                    v[:, 0].astype(staged.dtype))
+            with jax.named_scope("attn"):
+                if backend == "xla":
+                    h_mask = (
+                        hist_mask_local
+                        if cfg.layer_sliding(i)
+                        else hist_mask
+                    )
+                    if hists is not None:
+                        return attention_with_hist(
+                            q, hists[i][0], hists[i][1], h_mask,
+                            staged[i, 0], staged[i, 1], staged_mask,
+                            scale=cfg.attn_scale,
+                            softcap=cfg.attn_logit_softcap,
+                        )
+                    return paged_attention_with_staged(
+                        q, kv_caches[i], block_tables, h_mask,
                         staged[i, 0], staged[i, 1], staged_mask,
                         scale=cfg.attn_scale,
                         softcap=cfg.attn_logit_softcap,
                     )
-                return paged_attention_with_staged(
-                    q, kv_caches[i], block_tables, h_mask,
-                    staged[i, 0], staged[i, 1], staged_mask,
-                    scale=cfg.attn_scale,
-                    softcap=cfg.attn_logit_softcap,
-                )
-            if cfg.any_sliding or cfg.attn_logit_softcap:
-                # self-enforcing invariant (the runner gates these models
-                # to XLA): the decode kernel has no window masking or
-                # softcap — silently wrong numerics otherwise
-                raise NotImplementedError(
-                    "pallas decode does not support sliding-window or "
-                    "softcapped models"
-                )
-            if mesh is not None and mesh.size > 1:
-                # pallas_call has no GSPMD partition rule — shard_map over
-                # (dp, tp) places one kernel instance per device
-                return paged_decode_attention_sharded(
-                    mesh, q[:, 0], kv_caches[i], block_tables, hist_len,
+                if cfg.any_sliding or cfg.attn_logit_softcap:
+                    # self-enforcing invariant (the runner gates these models
+                    # to XLA): the decode kernel has no window masking or
+                    # softcap — silently wrong numerics otherwise
+                    raise NotImplementedError(
+                        "pallas decode does not support sliding-window or "
+                        "softcapped models"
+                    )
+                if mesh is not None and mesh.size > 1:
+                    # pallas_call has no GSPMD partition rule — shard_map
+                    # over (dp, tp) places one kernel instance per device
+                    return paged_decode_attention_sharded(
+                        mesh, q[:, 0], kv_caches[i], block_tables, hist_len,
+                        staged[i, 0], staged[i, 1], step_k,
+                        scale=cfg.attn_scale,
+                        interpret=backend == "pallas_interpret",
+                    )[:, None]
+                return paged_decode_attention(
+                    q[:, 0], kv_caches[i], block_tables, hist_len,
                     staged[i, 0], staged[i, 1], step_k, scale=cfg.attn_scale,
                     interpret=backend == "pallas_interpret",
                 )[:, None]
-            return paged_decode_attention(
-                q[:, 0], kv_caches[i], block_tables, hist_len,
-                staged[i, 0], staged[i, 1], step_k, scale=cfg.attn_scale,
-                interpret=backend == "pallas_interpret",
-            )[:, None]
 
         x = _layer_body(
             cfg, lp, x, positions[:, None], attend,
             _lora_layer_slice(lora, i), lora_idx,
         )
-    x = rms_norm(x[:, 0], params["final_norm"], cfg.rms_norm_eps, cfg.rms_norm_add_one,
-                 scale_f32=cfg.norm_scale_f32)
+    with jax.named_scope("final_norm"):
+        x = rms_norm(x[:, 0], params["final_norm"], cfg.rms_norm_eps,
+                     cfg.rms_norm_add_one, scale_f32=cfg.norm_scale_f32)
     return x, staged
 
 
@@ -614,10 +630,12 @@ def commit_staged_kv(
     scatter per layer — the only pool write of the fused decode window."""
     L, _, w, b, kvh, d = staged.shape
     new_kv: list[jax.Array] = []
-    for i in range(L):
-        k_rows = jnp.moveaxis(staged[i, 0], 0, 1).reshape(b * w, kvh, d)
-        v_rows = jnp.moveaxis(staged[i, 1], 0, 1).reshape(b * w, kvh, d)
-        new_kv.append(write_kv_pages(kv_caches[i], k_rows, v_rows, slot_mapping))
+    with jax.named_scope("kv_commit"):
+        for i in range(L):
+            k_rows = jnp.moveaxis(staged[i, 0], 0, 1).reshape(b * w, kvh, d)
+            v_rows = jnp.moveaxis(staged[i, 1], 0, 1).reshape(b * w, kvh, d)
+            new_kv.append(
+                write_kv_pages(kv_caches[i], k_rows, v_rows, slot_mapping))
     return tuple(new_kv)
 
 
@@ -771,10 +789,11 @@ def forward_context_parallel(
 def compute_logits(cfg: ModelConfig, params: dict, hidden: jax.Array) -> jax.Array:
     """hidden: (N, h) -> logits (N, vocab) in float32 (Gemma-2 applies a
     final tanh softcap)."""
-    if cfg.tie_word_embeddings:
-        logits = (hidden @ params["embed"].T).astype(jnp.float32)
-    else:
-        logits = _mm(hidden, params["lm_head"]).astype(jnp.float32)
     from ..ops.attention import _softcap
 
-    return _softcap(logits, cfg.final_logit_softcap)
+    with jax.named_scope("lm_head"):
+        if cfg.tie_word_embeddings:
+            logits = (hidden @ params["embed"].T).astype(jnp.float32)
+        else:
+            logits = _mm(hidden, params["lm_head"]).astype(jnp.float32)
+        return _softcap(logits, cfg.final_logit_softcap)
