@@ -20,7 +20,10 @@ from vllm_production_stack_tpu.ops import paged_attention_pallas as pk
 from vllm_production_stack_tpu.parallel import mesh as mesh_lib
 
 # (num_heads, num_kv_heads, head_dim) of the registry presets
-HEADS = {"qwen3-8b": (32, 8, 128), "llama-1b": (32, 8, 64)}
+HEADS = {"qwen3-8b": (32, 8, 128), "llama-1b": (32, 8, 64),
+         "qwen2-7b": (28, 4, 128),
+         # what one device runs under shard_map at tp=4
+         "qwen3-8b/tp4": (8, 2, 128), "qwen2-7b/tp4": (7, 1, 128)}
 POOL_BLOCKS = 1024
 CTX = 4096  # the context 'auto' starts choosing the decode kernel at
 BATCH, WINDOW, CHUNK = 8, 8, 512
@@ -49,9 +52,9 @@ def topo():
     cc.reset_cache()
 
 
-def _decode_args(sds, model, block, kv_dtype, specs):
+def _decode_args(sds, model, block, kv_dtype, specs, nb=None):
     nh, kvh, d = HEADS[model]
-    nb = CTX // block
+    nb = nb or CTX // block
     q, pool, b2, b1, staged, rep = specs
     return (
         sds((BATCH, nh, d), jnp.bfloat16, q),
@@ -89,16 +92,35 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("model,block,kv_dtype", [
-    ("qwen3-8b", 32, "bfloat16"),  # chip_smoke.py's served configuration
-    ("qwen3-8b", 16, "bfloat16"),
-    ("qwen3-8b", 32, "float8_e4m3fn"),
-    ("llama-1b", 32, "bfloat16"),
-    ("llama-1b", 16, "float8_e4m3fn"),
+@pytest.mark.parametrize("model,block,kv_dtype,width", [
+    # ids of the full-width cases as they were before the width was a case
+    pytest.param("qwen3-8b", 32, "bfloat16", None,  # chip_smoke.py's served
+                 id="qwen3-8b-32-bfloat16"),        # configuration
+    pytest.param("qwen3-8b", 16, "bfloat16", None, id="qwen3-8b-16-bfloat16"),
+    pytest.param("qwen3-8b", 32, "float8_e4m3fn", None,
+                 id="qwen3-8b-32-float8_e4m3fn"),
+    # D=64: pages do not fill the pool's tiles: the grid form of the kernel
+    pytest.param("llama-1b", 32, "bfloat16", None, id="llama-1b-32-bfloat16"),
+    pytest.param("llama-1b", 16, "float8_e4m3fn", None,
+                 id="llama-1b-16-float8_e4m3fn"),
+    # the benchmark's cells: 64-page tables (width_floor_blocks), both models
+    ("qwen3-8b", 32, "bfloat16", 64),
+    ("qwen2-7b", 32, "bfloat16", 64),
+    ("qwen2-7b", 32, "bfloat16", None),
+    ("qwen2-7b", 32, "float8_e4m3fn", 64),
+    # tp shards: one bf16 kv head is half a tile's rows, two fp8 heads too
+    # (the grid form of the kernel again)
+    ("qwen3-8b/tp4", 32, "bfloat16", 64),
+    ("qwen2-7b/tp4", 32, "bfloat16", 64),
+    ("qwen3-8b/tp4", 32, "float8_e4m3fn", 64),
+    # tables narrower than a page block (width_floor_blocks=1)
+    ("qwen3-8b", 32, "bfloat16", 1),
+    ("qwen3-8b", 32, "bfloat16", 2),
 ])
-def test_decode_kernel_compiles_for_v5e(topo, model, block, kv_dtype):
+def test_decode_kernel_compiles_for_v5e(topo, model, block, kv_dtype, width):
     args = _decode_args(
-        _one_chip(topo), model, block, jnp.dtype(kv_dtype), (None,) * 6
+        _one_chip(topo), model, block, jnp.dtype(kv_dtype), (None,) * 6,
+        nb=width,
     )
     scale = HEADS[model][2] ** -0.5
     _assert_kernel(jax.jit(
@@ -148,6 +170,44 @@ def test_sharded_kernels_compile_for_four_v5e_chips(topo):
     _assert_kernel(jax.jit(
         lambda *a: pk.paged_prefill_attention_sharded(mesh, *a, scale=scale)
     ).lower(*pargs).compile())
+
+
+@pytest.mark.parametrize("nb,bs,nh,kvh,d,kv_itemsize,want", [
+    (64, 32, 32, 8, 128, 2, 8),    # qwen3-8b, the benchmark's table
+    (128, 32, 28, 4, 128, 2, 8),   # qwen2-7b, the widest table
+    (256, 16, 32, 8, 128, 2, 16),  # 16-token pages: the same 256 tokens
+    (64, 64, 32, 8, 128, 2, 4),
+    (64, 32, 32, 8, 128, 1, 8),    # fp8 pool: the widened copy still fits
+    (64, 32, 64, 16, 128, 2, 8),   # 64/16 heads: 8 MiB, still inside
+    (64, 32, 64, 16, 128, 1, 4),   # ... but not with an fp8 pool's copy
+    (64, 32, 32, 32, 128, 2, 4),   # no GQA: every head its own columns
+    (2, 32, 32, 8, 128, 2, 2),     # never wider than the table
+    (1, 32, 32, 8, 128, 2, 1),
+])
+def test_decode_page_block_fits_scoped_vmem(nb, bs, nh, kvh, d, kv_itemsize,
+                                            want):
+    pages = pk._decode_pages_per_block(nb, bs, nh, kvh, d, kv_itemsize, 2)
+    assert pages == want
+    assert pages <= nb
+    assert pages * bs <= max(pk.DECODE_BLOCK_TOKENS, bs)
+    held = pk._decode_block_vmem(pages, bs, nh, kvh, d, kv_itemsize, 2)
+    assert held <= pk.DECODE_VMEM_BUDGET < 16 * 2**20
+    if pages < min(nb, pk.DECODE_BLOCK_TOKENS // bs):  # cut by the budget
+        assert pk._decode_block_vmem(
+            2 * pages, bs, nh, kvh, d, kv_itemsize, 2
+        ) > pk.DECODE_VMEM_BUDGET
+
+
+@pytest.mark.parametrize("kvh,d,itemsize,want", [
+    (8, 128, 2, True), (4, 128, 2, True), (2, 128, 2, True),
+    (1, 128, 2, False), (8, 64, 2, False), (8, 128, 1, True),
+    (4, 128, 1, True), (2, 128, 1, False), (1, 128, 4, True),
+    (16, 128, 2, True),
+])
+def test_decode_kernel_form_follows_the_pool_tiles(kvh, d, itemsize, want):
+    """Which pools the walk kernel serves (it slices pages out of HBM) and
+    which the grid kernel: both sides compile above, at these shapes."""
+    assert pk._pages_fill_tiles(kvh, d, itemsize) is want
 
 
 @pytest.mark.parametrize("nh,d,itemsize,want", [

@@ -81,6 +81,134 @@ def test_pallas_zero_history():
                                atol=2e-5)
 
 
+def _ragged_case(hist, nb, bs, kvh, qpk, d, w=4, seed=0, dtype=np.float32,
+                 dead="null"):
+    """A batch whose rows hold exactly the pages their history needs. Dead
+    table slots point at the null page, or (dead="garbage") at pages that
+    hold other data and belong to nobody in the batch."""
+    rng = np.random.RandomState(seed)
+    hist = np.asarray(hist, np.int32)
+    b, nh = len(hist), kvh * qpk
+    held = -(-hist // bs)
+    num_blocks = int(held.sum()) + 9
+    kv = rng.randn(2, num_blocks, bs, kvh, d).astype(dtype)
+    owned = rng.permutation(np.arange(1, num_blocks - 8))
+    tables = np.zeros((b, nb), np.int32)
+    if dead == "garbage":
+        tables[:] = np.random.RandomState(seed + 1).randint(
+            num_blocks - 8, num_blocks, size=(b, nb))
+    at = 0
+    for i, n in enumerate(held):
+        tables[i, :n] = owned[at:at + n]
+        at += n
+    q = rng.randn(b, nh, d).astype(dtype)
+    staged_k = rng.randn(w, b, kvh, d).astype(dtype)
+    staged_v = rng.randn(w, b, kvh, d).astype(dtype)
+    return q, kv, tables, hist, staged_k, staged_v
+
+
+def _kernel(case, step_k=1):
+    q, kv, tables, hist, staged_k, staged_v = case
+    return np.asarray(paged_decode_attention(
+        jnp.asarray(q), jnp.asarray(kv), jnp.asarray(tables),
+        jnp.asarray(hist), jnp.asarray(staged_k), jnp.asarray(staged_v),
+        jnp.asarray(np.int32(step_k)), scale=q.shape[-1] ** -0.5,
+        interpret=True,
+    ).astype(jnp.float32))
+
+
+def _reference(case, step_k=1):
+    q, kv, tables, hist, staged_k, staged_v = case
+    hist_mask = np.arange(tables.shape[1] * kv.shape[2])[None, :] < hist[:, None]
+    return np.asarray(paged_attention_with_staged(
+        jnp.asarray(q[:, None]), jnp.asarray(kv), jnp.asarray(tables),
+        jnp.asarray(hist_mask), jnp.asarray(staged_k), jnp.asarray(staged_v),
+        jnp.asarray(np.arange(staged_k.shape[0]) <= step_k),
+        scale=q.shape[-1] ** -0.5,
+    )[:, 0].astype(jnp.float32))
+
+
+# (nb, bs, kvh, qpk, d): the page block is 256 tokens (P = 256 // bs pages).
+# D=128 pools take the walk form of the kernel (its own page copies), D=64
+# pools the grid form (pipeline windows): _pages_fill_tiles
+RAGGED_SHAPES = {
+    "qwen3-8b-heads": (64, 32, 8, 4, 128),
+    "qwen2-7b-group7": (64, 32, 4, 7, 128),
+    "tp-shard-kvh1": (32, 32, 1, 4, 128),
+    "llama-1b-d64": (32, 32, 2, 4, 64),
+    "page16": (64, 16, 2, 2, 64),
+    "page64": (16, 64, 2, 2, 64),
+}
+
+
+@pytest.mark.parametrize("shape", list(RAGGED_SHAPES))
+def test_pallas_ragged_batch_matches_xla_reference(shape):
+    """One batch holding every edge of the page walk: no history, one token,
+    a page less one / exact / plus one, a block plus a bit (not a multiple
+    of P * bs), several blocks, and the full table."""
+    nb, bs, kvh, qpk, d = RAGGED_SHAPES[shape]
+    span = 256
+    hist = [0, 1, bs - 1, bs, bs + 1, span + 7, 2 * span, nb * bs - 3,
+            0, nb * bs]
+    case = _ragged_case(hist, nb, bs, kvh, qpk, d, seed=2)
+    np.testing.assert_allclose(_kernel(case), _reference(case), rtol=2e-5,
+                               atol=2e-5)
+
+
+@pytest.mark.parametrize("d", [64, 128])
+def test_pallas_bf16_pool_matches_xla_reference(d):
+    """bf16 operands, f32 statistics — what the chip runs: same products
+    and accumulation width as the XLA decode attention."""
+    case = _ragged_case([0, 40, 300, 700], 32, 32, 2, 4, d, seed=4,
+                        dtype=jnp.bfloat16)
+    np.testing.assert_allclose(_kernel(case), _reference(case), rtol=2e-2,
+                               atol=2e-2)
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("nb", [1, 2, 3, 12])
+def test_pallas_table_narrower_than_a_block(nb, d):
+    """width_floor_blocks=1 gives tables of 1, 2, 4 pages: the block shrinks
+    to the table; a width that is no multiple of the block (12 = 8 + 4) has
+    its last block's slots clamped to the table."""
+    bs = 32
+    case = _ragged_case([0, 5, bs, nb * bs], nb, bs, 2, 2, d, seed=3)
+    np.testing.assert_allclose(_kernel(case), _reference(case), rtol=2e-5,
+                               atol=2e-5)
+
+
+@pytest.mark.parametrize("d", [64, 128])
+def test_pallas_dead_table_slots_are_not_attended(d):
+    """Slots past a row's pages may hold stale ids of pages that are not the
+    row's: what they point at must not reach the output, bit for bit."""
+    hist = [0, 9, 250, 256, 300, 1000]
+    null = _ragged_case(hist, 64, 32, 2, 4, d, seed=6)
+    garbage = _ragged_case(hist, 64, 32, 2, 4, d, seed=6, dead="garbage")
+    assert (null[2] != garbage[2]).any()
+    np.testing.assert_array_equal(_kernel(null), _kernel(garbage))
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("hist", [9, 300, 2048])
+def test_pallas_row_is_batch_and_table_width_invariant(hist, d):
+    """The benchmark's batch-invariance probe: a row's output is bitwise the
+    same alone, among 15 others, and in the 64- and the 128-page program."""
+    rng = np.random.RandomState(hist)
+    others = [int(h) for h in rng.randint(0, 2048, size=15)]
+    at = 7
+    batch_hist = others[:at] + [hist] + others[at:]
+    q, kv, tables, lens, sk, sv = _ragged_case(
+        batch_hist, 64, 32, 2, 4, d, seed=8)
+    in_batch = _kernel((q, kv, tables, lens, sk, sv))[at]
+    row = slice(at, at + 1)
+    alone = (q[row], kv, tables[row], lens[row], sk[:, row], sv[:, row])
+    np.testing.assert_array_equal(_kernel(alone)[0], in_batch)
+    wide = np.zeros((len(batch_hist), 128), np.int32)
+    wide[:, :64] = tables
+    np.testing.assert_array_equal(
+        _kernel((q, kv, wide, lens, sk, sv))[at], in_batch)
+
+
 def test_decode_window_step_pallas_backend_matches_xla():
     """Full model step: interpret-mode pallas backend == xla backend."""
     from vllm_production_stack_tpu.engine.config import ModelConfig

@@ -60,12 +60,13 @@ def resolve_auto_attention_backend(
 ) -> str:
     """The 'auto' decode-attention choice as a pure predicate of the
     static engine config (testable without a device). Derived from the
-    v5e sweep in ModelRunner._resolve_attention_backend: the Pallas kernel
-    wins at >=32-token pages in the LONG-context regime (ctx ~4k: -7% to
-    -19%); at ~1k contexts the outcome is batch-dependent (XLA wins at
-    batch 16, the kernel edges batch 64/block 64 by ~6%) — the gate keys
-    on max_model_len because batch varies at runtime while the program is
-    compiled per config, a deliberately conservative trade. Single-device
+    v5e sweep of the kernel as it was before PR 25 (one grid step per table
+    slot): it won at >=32-token pages in the LONG-context regime (ctx ~4k:
+    -7% to -19%) and at ~1k contexts the outcome was batch-dependent — the
+    gate keys on max_model_len because batch varies at runtime while the
+    program is compiled per config, a deliberately conservative trade. The
+    sweep of the kernel as it is now (ModelRunner._resolve_attention_backend)
+    says the gate can widen; it has not been (ROADMAP D3). Single-device
     unquantized pools on a real TPU only for 'auto' (no GSPMD partition
     rule; Mosaic-compiled); explicit 'pallas' also supports fp8 pools."""
     if (
@@ -429,32 +430,37 @@ class ModelRunner:
     def _resolve_attention_backend(self) -> str:
         """'auto' → the measured winner for the pool's block size.
 
-        Swept on a v5e chip (benchmarks/sweep_attention.py, llama-1b decode
-        head shape, 64-iteration on-device loops, ms/iter):
+        Swept on a v5e chip on 2026-09-27 (PR 25: the kernel walks the pages
+        a row holds, 256 tokens a block; benchmarks/sweep_attention.py,
+        llama-1b decode head shape 32/8 x 64, bf16 pool, full tables,
+        64-iteration on-device loops, ms/iter):
 
             batch ctx   block   pallas   xla     winner
-            16    1024  16      1.93     1.63    xla
-            16    1024  32      1.73     1.58    xla
-            16    1024  64      1.63     1.57    xla (±4%)
-            16    4096  16      3.40     2.72    xla
-            16    4096  32      2.71     2.93    pallas
-            16    4096  64      2.48     2.50    pallas (±1%)
-            64    1024  16      3.48     3.24    xla
-            64    1024  64      2.57     2.73    pallas
-            64    4096  64      4.68     5.80    pallas (-19%)
+            16    1024  16      0.134    0.112   xla
+            16    1024  32      0.104    0.112   pallas (-7%)
+            16    1024  64      0.092    0.112   pallas (-18%)
+            16    4096  16      0.461    1.283   pallas (-64%)
+            16    4096  32      0.401    1.284   pallas (-69%)
+            16    4096  64      0.390    0.817   pallas (-52%)
+            64    1024  16      0.477    1.287   pallas (-63%)
+            64    1024  64      0.405    0.837   pallas (-52%)
+            64    4096  64      1.513    3.974   pallas (-62%)
 
-        At 16-token pages the kernel's per-page pipeline (16 KB DMAs,
-        16-token matmuls) loses to XLA's bulk gather — XLA is 'auto' there
-        (the shipped default config). At 32/64-token pages the winner
-        flips with context: XLA still edges ~1k contexts, the kernel wins
-        the 4k rows — so 'auto' requires BOTH block_size >= 32 and a
-        long-context engine (max_model_len >= 4096), single device,
-        unquantized (resolve_auto_attention_backend — the pure predicate
-        tests pin). The kernel also never materializes the O(B×S) gather
+        (the sweep's pool and tables do not change inside its loop, so XLA
+        may hoist its gather out of it: the 0.112 rows flatter XLA.) With an
+        fp8 pool XLA still wins every row, 2.2-3.0x (16 x 4096 x 32: 2.00
+        vs 0.92): the kernel's widening of a block costs more than the
+        bytes it saves. Before PR 25 the kernel ran one grid step per table
+        slot (16 x 4096 x 32: 2.71 vs 2.93; 16 x 1024 x 16: 1.93 vs 1.63),
+        which is what the predicate below was written from: 'auto' requires
+        BOTH block_size >= 32 and a long-context engine (max_model_len >=
+        4096), single device, unquantized (resolve_auto_attention_backend —
+        the pure predicate tests pin). The new table says it could widen to
+        16-token pages and ~1k contexts at block >= 32 (ROADMAP D3); fp8
+        stays XLA. The kernel also never materializes the O(BxS) gather
         scratch that OOMs large models (bench_northstar.py's llama-3b
-        finding). Explicit 'pallas' stays single-device-only (no GSPMD
-        partition rule for pallas_call; wrap in shard_map before enabling
-        under tp>1); CPU tests pin numerics via interpret mode."""
+        finding). Under tp>1 an explicit 'pallas' runs one kernel per device
+        through shard_map; CPU tests pin numerics via interpret mode."""
         backend = self.config.attention_backend
         if self.config.model.any_sliding:
             # sliding-window models (Mistral-v0.1, Gemma-2 class): the

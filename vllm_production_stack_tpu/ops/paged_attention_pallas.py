@@ -1,23 +1,29 @@
-"""Pallas TPU kernel: paged decode attention over the HBM KV pool.
+"""Pallas TPU kernels: paged decode and prefill attention over the HBM KV pool.
 
 The XLA fallback (ops/attention.py:paged_attention_with_staged) materializes
 each row's gathered history — (B, S, kvH, D) per layer per window iteration —
-in HBM before attending. This kernel never materializes the gather: the
-Pallas pipeline streams KV pages HBM→VMEM directly from the paged pool, with
-the page id for each grid step read from the scalar-prefetched block table
-(the "gather" IS the pipeline's index_map), and a flash-style online softmax
-accumulates across pages in VMEM scratch. The window's staged K/V (see
-models/llama.py:decode_window_step) are folded in by a final grid step so the
-kernel computes the complete decode attention output.
+in HBM before attending. The decode kernel never materializes the gather and
+never walks more of a block table than a row has filled: the pool stays in
+HBM, and per row the kernel copies the ceil(hist_len / block_size) pages the
+row holds into VMEM itself, several pages (256 tokens) a block, page ids from
+the scalar-prefetched block table, the next block's copies in flight while
+the current block is computed. A flash-style online softmax accumulates
+across blocks, and the window's staged K/V (see
+models/llama.py:decode_window_step) are folded in last, so the kernel
+computes the complete decode attention output in one op.
 
 Reference parity: the reference stack delegates this op to vLLM's CUDA
 paged-attention kernels inside its engine images (external to its repo);
 SURVEY §7.3 ranks a TPU-native equivalent as hard part #1.
 
-Layout notes (pallas_guide.md): last dim 128 lanes — head_dim (64/128) maps
-onto lanes; token-position and head axes map onto sublanes. All matmuls are
-(≤heads × D) @ (D × page) — small for the MXU, but decode is HBM-bandwidth
-bound, so the win is streaming pages once, not MXU utilization.
+Layout notes (pallas_guide.md): last dim 128 lanes — head_dim maps onto
+lanes; a page's (token, kv head) pairs map onto sublanes as they lie in the
+pool, so a block is one (P * bs * kvH, D) matrix and a decode step is two
+dots a block — (heads x D) @ (D x columns) and (heads x columns) @
+(columns x D), operands in the pool's/activations' dtype, f32 accumulation
+— with the columns of other kv heads masked. Decode stays HBM-bandwidth
+bound: the MXU's cost is loading each K/V tile once, which it must do
+however the heads are grouped. Measured on a v5e chip: docs/22.
 """
 
 from __future__ import annotations
@@ -32,114 +38,270 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _decode_kernel(
+# Decode: tokens per page block. A row's pages are visited DECODE_BLOCK_TOKENS
+# at a time (8 pages of 32), cut down where the double-buffered K/V block and
+# its score temporaries would not fit DECODE_VMEM_BUDGET of the 16 MiB
+# scoped-VMEM limit (tests/test_chip_compile.py), and never wider than the
+# block table.
+DECODE_BLOCK_TOKENS = 256
+DECODE_VMEM_BUDGET = 10 * 2**20
+
+
+def _decode_block_vmem(
+    pages: int, bs: int, nh: int, kvh: int, d: int, kv_itemsize: int,
+    q_itemsize: int,
+) -> int:
+    """Bytes of VMEM one decode grid step holds with `pages` pages a block:
+    two K/V slots at the pool's width, a widened copy of the block where the
+    pool is narrower than the queries (fp8), and the f32 score rows every
+    query head keeps against every (token, kv head) column."""
+    lanes = -(-d // 128) * 128
+    cols = pages * bs * kvh
+    slots = 2 * 2 * cols * lanes * kv_itemsize
+    widened = 0 if kv_itemsize >= q_itemsize else 2 * cols * lanes * (
+        4 + q_itemsize)
+    scores = 4 * (-(-nh // 8) * 8) * cols * 4
+    return slots + widened + scores
+
+
+def _decode_pages_per_block(
+    nb: int, bs: int, nh: int, kvh: int, d: int, kv_itemsize: int,
+    q_itemsize: int,
+) -> int:
+    """Pages per block, from static shapes alone: a row's blocks start at
+    multiples of pages * bs from position 0 whatever the table's width and
+    whoever shares the batch, so its output does not depend on either."""
+    pages = max(1, DECODE_BLOCK_TOKENS // bs)
+    while pages > 1 and _decode_block_vmem(
+        pages, bs, nh, kvh, d, kv_itemsize, q_itemsize
+    ) > DECODE_VMEM_BUDGET:
+        pages //= 2
+    return min(pages, nb)
+
+
+def _pages_fill_tiles(kvh: int, d: int, itemsize: int) -> bool:
+    """Whether a page's minor dims (kvh, D) fill whole tiles of the layout
+    Mosaic gives the pool in HBM — 128 lanes, and rows in the power of two
+    that holds kvh, from what one 32-bit sublane packs (2 bf16, 4 fp8) up to
+    eight sublanes. Only then can a kernel slice single pages out of the pool
+    for its own copies; a D=64 pool, one bf16 kv head or two fp8 ones (tp
+    shards) are padded in HBM, and only the Pallas pipeline can window them."""
+    packing = max(1, 4 // itemsize)
+    tile = min(max(packing, 1 << (kvh - 1).bit_length()), 8 * packing)
+    return kvh % tile == 0 and d % 128 == 0
+
+
+def _flash_block(q, keys, values, valid, carry, scale):
+    """One online-softmax update of every query head against a block laid out
+    as (columns, D), a column being one (token, kv head) pair. Every head's
+    row is scored against every column in ONE dot and `valid` keeps the
+    columns of the head's own kv head: the MXU's work is set by the K/V tiles
+    it must load, which are the same either way, and no per-head slice of the
+    head-interleaved pool layout has to be cut. Operands go to the MXU in the
+    query dtype (a narrower pool is widened, as ops/attention._to_compute
+    does); scores, statistics and the accumulator are f32."""
+    m_prev, l_prev, acc = carry
+    if keys.dtype != q.dtype:
+        # not every pair of dtypes converts directly on every chip: via f32
+        keys = keys.astype(jnp.float32).astype(q.dtype)
+        values = values.astype(jnp.float32).astype(q.dtype)
+    scores = jax.lax.dot_general(
+        q, keys, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )  # (nh, columns)
+    scores = jnp.where(valid, scores * scale, NEG_INF)
+    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(scores - m_new)  # exactly 0 on masked columns
+    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc = acc * alpha + jax.lax.dot(
+        p.astype(values.dtype), values, preferred_element_type=jnp.float32
+    )
+    return m_new, l_new, acc
+
+
+def _column_tokens(nh: int, cols: int, kvh: int):
+    """(nh, cols) int32: the token index of column c (c // kvh) where the
+    column's kv head (c % kvh) is the one query head r attends (r // qpk),
+    else a token index no length reaches."""
+    col = jax.lax.broadcasted_iota(jnp.int32, (nh, cols), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (nh, cols), 0)
+    own = col % kvh == row // (nh // kvh)
+    return jnp.where(own, col // kvh, jnp.iinfo(jnp.int32).max)
+
+
+def _fold_staged(q, staged_k_ref, staged_v_ref, step, carry, scale):
+    """The last flash update, over the window's staged K/V (W, 1, kvh, D) of
+    this row — slot w is written iff w <= step — and the normalized output."""
+    w, _, kvh, d = staged_k_ref.shape
+    _, l, acc = _flash_block(
+        q,
+        staged_k_ref[:, 0].reshape(w * kvh, d),
+        staged_v_ref[:, 0].reshape(w * kvh, d),
+        _column_tokens(q.shape[0], w * kvh, kvh) <= step,
+        carry, scale,
+    )
+    return acc / l
+
+
+def _decode_walk_kernel(
     # scalar prefetch
     tables_ref,  # (B, nb) int32 — page id per (row, page-slot)
     hist_ref,  # (B,) int32 — pool history length per row
     step_ref,  # (1,) int32 — iteration index inside the fused window
-    # pipeline inputs
+    # inputs
     q_ref,  # (1, nh, D)
-    kv_ref,  # (2, 1, bs, kvh, D) — this grid step's pool page (K and V)
+    kv_hbm,  # (2, num_blocks, bs, kvh, D) — the whole pool, left in HBM
     staged_k_ref,  # (W, 1, kvh, D) — this row's staged window K
     staged_v_ref,  # (W, 1, kvh, D)
     # output
     out_ref,  # (1, nh, D)
     # scratch
-    m_ref,  # (nh, 1) f32 running max
-    l_ref,  # (nh, 1) f32 running denominator
-    acc_ref,  # (nh, D) f32 running numerator
+    buf,  # (2, 2, P, bs, kvh, D) — two slots of one block's K and V pages
+    sems,  # DMA semaphore per slot
+    state,  # SMEM (2,) int32: slot of the next block; is it in flight
     *,
     scale: float,
-    block_size: int,
-    num_kv_heads: int,
 ):
+    """One grid step = one row. The row's ceil(hist_len / (P * bs)) page
+    blocks are walked in a loop whose trip count is the row's own, each block
+    copied HBM->VMEM by P async copies (page ids from the block table) while
+    the block before it is computed; the last block of a row starts the first
+    block of the next row that has any. A padding row (hist_len 0) copies and
+    computes nothing but the staged window."""
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    num_pages = pl.num_programs(1) - 1  # last step handles the staged window
-
+    rows = pl.num_programs(0)
+    nb = tables_ref.shape[1]
+    pages, bs, kvh = buf.shape[2], buf.shape[3], buf.shape[4]
     nh, d = q_ref.shape[1], q_ref.shape[2]
-    qpk = nh // num_kv_heads
-    q = q_ref[0].astype(jnp.float32)  # (nh, D)
+    span = pages * bs  # tokens per block
+    cols = span * kvh
+    hist = hist_ref[b]
+    n_blocks = pl.cdiv(hist, span)
+    blocks = buf.reshape(2, 2, cols, d)  # (slot, K|V, columns, D)
+
+    def block_copies(row, blk, slot):
+        # table slots past the row's pages hold the null page or stale ids:
+        # read whole, masked by position; slots past the table are clamped
+        return [
+            pltpu.make_async_copy(
+                kv_hbm.at[:, tables_ref[row, jnp.minimum(blk * pages + j,
+                                                         nb - 1)]],
+                buf.at[slot, :, j],
+                sems.at[slot],
+            )
+            for j in range(pages)
+        ]
+
+    def start_block(row, blk, slot):
+        for copy in block_copies(row, blk, slot):
+            copy.start()
+
+    @pl.when(b == 0)
+    def _reset():
+        state[0] = 0
+        state[1] = 0
+
+    slot0 = state[0]
+
+    @pl.when((n_blocks > 0) & (state[1] == 0))
+    def _first_block():
+        start_block(b, 0, slot0)
+
+    col_tokens = _column_tokens(nh, cols, kvh)
+    q = q_ref[0]  # (nh, D), native dtype
+
+    def visit(i, carry):
+        slot = (slot0 + i) % 2
+
+        @pl.when(i + 1 < n_blocks)
+        def _next_block():
+            start_block(b, i + 1, 1 - slot)
+
+        @pl.when(i + 1 == n_blocks)
+        def _next_row():
+            nxt = jax.lax.while_loop(
+                lambda r: (r < rows)
+                & (hist_ref[jnp.minimum(r, rows - 1)] == 0),
+                lambda r: r + 1,
+                b + 1,
+            )
+
+            @pl.when(nxt < rows)
+            def _its_first_block():
+                start_block(nxt, 0, 1 - slot)
+
+            state[0] = 1 - slot
+            state[1] = (nxt < rows).astype(jnp.int32)
+
+        for copy in block_copies(b, i, slot):
+            copy.wait()
+        return _flash_block(
+            q, blocks[slot, 0], blocks[slot, 1],
+            col_tokens < hist - i * span, carry, scale,
+        )
+
+    carry = jax.lax.fori_loop(0, n_blocks, visit, (
+        jnp.full((nh, 1), NEG_INF, jnp.float32),
+        jnp.zeros((nh, 1), jnp.float32),
+        jnp.zeros((nh, d), jnp.float32),
+    ))
+
+    out_ref[0] = _fold_staged(
+        q, staged_k_ref, staged_v_ref, step_ref[0], carry, scale
+    ).astype(out_ref.dtype)
+
+
+def _decode_grid_kernel(
+    # scalar prefetch
+    tables_ref,  # (B, nb) int32
+    hist_ref,  # (B,) int32
+    step_ref,  # (1,) int32
+    # pipeline inputs
+    q_ref,  # (1, nh, D)
+    *refs,  # P pool pages (2, 1, bs, kvh, D), staged K and V, out, m, l, acc
+    scale: float,
+):
+    """The same page blocks for pools whose pages the walk kernel cannot
+    slice out of HBM (_pages_fill_tiles): the grid is (rows, blocks the table
+    could hold), a block's P pages arrive through P pipeline windows, and a
+    row's dead steps come FIRST — their windows already name the row's first
+    block, so its copies start under the previous row's last block, no step
+    re-fetches an unchanged window, and only live steps compute."""
+    *page_refs, staged_k_ref, staged_v_ref, out_ref, m_ref, l_ref, acc_ref = refs
+    b, j = pl.program_id(0), pl.program_id(1)
+    bs, kvh = page_refs[0].shape[2], page_refs[0].shape[3]
+    nh, d = q_ref.shape[1], q_ref.shape[2]
+    span = len(page_refs) * bs
+    hist = hist_ref[b]
+    blk = j - (pl.num_programs(1) - pl.cdiv(hist, span))
+    q = q_ref[0]
 
     @pl.when(j == 0)
-    def _init_acc():
+    def _init():
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def flash_update(scores, values):
-        """scores: (nh, S) f32 already masked; values: (S, kvh, D)."""
-        m_prev, l_prev = m_ref[:], l_ref[:]
-        m_cur = jnp.max(scores, axis=1, keepdims=True)  # (nh, 1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)  # (nh, 1)
-        p = jnp.exp(scores - m_new)  # (nh, S)
-        l_ref[:] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[:] = m_new
-        # per-kv-head GQA: the qpk query heads of group g attend values[:, g].
-        # Built by concatenation — value-level .at[].add lowers to scatter-add,
-        # which the Pallas TPU lowering doesn't implement
-        v_f = values.astype(jnp.float32)
-        acc = acc_ref[:] * alpha  # (nh, D)
-        acc_ref[:] = jnp.concatenate(
-            [
-                acc[g * qpk : (g + 1) * qpk]
-                + jax.lax.dot(
-                    p[g * qpk : (g + 1) * qpk],
-                    v_f[:, g, :],
-                    preferred_element_type=jnp.float32,
-                )
-                for g in range(num_kv_heads)
-            ],
-            axis=0,
+    @pl.when(blk >= 0)
+    def _visit():
+        keys, values = (
+            jnp.concatenate(
+                [r[i, 0].reshape(bs * kvh, d) for r in page_refs], axis=0
+            )
+            for i in (0, 1)
+        )
+        m_ref[:], l_ref[:], acc_ref[:] = _flash_block(
+            q, keys, values,
+            _column_tokens(nh, span * kvh, kvh) < hist - blk * span,
+            (m_ref[:], l_ref[:], acc_ref[:]), scale,
         )
 
-    @pl.when(j < num_pages)
-    def _visit_page():
-        k_page = kv_ref[0, 0].astype(jnp.float32)  # (bs, kvh, D)
-        v_page = kv_ref[1, 0]
-        # token positions covered by this page slot
-        pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_size), 1
-        )
-        valid = pos < hist_ref[b]  # (1, bs)
-        scores = jnp.concatenate(
-            [
-                jax.lax.dot_general(
-                    q[g * qpk : (g + 1) * qpk],
-                    k_page[:, g, :],
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                for g in range(num_kv_heads)
-            ],
-            axis=0,
-        )  # (nh, bs)
-        scores = jnp.where(valid, scores * scale, NEG_INF)
-        flash_update(scores, v_page)
-
-    @pl.when(j == num_pages)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
-        w = staged_k_ref.shape[0]
-        k_st = staged_k_ref[:, 0].astype(jnp.float32)  # (W, kvh, D)
-        v_st = staged_v_ref[:, 0]
-        widx = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)
-        valid = widx <= step_ref[0]  # staged slot written iff w <= k
-        scores = jnp.concatenate(
-            [
-                jax.lax.dot_general(
-                    q[g * qpk : (g + 1) * qpk],
-                    k_st[:, g, :],
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                for g in range(num_kv_heads)
-            ],
-            axis=0,
-        )  # (nh, W)
-        scores = jnp.where(valid, scores * scale, NEG_INF)
-        flash_update(scores, v_st)
-        out_ref[0] = (acc_ref[:] / l_ref[:]).astype(out_ref.dtype)
+        out_ref[0] = _fold_staged(
+            q, staged_k_ref, staged_v_ref, step_ref[0],
+            (m_ref[:], l_ref[:], acc_ref[:]), scale,
+        ).astype(out_ref.dtype)
 
 
 def _hist_kernel(
@@ -164,10 +326,10 @@ def _hist_kernel(
     num_kv_heads: int,
 ):
     """Flash decode over HOISTED CONTIGUOUS history (B, S, kvh, D): large
-    sequential chunks instead of 16-token pool pages, so each HBM→VMEM DMA
-    moves C·kvh·D elements (e.g. 512 tokens ≈ 0.5 MB) — the regime where the
-    pipeline reaches memory bandwidth. Same flash recurrence as
-    _decode_kernel; the staged window folds in on the final grid step."""
+    sequential chunks streamed by the Pallas pipeline, so each HBM→VMEM DMA
+    moves C·kvh·D elements (e.g. 512 tokens ≈ 0.5 MB). One grid step a
+    chunk, per-kv-head dots; the staged window folds in on the final grid
+    step."""
     b = pl.program_id(0)
     j = pl.program_id(1)
     num_chunks = pl.num_programs(1) - 1
@@ -297,7 +459,7 @@ def _prefill_kernel(
 ):
     """Flash prefill over the paged pool: the page id for each grid step
     comes from the scalar-prefetched block table (the gather IS the
-    pipeline's index_map — same trick as _decode_kernel), the query tile
+    pipeline's index_map), the query tile
     stays in VMEM, and causality is computed from iotas alone: the serving
     scheduler feeds chunks with CONTIGUOUS positions (scheduler.py
     work.positions = range(start, start+len)), so q position = chunk_start
@@ -616,45 +778,56 @@ def paged_decode_attention(
     b, nh, d = q.shape
     kvh, bs = kv.shape[3], kv.shape[2]
     nb = block_tables.shape[1]
+    w = staged_k.shape[0]
+    pages = _decode_pages_per_block(
+        nb, bs, nh, kvh, d, kv.dtype.itemsize, q.dtype.itemsize
+    )
+    row = lambda i, *_: (i, 0, 0)  # q, out: one row a grid step
+    staged = pl.BlockSpec((w, 1, kvh, d), lambda i, *_: (0, i, 0, 0))
+    if _pages_fill_tiles(kvh, d, kv.dtype.itemsize):
+        kernel, grid = _decode_walk_kernel, (b,)
+        # the pool stays in HBM: the kernel copies the pages a row holds
+        # itself, page ids from the prefetched block table
+        pool = [pl.BlockSpec(memory_space=pl.ANY)]
+        scratch = [
+            pltpu.VMEM((2, 2, pages, bs, kvh, d), kv.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((2,), jnp.int32),
+        ]
+    else:
+        steps = -(-nb // pages)
+        kernel, grid = _decode_grid_kernel, (b, steps)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # tables, hist_len, step_k
-        grid=(b, nb + 1),
-        in_specs=[
-            pl.BlockSpec((1, nh, d), lambda i, j, t, h, s: (i, 0, 0)),
-            # the paged "gather": page id for grid step (i, j) comes straight
-            # from the prefetched block table (clamped on the final step,
-            # whose fetch is unused)
-            pl.BlockSpec(
-                (2, 1, bs, kvh, d),
-                lambda i, j, t, h, s: (
-                    0,
-                    t[i, jnp.minimum(j, t.shape[1] - 1)],
-                    0,
-                    0,
-                    0,
-                ),
-            ),
-            pl.BlockSpec(
-                (staged_k.shape[0], 1, kvh, d), lambda i, j, t, h, s: (0, i, 0, 0)
-            ),
-            pl.BlockSpec(
-                (staged_v.shape[0], 1, kvh, d), lambda i, j, t, h, s: (0, i, 0, 0)
-            ),
-        ],
-        out_specs=pl.BlockSpec((1, nh, d), lambda i, j, t, h, s: (i, 0, 0)),
-        scratch_shapes=[
+        def page(k):
+            def index_map(i, j, t, h, s):
+                blocks = pl.cdiv(h[i], pages * bs)
+                first = jnp.maximum(j - (steps - blocks), 0) * pages
+                return (0, t[i, jnp.minimum(first + k, nb - 1)], 0, 0, 0)
+
+            return pl.BlockSpec((2, 1, bs, kvh, d), index_map)
+
+        pool = [page(k) for k in range(pages)]
+        scratch = [
             pltpu.VMEM((nh, 1), jnp.float32),
             pltpu.VMEM((nh, 1), jnp.float32),
             pltpu.VMEM((nh, d), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        _decode_kernel, scale=scale, block_size=bs, num_kv_heads=kvh
-    )
+        ]
     return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
+        functools.partial(kernel, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # tables, hist_len, step_k
+            grid=grid,
+            in_specs=[pl.BlockSpec((1, nh, d), row), *pool, staged, staged],
+            out_specs=pl.BlockSpec((1, nh, d), row),
+            scratch_shapes=scratch,
+        ),
         out_shape=jax.ShapeDtypeStruct((b, nh, d), q.dtype),
+        # rows run in order: each hands the next its first block in flight
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * len(grid)
+        ),
         interpret=interpret,
-    )(block_tables, hist_len, jnp.reshape(step_k, (1,)), q, kv, staged_k, staged_v)
+    )(
+        block_tables, hist_len, jnp.reshape(step_k, (1,)), q,
+        *[kv] * len(pool), staged_k, staged_v,
+    )
