@@ -223,3 +223,109 @@ def test_prefill_query_tile_fits_scoped_vmem(nh, d, itemsize, want):
     resident = nh * tt * (4 * itemsize * lanes + 4 * lanes + 2 * 4 * 128)
     assert resident <= pk.PREFILL_VMEM_BUDGET
     assert pk._prefill_q_tile(64, nh, d, itemsize) == min(64, want)
+
+
+# -- a sparse-MoE model's step programs (ISSUE 26) ----------------------------
+
+
+def _moe_program_args(topo, cfg, batch, t, block=32, nb=64, window=8):
+    """Abstract arguments on the first chip: the int8 param tree of `cfg`,
+    a paged pool, and one step's inputs at (batch, t)."""
+    from vllm_production_stack_tpu.models import llama
+    from vllm_production_stack_tpu.models.quantization import quantize_params
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda k: quantize_params(cfg, llama.init_params(cfg, k)),
+        jax.random.PRNGKey(0)))
+    kv = on_chip(jax.eval_shape(
+        lambda: llama.init_kv_cache(cfg, POOL_BLOCKS, block)))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+    staged = on_chip(jax.eval_shape(
+        lambda: llama.init_staged_kv(cfg, window, batch)))
+    return params, kv, i32, staged, nb
+
+
+def _assert_no_widened_experts(cfg, text):
+    """No operation of the program writes a bf16 copy of a stacked expert
+    leaf (E, in, out): int8 experts are read as int8."""
+    import re
+
+    e, h, it = cfg.num_experts, cfg.hidden_size, cfg.intermediate_size
+    copies = [
+        line[:200] for line in text.splitlines()
+        if re.search(rf"= bf16\[({e},{h},{it}|{e},{it},{h})\]", line)
+        and " parameter(" not in line
+    ]
+    assert not copies, copies
+
+
+@pytest.mark.parametrize("rows,chunk", [
+    (1, CHUNK),  # 512 tokens: every expert on every token
+    (2, CHUNK),  # 1,024 tokens: the grouped matmul over the routed pairs
+])
+def test_moe_prefill_program_compiles_for_v5e(topo, rows, chunk):
+    """OLMoE-1B-7B's prefill step at published widths (two layers deep),
+    int8 experts, on one described v5e chip."""
+    import dataclasses
+
+    from vllm_production_stack_tpu.models import llama
+    from vllm_production_stack_tpu.models.registry import resolve_model_config
+
+    cfg = dataclasses.replace(
+        resolve_model_config("olmoe-1b-7b", quantization="int8"),
+        num_layers=2)
+    params, kv, i32, _staged, nb = _moe_program_args(topo, cfg, rows, chunk)
+
+    def step(params, kv, tokens, positions, tables, slots, lens):
+        stats: list = []
+        hidden, kv = llama.forward(
+            cfg, params, tokens, positions, kv, tables, slots, lens,
+            moe_stats=stats)
+        return (llama.compute_logits(cfg, params, hidden[:, -1]), kv,
+                sum(s["touched"] for s in stats))
+
+    compiled = jax.jit(step, donate_argnums=1).lower(
+        params, kv, i32(rows, chunk), i32(rows, chunk), i32(rows, nb),
+        i32(rows * chunk), i32(rows)).compile()
+    text = compiled.as_text()
+    _assert_no_widened_experts(cfg, text)
+    grouped = rows * chunk > llama._ALL_EXPERTS_MAX_TOKENS
+    assert ("ragged-dot" in text) == grouped
+
+
+def test_moe_decode_step_compiles_for_v5e(topo):
+    """One decode step of OLMoE-1B-7B at 16 rows: the Pallas decode kernel
+    at its third head shape (16 KV heads x 128) and the all-experts einsum
+    on int8 experts."""
+    import dataclasses
+
+    from vllm_production_stack_tpu.models import llama
+    from vllm_production_stack_tpu.models.registry import resolve_model_config
+
+    cfg = dataclasses.replace(
+        resolve_model_config("olmoe-1b-7b", quantization="int8"),
+        num_layers=2)
+    rows = 16
+    params, kv, i32, staged, nb = _moe_program_args(topo, cfg, rows, 1)
+
+    def step(params, kv, staged, tokens, positions, tables, hist):
+        stats: list = []
+        hidden, staged = llama.decode_window_step(
+            cfg, params, tokens, positions, kv, tables, staged,
+            jnp.int32(0), hist, backend="pallas", moe_stats=stats)
+        return (llama.compute_logits(cfg, params, hidden), staged,
+                sum(s["touched"] for s in stats))
+
+    compiled = jax.jit(step).lower(
+        params, kv, staged, i32(rows), i32(rows), i32(rows, nb), i32(rows),
+    ).compile()
+    text = compiled.as_text()
+    _assert_kernel(compiled)
+    assert "paged_decode_attention" in text
+    _assert_no_widened_experts(cfg, text)

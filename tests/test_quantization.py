@@ -5,6 +5,7 @@ bounds, spec-tree mirroring, memory accounting, and end-to-end serving
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -158,3 +159,69 @@ def test_quantized_qwen3_serves():
                                 ignore_eos=True),
     )
     assert len(out[0]["token_ids"]) == 6
+
+
+# -- int8 experts (ISSUE 26) ------------------------------------------------
+
+
+def _moe_cfg():
+    from vllm_production_stack_tpu.models.registry import resolve_model_config
+
+    return resolve_model_config("tiny-olmoe", quantization="int8")
+
+
+def test_int8_experts_shapes_and_rounding_bound():
+    cfg = _moe_cfg()
+    L, e, h, it = cfg.num_layers, 8, cfg.hidden_size, 32
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    qp = jax.jit(lambda p: quantize_params(cfg, p))(params)
+    for name, shape in (("gate", (L, e, h, it)), ("up", (L, e, h, it)),
+                        ("down", (L, e, it, h))):
+        leaf = qp["layers"]["moe"][name]
+        assert is_quantized_leaf(leaf)
+        assert leaf["q"].shape == shape and leaf["q"].dtype == jnp.int8
+        # one scale per layer, expert and output channel
+        assert leaf["s"].shape == (L, e, 1, shape[-1])
+        assert leaf["s"].dtype == jnp.float32
+        w = np.asarray(params["layers"]["moe"][name], np.float32)
+        deq = np.asarray(leaf["q"], np.float32) * np.asarray(leaf["s"])
+        bound = np.broadcast_to(np.asarray(leaf["s"]) / 2 + 1e-8, w.shape)
+        assert np.all(np.abs(w - deq) <= bound), name
+    # the router decides top-k: it stays in the model's dtype
+    assert not is_quantized_leaf(qp["layers"]["moe"]["router"])
+    # the host (numpy) path quantises layer by layer to the same result
+    host = quantize_params(cfg, jax.tree.map(np.asarray, params))
+    for name in ("gate", "up", "down"):
+        np.testing.assert_array_equal(
+            host["layers"]["moe"][name]["q"],
+            np.asarray(qp["layers"]["moe"][name]["q"]))
+
+
+def test_moe_spec_tree_mirrors_param_tree():
+    cfg = _moe_cfg()
+    params = quantize_params(cfg, llama.init_params(cfg, jax.random.PRNGKey(0)))
+    specs = quantize_specs(cfg, llama_param_specs(cfg))
+    assert (
+        jax.tree.structure(params)
+        == jax.tree.structure(specs, is_leaf=lambda x: not isinstance(x, dict))
+    )
+
+
+@pytest.mark.parametrize("quantization", [None, "int8"])
+def test_moe_param_bytes_equal_the_tree(quantization):
+    """param_bytes counts E experts and the router per layer: pool
+    auto-sizing was wrong by 6 GB for OLMoE-1B-7B when it counted one."""
+    cfg = dataclasses.replace(_moe_cfg(), quantization=quantization)
+    params = quantize_params(cfg, llama.init_params(cfg, jax.random.PRNGKey(0)))
+    real = sum(x.nbytes for x in jax.tree.leaves(params))
+    assert param_bytes(cfg) == real
+    # the published model: 6.44 B expert parameters at one byte each
+    from vllm_production_stack_tpu.models.registry import resolve_model_config
+
+    olmoe = resolve_model_config("olmoe-1b-7b", quantization="int8")
+    experts = 16 * 64 * 3 * 2048 * 1024
+    assert experts == 6_442_450_944
+    assert experts < param_bytes(olmoe) < experts + 0.6e9
+    assert param_bytes(dataclasses.replace(olmoe, quantization=None)) > 2 * experts
+    # experts shard over ep
+    assert param_bytes(olmoe, ep=4) < param_bytes(olmoe) - 0.7 * experts

@@ -30,7 +30,9 @@ import sys
 
 # the named scopes of the jitted steps (docs/14-tracing.md), innermost wins
 SCOPES = ("embed", "attn_qkv", "kv_stage", "attn", "attn_out", "mlp",
-          "final_norm", "lm_head", "sample", "kv_commit", "gather_pages")
+          "final_norm", "lm_head", "sample", "kv_commit", "gather_pages",
+          # inside `mlp` of a sparse-MoE layer (models/llama.py:_moe_mlp)
+          "moe_router", "moe_dispatch", "moe_experts", "moe_combine")
 # the benchmark's own names for an operation and a program, and its union of
 # intervals: this reduction must agree with benchmarks/chip/trace_reduce.py
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),

@@ -246,7 +246,10 @@ class FlightRecorder:
         return _Span(self, None, key, 0, "")
 
     def count(self, key: str, n: int = 1) -> None:
-        self._tot[key] += n
+        """Add to a cumulative count. A key outside the fixed set (an MoE
+        model's `moe_*_n`) appears in timing() once it has been counted: a
+        dense model serves none of them."""
+        self._tot[key] = self._tot.get(key, 0) + n
 
     @property
     def done_seq(self) -> int:

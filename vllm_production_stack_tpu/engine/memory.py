@@ -37,19 +37,34 @@ def dtype_bytes(dtype: str) -> int:
     return jax.numpy.dtype(dtype).itemsize
 
 
-def param_bytes(cfg: ModelConfig, tp: int = 1, pp: int = 1) -> int:
+def qk_norm_params(cfg: ModelConfig) -> int:
+    """Per-layer q_norm + k_norm weights (replicated): per head (qwen3) or
+    over the flat projections (olmo2, olmoe)."""
+    if cfg.qk_norm_flat:
+        return (cfg.num_heads + cfg.num_kv_heads) * cfg.head_dim
+    return 2 * cfg.head_dim if cfg.qk_norm else 0
+
+
+def param_bytes(cfg: ModelConfig, tp: int = 1, pp: int = 1,
+                ep: int = 1) -> int:
     """Per-device bytes of the stacked Llama param tree (models/llama.py
-    init_params) under tensor parallelism `tp` and pipeline stages `pp`
-    (per-layer leaves shard their L axis over pp, parallel/sharding.py)."""
+    init_params) under tensor parallelism `tp`, pipeline stages `pp`
+    (per-layer leaves shard their L axis over pp, parallel/sharding.py) and
+    expert parallelism `ep` (an MoE layer's num_experts SwiGLUs shard their
+    E axis over ep; its h x E router is replicated)."""
     if cfg.quantization:
         from ..models.quantization import quantized_param_bytes
 
-        return quantized_param_bytes(cfg, tp, pp)
+        return quantized_param_bytes(cfg, tp, pp, ep)
     h, hd = cfg.hidden_size, cfg.head_dim
     nh, nkv, it, L = cfg.num_heads, cfg.num_kv_heads, cfg.intermediate_size, cfg.num_layers
     attn = h * nh * hd + 2 * h * nkv * hd + nh * hd * h
     mlp = 3 * h * it
     norms = 2 * h
+    if cfg.num_experts:
+        mlp *= cfg.num_experts // ep
+        norms += h * cfg.num_experts  # the router: unsharded, like norms
+    norms += qk_norm_params(cfg)
     layers_per_stage = (L + pp - 1) // pp
     per_layer = (attn + mlp) // tp + norms
     embed = cfg.vocab_size * h // tp
@@ -111,6 +126,7 @@ def headroom_budget(
         - param_bytes(
             model, parallel.tensor_parallel_size,
             parallel.pipeline_parallel_size,
+            parallel.expert_parallel_size,
         )
         - RESERVE_BYTES
     )
@@ -178,6 +194,7 @@ def derive_num_blocks(
     hbm = hbm_bytes if hbm_bytes is not None else device_hbm_bytes()
     tp = parallel.tensor_parallel_size
     pp = parallel.pipeline_parallel_size
+    ep = parallel.expert_parallel_size
     budget = headroom_budget(
         model, cache, parallel, hbm
     ) - hoist_reserve_bytes(model, cache, parallel, max_num_seqs)
@@ -189,7 +206,7 @@ def derive_num_blocks(
     # pp-divisibility rounding below must never round UP past the budget)
     if budget < 2 * per_block * max(1, pp):
         raise ValueError(
-            f"model weights ({param_bytes(model, tp, pp) / 1024**3:.2f} GiB/device) "
+            f"model weights ({param_bytes(model, tp, pp, ep) / 1024**3:.2f} GiB/device) "
             "+ reserve leave no room for a KV pool in "
             f"{cache.hbm_utilization:.0%} of {hbm / 1024**3:.2f} GiB HBM — "
             f"raise hbm_utilization, shard wider (tp={tp}), or shrink the model"
@@ -211,6 +228,6 @@ def derive_num_blocks(
         cache.block_size,
         n * per_block / 1024**3,
         hbm / 1024**3,
-        param_bytes(model, tp, pp) / 1024**3,
+        param_bytes(model, tp, pp, ep) / 1024**3,
     )
     return int(n)

@@ -143,20 +143,32 @@ class StepHandle:
         # row's automaton state AFTER the window — a chained next window
         # gathers its gr_state0 from it the same way tokens chain
         self.grammar_states = None
+        # MoE models: (device int32 scalar — experts that got a pair, summed
+        # over this program's layers and steps; "decode" | "prefill"; pairs
+        # computed; layer-steps x experts). Fetched with the tokens.
+        self.moe: tuple | None = None
 
     def resolve(self) -> list[list[int]]:
         """Sync the step's results to the host — exactly ONE jax.device_get
-        covering tokens + every logprob array — and build the per-request
-        token rows. Idempotent: the transfer happens once."""
+        covering tokens + every logprob array (+ an MoE model's routing
+        count) — and build the per-request token rows. Idempotent: the
+        transfer happens once."""
         if self._rows is None:
+            lp_arrays = self.lp_arrays or ()
+            extra = (self.moe[0],) if self.moe is not None else ()
             with self.runner.clock.span("sync", self.seq, self.kind):
-                if self.lp_arrays is not None:
-                    got = jax.device_get((self.tokens, *self.lp_arrays))
-                    mat = np.asarray(got[0])
-                    lp = tuple(np.asarray(x) for x in got[1:])
-                else:
-                    mat = np.asarray(jax.device_get(self.tokens))
-                    lp = None
+                got = jax.device_get((self.tokens, *lp_arrays, *extra))
+            mat = np.asarray(got[0])
+            lp = (
+                tuple(np.asarray(x) for x in got[1:1 + len(lp_arrays)])
+                if self.lp_arrays is not None else None
+            )
+            if self.moe is not None:
+                _, kind, pairs, slots = self.moe
+                clock = self.runner.clock
+                clock.count(f"moe_{kind}_touched_n", int(got[-1]))
+                clock.count(f"moe_{kind}_pairs_n", pairs)
+                clock.count(f"moe_{kind}_slots_n", slots)
             self._rows, self.logprob_rows = self._postproc(mat, lp)
         return self._rows
 
@@ -664,6 +676,9 @@ class ModelRunner:
             want_grammar=False,  # static: grammar masking compiles in only
             #   when a batch row is constrained
         ):
+            # an MoE model's program also returns, last, the experts its
+            # layers touched (a dense model's outputs are what they were)
+            moe_stats: list | None = [] if cfg.num_experts else None
             hidden, kv_caches = llama.forward(
                 cfg, params, token_ids, positions, kv_caches,
                 block_tables, slot_mapping, context_lens,
@@ -675,6 +690,10 @@ class ModelRunner:
                 },
                 backend=self._prefill_backend,
                 mesh=self.mesh,
+                moe_stats=moe_stats,
+            )
+            moe_out = (
+                (sum(s["touched"] for s in moe_stats),) if moe_stats else ()
             )
             flat = hidden.reshape(-1, hidden.shape[-1])
             picked = flat[sample_rows]  # (num_samples, h)
@@ -695,9 +714,10 @@ class ModelRunner:
                 )
                 if want_logprobs:
                     return (
-                        kv_caches, tokens, _collect_logprobs(logits, tokens)
+                        kv_caches, tokens, _collect_logprobs(logits, tokens),
+                        *moe_out,
                     )
-            return kv_caches, tokens
+            return (kv_caches, tokens, *moe_out)
 
         return step_fn
 
@@ -862,6 +882,15 @@ class ModelRunner:
                 dead = gr_class_dest.shape[1] - 1
 
             def body(k, carry):
+                # an MoE model's carry ends with the experts touched so far
+                if not cfg.num_experts:
+                    return step(k, carry, None)
+                *carry, moe_n = carry
+                stats: list = []
+                out = step(k, carry, stats)
+                return (*out, moe_n + sum(s["touched"] for s in stats))
+
+            def step(k, carry, moe_stats):
                 if want_grammar:
                     (staged, cur, out, lp_out, top_lp_out, top_id_out,
                      gstate) = carry
@@ -874,7 +903,7 @@ class ModelRunner:
                     block_tables, staged, k, positions0,
                     backend=self._attention_backend,
                     lora=lora_params, lora_idx=lora_idx, hists=hists,
-                    mesh=self.mesh,
+                    mesh=self.mesh, moe_stats=moe_stats,
                 )
                 logits = llama.compute_logits(cfg, params, hidden)
                 with jax.named_scope("sample"):
@@ -927,20 +956,21 @@ class ModelRunner:
                         lp_out, top_lp_out, top_id_out,
                     )
 
+            moe_out = (jnp.int32(0),) if cfg.num_experts else ()
             if want_grammar:
                 (staged, _, out, lp_out, top_lp_out, top_id_out,
-                 gstates) = jax.lax.fori_loop(
+                 gstates, *moe_out) = jax.lax.fori_loop(
                     0, window, body,
                     (staged, first_tokens, out, lp_out, top_lp_out,
-                     top_id_out, gr_state0),
+                     top_id_out, gr_state0, *moe_out),
                 )
             else:
                 gstates = None
                 (staged, _, out, lp_out,
-                 top_lp_out, top_id_out) = jax.lax.fori_loop(
+                 top_lp_out, top_id_out, *moe_out) = jax.lax.fori_loop(
                     0, window, body,
                     (staged, first_tokens, out, lp_out, top_lp_out,
-                     top_id_out),
+                     top_id_out, *moe_out),
                 )
             # commit the window's KV to the pool: slots for row r, step k are
             # position positions0[r] + k via the row's block table
@@ -956,7 +986,7 @@ class ModelRunner:
                 ret = ret + ((lp_out, top_lp_out, top_id_out),)
             if want_grammar:
                 ret = ret + (gstates,)
-            return ret
+            return ret + tuple(moe_out)
 
         return decode_window_fn
 
@@ -1322,7 +1352,7 @@ class ModelRunner:
                         self._batch2,
                     )
                 self._ones_mask_cache[b_pad] = grammar_mask
-        tokens_dev, lp_dev, rng_before = self._run(
+        tokens_dev, lp_dev, rng_before, moe = self._run(
             token_ids, positions, block_tables,
             slots.reshape(-1) if slots is not None else np.zeros(1, np.int32),
             context_lens, chunk_lens, write_ids, start_off, lora_idx,
@@ -1336,11 +1366,13 @@ class ModelRunner:
             want_grammar=use_gr,
             aot_key=aot_key, watch_reqs=work.requests,
         )
-        return StepHandle(
+        handle = StepHandle(
             runner=self, work=work, tokens=tokens_dev, lp_arrays=lp_dev,
             rng_before=rng_before,
             postproc=functools.partial(self._prefill_rows, work, b),
         )
+        handle.moe = moe
+        return handle
 
     @staticmethod
     def _prefill_rows(work: PrefillWork, b: int, tokens, lp):
@@ -1560,6 +1592,10 @@ class ModelRunner:
                     "decode", aot_key, time.perf_counter() - t0,
                     work.requests,
                 )
+        moe = None
+        if self.config.model.num_experts:
+            *result, touched = result
+            moe = self._moe_counts(touched, "decode", b_pad, work.window)
         gstates = None
         if want_lp and want_gr:
             self.kv_caches, tokens, lp_arrays, gstates = result
@@ -1577,6 +1613,7 @@ class ModelRunner:
             postproc=functools.partial(self._decode_rows, work, b),
         )
         handle.grammar_states = gstates
+        handle.moe = moe
         return handle
 
     @staticmethod
@@ -1685,6 +1722,11 @@ class ModelRunner:
                         "prefill", aot_key, time.perf_counter() - t0,
                         watch_reqs,
                     )
+        moe = None
+        if self.config.model.num_experts and self._sp == 1:
+            # (the sp prefill program counts no routing)
+            *result, touched = result
+            moe = self._moe_counts(touched, "prefill", token_ids.size, 1)
         if want_logprobs:
             self.kv_caches, tokens, lp = result
         else:
@@ -1692,7 +1734,18 @@ class ModelRunner:
             lp = None
         # NO host sync here: the caller wraps these in a StepHandle whose
         # resolve() performs the single batched D2H transfer
-        return tokens, lp, rng_before
+        return tokens, lp, rng_before, moe
+
+    def _moe_counts(self, touched, kind: str, tokens: int, steps: int,
+                    ) -> tuple:
+        """StepHandle.moe for a program that ran `tokens` tokens (padded
+        rows and positions included: they are computed) through every layer
+        `steps` times: the pairs and the (layer-step, expert) slots follow
+        from shapes, only the touched experts come from the device."""
+        cfg = self.config.model
+        layer_steps = cfg.num_layers * steps
+        return (touched, kind, tokens * cfg.num_experts_per_tok * layer_steps,
+                layer_steps * cfg.num_experts)
 
     def _grammar_device_tables(self, grammars: list, gkey: tuple):
         """Replicated device copies of the batch's automaton tables, padded

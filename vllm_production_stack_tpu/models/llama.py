@@ -242,6 +242,9 @@ def _layer_body(
     attend,  # (q (B,T,nh,D), k (B,T,kvH,D), v (B,T,kvH,D)) -> (B,T,nh,D)
     lora: dict | None = None,  # layer-sliced init_lora_params tree
     lora_idx: jax.Array | None = None,  # (B,) slot per row
+    moe_stats: list | None = None,  # collects each MoE layer's routing
+    #   (_moe_mlp's second result; traced — what the caller leaves unused
+    #   is never computed)
 ) -> jax.Array:
     """The Llama layer math shared by every execution mode — prefill and the
     fused decode window differ ONLY in how attention consumes/stores KV, so
@@ -308,7 +311,11 @@ def _layer_body(
             x = rms_norm(x, lp["post_attn_norm"], cfg.rms_norm_eps,
                          cfg.rms_norm_add_one)
         if "moe" in lp:
-            return res + _moe_mlp(cfg, lp["moe"], x)
+            mlp_out, routing = _moe_mlp(
+                cfg, lp["moe"], x, lp.get("moe_stacked"))
+            if moe_stats is not None:
+                moe_stats.append(routing)
+            return res + mlp_out
         mp = lp["mlp"]
         inner = _activation(cfg)(proj(x, mp["gate"], "gate_proj")) * proj(
             x, mp["up"], "up_proj"
@@ -321,37 +328,164 @@ def _layer_body(
         return res + mlp_out
 
 
-def _moe_mlp(cfg: ModelConfig, mp: dict, x: jax.Array) -> jax.Array:
-    """Sparse-MoE MLP, HF Mixtral routing semantics: softmax over ALL expert
-    logits, take top-k, renormalize the selected weights to sum to 1.
+# `_moe_mlp` evaluates every expert on every token up to this many tokens,
+# and follows the routed pairs above it. Measured on a v5e chip, one OLMoE
+# layer (64 int8 experts of 2048 x 1024, 8 per token, stacked over 16
+# layers as served), ms a layer (benchmarks/sweep_moe.py, PERF.md §6 PR 26):
+#   tokens   all-experts einsum   ragged_dot   (bound: touched bytes | flops)
+#       16         0.57              1.00           0.44 | 0.01
+#      512         2.72              3.26           0.49 | 0.26
+#     1024         5.67              3.95           0.49 | 0.52
+#     2048        10.44              5.54           0.49 | 1.05
+#     8192     (3 GiB of temps)     18.16           0.49 | 4.19
+# At decode shapes a balanced router touches nearly every expert anyway (57
+# of 64 for 16 independent rows) and the einsum streams the int8 experts at
+# 87% of HBM bandwidth;
+# XLA's grouped kernel pays for groups of two rows. From ~1k tokens the
+# einsum's E/k-fold FLOPs lose.
+_ALL_EXPERTS_MAX_TOKENS = 512
 
-    Compute is the dense-expert formulation: every expert evaluates every
-    token and the top-k mask zeroes the rest. That spends num_experts/top_k
-    more FLOPs than a gather-based dispatch, but the shapes are static, every
-    matmul is a large dense MXU op, and under expert parallelism GSPMD shards
-    the E axis over the ep mesh axis — each device runs E/ep experts and the
-    final combine psums over ep (+ tp on the inner axis). At serving batch
-    sizes every expert is active anyway, so the "waste" is bounded and the
-    alternative (capacity-factor dispatch à la GShard) drops tokens — wrong
-    for inference. x: (B, T, h) → (B, T, h)."""
-    e, k = cfg.num_experts, cfg.num_experts_per_tok
-    logits = (x @ mp["router"]).astype(jnp.float32)  # (B, T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    topv, topi = jax.lax.top_k(probs, k)
-    if cfg.norm_topk_prob:
-        # Mixtral (always) / Qwen3-MoE norm_topk_prob=true: the selected
-        # weights renormalize to sum to 1; otherwise they stay raw
-        # softmax mass (HF Qwen3MoeSparseMoeBlock's "only diff")
-        topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
-    # (B, T, E) combine weights: topv scattered back onto the expert axis
-    w = jnp.sum(
-        jax.nn.one_hot(topi, e, dtype=jnp.float32) * topv[..., None], axis=-2
-    )
-    inner = _activation(cfg)(
-        jnp.einsum("bth,ehi->btei", x, mp["gate"])
-    ) * jnp.einsum("bth,ehi->btei", x, mp["up"])
-    out = jnp.einsum("btei,eih->bteh", inner, mp["down"])
-    return jnp.einsum("bteh,bte->bth", out, w.astype(x.dtype))
+
+def _expert_mm(spec: str, a: jax.Array, w) -> jax.Array:
+    """einsum of activations with every expert's matrix (E, in, out); an
+    int8 leaf is widened inside the einsum (XLA fuses the convert into the
+    operand read: no bf16 copy of the experts is written) and its
+    per-expert, per-output-channel scale multiplies the (..., E, out)
+    result, as `_mm` does for the dense matrices."""
+    if isinstance(w, dict):
+        return (jnp.einsum(spec, a, w["q"].astype(a.dtype))
+                * w["s"].astype(a.dtype)[:, 0])
+    return jnp.einsum(spec, a, w)
+
+
+def _grouped_mm(xs: jax.Array, w, layer: int, group_sizes: jax.Array,
+                row_expert: jax.Array) -> jax.Array:
+    """Rows of `xs` (M, in), sorted by expert, each times its own expert's
+    matrix of layer `layer` of the stacked leaf `w` (L, E, in, out):
+    `jax.lax.ragged_dot`, which XLA lowers on the TPU to a grouped-matmul
+    kernel that reads only the groups that hold rows. The kernel is a
+    custom call, and XLA copies a slice that feeds one (128 MiB per int8
+    leaf and layer at OLMoE-1B-7B, 5 GiB of temps in a 16-layer program);
+    so it gets the whole stacked leaf as L*E groups, all empty but this
+    layer's E. An int8 leaf goes in as int8 — the kernel widens a tile in
+    VMEM, no bf16 copy of the experts is written — and the per-expert,
+    per-output-channel scale multiplies the output rows."""
+    q = w["q"] if isinstance(w, dict) else w
+    n_layers, e = q.shape[:2]
+    out = jax.lax.ragged_dot(
+        xs, q.reshape(n_layers * e, *q.shape[2:]),
+        jnp.pad(group_sizes, (layer * e, (n_layers - 1 - layer) * e)),
+        preferred_element_type=xs.dtype)
+    if isinstance(w, dict):
+        out = out * w["s"][layer].astype(xs.dtype)[row_expert, 0]
+    return out
+
+
+def _moe_route(cfg: ModelConfig, router: jax.Array, xf: jax.Array):
+    """(N, h) -> routing weights (N, k) float32, experts (N, k), their
+    one-hot (N, k, E) and the pairs each expert got (E,)."""
+    with jax.named_scope("moe_router"):
+        logits = (xf @ router).astype(jnp.float32)  # (N, E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        topv, topi = jax.lax.top_k(probs, cfg.num_experts_per_tok)
+        if cfg.norm_topk_prob:
+            # Mixtral (always) / Qwen3-MoE norm_topk_prob=true: the selected
+            # weights renormalize to sum to 1; otherwise (OLMoE) they stay
+            # raw softmax mass
+            topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
+        chosen = jax.nn.one_hot(topi, cfg.num_experts, dtype=jnp.float32)
+        group_sizes = jnp.sum(chosen, axis=(0, 1)).astype(jnp.int32)
+    return topv, topi, chosen, group_sizes
+
+
+def _experts_all(cfg, mp, xf, topv, chosen):
+    """Every expert on every token, weighted by the routing mass or 0."""
+    with jax.named_scope("moe_dispatch"):
+        # (N, E) combine weights: topv scattered back onto the expert axis
+        w = jnp.sum(chosen * topv[..., None], axis=-2)
+    with jax.named_scope("moe_experts"):
+        inner = _activation(cfg)(
+            _expert_mm("nh,ehi->nei", xf, mp["gate"])
+        ) * _expert_mm("nh,ehi->nei", xf, mp["up"])
+        out = _expert_mm("nei,eih->neh", inner, mp["down"])
+    with jax.named_scope("moe_combine"):
+        return jnp.einsum("neh,ne->nh", out, w.astype(xf.dtype))
+
+
+def _experts_grouped(cfg, stacked, layer, xf, topv, topi, group_sizes):
+    """The routed (token, expert) pairs sorted by expert, each expert's rows
+    one group of a grouped matmul (over layer `layer` of the `stacked`
+    expert leaves), then un-sorted, weighted and summed."""
+    n, k = topi.shape
+    with jax.named_scope("moe_dispatch"):
+        pair_expert = topi.reshape(n * k)
+        order = jnp.argsort(pair_expert, stable=True)  # sorted row -> pair
+        row_expert = pair_expert[order]
+        xs = xf[order // k]  # (N*k, h): each pair's token, expert-major
+    with jax.named_scope("moe_experts"):
+        inner = _activation(cfg)(
+            _grouped_mm(xs, stacked["gate"], layer, group_sizes, row_expert)
+        ) * _grouped_mm(xs, stacked["up"], layer, group_sizes, row_expert)
+        out = _grouped_mm(
+            inner, stacked["down"], layer, group_sizes, row_expert)
+    with jax.named_scope("moe_combine"):
+        # pair p sits at sorted row inv[p]
+        out = out[jnp.argsort(order)].reshape(n, k, -1)
+        return jnp.einsum("nkh,nk->nh", out, topv.astype(xf.dtype))
+
+
+def _moe_mlp(cfg: ModelConfig, mp: dict, x: jax.Array,
+             stacked: tuple[dict, int] | None = None,
+             ) -> tuple[jax.Array, dict]:
+    """Sparse-MoE MLP, HF routing semantics (Mixtral, Qwen3-MoE, OLMoE):
+    softmax over ALL expert logits in float32, take top-k, and renormalize
+    the selected weights to sum to 1 only under `norm_topk_prob`.
+
+    The expert computation is chosen from the static token count (table
+    above; no flag). Few tokens (a decode step): every expert evaluates
+    every token and the routing weights zero the rest — bound by the expert
+    bytes, which such a step touches nearly all of anyway. Many tokens (a
+    prefill program): the work follows the routed pairs (`_experts_grouped`)
+    — B*T*k rows whatever the routing, every pair computed (no capacity, no
+    dropped token), an expert without rows costs nothing; the all-experts
+    form would spend num_experts/top_k times the FLOPs and hold three
+    (B*T, E, inter) intermediates, 3 GiB at 64 experts and 16 x 512 tokens.
+    Either way shapes are static, int8 experts are read as int8, and under
+    expert parallelism the E axis of the expert leaves stays sharded over ep
+    (parallel/sharding.py; GSPMD partitions the einsum or the grouped
+    matmul and psums the combine).
+
+    `mp` is one layer's leaves; `stacked` = (all layers' leaves, this
+    layer's index) is what the grouped matmul reads in place (_grouped_mm);
+    without it `mp` stands as a stack of one.
+
+    x: (B, T, h) → ((B, T, h), routing): `touched`, the experts that got
+    at least one pair (a scalar int32 the runner sums into
+    `moe_*_touched_n`), and `experts` (B, T, k), each token's choice (read
+    by the comparison with the reference, testing/reference_decoder.py)."""
+    b, t, h = x.shape
+    n = b * t
+    xf = x.reshape(n, h)
+    topv, topi, chosen, group_sizes = _moe_route(cfg, mp["router"], xf)
+    if n <= _ALL_EXPERTS_MAX_TOKENS:
+        y = _experts_all(cfg, mp, xf, topv, chosen)
+    else:
+        if stacked is None:
+            stacked = (jax.tree.map(lambda a: a[None], mp), 0)
+        y = _experts_grouped(cfg, *stacked, xf, topv, topi, group_sizes)
+    touched = jnp.sum((group_sizes > 0).astype(jnp.int32))
+    return y.reshape(b, t, h), {
+        "touched": touched, "experts": topi.reshape(b, t, -1)}
+
+
+def _layer_params(params: dict, i: int) -> dict:
+    """Layer i's slice of the stacked per-layer leaves (static slices, fused
+    into their consumers), and for an MoE model the stacked expert leaves
+    themselves beside it (see _grouped_mm)."""
+    lp = jax.tree.map(lambda a: a[i], params["layers"])
+    if "moe" in lp:
+        lp["moe_stacked"] = (params["layers"]["moe"], i)
+    return lp
 
 
 def _lora_layer_slice(lora: dict | None, i: int) -> dict | None:
@@ -381,6 +515,7 @@ def _layer(
     pallas_prefill: dict | None = None,  # {"context_lens", "chunk_start",
     #   "interpret", "mesh"} — route attention through the paged
     #   flash-prefill kernel instead of the XLA gather (mask is None then)
+    moe_stats: list | None = None,  # see _layer_body
 ) -> tuple[jax.Array, jax.Array]:
     b, t = x.shape[0], x.shape[1]
     hd, nkv = cfg.head_dim, cfg.num_kv_heads
@@ -419,7 +554,8 @@ def _layer(
                 softcap=cfg.attn_logit_softcap,
             )
 
-    x = _layer_body(cfg, lp, x, positions, attend, lora, lora_idx)
+    x = _layer_body(cfg, lp, x, positions, attend, lora, lora_idx,
+                    moe_stats)
     return x, kv_layer
 
 
@@ -443,6 +579,8 @@ def forward(
     #   attention path; pallas streams pool pages through the paged
     #   flash-prefill kernel and never builds the (B, T, S) mask
     mesh=None,  # required for the pallas backend on a >1-device mesh
+    moe_stats: list | None = None,  # MoE models: a list that receives each
+    #   layer's routing (see _moe_mlp)
 ) -> tuple[jax.Array, jax.Array]:
     """One model step over a token batch. Prefill is (B=1, T=chunk); decode is
     (B=batch, T=1). Returns (hidden (B,T,h), updated kv_caches)."""
@@ -485,12 +623,12 @@ def forward(
     # full pool (see init_kv_cache)
     new_kv: list[jax.Array] = []
     for i in range(cfg.num_layers):
-        lp = jax.tree.map(lambda a: a[i], params["layers"])
+        lp = _layer_params(params, i)
         x, layer_kv = _layer(
             cfg, lp, kv_caches[i], x, positions, block_tables, slot_mapping,
             mask_local if cfg.layer_sliding(i) else mask,
             _lora_layer_slice(lora, i), lora_idx, write_blocks,
-            pallas_prefill,
+            pallas_prefill, moe_stats,
         )
         new_kv.append(layer_kv)
     with jax.named_scope("final_norm"):
@@ -526,6 +664,7 @@ def decode_window_step(
     lora_idx: jax.Array | None = None,  # (B,) adapter slot per row
     hists: tuple | None = None,  # per-layer pre-gathered (hist_k, hist_v)
     mesh=None,  # required for the pallas backend on a >1-device mesh
+    moe_stats: list | None = None,  # as in forward
 ) -> tuple[jax.Array, jax.Array]:
     """One decode iteration inside a fused window: reads the pool, writes this
     token's K/V into `staged` (not the pool — the pool stays loop-invariant so
@@ -559,7 +698,7 @@ def decode_window_step(
             )
 
     for i in range(cfg.num_layers):
-        lp = jax.tree.map(lambda a: a[i], params["layers"])
+        lp = _layer_params(params, i)
 
         def attend(q, k, v, i=i):
             nonlocal staged
@@ -613,7 +752,7 @@ def decode_window_step(
 
         x = _layer_body(
             cfg, lp, x, positions[:, None], attend,
-            _lora_layer_slice(lora, i), lora_idx,
+            _lora_layer_slice(lora, i), lora_idx, moe_stats,
         )
     with jax.named_scope("final_norm"):
         x = rms_norm(x[:, 0], params["final_norm"], cfg.rms_norm_eps,
@@ -660,7 +799,7 @@ def embed_encode(
     )
 
     for i in range(cfg.num_layers):
-        lp = jax.tree.map(lambda a: a[i], params["layers"])
+        lp = _layer_params(params, i)
         m = mask_local if cfg.layer_sliding(i) else mask
 
         def attend(q, k, v, m=m):
@@ -715,7 +854,7 @@ def forward_sp_prefill(
 
     new_kv: list[jax.Array] = []
     for i in range(cfg.num_layers):
-        lp = jax.tree.map(lambda a: a[i], params["layers"])
+        lp = _layer_params(params, i)
 
         def attend(q, k, v, i=i):
             hist_k, hist_v = gather_pages(kv_caches[i], block_tables)
@@ -772,7 +911,7 @@ def forward_context_parallel(
 
     kv_out: list[jax.Array] = []
     for i in range(cfg.num_layers):
-        lp = jax.tree.map(lambda a: a[i], params["layers"])
+        lp = _layer_params(params, i)
 
         def attend(q, k, v):
             kv_out.append(jnp.stack([k, v]).astype(_dtype(cfg)))

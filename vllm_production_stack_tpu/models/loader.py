@@ -103,8 +103,8 @@ def load_checkpoint_params(cfg: ModelConfig) -> dict:
                 ]
             )
 
-        if cfg.architecture == "qwen3moe":
-            # Qwen3-MoE naming: mlp.gate router; experts carry
+        if cfg.architecture in ("qwen3moe", "olmoe"):
+            # Qwen3-MoE and OLMoE naming: mlp.gate router; experts carry
             # gate_proj/up_proj/down_proj like dense layers
             ex = "model.layers.{0}.mlp.experts.{1}."
             mlp = {

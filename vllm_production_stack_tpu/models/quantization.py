@@ -10,10 +10,13 @@ on the fly — `(x @ q.astype(bf16)) * s` — which XLA fuses into the matmul
 epilogue. The HBM read of the weight is the int8 tensor, so bandwidth-bound
 decode gets the 2x too.
 
-Quantized leaves: attention wq/wk/wv/wo, dense MLP gate/up/down, lm_head.
+Quantized leaves: attention wq/wk/wv/wo, dense MLP gate/up/down, the MoE
+experts' gate/up/down (one scale per expert and output channel on the
+(L, E, in, out) leaves; the grouped matmul of models/llama.py:_grouped_mm
+takes the int8 tensor as it is — OLMoE-1B-7B's 6.44 B expert parameters fit
+one v5e chip only so), lm_head.
 NOT quantized: embedding (a gather, not a matmul; quality-sensitive), norms,
-biases, and MoE expert weights (they flow through einsum paths — quantize
-when an MoE flagship needs the memory).
+biases, and the MoE router (h x E per layer, and top-k is decided on it).
 
 Enable with ModelConfig(quantization="int8") / engine `--quantization int8`.
 The model fingerprint covers it (quantized weights produce different
@@ -35,7 +38,17 @@ def is_quantized_leaf(w) -> bool:
 
 def _quantize(w):
     """(…, in, out) -> {"q": int8, "s": float32 (…, 1, out)}. Works on
-    numpy (host-side checkpoint path) and jax arrays (jitted init path)."""
+    numpy (host-side checkpoint path) and jax arrays (jitted init path).
+    A stacked expert leaf (L, E, in, out) goes one layer at a time, so the
+    float32 working copy is one layer's experts (0.5 GiB at OLMoE-1B-7B),
+    not the leaf's (8 GiB)."""
+    if w.ndim >= 4:
+        if isinstance(w, np.ndarray):
+            parts = [_quantize(layer) for layer in w]
+            return {k: np.stack([p[k] for p in parts]) for k in ("q", "s")}
+        import jax
+
+        return jax.lax.map(_quantize, w)
     xp = np if isinstance(w, np.ndarray) else _jnp()
     wf = w.astype(xp.float32)
     amax = xp.max(xp.abs(wf), axis=-2, keepdims=True)
@@ -55,9 +68,9 @@ def quantize_params(cfg, params: dict) -> dict:
     tree. Pure function of arrays — run it under jit for on-device
     quantization (XLA frees each bf16 leaf right after its int8 twin is
     built, so peak HBM is the int8 tree + the f32 copy of the largest
-    stacked leaf, not the bf16 tree: 8.21 + 6.75 GiB at qwen3-8b,
-    model_runner._init_device_params), or on numpy for the host-side
-    checkpoint path."""
+    stacked dense leaf, not the bf16 tree: 8.21 + 6.75 GiB at qwen3-8b,
+    model_runner._init_device_params; an expert leaf is quantised one layer
+    at a time, _quantize), or on numpy for the host-side checkpoint path."""
     if cfg.quantization is None:
         return params
     if cfg.quantization != "int8":
@@ -75,6 +88,11 @@ def quantize_params(cfg, params: dict) -> dict:
         for name in QUANTIZED_MLP:
             mlp[name] = _quantize(mlp[name])
         layers["mlp"] = mlp
+    if "moe" in layers:
+        moe = dict(layers["moe"])
+        for name in QUANTIZED_MLP:
+            moe[name] = _quantize(moe[name])
+        layers["moe"] = moe
     out["layers"] = layers
     if "lm_head" in params:
         out["lm_head"] = _quantize(params["lm_head"])
@@ -109,18 +127,24 @@ def quantize_specs(cfg, specs: dict) -> dict:
         for name in QUANTIZED_MLP:
             mlp[name] = q(mlp[name])
         layers["mlp"] = mlp
+    if "moe" in layers:
+        moe = dict(layers["moe"])
+        for name in QUANTIZED_MLP:
+            moe[name] = q(moe[name])
+        layers["moe"] = moe
     out["layers"] = layers
     if "lm_head" in specs:
         out["lm_head"] = q(specs["lm_head"])
     return out
 
 
-def quantized_param_bytes(cfg, tp: int = 1, pp: int = 1) -> int:
+def quantized_param_bytes(cfg, tp: int = 1, pp: int = 1, ep: int = 1) -> int:
     """Per-device weight bytes under int8 quantization (engine/memory.py
     delegates here when cfg.quantization is set): quantized leaves cost
-    1 byte/param + 4 bytes/output-channel; embed (+norms, biases) stay at
-    cfg.dtype."""
-    from ..engine.memory import dtype_bytes
+    1 byte/param + 4 bytes/output-channel; embed (+norms, biases, the MoE
+    router) stay at cfg.dtype. An MoE layer holds num_experts SwiGLUs
+    (1/ep of them per device) and its router."""
+    from ..engine.memory import dtype_bytes, qk_norm_params
 
     h, hd = cfg.hidden_size, cfg.head_dim
     nh, nkv, it, L = (
@@ -135,11 +159,18 @@ def quantized_param_bytes(cfg, tp: int = 1, pp: int = 1) -> int:
     # per-output-channel f32 scales
     attn_s = (nh * hd + 2 * nkv * hd + h) // tp * 4
     mlp_s = (2 * it + h) // tp * 4
-    per_layer = attn_q + mlp_q + attn_s + mlp_s + 2 * h * db
+    router = 0
+    if cfg.num_experts:
+        local = cfg.num_experts // ep
+        mlp_q, mlp_s = local * mlp_q, local * mlp_s
+        router = h * cfg.num_experts * db
+    per_layer = attn_q + mlp_q + attn_s + mlp_s + router + 2 * h * db
     total = cfg.vocab_size * h // tp * db  # embed stays unquantized
     total += layers_per_stage * per_layer + h * db
     if not cfg.tie_word_embeddings:
         total += h * cfg.vocab_size // tp + cfg.vocab_size // tp * 4
     if cfg.attention_bias:
         total += layers_per_stage * (nh * hd + 2 * nkv * hd) // tp * db
+    total += layers_per_stage * qk_norm_params(cfg) * db
     return total
+

@@ -2,8 +2,10 @@
 
 Named presets cover the baseline configs (BASELINE.md: opt-125m-class tiny
 models for CI, Llama-3-8B for the headline benchmark, Llama-3-70B for
-pipeline parallel); a local directory with an HF config.json is parsed
-directly (zero-egress environments can't download)."""
+pipeline parallel), the benchmarked dense models (qwen3-8b, qwen2-7b) and
+the sparse-MoE ones (mixtral-8x7b, olmoe-1b-7b; tiny-mixtral and tiny-olmoe
+for the CPU); a local directory with an HF config.json is parsed directly
+(zero-egress environments can't download)."""
 
 from __future__ import annotations
 
@@ -97,6 +99,23 @@ PRESETS: dict[str, dict] = {
         dtype="float32", architecture="mixtral", num_experts=4,
         num_experts_per_tok=2,
     ),
+    # allenai/OLMoE-1B-7B-0125-Instruct config.json: 64 experts of width
+    # 1024, 8 per token with raw softmax mass, MHA 16 x 128 (head_dim is
+    # hidden/heads by the family's convention), RMSNorm over the flat q/k
+    # projections with the Llama norm order (OlmoeRMSNorm), pre-norms
+    "olmoe-1b-7b": dict(
+        vocab_size=50304, hidden_size=2048, intermediate_size=1024,
+        num_layers=16, num_heads=16, num_kv_heads=16, head_dim=128,
+        max_model_len=4096, rope_theta=10000.0, rms_norm_eps=1e-5,
+        architecture="olmoe", num_experts=64, num_experts_per_tok=8,
+        norm_topk_prob=False, qk_norm_flat=True,
+    ),
+    "tiny-olmoe": dict(
+        vocab_size=512, hidden_size=64, intermediate_size=32, num_layers=2,
+        num_heads=4, num_kv_heads=4, head_dim=16, max_model_len=256,
+        dtype="float32", architecture="olmoe", num_experts=8,
+        num_experts_per_tok=2, norm_topk_prob=False, qk_norm_flat=True,
+    ),
 }
 
 _ARCH_MAP = {
@@ -106,6 +125,7 @@ _ARCH_MAP = {
     "Qwen3ForCausalLM": "qwen3",
     "Phi3ForCausalLM": "phi3",
     "Qwen3MoeForCausalLM": "qwen3moe",
+    "OlmoeForCausalLM": "olmoe",
     "Olmo2ForCausalLM": "olmo2",
     "MixtralForCausalLM": "mixtral",
     "GemmaForCausalLM": "gemma",
@@ -174,6 +194,18 @@ def _from_hf_config(path: str) -> dict:
             # intermediate size our expert tree uses
             intermediate_size=hf.get("moe_intermediate_size", 768),
         )
+    if arch == "olmoe":
+        if hf.get("clip_qkv") is not None:
+            raise ValueError(
+                f"olmoe with clip_qkv={hf['clip_qkv']} is not implemented "
+                f"({path}): the published OLMoE configs carry null"
+            )
+        moe = dict(
+            # OlmoeConfig's class defaults, for use_diff serialization
+            num_experts=hf.get("num_experts", 64),
+            num_experts_per_tok=hf.get("num_experts_per_tok", 8),
+            norm_topk_prob=bool(hf.get("norm_topk_prob", False)),
+        )
     gemma = (
         dict(
             hidden_act="gelu_tanh", rms_norm_add_one=True,
@@ -201,6 +233,10 @@ def _from_hf_config(path: str) -> dict:
     if arch == "olmo2":
         arch_flags = dict(qk_norm_flat=True, post_norms_only=True,
                           norm_scale_f32=True)
+    if arch == "olmoe":
+        # OLMo-2's flat QK-norm, but pre-norms and the Llama norm order
+        # (OlmoeRMSNorm downcasts before the weight multiply)
+        arch_flags = dict(qk_norm_flat=True)
     # sliding-window attention: Mistral-7B-v0.1 sets sliding_window=4096
     # on every layer (v0.2+ configs carry null). Silently serving full
     # attention would give wrong numerics past the window.
