@@ -1,11 +1,13 @@
-"""Decode-attention backend sweep: Pallas paged kernel vs XLA gather.
+"""Attention backend sweep: Pallas paged kernels vs the XLA gather.
 
 Measures one layer's decode attention per (block_size, context, batch)
 config on the real chip — the evidence behind ModelRunner's
 `_resolve_attention_backend` policy (VERDICT r2 #7: the shipped default
-must be the measured winner at the shipped config).
+must be the measured winner at the shipped config) — and with --prefill one
+layer's chunked-prefill attention at the benchmark cells' head shapes, the
+evidence behind `resolve_auto_prefill_backend`.
 
-    python benchmarks/sweep_attention.py [--quick]
+    python benchmarks/sweep_attention.py [--quick] [--fp8] [--prefill]
 """
 
 from __future__ import annotations
@@ -108,14 +110,18 @@ def bench_config(
 
 
 def bench_prefill_config(
-    batch: int, ctx: int, block_size: int, nh: int, kvh: int, d: int,
-    chunk: int = 512, dtype=jnp.bfloat16, kv_dtype=None,
+    batch: int, ctx: int, table_pages: int, nh: int, kvh: int, d: int,
+    block_size: int = 32, chunk: int = 512, dtype=jnp.bfloat16,
+    kv_dtype=None,
 ) -> dict:
-    """One layer's CHUNKED-PREFILL attention: a chunk-token query tile
-    attending [resident history + the chunk's own pages] — the paged
-    flash-prefill kernel vs the XLA gather+mask formulation. `ctx` is the
-    residency INCLUDING the chunk (the chunk is written before attending),
-    so the XLA path gathers ctx tokens and builds a (B, chunk, ctx) mask."""
+    """One layer's CHUNKED-PREFILL attention as the engine's prefill program
+    runs it: `batch` rows of a `chunk`-token bucket, each holding `ctx`
+    tokens INCLUDING its part of the chunk (written before attending) in a
+    table `table_pages` wide — the paged flash-prefill kernel vs the XLA
+    gather+mask formulation. A row with ctx < chunk is a short prompt in the
+    bucket (its tail queries are padding); one with ctx > chunk is a later
+    chunk of a long prompt. The XLA path gathers the table's whole width and
+    builds a (B, chunk, width) mask whatever the rows hold."""
     from vllm_production_stack_tpu.ops.attention import (
         causal_page_mask, paged_attention_xla,
     )
@@ -124,37 +130,57 @@ def bench_prefill_config(
     )
 
     rng = np.random.RandomState(0)
-    nb = ctx // block_size
-    num_blocks = batch * nb + 2
+    num_blocks = batch * table_pages + 2
     scale = d ** -0.5
     kvd = kv_dtype if kv_dtype is not None else dtype
 
     q = jnp.asarray(rng.randn(batch, chunk, nh, d), dtype)
     kv = jnp.asarray(rng.randn(2, num_blocks, block_size, kvh, d), kvd)
     tables = jnp.asarray(
-        rng.randint(1, num_blocks, size=(batch, nb)), jnp.int32
+        rng.randint(1, num_blocks, size=(batch, table_pages)), jnp.int32
     )
     ctx_lens = jnp.full((batch,), ctx, jnp.int32)
-    start = jnp.full((batch,), ctx - chunk, jnp.int32)
+    start = jnp.full((batch,), max(ctx - chunk, 0), jnp.int32)
+
+    def moved(qq, tb):
+        """The table as a function of the loop's carry (the same numbers):
+        neither backend's reads of the pool can be hoisted out of time_fn's
+        loop, as no engine step can hoist them."""
+        return tb + (qq[0, 0, 0, 0] > 1e30).astype(tb.dtype)
 
     pallas_fn = jax.jit(
-        lambda qq, *a: paged_prefill_attention(qq, *a, scale=scale)
+        lambda qq, pool, tb, *a: paged_prefill_attention(
+            qq, pool, moved(qq, tb), *a, scale=scale)
     )
     pallas_ms = time_fn(pallas_fn, q, kv, tables, ctx_lens, start)
 
     positions = start[:, None] + jnp.arange(chunk, dtype=jnp.int32)[None, :]
-    mask = causal_page_mask(positions, ctx_lens, nb * block_size)
-    xla_fn = jax.jit(
-        lambda qq, *a: paged_attention_xla(qq, *a, scale=scale)
-    )
-    xla_ms = time_fn(xla_fn, q, kv, tables, mask)
+
+    def xla(qq, pool, tb, lens):
+        # the engine builds the mask inside the program too (llama.forward)
+        mask = causal_page_mask(positions, lens, table_pages * block_size)
+        return paged_attention_xla(qq, pool, moved(qq, tb), mask, scale=scale)
+
+    xla_ms = time_fn(jax.jit(xla), q, kv, tables, ctx_lens)
     return {
-        "phase": "prefill", "batch": batch, "ctx": ctx, "chunk": chunk,
+        "phase": "prefill", "heads": f"{nh}/{kvh}x{d}", "batch": batch,
+        "ctx": ctx, "table_pages": table_pages, "chunk": chunk,
         "block_size": block_size, "kv_dtype": jnp.dtype(kvd).name,
         "pallas_ms": round(pallas_ms, 3), "xla_ms": round(xla_ms, 3),
         "winner": "pallas" if pallas_ms < xla_ms else "xla",
         "ratio": round(pallas_ms / xla_ms, 2),
     }
+
+
+# the benchmark cells' head shapes (Qwen3-8B, Qwen2-7B, OLMoE-1B-7B)
+PREFILL_HEADS = [(32, 8, 128), (28, 4, 128), (16, 16, 128)]
+# (rows, tokens a row holds, table pages): the cells' 64-page table at every
+# row bucket and at 128-2,048 resident tokens, then the widest table
+PREFILL_ROWS = [
+    (1, 128, 64), (1, 512, 64), (1, 1024, 64), (1, 2048, 64),
+    (2, 512, 64), (4, 512, 64), (4, 2048, 64), (16, 512, 64),
+    (1, 512, 128), (1, 2048, 128),
+]
 
 
 def main() -> None:
@@ -174,16 +200,11 @@ def main() -> None:
     nh, kvh, d = 32, 8, 64
     kvd = jnp.dtype(ml_dtypes.float8_e4m3fn) if args.fp8 else None
     if args.prefill:
-        configs = [
-            (4, 1024, 16), (4, 1024, 32),
-            (4, 4096, 16), (4, 4096, 32), (4, 4096, 64),
-        ]
-        if not args.quick:
-            configs += [(16, 4096, 32), (1, 8192, 32), (1, 8192, 64)]
-        for batch, ctx, bs in configs:
-            print(json.dumps(bench_prefill_config(
-                batch, ctx, bs, nh, kvh, d, kv_dtype=kvd
-            )), flush=True)
+        for heads in PREFILL_HEADS[:1] if args.quick else PREFILL_HEADS:
+            for batch, ctx, pages in PREFILL_ROWS:
+                print(json.dumps(bench_prefill_config(
+                    batch, ctx, pages, *heads, kv_dtype=kvd
+                )), flush=True)
         return
     configs = [
         (16, 1024, 16), (16, 1024, 32), (16, 1024, 64),

@@ -5,8 +5,9 @@ Drives the normal served path once, through the entry points the README's
 quick start uses, at the full width and depth of one model the repo supports
 (registry preset qwen3-8b, int8 weights, random from --seed):
 
-  kernels   each Pallas kernel that compiles for v5e, run NON-interpreted at
-            the qwen3-8b head shape against ops/attention.py's XLA path
+  kernels   each Pallas kernel that compiles for v5e, run NON-interpreted
+            against ops/attention.py's XLA path: decode at the qwen3-8b head
+            shape, prefill at the three benchmark cells' head shapes
   serve     `python -m ...engine.server` and `python -m ...router.app` as two
             OS processes; through the router: one chat completion, one
             streamed (SSE, every chunk parsed), four concurrent completions,
@@ -690,16 +691,25 @@ def child_kernels(seed: int) -> int:
         )[:, 0]
         report["kernels"][name] = diff(got, want)
 
-    b, t = 2, 512
-    q, tb = arr(b, t, nh, d, gain=3.0), tables(b)
-    start = jnp.asarray([1024, 0], jnp.int32)  # a continued and a first chunk
-    ctx = start + t
-    got = paged_prefill_attention(q, kv, tb, ctx, start, scale=scale)
+    # prefill at the benchmark cells' head shapes: a continued chunk, a first
+    # chunk, a short chunk that starts mid-page, a padding row (never read)
+    t = 512
+    start = jnp.asarray([1024, 0, 1000, 0], jnp.int32)
+    ctx = jnp.asarray([1536, 512, 1300, 0], jnp.int32)
     positions = start[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
-    want = paged_attention_xla(
-        q, kv, tb, causal_page_mask(positions, ctx, nb * bs), scale=scale,
-    )
-    report["kernels"]["prefill"] = diff(got, want)
+    real = np.asarray(positions < ctx[:, None])  # the chunk's own tokens
+    for heads, kv_heads in ((32, 8), (28, 4), (16, 16)):
+        q, tb = arr(4, t, heads, d, gain=3.0), tables(4)
+        pool = arr(2, num_blocks, bs, kv_heads, d)
+        got = paged_prefill_attention(q, pool, tb, ctx, start, scale=scale)
+        want = paged_attention_xla(
+            q, pool, tb, causal_page_mask(positions, ctx, nb * bs),
+            scale=scale,
+        )
+        got = np.asarray(got, np.float32)
+        check = diff(got[real], np.asarray(want, np.float32)[real])
+        check["ok"] = check["ok"] and bool(np.all(np.isfinite(got)))
+        report["kernels"][f"prefill_{heads}_{kv_heads}"] = check
 
     report["ok"] = all(k["ok"] for k in report["kernels"].values())
     emit(report)

@@ -21,7 +21,7 @@ from vllm_production_stack_tpu.parallel import mesh as mesh_lib
 
 # (num_heads, num_kv_heads, head_dim) of the registry presets
 HEADS = {"qwen3-8b": (32, 8, 128), "llama-1b": (32, 8, 64),
-         "qwen2-7b": (28, 4, 128),
+         "qwen2-7b": (28, 4, 128), "olmoe-1b-7b": (16, 16, 128),
          # what one device runs under shard_map at tp=4
          "qwen3-8b/tp4": (8, 2, 128), "qwen2-7b/tp4": (7, 1, 128)}
 POOL_BLOCKS = 1024
@@ -67,16 +67,16 @@ def _decode_args(sds, model, block, kv_dtype, specs, nb=None):
     )
 
 
-def _prefill_args(sds, model, block, kv_dtype, specs):
+def _prefill_args(sds, model, block, kv_dtype, specs, rows=1, nb=None):
     nh, kvh, d = HEADS[model]
-    nb = CTX // block
+    nb = nb or CTX // block
     q, pool, b2, b1 = specs
     return (
-        sds((1, CHUNK, nh, d), jnp.bfloat16, q),
+        sds((rows, CHUNK, nh, d), jnp.bfloat16, q),
         sds((2, POOL_BLOCKS, block, kvh, d), kv_dtype, pool),
-        sds((1, nb), jnp.int32, b2),
-        sds((1,), jnp.int32, b1),
-        sds((1,), jnp.int32, b1),
+        sds((rows, nb), jnp.int32, b2),
+        sds((rows,), jnp.int32, b1),
+        sds((rows,), jnp.int32, b1),
     )
 
 
@@ -128,15 +128,28 @@ def test_decode_kernel_compiles_for_v5e(topo, model, block, kv_dtype, width):
     ).lower(*args).compile())
 
 
-@pytest.mark.parametrize("model,block,kv_dtype", [
-    ("qwen3-8b", 32, "bfloat16"),
-    ("qwen3-8b", 16, "float8_e4m3fn"),
-    ("llama-1b", 32, "bfloat16"),
-    ("llama-1b", 16, "bfloat16"),
+@pytest.mark.parametrize("model,block,kv_dtype,rows,width", [
+    # ids of the cases the kernel had before it walked a row's pages
+    pytest.param("qwen3-8b", 32, "bfloat16", 1, None,
+                 id="qwen3-8b-32-bfloat16"),
+    pytest.param("qwen3-8b", 16, "float8_e4m3fn", 1, None,
+                 id="qwen3-8b-16-float8_e4m3fn"),
+    # the benchmark's cells: the 16 x 512 program the warm-up compiles and
+    # the one-row program the window runs most, 64- and 128-page tables
+    ("qwen3-8b", 32, "bfloat16", 16, 64),
+    ("qwen2-7b", 32, "bfloat16", 16, 64),
+    ("olmoe-1b-7b", 32, "bfloat16", 16, 64),
+    ("qwen2-7b", 32, "bfloat16", 1, 128),
+    ("olmoe-1b-7b", 32, "bfloat16", 1, 128),
+    # 16-token pages; a tp=4 shard that still fills tiles (two bf16 heads)
+    ("qwen3-8b", 16, "bfloat16", 4, 64),
+    ("qwen3-8b/tp4", 32, "bfloat16", 4, 64),
 ])
-def test_prefill_kernel_compiles_for_v5e(topo, model, block, kv_dtype):
+def test_prefill_kernel_compiles_for_v5e(topo, model, block, kv_dtype, rows,
+                                         width):
     args = _prefill_args(
-        _one_chip(topo), model, block, jnp.dtype(kv_dtype), (None,) * 4
+        _one_chip(topo), model, block, jnp.dtype(kv_dtype), (None,) * 4,
+        rows=rows, nb=width,
     )
     scale = HEADS[model][2] ** -0.5
     _assert_kernel(jax.jit(
@@ -210,19 +223,28 @@ def test_decode_kernel_form_follows_the_pool_tiles(kvh, d, itemsize, want):
     assert pk._pages_fill_tiles(kvh, d, itemsize) is want
 
 
-@pytest.mark.parametrize("nh,d,itemsize,want", [
-    (32, 128, 2, 128),  # qwen3-8b / llama-8b: 256 rows was 20.25 MiB
-    (32, 64, 2, 128),   # llama-1b: D=64 lane-pads to the same footprint
-    (8, 128, 2, 256),   # a tp=4 shard keeps the full tile
-    (64, 128, 2, 64),
+@pytest.mark.parametrize("t,nb,bs,nh,kvh,d,kv_itemsize,want", [
+    (512, 64, 32, 32, 8, 128, 2, (128, 8)),   # qwen3-8b: cell 1
+    (512, 64, 32, 28, 4, 128, 2, (128, 8)),   # qwen2-7b: cell 2
+    (512, 64, 32, 16, 16, 128, 2, (256, 8)),  # olmoe-1b-7b: the MoE cell
+    (512, 128, 32, 32, 8, 128, 2, (128, 8)),  # the table's width: no part
+    (1024, 64, 32, 8, 2, 128, 2, (256, 8)),   # a tp=4 shard: the full tile
+    (512, 256, 16, 32, 8, 128, 1, (128, 16)),  # fp8, 16-token pages
+    (512, 64, 32, 64, 16, 128, 2, (64, 8)),
+    (64, 64, 32, 32, 8, 128, 2, (64, 8)),     # never longer than the bucket
+    (512, 2, 32, 32, 8, 128, 2, (256, 2)),    # ... nor wider than the table
 ])
-def test_prefill_query_tile_fits_scoped_vmem(nh, d, itemsize, want):
-    tt = pk._prefill_q_tile(1024, nh, d, itemsize)
-    assert tt == want
-    lanes = -(-d // 128) * 128
-    resident = nh * tt * (4 * itemsize * lanes + 4 * lanes + 2 * 4 * 128)
-    assert resident <= pk.PREFILL_VMEM_BUDGET
-    assert pk._prefill_q_tile(64, nh, d, itemsize) == min(64, want)
+def test_prefill_tile_and_page_block_fit_the_vmem_the_kernel_asks_for(
+        t, nb, bs, nh, kvh, d, kv_itemsize, want):
+    tt, pages = pk._prefill_tiling(t, nb, bs, nh, kvh, d, kv_itemsize, 2)
+    assert (tt, pages) == want
+    assert pages * bs <= max(pk.PREFILL_BLOCK_TOKENS, bs) and pages <= nb
+    held = pk._prefill_vmem(tt, pages, bs, nh, kvh, d, kv_itemsize, 2)
+    assert held <= pk.PREFILL_VMEM_BUDGET < pk.PREFILL_VMEM_LIMIT
+    if tt < min(t, pk.PREFILL_Q_TILE):  # cut by the budget
+        assert pk._prefill_vmem(
+            2 * tt, pages, bs, nh, kvh, d, kv_itemsize, 2
+        ) > pk.PREFILL_VMEM_BUDGET
 
 
 # -- a sparse-MoE model's step programs (ISSUE 26) ----------------------------
@@ -297,6 +319,46 @@ def test_moe_prefill_program_compiles_for_v5e(topo, rows, chunk):
     _assert_no_widened_experts(cfg, text)
     grouped = rows * chunk > llama._ALL_EXPERTS_MAX_TOKENS
     assert ("ragged-dot" in text) == grouped
+
+
+@pytest.mark.parametrize("model", ["qwen3-8b", "qwen2-7b", "olmoe-1b-7b"])
+def test_prefill_program_of_a_cell_holds_no_score_temp(topo, model):
+    """The 16 x 512 prefill program the cells' warm-up compiles (two layers
+    deep, int8 weights, the 64-page table), with the kernel: no f32 buffer
+    of (rows, heads, chunk, table tokens) — 2 GiB at 32 heads — no gathered
+    history, no gather_pages scope, and all its temps under that size."""
+    import dataclasses
+    import re
+
+    from vllm_production_stack_tpu.models import llama
+    from vllm_production_stack_tpu.models.registry import resolve_model_config
+
+    cfg = dataclasses.replace(
+        resolve_model_config(model, quantization="int8"), num_layers=2)
+    rows, s_ctx = 16, 64 * 32
+    params, kv, i32, _staged, nb = _moe_program_args(topo, cfg, rows, CHUNK)
+
+    def step(params, kv, tokens, positions, tables, slots, lens):
+        hidden, kv = llama.forward(
+            cfg, params, tokens, positions, kv, tables, slots, lens,
+            backend="pallas",
+            moe_stats=[] if cfg.num_experts else None)
+        return llama.compute_logits(cfg, params, hidden[:, -1]), kv
+
+    compiled = jax.jit(step, donate_argnums=1).lower(
+        params, kv, i32(rows, CHUNK), i32(rows, CHUNK), i32(rows, nb),
+        i32(rows * CHUNK), i32(rows)).compile()
+    text = compiled.as_text()
+    assert "paged_prefill_attention" in text
+    assert "gather_pages" not in text
+    nh, kvh, d = HEADS[model]
+    scores = rf"\[{rows},{nh},{CHUNK},{s_ctx}\]|\[{rows},{kvh},\d+,{CHUNK},{s_ctx}\]"
+    history = rf"\[{rows},{s_ctx},{kvh},{d}\]"
+    held = [line[:160] for line in text.splitlines()
+            if re.search(scores, line) or re.search(history, line)]
+    assert not held, held
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    assert temps < rows * nh * CHUNK * s_ctx * 4, temps
 
 
 def test_moe_decode_step_compiles_for_v5e(topo):

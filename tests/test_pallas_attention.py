@@ -400,6 +400,138 @@ def test_prefill_kernel_multi_tile():
                                atol=2e-5)
 
 
+def _prefill_case(rows, nb, bs, kvh, qpk, d, t, seed=0, dtype=np.float32):
+    """Rows of (chunk_start, context_len) in one t-token bucket, each holding
+    exactly the pages its context needs; dead table slots point at pages that
+    hold other data and belong to no row."""
+    rng = np.random.RandomState(seed)
+    start = np.asarray([r[0] for r in rows], np.int32)
+    ctx = np.asarray([r[1] for r in rows], np.int32)
+    held = -(-ctx // bs)
+    num_blocks = int(held.sum()) + 9
+    kv = rng.randn(2, num_blocks, bs, kvh, d).astype(dtype)
+    owned = rng.permutation(np.arange(1, num_blocks - 8))
+    tables = rng.randint(num_blocks - 8, num_blocks, size=(len(rows), nb))
+    at = 0
+    for i, n in enumerate(held):
+        tables[i, :n] = owned[at:at + n]
+        at += n
+    q = rng.randn(len(rows), t, kvh * qpk, d).astype(dtype)
+    return q, kv, tables.astype(np.int32), ctx, start
+
+
+def _prefill_kernel_out(case):
+    from vllm_production_stack_tpu.ops.paged_attention_pallas import (
+        paged_prefill_attention,
+    )
+
+    q, kv, tables, ctx, start = case
+    return np.asarray(paged_prefill_attention(
+        jnp.asarray(q), jnp.asarray(kv), jnp.asarray(tables),
+        jnp.asarray(ctx), jnp.asarray(start), scale=q.shape[-1] ** -0.5,
+        interpret=True,
+    ).astype(jnp.float32))
+
+
+def _real_queries(case):
+    """(B, T) bool: the chunk's own tokens. Queries past a row's context are
+    bucket padding: never read, and the two backends differ on them (a tile
+    of nothing but padding is skipped by the kernel)."""
+    q, _, _, ctx, start = case
+    return (start[:, None] + np.arange(q.shape[1])[None, :]) < ctx[:, None]
+
+
+# name: (nb, bs, kvh, qpk, d, t, rows of (chunk_start, context_len), dtype).
+# The page block is 256 tokens; the query tile 256 rows, fewer where every
+# head of it would not fit (_prefill_tiling).
+PREFILL_CASES = {
+    # the benchmark cells' head shapes, bf16 as the chip runs them: a first
+    # chunk, one that starts mid-page, a padding row, a 4-page prompt
+    "qwen3-8b-heads-bf16": (64, 32, 8, 4, 128, 128,
+                            [(0, 128), (45, 173), (0, 0), (0, 100)],
+                            jnp.bfloat16),
+    "qwen2-7b-group7-bf16": (64, 32, 4, 7, 128, 128,
+                             [(0, 128), (45, 173), (0, 0), (300, 428)],
+                             jnp.bfloat16),
+    "olmoe-mha-bf16": (64, 32, 16, 1, 128, 128,
+                       [(0, 128), (45, 173), (0, 0), (300, 428)],
+                       jnp.bfloat16),
+    # the second 512-token chunk of a 1,024-token prompt (resident history +
+    # itself) beside a 128-token prompt in the same bucket: several query
+    # tiles, three of the short row's four skipped
+    "second-chunk-of-1024": (64, 32, 8, 4, 128, 512,
+                             [(512, 1024), (0, 128)], np.float32),
+    # a row holding 4 pages, in the 64- and in the 128-page table
+    "four-pages-table-64": (64, 32, 2, 2, 128, 256, [(0, 128), (0, 256)],
+                            np.float32),
+    "four-pages-table-128": (128, 32, 2, 2, 128, 256, [(0, 128), (0, 256)],
+                             np.float32),
+    # the last chunk of a full table; an odd head count (indexed, not
+    # strided); 16-token pages; a table narrower than a block
+    "full-table": (64, 32, 2, 2, 64, 64, [(1984, 2048), (1000, 1064)],
+                   np.float32),
+    "one-kv-head-bf16": (32, 32, 1, 4, 128, 64, [(0, 64), (70, 134)],
+                         jnp.bfloat16),
+    "page16": (64, 16, 2, 2, 64, 64, [(0, 64), (250, 300), (0, 0)],
+               np.float32),
+    "table-narrower-than-a-block": (3, 32, 2, 2, 64, 32, [(0, 20), (60, 92)],
+                                    np.float32),
+}
+
+
+@pytest.mark.parametrize("name", list(PREFILL_CASES))
+def test_prefill_walk_kernel_matches_xla_reference(name):
+    nb, bs, kvh, qpk, d, t, rows, dtype = PREFILL_CASES[name]
+    case = _prefill_case(rows, nb, bs, kvh, qpk, d, t, seed=3, dtype=dtype)
+    q, kv, tables, ctx, start = case
+    out = _prefill_kernel_out(case)
+    ref = np.asarray(_prefill_ref(
+        q, kv, tables, ctx, start, d ** -0.5).astype(jnp.float32))
+    tol = 2e-5 if dtype is np.float32 else 2e-2
+    real = _real_queries(case)
+    np.testing.assert_allclose(out[real], ref[real], rtol=tol, atol=tol)
+    assert np.all(np.isfinite(out))  # padding too: it flows on to the MLP
+
+
+def test_prefill_dead_table_slots_and_width_do_not_reach_a_row():
+    """What a row's output depends on: its own pages. Bitwise the same alone
+    and among others, in the 64- and the 128-page table, and whatever the
+    slots past its pages point at."""
+    rows = [(0, 256), (0, 0), (512, 700), (45, 173)]
+    case = _prefill_case(rows, 64, 32, 2, 2, 128, 256, seed=9)
+    q, kv, tables, ctx, start = case
+    out, real = _prefill_kernel_out(case), _real_queries(case)
+    at = slice(2, 3)
+    alone = _prefill_kernel_out(
+        (q[at], kv, tables[at], ctx[at], start[at]))
+    np.testing.assert_array_equal(alone[0][real[2]], out[2][real[2]])
+    wide = np.zeros((len(rows), 128), np.int32)  # dead slots: the null page
+    wide[:, :64] = tables
+    np.testing.assert_array_equal(
+        _prefill_kernel_out((q, kv, wide, ctx, start))[real], out[real])
+
+
+def test_prefill_kernel_walks_only_the_blocks_a_tile_needs():
+    """The walk's trip count, read off the copies the kernel starts: pages
+    past a row's context and blocks after a tile's last position are never
+    copied — poisoned, they cannot reach the output."""
+    rows = [(0, 128), (256, 512), (0, 0)]
+    case = _prefill_case(rows, 64, 32, 2, 2, 128, 256, seed=4)
+    q, kv, tables, ctx, start = case
+    clean = _prefill_kernel_out(case)
+    poisoned = kv.copy()
+    used = set()
+    for i, n in enumerate(-(-ctx // 32)):
+        used.update(tables[i, :n].tolist())
+    # the pages of the 256-token block that holds row 0's four pages are
+    # copied whole (a block is the unit), every other page is not
+    in_block = set(tables[0, :8].tolist())
+    spare = [b for b in range(1, kv.shape[1]) if b not in used | in_block]
+    poisoned[:, spare] = np.nan
+    np.testing.assert_array_equal(
+        _prefill_kernel_out((q, poisoned, tables, ctx, start)), clean)
+
+
 def test_prefill_sharded_matches_unsharded_tp2_dp2():
     """shard_map placement of the prefill kernel over (dp=2, tp=2): pure
     placement, no collective — must match the single-instance kernel."""
@@ -461,18 +593,125 @@ def test_engine_chunked_prefill_pallas_backend_matches_xla():
 
 
 def test_auto_prefill_backend_policy_gates():
-    """Prefill 'auto' is XLA-only until the kernel's on-chip sweep lands —
-    auto must only pick measured winners (the explicit 'pallas' knob is
-    the opt-in; parity is pinned above, perf is not yet)."""
+    """Prefill 'auto' is a pure predicate of static shapes and picks the
+    kernel where the sweep measured it (bf16 pools whose pages fill HBM
+    tiles, 32-token pages, one device); XLA everywhere else."""
     from vllm_production_stack_tpu.engine.model_runner import (
         resolve_auto_prefill_backend as auto,
     )
 
-    base = dict(block_size=32, max_model_len=8192, platform="tpu",
-                heads_divisible=True)
-    assert auto(**base) == "xla"  # flip with the sweep table in hand
-    assert auto(**{**base, "block_size": 16}) == "xla"
-    assert auto(**{**base, "platform": "cpu"}) == "xla"
+    base = dict(block_size=32, num_kv_heads=8, head_dim=128, kv_itemsize=2,
+                kv_quantized=False, mesh_size=1, platform="tpu")
+    assert auto(**base) == "pallas"  # qwen3-8b: cell 1
+    assert auto(**{**base, "num_kv_heads": 4}) == "pallas"  # qwen2-7b
+    assert auto(**{**base, "num_kv_heads": 16}) == "pallas"  # olmoe
+    assert auto(**{**base, "head_dim": 64}) == "xla"  # llama-1b: half tiles
+    assert auto(**{**base, "num_kv_heads": 1}) == "xla"  # a tp shard's head
+    assert auto(**{**base, "kv_itemsize": 1, "kv_quantized": True}) == "xla"
+    assert auto(**{**base, "block_size": 16}) == "xla"  # not swept
+    assert auto(**{**base, "mesh_size": 4}) == "xla"  # no GSPMD rule
+    assert auto(**{**base, "platform": "cpu"}) == "xla"  # needs Mosaic
+
+
+@pytest.mark.parametrize(
+    "model", ["tiny-gemma2", "tiny-mistral-sliding", "tiny-softcap"])
+def test_prefill_backend_is_xla_for_sliding_and_softcapped_models(model):
+    """The kernel has no window masking and no softcap: 'auto' resolves to
+    XLA for such models whatever their shapes, an explicit 'pallas' is
+    refused at construction, and the model code refuses it too."""
+    import dataclasses
+
+    from vllm_production_stack_tpu.engine.config import (
+        CacheConfig, EngineConfig, ModelConfig, SchedulerConfig,
+    )
+    from vllm_production_stack_tpu.engine.model_runner import ModelRunner
+    from vllm_production_stack_tpu.models import llama
+
+    tiny = ModelConfig.tiny()
+    cfg = {
+        "tiny-gemma2": dataclasses.replace(
+            tiny, sliding_window=16, sliding_window_pattern=2,
+            attn_logit_softcap=50.0),
+        "tiny-mistral-sliding": dataclasses.replace(tiny, sliding_window=16),
+        "tiny-softcap": dataclasses.replace(tiny, attn_logit_softcap=30.0),
+    }[model]
+
+    def runner(backend):
+        return ModelRunner(EngineConfig(
+            model=cfg, cache=CacheConfig(block_size=32, num_blocks=32),
+            scheduler=SchedulerConfig(
+                max_num_seqs=2, max_num_batched_tokens=64,
+                decode_buckets=(2,), prefill_buckets=(64,), decode_window=4,
+            ),
+            attention_backend="xla", prefill_attention_backend=backend,
+        ))
+
+    assert runner("auto")._prefill_backend == "xla"
+    assert not runner("auto").prefill_uses_kernel
+    with pytest.raises(ValueError, match="sliding-window or softcapped"):
+        runner("pallas_interpret")
+    with pytest.raises(NotImplementedError, match="sliding-window or"):
+        llama.forward(cfg, None, None, jnp.zeros((1, 4), jnp.int32), None,
+                      None, None, None, backend="pallas_interpret")
+
+
+@pytest.mark.parametrize("backend,resolved", [
+    ("pallas_interpret", "pallas_interpret"), ("xla", "xla"), ("auto", "xla"),
+])
+def test_prefill_kernel_counter_follows_the_backend(backend, resolved):
+    """/debug/timing's prefill_kernel_n: prefill dispatches whose program
+    holds the kernel — every one under pallas, none under xla (and 'auto'
+    is XLA on this platform)."""
+    from vllm_production_stack_tpu.engine.config import (
+        CacheConfig, EngineConfig, ModelConfig, SchedulerConfig,
+    )
+    from vllm_production_stack_tpu.engine.engine import LLMEngine
+    from vllm_production_stack_tpu.engine.request import SamplingParams
+
+    eng = LLMEngine(EngineConfig(
+        model=ModelConfig.tiny(max_model_len=256),
+        cache=CacheConfig(block_size=8, num_blocks=64),
+        scheduler=SchedulerConfig(
+            max_num_seqs=2, max_num_batched_tokens=32,
+            prefill_buckets=(32,), decode_buckets=(2,), decode_window=4,
+        ),
+        attention_backend="xla", prefill_attention_backend=backend,
+    ))
+    assert eng.runner.device_report()["attention"]["prefill"] == {
+        "requested": backend, "resolved": resolved}
+    eng.generate([list(range(1, 70))],
+                 SamplingParams(max_tokens=3, temperature=0.0,
+                                ignore_eos=True))
+    t = eng.flightrec.timing()
+    assert t["prefill_n"] >= 3  # 69 tokens in 32-token chunks
+    assert t["prefill_kernel_n"] == (
+        t["prefill_n"] if resolved.startswith("pallas") else 0)
+
+
+def test_explicit_prefill_kernel_needs_pages_that_fill_tiles():
+    """On a chip the kernel slices pages out of HBM itself: an explicit
+    'pallas' over a pool whose pages do not fill whole tiles (D = 16 here;
+    D = 64, one-head tp shards) is refused at construction, not at the
+    first compile. Interpret mode has no tiles and serves any shape."""
+    from vllm_production_stack_tpu.engine.config import (
+        CacheConfig, EngineConfig, ModelConfig, SchedulerConfig,
+    )
+    from vllm_production_stack_tpu.engine.model_runner import ModelRunner
+
+    def runner(backend):
+        return ModelRunner(EngineConfig(
+            model=ModelConfig.tiny(),
+            cache=CacheConfig(block_size=32, num_blocks=32),
+            scheduler=SchedulerConfig(
+                max_num_seqs=2, max_num_batched_tokens=64,
+                decode_buckets=(2,), prefill_buckets=(64,),
+            ),
+            attention_backend="xla", prefill_attention_backend=backend,
+        ))
+
+    with pytest.raises(ValueError, match="fill whole HBM tiles"):
+        runner("pallas")
+    assert runner("pallas_interpret").prefill_uses_kernel
 
 
 def test_auto_backend_policy_gates():

@@ -1309,6 +1309,8 @@ class LLMEngine:
                 rows = handle.resolve()
                 rec.resolve(handle.seq)
             rec.count(key + "_n")
+            if kind == "prefill" and self.runner.prefill_uses_kernel:
+                rec.count("prefill_kernel_n")
             if kind != "prefill":
                 self.scheduler.end_speculative(work)
             results = self.scheduler.postprocess(work, rows)

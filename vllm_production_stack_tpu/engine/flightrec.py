@@ -217,7 +217,7 @@ class FlightRecorder:
         # serves seconds. Every key /debug/timing ever had lives here.
         self._tot: dict[str, int] = dict.fromkeys((
             *(p + "_s" for p in PHASES),
-            "prefill_s", "prefill_n", "prefill_tokens",
+            "prefill_s", "prefill_n", "prefill_kernel_n", "prefill_tokens",
             "decode_s", "decode_n", "decode_tokens",
             "overlap_s", "step_wall_s", "rollback_n", "steps",
             "dispatch_n", "starved_n",

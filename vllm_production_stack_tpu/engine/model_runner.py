@@ -30,6 +30,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models import llama
 from ..ops.attention import gather_pages
+from ..ops.paged_attention_pallas import _pages_fill_tiles
 from ..utils.logging import init_logger
 from ..parallel import mesh as mesh_lib
 from ..parallel.sharding import (
@@ -81,32 +82,55 @@ def resolve_auto_attention_backend(
 
 
 def resolve_auto_prefill_backend(
-    *, block_size: int, max_model_len: int, platform: str,
-    heads_divisible: bool,
+    *, block_size: int, num_kv_heads: int, head_dim: int, kv_itemsize: int,
+    kv_quantized: bool, mesh_size: int, platform: str,
 ) -> str:
-    """The 'auto' PREFILL-attention choice, independent of decode: the
-    paged flash-prefill kernel (ops/paged_attention_pallas.py:
-    paged_prefill_attention) streams pool pages HBM→VMEM with a resident
-    query tile and never materializes the gathered (B, S, kvH, D) history
-    OR the (B, T, S) mask.
+    """The 'auto' PREFILL-attention choice, independent of decode, as a pure
+    predicate of static shapes: the paged flash-prefill kernel
+    (ops/paged_attention_pallas.py:paged_prefill_attention) walks the pages
+    a row holds and never materializes the gathered (B, S, kvH, D) history,
+    the (B, heads, T, S) scores or the (B, T, S) mask.
 
-    Gate: 'auto' returns XLA until the kernel's on-chip sweep lands —
-    auto must only ever pick MEASURED winners (the decode gate's
-    discipline), and the kernel has been compiled for v5e and checked on
-    the chip against the XLA path (chip_smoke.py) but never timed
-    (ROADMAP S5). The expected winning regime mirrors
-    decode's (the same page-DMA-size argument applies: 16-token pages
-    make per-page DMAs/matmuls too small, while the XLA gather's cost
-    tracks gathered bytes — paid per CHUNK in prefill, so long contexts
-    should favor the kernel strongly). To enable: run
-    benchmarks/sweep_attention.py --prefill on a chip, paste the table
-    here, and gate like the decode predicate. Until then the explicit
-    'pallas' knob is the opt-in (parity is pinned by
-    tests/test_pallas_attention.py; only perf is unmeasured).
-    heads_divisible is still threaded so the eventual gate composes with
-    tp meshes the same way the explicit knob's checks do."""
-    del block_size, max_model_len, heads_divisible  # used once measured
-    del platform
+    The kernel where it was measured to win: a pool whose pages fill whole
+    HBM tiles (_pages_fill_tiles: D = 128 with 2, 4, 8 or 16 bf16 kv heads do;
+    the sweep has the three last),
+    unquantized, >= 32-token pages, one device, a real TPU. XLA everywhere
+    else until swept: fp8 pools, D = 64 or one-head tp shards (their pages
+    the kernel cannot slice out of HBM), 16-token pages, meshes over one
+    device. Sliding-window and softcapped models never reach here
+    (ModelRunner._resolve_prefill_backend): the kernel has neither.
+
+    benchmarks/sweep_attention.py --prefill on a v5e chip (PR 28; one
+    layer, a 512-token bucket, 32-token pages, bf16; ms, kernel / XLA):
+
+    rows x tokens held (table)   32/8 heads      28/4 heads      16/16 heads
+     1 x 128   ( 64 pages)        0.040 / 0.646   0.037 / 0.171   0.036 / 0.136
+     1 x 512   ( 64 pages)        0.105 / 0.645   0.091 / 0.171   0.068 / 0.137
+     1 x 1024  ( 64 pages)        0.200 / 0.649   0.164 / 0.172   0.123 / 0.137
+     1 x 2048  ( 64 pages)        0.388 / 0.645   0.314 / 0.169   0.234 / 0.137
+     2 x 512   ( 64 pages)        0.191 / 0.839   0.165 / 0.711   0.117 / 0.741
+     4 x 512   ( 64 pages)        0.372 / 1.658   0.320 / 1.421   0.218 / 1.755
+     4 x 2048  ( 64 pages)        1.485 / 1.659   1.209 / 1.421   0.878 / 1.762
+    16 x 512   ( 64 pages)        1.905 / 9.505   1.460 / 6.122   0.837 / 8.690
+     1 x 512   (128 pages)        0.105 / 1.360   0.090 / 0.356   0.068 / 0.246
+     1 x 2048  (128 pages)        0.387 / 1.358   0.317 / 0.356   0.236 / 0.247
+
+    The kernel's time follows what the rows hold, XLA's the table's width
+    and the row bucket: the kernel wins every program of two rows or more
+    (4-10x at 512 tokens a row), every one-row program up to ~1,000 resident
+    tokens, and at 32 heads all of them; a lone row whose 2,048 tokens fill
+    a 64-page table is the one place XLA wins (28/4 and 16/16 heads, 1.7-1.9x:
+    the kernel's f32 softmax arithmetic, not its bytes), and in the
+    128-page table that row is a tie.
+    """
+    if (
+        _pages_fill_tiles(num_kv_heads, head_dim, kv_itemsize)
+        and not kv_quantized
+        and block_size >= 32
+        and mesh_size == 1
+        and platform == "tpu"
+    ):
+        return "pallas"
     return "xla"
 
 
@@ -539,19 +563,25 @@ class ModelRunner:
             and par.expert_parallel_size == 1
         )
         backend = self.config.prefill_attention_backend
-        if self.config.model.any_sliding:
+        model = self.config.model
+        if model.any_sliding or model.attn_logit_softcap:
+            # the kernel has no window masking and no softcap — XLA only
             if backend in ("pallas", "pallas_interpret"):
                 raise ValueError(
                     "prefill_attention_backend='pallas' does not support "
-                    "sliding-window models; use 'xla'"
+                    "sliding-window or softcapped models; use 'xla'"
                 )
             return "xla"
+        kv_itemsize = jnp.dtype(self._kv_dtype).itemsize
         if backend == "auto":
             return resolve_auto_prefill_backend(
                 block_size=self.config.cache.block_size,
-                max_model_len=self.config.model.max_model_len,
+                num_kv_heads=model.num_kv_heads,
+                head_dim=model.head_dim,
+                kv_itemsize=kv_itemsize,
+                kv_quantized=self._kv_dtype != model.dtype,
+                mesh_size=self.mesh.size,
                 platform=jax.devices()[0].platform,
-                heads_divisible=heads_ok,
             )
         if backend not in ("xla", "pallas", "pallas_interpret"):
             raise ValueError(
@@ -564,7 +594,21 @@ class ModelRunner:
                 "num_heads and num_kv_heads divisible by tp, and a dp/tp "
                 "mesh (pp/sp/ep shard axes the kernel cannot split)"
             )
+        if backend == "pallas" and not _pages_fill_tiles(
+            model.num_kv_heads // tp, model.head_dim, kv_itemsize
+        ):
+            raise ValueError(
+                "prefill_attention_backend='pallas' needs a pool whose "
+                f"pages fill whole HBM tiles ({model.num_kv_heads // tp} kv "
+                f"heads x {model.head_dim} a device do not): the kernel "
+                "copies pages out of HBM itself; use 'xla'"
+            )
         return backend
+
+    @property
+    def prefill_uses_kernel(self) -> bool:
+        """Whether the prefill programs hold the Pallas kernel."""
+        return self._prefill_backend.startswith("pallas")
 
     def device_report(self) -> dict:
         """The "device" section of GET /debug/programs: what the engine

@@ -584,17 +584,19 @@ def forward(
 ) -> tuple[jax.Array, jax.Array]:
     """One model step over a token batch. Prefill is (B=1, T=chunk); decode is
     (B=batch, T=1). Returns (hidden (B,T,h), updated kv_caches)."""
+    if backend.startswith("pallas") and (
+        cfg.any_sliding or cfg.attn_logit_softcap
+    ):
+        raise NotImplementedError(
+            "pallas prefill does not support sliding-window or softcapped "
+            "models (the runner gates these to the XLA backend)"
+        )
     with jax.named_scope("embed"):
         x = _embed(cfg, params, token_ids)
     if backend.startswith("pallas"):
         # the kernel masks from scalars alone — the scheduler feeds chunks
         # with contiguous positions (scheduler.py: range(start, start+len)),
         # so chunk_start is the first column. No (B, T, S) mask exists.
-        if cfg.any_sliding:
-            raise NotImplementedError(
-                "pallas prefill does not support sliding-window models "
-                "(the runner gates these to the XLA backend)"
-            )
         mask = None
         mask_local = None
         pallas_prefill = {

@@ -10,7 +10,10 @@ the scalar-prefetched block table, the next block's copies in flight while
 the current block is computed. A flash-style online softmax accumulates
 across blocks, and the window's staged K/V (see
 models/llama.py:decode_window_step) are folded in last, so the kernel
-computes the complete decode attention output in one op.
+computes the complete decode attention output in one op. The prefill
+kernel walks the same blocks under one row's query tile, every head of it
+resident: one dot a kv group, not one matrix with the other heads masked —
+prefill is bound by arithmetic, not by bytes.
 
 Reference parity: the reference stack delegates this op to vLLM's CUDA
 paged-attention kernels inside its engine images (external to its repo);
@@ -417,122 +420,262 @@ def _hist_kernel(
 # Paged PREFILL (chunked-prefill-aware) flash attention
 # --------------------------------------------------------------------------
 
-# query-tile length: q rows resident in VMEM across the page stream, capped
-# so that every head's tile state fits the 16 MiB scoped-VMEM limit the v5e
-# compiler enforces (tests/test_chip_compile.py). Per (head, row) the kernel
-# holds the double-buffered q and out tiles, the f32 acc, and the (rows, 1)
-# m/l scratch — which is lane-padded to 128, so it costs as much as acc at
-# D=128. The budget leaves the rest of the limit to the page double-buffers
-# and the per-head (Tt, bs) score temporaries.
+# Prefill: a grid step is one row's query tile of up to PREFILL_Q_TILE
+# tokens, every head of it resident; the row's pages are visited
+# PREFILL_BLOCK_TOKENS at a time as in decode. The tile is halved until what
+# a grid step holds (_prefill_vmem) fits PREFILL_VMEM_BUDGET of the
+# PREFILL_VMEM_LIMIT the kernel asks the compiler for (a v5e core has
+# 128 MiB of VMEM; 16 MiB is only the default scope).
 PREFILL_Q_TILE = 256
-PREFILL_VMEM_BUDGET = 10 * 2**20
+PREFILL_BLOCK_TOKENS = 256
+PREFILL_VMEM_BUDGET = 24 * 2**20
+PREFILL_VMEM_LIMIT = 32 * 2**20
 
 
-def _prefill_q_tile(t: int, nh: int, d: int, itemsize: int) -> int:
+def _prefill_vmem(
+    tt: int, pages: int, bs: int, nh: int, kvh: int, d: int,
+    kv_itemsize: int, q_itemsize: int,
+) -> int:
+    """Bytes of VMEM one prefill grid step holds: the double-buffered query
+    and output tiles and the queries regrouped by kv group, the f32
+    accumulator with its running max and sum (one column each, lane-padded
+    to 128), two K/V slots at the pool's width, and one kv group's f32
+    scores with their exponentials in both widths."""
     lanes = -(-d // 128) * 128
-    per_row = 4 * itemsize * lanes + 4 * lanes + 2 * 4 * 128
-    rows = PREFILL_VMEM_BUDGET // (nh * per_row)
+    tiles = 5 * tt * nh * lanes * q_itemsize
+    state = nh * tt * (lanes + 2 * 128) * 4
+    slots = 2 * 2 * pages * bs * kvh * lanes * kv_itemsize
+    scores = (nh // kvh) * tt * pages * bs * (4 + 4 + q_itemsize)
+    return tiles + state + slots + scores
+
+
+def _prefill_tiling(
+    t: int, nb: int, bs: int, nh: int, kvh: int, d: int, kv_itemsize: int,
+    q_itemsize: int,
+) -> tuple[int, int]:
+    """(query tile, pages per block), from static shapes alone: as in decode
+    a row's blocks start at multiples of pages * bs from position 0."""
+    pages = min(max(1, PREFILL_BLOCK_TOKENS // bs), nb)
     tt = min(t, PREFILL_Q_TILE)
-    while tt > 8 and tt > rows:
+    while tt > 16 and _prefill_vmem(
+        tt, pages, bs, nh, kvh, d, kv_itemsize, q_itemsize
+    ) > PREFILL_VMEM_BUDGET:
         tt //= 2
-    return tt
+    return tt, pages
 
 
-def _prefill_kernel(
+def _kv_heads_by_stride(dtype, kvh: int) -> bool:
+    """Whether a kv head's rows of a block are a strided load of 32-bit rows
+    (f32 pools; bf16 pools with an even head count, two heads a word), and
+    so can be read for a head index known only inside a loop."""
+    return dtype.itemsize == 4 or (dtype == jnp.bfloat16 and kvh % 2 == 0)
+
+
+def _kv_head_rows(buf, slot, which, g, dtype):
+    """K (which=0) or V (1) of kv head g in the block held by `slot`, as a
+    (tokens, D) matrix in `dtype`. The pool interleaves kv heads under each
+    token, so these are every kvh-th row of the block: a strided load of
+    32-bit rows — for a bf16 pool of the words that pack heads (2w, 2w+1),
+    whose halves are f32s' upper halves — and for any other pool (fp8; an
+    odd head count) an index into the head axis with a static g."""
+    _, _, pages, bs, kvh, d = buf.shape
+    span = pages * bs
+    if not _kv_heads_by_stride(buf.dtype, kvh):
+        rows = buf[slot, which, :, :, g, :].reshape(span, d)
+        return rows.astype(jnp.float32).astype(dtype)
+    if buf.dtype.itemsize == 4:
+        flat = buf.reshape(2, 2, span * kvh, d)
+        return flat.at[slot, which][pl.ds(g, span, stride=kvh), :].astype(dtype)
+    words = buf.bitcast(jnp.uint32).reshape(2, 2, span * kvh // 2, d)
+    words = words.at[slot, which][pl.ds(g // 2, span, stride=kvh // 2), :]
+    # head 2w is the word's low half, head 2w + 1 its high half
+    words = (words >> (16 * (g % 2)).astype(jnp.uint32)) << 16
+    return pltpu.bitcast(words, jnp.float32).astype(dtype)
+
+
+def _prefill_walk_kernel(
     # scalar prefetch
     tables_ref,  # (B, nb) int32 — page id per (row, page-slot)
     ctx_ref,  # (B,) int32 — resident tokens AFTER this chunk (incl. chunk)
     start_ref,  # (B,) int32 — logical position of the chunk's first token
-    # pipeline inputs
-    q_ref,  # (1, nh, Tt, D) — head-major so per-head slices are static 2D
-    kv_ref,  # (2, 1, bs, kvh, D) — this grid step's pool page
+    # inputs
+    q_ref,  # (1, Tt, nh * D) — this tile's queries, heads side by side
+    kv_hbm,  # (2, num_blocks, bs, kvh, D) — the whole pool, left in HBM
     # output
-    out_ref,  # (1, nh, Tt, D)
+    out_ref,  # (1, Tt, nh * D)
     # scratch
-    m_ref,  # (nh*Tt, 1) f32 running max, head-major rows
-    l_ref,  # (nh*Tt, 1) f32 running denominator
-    acc_ref,  # (nh*Tt, D) f32 running numerator
+    buf,  # (2, 2, P, bs, kvh, D) — two slots of one block's K and V pages
+    sems,  # DMA semaphore per slot
+    state,  # SMEM (2,) int32: slot of the next block; is it in flight
+    q_rows,  # (kvh, q_per_kv * Tt, D) — the tile's queries by kv group
+    m_ref,  # (nh * Tt, 1) f32 running max, rows ordered (head, token)
+    l_ref,  # (nh * Tt, 1) f32 running denominator
+    acc_ref,  # (nh * Tt, D) f32 running numerator
     *,
     scale: float,
-    block_size: int,
-    num_kv_heads: int,
 ):
-    """Flash prefill over the paged pool: the page id for each grid step
-    comes from the scalar-prefetched block table (the gather IS the
-    pipeline's index_map), the query tile
-    stays in VMEM, and causality is computed from iotas alone: the serving
-    scheduler feeds chunks with CONTIGUOUS positions (scheduler.py
-    work.positions = range(start, start+len)), so q position = chunk_start
-    + tile offset + row. Chunked prefill needs no special casing — resident
-    pages hold earlier chunks AND this chunk's freshly-written KV (forward
-    writes before attending), and `pos_k <= pos_q` masks the not-yet-valid
-    tail of the chunk's own pages."""
-    b = pl.program_id(0)
-    qt = pl.program_id(1)
-    j = pl.program_id(2)
-    num_pages = pl.num_programs(2)
+    """One grid step = one query tile of one row, all heads. The tile walks
+    the page blocks its row holds up to the tile's last position —
+    ceil(min(ctx, tile end) / (P * bs)) of them, none for a padding row or a
+    tile past the row's tokens, whatever the table's width — each block
+    copied HBM->VMEM by P async copies while the block before it is
+    computed, and the tile's last block starts the first block of the next
+    tile that has any (decode's hand-over). Per block and kv group: ONE dot
+    of the group's heads' queries (q_per_kv * Tt, D) against the group's
+    keys (D, P * bs), operands as they lie in pool and activations, f32
+    scores, statistics and accumulator. Causality comes from iotas alone and
+    only where a block straddles ctx or the tile's diagonal: the scheduler
+    feeds chunks with CONTIGUOUS positions (scheduler.py: range(start,
+    start + len)), so a query's position is chunk_start + its index. Chunked
+    prefill needs no special casing — resident pages hold earlier chunks AND
+    this chunk's freshly-written KV (forward writes before attending)."""
+    b, qt = pl.program_id(0), pl.program_id(1)
+    rows, tiles = pl.num_programs(0), pl.num_programs(1)
+    nb = tables_ref.shape[1]
+    pages, bs, kvh, d = buf.shape[2:]
+    tt = q_ref.shape[1]
+    qpk = q_ref.shape[2] // d // kvh
+    span = pages * bs  # tokens per block
+    group = qpk * tt  # query rows a kv group's dots see
 
-    nh, tt, d = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
-    qpk = nh // num_kv_heads
+    def tile_blocks(row, tile):
+        ctx, first = ctx_ref[row], start_ref[row] + tile * tt
+        return jnp.where(
+            first < ctx, pl.cdiv(jnp.minimum(ctx, first + tt), span), 0)
 
-    @pl.when(j == 0)
-    def _init_acc():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    # (Tt, bs) mask — identical for every head, built once per grid step
-    q_pos = (
-        start_ref[b]
-        + qt * tt
-        + jax.lax.broadcasted_iota(jnp.int32, (tt, block_size), 0)
-    )
-    pos_k = j * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (tt, block_size), 1
-    )
-    valid = (pos_k < ctx_ref[b]) & (pos_k <= q_pos)
-
-    # skip pages fully outside this tile's attendable range: beyond the
-    # row's residency, or entirely after the tile's last query position.
-    # The DMA still lands (static block spec) but the 2*nh dots don't run
-    page_live = (j * block_size < ctx_ref[b]) & (
-        j * block_size <= start_ref[b] + qt * tt + tt - 1
-    )
-
-    @pl.when(page_live)
-    def _visit_live_page():
-        k_page = kv_ref[0, 0].astype(jnp.float32)  # (bs, kvh, D)
-        v_page = kv_ref[1, 0].astype(jnp.float32)
-        for h in range(nh):
-            g = h // qpk
-            q_h = q_ref[0, h].astype(jnp.float32)  # (Tt, D)
-            scores = jax.lax.dot_general(
-                q_h, k_page[:, g, :],
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # (Tt, bs)
-            scores = jnp.where(valid, scores * scale, NEG_INF)
-            r0, r1 = h * tt, (h + 1) * tt
-            m_prev, l_prev = m_ref[r0:r1], l_ref[r0:r1]
-            m_cur = jnp.max(scores, axis=1, keepdims=True)  # (Tt, 1)
-            m_new = jnp.maximum(m_prev, m_cur)
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(scores - m_new)  # (Tt, bs)
-            l_ref[r0:r1] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-            m_ref[r0:r1] = m_new
-            acc_ref[r0:r1] = acc_ref[r0:r1] * alpha + jax.lax.dot(
-                p, v_page[:, g, :], preferred_element_type=jnp.float32
+    def block_copies(row, blk, slot):
+        return [
+            pltpu.make_async_copy(
+                kv_hbm.at[:, tables_ref[row, jnp.minimum(blk * pages + j,
+                                                         nb - 1)]],
+                buf.at[slot, :, j],
+                sems.at[slot],
             )
+            for j in range(pages)
+        ]
 
-    @pl.when(j == num_pages - 1)
+    def start_block(row, blk, slot):
+        for copy in block_copies(row, blk, slot):
+            copy.start()
+
+    @pl.when((b == 0) & (qt == 0))
+    def _reset():
+        state[0] = 0
+        state[1] = 0
+
+    n_blocks = tile_blocks(b, qt)
+    slot0 = state[0]
+
+    @pl.when((n_blocks > 0) & (state[1] == 0))
+    def _first_block():
+        start_block(b, 0, slot0)
+
+    ctx, first = ctx_ref[b], start_ref[b] + qt * tt
+    # the last key position each query row attends, rows ordered (head, token)
+    last_key = jnp.minimum(
+        first + jax.lax.broadcasted_iota(jnp.int32, (group, 1), 0) % tt,
+        ctx - 1,
+    )
+    key_offsets = jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+
+    @pl.when(n_blocks > 0)
+    def _regroup_queries():
+        # heads lie side by side in the tile's lanes; a kv group's dot wants
+        # its heads' rows one under the other
+        for h in range(kvh * qpk):
+            q_rows[h // qpk, pl.ds(h % qpk * tt, tt), :] = (
+                q_ref[0, :, h * d:(h + 1) * d])
+
+    def visit(i, carry):
+        slot = (slot0 + i) % 2
+
+        @pl.when(i + 1 < n_blocks)
+        def _next_block():
+            start_block(b, i + 1, 1 - slot)
+
+        @pl.when(i + 1 == n_blocks)
+        def _next_tile():
+            # the next grid step that walks any block: this row's next
+            # tile, else the first tile of the next row that holds tokens
+            same = (qt + 1 < tiles) & (
+                tile_blocks(b, jnp.minimum(qt + 1, tiles - 1)) > 0)
+            nxt = jax.lax.while_loop(
+                lambda r: (r < rows)
+                & (tile_blocks(jnp.minimum(r, rows - 1), 0) == 0),
+                lambda r: r + 1,
+                b + 1,
+            )
+            found = same | (nxt < rows)
+
+            @pl.when(found)
+            def _its_first_block():
+                start_block(
+                    jnp.where(same, b, jnp.minimum(nxt, rows - 1)), 0,
+                    1 - slot,
+                )
+
+            state[0] = 1 - slot
+            state[1] = found.astype(jnp.int32)
+
+        for copy in block_copies(b, i, slot):
+            copy.wait()
+        key0 = i * span
+        straddles = (key0 + span > ctx) | (key0 + span - 1 > first)
+
+        def attend(g, carry):
+            q = q_rows[g]  # (group, D)
+            keys = _kv_head_rows(buf, slot, 0, g, q.dtype)
+            values = _kv_head_rows(buf, slot, 1, g, q.dtype)
+            scores = jax.lax.dot_general(
+                q, keys, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # (group, span)
+            scores = jax.lax.cond(
+                straddles,
+                lambda s: jnp.where(key_offsets <= last_key - key0, s,
+                                    NEG_INF),
+                lambda s: s,
+                scores,
+            )
+            r = pl.ds(pl.multiple_of(g * group, group), group)
+            # block 0 starts the statistics: the scratch is never cleared
+            m_prev = jnp.where(i == 0, NEG_INF, m_ref[r])
+            l_prev = jnp.where(i == 0, 0.0, l_ref[r])
+            acc = jnp.where(i == 0, 0.0, acc_ref[r])
+            m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(scores - m_new)  # exactly 0 on masked keys
+            m_ref[r] = m_new
+            l_ref[r] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[r] = acc * alpha + jax.lax.dot(
+                p.astype(values.dtype), values,
+                preferred_element_type=jnp.float32,
+            )
+            return carry
+
+        # the kv groups in a loop, not unrolled, where a head's rows can be
+        # read for a traced head index: the body's size is what every
+        # engine start pays to lower, once per prefill program
+        if _kv_heads_by_stride(buf.dtype, kvh):
+            return jax.lax.fori_loop(0, kvh, attend, carry)
+        for g in range(kvh):
+            attend(g, carry)
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, visit, 0)
+
+    @pl.when(n_blocks > 0)
     def _finalize():
-        for h in range(nh):
-            r0, r1 = h * tt, (h + 1) * tt
-            # padding rows attend nothing (ctx 0) — l stays 0; the max
-            # keeps them finite (their outputs are never read)
-            out_ref[0, h] = (
-                acc_ref[r0:r1] / jnp.maximum(l_ref[r0:r1], 1e-30)
-            ).astype(out_ref.dtype)
+        # every query of a live tile attends key 0 at least: l > 0
+        for h in range(kvh * qpk):
+            r = pl.ds(h * tt, tt)
+            out_ref[0, :, h * d:(h + 1) * d] = (
+                acc_ref[r] / l_ref[r]).astype(out_ref.dtype)
+
+    @pl.when(n_blocks == 0)
+    def _padding():
+        # never read, but finite
+        out_ref[...] = jnp.zeros_like(out_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -547,8 +690,10 @@ def paged_prefill_attention(
     interpret: bool = False,
 ) -> jax.Array:
     """Complete chunked-prefill attention (resident pages + the chunk's own
-    freshly-written pages) without materializing the per-row gather OR the
-    (B, T, S) mask the XLA path builds. Returns (B, T, nh, D).
+    freshly-written pages) without materializing the per-row gather, the
+    (B, heads, T, S) scores OR the (B, T, S) mask the XLA path builds.
+    Returns (B, T, nh, D). On a chip the pool's pages have to fill whole HBM
+    tiles (_pages_fill_tiles): the kernel slices them out of HBM itself.
 
     Reference parity: covers what the reference's engine images get from
     vLLM's CUDA flash-prefill over paged KV; SURVEY §7.1 names paged
@@ -556,43 +701,37 @@ def paged_prefill_attention(
     b, t, nh, d = q.shape
     kvh, bs = kv.shape[3], kv.shape[2]
     nb = block_tables.shape[1]
-    tt = _prefill_q_tile(t, nh, d, q.dtype.itemsize)
+    tt, pages = _prefill_tiling(
+        t, nb, bs, nh, kvh, d, kv.dtype.itemsize, q.dtype.itemsize
+    )
     assert t % tt == 0, (t, tt)  # T is a power-of-two bucket
-
-    # head-major q so the kernel's per-head slices are static 2D views
-    q_hm = q.transpose(0, 2, 1, 3)  # (B, nh, T, D)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # tables, context_lens, chunk_start
-        grid=(b, t // tt, nb),
-        in_specs=[
-            pl.BlockSpec((1, nh, tt, d), lambda i, qt, j, tb, c, st: (i, 0, qt, 0)),
-            # the paged "gather": page id for grid step (i, qt, j) comes
-            # straight from the prefetched block table
-            pl.BlockSpec(
-                (2, 1, bs, kvh, d),
-                lambda i, qt, j, tb, c, st: (0, tb[i, j], 0, 0, 0),
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, nh, tt, d), lambda i, qt, j, tb, c, st: (i, 0, qt, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((nh * tt, 1), jnp.float32),
-            pltpu.VMEM((nh * tt, 1), jnp.float32),
-            pltpu.VMEM((nh * tt, d), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        _prefill_kernel, scale=scale, block_size=bs, num_kv_heads=kvh
-    )
+    tile = pl.BlockSpec((1, tt, nh * d), lambda i, j, *_: (i, j, 0))
     out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nh, t, d), q.dtype),
+        functools.partial(_prefill_walk_kernel, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # tables, context_lens, chunk_start
+            grid=(b, t // tt),
+            in_specs=[tile, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=tile,
+            scratch_shapes=[
+                pltpu.VMEM((2, 2, pages, bs, kvh, d), kv.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((2,), jnp.int32),
+                pltpu.VMEM((kvh, nh // kvh * tt, d), q.dtype),
+                pltpu.VMEM((nh * tt, 1), jnp.float32),
+                pltpu.VMEM((nh * tt, 1), jnp.float32),
+                pltpu.VMEM((nh * tt, d), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, t, nh * d), q.dtype),
+        # tiles run in order: each hands the next its first block in flight
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=PREFILL_VMEM_LIMIT,
+        ),
         interpret=interpret,
-    )(block_tables, context_lens, chunk_start, q_hm, kv)
-    return out.transpose(0, 2, 1, 3)  # back to (B, T, nh, D)
+    )(block_tables, context_lens, chunk_start, q.reshape(b, t, nh * d), kv)
+    return out.reshape(b, t, nh, d)
 
 
 def paged_prefill_attention_sharded(
