@@ -115,14 +115,6 @@ helm.sh/chart: {{ printf "%s-%s" .Chart.Name .Chart.Version }}
 - "--trace-buffer"
 - {{ .traceBuffer | quote }}
 {{- end }}
-{{- if eq (.stepMetering | default true) false }}
-- "--step-metering"
-- "false"
-{{- end }}
-{{- if eq (.kvFlowMetering | default true) false }}
-- "--kv-flow-metering"
-- "false"
-{{- end }}
 {{- if .kvHydration }}
 - "--kv-hydration"
 - {{ .kvHydration | quote }}
@@ -173,10 +165,6 @@ helm.sh/chart: {{ printf "%s-%s" .Chart.Name .Chart.Version }}
 {{- end }}
 {{- if eq (.enablePrefixCaching | default true) false }}
 - "--no-enable-prefix-caching"
-{{- end }}
-{{- if eq (.compileWatch | default true) false }}
-- "--compile-watch"
-- "false"
 {{- end }}
 {{- if .compileStormThreshold }}
 - "--compile-storm-threshold"

@@ -1,10 +1,10 @@
-"""Async pipelined step loop (engine/engine.py, config.async_scheduling).
+"""The pipelined step loop (engine/engine.py LLMEngine.step).
 
 The two-deep dispatch/resolve pipeline must emit BITWISE-identical token
-streams to the serial loop — greedy and seeded sampled decode, mid-window
-stop tokens, max-tokens truncation, and abort_request landing while a step
-is in flight — and the decode hot path must pay exactly ONE host sync
-(jax.device_get) per resolved step."""
+streams to the serial reference loop (testing/serial_loop.py) — greedy and
+seeded sampled decode, mid-window stop tokens, max-tokens truncation, and
+abort_request landing while a step is in flight — and the decode hot path
+must pay exactly ONE host sync (jax.device_get) per resolved step."""
 
 import numpy as np
 import pytest
@@ -12,6 +12,7 @@ import pytest
 from vllm_production_stack_tpu.engine.config import EngineConfig
 from vllm_production_stack_tpu.engine.engine import LLMEngine
 from vllm_production_stack_tpu.engine.request import SamplingParams
+from vllm_production_stack_tpu.testing.serial_loop import serial_engine
 
 
 @pytest.fixture(scope="module")
@@ -19,14 +20,13 @@ def pipe():
     engine = LLMEngine(EngineConfig.tiny())
     yield engine
     # cancel queued background compiles: leaked compile threads steal CPU
-    # from whatever module runs next (observed: pacing flakes in
-    # test_benchmarks' open-loop drive)
+    # from whatever module runs next
     engine.runner.shutdown(wait=True)
 
 
 @pytest.fixture(scope="module")
 def serial():
-    engine = LLMEngine(EngineConfig.tiny().replace(async_scheduling=False))
+    engine = serial_engine(EngineConfig.tiny())
     yield engine
     engine.runner.shutdown(wait=True)
 
@@ -42,10 +42,20 @@ def streams(eng, prompts, sp):
     return [o["token_ids"] for o in eng.generate(prompts, sp)]
 
 
-def test_async_scheduling_defaults_on(pipe, serial):
-    assert EngineConfig().async_scheduling
-    assert pipe._pipeline
-    assert not serial._pipeline
+def test_engine_has_no_loop_option(pipe, serial):
+    """The pipelined loop is the only one an engine can be started with:
+    no config field, no flag. The serial reference is a test helper that
+    replaces `step` on one instance."""
+    import dataclasses
+
+    from vllm_production_stack_tpu.engine.server import build_parser
+
+    assert "async_scheduling" not in {
+        f.name for f in dataclasses.fields(EngineConfig)
+    }
+    with pytest.raises(SystemExit):
+        build_parser().parse_args(["--async-scheduling", "false"])
+    assert "step" not in vars(pipe) and "step" in vars(serial)
 
 
 def test_greedy_equivalence(pipe, serial):
@@ -169,7 +179,7 @@ def test_decode_hot_path_single_host_sync(pipe, monkeypatch):
 
 
 def test_timing_keys_lockstep_with_metrics_contract(pipe):
-    """Guard: the step-loop timing decomposition (bench.py + /debug/timing)
+    """Guard: the step-loop timing decomposition (/debug/timing)
     and the engine→router metric contract stay in lockstep with what the
     engine actually exports."""
     from vllm_production_stack_tpu import metrics_contract as mc
@@ -235,6 +245,6 @@ def test_spec_decode_composes_with_pipeline():
     )
     eng = LLMEngine(cfg)
     try:
-        assert eng._pipeline  # the spec→serial latch is gone
+        assert "step" not in vars(eng)  # speculation swaps no loop in
     finally:
         eng.runner.shutdown(wait=True)  # no compile threads outlive the module

@@ -72,61 +72,6 @@ def test_multi_round_qa_against_router(tmp_path):
     ]
 
 
-def test_livestack_open_loop_drive():
-    """bench_livestack._drive's open-loop mode paces requests at the
-    reference's offered-QPS shape (multi-round-qa.py:349-354: user u's
-    round r is scheduled at u/qps + r*users/qps) instead of re-asking on
-    completion — the wave cannot finish before its last scheduled slot."""
-    from bench_livestack import _drive
-
-    users, rounds, qps = 4, 3, 8.0
-
-    async def go():
-        eng = FakeEngine(model="fake-model", tokens_per_sec=5000)
-        srv = TestServer(eng.build_app())
-        await srv.start_server()
-        router_srv = TestServer(build_app(parse_args([
-            "--static-backends", f"http://127.0.0.1:{srv.port}",
-            "--static-models", "fake-model",
-        ])))
-        await router_srv.start_server()
-        try:
-            # one warmup request: the cold path's one-time costs (router
-            # first hop, connection setup, CPU stolen by a previous test
-            # module's still-draining background compile thread) otherwise
-            # land inside the fixed schedule origin and every later slot
-            # counts as slipped — the real bench warms up before driving too
-            import aiohttp
-
-            async with aiohttp.ClientSession() as warm:
-                async with warm.post(
-                    f"http://127.0.0.1:{router_srv.port}/v1/completions",
-                    json={"model": "fake-model", "prompt": "warmup",
-                          "max_tokens": 1},
-                ) as resp:
-                    assert resp.status == 200
-            return await _drive(
-                f"http://127.0.0.1:{router_srv.port}", "fake-model",
-                users=users, rounds=rounds, answer_tokens=8,
-                sys_tokens=50, ramp_gap_s=0.0, q_range=(5, 10),
-                seed=0, qps=qps,
-            )
-        finally:
-            await router_srv.close()
-            await srv.close()
-
-    out = asyncio.run(go())
-    assert out["requests"] == users * rounds
-    assert out["errors"] == 0, out["error_samples"]
-    assert out["offered_qps"] == qps
-    assert out["slipped_requests"] == 0  # fake engine answers in ms
-    # last slot = (users-1)/qps + (rounds-1)*users/qps = 1.375 s — a
-    # closed-loop run against the ms-latency fake engine finishes in
-    # well under half that, so pacing is what set the wall clock
-    last_slot = (users - 1) / qps + (rounds - 1) * users / qps
-    assert out["elapsed_s"] >= last_slot
-
-
 def test_request_generator_against_router():
     proc, engines = _run_rig(lambda url: [
         "benchmarks/request_generator.py",

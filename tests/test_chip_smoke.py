@@ -72,7 +72,7 @@ def test_chip_smoke_drives_every_request_and_fails_without_a_tpu():
     assert dev["kvhash"] in ("native", "python")
 
 
-@pytest.mark.parametrize("module", ["chip_smoke", "bench_livestack", "bench"])
+@pytest.mark.parametrize("module", ["chip_smoke"])
 def test_orchestrators_stay_off_jax(module):
     """A parent that starts the processes that hold the chip must not import
     JAX itself: one process per chip."""
@@ -223,6 +223,3 @@ def test_failed_builds_are_counted_in_the_program_inventory():
     assert failed["total"] == 1
     assert failed["last"]["key"] == repr(key)
     assert failed["last"]["error"] == "RuntimeError: vmem"
-    off = CompileWatch(enabled=False)
-    off.record_failure("decode", key, "bg", RuntimeError("x"))
-    assert off.debug_payload()["failed_builds"]["total"] == 0

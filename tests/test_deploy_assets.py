@@ -30,7 +30,7 @@ def test_chart_layout_and_plain_yaml_parse():
 
 
 def test_example_values_cover_baseline_configs_and_match_schema():
-    """BASELINE.md's target configs 1-5 each ship as an example values file
+    """The reference's target configs 1-5 each ship as an example values file
     that validates against values.schema.json."""
     import jsonschema
 
@@ -194,15 +194,14 @@ def test_structured_output_knob_maps_to_engine_flag():
 
 
 def test_compile_watch_knobs_map_to_engine_flags():
-    """helm modelSpec.compileWatch/compileStormThreshold/compileStormWindowS
-    must reach the engine as the --compile-* flags the server actually
-    parses, with defaults matching the chart's documented ones (docs/42)."""
+    """helm modelSpec.compileStormThreshold/compileStormWindowS must reach
+    the engine as the --compile-storm-* flags the server actually parses,
+    with defaults matching the chart's documented ones (docs/42). The watch
+    itself has no switch: not in the chart, not on the command line."""
     import jsonschema
 
     tpl = (REPO / "helm/templates/_helpers.tpl").read_text()
-    # on-by-default bool knob renders only when explicitly disabled
-    assert "{{- if eq (.compileWatch | default true) false }}" in tpl
-    assert '"--compile-watch"' in tpl
+    assert "compileWatch" not in tpl and '"--compile-watch"' not in tpl
     assert "{{- if .compileStormThreshold }}" in tpl
     assert '"--compile-storm-threshold"' in tpl
     assert "{{- if .compileStormWindowS }}" in tpl
@@ -210,20 +209,20 @@ def test_compile_watch_knobs_map_to_engine_flags():
     schema = json.loads((REPO / "helm/values.schema.json").read_text())
     model_props = schema["properties"]["servingEngineSpec"]["properties"][
         "modelSpec"]["items"]["properties"]
-    assert model_props["compileWatch"] == {"type": "boolean"}
+    assert "compileWatch" not in model_props
     assert model_props["compileStormThreshold"]["type"] == "integer"
     assert model_props["compileStormWindowS"]["type"] == "number"
     # the argparse surface agrees (keep in lockstep with server.py)
     from vllm_production_stack_tpu.engine.server import build_parser
 
     actions = {s: a for a in build_parser()._actions for s in a.option_strings}
-    assert actions["--compile-watch"].default is True
+    assert "--compile-watch" not in actions
     assert actions["--compile-storm-threshold"].default == 6
     assert actions["--compile-storm-window-s"].default == 300.0
     example = yaml.safe_load(
         (REPO / "helm/examples/values-42-compile-telemetry.yaml").read_text())
     spec = example["servingEngineSpec"]["modelSpec"][0]
-    assert spec["compileWatch"] is True
+    assert "compileWatch" not in spec
     assert spec["compileStormThreshold"] >= 1
     jsonschema.validate(example, schema)
     bad = json.loads(json.dumps(example))

@@ -37,7 +37,7 @@ IDENT_A = {"mesh_group": "pool-a", "process_index": 0, "process_count": 2}
 IDENT_B = {"mesh_group": "pool-a", "process_index": 1, "process_count": 2}
 
 
-def _engine(mode="auto", num_blocks=64, peer=True, async_scheduling=True,
+def _engine(mode="auto", num_blocks=64, peer=True,
             chunk_blocks=2, timeout_s=0.0, seed=0, transport="http",
             codec="none"):
     from vllm_production_stack_tpu.engine.engine import LLMEngine
@@ -58,7 +58,6 @@ def _engine(mode="auto", num_blocks=64, peer=True, async_scheduling=True,
         kv_hydration_timeout_s=timeout_s,
         kv_peer_fetch=peer,
         kv_peer_transport=transport,
-        async_scheduling=async_scheduling,
     ))
 
 

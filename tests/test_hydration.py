@@ -41,10 +41,11 @@ GREEDY = SamplingParams(max_tokens=4, temperature=0.0, ignore_eos=True)
 
 
 def _engine(mode="sync", num_blocks=32, disk_dir="", remote_url="",
-            chunk_blocks=2, timeout_s=0.0, async_scheduling=True, seed=0):
+            chunk_blocks=2, timeout_s=0.0, serial=False, seed=0):
     from vllm_production_stack_tpu.engine.engine import LLMEngine
+    from vllm_production_stack_tpu.testing.serial_loop import serial_engine
 
-    return LLMEngine(EngineConfig(
+    return (serial_engine if serial else LLMEngine)(EngineConfig(
         model=ModelConfig.tiny(),
         cache=CacheConfig(
             block_size=BS, num_blocks=num_blocks, num_host_blocks=4,
@@ -59,7 +60,6 @@ def _engine(mode="sync", num_blocks=32, disk_dir="", remote_url="",
         kv_hydration=mode,
         kv_hydration_chunk_blocks=chunk_blocks,
         kv_hydration_timeout_s=timeout_s,
-        async_scheduling=async_scheduling,
     ))
 
 
@@ -236,9 +236,9 @@ def test_serial_and_pipelined_streams_identical_with_hydration(tmp_path):
     prompt = _prompt(2)
     _seed_disk(tmp_path, prompt)
     streams = []
-    for async_sched in (False, True):
+    for serial in (True, False):
         eng = _engine(mode="planner", disk_dir=str(tmp_path),
-                      async_scheduling=async_sched)
+                      serial=serial)
         _warm_measured(eng)
         outs = eng.generate([prompt, _prompt(3)], GREEDY)
         streams.append([o["token_ids"] for o in outs])
@@ -465,24 +465,23 @@ def test_flow_meter_decision_unknown_choice_fails_loud():
         flow.record_decision("lod")
 
 
-def test_metering_off_keeps_bandwidth_estimators_alive():
-    """--kv-flow-metering false silences the METRIC side only: the
-    TierBandwidth estimators are the planner's decision input, and
-    starving them would silently disable compute-or-load (no tier could
-    ever cross the sample floor)."""
+def test_null_flow_is_a_complete_noop():
+    """A live meter feeds the metric side AND the TierBandwidth estimators
+    (the planner's decision input) from one record; the shared NULL_FLOW
+    singleton stays a COMPLETE no-op: unrelated standalone tiers must not
+    cross-pollinate each other's samples."""
     from vllm_production_stack_tpu.engine.kv_flow import NULL_FLOW
 
-    flow = KVFlowMeter(enabled=False)
+    flow = KVFlowMeter()
     flow.record("disk", "in", TierBandwidth.MIN_BYTES, 32, 0.01)
     flow.record("disk", "in", TierBandwidth.MIN_BYTES, 32, 0.01)
     snap = flow.snapshot()
-    assert snap["bytes"]["disk/in"] == 0  # metric side silenced
-    assert snap["bandwidth_bytes_per_s"]["disk/in"] > 0  # planner input on
+    assert snap["bytes"]["disk/in"] == 2 * TierBandwidth.MIN_BYTES
+    assert snap["bandwidth_bytes_per_s"]["disk/in"] > 0
     assert flow.bandwidth_measured()[("disk", "in")]
-    # the shared NULL_FLOW singleton stays a COMPLETE no-op: unrelated
-    # standalone tiers must not cross-pollinate each other's samples
     NULL_FLOW.record("disk", "in", TierBandwidth.MIN_BYTES, 32, 0.01)
     assert NULL_FLOW.bandwidth[("disk", "in")].samples == 0
+    assert NULL_FLOW.snapshot()["bytes"]["disk/in"] == 0
 
 
 def test_hydrator_rejects_bad_mode():

@@ -232,7 +232,7 @@ def test_unknown_dtype_name_is_kv_dtype_error():
 
 
 def test_flow_meter_logical_bytes_and_ratio():
-    flow = KVFlowMeter(enabled=True)
+    flow = KVFlowMeter()
     flow.record("remote", "in", 1000, 1, 0.01, logical_nbytes=3550)
     flow.record("remote", "in", 1000, 1, 0.01, logical_nbytes=3550)
     flow.record("disk", "out", 500, 1, 0.01)  # no codec: logical = wire
@@ -250,7 +250,7 @@ def test_flow_meter_logical_bytes_and_ratio():
 def test_disk_tier_stores_wire_bytes(tmp_path):
     from vllm_production_stack_tpu.engine.kv_disk_tier import DiskKVTier
 
-    flow = KVFlowMeter(enabled=True)
+    flow = KVFlowMeter()
     codec = KVAtRestCodec("int4", 32)
     tier = DiskKVTier(str(tmp_path), 1 << 20, fingerprint="fp",
                       flow=flow, codec=codec)

@@ -273,37 +273,6 @@ def test_flash_chunked_matches_direct_long_context():
     )
 
 
-@pytest.mark.parametrize("step_k", [0, 3])
-def test_hist_kernel_matches_xla_reference(step_k):
-    """The hoisted-history flash kernel (contiguous chunks instead of pool
-    pages) matches attention_with_hist, including zero-history rows."""
-    from vllm_production_stack_tpu.ops.attention import attention_with_hist
-    from vllm_production_stack_tpu.ops.paged_attention_pallas import (
-        hist_decode_attention,
-    )
-
-    rng = np.random.RandomState(3)
-    b, nh, kvh, d, s, w = 4, 8, 2, 64, 256, 4
-    q = jnp.asarray(rng.randn(b, nh, d), jnp.float32)
-    hk = jnp.asarray(rng.randn(b, s, kvh, d), jnp.float32)
-    hv = jnp.asarray(rng.randn(b, s, kvh, d), jnp.float32)
-    hist_len = jnp.asarray([s, 100, 0, 37], jnp.int32)
-    sk = jnp.asarray(rng.randn(w, b, kvh, d), jnp.float32)
-    sv = jnp.asarray(rng.randn(w, b, kvh, d), jnp.float32)
-    scale = d**-0.5
-
-    out = hist_decode_attention(
-        q, hk, hv, hist_len, sk, sv, jnp.int32(step_k), scale=scale,
-        interpret=True,
-    )
-    hist_mask = jnp.arange(s)[None, :] < hist_len[:, None]
-    staged_mask = jnp.arange(w) <= step_k
-    ref = attention_with_hist(
-        q[:, None], hk, hv, hist_mask, sk, sv, staged_mask, scale=scale
-    )[:, 0]
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
-
-
 def _prefill_setup(b=2, nb=4, bs=8, kvh=2, qpk=2, d=16, t=8, seed=5,
                    hists=(8, 13)):
     """Chunked-prefill scenario: each row has `hist` earlier tokens resident

@@ -45,11 +45,12 @@ BS = 8
 GREEDY = SamplingParams(max_tokens=4, temperature=0.0, ignore_eos=True)
 
 
-def _engine(mode="auto", num_blocks=64, peer=True, async_scheduling=True,
+def _engine(mode="auto", num_blocks=64, peer=True, serial=False,
             chunk_blocks=2, timeout_s=0.0, seed=0):
     from vllm_production_stack_tpu.engine.engine import LLMEngine
+    from vllm_production_stack_tpu.testing.serial_loop import serial_engine
 
-    return LLMEngine(EngineConfig(
+    return (serial_engine if serial else LLMEngine)(EngineConfig(
         model=ModelConfig.tiny(),
         cache=CacheConfig(
             block_size=BS, num_blocks=num_blocks, num_host_blocks=4,
@@ -63,7 +64,6 @@ def _engine(mode="auto", num_blocks=64, peer=True, async_scheduling=True,
         kv_hydration_chunk_blocks=chunk_blocks,
         kv_hydration_timeout_s=timeout_s,
         kv_peer_fetch=peer,
-        async_scheduling=async_scheduling,
     ))
 
 
@@ -472,10 +472,8 @@ def test_peer_hydration_bit_identical_and_partition_exact():
         loop = asyncio.get_running_loop()
         results = {}
         try:
-            for label, async_sched in (("pipelined", True), ("serial", False)):
-                eng_b = _engine(
-                    mode="planner", async_scheduling=async_sched
-                )
+            for label in ("pipelined", "serial"):
+                eng_b = _engine(mode="planner", serial=label == "serial")
                 assert eng_b.peer_tier is not None
                 _warm(eng_b)
 

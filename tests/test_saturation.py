@@ -107,18 +107,8 @@ def _sched_cfg(**kw):
     return SchedulerConfig(**base)
 
 
-def test_meter_disabled_is_noop():
-    m = StepMeter(ModelConfig.tiny(), _sched_cfg(), enabled=False)
-    m.record_decode(rows=4, window=4, accepted_tokens=16, sum_context=100)
-    m.record_prefill(rows=2, chunk_tokens=32, sum_context=500)
-    snap = m.snapshot()
-    assert snap["steps"] == {"prefill": 0, "decode": 0}
-    assert snap["model_flops_total"] == 0.0
-    assert snap["occupancy_hist"]["count"] == 0
-
-
 def test_meter_occupancy_and_padding_accounting():
-    m = StepMeter(ModelConfig.tiny(), _sched_cfg(), enabled=True)
+    m = StepMeter(ModelConfig.tiny(), _sched_cfg())
     # 6 rows of 8 seats → occupancy 0.75; decode bucket pads 6 → 8 rows
     m.record_decode(rows=6, window=4, accepted_tokens=20, sum_context=100)
     snap = m.snapshot()
@@ -143,7 +133,7 @@ def test_meter_gauges_decay_when_idle():
     """With no steps resolving, the EWMA gauges must fall toward 0 at
     READ time — a frozen last-busy occupancy would hold the KEDA
     occupancy trigger above threshold forever (no scale-in)."""
-    m = StepMeter(ModelConfig.tiny(), _sched_cfg(), enabled=True)
+    m = StepMeter(ModelConfig.tiny(), _sched_cfg())
     m.record_decode(rows=8, window=4, accepted_tokens=32, sum_context=100)
     time.sleep(0.01)
     m.record_decode(rows=8, window=4, accepted_tokens=32, sum_context=100)
@@ -159,7 +149,7 @@ def test_meter_padding_gauge_excludes_overshoot():
     """The padding EWMA measures bucket padding ONLY: a full-bucket
     dispatch whose rows all stopped mid-window has zero padding (the
     discards are the ledger's wasted{overshoot}, not a bucket problem)."""
-    m = StepMeter(ModelConfig.tiny(), _sched_cfg(), enabled=True)
+    m = StepMeter(ModelConfig.tiny(), _sched_cfg())
     time.sleep(0.001)
     m.record_decode(rows=8, window=4, accepted_tokens=8, sum_context=100)
     time.sleep(0.01)
@@ -404,12 +394,13 @@ def _flood(engine, rng_seed=3):
 
 
 def test_engine_ledger_balances_serial_and_pipelined():
-    for async_on in (False, True):
-        from vllm_production_stack_tpu.engine.engine import LLMEngine
+    from vllm_production_stack_tpu.engine.engine import LLMEngine
+    from vllm_production_stack_tpu.testing.serial_loop import serial_engine
 
+    for async_on in (False, True):
         eng = None
         try:
-            eng = LLMEngine(_engine_cfg().replace(async_scheduling=async_on))
+            eng = (LLMEngine if async_on else serial_engine)(_engine_cfg())
             _flood(eng)
             bal = assert_balanced(eng)
             assert bal["pending"] == 0

@@ -13,7 +13,9 @@ from vllm_production_stack_tpu.engine.config import (
 )
 from vllm_production_stack_tpu.engine.engine import LLMEngine
 from vllm_production_stack_tpu.engine.request import SamplingParams
+from vllm_production_stack_tpu.engine.scheduler import VerifyWork
 from vllm_production_stack_tpu.engine.spec_decode import propose_ngram
+from vllm_production_stack_tpu.testing.serial_loop import serial_engine
 
 
 def test_propose_ngram_basic():
@@ -36,7 +38,7 @@ def _build(
 ):
     cache = dict(block_size=8, num_blocks=64)
     cache.update(cache_kw)
-    return LLMEngine(
+    return (LLMEngine if async_on else serial_engine)(
         EngineConfig(
             model=model or ModelConfig.tiny(),
             cache=CacheConfig(**cache),
@@ -46,7 +48,6 @@ def _build(
                 decode_window=4, num_speculative_tokens=spec_k,
                 speculative_method=method, draft_model=draft,
             ),
-            async_scheduling=async_on,
         )
     )
 
@@ -181,19 +182,179 @@ def test_spec_sole_request_near_pool_exhaustion_finishes():
 
 
 # -- composition with the pipelined step loop (docs/36) ----------------------
+#
+# What a random tiny model emits decides nothing here: the proposer is
+# scripted from the plain engine's greedy stream, so which proposals are
+# accepted — and with them every chained verify, rollback and counter — is
+# what the test says it is.
+
+VOCAB = ModelConfig.tiny().vocab_size
+
+
+def _n_accept(pattern: str, base: int, k: int) -> int:
+    """How many leading tokens of the proposal that starts at sequence
+    index `base` are the true ones. A function of the position alone, so
+    both loops — and a re-proposal after a rollback — script the same
+    proposal for the same place in the stream."""
+    if pattern == "all":
+        return k
+    if pattern == "none":
+        return 0
+    if pattern == "first_j":
+        return k // 2
+    assert pattern == "alternating"  # k+1 positions accepted, k+1 refused
+    return k if (base // (k + 1)) % 2 == 0 else 0
+
+
+class ScriptedProposer:
+    """The scheduler's draft-proposer protocol (`propose_batch`, `release`),
+    answered from recorded greedy streams: the true next k tokens are
+    accepted whole, the true tokens with position j replaced are accepted up
+    to j. A chained row (`spec_tails`) continues after its in-flight
+    proposals AND the in-flight bonus token nobody knows yet."""
+
+    def __init__(self, truth: dict[tuple, list[int]], pattern: str):
+        self.truth = truth  # prompt -> prompt + greedy stream
+        self.pattern = pattern
+        self.chained_calls = 0
+
+    def propose_batch(self, reqs, k, spec_tails=None):
+        spec_tails = spec_tails or {}
+        out = {}
+        for req in reqs:
+            full = self.truth[tuple(req.prompt_token_ids)]
+            base = len(req.all_token_ids)
+            if req.request_id in spec_tails:
+                base += len(spec_tails[req.request_id]) + 1
+                self.chained_calls += 1
+            proposal = full[base:base + k]
+            assert len(proposal) == k, "recorded stream too short"
+            j = _n_accept(self.pattern, base, k)
+            if j < k:
+                proposal[j] = (proposal[j] + 1) % VOCAB
+            out[req.request_id] = proposal
+        return out
+
+    def release(self, rid):
+        pass
+
+
+def _script_implies(pattern, n_prompt, k, max_tokens):
+    """(proposed, accepted, rollbacks) of ONE greedy row under `pattern`:
+    the serial loop's verify steps, and on the pipelined loop the chained
+    steps a partial acceptance discards. A step is chained on a verify
+    unless full acceptance would already finish the row."""
+    proposed = accepted = rollbacks = 0
+    n_out = 1  # the prefill's token
+    while n_out < max_tokens:
+        j = _n_accept(pattern, n_prompt + n_out, k)
+        emitted = min(j + 1, max_tokens - n_out)
+        proposed += k
+        accepted += emitted - 1
+        if n_out + k + 1 < max_tokens and emitted < k + 1:
+            rollbacks += 1
+        n_out += emitted
+    return proposed, accepted, rollbacks
+
+
+def _record_truth(prompts, n_tokens):
+    """prompt -> prompt + the plain engine's greedy stream."""
+    plain = _build(0)
+    try:
+        greedy = SamplingParams(
+            max_tokens=n_tokens, temperature=0.0, ignore_eos=True
+        )
+        outs = plain.generate(prompts, greedy)
+    finally:
+        _shutdown(plain)
+    return {
+        tuple(p): list(p) + o["token_ids"] for p, o in zip(prompts, outs)
+    }
+
+
+def _true_stream(truth, prompt, n):
+    return truth[tuple(prompt)][len(prompt):][:n]
+
+
+def _spec_counters(eng):
+    sch = eng.scheduler
+    return (
+        sch.spec_proposed_tokens, sch.spec_accepted_tokens,
+        eng.timing["rollback_n"],
+    )
+
+
+SCRIPT_MAX_TOKENS = 24
+SCRIPT_PROMPT = [int(t) for t in np.random.RandomState(21).randint(1, 500, 9)]
+
+
+@pytest.fixture(scope="module")
+def scripted():
+    """The recorded stream of SCRIPT_PROMPT and, per k, a serial and a
+    pipelined speculative engine (built once: the cases swap proposers)."""
+    truth = _record_truth([SCRIPT_PROMPT], SCRIPT_MAX_TOKENS + 8)
+    engines: dict = {}
+
+    def get(k):
+        if k not in engines:
+            engines[k] = (_build(k, async_on=False), _build(k, async_on=True))
+        return engines[k]
+
+    yield truth, get
+    for pair in engines.values():
+        _shutdown(*pair)
+
+
+@pytest.mark.parametrize("pattern,k", [
+    (pattern, k)
+    for pattern in ("all", "none", "first_j", "alternating")
+    for k in (1, 3, 4)
+    if (pattern, k) != ("first_j", 1)  # first 0 of 1 is the `none` pattern
+])
+def test_scripted_acceptance_serial_pipelined_exact(scripted, pattern, k):
+    """Acceptance pattern x k on both loops: the stream is the plain
+    engine's, bitwise; proposed / accepted tokens are what the script
+    implies on BOTH loops; the pipelined loop chained verify on verify and
+    rolled back exactly the chained steps a partial acceptance invalidated."""
+    truth, get = scripted
+    serial, pipe = get(k)
+    greedy = [SamplingParams(
+        max_tokens=SCRIPT_MAX_TOKENS, temperature=0.0, ignore_eos=True
+    )]
+    want = _true_stream(truth, SCRIPT_PROMPT, SCRIPT_MAX_TOKENS)
+    proposed, accepted, rollbacks = _script_implies(
+        pattern, len(SCRIPT_PROMPT), k, SCRIPT_MAX_TOKENS
+    )
+    for eng in (serial, pipe):
+        proposer = ScriptedProposer(truth, pattern)
+        eng.scheduler.draft_proposer = proposer
+        before = _spec_counters(eng)
+        got = _streams(eng, [SCRIPT_PROMPT], greedy)
+        delta = tuple(
+            b - a for a, b in zip(before, _spec_counters(eng))
+        )
+        assert got == [want]
+        want_rollbacks = rollbacks if eng is pipe else 0
+        assert delta == (proposed, accepted, want_rollbacks), (
+            pattern, k, eng is pipe
+        )
+        assert proposed > 0
+        # the chained verify ran: the script was asked past an in-flight
+        # verify's proposals — on the pipelined loop only
+        assert (proposer.chained_calls > 0) == (eng is pipe)
+        bal = eng.goodput_balance()
+        assert bal["balanced"] and bal["pending"] == 0, bal
 
 
 def test_serial_pipelined_equivalence_with_speculation():
     """The PR 1 equivalence bar, speculation active: greedy AND seeded
     sampled rows in one batch must produce bitwise-identical streams on
     the serial and pipelined loops — verify dispatches are in-flight
-    pipeline work now, and a partial acceptance is just another rollback."""
+    pipeline work now, and a partial acceptance is just another rollback.
+    The scripted proposer fires for every greedy row on both loops."""
     rng = np.random.RandomState(7)
-    base = list(rng.randint(1, 500, size=6))
     prompts = [
-        base * 3,  # repetitive: proposals fire
-        list(rng.randint(1, 500, size=9)),
-        base * 2 + list(rng.randint(1, 500, size=4)),
+        [int(t) for t in rng.randint(1, 500, size=n)] for n in (18, 9, 16)
     ]
     sampling = [
         SamplingParams(max_tokens=18, temperature=0.0, ignore_eos=True),
@@ -202,80 +363,141 @@ def test_serial_pipelined_equivalence_with_speculation():
         ),
         SamplingParams(max_tokens=18, temperature=0.0, ignore_eos=True),
     ]
+    truth = _record_truth([prompts[0], prompts[2]], 18 + 8)
     serial = _build(3, async_on=False)
     pipe = _build(3, async_on=True)
     try:
+        for eng in (serial, pipe):
+            eng.scheduler.draft_proposer = ScriptedProposer(
+                truth, "alternating"
+            )
         s = _streams(serial, prompts, sampling)
         p = _streams(pipe, prompts, sampling)
         assert p == s
+        for i in (0, 2):  # speculation is lossless for the greedy rows
+            assert s[i] == _true_stream(truth, prompts[i], 18)
         # the pipeline actually pipelined (overlap accrued) and the spec
-        # path actually fired on both loops
+        # path actually fired on both loops, verify chained on verify
         assert pipe.timing["overlap_s"] > 0
         assert serial.scheduler.spec_proposed_tokens > 0
         assert pipe.scheduler.spec_proposed_tokens > 0
+        assert pipe.scheduler.draft_proposer.chained_calls > 0
+        # a row's verify steps start where its stream says, on either
+        # loop: the counters agree
+        assert _spec_counters(pipe)[:2] == _spec_counters(serial)[:2]
     finally:
         _shutdown(serial, pipe)
 
 
 def test_partial_acceptance_trims_inflight_chain():
-    """A decode window chained on top of an in-flight verify speculates
-    full acceptance; a partial acceptance at resolve time must discard it
+    """A verify chained on top of an in-flight verify speculates full
+    acceptance; a partial acceptance at resolve time must discard it
     (rollback_n) and re-dispatch — with the stream still bitwise equal to
-    the serial speculative loop. Random tiny-model weights make partial
-    acceptance the common case; scan a few prompt seeds for one that
-    provably hit it."""
+    the serial speculative loop. The script accepts the first 2 of every 4
+    proposed tokens, so every chained step is trimmed."""
     greedy = [SamplingParams(max_tokens=24, temperature=0.0, ignore_eos=True)]
-    hit = False
-    for seed in range(4):
-        rng = np.random.RandomState(100 + seed)
-        base = list(rng.randint(1, 500, size=5))
-        prompt = [base * 4]
-        serial = _build(4, async_on=False)
-        pipe = _build(4, async_on=True)
-        try:
-            want = _streams(serial, prompt, greedy)
-            got = _streams(pipe, prompt, greedy)
-            assert got == want
-            partial = (
-                serial.scheduler.spec_proposed_tokens
-                > serial.scheduler.spec_accepted_tokens
+    prompt = [int(t) for t in np.random.RandomState(100).randint(1, 500, 20)]
+    truth = _record_truth([prompt], 24 + 8)
+    serial = _build(4, async_on=False)
+    pipe = _build(4, async_on=True)
+    try:
+        for eng in (serial, pipe):
+            eng.scheduler.draft_proposer = ScriptedProposer(truth, "first_j")
+        want = _streams(serial, [prompt], greedy)
+        got = _streams(pipe, [prompt], greedy)
+        assert got == want == [_true_stream(truth, prompt, 24)]
+        proposed, accepted, rollbacks = _script_implies(
+            "first_j", len(prompt), 4, 24
+        )
+        assert proposed > accepted > 0 and rollbacks > 0
+        assert _spec_counters(serial) == (proposed, accepted, 0)
+        assert _spec_counters(pipe) == (proposed, accepted, rollbacks)
+        assert pipe.scheduler.draft_proposer.chained_calls > 0
+    finally:
+        _shutdown(serial, pipe)
+
+
+def test_abort_while_chained_verify_in_flight():
+    """abort_request lands while a verify chained on a verify is executing:
+    the aborted row's in-flight tokens are pipeline waste, the other row's
+    stream stays the plain engine's, and the ledger still balances."""
+    rng = np.random.RandomState(31)
+    prompts = [
+        [int(t) for t in rng.randint(1, 500, size=n)] for n in (12, 10)
+    ]
+    greedy = SamplingParams(max_tokens=24, temperature=0.0, ignore_eos=True)
+    truth = _record_truth(prompts, 24 + 8)
+    pipe = _build(3, async_on=True)
+    try:
+        pipe.scheduler.draft_proposer = ScriptedProposer(truth, "all")
+        doomed, kept = (
+            pipe.add_request(prompt_token_ids=p, sampling=greedy)
+            for p in prompts
+        )
+        got: list[int] = []
+
+        def step():
+            for out in pipe.step():
+                if out.request_id == kept:
+                    got.extend(out.new_token_ids)
+
+        def chained_in_flight():
+            work = pipe._inflight.work if pipe._inflight else None
+            return isinstance(work, VerifyWork) and any(
+                src >= 0 and req.request_id == doomed
+                for src, req in zip(work.chain_rows, work.requests)
             )
-            if partial and pipe.timing["rollback_n"] > 0:
-                hit = True
-        finally:
-            _shutdown(serial, pipe)
-        if hit:
-            break
-    assert hit, "no prompt produced a partial acceptance with a chained step"
+
+        while not chained_in_flight():
+            assert pipe.has_unfinished(), "no verify ever chained"
+            step()
+        pipe.abort_request(doomed)
+        while pipe.has_unfinished():
+            step()
+        assert got == _true_stream(truth, prompts[1], 24)
+        bal = pipe.goodput_balance()
+        assert bal["balanced"] and bal["pending"] == 0, bal
+        assert bal["wasted"]["rollback"] > 0  # the aborted row's verify
+    finally:
+        _shutdown(pipe)
 
 
 def test_ledger_exact_with_rejections_on_both_loops():
     """GoodputLedger partition exactness at quiescence with speculative
     rejections charged as wasted{rollback} — on the serial AND pipelined
-    loops, n-gram and draft proposers both."""
+    loops, with a scripted proposer whose every proposal is refused from
+    its second token on, and with the draft-model proposer."""
     rng = np.random.RandomState(11)
-    base = list(rng.randint(1, 500, size=6))
-    prompts = [base * 3, list(rng.randint(1, 500, size=8))]
+    prompts = [
+        [int(t) for t in rng.randint(1, 500, size=n)] for n in (18, 8)
+    ]
     greedy = SamplingParams(max_tokens=16, temperature=0.0, ignore_eos=True)
+    truth = _record_truth(prompts, 16 + 8)
     # the draft deliberately DIFFERS from the target (3 layers vs 2) so
     # draft rejections actually occur
     target = ModelConfig.tiny(num_layers=3)
     for async_on in (False, True):
         for method, draft, model in (
-            ("ngram", "", None),
+            ("scripted", "", None),
             ("draft", "tiny-llama", target),
         ):
             eng = _build(
-                3, async_on=async_on, method=method, draft=draft, model=model
+                3, async_on=async_on, draft=draft, model=model,
+                method="draft" if draft else "ngram",
             )
+            if method == "scripted":
+                eng.scheduler.draft_proposer = ScriptedProposer(
+                    truth, "first_j"
+                )
             try:
                 eng.generate(prompts, greedy)
                 bal = eng.goodput_balance()
                 assert bal["balanced"], (method, async_on, bal)
                 assert bal["pending"] == 0
-                if eng.scheduler.spec_proposed_tokens > (
-                    eng.scheduler.spec_accepted_tokens
-                ):
+                sch = eng.scheduler
+                if method == "scripted":
+                    assert sch.spec_proposed_tokens > sch.spec_accepted_tokens
+                if sch.spec_proposed_tokens > sch.spec_accepted_tokens:
                     assert bal["wasted"]["rollback"] > 0
             finally:
                 _shutdown(eng)

@@ -21,10 +21,10 @@ from vllm_production_stack_tpu.engine.flightrec import (
     ThreadRegistry,
 )
 from vllm_production_stack_tpu.engine.request import SamplingParams
+from vllm_production_stack_tpu.testing.serial_loop import serial_engine
 
 # every key /debug/timing's `engine` group served before this timeline
-# existed (bench.py, bench_northstar.py, the chip benchmark's readers and
-# the tests read them)
+# existed (the chip benchmark's readers and the tests read them)
 PARENT_KEYS = {
     "sched_s", "post_s", "prefill_s", "prefill_n", "prefill_tokens",
     "decode_s", "decode_n", "decode_tokens", "dispatch_s", "sync_s",
@@ -37,8 +37,8 @@ PREFILL_SCOPES = LAYER_SCOPES | {"embed", "final_norm", "lm_head", "sample"}
 DECODE_SCOPES = PREFILL_SCOPES | {"kv_commit", "gather_pages"}
 
 
-def _engine(**kw) -> LLMEngine:
-    return LLMEngine(EngineConfig.tiny().replace(**kw))
+def _engine() -> LLMEngine:
+    return LLMEngine(EngineConfig.tiny())
 
 
 def _greedy(n: int) -> SamplingParams:
@@ -49,8 +49,8 @@ def _greedy(n: int) -> SamplingParams:
 def ran():
     """A pipelined and a serial engine after the same small workload."""
     out = {}
-    for name, on in (("pipelined", True), ("serial", False)):
-        eng = _engine(async_scheduling=on)
+    for name, make in (("pipelined", LLMEngine), ("serial", serial_engine)):
+        eng = make(EngineConfig.tiny())
         eng.generate([[1, 2, 3, 4, 5], list(range(7, 30))], _greedy(24))
         out[name] = eng
     return out
@@ -97,7 +97,7 @@ def _delta(eng, before):
 def test_prefill_drains_the_pipeline_and_chained_decodes_do_not():
     """decode -> decode -> prefill -> decode: exactly one gap, after the
     prefill, and it sits on the flight record of the decode that ended it."""
-    eng = _engine(async_scheduling=True)
+    eng = _engine()
     eng.add_request("a", prompt_token_ids=[1, 2, 3, 4, 5, 6],
                     sampling=_greedy(200))
     while eng.timing["decode_n"] < 2:  # a's prefill, then chained windows
@@ -172,7 +172,7 @@ def test_engine_rollback_opens_no_gap():
             stop_at = cand[0]
             break
     assert stop_at is not None, "no non-degenerate greedy stream found"
-    eng = _engine(async_scheduling=True)
+    eng = _engine()
     eng.add_request("long", prompt_token_ids=[1, 2, 3, 4],
                     sampling=_greedy(64))
     eng.add_request("short", prompt_token_ids=short,
@@ -186,7 +186,7 @@ def test_engine_rollback_opens_no_gap():
 
 
 def test_quiet_loop_counts_a_trailing_rollback_as_emptied():
-    rec = FlightRecorder(enabled=False)
+    rec = FlightRecorder()
     a = rec.dispatch("decode", rows=1, tokens=4)
     b = rec.dispatch("decode", rows=1, tokens=4)
     rec.resolve(a)
@@ -198,21 +198,11 @@ def test_quiet_loop_counts_a_trailing_rollback_as_emptied():
     t = rec.timing()
     assert t["starved_after_decode_n"] == 1
     assert t["starved_idle_s"] > 0 and t["starved_idle_s"] == t["starved_s"]
-
-
-def test_recording_off_keeps_counters_and_watchdog_cursor():
-    eng = _engine(flight_recording=False)
-    eng.generate([[1, 2, 3, 4, 5]], _greedy(12))
-    t = eng.timing
-    assert t["dispatch_n"] > 0 and t["starved_n"] > 0 and t["starved_s"] > 0
-    assert all(t[p + "_s"] > 0 for p in STEP_PHASES)
-    assert eng.flightrec.snapshot() == [] and not eng.flightrec._stamps
-    rec = eng.flightrec
-    rec.dispatch("decode", rows=1, tokens=4)
-    assert rec.outstanding_age_s()[1] == "decode"
+    # the watchdog's liveness cursor: the rolled-back step left it, the
+    # prefill nobody resolved is the stall candidate
+    assert rec.outstanding_age_s()[1] == "prefill"
     dog = Watchdog(ThreadRegistry(), rec, stall_after_s=-1.0)
-    report = dog.check()
-    assert report["findings"][0]["kind"] == "unresolved_step"
+    assert dog.check()["findings"][0]["kind"] == "unresolved_step"
 
 
 def test_async_loop_phases_and_loop_group():

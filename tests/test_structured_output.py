@@ -19,6 +19,7 @@ from vllm_production_stack_tpu.engine.config import (
 from vllm_production_stack_tpu.engine.engine import LLMEngine
 from vllm_production_stack_tpu.engine.grammar import GrammarCache, GrammarState
 from vllm_production_stack_tpu.engine.request import SamplingParams
+from vllm_production_stack_tpu.testing.serial_loop import serial_engine
 
 # enum/boolean-heavy: a RANDOM model's constrained walk terminates fast
 # (no open-ended strings or unbounded digit runs to wander in)
@@ -36,7 +37,7 @@ SPEC = {"kind": "json_schema", "schema": SCHEMA}
 def _build(async_on=True, spec_k=0):
     # minimal bucket ladders: every extra bucket is another background
     # XLA compile the engine's shutdown(wait=True) has to wait out
-    return LLMEngine(EngineConfig(
+    return (LLMEngine if async_on else serial_engine)(EngineConfig(
         model=ModelConfig.tiny(),
         cache=CacheConfig(block_size=8, num_blocks=64),
         scheduler=SchedulerConfig(
@@ -44,7 +45,6 @@ def _build(async_on=True, spec_k=0):
             decode_buckets=(4,), prefill_buckets=(16, 32),
             decode_window=4, num_speculative_tokens=spec_k,
         ),
-        async_scheduling=async_on,
     ))
 
 

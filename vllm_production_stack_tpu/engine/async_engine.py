@@ -101,9 +101,7 @@ class AsyncEngine:
         # the step thread's timeline (flightrec.PHASES): this loop's admit,
         # emit and idle phases land beside the engine's own. A test double
         # without a flight recorder gets a private one.
-        self._clock = getattr(engine, "flightrec", None) or FlightRecorder(
-            enabled=False
-        )
+        self._clock = getattr(engine, "flightrec", None) or FlightRecorder()
         # the asyncio side of /debug/timing's `loop` group:
         # tokenize+validate+queue time of submissions
         self.loop_timing = {"submits": 0, "submit_s": 0.0}

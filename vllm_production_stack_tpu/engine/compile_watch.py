@@ -76,21 +76,16 @@ class CompileWatch:
     One instance is shared by the target runner and (when spec decode
     runs a draft model) the PR 14 draft runner — entries carry a
     ``role`` tag so ``/debug/programs`` tells the two caches apart.
-    ``enabled=False`` turns every method into a cheap early return
-    (the ``--compile-watch false`` path; bench pins the overhead of
-    the *enabled* path at the noise floor too).
     """
 
     def __init__(
         self,
-        enabled: bool = True,
         storm_threshold: int = 6,
         storm_window_s: float = 300.0,
         capacity: int = DEFAULT_CAPACITY,
         recorder=None,
         clock=time.monotonic,
     ):
-        self.enabled = bool(enabled)
         self.storm_threshold = int(storm_threshold)
         self.storm_window_s = float(storm_window_s)
         self.capacity = int(capacity)
@@ -128,8 +123,6 @@ class CompileWatch:
         memory_bytes: int | None = None,
     ) -> None:
         """One program (or grammar-table) build landed."""
-        if not self.enabled:
-            return
         now = self._clock()
         key_str = _key_str(key)
         with self._lock:
@@ -190,8 +183,6 @@ class CompileWatch:
         *, role: str = "target",
     ) -> None:
         """A program build raised instead of landing."""
-        if not self.enabled:
-            return
         with self._lock:
             self.failed_builds += 1
             self.last_failure = {
@@ -206,8 +197,6 @@ class CompileWatch:
                         role: str = "target") -> None:
         """A dispatch was served: ``hit`` means the EXACT requested key
         was already compiled (no fallback, no sync compile)."""
-        if not self.enabled:
-            return
         with self._lock:
             if hit:
                 self.cache_hits += 1
@@ -270,12 +259,9 @@ class CompileWatch:
         """Per-stats()-call snapshot for EngineStatsSnapshot.compile.
         Drains the pending wall-clock list (the grammar_build_times
         idiom — each observation is exported exactly once)."""
-        if not self.enabled:
-            return {"enabled": False}
         with self._lock:
             walls, self._pending_walls = self._pending_walls, []
             return {
-                "enabled": True,
                 "programs": len(self._inventory),
                 "compiles": dict(self.compiles),
                 "hits": self.cache_hits,
@@ -307,7 +293,6 @@ class CompileWatch:
                 for e in self._inventory.values()
             ]
             return {
-                "enabled": self.enabled,
                 "programs": programs,
                 "capacity": self.capacity,
                 "compiles": dict(self.compiles),

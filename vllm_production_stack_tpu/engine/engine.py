@@ -1,7 +1,7 @@
 """LLMEngine: the synchronous serving core (scheduler + runner + detokenize).
 
 The OpenAI HTTP layer (engine/server.py) drives `step()` from a background
-loop; offline use (bench.py, tests) drives it directly. This composes the
+loop; offline use (chip_smoke.py, tests) drives it directly. This composes the
 pieces the reference gets from vLLM images, exporting the stats the router's
 scraper contract expects (SURVEY §5 metrics contract).
 """
@@ -208,12 +208,10 @@ class LLMEngine:
         # KV flow meter (docs/30-kv-flow-telemetry.md): ONE instance shared
         # by every tier object — host ring, disk tier, remote client,
         # device-path PD transfer — plus the scheduler's hydration
-        # attribution. Transfer metering is togglable
-        # (--kv-flow-metering false); the hydration partition counters are
-        # contract series and stay on, like the goodput ledger.
+        # attribution.
         from .kv_flow import KVFlowMeter
 
-        self.flow = KVFlowMeter(enabled=config.kv_flow_metering)
+        self.flow = KVFlowMeter()
         # flight recorder + thread-liveness registry (docs/37-flight-
         # recorder.md): created BEFORE every tier/thread owner so each
         # long-lived loop (remote writer, hydration fetcher, step thread,
@@ -224,9 +222,7 @@ class LLMEngine:
             ThreadRegistry,
         )
 
-        self.flightrec = FlightRecorder(
-            capacity=config.flight_records, enabled=config.flight_recording
-        )
+        self.flightrec = FlightRecorder(capacity=config.flight_records)
         self.threads = ThreadRegistry()
         # the runner's build/h2d/enqueue/sync spans land on the same timeline
         self.runner.clock = self.flightrec
@@ -479,7 +475,6 @@ class LLMEngine:
         from .compile_watch import CompileWatch
 
         self.compile_watch = CompileWatch(
-            enabled=config.compile_watch,
             storm_threshold=config.compile_storm_threshold,
             storm_window_s=config.compile_storm_window_s,
             recorder=self.flightrec,
@@ -500,25 +495,19 @@ class LLMEngine:
         # mid-decode deadline expiry separately — stats() sums them)
         self.shed_requests = 0
         self.deadline_admission_rejects = 0
-        # two-deep pipelined step loop (config.async_scheduling): dispatch
-        # step N+1 against speculatively-advanced scheduler state before
-        # step N's tokens reach the host. Speculative decoding COMPOSES
-        # with it (docs/36-speculative-decoding.md): verify dispatches are
+        # two-deep pipelined step loop: dispatch step N+1 against
+        # speculatively-advanced scheduler state before step N's tokens
+        # reach the host. Speculative decoding COMPOSES with it
+        # (docs/36-speculative-decoding.md): verify dispatches are
         # in-flight work like decode windows, a verify CHAINS on an
         # in-flight verify (its fed proposals are host-known under full
         # acceptance; only the bonus token is spliced device-side), and a
         # partial acceptance is just another rollback.
-        self._pipeline = config.async_scheduling
         self._inflight: _InflightStep | None = None
         # saturation telemetry (docs/29-saturation-slo.md): per-resolved-
         # step occupancy / padding / MFU accounting. The goodput LEDGER
-        # lives on the scheduler (always on — its counters are part of the
-        # metric contract); the meter is the togglable part
-        # (--step-metering false) whose cost the bench's saturation phase
-        # measures.
-        self.meter = StepMeter(
-            config.model, config.scheduler, enabled=config.step_metering
-        )
+        # lives on the scheduler.
+        self.meter = StepMeter(config.model, config.scheduler)
         # structured output (docs/41-structured-output.md): compiled-
         # grammar LRU, lazily built on the first constrained request (the
         # engine is the only layer holding both tokenizer and model vocab
@@ -1235,24 +1224,19 @@ class LLMEngine:
     def step(self) -> list[RequestOutput]:
         """Schedule + execute one device step; returns per-request deltas.
 
-        With async_scheduling (the default) this drives a TWO-DEEP
-        PIPELINE: the step dispatched on the previous call is still
-        executing on device while this call schedules and dispatches the
-        next one against speculatively-advanced request state — decode
-        inputs chain device-side from the in-flight step's output matrix —
-        and only then resolves the previous step (one batched D2H sync),
-        postprocesses it, and reconciles. When the reconcile shows the
-        speculation was wrong (a mid-window stop token, max-tokens finish,
-        stop-string hit, or abort), the just-dispatched step is discarded
-        and rolled back, so the emitted token streams are bitwise identical
-        to the serial loop. Outputs returned by one call therefore belong
-        to the step dispatched on the PREVIOUS call (one step of latency,
-        ~2x decode throughput when host and device times are comparable)."""
-        if not self._pipeline:
-            return self._step_sync()
-        return self._step_pipelined()
-
-    def _step_pipelined(self) -> list[RequestOutput]:
+        This drives a TWO-DEEP PIPELINE: the step dispatched on the
+        previous call is still executing on device while this call
+        schedules and dispatches the next one against speculatively-advanced
+        request state — decode inputs chain device-side from the in-flight
+        step's output matrix — and only then resolves the previous step
+        (one batched D2H sync), postprocesses it, and reconciles. When the
+        reconcile shows the speculation was wrong (a mid-window stop token,
+        max-tokens finish, stop-string hit, or abort), the just-dispatched
+        step is discarded and rolled back, so the emitted token streams are
+        bitwise identical to the serial reference loop's
+        (testing/serial_loop.py). Outputs returned by one call therefore
+        belong to the step dispatched on the PREVIOUS call (one step of
+        latency)."""
         outputs: list[RequestOutput] = []
         inflight, self._inflight = self._inflight, None
         try:
@@ -1416,20 +1400,6 @@ class LLMEngine:
         with rec.span("post"):
             self._drop_finished(outputs)
 
-    def _step_sync(self) -> list[RequestOutput]:
-        """The serial fallback loop: schedule → execute → sync →
-        postprocess, one step per call (async_scheduling=False)."""
-        outputs: list[RequestOutput] = []
-        with self.flightrec.wall("step_wall_s"):
-            work = self._schedule(outputs)
-            if work is not None:
-                self._execute_sync(work, outputs)
-            elif self.scheduler.hydration_parked():
-                time.sleep(0.001)  # see the pipelined loop's parked note
-            with self.flightrec.span("post"):
-                self._drop_finished(outputs)
-        return outputs
-
     # -- flight recorder (docs/37-flight-recorder.md) ----------------------
 
     def _rec_dispatch(self, work, kind: str) -> int:
@@ -1437,8 +1407,7 @@ class LLMEngine:
         the scheduler's decision summary, and queue/pool depths — what the
         engine was doing right before it (maybe) stopped doing anything.
         Also advances the dispatch/resolve cursor the watchdog's
-        unresolved-step detection and the starvation accounting key off
-        (tracked even with recording disabled)."""
+        unresolved-step detection and the starvation accounting key off."""
         sched = self.scheduler
         if kind == "decode":
             window = work.window
@@ -1446,18 +1415,10 @@ class LLMEngine:
         else:
             window = 0
             tokens = sum(len(t) for t in work.token_ids)
-        if self.flightrec.enabled:
-            # the O(batch) queue/pool summary is only worth computing
-            # when a record will actually be written; the liveness
-            # cursor needs none of it
-            waiting, running = sched.num_waiting, sched.num_running
-            pool_usage = sched.pool.usage_perc
-        else:
-            waiting = running = 0
-            pool_usage = 0.0
         return self.flightrec.dispatch(
             kind, rows=len(work.requests), tokens=tokens, window=window,
-            waiting=waiting, running=running, pool_usage=pool_usage,
+            waiting=sched.num_waiting, running=sched.num_running,
+            pool_usage=sched.pool.usage_perc,
         )
 
     # -- saturation & goodput telemetry (docs/29-saturation-slo.md) --------
@@ -1477,8 +1438,6 @@ class LLMEngine:
         """Record one resolved decode/verify dispatch with the meter. The
         context sum feeds the attention term of the FLOP estimate: row i's
         window tokens attend ~positions[i] + j each."""
-        if not self.meter.enabled:
-            return
         if isinstance(work, VerifyWork):
             rows = len(work.requests)
             fed = sum(len(t) for t in work.token_ids)
@@ -1501,8 +1460,6 @@ class LLMEngine:
         )
 
     def _meter_prefill(self, work: PrefillWork) -> None:
-        if not self.meter.enabled:
-            return
         chunk_tokens = sum(len(t) for t in work.token_ids)
         # each chunk token attends ~its absolute position: per row the sum
         # over [start, end) is len × (start + end) / 2
@@ -1544,8 +1501,7 @@ class LLMEngine:
         """Ledger balance check (delegates to the scheduler — the single
         definition of "live requests"): sampled == delivered + wasted +
         pending. At quiescence pending is 0, so delivered + wasted ==
-        sampled EXACTLY — tests and the bench's saturation phase assert
-        `balanced`."""
+        sampled EXACTLY — the tests assert `balanced`."""
         return self.scheduler.goodput_balance()
 
     def hydration_signal(self) -> dict:

@@ -14,10 +14,8 @@ and dump what it was doing. Four pieces:
 - :class:`FlightRecorder` — a bounded, lock-light ring of structured
   step records appended from the step loop (dispatch/resolve sequence,
   batch shape and phase, scheduler decision summary, queue/pool depths,
-  rollback and fault markers). Same noise-floor bar as the StepMeter
-  (≤~2% p50, measured by the bench's ``blackbox`` phase). The last N
-  records are the black box: what the engine was doing right before it
-  stopped doing anything.
+  rollback and fault markers). The last N records are the black box:
+  what the engine was doing right before it stopped doing anything.
 
 - :class:`ThreadRegistry` / :class:`Heartbeat` — every long-lived loop
   in the process beats a heartbeat: the step thread, the hydration
@@ -40,8 +38,7 @@ and dump what it was doing. Four pieces:
   fingerprint, timing/hydration snapshots, env) written to
   ``--postmortem-dir`` on watchdog trip, SIGQUIT, and fatal step-thread
   exceptions; served live at ``GET /debug/flight`` and on demand via
-  ``POST /debug/postmortem``. bench.py's preflight watchdog writes the
-  same artifact, so the r04/r05 chip wedge finally leaves a file behind.
+  ``POST /debug/postmortem``.
 
 :class:`EventLoopLagProbe` rides along for the asyncio processes (router
 and KV controller): a starved event loop serves nothing while every
@@ -176,8 +173,7 @@ class _Span:
         rec._cur = self.outer
         if self.outer is not None:
             self.outer.t_run = now
-        if rec.enabled:
-            rec._stamps.append((self.phase, self.t0, now, self.seq))
+        rec._stamps.append((self.phase, self.t0, now, self.seq))
         return False
 
 
@@ -189,14 +185,12 @@ class FlightRecorder:
     Appended from the step thread; snapshotted by the watchdog, the
     postmortem dumper, and GET /debug/flight. One small lock guards the
     ring (an append is a dict build + deque append — microseconds against
-    a millisecond-scale step). The dispatch/resolve cursor, the totals and
-    the starvation counters are kept even when recording is disabled: the
-    watchdog's unresolved-step detection and /debug/timing must survive
-    ``--flight-recording false``; only the ring and its stamps follow it.
+    a millisecond-scale step). The dispatch/resolve cursor is what the
+    watchdog's unresolved-step detection reads; the totals and the
+    starvation counters are what /debug/timing serves.
     """
 
-    def __init__(self, capacity: int = DEFAULT_CAPACITY, enabled: bool = True):
-        self.enabled = enabled
+    def __init__(self, capacity: int = DEFAULT_CAPACITY):
         self.capacity = capacity
         self._lock = threading.Lock()
         self._ring: deque[dict] = deque(maxlen=capacity)
@@ -308,8 +302,6 @@ class FlightRecorder:
     # -- step-loop recording (step thread) ---------------------------------
 
     def _append(self, event: str, fields: dict) -> None:
-        if not self.enabled:
-            return
         fields["event"] = event
         fields["t"] = time.time()
         fields["mono_ns"] = time.monotonic_ns()
@@ -340,13 +332,12 @@ class FlightRecorder:
             self._open[seq] = (now * 1e-9, kind)
         self._tot["dispatch_n"] += 1
         gap = self._flip_gap(now, None)
-        if self.enabled:
-            self._append("dispatch", {
-                "seq": seq, "kind": kind, "rows": rows, "tokens": tokens,
-                "window": window, "waiting": waiting, "running": running,
-                "pool_usage": round(pool_usage, 4),
-                "gap": gap, "phases": self._take_stamps(),
-            })
+        self._append("dispatch", {
+            "seq": seq, "kind": kind, "rows": rows, "tokens": tokens,
+            "window": window, "waiting": waiting, "running": running,
+            "pool_usage": round(pool_usage, 4),
+            "gap": gap, "phases": self._take_stamps(),
+        })
         return seq
 
     def resolve(self, seq: int) -> None:
@@ -362,10 +353,9 @@ class FlightRecorder:
             emptied = self._done >= self._seq
         if emptied:
             self._flip_gap(now, kind)
-        if self.enabled:
-            self._append("resolve", {
-                "seq": seq, "phases": self._take_stamps(),
-            })
+        self._append("resolve", {
+            "seq": seq, "phases": self._take_stamps(),
+        })
 
     def discard(self, seq: int) -> None:
         """A dispatched pipeline step was rolled back (speculation

@@ -290,8 +290,8 @@ class Hydrator:
       estimator past its sample floor, so auto self-bootstraps.
     - ``planner``: always plan; unmeasured tiers are recomputed
       (the sample-floor rule), never synchronously loaded.
-    - ``off``: ignore disk/remote residency entirely (recompute) — the
-      bench's compute-only arm and an operator kill switch.
+    - ``off``: ignore disk/remote residency entirely (recompute) — an
+      operator kill switch.
 
     The fetcher is one daemon thread: chunk loads are bandwidth-bound
     (disk IO / one HTTP mget per remote span), so a second thread would

@@ -10,18 +10,18 @@ FLOP/s.
 
 Two instruments, mirroring the PR 6 StepMeter/GoodputLedger split:
 
-- **Transfer meters** (togglable, ``--kv-flow-metering false``): every
-  tier move — host-ring offload/reload, disk store/load, remote
-  put/fetch, device-path PD transfer — records bytes, blocks and wall
-  latency into per-(tier, direction) counters, a fixed-bucket latency
-  histogram, and a :class:`TierBandwidth` recent-mean estimator. Plain
+- **Transfer meters**: every tier move — host-ring offload/reload,
+  disk store/load, remote put/fetch, device-path PD transfer — records
+  bytes, blocks and wall latency into per-(tier, direction) counters, a
+  fixed-bucket latency histogram, and a :class:`TierBandwidth`
+  recent-mean estimator. Plain
   ints under one small lock (transfers are orders of magnitude rarer
   than steps); the exporter renders histograms from cumulative bucket
   counts at scrape time, so no prometheus objects ride the engine or
   writer threads.
 
-- **Hydration attribution** (always on, like the goodput ledger — its
-  counters are part of the metric contract): every admitted request's
+- **Hydration attribution** (its counters are part of the metric
+  contract, like the goodput ledger): every admitted request's
   prompt tokens are classified EXACTLY once by KV origin, and the
   partition is audited::
 
@@ -133,15 +133,10 @@ class KVFlowMeter:
     One instance per engine, shared by every tier object (host ring,
     disk tier, remote client, device-path transfer) — the engine thread,
     the remote writer thread and HTTP executor threads all record here,
-    so mutation happens under ``_lock``. ``enabled=False`` turns
-    :meth:`record` into a no-op (the bench's ``kvflow`` phase measures
-    the difference); hydration attribution stays on regardless, because
-    its counters are contract series the dashboard's hydration panel
-    keys off (same always-on rule as the goodput ledger).
+    so mutation happens under ``_lock``.
     """
 
-    def __init__(self, enabled: bool = True, _null: bool = False):
-        self.enabled = enabled
+    def __init__(self, _null: bool = False):
         # NULL_FLOW only: a complete no-op, bandwidth estimators included
         # (the singleton is shared by UNRELATED standalone tier objects —
         # cross-polluting their bandwidth samples would fabricate a
@@ -179,7 +174,7 @@ class KVFlowMeter:
             c: 0 for c in mc.KV_HYDRATION_CHOICES
         }
 
-    # -- transfer meters (togglable) ----------------------------------------
+    # -- transfer meters ---------------------------------------------------
 
     def record(
         self, tier: str, direction: str, nbytes: int, blocks: int,
@@ -195,14 +190,7 @@ class KVFlowMeter:
         compression-ratio gauge. A FAILED transfer should still be
         recorded with whatever partial batch completed (possibly 0 bytes)
         — the elapsed time is real, and losing it would overstate the
-        tier's bandwidth exactly when the planner most needs the truth.
-
-        ``enabled=False`` silences the METRIC side (bytes/blocks/latency
-        counters) but the TierBandwidth estimators keep recording: they
-        are the hydration planner's decision input, and starving them
-        would silently disable compute-or-load (no tier could ever cross
-        the sample floor). Their cost is a dict lookup + a few float ops
-        per transfer — nothing next to the transfer itself."""
+        tier's bandwidth exactly when the planner most needs the truth."""
         if self._null:
             self.bandwidth[(tier, direction)]  # unknown key: still loud
             return
@@ -210,8 +198,6 @@ class KVFlowMeter:
         now = time.perf_counter()
         with self._lock:
             self.bandwidth[key].record(int(nbytes), seconds, now)
-            if not self.enabled:
-                return
             self.bytes[key] += int(nbytes)
             self.logical_bytes[key] += int(
                 nbytes if logical_nbytes is None else logical_nbytes
@@ -220,7 +206,7 @@ class KVFlowMeter:
             self.transfers[key] += 1
             self.seconds[key].observe(seconds)
 
-    # -- hydration attribution (always on) ----------------------------------
+    # -- hydration attribution ---------------------------------------------
 
     def record_hydration(
         self, counts: dict[str, int], requests: int = 1
@@ -263,7 +249,6 @@ class KVFlowMeter:
         shape EngineMetrics renders (keys are "tier/direction")."""
         with self._lock:
             return {
-                "enabled": self.enabled,
                 "bytes": {f"{t}/{d}": v for (t, d), v in self.bytes.items()},
                 "logical_bytes": {
                     f"{t}/{d}": v
@@ -307,6 +292,6 @@ class KVFlowMeter:
             }
 
 
-# Shared disabled singleton for tier objects constructed without an engine
+# Shared no-op singleton for tier objects constructed without an engine
 # (unit tests, standalone tools): call sites never branch on `if flow:`.
-NULL_FLOW = KVFlowMeter(enabled=False, _null=True)
+NULL_FLOW = KVFlowMeter(_null=True)

@@ -719,7 +719,7 @@ class EngineMetrics:
             self.grammar_build_time.labels(**lb).observe(seconds)
         # -- XLA compile telemetry (docs/42-compile-telemetry.md) ----------
         comp = s.compile or {}
-        if comp.get("enabled"):
+        if comp:
             self.program_cache_programs.labels(**lb).set(
                 int(comp.get("programs", 0))
             )

@@ -366,7 +366,7 @@ class ModelRunner:
         # the step timeline (flightrec.PHASES): this runner's h2d, enqueue
         # and sync spans. The engine hands in its flight recorder; a runner
         # on its own (tests, the draft proposer) keeps a private one
-        self.clock = FlightRecorder(enabled=False)
+        self.clock = FlightRecorder()
         # async pipeline: splice a chained row's input token from the
         # previous step's device-resident output matrix (last window
         # column), falling back to the host-provided token where
@@ -454,10 +454,10 @@ class ModelRunner:
         # the background thread exists to protect
         self.idle_check = None  # Callable[[], bool] | None
         # XLA compile telemetry (docs/42-compile-telemetry.md): the engine
-        # replaces this with its shared CompileWatch; the disabled default
-        # keeps a standalone runner importable at zero overhead. The draft
-        # runner shares the target's watch with role="draft".
-        self.compile_watch = CompileWatch(enabled=False)
+        # replaces this with its shared CompileWatch; a runner on its own
+        # keeps a private one. The draft runner shares the target's watch
+        # with role="draft".
+        self.compile_watch = CompileWatch()
         self.compile_role = "target"
         # verify programs have no pad-up fallback lattice (t_pad is the
         # pow2 of the fed width) — tracked separately for telemetry only
@@ -494,8 +494,7 @@ class ModelRunner:
         the pure predicate tests pin). The new table says it could widen to
         16-token pages and ~1k contexts at block >= 32 (ROADMAP D3); fp8
         stays XLA. The kernel also never materializes the O(BxS) gather
-        scratch that OOMs large models (bench_northstar.py's llama-3b
-        finding). Under tp>1 an explicit 'pallas' runs one kernel per device
+        scratch that OOMs large models. Under tp>1 an explicit 'pallas' runs one kernel per device
         through shard_map; CPU tests pin numerics via interpret mode."""
         backend = self.config.attention_backend
         if self.config.model.any_sliding:
@@ -1983,8 +1982,6 @@ class ModelRunner:
         key already compiled (no pad-up, no sync compile). The dispatch is
         charged to the key actually served."""
         watch = self.compile_watch
-        if not watch.enabled:
-            return
         with self._bg_lock:
             hit = aot_key == exact_key and exact_key in self._compiled_keys
         watch.record_dispatch(aot_key, hit, role=self.compile_role)
@@ -1998,8 +1995,6 @@ class ModelRunner:
         prevent — recorded against the requests whose step blocked, and
         stamped onto each request for its trace timeline."""
         watch = self.compile_watch
-        if not watch.enabled:
-            return
         trigger = "mid_traffic" if self.fallback_enabled else "warmup"
         rid = None
         if requests:

@@ -9,12 +9,11 @@ this module explains *why the chip isn't full*. Two instruments:
   vs the padded device shape actually computed), and an achieved-FLOP/s →
   MFU estimate (analytic model FLOPs × tokens ÷ resolve-cadence wall).
   Cheap by construction: a handful of float ops and one bucket increment
-  per resolved step, all on the step thread. ``enabled=False`` degrades to
-  a no-op (the bench's ``saturation`` phase measures the difference).
+  per resolved step, all on the step thread.
 
 - **GoodputLedger** — classifies every device-sampled token exactly once
   as *delivered* or *wasted* with a bounded reason label
-  (:data:`WASTE_REASONS`). The invariant the tests and bench enforce:
+  (:data:`WASTE_REASONS`). The invariant the tests enforce:
 
       sampled == delivered + sum(wasted) + pending-on-live-requests
 
@@ -251,8 +250,7 @@ class StepMeter:
     fraction describes steady state.
     """
 
-    def __init__(self, model_cfg=None, sched_cfg=None, enabled: bool = True):
-        self.enabled = enabled
+    def __init__(self, model_cfg=None, sched_cfg=None):
         self.model_cfg = model_cfg
         self.sched_cfg = sched_cfg
         # cumulative counters (exporter _bump pattern)
@@ -299,8 +297,6 @@ class StepMeter:
         """One RESOLVED decode (or verify) dispatch. `accepted_tokens` are
         the host-accepted tokens; `sum_context` the summed context length
         over the dispatch's sampled positions (FLOP estimate)."""
-        if not self.enabled:
-            return
         now = time.perf_counter()
         wall = self._wall(now)
         sched = self.sched_cfg
@@ -346,8 +342,6 @@ class StepMeter:
         tokens over a (pow2 rows × token-bucket) padded device shape.
         `max_chunk` is the longest row's chunk — the planner pads every
         row to ITS bucket (mean-chunk fallback when not provided)."""
-        if not self.enabled:
-            return
         now = time.perf_counter()
         wall = self._wall(now)
         sched = self.sched_cfg
@@ -382,7 +376,7 @@ class StepMeter:
 
     def peak_flops(self) -> float:
         if self._peak_flops is None:
-            self._peak_flops = detect_peak_flops() if self.enabled else 0.0
+            self._peak_flops = detect_peak_flops()
         return self._peak_flops
 
     def _decay(self) -> float:
@@ -402,7 +396,6 @@ class StepMeter:
         occupancy = self.seat_occupancy * decay
         achieved = self.achieved_flops * decay
         return {
-            "enabled": self.enabled,
             "decode_seat_occupancy": occupancy,
             "padding_waste_frac": self.padding_waste * decay,
             "achieved_flops_per_s": achieved,

@@ -1016,7 +1016,7 @@ class Scheduler:
         (matched is then a full-hierarchy blocking match — the auto-mode
         fallback, whose transfers are what feed the bandwidth estimator
         past its sample floor)."""
-        # kill switch / bench compute-only arm: lower-tier residency is
+        # kill switch: lower-tier residency is
         # ignored and — crucially — neither the remote store nor any peer
         # is ever probed (a sick store/peer is exactly why an operator
         # flips this off)

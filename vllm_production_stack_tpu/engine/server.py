@@ -1716,7 +1716,6 @@ class EngineServer:
             "config": {
                 "model": self.model_name,
                 "fingerprint": eng.model_fingerprint,
-                "async_scheduling": eng.config.async_scheduling,
                 "kv_hydration": eng.config.kv_hydration,
             },
             "timing": dict(eng.timing),
@@ -1745,7 +1744,6 @@ class EngineServer:
             return error(400, "last must be an integer")
         eng = self.engine
         body = {
-            "recording": eng.flightrec.enabled,
             "records_total": eng.flightrec.records_total,
             "flight": eng.flightrec.snapshot(last=max(1, last)),
             "heartbeats": eng.threads.snapshot(),
@@ -2449,15 +2447,6 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--trace-buffer", type=int, default=256,
                    help="finished request timelines kept in the in-process "
                         "ring buffer behind /debug/requests")
-    p.add_argument("--flight-recording", default=True, type=_parse_bool_flag,
-                   help="flight recorder (docs/37-flight-recorder.md): "
-                        "bounded ring of structured step records "
-                        "(dispatch/resolve seq, batch shape, queue/pool "
-                        "depths, rollback/fault markers) served by "
-                        "/debug/flight and carried in stall reports and "
-                        "postmortems. 'false' disables the ring; the "
-                        "watchdog's unresolved-step liveness cursor stays "
-                        "on either way")
     p.add_argument("--flight-records", type=int, default=512,
                    help="flight-recorder ring capacity (the last-N step "
                         "records a stall report / postmortem carries)")
@@ -2476,15 +2465,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "lazy-compile stall; per-loop thresholds for the "
                         "fetcher/publisher/bg-compile ride their own "
                         "registrations)")
-    p.add_argument("--compile-watch", default=True, type=_parse_bool_flag,
-                   help="XLA compile telemetry (docs/42-compile-"
-                        "telemetry.md): record every program build "
-                        "(inventory at /debug/programs, "
-                        "tpu:engine_compiles_total{phase,trigger} / "
-                        "compile-seconds histogram / program-cache "
-                        "hit-miss counters, compile_stall trace events) "
-                        "plus the recompile-storm detector. 'false' "
-                        "disables the watch entirely")
     p.add_argument("--compile-storm-threshold", type=int, default=6,
                    help="mid-traffic compiles (sync compiles on the "
                         "dispatch path after warmup — shapes the bucket "
@@ -2501,25 +2481,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "death, POST /debug/postmortem). Empty = no files "
                         "(/debug/postmortem then returns the document "
                         "inline)")
-    p.add_argument("--step-metering", default=True, type=_parse_bool_flag,
-                   help="per-step saturation accounting (docs/29-"
-                        "saturation-slo.md): decode-seat occupancy, "
-                        "padding-waste fraction, achieved-FLOP/s → MFU and "
-                        "the tpu:engine_step_* histograms, metered in the "
-                        "step loop. 'false' disables the meter; the "
-                        "goodput token ledger (tpu:goodput_tokens_total / "
-                        "tpu:wasted_tokens_total) stays on either way")
-    p.add_argument("--kv-flow-metering", default=True, type=_parse_bool_flag,
-                   help="per-tier KV transfer metering (docs/30-kv-flow-"
-                        "telemetry.md): bytes/blocks/latency per tier move "
-                        "(tpu:kv_transfer_*) and the per-tier bandwidth "
-                        "estimators (tpu:kv_tier_bandwidth_bytes_per_s) "
-                        "behind the compute-or-load hydration signal. "
-                        "'false' disables the transfer meters; the "
-                        "hydration attribution counters "
-                        "(tpu:request_prefix_tokens_total) AND the "
-                        "bandwidth estimators (the hydration planner's "
-                        "decision input) stay on either way")
     p.add_argument("--kv-hydration", default="auto",
                    choices=["auto", "planner", "sync", "off"],
                    help="compute-or-load KV hydration for disk/remote-"
@@ -2664,15 +2625,6 @@ def build_parser() -> argparse.ArgumentParser:
                    help="hold host-ring entries in at-rest wire form too: "
                         "the same host-RAM budget buys wire-ratio x more "
                         "blocks, at a dequant on every ring reload")
-    p.add_argument("--async-scheduling", default=True,
-                   type=_parse_bool_flag,
-                   help="two-deep pipelined step loop: dispatch step N+1 "
-                        "against speculatively-advanced state before step "
-                        "N's tokens sync to the host (decode inputs chain "
-                        "device-side; one D2H sync per resolved step). "
-                        "Token streams are bitwise identical to the serial "
-                        "loop. 'false' restores the serial "
-                        "schedule→execute→sync→postprocess path")
     p.add_argument("--enable-prefix-caching", action="store_true", default=True)
     p.add_argument("--no-enable-prefix-caching", dest="enable_prefix_caching",
                    action="store_false")
@@ -2783,9 +2735,6 @@ def engine_config_from_args(args: argparse.Namespace) -> EngineConfig:
         prefill_attention_backend=getattr(
             args, "prefill_attention_backend", "auto"
         ),
-        async_scheduling=getattr(args, "async_scheduling", True),
-        step_metering=getattr(args, "step_metering", True),
-        kv_flow_metering=getattr(args, "kv_flow_metering", True),
         kv_hydration=getattr(args, "kv_hydration", "auto"),
         kv_hydration_chunk_blocks=getattr(
             args, "kv_hydration_chunk_blocks", 16
@@ -2796,10 +2745,8 @@ def engine_config_from_args(args: argparse.Namespace) -> EngineConfig:
         kv_peer_fetch_timeout_s=getattr(
             args, "kv_peer_fetch_timeout_s", 2.0
         ),
-        flight_recording=getattr(args, "flight_recording", True),
         flight_records=getattr(args, "flight_records", 512),
         structured_output=getattr(args, "structured_output", "enforce"),
-        compile_watch=getattr(args, "compile_watch", True),
         compile_storm_threshold=getattr(
             args, "compile_storm_threshold", 6
         ),

@@ -17,9 +17,9 @@ HBM_KV_USAGE_PERC = "tpu:hbm_kv_usage_perc"
 PREFIX_CACHE_HIT_RATE = "tpu:hbm_prefix_cache_hit_rate"
 # host-RAM offload tier (LMCache CPU-offload equivalent)
 HOST_KV_USAGE_PERC = "tpu:host_kv_usage_perc"
-# pipelined step loop (engine/engine.py async_scheduling): fraction of
-# step-loop wall time in which host scheduling/postprocess overlapped an
-# in-flight device step — 0.0 on the serial fallback path
+# pipelined step loop (engine/engine.py): fraction of step-loop wall
+# time in which host scheduling/postprocess overlapped an in-flight
+# device step
 STEP_OVERLAP_FRAC = "tpu:engine_step_overlap_frac"
 
 # counters

@@ -1,6 +1,6 @@
 """Model name/path resolution to ModelConfig.
 
-Named presets cover the baseline configs (BASELINE.md: opt-125m-class tiny
+Named presets cover the reference target configs (opt-125m-class tiny
 models for CI, Llama-3-8B for the headline benchmark, Llama-3-70B for
 pipeline parallel), the benchmarked dense models (qwen3-8b, qwen2-7b) and
 the sparse-MoE ones (mixtral-8x7b, olmoe-1b-7b; tiny-mixtral and tiny-olmoe

@@ -307,115 +307,6 @@ def _decode_grid_kernel(
         ).astype(out_ref.dtype)
 
 
-def _hist_kernel(
-    # scalar prefetch
-    hist_ref,  # (B,) int32 — history length per row
-    step_ref,  # (1,) int32 — iteration index inside the fused window
-    # pipeline inputs
-    q_ref,  # (1, nh, D)
-    k_ref,  # (1, C, kvh, D) — this grid step's contiguous history chunk
-    v_ref,  # (1, C, kvh, D)
-    staged_k_ref,  # (W, 1, kvh, D)
-    staged_v_ref,  # (W, 1, kvh, D)
-    # output
-    out_ref,  # (1, nh, D)
-    # scratch
-    m_ref,  # (nh, 1) f32
-    l_ref,  # (nh, 1) f32
-    acc_ref,  # (nh, D) f32
-    *,
-    scale: float,
-    chunk: int,
-    num_kv_heads: int,
-):
-    """Flash decode over HOISTED CONTIGUOUS history (B, S, kvh, D): large
-    sequential chunks streamed by the Pallas pipeline, so each HBM→VMEM DMA
-    moves C·kvh·D elements (e.g. 512 tokens ≈ 0.5 MB). One grid step a
-    chunk, per-kv-head dots; the staged window folds in on the final grid
-    step."""
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    num_chunks = pl.num_programs(1) - 1
-
-    nh, d = q_ref.shape[1], q_ref.shape[2]
-    qpk = nh // num_kv_heads
-    q = q_ref[0]  # (nh, D) native dtype; dots accumulate f32
-
-    @pl.when(j == 0)
-    def _init_acc():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    def flash_update(scores, values):
-        """scores: (nh, S) f32 already masked; values: (S, kvh, D)."""
-        m_prev, l_prev = m_ref[:], l_ref[:]
-        m_cur = jnp.max(scores, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(scores - m_new)
-        l_ref[:] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[:] = m_new
-        p = p.astype(values.dtype)
-        acc = acc_ref[:] * alpha
-        acc_ref[:] = jnp.concatenate(
-            [
-                acc[g * qpk : (g + 1) * qpk]
-                + jax.lax.dot(
-                    p[g * qpk : (g + 1) * qpk],
-                    values[:, g, :],
-                    preferred_element_type=jnp.float32,
-                )
-                for g in range(num_kv_heads)
-            ],
-            axis=0,
-        )
-
-    @pl.when(j < num_chunks)
-    def _visit_chunk():
-        k_chunk = k_ref[0]  # (C, kvh, D)
-        v_chunk = v_ref[0]
-        pos = j * chunk + jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
-        valid = pos < hist_ref[b]  # (1, C)
-        scores = jnp.concatenate(
-            [
-                jax.lax.dot_general(
-                    q[g * qpk : (g + 1) * qpk],
-                    k_chunk[:, g, :],
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                for g in range(num_kv_heads)
-            ],
-            axis=0,
-        )  # (nh, C)
-        scores = jnp.where(valid, scores * scale, NEG_INF)
-        flash_update(scores, v_chunk)
-
-    @pl.when(j == num_chunks)
-    def _finalize():
-        w = staged_k_ref.shape[0]
-        k_st = staged_k_ref[:, 0]  # (W, kvh, D)
-        v_st = staged_v_ref[:, 0]
-        widx = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)
-        valid = widx <= step_ref[0]
-        scores = jnp.concatenate(
-            [
-                jax.lax.dot_general(
-                    q[g * qpk : (g + 1) * qpk],
-                    k_st[:, g, :],
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                for g in range(num_kv_heads)
-            ],
-            axis=0,
-        )  # (nh, W)
-        scores = jnp.where(valid, scores * scale, NEG_INF)
-        flash_update(scores, v_st)
-        out_ref[0] = (acc_ref[:] / l_ref[:]).astype(out_ref.dtype)
-
-
 # --------------------------------------------------------------------------
 # Paged PREFILL (chunked-prefill-aware) flash attention
 # --------------------------------------------------------------------------
@@ -776,74 +667,6 @@ def paged_prefill_attention_sharded(
         check_vma=False,
     )
     return fn(q, kv, block_tables, context_lens, chunk_start)
-
-
-HIST_CHUNK = 512
-
-
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def hist_decode_attention(
-    q: jax.Array,  # (B, nh, D) — decode queries, one token per row
-    hist_k: jax.Array,  # (B, S, kvh, D) — hoisted contiguous history
-    hist_v: jax.Array,  # (B, S, kvh, D)
-    hist_len: jax.Array,  # (B,) int32
-    staged_k: jax.Array,  # (W, B, kvh, D)
-    staged_v: jax.Array,  # (W, B, kvh, D)
-    step_k: jax.Array,  # scalar int32
-    *,
-    scale: float,
-    interpret: bool = False,
-) -> jax.Array:
-    """Flash decode attention over hoisted contiguous history + the staged
-    window. Returns (B, nh, D). S must divide into HIST_CHUNK-or-smaller
-    power-of-two chunks (the runner's context buckets are powers of two).
-
-    Status: opt-in experiment, NOT wired into the serving path. Measured on a
-    v5e chip (llama-1b decode, B=256, W=64, S=256): 20.2 ms/iter vs 12.1 for
-    the XLA formulation over the same hoisted history
-    (ops/attention.py:attention_with_hist) — at these shapes XLA's fused
-    einsum pipeline beats the kernel's per-(row, chunk) grid; revisit for
-    long-context shapes where the score matrix stops fitting fused form."""
-    b, nh, d = q.shape
-    kvh = hist_k.shape[2]
-    s = hist_k.shape[1]
-    chunk = min(s, HIST_CHUNK)
-    assert s % chunk == 0, (s, chunk)
-    n_chunks = s // chunk
-    w = staged_k.shape[0]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # hist_len, step_k
-        grid=(b, n_chunks + 1),
-        in_specs=[
-            pl.BlockSpec((1, nh, d), lambda i, j, h, st: (i, 0, 0)),
-            pl.BlockSpec(
-                (1, chunk, kvh, d),
-                lambda i, j, h, st: (i, jnp.minimum(j, (s // chunk) - 1), 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, chunk, kvh, d),
-                lambda i, j, h, st: (i, jnp.minimum(j, (s // chunk) - 1), 0, 0),
-            ),
-            pl.BlockSpec((w, 1, kvh, d), lambda i, j, h, st: (0, i, 0, 0)),
-            pl.BlockSpec((w, 1, kvh, d), lambda i, j, h, st: (0, i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, nh, d), lambda i, j, h, st: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((nh, 1), jnp.float32),
-            pltpu.VMEM((nh, 1), jnp.float32),
-            pltpu.VMEM((nh, d), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        _hist_kernel, scale=scale, chunk=chunk, num_kv_heads=kvh
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nh, d), q.dtype),
-        interpret=interpret,
-    )(hist_len, jnp.reshape(step_k, (1,)), q, hist_k, hist_v, staged_k, staged_v)
 
 
 def paged_decode_attention_sharded(

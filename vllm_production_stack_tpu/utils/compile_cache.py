@@ -8,7 +8,7 @@ engine's `--compilation-cache-dir`) or ONE fixed directory inside the
 checkout. Nothing here is derived from a temporary name, a pid or the time.
 
 `compile_cache_dir` is importable without JAX, so a parent process that must
-stay off the chip (chip_smoke.py, bench_livestack.py) can look at the
+stay off the chip (chip_smoke.py) can look at the
 directory its children will use.
 """
 
