@@ -287,67 +287,72 @@ def _assert_no_widened_experts(cfg, text):
     assert not copies, copies
 
 
-@pytest.mark.parametrize("rows,chunk", [
-    (1, CHUNK),  # 512 tokens: every expert on every token
-    (2, CHUNK),  # 1,024 tokens: the grouped matmul over the routed pairs
-])
-def test_moe_prefill_program_compiles_for_v5e(topo, rows, chunk):
-    """OLMoE-1B-7B's prefill step at published widths (two layers deep),
-    int8 experts, on one described v5e chip."""
+def _packed_prefill(topo, cfg, rows, n, backend="xla"):
+    """The served prefill program, compiled: `rows` rows whose chunk tokens
+    lie end to end on one axis of `n` positions (llama.forward with `pack`,
+    blockwise KV write), two layers deep, int8 weights, the 64-page table."""
+    from vllm_production_stack_tpu.models import llama
+
+    params, kv, i32, _staged, nb = _moe_program_args(topo, cfg, rows, n)
+    nbw = (n - 1) // 32 + 2
+
+    def step(params, kv, tokens, positions, tables, lens, chunk, ids, off):
+        stats: list | None = [] if cfg.num_experts else None
+        hidden, kv = llama.forward(
+            cfg, params, tokens, positions, kv, tables,
+            jnp.zeros((1,), jnp.int32), lens,
+            write_blocks={"ids": ids, "start_off": off, "chunk_lens": chunk},
+            backend=backend, moe_stats=stats,
+            pack=llama.pack_index(chunk, lens, n))
+        return (llama.compute_logits(cfg, params, hidden[-rows:, 0]), kv,
+                sum(s["touched"] for s in stats or ()))
+
+    return jax.jit(step, donate_argnums=1).lower(
+        params, kv, i32(n), i32(n), i32(rows, nb), i32(rows), i32(rows),
+        i32(rows, nbw), i32(rows)).compile()
+
+
+def _cell_config(model):
     import dataclasses
 
-    from vllm_production_stack_tpu.models import llama
     from vllm_production_stack_tpu.models.registry import resolve_model_config
 
-    cfg = dataclasses.replace(
-        resolve_model_config("olmoe-1b-7b", quantization="int8"),
-        num_layers=2)
-    params, kv, i32, _staged, nb = _moe_program_args(topo, cfg, rows, chunk)
+    return dataclasses.replace(
+        resolve_model_config(model, quantization="int8"), num_layers=2)
 
-    def step(params, kv, tokens, positions, tables, slots, lens):
-        stats: list = []
-        hidden, kv = llama.forward(
-            cfg, params, tokens, positions, kv, tables, slots, lens,
-            moe_stats=stats)
-        return (llama.compute_logits(cfg, params, hidden[:, -1]), kv,
-                sum(s["touched"] for s in stats))
 
-    compiled = jax.jit(step, donate_argnums=1).lower(
-        params, kv, i32(rows, chunk), i32(rows, chunk), i32(rows, nb),
-        i32(rows * chunk), i32(rows)).compile()
-    text = compiled.as_text()
+@pytest.mark.parametrize("rows,tokens", [
+    (1, CHUNK),  # 512 tokens: every expert on every token
+    (2, CHUNK),  # still 512: the token SUM chooses, not rows x bucket
+    (2, 2 * CHUNK),  # 1,024 tokens: the grouped matmul over the routed pairs
+])
+def test_moe_prefill_program_compiles_for_v5e(topo, rows, tokens):
+    """OLMoE-1B-7B's prefill step at published widths (two layers deep),
+    int8 experts, on one described v5e chip."""
+    from vllm_production_stack_tpu.models import llama
+
+    cfg = _cell_config("olmoe-1b-7b")
+    text = _packed_prefill(topo, cfg, rows, tokens).as_text()
     _assert_no_widened_experts(cfg, text)
-    grouped = rows * chunk > llama._ALL_EXPERTS_MAX_TOKENS
+    grouped = tokens > llama._ALL_EXPERTS_MAX_TOKENS
     assert ("ragged-dot" in text) == grouped
 
 
 @pytest.mark.parametrize("model", ["qwen3-8b", "qwen2-7b", "olmoe-1b-7b"])
 def test_prefill_program_of_a_cell_holds_no_score_temp(topo, model):
-    """The 16 x 512 prefill program the cells' warm-up compiles (two layers
-    deep, int8 weights, the 64-page table), with the kernel: no f32 buffer
-    of (rows, heads, chunk, table tokens) — 2 GiB at 32 heads — no gathered
-    history, no gather_pages scope, and all its temps under that size."""
-    import dataclasses
+    """The 16-row, 512-token prefill program the cells' warm-up compiles,
+    with the kernel: no f32 buffer of (rows, heads, chunk, table tokens) —
+    2 GiB at 32 heads — no gathered history, no gather_pages scope, and all
+    its temps under that size. And it is PACKED: nothing that is linear in
+    tokens exists at rows x chunk positions (no MLP intermediate of that
+    height), and sixteen rows add to a lone row's temps only what attention
+    and the KV write hold per row (the query row view and its result,
+    (rows, chunk, heads x D) each, and the written pages)."""
     import re
 
-    from vllm_production_stack_tpu.models import llama
-    from vllm_production_stack_tpu.models.registry import resolve_model_config
-
-    cfg = dataclasses.replace(
-        resolve_model_config(model, quantization="int8"), num_layers=2)
+    cfg = _cell_config(model)
     rows, s_ctx = 16, 64 * 32
-    params, kv, i32, _staged, nb = _moe_program_args(topo, cfg, rows, CHUNK)
-
-    def step(params, kv, tokens, positions, tables, slots, lens):
-        hidden, kv = llama.forward(
-            cfg, params, tokens, positions, kv, tables, slots, lens,
-            backend="pallas",
-            moe_stats=[] if cfg.num_experts else None)
-        return llama.compute_logits(cfg, params, hidden[:, -1]), kv
-
-    compiled = jax.jit(step, donate_argnums=1).lower(
-        params, kv, i32(rows, CHUNK), i32(rows, CHUNK), i32(rows, nb),
-        i32(rows * CHUNK), i32(rows)).compile()
+    compiled = _packed_prefill(topo, cfg, rows, CHUNK, backend="pallas")
     text = compiled.as_text()
     assert "paged_prefill_attention" in text
     assert "gather_pages" not in text
@@ -359,20 +364,25 @@ def test_prefill_program_of_a_cell_holds_no_score_temp(topo, model):
     assert not held, held
     temps = compiled.memory_analysis().temp_size_in_bytes
     assert temps < rows * nh * CHUNK * s_ctx * 4, temps
+    tall = rf"\[({rows * CHUNK}|{rows},{CHUNK}),(1,)?{cfg.intermediate_size}\]"
+    held = [line[:160] for line in text.splitlines() if re.search(tall, line)]
+    assert not held, held
+    lone = _packed_prefill(
+        topo, cfg, 1, CHUNK, backend="pallas"
+    ).memory_analysis().temp_size_in_bytes
+    row_view = rows * CHUNK * nh * d * 2  # bf16 queries; the result too
+    written = rows * ((CHUNK - 1) // 32 + 2) * 32 * kvh * d * 2  # K's pages
+    # (as read, and as merged; V's the same)
+    assert temps - lone <= 2 * row_view + 4 * written, (temps, lone)
 
 
 def test_moe_decode_step_compiles_for_v5e(topo):
     """One decode step of OLMoE-1B-7B at 16 rows: the Pallas decode kernel
     at its third head shape (16 KV heads x 128) and the all-experts einsum
     on int8 experts."""
-    import dataclasses
-
     from vllm_production_stack_tpu.models import llama
-    from vllm_production_stack_tpu.models.registry import resolve_model_config
 
-    cfg = dataclasses.replace(
-        resolve_model_config("olmoe-1b-7b", quantization="int8"),
-        num_layers=2)
+    cfg = _cell_config("olmoe-1b-7b")
     rows = 16
     params, kv, i32, staged, nb = _moe_program_args(topo, cfg, rows, 1)
 

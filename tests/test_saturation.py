@@ -121,8 +121,9 @@ def test_meter_occupancy_and_padding_accounting():
     # the 0.75 observation lands in the le=0.75 bucket
     idx = list(h["buckets"]).index(0.75)
     assert h["counts"][idx] == 1
-    # prefill: 2 rows × 24 tokens = 48 useful; pads to pow2(2) × bucket(24→32)
-    m.record_prefill(rows=2, chunk_tokens=48, sum_context=600)
+    # prefill: 2 rows × 24 tokens = 48 useful, of the 64 positions the
+    # program computed (the runner's count: the packed axis's bucket)
+    m.record_prefill(chunk_tokens=48, sum_context=600, positions=64)
     snap = m.snapshot()
     assert snap["step_tokens"]["prefill"] == 48
     assert snap["padded_tokens"]["prefill"] == 2 * 32

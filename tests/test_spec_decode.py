@@ -13,7 +13,7 @@ from vllm_production_stack_tpu.engine.config import (
 )
 from vllm_production_stack_tpu.engine.engine import LLMEngine
 from vllm_production_stack_tpu.engine.request import SamplingParams
-from vllm_production_stack_tpu.engine.scheduler import VerifyWork
+from vllm_production_stack_tpu.engine.scheduler import PrefillWork, VerifyWork
 from vllm_production_stack_tpu.engine.spec_decode import propose_ngram
 from vllm_production_stack_tpu.testing.serial_loop import serial_engine
 
@@ -528,6 +528,44 @@ def test_draft_proposer_matches_plain_greedy_and_attributes():
         assert sch.spec_proposed_by["ngram"] == 0
         # identical weights → the draft predicts the target's argmax:
         # near-total acceptance (ties/clipping allow a little slack)
+        assert (
+            sch.spec_accepted_by["draft"]
+            >= 0.8 * sch.spec_proposed_by["draft"]
+        )
+    finally:
+        _shutdown(plain, eng)
+
+
+@pytest.mark.parametrize("sizes", [(20, 25), (40, 30, 9), (31, 31, 31, 31)])
+def test_draft_catch_up_fits_the_largest_prefill_program(sizes):
+    """The draft's catch-up feed is built outside the scheduler's token
+    budget: on a first proposal every row brings its whole prompt. Rows
+    whose tokens SUM past the draft runner's largest prefill bucket (32
+    here) must be split over dispatches (`ModelRunner.prefill_row_room`),
+    not handed to one program that does not exist; streams stay the plain
+    greedy ones and the identical-weights draft is accepted."""
+    rng = np.random.RandomState(11)
+    prompts = [list(rng.randint(1, 500, size=n)) for n in sizes]
+    greedy = SamplingParams(max_tokens=12, temperature=0.0, ignore_eos=True)
+    plain = _build(0)
+    eng = _build(3, method="draft", draft="tiny-llama")
+    seen: list[int] = []
+    execute = eng.draft_runner.execute
+
+    def spy(work):
+        if isinstance(work, PrefillWork):
+            seen.append(sum(len(t) for t in work.token_ids))
+        return execute(work)
+
+    eng.draft_runner.execute = spy
+    try:
+        ref = [r["token_ids"] for r in plain.generate(prompts, greedy)]
+        got = [r["token_ids"] for r in eng.generate(prompts, greedy)]
+        assert got == ref
+        assert seen and max(seen) <= 32
+        # the rows really were combined up to the cap, not fed one by one
+        assert max(seen) > max(min(sizes), 16)
+        sch = eng.scheduler
         assert (
             sch.spec_accepted_by["draft"]
             >= 0.8 * sch.spec_proposed_by["draft"]

@@ -79,6 +79,17 @@ def test_phase_totals_reconcile(ran, loop):
     assert 0 < t["starved_s"] < t["clock_s"]
 
 
+@pytest.mark.parametrize("loop", ["pipelined", "serial"])
+def test_prefill_positions_counts_what_the_program_computed(ran, loop):
+    """`prefill_positions` beside `prefill_tokens`: the token positions the
+    dispatched prefill programs computed, padding included. The fixture's
+    two prompts (5 + 23 tokens) share one two-row dispatch, packed end to
+    end on the 32-token bucket — rows x bucket would be 2 x 32."""
+    t = ran[loop].timing
+    assert t["prefill_n"] == 1 and t["prefill_tokens"] == 28
+    assert t["prefill_positions"] == 32
+
+
 def test_serial_loop_starves_after_every_dispatch(ran):
     t = ran["serial"].timing
     # the first dispatch ends the gap open since `start`; each later one

@@ -832,18 +832,26 @@ class LLMEngine:
                 # Missing these was the live-stack collapse mode: every new
                 # (rows, bucket) pair stalled serving for a 30-60s compile
                 # while queued decoders starved. One mixed-length wave per pow2
-                # row count covers them (lead row lands bucket t, 16-token
-                # residuals fill the rows within the token budget).
+                # row count covers them: r rows that one dispatch holds and
+                # whose program lands bucket t (the runner says which bucket
+                # a dispatch lands and how long a row may be beside the
+                # others): a lead row as long as (up to) 16-token residuals
+                # and the token budget leave it.
                 r = 1
                 while r <= sched.max_num_seqs:
+                    rest = [min(16, prompt_len // r)] * (r - 1)
                     lead = min(
-                        t, longest_chunk,
-                        sched.max_num_batched_tokens - (r - 1) * 16,
+                        prompt_len,
+                        self.runner.prefill_row_room(rest, t),
+                        sched.max_num_batched_tokens - sum(rest),
                     )
-                    if lead <= prev_bucket or r == rows:
+                    if (
+                        r > prompt_len or lead <= 0
+                        or self.runner.prefill_bucket([lead] + rest) != t
+                    ):
                         r *= 2
-                        continue  # combo unreachable or already warmed above
-                    wave(r, lead, 1, row_lens=[lead] + [16] * (r - 1))
+                        continue  # no r-row dispatch lands this bucket
+                    wave(r, lead, 1, row_lens=[lead] + rest)
                     r *= 2
                 prev_bucket = t
             w = 1
@@ -1303,7 +1311,11 @@ class LLMEngine:
                 rec.count(
                     "prefill_tokens", sum(len(t) for t in work.token_ids)
                 )
-                self._meter_prefill(work)
+                # ... and the positions its program computed for them: the
+                # packed axis with its padding (rows x bucket where the
+                # chunk is row-major); tokens / positions = the fill share
+                rec.count("prefill_positions", handle.positions)
+                self._meter_prefill(work, handle.positions)
             else:
                 # tokens actually ACCEPTED — exact for both the fused
                 # window (mid-window stops discard the tail) and
@@ -1459,7 +1471,7 @@ class LLMEngine:
             sum_context=w * sum(work.positions) + rows * (w * (w + 1) // 2),
         )
 
-    def _meter_prefill(self, work: PrefillWork) -> None:
+    def _meter_prefill(self, work: PrefillWork, positions: int) -> None:
         chunk_tokens = sum(len(t) for t in work.token_ids)
         # each chunk token attends ~its absolute position: per row the sum
         # over [start, end) is len × (start + end) / 2
@@ -1468,10 +1480,9 @@ class LLMEngine:
             n = len(ids)
             sum_ctx += n * (2 * end - n + 1) // 2
         self.meter.record_prefill(
-            rows=len(work.requests),
             chunk_tokens=chunk_tokens,
             sum_context=sum_ctx,
-            max_chunk=max(len(t) for t in work.token_ids),
+            positions=positions,
         )
 
     def _kv_tier_usage(self) -> dict[str, float]:

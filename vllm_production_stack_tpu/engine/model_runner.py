@@ -21,6 +21,7 @@ import functools
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
+from collections.abc import Sequence
 from typing import Any
 
 import jax
@@ -171,6 +172,9 @@ class StepHandle:
         # over this program's layers and steps; "decode" | "prefill"; pairs
         # computed; layer-steps x experts). Fetched with the tokens.
         self.moe: tuple | None = None
+        # prefill handles: the token positions the program computed (the
+        # packed axis, padding included; rows x bucket where row-major)
+        self.positions = 0
 
     def resolve(self) -> list[list[int]]:
         """Sync the step's results to the host — exactly ONE jax.device_get
@@ -605,6 +609,38 @@ class ModelRunner:
         return backend
 
     @property
+    def prefill_packed(self) -> bool:
+        """Does a prefill dispatch lay its rows' tokens end to end on one
+        axis (`_dispatch_prefill`)? On one replica, yes. Under dp > 1 rows
+        shard over replicas and under sp > 1 the chunk axis over devices:
+        there a chunk is row-major, every row padded to the bucket."""
+        return self._dp == 1 and self._sp == 1
+
+    def prefill_bucket(self, lens: Sequence[int]) -> int:
+        """The token bucket `t_pad` of the program that runs ONE prefill
+        dispatch of rows with these chunk lengths: the bucket of their sum
+        where the chunk is packed, of the longest row where it is row-major.
+        This and `prefill_row_room` are all the rest of the engine knows of
+        the layout. ValueError past the largest bucket."""
+        sched = self.config.scheduler
+        return sched.bucket_for(
+            sum(lens) if self.prefill_packed else max(lens),
+            sched.prefill_buckets,
+        )
+
+    def prefill_row_room(
+        self, lens: Sequence[int], bucket: int | None = None
+    ) -> int:
+        """The longest chunk one MORE row may bring to a dispatch that holds
+        rows of `lens` and is to stay within `bucket` (the largest, if none
+        is given): what the others leave of it where the chunk is packed,
+        the whole of it where every row has a bucket of its own. Whoever
+        builds a `PrefillWork` outside the scheduler's token budget (the
+        draft proposer's catch-up) sizes its rows by this."""
+        cap = bucket or max(self.config.scheduler.prefill_buckets)
+        return cap - sum(lens) if self.prefill_packed else cap
+
+    @property
     def prefill_uses_kernel(self) -> bool:
         """Whether the prefill programs hold the Pallas kernel."""
         return self._prefill_backend.startswith("pallas")
@@ -691,8 +727,10 @@ class ModelRunner:
             params,
             lora_params,  # stacked adapter tree, or None when LoRA disabled
             kv_caches,
-            token_ids,  # (B, T)
-            positions,  # (B, T)
+            token_ids,  # (N,) the PACKED chunk: the rows' tokens end to
+            #   end, N the bucket of their sum; (B, T) row-major under dp > 1
+            #   (rows sharded over replicas) — the layout is read off the rank
+            positions,  # as token_ids
             block_tables,  # (B, max_blocks)
             slot_mapping,  # (1,) placeholder — only the sp path row-scatters
             context_lens,  # (B,)
@@ -700,7 +738,8 @@ class ModelRunner:
             write_ids,  # (B, NBW) pool blocks of the chunk's written span
             start_off,  # (B,) chunk's first-token offset in its first block
             lora_idx,  # (B,) adapter slot per row (None when disabled)
-            sample_rows,  # (num_samples,) row index into (B*T) flat hidden
+            sample_rows,  # (num_samples,) index into the flat hidden, (N)
+            #   or (B*T), of each row's last chunk token
             temperature,  # (num_samples,)
             top_p,  # (num_samples,)
             top_k,  # (num_samples,)
@@ -722,6 +761,12 @@ class ModelRunner:
             # an MoE model's program also returns, last, the experts its
             # layers touched (a dense model's outputs are what they were)
             moe_stats: list | None = [] if cfg.num_experts else None
+            pack = None
+            if token_ids.ndim == 1:
+                pack = llama.pack_index(
+                    chunk_lens, context_lens, token_ids.shape[0])
+                if lora_idx is not None:
+                    lora_idx = lora_idx[pack["tok_row"]]
             hidden, kv_caches = llama.forward(
                 cfg, params, token_ids, positions, kv_caches,
                 block_tables, slot_mapping, context_lens,
@@ -734,6 +779,7 @@ class ModelRunner:
                 backend=self._prefill_backend,
                 mesh=self.mesh,
                 moe_stats=moe_stats,
+                pack=pack,
             )
             moe_out = (
                 (sum(s["touched"] for s in moe_stats),) if moe_stats else ()
@@ -1282,15 +1328,21 @@ class ModelRunner:
         )
 
     def _dispatch_prefill(self, work: PrefillWork) -> StepHandle:
-        """One dispatch for the whole prefill batch: rows padded to a common
-        chunk bucket, batch padded to a power of two. Every row samples at its
-        chunk's last token (static shapes); non-sampling rows' tokens are
-        discarded host-side."""
+        """One dispatch for the whole prefill batch, PACKED: the rows'
+        chunk tokens laid end to end on one axis padded to the chunk bucket
+        of their SUM (`prefill_bucket`; the scheduler's token budget bounds
+        it, and the draft proposer sizes its rows by `prefill_row_room`), so
+        the program computes the tokens it was given whatever the number of
+        rows; the per-row arrays (tables, lengths, sampling) pad to a power
+        of two. Under dp > 1 rows shard over replicas, and under sp > 1 the
+        chunk axis over devices: there every row pads to the bucket of the
+        longest, (b_pad, t_pad). Every row samples at its chunk's last token
+        (static shapes); non-sampling rows' tokens are discarded host-side."""
         sched = self.config.scheduler
         b = len(work.requests)
         b_pad = self._batch_bucket(b)
-        t = max(len(row) for row in work.token_ids)
-        t_pad = sched.bucket_for(t, sched.prefill_buckets)
+        packed = self.prefill_packed
+        t_pad = self.prefill_bucket([len(row) for row in work.token_ids])
         want_lp = any(
             work.sample[i] and req.sampling.logprobs is not None
             for i, req in enumerate(work.requests)
@@ -1316,8 +1368,9 @@ class ModelRunner:
         self._watch_dispatch(exact_key, aot_key)
         _, b_pad, t_pad, nb, _lp, use_mt, use_gr = aot_key
 
-        token_ids = np.zeros((b_pad, t_pad), np.int32)
-        positions = np.zeros((b_pad, t_pad), np.int32)
+        tok_shape = (t_pad,) if packed else (b_pad, t_pad)
+        token_ids = np.zeros(tok_shape, np.int32)
+        positions = np.zeros(tok_shape, np.int32)
         # per-token slots feed only the sp path's row scatter; the paged path
         # commits blockwise (write_ids below) and takes a placeholder
         slots = (
@@ -1339,10 +1392,17 @@ class ModelRunner:
         top_ks = np.zeros(b_pad, np.int32)
         seeds: list[int | None] = [None] * b_pad
         counts = np.zeros(b_pad, np.int32)
+        row_off = 0
         for i, req in enumerate(work.requests):
             row = work.token_ids[i]
-            token_ids[i, : len(row)] = row
-            positions[i, : len(row)] = work.positions[i]
+            # row i's tokens: the next len(row) of the packed axis, or the
+            # head of row i
+            at = (
+                slice(row_off, row_off + len(row)) if packed
+                else (i, slice(len(row)))
+            )
+            token_ids[at] = row
+            positions[at] = work.positions[i]
             if slots is not None:
                 slots[i, : len(row)] = work.slot_mappings[i]
             context_lens[i] = work.context_lens[i]
@@ -1352,7 +1412,8 @@ class ModelRunner:
             n_span = (work.context_lens[i] - 1) // bs - first_blk + 1
             write_ids[i, :n_span] = req.block_table[first_blk : first_blk + n_span]
             start_off[i] = hist % bs
-            sample_rows[i] = i * t_pad + len(row) - 1
+            sample_rows[i] = (row_off if packed else i * t_pad) + len(row) - 1
+            row_off += len(row)
             s = req.sampling
             temps[i], top_ps[i], top_ks[i] = s.temperature, s.top_p, s.top_k
             seeds[i] = s.seed
@@ -1415,6 +1476,7 @@ class ModelRunner:
             postproc=functools.partial(self._prefill_rows, work, b),
         )
         handle.moe = moe
+        handle.positions = token_ids.size
         return handle
 
     @staticmethod
@@ -1703,8 +1765,12 @@ class ModelRunner:
         seed_vals = np.asarray(
             [(s or 0) & 0xFFFFFFFF for s in seeds], np.uint32
         )
-        # sp shards the chunk axis; dp-only meshes leave T unsharded
-        tok_sh = self._seq2 if self._sp > 1 else self._batch2
+        # sp shards the chunk axis; dp-only meshes leave T unsharded; the
+        # packed chunk (dp = sp = 1) is one axis of tokens, on every device
+        tok_sh = (
+            self._rep if token_ids.ndim == 1
+            else self._seq2 if self._sp > 1 else self._batch2
+        )
         clock = self.clock
         with clock.span("h2d", kind="prefill"):
             dyn_args = (
@@ -2145,11 +2211,13 @@ class ModelRunner:
             if self._compile_key_now(("prefill", b_top, t, top_w,
                                       False, False, False), "warmup"):
                 n += 1
-        # the pow2 ROWS ladder at (top chunk, top width): rows are the
-        # expensive padding axis (each padded row computes t_pad tokens of
-        # dense FLOPs), so a few extra programs here turn the worst-case
-        # fallback from "jump to full batch" (up to max_num_seqs/rows x
-        # compute) into "pad width only" (~1.2x HBM)
+        # the pow2 ROWS ladder at (top chunk, top width): a packed program
+        # computes t_pad tokens whatever its rows, but each padded row
+        # still costs attention its query tiles' copies, the KV write a
+        # span of null pages and the sampler a row of logits (and, under
+        # dp > 1, t_pad tokens of dense FLOPs: rows x bucket there), so a
+        # few extra programs here turn the worst-case fallback from "jump
+        # to full batch" into "pad width only" (~1.2x HBM)
         b = 1
         while b < b_top:
             if self._compile_key_now(("prefill", b, t_top, top_w,
@@ -2193,9 +2261,12 @@ class ModelRunner:
         s = self._sds
         v = self.config.model.vocab_size
         gr = ((s((b, v), jnp.bool_, b2),) if want_gr else ())  # grammar_mask
+        # (sp > 1 compiles nothing through here: _dynamic_programs_ok)
+        packed = self.prefill_packed
+        tok = s((t,), i32, rep) if packed else s((b, t), i32, b2)
         return (
-            s((b, t), i32, b2),       # token_ids
-            s((b, t), i32, b2),       # positions
+            tok,                      # token_ids
+            tok,                      # positions
             s((b, nb), i32, b2),      # block_tables
             s((1,), i32, rep),        # slots placeholder (paged path)
             s((b,), i32, b1),         # context_lens

@@ -335,27 +335,16 @@ class StepMeter:
             self.seat_occupancy = occ
 
     def record_prefill(
-        self, rows: int, chunk_tokens: int, sum_context: int,
-        max_chunk: int | None = None,
+        self, chunk_tokens: int, sum_context: int, positions: int,
     ) -> None:
         """One resolved prefill dispatch: `chunk_tokens` useful prompt
-        tokens over a (pow2 rows × token-bucket) padded device shape.
-        `max_chunk` is the longest row's chunk — the planner pads every
-        row to ITS bucket (mean-chunk fallback when not provided)."""
+        tokens of the `positions` its program computed (the runner's count:
+        the packed token axis with its padding, or rows x bucket where the
+        chunk is row-major)."""
         now = time.perf_counter()
         wall = self._wall(now)
-        sched = self.sched_cfg
-        padded = chunk_tokens
-        if sched and rows > 0:
-            t = max_chunk or max(1, -(-chunk_tokens // rows))
-            try:
-                t_pad = sched.bucket_for(t, sched.prefill_buckets)
-            except ValueError:
-                t_pad = t
-            b_pad = 1 << max(0, rows - 1).bit_length()
-            padded = b_pad * t_pad
         self.step_tokens["prefill"] += chunk_tokens
-        self.padded_tokens["prefill"] += max(padded, chunk_tokens)
+        self.padded_tokens["prefill"] += positions
         self.steps["prefill"] += 1
         if wall > 0.0:
             self.wall_hist["prefill"].observe(wall)
@@ -364,9 +353,7 @@ class StepMeter:
             flops = step_flops(self.model_cfg, chunk_tokens, sum_context)
             self.flops_total += flops
         if wall > 0.0:
-            waste = (
-                1.0 - chunk_tokens / padded if padded > 0 else 0.0
-            )
+            waste = 1.0 - chunk_tokens / positions if positions else 0.0
             self.padding_waste = self._ewma(self.padding_waste, waste, wall)
             self.achieved_flops = self._ewma(
                 self.achieved_flops, flops / wall, wall

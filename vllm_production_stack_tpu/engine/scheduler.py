@@ -37,8 +37,9 @@ def _is_stop_token(
 
 @dataclass
 class PrefillWork:
-    """One batched prefill dispatch: one chunk from each of N requests, padded
-    by the runner into a (B, T) device shape. Per-request `sample[i]` is set
+    """One batched prefill dispatch: one chunk from each of N requests, laid
+    out by the runner (`ModelRunner._dispatch_prefill`: packed end to end on
+    one token axis, or a padded (B, T) under dp > 1). Per-request `sample[i]` is set
     when row i's chunk reaches the end of its prompt (its last-token logits
     produce the first output token). Batching prompts into one dispatch is
     where prefill throughput comes from — B=1-per-dispatch serializes the MXU

@@ -123,8 +123,6 @@ class DraftModelProposer:
         self.max_model_len = max_model_len
         self.min_free_reserve = min_free_reserve
         self._states: dict[str, _DraftState] = {}
-        sched = runner.config.scheduler
-        self._chunk_cap = max(sched.prefill_buckets)
         # observability: rows that fell back to n-gram on pool pressure
         # (surfaced on /debug/timing's spec section)
         self.declined_rows = 0
@@ -222,9 +220,10 @@ class DraftModelProposer:
             return memo_hits
         # -- batched catch-up: feed every not-yet-valid true token ---------
         # (first proposal: the whole prompt; steady state: the tokens the
-        # last verify accepted). Chunked at the draft's largest prefill
-        # bucket; only the FINAL chunk of a row samples (its tail logits
-        # are the first draft token).
+        # last verify accepted). Each dispatch holds what the draft's
+        # largest prefill program can compute (`prefill_row_room`); only
+        # the FINAL chunk of a row samples (its tail logits are the first
+        # draft token).
         first: dict[str, int] = {}
         # every row re-feeds at least its current tail token (a re-propose
         # after a dropped verify row has nothing new to feed, but still
@@ -239,7 +238,15 @@ class DraftModelProposer:
                 start = pending[id(st)]
                 if start >= len(seq):
                     continue
-                end = min(len(seq), start + self._chunk_cap)
+                # what this dispatch can still take beside the rows it
+                # holds (they are outside the scheduler's token budget:
+                # the runner's largest prefill program is the bound)
+                room = self.runner.prefill_row_room(
+                    [len(t) for t in work.token_ids]
+                )
+                if room <= 0:
+                    break  # full: the rest catch up in the next dispatch
+                end = min(len(seq), start + room)
                 idxs = range(start, end)
                 work.add_row(
                     request=st.shadow,

@@ -500,6 +500,42 @@ def _lora_layer_slice(lora: dict | None, i: int) -> dict | None:
     return out
 
 
+def pack_index(chunk_lens: jax.Array, context_lens: jax.Array,
+               n: int) -> dict:
+    """The index of a PACKED prefill chunk — the rows' tokens laid end to
+    end on one axis of `n` positions, row i's at [row_off[i], row_off[i] +
+    chunk_lens[i]) — from the per-row lengths alone (a cumulative sum and
+    one compare on the device: nothing more to copy from the host).
+    `tok_row` clamps the padding tokens past the last row onto the last
+    row: what they compute is never read."""
+    ends = jnp.cumsum(chunk_lens)
+    tok = jnp.arange(n, dtype=jnp.int32)
+    tok_row = jnp.sum(tok[:, None] >= ends[None, :], axis=1, dtype=jnp.int32)
+    return {
+        "row_off": (ends - chunk_lens).astype(jnp.int32),  # (B,)
+        "tok_row": jnp.minimum(tok_row, chunk_lens.shape[0] - 1),  # (N,)
+        "chunk_start": context_lens - chunk_lens,  # (B,) position of the
+        #   row's first chunk token
+    }
+
+
+def _row_view(pack: dict, n: int, b: int) -> dict:
+    """The two gather indices between a packed chunk of `n` tokens and the
+    (b, n) row view attention takes: "to_rows" (b, n), the packed index of
+    each (row, column), clamped — columns past a row's tokens read a
+    neighbour's, which attention masks by length — and "to_packed" (n,), the
+    flat (row * n + column) of each token. A lone row is its own view: both
+    None."""
+    row_off, tok_row = pack["row_off"], pack["tok_row"]
+    rows = {"row_off": row_off, "to_rows": None, "to_packed": None}
+    if b > 1:
+        tok = jnp.arange(n, dtype=jnp.int32)
+        rows["to_rows"] = jnp.clip(row_off[:, None] + tok[None], 0, n - 1)
+        rows["to_packed"] = tok_row * n + jnp.clip(
+            tok - row_off[tok_row], 0, n - 1)
+    return rows
+
+
 def _layer(
     cfg: ModelConfig,
     lp: dict,
@@ -516,17 +552,43 @@ def _layer(
     #   "interpret", "mesh"} — route attention through the paged
     #   flash-prefill kernel instead of the XLA gather (mask is None then)
     moe_stats: list | None = None,  # see _layer_body
+    rows: dict | None = None,  # _row_view(...) of a packed chunk: x is
+    #   (N, 1, h) then
 ) -> tuple[jax.Array, jax.Array]:
     b, t = x.shape[0], x.shape[1]
     hd, nkv = cfg.head_dim, cfg.num_kv_heads
 
+    def _attention(q):
+        if pallas_prefill is not None:
+            mesh = pallas_prefill["mesh"]
+            if mesh is not None and mesh.size > 1:
+                return paged_prefill_attention_sharded(
+                    mesh, q, kv_layer, block_tables,
+                    pallas_prefill["context_lens"],
+                    pallas_prefill["chunk_start"], scale=cfg.attn_scale,
+                    interpret=pallas_prefill["interpret"],
+                )
+            return paged_prefill_attention(
+                q, kv_layer, block_tables,
+                pallas_prefill["context_lens"],
+                pallas_prefill["chunk_start"], scale=cfg.attn_scale,
+                interpret=pallas_prefill["interpret"],
+            )
+        return paged_attention_xla(
+            q, kv_layer, block_tables, mask, scale=cfg.attn_scale,
+            softcap=cfg.attn_logit_softcap,
+        )
+
     def attend(q, k, v):
         nonlocal kv_layer
+        if rows is not None:
+            q, k, v = q[:, 0], k[:, 0], v[:, 0]  # (N, heads, D)
         with jax.named_scope("kv_stage"):
             if write_blocks is not None:
                 kv_layer = write_kv_pages_blockwise(
                     kv_layer, k, v, write_blocks["ids"],
                     write_blocks["start_off"], write_blocks["chunk_lens"],
+                    row_off=None if rows is None else rows["row_off"],
                 )
             else:
                 kv_layer = write_kv_pages(
@@ -534,25 +596,15 @@ def _layer(
                     v.reshape(b * t, nkv, hd), slot_mapping,
                 )
         with jax.named_scope("attn"):
-            if pallas_prefill is not None:
-                mesh = pallas_prefill["mesh"]
-                if mesh is not None and mesh.size > 1:
-                    return paged_prefill_attention_sharded(
-                        mesh, q, kv_layer, block_tables,
-                        pallas_prefill["context_lens"],
-                        pallas_prefill["chunk_start"], scale=cfg.attn_scale,
-                        interpret=pallas_prefill["interpret"],
-                    )
-                return paged_prefill_attention(
-                    q, kv_layer, block_tables,
-                    pallas_prefill["context_lens"],
-                    pallas_prefill["chunk_start"], scale=cfg.attn_scale,
-                    interpret=pallas_prefill["interpret"],
-                )
-            return paged_attention_xla(
-                q, kv_layer, block_tables, mask, scale=cfg.attn_scale,
-                softcap=cfg.attn_logit_softcap,
-            )
+            if rows is None:
+                return _attention(q)
+            # attention is the one place that needs rows: it gets the row
+            # view it takes from a row-major chunk (one gather), and its
+            # result is gathered back. A lone row is its own view.
+            if rows["to_rows"] is None:
+                return _attention(q[None])[0][:, None]
+            out = _attention(q[rows["to_rows"]])
+            return out.reshape(-1, *out.shape[2:])[rows["to_packed"]][:, None]
 
     x = _layer_body(cfg, lp, x, positions, attend, lora, lora_idx,
                     moe_stats)
@@ -562,14 +614,15 @@ def _layer(
 def forward(
     cfg: ModelConfig,
     params: dict,
-    token_ids: jax.Array,  # (B, T) int32
-    positions: jax.Array,  # (B, T) int32 logical positions
+    token_ids: jax.Array,  # (B, T) int32; packed: (N,)
+    positions: jax.Array,  # (B, T) int32 logical positions; packed: (N,)
     kv_caches: jax.Array,  # (L, 2, num_blocks, block_size, kvH, D)
     block_tables: jax.Array,  # (B, max_blocks) int32
     slot_mapping: jax.Array,  # (B*T,) flat slots (padding -> block 0 slots)
     context_lens: jax.Array,  # (B,) tokens resident after this step
     lora: dict | None = None,  # stacked adapter tree (init_lora_params)
-    lora_idx: jax.Array | None = None,  # (B,) adapter slot per row
+    lora_idx: jax.Array | None = None,  # (B,) adapter slot per row; packed:
+    #   (N,), the row's slot repeated over its tokens
     write_blocks: dict | None = None,  # {"ids": (B, NBW) written-span pool
     #   blocks, "start_off": (B,), "chunk_lens": (B,)} — when given, chunk
     #   K/V commits via the page-granular read-modify-write
@@ -581,9 +634,20 @@ def forward(
     mesh=None,  # required for the pallas backend on a >1-device mesh
     moe_stats: list | None = None,  # MoE models: a list that receives each
     #   layer's routing (see _moe_mlp)
+    pack: dict | None = None,  # pack_index(...) — the chunk is PACKED: the
+    #   rows' tokens end to end on one axis of N positions
 ) -> tuple[jax.Array, jax.Array]:
     """One model step over a token batch. Prefill is (B=1, T=chunk); decode is
-    (B=batch, T=1). Returns (hidden (B,T,h), updated kv_caches)."""
+    (B=batch, T=1). Returns (hidden (B,T,h), updated kv_caches).
+
+    With `pack` the chunk is the served prefill's layout: everything that
+    is linear in tokens (embed, norms, projections, rope, MLP / experts)
+    runs on the N packed tokens as (N, 1, h) — the shape a decode step has,
+    so `_layer_body` is what it is — whatever the number of rows; only
+    attention gets the (B, N) row view it takes from a row-major chunk
+    (`_layer`), and the KV write reads the packed k/v through each row's
+    offset. Returns hidden (N, 1, h). The speculative verify programs and
+    a dp > 1 prefill (rows sharded over replicas) stay row-major."""
     if backend.startswith("pallas") and (
         cfg.any_sliding or cfg.attn_logit_softcap
     ):
@@ -591,17 +655,27 @@ def forward(
             "pallas prefill does not support sliding-window or softcapped "
             "models (the runner gates these to the XLA backend)"
         )
+    rows, row_positions, chunk_start = None, positions, None
+    if pack is not None:
+        rows = _row_view(pack, token_ids.shape[0], block_tables.shape[0])
+        row_positions = (
+            positions[None] if rows["to_rows"] is None
+            else positions[rows["to_rows"]])
+        chunk_start = pack["chunk_start"]
+        token_ids, positions = token_ids[:, None], positions[:, None]
     with jax.named_scope("embed"):
         x = _embed(cfg, params, token_ids)
     if backend.startswith("pallas"):
         # the kernel masks from scalars alone — the scheduler feeds chunks
         # with contiguous positions (scheduler.py: range(start, start+len)),
-        # so chunk_start is the first column. No (B, T, S) mask exists.
+        # so chunk_start is the first column (a packed chunk brings it per
+        # row). No (B, T, S) mask exists.
         mask = None
         mask_local = None
         pallas_prefill = {
             "context_lens": context_lens,
-            "chunk_start": positions[:, 0],
+            "chunk_start": (
+                positions[:, 0] if chunk_start is None else chunk_start),
             "interpret": backend == "pallas_interpret",
             "mesh": mesh,
         }
@@ -610,9 +684,9 @@ def forward(
         # layer of that kind (full everywhere; plus the sliding variant
         # for Mistral-v0.1 / Gemma-2 class models)
         s_ctx = block_tables.shape[1] * kv_caches[0].shape[2]
-        mask = causal_page_mask(positions, context_lens, s_ctx)
+        mask = causal_page_mask(row_positions, context_lens, s_ctx)
         mask_local = (
-            causal_page_mask(positions, context_lens, s_ctx,
+            causal_page_mask(row_positions, context_lens, s_ctx,
                              window=cfg.sliding_window)
             if cfg.any_sliding
             else None
@@ -630,7 +704,7 @@ def forward(
             cfg, lp, kv_caches[i], x, positions, block_tables, slot_mapping,
             mask_local if cfg.layer_sliding(i) else mask,
             _lora_layer_slice(lora, i), lora_idx, write_blocks,
-            pallas_prefill, moe_stats,
+            pallas_prefill, moe_stats, rows,
         )
         new_kv.append(layer_kv)
     with jax.named_scope("final_norm"):

@@ -105,12 +105,15 @@ def write_kv_pages(
 
 def write_kv_pages_blockwise(
     kv: jax.Array,  # (2, num_blocks, bs, kvH, D)
-    k: jax.Array,  # (B, T, kvH, D) — the chunk's new K rows
-    v: jax.Array,  # (B, T, kvH, D)
+    k: jax.Array,  # (B, T, kvH, D) — the chunk's new K rows; or, with
+    #   `row_off`, the packed chunk (N, kvH, D): the rows' tokens end to end
+    v: jax.Array,  # as k
     write_block_ids: jax.Array,  # (B, NBW) pool blocks covering the chunk's
     #   written span, in order (padding rows/slots -> 0, the null page)
     start_off: jax.Array,  # (B,) chunk's first token offset in its 1st block
     chunk_lens: jax.Array,  # (B,) real tokens in the chunk per row
+    row_off: jax.Array | None = None,  # (B,) each row's first token on the
+    #   packed axis; None: k and v are row-major
 ) -> jax.Array:
     """Chunk K/V write at BLOCK granularity: read-modify-write whole pages
     instead of scattering one row per token. A token-row scatter of a 256x128
@@ -122,18 +125,22 @@ def write_kv_pages_blockwise(
     (earlier chunks of the same sequence living in the first page), so
     chunked prefill continuation is exact. Rows may start mid-block; padding
     rows point every id at the null page."""
-    b, t, kvh, d = k.shape
-    nbw = write_block_ids.shape[1]
+    b, nbw = write_block_ids.shape
+    kvh, d = k.shape[-2:]
     bs = kv.shape[2]
     s = nbw * bs
     # chunk-token index of each (row, span-position); clamp for the gather,
     # mask decides validity
     tok = jnp.arange(s, dtype=jnp.int32)[None, :] - start_off[:, None]
     mask = (tok >= 0) & (tok < chunk_lens[:, None])  # (B, S)
-    tok_c = jnp.clip(tok, 0, t - 1)
-    rows = jnp.arange(b)[:, None]
-    k_sp = k[rows, tok_c].reshape(b, nbw, bs, kvh, d).astype(kv.dtype)
-    v_sp = v[rows, tok_c].reshape(b, nbw, bs, kvh, d).astype(kv.dtype)
+    if row_off is None:
+        tok_c = jnp.clip(tok, 0, k.shape[1] - 1)
+        at = (jnp.arange(b)[:, None], tok_c)
+    else:
+        # the same gather through the row's offset: no row view of k/v
+        at = (jnp.clip(row_off[:, None] + tok, 0, k.shape[0] - 1),)
+    k_sp = k[at].reshape(b, nbw, bs, kvh, d).astype(kv.dtype)
+    v_sp = v[at].reshape(b, nbw, bs, kvh, d).astype(kv.dtype)
     m = mask.reshape(b, nbw, bs, 1, 1)
     ids = write_block_ids.reshape(-1)
     old = kv[:, ids].reshape(2, b, nbw, bs, kvh, d)
