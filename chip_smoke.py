@@ -95,6 +95,26 @@ REFERENCE_ATOL = 0.09
 # be a fault, not rounding.
 ROUTING_DISAGREEMENT_MAX = 0.15
 ROUTING_MARGIN_MAX = 0.02
+# Kanana-2-30B-A3B at 8 layers (bf16 weights, activations and latents, the
+# absorbed kernels) against reference/mla.py (float32, expanded form),
+# which FOLLOWS the served programs' routing (reference_check.RoutingTap):
+# its top-6 of 128 with renormalised weights makes a boundary expert
+# flipped by bf16 rounding swap a sixth of the routed output, and the
+# free-running comparison read 3.25 nats of that (PR 31, first chip run).
+# REFERENCE_ATOL_MLA lies between its two readings on the chip at the
+# published widths (PR 31, PERF.md §6; prompts 2k-16k, 32 decoded
+# positions, 1,536 logprobs a seed): the largest max |Δlogprob| the served
+# configuration gave over its three seeds, 0.0661 (0.0642, 0.0656, 0.0661),
+# and what the nearest precision below gives — the same engine and
+# kernels over float8 latents, 0.1930 — which has to come out as not
+# correct. The same bf16 rounding carried through 8 layers as OLMoE's 16
+# (0.0724 there): logprobs of size ~ln V ~ 12.
+REFERENCE_ATOL_MLA = 0.09
+# the served choice differed from the reference's own in 9.3% of (token,
+# layer) pairs, every one with the served set's worst expert within 0.0151
+# of the reference's 6th best biased score (sigmoid score + bias; 0.0138 to
+# 0.0151 over the seeds; the float8 arm: 28% of pairs, up to 0.064)
+ROUTING_MARGIN_MAX_MLA = 0.02
 
 
 def engine_flags(model: str, seed: int, tp: int = 1,
@@ -721,6 +741,7 @@ def child_reference(args) -> int:
     holds the chip). One JSON line; exit 0 only if the served arm is inside
     REFERENCE_ATOL, its routing inside its bounds, and the fp8-pool arm
     outside REFERENCE_ATOL."""
+    import contextlib
     import importlib.util
 
     import jax
@@ -736,22 +757,28 @@ def child_reference(args) -> int:
         configure_compile_cache,
     )
 
+    with open(os.path.join(REPO, args.config)) as f:
+        config = json.load(f)
+    # the reference by architecture: the Llama lineage's, or the
+    # DeepSeek-V3 family's (latent attention, sigmoid routing)
+    mla = config.get("model_type") == "deepseek_v3"
     # the benchmark's copy of the reference, not the package's: a later PR
     # to the program cannot move the yardstick
     spec = importlib.util.spec_from_file_location(
         "reference_decoder", os.path.join(
-            REPO, "benchmarks", "chip", "reference", "decoder.py"))
+            REPO, "benchmarks", "chip", "reference",
+            "mla.py" if mla else "decoder.py"))
     reference = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(reference)
-
-    with open(os.path.join(REPO, args.config)) as f:
-        config = json.load(f)
+    atol = REFERENCE_ATOL_MLA if mla else REFERENCE_ATOL
+    margin_max = ROUTING_MARGIN_MAX_MLA if mla else ROUTING_MARGIN_MAX
     flags = (config["rehearse"] if args.rehearse else config)["engine_flags"]
     dev = jax.devices()[0]
     report: dict = {"phase": "reference", "config": config["name"],
                     "platform": dev.platform, "kind": dev.device_kind,
-                    "seed": args.seed, "atol": REFERENCE_ATOL,
-                    "routing_disagreement_max": ROUTING_DISAGREEMENT_MAX}
+                    "seed": args.seed, "atol": atol,
+                    "routing_disagreement_max": ROUTING_DISAGREEMENT_MAX,
+                    "routing_margin_max": margin_max}
     if dev.platform != "tpu" and not args.rehearse:
         report.update(ok=False, error=f"platform is {dev.platform!r}")
         emit(report)
@@ -759,8 +786,14 @@ def child_reference(args) -> int:
     configure_compile_cache()
     rng = np.random.RandomState(args.seed % (2 ** 31))
     lo, hi, n_out = (16, 64, 8) if args.rehearse else (128, 1024, 32)
+    n_prompts = 8
+    if mla and not args.rehearse:
+        # the long-document cell's range: one prompt at its top, one past
+        # 8k that ends mid-chunk, the rest log-uniform over it
+        lo, hi, n_prompts = 2048, 16320, 6
     chunk = int(flags[flags.index("--max-num-batched-tokens") + 1])
-    lengths = np.exp(rng.uniform(np.log(lo), np.log(hi), size=8)).astype(int)
+    lengths = np.exp(
+        rng.uniform(np.log(lo), np.log(hi), size=n_prompts)).astype(int)
     lengths[:2] = [hi, (chunk + hi) // 2]  # two prompts cross a chunk edge
     report["prompt_lengths"] = [int(n) for n in lengths]
 
@@ -772,10 +805,15 @@ def child_reference(args) -> int:
         prompts = [list(map(int, rng.randint(1, vocab, size=n)))
                    for n in lengths]
         t0 = time.monotonic()
-        engine = LLMEngine(ecfg)
-        out = {"attention": engine.runner.device_report().get("attention"),
-               "kv_dtype": str(engine.runner._kv_dtype)}
-        served = reference_check.served_logprobs(engine, prompts, n_out)
+        # (MLA: the served programs' own routing is tapped, and the
+        # reference follows it — reference_check.compare says why)
+        tap = reference_check.RoutingTap() if mla else None
+        with tap or contextlib.nullcontext():
+            engine = LLMEngine(ecfg)
+            out = {"attention":
+                   engine.runner.device_report().get("attention"),
+                   "kv_dtype": str(engine.runner._kv_dtype)}
+            served = reference_check.served_logprobs(engine, prompts, n_out)
         out["engine_s"] = round(time.monotonic() - t0, 1)
         # the reference needs the weights as served and room for one
         # layer in float32: let the pool and the programs go
@@ -784,8 +822,14 @@ def child_reference(args) -> int:
         engine.runner.kv_caches = None
         del engine
         t0 = time.monotonic()
+        # a long sequence's routing through the system's own model code
+        # goes in the served chunks and through the served attention
         out.update(reference_check.compare(
-            reference, ecfg.model, params, prompts, served))
+            reference, ecfg.model, params, prompts, served,
+            routing_chunk=chunk if mla else None,
+            routing_backend=(
+                out["attention"]["prefill"]["resolved"] if mla else "xla"),
+            served_routing=tap))
         out["reference_s"] = round(time.monotonic() - t0, 1)
         return out
 
@@ -795,13 +839,19 @@ def child_reference(args) -> int:
         "routing_disagreement_share" not in served
         or (served["routing_disagreement_share"] <= ROUTING_DISAGREEMENT_MAX
             and served["routing_margin_max_where_differing"]
-            <= ROUTING_MARGIN_MAX))
-    report["ok"] = bool(
-        served["max_abs_dlogprob"] <= REFERENCE_ATOL and routing_ok)
+            <= margin_max))
+    report["ok"] = bool(served["max_abs_dlogprob"] <= atol and routing_ok)
     if args.fp8_arm:
-        fp8 = report["fp8_pool"] = arm(["--kv-cache-dtype", "fp8"])
+        # (a quantized pool resolves 'auto' to XLA attention, whose
+        # gathered history and scores a long-document prompt cannot
+        # afford: the latent kernels widen fp8 pages themselves)
+        kernels = (["--attention-backend", "pallas",
+                    "--prefill-attention-backend", "pallas"]
+                   if mla and not args.rehearse else [])
+        fp8 = report["fp8_pool"] = arm(
+            ["--kv-cache-dtype", "fp8", *kernels])
         report["ok"] = report["ok"] and bool(
-            args.rehearse or fp8["max_abs_dlogprob"] > REFERENCE_ATOL)
+            args.rehearse or fp8["max_abs_dlogprob"] > atol)
     emit(report)
     return 0 if report["ok"] else 1
 

@@ -401,3 +401,139 @@ def test_moe_decode_step_compiles_for_v5e(topo):
     _assert_kernel(compiled)
     assert "paged_decode_attention" in text
     _assert_no_widened_experts(cfg, text)
+
+
+# -- a latent-attention (MLA) model's kernels and step programs (ISSUE 31) ----
+
+
+@pytest.mark.parametrize("kv_dtype,width,window", [
+    ("bfloat16", 1024, 8),  # the cell: one table width, max_model_len / 32
+    ("bfloat16", 1024, 1),  # the 1-, 2-, 4-step windows the warm-up builds:
+    ("bfloat16", 1024, 2),  # their staged latents are padded to a tile
+    ("bfloat16", 64, 4),
+    ("float8_e4m3fn", 1024, 8),  # the reference check's lower-precision arm
+])
+def test_latent_decode_kernel_compiles_for_v5e(topo, kv_dtype, width, window):
+    from vllm_production_stack_tpu.ops import mla_attention_pallas as mk
+
+    sds = _one_chip(topo)
+    args = (
+        sds((16, 32, 640), jnp.bfloat16, None),
+        sds((1, 8192, 32, 640), jnp.dtype(kv_dtype), None),
+        sds((16, width), jnp.int32, None), sds((16,), jnp.int32, None),
+        sds((16, window, 640), jnp.bfloat16, None), sds((), jnp.int32, None),
+    )
+    compiled = jax.jit(lambda *a: mk.mla_decode_attention(
+        *a, scale=192 ** -0.5, rank=512)).lower(*args).compile()
+    _assert_kernel(compiled)
+    assert "mla_decode_attention" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kv_dtype,rows", [
+    ("bfloat16", 1), ("bfloat16", 16), ("float8_e4m3fn", 2)])
+def test_latent_prefill_kernel_compiles_for_v5e(topo, kv_dtype, rows):
+    """A 2,048-token chunk, 32 heads x 640: the query tile (128 tokens, 4,096
+    rows of the two dots) within the VMEM the kernel asks for."""
+    from vllm_production_stack_tpu.ops import mla_attention_pallas as mk
+
+    sds = _one_chip(topo)
+    args = (
+        sds((rows, 2048, 32, 640), jnp.bfloat16, None),
+        sds((1, 8192, 32, 640), jnp.dtype(kv_dtype), None),
+        sds((rows, 1024), jnp.int32, None), sds((rows,), jnp.int32, None),
+        sds((rows,), jnp.int32, None),
+    )
+    compiled = jax.jit(lambda *a: mk.mla_prefill_attention(
+        *a, scale=192 ** -0.5, rank=512)).lower(*args).compile()
+    _assert_kernel(compiled)
+    assert "mla_prefill_attention" in compiled.as_text()
+    assert mk._prefill_tile(2048, 8, 32, 32, 640, 512, 2, 2) == 128
+
+
+def _kanana(layers=2):
+    import dataclasses
+
+    from vllm_production_stack_tpu.models.registry import resolve_model_config
+
+    # layer 0 dense, layer 1 an expert layer: both MLP stacks
+    return dataclasses.replace(
+        resolve_model_config("kanana-2-30b-a3b-8l"), num_layers=layers)
+
+
+def test_latent_prefill_program_compiles_for_v5e(topo):
+    """Kanana-2-30B-A3B's served prefill step at published widths (two
+    layers deep): two rows packed on 2,048 positions, the 1,024-page table,
+    the latent kernel, the routed pairs through the grouped matmul (2,048
+    tokens is above _ALL_EXPERTS_MAX_TOKENS), and no (rows, heads, T, S)
+    score array nor gathered history."""
+    import re
+
+    from vllm_production_stack_tpu.models import llama
+
+    cfg = _kanana()
+    one = SingleDeviceSharding(topo.devices[0])
+    on_chip = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one), tree)
+    params = on_chip(jax.eval_shape(
+        lambda k: llama.init_params(cfg, k), jax.random.PRNGKey(0)))
+    kv = on_chip(jax.eval_shape(lambda: llama.init_kv_cache(cfg, 8192, 32)))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+    rows, n, nb = 2, 2048, 1024
+    nbw = (n - 1) // 32 + 2
+
+    def step(params, kv, tokens, positions, tables, lens, chunk, ids, off):
+        stats: list = []
+        hidden, kv = llama.forward(
+            cfg, params, tokens, positions, kv, tables,
+            jnp.zeros((1,), jnp.int32), lens,
+            write_blocks={"ids": ids, "start_off": off, "chunk_lens": chunk},
+            backend="pallas", moe_stats=stats,
+            pack=llama.pack_index(chunk, lens, n))
+        return (llama.compute_logits(cfg, params, hidden[-rows:, 0]), kv,
+                sum(s["touched"] for s in stats))
+
+    compiled = jax.jit(step, donate_argnums=1).lower(
+        params, kv, i32(n), i32(n), i32(rows, nb), i32(rows), i32(rows),
+        i32(rows, nbw), i32(rows)).compile()
+    text = compiled.as_text()
+    assert "mla_prefill_attention" in text and "ragged-dot" in text
+    assert "paged_prefill_attention" not in text
+    held = [line[:160] for line in text.splitlines()
+            if re.search(rf"\[{rows},32,{n},{nb * 32}\]", line)
+            or re.search(rf"\[{rows},{nb * 32},(1,)?640\]", line)]
+    assert not held, held
+    # two rows' query row views and results, the written pages: well under
+    # one row's float32 scores against a 16k context (4 GiB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+
+
+def test_latent_decode_step_compiles_for_v5e(topo):
+    """One decode step at 16 rows: the latent decode kernel, every expert on
+    every token (16 tokens), the shared experts, the leading dense layer."""
+    from vllm_production_stack_tpu.models import llama
+
+    cfg = _kanana()
+    one = SingleDeviceSharding(topo.devices[0])
+    on_chip = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one), tree)
+    params = on_chip(jax.eval_shape(
+        lambda k: llama.init_params(cfg, k), jax.random.PRNGKey(0)))
+    kv = on_chip(jax.eval_shape(lambda: llama.init_kv_cache(cfg, 8192, 32)))
+    staged = on_chip(jax.eval_shape(lambda: llama.init_staged_kv(cfg, 8, 16)))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+    def step(params, kv, staged, tokens, positions, tables, hist):
+        stats: list = []
+        hidden, staged = llama.decode_window_step(
+            cfg, params, tokens, positions, kv, tables, staged,
+            jnp.int32(0), hist, backend="pallas", moe_stats=stats)
+        return (llama.compute_logits(cfg, params, hidden), staged,
+                sum(s["touched"] for s in stats))
+
+    compiled = jax.jit(step).lower(
+        params, kv, staged, i32(16), i32(16), i32(16, 1024), i32(16),
+    ).compile()
+    text = compiled.as_text()
+    _assert_kernel(compiled)
+    assert "mla_decode_attention" in text
+    assert "paged_decode_attention" not in text and "ragged-dot" not in text

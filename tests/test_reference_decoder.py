@@ -96,13 +96,17 @@ def test_reference_refuses_what_it_does_not_compute():
         reference.published_keys(ModelConfig.tiny(sliding_window=16))
 
 
-def test_benchmark_copy_is_byte_identical():
-    """benchmarks/chip/reference/decoder.py is the yardstick's own copy: a
-    later PR that edits the package's file has to edit the benchmark too."""
+@pytest.mark.parametrize("package_file,benchmark_file", [
+    ("reference_decoder.py", "decoder.py"),  # the Llama lineage
+    ("reference_mla.py", "mla.py"),  # the DeepSeek-V3 family (ISSUE 31)
+])
+def test_benchmark_copy_is_byte_identical(package_file, benchmark_file):
+    """benchmarks/chip/reference/<file> is the yardstick's own copy: a later
+    PR that edits the package's file has to edit the benchmark too."""
     assert filecmp.cmp(
         os.path.join(REPO, "vllm_production_stack_tpu", "testing",
-                     "reference_decoder.py"),
-        os.path.join(REPO, "benchmarks", "chip", "reference", "decoder.py"),
+                     package_file),
+        os.path.join(REPO, "benchmarks", "chip", "reference", benchmark_file),
         shallow=False)
 
 

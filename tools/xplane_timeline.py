@@ -32,7 +32,10 @@ import sys
 SCOPES = ("embed", "attn_qkv", "kv_stage", "attn", "attn_out", "mlp",
           "final_norm", "lm_head", "sample", "kv_commit", "gather_pages",
           # inside `mlp` of a sparse-MoE layer (models/llama.py:_moe_mlp)
-          "moe_router", "moe_dispatch", "moe_experts", "moe_combine")
+          "moe_router", "moe_dispatch", "moe_experts", "moe_combine",
+          # an MLA layer (models/llama.py:_mla_attention) and the shared
+          # experts of a DeepSeek-V3-style expert layer
+          "mla_q", "mla_latent", "mla_absorb", "moe_shared")
 # the benchmark's own names for an operation and a program, and its union of
 # intervals: this reduction must agree with benchmarks/chip/trace_reduce.py
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),

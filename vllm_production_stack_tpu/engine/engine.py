@@ -135,6 +135,36 @@ def _work_kind(work) -> str:
     return "verify" if isinstance(work, VerifyWork) else "decode"
 
 
+def _refuse_kv_tiers_for_latent_pool(config: EngineConfig) -> None:
+    """Every KV tier below the pool was written against (2, kvH, D) pages;
+    none has been run on a one-latent-per-token page (engine/kv_codec.py
+    groups along head_dim, the disk/remote/peer wires carry the K/V page
+    geometry in their fingerprinted frames). Until each is, an MLA model
+    refuses it at start with one sentence instead of mis-sizing silently."""
+    cache = config.cache
+    asked = {
+        "the host-RAM tier (--num-host-blocks / --host-kv-gib, "
+        "engine/kv_host_tier.py)": (
+            cache.num_host_blocks > 0 or cache.host_kv_gib > 0),
+        "the disk tier (--disk-kv-dir, engine/kv_disk_tier.py)": bool(
+            cache.disk_kv_dir and cache.disk_kv_gib > 0),
+        "the remote KV store (--remote-kv-url, kvstore/)": bool(
+            cache.remote_kv_url),
+        "the peer-engine tier (--kv-peer-fetch, engine/kv_peer.py, "
+        "engine/kv_device_transfer.py)": config.kv_peer_fetch,
+        "the at-rest codec (--kv-at-rest-codec, engine/kv_codec.py)": (
+            cache.kv_at_rest_codec != "none"),
+    }
+    hit = [name for name, on in asked.items() if on]
+    if hit:
+        raise ValueError(
+            f"{config.model.model} keeps one latent a token in its pool "
+            f"(multi-head latent attention); {'; '.join(hit)} "
+            f"{'is' if len(hit) == 1 else 'are'} not implemented for a "
+            "latent page: run without"
+        )
+
+
 class LLMEngine:
     def __init__(
         self,
@@ -175,6 +205,8 @@ class LLMEngine:
                     ),
                 )
             )
+        if config.model.mla:
+            _refuse_kv_tiers_for_latent_pool(config)
         self.config = config
         self.tokenizer = tokenizer or TokenizerWrapper(
             config.model.tokenizer or config.model.checkpoint
@@ -1315,6 +1347,11 @@ class LLMEngine:
                 # packed axis with its padding (rows x bucket where the
                 # chunk is row-major); tokens / positions = the fill share
                 rec.count("prefill_positions", handle.positions)
+                # (query, key) pairs its attention covers: a chunk's query
+                # at position p sees keys 0..p (chunks are contiguous)
+                rec.count("attn_prefill_pairs_n", sum(
+                    len(p) * int(p[0]) + len(p) * (len(p) + 1) // 2
+                    for p in work.positions if len(p)))
                 self._meter_prefill(work, handle.positions)
             else:
                 # tokens actually ACCEPTED — exact for both the fused
@@ -1528,7 +1565,7 @@ class LLMEngine:
         have never moved bytes."""
         from .kv_flow import TRANSFER_TIERS
         from .memory import kv_block_bytes
-        from .saturation import matmul_params
+        from .saturation import attn_flops_per_token_ctx, matmul_params
 
         cfg = self.config.model
 
@@ -1580,9 +1617,7 @@ class LLMEngine:
             # analytic model charges (saturation.step_flops), so the
             # planner prices long-context recompute with the context term
             # the achieved-FLOP/s denominator was measured against
-            "attn_flops_per_token_ctx": (
-                4.0 * cfg.num_heads * cfg.head_dim * cfg.num_layers
-            ),
+            "attn_flops_per_token_ctx": attn_flops_per_token_ctx(cfg),
             "block_bytes": block_bytes,
             "wire_block_bytes": {
                 "hbm": block_bytes,

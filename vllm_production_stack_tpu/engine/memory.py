@@ -45,6 +45,41 @@ def qk_norm_params(cfg: ModelConfig) -> int:
     return 2 * cfg.head_dim if cfg.qk_norm else 0
 
 
+def layer_matrix_params(cfg: ModelConfig) -> dict:
+    """Matrix parameters of the stacked tree by kind — the ONE count that
+    `param_bytes` (what the tree weighs) and saturation.matmul_params (what
+    a token multiplies through) both read. "attn": one layer's attention
+    matrices (MLA: W_q, W_kv_a, W_kv_b, W_o); "dense_mlp": one dense SwiGLU;
+    "expert": ONE expert's SwiGLU; "shared": the shared experts' SwiGLU;
+    "router": h x E; "dense_layers" / "moe_layers": how many layers carry
+    which MLP."""
+    h = cfg.hidden_size
+    if cfg.mla:
+        nh = cfg.num_heads
+        attn = (
+            h * nh * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)  # W_q
+            + h * cfg.latent_dim  # W_kv_a
+            + cfg.kv_lora_rank * nh * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+            + nh * cfg.v_head_dim * h  # W_o
+        )
+    else:
+        attn = (
+            h * cfg.num_heads * cfg.head_dim
+            + 2 * h * cfg.num_kv_heads * cfg.head_dim
+            + cfg.num_heads * cfg.head_dim * h
+        )
+    moe_layers = cfg.num_moe_layers
+    return {
+        "attn": attn,
+        "dense_mlp": 3 * h * cfg.intermediate_size,
+        "expert": 3 * h * cfg.expert_intermediate_size,
+        "shared": 3 * h * cfg.n_shared_experts * cfg.expert_intermediate_size,
+        "router": h * cfg.num_experts,
+        "dense_layers": cfg.num_layers - moe_layers,
+        "moe_layers": moe_layers,
+    }
+
+
 def param_bytes(cfg: ModelConfig, tp: int = 1, pp: int = 1,
                 ep: int = 1) -> int:
     """Per-device bytes of the stacked Llama param tree (models/llama.py
@@ -56,6 +91,20 @@ def param_bytes(cfg: ModelConfig, tp: int = 1, pp: int = 1,
         from ..models.quantization import quantized_param_bytes
 
         return quantized_param_bytes(cfg, tp, pp, ep)
+    if cfg.mla:
+        # one device (parallel layouts of the latent tree are refused at
+        # engine start): the tree of models/llama.py:_init_params_mla
+        m = layer_matrix_params(cfg)
+        h, L = cfg.hidden_size, cfg.num_layers
+        total = (
+            cfg.vocab_size * h * (1 if cfg.tie_word_embeddings else 2) + h
+            + L * (m["attn"] + 2 * h + cfg.kv_lora_rank)
+            + m["dense_layers"] * m["dense_mlp"]
+            + m["moe_layers"] * (
+                cfg.num_experts * m["expert"] + m["shared"] + m["router"])
+        ) * dtype_bytes(cfg.dtype)
+        # the selection bias is float32 whatever the model's dtype
+        return total + m["moe_layers"] * cfg.num_experts * 4
     h, hd = cfg.hidden_size, cfg.head_dim
     nh, nkv, it, L = cfg.num_heads, cfg.num_kv_heads, cfg.intermediate_size, cfg.num_layers
     attn = h * nh * hd + 2 * h * nkv * hd + nh * hd * h
@@ -82,6 +131,13 @@ def kv_block_bytes(cfg: ModelConfig, block_size: int, tp: int = 1,
     layer's pages for 1/pp of the blocks — per-device cost of adding a
     block is therefore 1/pp of its global bytes. kv_dtype overrides the
     model dtype when the pool is quantized (CacheConfig.kv_cache_dtype)."""
+    if cfg.mla:
+        # one latent a token and layer, `latent_width` values as stored
+        # (the zero padding to whole lane tiles is real HBM, so it counts)
+        return max(1, (
+            cfg.num_layers * block_size * cfg.latent_width
+            * dtype_bytes(kv_dtype or cfg.dtype)
+        ) // pp)
     kvh = max(1, cfg.num_kv_heads // tp)
     return max(1, (
         cfg.num_layers * 2 * block_size * kvh * cfg.head_dim

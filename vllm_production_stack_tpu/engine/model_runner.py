@@ -85,6 +85,7 @@ def resolve_auto_attention_backend(
 def resolve_auto_prefill_backend(
     *, block_size: int, num_kv_heads: int, head_dim: int, kv_itemsize: int,
     kv_quantized: bool, mesh_size: int, platform: str,
+    latent_pages: bool = False,
 ) -> str:
     """The 'auto' PREFILL-attention choice, independent of decode, as a pure
     predicate of static shapes: the paged flash-prefill kernel
@@ -123,9 +124,16 @@ def resolve_auto_prefill_backend(
     a 64-page table is the one place XLA wins (28/4 and 16/16 heads, 1.7-1.9x:
     the kernel's f32 softmax arithmetic, not its bytes), and in the
     128-page table that row is a tie.
+
+    `latent_pages`: the pool holds one latent a token (MLA) and the kernel
+    is ops/mla_attention_pallas.py's — a page is (block_size, latent_width),
+    whole lane tiles by construction and whole sublane tiles at the page
+    sizes the gate admits; it beat both XLA forms at 2k, 8k and 16k of
+    context (benchmarks/sweep_mla.py, PERF.md §6 PR 31).
     """
     if (
-        _pages_fill_tiles(num_kv_heads, head_dim, kv_itemsize)
+        (latent_pages
+         or _pages_fill_tiles(num_kv_heads, head_dim, kv_itemsize))
         and not kv_quantized
         and block_size >= 32
         and mesh_size == 1
@@ -133,6 +141,43 @@ def resolve_auto_prefill_backend(
     ):
         return "pallas"
     return "xla"
+
+
+def _refuse_unbuilt_for_latent_attention(config: EngineConfig) -> None:
+    """A multi-head-latent-attention model (models/llama.py:_init_params_mla)
+    runs on ONE device, unquantized, without adapters: what has no layout
+    for its leaves or its latent pool is refused by name at start, never
+    guessed."""
+    cfg, name = config.model, config.model.model
+    if config.parallel.world_size > 1:
+        raise ValueError(
+            f"{name}: tensor/data/pipeline/sequence/expert parallelism is not "
+            "implemented for multi-head latent attention — parallel/"
+            "sharding.py has no spec for its leaves (attn.wq, wkv_a, "
+            "kv_a_norm, wkv_b; moe.bias, moe.shared; the mlp/moe stacks of "
+            "unequal depth) nor for the one-latent-per-token pool, which has "
+            "no kv-head axis to shard"
+        )
+    if cfg.quantization:
+        raise ValueError(
+            f"{name}: --quantization {cfg.quantization} is not implemented "
+            "for multi-head latent attention — models/quantization.py names "
+            "no int8 form for attn.wkv_a / attn.wkv_b (the absorbed "
+            "projections read W_kv_b per head) or moe.shared"
+        )
+    if config.lora.max_loras > 0:
+        raise ValueError(
+            f"{name}: LoRA adapters are not implemented for multi-head "
+            "latent attention (its projections are wq, wkv_a, wkv_b)"
+        )
+    if config.scheduler.num_speculative_tokens and (
+        config.scheduler.speculative_method == "draft"
+    ):
+        raise ValueError(
+            f"{name}: a draft model sharing a latent pool is not implemented "
+            "(the draft's K/V pages have another shape); use the n-gram "
+            "proposer"
+        )
 
 
 class StepHandle:
@@ -230,6 +275,8 @@ class ModelRunner:
     ):
         self.config = config
         cfg = config.model
+        if cfg.mla:
+            _refuse_unbuilt_for_latent_attention(config)
         pp = config.parallel.pipeline_parallel_size
         if pp > 1:
             # friendly errors beat JAX's deep 'dimension not divisible'
@@ -282,7 +329,9 @@ class ModelRunner:
             self.params = self._init_device_params(param_shardings)
         else:
             self.params = jax.tree.map(jax.device_put, params, param_shardings)
-        kv_sharding = NamedSharding(self.mesh, kv_cache_spec())
+        # (a latent pool has no kv-head axis; it runs on one device)
+        kv_sharding = NamedSharding(
+            self.mesh, P() if cfg.mla else kv_cache_spec())
         self._kv_dtype = config.cache.resolved_kv_dtype(cfg.dtype)
         self.kv_caches = jax.jit(
             lambda: llama.init_kv_cache(
@@ -576,15 +625,23 @@ class ModelRunner:
                 )
             return "xla"
         kv_itemsize = jnp.dtype(self._kv_dtype).itemsize
+        # an MLA model's kernel is the latent one (ops/mla_attention_pallas
+        # .py), the one prefill form kept for it — the XLA form is the CPU
+        # fallback and the kernel's oracle; its pages are (block_size,
+        # latent_width), one "kv head" wide
+        kv_heads, head_dim = (
+            (1, model.latent_width) if model.mla
+            else (model.num_kv_heads, model.head_dim))
         if backend == "auto":
             return resolve_auto_prefill_backend(
                 block_size=self.config.cache.block_size,
-                num_kv_heads=model.num_kv_heads,
-                head_dim=model.head_dim,
+                num_kv_heads=kv_heads,
+                head_dim=head_dim,
                 kv_itemsize=kv_itemsize,
                 kv_quantized=self._kv_dtype != model.dtype,
                 mesh_size=self.mesh.size,
                 platform=jax.devices()[0].platform,
+                latent_pages=model.mla,
             )
         if backend not in ("xla", "pallas", "pallas_interpret"):
             raise ValueError(
@@ -597,7 +654,7 @@ class ModelRunner:
                 "num_heads and num_kv_heads divisible by tp, and a dp/tp "
                 "mesh (pp/sp/ep shard axes the kernel cannot split)"
             )
-        if backend == "pallas" and not _pages_fill_tiles(
+        if backend == "pallas" and not model.mla and not _pages_fill_tiles(
             model.num_kv_heads // tp, model.head_dim, kv_itemsize
         ):
             raise ValueError(
@@ -950,6 +1007,7 @@ class ModelRunner:
             s_ctx = block_tables.shape[1] * self.config.cache.block_size
             hoist = (
                 self._attention_backend == "xla"
+                and not cfg.mla  # (the latent fallback gathers per step)
                 and self._hoist_bytes(b, s_ctx) <= self._hoist_budget
             )
             hists = None
@@ -1852,7 +1910,8 @@ class ModelRunner:
         `steps` times: the pairs and the (layer-step, expert) slots follow
         from shapes, only the touched experts come from the device."""
         cfg = self.config.model
-        layer_steps = cfg.num_layers * steps
+        # expert layers only: leading dense layers route nothing
+        layer_steps = cfg.num_moe_layers * steps
         return (touched, kind, tokens * cfg.num_experts_per_tok * layer_steps,
                 layer_steps * cfg.num_experts)
 

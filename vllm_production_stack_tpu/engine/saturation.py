@@ -134,32 +134,41 @@ class GoodputLedger:
 def matmul_params(cfg) -> int:
     """Dense matmul parameters touched per token (embedding GATHER
     excluded, unembedding matmul included — computed whether or not the
-    weights are tied). For MoE, only the activated experts count."""
-    h = cfg.hidden_size
-    attn = (
-        h * cfg.num_heads * cfg.head_dim  # q
-        + 2 * h * cfg.num_kv_heads * cfg.head_dim  # k, v
-        + cfg.num_heads * cfg.head_dim * h  # o
+    weights are tied), from the same per-kind count `memory.param_bytes`
+    weighs the tree with. For MoE, only the activated experts count, plus
+    the shared experts and the router on every expert layer; leading dense
+    layers count their dense MLP."""
+    from .memory import layer_matrix_params
+
+    m = layer_matrix_params(cfg)
+    moe = (cfg.num_experts_per_tok * m["expert"] + m["shared"] + m["router"])
+    return (
+        cfg.num_layers * m["attn"] + m["dense_layers"] * m["dense_mlp"]
+        + m["moe_layers"] * moe + cfg.vocab_size * cfg.hidden_size
     )
-    if cfg.num_experts > 0:
-        mlp = (
-            cfg.num_experts_per_tok * 3 * h * cfg.intermediate_size
-            + h * cfg.num_experts  # router
-        )
+
+
+def attn_flops_per_token_ctx(cfg) -> float:
+    """Attention score/value FLOPs per (token, attended position) pair,
+    all layers: QK^T and PV over each head's widths (MLA, as published:
+    qk_nope + qk_rope for the scores, v_head for the values)."""
+    if cfg.mla:
+        per_head = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+                    + cfg.v_head_dim)
     else:
-        mlp = 3 * h * cfg.intermediate_size
-    return cfg.num_layers * (attn + mlp) + cfg.vocab_size * h
+        per_head = 2 * cfg.head_dim
+    return 2.0 * cfg.num_heads * per_head * cfg.num_layers
 
 
 def step_flops(cfg, n_tokens: int, sum_context: int) -> float:
     """Forward-pass FLOPs for one dispatch: 2 × matmul-params per token
-    plus the attention score/value term (4 × n_heads × head_dim per layer
-    per (token, context-position) pair). `sum_context` is the summed
+    plus the attention score/value term (`attn_flops_per_token_ctx` per
+    (token, context-position) pair). `sum_context` is the summed
     attended context length over the dispatch's tokens — an estimate, like
     every MFU number."""
     return (
         2.0 * matmul_params(cfg) * n_tokens
-        + 4.0 * cfg.num_heads * cfg.head_dim * cfg.num_layers * sum_context
+        + attn_flops_per_token_ctx(cfg) * sum_context
     )
 
 

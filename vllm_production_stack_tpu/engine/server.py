@@ -219,6 +219,21 @@ class EngineServer:
 
     # -- app wiring --------------------------------------------------------
 
+    def _refuse_for_latent_pool(self, handler):
+        if not self.engine.config.model.mla:
+            return handler
+
+        async def refused(request: web.Request) -> web.Response:
+            return web.json_response({"error": {
+                "message": (
+                    f"{request.path}: KV page transfer is not implemented "
+                    f"for {self.engine.config.model.model}, whose pool holds "
+                    "one latent a token (multi-head latent attention), not "
+                    "K and V pages"),
+                "type": "not_implemented"}}, status=501)
+
+        return refused
+
     def build_app(self) -> web.Application:
         app = web.Application(client_max_size=64 * 1024 * 1024)
         r = app.router
@@ -249,14 +264,18 @@ class EngineServer:
         r.add_post("/v1/unload_lora_adapter", self.unload_lora_adapter)
         r.add_post("/kv/lookup", self.kv_lookup)
         r.add_post("/kv/peer_contains", self.kv_peer_contains)
-        r.add_post("/kv/peer_fetch", self.kv_peer_fetch)
-        r.add_post("/kv/peer_device_pull", self.kv_peer_device_pull)
-        r.add_post("/kv/peer_replicate", self.kv_peer_replicate)
+        # the endpoints that move KV pages between engines ship (2, kvH, D)
+        # pages: an engine whose pool holds latents refuses them, one
+        # sentence, instead of mis-sizing a frame
+        moves = self._refuse_for_latent_pool
+        r.add_post("/kv/peer_fetch", moves(self.kv_peer_fetch))
+        r.add_post("/kv/peer_device_pull", moves(self.kv_peer_device_pull))
+        r.add_post("/kv/peer_replicate", moves(self.kv_peer_replicate))
         r.add_post("/kv/replicated", self.kv_replicated)
-        r.add_post("/kv/export", self.kv_export)
-        r.add_post("/kv/export_stream", self.kv_export_stream)
-        r.add_post("/kv/import", self.kv_import)
-        r.add_post("/kv/pull", self.kv_pull)
+        r.add_post("/kv/export", moves(self.kv_export))
+        r.add_post("/kv/export_stream", moves(self.kv_export_stream))
+        r.add_post("/kv/import", moves(self.kv_import))
+        r.add_post("/kv/pull", moves(self.kv_pull))
         r.add_post("/tokenize", self.tokenize)
         r.add_post("/detokenize", self.detokenize)
         r.add_get("/version", self.version)

@@ -31,6 +31,16 @@ from ..ops.attention import (
     write_kv_pages,
     write_kv_pages_blockwise,
 )
+from ..ops.mla_attention import (
+    latent_attention_with_staged,
+    latent_attention_xla,
+    write_latent_pages,
+    write_latent_pages_blockwise,
+)
+from ..ops.mla_attention_pallas import (
+    mla_decode_attention,
+    mla_prefill_attention,
+)
 from ..ops.paged_attention_pallas import (
     paged_decode_attention,
     paged_decode_attention_sharded,
@@ -43,8 +53,29 @@ def _dtype(cfg: ModelConfig):
     return jnp.dtype(cfg.dtype)
 
 
+# A test's tap on the routing of an MoE model's programs: when set (before a
+# program is TRACED) to a host function, `forward` and `decode_window_step`
+# hand it, per execution, (token ids, positions, each expert layer's chosen
+# experts stacked (Lm, tokens, k)). None — always, outside
+# testing/reference_check.py — traces nothing: the served programs are what
+# they are without it.
+ROUTING_TAP = None
+
+
+def _tap_routing(token_ids, positions, layers_routing: list) -> None:
+    if ROUTING_TAP is not None and layers_routing:
+        experts = jnp.stack(
+            [r["experts"].reshape(-1, r["experts"].shape[-1])
+             for r in layers_routing])
+        jax.debug.callback(
+            ROUTING_TAP, token_ids.reshape(-1), positions.reshape(-1),
+            experts)
+
+
 def init_params(cfg: ModelConfig, rng: jax.Array) -> dict:
     """Random-init a stacked param tree (tests + benchmarks without weights)."""
+    if cfg.mla:
+        return _init_params_mla(cfg, rng)
     L = cfg.num_layers
     h, hd = cfg.hidden_size, cfg.head_dim
     nh, nkv, it = cfg.num_heads, cfg.num_kv_heads, cfg.intermediate_size
@@ -110,6 +141,74 @@ def init_params(cfg: ModelConfig, rng: jax.Array) -> dict:
     return params
 
 
+def _init_params_mla(cfg: ModelConfig, rng: jax.Array) -> dict:
+    """The tree of a DeepSeek-V3-family model (Kanana-2): latent attention
+    in every layer; a dense SwiGLU in the first `first_k_dense_replace`
+    layers and experts in the rest, so the MLP leaves are TWO stacks —
+    "mlp" over the dense layers, "moe" over the expert layers (`_layer_params`
+    indexes each by its own count; `_grouped_mm`'s L*E groups are the expert
+    layers') — beside the attention leaves and norms stacked over all L.
+
+    attn: wq (L, h, heads*(nope+rope)), head-major, each head nope ‖ rope;
+    wkv_a (L, h, rank+rope): the kv latent ‖ the shared rope key; kv_a_norm
+    (L, rank); wkv_b (L, rank, heads*(nope+v)): each head k_nope ‖ v; wo
+    (L, heads*v, h). Rope columns are in rotate-half order (a checkpoint's
+    interleaved pairs are de-interleaved at load, models/loader.py).
+    moe: router (Lm, h, E), bias (Lm, E) float32 — the selection bias, drawn
+    NON-zero here: a zero bias would leave the rule that it moves the choice
+    and never the weight untested; small (std 0.02 beside sigmoid scores
+    that spread ~0.2 a token) as a trained router's is — it exists to
+    BALANCE the experts, and at std 0.1 it concentrated a random router's
+    choices on the experts it favoured (18% of experts touched a decode
+    step where independent choices give 28%; my chip run, PR 31) —
+    gate/up/down (Lm, E, ...), and "shared",
+    one SwiGLU of n_shared_experts x the experts' width."""
+    L, h, nh = cfg.num_layers, cfg.hidden_size, cfg.num_heads
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    r, e = cfg.kv_lora_rank, cfg.num_experts
+    k_dense = cfg.first_k_dense_replace if e else L
+    lm, it, mit = L - k_dense, cfg.intermediate_size, cfg.expert_intermediate_size
+    dt = _dtype(cfg)
+    keys = iter(jax.random.split(rng, 24))
+
+    def w(*shape, scale=None):
+        scale = scale if scale is not None else shape[-2] ** -0.5
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * scale).astype(dt)
+
+    layers: dict[str, Any] = {
+        "attn": {
+            "wq": w(L, h, nh * (dn + dr)),
+            "wkv_a": w(L, h, r + dr),
+            "kv_a_norm": jnp.ones((L, r), dt),
+            "wkv_b": w(L, r, nh * (dn + dv)),
+            "wo": w(L, nh * dv, h),
+        },
+        "input_norm": jnp.ones((L, h), dt),
+        "post_attn_norm": jnp.ones((L, h), dt),
+    }
+    if k_dense:
+        layers["mlp"] = {"gate": w(k_dense, h, it), "up": w(k_dense, h, it),
+                         "down": w(k_dense, it, h)}
+    if lm:
+        sit = cfg.n_shared_experts * mit
+        layers["moe"] = {
+            "router": w(lm, h, e),
+            "bias": 0.02 * jax.random.normal(next(keys), (lm, e), jnp.float32),
+            "gate": w(lm, e, h, mit), "up": w(lm, e, h, mit),
+            "down": w(lm, e, mit, h),
+        }
+        if sit:
+            layers["moe"]["shared"] = {
+                "gate": w(lm, h, sit), "up": w(lm, h, sit),
+                "down": w(lm, sit, h)}
+    params = {"embed": w(cfg.vocab_size, h, scale=0.02), "layers": layers,
+              "final_norm": jnp.ones((h,), dt)}
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = w(h, cfg.vocab_size, scale=0.02)
+    return params
+
+
 def rms_norm(
     x: jax.Array, weight: jax.Array, eps: float, add_one: bool = False,
     scale_f32: bool = False,
@@ -160,17 +259,19 @@ def init_kv_cache(
     cfg: ModelConfig, num_blocks: int, block_size: int, dtype: Any | None = None
 ) -> tuple[jax.Array, ...]:
     """Paged pool: a TUPLE of per-layer (2, num_blocks, block_size, kvH, D)
-    arrays, NOT one stacked array. Per-layer leaves let jit donation alias
+    arrays (MLA: (1, num_blocks, block_size, latent_width)), NOT one stacked
+    array. Per-layer leaves let jit donation alias
     each layer's pool in place; a stacked pool updated inside a scan forces
     XLA to hold a second full-pool buffer (observed +9.8 GiB on a
     utilization-sized pool — an instant OOM)."""
     dt = jnp.dtype(dtype) if dtype is not None else _dtype(cfg)
-    return tuple(
-        jnp.zeros(
-            (2, num_blocks, block_size, cfg.num_kv_heads, cfg.head_dim), dt
-        )
-        for _ in range(cfg.num_layers)
-    )
+    if cfg.mla:
+        # ONE plane a layer: a token's latent (ops/mla_attention.py), no
+        # second copy of it as "V"
+        shape = (1, num_blocks, block_size, cfg.latent_width)
+    else:
+        shape = (2, num_blocks, block_size, cfg.num_kv_heads, cfg.head_dim)
+    return tuple(jnp.zeros(shape, dt) for _ in range(cfg.num_layers))
 
 
 def lora_module_dims(cfg: ModelConfig) -> dict[str, tuple[int, int]]:
@@ -178,6 +279,10 @@ def lora_module_dims(cfg: ModelConfig) -> dict[str, tuple[int, int]]:
     attention projections (per-expert MLP LoRA would need per-expert deltas —
     the MoE path never consults the adapter tree, so advertising mlp modules
     there would be a silent no-op)."""
+    if cfg.mla:
+        raise ValueError(
+            "LoRA adapters are not implemented for multi-head latent "
+            f"attention ({cfg.model}): its projections are wq, wkv_a, wkv_b")
     h, hd = cfg.hidden_size, cfg.head_dim
     nh, nkv, it = cfg.num_heads, cfg.num_kv_heads, cfg.intermediate_size
     dims = {
@@ -268,6 +373,9 @@ def _layer_body(
 
     res = x
     ap = lp["attn"]
+    if cfg.mla:
+        x = _mla_attention(cfg, ap, lp["input_norm"], x, positions, attend)
+        return _mlp_block(cfg, lp, res + x, proj, moe_stats)
     with jax.named_scope("attn_qkv"):
         if not cfg.post_norms_only:
             x = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps,
@@ -304,7 +412,56 @@ def _layer_body(
                                 cfg.rms_norm_eps, cfg.rms_norm_add_one,
                      scale_f32=cfg.norm_scale_f32)
         x = res + attn_out
+    return _mlp_block(cfg, lp, x, proj, moe_stats)
 
+
+def _mla_attention(cfg: ModelConfig, ap: dict, norm: jax.Array,
+                   x: jax.Array, positions: jax.Array, attend) -> jax.Array:
+    """Multi-head latent attention in the absorbed form, (B, T, h) -> the
+    attention block's output before the residual add.
+
+    Per token the layer makes its queries (heads x (nope ‖ rope), rope on
+    the rope part) and ONE latent: the normed kv latent c_kv (rank) ‖ the
+    roped key k_rope every head shares, zero padded to `latent_width` —
+    what the pool holds. The published equations expand c_kv through W_kv_b
+    into per-head k_nope and v; here W_kv_b's key half W_UK moves onto the
+    query (q_abs = W_UK^T q_nope ‖ q_rope: scores q_abs · latent equal
+    q_nope · k_nope + q_rope · k_rope) and its value half W_UV onto the
+    output (o_head = W_UV Σ p · c_kv): the same mathematics, with attention
+    itself multi-query over the latents. `attend(q_abs (B, T, heads, width),
+    latent (B, T, width), None)` stores the latents and returns
+    Σ p · c_kv, (B, T, heads, rank)."""
+    b, t, _ = x.shape
+    nh, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    pad = cfg.latent_width - cfg.latent_dim
+    w_kvb = ap["wkv_b"].reshape(r, nh, dn + dv)
+    with jax.named_scope("attn_qkv"):
+        x = rms_norm(x, norm, cfg.rms_norm_eps)
+        with jax.named_scope("mla_q"):
+            q = _mm(x, ap["wq"]).reshape(b, t, nh, dn + dr)
+            q_rope = apply_rope(q[..., dn:], positions, cfg.rope_theta)
+        with jax.named_scope("mla_latent"):
+            ckr = _mm(x, ap["wkv_a"])  # (B, T, rank + rope)
+            c_kv = rms_norm(ckr[..., :r], ap["kv_a_norm"], cfg.rms_norm_eps)
+            k_rope = apply_rope(
+                ckr[..., None, r:], positions, cfg.rope_theta)[..., 0, :]
+            latent = jnp.concatenate(
+                [c_kv, k_rope, jnp.zeros((b, t, pad), x.dtype)], axis=-1)
+        with jax.named_scope("mla_absorb"):
+            q_lat = jnp.einsum("bthd,rhd->bthr", q[..., :dn], w_kvb[..., :dn])
+            q_abs = jnp.concatenate(
+                [q_lat, q_rope, jnp.zeros((b, t, nh, pad), x.dtype)], axis=-1)
+    o_lat = attend(q_abs, latent, None)  # (B, T, heads, rank)
+    with jax.named_scope("attn_out"):
+        with jax.named_scope("mla_absorb"):
+            o = jnp.einsum("bthr,rhd->bthd", o_lat, w_kvb[..., dn:])
+        return _mm(o.reshape(b, t, nh * dv), ap["wo"])
+
+
+def _mlp_block(cfg: ModelConfig, lp: dict, x: jax.Array, proj,
+               moe_stats: list | None) -> jax.Array:
+    """The second half of a layer: norm, dense SwiGLU or experts, residual."""
     res = x
     with jax.named_scope("mlp"):
         if not cfg.post_norms_only:
@@ -381,21 +538,53 @@ def _grouped_mm(xs: jax.Array, w, layer: int, group_sizes: jax.Array,
     return out
 
 
-def _moe_route(cfg: ModelConfig, router: jax.Array, xf: jax.Array):
+def _moe_route(cfg: ModelConfig, router: jax.Array, xf: jax.Array,
+               bias: jax.Array | None = None):
     """(N, h) -> routing weights (N, k) float32, experts (N, k), their
     one-hot (N, k, E) and the pairs each expert got (E,)."""
     with jax.named_scope("moe_router"):
-        logits = (xf @ router).astype(jnp.float32)  # (N, E)
-        probs = jax.nn.softmax(logits, axis=-1)
-        topv, topi = jax.lax.top_k(probs, cfg.num_experts_per_tok)
-        if cfg.norm_topk_prob:
-            # Mixtral (always) / Qwen3-MoE norm_topk_prob=true: the selected
-            # weights renormalize to sum to 1; otherwise (OLMoE) they stay
-            # raw softmax mass
-            topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
+        if cfg.scoring_func == "sigmoid":
+            # the router's matmul itself in float32, as published
+            # (DeepseekV3TopkRouter): the top-k is a hard choice among 128
+            # scores a few thousandths apart and the chosen weights are
+            # renormalised, so a flipped boundary expert swaps a sixth of
+            # the routed output — a bf16 product's rounding (2^-8 of a
+            # logit) flipped 4% of tokens a layer (PERF.md §6, PR 31)
+            logits = jnp.dot(
+                xf.astype(jnp.float32), router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)  # (N, E)
+            topv, topi = _sigmoid_top_k(cfg, logits, bias)
+        else:
+            logits = (xf @ router).astype(jnp.float32)  # (N, E)
+            topv, topi = _softmax_top_k(cfg, logits)
         chosen = jax.nn.one_hot(topi, cfg.num_experts, dtype=jnp.float32)
         group_sizes = jnp.sum(chosen, axis=(0, 1)).astype(jnp.int32)
     return topv, topi, chosen, group_sizes
+
+
+def _sigmoid_top_k(cfg: ModelConfig, logits: jax.Array, bias: jax.Array):
+    """DeepSeek-V3 routing with one group: scores are sigmoid(logits) in
+    float32; the k experts are the top-k of scores + the selection bias
+    (ties to the lower index, jax.lax.top_k); their weights are the SCORES
+    (the bias moves the choice, never the weight), divided by their sum
+    under norm_topk_prob, times routed_scaling_factor."""
+    scores = jax.nn.sigmoid(logits)
+    _, topi = jax.lax.top_k(scores + bias, cfg.num_experts_per_tok)
+    topv = jnp.take_along_axis(scores, topi, axis=-1)
+    if cfg.norm_topk_prob:
+        topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
+    return topv * cfg.routed_scaling_factor, topi
+
+
+def _softmax_top_k(cfg: ModelConfig, logits: jax.Array):
+    probs = jax.nn.softmax(logits, axis=-1)
+    topv, topi = jax.lax.top_k(probs, cfg.num_experts_per_tok)
+    if cfg.norm_topk_prob:
+        # Mixtral (always) / Qwen3-MoE norm_topk_prob=true: the selected
+        # weights renormalize to sum to 1; otherwise (OLMoE) they stay
+        # raw softmax mass
+        topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
+    return topv, topi
 
 
 def _experts_all(cfg, mp, xf, topv, chosen):
@@ -466,13 +655,20 @@ def _moe_mlp(cfg: ModelConfig, mp: dict, x: jax.Array,
     b, t, h = x.shape
     n = b * t
     xf = x.reshape(n, h)
-    topv, topi, chosen, group_sizes = _moe_route(cfg, mp["router"], xf)
+    topv, topi, chosen, group_sizes = _moe_route(
+        cfg, mp["router"], xf, mp.get("bias"))
     if n <= _ALL_EXPERTS_MAX_TOKENS:
         y = _experts_all(cfg, mp, xf, topv, chosen)
     else:
         if stacked is None:
             stacked = (jax.tree.map(lambda a: a[None], mp), 0)
         y = _experts_grouped(cfg, *stacked, xf, topv, topi, group_sizes)
+    if "shared" in mp:
+        # the shared expert: one ungated SwiGLU on every token
+        with jax.named_scope("moe_shared"):
+            sp = mp["shared"]
+            y = y + _mm(_activation(cfg)(_mm(xf, sp["gate"]))
+                        * _mm(xf, sp["up"]), sp["down"])
     touched = jnp.sum((group_sizes > 0).astype(jnp.int32))
     return y.reshape(b, t, h), {
         "touched": touched, "experts": topi.reshape(b, t, -1)}
@@ -482,9 +678,21 @@ def _layer_params(params: dict, i: int) -> dict:
     """Layer i's slice of the stacked per-layer leaves (static slices, fused
     into their consumers), and for an MoE model the stacked expert leaves
     themselves beside it (see _grouped_mm)."""
-    lp = jax.tree.map(lambda a: a[i], params["layers"])
+    layers = params["layers"]
+    if "moe" in layers and "mlp" in layers:
+        # leading dense layers before the expert layers (_init_params_mla):
+        # two MLP stacks, each indexed by its own count
+        k = layers["mlp"]["gate"].shape[0]
+        kind, j = ("mlp", i) if i < k else ("moe", i - k)
+        lp = jax.tree.map(lambda a: a[i], {
+            name: leaf for name, leaf in layers.items()
+            if name not in ("mlp", "moe")})
+        lp[kind] = jax.tree.map(lambda a: a[j], layers[kind])
+    else:
+        j = i
+        lp = jax.tree.map(lambda a: a[i], layers)
     if "moe" in lp:
-        lp["moe_stacked"] = (params["layers"]["moe"], i)
+        lp["moe_stacked"] = (layers["moe"], j)
     return lp
 
 
@@ -559,6 +767,20 @@ def _layer(
     hd, nkv = cfg.head_dim, cfg.num_kv_heads
 
     def _attention(q):
+        if cfg.mla:
+            # q is the absorbed queries (B, T, heads, width)
+            if pallas_prefill is not None:
+                return mla_prefill_attention(
+                    q, kv_layer, block_tables,
+                    pallas_prefill["context_lens"],
+                    pallas_prefill["chunk_start"], scale=cfg.attn_scale,
+                    rank=cfg.kv_lora_rank,
+                    interpret=pallas_prefill["interpret"],
+                )
+            return latent_attention_xla(
+                q, kv_layer, block_tables, mask, scale=cfg.attn_scale,
+                rank=cfg.kv_lora_rank,
+            )
         if pallas_prefill is not None:
             mesh = pallas_prefill["mesh"]
             if mesh is not None and mesh.size > 1:
@@ -582,9 +804,20 @@ def _layer(
     def attend(q, k, v):
         nonlocal kv_layer
         if rows is not None:
-            q, k, v = q[:, 0], k[:, 0], v[:, 0]  # (N, heads, D)
+            q, k = q[:, 0], k[:, 0]  # (N, heads, D)
+            v = None if v is None else v[:, 0]
         with jax.named_scope("kv_stage"):
-            if write_blocks is not None:
+            if cfg.mla and write_blocks is not None:
+                # k is the token's latent (models/llama.py:_mla_attention)
+                kv_layer = write_latent_pages_blockwise(
+                    kv_layer, k, write_blocks["ids"],
+                    write_blocks["start_off"], write_blocks["chunk_lens"],
+                    row_off=None if rows is None else rows["row_off"],
+                )
+            elif cfg.mla:
+                kv_layer = write_latent_pages(
+                    kv_layer, k.reshape(b * t, -1), slot_mapping)
+            elif write_blocks is not None:
                 kv_layer = write_kv_pages_blockwise(
                     kv_layer, k, v, write_blocks["ids"],
                     write_blocks["start_off"], write_blocks["chunk_lens"],
@@ -698,6 +931,7 @@ def forward(
     # donated input buffer — the scan alternatives all materialized a second
     # full pool (see init_kv_cache)
     new_kv: list[jax.Array] = []
+    n_stats = len(moe_stats or ())
     for i in range(cfg.num_layers):
         lp = _layer_params(params, i)
         x, layer_kv = _layer(
@@ -707,19 +941,35 @@ def forward(
             pallas_prefill, moe_stats, rows,
         )
         new_kv.append(layer_kv)
+    if moe_stats is not None:
+        _tap_routing(token_ids, positions, moe_stats[n_stats:])
     with jax.named_scope("final_norm"):
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
                      cfg.rms_norm_add_one, scale_f32=cfg.norm_scale_f32)
     return x, tuple(new_kv)
 
 
+def _refuse_mla(cfg: ModelConfig, what: str) -> None:
+    if cfg.mla:
+        raise NotImplementedError(
+            f"{what} is not implemented for multi-head latent attention "
+            f"({cfg.model}): its pool holds one latent a token, not K and V")
+
+
 def init_staged_kv(
     cfg: ModelConfig, window: int, batch: int, dtype: Any | None = None
 ) -> jax.Array:
-    """Staging buffer for one fused decode window: (L, 2, W, B, kvH, D).
+    """Staging buffer for one fused decode window: (L, 2, W, B, kvH, D)
+    (MLA: (L, B, W, latent_width)).
     Small (MBs), so carrying it through the window loop is cheap — unlike the
     pool itself (see paged_attention_with_staged)."""
     dt = jnp.dtype(dtype) if dtype is not None else _dtype(cfg)
+    if cfg.mla:
+        # one latent a token, rows first: (L, B, W, width) — a row's window
+        # is one block of the decode kernel, and (b, w) row-major is the
+        # commit's slot order
+        return jnp.zeros(
+            (cfg.num_layers, batch, window, cfg.latent_width), dt)
     return jnp.zeros(
         (cfg.num_layers, 2, window, batch, cfg.num_kv_heads, cfg.head_dim), dt
     )
@@ -749,7 +999,8 @@ def decode_window_step(
     loop-invariant history gather out of the window loop, `hists` carries the
     contiguous per-layer (hist_k, hist_v) and the pool is not touched here
     (ops/attention.py:attention_with_hist). Returns (hidden (B, h), staged')."""
-    window = staged.shape[2]
+    window = staged.shape[2]  # (MLA: (L, B, W, width), the same axis)
+    n_stats = len(moe_stats or ())
     with jax.named_scope("embed"):
         x = _embed(cfg, params, token_ids)[:, None]  # (B, 1, h)
     # staged slot w is attendable once written: w <= k
@@ -775,6 +1026,25 @@ def decode_window_step(
 
     for i in range(cfg.num_layers):
         lp = _layer_params(params, i)
+
+        def attend_latent(q_abs, latent, _v, i=i):
+            nonlocal staged
+            with jax.named_scope("kv_stage"):
+                staged = staged.at[i, :, step_k].set(
+                    latent[:, 0].astype(staged.dtype))
+            with jax.named_scope("attn"):
+                if backend == "xla":
+                    return latent_attention_with_staged(
+                        q_abs, kv_caches[i], block_tables, hist_mask,
+                        jnp.moveaxis(staged[i], 0, 1), staged_mask,
+                        scale=cfg.attn_scale, rank=cfg.kv_lora_rank,
+                    )
+                return mla_decode_attention(
+                    q_abs[:, 0], kv_caches[i], block_tables, hist_len,
+                    staged[i], step_k, scale=cfg.attn_scale,
+                    rank=cfg.kv_lora_rank,
+                    interpret=backend == "pallas_interpret",
+                )[:, None]
 
         def attend(q, k, v, i=i):
             nonlocal staged
@@ -827,9 +1097,12 @@ def decode_window_step(
                 )[:, None]
 
         x = _layer_body(
-            cfg, lp, x, positions[:, None], attend,
+            cfg, lp, x, positions[:, None],
+            attend_latent if cfg.mla else attend,
             _lora_layer_slice(lora, i), lora_idx, moe_stats,
         )
+    if moe_stats is not None:
+        _tap_routing(token_ids, positions, moe_stats[n_stats:])
     with jax.named_scope("final_norm"):
         x = rms_norm(x[:, 0], params["final_norm"], cfg.rms_norm_eps,
                      cfg.rms_norm_add_one, scale_f32=cfg.norm_scale_f32)
@@ -843,6 +1116,12 @@ def commit_staged_kv(
 ) -> tuple[jax.Array, ...]:
     """Scatter a whole window's staged K/V into the (donated) pool, one
     scatter per layer — the only pool write of the fused decode window."""
+    if staged.ndim == 4:  # MLA: (L, B, W, width), rows (b, w) as the slots
+        with jax.named_scope("kv_commit"):
+            return tuple(
+                write_latent_pages(
+                    kv, staged[i].reshape(-1, staged.shape[-1]), slot_mapping)
+                for i, kv in enumerate(kv_caches))
     L, _, w, b, kvh, d = staged.shape
     new_kv: list[jax.Array] = []
     with jax.named_scope("kv_commit"):
@@ -879,6 +1158,10 @@ def embed_encode(
         m = mask_local if cfg.layer_sliding(i) else mask
 
         def attend(q, k, v, m=m):
+            if cfg.mla:  # absorbed queries against the chunk's own latents
+                k = k[:, :, None]
+                return masked_attention(
+                    q, k, k, m, scale=cfg.attn_scale)[..., :cfg.kv_lora_rank]
             return masked_attention(
                 q, k, v, m, scale=cfg.attn_scale,
                 softcap=cfg.attn_logit_softcap,
@@ -921,6 +1204,7 @@ def forward_sp_prefill(
     Returns (hidden (B, T, h) sp-sharded, updated kv_caches)."""
     from ..parallel.ring_attention import ring_attention
 
+    _refuse_mla(cfg, "sequence-parallel prefill (ring attention)")
     b, t = token_ids.shape
     kv_valid = (
         jnp.arange(t, dtype=jnp.int32)[None, :] < chunk_lens[:, None]
@@ -980,6 +1264,7 @@ def forward_context_parallel(
     """
     from ..parallel.ring_attention import ring_attention
 
+    _refuse_mla(cfg, "context-parallel prefill (ring attention)")
     b, t = token_ids.shape
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None], (b, t))
     kv_valid = positions < lengths[:, None]

@@ -81,6 +81,13 @@ def load_checkpoint_params(cfg: ModelConfig) -> dict:
     def stack(fmt: str, kind) -> np.ndarray:
         return np.stack([kind(fmt.format(i)) for i in range(cfg.num_layers)])
 
+    if cfg.mla:
+        params = _deepseek_v3_tree(cfg, mat, vec)
+        logger.info(
+            "loaded checkpoint %s (%d tensors, dtype %s)",
+            cfg.checkpoint, len(list(ckpt.keys())), cfg.dtype,
+        )
+        return params
     p = "model.layers.{}."
     if cfg.num_experts and cfg.architecture == "phi3":
         raise NotImplementedError(
@@ -212,3 +219,175 @@ def load_checkpoint_params(cfg: ModelConfig) -> dict:
         cfg.checkpoint, len(list(ckpt.keys())), cfg.dtype,
     )
     return params
+
+
+# -- DeepSeek-V3 family (Kanana-2): latent attention, leading dense layers ----
+
+def _rope_columns(cfg: ModelConfig, w, to_checkpoint: bool):
+    """The rope part of a projection's OUTPUT columns (last axis, width
+    qk_rope_head_dim) between the checkpoint's order and the program's.
+    With `rope_interleave` a checkpoint holds rope pairs side by side,
+    (x0, x1), (x2, x3), ...; the program's rotate-half rope
+    (ops/attention.py:apply_rope) pairs column i with i + d/2. Scores are
+    invariant under one permutation of q_rope and k_rope alike, so the
+    columns of W_q and W_kv_a are de-interleaved ONCE, here, and no
+    activation is permuted at run time."""
+    if not cfg.rope_interleave:
+        return w
+    d = w.shape[-1]
+    if to_checkpoint:
+        return np.stack([w[..., : d // 2], w[..., d // 2:]], axis=-1).reshape(
+            w.shape)
+    return np.concatenate([w[..., 0::2], w[..., 1::2]], axis=-1)
+
+
+def _deepseek_v3_tree(cfg: ModelConfig, mat, vec) -> dict:
+    """Checkpoint names -> models/llama.py:_init_params_mla's tree."""
+    nh, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    r, k_dense = cfg.kv_lora_rank, cfg.first_k_dense_replace
+    p = "model.layers.{}."
+
+    def stack(fmt, kind, layers):
+        return np.stack([kind(fmt.format(i)) for i in layers])
+
+    def wq(name):
+        w = mat(name).reshape(-1, nh, dn + dr)  # (h, heads, nope ‖ rope)
+        w[..., dn:] = _rope_columns(cfg, w[..., dn:], to_checkpoint=False)
+        return w.reshape(w.shape[0], -1)
+
+    def wkv_a(name):
+        w = mat(name)  # (h, rank ‖ rope)
+        w[:, r:] = _rope_columns(cfg, w[:, r:], to_checkpoint=False)
+        return w
+
+    every = range(cfg.num_layers)
+    dense = range(k_dense if cfg.num_experts else cfg.num_layers)
+    sparse = range(len(dense), cfg.num_layers)
+    a = p + "self_attn."
+    layers: dict = {
+        "attn": {
+            "wq": stack(a + "q_proj.weight", wq, every),
+            "wkv_a": stack(a + "kv_a_proj_with_mqa.weight", wkv_a, every),
+            "kv_a_norm": stack(a + "kv_a_layernorm.weight", vec, every),
+            "wkv_b": stack(a + "kv_b_proj.weight", mat, every),
+            "wo": stack(a + "o_proj.weight", mat, every),
+        },
+        "input_norm": stack(p + "input_layernorm.weight", vec, every),
+        "post_attn_norm": stack(
+            p + "post_attention_layernorm.weight", vec, every),
+    }
+    if len(dense):
+        layers["mlp"] = {
+            key: stack(p + f"mlp.{key}_proj.weight", mat, dense)
+            for key in ("gate", "up", "down")}
+    if len(sparse):
+        ex = "model.layers.{}.mlp.experts.{}."
+
+        def experts(key):
+            return np.stack([
+                np.stack([mat((ex + f"{key}_proj.weight").format(i, j))
+                          for j in range(cfg.num_experts)])
+                for i in sparse])
+
+        layers["moe"] = {
+            "router": stack(p + "mlp.gate.weight", mat, sparse),
+            # the selection bias stays float32, as the router's scores
+            "bias": np.stack([
+                np.asarray(vec(
+                    p.format(i) + "mlp.gate.e_score_correction_bias"),
+                    np.float32) for i in sparse]),
+            **{key: experts(key) for key in ("gate", "up", "down")},
+        }
+        if cfg.n_shared_experts:
+            layers["moe"]["shared"] = {
+                key: stack(p + f"mlp.shared_experts.{key}_proj.weight", mat,
+                           sparse)
+                for key in ("gate", "up", "down")}
+    params = {"embed": vec("model.embed_tokens.weight"), "layers": layers,
+              "final_norm": vec("model.norm.weight")}
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = mat("lm_head.weight")
+    return params
+
+
+class DeepseekCheckpointView:
+    """The served tree read back under the checkpoint's tensor names and
+    orientation ((out, in) matrices, interleaved rope columns): the inverse
+    of `_deepseek_v3_tree`, one tensor at a time and on demand, so that a
+    comparison with the plain reference (testing/reference_mla.py) never
+    holds a second copy of the weights. Works on numpy or device arrays."""
+
+    def __init__(self, cfg: ModelConfig, params: dict):
+        self.cfg, self.params = cfg, params
+        layers = params["layers"]
+        self._k_dense = (
+            layers["mlp"]["gate"].shape[0] if "mlp" in layers else 0)
+
+    def __getitem__(self, name: str):
+        cfg, layers = self.cfg, self.params["layers"]
+        top = {"model.embed_tokens.weight": lambda: self.params["embed"],
+               "model.norm.weight": lambda: self.params["final_norm"],
+               "lm_head.weight": lambda: self.params["lm_head"].T}
+        if name in top:
+            return top[name]()
+        parts = name.split(".")  # model.layers.<i>.<...>
+        i, rest = int(parts[2]), ".".join(parts[3:])
+        k_dense = self._k_dense
+        nh, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        attn = layers["attn"]
+        if rest == "input_layernorm.weight":
+            return layers["input_norm"][i]
+        if rest == "post_attention_layernorm.weight":
+            return layers["post_attn_norm"][i]
+        if rest == "self_attn.q_proj.weight":
+            w = np.array(attn["wq"][i]).reshape(-1, nh, dn + dr)
+            w[..., dn:] = _rope_columns(cfg, w[..., dn:], to_checkpoint=True)
+            return w.reshape(w.shape[0], -1).T
+        if rest == "self_attn.kv_a_proj_with_mqa.weight":
+            w = np.array(attn["wkv_a"][i])
+            w[:, cfg.kv_lora_rank:] = _rope_columns(
+                cfg, w[:, cfg.kv_lora_rank:], to_checkpoint=True)
+            return w.T
+        if rest == "self_attn.kv_a_layernorm.weight":
+            return attn["kv_a_norm"][i]
+        if rest == "self_attn.kv_b_proj.weight":
+            return attn["wkv_b"][i].T
+        if rest == "self_attn.o_proj.weight":
+            return attn["wo"][i].T
+        if i < k_dense:
+            return layers["mlp"][parts[4].split("_")[0]][i].T
+        moe, j = layers["moe"], i - k_dense
+        if rest == "mlp.gate.weight":
+            return moe["router"][j].T
+        if rest == "mlp.gate.e_score_correction_bias":
+            return moe["bias"][j]
+        if parts[4] == "shared_experts":
+            return moe["shared"][parts[5].split("_")[0]][j].T
+        if parts[4] == "experts":
+            return moe[parts[6].split("_")[0]][j, int(parts[5])].T
+        raise KeyError(name)
+
+    def names(self) -> list[str]:
+        """Every tensor name of the checkpoint this tree stands for."""
+        cfg, layers, k_dense = self.cfg, self.params["layers"], self._k_dense
+        out = ["model.embed_tokens.weight", "model.norm.weight"]
+        if "lm_head" in self.params:
+            out.append("lm_head.weight")
+        for i in range(cfg.num_layers):
+            p = f"model.layers.{i}."
+            out += [p + "input_layernorm.weight",
+                    p + "post_attention_layernorm.weight"]
+            out += [p + f"self_attn.{n}.weight" for n in (
+                "q_proj", "kv_a_proj_with_mqa", "kv_a_layernorm", "kv_b_proj",
+                "o_proj")]
+            projs = [f"{n}_proj.weight" for n in ("gate", "up", "down")]
+            if i < k_dense:
+                out += [p + "mlp." + n for n in projs]
+                continue
+            out += [p + "mlp.gate.weight",
+                    p + "mlp.gate.e_score_correction_bias"]
+            if "shared" in layers["moe"]:
+                out += [p + "mlp.shared_experts." + n for n in projs]
+            out += [p + f"mlp.experts.{e}." + n
+                    for e in range(cfg.num_experts) for n in projs]
+        return out

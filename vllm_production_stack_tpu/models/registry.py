@@ -2,10 +2,12 @@
 
 Named presets cover the reference target configs (opt-125m-class tiny
 models for CI, Llama-3-8B for the headline benchmark, Llama-3-70B for
-pipeline parallel), the benchmarked dense models (qwen3-8b, qwen2-7b) and
-the sparse-MoE ones (mixtral-8x7b, olmoe-1b-7b; tiny-mixtral and tiny-olmoe
-for the CPU); a local directory with an HF config.json is parsed directly
-(zero-egress environments can't download)."""
+pipeline parallel), the benchmarked dense models (qwen3-8b, qwen2-7b), the
+sparse-MoE ones (mixtral-8x7b, olmoe-1b-7b; tiny-mixtral and tiny-olmoe
+for the CPU) and the latent-attention MoE (kanana-2-30b-a3b as published,
+kanana-2-30b-a3b-8l at the benchmark cell's depth, tiny-kanana for the CPU);
+a local directory with an HF config.json is parsed directly (zero-egress
+environments can't download)."""
 
 from __future__ import annotations
 
@@ -13,6 +15,17 @@ import json
 import os
 
 from ..engine.config import ModelConfig
+
+_KANANA = dict(
+    vocab_size=128256, hidden_size=2048, intermediate_size=6144,
+    num_heads=32, num_kv_heads=32, head_dim=64, max_model_len=32768,
+    rope_theta=1000000.0, rms_norm_eps=1e-6, architecture="deepseek_v3",
+    kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+    v_head_dim=128, first_k_dense_replace=1, num_experts=128,
+    num_experts_per_tok=6, moe_intermediate_size=768, n_shared_experts=2,
+    scoring_func="sigmoid", routed_scaling_factor=2.448, norm_topk_prob=True,
+    rope_interleave=True,
+)
 
 # Architecture hyperparameters follow the public model cards.
 PRESETS: dict[str, dict] = {
@@ -116,6 +129,27 @@ PRESETS: dict[str, dict] = {
         dtype="float32", architecture="olmoe", num_experts=8,
         num_experts_per_tok=2, norm_topk_prob=False, qk_norm_flat=True,
     ),
+    # kakaocorp/kanana-2-30b-a3b-instruct-2601 config.json (model_type
+    # deepseek_v3): MLA with a 512-value latent and one 64-value rope key a
+    # token, 32 heads of 128 + 64 (q) and 128 (v), no query latent; layer 0
+    # a dense SwiGLU of 6144, layers 1.. 128 experts of 768, top-6 of
+    # sigmoid scores + a selection bias, weights renormalised x 2.448, one
+    # shared SwiGLU of 2 x 768. num_kv_heads / head_dim are the published keys
+    # (the serving path reads neither, engine/config.py)
+    "kanana-2-30b-a3b": dict(**_KANANA, num_layers=48),
+    # the benchmark cell's cut: layer 0 dense + 7 expert layers (one
+    # pipeline stage of the published 48), every width as published
+    "kanana-2-30b-a3b-8l": dict(**_KANANA, num_layers=8),
+    "tiny-kanana": dict(
+        vocab_size=512, hidden_size=64, intermediate_size=128, num_layers=3,
+        num_heads=4, num_kv_heads=4, head_dim=8, max_model_len=256,
+        dtype="float32", architecture="deepseek_v3", rms_norm_eps=1e-6,
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, first_k_dense_replace=1, num_experts=8,
+        num_experts_per_tok=2, moe_intermediate_size=32, n_shared_experts=1,
+        scoring_func="sigmoid", routed_scaling_factor=2.448,
+        norm_topk_prob=True, rope_interleave=True,
+    ),
 }
 
 _ARCH_MAP = {
@@ -130,6 +164,7 @@ _ARCH_MAP = {
     "MixtralForCausalLM": "mixtral",
     "GemmaForCausalLM": "gemma",
     "Gemma2ForCausalLM": "gemma2",
+    "DeepseekV3ForCausalLM": "deepseek_v3",
 }
 
 
@@ -158,6 +193,44 @@ def resolve_model_config(
         kw["quantization"] = quantization
     kw.setdefault("dtype", "bfloat16")
     return ModelConfig(**kw)
+
+
+def _deepseek_v3_keys(hf: dict, path: str) -> dict:
+    """The DeepSeek-V3-family keys (Kanana-2), and by name what of the
+    family is not implemented: a config that sets one is refused, not
+    guessed at."""
+    refused = {
+        "q_lora_rank": hf.get("q_lora_rank") is not None,
+        "n_group > 1 (group-limited routing)": (hf.get("n_group") or 1) > 1,
+        "topk_group > 1": (hf.get("topk_group") or 1) > 1,
+        "rope_scaling (yarn and its mscale)": bool(hf.get("rope_scaling")),
+        "moe_layer_freq != 1": (hf.get("moe_layer_freq") or 1) != 1,
+        "scoring_func other than sigmoid": hf.get(
+            "scoring_func", "sigmoid") != "sigmoid",
+        "attention_bias": bool(hf.get("attention_bias")),
+    }
+    hit = [k for k, v in refused.items() if v]
+    if hit:
+        raise ValueError(
+            f"deepseek_v3 config {path} sets {', '.join(hit)}: not "
+            "implemented (multi-head latent attention is served with a "
+            "direct query projection, one routing group and plain rope)"
+        )
+    return dict(
+        kv_lora_rank=hf["kv_lora_rank"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"],
+        qk_rope_head_dim=hf["qk_rope_head_dim"],
+        v_head_dim=hf["v_head_dim"],
+        first_k_dense_replace=hf.get("first_k_dense_replace", 0),
+        num_experts=hf["n_routed_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        n_shared_experts=hf.get("n_shared_experts") or 0,
+        scoring_func="sigmoid",
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        rope_interleave=bool(hf.get("rope_interleave", True)),
+    )
 
 
 def _from_hf_config(path: str) -> dict:
@@ -190,9 +263,6 @@ def _from_hf_config(path: str) -> dict:
             num_experts=hf.get("num_experts", 128),
             num_experts_per_tok=hf.get("num_experts_per_tok", 8),
             norm_topk_prob=bool(hf.get("norm_topk_prob", False)),
-            # all layers are MoE: the expert inner width IS the
-            # intermediate size our expert tree uses
-            intermediate_size=hf.get("moe_intermediate_size", 768),
         )
     if arch == "olmoe":
         if hf.get("clip_qkv") is not None:
@@ -206,6 +276,8 @@ def _from_hf_config(path: str) -> dict:
             num_experts_per_tok=hf.get("num_experts_per_tok", 8),
             norm_topk_prob=bool(hf.get("norm_topk_prob", False)),
         )
+    if arch == "deepseek_v3":
+        moe = _deepseek_v3_keys(hf, path)
     gemma = (
         dict(
             hidden_act="gelu_tanh", rms_norm_add_one=True,
@@ -278,10 +350,15 @@ def _from_hf_config(path: str) -> dict:
             f"unsupported rope_scaling type {rs_type!r} in {path} "
             "(supported: llama3, linear)"
         )
-    # dict() right-most wins: the explicit intermediate_size below would
-    # clobber a MoE-specific expert width, so hoist it first
-    inter = moe.pop("intermediate_size", None)
-    if inter is None:  # lazy: qwen3moe configs may omit the dense field
+    # two MLP widths, kept apart: `intermediate_size` is the width of the
+    # tree's dense MLP leaves or, where every layer is an expert layer
+    # (qwen3moe publishes the experts' as moe_intermediate_size; mixtral
+    # and olmoe as intermediate_size), of its expert leaves;
+    # `moe_intermediate_size` is set only for a model that has BOTH
+    # (deepseek_v3: a leading dense layer beside the expert layers)
+    if arch == "qwen3moe":
+        inter = hf.get("moe_intermediate_size", 768)
+    else:
         inter = hf["intermediate_size"]
     return dict(
         **moe,

@@ -32,7 +32,19 @@ def llama_param_specs(cfg: ModelConfig) -> dict:
     contiguous block of layers and XLA moves the activations between stages
     (the TPU replacement for the reference's Ray-deployed
     --pipeline-parallel-size, ray-cluster.yaml:556-566). On a pp=1 mesh the
-    axis is size 1 and the specs degrade to pure tp."""
+    axis is size 1 and the specs degrade to pure tp.
+
+    A multi-head-latent-attention tree (models/llama.py:_init_params_mla)
+    has no sharded layout yet — the runner refuses a mesh over one device
+    for it by name — so every leaf of it is replicated."""
+    if cfg.mla:
+        import jax
+
+        from ..models.llama import init_params
+
+        return jax.tree.map(
+            lambda _: P(),
+            jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
     attn = {
         # [L, hidden, heads*head_dim] — L over pp stages, head axis over tp
         "wq": P(PP_AXIS, None, TP_AXIS),

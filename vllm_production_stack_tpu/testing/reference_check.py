@@ -12,6 +12,8 @@ the largest logit changes on rounding.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -39,49 +41,125 @@ def served_logprobs(engine, prompts: list[list[int]], max_tokens: int,
     return [done[i] for i in ids]
 
 
-def system_routing(cfg, params, tokens: list[int]) -> list[np.ndarray]:
+class RoutingTap:
+    """The experts the SERVED programs chose, token by token: set as
+    `llama.ROUTING_TAP` while an engine is built and driven (the tap has to
+    be there when a program is traced), it receives every execution's
+    (token ids, positions, experts (Lm, tokens, k)) and gives them back per
+    sequence, keyed by (position, token id) — which names a fed token of
+    random prompts uniquely; padding rows and positions match nothing."""
+
+    def __init__(self):
+        self.records: list[tuple] = []
+        self._by_key: dict | None = None
+
+    def __call__(self, token_ids, positions, experts) -> None:
+        self.records.append((np.asarray(token_ids), np.asarray(positions),
+                             np.asarray(experts)))
+
+    def __enter__(self) -> "RoutingTap":
+        llama.ROUTING_TAP = self
+        return self
+
+    def __exit__(self, *exc) -> None:
+        llama.ROUTING_TAP = None
+        jax.effects_barrier()  # every callback has run
+
+    def routing_of(self, tokens: list[int]) -> list[np.ndarray]:
+        """Per expert layer the (T, k) experts chosen for `tokens` fed at
+        positions 0..T-1 (whichever program fed each)."""
+        if self._by_key is None:
+            self._by_key = {}
+            for toks, pos, experts in self.records:
+                for i, key in enumerate(zip(pos.tolist(), toks.tolist())):
+                    self._by_key[key] = experts[:, i]
+        rows = [self._by_key[(p, t)] for p, t in enumerate(tokens)]
+        return list(np.stack(rows, axis=1))  # Lm x (T, k)
+
+
+def system_routing(cfg, params, tokens: list[int], chunk: int | None = None,
+                   backend: str = "xla") -> list[np.ndarray]:
     """Each MoE layer's chosen experts (T, k) as the system's own model code
-    computes them (`llama.forward`, the model's dtype, the served weights) in
-    one pass over the whole sequence on a scratch pool. The served programs
-    export no routing, so this stands for them: the same arithmetic, one
-    chunk instead of several."""
+    computes them (`llama.forward`, the model's dtype, the served weights)
+    on a scratch pool: in one pass over the whole sequence, or — `chunk`,
+    what a long sequence needs: one pass holds (heads, T, T) scores — in
+    chunks of that many tokens through the pool, as served. The served
+    programs export no routing, so this stands for them: the same
+    arithmetic."""
     t, block = len(tokens), 32
-    nb = -(-t // block)
+    chunk = chunk or t
+    # (the table's width a power of two: sequences of many lengths share
+    # a few programs)
+    nb = 1 << (-(-t // block) - 1).bit_length()
     kv = llama.init_kv_cache(cfg, nb + 1, block)
     table = jnp.arange(1, nb + 1, dtype=jnp.int32)[None]
-    pos = jnp.arange(t, dtype=jnp.int32)
-    slots = table[0, pos // block] * block + pos % block
 
-    @jax.jit
-    def run(params, kv):
+    @functools.partial(jax.jit, donate_argnums=1)
+    def run(params, kv, toks, pos, ctx):
         stats: list = []
-        llama.forward(cfg, params, jnp.asarray([tokens], jnp.int32),
-                      pos[None], kv, table, slots,
-                      jnp.asarray([t], jnp.int32), moe_stats=stats)
-        return [s["experts"][0] for s in stats]
+        slots = table[0, pos // block] * block + pos % block
+        _, kv = llama.forward(cfg, params, toks[None], pos[None], kv, table,
+                              slots, ctx[None], backend=backend,
+                              moe_stats=stats)
+        return kv, [s["experts"][0] for s in stats]
 
-    return [np.asarray(e) for e in run(params, kv)]
+    parts = []
+    for lo in range(0, t, chunk):
+        # the last chunk is padded to the others' length (one program):
+        # its padding positions lie past the context and are cut below
+        pos = jnp.minimum(jnp.arange(lo, lo + chunk, dtype=jnp.int32), t - 1)
+        toks = jnp.asarray(tokens, jnp.int32)[pos]
+        kv, experts = run(params, kv, toks, pos,
+                          jnp.int32(min(lo + chunk, t)))
+        parts.append([np.asarray(e)[: t - lo] for e in experts])
+    return [np.concatenate(layer) for layer in zip(*parts)]
 
 
 def compare(reference, cfg, params, prompts: list[list[int]],
-            served: list[dict]) -> dict:
+            served: list[dict], routing_chunk: int | None = None,
+            routing_backend: str = "xla",
+            served_routing: RoutingTap | None = None) -> dict:
     """`reference` is the reference module (the package's, or the
     benchmark's byte-identical copy). Returns the worst |Δlogprob| over
     every compared (position, id), how many were compared, and for an MoE
     model the share of (token, layer) pairs whose expert set differs from
-    the reference's, with the reference's margin there. The system's routing
-    is compared, never fed into the reference."""
+    the reference's, with the reference's margin there.
+
+    Without `served_routing` the system's routing (`system_routing`) is
+    compared and never fed into the reference: right where a flipped
+    boundary expert carries little weight (OLMoE's raw softmax mass), or in
+    float32. With it (`reference_mla` only) the reference FOLLOWS the
+    experts the served programs chose and reports how far each choice lay
+    from its own: for renormalised top-6-of-128 weights in bf16 a
+    free-running comparison measures the flips, amplified, and nothing
+    else (3.25 nats at 8 layers on the chip, PERF.md §6 PR 31)."""
     ref_cfg = reference.published_keys(cfg)
     worst, positions, ids_compared = 0.0, 0, 0
     chosen_worst = 0.0
     routed = differing = 0
     margins: list[float] = []
+    if cfg.mla:
+        # reference_mla.py reads the checkpoint's names and orientation
+        from ..models.loader import DeepseekCheckpointView
+
+        params_ref = DeepseekCheckpointView(cfg, params)
     for prompt, got in zip(prompts, served):
         seq = list(prompt) + list(got["token_ids"][:-1])
-        logits, routing = reference.forward(ref_cfg, params, seq)
+        first = len(prompt) - 1  # the first compared position
+        if cfg.mla:
+            # ... and gives the logits of the asked positions only (all of
+            # a long sequence's are GBs of float32)
+            forced = (served_routing.routing_of(seq)
+                      if served_routing is not None else None)
+            logits, routing = reference.forward(
+                ref_cfg, params_ref, seq, range(first, len(seq)),
+                forced_experts=forced)
+            first = 0
+        else:
+            logits, routing = reference.forward(ref_cfg, params, seq)
         ref_lp = np.asarray(jax.nn.log_softmax(logits, axis=-1))
         for k, (chosen_lp, top_ids, top_lps) in enumerate(got["logprobs"]):
-            row = ref_lp[len(prompt) - 1 + k]
+            row = ref_lp[first + k]
             diff = np.abs(row[np.asarray(top_ids)] - np.asarray(top_lps))
             worst = max(worst, float(diff.max()))
             chosen_worst = max(chosen_worst, abs(
@@ -89,7 +167,11 @@ def compare(reference, cfg, params, prompts: list[list[int]],
             positions += 1
             ids_compared += len(top_ids)
         if cfg.num_experts:
-            for ours, ref in zip(system_routing(cfg, params, seq), routing):
+            ours_all = forced if cfg.mla and forced is not None else (
+                system_routing(cfg, params, seq, routing_chunk,
+                               routing_backend))
+            # (a leading dense layer routes nothing: the reference says None)
+            for ours, ref in zip(ours_all, [r for r in routing if r]):
                 same = (np.sort(ours, -1) == np.sort(
                     np.asarray(ref["experts"]), -1)).all(-1)
                 routed += same.size
