@@ -6,6 +6,7 @@ seeded sampled decode, mid-window stop tokens, max-tokens truncation, and
 abort_request landing while a step is in flight — and the decode hot path
 must pay exactly ONE host sync (jax.device_get) per resolved step."""
 
+import jax
 import numpy as np
 import pytest
 
@@ -159,8 +160,8 @@ def test_decode_hot_path_single_host_sync(pipe, monkeypatch):
 
     sp = SamplingParams(max_tokens=40, temperature=0.0, ignore_eos=True)
     pipe.add_request(prompt_token_ids=prompt_ids(9, 6), sampling=sp)
-    pipe.step()  # prefill (resolves in-step)
-    pipe.step()  # first decode window dispatched — pipeline filled
+    pipe.step()  # the prefill chunk, kept in flight
+    pipe.step()  # first window enqueued behind it, the chunk resolved
     calls = []
     real = _jax.device_get
 
@@ -230,6 +231,353 @@ def test_multi_tenant_mix_equivalence(pipe, serial):
         out[eng is pipe] = [got[rid] for rid in rids]
     assert out[True] == out[False]
     assert all(len(s) == 15 for s in out[True])  # everyone ran to budget
+
+
+# -- a prefill chunk is an in-flight step (docs/37 "The step timeline") ------
+#
+# A chunk stays in flight over a call like a window; the window enqueued
+# behind it takes the first tokens of the rows whose prompt it ends from the
+# chunk's device output. Arrivals are scripted by step() CALL index, so both
+# loops see the same requests at the same point of their dispatch sequence.
+
+
+def drive(eng, arrivals, hook=None):
+    """step() until everything finished. `arrivals`: {call index: [(rid,
+    prompt, sampling[, tenant])]}; `hook(eng, i)` runs after call i.
+    Returns {rid: token stream}."""
+    got, i = {}, 0
+    while i <= max(arrivals) or eng.has_unfinished():
+        for rid, prompt, sp, *tenant in arrivals.get(i, ()):
+            eng.add_request(rid, prompt_token_ids=prompt, sampling=sp,
+                            tenant=tenant[0] if tenant else None)
+            got[rid] = []
+        for o in eng.step():
+            got[o.request_id].extend(o.new_token_ids)
+        if hook is not None:
+            hook(eng, i)
+        i += 1
+        assert i < 2000, "the loop does not drain"
+    return got
+
+
+def dispatch_order(eng, after_seq):
+    """(kind, rows) of every dispatch past `after_seq` that was not rolled
+    back: the sequence whose RNG keys the token streams were drawn with."""
+    records = eng.flightrec.snapshot()
+    gone = {r["seq"] for r in records if r["event"] == "rollback"}
+    return [
+        (r["kind"], r["rows"]) for r in records
+        if r["event"] == "dispatch" and r["seq"] > after_seq
+        and r["seq"] not in gone
+    ]
+
+
+def _last_seq(eng):
+    return max((r["seq"] for r in eng.flightrec.snapshot()
+                if r["event"] == "dispatch"), default=0)
+
+
+def _pool_is_whole(eng):
+    pool = eng.scheduler.pool
+    assert eng._inflight is None
+    assert not eng.scheduler.running and not eng.scheduler.waiting
+    return pool.num_free == pool.num_usable
+
+
+def _mix(tag):
+    from vllm_production_stack_tpu.qos import TenantContext
+
+    return {
+        "greedy": (SamplingParams(max_tokens=30, temperature=0.0,
+                                  ignore_eos=True), {}),
+        "seeded": (SamplingParams(max_tokens=26, temperature=0.9, top_p=0.9,
+                                  seed=4321, ignore_eos=True), {}),
+        # no seed: the streams depend on the dispatch ORDER alone (the
+        # runner's RNG is split once per dispatch)
+        "sampled": (SamplingParams(max_tokens=26, temperature=0.8,
+                                   ignore_eos=True), {}),
+        "tenants": (
+            SamplingParams(max_tokens=22, temperature=0.0, ignore_eos=True),
+            {0: TenantContext("bulk", priority=2, weight=1.0),
+             1: TenantContext("acme", priority=0, weight=3.0),
+             2: TenantContext("std", priority=1, weight=2.0)},
+        ),
+    }[tag]
+
+
+@pytest.mark.parametrize("tag", ["greedy", "seeded", "sampled", "tenants"])
+def test_prefills_arriving_while_rows_decode_equal_the_serial_loop(
+        pipe, serial, tag):
+    """Prompts arrive while other rows decode: every chunk is enqueued
+    behind a window and a window behind every chunk, the new rows' first
+    tokens chained on the device — streams and dispatch order are the
+    serial loop's, and no window is thrown away for it."""
+    sp, tenants = _mix(tag)
+    calls = (0, 0, 3, 5, 6, 9, 14)
+    out, order = {}, {}
+    before = pipe.timing
+    for eng in (pipe, serial):
+        # the same RNG state on both sides: the unseeded case compares
+        # streams drawn from the runner's own key
+        eng.runner._rng = jax.random.PRNGKey(11)
+        arrivals = {}
+        for n, call in enumerate(calls):
+            row = (f"{tag}-{n}", prompt_ids(20 + n, 5 + 3 * n), sp)
+            if tenants:
+                row += (tenants[n % 3],)
+            arrivals.setdefault(call, []).append(row)
+        seq0 = _last_seq(eng)
+        out[eng is pipe] = drive(eng, arrivals)
+        order[eng is pipe] = dispatch_order(eng, seq0)
+    assert out[True] == out[False]
+    assert all(len(t) == sp.max_tokens for t in out[True].values())
+    assert order[True] == order[False]
+    after = pipe.timing
+    chained = after["prefill_chained_n"] - before["prefill_chained_n"]
+    chunks = after["prefill_n"] - before["prefill_n"]
+    # every chunk but the very first (nothing decodes beside it yet)
+    assert 0 < chained <= chunks and chained >= chunks - 1
+    assert after["rollback_n"] - before["rollback_n"] <= 1  # the tail only
+    assert _pool_is_whole(pipe)
+
+
+def _first_token_finisher(serial, how):
+    """A prompt whose greedy FIRST token ends its request: as a stop token,
+    or as the whole of a stop string."""
+    greedy = SamplingParams(max_tokens=4, temperature=0.0, ignore_eos=True)
+    for seed in range(30, 130):
+        p = prompt_ids(seed, 8)
+        first = streams(serial, [p], greedy)[0][0]
+        if how == "stop_token":
+            return p, SamplingParams(max_tokens=12, temperature=0.0,
+                                     stop_token_ids=(first,))
+        if 32 <= first < 127:  # one printable ASCII byte of the tokenizer
+            return p, SamplingParams(max_tokens=12, temperature=0.0,
+                                     ignore_eos=True, stop=(chr(first),))
+    raise AssertionError("no usable first token found")
+
+
+@pytest.mark.parametrize("how", ["stop_token", "stop_string"])
+def test_first_token_that_finishes_its_row_rolls_the_window_back(
+        pipe, serial, how):
+    """The one thing the window behind a chunk assumes is that no row
+    finishes ON its first token. Where one does, the reconcile of the chunk
+    discards the window (rollback_n + 1) and the re-dispatch takes the
+    serial loop's turn: a decode window, not the chunk that waits."""
+    prompt, sp = _first_token_finisher(serial, how)
+    long = SamplingParams(max_tokens=40, temperature=0.7, ignore_eos=True)
+    arrivals = {
+        0: [(f"{how}-keeper", prompt_ids(61, 6), long)],
+        # the finisher's chunk, then a prompt that waits behind it
+        4: [(f"{how}-once", prompt, sp)],
+        5: [(f"{how}-late", prompt_ids(62, 9), long)],
+    }
+    out, order = {}, {}
+    before = pipe.timing["rollback_n"]
+    for eng in (pipe, serial):
+        eng.runner._rng = jax.random.PRNGKey(5)
+        seq0 = _last_seq(eng)
+        out[eng is pipe] = drive(eng, arrivals)
+        order[eng is pipe] = dispatch_order(eng, seq0)
+    assert out[True] == out[False]
+    assert len(out[True][f"{how}-once"]) == 1
+    assert order[True] == order[False]
+    # the window behind the finisher's chunk, and the tail's
+    assert 1 <= pipe.timing["rollback_n"] - before <= 2
+    assert _pool_is_whole(pipe)
+
+
+def test_max_tokens_one_joins_no_window(pipe, serial):
+    """A row that may emit one token is finished by its chunk, which is
+    known beforehand: it joins no window and nothing is rolled back."""
+    one = SamplingParams(max_tokens=1, temperature=0.0, ignore_eos=True)
+    long = SamplingParams(max_tokens=17, temperature=0.0, ignore_eos=True)
+    arrivals = {
+        0: [("one-keeper", prompt_ids(63, 6), long)],
+        3: [("one-shot", prompt_ids(64, 7), one)],
+    }
+    seen = []
+
+    def rows_behind_the_chunk(eng, i):
+        step = eng._inflight
+        if step is not None and step.handle.kind == "decode":
+            seen.extend(r.request_id for r in step.work.requests)
+
+    before = pipe.timing
+    got = drive(pipe, arrivals, hook=rows_behind_the_chunk)
+    assert got == drive(serial, arrivals)
+    assert len(got["one-shot"]) == 1 and "one-shot" not in seen
+    after = pipe.timing
+    assert after["prefill_chained_n"] - before["prefill_chained_n"] == 1
+    # only the keeper's last window had a window thrown away behind it
+    assert after["rollback_n"] - before["rollback_n"] <= 1
+    assert _pool_is_whole(pipe)
+
+
+@pytest.mark.parametrize("how", ["abort", "deadline"])
+def test_row_of_an_unresolved_chunk_is_cut_between_two_calls(
+        pipe, serial, how):
+    """An abort or an expired deadline finishes a row whose chunk is still
+    in flight: its pages go back at once (device order makes that safe),
+    the window planned next does without it, the reconcile skips its first
+    token, and the survivor's stream is untouched."""
+    sp = SamplingParams(max_tokens=24, temperature=0.0, ignore_eos=True)
+    ref = streams(serial, [prompt_ids(65, 6)], sp)[0]
+    cut = []
+
+    def hook(eng, i):
+        step = eng._inflight
+        if cut or step is None or step.handle.kind != "prefill":
+            return
+        victim = next((r for r in step.work.requests
+                       if r.request_id == f"{how}-victim"), None)
+        if victim is None:
+            return
+        assert victim.num_inflight_tokens > 0 == victim.num_computed_tokens
+        if how == "abort":
+            assert eng.abort_request(victim.request_id)
+        else:
+            victim.deadline = 0.0  # long past on the monotonic clock
+        cut.append(i)
+
+    arrivals = {
+        0: [(f"{how}-keeper", prompt_ids(65, 6), sp)],
+        3: [(f"{how}-victim", prompt_ids(66, 11), sp)],
+    }
+    before = pipe.timing["rollback_n"]
+    got = drive(pipe, arrivals, hook=hook)
+    assert cut and got[f"{how}-keeper"] == ref
+    assert got[f"{how}-victim"] == []
+    assert pipe.timing["rollback_n"] - before <= 1  # the keeper's tail
+    assert _pool_is_whole(pipe)
+    bal = pipe.scheduler.goodput_balance()
+    assert bal["balanced"], bal
+
+
+def test_fault_in_a_resolve_with_a_chunk_enqueued_behind_it(pipe, serial):
+    """The previous window's resolve raises after a chunk was enqueued
+    behind it: the chunk is rolled back like a window (its rows keep their
+    seats at the position the host knows, the turn goes back), the window
+    is put back, and the next calls serve everything; nothing leaks."""
+    sp = SamplingParams(max_tokens=20, temperature=0.0, ignore_eos=True)
+    prompts = [prompt_ids(67, 6), prompt_ids(68, 10)]
+    ref = streams(serial, prompts, sp)
+    a = pipe.add_request("fault-a", prompt_token_ids=prompts[0], sampling=sp)
+    got = {a: [], "fault-b": []}
+
+    def pump():
+        for o in pipe.step():
+            got[o.request_id].extend(o.new_token_ids)
+
+    while pipe._inflight is None or pipe._inflight.handle.kind != "decode":
+        pump()
+    pipe.add_request("fault-b", prompt_token_ids=prompts[1], sampling=sp)
+    window = pipe._inflight.handle
+    real = window.resolve
+
+    def boom():
+        raise RuntimeError("injected D2H fault")
+
+    window.resolve = boom
+    before = pipe.timing["prefill_n"]
+    with pytest.raises(RuntimeError, match="injected"):
+        pipe.step()
+    # the window is back in flight, the chunk behind it was discarded
+    assert pipe._inflight.handle is window and not window.resolved
+    b = next(r for r in pipe.scheduler.running if r.request_id == "fault-b")
+    assert b.num_inflight_tokens == 0 == b.num_inflight_outputs
+    assert b.num_computed_tokens == 0
+    window.resolve = real
+    while pipe.has_unfinished():
+        pump()
+    assert [got[a], got["fault-b"]] == ref
+    assert pipe.timing["prefill_n"] == before + 1
+    assert _pool_is_whole(pipe)
+
+
+def test_an_unresolved_chunk_is_protected_like_a_window(pipe):
+    """Between the call that enqueued a chunk and the one that resolves it
+    its rows are no seat victim and no preemption victim, the engine does
+    not go to sleep over it, the watchdog's cursor names it, and one more
+    step() resolves it even when every row of it was aborted meanwhile."""
+    sp = SamplingParams(max_tokens=6, temperature=0.0, ignore_eos=True)
+    pipe.add_request("held", prompt_token_ids=prompt_ids(72, 9), sampling=sp)
+    pipe.step()
+    step = pipe._inflight
+    assert step.handle.kind == "prefill" and not step.handle.resolved
+    (row,) = step.work.requests
+    assert (row.num_inflight_tokens, row.num_inflight_outputs) == (9, 1)
+    sched = pipe.scheduler
+    assert sched._seat_victim(-1) is None  # whatever the arrival's class
+    free = sched.pool.num_free
+    assert not sched._ensure_blocks(row, (free + 64) * sched.block_size)
+    assert row in sched.running and row.num_preemptions == 0
+    while len(row.block_table) > sched._blocks_needed(9):  # the probe's
+        sched.pool.free_block(row.block_table.pop())
+    with pytest.raises(RuntimeError, match="unfinished"):
+        pipe.sleep()
+    assert pipe.flightrec.outstanding_age_s()[1] == "prefill"
+    assert pipe.abort_request("held")
+    assert not sched.has_unfinished() and pipe.has_unfinished()
+    assert pipe.step() == []
+    assert pipe.flightrec.outstanding_age_s() is None
+    assert _pool_is_whole(pipe) and not pipe.has_unfinished()
+
+
+def test_nothing_decode_ready_resolves_the_chunk_first(pipe, serial):
+    """A lone prompt of several chunks: there is no row to decode beside a
+    chunk, so each is resolved before the next is planned — never a second
+    chunk over rows whose positions an unresolved one still owes."""
+    sp = SamplingParams(max_tokens=9, temperature=0.0, ignore_eos=True)
+    budget = pipe.config.scheduler.max_num_batched_tokens
+    prompt = prompt_ids(69, 2 * budget + 7)  # three chunks
+    states = []
+
+    def hook(eng, i):
+        step = eng._inflight
+        if step is not None and step.handle.kind == "prefill":
+            (row,) = step.work.requests
+            states.append((row.num_computed_tokens, row.num_inflight_tokens))
+
+    before = pipe.timing
+    got = drive(pipe, {0: [("lone", prompt, sp)]}, hook=hook)
+    assert got == drive(serial, {0: [("lone", prompt, sp)]})
+    # one chunk in flight at a time, each planned from resolved positions
+    assert states == [(0, budget), (budget, budget), (2 * budget, 7)]
+    after = pipe.timing
+    assert after["prefill_n"] - before["prefill_n"] == 3
+    # only the last chunk has something decode-ready behind it: its own row
+    assert after["prefill_chained_n"] - before["prefill_chained_n"] == 1
+    assert _pool_is_whole(pipe)
+
+
+def test_grammar_row_sits_out_the_window_behind_its_chunk(pipe, serial):
+    """A constrained row's automaton state after its first token is on the
+    host only once the chunk has resolved: it sits out ONE window and joins
+    the next with host tokens; its stream is the serial loop's."""
+    spec = {"kind": "json_schema", "schema": {
+        "type": "object", "properties": {"ok": {"type": "boolean"}}}}
+    plain = SamplingParams(max_tokens=30, temperature=0.0, ignore_eos=True)
+    out, behind = {}, []
+
+    def hook(eng, i):
+        step = eng._inflight
+        if eng is pipe and step is not None and step.handle.kind == "decode":
+            behind.append([r.request_id for r in step.work.requests])
+
+    before = pipe.timing["rollback_n"]
+    for eng in (pipe, serial):
+        gsp = SamplingParams(max_tokens=24, temperature=0.0,
+                             grammar=eng.grammar_cache.get(spec)[0])
+        arrivals = {0: [("gr-keeper", prompt_ids(70, 6), plain)],
+                    4: [("gr-row", prompt_ids(71, 9), gsp)]}
+        out[eng is pipe] = drive(eng, arrivals, hook=hook)
+    assert out[True] == out[False] and out[True]["gr-row"]
+    first = next(i for i, rows in enumerate(behind) if "gr-row" in rows)
+    # the window right behind the chunk ran without the constrained row
+    assert behind[first - 1] == ["gr-keeper"]
+    assert pipe.timing["rollback_n"] - before <= 2
+    assert _pool_is_whole(pipe)
 
 
 def test_spec_decode_composes_with_pipeline():

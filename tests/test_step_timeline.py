@@ -105,9 +105,13 @@ def _delta(eng, before):
     return {k: now[k] - before[k] for k in now}
 
 
-def test_prefill_drains_the_pipeline_and_chained_decodes_do_not():
-    """decode -> decode -> prefill -> decode: exactly one gap, after the
-    prefill, and it sits on the flight record of the decode that ended it."""
+def test_a_prefill_chunk_does_not_drain_the_pipeline():
+    """decode -> decode -> prefill -> decode: the chunk is enqueued behind a
+    window and the next window behind the chunk (the new row's first token
+    chained on the device), so the device's queue never empties: no
+    `starved_after_prefill` gap, and the chunk counts as chained. The one
+    gap of the run is the one since `start`, on the flight record of the
+    dispatch that ended it."""
     eng = _engine()
     eng.add_request("a", prompt_token_ids=[1, 2, 3, 4, 5, 6],
                     sampling=_greedy(200))
@@ -123,22 +127,32 @@ def test_prefill_drains_the_pipeline_and_chained_decodes_do_not():
     before = eng.timing
     eng.add_request("b", prompt_token_ids=[9, 8, 7, 6, 5],
                     sampling=_greedy(200))
-    while _delta(eng, before)["prefill_n"] == 0:
-        eng.step()  # b's prefill chunk, resolved in the same call
-    while _delta(eng, before)["decode_n"] < 3:
+    kinds = []
+    while _delta(eng, before)["decode_n"] < 4:
         eng.step()
+        kinds.append(eng._inflight.handle.kind)
     d = _delta(eng, before)
-    assert d["prefill_n"] == 1
-    assert d["starved_n"] == 1
-    assert d["starved_after_prefill_n"] == 1
-    assert d["starved_after_decode_n"] == 0 == d["rollback_n"]
-    assert d["starved_s"] > 0
-    assert d["starved_s"] == pytest.approx(
-        sum(d["starved_" + p + "_s"] for p in PHASES), rel=1e-9)
-    gaps = [r for r in eng.flightrec.snapshot()
-            if r["event"] == "dispatch" and r["gap"]]
+    # b's chunk stayed in flight over a call, like a window
+    assert "prefill" in kinds and kinds[-1] == "decode"
+    assert d["prefill_n"] == 1 == d["prefill_chained_n"]
+    assert d["starved_n"] == 0 == d["starved_s"]
+    assert d["starved_after_prefill_n"] == 0 == d["rollback_n"]
+    t = eng.timing
+    assert t["prefill_chained_n"] <= t["prefill_n"]
+    assert t["starved_n"] == 1 == t["starved_after_start_n"]
+    # b decodes in the window behind its chunk: 16 seats x 8 steps would
+    # hold it anyway, but its row is there from that window's first step
+    records = eng.flightrec.snapshot()
+    chunk = [r for r in records
+             if r["event"] == "dispatch" and r["kind"] == "prefill"][-1]
+    behind = next(r for r in records if r["event"] == "dispatch"
+                  and r["seq"] == chunk["seq"] + 1)
+    assert behind["kind"] == "decode" and behind["rows"] == 2
+    assert behind["gap"] is None
+    gaps = [r for r in records if r["event"] == "dispatch" and r["gap"]]
+    assert len(gaps) == 1
     last = gaps[-1]
-    assert last["kind"] == "decode" and last["gap"]["after"] == "prefill"
+    assert last["kind"] == "prefill" and last["gap"]["after"] == "start"
     assert last["gap"]["ms"] == pytest.approx(
         sum(last["gap"]["phases_ms"].values()), abs=0.01)
     assert set(last["gap"]["phases_ms"]) <= set(PHASES)
@@ -149,6 +163,49 @@ def test_prefill_drains_the_pipeline_and_chained_decodes_do_not():
         assert start <= end <= last["mono_ns"]
         if name in DISPATCH_PHASES:
             assert seq == last["seq"]
+
+
+@pytest.mark.parametrize("start, end, want", [
+    (dict(prefill_n=10, prefill_chained_n=4),
+     dict(prefill_n=50, prefill_chained_n=34), 75.0),
+    # a parent that lacks the counter: the metric is left out, not raised
+    (dict(prefill_n=10), dict(prefill_n=50), None),
+    # no chunk in the window: nothing to divide by
+    (dict(prefill_n=7, prefill_chained_n=7),
+     dict(prefill_n=7, prefill_chained_n=7), None),
+])
+def test_prefill_chained_share_from_its_data_file(start, end, want):
+    """The benchmark's `prefill_chained_share` is a data file for a reader
+    the benchmark already has: chunks that had a window enqueued behind
+    them before their resolve, of all chunks, over the window."""
+    import importlib.util
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    chip = os.path.join(root, "benchmarks", "chip")
+    with open(os.path.join(chip, "metrics",
+                           "prefill_chained_share.json")) as f:
+        spec = json.load(f)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        (entry,) = [m for m in json.load(f)["per_layer"]
+                    if m["name"] == "prefill_chained_share"]
+    assert entry == {
+        "name": "prefill_chained_share", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "scheduler",
+        "moves": "out_tok_per_s"}  # no `workloads`: every cell reports it
+    mod = importlib.util.spec_from_file_location(
+        "timing_ratio_opt",
+        os.path.join(chip, "readers", spec["reader"] + ".py"))
+    reader = importlib.util.module_from_spec(mod)
+    mod.loader.exec_module(reader)
+    ctx = {"start": {"timing": {"engine": start}},
+           "end": {"timing": {"engine": end}}}
+    got = reader.read(ctx, **spec["args"])
+    assert got == (pytest.approx(want) if want is not None else None)
+    # the counters it names are served by every engine
+    assert {spec["args"]["num"], spec["args"]["den"]} <= set(
+        FlightRecorder().timing())
 
 
 def test_rolled_back_dispatch_does_not_empty_the_queue():

@@ -117,7 +117,7 @@ class AsyncEngine:
         # _bg_compile_job): compile only when nothing is queued or running
         def idle() -> bool:
             return (
-                not self.engine.scheduler.has_unfinished()
+                not self.engine.has_unfinished()
                 and not self._pending
             )
 
@@ -579,7 +579,7 @@ class AsyncEngine:
         while time.monotonic() < deadline:
             with self._pending_lock:
                 pending = bool(self._pending) or self._admitting > 0
-            if not pending and not self.engine.scheduler.has_unfinished():
+            if not pending and not self.engine.has_unfinished():
                 return True
             await asyncio.sleep(0.05)
         return False
@@ -603,7 +603,7 @@ class AsyncEngine:
             with self._lock:
                 with self._pending_lock:
                     pending = bool(self._pending)
-                if not pending and not self.engine.scheduler.has_unfinished():
+                if not pending and not self.engine.has_unfinished():
                     self.engine.sleep(level)
                     return
             if time.monotonic() > deadline:

@@ -120,11 +120,12 @@ class _RequestState:
 
 @dataclass
 class _InflightStep:
-    """A decode (or speculative-verify) step dispatched to the device but
-    not yet resolved — the unit the pipelined step loop keeps in flight
-    while the host schedules and postprocesses around it."""
+    """A step (decode window, speculative verify or prefill chunk)
+    dispatched to the device but not yet resolved — the unit the pipelined
+    step loop keeps in flight while the host schedules and postprocesses
+    around it."""
 
-    work: DecodeWork | VerifyWork
+    work: DecodeWork | VerifyWork | PrefillWork
     handle: StepHandle
 
 
@@ -566,7 +567,8 @@ class LLMEngine:
         starvation counters, and the keys that predate them: dispatch_s =
         build + h2d + enqueue, overlap_s = host time that ran while a device
         step was in flight, step_wall_s = total step() wall, rollback_n =
-        speculative steps discarded)."""
+        speculative steps discarded, prefill_chained_n = prefill chunks
+        that had the next step enqueued behind them before their resolve)."""
         return self.flightrec.timing()
 
     @property
@@ -840,6 +842,9 @@ class LLMEngine:
             # the pool cannot physically hold max_num_seqs × max_model_len
             # (generate-based waves could never reach that shape)
             passes += self.runner.precompile_dominating()
+            # ... and the gather that hands a window enqueued behind a
+            # prefill chunk its rows' first tokens, per chunk row bucket
+            self.runner.warm_chain_gathers()
             if scope == "coarse":
                 logger.info("coarse warmup compiled %d programs", passes)
                 return passes
@@ -1257,7 +1262,11 @@ class LLMEngine:
                 )
 
     def has_unfinished(self) -> bool:
-        return self.scheduler.has_unfinished()
+        # a step still in flight is unfinished work even when every row of
+        # it was aborted meanwhile: one more step() resolves it, so nothing
+        # stays open behind an idle loop (the watchdog's unresolved-step
+        # cursor, the handle's device arrays)
+        return self.scheduler.has_unfinished() or self._inflight is not None
 
     # -- stepping ----------------------------------------------------------
 
@@ -1265,18 +1274,26 @@ class LLMEngine:
         """Schedule + execute one device step; returns per-request deltas.
 
         This drives a TWO-DEEP PIPELINE: the step dispatched on the
-        previous call is still executing on device while this call
-        schedules and dispatches the next one against speculatively-advanced
-        request state — decode inputs chain device-side from the in-flight
-        step's output matrix — and only then resolves the previous step
-        (one batched D2H sync), postprocesses it, and reconciles. When the
-        reconcile shows the speculation was wrong (a mid-window stop token,
-        max-tokens finish, stop-string hit, or abort), the just-dispatched
-        step is discarded and rolled back, so the emitted token streams are
-        bitwise identical to the serial reference loop's
-        (testing/serial_loop.py). Outputs returned by one call therefore
-        belong to the step dispatched on the PREVIOUS call (one step of
-        latency)."""
+        previous call — a decode window, a verify, or a PREFILL CHUNK — is
+        still executing on device while this call schedules and dispatches
+        the next one against speculatively-advanced request state, and only
+        then resolves the previous step (one batched D2H sync),
+        postprocesses it, and reconciles. Decode inputs chain device-side
+        from the in-flight step's output: the last column of a window's
+        token matrix, a verify's bonus tokens, or — for a row whose prompt
+        the in-flight chunk ends — the FIRST token that chunk samples, so
+        the row joins the very window enqueued behind its chunk and the
+        device's queue never empties at a prefill. When the reconcile shows
+        the speculation was wrong (a mid-window stop token, max-tokens
+        finish, stop-string hit, abort, or a first token that finished its
+        row), the just-dispatched step is discarded and rolled back, so the
+        emitted token streams are bitwise identical to the serial reference
+        loop's (testing/serial_loop.py). What cannot be enqueued behind the
+        in-flight step (a second prefill chunk over an unresolved one, a
+        chunk with nothing to decode beside it, a priority stall) waits for
+        its resolve and is dispatched in the same call. Outputs returned by
+        one call therefore belong to the step dispatched on the PREVIOUS
+        call (one step of latency)."""
         outputs: list[RequestOutput] = []
         inflight, self._inflight = self._inflight, None
         try:
@@ -1314,10 +1331,10 @@ class LLMEngine:
         kind = _work_kind(work)
         with self.flightrec.span("build", kind=kind):
             handle = self.runner.execute_async(work, prev=prev)
-            if kind != "prefill":
-                # rows advance speculatively by their fed length (full
-                # acceptance): the next step can chain off this handle
-                self.scheduler.begin_speculative(work)
+            # rows advance speculatively by their fed length (full
+            # acceptance; a chunk that ends its prompt by the one token it
+            # samples): the next step can chain off this handle
+            self.scheduler.begin_speculative(work)
         handle.seq, handle.kind = self._rec_dispatch(work, kind), kind
         return handle
 
@@ -1335,8 +1352,7 @@ class LLMEngine:
             rec.count(key + "_n")
             if kind == "prefill" and self.runner.prefill_uses_kernel:
                 rec.count("prefill_kernel_n")
-            if kind != "prefill":
-                self.scheduler.end_speculative(work)
+            self.scheduler.end_speculative(work)
             results = self.scheduler.postprocess(work, rows)
             if kind == "prefill":
                 # chunk tokens PROCESSED (mid-prompt chunks emit none)
@@ -1362,19 +1378,10 @@ class LLMEngine:
                 self._meter_decode(work, accepted)
             self._emit_results(results, handle.logprob_rows, outputs)
 
-    def _execute_sync(self, work, outputs: list[RequestOutput]) -> None:
-        """Dispatch and resolve at once: here the host's wait per dispatch
-        (prefill_s / decode_s) takes in the dispatch too."""
-        key = "prefill_s" if isinstance(work, PrefillWork) else "decode_s"
-        with self.flightrec.wall(key):
-            handle = self._dispatch(work)
-        self._resolve(work, handle, outputs)
-
     def _rollback(self, nxt: _InflightStep) -> None:
         self.scheduler.rollback_speculative(nxt.work)
         nxt.handle.discard()
         self.flightrec.discard(nxt.handle.seq)
-        self._ledger_rollback(nxt.work)
 
     def _step_pipelined_inner(
         self,
@@ -1382,36 +1389,34 @@ class LLMEngine:
         outputs: list[RequestOutput],
     ) -> None:
         rec = self.flightrec
+        nxt: _InflightStep | None = None
         # everything up to the resolve runs while the previous step is
         # still executing on device — the overlap the pipeline buys
         with rec.wall("overlap_s") if inflight else contextlib.nullcontext():
             work = self._schedule(
                 outputs, inflight=inflight.work if inflight else None
             )
-            nxt: _InflightStep | None = None
-            pre_handle: StepHandle | None = None
-            if isinstance(work, (DecodeWork, VerifyWork)):
-                # a verify dispatch pipelines exactly like a decode window
+            if work is not None:
+                # a verify or a prefill chunk pipelines exactly like a
+                # decode window: enqueued behind the in-flight step, kept
+                # in flight over the next call
                 nxt = _InflightStep(work=work, handle=self._dispatch(
                     work, prev=inflight.handle if inflight else None
                 ))
-            elif isinstance(work, PrefillWork):
-                # dispatched before resolving the in-flight decode so the
-                # host array building overlaps device execution; resolved
-                # below in this same call (prefill outputs are never
-                # speculated on)
-                pre_handle = self._dispatch(work)
         if inflight is not None:
             try:
                 self._resolve(inflight.work, inflight.handle, outputs)
             except Exception:
                 # the previous step's resolve faulted AFTER nxt was
-                # dispatched: roll nxt back too, or its speculative window
+                # dispatched: roll nxt back too, or its speculative advance
                 # would leak (rows stuck one window ahead with their
                 # sampled tokens silently dropped)
                 if nxt is not None:
                     self._rollback(nxt)
                 raise
+            if nxt is not None and inflight.handle.kind == "prefill":
+                # counted where prefill_n is, so it never runs ahead of it
+                rec.count("prefill_chained_n")
             if nxt is not None and not self.scheduler.speculation_valid(
                 nxt.work
             ):
@@ -1422,20 +1427,21 @@ class LLMEngine:
                 self._rollback(nxt)
                 rec.count("rollback_n")
                 nxt = None
-        if work is None and inflight is not None and nxt is None:
-            # a priority stall: the scheduler declined to chain because a
-            # higher-priority waiter was blocked by in-flight victims. The
-            # victims are resolved now — re-schedule IN THIS CALL so the
-            # preempt-and-admit happens a full loop round-trip sooner
-            # (the realtime arrival's TTFT is the point of the stall).
-            work2 = self._schedule(outputs)
-            if work2 is not None:
-                self._execute_sync(work2, outputs)
-        if (
-            work is None
-            and inflight is None
-            and nxt is None
-            and self.scheduler.hydration_parked()
+            elif work is None:
+                # nothing could be enqueued behind the in-flight step: a
+                # prefill chunk with nothing to decode beside it (the
+                # scheduler plans no second chunk over the rows an
+                # unresolved one still owes), or a priority stall (a
+                # higher-priority waiter blocked by in-flight victims). It
+                # is resolved now — re-schedule IN THIS CALL, so the next
+                # chunk, or the preempt-and-admit, happens a full loop
+                # round-trip sooner.
+                work = self._schedule(outputs)
+                if work is not None:
+                    nxt = _InflightStep(
+                        work=work, handle=self._dispatch(work))
+        if work is None and inflight is None and (
+            self.scheduler.hydration_parked()
         ):
             # the only schedulable work is parked at a pending hydration
             # fetch: yield a beat instead of busy-spinning step() — the
@@ -1443,8 +1449,6 @@ class LLMEngine:
             # whose landing we're waiting on, inflating the fetch latency
             # the planner priced
             time.sleep(0.001)
-        if pre_handle is not None:
-            self._resolve(work, pre_handle, outputs)
         self._inflight = nxt
         with rec.span("post"):
             self._drop_finished(outputs)
@@ -1471,17 +1475,6 @@ class LLMEngine:
         )
 
     # -- saturation & goodput telemetry (docs/29-saturation-slo.md) --------
-
-    def _ledger_rollback(self, work: DecodeWork | VerifyWork) -> None:
-        """A dispatched pipeline step was discarded: the device still
-        executed it, sampling window × rows (or every verify row's fed
-        positions) nobody will consume — sampled AND wasted in one motion
-        (they never reach postprocess)."""
-        if isinstance(work, VerifyWork):
-            n = sum(len(t) for t in work.token_ids)
-        else:
-            n = work.window * len(work.requests)
-        self.scheduler.ledger.rollback(n)
 
     def _meter_decode(self, work, accepted: int) -> None:
         """Record one resolved decode/verify dispatch with the meter. The
@@ -1998,7 +1991,7 @@ class LLMEngine:
         return self.runner.is_sleeping
 
     def sleep(self, level: int = 1) -> None:
-        if self.scheduler.has_unfinished():
+        if self.has_unfinished():  # a step still in flight included
             raise RuntimeError("cannot sleep with unfinished requests")
         self.runner.sleep(level)
         # the device pool is dropped; its content-addressed hashes would

@@ -211,8 +211,8 @@ class FlightRecorder:
         # serves seconds. Every key /debug/timing ever had lives here.
         self._tot: dict[str, int] = dict.fromkeys((
             *(p + "_s" for p in PHASES),
-            "prefill_s", "prefill_n", "prefill_kernel_n", "prefill_tokens",
-            "prefill_positions",
+            "prefill_s", "prefill_n", "prefill_chained_n",
+            "prefill_kernel_n", "prefill_tokens", "prefill_positions",
             "decode_s", "decode_n", "decode_tokens",
             "overlap_s", "step_wall_s", "rollback_n", "steps",
             "dispatch_n", "starved_n",

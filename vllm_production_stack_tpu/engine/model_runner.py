@@ -207,7 +207,9 @@ class StepHandle:
         # verify handles: (B_pad,) device vector of each row's full-
         # acceptance bonus token — the chain source for a decode window
         # dispatched on top of this still-in-flight verify step (decode
-        # handles chain from tokens[:, -1] instead; see _chain_fn)
+        # handles chain from tokens[:, -1] instead; see _chain_fn). A
+        # prefill handle's is its `tokens`: the first token of each row
+        # whose prompt the chunk ends
         self.chain_vec = None
         # grammar-enabled decode handles: (B_pad,) device vector of each
         # row's automaton state AFTER the window — a chained next window
@@ -1535,6 +1537,7 @@ class ModelRunner:
         )
         handle.moe = moe
         handle.positions = token_ids.size
+        handle.chain_vec = tokens_dev
         return handle
 
     @staticmethod
@@ -1639,7 +1642,7 @@ class ModelRunner:
         # step count as generated (seeded-sampling fold and min_tokens
         # suppression must see the serial-world counter); 0 on the sync path
         counts = [
-            len(r.output_token_ids) + r.num_inflight_tokens
+            len(r.output_token_ids) + r.num_inflight_outputs
             for r in work.requests
         ] + [0] * (b_pad - b)
 
@@ -1657,7 +1660,7 @@ class ModelRunner:
                 idx_dev = self._put(idx, self._batch1)
         if chained:
             with clock.span("enqueue", kind="decode"):
-                if prev.chain_vec is not None:  # previous step was a verify
+                if prev.chain_vec is not None:  # a verify, or a prefill
                     ft = self._chain_vec_fn(prev.chain_vec, ft, idx_dev)
                 else:
                     ft = self._chain_fn(prev.tokens, ft, idx_dev)
@@ -2309,6 +2312,32 @@ class ModelRunner:
                 n += 1
         logger.info("precompiled %d dominating programs", n)
         return n
+
+    def warm_chain_gathers(self) -> int:
+        """Compile the first-token gather (`_chain_vec_fn`) for every
+        (prefill row bucket, decode bucket) pair the built prefill programs
+        can feed, so the first window enqueued behind a chunk of each row
+        count waits on no compile. The gather is not a step program (tenths
+        of a second for all of them, no cache entry worth keeping): it is
+        run once on zeros that carry the sharding the prefill programs give
+        their token vector, which is part of the gather's cache key. Returns
+        the pairs run."""
+        sched = self.config.scheduler
+        # (kv_caches, tokens, ...) -> the token vector's sharding, per b_pad
+        srcs = {
+            key[1]: exe.output_shardings[1]
+            for key, exe in self._aot_exec.items() if key[0] == "prefill"
+        }
+        dsts = [
+            (self._put(np.zeros(d, np.int32), self._batch1),
+             self._put(np.full(d, -1, np.int32), self._batch1))
+            for d in sorted(set(sched.decode_buckets))
+        ]
+        for b, sharding in srcs.items():
+            vec = self._put(np.zeros(b, np.int32), sharding)
+            for host, idx in dsts:
+                self._chain_vec_fn(vec, host, idx)
+        return len(srcs) * len(dsts)
 
     def _prefill_avals(self, b: int, t: int, nb: int, want_gr: bool = False):
         """ShapeDtypeStructs mirroring _run's dynamic args for one prefill

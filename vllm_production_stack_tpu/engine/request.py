@@ -103,12 +103,19 @@ class Request:
     first_token_time: float | None = None
     finish_time: float | None = None
     num_preemptions: int = 0
-    # tokens dispatched in not-yet-resolved device steps (async pipeline):
-    # the scheduler plans the NEXT step at num_computed_tokens +
-    # num_inflight_tokens and treats the in-flight window as generated for
-    # max_tokens/window clamping; postprocess of the resolved step moves
-    # these into num_computed_tokens / output_token_ids for real
+    # positions dispatched in not-yet-resolved device steps (async
+    # pipeline): a decode window's or a verify row's fed tokens, a prefill
+    # chunk's tokens — their KV is being written. The scheduler plans the
+    # NEXT step at num_computed_tokens + num_inflight_tokens; postprocess
+    # of the resolved step moves these into num_computed_tokens for real
     num_inflight_tokens: int = 0
+    # output tokens those steps sample that have not reached the host: the
+    # whole window of a decode or verify step (full acceptance), the ONE
+    # first token of a prefill chunk that ends its prompt, none for a
+    # mid-prompt chunk. Counted as generated for max_tokens / window
+    # clamping and the seeded-sampling fold; a row with one takes its next
+    # input token from the in-flight step's device output
+    num_inflight_outputs: int = 0
     # goodput ledger (engine/saturation.GoodputLedger): accepted output
     # tokens not yet classified delivered/wasted. Charged in postprocess,
     # settled exactly once at finish (delivered for stop/length,

@@ -80,7 +80,8 @@ class DecodeWork:
     window: int = 1
     token_ids: list[int] = field(default_factory=list)  # first token per req
     positions: list[int] = field(default_factory=list)  # first position per req
-    # async pipeline: row index into the IN-FLIGHT DecodeWork whose last
+    # async pipeline: row index into the IN-FLIGHT step (a decode window,
+    # a verify, or the prefill chunk that ends this row's prompt) whose last
     # sampled (device-resident) token is this row's input, or -1 when
     # token_ids[i] carries a host-known token. Empty = all host (sync path).
     chain_rows: list[int] = field(default_factory=list)
@@ -158,6 +159,10 @@ class Scheduler:
         self._hash_chains: dict[str, list[int]] = {}  # req id -> per-block hashes
         self._last_was_prefill = False
         self._last_was_verify = False
+        # the two alternation flags as the last schedule() that returned
+        # work found them: a rollback of that work puts them back, so the
+        # serial re-dispatch takes the turn the serial loop would
+        self._turn_before = (False, False)
         self.total_preemptions = 0
         # speculative-decoding counters (vLLM metric parity:
         # spec_decode_num_draft_tokens / num_accepted_tokens), plus the
@@ -406,16 +411,22 @@ class Scheduler:
         return max(cands, key=lambda r: r.priority)  # first maximal = newest
 
     def schedule(
-        self, inflight: DecodeWork | VerifyWork | None = None
+        self, inflight: ScheduleOutput | None = None
     ) -> ScheduleOutput | None:
         """Build the next work item. `inflight` (async pipeline) is the
-        decode or verify step currently executing on device: rows carried
-        by it are planned at their speculatively-advanced positions and
-        chain their input token from its device-resident output
-        (chain_rows) — a verify in flight additionally lets its rows
-        PROPOSE again (its fed proposals are host-known values under full
-        acceptance), so verify steps chain on verify steps and speculation
-        stays engaged in the steady two-deep pipeline."""
+        step currently executing on device: rows carried by it are planned
+        at their speculatively-advanced positions and chain their input
+        token from its device-resident output (chain_rows) — a verify in
+        flight additionally lets its rows PROPOSE again (its fed proposals
+        are host-known values under full acceptance), so verify steps chain
+        on verify steps and speculation stays engaged in the steady
+        two-deep pipeline. A PREFILL chunk in flight is planned the same
+        way: a row whose prompt it ends is decode-ready at its prompt
+        length and takes its first token from the chunk's device output,
+        so the next window is enqueued behind the chunk. No second prefill
+        is scheduled over an unresolved one (its rows' num_computed_tokens
+        are still owed): with nothing to decode beside it this returns
+        None, and the step loop resolves the chunk first."""
         self.expire_deadlines()
         self.apply_evictions()
         if (
@@ -441,8 +452,16 @@ class Scheduler:
                 # resolvable victim. Bounds realtime priority inversion at
                 # one decode window instead of a whole seat turnover.
                 return None
-        decode_ready = [r for r in self.running if r.prefill_done]
-        prefilling = [r for r in self.running if not r.prefill_done]
+        # prefill_done at the speculative position (an in-flight chunk
+        # that reaches the row's target counts; a row that decodes has
+        # passed its target already)
+        decode_ready, prefilling = [], []
+        for r in self.running:
+            done = (
+                r.num_computed_tokens + r.num_inflight_tokens
+                >= r.prefill_target
+            )
+            (decode_ready if done else prefilling).append(r)
         can_admit = bool(self.waiting) and (
             len(self.running) < self.config.max_num_seqs
             or (
@@ -452,18 +471,19 @@ class Scheduler:
             )
         )
 
-        want_prefill = bool(prefilling) or can_admit
+        want_prefill = (
+            bool(prefilling) or can_admit
+        ) and not isinstance(inflight, PrefillWork)
+        turn = (self._last_was_prefill, self._last_was_verify)
+        work = None
         if want_prefill and (not decode_ready or not self._last_was_prefill):
             work = self._schedule_prefill(prefilling)
-            if work is not None:
-                self._last_was_prefill = True
-                return work
-        if decode_ready:
+        if work is None and decode_ready:
             work = self._schedule_decode_or_verify(decode_ready, inflight)
-            if work is not None:
-                self._last_was_prefill = False
-                return work
-        return None
+        if work is not None:
+            self._last_was_prefill = isinstance(work, PrefillWork)
+            self._turn_before = turn
+        return work
 
     def _schedule_decode_or_verify(
         self, ready: list[Request], inflight=None
@@ -532,7 +552,7 @@ class Scheduler:
             # a chained step for a row whose full acceptance would already
             # finish it (max_tokens / model length) is guaranteed waste —
             # it sits out until the in-flight verify resolves it
-            eff_out = len(r.output_token_ids) + r.num_inflight_tokens
+            eff_out = len(r.output_token_ids) + r.num_inflight_outputs
             eff_pos = r.num_computed_tokens + r.num_inflight_tokens
             if (
                 r.sampling.max_tokens - eff_out <= 0
@@ -802,19 +822,20 @@ class Scheduler:
         return chunk
 
     def _schedule_decode(
-        self, ready: list[Request], inflight: DecodeWork | None = None
+        self, ready: list[Request], inflight: ScheduleOutput | None = None
     ) -> DecodeWork | None:
         # async pipeline: rows with tokens in flight plan from their
         # speculatively-advanced position / output count (assumes the whole
-        # in-flight window gets accepted — any deviation finishes the
-        # request, which the engine's reconcile turns into a rollback of
-        # the step scheduled here). On the sync path inflight counts are 0
-        # and these reduce to the plain fields.
+        # in-flight window gets accepted, and that a first token sampled by
+        # an in-flight prefill chunk does not finish its row — any
+        # deviation finishes the request, which the engine's reconcile
+        # turns into a rollback of the step scheduled here). On the sync
+        # path inflight counts are 0 and these reduce to the plain fields.
         def eff_computed(r: Request) -> int:
             return r.num_computed_tokens + r.num_inflight_tokens
 
         def eff_outputs(r: Request) -> int:
-            return len(r.output_token_ids) + r.num_inflight_tokens
+            return len(r.output_token_ids) + r.num_inflight_outputs
 
         # rows whose speculative state already exhausted max_tokens /
         # max_model_len would have FINISHED in the resolved world — they
@@ -826,8 +847,8 @@ class Scheduler:
             and self.model_config.max_model_len - eff_computed(r) > 0
             # constrained rows chain decode-on-decode (the window program
             # hands the next window its post-window automaton states on
-            # device) but cannot chain onto a verify step, which carries no
-            # state vector — those sit one step out
+            # device) but cannot chain onto a verify step or a prefill
+            # chunk, which carry no state vector — those sit one step out
             and not (
                 r.sampling.grammar is not None
                 and r.num_inflight_tokens > 0
@@ -886,16 +907,17 @@ class Scheduler:
         for req in picked:
             pos = eff_computed(req)
             batch.positions.append(pos)
-            if req.num_inflight_tokens > 0:
+            if req.num_inflight_outputs > 0:
                 # input token is still device-resident (last column of the
-                # in-flight step's output matrix) — the runner chains it
+                # in-flight window's output matrix, or the first token the
+                # in-flight prefill chunk samples) — the runner chains it
                 # without a D2H→H2D round trip
                 src = inflight_row.get(req.request_id)
                 if src is None:
                     raise RuntimeError(
                         f"request {req.request_id} has "
-                        f"{req.num_inflight_tokens} in-flight tokens but is "
-                        "not in the in-flight decode step"
+                        f"{req.num_inflight_outputs} in-flight tokens but is "
+                        "not in the in-flight step"
                     )
                 batch.token_ids.append(0)  # placeholder; chained on device
                 batch.chain_rows.append(src)
@@ -1333,71 +1355,93 @@ class Scheduler:
 
     # -- async pipeline bookkeeping (engine/engine.py pipelined step loop) --
     #
-    # A dispatched-but-unresolved decode OR verify step advances its rows
-    # SPECULATIVELY: num_inflight_tokens carries the row's window (the
-    # decode window, or a verify row's fed length — full acceptance) so the
-    # next step can be scheduled (and dispatched, chaining its input tokens
-    # device-side) before the sampled tokens ever reach the host. The
-    # speculation is "every row accepts its whole window" — any deviation
-    # (mid-window stop token, PARTIAL draft acceptance, max-tokens/
-    # model-len finish, abort, stop-string hit) moves the reconciled base,
-    # which is what speculation_valid detects and the engine answers with
+    # A dispatched-but-unresolved step advances its rows SPECULATIVELY:
+    # num_inflight_tokens carries the positions it computes (the decode
+    # window, a verify row's fed length, a prefill chunk's tokens) and
+    # num_inflight_outputs the tokens it samples (the same window under
+    # full acceptance; the one first token of a chunk that ends its prompt)
+    # so the next step can be scheduled (and dispatched, chaining its input
+    # tokens device-side) before the sampled tokens ever reach the host.
+    # The speculation is "every row accepts its whole window, and a first
+    # token does not finish its row" — any deviation (mid-window stop
+    # token, PARTIAL draft acceptance, max-tokens/model-len finish, abort,
+    # stop-string hit) moves the reconciled base, which is what
+    # speculation_valid detects and the engine answers with
     # rollback_speculative on the step dispatched on top of it. A
     # mispredicted draft is therefore just another rollback — the unifying
     # move that lets speculation ride the pipeline (docs/36).
 
     @staticmethod
-    def _row_windows(work: DecodeWork | VerifyWork) -> list[int]:
-        """Per-row speculative advance of a dispatched step: the uniform
-        decode window, or each verify row's fed length (current token +
-        proposals == the tokens a full acceptance would resolve)."""
+    def _row_advance(work: ScheduleOutput) -> list[tuple[int, int]]:
+        """Per-row speculative advance of a dispatched step, as (positions,
+        sampled tokens): the uniform decode window twice, each verify row's
+        fed length twice (current token + proposals == the tokens a full
+        acceptance would resolve), a prefill row's chunk length and the one
+        token it samples where the chunk ends its prompt."""
+        if isinstance(work, PrefillWork):
+            return [
+                (len(t), int(s)) for t, s in zip(work.token_ids, work.sample)
+            ]
         if isinstance(work, VerifyWork):
-            return [len(t) for t in work.token_ids]
-        return [work.window] * len(work.requests)
+            return [(len(t), len(t)) for t in work.token_ids]
+        return [(work.window, work.window)] * len(work.requests)
 
     @staticmethod
-    def _row_positions(work: DecodeWork | VerifyWork) -> list[int]:
-        if isinstance(work, VerifyWork):
-            return [p[0] for p in work.positions]
-        return list(work.positions)
+    def _row_positions(work: ScheduleOutput) -> list[int]:
+        if isinstance(work, DecodeWork):
+            return list(work.positions)
+        return [p[0] for p in work.positions]
 
-    def begin_speculative(self, work: DecodeWork | VerifyWork) -> None:
-        """Mark `work`'s window as in flight on its rows (called right
+    def begin_speculative(self, work: ScheduleOutput) -> None:
+        """Mark `work`'s advance as in flight on its rows (called right
         after the engine dispatches the step to the device)."""
-        for req, w in zip(work.requests, self._row_windows(work)):
-            req.num_inflight_tokens += w
+        for req, (n, out) in zip(work.requests, self._row_advance(work)):
+            req.num_inflight_tokens += n
+            req.num_inflight_outputs += out
 
-    def end_speculative(self, work: DecodeWork | VerifyWork) -> None:
-        """Clear `work`'s window from its rows — the step has resolved and
-        postprocess() is about to apply its real results."""
-        for req, w in zip(work.requests, self._row_windows(work)):
-            req.num_inflight_tokens = max(0, req.num_inflight_tokens - w)
+    def end_speculative(self, work: ScheduleOutput) -> None:
+        """Clear `work`'s advance from its rows — the step has resolved and
+        postprocess() is about to apply its real results (or it was
+        discarded)."""
+        for req, (n, out) in zip(work.requests, self._row_advance(work)):
+            req.num_inflight_tokens = max(0, req.num_inflight_tokens - n)
+            req.num_inflight_outputs = max(0, req.num_inflight_outputs - out)
 
-    def speculation_valid(self, work: DecodeWork | VerifyWork) -> bool:
+    def speculation_valid(self, work: ScheduleOutput) -> bool:
         """After the PREVIOUS step resolved, is the speculatively dispatched
         `work` still consistent? Every row must still be running with its
         reconciled base position exactly where the dispatch assumed — a
         mid-window stop, a partial draft acceptance, max-tokens finish,
-        stop-string hit, or abort moves (or removes) it."""
-        for req, pos, w in zip(
-            work.requests, self._row_positions(work), self._row_windows(work)
+        stop-string hit, or abort moves (or removes) it; so does a first
+        token that finished its row."""
+        for req, pos, (n, _) in zip(
+            work.requests, self._row_positions(work), self._row_advance(work)
         ):
             if req.status.finished or req not in self.running:
                 return False
-            base = req.num_computed_tokens + req.num_inflight_tokens - w
+            base = req.num_computed_tokens + req.num_inflight_tokens - n
             if base != pos:
                 return False
         return True
 
-    def rollback_speculative(self, work: DecodeWork | VerifyWork) -> None:
-        """Discard a dispatched-but-invalidated decode/verify step: clear
-        its in-flight window and free the blocks allocated beyond each
-        row's real residency. The device still executes the discarded step,
-        but its writes land only at positions >= the speculative base —
-        beyond every registered prefix-cache block, and fully overwritten
-        (in device order) by whichever dispatch next owns those slots."""
-        for req, w in zip(work.requests, self._row_windows(work)):
-            req.num_inflight_tokens = max(0, req.num_inflight_tokens - w)
+    def rollback_speculative(self, work: ScheduleOutput) -> None:
+        """Discard a dispatched-but-invalidated step: clear its in-flight
+        advance and free the blocks allocated beyond each row's real
+        residency. The device still executes the discarded step, but its
+        writes land only at positions >= the speculative base — beyond
+        every registered prefix-cache block, and fully overwritten (in
+        device order) by whichever dispatch next owns those slots. The
+        prefill / decode and verify / decode turns go back to where the
+        discarded work's schedule() found them (nothing is scheduled
+        between a dispatch and its rollback), so the re-dispatch follows the
+        serial loop's order. Goodput ledger: what the step samples (a
+        window x its rows, a verify row's fed positions, a chunk's first
+        tokens) nobody will consume — sampled AND wasted in one motion
+        (they never reach postprocess)."""
+        self.ledger.rollback(sum(out for _, out in self._row_advance(work)))
+        self.end_speculative(work)
+        self._last_was_prefill, self._last_was_verify = self._turn_before
+        for req in work.requests:
             if req.status.finished or req not in self.running:
                 continue  # blocks already released by its finish
             keep = self._blocks_needed(
@@ -1451,6 +1495,15 @@ class Scheduler:
             sampled = accepted_rows
         if isinstance(work, PrefillWork):
             for i, req in enumerate(work.requests):
+                if req.status.finished:
+                    # aborted or expired while its chunk was in flight: its
+                    # blocks are released (the chunk's writes landed before
+                    # any later dispatch could own them) and its stream is
+                    # closed — a sampled first token is void, like a decode
+                    # row's (ledger: sampled and wasted in one motion)
+                    self.ledger.rollback(int(work.sample[i]))
+                    results.append((req, []))
+                    continue
                 start = req.num_computed_tokens
                 end = work.context_lens[i]
                 req.num_computed_tokens = end
