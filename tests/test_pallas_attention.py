@@ -584,10 +584,12 @@ def test_auto_prefill_backend_policy_gates():
 
 @pytest.mark.parametrize(
     "model", ["tiny-gemma2", "tiny-mistral-sliding", "tiny-softcap"])
-def test_prefill_backend_is_xla_for_sliding_and_softcapped_models(model):
-    """The kernel has no window masking and no softcap: 'auto' resolves to
-    XLA for such models whatever their shapes, an explicit 'pallas' is
-    refused at construction, and the model code refuses it too."""
+def test_prefill_backend_is_xla_for_softcapped_models_only(model):
+    """The kernels have no softcap: 'auto' resolves to XLA for a softcapped
+    model whatever its shapes, an explicit 'pallas' is refused at
+    construction, and the model code refuses it too. A window model WITHOUT
+    softcaps has kernels of its own (paged_window_*_attention): an explicit
+    'pallas' is served."""
     import dataclasses
 
     from vllm_production_stack_tpu.engine.config import (
@@ -617,9 +619,12 @@ def test_prefill_backend_is_xla_for_sliding_and_softcapped_models(model):
 
     assert runner("auto")._prefill_backend == "xla"
     assert not runner("auto").prefill_uses_kernel
-    with pytest.raises(ValueError, match="sliding-window or softcapped"):
+    if model == "tiny-mistral-sliding":
+        assert runner("pallas_interpret").prefill_uses_kernel
+        return
+    with pytest.raises(ValueError, match="does not support softcapped"):
         runner("pallas_interpret")
-    with pytest.raises(NotImplementedError, match="sliding-window or"):
+    with pytest.raises(NotImplementedError, match="softcapped models"):
         llama.forward(cfg, None, None, jnp.zeros((1, 4), jnp.int32), None,
                       None, None, None, backend="pallas_interpret")
 

@@ -49,7 +49,10 @@ class EngineStatsSnapshot:
 
     num_requests_running: int = 0
     num_requests_waiting: int = 0
-    kv_usage_perc: float = 0.0
+    kv_usage_perc: float = 0.0  # the fuller page group's share
+    kv_group_usage: dict = field(default_factory=dict)  # group -> share
+    kv_window_share: float = 0.0
+    kv_window_pages_given_back: int = 0
     prefix_cache_hit_rate: float = 0.0
     prefix_cache_hits: int = 0
     prefix_cache_queries: int = 0
@@ -142,28 +145,22 @@ def _refuse_kv_tiers_for_latent_pool(config: EngineConfig) -> None:
     groups along head_dim, the disk/remote/peer wires carry the K/V page
     geometry in their fingerprinted frames). Until each is, an MLA model
     refuses it at start with one sentence instead of mis-sizing silently."""
-    cache = config.cache
-    asked = {
-        "the host-RAM tier (--num-host-blocks / --host-kv-gib, "
-        "engine/kv_host_tier.py)": (
-            cache.num_host_blocks > 0 or cache.host_kv_gib > 0),
-        "the disk tier (--disk-kv-dir, engine/kv_disk_tier.py)": bool(
-            cache.disk_kv_dir and cache.disk_kv_gib > 0),
-        "the remote KV store (--remote-kv-url, kvstore/)": bool(
-            cache.remote_kv_url),
-        "the peer-engine tier (--kv-peer-fetch, engine/kv_peer.py, "
-        "engine/kv_device_transfer.py)": config.kv_peer_fetch,
-        "the at-rest codec (--kv-at-rest-codec, engine/kv_codec.py)": (
-            cache.kv_at_rest_codec != "none"),
-    }
-    hit = [name for name, on in asked.items() if on]
-    if hit:
+    asked = config.kv_tiers_asked()
+    if asked:
         raise ValueError(
             f"{config.model.model} keeps one latent a token in its pool "
-            f"(multi-head latent attention); {'; '.join(hit)} "
-            f"{'is' if len(hit) == 1 else 'are'} not implemented for a "
+            f"(multi-head latent attention); {'; '.join(asked)} "
+            f"{'is' if len(asked) == 1 else 'are'} not implemented for a "
             "latent page: run without"
         )
+
+
+def _window_pairs(start: int, n: int, window: int) -> int:
+    """Σ min(q + 1, window) over the queries q = start .. start + n - 1 of
+    one contiguous chunk: the (query, key) pairs a window layer covers."""
+    short = max(0, min(start + n, window - 1) - start)  # queries with q + 1 < window
+    return (short * (start + 1) + short * (short - 1) // 2
+            + (n - short) * window)
 
 
 class LLMEngine:
@@ -174,15 +171,22 @@ class LLMEngine:
         mesh=None,
         tokenizer: TokenizerWrapper | None = None,
     ):
+        from dataclasses import replace
+
+        from .memory import (
+            derive_num_blocks, resolve_window_blocks, window_group_blockers,
+        )
+
+        window_blocks = None
         if config.model.any_sliding:
             # the fused decode window's staged slots are globally
             # attendable — sound only while every staged position is
-            # within the sliding window; sp ring prefill has no window
-            # masking
-            if config.model.sliding_window <= config.scheduler.decode_window:
+            # within the window; sp ring prefill has no window masking
+            window = min(
+                s.window for s in config.model.layer_specs if s.window)
+            if window <= config.scheduler.decode_window:
                 raise ValueError(
-                    f"sliding_window ({config.model.sliding_window}) must "
-                    "exceed decode_window "
+                    f"sliding_window ({window}) must exceed decode_window "
                     f"({config.scheduler.decode_window})"
                 )
             if config.parallel.sequence_parallel_size > 1:
@@ -190,11 +194,25 @@ class LLMEngine:
                     "sequence parallelism does not support sliding-window "
                     "models yet"
                 )
+            # where the window layers' pages live is settled here, once:
+            # the runner's arrays and the scheduler's allocators follow the
+            # same rule (engine/memory.py:resolve_window_blocks)
+            window_blocks = resolve_window_blocks(config)
+            if window_blocks is not None:
+                logger.info(
+                    "%s: window layers keep their pages in a group of their "
+                    "own (%d pages: the window, a chunk and two decode "
+                    "windows a seat), given back behind the window",
+                    config.model.model, window_blocks)
+            elif not config.model.mla:
+                logger.info(
+                    "%s: window layers hold the whole context like the full "
+                    "layers (the window is a mask), because this "
+                    "configuration asks for %s; without, their pages live "
+                    "in a group of their own that holds the window only",
+                    config.model.model,
+                    "; ".join(window_group_blockers(config)))
         if config.cache.num_blocks is None:
-            from dataclasses import replace
-
-            from .memory import derive_num_blocks
-
             config = config.replace(
                 cache=replace(
                     config.cache,
@@ -203,6 +221,7 @@ class LLMEngine:
                         config.cache,
                         config.parallel,
                         max_num_seqs=config.scheduler.max_num_seqs,
+                        num_window_blocks=window_blocks,
                     ),
                 )
             )
@@ -427,6 +446,7 @@ class LLMEngine:
             need_slot_mappings=config.parallel.sequence_parallel_size > 1,
             flow=self.flow,
             hydrator=self.hydrator,
+            window_blocks=window_blocks,
         )
         if self.runner.kv_caches:
             # page geometry the remote-match path validates fetched blocks
@@ -1368,6 +1388,21 @@ class LLMEngine:
                 rec.count("attn_prefill_pairs_n", sum(
                     len(p) * int(p[0]) + len(p) * (len(p) + 1) // 2
                     for p in work.positions if len(p)))
+                window = self.config.model.max_window
+                if window:
+                    # a model with window layers: the pairs a WINDOW layer's
+                    # attention covers (a query at p sees min(p + 1, window)
+                    # keys), and the keys a chunk's attention must read at
+                    # least once in a full layer (its context) and in a
+                    # window layer (the chunk and the window before it)
+                    rec.count("attn_prefill_window_pairs_n", sum(
+                        _window_pairs(int(p[0]), len(p), window)
+                        for p in work.positions if len(p)))
+                    rec.count("attn_prefill_keys_n", sum(
+                        int(p[-1]) + 1 for p in work.positions if len(p)))
+                    rec.count("attn_prefill_window_keys_n", sum(
+                        min(int(p[-1]) + 1, len(p) + window - 1)
+                        for p in work.positions if len(p)))
                 self._meter_prefill(work, handle.positions)
             else:
                 # tokens actually ACCEPTED — exact for both the fused
@@ -1940,7 +1975,11 @@ class LLMEngine:
             kv_bytes_per_token=self.kv_bytes_per_token(),
             num_requests_running=self.scheduler.num_running,
             num_requests_waiting=self.scheduler.num_waiting,
-            kv_usage_perc=pool.usage_perc,
+            kv_usage_perc=pool.fullest_usage_perc,
+            kv_group_usage=pool.group_usage,
+            kv_window_share=self.scheduler.window_share(),
+            kv_window_pages_given_back=(
+                pool.window.given_back_total if pool.window else 0),
             prefix_cache_hit_rate=pool.stats.hit_rate,
             prefix_cache_hits=pool.stats.hits,
             prefix_cache_queries=pool.stats.queries,

@@ -73,6 +73,83 @@ class CacheStats:
         return self.hits / self.queries if self.queries else 0.0
 
 
+class WindowPageGroup:
+    """Host-side accounting for the WINDOW layers' pages — the second of an
+    engine's two page groups when its model mixes window and full attention
+    layers (models/llama.py:init_kv_cache gives each kind arrays of its own
+    size). `KVBlockPool` is the full group: a row's whole context, content
+    addressed. This group holds what a window layer can still read: a row's
+    table here has the full table's logical indexing, and a page wholly
+    behind the window of the row's RESOLVED position is given back — its
+    table slot becomes 0, the null page, which no kernel reads: a window
+    layer's walk starts at the page that holds the first visible key
+    (ops/paged_attention_pallas.py) and the XLA path masks it.
+
+    No content addressing: a prefix hit would also need the window group's
+    pages for the window before its boundary, which were given back — so
+    the group exists only where prefix caching is off, and with it on the
+    window layers hold the whole context in the full group like any other
+    layer (engine/memory.py:resolve_window_blocks; LLMEngine says at start
+    which). Page 0 is the null page, as in the full group."""
+
+    def __init__(self, num_blocks: int, block_size: int, window: int):
+        if num_blocks < 2:
+            raise ValueError("need at least 2 blocks (block 0 is the null page)")
+        self.num_blocks, self.block_size, self.window = (
+            num_blocks, block_size, window)
+        self._free: deque[int] = deque(range(1, num_blocks))
+        self.given_back_total = 0  # pages returned BEHIND a live row
+
+    @property
+    def num_usable(self) -> int:
+        return self.num_blocks - 1
+
+    @property
+    def num_free(self) -> int:
+        return len(self._free)
+
+    @property
+    def num_resident(self) -> int:
+        return self.num_usable - len(self._free)
+
+    @property
+    def usage_perc(self) -> float:
+        return 1.0 - self.num_free / self.num_usable
+
+    def allocate(self) -> int | None:
+        return self._free.popleft() if self._free else None
+
+    def free_block(self, blk: int) -> None:
+        self._free.append(blk)
+
+    def first_needed_block(self, num_computed_tokens: int) -> int:
+        """The first logical block a row still needs: the one that holds
+        the first key its lowest unresolved query (position
+        `num_computed_tokens`) sees."""
+        return max(0, num_computed_tokens - self.window + 1) // self.block_size
+
+    def give_back(self, table: list[int], first_live: int,
+                  num_computed_tokens: int) -> int:
+        """Return the pages of `table` (logical index -> page, 0 = given
+        back) that lie wholly behind the window of a row whose positions
+        below `num_computed_tokens` are resolved; `first_live` is the first
+        index that may still hold a page. Returns the new `first_live`."""
+        upto = min(self.first_needed_block(num_computed_tokens), len(table))
+        for j in range(first_live, upto):
+            if table[j]:
+                self.free_block(table[j])
+                table[j] = 0
+                self.given_back_total += 1
+        return max(first_live, upto)
+
+    def release(self, table: list[int], first_live: int = 0) -> None:
+        """Free every page a row still holds (finish, preemption)."""
+        for j in range(first_live, len(table)):
+            if table[j]:
+                self.free_block(table[j])
+        table.clear()
+
+
 class KVBlockPool:
     """Host-side accounting for the device page pool of ONE engine."""
 
@@ -91,6 +168,9 @@ class KVBlockPool:
         # optional HostKVTier: evicted cached blocks offload HBM→host and
         # prefix matches continue into it (engine/kv_host_tier.py)
         self.host_tier = host_tier
+        # the window layers' page group, where the model has window layers
+        # (the engine attaches it; the scheduler grows and gives back both)
+        self.window: WindowPageGroup | None = None
         # event stream for the cluster KV index (engine/kv_events.py): every
         # transition of a hash's local matchability is published so the KV
         # controller/router can answer /lookup without probing this engine.
@@ -163,6 +243,29 @@ class KVBlockPool:
         """Fraction of pool actively referenced — the TPU analogue of
         vllm:gpu_cache_usage_perc."""
         return 1.0 - self.num_free / self.num_usable
+
+    @property
+    def group_usage(self) -> dict[str, float]:
+        """Share of each page group in use. ONE group ("full") without
+        window layers."""
+        out = {"full": self.usage_perc}
+        if self.window is not None:
+            out["window"] = self.window.usage_perc
+        return out
+
+    @property
+    def fullest_usage_perc(self) -> float:
+        """The fuller group's share — the one that would preempt: what
+        `tpu:hbm_kv_usage_perc` reports."""
+        return max(self.group_usage.values())
+
+    @property
+    def growable_blocks(self) -> int:
+        """How many more logical blocks a row's tables could grow by right
+        now: both groups grow in lockstep, so the scarcer one decides."""
+        if self.window is None:
+            return self.num_free
+        return min(self.num_free, self.window.num_free)
 
     # -- allocation --------------------------------------------------------
 

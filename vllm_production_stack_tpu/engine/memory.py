@@ -13,7 +13,9 @@ import math
 import jax
 
 from ..utils.logging import init_logger
-from .config import CacheConfig, ModelConfig, ParallelConfig
+from .config import (
+    CacheConfig, EngineConfig, ModelConfig, ParallelConfig, SchedulerConfig,
+)
 
 logger = init_logger(__name__)
 
@@ -105,32 +107,50 @@ def param_bytes(cfg: ModelConfig, tp: int = 1, pp: int = 1,
         ) * dtype_bytes(cfg.dtype)
         # the selection bias is float32 whatever the model's dtype
         return total + m["moe_layers"] * cfg.num_experts * 4
-    h, hd = cfg.hidden_size, cfg.head_dim
-    nh, nkv, it, L = cfg.num_heads, cfg.num_kv_heads, cfg.intermediate_size, cfg.num_layers
-    attn = h * nh * hd + 2 * h * nkv * hd + nh * hd * h
-    mlp = 3 * h * it
-    norms = 2 * h
-    if cfg.num_experts:
-        mlp *= cfg.num_experts // ep
-        norms += h * cfg.num_experts  # the router: unsharded, like norms
-    norms += qk_norm_params(cfg)
-    layers_per_stage = (L + pp - 1) // pp
-    per_layer = (attn + mlp) // tp + norms
+    # every other tree, from the per-layer list: a layer's matrices shard
+    # over tp (its held experts over ep first), its norms, q/k norms and
+    # router are replicated; pp shards the layer axis (a tree stacked by
+    # layer kind runs on one device: tp = pp = ep = 1 there)
+    h, hd, nkv, L = (
+        cfg.hidden_size, cfg.head_dim, cfg.num_kv_heads, cfg.num_layers)
+    expert = 3 * h * cfg.expert_intermediate_size
+    layers = 0
+    for spec in cfg.layer_specs:
+        nh = spec.num_heads
+        sharded = 2 * h * nh * hd + 2 * h * nkv * hd
+        norms = (0 if cfg.post_norms_only else 2) + (
+            2 if cfg.sandwich_norms or cfg.post_norms_only else 0)
+        replicated = norms * h + qk_norm_params(cfg)
+        if cfg.attn_output_gate:
+            sharded += h * nh
+        if spec.experts:
+            sharded += (
+                cfg.held_experts // ep + cfg.n_shared_experts) * expert
+            replicated += h * cfg.num_experts  # the router, published width
+        else:
+            sharded += 3 * h * cfg.intermediate_size
+        layers += sharded // tp + replicated
+        if cfg.attention_bias:
+            layers += (nh * hd + 2 * nkv * hd) // tp
     embed = cfg.vocab_size * h // tp
     head = 0 if cfg.tie_word_embeddings else h * cfg.vocab_size // tp
-    total = embed + layers_per_stage * per_layer + h + head
-    if cfg.attention_bias:
-        total += layers_per_stage * (nh * hd + 2 * nkv * hd) // tp
+    total = embed + layers * ((L + pp - 1) // pp) // L + h + head
     return total * dtype_bytes(cfg.dtype)
 
 
 def kv_block_bytes(cfg: ModelConfig, block_size: int, tp: int = 1,
-                   pp: int = 1, kv_dtype: str | None = None) -> int:
+                   pp: int = 1, kv_dtype: str | None = None,
+                   group: str | None = None) -> int:
     """Per-device bytes of ONE pool block across all layers: kv heads shard
     over tp and the block axis shards over pp, so a device holds every
     layer's pages for 1/pp of the blocks — per-device cost of adding a
     block is therefore 1/pp of its global bytes. kv_dtype overrides the
-    model dtype when the pool is quantized (CacheConfig.kv_cache_dtype)."""
+    model dtype when the pool is quantized (CacheConfig.kv_cache_dtype).
+
+    `group`: "full" or "window" counts the layers of that PAGE GROUP only
+    (engine/kv_cache.py: a model with window layers keeps two groups of
+    pages, each sized on its own; 0 for a group without layers); None
+    counts every layer (a model with one group)."""
     if cfg.mla:
         # one latent a token and layer, `latent_width` values as stored
         # (the zero padding to whole lane tiles is real HBM, so it counts)
@@ -139,10 +159,85 @@ def kv_block_bytes(cfg: ModelConfig, block_size: int, tp: int = 1,
             * dtype_bytes(kv_dtype or cfg.dtype)
         ) // pp)
     kvh = max(1, cfg.num_kv_heads // tp)
-    return max(1, (
-        cfg.num_layers * 2 * block_size * kvh * cfg.head_dim
+    layers = {None: cfg.num_layers, "full": len(cfg.full_layers),
+              "window": len(cfg.window_layers)}[group]
+    nbytes = (
+        layers * 2 * block_size * kvh * cfg.head_dim
         * dtype_bytes(kv_dtype or cfg.dtype)
-    ) // pp)
+    ) // pp
+    return nbytes if group else max(1, nbytes)
+
+
+def window_row_blocks(model: ModelConfig, cache: CacheConfig,
+                      sched: SchedulerConfig) -> int:
+    """Pages of the WINDOW group one row can hold at once: the window behind
+    its resolved position, one prefill chunk in flight (the give-back runs
+    on resolved positions only: a rolled-back step must find its pages),
+    two decode windows (one in flight, one enqueued behind it), and the
+    page rounding at both ends."""
+    tokens = (model.max_window + sched.max_num_batched_tokens
+              + 2 * sched.decode_window)
+    return math.ceil(tokens / cache.block_size) + 2
+
+
+def window_group_blockers(config: EngineConfig) -> list[str]:
+    """What a configuration asks for that needs a window layer's pages to
+    live as long as the row's, or that no layout of the window page group
+    has been built for. Any of them keeps the window layers on the ONE
+    full-length group (every layer holds the context, the window is a mask
+    over it: what a sliding model ran on before the group existed), so
+    nothing a sliding model could be served with is lost to the group."""
+    asked = []
+    if config.cache.enable_prefix_caching:
+        # a hit would also need the window group's pages for the window
+        # before its boundary, which are given back
+        asked.append("prefix caching (on unless --no-enable-prefix-caching)")
+    asked += config.kv_tiers_asked()  # content-addressed pages of one group
+    if config.parallel.world_size > 1:
+        # one window table a row on one device: no dp shard of it, no pp
+        # shard of the group's block axis, no ring-attention window mask
+        asked.append(f"a mesh of {config.parallel.world_size} devices")
+    if config.lora.max_loras > 0:
+        # adapters ran only over one table a row (and salt prefix hits)
+        asked.append("LoRA adapters")
+    sched = config.scheduler
+    if sched.num_speculative_tokens and sched.speculative_method == "draft":
+        # the draft's scratch pages live in the full group only
+        asked.append("a draft model")
+    return asked
+
+
+def resolve_window_blocks(config: EngineConfig) -> int | None:
+    """Pages of the WINDOW group (incl. its null page 0), or None where
+    every layer's pages live in one group: a model without window layers,
+    a latent pool, or a configuration that asks for something the group
+    has no layout for (`window_group_blockers`). Every seat's worst case
+    (`window_row_blocks`), so the group never runs out, whatever the rows
+    do. The ONE rule the engine, the runner's arrays and the scheduler's
+    allocator follow."""
+    model = config.model
+    if not model.any_sliding or model.mla or window_group_blockers(config):
+        return None
+    return config.scheduler.max_num_seqs * window_row_blocks(
+        model, config.cache, config.scheduler) + 1
+
+
+def kv_pool_bytes(model: ModelConfig, cache: CacheConfig,
+                  parallel: ParallelConfig, num_window_blocks: int | None,
+                  kv_dtype: str | None = None) -> int:
+    """Per-device bytes of the whole paged pool: `num_blocks` pages of
+    every layer, or with a window group `num_blocks` pages of the full
+    layers and `num_window_blocks` of the window layers."""
+    tp, pp = parallel.tensor_parallel_size, parallel.pipeline_parallel_size
+    kv_dtype = kv_dtype or cache.resolved_kv_dtype(model.dtype)
+    if num_window_blocks is None:
+        return cache.num_blocks * kv_block_bytes(
+            model, cache.block_size, tp, pp, kv_dtype=kv_dtype)
+    return (
+        cache.num_blocks * kv_block_bytes(
+            model, cache.block_size, tp, pp, kv_dtype, group="full")
+        + num_window_blocks * kv_block_bytes(
+            model, cache.block_size, tp, pp, kv_dtype, group="window"))
 
 
 def hoist_reserve_bytes(
@@ -237,8 +332,14 @@ def derive_num_blocks(
     parallel: ParallelConfig,
     hbm_bytes: int | None = None,
     max_num_seqs: int | None = None,
+    num_window_blocks: int | None = None,
 ) -> int:
     """Blocks that fit in hbm_utilization × HBM after weights + reserve.
+
+    With a window page group (`num_window_blocks`, resolve_window_blocks)
+    its bytes come off the budget first and the result counts the FULL
+    group's pages, at the full layers' bytes a page; a model whose layers
+    are all window layers keeps only bookkeeping in the full group.
 
     The fused decode window keeps the pool loop-invariant (staged-KV design,
     ops/attention.py:paged_attention_with_staged), so compile-time temps no
@@ -254,10 +355,18 @@ def derive_num_blocks(
     budget = headroom_budget(
         model, cache, parallel, hbm
     ) - hoist_reserve_bytes(model, cache, parallel, max_num_seqs)
-    per_block = kv_block_bytes(
-        model, cache.block_size, tp, pp,
-        kv_dtype=cache.resolved_kv_dtype(model.dtype),
-    )
+    kv_dtype = cache.resolved_kv_dtype(model.dtype)
+    per_block = kv_block_bytes(model, cache.block_size, tp, pp, kv_dtype)
+    if num_window_blocks is not None:
+        budget -= num_window_blocks * kv_block_bytes(
+            model, cache.block_size, tp, pp, kv_dtype, group="window")
+        per_block = kv_block_bytes(
+            model, cache.block_size, tp, pp, kv_dtype, group="full")
+        if per_block == 0:
+            # no full layer: the full group's block ids are the scheduler's
+            # bookkeeping only, and no array holds them
+            return (max_num_seqs or 1) * cache.max_blocks_per_seq(
+                model.max_model_len) + 1
     # pp shards the block axis, so the pool must hold >= pp blocks (and the
     # pp-divisibility rounding below must never round UP past the budget)
     if budget < 2 * per_block * max(1, pp):

@@ -172,6 +172,21 @@ class EngineMetrics:
         self.kv_usage = gauge(
             mc.HBM_KV_USAGE_PERC, "Fraction of HBM KV pages in active use"
         )
+        self.kv_group_usage = Gauge(
+            mc.HBM_KV_GROUP_USAGE_PERC,
+            "Fraction of each KV page group in active use (full | window)",
+            [*names, "group"], registry=self.registry,
+        )
+        self.kv_group_usage.labels(**self._labels, group="full")
+        self.kv_window_share = gauge(
+            mc.HBM_KV_WINDOW_SHARE,
+            "Window-group pages resident / pages the same rows would hold "
+            "in those layers at full length",
+        )
+        self.kv_window_given_back = counter(
+            mc.HBM_KV_WINDOW_PAGES_GIVEN_BACK,
+            "Window-group pages given back behind live rows",
+        )
         self.prefix_hit_rate = gauge(
             mc.PREFIX_CACHE_HIT_RATE, "Prefix cache block hit rate"
         )
@@ -655,6 +670,11 @@ class EngineMetrics:
         self.num_running.labels(**lb).set(s.num_requests_running)
         self.num_waiting.labels(**lb).set(s.num_requests_waiting)
         self.kv_usage.labels(**lb).set(s.kv_usage_perc)
+        for group, frac in s.kv_group_usage.items():
+            self.kv_group_usage.labels(**lb, group=group).set(frac)
+        self.kv_window_share.labels(**lb).set(s.kv_window_share)
+        self._bump(self.kv_window_given_back, "win_back",
+                   s.kv_window_pages_given_back)
         self.prefix_hit_rate.labels(**lb).set(s.prefix_cache_hit_rate)
         self._bump(self.prefix_hits, "hits", s.prefix_cache_hits)
         self._bump(self.prefix_queries, "queries", s.prefix_cache_queries)

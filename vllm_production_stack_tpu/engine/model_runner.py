@@ -180,6 +180,48 @@ def _refuse_unbuilt_for_latent_attention(config: EngineConfig) -> None:
         )
 
 
+def _moe_device_counts(cfg, stats: list):
+    """What an MoE program hands back of its routing, summed over `stats`
+    (one entry an expert layer executed): the experts touched, a scalar
+    int32 — and, from a program that holds a share of the experts
+    (ModelConfig.num_experts_held), beside it the pairs that landed on the
+    held experts, as (2,) int32."""
+    touched = sum((s["touched"] for s in stats), jnp.int32(0))
+    if not cfg.num_experts_held:
+        return touched
+    return jnp.stack(
+        [touched, sum((s["held_pairs"] for s in stats), jnp.int32(0))])
+
+
+def _refuse_unbuilt_for_tree_by_kind(config: EngineConfig) -> None:
+    """A model whose param tree stacks its leaves by layer kind
+    (ModelConfig.tree_by_kind, models/llama.py:_init_params_by_kind) runs
+    on ONE device, unquantized, without adapters: what knows the flat tree
+    only is refused by name at start. (Where its window layers' pages live
+    is not refused but chosen: engine/memory.py:resolve_window_blocks.)"""
+    cfg, name, par = config.model, config.model.model, config.parallel
+    if par.world_size > 1:
+        raise ValueError(
+            f"{name}: tensor/data/pipeline/sequence/expert parallelism "
+            f"(world size {par.world_size}) is not implemented for a param "
+            "tree stacked by layer kind — parallel/sharding.py has no spec "
+            "for its leaves (attn_window.*, attn.wg, moe.shared, the "
+            "mlp/moe stacks of unequal depth, a held share of the experts)"
+        )
+    if cfg.quantization:
+        raise ValueError(
+            f"{name}: --quantization {cfg.quantization} is not implemented "
+            "for a param tree stacked by layer kind — models/quantization.py "
+            "names no int8 form for attn_window.*, attn.wg or moe.shared"
+        )
+    if config.lora.max_loras > 0:
+        raise ValueError(
+            f"{name}: LoRA adapters are not implemented for a param tree "
+            "stacked by layer kind (adapter buffers are stacked over all "
+            "layers at one head count)"
+        )
+
+
 class StepHandle:
     """One dispatched-but-unresolved device step — the async pipeline's
     unit of in-flight work (engine/engine.py pipelined step loop).
@@ -241,9 +283,15 @@ class StepHandle:
             if self.moe is not None:
                 _, kind, pairs, slots = self.moe
                 clock = self.runner.clock
-                clock.count(f"moe_{kind}_touched_n", int(got[-1]))
+                # (touched,), or (touched, held pairs) from a program that
+                # holds a share of the experts: _moe_device_counts
+                counts = np.ravel(got[-1])
+                clock.count(f"moe_{kind}_touched_n", int(counts[0]))
                 clock.count(f"moe_{kind}_pairs_n", pairs)
                 clock.count(f"moe_{kind}_slots_n", slots)
+                clock.count(
+                    f"moe_{kind}_held_pairs_n",
+                    int(counts[1]) if counts.size > 1 else pairs)
             self._rows, self.logprob_rows = self._postproc(mat, lp)
         return self._rows
 
@@ -279,6 +327,8 @@ class ModelRunner:
         cfg = config.model
         if cfg.mla:
             _refuse_unbuilt_for_latent_attention(config)
+        if cfg.tree_by_kind:
+            _refuse_unbuilt_for_tree_by_kind(config)
         pp = config.parallel.pipeline_parallel_size
         if pp > 1:
             # friendly errors beat JAX's deep 'dimension not divisible'
@@ -335,10 +385,19 @@ class ModelRunner:
         kv_sharding = NamedSharding(
             self.mesh, P() if cfg.mla else kv_cache_spec())
         self._kv_dtype = config.cache.resolved_kv_dtype(cfg.dtype)
+        # a model with window layers keeps their pages in a group of their
+        # own (engine/kv_cache.py:WindowPageGroup): a second block table,
+        # written span and slot vector ride beside the full group's, as
+        # (full, window) pairs (models/llama.py:forward)
+        from .memory import resolve_window_blocks
+
+        self.num_window_blocks = resolve_window_blocks(config)
+        self._window_group = self.num_window_blocks is not None
         self.kv_caches = jax.jit(
             lambda: llama.init_kv_cache(
                 cfg, config.cache.num_blocks, config.cache.block_size,
                 dtype=self._kv_dtype,
+                num_window_blocks=self.num_window_blocks,
             ),
             out_shardings=kv_sharding,
         )()
@@ -552,13 +611,14 @@ class ModelRunner:
         scratch that OOMs large models. Under tp>1 an explicit 'pallas' runs one kernel per device
         through shard_map; CPU tests pin numerics via interpret mode."""
         backend = self.config.attention_backend
-        if self.config.model.any_sliding:
-            # sliding-window models (Mistral-v0.1, Gemma-2 class): the
-            # Pallas kernels have no window masking yet — XLA only
+        if self.config.model.attn_logit_softcap:
+            # softcapped scores (Gemma-2 class): the Pallas kernels have
+            # no softcap — XLA only. (A window layer has kernels of its
+            # own: paged_window_decode_attention.)
             if backend in ("pallas", "pallas_interpret"):
                 raise ValueError(
                     "attention_backend='pallas' does not support "
-                    "sliding-window models; use 'xla'"
+                    "softcapped models; use 'xla'"
                 )
             return "xla"
         if backend == "auto":
@@ -618,12 +678,12 @@ class ModelRunner:
         )
         backend = self.config.prefill_attention_backend
         model = self.config.model
-        if model.any_sliding or model.attn_logit_softcap:
-            # the kernel has no window masking and no softcap — XLA only
+        if model.attn_logit_softcap:
+            # the kernel has no softcap — XLA only
             if backend in ("pallas", "pallas_interpret"):
                 raise ValueError(
                     "prefill_attention_backend='pallas' does not support "
-                    "sliding-window or softcapped models; use 'xla'"
+                    "softcapped models; use 'xla'"
                 )
             return "xla"
         kv_itemsize = jnp.dtype(self._kv_dtype).itemsize
@@ -736,13 +796,12 @@ class ModelRunner:
         falls back to the per-iteration gather when it doesn't fit. The cap
         matters: memory the operator withheld via hbm_utilization (co-located
         workloads) must not be absorbed by hoisted copies."""
-        from .memory import headroom_budget, kv_block_bytes
+        from .memory import headroom_budget, kv_pool_bytes
 
         par = self.config.parallel
-        pool = self.config.cache.num_blocks * kv_block_bytes(
-            self.config.model, self.config.cache.block_size,
-            par.tensor_parallel_size, par.pipeline_parallel_size,
-            kv_dtype=self._kv_dtype,
+        pool = kv_pool_bytes(
+            self.config.model, self.config.cache, par,
+            self.num_window_blocks, kv_dtype=self._kv_dtype,
         )
         return max(
             0,
@@ -841,7 +900,7 @@ class ModelRunner:
                 pack=pack,
             )
             moe_out = (
-                (sum(s["touched"] for s in moe_stats),) if moe_stats else ()
+                (_moe_device_counts(cfg, moe_stats),) if moe_stats else ()
             )
             flat = hidden.reshape(-1, hidden.shape[-1])
             picked = flat[sample_rows]  # (num_samples, h)
@@ -1003,10 +1062,12 @@ class ModelRunner:
             top_lp_out = jnp.zeros((b, window, LOGPROBS_TOPN), jnp.float32)
             top_id_out = jnp.zeros((b, window, LOGPROBS_TOPN), jnp.int32)
             staged = llama.init_staged_kv(cfg, window, b)
+            windows = [spec.window for spec in cfg.layer_specs]
             # hoist the loop-invariant history gather out of the window loop
             # when this program's contiguous copy fits HBM headroom (static
             # per compiled (batch, nb, window) program — no runtime branch)
-            s_ctx = block_tables.shape[1] * self.config.cache.block_size
+            s_ctx = (llama._group(block_tables, 0).shape[1]
+                     * self.config.cache.block_size)
             hoist = (
                 self._attention_backend == "xla"
                 and not cfg.mla  # (the latent fallback gathers per step)
@@ -1016,8 +1077,9 @@ class ModelRunner:
             if hoist:
                 with jax.named_scope("gather_pages"):
                     hists = tuple(
-                        gather_pages(kv_caches[i], block_tables)
-                        for i in range(cfg.num_layers)
+                        gather_pages(
+                            kv_caches[i], llama._group(block_tables, w))
+                        for i, w in enumerate(windows)
                     )
 
             if want_grammar:
@@ -1037,7 +1099,7 @@ class ModelRunner:
                 *carry, moe_n = carry
                 stats: list = []
                 out = step(k, carry, stats)
-                return (*out, moe_n + sum(s["touched"] for s in stats))
+                return (*out, moe_n + _moe_device_counts(cfg, stats))
 
             def step(k, carry, moe_stats):
                 if want_grammar:
@@ -1105,7 +1167,9 @@ class ModelRunner:
                         lp_out, top_lp_out, top_id_out,
                     )
 
-            moe_out = (jnp.int32(0),) if cfg.num_experts else ()
+            moe_out = (
+                (jnp.zeros_like(_moe_device_counts(cfg, [])),)
+                if cfg.num_experts else ())
             if want_grammar:
                 (staged, _, out, lp_out, top_lp_out, top_id_out,
                  gstates, *moe_out) = jax.lax.fori_loop(
@@ -1124,9 +1188,15 @@ class ModelRunner:
             # commit the window's KV to the pool: slots for row r, step k are
             # position positions0[r] + k via the row's block table
             pos = positions0[:, None] + jnp.arange(window, dtype=jnp.int32)[None, :]
-            blk = jnp.take_along_axis(block_tables, pos // block_size, axis=1)
-            slots = (blk * block_size + pos % block_size).reshape(-1)
-            kv_caches = llama.commit_staged_kv(kv_caches, staged, slots)
+
+            def slots_of(tables):
+                blk = jnp.take_along_axis(tables, pos // block_size, axis=1)
+                return (blk * block_size + pos % block_size).reshape(-1)
+
+            # (a table a page group: a window layer's slots are its own)
+            slots = jax.tree.map(slots_of, block_tables)
+            kv_caches = llama.commit_staged_kv(
+                kv_caches, staged, slots, cfg.window_layers)
             # grammar programs also return the final per-row automaton
             # state — a chained next window gathers its gr_state0 from it
             # on device (same pattern as chain_vec for tokens)
@@ -1249,9 +1319,8 @@ class ModelRunner:
             write_ids[i, :n_span] = req.block_table[first_blk : first_blk + n_span]
             start_off[i] = hist % bs
             lora_idx[i] = req.lora_index
-        block_tables = self._block_table_array(
-            [r.block_table for r in work.requests], pad_to=b_pad
-        )
+        write_ids = self._with_window_span(write_ids, work, bs)
+        block_tables = self._group_tables(work.requests, pad_to=b_pad)
         # structured output: per-position masks are host-buildable because a
         # verify row's fed tokens are host-known (the scheduler never chains
         # a constrained row's verify input from an in-flight step). Position
@@ -1478,9 +1547,9 @@ class ModelRunner:
             temps[i], top_ps[i], top_ks[i] = s.temperature, s.top_p, s.top_k
             seeds[i] = s.seed
             counts[i] = len(req.output_token_ids)
-        block_tables = self._block_table_array(
-            [r.block_table for r in work.requests], pad_to=b_pad, width=nb
-        )
+        write_ids = self._with_window_span(write_ids, work, bs)
+        block_tables = self._group_tables(
+            work.requests, pad_to=b_pad, width=nb)
         lora_idx = np.zeros(b_pad, np.int32)
         for i, req in enumerate(work.requests):
             lora_idx[i] = req.lora_index
@@ -1631,9 +1700,8 @@ class ModelRunner:
             idx[: len(chain)] = chain
         positions0 = np.zeros(b_pad, np.int32)
         positions0[:b] = work.positions
-        block_tables = self._block_table_array(
-            [r.block_table for r in work.requests], pad_to=b_pad, width=nb
-        )
+        block_tables = self._group_tables(
+            work.requests, pad_to=b_pad, width=nb)
         temps = [r.sampling.temperature for r in work.requests] + [0.0] * (b_pad - b)
         top_ps = [r.sampling.top_p for r in work.requests] + [1.0] * (b_pad - b)
         top_ks = [r.sampling.top_k for r in work.requests] + [0] * (b_pad - b)
@@ -1916,7 +1984,7 @@ class ModelRunner:
         # expert layers only: leading dense layers route nothing
         layer_steps = cfg.num_moe_layers * steps
         return (touched, kind, tokens * cfg.num_experts_per_tok * layer_steps,
-                layer_steps * cfg.num_experts)
+                layer_steps * cfg.held_experts)
 
     def _grammar_device_tables(self, grammars: list, gkey: tuple):
         """Replicated device copies of the batch's automaton tables, padded
@@ -2352,14 +2420,16 @@ class ModelRunner:
         # (sp > 1 compiles nothing through here: _dynamic_programs_ok)
         packed = self.prefill_packed
         tok = s((t,), i32, rep) if packed else s((b, t), i32, b2)
+        # (a (full, window) pair of each where window layers have a group)
+        groups = (lambda a: (a, a)) if self._window_group else (lambda a: a)
         return (
             tok,                      # token_ids
             tok,                      # positions
-            s((b, nb), i32, b2),      # block_tables
+            groups(s((b, nb), i32, b2)),  # block_tables
             s((1,), i32, rep),        # slots placeholder (paged path)
             s((b,), i32, b1),         # context_lens
             s((b,), i32, b1),         # chunk_lens
-            s((b, nbw), i32, b2),     # write_ids
+            groups(s((b, nbw), i32, b2)),  # write_ids
             s((b,), i32, b1),         # start_off
             s((b,), i32, b1) if self._use_lora else None,  # lora_idx
             s((b,), i32, b1),         # sample_rows
@@ -2392,10 +2462,11 @@ class ModelRunner:
                 s((b,), i32, b1),          # gr_state0
                 s((1,), i32, rep),         # gr_eos
             )
+        tables = s((b, nb), i32, b2)
         return (
             s((b,), i32, b1),         # first_tokens
             s((b,), i32, b1),         # positions0
-            s((b, nb), i32, b2),      # block_tables
+            (tables, tables) if self._window_group else tables,  # block_tables
             s((b,), i32, b1) if self._use_lora else None,  # lora_idx
             s((b,), f32, b1),         # temperature
             s((b,), f32, b1),         # top_p
@@ -2454,6 +2525,32 @@ class ModelRunner:
         for i, tbl in enumerate(tables):
             arr[i, : len(tbl)] = tbl
         return arr
+
+    def _group_tables(self, requests, pad_to=None, width=None):
+        """The rows' block tables: one array, or for a model with window
+        layers the (full, window) pair — the window group's table has the
+        same logical indexing, 0 (the null page) where a page was given
+        back (kv_cache.py:WindowPageGroup.give_back)."""
+        full = self._block_table_array(
+            [r.block_table for r in requests], pad_to=pad_to, width=width)
+        if not self._window_group:
+            return full
+        return full, self._block_table_array(
+            [r.window_table for r in requests], pad_to=pad_to,
+            width=full.shape[1])
+
+    def _with_window_span(self, write_ids: np.ndarray, work, bs: int):
+        """`write_ids` (the full group's pages of each row's written span)
+        paired with the window group's pages of the same span."""
+        if not self._window_group:
+            return write_ids
+        ids_w = np.zeros_like(write_ids)
+        for i, req in enumerate(work.requests):
+            first_blk = (work.context_lens[i] - len(work.token_ids[i])) // bs
+            n_span = (work.context_lens[i] - 1) // bs - first_blk + 1
+            ids_w[i, :n_span] = req.window_table[
+                first_blk : first_blk + n_span]
+        return write_ids, ids_w
 
     # -- embeddings (/v1/embeddings; plain encode, no paged KV) ------------
 
@@ -2684,6 +2781,7 @@ class ModelRunner:
             lambda: llama.init_kv_cache(
                 cfg.model, cfg.cache.num_blocks, cfg.cache.block_size,
                 dtype=self._kv_dtype,
+                num_window_blocks=self.num_window_blocks,
             ),
             out_shardings=NamedSharding(self.mesh, kv_cache_spec()),
         )()

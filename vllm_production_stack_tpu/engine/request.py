@@ -90,6 +90,12 @@ class Request:
     # blocks owned by this request, logical order (block_table[i] = page of
     # tokens [i*block_size, (i+1)*block_size))
     block_table: list[int] = field(default_factory=list)
+    # the row's pages in the WINDOW group (engine/kv_cache.py), where the
+    # model has window layers: the same logical indexing and length as
+    # block_table, 0 where a page fell behind the window and was given
+    # back; window_first_live is the first index that may still hold one
+    window_table: list[int] = field(default_factory=list)
+    window_first_live: int = 0
     # tokens whose KV is resident (prefix-cache hits + computed prefill/decode)
     num_computed_tokens: int = 0
     num_cached_prompt_tokens: int = 0  # prefix-cache hits at admission

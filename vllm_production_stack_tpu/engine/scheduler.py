@@ -17,7 +17,7 @@ from dataclasses import dataclass, field
 
 from ..qos import FairShareClock, TenantAccounting
 from .config import CacheConfig, ModelConfig, SchedulerConfig
-from .kv_cache import KVBlockPool, chain_hash
+from .kv_cache import KVBlockPool, WindowPageGroup, chain_hash
 from .request import Request, RequestStatus
 from .saturation import GoodputLedger
 from .spec_decode import propose_ngram
@@ -139,6 +139,7 @@ class Scheduler:
         accounting: TenantAccounting | None = None,
         flow=None,
         hydrator=None,
+        window_blocks: int | None = None,
     ):
         self.model_config = model_config
         self.cache_config = cache_config
@@ -154,6 +155,21 @@ class Scheduler:
             cache_config.enable_prefix_caching,
             host_tier=host_tier,
         )
+        # `window_blocks` (engine/memory.py:resolve_window_blocks; never
+        # with prefix caching): the window layers' pages live in a second
+        # group of that many pages (kv_cache.WindowPageGroup)
+        if window_blocks is not None:
+            from .memory import window_row_blocks
+
+            if cache_config.enable_prefix_caching:
+                raise ValueError(
+                    "a window page group gives pages back behind the "
+                    "window: a prefix hit could not find them")
+            self._window_row_blocks = window_row_blocks(
+                model_config, cache_config, scheduler_config)
+            self.pool.window = WindowPageGroup(
+                window_blocks, cache_config.block_size,
+                model_config.max_window)
         self.waiting: deque[Request] = deque()
         self.running: list[Request] = []
         self._hash_chains: dict[str, list[int]] = {}  # req id -> per-block hashes
@@ -669,7 +685,7 @@ class Scheduler:
             while proposal and (
                 self._blocks_needed(start + 1 + len(proposal))
                 - len(req.block_table)
-                > self.pool.num_free
+                > self.pool.growable_blocks
             ):
                 proposal.pop()
             if not self._ensure_blocks(req, start + 1 + len(proposal)):
@@ -880,7 +896,9 @@ class Scheduler:
         # never preempts a candidate to grow another candidate's window (a
         # sole running request preempting *itself* is a livelock: it re-admits,
         # recomputes, and hits the same wall forever)
-        while window > 1 and self._extra_blocks(cand, window) > self.pool.num_free:
+        while window > 1 and (
+            self._extra_blocks(cand, window) > self.pool.growable_blocks
+        ):
             window -= 1
         # snap down to a power of two: `window` is a static jit arg of the
         # fused decode program, so every distinct value costs a compile —
@@ -950,13 +968,38 @@ class Scheduler:
         recompute target plus one decode token — prevents admission/preemption
         thrash (the oldest running request must always be able to finish)."""
         need = self._blocks_needed(req.prefill_target + 1)
-        if need > self.pool.num_usable:
+        # the window group holds at most a window and what is in flight of
+        # a row, whatever its length
+        wpool = self.pool.window
+        if need > self.pool.num_usable or (
+            wpool is not None
+            and min(need, self._window_row_blocks) > wpool.num_usable
+        ):
             # can never fit (e.g. resumed request outgrew the pool)
             self.waiting.remove(req)
             self._finish(req, RequestStatus.FINISHED_ABORTED)
             self._finished_externally.append(req)
             return False
+        if wpool is not None:
+            # ... and a row is admitted when its first chunk fits there
+            # (later growth that finds the group full preempts the newest
+            # row, as in the full group)
+            first_chunk = self._blocks_needed(
+                self.config.max_num_batched_tokens) + 1
+            if wpool.num_free < min(need, first_chunk):
+                return False
         return self.pool.num_free >= need
+
+    def window_share(self) -> float:
+        """Window-group pages resident / the pages the same live rows would
+        hold in those layers at full length (their full-group tables'
+        lengths): what giving pages back saves. 0 without a window group
+        or without rows."""
+        wpool = self.pool.window
+        held = sum(len(r.block_table) for r in self.running)
+        if wpool is None or not held:
+            return 0.0
+        return wpool.num_resident / held
 
     def take_finished_externally(self) -> list[Request]:
         out, self._finished_externally = self._finished_externally, []
@@ -1288,8 +1331,24 @@ class Scheduler:
         check; the decode-window headroom clamp in `_schedule_decode` keeps
         windowed decode from self-preempting before that point."""
         need = self._blocks_needed(num_tokens)
+        wpool = self.pool.window
+        if wpool is not None:
+            # first what the row no longer needs: pages wholly behind the
+            # window of its RESOLVED position (a step in flight, or one
+            # rolled back, still reads from there on)
+            req.window_first_live = wpool.give_back(
+                req.window_table, req.window_first_live,
+                req.num_computed_tokens)
         while len(req.block_table) < need:
+            # both groups grow in lockstep (one logical index, two pages);
+            # whichever is full preempts
+            wblk = 0
             blk = self.pool.allocate()
+            if blk is not None and wpool is not None:
+                wblk = wpool.allocate()
+                if wblk is None:
+                    self.pool.free_block(blk)
+                    blk = None
             if blk is None:
                 # LOWEST-priority-class-first, newest-within-class loses
                 # (pure-default traffic: every rank ties, so this reduces
@@ -1315,6 +1374,8 @@ class Scheduler:
                     return False
                 continue
             req.block_table.append(blk)
+            if wpool is not None:
+                req.window_table.append(wblk)
         return True
 
     def _preempt(self, req: Request) -> None:
@@ -1347,6 +1408,9 @@ class Scheduler:
         for blk in reversed(req.block_table):
             self.pool.free_block(blk)
         req.block_table = []
+        if self.pool.window is not None:
+            self.pool.window.release(req.window_table, req.window_first_live)
+            req.window_first_live = 0
         self._hash_chains.pop(req.request_id, None)
 
     def _slot(self, req: Request, token_idx: int) -> int:
@@ -1449,6 +1513,12 @@ class Scheduler:
             )
             while len(req.block_table) > keep:
                 self.pool.free_block(req.block_table.pop())
+                if self.pool.window is not None:
+                    # (never a page given back: those lie behind the
+                    # resolved position, `keep` ahead of it)
+                    wblk = req.window_table.pop()
+                    if wblk:
+                        self.pool.window.free_block(wblk)
 
     # -- post-step ---------------------------------------------------------
 

@@ -14,6 +14,15 @@ scraper (router/stats/engine_stats.py) import these names.
 NUM_REQUESTS_RUNNING = "tpu:num_requests_running"
 NUM_REQUESTS_WAITING = "tpu:num_requests_waiting"
 HBM_KV_USAGE_PERC = "tpu:hbm_kv_usage_perc"
+# page groups (engine/kv_cache.py: a model with window layers keeps their
+# pages apart). HBM_KV_USAGE_PERC stays ONE series, the fuller group's
+# share (the one that would preempt); the per-group shares ride a name of
+# their own (group= closed set full|window) so that a reader that sums a
+# name's series still reads one pool. HBM_KV_WINDOW_SHARE: the window
+# group's resident pages / the pages the same live rows would hold in
+# those layers at full length (0 without a window group).
+HBM_KV_GROUP_USAGE_PERC = "tpu:hbm_kv_group_usage_perc"
+HBM_KV_WINDOW_SHARE = "tpu:hbm_kv_window_share"
 PREFIX_CACHE_HIT_RATE = "tpu:hbm_prefix_cache_hit_rate"
 # host-RAM offload tier (LMCache CPU-offload equivalent)
 HOST_KV_USAGE_PERC = "tpu:host_kv_usage_perc"
@@ -26,6 +35,8 @@ STEP_OVERLAP_FRAC = "tpu:engine_step_overlap_frac"
 PREFIX_CACHE_HITS = "tpu:hbm_prefix_cache_hits_total"
 PREFIX_CACHE_QUERIES = "tpu:hbm_prefix_cache_queries_total"
 NUM_PREEMPTIONS = "tpu:num_preemptions_total"
+# window-group pages given back behind live rows (engine/kv_cache.py)
+HBM_KV_WINDOW_PAGES_GIVEN_BACK = "tpu:hbm_kv_window_pages_given_back_total"
 PROMPT_TOKENS = "tpu:prompt_tokens_total"
 GENERATION_TOKENS = "tpu:generation_tokens_total"
 HOST_KV_OFFLOADS = "tpu:host_kv_offloaded_blocks_total"
@@ -582,6 +593,8 @@ ALL_GAUGES = (
     NUM_REQUESTS_RUNNING,
     NUM_REQUESTS_WAITING,
     HBM_KV_USAGE_PERC,
+    HBM_KV_GROUP_USAGE_PERC,
+    HBM_KV_WINDOW_SHARE,
     PREFIX_CACHE_HIT_RATE,
     HOST_KV_USAGE_PERC,
     STEP_OVERLAP_FRAC,
@@ -619,6 +632,7 @@ ALL_COUNTERS = (
     PREFIX_CACHE_HITS,
     PREFIX_CACHE_QUERIES,
     NUM_PREEMPTIONS,
+    HBM_KV_WINDOW_PAGES_GIVEN_BACK,
     PROMPT_TOKENS,
     GENERATION_TOKENS,
     HOST_KV_OFFLOADS,

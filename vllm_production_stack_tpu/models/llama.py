@@ -19,7 +19,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..engine.config import ModelConfig
+from ..engine.config import LayerSpec, ModelConfig
 from ..ops.attention import (
     apply_rope,
     attention_with_hist,
@@ -46,6 +46,8 @@ from ..ops.paged_attention_pallas import (
     paged_decode_attention_sharded,
     paged_prefill_attention,
     paged_prefill_attention_sharded,
+    paged_window_decode_attention,
+    paged_window_prefill_attention,
 )
 
 
@@ -76,6 +78,8 @@ def init_params(cfg: ModelConfig, rng: jax.Array) -> dict:
     """Random-init a stacked param tree (tests + benchmarks without weights)."""
     if cfg.mla:
         return _init_params_mla(cfg, rng)
+    if cfg.tree_by_kind:
+        return _init_params_by_kind(cfg, rng)
     L = cfg.num_layers
     h, hd = cfg.hidden_size, cfg.head_dim
     nh, nkv, it = cfg.num_heads, cfg.num_kv_heads, cfg.intermediate_size
@@ -209,6 +213,98 @@ def _init_params_mla(cfg: ModelConfig, rng: jax.Array) -> dict:
     return params
 
 
+def _init_params_by_kind(cfg: ModelConfig, rng: jax.Array) -> dict:
+    """The tree of a model the flat tree cannot say
+    (`ModelConfig.tree_by_kind`): window and full attention layers with
+    their own head counts, a per-head output gate, a dense SwiGLU or a
+    share of the experts a layer, a shared expert.
+
+    The attention leaves are TWO stacks where the kinds' head counts differ
+    — "attn" over the full layers, "attn_window" over the window layers:
+    wq (L_kind, h, heads_kind * D), wk, wv (L_kind, h, kvH * D), wo
+    (L_kind, heads_kind * D, h), wg (L_kind, h, heads_kind), the gate — and
+    one "attn" stack over all L where they agree. The MLP leaves are two
+    stacks as `_init_params_mla`'s: "mlp" over the dense layers, "moe" over
+    the expert layers, whose router is `num_experts` wide (the published
+    width) while gate/up/down hold the `held_experts` this program was
+    given (ModelConfig.num_experts_held); "shared" is one ungated SwiGLU.
+    `_layer_params` indexes each stack by the layer's own count."""
+    unsaid = [name for name in (
+        "attention_bias", "qk_norm", "qk_norm_flat", "sandwich_norms",
+        "post_norms_only") if getattr(cfg, name)]
+    if unsaid:
+        raise ValueError(
+            f"{cfg.model}: a param tree stacked by layer kind has no leaves "
+            f"for {', '.join(unsaid)}")
+    h, hd, nkv = cfg.hidden_size, cfg.head_dim, cfg.num_kv_heads
+    specs = cfg.layer_specs
+    it, mit = cfg.intermediate_size, cfg.expert_intermediate_size
+    e, held = cfg.num_experts, cfg.held_experts
+    dt = _dtype(cfg)
+    keys = iter(jax.random.split(rng, 32))
+
+    def w(*shape, scale=None):
+        scale = scale if scale is not None else shape[-2] ** -0.5
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * scale).astype(dt)
+
+    def attn(n, nh):
+        leaves = {"wq": w(n, h, nh * hd), "wk": w(n, h, nkv * hd),
+                  "wv": w(n, h, nkv * hd), "wo": w(n, nh * hd, h)}
+        if cfg.attn_output_gate:
+            leaves["wg"] = w(n, h, nh)
+        return leaves
+
+    L = cfg.num_layers
+    layers: dict[str, Any] = {
+        "input_norm": jnp.ones((L, h), dt),
+        "post_attn_norm": jnp.ones((L, h), dt),
+    }
+    if cfg.attn_stacks_by_kind:
+        for name, idx in (("attn", cfg.full_layers),
+                          ("attn_window", cfg.window_layers)):
+            if idx:
+                layers[name] = attn(len(idx), specs[idx[0]].num_heads)
+    else:
+        layers["attn"] = attn(L, specs[0].num_heads)
+    def w_by_layer(n, *shape):
+        """A stacked leaf drawn one layer at a time into its buffer: the
+        float32 draw that is live is one layer's (0.8 GB at 64 experts of
+        3072 x 1024), not the whole leaf's (6.4 GB beside 10.6 GB of
+        weights on a 16 GB chip)."""
+        layer_keys = jax.random.split(next(keys), n)
+        scale = shape[-2] ** -0.5
+
+        def draw(i, buf):
+            piece = jax.random.normal(layer_keys[i], shape, jnp.float32)
+            return jax.lax.dynamic_update_index_in_dim(
+                buf, (piece * scale).astype(dt), i, 0)
+
+        return jax.lax.fori_loop(0, n, draw, jnp.zeros((n, *shape), dt))
+
+    lm = cfg.num_moe_layers
+    if L - lm:
+        layers["mlp"] = {"gate": w(L - lm, h, it), "up": w(L - lm, h, it),
+                         "down": w(L - lm, it, h)}
+    if lm:
+        layers["moe"] = {
+            "router": w(lm, h, e),
+            "gate": w_by_layer(lm, held, h, mit),
+            "up": w_by_layer(lm, held, h, mit),
+            "down": w_by_layer(lm, held, mit, h),
+        }
+        sit = cfg.n_shared_experts * mit
+        if sit:
+            layers["moe"]["shared"] = {
+                "gate": w(lm, h, sit), "up": w(lm, h, sit),
+                "down": w(lm, sit, h)}
+    params = {"embed": w(cfg.vocab_size, h, scale=0.02), "layers": layers,
+              "final_norm": jnp.ones((h,), dt)}
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = w(h, cfg.vocab_size, scale=0.02)
+    return params
+
+
 def rms_norm(
     x: jax.Array, weight: jax.Array, eps: float, add_one: bool = False,
     scale_f32: bool = False,
@@ -256,22 +352,42 @@ def _embed(cfg: ModelConfig, params: dict, token_ids: jax.Array) -> jax.Array:
 
 
 def init_kv_cache(
-    cfg: ModelConfig, num_blocks: int, block_size: int, dtype: Any | None = None
+    cfg: ModelConfig, num_blocks: int, block_size: int,
+    dtype: Any | None = None, num_window_blocks: int | None = None,
 ) -> tuple[jax.Array, ...]:
     """Paged pool: a TUPLE of per-layer (2, num_blocks, block_size, kvH, D)
     arrays (MLA: (1, num_blocks, block_size, latent_width)), NOT one stacked
     array. Per-layer leaves let jit donation alias
     each layer's pool in place; a stacked pool updated inside a scan forces
     XLA to hold a second full-pool buffer (observed +9.8 GiB on a
-    utilization-sized pool — an instant OOM)."""
+    utilization-sized pool — an instant OOM).
+
+    With `num_window_blocks` the pages are TWO GROUPS (engine/kv_cache.py):
+    a full-attention layer's array holds `num_blocks` pages, a window
+    layer's `num_window_blocks` — it only ever holds a row's window and
+    what is in flight. Without it every layer holds `num_blocks` (a window
+    layer then masks a context it holds whole: tests, the XLA oracle)."""
     dt = jnp.dtype(dtype) if dtype is not None else _dtype(cfg)
     if cfg.mla:
         # ONE plane a layer: a token's latent (ops/mla_attention.py), no
         # second copy of it as "V"
         shape = (1, num_blocks, block_size, cfg.latent_width)
-    else:
-        shape = (2, num_blocks, block_size, cfg.num_kv_heads, cfg.head_dim)
-    return tuple(jnp.zeros(shape, dt) for _ in range(cfg.num_layers))
+        return tuple(jnp.zeros(shape, dt) for _ in range(cfg.num_layers))
+    return tuple(
+        jnp.zeros((
+            2,
+            num_window_blocks if spec.window and num_window_blocks
+            else num_blocks,
+            block_size, cfg.num_kv_heads, cfg.head_dim), dt)
+        for spec in cfg.layer_specs)
+
+
+def _group(x, window: int):
+    """Layer kind's part of an input that exists once a PAGE GROUP — a
+    (full, window) pair of block tables, written-span ids or slots — or the
+    one array every layer shares (a model with one group; a window model
+    whose layers all hold the whole context)."""
+    return x[1 if window else 0] if isinstance(x, tuple) else x
 
 
 def lora_module_dims(cfg: ModelConfig) -> dict[str, tuple[int, int]]:
@@ -350,13 +466,17 @@ def _layer_body(
     moe_stats: list | None = None,  # collects each MoE layer's routing
     #   (_moe_mlp's second result; traced — what the caller leaves unused
     #   is never computed)
+    spec: LayerSpec | None = None,  # this layer's entry of the per-layer
+    #   list (its head count and rope); None: layer 0's
 ) -> jax.Array:
     """The Llama layer math shared by every execution mode — prefill and the
     fused decode window differ ONLY in how attention consumes/stores KV, so
     that strategy is injected as `attend` and everything else (projections,
     bias, RoPE, residuals, MLP, LoRA deltas) exists exactly once."""
     b, t, h = x.shape
-    hd, nh, nkv = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
+    spec = spec or cfg.layer_specs[0]
+    hd, nh, nkv = cfg.head_dim, spec.num_heads, cfg.num_kv_heads
+    rope = spec.rope
 
     if lora is not None:
         lscale = lora["scale"][lora_idx]
@@ -397,13 +517,27 @@ def _layer_body(
             # qwen3: per-head RMSNorm on q/k BEFORE rope (HF Qwen3Attention)
             q = rms_norm(q, ap["q_norm"], cfg.rms_norm_eps)
             k = rms_norm(k, ap["k_norm"], cfg.rms_norm_eps)
-        q = apply_rope(q, positions, cfg.rope_theta, scaling=cfg.rope_scaling)
-        k = apply_rope(k, positions, cfg.rope_theta, scaling=cfg.rope_scaling)
+        q = apply_rope(q, positions, rope.theta, scaling=rope.scaling,
+                       rotary_dim=rope.rotary_dim,
+                       attention_factor=rope.attention_factor)
+        k = apply_rope(k, positions, rope.theta, scaling=rope.scaling,
+                       rotary_dim=rope.rotary_dim,
+                       attention_factor=rope.attention_factor)
         v = v.reshape(b, t, nkv, hd)
+        if cfg.attn_output_gate:
+            with jax.named_scope("attn_gate"):
+                # one scalar a head and token, from the same normed input
+                # as q (float32 logistic, as softmax statistics are)
+                gate = jax.nn.sigmoid(
+                    _mm(x, ap["wg"]).astype(jnp.float32)).astype(x.dtype)
 
     # `attend` names its own parts: kv_stage (this step's K/V into the
     # pool or the window's staging buffer) and attn
-    attn = attend(q, k, v).reshape(b, t, nh * hd)
+    attn = attend(q, k, v)
+    if cfg.attn_output_gate:
+        with jax.named_scope("attn_gate"):
+            attn = attn * gate[..., None]
+    attn = attn.reshape(b, t, nh * hd)
     with jax.named_scope("attn_out"):
         attn_out = proj(attn, ap["wo"], "o_proj")
         if cfg.sandwich_norms or cfg.post_norms_only:
@@ -557,7 +691,12 @@ def _moe_route(cfg: ModelConfig, router: jax.Array, xf: jax.Array,
         else:
             logits = (xf @ router).astype(jnp.float32)  # (N, E)
             topv, topi = _softmax_top_k(cfg, logits)
-        chosen = jax.nn.one_hot(topi, cfg.num_experts, dtype=jnp.float32)
+        # the experts this program holds, numbered from 0: a pair that
+        # lands on another chip's expert is a row of zeros (one_hot of an
+        # index out of range) and belongs to no group
+        local = (topi - cfg.first_expert_held if cfg.num_experts_held
+                 else topi)
+        chosen = jax.nn.one_hot(local, cfg.held_experts, dtype=jnp.float32)
         group_sizes = jnp.sum(chosen, axis=(0, 1)).astype(jnp.int32)
     return topv, topi, chosen, group_sizes
 
@@ -569,7 +708,9 @@ def _sigmoid_top_k(cfg: ModelConfig, logits: jax.Array, bias: jax.Array):
     (the bias moves the choice, never the weight), divided by their sum
     under norm_topk_prob, times routed_scaling_factor."""
     scores = jax.nn.sigmoid(logits)
-    _, topi = jax.lax.top_k(scores + bias, cfg.num_experts_per_tok)
+    # (a family without the selection bias has no such leaf: laguna)
+    _, topi = jax.lax.top_k(
+        scores if bias is None else scores + bias, cfg.num_experts_per_tok)
     topv = jnp.take_along_axis(scores, topi, axis=-1)
     if cfg.norm_topk_prob:
         topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
@@ -608,6 +749,14 @@ def _experts_grouped(cfg, stacked, layer, xf, topv, topi, group_sizes):
     n, k = topi.shape
     with jax.named_scope("moe_dispatch"):
         pair_expert = topi.reshape(n * k)
+        if cfg.num_experts_held:
+            # pairs on experts held elsewhere sort behind every group: the
+            # grouped matmul does not compute their rows, and their weight
+            # is zeroed below (whatever those rows of its output hold)
+            local = pair_expert - cfg.first_expert_held
+            here = (local >= 0) & (local < cfg.held_experts)
+            pair_expert = jnp.where(here, local, cfg.held_experts)
+            topv = jnp.where(here.reshape(n, k), topv, 0.0)
         order = jnp.argsort(pair_expert, stable=True)  # sorted row -> pair
         row_expert = pair_expert[order]
         xs = xf[order // k]  # (N*k, h): each pair's token, expert-major
@@ -620,6 +769,8 @@ def _experts_grouped(cfg, stacked, layer, xf, topv, topi, group_sizes):
     with jax.named_scope("moe_combine"):
         # pair p sits at sorted row inv[p]
         out = out[jnp.argsort(order)].reshape(n, k, -1)
+        if cfg.num_experts_held:
+            out = jnp.where(here.reshape(n, k, 1), out, 0)
         return jnp.einsum("nkh,nk->nh", out, topv.astype(xf.dtype))
 
 
@@ -648,10 +799,18 @@ def _moe_mlp(cfg: ModelConfig, mp: dict, x: jax.Array,
     layer's index) is what the grouped matmul reads in place (_grouped_mm);
     without it `mp` stands as a stack of one.
 
-    x: (B, T, h) → ((B, T, h), routing): `touched`, the experts that got
-    at least one pair (a scalar int32 the runner sums into
-    `moe_*_touched_n`), and `experts` (B, T, k), each token's choice (read
-    by the comparison with the reference, testing/reference_decoder.py)."""
+    A program that holds a share of the experts (ModelConfig
+    .num_experts_held) routes over all `num_experts` and computes the pairs
+    that land on its own: `chosen` and `group_sizes` are over the held
+    experts, and the result is their part of the layer's output plus the
+    shared expert's.
+
+    x: (B, T, h) → ((B, T, h), routing): `touched`, the HELD experts that
+    got at least one pair (a scalar int32 the runner sums into
+    `moe_*_touched_n`), `held_pairs`, the pairs that landed on them (only
+    from a program that holds a share: `moe_*_held_pairs_n`), and
+    `experts` (B, T, k), each token's choice (read by the comparison with
+    the reference, testing/reference_decoder.py)."""
     b, t, h = x.shape
     n = b * t
     xf = x.reshape(n, h)
@@ -670,28 +829,40 @@ def _moe_mlp(cfg: ModelConfig, mp: dict, x: jax.Array,
             y = y + _mm(_activation(cfg)(_mm(xf, sp["gate"]))
                         * _mm(xf, sp["up"]), sp["down"])
     touched = jnp.sum((group_sizes > 0).astype(jnp.int32))
-    return y.reshape(b, t, h), {
-        "touched": touched, "experts": topi.reshape(b, t, -1)}
+    routing = {"touched": touched, "experts": topi.reshape(b, t, -1)}
+    if cfg.num_experts_held:
+        routing["held_pairs"] = jnp.sum(group_sizes)
+    return y.reshape(b, t, h), routing
 
 
-def _layer_params(params: dict, i: int) -> dict:
+def _layer_params(cfg: ModelConfig, params: dict, i: int) -> dict:
     """Layer i's slice of the stacked per-layer leaves (static slices, fused
     into their consumers), and for an MoE model the stacked expert leaves
-    themselves beside it (see _grouped_mm)."""
+    themselves beside it (see _grouped_mm). Where a tree stacks a kind of
+    layer on its own — dense and expert MLPs (_init_params_mla), full and
+    window attention (_init_params_by_kind) — the layer's leaves sit at its
+    index among the layers of its kind (ModelConfig.layer_stack_index)."""
     layers = params["layers"]
-    if "moe" in layers and "mlp" in layers:
-        # leading dense layers before the expert layers (_init_params_mla):
-        # two MLP stacks, each indexed by its own count
-        k = layers["mlp"]["gate"].shape[0]
-        kind, j = ("mlp", i) if i < k else ("moe", i - k)
-        lp = jax.tree.map(lambda a: a[i], {
-            name: leaf for name, leaf in layers.items()
-            if name not in ("mlp", "moe")})
-        lp[kind] = jax.tree.map(lambda a: a[j], layers[kind])
-    else:
-        j = i
-        lp = jax.tree.map(lambda a: a[i], layers)
-    if "moe" in lp:
+    spec = cfg.layer_specs[i]
+    ai, mi = cfg.layer_stack_index[i]
+    two_mlps = "moe" in layers and "mlp" in layers
+    mlp_kind = "moe" if spec.experts else "mlp"
+    # name in the layer's dict -> (stack in the tree, the layer's index there)
+    picks = {name: (name, i) for name in layers
+             if name not in ("attn", "attn_window", "mlp", "moe")}
+    picks["attn"] = (
+        ("attn_window" if spec.window else "attn", ai)
+        if cfg.attn_stacks_by_kind else ("attn", i))
+    picks[mlp_kind] = (mlp_kind, j := mi if two_mlps else i)
+    # (slices are traced in the tree's own sorted order, a kind-stacked MLP
+    # last: the order the programs of every older model were traced in, so
+    # their StableHLO stays byte-identical)
+    lp = {
+        name: jax.tree.map(lambda a, k=picks[name][1]: a[k],
+                           layers[picks[name][0]])
+        for name in sorted(picks, key=lambda n: (two_mlps and n == mlp_kind, n))
+    }
+    if spec.experts:
         lp["moe_stacked"] = (layers["moe"], j)
     return lp
 
@@ -762,9 +933,17 @@ def _layer(
     moe_stats: list | None = None,  # see _layer_body
     rows: dict | None = None,  # _row_view(...) of a packed chunk: x is
     #   (N, 1, h) then
+    spec: LayerSpec | None = None,  # the layer's entry of the per-layer list
 ) -> tuple[jax.Array, jax.Array]:
     b, t = x.shape[0], x.shape[1]
     hd, nkv = cfg.head_dim, cfg.num_kv_heads
+    spec = spec or cfg.layer_specs[0]
+    # the layer kind's page group: its block tables, written span, slots
+    block_tables = _group(block_tables, spec.window)
+    slot_mapping = _group(slot_mapping, spec.window)
+    if write_blocks is not None:
+        write_blocks = {
+            **write_blocks, "ids": _group(write_blocks["ids"], spec.window)}
 
     def _attention(q):
         if cfg.mla:
@@ -788,6 +967,14 @@ def _layer(
                     mesh, q, kv_layer, block_tables,
                     pallas_prefill["context_lens"],
                     pallas_prefill["chunk_start"], scale=cfg.attn_scale,
+                    interpret=pallas_prefill["interpret"],
+                )
+            if spec.window:
+                return paged_window_prefill_attention(
+                    q, kv_layer, block_tables,
+                    pallas_prefill["context_lens"],
+                    pallas_prefill["chunk_start"], scale=cfg.attn_scale,
+                    window=spec.window,
                     interpret=pallas_prefill["interpret"],
                 )
             return paged_prefill_attention(
@@ -840,7 +1027,7 @@ def _layer(
             return out.reshape(-1, *out.shape[2:])[rows["to_packed"]][:, None]
 
     x = _layer_body(cfg, lp, x, positions, attend, lora, lora_idx,
-                    moe_stats)
+                    moe_stats, spec)
     return x, kv_layer
 
 
@@ -850,7 +1037,10 @@ def forward(
     token_ids: jax.Array,  # (B, T) int32; packed: (N,)
     positions: jax.Array,  # (B, T) int32 logical positions; packed: (N,)
     kv_caches: jax.Array,  # (L, 2, num_blocks, block_size, kvH, D)
-    block_tables: jax.Array,  # (B, max_blocks) int32
+    block_tables: jax.Array,  # (B, max_blocks) int32; or a (full, window)
+    #   pair, a table a PAGE GROUP (engine/kv_cache.py): window layers
+    #   read and write the second. `slot_mapping` and `write_blocks["ids"]`
+    #   pair up the same way
     slot_mapping: jax.Array,  # (B*T,) flat slots (padding -> block 0 slots)
     context_lens: jax.Array,  # (B,) tokens resident after this step
     lora: dict | None = None,  # stacked adapter tree (init_lora_params)
@@ -881,16 +1071,15 @@ def forward(
     (`_layer`), and the KV write reads the packed k/v through each row's
     offset. Returns hidden (N, 1, h). The speculative verify programs and
     a dp > 1 prefill (rows sharded over replicas) stay row-major."""
-    if backend.startswith("pallas") and (
-        cfg.any_sliding or cfg.attn_logit_softcap
-    ):
+    if backend.startswith("pallas") and cfg.attn_logit_softcap:
         raise NotImplementedError(
-            "pallas prefill does not support sliding-window or softcapped "
-            "models (the runner gates these to the XLA backend)"
+            "pallas prefill does not support softcapped models (the runner "
+            "gates these to the XLA backend)"
         )
     rows, row_positions, chunk_start = None, positions, None
     if pack is not None:
-        rows = _row_view(pack, token_ids.shape[0], block_tables.shape[0])
+        rows = _row_view(
+            pack, token_ids.shape[0], _group(block_tables, 0).shape[0])
         row_positions = (
             positions[None] if rows["to_rows"] is None
             else positions[rows["to_rows"]])
@@ -903,8 +1092,7 @@ def forward(
         # with contiguous positions (scheduler.py: range(start, start+len)),
         # so chunk_start is the first column (a packed chunk brings it per
         # row). No (B, T, S) mask exists.
-        mask = None
-        mask_local = None
+        masks = {}
         pallas_prefill = {
             "context_lens": context_lens,
             "chunk_start": (
@@ -914,16 +1102,12 @@ def forward(
         }
     else:
         # attention masks, built once per WINDOW KIND and reused by every
-        # layer of that kind (full everywhere; plus the sliding variant
-        # for Mistral-v0.1 / Gemma-2 class models)
-        s_ctx = block_tables.shape[1] * kv_caches[0].shape[2]
-        mask = causal_page_mask(row_positions, context_lens, s_ctx)
-        mask_local = (
-            causal_page_mask(row_positions, context_lens, s_ctx,
-                             window=cfg.sliding_window)
-            if cfg.any_sliding
-            else None
-        )
+        # layer of that kind (full; a window's sliding variant)
+        s_ctx = _group(block_tables, 0).shape[1] * kv_caches[0].shape[2]
+        masks = {
+            w: causal_page_mask(row_positions, context_lens, s_ctx, window=w)
+            for w in cfg.window_kinds
+        }
         pallas_prefill = None
 
     # unrolled layer loop (params stay stacked; each layer slices statically).
@@ -932,13 +1116,13 @@ def forward(
     # full pool (see init_kv_cache)
     new_kv: list[jax.Array] = []
     n_stats = len(moe_stats or ())
-    for i in range(cfg.num_layers):
-        lp = _layer_params(params, i)
+    for i, spec in enumerate(cfg.layer_specs):
+        lp = _layer_params(cfg, params, i)
         x, layer_kv = _layer(
             cfg, lp, kv_caches[i], x, positions, block_tables, slot_mapping,
-            mask_local if cfg.layer_sliding(i) else mask,
+            masks.get(spec.window),
             _lora_layer_slice(lora, i), lora_idx, write_blocks,
-            pallas_prefill, moe_stats, rows,
+            pallas_prefill, moe_stats, rows, spec,
         )
         new_kv.append(layer_kv)
     if moe_stats is not None:
@@ -981,7 +1165,8 @@ def decode_window_step(
     token_ids: jax.Array,  # (B,) this iteration's input token per row
     positions: jax.Array,  # (B,) this iteration's position per row
     kv_caches: tuple[jax.Array, ...],  # read-only pool
-    block_tables: jax.Array,  # (B, max_blocks)
+    block_tables: jax.Array,  # (B, max_blocks), or a (full, window) pair
+    #   (see forward)
     staged: jax.Array,  # (L, 2, W, B, kvH, D) window staging buffer
     step_k: jax.Array,  # scalar int32: iteration index within the window
     hist_len: jax.Array,  # (B,): pool positions < hist_len are history
@@ -1009,23 +1194,23 @@ def decode_window_step(
         s_ctx = (
             hists[0][0].shape[1]
             if hists is not None
-            else block_tables.shape[1] * kv_caches[0].shape[2]
+            else _group(block_tables, 0).shape[1] * kv_caches[0].shape[2]
         )
         arange = jnp.arange(s_ctx, dtype=jnp.int32)[None, :]
         hist_mask = arange < hist_len[:, None]
-        hist_mask_local = None
-        if cfg.any_sliding:
-            # sliding layers: the query at `positions` sees only pool
-            # history within the window. Staged slots stay globally
-            # attendable — they are the most recent `decode_window`
-            # positions, always inside any real sliding window (asserted
-            # at engine init: sliding_window > decode_window)
-            hist_mask_local = hist_mask & (
-                arange > (positions - cfg.sliding_window)[:, None]
-            )
+        # window layers: the query at `positions` sees only pool history
+        # within the window. Staged slots stay globally attendable — they
+        # are the most recent `decode_window` positions, always inside any
+        # real window (asserted at engine init: window > decode_window)
+        hist_masks = {
+            w: hist_mask & (arange > (positions - w)[:, None]) if w
+            else hist_mask
+            for w in cfg.window_kinds
+        }
 
-    for i in range(cfg.num_layers):
-        lp = _layer_params(params, i)
+    for i, spec in enumerate(cfg.layer_specs):
+        lp = _layer_params(cfg, params, i)
+        tables = _group(block_tables, spec.window)
 
         def attend_latent(q_abs, latent, _v, i=i):
             nonlocal staged
@@ -1046,7 +1231,7 @@ def decode_window_step(
                     interpret=backend == "pallas_interpret",
                 )[:, None]
 
-        def attend(q, k, v, i=i):
+        def attend(q, k, v, i=i, spec=spec, tables=tables):
             nonlocal staged
             with jax.named_scope("kv_stage"):
                 staged = staged.at[i, 0, step_k].set(
@@ -1055,11 +1240,7 @@ def decode_window_step(
                     v[:, 0].astype(staged.dtype))
             with jax.named_scope("attn"):
                 if backend == "xla":
-                    h_mask = (
-                        hist_mask_local
-                        if cfg.layer_sliding(i)
-                        else hist_mask
-                    )
+                    h_mask = hist_masks[spec.window]
                     if hists is not None:
                         return attention_with_hist(
                             q, hists[i][0], hists[i][1], h_mask,
@@ -1068,30 +1249,36 @@ def decode_window_step(
                             softcap=cfg.attn_logit_softcap,
                         )
                     return paged_attention_with_staged(
-                        q, kv_caches[i], block_tables, h_mask,
+                        q, kv_caches[i], tables, h_mask,
                         staged[i, 0], staged[i, 1], staged_mask,
                         scale=cfg.attn_scale,
                         softcap=cfg.attn_logit_softcap,
                     )
-                if cfg.any_sliding or cfg.attn_logit_softcap:
+                if cfg.attn_logit_softcap:
                     # self-enforcing invariant (the runner gates these models
-                    # to XLA): the decode kernel has no window masking or
-                    # softcap — silently wrong numerics otherwise
+                    # to XLA): the decode kernel has no softcap — silently
+                    # wrong numerics otherwise
                     raise NotImplementedError(
-                        "pallas decode does not support sliding-window or "
-                        "softcapped models"
+                        "pallas decode does not support softcapped models"
                     )
                 if mesh is not None and mesh.size > 1:
                     # pallas_call has no GSPMD partition rule — shard_map
                     # over (dp, tp) places one kernel instance per device
                     return paged_decode_attention_sharded(
-                        mesh, q[:, 0], kv_caches[i], block_tables, hist_len,
+                        mesh, q[:, 0], kv_caches[i], tables, hist_len,
                         staged[i, 0], staged[i, 1], step_k,
                         scale=cfg.attn_scale,
                         interpret=backend == "pallas_interpret",
                     )[:, None]
+                if spec.window:
+                    return paged_window_decode_attention(
+                        q[:, 0], kv_caches[i], tables, hist_len,
+                        staged[i, 0], staged[i, 1], step_k,
+                        scale=cfg.attn_scale, window=spec.window,
+                        interpret=backend == "pallas_interpret",
+                    )[:, None]
                 return paged_decode_attention(
-                    q[:, 0], kv_caches[i], block_tables, hist_len,
+                    q[:, 0], kv_caches[i], tables, hist_len,
                     staged[i, 0], staged[i, 1], step_k, scale=cfg.attn_scale,
                     interpret=backend == "pallas_interpret",
                 )[:, None]
@@ -1099,7 +1286,7 @@ def decode_window_step(
         x = _layer_body(
             cfg, lp, x, positions[:, None],
             attend_latent if cfg.mla else attend,
-            _lora_layer_slice(lora, i), lora_idx, moe_stats,
+            _lora_layer_slice(lora, i), lora_idx, moe_stats, spec,
         )
     if moe_stats is not None:
         _tap_routing(token_ids, positions, moe_stats[n_stats:])
@@ -1113,6 +1300,8 @@ def commit_staged_kv(
     kv_caches: tuple[jax.Array, ...],
     staged: jax.Array,  # (L, 2, W, B, kvH, D)
     slot_mapping: jax.Array,  # (B*W,) flat pool slots, row-major (b, w)
+    window_layers: tuple[int, ...] = (),  # layers whose slots are the
+    #   second of a (full, window) `slot_mapping` pair
 ) -> tuple[jax.Array, ...]:
     """Scatter a whole window's staged K/V into the (donated) pool, one
     scatter per layer — the only pool write of the fused decode window."""
@@ -1128,8 +1317,9 @@ def commit_staged_kv(
         for i in range(L):
             k_rows = jnp.moveaxis(staged[i, 0], 0, 1).reshape(b * w, kvh, d)
             v_rows = jnp.moveaxis(staged[i, 1], 0, 1).reshape(b * w, kvh, d)
-            new_kv.append(
-                write_kv_pages(kv_caches[i], k_rows, v_rows, slot_mapping))
+            new_kv.append(write_kv_pages(
+                kv_caches[i], k_rows, v_rows,
+                _group(slot_mapping, i in window_layers)))
     return tuple(new_kv)
 
 
@@ -1146,16 +1336,14 @@ def embed_encode(
     b, t = token_ids.shape
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None], (b, t))
     x = _embed(cfg, params, token_ids)
-    mask = causal_page_mask(positions, lengths, t)  # (B, T, T)
-    mask_local = (
-        causal_page_mask(positions, lengths, t, window=cfg.sliding_window)
-        if cfg.any_sliding
-        else None
-    )
+    masks = {  # (B, T, T) a window kind
+        w: causal_page_mask(positions, lengths, t, window=w)
+        for w in cfg.window_kinds
+    }
 
-    for i in range(cfg.num_layers):
-        lp = _layer_params(params, i)
-        m = mask_local if cfg.layer_sliding(i) else mask
+    for i, spec in enumerate(cfg.layer_specs):
+        lp = _layer_params(cfg, params, i)
+        m = masks[spec.window]
 
         def attend(q, k, v, m=m):
             if cfg.mla:  # absorbed queries against the chunk's own latents
@@ -1167,7 +1355,7 @@ def embed_encode(
                 softcap=cfg.attn_logit_softcap,
             )
 
-        x = _layer_body(cfg, lp, x, positions, attend)
+        x = _layer_body(cfg, lp, x, positions, attend, spec=spec)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, cfg.rms_norm_add_one,
                  scale_f32=cfg.norm_scale_f32)
     last = jnp.take_along_axis(
@@ -1214,7 +1402,7 @@ def forward_sp_prefill(
 
     new_kv: list[jax.Array] = []
     for i in range(cfg.num_layers):
-        lp = _layer_params(params, i)
+        lp = _layer_params(cfg, params, i)
 
         def attend(q, k, v, i=i):
             hist_k, hist_v = gather_pages(kv_caches[i], block_tables)
@@ -1272,7 +1460,7 @@ def forward_context_parallel(
 
     kv_out: list[jax.Array] = []
     for i in range(cfg.num_layers):
-        lp = _layer_params(params, i)
+        lp = _layer_params(cfg, params, i)
 
         def attend(q, k, v):
             kv_out.append(jnp.stack([k, v]).astype(_dtype(cfg)))

@@ -66,6 +66,14 @@ def load_checkpoint_params(cfg: ModelConfig) -> dict:
     import ml_dtypes
 
     assert cfg.checkpoint, "ModelConfig.checkpoint is not set"
+    if cfg.architecture == "laguna":
+        raise NotImplementedError(
+            f"{cfg.model}: reading a laguna checkpoint is not implemented — "
+            "the published file's tensor names (the per-head gate's, the "
+            "shared expert's) could not be checked offline, and a guessed "
+            "mapping would load silently wrong weights; the config is read "
+            "(models/registry.py:_laguna_keys) and the registry presets "
+            "serve random weights")
     ckpt = _ShardedCheckpoint(cfg.checkpoint)
     dt = (
         ml_dtypes.bfloat16 if cfg.dtype == "bfloat16" else np.dtype(cfg.dtype)
@@ -391,3 +399,54 @@ class DeepseekCheckpointView:
             out += [p + f"mlp.experts.{e}." + n
                     for e in range(cfg.num_experts) for n in projs]
         return out
+
+
+class LagunaCheckpointView:
+    """The served tree of a laguna model (models/llama.py:
+    _init_params_by_kind) read back under `transformers`-style tensor names
+    and orientation ((out, in) matrices), one tensor at a time and on
+    demand: what the plain reference (testing/reference_laguna.py) reads.
+    Experts are named by their GLOBAL index: the tree holds
+    [first_expert_held, first_expert_held + held_experts). No checkpoint of
+    this family has been read here (load_checkpoint_params refuses one:
+    its tensor names are not known offline), so the names are the
+    reference's, not a published file's."""
+
+    def __init__(self, cfg: ModelConfig, params: dict):
+        self.cfg, self.params = cfg, params
+
+    def __getitem__(self, name: str):
+        cfg, layers = self.cfg, self.params["layers"]
+        top = {"model.embed_tokens.weight": lambda: self.params["embed"],
+               "model.norm.weight": lambda: self.params["final_norm"],
+               "lm_head.weight": lambda: self.params["lm_head"].T}
+        if name in top:
+            return top[name]()
+        parts = name.split(".")  # model.layers.<i>.<...>
+        i, rest = int(parts[2]), ".".join(parts[3:])
+        spec = cfg.layer_specs[i]
+        ai, mi = cfg.layer_stack_index[i]
+        if rest == "input_layernorm.weight":
+            return layers["input_norm"][i]
+        if rest == "post_attention_layernorm.weight":
+            return layers["post_attn_norm"][i]
+        if parts[3] == "self_attn":
+            if cfg.attn_stacks_by_kind:
+                attn, j = layers[
+                    "attn_window" if spec.window else "attn"], ai
+            else:
+                attn, j = layers["attn"], i
+            return attn["w" + parts[4][0]][j].T  # q_proj -> wq, g_proj -> wg
+        both = "mlp" in layers and "moe" in layers
+        j = mi if both else i
+        if not spec.experts:
+            return layers["mlp"][parts[4].split("_")[0]][j].T
+        moe = layers["moe"]
+        if rest == "mlp.gate.weight":
+            return moe["router"][j].T
+        if parts[4] == "shared_expert":
+            return moe["shared"][parts[5].split("_")[0]][j].T
+        if parts[4] == "experts":
+            return moe[parts[6].split("_")[0]][
+                j, int(parts[5]) - cfg.first_expert_held].T
+        raise KeyError(name)

@@ -12,9 +12,10 @@ environments can't download)."""
 from __future__ import annotations
 
 import json
+import math
 import os
 
-from ..engine.config import ModelConfig
+from ..engine.config import LayerSpec, ModelConfig, RopeSpec
 
 _KANANA = dict(
     vocab_size=128256, hidden_size=2048, intermediate_size=6144,
@@ -26,6 +27,90 @@ _KANANA = dict(
     scoring_func="sigmoid", routed_scaling_factor=2.448, norm_topk_prob=True,
     rope_interleave=True,
 )
+
+
+def laguna_layers(
+    layer_types: list[str], heads_per_layer: list[int],
+    mlp_layer_types: list[str], rope_parameters: dict, sliding_window: int,
+    head_dim: int,
+) -> tuple[LayerSpec, ...]:
+    """The per-layer list of a `model_type: laguna` config, from its four
+    per-layer lists and its `rope_parameters` (one entry a layer kind)."""
+
+    def rope(kind: str) -> RopeSpec:
+        rp = rope_parameters[kind]
+        rot = int(round(float(rp.get("partial_rotary_factor", 1.0))
+                        * head_dim))
+        kw = dict(theta=float(rp["rope_theta"]),
+                  rotary_dim=0 if rot == head_dim else rot)
+        kind_ = rp.get("rope_type", "default")
+        if kind_ == "yarn":
+            factor = float(rp["factor"])
+            af = rp.get("attention_factor")
+            kw.update(
+                scaling=("yarn", factor, float(rp.get("beta_fast", 32)),
+                         float(rp.get("beta_slow", 1)),
+                         int(rp["original_max_position_embeddings"])),
+                # transformers' default where the config leaves it out
+                attention_factor=float(af) if af is not None
+                else 0.1 * math.log(factor) + 1.0)
+        elif kind_ != "default":
+            raise ValueError(
+                f"laguna rope_type {kind_!r} is not implemented "
+                "(default and yarn are)")
+        return RopeSpec(**kw)
+
+    ropes = {kind: rope(kind) for kind in set(layer_types)}
+    return tuple(
+        LayerSpec(
+            window=sliding_window if kind == "sliding_attention" else 0,
+            num_heads=nh, rope=ropes[kind], experts=mlp == "sparse")
+        for kind, nh, mlp in zip(
+            layer_types, heads_per_layer, mlp_layer_types))
+
+
+# poolside/Laguna-S-2.1 config.json (`model_type: laguna`): 48 layers in
+# periods of one full-attention layer (48 query heads; rope over the first
+# half of a head, theta 500 000, yarn x128 from 8 192) and three
+# sliding-window layers (72 query heads, window 512, default rope, theta
+# 10 000), 8 kv heads x 128 everywhere, a per-head output gate; layer 0 a
+# dense SwiGLU of 12 288, layers 1.. 256 experts of 1 024, top-10 of
+# sigmoid scores renormalised x 2.5, one ungated shared SwiGLU of 1 024;
+# vocabulary 100 352 untied; 1 048 576 positions published.
+_LAGUNA_ROPE = {
+    "full_attention": {
+        "rope_theta": 500000, "rope_type": "yarn", "factor": 128,
+        "original_max_position_embeddings": 8192, "beta_slow": 1,
+        "beta_fast": 32, "attention_factor": 1.4852030263919618,
+        "partial_rotary_factor": 0.5},
+    "sliding_attention": {
+        "rope_type": "default", "rope_theta": 10000,
+        "partial_rotary_factor": 1},
+}
+
+
+def _laguna_preset(n_layers: int, rope: dict = _LAGUNA_ROPE,
+                   heads: tuple[int, int] = (48, 72), **over) -> dict:
+    """A laguna preset of `n_layers`: periods of one full layer (`heads[0]`
+    query heads) and three window layers (`heads[1]`), a dense layer 0."""
+    kinds = ["full_attention" if i % 4 == 0 else "sliding_attention"
+             for i in range(n_layers)]
+    kw = dict(
+        vocab_size=100352, hidden_size=3072, intermediate_size=12288,
+        num_layers=n_layers, num_heads=heads[0], num_kv_heads=8,
+        head_dim=128, max_model_len=32768, rms_norm_eps=1e-6,
+        architecture="laguna", num_experts=256, num_experts_per_tok=10,
+        moe_intermediate_size=1024, n_shared_experts=1,
+        scoring_func="sigmoid", routed_scaling_factor=2.5,
+        norm_topk_prob=True, attn_output_gate=True, sliding_window=512,
+    )
+    kw.update(over)
+    kw["layers"] = laguna_layers(
+        kinds, [heads[k != "full_attention"] for k in kinds],
+        ["dense"] + ["sparse"] * (n_layers - 1), rope,
+        kw["sliding_window"], kw["head_dim"])
+    return kw
+
 
 # Architecture hyperparameters follow the public model cards.
 PRESETS: dict[str, dict] = {
@@ -150,6 +235,29 @@ PRESETS: dict[str, dict] = {
         scoring_func="sigmoid", routed_scaling_factor=2.448,
         norm_topk_prob=True, rope_interleave=True,
     ),
+    "laguna-s-2.1": _laguna_preset(48),
+    # the benchmark cell's cut: ONE chip of a four-chip expert-parallel
+    # host that is the first pipeline stage — layer 0 (full, dense) and
+    # layers 1-8 (two whole periods S S S F S S S F), experts 0-63 of the
+    # 256 the router scores, vocabulary rows 0-25 087; every width as
+    # published, 32 768 positions served
+    "laguna-s-2.1-ep4-9l": _laguna_preset(
+        9, num_experts_held=64, first_expert_held=0, vocab_size=25088),
+    # two periods at toy widths (float32): window 16, heads 4 (full) and 6
+    # (window) x 16, 2 kv heads, a dense layer 0, 16 experts top-4 with
+    # experts 4-7 held, yarn x8 from 64 positions
+    "tiny-laguna": _laguna_preset(
+        9, heads=(4, 6), vocab_size=512, hidden_size=64,
+        intermediate_size=128, num_kv_heads=2, head_dim=16,
+        max_model_len=512, dtype="float32", num_experts=16,
+        num_experts_per_tok=4, num_experts_held=4, first_expert_held=4,
+        moe_intermediate_size=32, sliding_window=16,
+        rope={
+            "full_attention": {
+                **_LAGUNA_ROPE["full_attention"], "factor": 8,
+                "original_max_position_embeddings": 64,
+                "attention_factor": 1.2079441541679836},  # 0.1 ln 8 + 1
+            "sliding_attention": _LAGUNA_ROPE["sliding_attention"]}),
 }
 
 _ARCH_MAP = {
@@ -165,6 +273,7 @@ _ARCH_MAP = {
     "GemmaForCausalLM": "gemma",
     "Gemma2ForCausalLM": "gemma2",
     "DeepseekV3ForCausalLM": "deepseek_v3",
+    "LagunaForCausalLM": "laguna",
 }
 
 
@@ -233,6 +342,55 @@ def _deepseek_v3_keys(hf: dict, path: str) -> dict:
     )
 
 
+def _laguna_keys(hf: dict, path: str) -> dict:
+    """The `model_type: laguna` keys, and by name what of the family is not
+    served: a config that sets one is refused, not guessed at."""
+    n = hf["num_hidden_layers"]
+    kinds = hf.get("layer_types") or ["full_attention"] * n
+    mlp_kinds = hf.get("mlp_layer_types") or [
+        "dense" if i in (hf.get("mlp_only_layers") or []) else "sparse"
+        for i in range(n)]
+    gating = hf.get("gating_types") or [
+        "per_head" if hf.get("gating") == "per-head" else "none"] * n
+    refused = {
+        "attention_bias": bool(hf.get("attention_bias")),
+        "moe_router_logit_softcapping": bool(
+            hf.get("moe_router_logit_softcapping")),
+        "moe_apply_router_weight_on_input": bool(
+            hf.get("moe_apply_router_weight_on_input")),
+        "decoder_sparse_step != 1": (hf.get("decoder_sparse_step") or 1) != 1,
+        "gating other than per-head in every layer": any(
+            g != "per_head" for g in gating),
+        "a layer kind other than full_attention / sliding_attention": any(
+            k not in ("full_attention", "sliding_attention") for k in kinds),
+    }
+    hit = [k for k, v in refused.items() if v]
+    if hit:
+        raise ValueError(
+            f"laguna config {path} sets {', '.join(hit)}: not implemented")
+    heads = hf.get("num_attention_heads_per_layer") or [
+        hf["num_attention_heads"]] * n
+    hd = hf.get("head_dim") or hf["hidden_size"] // hf["num_attention_heads"]
+    sw = int(hf.get("sliding_window") or 0)
+    return dict(
+        layers=laguna_layers(
+            kinds, heads, mlp_kinds, hf["rope_parameters"], sw, hd),
+        sliding_window=sw,
+        num_experts=hf["num_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        # one ungated shared SwiGLU of shared_expert_intermediate_size
+        n_shared_experts=(hf.get("shared_expert_intermediate_size") or 0)
+        // hf["moe_intermediate_size"],
+        # assumed (no key): the logistic sigmoid, as every public config
+        # that pairs a route scale near 2.5 with renormalised top-k
+        scoring_func="sigmoid",
+        routed_scaling_factor=float(hf.get("moe_routed_scaling_factor", 1.0)),
+        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        attn_output_gate=True,
+    )
+
+
 def _from_hf_config(path: str) -> dict:
     with open(os.path.join(path, "config.json")) as f:
         hf = json.load(f)
@@ -278,6 +436,10 @@ def _from_hf_config(path: str) -> dict:
         )
     if arch == "deepseek_v3":
         moe = _deepseek_v3_keys(hf, path)
+    if arch == "laguna":
+        moe = _laguna_keys(hf, path)
+        # its ropes are per layer kind (rope_parameters), read above
+        hf = {**hf, "partial_rotary_factor": None, "rope_scaling": None}
     gemma = (
         dict(
             hidden_act="gelu_tanh", rms_norm_add_one=True,

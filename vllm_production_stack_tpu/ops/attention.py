@@ -28,7 +28,8 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
-def _scale_inv_freq(inv_freq: jax.Array, scaling: tuple) -> jax.Array:
+def _scale_inv_freq(inv_freq: jax.Array, scaling: tuple,
+                    theta: float = 10000.0) -> jax.Array:
     """RoPE frequency scaling (HF rope_scaling semantics).
 
     "linear": positional interpolation — every frequency divided by the
@@ -42,6 +43,8 @@ def _scale_inv_freq(inv_freq: jax.Array, scaling: tuple) -> jax.Array:
     kind, factor, low, high, orig = scaling
     if kind == "linear":
         return inv_freq / factor
+    if kind == "yarn":
+        return _yarn_inv_freq(inv_freq, theta, factor, low, high, orig)
     if kind != "llama3":
         raise ValueError(f"unsupported rope_scaling type {kind!r}")
     low_wl = orig / low
@@ -56,28 +59,65 @@ def _scale_inv_freq(inv_freq: jax.Array, scaling: tuple) -> jax.Array:
     )
 
 
+def _yarn_inv_freq(inv_freq: jax.Array, theta: float, factor: float,
+                   beta_fast: float, beta_slow: float, orig: int) -> jax.Array:
+    """YaRN (transformers modeling_rope_utils _compute_yarn_parameters):
+    dims whose wavelength fits the original context `beta_fast` times or
+    more keep their frequency (extrapolation), dims that fit it `beta_slow`
+    times or fewer are divided by `factor` (interpolation), a linear ramp
+    over the dim index between. With d = 2 * len(inv_freq) rotated dims:
+    corr(beta) = d ln(orig / (2 pi beta)) / (2 ln theta); low =
+    floor(corr(beta_fast)), high = ceil(corr(beta_slow)), clamped to
+    [0, d - 1]."""
+    import math
+
+    half = inv_freq.shape[0]
+    d = 2 * half
+
+    def corr(beta):
+        return d * math.log(orig / (beta * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(corr(beta_fast)), 0)
+    high = min(math.ceil(corr(beta_slow)), d - 1)
+    if low == high:
+        high += 0.001  # transformers' guard against a zero-width ramp
+    ramp = jnp.clip(
+        (jnp.arange(half, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    return (1.0 - ramp) * inv_freq + ramp * inv_freq / factor
+
+
 def apply_rope(
     x: jax.Array, positions: jax.Array, theta: float = 10000.0,
-    scaling: tuple | None = None,
+    scaling: tuple | None = None, rotary_dim: int = 0,
+    attention_factor: float = 1.0,
 ) -> jax.Array:
     """Rotary position embedding, non-interleaved (HF Llama convention).
 
     x: (..., T, heads, head_dim), positions: (..., T) int32;
-    scaling: ModelConfig.rope_scaling tuple (llama3 / linear) or None.
+    scaling: a RopeSpec.scaling tuple (llama3 / linear / yarn) or None;
+    rotary_dim: the leading dims of a head that rotate (rotate-half over
+    those; the rest pass through), 0 = all; attention_factor multiplies cos
+    and sin (yarn).
     """
-    head_dim = x.shape[-1]
-    half = head_dim // 2
+    rot = rotary_dim or x.shape[-1]
+    half = rot // 2
     freqs = jnp.arange(half, dtype=jnp.float32) / half
     inv_freq = theta**-freqs  # (half,)
     if scaling is not None:
-        inv_freq = _scale_inv_freq(inv_freq, scaling)
+        inv_freq = _scale_inv_freq(inv_freq, scaling, theta)
     angles = positions.astype(jnp.float32)[..., None] * inv_freq  # (..., T, half)
     cos = jnp.cos(angles)[..., None, :]  # (..., T, 1, half)
     sin = jnp.sin(angles)[..., None, :]
-    x1, x2 = x[..., :half], x[..., half:]
+    if attention_factor != 1.0:
+        cos, sin = cos * attention_factor, sin * attention_factor
+    x1, x2 = x[..., :half], x[..., half:rot]
     rx1 = x1 * cos - x2 * sin
     rx2 = x2 * cos + x1 * sin
-    return jnp.concatenate([rx1, rx2], axis=-1).astype(x.dtype)
+    if rot == x.shape[-1]:
+        return jnp.concatenate([rx1, rx2], axis=-1).astype(x.dtype)
+    return jnp.concatenate(
+        [rx1.astype(x.dtype), rx2.astype(x.dtype), x[..., rot:]], axis=-1)
 
 
 

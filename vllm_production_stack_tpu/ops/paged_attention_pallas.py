@@ -164,13 +164,20 @@ def _decode_walk_kernel(
     state,  # SMEM (2,) int32: slot of the next block; is it in flight
     *,
     scale: float,
+    window: int = 0,
 ):
     """One grid step = one row. The row's ceil(hist_len / (P * bs)) page
     blocks are walked in a loop whose trip count is the row's own, each block
     copied HBM->VMEM by P async copies (page ids from the block table) while
     the block before it is computed; the last block of a row starts the first
     block of the next row that has any. A padding row (hist_len 0) copies and
-    computes nothing but the staged window."""
+    computes nothing but the staged window.
+
+    `window` > 0 (a window layer): the query at position hist + step sees
+    keys at positions > hist + step - window, so the walk starts at the PAGE
+    that holds the first of them — pages before it may have been given back
+    (engine/kv_cache.py: their table slots hold the null page) and are never
+    read — and the head of that page is masked."""
     b = pl.program_id(0)
     rows = pl.num_programs(0)
     nb = tables_ref.shape[1]
@@ -179,16 +186,30 @@ def _decode_walk_kernel(
     span = pages * bs  # tokens per block
     cols = span * kvh
     hist = hist_ref[b]
-    n_blocks = pl.cdiv(hist, span)
     blocks = buf.reshape(2, 2, cols, d)  # (slot, K|V, columns, D)
+
+    # (with `window` == 0 every expression below traces to what it was
+    # before the window existed: a full layer's kernel is unchanged)
+
+    def first_page(row):
+        """The page a window layer's walk of `row` starts at."""
+        return jnp.maximum(
+            hist_ref[row] + step_ref[0] - (window - 1), 0) // bs
+
+    if window:
+        page0 = first_page(b)
+        n_blocks = pl.cdiv(hist - page0 * bs, span)
+    else:
+        n_blocks = pl.cdiv(hist, span)
 
     def block_copies(row, blk, slot):
         # table slots past the row's pages hold the null page or stale ids:
         # read whole, masked by position; slots past the table are clamped
         return [
             pltpu.make_async_copy(
-                kv_hbm.at[:, tables_ref[row, jnp.minimum(blk * pages + j,
-                                                         nb - 1)]],
+                kv_hbm.at[:, tables_ref[row, jnp.minimum(
+                    blk * pages + j + first_page(row) if window
+                    else blk * pages + j, nb - 1)]],
                 buf.at[slot, :, j],
                 sems.at[slot],
             )
@@ -223,6 +244,8 @@ def _decode_walk_kernel(
         @pl.when(i + 1 == n_blocks)
         def _next_row():
             nxt = jax.lax.while_loop(
+                # (a window layer's row has blocks iff it has history:
+                # its first page starts before hist, window > decode_window)
                 lambda r: (r < rows)
                 & (hist_ref[jnp.minimum(r, rows - 1)] == 0),
                 lambda r: r + 1,
@@ -238,10 +261,15 @@ def _decode_walk_kernel(
 
         for copy in block_copies(b, i, slot):
             copy.wait()
-        return _flash_block(
-            q, blocks[slot, 0], blocks[slot, 1],
-            col_tokens < hist - i * span, carry, scale,
-        )
+        keys, values = blocks[slot, 0], blocks[slot, 1]
+        # the block's first token, as a position of the row
+        base = i * span
+        if window:
+            base = base + page0 * bs
+        valid = col_tokens < hist - base
+        if window:
+            valid &= col_tokens > hist + step_ref[0] - window - base
+        return _flash_block(q, keys, values, valid, carry, scale)
 
     carry = jax.lax.fori_loop(0, n_blocks, visit, (
         jnp.full((nh, 1), NEG_INF, jnp.float32),
@@ -263,6 +291,7 @@ def _decode_grid_kernel(
     q_ref,  # (1, nh, D)
     *refs,  # P pool pages (2, 1, bs, kvh, D), staged K and V, out, m, l, acc
     scale: float,
+    window: int = 0,
 ):
     """The same page blocks for pools whose pages the walk kernel cannot
     slice out of HBM (_pages_fill_tiles): the grid is (rows, blocks the table
@@ -285,7 +314,14 @@ def _decode_grid_kernel(
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(blk >= 0)
+    live = blk >= 0
+    if window:
+        # the last position the window leaves out; blocks wholly at or
+        # before it are not computed (their pages may have been given back)
+        behind = hist + step_ref[0] - window
+        live &= (blk + 1) * span - 1 > behind
+
+    @pl.when(live)
     def _visit():
         keys, values = (
             jnp.concatenate(
@@ -293,9 +329,12 @@ def _decode_grid_kernel(
             )
             for i in (0, 1)
         )
+        col_tokens = _column_tokens(nh, span * kvh, kvh)
+        valid = col_tokens < hist - blk * span
+        if window:
+            valid &= col_tokens > behind - blk * span
         m_ref[:], l_ref[:], acc_ref[:] = _flash_block(
-            q, keys, values,
-            _column_tokens(nh, span * kvh, kvh) < hist - blk * span,
+            q, keys, values, valid,
             (m_ref[:], l_ref[:], acc_ref[:]), scale,
         )
 
@@ -404,6 +443,7 @@ def _prefill_walk_kernel(
     acc_ref,  # (nh * Tt, D) f32 running numerator
     *,
     scale: float,
+    window: int = 0,
 ):
     """One grid step = one query tile of one row, all heads. The tile walks
     the page blocks its row holds up to the tile's last position —
@@ -419,7 +459,14 @@ def _prefill_walk_kernel(
     feeds chunks with CONTIGUOUS positions (scheduler.py: range(start,
     start + len)), so a query's position is chunk_start + its index. Chunked
     prefill needs no special casing — resident pages hold earlier chunks AND
-    this chunk's freshly-written KV (forward writes before attending)."""
+    this chunk's freshly-written KV (forward writes before attending).
+
+    `window` > 0 (a window layer): a query at position p sees keys at
+    positions > p - window, so a tile's walk starts at the page that holds
+    the first key its FIRST query sees and a tile of Tt queries visits at
+    most window + Tt keys (plus page rounding) whatever the context: the
+    layer's work is O(T * window), not O(T * context). Pages before that
+    one may have been given back and are never read."""
     b, qt = pl.program_id(0), pl.program_id(1)
     rows, tiles = pl.num_programs(0), pl.num_programs(1)
     nb = tables_ref.shape[1]
@@ -429,24 +476,37 @@ def _prefill_walk_kernel(
     span = pages * bs  # tokens per block
     group = qpk * tt  # query rows a kv group's dots see
 
+    # (with `window` == 0 every expression below traces to what it was
+    # before the window existed, and `tile` is not read: a full layer's
+    # kernel is unchanged)
+
+    def tile_page0(row, tile):
+        """The page a window layer's tile starts its walk at."""
+        return jnp.maximum(
+            start_ref[row] + tile * tt - (window - 1), 0) // bs
+
     def tile_blocks(row, tile):
         ctx, first = ctx_ref[row], start_ref[row] + tile * tt
-        return jnp.where(
-            first < ctx, pl.cdiv(jnp.minimum(ctx, first + tt), span), 0)
+        walks = first < ctx
+        last = jnp.minimum(ctx, first + tt)
+        if window:
+            last = last - tile_page0(row, tile) * bs
+        return jnp.where(walks, pl.cdiv(last, span), 0)
 
-    def block_copies(row, blk, slot):
+    def block_copies(row, tile, blk, slot):
         return [
             pltpu.make_async_copy(
-                kv_hbm.at[:, tables_ref[row, jnp.minimum(blk * pages + j,
-                                                         nb - 1)]],
+                kv_hbm.at[:, tables_ref[row, jnp.minimum(
+                    blk * pages + j + tile_page0(row, tile) if window
+                    else blk * pages + j, nb - 1)]],
                 buf.at[slot, :, j],
                 sems.at[slot],
             )
             for j in range(pages)
         ]
 
-    def start_block(row, blk, slot):
-        for copy in block_copies(row, blk, slot):
+    def start_block(row, tile, blk, slot):
+        for copy in block_copies(row, tile, blk, slot):
             copy.start()
 
     @pl.when((b == 0) & (qt == 0))
@@ -459,9 +519,10 @@ def _prefill_walk_kernel(
 
     @pl.when((n_blocks > 0) & (state[1] == 0))
     def _first_block():
-        start_block(b, 0, slot0)
+        start_block(b, qt, 0, slot0)
 
     ctx, first = ctx_ref[b], start_ref[b] + qt * tt
+    page0 = tile_page0(b, qt) if window else 0
     # the last key position each query row attends, rows ordered (head, token)
     last_key = jnp.minimum(
         first + jax.lax.broadcasted_iota(jnp.int32, (group, 1), 0) % tt,
@@ -482,7 +543,7 @@ def _prefill_walk_kernel(
 
         @pl.when(i + 1 < n_blocks)
         def _next_block():
-            start_block(b, i + 1, 1 - slot)
+            start_block(b, qt, i + 1, 1 - slot)
 
         @pl.when(i + 1 == n_blocks)
         def _next_tile():
@@ -501,17 +562,23 @@ def _prefill_walk_kernel(
             @pl.when(found)
             def _its_first_block():
                 start_block(
-                    jnp.where(same, b, jnp.minimum(nxt, rows - 1)), 0,
-                    1 - slot,
+                    jnp.where(same, b, jnp.minimum(nxt, rows - 1)),
+                    jnp.where(same, qt + 1, 0) if window else None,
+                    0, 1 - slot,
                 )
 
             state[0] = 1 - slot
             state[1] = found.astype(jnp.int32)
 
-        for copy in block_copies(b, i, slot):
+        for copy in block_copies(b, qt, i, slot):
             copy.wait()
         key0 = i * span
+        if window:
+            key0 = key0 + page0 * bs
         straddles = (key0 + span > ctx) | (key0 + span - 1 > first)
+        if window:
+            # ... or holds a key the tile's LAST query no longer sees
+            straddles |= key0 <= first + tt - 1 - window
 
         def attend(g, carry):
             q = q_rows[g]  # (group, D)
@@ -521,13 +588,15 @@ def _prefill_walk_kernel(
                 q, keys, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ) * scale  # (group, span)
-            scores = jax.lax.cond(
-                straddles,
-                lambda s: jnp.where(key_offsets <= last_key - key0, s,
-                                    NEG_INF),
-                lambda s: s,
-                scores,
-            )
+            def masked(s):
+                seen = key_offsets <= last_key - key0
+                if window:
+                    # (a padded query sees what the row's last token sees:
+                    # its row of the output is never read, but stays finite)
+                    seen &= key_offsets > last_key - window - key0
+                return jnp.where(seen, s, NEG_INF)
+
+            scores = jax.lax.cond(straddles, masked, lambda s: s, scores)
             r = pl.ds(pl.multiple_of(g * group, group), group)
             # block 0 starts the statistics: the scratch is never cleared
             m_prev = jnp.where(i == 0, NEG_INF, m_ref[r])
@@ -569,8 +638,7 @@ def _prefill_walk_kernel(
         out_ref[...] = jnp.zeros_like(out_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def paged_prefill_attention(
+def _paged_prefill_attention(
     q: jax.Array,  # (B, T, nh, D) — the chunk's queries
     kv: jax.Array,  # (2, num_blocks, bs, kvh, D) — pool, chunk KV already in
     block_tables: jax.Array,  # (B, nb) int32
@@ -578,6 +646,7 @@ def paged_prefill_attention(
     chunk_start: jax.Array,  # (B,) int32 — logical position of q[:, 0]
     *,
     scale: float,
+    window: int = 0,
     interpret: bool = False,
 ) -> jax.Array:
     """Complete chunked-prefill attention (resident pages + the chunk's own
@@ -597,8 +666,11 @@ def paged_prefill_attention(
     )
     assert t % tt == 0, (t, tt)  # T is a power-of-two bucket
     tile = pl.BlockSpec((1, tt, nh * d), lambda i, j, *_: (i, j, 0))
+    # (a window layer's kernel is a kernel of its own, and named so: the
+    # device trace tells the two apart)
     out = pl.pallas_call(
-        functools.partial(_prefill_walk_kernel, scale=scale),
+        functools.partial(_prefill_walk_kernel, scale=scale, window=window),
+        **({"name": "paged_window_prefill_attention"} if window else {}),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,  # tables, context_lens, chunk_start
             grid=(b, t // tt),
@@ -623,6 +695,30 @@ def paged_prefill_attention(
         interpret=interpret,
     )(block_tables, context_lens, chunk_start, q.reshape(b, t, nh * d), kv)
     return out.reshape(b, t, nh, d)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def paged_prefill_attention(q, kv, block_tables, context_lens, chunk_start,
+                            *, scale: float, interpret: bool = False):
+    return _paged_prefill_attention(
+        q, kv, block_tables, context_lens, chunk_start, scale=scale,
+        interpret=interpret)
+
+
+paged_prefill_attention.__doc__ = _paged_prefill_attention.__doc__
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "window", "interpret"))
+def paged_window_prefill_attention(
+    q, kv, block_tables, context_lens, chunk_start, *, scale: float,
+    window: int, interpret: bool = False,
+):
+    """`paged_prefill_attention` for a window layer: a query at position p
+    sees keys at positions > p - window (itself among the `window`). Under
+    its own name in the device trace."""
+    return _paged_prefill_attention(
+        q, kv, block_tables, context_lens, chunk_start, scale=scale,
+        window=window, interpret=interpret)
 
 
 def paged_prefill_attention_sharded(
@@ -722,8 +818,7 @@ def paged_decode_attention_sharded(
     return fn(q, kv, block_tables, hist_len, staged_k, staged_v, step_k)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def paged_decode_attention(
+def _paged_decode_attention(
     q: jax.Array,  # (B, nh, D) — decode queries, one token per row
     kv: jax.Array,  # (2, num_blocks, bs, kvh, D) — the pool, read-only
     block_tables: jax.Array,  # (B, nb) int32
@@ -733,6 +828,7 @@ def paged_decode_attention(
     step_k: jax.Array,  # scalar int32 — current iteration in the window
     *,
     scale: float,
+    window: int = 0,
     interpret: bool = False,
 ) -> jax.Array:
     """Complete decode attention (pooled history + staged window) without
@@ -775,7 +871,8 @@ def paged_decode_attention(
             pltpu.VMEM((nh, d), jnp.float32),
         ]
     return pl.pallas_call(
-        functools.partial(kernel, scale=scale),
+        functools.partial(kernel, scale=scale, window=window),
+        **({"name": "paged_window_decode_attention"} if window else {}),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,  # tables, hist_len, step_k
             grid=grid,
@@ -793,3 +890,28 @@ def paged_decode_attention(
         block_tables, hist_len, jnp.reshape(step_k, (1,)), q,
         *[kv] * len(pool), staged_k, staged_v,
     )
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def paged_decode_attention(q, kv, block_tables, hist_len, staged_k, staged_v,
+                           step_k, *, scale: float, interpret: bool = False):
+    return _paged_decode_attention(
+        q, kv, block_tables, hist_len, staged_k, staged_v, step_k,
+        scale=scale, interpret=interpret)
+
+
+paged_decode_attention.__doc__ = _paged_decode_attention.__doc__
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "window", "interpret"))
+def paged_window_decode_attention(
+    q, kv, block_tables, hist_len, staged_k, staged_v, step_k, *,
+    scale: float, window: int, interpret: bool = False,
+):
+    """`paged_decode_attention` for a window layer: the query at position
+    hist_len + step_k sees pool keys at positions > hist_len + step_k -
+    window (every staged slot lies inside the window: window >
+    decode_window). Under its own name in the device trace."""
+    return _paged_decode_attention(
+        q, kv, block_tables, hist_len, staged_k, staged_v, step_k,
+        scale=scale, window=window, interpret=interpret)

@@ -37,7 +37,9 @@ def llama_param_specs(cfg: ModelConfig) -> dict:
     A multi-head-latent-attention tree (models/llama.py:_init_params_mla)
     has no sharded layout yet — the runner refuses a mesh over one device
     for it by name — so every leaf of it is replicated."""
-    if cfg.mla:
+    if cfg.mla or cfg.tree_by_kind:
+        # (a tree stacked by layer kind has no sharded layout yet either,
+        # and is refused by name the same way)
         import jax
 
         from ..models.llama import init_params

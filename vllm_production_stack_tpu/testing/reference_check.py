@@ -52,6 +52,12 @@ class RoutingTap:
     def __init__(self):
         self.records: list[tuple] = []
         self._by_key: dict | None = None
+        # (position, token id) keys under which two executions recorded
+        # DIFFERENT experts: two sequences in flight with the same token at
+        # the same position (a decoded token that equals another prompt's,
+        # say). The key cannot say whose choice is whose: `routing_of(...,
+        # mark_ambiguous=True)` hands such a token back as a row of -1
+        self.ambiguous: set = set()
 
     def __call__(self, token_ids, positions, experts) -> None:
         self.records.append((np.asarray(token_ids), np.asarray(positions),
@@ -65,15 +71,25 @@ class RoutingTap:
         llama.ROUTING_TAP = None
         jax.effects_barrier()  # every callback has run
 
-    def routing_of(self, tokens: list[int]) -> list[np.ndarray]:
+    def routing_of(self, tokens: list[int], mark_ambiguous: bool = False,
+                   ) -> list[np.ndarray]:
         """Per expert layer the (T, k) experts chosen for `tokens` fed at
-        positions 0..T-1 (whichever program fed each)."""
+        positions 0..T-1 (whichever program fed each). `mark_ambiguous`: a
+        token whose key two sequences share comes back as a row of -1 (a
+        reference that takes such rows follows its own choice there)."""
         if self._by_key is None:
             self._by_key = {}
             for toks, pos, experts in self.records:
                 for i, key in enumerate(zip(pos.tolist(), toks.tolist())):
+                    seen = self._by_key.get(key)
+                    if seen is not None and not np.array_equal(
+                            seen, experts[:, i]):
+                        self.ambiguous.add(key)
                     self._by_key[key] = experts[:, i]
         rows = [self._by_key[(p, t)] for p, t in enumerate(tokens)]
+        if mark_ambiguous:
+            rows = [np.full_like(r, -1) if (p, t) in self.ambiguous else r
+                    for (p, t), r in zip(enumerate(tokens), rows)]
         return list(np.stack(rows, axis=1))  # Lm x (T, k)
 
 
@@ -134,26 +150,36 @@ def compare(reference, cfg, params, prompts: list[list[int]],
     free-running comparison measures the flips, amplified, and nothing
     else (3.25 nats at 8 layers on the chip, PERF.md §6 PR 31)."""
     ref_cfg = reference.published_keys(cfg)
-    worst, positions, ids_compared = 0.0, 0, 0
+    worst, positions, ids_compared, total = 0.0, 0, 0, 0.0
     chosen_worst = 0.0
     routed = differing = 0
     margins: list[float] = []
-    if cfg.mla:
-        # reference_mla.py reads the checkpoint's names and orientation
-        from ..models.loader import DeepseekCheckpointView
+    # reference_mla.py and reference_laguna.py read checkpoint-style names
+    # and orientation, and take `logit_positions` and `forced_experts`
+    by_name = cfg.mla or cfg.architecture == "laguna"
+    if by_name:
+        from ..models import loader
 
-        params_ref = DeepseekCheckpointView(cfg, params)
+        view = (loader.DeepseekCheckpointView if cfg.mla
+                else loader.LagunaCheckpointView)
+        params_ref = view(cfg, params)
     for prompt, got in zip(prompts, served):
         seq = list(prompt) + list(got["token_ids"][:-1])
         first = len(prompt) - 1  # the first compared position
-        if cfg.mla:
+        if by_name:
             # ... and gives the logits of the asked positions only (all of
             # a long sequence's are GBs of float32)
-            forced = (served_routing.routing_of(seq)
-                      if served_routing is not None else None)
+            forced = (
+                served_routing.routing_of(
+                    seq, mark_ambiguous=cfg.architecture == "laguna")
+                if served_routing is not None else None)
+            # (laguna: 72 heads x 16k keys of float32 scores a query —
+            # 64 queries a block keep a block's scores under 0.4 GB beside
+            # the served weights, which stay on the chip)
+            block = {"query_block": 64} if cfg.architecture == "laguna" else {}
             logits, routing = reference.forward(
                 ref_cfg, params_ref, seq, range(first, len(seq)),
-                forced_experts=forced)
+                forced_experts=forced, **block)
             first = 0
         else:
             logits, routing = reference.forward(ref_cfg, params, seq)
@@ -162,23 +188,31 @@ def compare(reference, cfg, params, prompts: list[list[int]],
             row = ref_lp[first + k]
             diff = np.abs(row[np.asarray(top_ids)] - np.asarray(top_lps))
             worst = max(worst, float(diff.max()))
+            total += float(diff.sum())
             chosen_worst = max(chosen_worst, abs(
                 float(row[got["token_ids"][k]]) - chosen_lp))
             positions += 1
             ids_compared += len(top_ids)
         if cfg.num_experts:
-            ours_all = forced if cfg.mla and forced is not None else (
+            ours_all = forced if by_name and forced is not None else (
                 system_routing(cfg, params, seq, routing_chunk,
                                routing_backend))
             # (a leading dense layer routes nothing: the reference says None)
             for ours, ref in zip(ours_all, [r for r in routing if r]):
                 same = (np.sort(ours, -1) == np.sort(
                     np.asarray(ref["experts"]), -1)).all(-1)
+                same |= (np.asarray(ours) < 0).all(-1)  # ambiguous: unforced
                 routed += same.size
                 differing += int((~same).sum())
                 margins.extend(np.asarray(ref["margin"])[~same].tolist())
+    # (the mean beside the max: rounding scatters, with a few large
+    # outliers among small logprobs; a fault in the mathematics shifts
+    # every position)
     out = {"max_abs_dlogprob": max(worst, chosen_worst),
+           "mean_abs_dlogprob": total / max(ids_compared, 1),
            "positions": positions, "ids_compared": ids_compared}
+    if served_routing is not None:
+        out["routing_keys_ambiguous"] = len(served_routing.ambiguous)
     if cfg.num_experts:
         out.update(
             routing_pairs=routed,
