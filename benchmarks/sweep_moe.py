@@ -18,6 +18,21 @@ program's temp bytes (a widened or sliced copy of one layer's experts would
 be 128 to 256 MiB a leaf) and the int8 expert bytes a layer must read.
 
     python benchmarks/sweep_moe.py [--tokens 16,512,2048,8192] [--layers 16]
+
+`--decode` is the decode arm (ISSUE 36), the evidence behind `_moe_mlp`'s
+third regime and its crossover: the fused touched-experts kernel
+(`ops/moe_experts_pallas.py:moe_decode_experts`) against `_experts_all` and
+`ragged_dot` at 16, 32 and 64 rows, for Kanana-2's (128 x 2048 x 768 bf16,
+7 layers), Laguna's (64 held of 256 x 3072 x 1024 bf16, 8 layers) and
+OLMoE's (64 x 2048 x 1024 int8, 16 layers) expert stacks as served, with a
+GIVEN share of the held experts touched (10 / 25 / 50 / 75 / 100%, or what
+rows x k pairs can reach): the routing is an input of the timed program, so
+one compile serves every share. Prints one JSON line per (model, rows,
+share, variant): ms a layer, the share of 819 GB/s reached on the touched
+experts' bytes, and the kernel's largest difference from the einsum.
+
+    python benchmarks/sweep_moe.py --decode [--models kanana-2-30b-a3b-8l,...]
+        [--rows 16,32,64] [--shares 10,25,50,75,100]
 """
 
 from __future__ import annotations
@@ -110,14 +125,173 @@ def time_layers(fn, stacked, x) -> tuple[float, int]:
     return best / (LOOP_ITERS * n_layers) * 1e3, temp
 
 
+DECODE_MODELS = (
+    # preset, quantization of the expert leaves as the cell serves them
+    ("kanana-2-30b-a3b-8l", None),
+    ("laguna-s-2.1-ep4-9l", None),
+    ("olmoe-1b-7b", "int8"),
+)
+DECODE_LOOP_ITERS = 4
+HBM_BYTES_PER_S = 819e9  # benchmarks/chip/peaks.json, TPU v5 lite
+
+
+def given_routing(n: int, k: int, e_held: int, first: int, touched: int):
+    """(topv (n, k), topi (n, k)) whose n * k pairs fall on exactly
+    min(touched, n * k) of the held experts [first, first + e_held), spread
+    evenly and strided over the expert axis; a row's experts are distinct
+    where `touched` >= k (fewer: the row's weights on one expert add)."""
+    t = max(1, min(touched, n * k))
+    pair = jnp.arange(n * k, dtype=jnp.int32).reshape(n, k)
+    topi = first + (pair % t) * e_held // t
+    topv = jnp.full((n, k), 1.0 / k, jnp.float32)
+    return topv, topi.astype(jnp.int32)
+
+
+def decode_variants(cfg):
+    """name -> fn(stacked, layer, xf, topv, topi) -> (n, h): one layer's
+    routed experts, three ways, from a given routing."""
+    def route(topv, topi):
+        local = topi - cfg.first_expert_held
+        chosen = jax.nn.one_hot(local, cfg.held_experts, dtype=jnp.float32)
+        return chosen, jnp.sum(chosen, axis=(0, 1)).astype(jnp.int32)
+
+    def kernel(st, i, xf, topv, topi):
+        return llama._experts_touched(
+            cfg, st, i, xf, topv, *route(topv, topi),
+            jax.devices()[0].platform != "tpu")  # interpreted off the chip
+
+    def einsum(st, i, xf, topv, topi):
+        mp = jax.tree.map(lambda a: a[i], st)
+        return llama._experts_all(cfg, mp, xf, topv, route(topv, topi)[0])
+
+    def ragged(st, i, xf, topv, topi):
+        return llama._experts_grouped(
+            cfg, st, i, xf, topv, topi, route(topv, topi)[1])
+
+    return {"kernel": kernel, "einsum": einsum, "ragged": ragged}
+
+
+def time_decode(fn, stacked, n_layers, x, routings) -> list[float]:
+    """ms a layer for each (topv, topi) of `routings`: every layer in turn
+    DECODE_LOOP_ITERS times in one dispatch, compiled once (the routing is
+    an operand), each output renormalised into the next input."""
+
+    @jax.jit
+    def run(stacked, x0, topv, topi):
+        def body(_, xx):
+            for i in range(n_layers):
+                y = fn(stacked, i, xx, topv, topi).astype(jnp.float32)
+                y = y / (jnp.sqrt(jnp.mean(y * y, -1, keepdims=True)) + 1e-6)
+                xx = (xx + y.astype(xx.dtype)) * 0.7071
+            return xx
+
+        return jax.lax.fori_loop(0, DECODE_LOOP_ITERS, body, x0)
+
+    compiled = run.lower(stacked, x, *routings[0]).compile()
+    out = []
+    for topv, topi in routings:
+        compiled(stacked, x, topv, topi).block_until_ready()
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            compiled(stacked, x, topv, topi).block_until_ready()
+            best = min(best, time.perf_counter() - t0)
+        out.append(best / (DECODE_LOOP_ITERS * n_layers) * 1e3)
+    return out
+
+
+def decode_arm(args) -> None:
+    dev = jax.devices()[0]
+    # (a chip call returns the end of its output only: the lines are also
+    # kept where such a call brings files back from)
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/sweep_moe_decode.jsonl", "a") as kept:
+        def emit(row):
+            for f in (sys.stdout, kept):
+                print(json.dumps(row), file=f, flush=True)
+
+        emit({"platform": dev.platform, "kind": dev.device_kind,
+              "arm": "decode"})
+        for model in args.models.split(","):
+            decode_model(args, model, emit)
+
+
+def decode_model(args, model: str, emit) -> None:
+    """One model's expert stack through every (rows, share, variant)."""
+    quant = dict(DECODE_MODELS).get(model)
+    cfg = resolve_model_config(model)
+    n_layers = args.layers or cfg.num_moe_layers
+    e, k = cfg.held_experts, cfg.num_experts_per_tok
+    h, it = cfg.hidden_size, cfg.expert_intermediate_size
+    dt = jnp.dtype(cfg.dtype)
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+
+    def make(key, *shape):
+        def leaf(kk):
+            w = (jax.random.normal(kk, shape, jnp.float32)
+                 * shape[-2] ** -0.5).astype(dt)
+            return _quantize(w) if quant else w
+
+        return jax.jit(leaf)(key)
+
+    stacked = {"gate": make(keys[0], n_layers, e, h, it),
+               "up": make(keys[1], n_layers, e, h, it),
+               "down": make(keys[2], n_layers, e, it, h)}
+    expert_bytes = 3 * h * it * (1 if quant else dt.itemsize)
+    for n in [int(r) for r in args.rows.split(",")]:
+        x = jax.random.normal(keys[3], (n, h), jnp.float32).astype(dt)
+        counts = sorted({max(1, min(round(e * s / 100), n * k))
+                         for s in map(int, args.shares.split(","))})
+        routings = [given_routing(n, k, e, cfg.first_expert_held, t)
+                    for t in counts]
+        fns = decode_variants(cfg)
+        one = {name: jax.jit(lambda st, xx, tv, ti, f=f: f(
+            st, n_layers - 1, xx, tv, ti)) for name, f in fns.items()}
+        diffs = [
+            float(jnp.max(jnp.abs(
+                one["kernel"](stacked, x, *r).astype(jnp.float32)
+                - one["einsum"](stacked, x, *r).astype(jnp.float32))))
+            for r in routings]
+        for name, fn in fns.items():
+            row = {"model": model, "quantization": quant, "rows": n,
+                   "layers": n_layers, "variant": name}
+            try:
+                times = time_decode(fn, stacked, n_layers, x, routings)
+            except Exception as err:  # a variant the compiler refuses
+                row["error"] = f"{type(err).__name__}: {err}"[:300]
+                emit(row)
+                continue
+            for t, ms, diff in zip(counts, times, diffs):
+                line = dict(
+                    row, touched=t, touched_share=round(100 * t / e, 1),
+                    ms=round(ms, 4),
+                    all_bytes_bound_ms=round(
+                        e * expert_bytes / HBM_BYTES_PER_S * 1e3, 4),
+                    touched_bw_share=round(
+                        100 * t * expert_bytes / HBM_BYTES_PER_S
+                        / (ms * 1e-3), 1))
+                if name == "kernel":
+                    line["max_abs_diff_vs_einsum"] = round(diff, 5)
+                emit(line)
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
+    ap.add_argument("--decode", action="store_true",
+                    help="the decode arm: touched-experts kernel vs einsum "
+                         "vs ragged_dot at given touched shares")
+    ap.add_argument("--models",
+                    default=",".join(m for m, _ in DECODE_MODELS))
+    ap.add_argument("--rows", default="16,32,64")
+    ap.add_argument("--shares", default="10,25,50,75,100")
     ap.add_argument("--model", default="olmoe-1b-7b")
     ap.add_argument("--tokens", default="16,512,1024,2048,8192")
     ap.add_argument("--layers", type=int, default=None,
                     help="layers of int8 experts, stacked as the model "
                          "holds them (default: the model's)")
     args = ap.parse_args()
+    if args.decode:
+        return decode_arm(args)
     cfg = resolve_model_config(args.model)
     dev = jax.devices()[0]
     n_layers = args.layers or cfg.num_layers

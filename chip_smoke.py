@@ -851,8 +851,9 @@ def child_reference(args) -> int:
         tap = reference_check.RoutingTap() if mla else None
         with tap or contextlib.nullcontext():
             engine = LLMEngine(ecfg)
-            out = {"attention":
-                   engine.runner.device_report().get("attention"),
+            device = engine.runner.device_report()
+            out = {"attention": device.get("attention"),
+                   "moe": device.get("moe"),
                    "kv_dtype": str(engine.runner._kv_dtype)}
             served = reference_check.served_logprobs(engine, prompts, n_out)
         out["engine_s"] = round(time.monotonic() - t0, 1)

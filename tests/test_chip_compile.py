@@ -379,7 +379,8 @@ def test_prefill_program_of_a_cell_holds_no_score_temp(topo, model):
 def test_moe_decode_step_compiles_for_v5e(topo):
     """One decode step of OLMoE-1B-7B at 16 rows: the Pallas decode kernel
     at its third head shape (16 KV heads x 128) and the all-experts einsum
-    on int8 experts."""
+    on int8 experts — 16 rows x top-8 of 64 cover the experts, so a program
+    that may hold the touched-experts kernel does not."""
     from vllm_production_stack_tpu.models import llama
 
     cfg = _cell_config("olmoe-1b-7b")
@@ -390,7 +391,8 @@ def test_moe_decode_step_compiles_for_v5e(topo):
         stats: list = []
         hidden, staged = llama.decode_window_step(
             cfg, params, tokens, positions, kv, tables, staged,
-            jnp.int32(0), hist, backend="pallas", moe_stats=stats)
+            jnp.int32(0), hist, backend="pallas", moe_stats=stats,
+            moe_backend="pallas")
         return (llama.compute_logits(cfg, params, hidden), staged,
                 sum(s["touched"] for s in stats))
 
@@ -400,6 +402,7 @@ def test_moe_decode_step_compiles_for_v5e(topo):
     text = compiled.as_text()
     _assert_kernel(compiled)
     assert "paged_decode_attention" in text
+    assert "moe_decode_experts" not in text
     _assert_no_widened_experts(cfg, text)
 
 
@@ -508,8 +511,12 @@ def test_latent_prefill_program_compiles_for_v5e(topo):
 
 
 def test_latent_decode_step_compiles_for_v5e(topo):
-    """One decode step at 16 rows: the latent decode kernel, every expert on
-    every token (16 tokens), the shared experts, the leading dense layer."""
+    """One decode step at 16 rows: the latent decode kernel, the touched
+    experts through the fused kernel (16 rows x top-6 of 128 cannot cover
+    them) reading the stacked leaves in place, the shared experts, the
+    leading dense layer."""
+    import re
+
     from vllm_production_stack_tpu.models import llama
 
     cfg = _kanana()
@@ -526,7 +533,8 @@ def test_latent_decode_step_compiles_for_v5e(topo):
         stats: list = []
         hidden, staged = llama.decode_window_step(
             cfg, params, tokens, positions, kv, tables, staged,
-            jnp.int32(0), hist, backend="pallas", moe_stats=stats)
+            jnp.int32(0), hist, backend="pallas", moe_stats=stats,
+            moe_backend="pallas")
         return (llama.compute_logits(cfg, params, hidden), staged,
                 sum(s["touched"] for s in stats))
 
@@ -535,5 +543,53 @@ def test_latent_decode_step_compiles_for_v5e(topo):
     ).compile()
     text = compiled.as_text()
     _assert_kernel(compiled)
-    assert "mla_decode_attention" in text
+    assert "mla_decode_attention" in text and "moe_decode_experts" in text
     assert "paged_decode_attention" not in text and "ragged-dot" not in text
+    # no operation writes a copy of a layer's experts (a slice of a stacked
+    # leaf that fed the custom call would), nor an (N, E, inner) product
+    e, h, it = cfg.num_experts, cfg.hidden_size, cfg.moe_intermediate_size
+    copies = [
+        line[:200] for line in text.splitlines()
+        if re.search(rf"= bf16\[(1,)?({e},{h},{it}|{e},{it},{h}|16,{e},{it})\]",
+                     line) and " parameter(" not in line]
+    assert not copies, copies
+
+
+@pytest.mark.parametrize("model,quantization,rows", [
+    ("kanana-2-30b-a3b-8l", None, 16),  # a whole expert a grid step
+    ("laguna-s-2.1-ep4-9l", None, 16),  # 64 held of 256, 18.9 MB an expert
+    ("laguna-s-2.1-ep4-9l", None, 5),  # rows padded to a sublane tile
+    ("olmoe-1b-7b", "int8", 16),  # int8 tiles widened in VMEM
+])
+def test_touched_experts_kernel_compiles_for_v5e(topo, model, quantization,
+                                                 rows):
+    """The fused expert kernel at the three cells' expert stacks as served
+    (every layer stacked, the last layer read), inside the VMEM it asks
+    for."""
+    from vllm_production_stack_tpu.models.registry import resolve_model_config
+    from vllm_production_stack_tpu.ops import moe_experts_pallas as mk
+
+    cfg = resolve_model_config(model)
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    layers, e, k = cfg.num_moe_layers, cfg.held_experts, cfg.num_experts_per_tok
+    h, it = cfg.hidden_size, cfg.expert_intermediate_size
+    assert 2 * 3 * h * it * (1 if quantization else 2) // mk._inner_tiles(
+        h, it, 1 if quantization else 2) <= mk.WEIGHT_VMEM_BUDGET
+
+    def leaf(*shape):
+        if quantization:
+            return {"q": sds(shape, jnp.int8),
+                    "s": sds((*shape[:2], 1, shape[3]), jnp.float32)}
+        return sds(shape, jnp.bfloat16)
+
+    compiled = jax.jit(lambda x, w, g, wg, wu, wd: mk.moe_decode_experts(
+        x, w, g, wg, wu, wd, layer=layers - 1, slots=min(e, rows * k),
+        act=jax.nn.silu)).lower(
+        sds((rows, h), jnp.bfloat16), sds((rows, e), jnp.float32),
+        sds((e,), jnp.int32), leaf(layers, e, h, it), leaf(layers, e, h, it),
+        leaf(layers, e, it, h)).compile()
+    _assert_kernel(compiled)
+    # what the program holds beside its operands: the padded rows, not a
+    # copy of anything expert-sized
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20

@@ -19,6 +19,7 @@ from vllm_production_stack_tpu.engine.config import (
     CacheConfig, EngineConfig, SchedulerConfig,
 )
 from vllm_production_stack_tpu.engine.engine import LLMEngine
+from vllm_production_stack_tpu.engine.request import SamplingParams
 from vllm_production_stack_tpu.models import llama
 from vllm_production_stack_tpu.models.loader import LagunaCheckpointView
 from vllm_production_stack_tpu.models.registry import resolve_model_config
@@ -102,6 +103,33 @@ def test_engine_against_the_float32_reference(
     assert wpool.given_back_total > 0
     assert wpool.num_free == wpool.num_usable  # every page came back
     assert engine.scheduler.total_preemptions == 0
+
+
+def test_held_experts_through_the_touched_kernel(cfg):
+    """A program that holds 4 of 16 experts and decodes two rows runs the
+    touched-experts kernel (interpret mode) over the held experts only:
+    what it read is what was touched, pairs on other chips' experts touch
+    nothing here, and the tokens are the einsum engine's."""
+    model = _cut(cfg, 2)
+    sched = dict(decode_buckets=(2,), max_num_seqs=2)
+    prompts = [[5, 6, 7, 8, 9], list(range(20, 45))]
+    sp = SamplingParams(max_tokens=9, temperature=0.0, ignore_eos=True)
+    got = {}
+    for backend in ("xla", "pallas_interpret"):
+        econf = _engine_config(model, backend)
+        engine = LLMEngine(econf.replace(scheduler=dataclasses.replace(
+            econf.scheduler, **sched)))
+        got[backend] = (
+            [o["token_ids"] for o in engine.generate(prompts, sp)],
+            engine.flightrec.timing(),
+            engine.runner.device_report()["moe"]["decode"]["resolved"])
+    (tok_x, t_x, form_x), (tok_k, t_k, form_k) = got.values()
+    assert (form_x, form_k) == ("all_experts", "touched_kernel")
+    assert tok_k == tok_x
+    assert t_k["moe_decode_touched_n"] == t_x["moe_decode_touched_n"] > 0
+    assert t_k["moe_decode_read_n"] == t_k["moe_decode_touched_n"]
+    assert t_x["moe_decode_read_n"] == t_x["moe_decode_slots_n"]
+    assert t_k["moe_decode_held_pairs_n"] < t_k["moe_decode_pairs_n"]
 
 
 def test_a_model_whose_layer_kinds_are_swapped_differs(cfg, params):

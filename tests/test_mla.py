@@ -17,6 +17,7 @@ from vllm_production_stack_tpu.engine.config import (
     CacheConfig, EngineConfig, ParallelConfig, SchedulerConfig,
 )
 from vllm_production_stack_tpu.engine.engine import LLMEngine
+from vllm_production_stack_tpu.engine.request import SamplingParams
 from vllm_production_stack_tpu.engine.memory import (
     kv_block_bytes, param_bytes,
 )
@@ -87,6 +88,36 @@ def test_engine_against_the_float32_reference(cfg, backend):
     # each chunk's queries x the keys each may see: position p sees p + 1
     assert t["attn_prefill_pairs_n"] == sum(
         n * (n + 1) // 2 for n in (70, 9, 33, 50))
+
+
+@pytest.mark.parametrize("backend,rows,form", [
+    ("xla", 2, "all_experts"),  # no Pallas kernel in an XLA engine
+    ("pallas_interpret", 2, "touched_kernel"),  # 1 - (3/4)^2 = 0.44
+    ("pallas_interpret", 8, "all_experts"),  # 1 - (3/4)^8 = 0.90
+])
+def test_read_counter_and_report_follow_the_program_form(
+        cfg, backend, rows, form):
+    """`moe_decode_read_n` counts the experts whose bytes the decode
+    programs' expert computation read — the touched ones from a program
+    built with the kernel, every slot from one built with the einsum — and
+    /debug/programs' `device.moe.decode.resolved` says which; the host
+    knows the form from the program's shapes (ISSUE 36)."""
+    econf = _engine_config(cfg, backend)
+    econf = econf.replace(scheduler=dataclasses.replace(
+        econf.scheduler, decode_buckets=(rows,), max_num_seqs=rows))
+    engine = LLMEngine(econf)
+    engine.generate(
+        [[5, 6, 7, 8, 9], list(range(20, 50))],
+        SamplingParams(max_tokens=9, temperature=0.0, ignore_eos=True))
+    moe = engine.runner.device_report()["moe"]
+    assert moe["decode"] == {"resolved": form, "by_bucket": {str(rows): form}}
+    t = engine.flightrec.timing()
+    assert 0 < t["moe_decode_touched_n"] < t["moe_decode_slots_n"]
+    assert t["moe_decode_read_n"] == (
+        t["moe_decode_touched_n"] if form == "touched_kernel"
+        else t["moe_decode_slots_n"])
+    # 16- and 32-token chunks cover tiny-kanana's 8 experts: the einsum
+    assert t["moe_prefill_read_n"] == t["moe_prefill_slots_n"]
 
 
 def test_forward_in_one_chunk_equals_the_expanded_reference(cfg, params):

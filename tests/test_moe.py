@@ -499,6 +499,205 @@ def test_moe_mlp_takes_the_grouped_path_above_the_token_limit(monkeypatch):
     assert r_grp["experts"].shape == (2, 12, cfg.num_experts_per_tok)
 
 
+# -- the touched-experts kernel and the rule that chooses it (ISSUE 36) -------
+
+
+def _touched_case(case: str, dtype: str, quantization):
+    """(cfg, params, layer, xf, topv, topi) for one kernel case: a model of
+    two stacked expert layers at toy widths whose first two experts are held
+    elsewhere in the "held-share" cases."""
+    from vllm_production_stack_tpu.models.quantization import quantize_params
+
+    held = case in ("held-share", "none-touched")
+    cfg = ModelConfig.tiny(
+        model="tiny-olmoe", architecture="olmoe", intermediate_size=32,
+        num_experts=8, num_experts_per_tok=3, norm_topk_prob=False,
+        dtype=dtype, quantization=quantization,
+        num_experts_held=4 if held else 0, first_expert_held=2)
+    params = quantize_params(
+        cfg, llama.init_params(cfg, jax.random.PRNGKey(3)))
+    rng = np.random.RandomState(5)
+    n, e, k = 16, cfg.num_experts, cfg.num_experts_per_tok
+    xf = jnp.asarray(rng.normal(size=(n, cfg.hidden_size)), cfg.dtype)
+    if case == "one-expert":  # every pair of every row on expert 5
+        topi = np.full((n, k), 5)
+    elif case == "all-touched":
+        topi = np.stack([(np.arange(k) + r) % e for r in range(n)])
+    elif case == "none-touched":  # every pair on another chip's experts
+        topi = np.stack([rng.permutation([0, 1, 6, 7])[:k] for _ in range(n)])
+    else:  # "random", "held-share": some experts without a pair
+        topi = np.stack([rng.permutation(e - 1)[:k] for _ in range(n)])
+    topv = jnp.asarray(rng.uniform(0.05, 0.5, size=(n, k)), jnp.float32)
+    return cfg, params, xf, topv, jnp.asarray(topi, jnp.int32)
+
+
+def _both_forms(cfg, params, layer, xf, topv, topi):
+    local = topi - cfg.first_expert_held if cfg.num_experts_held else topi
+    chosen = jax.nn.one_hot(local, cfg.held_experts, dtype=jnp.float32)
+    sizes = jnp.sum(chosen, axis=(0, 1)).astype(jnp.int32)
+    stacked = params["layers"]["moe"]
+    mp = jax.tree.map(lambda a: a[layer], stacked)
+    dense = llama._experts_all(cfg, mp, xf, topv, chosen)
+    kernel = llama._experts_touched(
+        cfg, stacked, layer, xf, topv, chosen, sizes, True)
+    return dense, kernel, sizes
+
+
+@pytest.mark.parametrize("dtype,quantization", [
+    ("bfloat16", None), ("bfloat16", "int8"), ("float32", None)])
+@pytest.mark.parametrize("case", [
+    "random", "one-expert", "all-touched", "held-share", "none-touched"])
+def test_touched_kernel_equals_all_experts(case, dtype, quantization):
+    """The fused kernel (interpret mode) against the all-experts einsum on
+    layer 1 of the stacked leaves, read in place: equal to the rounding of
+    the model's dtype (the kernel keeps float32 where the einsum rounds),
+    at one, some and all experts touched, with pairs on experts held
+    elsewhere, and zeros where no held expert got a pair."""
+    cfg, params, xf, topv, topi = _touched_case(case, dtype, quantization)
+    dense, kernel, sizes = _both_forms(cfg, params, 1, xf, topv, topi)
+    touched = int(jnp.sum(sizes > 0))
+    assert touched == {"one-expert": 1, "all-touched": 8,
+                       "none-touched": 0}.get(case, touched)
+    assert kernel.shape == dense.shape and kernel.dtype == dense.dtype
+    if case == "none-touched":
+        assert not np.asarray(kernel, np.float32).any()
+    tol = 3e-2 if dtype == "bfloat16" else 1e-5
+    np.testing.assert_allclose(
+        np.asarray(kernel, np.float32), np.asarray(dense, np.float32),
+        atol=tol * float(jnp.max(jnp.abs(dense.astype(jnp.float32))) + 1),
+        rtol=tol)
+
+
+def test_touched_ids_compact_the_touched_experts():
+    from vllm_production_stack_tpu.ops.moe_experts_pallas import touched_ids
+
+    ids, n = touched_ids(jnp.asarray([0, 2, 0, 1, 5, 0], jnp.int32), 6)
+    assert int(n[0]) == 3 and ids.tolist() == [1, 3, 4, 4, 4, 4]
+    ids, n = touched_ids(jnp.zeros((6,), jnp.int32), 4)
+    assert int(n[0]) == 0 and ids.tolist() == [0, 0, 0, 0]
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_a_rows_expert_output_does_not_depend_on_its_batch(dtype):
+    """Bit for bit: a row alone (fifteen padding rows of zeros that route
+    nowhere near it) and the same row among fifteen others that touch every
+    expert — its k experts are summed in ascending order with exact zeros
+    between them (the harness's batch-invariance probe)."""
+    cfg, params, xf, topv, topi = _touched_case("all-touched", dtype, None)
+    _, among, _ = _both_forms(cfg, params, 1, xf, topv, topi)
+    alone_x = jnp.zeros_like(xf).at[7].set(xf[7])
+    alone_i = jnp.broadcast_to(topi[7], topi.shape)
+    _, alone, sizes = _both_forms(cfg, params, 1, alone_x, topv, alone_i)
+    assert int(jnp.sum(sizes > 0)) == cfg.num_experts_per_tok
+    assert np.asarray(alone[7]).tobytes() == np.asarray(among[7]).tobytes()
+
+
+def test_moe_mlp_reads_a_leading_dense_models_expert_stack_in_place():
+    """tiny-kanana: layer 1 is index 0 of the expert stack. `_moe_mlp`
+    under a backend that may hold the kernel takes it at two rows (the
+    rule) and gives what the einsum gives, routing and all."""
+    from vllm_production_stack_tpu.models.registry import resolve_model_config
+
+    cfg = resolve_model_config("tiny-kanana")
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 1, cfg.hidden_size))
+    assert llama.moe_experts_form(cfg, 2, kernel=True) == "touched_kernel"
+    for i in (1, 2):
+        lp = llama._layer_params(cfg, params, i)
+        assert lp["moe_stacked"][1] == i - 1
+        want, r_want = llama._moe_mlp(cfg, lp["moe"], x, lp["moe_stacked"])
+        got, r_got = llama._moe_mlp(
+            cfg, lp["moe"], x, lp["moe_stacked"], "pallas_interpret")
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), atol=1e-5, rtol=1e-5)
+        assert int(r_got["touched"]) == int(r_want["touched"])
+        np.testing.assert_array_equal(r_got["experts"], r_want["experts"])
+
+
+@pytest.mark.parametrize("model,tokens,quantization,want", [
+    # the benchmark cells' one decode bucket (16 rows)
+    ("kanana-2-30b-a3b-8l", 16, None, "touched_kernel"),  # 0.54
+    ("laguna-s-2.1-ep4-9l", 16, None, "touched_kernel"),  # 0.47 (of 256)
+    ("olmoe-1b-7b", 16, "int8", "all_experts"),  # 0.88
+    ("tiny-mixtral", 16, None, "all_experts"),  # 1.00
+    ("olmoe-1b-7b", 512, "int8", "all_experts"),  # a prefill chunk
+    ("kanana-2-30b-a3b-8l", 64, None, "all_experts"),  # 0.95
+    ("kanana-2-30b-a3b-8l", 32, None, "touched_kernel"),  # 0.79
+    ("kanana-2-30b-a3b-8l", 2048, None, "grouped"),
+    ("olmoe-1b-7b", 8, "int8", "touched_kernel"),  # 0.66: int8 leaves too
+])
+def test_the_shape_rule_picks_the_expert_form(model, tokens, quantization,
+                                              want):
+    """One family, three forms, from (N, k, published E) alone."""
+    from vllm_production_stack_tpu.models.registry import resolve_model_config
+
+    cfg = resolve_model_config(model, quantization=quantization)
+    assert llama.moe_experts_form(cfg, tokens, kernel=True) == want
+    # a program that may hold no Pallas kernel keeps today's two forms
+    assert llama.moe_experts_form(cfg, tokens, kernel=False) == (
+        "grouped" if tokens > 512 else "all_experts")
+
+
+@pytest.mark.parametrize("mesh_size,attention_backend,want", [
+    (1, "pallas_interpret", "pallas_interpret"),
+    (1, "xla", "xla"),  # off the TPU nothing but interpret mode runs it
+    (2, "pallas_interpret", "xla"),  # any mesh keeps the einsum
+])
+def test_the_runner_resolves_the_einsum_under_a_mesh(mesh_size,
+                                                     attention_backend, want):
+    from types import SimpleNamespace
+
+    from vllm_production_stack_tpu.engine.model_runner import ModelRunner
+
+    stub = SimpleNamespace(
+        config=SimpleNamespace(
+            model=olmoe_cfg(), attention_backend=attention_backend),
+        mesh=SimpleNamespace(size=mesh_size))
+    assert ModelRunner._resolve_moe_backend(stub) == want
+
+
+@pytest.mark.parametrize("model", ["tiny-llama", "tiny-olmoe", "tiny-mixtral"])
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_programs_the_rule_leaves_alone_are_the_same_text(model, program):
+    """A dense model, and an MoE model whose 16 rows touch nearly every
+    expert, lower to the SAME StableHLO whether or not their programs may
+    hold the kernel: the rule, not the backend, decides."""
+    from vllm_production_stack_tpu.models.registry import resolve_model_config
+
+    cfg = resolve_model_config(model)
+    params = jax.eval_shape(
+        lambda k: llama.init_params(cfg, k), jax.random.PRNGKey(0))
+    kv = jax.eval_shape(lambda: llama.init_kv_cache(cfg, 16, 8))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    rows = 16
+
+    def decode(moe_backend, params, kv, staged, tokens, tables):
+        return llama.decode_window_step(
+            cfg, params, tokens, tokens, kv, tables, staged, jnp.int32(0),
+            tokens, moe_stats=[] if cfg.num_experts else None,
+            moe_backend=moe_backend)
+
+    def prefill(moe_backend, params, kv, tokens, tables, lens):
+        return llama.forward(
+            cfg, params, tokens, tokens, kv, tables,
+            jnp.zeros((rows * 32,), jnp.int32), lens,
+            moe_stats=[] if cfg.num_experts else None,
+            moe_backend=moe_backend)
+
+    if program == "decode":
+        staged = jax.eval_shape(lambda: llama.init_staged_kv(cfg, 4, rows))
+        args = (params, kv, staged, i32(rows), i32(rows, 4))
+        fn = decode
+    else:
+        args = (params, kv, i32(rows, 32), i32(rows, 8), i32(rows))
+        fn = prefill
+    texts = {
+        b: jax.jit(fn, static_argnums=0).lower(b, *args).as_text()
+        for b in ("xla", "pallas")}
+    assert texts["xla"] == texts["pallas"]
+    assert "moe_decode_experts" not in texts["pallas"]
+
+
 def test_olmoe_checkpoint_roundtrip_and_config_parse(tmp_path):
     """save_pretrained -> config.json parse -> our loader -> HF's logits."""
     import json

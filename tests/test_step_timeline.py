@@ -208,6 +208,54 @@ def test_prefill_chained_share_from_its_data_file(start, end, want):
         FlightRecorder().timing())
 
 
+@pytest.mark.parametrize("start, end, want", [
+    # decode programs built with the touched-experts kernel: what they read
+    # is what their rows touched
+    (dict(moe_decode_slots_n=896, moe_decode_read_n=200),
+     dict(moe_decode_slots_n=896 * 11, moe_decode_read_n=200 + 2240), 25.0),
+    # built with the all-experts einsum: every slot
+    (dict(moe_decode_slots_n=1024, moe_decode_read_n=1024),
+     dict(moe_decode_slots_n=5120, moe_decode_read_n=5120), 100.0),
+    # a parent that lacks the counter, and a dense model that serves
+    # neither: the metric is left out, not raised
+    (dict(moe_decode_slots_n=10), dict(moe_decode_slots_n=50), None),
+    (dict(), dict(), None),
+])
+def test_moe_read_share_from_its_data_file(start, end, want):
+    """The benchmark's `moe_read_share` is a data file for a reader the
+    benchmark already has: the experts whose bytes the decode programs'
+    expert computation read, of layer-steps x held experts, over the
+    window (ISSUE 36)."""
+    import importlib.util
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    chip = os.path.join(root, "benchmarks", "chip")
+    with open(os.path.join(chip, "metrics", "moe_read_share.json")) as f:
+        spec = json.load(f)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry = bench["per_layer"][-1]
+    assert entry == {
+        "name": "moe_read_share", "unit": "%", "better": "lower",
+        "source": "program_counter", "layer": "kernels",
+        "moves": "out_tok_per_s", "workloads": [
+            "olmoe-1b-7b-int8.chat-saturated",
+            "kanana-2-30b-a3b-bf16-8l.longdoc-saturated",
+            "laguna-s21-bf16-ep4-9l.longdoc-saturated"]}
+    assert set(entry["workloads"]) <= {w["name"] for w in bench["workloads"]}
+    mod = importlib.util.spec_from_file_location(
+        "timing_ratio_opt",
+        os.path.join(chip, "readers", spec["reader"] + ".py"))
+    reader = importlib.util.module_from_spec(mod)
+    mod.loader.exec_module(reader)
+    ctx = {"start": {"timing": {"engine": start}},
+           "end": {"timing": {"engine": end}}}
+    got = reader.read(ctx, **spec["args"])
+    assert got == (pytest.approx(want) if want is not None else None)
+
+
 def test_rolled_back_dispatch_does_not_empty_the_queue():
     rec = FlightRecorder()
     a = rec.dispatch("decode", rows=1, tokens=4)

@@ -281,7 +281,7 @@ class StepHandle:
                 if self.lp_arrays is not None else None
             )
             if self.moe is not None:
-                _, kind, pairs, slots = self.moe
+                _, kind, pairs, slots, form = self.moe
                 clock = self.runner.clock
                 # (touched,), or (touched, held pairs) from a program that
                 # holds a share of the experts: _moe_device_counts
@@ -289,6 +289,12 @@ class StepHandle:
                 clock.count(f"moe_{kind}_touched_n", int(counts[0]))
                 clock.count(f"moe_{kind}_pairs_n", pairs)
                 clock.count(f"moe_{kind}_slots_n", slots)
+                # experts whose bytes the program's expert computation
+                # read: every slot from the all-experts einsum, the touched
+                # ones from the kernel and the grouped matmul
+                clock.count(
+                    f"moe_{kind}_read_n",
+                    slots if form == "all_experts" else int(counts[0]))
                 clock.count(
                     f"moe_{kind}_held_pairs_n",
                     int(counts[1]) if counts.size > 1 else pairs)
@@ -463,6 +469,7 @@ class ModelRunner:
             )
         self._attention_backend = self._resolve_attention_backend()
         self._prefill_backend = self._resolve_prefill_backend()
+        self._moe_backend = self._resolve_moe_backend()
         self._hoist_budget = self._compute_hoist_budget()
         self._step_fn = (
             self._build_sp_step_fn() if self._sp > 1 else self._build_step_fn()
@@ -727,6 +734,27 @@ class ModelRunner:
             )
         return backend
 
+    def _resolve_moe_backend(self) -> str:
+        """May this engine's programs hold the touched-experts kernel
+        (models/llama.py:_moe_mlp, which then chooses per program from its
+        static shapes)? Not an option: "pallas" on one TPU device, "xla"
+        under any mesh (the kernel has no partition rule and no shard_map
+        wrapper) and off the TPU — where an engine asked for its attention
+        kernels in interpret mode (CPU tests) this one is interpreted too."""
+        if not self.config.model.num_experts or self.mesh.size > 1:
+            return "xla"
+        if jax.devices()[0].platform == "tpu":
+            return "pallas"
+        if self.config.attention_backend == "pallas_interpret":
+            return "pallas_interpret"
+        return "xla"
+
+    def moe_experts_form(self, tokens: int) -> str:
+        """The form a program of `tokens` tokens computes its expert layers
+        in: "touched_kernel" | "all_experts" | "grouped"."""
+        return llama.moe_experts_form(
+            self.config.model, tokens, kernel=self._moe_backend != "xla")
+
     @property
     def prefill_packed(self) -> bool:
         """Does a prefill dispatch lay its rows' tokens end to end on one
@@ -783,6 +811,15 @@ class ModelRunner:
                 "resolved": self._prefill_backend,
             },
         }
+        if self.config.model.num_experts:
+            # the expert computation a decode program holds follows from
+            # its row bucket (`resolved`: the widest, what a full batch runs)
+            buckets = self.config.scheduler.decode_buckets
+            report["moe"] = {"decode": {
+                "resolved": self.moe_experts_form(max(buckets)),
+                "by_bucket": {
+                    str(n): self.moe_experts_form(n) for n in buckets},
+            }}
         return report
 
     def _compute_hoist_budget(self) -> int:
@@ -898,6 +935,7 @@ class ModelRunner:
                 mesh=self.mesh,
                 moe_stats=moe_stats,
                 pack=pack,
+                moe_backend=self._moe_backend,
             )
             moe_out = (
                 (_moe_device_counts(cfg, moe_stats),) if moe_stats else ()
@@ -1115,6 +1153,7 @@ class ModelRunner:
                     backend=self._attention_backend,
                     lora=lora_params, lora_idx=lora_idx, hists=hists,
                     mesh=self.mesh, moe_stats=moe_stats,
+                    moe_backend=self._moe_backend,
                 )
                 logits = llama.compute_logits(cfg, params, hidden)
                 with jax.named_scope("sample"):
@@ -1256,6 +1295,7 @@ class ModelRunner:
                     "start_off": start_off,
                     "chunk_lens": chunk_lens,
                 },
+                moe_backend=self._moe_backend,
             )
             logits = llama.compute_logits(
                 cfg, params, hidden.reshape(-1, hidden.shape[-1])
@@ -1978,13 +2018,14 @@ class ModelRunner:
                     ) -> tuple:
         """StepHandle.moe for a program that ran `tokens` tokens (padded
         rows and positions included: they are computed) through every layer
-        `steps` times: the pairs and the (layer-step, expert) slots follow
-        from shapes, only the touched experts come from the device."""
+        `steps` times: the pairs, the (layer-step, expert) slots and the
+        form of the expert computation follow from shapes, only the touched
+        experts come from the device."""
         cfg = self.config.model
         # expert layers only: leading dense layers route nothing
         layer_steps = cfg.num_moe_layers * steps
         return (touched, kind, tokens * cfg.num_experts_per_tok * layer_steps,
-                layer_steps * cfg.held_experts)
+                layer_steps * cfg.held_experts, self.moe_experts_form(tokens))
 
     def _grammar_device_tables(self, grammars: list, gkey: tuple):
         """Replicated device copies of the batch's automaton tables, padded

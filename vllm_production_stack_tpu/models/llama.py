@@ -41,6 +41,7 @@ from ..ops.mla_attention_pallas import (
     mla_decode_attention,
     mla_prefill_attention,
 )
+from ..ops.moe_experts_pallas import moe_decode_experts
 from ..ops.paged_attention_pallas import (
     paged_decode_attention,
     paged_decode_attention_sharded,
@@ -468,6 +469,7 @@ def _layer_body(
     #   is never computed)
     spec: LayerSpec | None = None,  # this layer's entry of the per-layer
     #   list (its head count and rope); None: layer 0's
+    moe_backend: str = "xla",  # see _moe_mlp
 ) -> jax.Array:
     """The Llama layer math shared by every execution mode — prefill and the
     fused decode window differ ONLY in how attention consumes/stores KV, so
@@ -495,7 +497,7 @@ def _layer_body(
     ap = lp["attn"]
     if cfg.mla:
         x = _mla_attention(cfg, ap, lp["input_norm"], x, positions, attend)
-        return _mlp_block(cfg, lp, res + x, proj, moe_stats)
+        return _mlp_block(cfg, lp, res + x, proj, moe_stats, moe_backend)
     with jax.named_scope("attn_qkv"):
         if not cfg.post_norms_only:
             x = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps,
@@ -546,7 +548,7 @@ def _layer_body(
                                 cfg.rms_norm_eps, cfg.rms_norm_add_one,
                      scale_f32=cfg.norm_scale_f32)
         x = res + attn_out
-    return _mlp_block(cfg, lp, x, proj, moe_stats)
+    return _mlp_block(cfg, lp, x, proj, moe_stats, moe_backend)
 
 
 def _mla_attention(cfg: ModelConfig, ap: dict, norm: jax.Array,
@@ -594,7 +596,7 @@ def _mla_attention(cfg: ModelConfig, ap: dict, norm: jax.Array,
 
 
 def _mlp_block(cfg: ModelConfig, lp: dict, x: jax.Array, proj,
-               moe_stats: list | None) -> jax.Array:
+               moe_stats: list | None, moe_backend: str = "xla") -> jax.Array:
     """The second half of a layer: norm, dense SwiGLU or experts, residual."""
     res = x
     with jax.named_scope("mlp"):
@@ -603,7 +605,7 @@ def _mlp_block(cfg: ModelConfig, lp: dict, x: jax.Array, proj,
                          cfg.rms_norm_add_one)
         if "moe" in lp:
             mlp_out, routing = _moe_mlp(
-                cfg, lp["moe"], x, lp.get("moe_stacked"))
+                cfg, lp["moe"], x, lp.get("moe_stacked"), moe_backend)
             if moe_stats is not None:
                 moe_stats.append(routing)
             return res + mlp_out
@@ -619,22 +621,57 @@ def _mlp_block(cfg: ModelConfig, lp: dict, x: jax.Array, proj,
         return res + mlp_out
 
 
-# `_moe_mlp` evaluates every expert on every token up to this many tokens,
-# and follows the routed pairs above it. Measured on a v5e chip, one OLMoE
-# layer (64 int8 experts of 2048 x 1024, 8 per token, stacked over 16
-# layers as served), ms a layer (benchmarks/sweep_moe.py, PERF.md §6 PR 26):
+# The three forms of `_moe_mlp`'s expert computation and the two constants
+# that choose between them, measured on a v5e chip (benchmarks/sweep_moe.py;
+# ms a layer, the expert stacks as served: every layer's leaves stacked, the
+# layers run in turn).
+#
+# Few tokens (`--decode`, PERF.md §6 PR 36): the fused touched-experts kernel
+# against the all-experts einsum and `ragged_dot` with a GIVEN number of
+# experts touched, and the share of 819 GB/s the kernel reaches on the
+# touched experts' bytes:
+#   experts (stack), rows   touched    kernel (share)   einsum   ragged_dot
+#   128 x 2048 x 768 bf16    13 (10%)   0.203 (74%)      1.633    0.329
+#    (Kanana-2, 7 layers),   32 (25%)   0.437 (84%)      1.630    0.691
+#    16 rows (all 128 at     64 (50%)   0.839 (88%)      1.628    1.234
+#    64 rows)                96 (75%)   1.231 (90%)      1.630    1.808
+#                           128 (all)   1.638 (90%)      1.633    2.781
+#   64 held x 3072 x 1024     6 (10%)   0.189 (73%)      1.692    0.327
+#    bf16 (Laguna, 8         16 (25%)   0.436 (85%)      1.689    0.586
+#    layers), 16 rows        32 (50%)   0.835 (88%)      1.692    1.007
+#                            48 (75%)   1.231 (90%)      1.691    1.371
+#                            64 (all)   1.628 (91%)      1.688    1.792
+#   64 x 2048 x 1024 int8     6 (10%)   0.081 (57%)      0.550    0.170
+#    (OLMoE, 16 layers),     16 (25%)   0.162 (76%)      0.551    0.329
+#    16 rows                 32 (50%)   0.293 (84%)      0.548    0.582
+#                            48 (75%)   0.423 (87%)      0.549    0.834
+#                            64 (all)   0.553 (89%)      0.550    1.084
+# (32 and 64 rows read the same to 2% for kernel and einsum: at these sizes
+# the rows are free.)
+# The kernel's time follows the touched bytes at 84-91% of HBM bandwidth
+# from a quarter touched upwards (an empty grid slot costs ~0.5 us, the
+# first expert's copy is not overlapped), int8 leaves widened in VMEM
+# included; the einsum streams EVERY expert at 87-90%, so the two tie where
+# every expert is touched and the kernel wins by the untouched share below.
+# `_TOUCHED_KERNEL_MAX_SHARE` is set under that tie, where a balanced router
+# (expected_touched_share) leaves at least a fifth of the expert bytes
+# unread: 16 rows of 128 x top-6 (0.54), 256 x top-10 (0.47), 256 x top-8
+# (0.40) take the kernel; 16 rows of 64 x top-8 (0.88) keep the einsum —
+# there a saving would be the routing's (seeded routers that agree), not
+# the architecture's.
+#
+# Many tokens (PERF.md §6 PR 26; one OLMoE layer, 64 int8 experts, 8 per
+# token):
 #   tokens   all-experts einsum   ragged_dot   (bound: touched bytes | flops)
 #       16         0.57              1.00           0.44 | 0.01
 #      512         2.72              3.26           0.49 | 0.26
 #     1024         5.67              3.95           0.49 | 0.52
 #     2048        10.44              5.54           0.49 | 1.05
 #     8192     (3 GiB of temps)     18.16           0.49 | 4.19
-# At decode shapes a balanced router touches nearly every expert anyway (57
-# of 64 for 16 independent rows) and the einsum streams the int8 experts at
-# 87% of HBM bandwidth;
-# XLA's grouped kernel pays for groups of two rows. From ~1k tokens the
-# einsum's E/k-fold FLOPs lose.
+# XLA's grouped kernel pays for groups of two rows; from ~1k tokens the
+# einsum's E/k-fold FLOPs lose: `_ALL_EXPERTS_MAX_TOKENS`.
 _ALL_EXPERTS_MAX_TOKENS = 512
+_TOUCHED_KERNEL_MAX_SHARE = 0.8
 
 
 def _expert_mm(spec: str, a: jax.Array, w) -> jax.Array:
@@ -728,11 +765,15 @@ def _softmax_top_k(cfg: ModelConfig, logits: jax.Array):
     return topv, topi
 
 
+def _combine_weights(topv, chosen):
+    """(N, E) combine weights: topv scattered back onto the expert axis."""
+    with jax.named_scope("moe_dispatch"):
+        return jnp.sum(chosen * topv[..., None], axis=-2)
+
+
 def _experts_all(cfg, mp, xf, topv, chosen):
     """Every expert on every token, weighted by the routing mass or 0."""
-    with jax.named_scope("moe_dispatch"):
-        # (N, E) combine weights: topv scattered back onto the expert axis
-        w = jnp.sum(chosen * topv[..., None], axis=-2)
+    w = _combine_weights(topv, chosen)
     with jax.named_scope("moe_experts"):
         inner = _activation(cfg)(
             _expert_mm("nh,ehi->nei", xf, mp["gate"])
@@ -774,30 +815,86 @@ def _experts_grouped(cfg, stacked, layer, xf, topv, topi, group_sizes):
         return jnp.einsum("nkh,nk->nh", out, topv.astype(xf.dtype))
 
 
+def expected_touched_share(cfg: ModelConfig, n: int) -> float:
+    """The share of a layer's experts that `n` tokens touch under a
+    perfectly balanced router, 1 - (1 - k/E)^n over the PUBLISHED expert
+    count (a program that holds a share of the experts touches the same
+    share of those): what the static shapes say of how sparse the expert
+    reads of a program can be."""
+    return 1.0 - (1.0 - cfg.num_experts_per_tok / cfg.num_experts) ** n
+
+
+def moe_experts_form(cfg: ModelConfig, n: int, *, kernel: bool) -> str:
+    """How a program of `n` tokens computes an expert layer (_moe_mlp), from
+    static shapes alone: "touched_kernel" | "all_experts" | "grouped".
+    `kernel`: the program may hold the Pallas kernel (`moe_backend` is not
+    "xla": one TPU device, no mesh)."""
+    if n > _ALL_EXPERTS_MAX_TOKENS:
+        return "grouped"
+    if kernel and expected_touched_share(cfg, n) < _TOUCHED_KERNEL_MAX_SHARE:
+        return "touched_kernel"
+    return "all_experts"
+
+
+def _experts_touched(cfg, stacked, layer, xf, topv, chosen, group_sizes,
+                     interpret):
+    """The experts that got a pair, and only those, through the fused
+    Pallas kernel (ops/moe_experts_pallas.py) over layer `layer` of the
+    `stacked` expert leaves."""
+    n, k = topv.shape
+    w = _combine_weights(topv, chosen)
+    with jax.named_scope("moe_experts"):
+        return moe_decode_experts(
+            xf, w, group_sizes, stacked["gate"], stacked["up"],
+            stacked["down"], layer=layer,
+            slots=min(cfg.held_experts, n * k), act=_activation(cfg),
+            interpret=interpret)
+
+
 def _moe_mlp(cfg: ModelConfig, mp: dict, x: jax.Array,
              stacked: tuple[dict, int] | None = None,
+             backend: str = "xla",
              ) -> tuple[jax.Array, dict]:
     """Sparse-MoE MLP, HF routing semantics (Mixtral, Qwen3-MoE, OLMoE):
     softmax over ALL expert logits in float32, take top-k, and renormalize
     the selected weights to sum to 1 only under `norm_topk_prob`.
 
-    The expert computation is chosen from the static token count (table
-    above; no flag). Few tokens (a decode step): every expert evaluates
-    every token and the routing weights zero the rest — bound by the expert
-    bytes, which such a step touches nearly all of anyway. Many tokens (a
-    prefill program): the work follows the routed pairs (`_experts_grouped`)
-    — B*T*k rows whatever the routing, every pair computed (no capacity, no
-    dropped token), an expert without rows costs nothing; the all-experts
-    form would spend num_experts/top_k times the FLOPs and hold three
-    (B*T, E, inter) intermediates, 3 GiB at 64 experts and 16 x 512 tokens.
-    Either way shapes are static, int8 experts are read as int8, and under
-    expert parallelism the E axis of the expert leaves stays sharded over ep
+    The expert computation is ONE family in three forms, chosen by
+    `moe_experts_form` from the static token count N, the top-k and the
+    published expert count (table above; no flag, no model name):
+
+    - "touched_kernel" — few tokens of a many-expert model, where the N*k
+      pairs cannot cover the experts (the expected touched share under a
+      balanced router, 1 - (1 - k/E)^N, lies under
+      `_TOUCHED_KERNEL_MAX_SHARE`: 16 rows of 128 x top-6 or 256 x top-10):
+      one fused Pallas kernel walks the experts that got a pair and reads
+      only their bytes (`_experts_touched`). Only where the program may
+      hold a Pallas kernel — `backend` "pallas" | "pallas_interpret", which
+      the runner resolves to on one TPU device (under an ep / tp mesh the
+      einsum stays: a pallas_call has no GSPMD partition rule, and the
+      kernel is not wrapped in a shard_map as paged_decode_attention_sharded
+      is). int8 leaves take it too: the kernel widens a tile in VMEM and
+      the sweep's int8 arm wins below the same crossover.
+    - "all_experts" — few tokens otherwise (a decode step of a 64 x top-8
+      model touches 88% of its experts anyway): every expert evaluates
+      every token and the routing weights zero the rest, bound by the
+      expert bytes (`_experts_all`).
+    - "grouped" — more than `_ALL_EXPERTS_MAX_TOKENS` tokens (a prefill
+      program): the work follows the routed pairs (`_experts_grouped`) —
+      B*T*k rows whatever the routing, an expert without rows costs
+      nothing; the all-experts form would spend num_experts/top_k times
+      the FLOPs and hold three (B*T, E, inter) intermediates, 3 GiB at 64
+      experts and 16 x 512 tokens.
+
+    Every form computes every routed pair (no capacity, no dropped token)
+    from static shapes and reads int8 experts as int8; under expert
+    parallelism the E axis of the expert leaves stays sharded over ep
     (parallel/sharding.py; GSPMD partitions the einsum or the grouped
     matmul and psums the combine).
 
     `mp` is one layer's leaves; `stacked` = (all layers' leaves, this
-    layer's index) is what the grouped matmul reads in place (_grouped_mm);
-    without it `mp` stands as a stack of one.
+    layer's index) is what the grouped matmul and the kernel read in place
+    (_grouped_mm); without it `mp` stands as a stack of one.
 
     A program that holds a share of the experts (ModelConfig
     .num_experts_held) routes over all `num_experts` and computes the pairs
@@ -816,12 +913,17 @@ def _moe_mlp(cfg: ModelConfig, mp: dict, x: jax.Array,
     xf = x.reshape(n, h)
     topv, topi, chosen, group_sizes = _moe_route(
         cfg, mp["router"], xf, mp.get("bias"))
-    if n <= _ALL_EXPERTS_MAX_TOKENS:
+    form = moe_experts_form(cfg, n, kernel=backend != "xla")
+    if form == "all_experts":
         y = _experts_all(cfg, mp, xf, topv, chosen)
     else:
         if stacked is None:
             stacked = (jax.tree.map(lambda a: a[None], mp), 0)
-        y = _experts_grouped(cfg, *stacked, xf, topv, topi, group_sizes)
+        if form == "grouped":
+            y = _experts_grouped(cfg, *stacked, xf, topv, topi, group_sizes)
+        else:
+            y = _experts_touched(cfg, *stacked, xf, topv, chosen,
+                                 group_sizes, backend == "pallas_interpret")
     if "shared" in mp:
         # the shared expert: one ungated SwiGLU on every token
         with jax.named_scope("moe_shared"):
@@ -934,6 +1036,7 @@ def _layer(
     rows: dict | None = None,  # _row_view(...) of a packed chunk: x is
     #   (N, 1, h) then
     spec: LayerSpec | None = None,  # the layer's entry of the per-layer list
+    moe_backend: str = "xla",  # see _moe_mlp
 ) -> tuple[jax.Array, jax.Array]:
     b, t = x.shape[0], x.shape[1]
     hd, nkv = cfg.head_dim, cfg.num_kv_heads
@@ -1027,7 +1130,7 @@ def _layer(
             return out.reshape(-1, *out.shape[2:])[rows["to_packed"]][:, None]
 
     x = _layer_body(cfg, lp, x, positions, attend, lora, lora_idx,
-                    moe_stats, spec)
+                    moe_stats, spec, moe_backend)
     return x, kv_layer
 
 
@@ -1059,6 +1162,8 @@ def forward(
     #   layer's routing (see _moe_mlp)
     pack: dict | None = None,  # pack_index(...) — the chunk is PACKED: the
     #   rows' tokens end to end on one axis of N positions
+    moe_backend: str = "xla",  # may an MoE layer's experts run the Pallas
+    #   touched-experts kernel where the shapes choose it (see _moe_mlp)
 ) -> tuple[jax.Array, jax.Array]:
     """One model step over a token batch. Prefill is (B=1, T=chunk); decode is
     (B=batch, T=1). Returns (hidden (B,T,h), updated kv_caches).
@@ -1122,7 +1227,7 @@ def forward(
             cfg, lp, kv_caches[i], x, positions, block_tables, slot_mapping,
             masks.get(spec.window),
             _lora_layer_slice(lora, i), lora_idx, write_blocks,
-            pallas_prefill, moe_stats, rows, spec,
+            pallas_prefill, moe_stats, rows, spec, moe_backend,
         )
         new_kv.append(layer_kv)
     if moe_stats is not None:
@@ -1176,6 +1281,7 @@ def decode_window_step(
     hists: tuple | None = None,  # per-layer pre-gathered (hist_k, hist_v)
     mesh=None,  # required for the pallas backend on a >1-device mesh
     moe_stats: list | None = None,  # as in forward
+    moe_backend: str = "xla",  # as in forward
 ) -> tuple[jax.Array, jax.Array]:
     """One decode iteration inside a fused window: reads the pool, writes this
     token's K/V into `staged` (not the pool — the pool stays loop-invariant so
@@ -1287,6 +1393,7 @@ def decode_window_step(
             cfg, lp, x, positions[:, None],
             attend_latent if cfg.mla else attend,
             _lora_layer_slice(lora, i), lora_idx, moe_stats, spec,
+            moe_backend,
         )
     if moe_stats is not None:
         _tap_routing(token_ids, positions, moe_stats[n_stats:])
