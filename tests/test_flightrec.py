@@ -94,6 +94,321 @@ def test_discard_and_fault_clear_the_cursor():
 
 # -- Heartbeat / ThreadRegistry ----------------------------------------------
 
+# -- dry gaps: the newest enqueued program, polled at the phase boundaries ---
+
+class FakeClock:
+    """Stands in for the `time` module inside flightrec: the test moves it."""
+
+    def __init__(self):
+        self.ns = 1_000_000_000
+
+    def monotonic_ns(self):
+        return self.ns
+
+    def monotonic(self):
+        return self.ns * 1e-9
+
+    def time(self):
+        return 1.7e9 + self.ns * 1e-9
+
+    def time_ns(self):
+        return int(1.7e18) + self.ns
+
+    def tick(self, ms: float) -> None:
+        self.ns += int(ms * 1e6)
+
+
+class FakeDevice:
+    """A program that is complete from `done_at` (ns) on, and counts how
+    often it was asked."""
+
+    def __init__(self, clock, done_at=None):
+        self.clock, self.done_at, self.asked = clock, done_at, 0
+
+    def ready(self):
+        self.asked += 1
+        return self.done_at is not None and self.clock.ns >= self.done_at
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    from vllm_production_stack_tpu.engine import flightrec
+
+    fake = FakeClock()
+    monkeypatch.setattr(flightrec, "time", fake)
+    return fake
+
+
+def _phase(rec, clock, phase, ms):
+    with rec.span(phase):
+        clock.tick(ms)
+
+
+def _dry(rec):
+    t = rec.timing()
+    return {k: v for k, v in t.items() if k.startswith("dry_")}
+
+
+def test_dry_gap_brackets_the_true_instant_and_splits_by_phase(clock):
+    from vllm_production_stack_tpu.engine.flightrec import PHASES
+
+    rec = FlightRecorder()
+    c = rec.dispatch("prefill", rows=1, tokens=8)
+    dev = FakeDevice(clock)
+    d = rec.dispatch("decode", rows=4, tokens=32, ready=dev.ready)
+    t_enq = clock.ns
+    true_end = t_enq + int(7.3e6)  # inside `sched`, between two polls
+    dev.done_at = true_end
+    _phase(rec, clock, "post", 3.0)  # still running: polls say no
+    _phase(rec, clock, "admit", 1.0)
+    _phase(rec, clock, "sched", 5.0)  # completes 3.3 ms into it
+    _phase(rec, clock, "build", 4.0)  # the first boundary after: seen here
+    with rec.span("build"):
+        with rec.span("h2d"):
+            clock.tick(2.0)
+        with rec.span("enqueue"):
+            clock.tick(1.0)
+    e = rec.dispatch("prefill", rows=1, tokens=8, ready=lambda: False)
+    t_close = clock.ns
+    t = rec.timing()
+    true_dry = (t_close - true_end) * 1e-9
+    assert t["dry_n"] == 1 == t["dry_after_decode_n"]
+    assert t["dry_after_prefill_n"] == 0 == t["dry_after_verify_n"]
+    assert t["dry_s"] <= true_dry <= t["dry_s"] + t["dry_slack_s"]
+    # seen at the exit of `sched` (9 ms in): 4 + 2 + 1 ms of gap, bracket
+    # back to the poll at its entry (4 ms in)
+    assert t["dry_s"] == pytest.approx(7e-3)
+    assert t["dry_slack_s"] == pytest.approx(5e-3)
+    assert t["dry_build_s"] == pytest.approx(4e-3)
+    assert t["dry_h2d_s"] == pytest.approx(2e-3)
+    assert t["dry_enqueue_s"] == pytest.approx(1e-3)
+    assert sum(t["dry_" + p + "_s"] for p in PHASES) == pytest.approx(
+        t["dry_s"], rel=1e-12)
+    # the host never emptied the queue itself: the other account is still
+    # the one gap since `start`, which ended at c
+    assert t["starved_n"] == 1 == t["starved_after_start_n"]
+    last = rec.snapshot()[-1]
+    assert last["seq"] == e and last["gap"] == {
+        "seen": "poll", "after": "decode", "ms": 7.0, "slack_ms": 5.0,
+        "phases_ms": {"build": 4.0, "h2d": 2.0, "enqueue": 1.0}}
+    first = next(r for r in rec.snapshot() if r["seq"] == c)
+    assert first["gap"]["seen"] == "resolve"
+    assert first["gap"]["after"] == "start" and "slack_ms" not in first["gap"]
+    assert d == c + 1
+
+
+def test_polls_stop_after_the_first_true(clock):
+    rec = FlightRecorder()
+    dev = FakeDevice(clock, done_at=clock.ns + int(2e6))
+    rec.dispatch("decode", rows=1, tokens=8, ready=dev.ready)
+    _phase(rec, clock, "post", 1.0)  # two polls: no, no
+    _phase(rec, clock, "admit", 2.0)  # no at the entry, yes at the exit
+    asked = dev.asked
+    assert asked == 4 and rec.timing()["dry_poll_n"] == 4
+    for phase in ("sched", "build", "h2d", "enqueue"):
+        _phase(rec, clock, phase, 1.0)
+    rec.poll()
+    assert dev.asked == asked  # nobody asks a program that was seen ready
+    assert rec.timing()["dry_poll_n"] == 4
+    assert rec.timing()["dry_n"] == 0  # the gap is open, not yet counted
+    rec.dispatch("decode", rows=1, tokens=8)
+    assert rec.timing()["dry_n"] == 1
+    assert rec.timing()["dry_s"] == pytest.approx(4e-3)
+
+
+def test_nothing_watched_nothing_polled(clock):
+    rec = FlightRecorder()
+    _phase(rec, clock, "sched", 1.0)
+    rec.poll()
+    a = rec.dispatch("decode", rows=1, tokens=8)  # no `ready` handed over
+    _phase(rec, clock, "post", 1.0)
+    rec.poll()
+    rec.resolve(a)
+    t = rec.timing()
+    assert t["dry_poll_n"] == 0 == t["dry_poll_s"]
+    assert all(v == 0 for v in _dry(rec).values())
+
+
+def test_gap_seen_only_by_the_dispatch_counts_with_length_zero(clock):
+    rec = FlightRecorder()
+    dev = FakeDevice(clock)
+    rec.dispatch("decode", rows=1, tokens=8, ready=dev.ready)
+    with rec.span("build"):
+        clock.tick(6.0)
+        dev.done_at = clock.ns - int(1e6)  # completed 1 ms before the exit
+        # ... but after this phase's last poll: the exit's is skipped here
+        rec._watch, keep = None, rec._watch
+    rec._watch = keep
+    clock.tick(0.5)
+    rec.dispatch("prefill", rows=1, tokens=8)
+    t = rec.timing()
+    assert t["dry_n"] == 1 and t["dry_s"] == 0.0
+    assert t["dry_slack_s"] == pytest.approx(6.5e-3)  # back to the entry
+    gap = rec.snapshot()[-1]["gap"]
+    assert gap["seen"] == "poll" and gap["ms"] == 0.0
+    assert gap["slack_ms"] == 6.5 and gap["phases_ms"] == {}
+
+
+def test_dry_gap_hands_over_to_a_starvation_gap_at_the_resolve(clock):
+    rec = FlightRecorder()
+    dev = FakeDevice(clock, done_at=clock.ns + int(1.5e6))
+    a = rec.dispatch("decode", rows=1, tokens=8, ready=dev.ready)
+    _phase(rec, clock, "sched", 2.0)  # nothing to enqueue; a seen complete
+    with rec.span("post", a, "decode"):
+        with rec.span("sync", a, "decode"):
+            clock.tick(0.25)
+        rec.resolve(a)  # the host empties the queue itself
+        clock.tick(1.0)
+    _phase(rec, clock, "idle", 10.0)
+    _phase(rec, clock, "build", 3.0)
+    rec.dispatch("prefill", rows=1, tokens=8)
+    t = rec.timing()
+    # the dry gap: exit of `sched` -> resolve; the starvation gap from there.
+    # One stretch of an empty device, one late dispatch: `starved_n` has it
+    assert t["dry_n"] == 0 and t["dry_s"] == pytest.approx(0.25e-3)
+    assert t["dry_slack_s"] == pytest.approx(2e-3)
+    assert t["dry_sync_s"] == pytest.approx(0.25e-3)
+    assert t["starved_after_decode_n"] == 1
+    assert t["starved_post_s"] == pytest.approx(1e-3)
+    assert t["starved_idle_s"] == pytest.approx(10e-3)
+    starved = t["starved_s"] - 0.0
+    # never both at once: together they cover exit-of-sched -> enqueue once
+    assert t["dry_s"] + starved == pytest.approx(14.25e-3)
+    for p in ("post", "idle", "build"):
+        assert t["dry_" + p + "_s"] == 0.0
+    assert t["starved_sync_s"] == 0.0
+    resolve = next(r for r in rec.snapshot() if r["event"] == "resolve")
+    assert resolve["gap"]["seen"] == "poll"
+    assert rec.snapshot()[-1]["gap"]["seen"] == "resolve"
+    assert rec.snapshot()[-1]["gap"]["after"] == "decode"
+
+
+def test_sync_of_the_watched_step_itself_opens_no_dry_gap(clock):
+    """The serial loop: every dispatch is resolved before the next one. Its
+    `sync` ends because the program completed; the resolve that follows
+    knows the instant, so the whole wait is nobody's slack."""
+    rec = FlightRecorder()
+    for _ in range(3):
+        dev = FakeDevice(clock, done_at=clock.ns + int(5e6))
+        a = rec.dispatch("decode", rows=1, tokens=8, ready=dev.ready)
+        with rec.span("post", a, "decode"):
+            with rec.span("sync", a, "decode"):
+                clock.tick(5.0)
+            rec.resolve(a)
+        _phase(rec, clock, "build", 1.0)
+    t = rec.timing()
+    assert t["dry_n"] == 0 == t["dry_s"] == t["dry_slack_s"]
+    assert t["starved_after_decode_n"] == 2
+
+
+def test_rolled_back_step_stays_watched(clock):
+    rec = FlightRecorder()
+    a = rec.dispatch("decode", rows=1, tokens=8, ready=lambda: False)
+    dev = FakeDevice(clock, done_at=clock.ns + int(2e6))
+    b = rec.dispatch("decode", rows=1, tokens=8, ready=dev.ready)
+    with rec.span("post", a, "decode"):
+        rec.resolve(a)
+    rec.discard(b)  # the device still runs b
+    _phase(rec, clock, "sched", 3.0)  # ... and is seen to finish it
+    assert dev.asked > 0
+    _phase(rec, clock, "build", 2.0)
+    rec.dispatch("decode", rows=1, tokens=8)
+    t = rec.timing()
+    assert t["dry_n"] == 1 == t["dry_after_decode_n"]
+    assert t["dry_s"] == pytest.approx(2e-3)
+    assert t["starved_n"] == 1  # only the gap since `start`
+
+
+@pytest.mark.parametrize("drop", ["fault", "quiet"])
+def test_fault_and_quiet_drop_the_watch(clock, drop):
+    rec = FlightRecorder()
+    a = rec.dispatch("decode", rows=1, tokens=8, ready=lambda: False)
+    dev = FakeDevice(clock, done_at=clock.ns + int(1e6))
+    b = rec.dispatch("decode", rows=1, tokens=8, ready=dev.ready)
+    _phase(rec, clock, "sched", 2.0)  # b seen complete: a dry gap opens
+    assert dev.asked == 2
+    _phase(rec, clock, "post", 1.0)
+    if drop == "fault":
+        rec.fault("boom")
+    else:
+        rec.resolve(a)
+        rec.discard(b)
+        rec.quiet()
+    _phase(rec, clock, "idle", 4.0)
+    assert dev.asked == 2
+    rec.dispatch("prefill", rows=1, tokens=8)
+    t = rec.timing()
+    # the dry gap closed where the host counted the queue empty, and the
+    # starvation gap ran from there (and counts the late dispatch)
+    assert t["dry_n"] == 0 and t["dry_idle_s"] == 0.0
+    assert t["dry_s"] == pytest.approx(1e-3) == t["dry_post_s"]
+    assert t["starved_idle_s"] == pytest.approx(4e-3)
+    after = "start" if drop == "fault" else "decode"
+    assert t["starved_after_" + after + "_n"] == (2 if drop == "fault" else 1)
+    # a watch that was never seen ready is dropped the same way
+    rec2 = FlightRecorder()
+    dev2 = FakeDevice(clock)  # never completes
+    a2 = rec2.dispatch("decode", rows=1, tokens=8, ready=lambda: False)
+    b2 = rec2.dispatch("decode", rows=1, tokens=8, ready=dev2.ready)
+    if drop == "fault":
+        rec2.fault("boom")
+    else:
+        rec2.resolve(a2)
+        rec2.discard(b2)
+        rec2.quiet()
+    asked = dev2.asked
+    _phase(rec2, clock, "idle", 1.0)
+    assert dev2.asked == asked and rec2.timing()["dry_n"] == 0
+
+
+def test_a_ready_that_raises_is_dropped_not_raised(clock):
+    rec = FlightRecorder()
+
+    def deleted():
+        raise RuntimeError("Array has been deleted.")
+
+    rec.dispatch("decode", rows=1, tokens=8, ready=deleted)
+    _phase(rec, clock, "sched", 1.0)
+    assert rec._watch is None and rec.timing()["dry_n"] == 0
+
+
+def test_turn_is_the_seven_phases_outside_sync_and_idle(clock):
+    from vllm_production_stack_tpu.engine.flightrec import (
+        PHASES, TURN_PHASES,
+    )
+
+    rec = FlightRecorder()
+    for i, phase in enumerate(PHASES):
+        _phase(rec, clock, phase, 1.0 + i)
+    t = rec.timing()
+    assert set(PHASES) - set(TURN_PHASES) == {"sync", "idle"}
+    assert len(TURN_PHASES) == 7
+    assert t["turn_s"] == pytest.approx(
+        sum(t[p + "_s"] for p in PHASES) - t["sync_s"] - t["idle_s"])
+    assert t["turn_s"] == pytest.approx(t["admit_s"] + t["sched_s"]
+                                        + t["dispatch_s"] + t["post_s"]
+                                        + t["emit_s"])
+
+
+def test_flightrec_module_stays_jax_free():
+    """The router and the KV controller import it: `ready` is a callable
+    the engine hands over, never a JAX type, and polling it imports nothing
+    (only a span or a `dev:dry` event looks for jax.profiler, lazily)."""
+    import subprocess
+    import sys
+
+    code = ("import sys; "
+            "import vllm_production_stack_tpu.engine.flightrec as f; "
+            "r = f.FlightRecorder(); "
+            "r.dispatch('decode', 1, 1, ready=lambda: False); "
+            "r.poll(); "
+            "r.dispatch('decode', 1, 1); "
+            "assert r.timing()['dry_poll_n'] == 2; "
+            "sys.exit(1 if any(m == 'jax' or m.startswith('jax.') "
+            "for m in sys.modules) else 0)")
+    assert subprocess.run([sys.executable, "-c", code]).returncode == 0
+
+
 def test_idle_heartbeat_is_never_stale():
     hb = Heartbeat("step", stall_after_s=0.01)
     hb.idle()

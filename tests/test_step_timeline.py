@@ -14,8 +14,10 @@ from vllm_production_stack_tpu.engine.config import EngineConfig
 from vllm_production_stack_tpu.engine.engine import LLMEngine
 from vllm_production_stack_tpu.engine.flightrec import (
     DISPATCH_PHASES,
+    DRY_AFTER,
     GAP_AFTER,
     PHASES,
+    TURN_PHASES,
     FlightRecorder,
     Watchdog,
     ThreadRegistry,
@@ -72,6 +74,11 @@ def test_phase_totals_reconcile(ran, loop):
         t["starved_s"], rel=1e-9)
     assert sum(t["starved_after_" + k + "_n"] for k in GAP_AFTER) \
         == t["starved_n"] > 0
+    assert sum(t["dry_" + p + "_s"] for p in PHASES) == pytest.approx(
+        t["dry_s"], rel=1e-9)
+    assert sum(t["dry_after_" + k + "_n"] for k in DRY_AFTER) == t["dry_n"]
+    assert sum(t[p + "_s"] for p in TURN_PHASES) == pytest.approx(
+        t["turn_s"], rel=1e-9)
     # the phases of a step call cover it: what runs between two spans is a
     # few Python statements
     covered = sum(t[p + "_s"] for p in STEP_PHASES)
@@ -148,8 +155,11 @@ def test_a_prefill_chunk_does_not_drain_the_pipeline():
     behind = next(r for r in records if r["event"] == "dispatch"
                   and r["seq"] == chunk["seq"] + 1)
     assert behind["kind"] == "decode" and behind["rows"] == 2
-    assert behind["gap"] is None
-    gaps = [r for r in records if r["event"] == "dispatch" and r["gap"]]
+    # no starvation gap ended at it; a tiny chunk on the CPU may well have
+    # run dry before the window's enqueue returned (a dry gap, seen by poll)
+    assert behind["gap"] is None or behind["gap"]["seen"] == "poll"
+    gaps = [r for r in records if r["event"] == "dispatch" and r["gap"]
+            and r["gap"]["seen"] == "resolve"]
     assert len(gaps) == 1
     last = gaps[-1]
     assert last["kind"] == "prefill" and last["gap"]["after"] == "start"
@@ -165,6 +175,34 @@ def test_a_prefill_chunk_does_not_drain_the_pipeline():
             assert seq == last["seq"]
 
 
+def _chip_reader(name: str):
+    """A reader module of the chip benchmark, loaded from its file."""
+    import importlib.util
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    path = os.path.join(root, "benchmarks", "chip", "readers", name + ".py")
+    mod = importlib.util.spec_from_file_location(name, path)
+    reader = importlib.util.module_from_spec(mod)
+    mod.loader.exec_module(reader)
+    return reader
+
+
+def _chip_metric(name: str) -> tuple[dict, dict]:
+    """(BENCHMARK.json entry, metrics/<name>.json) of a per-layer metric."""
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "chip", "metrics",
+                           name + ".json")) as f:
+        spec = json.load(f)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        (entry,) = [m for m in json.load(f)["per_layer"]
+                    if m["name"] == name]
+    return entry, spec
+
+
 @pytest.mark.parametrize("start, end, want", [
     (dict(prefill_n=10, prefill_chained_n=4),
      dict(prefill_n=50, prefill_chained_n=34), 75.0),
@@ -178,27 +216,12 @@ def test_prefill_chained_share_from_its_data_file(start, end, want):
     """The benchmark's `prefill_chained_share` is a data file for a reader
     the benchmark already has: chunks that had a window enqueued behind
     them before their resolve, of all chunks, over the window."""
-    import importlib.util
-    import json
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    chip = os.path.join(root, "benchmarks", "chip")
-    with open(os.path.join(chip, "metrics",
-                           "prefill_chained_share.json")) as f:
-        spec = json.load(f)
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
-        (entry,) = [m for m in json.load(f)["per_layer"]
-                    if m["name"] == "prefill_chained_share"]
+    entry, spec = _chip_metric("prefill_chained_share")
     assert entry == {
         "name": "prefill_chained_share", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "scheduler",
         "moves": "out_tok_per_s"}  # no `workloads`: every cell reports it
-    mod = importlib.util.spec_from_file_location(
-        "timing_ratio_opt",
-        os.path.join(chip, "readers", spec["reader"] + ".py"))
-    reader = importlib.util.module_from_spec(mod)
-    mod.loader.exec_module(reader)
+    reader = _chip_reader(spec["reader"])
     ctx = {"start": {"timing": {"engine": start}},
            "end": {"timing": {"engine": end}}}
     got = reader.read(ctx, **spec["args"])
@@ -226,17 +249,10 @@ def test_moe_read_share_from_its_data_file(start, end, want):
     benchmark already has: the experts whose bytes the decode programs'
     expert computation read, of layer-steps x held experts, over the
     window (ISSUE 36)."""
-    import importlib.util
     import json
     import os
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    chip = os.path.join(root, "benchmarks", "chip")
-    with open(os.path.join(chip, "metrics", "moe_read_share.json")) as f:
-        spec = json.load(f)
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    entry = bench["per_layer"][-1]
+    entry, spec = _chip_metric("moe_read_share")
     assert entry == {
         "name": "moe_read_share", "unit": "%", "better": "lower",
         "source": "program_counter", "layer": "kernels",
@@ -244,16 +260,218 @@ def test_moe_read_share_from_its_data_file(start, end, want):
             "olmoe-1b-7b-int8.chat-saturated",
             "kanana-2-30b-a3b-bf16-8l.longdoc-saturated",
             "laguna-s21-bf16-ep4-9l.longdoc-saturated"]}
-    assert set(entry["workloads"]) <= {w["name"] for w in bench["workloads"]}
-    mod = importlib.util.spec_from_file_location(
-        "timing_ratio_opt",
-        os.path.join(chip, "readers", spec["reader"] + ".py"))
-    reader = importlib.util.module_from_spec(mod)
-    mod.loader.exec_module(reader)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        cells = {w["name"] for w in json.load(f)["workloads"]}
+    assert set(entry["workloads"]) <= cells
+    reader = _chip_reader(spec["reader"])
     ctx = {"start": {"timing": {"engine": start}},
            "end": {"timing": {"engine": end}}}
     got = reader.read(ctx, **spec["args"])
     assert got == (pytest.approx(want) if want is not None else None)
+
+
+_W0 = dict(dry_s=1.0, dry_n=10, clock_s=100.0, turn_s=40.0, dispatch_n=50)
+
+
+@pytest.mark.parametrize("name, source, end, want", [
+    ("device_dry_share", "program_span",
+     dict(dry_s=1.75, dry_n=60, clock_s=150.0, turn_s=46.0, dispatch_n=250),
+     1.5),
+    ("dry_dispatch_share", "program_counter",
+     dict(dry_s=1.75, dry_n=60, clock_s=150.0, turn_s=46.0, dispatch_n=250),
+     25.0),
+    ("host_turn_ms", "program_span",
+     dict(dry_s=1.75, dry_n=60, clock_s=150.0, turn_s=46.0, dispatch_n=250),
+     30.0),
+    # a window without a single dry gap reads 0, it is not left out
+    ("device_dry_share", "program_span",
+     dict(dry_s=1.0, dry_n=10, clock_s=150.0, turn_s=46.0, dispatch_n=250),
+     0.0),
+    ("dry_dispatch_share", "program_counter",
+     dict(dry_s=1.0, dry_n=10, clock_s=150.0, turn_s=46.0, dispatch_n=250),
+     0.0),
+    # a parent that lacks the counters: left out, not raised
+    ("device_dry_share", "program_span",
+     dict(clock_s=150.0, dispatch_n=250), None),
+    ("dry_dispatch_share", "program_counter",
+     dict(clock_s=150.0, dispatch_n=250), None),
+    ("host_turn_ms", "program_span",
+     dict(clock_s=150.0, dispatch_n=250), None),
+])
+def test_dry_metrics_from_their_data_files(name, source, end, want):
+    """Three of ISSUE 37's metrics are data files for a reader the benchmark
+    already has (`timing_ratio_opt`), over the window."""
+    entry, spec = _chip_metric(name)
+    assert entry == {
+        "name": name, "unit": "ms" if name.endswith("_ms") else "%",
+        "better": "lower", "source": source, "layer": "scheduler",
+        "moves": "out_tok_per_s"}  # no `workloads`: every cell reports it
+    assert spec["reader"] == "timing_ratio_opt"
+    reader = _chip_reader("timing_ratio_opt")
+    start = _W0 if want is not None else dict(clock_s=100.0, dispatch_n=50)
+    ctx = {"start": {"timing": {"engine": start}},
+           "end": {"timing": {"engine": end}}}
+    got = reader.read(ctx, **spec["args"])
+    assert got == (pytest.approx(want) if want is not None else None)
+    # the counters it names are served by every engine
+    assert {spec["args"]["num"], spec["args"]["den"]} <= set(
+        FlightRecorder().timing())
+
+
+@pytest.mark.parametrize("t0, t1, want_unseen, want_untraced", [
+    # the slice: 0.70 s idle, 0.02 starved + 0.62 dry seen -> 0.06 of 8 s;
+    # before it: (0.1 + 0.5) of 20 s
+    (dict(starved_s=1.1, dry_s=2.5, clock_s=120.0),
+     dict(starved_s=1.12, dry_s=3.12, clock_s=128.0), 0.75, 3.0),
+    # a parent: neither reader finds its counters
+    (dict(starved_s=1.1, clock_s=120.0),
+     dict(starved_s=1.12, clock_s=128.0), None, None),
+])
+def test_idle_unseen_and_untraced_share_on_fixture_documents(
+        t0, t1, want_unseen, want_untraced):
+    """The two readers ISSUE 37 adds: the idle of the device trace that
+    neither account explains, over the traced slice; and the host-side
+    share of an empty device from the window's open to the capture's start,
+    where no profiler is attached."""
+    entry, spec = _chip_metric("idle_unseen_share")
+    assert (entry["layer"], entry["source"]) == ("device", "device_trace")
+    assert "workloads" not in entry and entry["better"] == "lower"
+    start = dict(starved_s=1.0, dry_s=2.0, clock_s=100.0)
+    if want_unseen is None:
+        del start["dry_s"]
+    ctx = {"trace": {"gap_total_s": 0.70, "window_s": 8.0},
+           "trace_span": {"timing0": {"engine": t0},
+                          "timing1": {"engine": t1}},
+           "start": {"timing": {"engine": start}}}
+    unseen = _chip_reader(spec["reader"]).read(ctx, **spec.get("args", {}))
+    assert unseen == (pytest.approx(want_unseen)
+                      if want_unseen is not None else None)
+    entry, spec = _chip_metric("device_empty_share_untraced")
+    assert (entry["layer"], entry["source"]) == ("scheduler", "program_span")
+    reader = _chip_reader(spec["reader"])
+    got = reader.read(ctx, **spec["args"])
+    assert got == (pytest.approx(want_untraced)
+                   if want_untraced is not None else None)
+    # an untraced run has no slice: both are left out
+    bare = {**ctx, "trace": None, "trace_span": {}}
+    assert reader.read(bare, **spec["args"]) is None
+    assert _chip_reader("idle_unseen").read(bare) is None
+    assert set(spec["args"]["nums"]) | {spec["args"]["den"]} <= set(
+        FlightRecorder().timing())
+
+
+def _slow_sched(eng, monkeypatch, seconds: float):
+    """Make the host's turn longer than any tiny program: `sched` sleeps
+    while a step is in flight."""
+    import time
+
+    schedule = eng.scheduler.schedule
+
+    def slow(inflight=None):
+        if inflight is not None:
+            time.sleep(seconds)
+        return schedule(inflight=inflight)
+
+    monkeypatch.setattr(eng.scheduler, "schedule", slow)
+
+
+def _count_dispatches_behind_a_step(eng, monkeypatch) -> list:
+    behind = []
+    dispatch = eng._dispatch
+
+    def counting(work, prev=None):
+        if prev is not None:
+            behind.append(work)
+        return dispatch(work, prev=prev)
+
+    monkeypatch.setattr(eng, "_dispatch", counting)
+    return behind
+
+
+def test_slow_host_turn_reads_a_dry_gap_at_every_dispatch_behind_a_step(
+        monkeypatch):
+    """The program in flight completes while the host is still in `sched`:
+    every enqueue behind it comes too late, and the starvation account sees
+    none of it (the host never emptied the queue itself)."""
+    eng = _engine()
+    eng.generate([[1, 2, 3, 4, 5]], _greedy(24))  # compiled
+    _slow_sched(eng, monkeypatch, 0.15)
+    behind = _count_dispatches_behind_a_step(eng, monkeypatch)
+    before = eng.timing
+    eng.generate([[5, 4, 3, 2, 1, 7]], _greedy(40))
+    d = _delta(eng, before)
+    assert len(behind) >= 4
+    assert d["dry_n"] == len(behind)
+    assert d["dry_after_decode_n"] + d["dry_after_prefill_n"] == d["dry_n"]
+    # the sleeping phase is where the gaps open (`sched` has no sub-step to
+    # poll: its length is the bracket, or part of the gap where the program
+    # was seen complete before it); what follows it up to the enqueue's
+    # return is the lower bound
+    assert d["dry_s"] > 0 and d["dry_s"] + d["dry_slack_s"] >= 0.15 * 0.9 * (
+        d["dry_n"])
+    # the last window ran dry too, with nothing to enqueue behind it: its
+    # gap went on as a starvation gap from its resolve, counted there
+    assert d["starved_after_decode_n"] <= 1
+    assert d["dry_poll_n"] >= d["dry_n"] and d["dry_poll_s"] > 0
+    gaps = [r["gap"] for r in eng.flightrec.snapshot()
+            if r["event"] == "dispatch" and r["gap"]
+            and r["gap"]["seen"] == "poll"]
+    assert len(gaps) >= len(behind) and all(
+        g["slack_ms"] >= 0 and g["after"] in DRY_AFTER for g in gaps)
+
+
+def test_a_chunk_row_is_sliced_not_walked():
+    """`Request.tokens_between` is `token_at` over a range, across the
+    prompt's end (a resumed request's chunk holds outputs too): `sched` of a
+    2,048-token chunk builds its row in two list operations, so a profiler's
+    Python tracer has no 2,048 calls to stretch."""
+    from vllm_production_stack_tpu.engine.request import Request
+
+    req = Request("r", list(range(100, 110)), _greedy(4))
+    req.output_token_ids.extend([7, 8, 9])
+    for start, end in ((0, 10), (2, 5), (8, 13), (10, 13), (11, 12), (3, 3)):
+        assert req.tokens_between(start, end) == [
+            req.token_at(i) for i in range(start, end)]
+    row = req.tokens_between(0, 10)
+    row.append(1)
+    assert len(req.prompt_token_ids) == 10  # a copy, never the prompt itself
+
+
+def test_slow_device_program_reads_no_dry_gap(monkeypatch):
+    """A program that outlasts the host's turn: the next one is enqueued
+    while it still runs, at every dispatch."""
+    import time
+
+    import jax
+
+    @jax.jit
+    def slowed(tokens):
+        def wait(x):
+            time.sleep(0.12)
+            return x
+        return jax.pure_callback(
+            wait, jax.ShapeDtypeStruct(tokens.shape, tokens.dtype), tokens)
+
+    eng = _engine()
+    execute_async = eng.runner.execute_async
+
+    def slow_program(work, prev=None):
+        handle = execute_async(work, prev=prev)
+        handle.tokens = slowed(handle.tokens)  # the program's end, later
+        if handle.chain_vec is not None:
+            handle.chain_vec = handle.tokens
+        return handle
+
+    monkeypatch.setattr(eng.runner, "execute_async", slow_program)
+    eng.generate([[1, 2, 3, 4, 5]], _greedy(24))  # compiled, `slowed` too
+    behind = _count_dispatches_behind_a_step(eng, monkeypatch)
+    before = eng.timing
+    out = eng.generate([[5, 4, 3, 2, 1, 7]], _greedy(40))
+    d = _delta(eng, before)
+    assert len(out[0]["token_ids"]) == 40
+    assert len(behind) >= 4 and d["dry_poll_n"] > len(behind)
+    assert d["dry_n"] == 0 == d["dry_s"] == d["dry_slack_s"]
 
 
 def test_rolled_back_dispatch_does_not_empty_the_queue():
@@ -386,6 +604,95 @@ def test_capture_holds_step_spans_and_clock_pair(tmp_path):
     marks = [dict(ev.stats) for ln in host.lines for ev in ln.events
              if ev.name == "clock_pair"]
     assert marks == [pair]
+
+
+def test_capture_holds_dev_dry_beside_step_spans(tmp_path, monkeypatch):
+    """The first poll that sees the watched program complete leaves one
+    zero-length `dev:dry` event with that program's seq on the step
+    thread's line, beside the `step:*` spans and the clock pair."""
+    import jax
+    from jax.profiler import ProfileData
+
+    eng = _engine()
+    async_engine = AsyncEngine(eng)
+
+    async def go():
+        async_engine.start(asyncio.get_running_loop())
+        try:
+            async for _ in async_engine.generate(
+                    prompt_token_ids=[1, 2, 3], sampling=_greedy(4)):
+                pass  # compiled before the capture starts
+            _slow_sched(eng, monkeypatch, 0.05)
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                eng.flightrec.clock_pair()
+                before = eng.timing
+                async for _ in async_engine.generate(
+                        prompt_token_ids=[4, 5, 6, 7], sampling=_greedy(24)):
+                    pass
+                await asyncio.sleep(0.05)
+            finally:
+                jax.profiler.stop_trace()
+            return _delta(eng, before)
+        finally:
+            async_engine.shutdown()
+
+    d = asyncio.run(go())
+    assert d["dry_n"] >= 2
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[-1]
+    host = next(p for p in ProfileData.from_file(path).planes
+                if p.name == "/host:CPU")
+    step_line = next(ln for ln in host.lines if ln.name == "engine-step")
+    names = {ev.name for ev in step_line.events}
+    assert {"step:sched", "step:build", "dev:dry"} <= names
+    assert any(ev.name == "clock_pair" for ln in host.lines
+               for ev in ln.events)
+    dries = [dict(ev.stats) for ev in step_line.events
+             if ev.name == "dev:dry"]
+    # one event a gap that opened inside the capture: those a dispatch
+    # ended (`dry_n`) and those the host's own resolve ended (the prompt's
+    # chunk and the last window, with nothing to enqueue behind them)
+    assert d["dry_n"] <= len(dries) <= d["dry_n"] + 3
+    assert all(ev["seq"] > 0 and ev["slack_ns"] >= 0 for ev in dries)
+    seqs = [ev["seq"] for ev in dries]
+    assert seqs == sorted(set(seqs))  # one sighting a program, in order
+    # the dispatch that closed each gap is the next seq's flight record
+    closed = {r["seq"] for r in eng.flightrec.snapshot()
+              if r["event"] == "dispatch" and r["gap"]
+              and r["gap"]["seen"] == "poll"}
+    assert {s + 1 for s in seqs[:-1]} <= closed
+
+
+def test_xplane_timeline_rebuilds_both_accounts_from_the_capture():
+    """tools/xplane_timeline.py: starvation gaps and dry gaps over a capture
+    from `step:*` spans and `dev:dry` events alone."""
+    import importlib.util
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    mod = importlib.util.spec_from_file_location(
+        "xplane_timeline", os.path.join(root, "tools", "xplane_timeline.py"))
+    tool = importlib.util.module_from_spec(mod)
+    mod.loader.exec_module(tool)
+    ms = 1e6
+    steps = [  # (phase, start, end, seq, kind), ns
+        ("build", 0 * ms, 10 * ms, 1, "prefill"),
+        ("sync", 10 * ms, 50 * ms, 1, "prefill"),  # nothing behind seq 1
+        ("build", 52 * ms, 60 * ms, 2, "decode"),  # starved 50 -> 60
+        ("build", 61 * ms, 70 * ms, 3, "prefill"),  # behind seq 2
+        ("sync", 70 * ms, 90 * ms, 2, "decode"),
+        ("sched", 90 * ms, 120 * ms, 4, ""),
+        ("build", 120 * ms, 130 * ms, 4, "decode"),  # seq 3 ran dry at ~100
+        ("sync", 130 * ms, 131 * ms, 3, "prefill"),
+    ]
+    dries = [(120 * ms, 3, 30 * ms)]  # seen at the exit of `sched`
+    got = tool.host_account(steps, dries, 0.0, 200 * ms)
+    assert got == {"starved_s": 0.01, "dry_s": 0.01, "dry_slack_s": 0.03,
+                   "dry_n": 1}
+    # clipped to the device plane's own window
+    assert tool.host_account(steps, dries, 55 * ms, 125 * ms) == {
+        "starved_s": 0.005, "dry_s": 0.005, "dry_slack_s": 0.03, "dry_n": 1}
+    assert tool.host_account(steps, [], 0.0, 200 * ms)["dry_n"] == 0
 
 
 def _scopes(hlo: str) -> set[str]:

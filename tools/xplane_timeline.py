@@ -3,8 +3,13 @@
 to its step timeline (docs/14-tracing.md):
 
 - the device's idle stretches between step programs, each matched to the
-  `step:<phase>` spans of the engine's step thread that ran inside it and
-  to the program that ended before it;
+  `step:<phase>` spans of the engine's step thread that ran inside it, to
+  the program that ended before it, and to the `dev:dry` event in which the
+  step thread first saw that program complete (with its lag behind the
+  program's end on the device's line);
+- the host's own account of an empty device over the capture — starvation
+  gaps and dry gaps rebuilt from the `step:*` spans and `dev:dry` events —
+  against the idle the device plane shows;
 - device seconds per operation family, split by the jitted steps' named
   scopes and by program;
 - the `clock_pair` event that ties the capture to the flight records.
@@ -109,10 +114,10 @@ class Plane:
         return out
 
 
-def host_spans(planes) -> tuple[list, list, list]:
+def host_spans(planes) -> tuple[list, list, list, list]:
     """(step:* spans, clock_pair marks, names of the lines that hold
-    spans) over the host planes."""
-    steps, marks, lines = [], [], set()
+    spans, dev:dry events as (time, seq, slack_ns)) over the host planes."""
+    steps, marks, lines, dries = [], [], set(), []
     for pl in planes:
         if pl.name.startswith("/device:"):
             continue
@@ -124,7 +129,50 @@ def host_spans(planes) -> tuple[list, list, list]:
                     lines.add(lname)
                 elif name == "clock_pair":
                     marks.append({"capture_ns": s, **st})
-    return steps, marks, sorted(lines)
+                elif name == "dev:dry":
+                    dries.append((s, st.get("seq"), st.get("slack_ns") or 0))
+    return steps, marks, sorted(lines), sorted(dries)
+
+
+def host_account(steps, dries, lo: float, hi: float) -> dict:
+    """The program's two accounts of an empty device (docs/37), rebuilt from
+    the capture alone and clipped to [lo, hi): a STARVATION gap runs from the
+    end of the `sync` that resolved the newest enqueued step to the end of
+    the next dispatch's `build`; a DRY gap from a `dev:dry` event (the first
+    poll that saw the newest enqueued program complete behind an unresolved
+    step) to the end of the next dispatch's `build`, or to that step's own
+    resolve where nothing was enqueued behind it; `slack` is the bracket
+    before each `dev:dry` (the poll before it)."""
+    enq_end, sync_end = {}, {}
+    for ph, _, e, seq, _ in steps:
+        if seq is None:
+            continue
+        if ph == "build":
+            enq_end[seq] = max(e, enq_end.get(seq, e))
+        elif ph == "sync":
+            sync_end[seq] = e
+
+    def clipped(a: float, b: float) -> float:
+        return max(0.0, min(b, hi) - max(a, lo))
+
+    starved = sum(
+        clipped(t, enq_end[n + 1]) for n, t in sync_end.items()
+        if n + 1 in enq_end and enq_end[n + 1] > t)
+    dry = slack = 0.0
+    n_dry = 0
+    for t, seq, slack_ns in dries:
+        if seq is None or not lo <= t < hi:
+            continue
+        ends = [x for x in (enq_end.get(seq + 1), sync_end.get(seq))
+                if x is not None and x >= t]
+        if not ends:  # still open where the capture stops
+            continue
+        n_dry += 1
+        dry += clipped(t, min(ends))
+        slack += slack_ns
+    return {"starved_s": round(starved * 1e-9, 6),
+            "dry_s": round(dry * 1e-9, 6),
+            "dry_slack_s": round(slack * 1e-9, 6), "dry_n": n_dry}
 
 
 def self_ms_inside(steps, lo: float, hi: float) -> dict:
@@ -142,7 +190,8 @@ def self_ms_inside(steps, lo: float, hi: float) -> dict:
     return {k: round(v, 3) for k, v in inside.items()}
 
 
-def reduce_device(pl: Plane, steps, t0: float, min_gap_ms: float) -> dict:
+def reduce_device(pl: Plane, steps, dries, t0: float,
+                  min_gap_ms: float) -> dict:
     ops = pl.events("XLA Ops", with_stats=True)
     mods = [(module_family(n), s, e) for n, s, e, _ in pl.events("XLA Modules")]
     dev: dict = {"plane": pl.name, "ops": len(ops), "modules": len(mods)}
@@ -160,11 +209,18 @@ def reduce_device(pl: Plane, steps, t0: float, min_gap_ms: float) -> dict:
             continue
         glue = [(s, e) for n, s, e in mods
                 if e0 <= s and e <= s1 and (e - s) * 1e-6 < STEP_PROGRAM_MS]
+        # the step thread's first sight of the program before the gap as
+        # complete, and how long after the program's end that was
+        i = bisect.bisect_left(dries, (e0,))
+        seen = dries[i] if i < len(dries) and dries[i][0] < s1 else None
         gaps.append({
             "at_s": round((e0 - t0) * 1e-9, 6),
             "ms": round((s1 - e0) * 1e-6, 3), "after": n0, "before": n1,
             "glue_programs": len(glue),
             "host_ms": self_ms_inside(steps, e0, s1),
+            "dry": None if seen is None else {
+                "seq": seen[1], "lag_ms": round((seen[0] - e0) * 1e-6, 3),
+                "slack_ms": round(seen[2] * 1e-6, 3)},
         })
     dev["gaps_over_ms"] = min_gap_ms
     dev["gaps"] = gaps
@@ -180,6 +236,15 @@ def reduce_device(pl: Plane, steps, t0: float, min_gap_ms: float) -> dict:
             by_phase[ph] = round(by_phase.get(ph, 0.0) + ms, 3)
     dev["gaps_by_after"] = by_after
     dev["gaps_host_ms_by_phase"] = by_phase
+    # trace idle against the host's own account over the same stretch
+    host = host_account(steps, dries, lo, hi)
+    seen = host["starved_s"] + host["dry_s"]
+    dev["idle_against_host"] = {
+        "trace_idle_s": round(dev["window_s"] - dev["busy_s"], 6), **host,
+        "starved_plus_dry_s": round(seen, 6),
+        "plus_slack_s": round(seen + host["dry_slack_s"], 6),
+        "gaps_with_dry": sum(g["dry"] is not None for g in gaps),
+    }
     # -- operations by program, scope and family --------------------------
     starts = [p[0] for p in progs]
     table, unscoped = {}, {}
@@ -223,19 +288,20 @@ def reduce_device(pl: Plane, steps, t0: float, min_gap_ms: float) -> dict:
 
 def reduce(space, min_gap_ms: float) -> dict:
     planes = [Plane(p) for p in space.planes]
-    steps, marks, lines = host_spans(planes)
+    steps, marks, lines, dries = host_spans(planes)
     t0 = min((s for _, s, *_ in steps), default=0.0)
     out: dict = {
         "planes": [[p.name, {k: sum(len(ln.events) for ln in v)
                              for k, v in list(p.lines.items())[:40]}]
                    for p in planes],
         "clock_pair": marks, "step_thread_lines": lines, "step_spans": {},
+        "dev_dry_events": len(dries),
     }
     for ph, s, e, *_ in steps:
         row = out["step_spans"].setdefault(ph, {"n": 0, "s": 0.0})
         row["n"] += 1
         row["s"] = round(row["s"] + (e - s) * 1e-9, 6)
-    out["devices"] = [reduce_device(p, steps, t0, min_gap_ms)
+    out["devices"] = [reduce_device(p, steps, dries, t0, min_gap_ms)
                       for p in planes if p.name.startswith("/device:TPU:")]
     return out
 
@@ -255,11 +321,14 @@ def main(argv=None) -> int:
     with open(args.out_json, "w") as f:
         json.dump(result, f, indent=1)
     print(json.dumps({"step_thread_lines": result["step_thread_lines"],
-                      "step_spans": result["step_spans"]}))
+                      "step_spans": result["step_spans"],
+                      "dev_dry_events": result["dev_dry_events"]}))
     for dev in result["devices"]:
+        for g in dev.get("gaps", []):
+            print(json.dumps(g))
         print(json.dumps({k: dev.get(k) for k in (
             "plane", "window_s", "busy_s", "gap_sum_s", "other_idle_s",
-            "gaps_by_after", "gaps_host_ms_by_phase")}))
+            "gaps_by_after", "gaps_host_ms_by_phase", "idle_against_host")}))
     return 0
 
 

@@ -1351,11 +1351,12 @@ class LLMEngine:
         kind = _work_kind(work)
         with self.flightrec.span("build", kind=kind):
             handle = self.runner.execute_async(work, prev=prev)
+            self.flightrec.poll()
             # rows advance speculatively by their fed length (full
             # acceptance; a chunk that ends its prompt by the one token it
             # samples): the next step can chain off this handle
             self.scheduler.begin_speculative(work)
-        handle.seq, handle.kind = self._rec_dispatch(work, kind), kind
+        handle.seq, handle.kind = self._rec_dispatch(work, kind, handle), kind
         return handle
 
     def _resolve(self, work, handle: StepHandle,
@@ -1374,6 +1375,7 @@ class LLMEngine:
                 rec.count("prefill_kernel_n")
             self.scheduler.end_speculative(work)
             results = self.scheduler.postprocess(work, rows)
+            rec.poll()  # `post` of a chunk is long: see FlightRecorder.poll
             if kind == "prefill":
                 # chunk tokens PROCESSED (mid-prompt chunks emit none)
                 rec.count(
@@ -1411,6 +1413,7 @@ class LLMEngine:
                 accepted = sum(len(t) for _, t in results)
                 rec.count("decode_tokens", accepted)
                 self._meter_decode(work, accepted)
+            rec.poll()
             self._emit_results(results, handle.logprob_rows, outputs)
 
     def _rollback(self, nxt: _InflightStep) -> None:
@@ -1490,12 +1493,14 @@ class LLMEngine:
 
     # -- flight recorder (docs/37-flight-recorder.md) ----------------------
 
-    def _rec_dispatch(self, work, kind: str) -> int:
+    def _rec_dispatch(self, work, kind: str, handle: StepHandle) -> int:
         """One black-box record per device dispatch: batch shape + phase,
         the scheduler's decision summary, and queue/pool depths — what the
         engine was doing right before it (maybe) stopped doing anything.
         Also advances the dispatch/resolve cursor the watchdog's
-        unresolved-step detection and the starvation accounting key off."""
+        unresolved-step detection and the starvation accounting key off,
+        and hands the recorder the program's `is_ready` to watch: the polls
+        at the phase boundaries see the chip run dry behind it."""
         sched = self.scheduler
         if kind == "decode":
             window = work.window
@@ -1507,6 +1512,7 @@ class LLMEngine:
             kind, rows=len(work.requests), tokens=tokens, window=window,
             waiting=sched.num_waiting, running=sched.num_running,
             pool_usage=sched.pool.usage_perc,
+            ready=getattr(handle.tokens, "is_ready", None),
         )
 
     # -- saturation & goodput telemetry (docs/29-saturation-slo.md) --------

@@ -98,8 +98,13 @@ PHASES = (
 )
 _SPAN_NAMES = {p: "step:" + p for p in PHASES}  # nothing formatted per span
 DISPATCH_PHASES = ("build", "h2d", "enqueue")  # dispatch_s is their sum
+# the step thread's time outside `sync` and `idle` (turn_s is their sum): the
+# host's turn, which has to fit inside the program in flight
+TURN_PHASES = ("admit", "sched", *DISPATCH_PHASES, "post", "emit")
 # what emptied the device's queue before a starvation gap
 GAP_AFTER = ("start", "prefill", "decode", "verify")
+# the kind of the program that ran dry before a dry gap
+DRY_AFTER = GAP_AFTER[1:]
 
 _TRACE_ANNOTATION = None
 
@@ -147,6 +152,8 @@ class _Span:
         if self.outer is not None:
             self.outer._credit(now)
         rec._cur = self
+        if rec._watch is not None:
+            rec._poll(now)
         self.ann = _trace_annotation()(
             self.key, seq=self.seq, kind=self.kind)
         self.ann.__enter__()
@@ -158,8 +165,8 @@ class _Span:
         d = now - self.t_run
         self.t_run = now
         rec._tot[self.phase + "_s"] += d
-        if rec._gap_after is not None:
-            rec._tot["starved_" + self.phase + "_s"] += d
+        if rec._gap_after is not None:  # a starvation gap or a dry gap
+            rec._tot[rec._gap_key + self.phase + "_s"] += d
             rec._gap[self.phase] = rec._gap.get(self.phase, 0) + d
 
     def __exit__(self, *exc):
@@ -174,6 +181,11 @@ class _Span:
         if self.outer is not None:
             self.outer.t_run = now
         rec._stamps.append((self.phase, self.t0, now, self.seq))
+        # the `sync` of the watched step itself ends BECAUSE the program
+        # completed: the resolve that follows knows the instant
+        if rec._watch is not None and (
+                self.phase != "sync" or self.seq != rec._watch_seq):
+            rec._poll(now)
         return False
 
 
@@ -218,12 +230,28 @@ class FlightRecorder:
             "dispatch_n", "starved_n",
             *("starved_" + p + "_s" for p in PHASES),
             *("starved_after_" + k + "_n" for k in GAP_AFTER),
+            "dry_n", "dry_slack_s", "dry_poll_n", "dry_poll_s",
+            *("dry_" + p + "_s" for p in PHASES),
+            *("dry_after_" + k + "_n" for k in DRY_AFTER),
         ), 0)
         self._cur: _Span | None = None
-        # a starvation gap is open while `_gap_after` names what emptied
-        # the device's queue; `_gap` splits it over the phases run inside
+        # a gap is open while `_gap_after` names the program before it: a
+        # starvation gap (`_gap_key` "starved_": the host's own resolve
+        # emptied the device's queue) or a dry gap ("dry_": a poll saw the
+        # newest enqueued program complete behind an unresolved step). Never
+        # both; `_gap` splits it over the phases run inside
         self._gap_after: str | None = "start"
+        self._gap_key = "starved_"
         self._gap: dict[str, int] = {}
+        # a dry gap's bracket: its first true poll - the poll before it
+        self._gap_slack = 0
+        # the newest enqueued program's zero-argument `ready` (None: nothing
+        # watched, or already seen ready), its seq and kind, and when it was
+        # last known to be running (its enqueue's return, then each poll)
+        self._watch = None
+        self._watch_seq = 0
+        self._watch_kind = ""
+        self._poll_t = 0
         # phase stamps since the last ring record (bounded: an idle loop
         # spins admit/idle with no record to carry them)
         self._stamps: deque[tuple] = deque(maxlen=64)
@@ -251,39 +279,89 @@ class FlightRecorder:
         """Highest dispatch seq known complete on the device."""
         return self._done
 
-    def _flip_gap(self, now: int, after: str | None) -> dict | None:
-        """Open (`after` = what emptied the queue) or close (None) the
-        starvation gap at `now`, splitting the running phase at the edge.
-        Closing returns the gap for the flight record."""
-        if (after is None) == (self._gap_after is None):
+    def _open_gap(self, now: int, after: str, key: str = "starved_",
+                  slack: int = 0) -> None:
+        """Open a gap at `now` (`after`: what ran before it), splitting the
+        running phase at the edge. A gap that is open stays as it is."""
+        if self._gap_after is not None:
+            return
+        if self._cur is not None:
+            self._cur._credit(now)
+        self._gap_after, self._gap_key, self._gap_slack = after, key, slack
+
+    def _close_gap(self, now: int, counted: bool = True) -> dict | None:
+        """Close the open gap at `now` and return it for the flight record.
+        `counted`: a dispatch ended it — the `_n` counters count dispatches
+        that came too late, once each."""
+        after = self._gap_after
+        if after is None:
             return None
         if self._cur is not None:
             self._cur._credit(now)
-        closed = None
-        if after is None:
-            self._tot["starved_n"] += 1
-            self._tot["starved_after_" + self._gap_after + "_n"] += 1
-            closed = {
-                "after": self._gap_after,
-                "ms": round(sum(self._gap.values()) * 1e-6, 3),
-                "phases_ms": {p: round(d * 1e-6, 3)
-                              for p, d in self._gap.items()},
-            }
-            self._gap = {}
-        self._gap_after = after
+        key, tot = self._gap_key, self._tot
+        dry = key == "dry_"
+        if counted:
+            tot[key + "n"] += 1
+            tot[key + "after_" + after + "_n"] += 1
+        closed = {
+            "seen": "poll" if dry else "resolve", "after": after,
+            "ms": round(sum(self._gap.values()) * 1e-6, 3),
+            "phases_ms": {p: round(d * 1e-6, 3)
+                          for p, d in self._gap.items()},
+        }
+        if dry:
+            tot["dry_slack_s"] += self._gap_slack
+            closed["slack_ms"] = round(self._gap_slack * 1e-6, 3)
+        self._gap = {}
+        self._gap_after = None
         return closed
+
+    def _poll(self, now: int) -> None:
+        """Ask the watched program whether it is complete (callers test
+        `_watch` first). The first poll that says yes opens a dry gap at
+        `now` — the device has nothing to run behind an unresolved step —
+        and ends the polling; the poll before it is the other end of the
+        bracket that holds the true instant."""
+        tot = self._tot
+        t0 = time.monotonic_ns()
+        try:
+            done = self._watch()
+        except RuntimeError:  # a deleted array: nothing left to watch
+            self._watch = None
+            return
+        tot["dry_poll_s"] += time.monotonic_ns() - t0
+        tot["dry_poll_n"] += 1
+        if not done:
+            self._poll_t = now
+            return
+        self._watch = None
+        slack = now - self._poll_t
+        self._open_gap(now, self._watch_kind, "dry_", slack)
+        # the host's observation on the profiler's clock, beside the
+        # program's true end on the device's line
+        with _trace_annotation()(
+                "dev:dry", seq=self._watch_seq, slack_ns=slack):
+            pass
+
+    def poll(self) -> None:
+        """One more poll between two phase boundaries, for a phase long
+        enough to leave the bracket wide."""
+        if self._watch is not None:
+            self._poll(time.monotonic_ns())
 
     def timing(self) -> dict:
         """The cumulative totals as /debug/timing serves them (seconds and
-        counts; monotonic — readers subtract two snapshots). `dispatch_s`
-        and `starved_s` are sums of their parts; `clock_s` is the step
-        thread's clock at this snapshot, the denominator of a share."""
+        counts; monotonic — readers subtract two snapshots). `dispatch_s`,
+        `turn_s`, `starved_s` and `dry_s` are sums of their parts; `clock_s`
+        is the step thread's clock at this snapshot, the denominator of a
+        share."""
         tot = self._tot
         out = {k: (v * 1e-9 if k.endswith("_s") else v)
                for k, v in tot.items()}
         out["dispatch_s"] = sum(tot[p + "_s"] for p in DISPATCH_PHASES) * 1e-9
-        out["starved_s"] = sum(
-            tot["starved_" + p + "_s"] for p in PHASES) * 1e-9
+        out["turn_s"] = sum(tot[p + "_s"] for p in TURN_PHASES) * 1e-9
+        for key in ("starved_", "dry_"):
+            out[key + "s"] = sum(tot[key + p + "_s"] for p in PHASES) * 1e-9
         out["clock_s"] = (time.monotonic_ns() - self._t_origin) * 1e-9
         return out
 
@@ -320,19 +398,26 @@ class FlightRecorder:
     def dispatch(
         self, kind: str, rows: int, tokens: int,
         waiting: int = 0, running: int = 0, pool_usage: float = 0.0,
-        window: int = 0,
+        window: int = 0, ready=None,
     ) -> int:
         """One device dispatch (decode window / verify / prefill chunk),
         called when its enqueue has returned: the device has work again, so
-        an open starvation gap ends here. Returns the dispatch seq the
-        matching resolve()/discard() names."""
+        an open gap — starvation or dry — ends here. `ready`, a zero-argument
+        callable that says whether this program is complete, is what the
+        polls at the phase boundaries watch from here on (the newest
+        enqueued program only). Returns the dispatch seq the matching
+        resolve()/discard() names."""
         now = time.monotonic_ns()
+        if self._watch is not None:
+            self._poll(now)  # seen only now: a dry gap of length 0
         with self._lock:
             self._seq += 1
             seq = self._seq
             self._open[seq] = (now * 1e-9, kind)
         self._tot["dispatch_n"] += 1
-        gap = self._flip_gap(now, None)
+        gap = self._close_gap(now)
+        self._watch, self._watch_seq, self._watch_kind = ready, seq, kind
+        self._poll_t = now
         self._append("dispatch", {
             "seq": seq, "kind": kind, "rows": rows, "tokens": tokens,
             "window": window, "waiting": waiting, "running": running,
@@ -352,11 +437,14 @@ class FlightRecorder:
                 del self._open[s]
             self._done = max(self._done, seq)
             emptied = self._done >= self._seq
+        fields = {"seq": seq, "phases": self._take_stamps()}
         if emptied:
-            self._flip_gap(now, kind)
-        self._append("resolve", {
-            "seq": seq, "phases": self._take_stamps(),
-        })
+            # the watched step itself, nothing enqueued behind it: the host
+            # knows this instant, so a dry gap hands over to a starvation gap
+            closed = self._empty(now, kind)
+            if closed is not None:
+                fields["gap"] = closed
+        self._append("resolve", fields)
 
     def discard(self, seq: int) -> None:
         """A dispatched pipeline step was rolled back (speculation
@@ -377,14 +465,26 @@ class FlightRecorder:
             if self._open or self._done >= self._seq:
                 return
             self._done = self._seq
-        self._flip_gap(time.monotonic_ns(), self._discarded_kind)
+        self._empty(time.monotonic_ns(), self._discarded_kind)
+
+    def _empty(self, now: int, after: str) -> dict | None:
+        """The host counts the device's queue as empty from `now`: nothing
+        is watched any more, an open dry gap closes (and is returned) and
+        the starvation gap opens in its place — one stretch of an empty
+        device, counted once, by `starved_n`, at the dispatch that ends it."""
+        self._watch = None
+        closed = None
+        if self._gap_key == "dry_":
+            closed = self._close_gap(now, counted=False)
+        self._open_gap(now, after)
+        return closed
 
     def fault(self, message: str) -> None:
         """A step-loop exception (transient or fatal)."""
         with self._lock:
             self._open.clear()  # the step loop abandoned it
             self._done = self._seq
-        self._flip_gap(time.monotonic_ns(), "start")
+        self._empty(time.monotonic_ns(), "start")
         self._append("fault", {"message": str(message)[:500]})
 
     def note(self, event: str, **fields) -> None:

@@ -1528,6 +1528,7 @@ class ModelRunner:
         nb = self._width_bucket(
             max((len(r.block_table) for r in work.requests), default=1)
         )
+        self.clock.poll()  # (the first of this build's polls: see below)
         # a first-seen program key pads up to an already-compiled shape
         # instead of stalling serving on a synchronous XLA compile
         exact_key = ("prefill", b_pad, t_pad, nb, want_lp, want_mt, want_gr)
@@ -1536,6 +1537,7 @@ class ModelRunner:
         )
         self._watch_dispatch(exact_key, aot_key)
         _, b_pad, t_pad, nb, _lp, use_mt, use_gr = aot_key
+        self.clock.poll()
 
         tok_shape = (t_pad,) if packed else (b_pad, t_pad)
         token_ids = np.zeros(tok_shape, np.int32)
@@ -1587,9 +1589,13 @@ class ModelRunner:
             temps[i], top_ps[i], top_ks[i] = s.temperature, s.top_p, s.top_k
             seeds[i] = s.seed
             counts[i] = len(req.output_token_ids)
+        # (the recorder's polls between phase boundaries: the build of a
+        # 2,048-token chunk is long enough to leave a dry gap's bracket wide)
+        self.clock.poll()
         write_ids = self._with_window_span(write_ids, work, bs)
         block_tables = self._group_tables(
             work.requests, pad_to=b_pad, width=nb)
+        self.clock.poll()
         lora_idx = np.zeros(b_pad, np.int32)
         for i, req in enumerate(work.requests):
             lora_idx[i] = req.lora_index
@@ -1772,21 +1778,22 @@ class ModelRunner:
                     ft = self._chain_vec_fn(prev.chain_vec, ft, idx_dev)
                 else:
                     ft = self._chain_fn(prev.tokens, ft, idx_dev)
+        put = self._put_polled
         with clock.span("h2d", kind="decode"):
             dyn_args = (
                 ft,
-                self._put(positions0, self._batch1),
-                self._put(block_tables, self._batch2),
-                self._put(lora_idx, self._batch1) if self._use_lora else None,
-                self._put(np.asarray(temps, np.float32), self._batch1),
-                self._put(np.asarray(top_ps, np.float32), self._batch1),
-                self._put(np.asarray(top_ks, np.int32), self._batch1),
-                self._put(step_key, self._rep),
-                self._put(seed_vals, self._batch1),
-                self._put(has_seed, self._batch1),
-                self._put(np.asarray(counts, np.int32), self._batch1),
-                self._put(min_toks, self._batch1),
-                self._put(stop_ids_arr, self._batch2),
+                put(positions0, self._batch1),
+                put(block_tables, self._batch2),
+                put(lora_idx, self._batch1) if self._use_lora else None,
+                put(np.asarray(temps, np.float32), self._batch1),
+                put(np.asarray(top_ps, np.float32), self._batch1),
+                put(np.asarray(top_ks, np.int32), self._batch1),
+                put(step_key, self._rep),
+                put(seed_vals, self._batch1),
+                put(has_seed, self._batch1),
+                put(np.asarray(counts, np.int32), self._batch1),
+                put(min_toks, self._batch1),
+                put(stop_ids_arr, self._batch2),
             )
         if want_gr:
             tc_dev, cd_dev, acc_dev = self._grammar_device_tables(
@@ -1927,8 +1934,11 @@ class ModelRunner:
     ):
         if self._sleeping_params_host is not None:
             raise RuntimeError("engine is sleeping; wake it before running")
+        clock = self.clock
+        clock.poll()  # around the RNG split: an enqueue of its own
         rng_before = self._rng
         self._rng, step_key = jax.random.split(self._rng)
+        clock.poll()
         has_seed = np.asarray([s is not None for s in seeds], bool)
         # 64-bit user seeds (legal per the OpenAI API) fold down to uint32
         seed_vals = np.asarray(
@@ -1940,30 +1950,30 @@ class ModelRunner:
             self._rep if token_ids.ndim == 1
             else self._seq2 if self._sp > 1 else self._batch2
         )
-        clock = self.clock
+        put = self._put_polled
         with clock.span("h2d", kind="prefill"):
             dyn_args = (
-                self._put(token_ids, tok_sh),
-                self._put(positions, tok_sh),
-                self._put(block_tables, self._batch2),
+                put(token_ids, tok_sh),
+                put(positions, tok_sh),
+                put(block_tables, self._batch2),
                 # (B*T,) for the sp path (B divisible by dp); (1,)
                 # placeholder (replicated) for the paged path
-                self._put(slots, self._batch1 if self._sp > 1 else self._rep),
-                self._put(context_lens, self._batch1),
-                self._put(chunk_lens, self._batch1),
-                self._put(write_ids, self._batch2),
-                self._put(start_off, self._batch1),
-                self._put(lora_idx, self._batch1) if self._use_lora else None,
-                self._put(sample_rows, self._batch1),
-                self._put(np.asarray(temps, np.float32), self._batch1),
-                self._put(np.asarray(top_ps, np.float32), self._batch1),
-                self._put(np.asarray(top_ks, np.int32), self._batch1),
-                self._put(step_key, self._rep),
-                self._put(seed_vals, self._batch1),
-                self._put(has_seed, self._batch1),
-                self._put(np.asarray(counts, np.int32), self._batch1),
-                self._put(min_toks, self._batch1),
-                self._put(stop_ids_arr, self._batch2),
+                put(slots, self._batch1 if self._sp > 1 else self._rep),
+                put(context_lens, self._batch1),
+                put(chunk_lens, self._batch1),
+                put(write_ids, self._batch2),
+                put(start_off, self._batch1),
+                put(lora_idx, self._batch1) if self._use_lora else None,
+                put(sample_rows, self._batch1),
+                put(np.asarray(temps, np.float32), self._batch1),
+                put(np.asarray(top_ps, np.float32), self._batch1),
+                put(np.asarray(top_ks, np.int32), self._batch1),
+                put(step_key, self._rep),
+                put(seed_vals, self._batch1),
+                put(has_seed, self._batch1),
+                put(np.asarray(counts, np.int32), self._batch1),
+                put(min_toks, self._batch1),
+                put(stop_ids_arr, self._batch2),
             )
         if want_grammar:
             dyn_args = dyn_args + (grammar_mask,)  # already device-resident
@@ -2529,6 +2539,12 @@ class ModelRunner:
         """Batch rows pad to a power of two ≥ dp so the batch axis shards
         evenly (dp is validated to be a power of two)."""
         return max(self._dp, self._pow2(b))
+
+    def _put_polled(self, x, sharding):
+        """`_put` behind one poll of the recorder: the copies of one dispatch
+        are its `h2d` phase's natural sub-steps (step thread only)."""
+        self.clock.poll()
+        return self._put(x, sharding)
 
     def _put(self, x, sharding):
         """Place a host array directly into its mesh sharding — one

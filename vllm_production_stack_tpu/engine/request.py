@@ -220,6 +220,16 @@ class Request:
             else self.output_token_ids[idx - np_]
         )
 
+    def tokens_between(self, start: int, end: int) -> list[int]:
+        """[token_at(i) for i in range(start, end)] by slices: a 2,048-token
+        chunk's row in two list operations, not 2,048 calls (which a
+        profiler's Python tracer stretches to ~10 ms of `sched`)."""
+        np_ = len(self.prompt_token_ids)
+        row = list(self.prompt_token_ids[start:min(end, np_)])
+        if end > np_:
+            row += self.output_token_ids[max(start - np_, 0):end - np_]
+        return row
+
 
 @dataclass
 class RequestOutput:

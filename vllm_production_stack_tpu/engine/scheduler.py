@@ -823,7 +823,7 @@ class Scheduler:
         idxs = range(start, start + chunk)
         work.add_row(
             request=req,
-            token_ids=[req.token_at(i) for i in idxs],
+            token_ids=req.tokens_between(start, start + chunk),
             positions=list(idxs),
             slot_mapping=(
                 [self._slot(req, i) for i in idxs]
