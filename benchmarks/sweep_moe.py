@@ -33,11 +33,29 @@ experts' bytes, and the kernel's largest difference from the einsum.
 
     python benchmarks/sweep_moe.py --decode [--models kanana-2-30b-a3b-8l,...]
         [--rows 16,32,64] [--shares 10,25,50,75,100]
+
+`--prefill` is the prefill arm (ISSUE 38), the evidence behind the
+"grouped_kernel" form: the same three expert stacks as served at 512 to
+8,192 tokens, the fused grouped-expert kernel
+(`ops/moe_experts_pallas.py:moe_prefill_experts`) against `ragged_dot` and
+(up to 2,048 tokens) the all-experts einsum, each as the model runs it —
+sort, gather, experts, un-sort, combine — and, `*_only`, the expert
+computation alone on rows that are already sorted; `aligned*` is the same
+kernel over the other layout (every group starts on a row-tile boundary:
+no tile straddles, M + E x tile rows gathered). The routing is an operand:
+`balanced` is a random router's, `skewed` the same logits plus a
+per-expert bias of 1.5 standard deviations. Prints one JSON line per
+(model, tokens, routing, variant): ms a layer and the share of 819 GB/s
+reached on the touched experts' and the held rows' bytes.
+
+    python benchmarks/sweep_moe.py --prefill [--models ...]
+        [--tokens 512,1024,2048,8192] [--row-tiles 128,256] [--aligned]
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -57,9 +75,17 @@ from vllm_production_stack_tpu.models.quantization import (  # noqa: E402
 from vllm_production_stack_tpu.models.registry import (  # noqa: E402
     resolve_model_config,
 )
+from vllm_production_stack_tpu.ops.moe_experts_pallas import (  # noqa: E402
+    moe_prefill_experts,
+)
 
 LOOP_ITERS = 2
 EINSUM_MAX_TOKENS = 2048  # beyond: three (tokens, 64, 1024) bf16 temps
+
+
+def _interpret() -> bool:
+    """Off the chip (a rehearsal) the Pallas kernels are interpreted."""
+    return jax.devices()[0].platform != "tpu"
 
 
 def all_experts(cfg, stacked, layer, x):
@@ -157,8 +183,7 @@ def decode_variants(cfg):
 
     def kernel(st, i, xf, topv, topi):
         return llama._experts_touched(
-            cfg, st, i, xf, topv, *route(topv, topi),
-            jax.devices()[0].platform != "tpu")  # interpreted off the chip
+            cfg, st, i, xf, topv, *route(topv, topi), _interpret())
 
     def einsum(st, i, xf, topv, topi):
         mp = jax.tree.map(lambda a: a[i], st)
@@ -200,20 +225,20 @@ def time_decode(fn, stacked, n_layers, x, routings) -> list[float]:
     return out
 
 
-def decode_arm(args) -> None:
+def run_arm(args, arm: str, per_model) -> None:
+    """`per_model(args, model, emit)` for each of `--models`; a chip call
+    returns the end of its output only, so the lines are also kept where
+    such a call brings files back from."""
     dev = jax.devices()[0]
-    # (a chip call returns the end of its output only: the lines are also
-    # kept where such a call brings files back from)
     os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/sweep_moe_decode.jsonl", "a") as kept:
+    with open(f"chiprun_out/sweep_moe_{arm}.jsonl", "a") as kept:
         def emit(row):
             for f in (sys.stdout, kept):
                 print(json.dumps(row), file=f, flush=True)
 
-        emit({"platform": dev.platform, "kind": dev.device_kind,
-              "arm": "decode"})
+        emit({"platform": dev.platform, "kind": dev.device_kind, "arm": arm})
         for model in args.models.split(","):
-            decode_model(args, model, emit)
+            per_model(args, model, emit)
 
 
 def decode_model(args, model: str, emit) -> None:
@@ -225,18 +250,7 @@ def decode_model(args, model: str, emit) -> None:
     h, it = cfg.hidden_size, cfg.expert_intermediate_size
     dt = jnp.dtype(cfg.dtype)
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
-
-    def make(key, *shape):
-        def leaf(kk):
-            w = (jax.random.normal(kk, shape, jnp.float32)
-                 * shape[-2] ** -0.5).astype(dt)
-            return _quantize(w) if quant else w
-
-        return jax.jit(leaf)(key)
-
-    stacked = {"gate": make(keys[0], n_layers, e, h, it),
-               "up": make(keys[1], n_layers, e, h, it),
-               "down": make(keys[2], n_layers, e, it, h)}
+    stacked = make_stack(cfg, n_layers, quant, keys)
     expert_bytes = 3 * h * it * (1 if quant else dt.itemsize)
     for n in [int(r) for r in args.rows.split(",")]:
         x = jax.random.normal(keys[3], (n, h), jnp.float32).astype(dt)
@@ -275,8 +289,212 @@ def decode_model(args, model: str, emit) -> None:
                 emit(line)
 
 
+def prefill_routings(cfg, n: int, key):
+    """name -> (topv (n, k), topi (n, k)) over the PUBLISHED experts."""
+    k1, k2 = jax.random.split(key)
+    logits = jax.random.normal(k1, (n, cfg.num_experts), jnp.float32)
+    bias = 1.5 * jax.random.normal(k2, (cfg.num_experts,), jnp.float32)
+    out = {}
+    for name, lg in (("balanced", logits), ("skewed", logits + bias)):
+        topv, topi = jax.lax.top_k(
+            jax.nn.softmax(lg, axis=-1), cfg.num_experts_per_tok)
+        out[name] = (topv / jnp.sum(topv, -1, keepdims=True),
+                     topi.astype(jnp.int32))
+    return out
+
+
+def _held_sizes(cfg, topi):
+    local = topi - cfg.first_expert_held
+    chosen = jax.nn.one_hot(local, cfg.held_experts, dtype=jnp.int32)
+    return jnp.sum(chosen, axis=(0, 1))
+
+
+def _sorted_pairs(cfg, topi):
+    """(pair -> held expert or E, sorted row -> pair, pair held here?), as
+    `_experts_grouped` sorts them."""
+    local = topi.reshape(-1) - cfg.first_expert_held
+    here = (local >= 0) & (local < cfg.held_experts)
+    pair_expert = jnp.where(here, local, cfg.held_experts)
+    return pair_expert, jnp.argsort(pair_expert, stable=True), here
+
+
+def _aligned(cfg, tm, stacked, layer, xf, topv, topi):
+    """The kernel over the OTHER layout: every group padded to whole row
+    tiles (group sizes rounded up are what the kernel is told, so no tile
+    straddles), the gather and the un-sort through the padded positions."""
+    n, k = topi.shape
+    e = cfg.held_experts
+    sizes = _held_sizes(cfg, topi)
+    pair_expert, order, here = _sorted_pairs(cfg, topi)
+    tiles_e = (sizes + tm - 1) // tm
+    pstart = (jnp.cumsum(tiles_e) - tiles_e) * tm
+    start = jnp.cumsum(sizes) - sizes
+    m_pad = n * k + e * tm
+    r = jnp.arange(m_pad, dtype=jnp.int32)
+    r_e = jnp.minimum(jnp.sum(
+        (r // tm)[:, None] >= jnp.cumsum(tiles_e)[None, :], axis=1), e - 1)
+    off = r - pstart[r_e]
+    srow = jnp.clip(start[r_e] + off, 0, n * k - 1)
+    token = jnp.where((off >= 0) & (off < sizes[r_e]), order[srow] // k, 0)
+    out = _kernel_mm(cfg, tm, True)(stacked, layer, xf[token], sizes)
+    pe = jnp.minimum(pair_expert, e - 1)
+    pos = pstart[pe] + jnp.argsort(order) - start[pe]
+    out = jnp.where(here[:, None], out[jnp.clip(pos, 0, m_pad - 1)], 0)
+    return jnp.einsum("nkh,nk->nh", out.reshape(n, k, -1),
+                      jnp.where(here.reshape(n, k), topv, 0).astype(xf.dtype))
+
+
+def _kernel_mm(cfg, tm: int, aligned: bool):
+    """(stacked, layer, rows, sizes) -> rows through the fused kernel;
+    `aligned`: the rows are laid out with every group on a tile boundary,
+    so the kernel is told the group sizes rounded up to whole tiles."""
+    def mm(st, i, xs, sizes):
+        if aligned:
+            sizes = (sizes + tm - 1) // tm * tm
+        return moe_prefill_experts(
+            xs, sizes, st["gate"], st["up"], st["down"], layer=i,
+            act=llama._activation(cfg), row_tile=tm, interpret=_interpret())
+    return mm
+
+
+def _ragged_mm(cfg):
+    def mm(st, i, xs, sizes):
+        row_expert = jnp.minimum(jnp.sum(
+            jnp.arange(xs.shape[0])[:, None] >= jnp.cumsum(sizes)[None, :],
+            axis=1), cfg.held_experts - 1)
+        inner = llama._activation(cfg)(
+            llama._grouped_mm(xs, st["gate"], i, sizes, row_expert)
+        ) * llama._grouped_mm(xs, st["up"], i, sizes, row_expert)
+        return llama._grouped_mm(inner, st["down"], i, sizes, row_expert)
+    return mm
+
+
+def _only(cfg, mm):
+    """The expert computation alone, on rows that are sorted already (the
+    carried array IS the sorted rows; rows behind the groups read 0)."""
+    def fn(st, i, xs, topv, topi):
+        sizes = _held_sizes(cfg, topi)
+        out = mm(st, i, xs, sizes)
+        row = jnp.arange(xs.shape[0])[:, None]
+        return jnp.where(row < jnp.sum(sizes), out, 0)
+    return fn
+
+
+def prefill_variants(cfg, n: int, row_tiles, aligned: bool):
+    """name -> (fn(stacked, layer, x, topv, topi) -> x-shaped, rows of x):
+    the whole forms carry the n tokens, the `*_only` ones the sorted rows
+    (n * k, and + E x tile where the groups sit on tile boundaries)."""
+    m = n * cfg.num_experts_per_tok
+
+    def kernel(st, i, xf, topv, topi):
+        return llama._experts_grouped(
+            cfg, st, i, xf, topv, topi, _held_sizes(cfg, topi), _interpret())
+
+    def ragged(st, i, xf, topv, topi):
+        return llama._experts_grouped(
+            cfg, st, i, xf, topv, topi, _held_sizes(cfg, topi))
+
+    def einsum(st, i, xf, topv, topi):
+        mp = jax.tree.map(lambda a: a[i], st)
+        chosen = jax.nn.one_hot(topi - cfg.first_expert_held,
+                                cfg.held_experts, dtype=jnp.float32)
+        return llama._experts_all(cfg, mp, xf, topv, chosen)
+
+    out = {"kernel": (kernel, n), "ragged": (ragged, n),
+           "ragged_only": (_only(cfg, _ragged_mm(cfg)), m)}
+    for tm in row_tiles:
+        out[f"kernel_only_tm{tm}"] = (_only(cfg, _kernel_mm(cfg, tm, False)), m)
+        if not aligned:
+            continue
+        out[f"aligned_tm{tm}"] = (functools.partial(_aligned, cfg, tm), n)
+        out[f"aligned_only_tm{tm}"] = (
+            _only(cfg, _kernel_mm(cfg, tm, True)), m + cfg.held_experts * tm)
+    if n <= EINSUM_MAX_TOKENS:
+        out["einsum"] = (einsum, n)
+    return out
+
+
+def make_stack(cfg, n_layers: int, quant, keys):
+    """The three expert leaves stacked over `n_layers`, as served."""
+    e = cfg.held_experts
+    h, it = cfg.hidden_size, cfg.expert_intermediate_size
+    dt = jnp.dtype(cfg.dtype)
+
+    def make(key, *shape):
+        def leaf(kk):
+            w = (jax.random.normal(kk, shape, jnp.float32)
+                 * shape[-2] ** -0.5).astype(dt)
+            return _quantize(w) if quant else w
+
+        return jax.jit(leaf)(key)
+
+    return {"gate": make(keys[0], n_layers, e, h, it),
+            "up": make(keys[1], n_layers, e, h, it),
+            "down": make(keys[2], n_layers, e, it, h)}
+
+
+def prefill_model(args, model: str, emit) -> None:
+    """One model's expert stack through every (tokens, routing, variant)."""
+    quant = dict(DECODE_MODELS).get(model)
+    cfg = resolve_model_config(model)
+    n_layers = args.layers or cfg.num_moe_layers
+    h, it = cfg.hidden_size, cfg.expert_intermediate_size
+    dt = jnp.dtype(cfg.dtype)
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    stacked = make_stack(cfg, n_layers, quant, keys)
+    expert_bytes = 3 * h * it * (1 if quant else dt.itemsize)
+    row_tiles = [int(t) for t in args.row_tiles.split(",")]
+    for n in [int(t) for t in args.tokens.split(",")]:
+        routings = prefill_routings(cfg, n, keys[4])
+        sizes = {name: jax.device_get(_held_sizes(cfg, r[1]))
+                 for name, r in routings.items()}
+        fns = prefill_variants(cfg, n, row_tiles, args.aligned)
+        one = {name: jax.jit(lambda st, xx, tv, ti, f=f: f(
+            st, n_layers - 1, xx, tv, ti))
+            for name, (f, rows) in fns.items() if rows == n}
+        x = jax.random.normal(keys[3], (n, h), jnp.float32).astype(dt)
+        for name, (fn, rows) in fns.items():
+            row = {"model": model, "quantization": quant, "tokens": n,
+                   "layers": n_layers, "variant": name}
+            try:
+                times = time_decode(
+                    fn, stacked, n_layers,
+                    jax.random.normal(
+                        keys[3], (rows, h), jnp.float32).astype(dt),
+                    list(routings.values()))
+            except Exception as err:  # a variant the compiler refuses
+                row["error"] = f"{type(err).__name__}: {err}"[:300]
+                emit(row)
+                continue
+            for (rname, r), ms in zip(routings.items(), times):
+                gs = sizes[rname]
+                touched, held = int((gs > 0).sum()), int(gs.sum())
+                need = touched * expert_bytes + 2 * held * h * dt.itemsize
+                line = dict(
+                    row, routing=rname, touched=touched, held_pairs=held,
+                    largest_group=int(gs.max()), ms=round(ms, 4),
+                    bytes_bound_ms=round(need / HBM_BYTES_PER_S * 1e3, 4),
+                    flops_bound_ms=round(
+                        2.0 * held * 3 * h * it / 197e12 * 1e3, 4),
+                    bw_share=round(
+                        100 * need / HBM_BYTES_PER_S / (ms * 1e-3), 1))
+                if name in one and name != "ragged":
+                    line["max_abs_diff_vs_ragged"] = round(float(jnp.max(
+                        jnp.abs(one[name](stacked, x, *r).astype(jnp.float32)
+                                - one["ragged"](stacked, x, *r).astype(
+                                    jnp.float32)))), 5)
+                emit(line)
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
+    ap.add_argument("--prefill", action="store_true",
+                    help="the prefill arm: fused grouped-expert kernel vs "
+                         "ragged_dot vs einsum at 512 to 8,192 tokens")
+    ap.add_argument("--row-tiles", default="128")
+    ap.add_argument("--aligned", action="store_true",
+                    help="--prefill: also the layout with every group on "
+                         "a row-tile boundary")
     ap.add_argument("--decode", action="store_true",
                     help="the decode arm: touched-experts kernel vs einsum "
                          "vs ragged_dot at given touched shares")
@@ -285,13 +503,18 @@ def main() -> None:
     ap.add_argument("--rows", default="16,32,64")
     ap.add_argument("--shares", default="10,25,50,75,100")
     ap.add_argument("--model", default="olmoe-1b-7b")
-    ap.add_argument("--tokens", default="16,512,1024,2048,8192")
+    ap.add_argument("--tokens", default=None,
+                    help="default 16,512,1024,2048,8192; --prefill: from 512")
     ap.add_argument("--layers", type=int, default=None,
                     help="layers of int8 experts, stacked as the model "
                          "holds them (default: the model's)")
     args = ap.parse_args()
     if args.decode:
-        return decode_arm(args)
+        return run_arm(args, "decode", decode_model)
+    if args.prefill:
+        args.tokens = args.tokens or "512,1024,2048,8192"
+        return run_arm(args, "prefill", prefill_model)
+    args.tokens = args.tokens or "16,512,1024,2048,8192"
     cfg = resolve_model_config(args.model)
     dev = jax.devices()[0]
     n_layers = args.layers or cfg.num_layers

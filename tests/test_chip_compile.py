@@ -274,11 +274,13 @@ def _moe_program_args(topo, cfg, batch, t, block=32, nb=64, window=8):
 
 
 def _assert_no_widened_experts(cfg, text):
-    """No operation of the program writes a bf16 copy of a stacked expert
-    leaf (E, in, out): int8 experts are read as int8."""
+    """No operation of the program writes a bf16 copy of one layer's expert
+    leaf (E, in, out): int8 experts are read as int8, and a slice of a
+    stacked leaf that fed a custom call (which XLA would copy) is not
+    taken."""
     import re
 
-    e, h, it = cfg.num_experts, cfg.hidden_size, cfg.intermediate_size
+    e, h, it = cfg.held_experts, cfg.hidden_size, cfg.expert_intermediate_size
     copies = [
         line[:200] for line in text.splitlines()
         if re.search(rf"= bf16\[({e},{h},{it}|{e},{it},{h})\]", line)
@@ -287,7 +289,7 @@ def _assert_no_widened_experts(cfg, text):
     assert not copies, copies
 
 
-def _packed_prefill(topo, cfg, rows, n, backend="xla"):
+def _packed_prefill(topo, cfg, rows, n, backend="xla", moe_backend="xla"):
     """The served prefill program, compiled: `rows` rows whose chunk tokens
     lie end to end on one axis of `n` positions (llama.forward with `pack`,
     blockwise KV write), two layers deep, int8 weights, the 64-page table."""
@@ -302,7 +304,7 @@ def _packed_prefill(topo, cfg, rows, n, backend="xla"):
             cfg, params, tokens, positions, kv, tables,
             jnp.zeros((1,), jnp.int32), lens,
             write_blocks={"ids": ids, "start_off": off, "chunk_lens": chunk},
-            backend=backend, moe_stats=stats,
+            backend=backend, moe_stats=stats, moe_backend=moe_backend,
             pack=llama.pack_index(chunk, lens, n))
         return (llama.compute_logits(cfg, params, hidden[-rows:, 0]), kv,
                 sum(s["touched"] for s in stats or ()))
@@ -321,21 +323,30 @@ def _cell_config(model):
         resolve_model_config(model, quantization="int8"), num_layers=2)
 
 
-@pytest.mark.parametrize("rows,tokens", [
-    (1, CHUNK),  # 512 tokens: every expert on every token
-    (2, CHUNK),  # still 512: the token SUM chooses, not rows x bucket
-    (2, 2 * CHUNK),  # 1,024 tokens: the grouped matmul over the routed pairs
+@pytest.mark.parametrize("rows,tokens,moe_backend", [
+    (1, CHUNK, "pallas"),  # 512 tokens: every expert on every token
+    (2, CHUNK, "pallas"),  # still 512: the token SUM chooses, not rows x bucket
+    # 1,024 tokens, the routed pairs sorted by expert: through the fused
+    # kernel on one TPU device (int8 tiles widened in VMEM), through
+    # ragged_dot where the program may hold no Pallas kernel (a mesh)
+    (2, 2 * CHUNK, "pallas"),
+    (2, 2 * CHUNK, "xla"),
 ])
-def test_moe_prefill_program_compiles_for_v5e(topo, rows, tokens):
+def test_moe_prefill_program_compiles_for_v5e(topo, rows, tokens,
+                                              moe_backend):
     """OLMoE-1B-7B's prefill step at published widths (two layers deep),
     int8 experts, on one described v5e chip."""
     from vllm_production_stack_tpu.models import llama
 
     cfg = _cell_config("olmoe-1b-7b")
-    text = _packed_prefill(topo, cfg, rows, tokens).as_text()
+    text = _packed_prefill(
+        topo, cfg, rows, tokens, moe_backend=moe_backend).as_text()
     _assert_no_widened_experts(cfg, text)
-    grouped = tokens > llama._ALL_EXPERTS_MAX_TOKENS
-    assert ("ragged-dot" in text) == grouped
+    form = llama.moe_experts_form(cfg, tokens, kernel=moe_backend != "xla")
+    assert form == {CHUNK: "all_experts"}.get(
+        tokens, "grouped" if moe_backend == "xla" else "grouped_kernel")
+    assert ("ragged-dot" in text) == (form == "grouped")
+    assert ("moe_prefill_experts" in text) == (form == "grouped_kernel")
 
 
 @pytest.mark.parametrize("model", ["qwen3-8b", "qwen2-7b", "olmoe-1b-7b"])
@@ -466,9 +477,10 @@ def _kanana(layers=2):
 def test_latent_prefill_program_compiles_for_v5e(topo):
     """Kanana-2-30B-A3B's served prefill step at published widths (two
     layers deep): two rows packed on 2,048 positions, the 1,024-page table,
-    the latent kernel, the routed pairs through the grouped matmul (2,048
-    tokens is above _ALL_EXPERTS_MAX_TOKENS), and no (rows, heads, T, S)
-    score array nor gathered history."""
+    the latent kernel, the routed pairs through the fused grouped-expert
+    kernel (2,048 tokens is above _ALL_EXPERTS_MAX_TOKENS) reading the
+    stacked leaves in place, and no (rows, heads, T, S) score array nor
+    gathered history."""
     import re
 
     from vllm_production_stack_tpu.models import llama
@@ -490,7 +502,7 @@ def test_latent_prefill_program_compiles_for_v5e(topo):
             cfg, params, tokens, positions, kv, tables,
             jnp.zeros((1,), jnp.int32), lens,
             write_blocks={"ids": ids, "start_off": off, "chunk_lens": chunk},
-            backend="pallas", moe_stats=stats,
+            backend="pallas", moe_stats=stats, moe_backend="pallas",
             pack=llama.pack_index(chunk, lens, n))
         return (llama.compute_logits(cfg, params, hidden[-rows:, 0]), kv,
                 sum(s["touched"] for s in stats))
@@ -499,8 +511,9 @@ def test_latent_prefill_program_compiles_for_v5e(topo):
         params, kv, i32(n), i32(n), i32(rows, nb), i32(rows), i32(rows),
         i32(rows, nbw), i32(rows)).compile()
     text = compiled.as_text()
-    assert "mla_prefill_attention" in text and "ragged-dot" in text
-    assert "paged_prefill_attention" not in text
+    assert "mla_prefill_attention" in text and "moe_prefill_experts" in text
+    assert "paged_prefill_attention" not in text and "ragged-dot" not in text
+    _assert_no_widened_experts(cfg, text)
     held = [line[:160] for line in text.splitlines()
             if re.search(rf"\[{rows},32,{n},{nb * 32}\]", line)
             or re.search(rf"\[{rows},{nb * 32},(1,)?640\]", line)]
@@ -592,4 +605,44 @@ def test_touched_experts_kernel_compiles_for_v5e(topo, model, quantization,
     _assert_kernel(compiled)
     # what the program holds beside its operands: the padded rows, not a
     # copy of anything expert-sized
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+@pytest.mark.parametrize("model,quantization,tokens", [
+    ("kanana-2-30b-a3b-8l", None, 2048),  # 12,288 sorted rows, 128 experts
+    ("laguna-s-2.1-ep4-9l", None, 2048),  # 20,480 rows, 64 held of 256
+    ("olmoe-1b-7b", "int8", 1024),  # int8 tiles widened in VMEM
+    ("mixtral-8x7b", None, 1024),  # an expert cut over its inner width
+])
+def test_grouped_experts_kernel_compiles_for_v5e(topo, model, quantization,
+                                                 tokens):
+    """The fused grouped-expert kernel of a prefill chunk at the cells'
+    expert stacks as served (every layer stacked, the last layer read; the
+    wide Mixtral expert two layers deep), inside the VMEM it asks for and
+    with nothing expert- or row-sized beside its operands."""
+    from vllm_production_stack_tpu.models.registry import resolve_model_config
+    from vllm_production_stack_tpu.ops import moe_experts_pallas as mk
+
+    cfg = resolve_model_config(model)
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    e, k = cfg.held_experts, cfg.num_experts_per_tok
+    h, it = cfg.hidden_size, cfg.expert_intermediate_size
+    layers = min(cfg.num_moe_layers, 16 if quantization else 8)
+    if model.startswith("mixtral"):
+        layers = 2
+        assert mk._inner_tiles(h, it, 2) > 1
+
+    def leaf(*shape):
+        if quantization:
+            return {"q": sds(shape, jnp.int8),
+                    "s": sds((*shape[:2], 1, shape[3]), jnp.float32)}
+        return sds(shape, jnp.bfloat16)
+
+    compiled = jax.jit(lambda x, g, wg, wu, wd: mk.moe_prefill_experts(
+        x, g, wg, wu, wd, layer=layers - 1, act=jax.nn.silu)).lower(
+        sds((tokens * k, h), jnp.bfloat16), sds((e,), jnp.int32),
+        leaf(layers, e, h, it), leaf(layers, e, h, it),
+        leaf(layers, e, it, h)).compile()
+    _assert_kernel(compiled)
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20

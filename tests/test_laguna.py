@@ -132,6 +132,32 @@ def test_held_experts_through_the_touched_kernel(cfg):
     assert t_k["moe_decode_held_pairs_n"] < t_k["moe_decode_pairs_n"]
 
 
+def test_held_experts_through_the_grouped_kernel(cfg, monkeypatch):
+    """A program that holds 4 of 16 experts and prefills chunks above the
+    token limit (lowered to 8 tokens here) sorts every routed pair but
+    runs only those that land on its own experts through the fused kernel
+    (interpret mode): the tokens and the counters are the `ragged_dot`
+    engine's, and `device.moe.prefill` says which form each holds."""
+    monkeypatch.setattr(llama, "_ALL_EXPERTS_MAX_TOKENS", 8)
+    model = _cut(cfg, 2)
+    prompts = [[5, 6, 7, 8, 9] * 4, list(range(20, 65))]
+    sp = SamplingParams(max_tokens=6, temperature=0.0, ignore_eos=True)
+    got = {}
+    for backend in ("xla", "pallas_interpret"):
+        engine = LLMEngine(_engine_config(model, backend))
+        got[backend] = (
+            [o["token_ids"] for o in engine.generate(prompts, sp)],
+            engine.flightrec.timing(),
+            engine.runner.device_report()["moe"]["prefill"]["resolved"])
+    (tok_x, t_x, form_x), (tok_k, t_k, form_k) = got.values()
+    assert (form_x, form_k) == ("grouped", "grouped_kernel")
+    assert tok_k == tok_x
+    for key in ("touched", "held_pairs", "pairs", "slots", "read"):
+        assert t_k[f"moe_prefill_{key}_n"] == t_x[f"moe_prefill_{key}_n"] > 0
+    assert t_k["moe_prefill_read_n"] == t_k["moe_prefill_touched_n"]
+    assert 0 < t_k["moe_prefill_held_pairs_n"] < t_k["moe_prefill_pairs_n"]
+
+
 def test_a_model_whose_layer_kinds_are_swapped_differs(cfg, params):
     """Head counts, ropes and windows belong to the layer's kind: the same
     tokens through a model with full and window layers swapped (the leaves

@@ -120,6 +120,36 @@ def test_read_counter_and_report_follow_the_program_form(
     assert t["moe_prefill_read_n"] == t["moe_prefill_slots_n"]
 
 
+@pytest.mark.parametrize("backend,form", [
+    ("xla", "grouped"), ("pallas_interpret", "grouped_kernel")])
+def test_a_long_chunk_through_the_grouped_forms_against_the_reference(
+        cfg, backend, form, monkeypatch):
+    """Above the token limit (lowered to 8 tokens here: the tiny engine's
+    chunks are 16 and 32) a prefill chunk sorts its routed pairs and runs
+    them through `ragged_dot`, or through the fused kernel where the
+    program may hold one (interpret mode): both within the float32
+    reference's tolerance, `device.moe.prefill` says which, and the read
+    counter counts the touched experts either way (ISSUE 38)."""
+    monkeypatch.setattr(llama, "_ALL_EXPERTS_MAX_TOKENS", 8)
+    engine = LLMEngine(_engine_config(cfg, backend))
+    rng = np.random.RandomState(2)
+    prompts = [list(map(int, rng.randint(1, cfg.vocab_size, n)))
+               for n in (70, 9, 33)]
+    served = reference_check.served_logprobs(engine, prompts, 6)
+    out = reference_check.compare(
+        reference, cfg, engine.runner.params, prompts, served,
+        routing_chunk=32)
+    assert out["max_abs_dlogprob"] <= LOGPROB_ATOL, out
+    assert out["routing_disagreement_share"] == 0.0, out
+    moe = engine.runner.device_report()["moe"]
+    assert moe["prefill"] == {
+        "resolved": form, "by_bucket": {"16": form, "32": form}}
+    t = engine.flightrec.timing()
+    assert 0 < t["moe_prefill_touched_n"] <= t["moe_prefill_slots_n"]
+    assert t["moe_prefill_read_n"] == t["moe_prefill_touched_n"]
+    assert t["moe_prefill_held_pairs_n"] == t["moe_prefill_pairs_n"]
+
+
 def test_forward_in_one_chunk_equals_the_expanded_reference(cfg, params):
     """Absorbed == expanded: one layer stack, one pass, no chunking."""
     tokens = np.random.RandomState(0).randint(1, cfg.vocab_size, 40).tolist()

@@ -1,6 +1,8 @@
 """Mixtral-family sparse MoE: HF logits parity, expert-parallel sharding on
 the virtual mesh, engine e2e, and checkpoint round-trip."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -502,10 +504,10 @@ def test_moe_mlp_takes_the_grouped_path_above_the_token_limit(monkeypatch):
 # -- the touched-experts kernel and the rule that chooses it (ISSUE 36) -------
 
 
-def _touched_case(case: str, dtype: str, quantization):
-    """(cfg, params, layer, xf, topv, topi) for one kernel case: a model of
-    two stacked expert layers at toy widths whose first two experts are held
-    elsewhere in the "held-share" cases."""
+def _touched_case(case: str, dtype: str, quantization, n: int = 16):
+    """(cfg, params, xf, topv, topi) for one kernel case of `n` tokens: a
+    model of two stacked expert layers at toy widths whose first and last
+    two experts are held elsewhere in the "held-share" cases."""
     from vllm_production_stack_tpu.models.quantization import quantize_params
 
     held = case in ("held-share", "none-touched")
@@ -517,7 +519,7 @@ def _touched_case(case: str, dtype: str, quantization):
     params = quantize_params(
         cfg, llama.init_params(cfg, jax.random.PRNGKey(3)))
     rng = np.random.RandomState(5)
-    n, e, k = 16, cfg.num_experts, cfg.num_experts_per_tok
+    e, k = cfg.num_experts, cfg.num_experts_per_tok
     xf = jnp.asarray(rng.normal(size=(n, cfg.hidden_size)), cfg.dtype)
     if case == "one-expert":  # every pair of every row on expert 5
         topi = np.full((n, k), 5)
@@ -623,12 +625,18 @@ def test_moe_mlp_reads_a_leading_dense_models_expert_stack_in_place():
     ("olmoe-1b-7b", 512, "int8", "all_experts"),  # a prefill chunk
     ("kanana-2-30b-a3b-8l", 64, None, "all_experts"),  # 0.95
     ("kanana-2-30b-a3b-8l", 32, None, "touched_kernel"),  # 0.79
-    ("kanana-2-30b-a3b-8l", 2048, None, "grouped"),
+    ("kanana-2-30b-a3b-8l", 2048, None, "grouped_kernel"),
     ("olmoe-1b-7b", 8, "int8", "touched_kernel"),  # 0.66: int8 leaves too
+    # the long-document cells' one prefill bucket, and the token limit
+    ("laguna-s-2.1-ep4-9l", 2048, None, "grouped_kernel"),
+    ("kanana-2-30b-a3b-8l", 512, None, "all_experts"),
+    ("kanana-2-30b-a3b-8l", 513, None, "grouped_kernel"),
+    ("olmoe-1b-7b", 513, "int8", "grouped_kernel"),  # int8 leaves too
+    ("tiny-mixtral", 1024, None, "grouped_kernel"),
 ])
 def test_the_shape_rule_picks_the_expert_form(model, tokens, quantization,
                                               want):
-    """One family, three forms, from (N, k, published E) alone."""
+    """One family, four forms, from (N, k, published E) alone."""
     from vllm_production_stack_tpu.models.registry import resolve_model_config
 
     cfg = resolve_model_config(model, quantization=quantization)
@@ -654,6 +662,180 @@ def test_the_runner_resolves_the_einsum_under_a_mesh(mesh_size,
             model=olmoe_cfg(), attention_backend=attention_backend),
         mesh=SimpleNamespace(size=mesh_size))
     assert ModelRunner._resolve_moe_backend(stub) == want
+
+
+@pytest.mark.parametrize("tokens,mesh_size,want", [
+    (2048, 1, "grouped_kernel"),
+    (2048, 2, "grouped"),  # any mesh keeps ragged_dot: no shard_map wrapper
+    (512, 1, "all_experts"),
+])
+def test_the_runner_reports_the_prefill_form(tokens, mesh_size, want):
+    """`/debug/programs` -> `device.moe.prefill` and the form the read
+    counter is told follow the rule and the mesh (ISSUE 38)."""
+    from types import SimpleNamespace
+
+    from vllm_production_stack_tpu.engine.model_runner import ModelRunner
+
+    stub = SimpleNamespace(
+        config=SimpleNamespace(
+            model=olmoe_cfg(), attention_backend="pallas_interpret"),
+        mesh=SimpleNamespace(size=mesh_size))
+    stub._moe_backend = ModelRunner._resolve_moe_backend(stub)
+    assert ModelRunner.moe_experts_form(stub, tokens) == want
+
+
+# -- the fused grouped-expert kernel of a prefill chunk (ISSUE 38) ------------
+
+
+def _np_grouped(xs, sizes, gate, up, down):
+    """Each group of sorted rows through its own expert, in float64."""
+    f = lambda a: np.asarray(a, np.float64)
+    out, lo = np.zeros(xs.shape, np.float64), 0
+    for e, n in enumerate(np.asarray(sizes)):
+        x = f(xs[lo:lo + n])
+        a = x @ f(gate[e])
+        out[lo:lo + n] = (a / (1 + np.exp(-a)) * (x @ f(up[e]))) @ f(down[e])
+        lo += n
+    return out, lo
+
+
+@pytest.mark.parametrize("sizes", [
+    [7, 0, 40, 3, 10],  # an expert without rows; tiles shared by 2-3 groups
+    [0, 0, 0, 0, 0],  # no pair landed here: every step is skipped
+    [77, 0, 0, 0, 0],  # one expert with every row
+    [0, 0, 0, 0, 37],  # the last expert only, rows behind it uncomputed
+    [16, 16, 16, 16, 13],  # groups on tile boundaries
+    [1, 1, 1, 1, 1],  # five groups inside one tile
+])
+def test_prefill_kernel_runs_each_group_under_its_expert(sizes):
+    """`moe_prefill_experts` (interpret mode) on 77 sorted rows — no
+    multiple of the 16-row tile — over layer 1 of stacked leaves: each
+    group's rows through that expert, whatever the tiles straddle."""
+    from vllm_production_stack_tpu.ops.moe_experts_pallas import (
+        moe_prefill_experts,
+    )
+
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    layers, e, h, inner, m = 2, 5, 128, 256, 77
+    gate = jax.random.normal(ks[0], (layers, e, h, inner)) * h ** -0.5
+    up = jax.random.normal(ks[1], (layers, e, h, inner)) * h ** -0.5
+    down = jax.random.normal(ks[2], (layers, e, inner, h)) * inner ** -0.5
+    xs = jax.random.normal(ks[3], (m, h))
+    got = moe_prefill_experts(
+        xs, jnp.asarray(sizes, jnp.int32), gate, up, down, layer=1,
+        act=jax.nn.silu, row_tile=16, interpret=True)
+    want, held = _np_grouped(xs, sizes, gate[1], up[1], down[1])
+    assert got.shape == xs.shape and got.dtype == xs.dtype
+    np.testing.assert_allclose(
+        np.asarray(got)[:held], want[:held], atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("sizes,want", [
+    # 3 tiles of 4 rows; group 0 = rows 0-5 (tiles 0, 1), group 2 = rows
+    # 6-6 (tile 1), group 3 = rows 7-11 (tiles 1, 2): five visits of 6 steps
+    ([6, 0, 1, 5], ([0, 0, 2, 3, 3, 3], [0, 1, 1, 1, 2, 2], 5)),
+    ([0, 0, 0, 0], ([3, 3, 3, 3, 3, 3], [0, 0, 0, 0, 0, 0], 0)),
+    ([0, 12, 0, 0], ([1, 1, 1, 1, 1, 1], [0, 1, 2, 2, 2, 2], 3)),
+])
+def test_visit_tables_walk_experts_then_tiles(sizes, want):
+    from vllm_production_stack_tpu.ops.moe_experts_pallas import visit_tables
+
+    expert, tile, lo, hi, n = visit_tables(
+        jnp.asarray(sizes, jnp.int32), 3, 4)
+    assert (expert.tolist(), tile.tolist(), int(n[0])) == want
+    assert hi.tolist() == np.cumsum(sizes).tolist()
+    assert (hi - lo).tolist() == sizes
+    # a tile's visits are consecutive steps: the tile never steps back
+    assert (np.diff(tile) >= 0).all()
+
+
+# 90 tokens x top-3 = 270 sorted rows: three row tiles of 128, the last short
+_prefill_case = functools.partial(_touched_case, n=90)
+
+
+def _grouped_forms(cfg, params, layer, xf, topv, topi):
+    """(all-experts einsum, ragged_dot, fused kernel, group sizes)."""
+    local = topi - cfg.first_expert_held if cfg.num_experts_held else topi
+    chosen = jax.nn.one_hot(local, cfg.held_experts, dtype=jnp.float32)
+    sizes = jnp.sum(chosen, axis=(0, 1)).astype(jnp.int32)
+    stacked = params["layers"]["moe"]
+    mp = jax.tree.map(lambda a: a[layer], stacked)
+    return (llama._experts_all(cfg, mp, xf, topv, chosen),
+            llama._experts_grouped(cfg, stacked, layer, xf, topv, topi, sizes),
+            llama._experts_grouped(
+                cfg, stacked, layer, xf, topv, topi, sizes, True),
+            sizes)
+
+
+@pytest.mark.parametrize("dtype,quantization", [
+    ("bfloat16", None), ("bfloat16", "int8"), ("float32", None)])
+@pytest.mark.parametrize("case", [
+    "random", "one-expert", "all-touched", "held-share", "none-touched"])
+def test_grouped_kernel_equals_ragged_dot_and_all_experts(
+        case, dtype, quantization):
+    """The "grouped_kernel" form (interpret mode) against its two oracles
+    on layer 1 of the stacked leaves: equal to the rounding of the model's
+    dtype (the kernel rounds once where `ragged_dot` rounds three times),
+    with an expert that got no row ("random": expert 7), one that got every
+    row, and exact zeros from pairs that land on experts held elsewhere."""
+    cfg, params, xf, topv, topi = _prefill_case(case, dtype, quantization)
+    dense, ragged, kernel, sizes = _grouped_forms(
+        cfg, params, 1, xf, topv, topi)
+    assert int(jnp.sum(sizes)) == {
+        "none-touched": 0, "held-share": int(jnp.sum(sizes))}.get(case, 270)
+    if case == "random":
+        assert int(sizes[-1]) == 0
+    assert kernel.shape == dense.shape and kernel.dtype == dense.dtype
+    if case == "none-touched":
+        assert not np.asarray(kernel, np.float32).any()
+    if case == "held-share":
+        # a token none of whose experts is held here gets exact zeros
+        away = np.asarray(jnp.all((topi < 2) | (topi > 5), axis=1))
+        assert away.any()
+        assert not np.asarray(kernel, np.float32)[away].any()
+    tol = 3e-2 if dtype == "bfloat16" else 1e-5
+    for oracle in (dense, ragged):
+        np.testing.assert_allclose(
+            np.asarray(kernel, np.float32), np.asarray(oracle, np.float32),
+            atol=tol * float(jnp.max(jnp.abs(dense.astype(jnp.float32))) + 1),
+            rtol=tol)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_a_rows_grouped_output_does_not_depend_on_its_chunk(dtype):
+    """Bit for bit: token 7 among 89 others, and the same token where the
+    others are zeros routed elsewhere — its pairs then sit at other rows of
+    other tiles of the sorted order, under the same experts."""
+    cfg, params, xf, topv, topi = _prefill_case("random", dtype, None)
+    *_, among, _ = _grouped_forms(cfg, params, 1, xf, topv, topi)
+    alone_x = jnp.zeros_like(xf).at[7].set(xf[7])
+    others = (topi[7] + 1) % cfg.num_experts  # a rotation of token 7's
+    alone_i = jnp.broadcast_to(others, topi.shape).at[7].set(topi[7])
+    *_, alone, _ = _grouped_forms(cfg, params, 1, alone_x, topv, alone_i)
+    assert np.asarray(alone[7]).tobytes() == np.asarray(among[7]).tobytes()
+
+
+def test_moe_mlp_takes_the_grouped_kernel_where_it_may_hold_one(monkeypatch):
+    """`_moe_mlp` above the token limit: ragged_dot under the XLA backend,
+    the fused kernel under a backend that may hold one, the same result
+    and the same routing report."""
+    cfg = olmoe_cfg()
+    params = llama.init_params(cfg, jax.random.PRNGKey(7))
+    mp = jax.tree.map(lambda a: a[0], params["layers"]["moe"])
+    x = jax.random.normal(jax.random.PRNGKey(8), (2, 12, cfg.hidden_size))
+    monkeypatch.setattr(llama, "_ALL_EXPERTS_MAX_TOKENS", 23)
+    forms = []
+    real = llama._experts_grouped
+    monkeypatch.setattr(
+        llama, "_experts_grouped",
+        lambda *a: forms.append(len(a)) or real(*a))
+    y_x, r_x = llama._moe_mlp(cfg, mp, x)
+    y_k, r_k = llama._moe_mlp(cfg, mp, x, None, "pallas_interpret")
+    assert forms == [7, 8]  # the kernel form hands on `interpret`
+    np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_x),
+                               atol=1e-5, rtol=1e-5)
+    assert int(r_k["touched"]) == int(r_x["touched"])
+    np.testing.assert_array_equal(r_k["experts"], r_x["experts"])
 
 
 @pytest.mark.parametrize("model", ["tiny-llama", "tiny-olmoe", "tiny-mixtral"])
@@ -696,6 +878,38 @@ def test_programs_the_rule_leaves_alone_are_the_same_text(model, program):
         for b in ("xla", "pallas")}
     assert texts["xla"] == texts["pallas"]
     assert "moe_decode_experts" not in texts["pallas"]
+    assert "moe_prefill_experts" not in texts["pallas"]
+
+
+@pytest.mark.parametrize("model", ["tiny-olmoe", "tiny-mixtral", "tiny-kanana"])
+def test_a_long_prefill_program_holds_the_kernel_or_ragged_dot(model):
+    """Above the token limit (16 rows x 64 = 1,024 tokens) the backend does
+    decide: ragged_dot and no kernel under XLA (the parent's program), the
+    kernel and no ragged_dot where the program may hold one."""
+    from vllm_production_stack_tpu.models.registry import resolve_model_config
+
+    cfg = resolve_model_config(model)
+    params = jax.eval_shape(
+        lambda k: llama.init_params(cfg, k), jax.random.PRNGKey(0))
+    kv = jax.eval_shape(lambda: llama.init_kv_cache(cfg, 16, 8))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    rows = 16
+
+    def prefill(moe_backend, params, kv, tokens, tables, lens):
+        return llama.forward(
+            cfg, params, tokens, tokens, kv, tables,
+            jnp.zeros((rows * 64,), jnp.int32), lens, moe_stats=[],
+            moe_backend=moe_backend)
+
+    # (a jaxpr, not a lowering: off the TPU only interpret mode lowers)
+    texts = {
+        b: str(jax.make_jaxpr(functools.partial(prefill, b))(
+            params, kv, i32(rows, 64), i32(rows, 8), i32(rows)))
+        for b in ("xla", "pallas")}
+    assert "ragged_dot" in texts["xla"]
+    assert "moe_prefill_experts" not in texts["xla"]
+    assert "moe_prefill_experts" in texts["pallas"]
+    assert "ragged_dot" not in texts["pallas"]
 
 
 def test_olmoe_checkpoint_roundtrip_and_config_parse(tmp_path):

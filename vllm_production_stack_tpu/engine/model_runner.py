@@ -751,7 +751,8 @@ class ModelRunner:
 
     def moe_experts_form(self, tokens: int) -> str:
         """The form a program of `tokens` tokens computes its expert layers
-        in: "touched_kernel" | "all_experts" | "grouped"."""
+        in: "touched_kernel" | "all_experts" | "grouped" |
+        "grouped_kernel"."""
         return llama.moe_experts_form(
             self.config.model, tokens, kernel=self._moe_backend != "xla")
 
@@ -812,14 +813,19 @@ class ModelRunner:
             },
         }
         if self.config.model.num_experts:
-            # the expert computation a decode program holds follows from
-            # its row bucket (`resolved`: the widest, what a full batch runs)
-            buckets = self.config.scheduler.decode_buckets
-            report["moe"] = {"decode": {
-                "resolved": self.moe_experts_form(max(buckets)),
-                "by_bucket": {
-                    str(n): self.moe_experts_form(n) for n in buckets},
-            }}
+            # the expert computation a program holds follows from its row
+            # (decode) or token (prefill) bucket; `resolved`: the widest,
+            # what a full batch or a full chunk runs
+            sched = self.config.scheduler
+            report["moe"] = {
+                phase: {
+                    "resolved": self.moe_experts_form(max(buckets)),
+                    "by_bucket": {
+                        str(n): self.moe_experts_form(n) for n in buckets},
+                }
+                for phase, buckets in (("decode", sched.decode_buckets),
+                                       ("prefill", sched.prefill_buckets))
+            }
         return report
 
     def _compute_hoist_budget(self) -> int:

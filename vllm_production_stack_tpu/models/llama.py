@@ -41,7 +41,7 @@ from ..ops.mla_attention_pallas import (
     mla_decode_attention,
     mla_prefill_attention,
 )
-from ..ops.moe_experts_pallas import moe_decode_experts
+from ..ops.moe_experts_pallas import moe_decode_experts, moe_prefill_experts
 from ..ops.paged_attention_pallas import (
     paged_decode_attention,
     paged_decode_attention_sharded,
@@ -621,7 +621,7 @@ def _mlp_block(cfg: ModelConfig, lp: dict, x: jax.Array, proj,
         return res + mlp_out
 
 
-# The three forms of `_moe_mlp`'s expert computation and the two constants
+# The four forms of `_moe_mlp`'s expert computation and the two constants
 # that choose between them, measured on a v5e chip (benchmarks/sweep_moe.py;
 # ms a layer, the expert stacks as served: every layer's leaves stacked, the
 # layers run in turn).
@@ -670,6 +670,37 @@ def _mlp_block(cfg: ModelConfig, lp: dict, x: jax.Array, proj,
 #     8192     (3 GiB of temps)     18.16           0.49 | 4.19
 # XLA's grouped kernel pays for groups of two rows; from ~1k tokens the
 # einsum's E/k-fold FLOPs lose: `_ALL_EXPERTS_MAX_TOKENS`.
+#
+# Many tokens, the fused grouped-expert kernel (`--prefill`, PERF.md §6 PR
+# 38): the sorted pairs through `moe_prefill_experts` against three
+# `ragged_dot` calls and the einsum, each with its sort, gather, un-sort and
+# combine as the model runs it, under a random router's (balanced) routing;
+# in brackets the expert computation alone on sorted rows, and the share of
+# 819 GB/s it reaches on the touched experts' and the held rows' bytes:
+#   experts (stack)         tokens   kernel (alone, share)   ragged_dot (alone)   einsum
+#   128 x 2048 x 768 bf16      512    1.916 (1.807, 83%)      4.719 (4.731)        3.973
+#    (Kanana-2, 7 layers)    1,024    2.208 (2.027, 76%)      5.062 (4.998)        7.937
+#                            2,048    2.683 (2.546, 63%)      5.610 (5.614)       16.809
+#                            8,192    7.649 (5.410, 36%)     13.306 (11.247)         -
+#   64 held of 256 x 3072      512    2.013 (1.833, 82%)      4.158 (4.020)        3.985
+#    x 1024 bf16 (Laguna,    1,024    2.953 (2.178, 69%)      4.721 (4.511)        9.217
+#    8 layers; 3/4 of the    2,048    4.288 (2.922, 53%)      6.798 (5.557)       14.922
+#    sorted rows elsewhere)  8,192   12.245 (6.697, 27%)     16.176 (10.640)         -
+#   64 x 2048 x 1024 int8      512    0.944 (0.843, 63%)      3.206 (3.117)        2.499
+#    (OLMoE, 16 layers)      1,024    1.361 (1.209, 48%)      3.864 (3.741)        5.538
+#                            2,048    2.487 (2.015, 33%)      5.370 (5.383)       10.277
+#                            8,192    8.238 (6.521, 18%)     17.667 (15.479)         -
+# (a skewed routing — the same logits plus a per-expert bias of 1.5 sigma, 78
+# / 34 / 58 experts touched at 2,048 tokens — reads 2.044 / 3.357 / 2.413
+# against ragged_dot's 3.912 / 4.771 / 5.174.) The kernel wins 1.6-2.6 x at
+# every size, int8 leaves included (widened a tile at a time in VMEM), so the
+# "grouped_kernel" form takes every leaf dtype; at 512 tokens it also beats
+# the einsum 2.0-2.6 x, which `_ALL_EXPERTS_MAX_TOKENS` does not use yet
+# (PERF.md §7). The "alone" columns include ~0.2-0.4 ms of the sweep's own
+# renormalisation of the carried rows; what the kernel leaves on the table
+# is the one-step lookahead of its pipeline (an expert's copy overlaps only
+# the last visit of the expert before it) and, from ~4k tokens, MXU passes
+# of 128 rows (78% of the peak; 256 rows reach 94%).
 _ALL_EXPERTS_MAX_TOKENS = 512
 _TOUCHED_KERNEL_MAX_SHARE = 0.8
 
@@ -783,10 +814,16 @@ def _experts_all(cfg, mp, xf, topv, chosen):
         return jnp.einsum("neh,ne->nh", out, w.astype(xf.dtype))
 
 
-def _experts_grouped(cfg, stacked, layer, xf, topv, topi, group_sizes):
+def _experts_grouped(cfg, stacked, layer, xf, topv, topi, group_sizes,
+                     interpret: bool | None = None):
     """The routed (token, expert) pairs sorted by expert, each expert's rows
     one group of a grouped matmul (over layer `layer` of the `stacked`
-    expert leaves), then un-sorted, weighted and summed."""
+    expert leaves), then un-sorted, weighted and summed. The grouped matmul
+    is three `ragged_dot` calls (`interpret` None: the "grouped" form), or
+    the fused Pallas kernel that reads each expert once and keeps the
+    (pairs, inner) intermediate in VMEM (ops/moe_experts_pallas.py:
+    moe_prefill_experts, the "grouped_kernel" form; `interpret`: run it in
+    interpret mode) — the sort and the un-sort are the same."""
     n, k = topi.shape
     with jax.named_scope("moe_dispatch"):
         pair_expert = topi.reshape(n * k)
@@ -802,11 +839,17 @@ def _experts_grouped(cfg, stacked, layer, xf, topv, topi, group_sizes):
         row_expert = pair_expert[order]
         xs = xf[order // k]  # (N*k, h): each pair's token, expert-major
     with jax.named_scope("moe_experts"):
-        inner = _activation(cfg)(
-            _grouped_mm(xs, stacked["gate"], layer, group_sizes, row_expert)
-        ) * _grouped_mm(xs, stacked["up"], layer, group_sizes, row_expert)
-        out = _grouped_mm(
-            inner, stacked["down"], layer, group_sizes, row_expert)
+        if interpret is None:
+            inner = _activation(cfg)(_grouped_mm(
+                xs, stacked["gate"], layer, group_sizes, row_expert)
+            ) * _grouped_mm(xs, stacked["up"], layer, group_sizes, row_expert)
+            out = _grouped_mm(
+                inner, stacked["down"], layer, group_sizes, row_expert)
+        else:
+            out = moe_prefill_experts(
+                xs, group_sizes, stacked["gate"], stacked["up"],
+                stacked["down"], layer=layer, act=_activation(cfg),
+                interpret=interpret)
     with jax.named_scope("moe_combine"):
         # pair p sits at sorted row inv[p]
         out = out[jnp.argsort(order)].reshape(n, k, -1)
@@ -826,11 +869,11 @@ def expected_touched_share(cfg: ModelConfig, n: int) -> float:
 
 def moe_experts_form(cfg: ModelConfig, n: int, *, kernel: bool) -> str:
     """How a program of `n` tokens computes an expert layer (_moe_mlp), from
-    static shapes alone: "touched_kernel" | "all_experts" | "grouped".
-    `kernel`: the program may hold the Pallas kernel (`moe_backend` is not
-    "xla": one TPU device, no mesh)."""
+    static shapes alone: "touched_kernel" | "all_experts" | "grouped" |
+    "grouped_kernel". `kernel`: the program may hold a Pallas kernel
+    (`moe_backend` is not "xla": one TPU device, no mesh)."""
     if n > _ALL_EXPERTS_MAX_TOKENS:
-        return "grouped"
+        return "grouped_kernel" if kernel else "grouped"
     if kernel and expected_touched_share(cfg, n) < _TOUCHED_KERNEL_MAX_SHARE:
         return "touched_kernel"
     return "all_experts"
@@ -859,9 +902,10 @@ def _moe_mlp(cfg: ModelConfig, mp: dict, x: jax.Array,
     softmax over ALL expert logits in float32, take top-k, and renormalize
     the selected weights to sum to 1 only under `norm_topk_prob`.
 
-    The expert computation is ONE family in three forms, chosen by
-    `moe_experts_form` from the static token count N, the top-k and the
-    published expert count (table above; no flag, no model name):
+    The expert computation is ONE family in four forms, chosen by
+    `moe_experts_form` from the static token count N, the top-k, the
+    published expert count and whether the program may hold a Pallas
+    kernel (tables above; no flag, no model name):
 
     - "touched_kernel" — few tokens of a many-expert model, where the N*k
       pairs cannot cover the experts (the expected touched share under a
@@ -881,10 +925,21 @@ def _moe_mlp(cfg: ModelConfig, mp: dict, x: jax.Array,
       expert bytes (`_experts_all`).
     - "grouped" — more than `_ALL_EXPERTS_MAX_TOKENS` tokens (a prefill
       program): the work follows the routed pairs (`_experts_grouped`) —
-      B*T*k rows whatever the routing, an expert without rows costs
-      nothing; the all-experts form would spend num_experts/top_k times
-      the FLOPs and hold three (B*T, E, inter) intermediates, 3 GiB at 64
-      experts and 16 x 512 tokens.
+      B*T*k rows sorted by expert whatever the routing, an expert without
+      rows costs nothing; the all-experts form would spend
+      num_experts/top_k times the FLOPs and hold three (B*T, E, inter)
+      intermediates, 3 GiB at 64 experts and 16 x 512 tokens. Three
+      `ragged_dot` calls a layer: what a program under an ep / tp mesh, on
+      the CPU and under `backend` "xla" runs (no shard_map wrapper), and
+      the kernel's oracle in the tests.
+    - "grouped_kernel" — the same sorted pairs where the program may hold a
+      Pallas kernel: ONE fused kernel a layer
+      (ops/moe_experts_pallas.py:moe_prefill_experts) walks the held
+      experts in ascending order, brings each expert's gate, up and down
+      matrices across HBM once a layer and chunk and runs its rows under
+      them tile by tile; no (pairs, inner) intermediate in HBM. The sort,
+      the gather and the un-sort are the "grouped" form's. int8 leaves
+      take it too (widened a tile at a time in VMEM).
 
     Every form computes every routed pair (no capacity, no dropped token)
     from static shapes and reads int8 experts as int8; under expert
@@ -921,6 +976,9 @@ def _moe_mlp(cfg: ModelConfig, mp: dict, x: jax.Array,
             stacked = (jax.tree.map(lambda a: a[None], mp), 0)
         if form == "grouped":
             y = _experts_grouped(cfg, *stacked, xf, topv, topi, group_sizes)
+        elif form == "grouped_kernel":
+            y = _experts_grouped(cfg, *stacked, xf, topv, topi, group_sizes,
+                                 backend == "pallas_interpret")
         else:
             y = _experts_touched(cfg, *stacked, xf, topv, chosen,
                                  group_sizes, backend == "pallas_interpret")
