@@ -1,0 +1,155 @@
+"""The KDA kernels on the chip: checked against the token-by-token
+recurrence (XLA, float32 at the highest matmul precision), then timed — the
+evidence behind the shares `kda_prefill_roofline` and `kda_decode_roofline`
+read in the cell (PERF.md §6, PR 39).
+
+    python benchmarks/sweep_kda.py
+
+At Solar-Open2's linear-attention shapes (64 heads x 128 keys and values,
+bf16 q, k, v, float32 state), one layer:
+
+- check: `kda_prefill_attention` on a packed 2,048-token axis (one row; two
+  rows with a seam inside a block; a continued row, a fresh row and a
+  padding row) and `kda_decode_attention` at 16 rows, against
+  ops/kda_attention.py's XLA forms;
+- time: the prefill kernel's ms a 2,048-token chunk and layer, the decode
+  kernel's ms a 16-row step and layer, with the share of the HBM bound of
+  what each must move (benchmarks/chip/costs_kda.py's counts).
+
+One JSON line per measurement, kept in chiprun_out/sweep_kda.jsonl too; the
+last line is {"ok": ...}.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+from vllm_production_stack_tpu.ops import kda_attention as kda  # noqa: E402
+
+H, D, N, SLOTS = 64, 128, 2048, 34
+HBM_BYTES_PER_S = 819e9  # benchmarks/chip/peaks.json, TPU v5 lite
+OUT = os.path.join(REPO, "chiprun_out", "sweep_kda.jsonl")
+# float32 state on both sides; q, k, v are the same bf16 values; what
+# differs is a 64-token block solved at once against 64 updates in turn
+ATOL, RTOL = 2e-3, 2e-3
+
+
+def emit(obj: dict) -> None:
+    print(json.dumps(obj), flush=True)
+    os.makedirs(os.path.dirname(OUT), exist_ok=True)
+    with open(OUT, "a") as f:
+        f.write(json.dumps(obj) + "\n")
+
+
+def inputs(rng, n):
+    def unit(x):
+        return x / np.linalg.norm(x, axis=-1, keepdims=True)
+
+    q = unit(rng.randn(n, H, D)) * D ** -0.5
+    k = unit(rng.randn(n, H, D))
+    v = rng.randn(n, H, D)
+    # decays as the presets draw them: rate log U(1, 16), softplus of a
+    # unit-normal input around a bias for a step of 0.001-0.1
+    rate = np.exp(rng.uniform(0, np.log(16), (1, H, 1)))
+    step = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), (1, H, D)))
+    bias = step + np.log(-np.expm1(-step))
+    g = -rate * np.log1p(np.exp(rng.randn(n, H, D) + bias))
+    beta = 2 / (1 + np.exp(-rng.randn(n, H)))
+    return (jnp.asarray(q, jnp.bfloat16), jnp.asarray(k, jnp.bfloat16),
+            jnp.asarray(v, jnp.bfloat16), jnp.asarray(g, jnp.float32),
+            jnp.asarray(beta, jnp.float32))
+
+
+def timed(fn, *args, reps=5):
+    out = fn(*args)
+    jax.block_until_ready(out)
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / reps, out
+
+
+def main() -> int:
+    dev = jax.devices()[0]
+    emit({"platform": dev.platform, "kind": dev.device_kind})
+    rng = np.random.RandomState(0)
+    ok = True
+    pool = jnp.asarray(rng.randn(SLOTS, H, D, D) * 0.1, jnp.float32)
+    q, k, v, g, beta = inputs(rng, N)
+    for name, lens, fresh in (
+            ("one_row", (N, 0), (True, False)),
+            ("seam_inside_a_block", (1000, 1048), (False, True)),
+            ("continued_fresh_padding", (700, 900, 0), (False, True, False))):
+        b = len(lens)
+        lens_a = jnp.asarray(lens, jnp.int32)
+        off = jnp.cumsum(lens_a) - lens_a
+        read = jnp.arange(1, b + 1, dtype=jnp.int32)
+        write = read + 17
+        fresh_a = jnp.asarray(fresh)
+        kern = jax.jit(lambda p: kda.kda_prefill_attention(
+            p, read, write, fresh_a, q, k, v, g, beta, off, lens_a))
+        secs, (o, new) = timed(kern, pool)
+        # the recurrence, rows first
+        idx = jnp.clip(off[:, None] + jnp.arange(N)[None], 0, N - 1)
+        with jax.default_matmul_precision("highest"):
+            want_o, want = jax.jit(lambda p: kda.kda_prefill_xla(
+                p, read, write, fresh_a, q[idx], k[idx], v[idx], g[idx],
+                beta[idx], lens_a))(pool)
+        errs = []
+        for r, n in enumerate(lens):
+            if n:
+                a = np.asarray(o[int(off[r]):int(off[r]) + n])
+                w = np.asarray(want_o[r, :n])
+                errs.append(float(np.max(np.abs(a - w) - RTOL * np.abs(w))))
+        # (a row without tokens has no work item: the kernel leaves its
+        # write slot as it was, the XLA form copies what it read)
+        wrote = jnp.asarray([int(write[r]) for r, n in enumerate(lens) if n])
+        s_err = float(jnp.max(jnp.abs(new[wrote] - want[wrote])
+                              - RTOL * jnp.abs(want[wrote])))
+        good = max(errs) <= ATOL and s_err <= ATOL
+        ok &= good
+        tokens = sum(lens)
+        need = (tokens * (H * D * (4 * 2 + 4) + H * 4)
+                + sum(1 for n in lens if n) * 2 * H * D * D * 4)
+        emit({"kernel": "kda_prefill_attention", "case": name, "ok": good,
+              "o_err_over_tol": max(errs), "state_err_over_tol": s_err,
+              "ms": secs * 1e3, "tokens": tokens,
+              "hbm_bound_ms": need / HBM_BYTES_PER_S * 1e3,
+              "share_of_hbm_bound": need / HBM_BYTES_PER_S / secs})
+    rows = 16
+    qd, kd, vd, gd, bd = inputs(rng, rows)
+    read = jnp.arange(1, rows + 1, dtype=jnp.int32)
+    for name, write in (("in_place", read), ("other_bank", read + 17)):
+        kern = jax.jit(lambda p: kda.kda_decode_attention(
+            p, read, write, qd, kd, vd, gd, bd))
+        secs, (o, new) = timed(kern, pool, reps=20)
+        with jax.default_matmul_precision("highest"):
+            want_o, want = jax.jit(lambda p: kda.kda_decode_xla(
+                p, read, write, qd, kd, vd, gd, bd))(pool)
+        o_err = float(jnp.max(jnp.abs(o - want_o) - RTOL * jnp.abs(want_o)))
+        s_err = float(jnp.max(jnp.abs(new - want) - RTOL * jnp.abs(want)))
+        good = o_err <= ATOL and s_err <= ATOL
+        ok &= good
+        need = rows * (2 * H * D * D * 4 + H * D * (3 * 2 + 8) + H * 4)
+        emit({"kernel": "kda_decode_attention", "case": name, "ok": good,
+              "o_err_over_tol": o_err, "state_err_over_tol": s_err,
+              "ms": secs * 1e3, "rows": rows,
+              "hbm_bound_ms": need / HBM_BYTES_PER_S * 1e3,
+              "share_of_hbm_bound": need / HBM_BYTES_PER_S / secs})
+    emit({"ok": bool(ok)})
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -192,7 +192,7 @@ def test_the_gate_multiplies_each_head(cfg, params):
     model = _cut(cfg, 1)
     p = llama.init_params(model, jax.random.PRNGKey(0))
     p["layers"]["attn"]["wg"] = jnp.zeros_like(p["layers"]["attn"]["wg"])
-    ungated = dataclasses.replace(model, attn_output_gate=False)
+    ungated = dataclasses.replace(model, attn_output_gate="")
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 6, model.hidden_size))
     pos = jnp.arange(6)[None]
 

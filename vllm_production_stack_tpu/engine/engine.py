@@ -53,6 +53,11 @@ class EngineStatsSnapshot:
     kv_group_usage: dict = field(default_factory=dict)  # group -> share
     kv_window_share: float = 0.0
     kv_window_pages_given_back: int = 0
+    # state slots (kv_cache.StateSlots): bytes and slots in use, and their
+    # share of the cache bytes in use
+    state_bytes: int = 0
+    state_slots_in_use: int = 0
+    state_share: float = 0.0
     prefix_cache_hit_rate: float = 0.0
     prefix_cache_hits: int = 0
     prefix_cache_queries: int = 0
@@ -174,7 +179,8 @@ class LLMEngine:
         from dataclasses import replace
 
         from .memory import (
-            derive_num_blocks, resolve_window_blocks, window_group_blockers,
+            derive_num_blocks, resolve_state_slots, resolve_window_blocks,
+            window_group_blockers,
         )
 
         window_blocks = None
@@ -212,6 +218,15 @@ class LLMEngine:
                     "in a group of their own that holds the window only",
                     config.model.model,
                     "; ".join(window_group_blockers(config)))
+        if config.model.linear_layers and config.cache.enable_prefix_caching:
+            # a hit would need the recurrent state at its boundary, which
+            # no slot keeps (state snapshots: ROADMAP R-M5)
+            logger.info(
+                "%s: layers that hold a recurrent state — the engine runs "
+                "WITHOUT prefix hits (--no-enable-prefix-caching says so "
+                "outright)", config.model.model)
+            config = config.replace(
+                cache=replace(config.cache, enable_prefix_caching=False))
         if config.cache.num_blocks is None:
             config = config.replace(
                 cache=replace(
@@ -222,6 +237,7 @@ class LLMEngine:
                         config.parallel,
                         max_num_seqs=config.scheduler.max_num_seqs,
                         num_window_blocks=window_blocks,
+                        state_slots=resolve_state_slots(config),
                     ),
                 )
             )
@@ -1390,21 +1406,38 @@ class LLMEngine:
                 rec.count("attn_prefill_pairs_n", sum(
                     len(p) * int(p[0]) + len(p) * (len(p) + 1) // 2
                     for p in work.positions if len(p)))
+                # ... and the keys it must read at least once (its context)
+                rec.count("attn_prefill_keys_n", sum(
+                    int(p[-1]) + 1 for p in work.positions if len(p)))
                 window = self.config.model.max_window
                 if window:
                     # a model with window layers: the pairs a WINDOW layer's
                     # attention covers (a query at p sees min(p + 1, window)
                     # keys), and the keys a chunk's attention must read at
-                    # least once in a full layer (its context) and in a
-                    # window layer (the chunk and the window before it)
+                    # least once in a window layer (the chunk and the window
+                    # before it)
                     rec.count("attn_prefill_window_pairs_n", sum(
                         _window_pairs(int(p[0]), len(p), window)
                         for p in work.positions if len(p)))
-                    rec.count("attn_prefill_keys_n", sum(
-                        int(p[-1]) + 1 for p in work.positions if len(p)))
                     rec.count("attn_prefill_window_keys_n", sum(
                         min(int(p[-1]) + 1, len(p) + window - 1)
                         for p in work.positions if len(p)))
+                if self.config.model.linear_layers:
+                    rec.count("kda_prefill_tokens_n",
+                              sum(len(t) for t in work.token_ids))
+                    rec.count("kda_prefill_segments_n",
+                              sum(1 for t in work.token_ids if len(t)))
+                    rec.count("state_slots_zeroed_n", sum(
+                        1 for p in work.positions
+                        if len(p) and int(p[0]) == 0))
+                    # positions a row had computed before a preemption
+                    # took its pages AND its state slot
+                    rec.count("state_recomputed_tokens_n", sum(
+                        max(0, min(end, req.state_recompute_until)
+                            - (end - len(toks)))
+                        for req, toks, end in zip(
+                            work.requests, work.token_ids,
+                            work.context_lens)))
                 self._meter_prefill(work, handle.positions)
             else:
                 # tokens actually ACCEPTED — exact for both the fused
@@ -1412,9 +1445,31 @@ class LLMEngine:
                 # spec-decode verify (1..k+1 accepted per row)
                 accepted = sum(len(t) for _, t in results)
                 rec.count("decode_tokens", accepted)
+                if self.config.model.linear_layers and kind == "decode":
+                    rec.count("kda_decode_rows_n",
+                              len(work.requests) * work.window)
                 self._meter_decode(work, accepted)
             rec.poll()
             self._emit_results(results, handle.logprob_rows, outputs)
+
+    def _state_stats(self) -> dict:
+        """The state slots' part of a stats snapshot (empty for a model no
+        layer of which holds state): a slot in use holds its bytes in the
+        bank that is current, and the other bank's are the price of an
+        exact rollback — both count."""
+        pool = self.scheduler.pool
+        if pool.state is None:
+            return {}
+        from .memory import kv_block_bytes, state_slot_bytes
+
+        cfg = self.config
+        used = pool.state.num_in_use
+        state = 2 * used * state_slot_bytes(cfg.model)
+        pages = (pool.num_usable - pool.num_free) * kv_block_bytes(
+            cfg.model, cfg.cache.block_size,
+            kv_dtype=cfg.cache.resolved_kv_dtype(cfg.model.dtype))
+        return {"state_bytes": state, "state_slots_in_use": used,
+                "state_share": state / (state + pages) if state else 0.0}
 
     def _rollback(self, nxt: _InflightStep) -> None:
         self.scheduler.rollback_speculative(nxt.work)
@@ -1986,6 +2041,7 @@ class LLMEngine:
             kv_window_share=self.scheduler.window_share(),
             kv_window_pages_given_back=(
                 pool.window.given_back_total if pool.window else 0),
+            **self._state_stats(),
             prefix_cache_hit_rate=pool.stats.hit_rate,
             prefix_cache_hits=pool.stats.hits,
             prefix_cache_queries=pool.stats.queries,

@@ -150,6 +150,58 @@ class WindowPageGroup:
         table.clear()
 
 
+class StateSlots:
+    """Host-side accounting for the STATE SLOTS of a model whose
+    linear-attention layers hold a recurrent state and no pages
+    (LayerSpec.holds_state; models/llama.py:init_kv_cache gives each such
+    layer {"s", "conv"} pools of two banks x `num_slots` slots). The second
+    kind of cache beside the pages, in the same manager: a row takes ONE
+    slot with its first pages and gives it back with them — when it ends, is
+    aborted or is preempted — and holds it whatever its context length.
+    Slot 0 is the null slot, which padding rows read and write. One slot a
+    seat, so slots cannot run out before seats do.
+
+    No slot is ever cleared on the host or trusted on the device: the
+    program that runs a row's FIRST token starts it from zeros
+    (models/llama.py:forward). No content addressing: a prefix hit would
+    need the state at its boundary, which nothing keeps, so the engine
+    runs without prefix caching beside such layers (and says so at start).
+
+    Two banks (bank b's slot s lies at b * num_slots + s of the pools): a
+    dispatch reads a row's state in the row's bank and writes it in the
+    other (ModelRunner._state_slot_arrays), and the row's bank flips when the
+    dispatch goes out (Scheduler.begin_speculative). A dispatch that is
+    rolled back (Scheduler.rollback_speculative) has run on the device all
+    the same — unlike a page, a state overwritten in place could not be
+    computed again — but what it read is untouched: the row's bank flips
+    back and the re-dispatch starts from it."""
+
+    def __init__(self, num_slots: int):
+        if num_slots < 2:
+            raise ValueError("need at least 2 slots (slot 0 is the null slot)")
+        self.num_slots = num_slots
+        self._free: deque[int] = deque(range(1, num_slots))
+
+    @property
+    def num_usable(self) -> int:
+        return self.num_slots - 1
+
+    @property
+    def num_free(self) -> int:
+        return len(self._free)
+
+    @property
+    def num_in_use(self) -> int:
+        return self.num_usable - len(self._free)
+
+    def allocate(self) -> int | None:
+        return self._free.popleft() if self._free else None
+
+    def release(self, slot: int) -> None:
+        if slot:
+            self._free.append(slot)
+
+
 class KVBlockPool:
     """Host-side accounting for the device page pool of ONE engine."""
 
@@ -171,6 +223,9 @@ class KVBlockPool:
         # the window layers' page group, where the model has window layers
         # (the engine attaches it; the scheduler grows and gives back both)
         self.window: WindowPageGroup | None = None
+        # the state slots of a model with layers that hold a recurrent
+        # state (the scheduler attaches them): the other KIND of cache
+        self.state: StateSlots | None = None
         # event stream for the cluster KV index (engine/kv_events.py): every
         # transition of a hash's local matchability is published so the KV
         # controller/router can answer /lookup without probing this engine.

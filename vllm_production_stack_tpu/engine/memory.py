@@ -14,7 +14,8 @@ import jax
 
 from ..utils.logging import init_logger
 from .config import (
-    CacheConfig, EngineConfig, ModelConfig, ParallelConfig, SchedulerConfig,
+    LINEAR_CONV_TAPS, CacheConfig, EngineConfig, ModelConfig, ParallelConfig,
+    SchedulerConfig,
 )
 
 logger = init_logger(__name__)
@@ -115,14 +116,29 @@ def param_bytes(cfg: ModelConfig, tp: int = 1, pp: int = 1,
         cfg.hidden_size, cfg.head_dim, cfg.num_kv_heads, cfg.num_layers)
     expert = 3 * h * cfg.expert_intermediate_size
     layers = 0
+    f32_extra = 0  # bytes of float32 leaves beyond the model dtype's count
     for spec in cfg.layer_specs:
         nh = spec.num_heads
         sharded = 2 * h * nh * hd + 2 * h * nkv * hd
         norms = (0 if cfg.post_norms_only else 2) + (
             2 if cfg.sandwich_norms or cfg.post_norms_only else 0)
         replicated = norms * h + qk_norm_params(cfg)
-        if cfg.attn_output_gate:
-            sharded += h * nh
+        if spec.holds_state:
+            # the KDA mixer (models/llama.py:attn_linear): q, k, v, o; the
+            # two low-rank pairs; the step size's projection; the taps; the
+            # output norm; A_log and dt_bias in float32
+            lhd = cfg.linear_num_heads * cfg.linear_head_dim
+            r = cfg.linear_gate_rank
+            sharded = (4 * h * lhd + 2 * (h * r + r * lhd)
+                       + h * cfg.linear_num_heads
+                       + LINEAR_CONV_TAPS * 3 * lhd
+                       + cfg.linear_head_dim)
+            f32_extra += 4 * (cfg.linear_num_heads + lhd)
+        elif cfg.attn_output_gate:
+            sharded += h * nh * (
+                hd if cfg.attn_output_gate == "element" else 1)
+        if spec.experts and cfg.moe_selection_bias:
+            f32_extra += 4 * cfg.num_experts
         if spec.experts:
             sharded += (
                 cfg.held_experts // ep + cfg.n_shared_experts) * expert
@@ -135,7 +151,7 @@ def param_bytes(cfg: ModelConfig, tp: int = 1, pp: int = 1,
     embed = cfg.vocab_size * h // tp
     head = 0 if cfg.tie_word_embeddings else h * cfg.vocab_size // tp
     total = embed + layers * ((L + pp - 1) // pp) // L + h + head
-    return total * dtype_bytes(cfg.dtype)
+    return total * dtype_bytes(cfg.dtype) + f32_extra
 
 
 def kv_block_bytes(cfg: ModelConfig, block_size: int, tp: int = 1,
@@ -159,7 +175,8 @@ def kv_block_bytes(cfg: ModelConfig, block_size: int, tp: int = 1,
             * dtype_bytes(kv_dtype or cfg.dtype)
         ) // pp)
     kvh = max(1, cfg.num_kv_heads // tp)
-    layers = {None: cfg.num_layers, "full": len(cfg.full_layers),
+    # (a layer that holds state has no pages: LayerSpec.holds_pages)
+    layers = {None: len(cfg.page_layers), "full": len(cfg.full_layers),
               "window": len(cfg.window_layers)}[group]
     nbytes = (
         layers * 2 * block_size * kvh * cfg.head_dim
@@ -222,18 +239,46 @@ def resolve_window_blocks(config: EngineConfig) -> int | None:
         model, config.cache, config.scheduler) + 1
 
 
+def state_slot_bytes(model: ModelConfig) -> int:
+    """Bytes ONE state slot holds across the layers that hold state: a
+    float32 (heads, D, D) state and the convolution's tail — the last
+    taps - 1 rows of the q | k | v projections in the model's dtype — a
+    layer, whatever the context length. 0 for a model without such
+    layers."""
+    lhd = model.linear_num_heads * model.linear_head_dim
+    return len(model.linear_layers) * (
+        lhd * model.linear_head_dim * 4
+        + (LINEAR_CONV_TAPS - 1) * 3 * lhd * dtype_bytes(model.dtype))
+
+
+def resolve_state_slots(config: EngineConfig) -> int:
+    """State slots a BANK of the state pools holds: one a seat and the null
+    slot 0 that padding rows write to; 0 for a model no layer of which
+    holds state. The arrays hold two banks (kv_cache.StateSlots)."""
+    if not config.model.linear_layers:
+        return 0
+    return config.scheduler.max_num_seqs + 1
+
+
+def state_pool_bytes(model: ModelConfig, state_slots: int) -> int:
+    """Bytes of the state pools: two banks of `state_slots` slots."""
+    return 2 * state_slots * state_slot_bytes(model)
+
+
 def kv_pool_bytes(model: ModelConfig, cache: CacheConfig,
                   parallel: ParallelConfig, num_window_blocks: int | None,
-                  kv_dtype: str | None = None) -> int:
-    """Per-device bytes of the whole paged pool: `num_blocks` pages of
-    every layer, or with a window group `num_blocks` pages of the full
-    layers and `num_window_blocks` of the window layers."""
+                  kv_dtype: str | None = None, state_slots: int = 0) -> int:
+    """Per-device bytes of the whole cache: `num_blocks` pages of every
+    layer that holds pages — or with a window group `num_blocks` pages of
+    the full layers and `num_window_blocks` of the window layers — and the
+    state pools of the layers that hold state (`state_slots` a bank)."""
     tp, pp = parallel.tensor_parallel_size, parallel.pipeline_parallel_size
     kv_dtype = kv_dtype or cache.resolved_kv_dtype(model.dtype)
+    state = state_pool_bytes(model, state_slots)
     if num_window_blocks is None:
-        return cache.num_blocks * kv_block_bytes(
+        return state + cache.num_blocks * kv_block_bytes(
             model, cache.block_size, tp, pp, kv_dtype=kv_dtype)
-    return (
+    return state + (
         cache.num_blocks * kv_block_bytes(
             model, cache.block_size, tp, pp, kv_dtype, group="full")
         + num_window_blocks * kv_block_bytes(
@@ -333,8 +378,12 @@ def derive_num_blocks(
     hbm_bytes: int | None = None,
     max_num_seqs: int | None = None,
     num_window_blocks: int | None = None,
+    state_slots: int = 0,
 ) -> int:
     """Blocks that fit in hbm_utilization × HBM after weights + reserve.
+
+    The state pools of a model with layers that hold state (`state_slots` a
+    bank, resolve_state_slots) come off the budget first.
 
     With a window page group (`num_window_blocks`, resolve_window_blocks)
     its bytes come off the budget first and the result counts the FULL
@@ -355,6 +404,7 @@ def derive_num_blocks(
     budget = headroom_budget(
         model, cache, parallel, hbm
     ) - hoist_reserve_bytes(model, cache, parallel, max_num_seqs)
+    budget -= state_pool_bytes(model, state_slots)
     kv_dtype = cache.resolved_kv_dtype(model.dtype)
     per_block = kv_block_bytes(model, cache.block_size, tp, pp, kv_dtype)
     if num_window_blocks is not None:

@@ -187,6 +187,17 @@ class EngineMetrics:
             mc.HBM_KV_WINDOW_PAGES_GIVEN_BACK,
             "Window-group pages given back behind live rows",
         )
+        self.state_bytes = gauge(
+            mc.HBM_STATE_BYTES,
+            "Bytes of recurrent state the state slots in use hold",
+        )
+        self.state_slots_in_use = gauge(
+            mc.HBM_STATE_SLOTS_IN_USE, "State slots held by rows"
+        )
+        self.state_share = gauge(
+            mc.HBM_STATE_SHARE,
+            "State bytes in use / (state + page bytes in use)",
+        )
         self.prefix_hit_rate = gauge(
             mc.PREFIX_CACHE_HIT_RATE, "Prefix cache block hit rate"
         )
@@ -675,6 +686,9 @@ class EngineMetrics:
         self.kv_window_share.labels(**lb).set(s.kv_window_share)
         self._bump(self.kv_window_given_back, "win_back",
                    s.kv_window_pages_given_back)
+        self.state_bytes.labels(**lb).set(s.state_bytes)
+        self.state_slots_in_use.labels(**lb).set(s.state_slots_in_use)
+        self.state_share.labels(**lb).set(s.state_share)
         self.prefix_hit_rate.labels(**lb).set(s.prefix_cache_hit_rate)
         self._bump(self.prefix_hits, "hits", s.prefix_cache_hits)
         self._bump(self.prefix_queries, "queries", s.prefix_cache_queries)

@@ -222,6 +222,48 @@ def _refuse_unbuilt_for_tree_by_kind(config: EngineConfig) -> None:
         )
 
 
+def _refuse_unbuilt_for_recurrent_state(config: EngineConfig) -> None:
+    """A model with layers that hold a recurrent state in state slots
+    (LayerSpec.holds_state; engine/kv_cache.py:StateSlots) is refused, by
+    name and one sentence each, what no state slot has been built for.
+    (Prefix caching is not refused: LLMEngine turns it off and says so; the
+    scheduler refuses it where it is built on its own.)"""
+    cfg, name, sched = config.model, config.model.model, config.scheduler
+    why = {
+        **{tier: (True, "a page that comes back from a tier brings no "
+                  "recurrent state with it, and PD transfer ships pages only")
+           for tier in config.kv_tiers_asked()},
+        f"speculative decoding (--speculative-config "
+        f"{sched.speculative_method})": (
+            sched.num_speculative_tokens > 0,
+            "a rejected draft would already have advanced the state, and "
+            "the verify program cannot roll it back"),
+        f"a mesh of {config.parallel.world_size} devices (tp / dp / pp / "
+        "sp / ep, context parallelism included)": (
+            config.parallel.world_size > 1,
+            "parallel/sharding.py has no spec for the attn_linear leaves "
+            "nor for the state pools, and the ring prefill carries no state"),
+        "LoRA adapters": (
+            config.lora.max_loras > 0,
+            "the adapter buffers know the q/k/v/o projections of softmax "
+            "layers only"),
+        f"--quantization {cfg.quantization}": (
+            bool(cfg.quantization),
+            "models/quantization.py names no int8 form for the attn_linear "
+            "leaves"),
+        "a checkpoint": (
+            bool(cfg.checkpoint),
+            "the tensor names of the linear-attention leaves were never "
+            "checked against a published checkpoint offline; the presets "
+            "serve random weights"),
+    }
+    for what, (asked, reason) in why.items():
+        if asked:
+            raise ValueError(
+                f"{name}: {what} cannot be combined with layers that hold a "
+                f"recurrent state — {reason}")
+
+
 class StepHandle:
     """One dispatched-but-unresolved device step — the async pipeline's
     unit of in-flight work (engine/engine.py pipelined step loop).
@@ -333,6 +375,8 @@ class ModelRunner:
         cfg = config.model
         if cfg.mla:
             _refuse_unbuilt_for_latent_attention(config)
+        if cfg.linear_layers:
+            _refuse_unbuilt_for_recurrent_state(config)
         if cfg.tree_by_kind:
             _refuse_unbuilt_for_tree_by_kind(config)
         pp = config.parallel.pipeline_parallel_size
@@ -387,26 +431,20 @@ class ModelRunner:
             self.params = self._init_device_params(param_shardings)
         else:
             self.params = jax.tree.map(jax.device_put, params, param_shardings)
-        # (a latent pool has no kv-head axis; it runs on one device)
-        kv_sharding = NamedSharding(
-            self.mesh, P() if cfg.mla else kv_cache_spec())
         self._kv_dtype = config.cache.resolved_kv_dtype(cfg.dtype)
         # a model with window layers keeps their pages in a group of their
         # own (engine/kv_cache.py:WindowPageGroup): a second block table,
         # written span and slot vector ride beside the full group's, as
         # (full, window) pairs (models/llama.py:forward)
-        from .memory import resolve_window_blocks
+        from .memory import resolve_state_slots, resolve_window_blocks
 
         self.num_window_blocks = resolve_window_blocks(config)
         self._window_group = self.num_window_blocks is not None
-        self.kv_caches = jax.jit(
-            lambda: llama.init_kv_cache(
-                cfg, config.cache.num_blocks, config.cache.block_size,
-                dtype=self._kv_dtype,
-                num_window_blocks=self.num_window_blocks,
-            ),
-            out_shardings=kv_sharding,
-        )()
+        # a model with layers that hold a recurrent state keeps it in state
+        # slots (engine/kv_cache.py:StateSlots): `state_slots` a bank, two
+        # banks; each program takes its rows' (read, write) slot indices
+        self.state_slots = resolve_state_slots(config)
+        self.kv_caches = self._new_kv_caches()
         self._use_lora = config.lora.max_loras > 0
         if self._use_lora:
             lora_sh = jax.tree.map(
@@ -583,6 +621,40 @@ class ModelRunner:
         # verify programs have no pad-up fallback lattice (t_pad is the
         # pow2 of the fed width) — tracked separately for telemetry only
         self._verify_keys: set[tuple] = set()
+
+    def _new_kv_caches(self):
+        """The zeroed cache arrays: pages of the layers that hold pages,
+        state pools of those that hold state (models/llama.py:
+        init_kv_cache)."""
+        config, cfg = self.config, self.config.model
+        # (a latent pool has no kv-head axis and a state pool none to shard
+        # over tp: both run on one device)
+        spec = P() if cfg.mla or cfg.linear_layers else kv_cache_spec()
+        return jax.jit(
+            lambda: llama.init_kv_cache(
+                cfg, config.cache.num_blocks, config.cache.block_size,
+                dtype=self._kv_dtype,
+                num_window_blocks=self.num_window_blocks,
+                state_slots=self.state_slots,
+            ),
+            out_shardings=NamedSharding(self.mesh, spec),
+        )()
+
+    def _state_slot_arrays(self, requests, pad_to: int):
+        """(read, write) of a dispatch's rows, (pad_to,) int32 each: where on
+        the state pools' first axis the program reads a row's state slot and
+        where it writes it — the other bank of the same slot (kv_cache.
+        StateSlots; the scheduler flips the row's bank once the dispatch is
+        out). Padding rows read and write the null slot 0. None for a model
+        without such layers: the programs are what they were."""
+        if not self.state_slots:
+            return None
+        read = np.zeros(pad_to, np.int32)
+        write = np.zeros(pad_to, np.int32)
+        for i, req in enumerate(requests):
+            read[i] = req.state_bank * self.state_slots + req.state_slot
+            write[i] = (req.state_bank ^ 1) * self.state_slots + req.state_slot
+        return (self._put(read, self._batch1), self._put(write, self._batch1))
 
     def _resolve_attention_backend(self) -> str:
         """'auto' → the measured winner for the pool's block size.
@@ -812,6 +884,25 @@ class ModelRunner:
                 "resolved": self._prefill_backend,
             },
         }
+        cfg = self.config.model
+        if cfg.linear_layers:
+            # the mixer kinds of every program: layers that hold pages
+            # (softmax attention) and layers that hold state (the gated
+            # delta rule, through its kernels wherever attention runs
+            # through its own)
+            report["mixers"] = {
+                "by_layer": [s.mixer for s in cfg.layer_specs],
+                "softmax": len(cfg.page_layers),
+                "linear": len(cfg.linear_layers),
+                "state_slots": self.state_slots,
+                "linear_backend": {
+                    "decode": ("kda_decode_attention"
+                               if self._attention_backend != "xla"
+                               else "xla"),
+                    "prefill": ("kda_prefill_attention"
+                                if self._prefill_backend.startswith("pallas")
+                                else "xla")},
+            }
         if self.config.model.num_experts:
             # the expert computation a program holds follows from its row
             # (decode) or token (prefill) bucket; `resolved`: the widest,
@@ -845,6 +936,7 @@ class ModelRunner:
         pool = kv_pool_bytes(
             self.config.model, self.config.cache, par,
             self.num_window_blocks, kv_dtype=self._kv_dtype,
+            state_slots=self.state_slots,
         )
         return max(
             0,
@@ -910,6 +1002,9 @@ class ModelRunner:
             counts,  # (num_samples,) int32 output tokens so far
             min_toks,  # (num_samples,) min_tokens per row
             stop_ids,  # (num_samples, SUPPRESS_IDS) eos/stop ids, -1 pad
+            state_slots,  # (read (B,), write (B,)): the rows' state slots
+            #   (_state_slot_arrays); None for a model no layer of which
+            #   holds state
             grammar_mask=None,  # (num_samples, V) bool — constrained rows'
             #   allowed tokens, all-True for unconstrained rows (mask is
             #   DATA; docs/41-structured-output.md)
@@ -942,6 +1037,7 @@ class ModelRunner:
                 moe_stats=moe_stats,
                 pack=pack,
                 moe_backend=self._moe_backend,
+                state_slots=state_slots,
             )
             moe_out = (
                 (_moe_device_counts(cfg, moe_stats),) if moe_stats else ()
@@ -1010,12 +1106,13 @@ class ModelRunner:
             counts,
             min_toks,
             stop_ids,
+            state_slots,  # always None: a mesh is refused beside state slots
             grammar_mask=None,
             want_logprobs=False,
             want_min_tokens=False,
             want_grammar=False,
         ):
-            del write_ids, start_off
+            del write_ids, start_off, state_slots
             hist_lens = context_lens - chunk_lens
             hidden, kv_caches = llama.forward_sp_prefill(
                 cfg, params, token_ids, positions, kv_caches, block_tables,
@@ -1084,6 +1181,7 @@ class ModelRunner:
             counts0,  # (B,) output tokens generated before this window
             min_toks,  # (B,) min_tokens per row
             stop_ids,  # (B, SUPPRESS_IDS) eos/stop ids, -1 pad
+            state_slots,  # as the prefill program's
             # structured output (docs/41-structured-output.md): the token-
             # class automaton runs ON DEVICE inside the window loop — the
             # precomputed tables arrive as DATA padded to (G, S, C) buckets,
@@ -1105,7 +1203,8 @@ class ModelRunner:
             lp_out = jnp.zeros((b, window), jnp.float32)
             top_lp_out = jnp.zeros((b, window, LOGPROBS_TOPN), jnp.float32)
             top_id_out = jnp.zeros((b, window, LOGPROBS_TOPN), jnp.int32)
-            staged = llama.init_staged_kv(cfg, window, b)
+            staged = llama.init_staged_kv(
+                cfg, window, b, kv_caches=kv_caches)
             windows = [spec.window for spec in cfg.layer_specs]
             # hoist the loop-invariant history gather out of the window loop
             # when this program's contiguous copy fits HBM headroom (static
@@ -1123,6 +1222,7 @@ class ModelRunner:
                     hists = tuple(
                         gather_pages(
                             kv_caches[i], llama._group(block_tables, w))
+                        if cfg.layer_specs[i].holds_pages else None
                         for i, w in enumerate(windows)
                     )
 
@@ -1159,7 +1259,7 @@ class ModelRunner:
                     backend=self._attention_backend,
                     lora=lora_params, lora_idx=lora_idx, hists=hists,
                     mesh=self.mesh, moe_stats=moe_stats,
-                    moe_backend=self._moe_backend,
+                    moe_backend=self._moe_backend, state_slots=state_slots,
                 )
                 logits = llama.compute_logits(cfg, params, hidden)
                 with jax.named_scope("sample"):
@@ -1650,6 +1750,7 @@ class ModelRunner:
             want_logprobs=want_lp, want_min_tokens=use_mt,
             want_grammar=use_gr,
             aot_key=aot_key, watch_reqs=work.requests,
+            state=self._state_slot_arrays(work.requests, b_pad),
         )
         handle = StepHandle(
             runner=self, work=work, tokens=tokens_dev, lp_arrays=lp_dev,
@@ -1800,6 +1901,7 @@ class ModelRunner:
                 put(np.asarray(counts, np.int32), self._batch1),
                 put(min_toks, self._batch1),
                 put(stop_ids_arr, self._batch2),
+                self._state_slot_arrays(work.requests, b_pad),
             )
         if want_gr:
             tc_dev, cd_dev, acc_dev = self._grammar_device_tables(
@@ -1937,6 +2039,7 @@ class ModelRunner:
         grammar_mask=None,  # device (B, V) bool when want_grammar
         want_logprobs=False, want_min_tokens=False, want_grammar=False,
         aot_key=None, watch_reqs=None,
+        state=None,  # _state_slot_arrays(...) of the rows
     ):
         if self._sleeping_params_host is not None:
             raise RuntimeError("engine is sleeping; wake it before running")
@@ -1980,6 +2083,7 @@ class ModelRunner:
                 put(np.asarray(counts, np.int32), self._batch1),
                 put(min_toks, self._batch1),
                 put(stop_ids_arr, self._batch2),
+                state,
             )
         if want_grammar:
             dyn_args = dyn_args + (grammar_mask,)  # already device-resident
@@ -2499,6 +2603,7 @@ class ModelRunner:
             s((b,), i32, b1),         # counts
             s((b,), i32, b1),         # min_toks
             s((b, SUPPRESS_IDS), i32, b2),  # stop_ids
+            (s((b,), i32, b1),) * 2 if self.state_slots else None,
         ) + gr
 
     def _decode_avals(self, b: int, nb: int, gkey: tuple | None = None):
@@ -2534,6 +2639,7 @@ class ModelRunner:
             s((b,), i32, b1),         # counts0
             s((b,), i32, b1),         # min_toks
             s((b, SUPPRESS_IDS), i32, b2),  # stop_ids
+            (s((b,), i32, b1),) * 2 if self.state_slots else None,
         ) + gr
 
     @staticmethod
@@ -2840,12 +2946,5 @@ class ModelRunner:
                 self._sleeping_lora_host, self._lora_shardings
             )
             self._sleeping_lora_host = None
-        self.kv_caches = jax.jit(
-            lambda: llama.init_kv_cache(
-                cfg.model, cfg.cache.num_blocks, cfg.cache.block_size,
-                dtype=self._kv_dtype,
-                num_window_blocks=self.num_window_blocks,
-            ),
-            out_shardings=NamedSharding(self.mesh, kv_cache_spec()),
-        )()
+        self.kv_caches = self._new_kv_caches()
         self._sleeping_params_host = None

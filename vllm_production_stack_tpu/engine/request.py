@@ -96,6 +96,14 @@ class Request:
     # back; window_first_live is the first index that may still hold one
     window_table: list[int] = field(default_factory=list)
     window_first_live: int = 0
+    # the row's STATE SLOT (engine/kv_cache.py:StateSlots), where the model
+    # has layers that hold a recurrent state: 0 = none; state_bank is the
+    # bank that holds the state of the row's dispatched positions
+    state_slot: int = 0
+    state_bank: int = 0
+    # the most positions the row had computed when a preemption took its
+    # pages and its slot: what it computes again up to there is recompute
+    state_recompute_until: int = 0
     # tokens whose KV is resident (prefix-cache hits + computed prefill/decode)
     num_computed_tokens: int = 0
     num_cached_prompt_tokens: int = 0  # prefix-cache hits at admission

@@ -17,7 +17,7 @@ from dataclasses import dataclass, field
 
 from ..qos import FairShareClock, TenantAccounting
 from .config import CacheConfig, ModelConfig, SchedulerConfig
-from .kv_cache import KVBlockPool, WindowPageGroup, chain_hash
+from .kv_cache import KVBlockPool, StateSlots, WindowPageGroup, chain_hash
 from .request import Request, RequestStatus
 from .saturation import GoodputLedger
 from .spec_decode import propose_ngram
@@ -170,6 +170,14 @@ class Scheduler:
             self.pool.window = WindowPageGroup(
                 window_blocks, cache_config.block_size,
                 model_config.max_window)
+        if model_config.linear_layers:
+            # layers that hold a recurrent state: a slot a seat beside the
+            # pages (kv_cache.StateSlots)
+            if cache_config.enable_prefix_caching:
+                raise ValueError(
+                    "a prefix hit needs the recurrent state at its "
+                    "boundary, which no slot keeps")
+            self.pool.state = StateSlots(scheduler_config.max_num_seqs + 1)
         self.waiting: deque[Request] = deque()
         self.running: list[Request] = []
         self._hash_chains: dict[str, list[int]] = {}  # req id -> per-block hashes
@@ -988,6 +996,8 @@ class Scheduler:
                 self.config.max_num_batched_tokens) + 1
             if wpool.num_free < min(need, first_chunk):
                 return False
+        if self.pool.state is not None and not self.pool.state.num_free:
+            return False  # no admission without a free state slot
         return self.pool.num_free >= need
 
     def window_share(self) -> float:
@@ -1044,6 +1054,9 @@ class Scheduler:
         seq = req.all_token_ids
         root = self._chain_root(req)
         plan = None
+        if self.pool.state is not None:
+            # its state slot, with its first pages (_can_admit saw one free)
+            req.state_slot, req.state_bank = self.pool.state.allocate(), 0
         if (
             self.hydrator is not None
             and req.hydration is None  # first admission only
@@ -1390,6 +1403,8 @@ class Scheduler:
         # re-admission via the catch-up feed — cheap, it's a small model)
         if self.draft_proposer is not None:
             self.draft_proposer.release(req.request_id)
+        req.state_recompute_until = max(
+            req.state_recompute_until, req.num_computed_tokens)
         self._release_blocks(req)
         # goodput ledger: nothing to classify here — the preempted
         # request's pending tokens keep their unknown fate (the VALUES
@@ -1411,6 +1426,9 @@ class Scheduler:
         if self.pool.window is not None:
             self.pool.window.release(req.window_table, req.window_first_live)
             req.window_first_live = 0
+        if self.pool.state is not None:
+            self.pool.state.release(req.state_slot)
+            req.state_slot = 0
         self._hash_chains.pop(req.request_id, None)
 
     def _slot(self, req: Request, token_idx: int) -> int:
@@ -1462,6 +1480,10 @@ class Scheduler:
         for req, (n, out) in zip(work.requests, self._row_advance(work)):
             req.num_inflight_tokens += n
             req.num_inflight_outputs += out
+            # the dispatch wrote the row's state into its slot's other bank
+            # (kv_cache.StateSlots; ModelRunner._state_slot_arrays named
+            # both banks to it)
+            req.state_bank ^= 1
 
     def end_speculative(self, work: ScheduleOutput) -> None:
         """Clear `work`'s advance from its rows — the step has resolved and
@@ -1508,6 +1530,9 @@ class Scheduler:
         for req in work.requests:
             if req.status.finished or req not in self.running:
                 continue  # blocks already released by its finish
+            # the discarded step ran on the device and wrote the row's state
+            # into the other bank: what it read is where it was
+            req.state_bank ^= 1
             keep = self._blocks_needed(
                 req.num_computed_tokens + req.num_inflight_tokens
             )

@@ -23,6 +23,15 @@ HBM_KV_USAGE_PERC = "tpu:hbm_kv_usage_perc"
 # those layers at full length (0 without a window group).
 HBM_KV_GROUP_USAGE_PERC = "tpu:hbm_kv_group_usage_perc"
 HBM_KV_WINDOW_SHARE = "tpu:hbm_kv_window_share"
+# the other KIND of cache (engine/kv_cache.py:StateSlots; 0 for a model no
+# layer of which holds a recurrent state): the state pools' bytes in use
+# (slots held x the bytes a slot holds across the linear layers), the slots
+# held, and the state's share of the cache bytes in use — state bytes in
+# use / (state + page bytes in use). HBM_KV_USAGE_PERC stays the page
+# group's share: slots cannot run out before seats do.
+HBM_STATE_BYTES = "tpu:hbm_state_bytes"
+HBM_STATE_SLOTS_IN_USE = "tpu:hbm_state_slots_in_use"
+HBM_STATE_SHARE = "tpu:hbm_state_share"
 PREFIX_CACHE_HIT_RATE = "tpu:hbm_prefix_cache_hit_rate"
 # host-RAM offload tier (LMCache CPU-offload equivalent)
 HOST_KV_USAGE_PERC = "tpu:host_kv_usage_perc"
@@ -595,6 +604,9 @@ ALL_GAUGES = (
     HBM_KV_USAGE_PERC,
     HBM_KV_GROUP_USAGE_PERC,
     HBM_KV_WINDOW_SHARE,
+    HBM_STATE_BYTES,
+    HBM_STATE_SLOTS_IN_USE,
+    HBM_STATE_SHARE,
     PREFIX_CACHE_HIT_RATE,
     HOST_KV_USAGE_PERC,
     STEP_OVERLAP_FRAC,
@@ -686,4 +698,18 @@ ALL_COUNTERS = (
     ENGINE_PROGRAM_CACHE_HITS,
     ENGINE_PROGRAM_CACHE_MISSES,
     ENGINE_COMPILE_STORMS,
+)
+
+# GET /debug/timing -> engine: the counts a model with layers that hold a
+# recurrent state adds (engine/flightrec.py:count; absent otherwise): the
+# tokens and the row segments its prefill chunks ran through the chunked
+# kernel, the live rows x steps of its decode windows, the state slots a
+# chunk that held a row's first token started from zeros, and the positions
+# computed a second time after a preemption took a row's slot.
+DEBUG_TIMING_STATE_COUNTS = (
+    "kda_prefill_tokens_n",
+    "kda_prefill_segments_n",
+    "kda_decode_rows_n",
+    "state_slots_zeroed_n",
+    "state_recomputed_tokens_n",
 )

@@ -19,7 +19,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..engine.config import LayerSpec, ModelConfig
+from ..engine.config import LINEAR_CONV_TAPS, LayerSpec, ModelConfig
 from ..ops.attention import (
     apply_rope,
     attention_with_hist,
@@ -30,6 +30,12 @@ from ..ops.attention import (
     paged_attention_xla,
     write_kv_pages,
     write_kv_pages_blockwise,
+)
+from ..ops.kda_attention import (
+    kda_decode_attention,
+    kda_decode_xla,
+    kda_prefill_attention,
+    kda_prefill_xla,
 )
 from ..ops.mla_attention import (
     latent_attention_with_staged,
@@ -73,6 +79,21 @@ def _tap_routing(token_ids, positions, layers_routing: list) -> None:
         jax.debug.callback(
             ROUTING_TAP, token_ids.reshape(-1), positions.reshape(-1),
             experts)
+
+
+# The same for the recurrence of ONE layer that holds state (`STATE_TAP.layer`):
+# per execution, (the pool index each token's row writes its state slot at
+# — 0, the null slot, for padding —, positions, and q, k, v, g, beta as the
+# program hands them to the scan). testing/reference_check.py:StateTap.
+STATE_TAP = None
+
+
+def _state_tap(layer: int, slots, positions):
+    """What `_kda_attention` hands the scan's inputs to, or None."""
+    if STATE_TAP is None or STATE_TAP.layer != layer:
+        return None
+    return lambda *fed: jax.debug.callback(
+        STATE_TAP, slots, positions.reshape(-1), *fed)
 
 
 def init_params(cfg: ModelConfig, rng: jax.Array) -> dict:
@@ -229,7 +250,9 @@ def _init_params_by_kind(cfg: ModelConfig, rng: jax.Array) -> dict:
     the expert layers, whose router is `num_experts` wide (the published
     width) while gate/up/down hold the `held_experts` this program was
     given (ModelConfig.num_experts_held); "shared" is one ungated SwiGLU.
-    `_layer_params` indexes each stack by the layer's own count."""
+    `_layer_params` indexes each stack by the layer's own count. Layers
+    whose mixer is linear (LayerSpec.holds_state) are a third stack,
+    "attn_linear" (`attn_linear` below)."""
     unsaid = [name for name in (
         "attention_bias", "qk_norm", "qk_norm_flat", "sandwich_norms",
         "post_norms_only") if getattr(cfg, name)]
@@ -253,7 +276,40 @@ def _init_params_by_kind(cfg: ModelConfig, rng: jax.Array) -> dict:
         leaves = {"wq": w(n, h, nh * hd), "wk": w(n, h, nkv * hd),
                   "wv": w(n, h, nkv * hd), "wo": w(n, nh * hd, h)}
         if cfg.attn_output_gate:
-            leaves["wg"] = w(n, h, nh)
+            # one gate a head, or one a head and channel
+            leaves["wg"] = w(
+                n, h, nh * (hd if cfg.attn_output_gate == "element" else 1))
+        return leaves
+
+    def attn_linear(n):
+        """The KDA layers' stack (`_kda_attention`): q, k, v and o
+        projections over heads x D keys and values alike; the decay gate's
+        and the output gate's low-rank pairs; the step size's projection;
+        the depthwise convolution's taps over q | k | v (tap 3 multiplies
+        the current token); per head the log of the decay's rate (drawn as
+        log U(1, 16)) and per channel the bias of its softplus (the inverse
+        softplus of a step drawn log-uniform in [0.001, 0.1], both as the
+        gated delta rule's public implementations initialise them: decays
+        from barely any to e^-1.6 a token at a zero gate input); the
+        output norm's weight, shared by the heads."""
+        lh, ld, r = (cfg.linear_num_heads, cfg.linear_head_dim,
+                     cfg.linear_gate_rank)
+        taps = LINEAR_CONV_TAPS
+        leaves = {
+            "wq": w(n, h, lh * ld), "wk": w(n, h, lh * ld),
+            "wv": w(n, h, lh * ld), "wo": w(n, lh * ld, h),
+            "wf_a": w(n, h, r), "wf_b": w(n, r, lh * ld),
+            "wg_a": w(n, h, r), "wg_b": w(n, r, lh * ld),
+            "wb": w(n, h, lh),
+            "conv": w(n, taps, 3 * lh * ld, scale=taps ** -0.5),
+            "A_log": jnp.log(jax.random.uniform(
+                next(keys), (n, lh), jnp.float32, 1.0, 16.0)),
+            "o_norm": jnp.ones((n, ld), dt),
+        }
+        step = jnp.exp(jax.random.uniform(
+            next(keys), (n, lh * ld), jnp.float32,
+            jnp.log(1e-3), jnp.log(1e-1)))
+        leaves["dt_bias"] = step + jnp.log(-jnp.expm1(-step))
         return leaves
 
     L = cfg.num_layers
@@ -266,6 +322,8 @@ def _init_params_by_kind(cfg: ModelConfig, rng: jax.Array) -> dict:
                           ("attn_window", cfg.window_layers)):
             if idx:
                 layers[name] = attn(len(idx), specs[idx[0]].num_heads)
+        if cfg.linear_layers:
+            layers["attn_linear"] = attn_linear(len(cfg.linear_layers))
     else:
         layers["attn"] = attn(L, specs[0].num_heads)
     def w_by_layer(n, *shape):
@@ -303,6 +361,11 @@ def _init_params_by_kind(cfg: ModelConfig, rng: jax.Array) -> dict:
               "final_norm": jnp.ones((h,), dt)}
     if not cfg.tie_word_embeddings:
         params["lm_head"] = w(h, cfg.vocab_size, scale=0.02)
+    if lm and cfg.moe_selection_bias:
+        # the selection bias, non-zero and small (see _init_params_mla;
+        # drawn last: the older trees' draws stay what they were)
+        layers["moe"]["bias"] = 0.02 * jax.random.normal(
+            next(keys), (lm, e), jnp.float32)
     return params
 
 
@@ -355,6 +418,7 @@ def _embed(cfg: ModelConfig, params: dict, token_ids: jax.Array) -> jax.Array:
 def init_kv_cache(
     cfg: ModelConfig, num_blocks: int, block_size: int,
     dtype: Any | None = None, num_window_blocks: int | None = None,
+    state_slots: int = 0,
 ) -> tuple[jax.Array, ...]:
     """Paged pool: a TUPLE of per-layer (2, num_blocks, block_size, kvH, D)
     arrays (MLA: (1, num_blocks, block_size, latent_width)), NOT one stacked
@@ -367,14 +431,27 @@ def init_kv_cache(
     a full-attention layer's array holds `num_blocks` pages, a window
     layer's `num_window_blocks` — it only ever holds a row's window and
     what is in flight. Without it every layer holds `num_blocks` (a window
-    layer then masks a context it holds whole: tests, the XLA oracle)."""
+    layer then masks a context it holds whole: tests, the XLA oracle).
+
+    A layer that HOLDS STATE (LayerSpec.holds_state) has no pages: its leaf
+    is {"s": (2 * state_slots, H, D, D) float32, "conv": (2 * state_slots,
+    taps - 1, 3 * H * D)} — the recurrent state and the convolution's tail
+    of every seat (slot 0: the null slot padding rows use), in two BANKS:
+    a dispatch reads a row's slot in one bank and writes it in the other
+    (engine/kv_cache.py:StateSlots)."""
     dt = jnp.dtype(dtype) if dtype is not None else _dtype(cfg)
     if cfg.mla:
         # ONE plane a layer: a token's latent (ops/mla_attention.py), no
         # second copy of it as "V"
         shape = (1, num_blocks, block_size, cfg.latent_width)
         return tuple(jnp.zeros(shape, dt) for _ in range(cfg.num_layers))
+    lh, ld = cfg.linear_num_heads, cfg.linear_head_dim
     return tuple(
+        {"s": jnp.zeros((2 * state_slots, lh, ld, ld), jnp.float32),
+         "conv": jnp.zeros(
+             (2 * state_slots, LINEAR_CONV_TAPS - 1, 3 * lh * ld),
+             _dtype(cfg))}
+        if spec.holds_state else
         jnp.zeros((
             2,
             num_window_blocks if spec.window and num_window_blocks
@@ -498,6 +575,9 @@ def _layer_body(
     if cfg.mla:
         x = _mla_attention(cfg, ap, lp["input_norm"], x, positions, attend)
         return _mlp_block(cfg, lp, res + x, proj, moe_stats, moe_backend)
+    if spec.holds_state:
+        x = _kda_attention(cfg, ap, lp["input_norm"], x, attend)
+        return _mlp_block(cfg, lp, res + x, proj, moe_stats, moe_backend)
     with jax.named_scope("attn_qkv"):
         if not cfg.post_norms_only:
             x = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps,
@@ -519,17 +599,19 @@ def _layer_body(
             # qwen3: per-head RMSNorm on q/k BEFORE rope (HF Qwen3Attention)
             q = rms_norm(q, ap["q_norm"], cfg.rms_norm_eps)
             k = rms_norm(k, ap["k_norm"], cfg.rms_norm_eps)
-        q = apply_rope(q, positions, rope.theta, scaling=rope.scaling,
-                       rotary_dim=rope.rotary_dim,
-                       attention_factor=rope.attention_factor)
-        k = apply_rope(k, positions, rope.theta, scaling=rope.scaling,
-                       rotary_dim=rope.rotary_dim,
-                       attention_factor=rope.attention_factor)
+        if rope is not None:  # (None: no position encoding at all)
+            q = apply_rope(q, positions, rope.theta, scaling=rope.scaling,
+                           rotary_dim=rope.rotary_dim,
+                           attention_factor=rope.attention_factor)
+            k = apply_rope(k, positions, rope.theta, scaling=rope.scaling,
+                           rotary_dim=rope.rotary_dim,
+                           attention_factor=rope.attention_factor)
         v = v.reshape(b, t, nkv, hd)
         if cfg.attn_output_gate:
             with jax.named_scope("attn_gate"):
-                # one scalar a head and token, from the same normed input
-                # as q (float32 logistic, as softmax statistics are)
+                # one scalar a head (or a head and channel) and token, from
+                # the same normed input as q (float32 logistic, as softmax
+                # statistics are)
                 gate = jax.nn.sigmoid(
                     _mm(x, ap["wg"]).astype(jnp.float32)).astype(x.dtype)
 
@@ -538,7 +620,9 @@ def _layer_body(
     attn = attend(q, k, v)
     if cfg.attn_output_gate:
         with jax.named_scope("attn_gate"):
-            attn = attn * gate[..., None]
+            attn = attn * (gate.reshape(attn.shape)
+                           if cfg.attn_output_gate == "element"
+                           else gate[..., None])
     attn = attn.reshape(b, t, nh * hd)
     with jax.named_scope("attn_out"):
         attn_out = proj(attn, ap["wo"], "o_proj")
@@ -549,6 +633,156 @@ def _layer_body(
                      scale_f32=cfg.norm_scale_f32)
         x = res + attn_out
     return _mlp_block(cfg, lp, x, proj, moe_stats, moe_backend)
+
+
+def _kda_attention(cfg: ModelConfig, ap: dict, norm: jax.Array,
+                   x: jax.Array, recur) -> jax.Array:
+    """A linear-attention layer's mixer (the gated delta rule with a
+    per-channel gate, KDA; ops/kda_attention.py has the recurrence),
+    (N, 1, h) -> the block's output before the residual add. The tokens lie
+    on the first axis (a packed chunk, or a decode step's rows); what a
+    token needs of the tokens before it — the convolution's tail and the
+    state — `recur` brings: `recur.conv(x (N, 3 H D), taps)` returns the
+    causal depthwise convolution over each row's own tokens (float32), and
+    `recur.scan(q, k, v, g, beta)` runs the recurrence and returns o
+    (N, H, D) float32."""
+    n = x.shape[0]
+    lh, ld = cfg.linear_num_heads, cfg.linear_head_dim
+    f32 = jnp.float32
+    with jax.named_scope("kda_qkv"):
+        u = rms_norm(x[:, 0], norm, cfg.rms_norm_eps)
+        qkv = jnp.concatenate(
+            [_mm(u, ap["wq"]), _mm(u, ap["wk"]), _mm(u, ap["wv"])], axis=-1)
+    with jax.named_scope("kda_conv"):
+        qkv = jax.nn.silu(recur.conv(qkv, ap["conv"]))
+        q, k, v = (y.reshape(n, lh, ld) for y in jnp.split(qkv, 3, axis=-1))
+        # L2 norm over a head's dims (float32), q also times D^-0.5
+        q = q * jax.lax.rsqrt(
+            jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6) * ld ** -0.5
+        k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
+        q, k, v = (y.astype(x.dtype) for y in (q, k, v))
+    with jax.named_scope("kda_gate"):
+        a = _mm(_mm(u, ap["wf_a"]), ap["wf_b"]).astype(f32)
+        # log of the decay a key channel: -exp(A_log) softplus(a + dt_bias)
+        g = (-jnp.exp(ap["A_log"].astype(f32))[None, :, None]
+             * jax.nn.softplus(a + ap["dt_bias"].astype(f32)).reshape(
+                 n, lh, ld))
+        # the step size in (0, 2): eigenvalues of I - beta k k^T in (-1, 1)
+        beta = 2.0 * jax.nn.sigmoid(_mm(u, ap["wb"]).astype(f32))
+    if recur.tap is not None:
+        recur.tap(q, k, v, g, beta)
+    with jax.named_scope("kda_scan"):
+        o = recur.scan(q, k, v, g, beta)
+    with jax.named_scope("kda_out"):
+        z = _mm(_mm(u, ap["wg_a"]), ap["wg_b"]).astype(f32)
+        o = rms_norm(o, ap["o_norm"].astype(f32), cfg.rms_norm_eps)
+        o = (o.reshape(n, lh * ld) * jax.nn.sigmoid(z)).astype(x.dtype)
+        return _mm(o, ap["wo"])[:, None]
+
+
+class _Recurrence:
+    """What `_kda_attention` asks of the tokens before its own: the
+    convolution and the scan of ONE linear layer, reading a row's state
+    slot at `read[row]` and writing it at `write[row]` of the layer's pools
+    (`self.pools`, replaced by what the ops return). `tap`: see STATE_TAP."""
+
+    def __init__(self, pools: dict, read, write, tap=None):
+        self.pools, self.read, self.write, self.tap = pools, read, write, tap
+
+
+class _PackedRecurrence(_Recurrence):
+    """A packed prefill chunk: row i's tokens at [row_off[i], row_off[i] +
+    chunk_lens[i]) of the axis; a row whose first token the chunk holds
+    (`fresh`) starts from a zero state and a zero tail, whatever its slot
+    held."""
+
+    def __init__(self, pools, st: dict, rows: dict):
+        super().__init__(pools, st["read"], st["write"], st["tap"])
+        self.st, self.rows = st, rows
+
+    def conv(self, x, taps):
+        st = self.st
+        n, kk = x.shape[0], taps.shape[0]
+        off, lens = st["row_off"], st["chunk_lens"]
+        tail = jnp.where(st["fresh"][:, None, None], 0,
+                         self.pools["conv"][self.read])  # (B, kk - 1, C)
+        j = (jnp.arange(n, dtype=jnp.int32) - off[st["tok_row"]])[:, None]
+        xf, taps = x.astype(jnp.float32), taps.astype(jnp.float32)
+        y = xf * taps[kk - 1]
+        for d in range(1, kk):
+            # the token d before, where the row has one in this chunk: a
+            # shift along the packed axis must not read across a seam
+            prev = jnp.concatenate([jnp.zeros_like(xf[:d]), xf[:-d]], axis=0)
+            y = y + jnp.where(j >= d, prev, 0) * taps[kk - 1 - d]
+        # a row's first kk - 1 tokens also read its tail
+        tail_f = tail.astype(jnp.float32)
+        corr = jnp.stack([
+            sum(tail_f[:, kk - 1 + jj - d] * taps[kk - 1 - d]
+                for d in range(jj + 1, kk))
+            for jj in range(kk - 1)], axis=1)  # (B, kk - 1, C)
+        jj = jnp.arange(kk - 1, dtype=jnp.int32)[None]
+        at = jnp.where(jj < lens[:, None], off[:, None] + jj, n)
+        y = y.at[at.reshape(-1)].add(
+            corr.reshape(-1, corr.shape[-1]), mode="drop")
+        # the new tail: the last kk - 1 of [old tail | the row's tokens]
+        back = lens[:, None] - (kk - 1) + jj  # in-row index, < 0: old tail
+        new = jnp.where(
+            (back >= 0)[..., None],
+            x[jnp.clip(off[:, None] + back, 0, n - 1)],
+            jnp.take_along_axis(
+                tail, jnp.clip(kk - 1 + back, 0, kk - 2)[..., None], axis=1))
+        self.pools = {**self.pools, "conv": self.pools["conv"].at[
+            self.write].set(new.astype(self.pools["conv"].dtype))}
+        return y
+
+    def scan(self, q, k, v, g, beta):
+        st, rows = self.st, self.rows
+        if st["interpret"] is not None:
+            o, s = kda_prefill_attention(
+                self.pools["s"], self.read, self.write, st["fresh"],
+                q, k, v, g, beta, st["row_off"], st["chunk_lens"],
+                interpret=st["interpret"])
+        else:
+            # the XLA form takes rows first, as attention does
+            view = ((lambda y: y[None]) if rows["to_rows"] is None
+                    else (lambda y: y[rows["to_rows"]]))
+            o, s = kda_prefill_xla(
+                self.pools["s"], self.read, self.write, st["fresh"],
+                *(view(y) for y in (q, k, v, g, beta)), st["chunk_lens"])
+            o = (o[0] if rows["to_rows"] is None
+                 else o.reshape(-1, *o.shape[2:])[rows["to_packed"]])
+        self.pools = {**self.pools, "s": s}
+        return o
+
+
+class _StepRecurrence(_Recurrence):
+    """One decode step: a token a row."""
+
+    def __init__(self, pools, read, write, interpret: bool | None, tap=None):
+        super().__init__(pools, read, write, tap)
+        self.interpret = interpret
+
+    def conv(self, x, taps):
+        kk = taps.shape[0]
+        tail = self.pools["conv"][self.read]  # (B, kk - 1, C)
+        window = jnp.concatenate([tail, x[:, None].astype(tail.dtype)],
+                                 axis=1)
+        self.pools = {**self.pools, "conv": self.pools["conv"].at[
+            self.write].set(window[:, 1:])}
+        taps = taps.astype(jnp.float32)
+        return sum(window[:, i].astype(jnp.float32) * taps[i]
+                   for i in range(kk))
+
+    def scan(self, q, k, v, g, beta):
+        if self.interpret is None:
+            o, s = kda_decode_xla(
+                self.pools["s"], self.read, self.write, q, k, v, g, beta)
+        else:
+            o, s = kda_decode_attention(
+                self.pools["s"], self.read, self.write, q, k, v, g, beta,
+                interpret=self.interpret)
+        self.pools = {**self.pools, "s": s}
+        return o
 
 
 def _mla_attention(cfg: ModelConfig, ap: dict, norm: jax.Array,
@@ -1009,9 +1243,11 @@ def _layer_params(cfg: ModelConfig, params: dict, i: int) -> dict:
     mlp_kind = "moe" if spec.experts else "mlp"
     # name in the layer's dict -> (stack in the tree, the layer's index there)
     picks = {name: (name, i) for name in layers
-             if name not in ("attn", "attn_window", "mlp", "moe")}
+             if name not in ("attn", "attn_window", "attn_linear", "mlp",
+                             "moe")}
     picks["attn"] = (
-        ("attn_window" if spec.window else "attn", ai)
+        ({"full": "attn", "window": "attn_window",
+          "linear": "attn_linear"}[spec.attn_kind], ai)
         if cfg.attn_stacks_by_kind else ("attn", i))
     picks[mlp_kind] = (mlp_kind, j := mi if two_mlps else i)
     # (slices are traced in the tree's own sorted order, a kind-stacked MLP
@@ -1095,10 +1331,17 @@ def _layer(
     #   (N, 1, h) then
     spec: LayerSpec | None = None,  # the layer's entry of the per-layer list
     moe_backend: str = "xla",  # see _moe_mlp
+    state: dict | None = None,  # a layer that holds state: the rows' slots
+    #   and the pack (see forward); `kv_layer` is its pools then
 ) -> tuple[jax.Array, jax.Array]:
     b, t = x.shape[0], x.shape[1]
     hd, nkv = cfg.head_dim, cfg.num_kv_heads
     spec = spec or cfg.layer_specs[0]
+    if spec.holds_state:
+        recur = _PackedRecurrence(kv_layer, state, rows)
+        x = _layer_body(cfg, lp, x, positions, recur, lora, lora_idx,
+                        moe_stats, spec, moe_backend)
+        return x, recur.pools
     # the layer kind's page group: its block tables, written span, slots
     block_tables = _group(block_tables, spec.window)
     slot_mapping = _group(slot_mapping, spec.window)
@@ -1222,6 +1465,10 @@ def forward(
     #   rows' tokens end to end on one axis of N positions
     moe_backend: str = "xla",  # may an MoE layer's experts run the Pallas
     #   touched-experts kernel where the shapes choose it (see _moe_mlp)
+    state_slots: tuple | None = None,  # a model with layers that hold state:
+    #   (read (B,), write (B,)) — where each row's state slot is read and
+    #   where it is written (engine/kv_cache.py:StateSlots); such a model
+    #   prefills PACKED chunks only
 ) -> tuple[jax.Array, jax.Array]:
     """One model step over a token batch. Prefill is (B=1, T=chunk); decode is
     (B=batch, T=1). Returns (hidden (B,T,h), updated kv_caches).
@@ -1272,6 +1519,28 @@ def forward(
             for w in cfg.window_kinds
         }
         pallas_prefill = None
+    state = None
+    if cfg.linear_layers:
+        if pack is None or state_slots is None:
+            raise NotImplementedError(
+                f"{cfg.model}: layers that hold a recurrent state prefill "
+                "packed chunks with their rows' state slots only")
+        state = {
+            "read": state_slots[0], "write": state_slots[1],
+            "row_off": pack["row_off"], "tok_row": pack["tok_row"],
+            "chunk_lens": context_lens - pack["chunk_start"],
+            # a row whose first token the chunk holds starts from zeros:
+            # a slot is never trusted from its previous holder
+            "fresh": pack["chunk_start"] == 0,
+            "interpret": (None if pallas_prefill is None
+                          else pallas_prefill["interpret"]),
+        }
+        tok_slot = None
+        if STATE_TAP is not None:
+            tok_row, lens = pack["tok_row"], state["chunk_lens"]
+            in_row = (jnp.arange(token_ids.shape[0], dtype=jnp.int32)
+                      - pack["row_off"][tok_row]) < lens[tok_row]
+            tok_slot = jnp.where(in_row, state_slots[1][tok_row], 0)
 
     # unrolled layer loop (params stay stacked; each layer slices statically).
     # Unrolling instead of lax.scan lets each per-layer KV leaf alias its
@@ -1286,6 +1555,8 @@ def forward(
             masks.get(spec.window),
             _lora_layer_slice(lora, i), lora_idx, write_blocks,
             pallas_prefill, moe_stats, rows, spec, moe_backend,
+            *(({**state, "tap": _state_tap(i, tok_slot, positions)},)
+              if spec.holds_state else ()),
         )
         new_kv.append(layer_kv)
     if moe_stats is not None:
@@ -1304,13 +1575,27 @@ def _refuse_mla(cfg: ModelConfig, what: str) -> None:
 
 
 def init_staged_kv(
-    cfg: ModelConfig, window: int, batch: int, dtype: Any | None = None
+    cfg: ModelConfig, window: int, batch: int, dtype: Any | None = None,
+    kv_caches: tuple | None = None,
 ) -> jax.Array:
     """Staging buffer for one fused decode window: (L, 2, W, B, kvH, D)
     (MLA: (L, B, W, latent_width)).
     Small (MBs), so carrying it through the window loop is cheap — unlike the
-    pool itself (see paged_attention_with_staged)."""
+    pool itself (see paged_attention_with_staged).
+
+    A model with layers that hold state carries those layers' pools
+    (`kv_caches[i]`, 68 MiB a layer at 17 slots x 64 heads x 128 x 128) through
+    the window beside the buffer, as {"kv": the buffer, "state": {layer:
+    pools}}: the state advances in place at every step and is handed back
+    by `commit_staged_kv`, as the staged K/V are."""
     dt = jnp.dtype(dtype) if dtype is not None else _dtype(cfg)
+    if cfg.linear_layers:
+        # (the buffer keeps a plane for every layer, so a layer's index is
+        # its plane; the state layers' planes, 0.5 MiB each, stay unused)
+        return {
+            "kv": jnp.zeros((cfg.num_layers, 2, window, batch,
+                             cfg.num_kv_heads, cfg.head_dim), dt),
+            "state": {i: kv_caches[i] for i in cfg.linear_layers}}
     if cfg.mla:
         # one latent a token, rows first: (L, B, W, width) — a row's window
         # is one block of the decode kernel, and (b, w) row-major is the
@@ -1340,6 +1625,9 @@ def decode_window_step(
     mesh=None,  # required for the pallas backend on a >1-device mesh
     moe_stats: list | None = None,  # as in forward
     moe_backend: str = "xla",  # as in forward
+    state_slots: tuple | None = None,  # as in forward: the window's FIRST
+    #   step reads a row's state at `read` and writes it at `write`, every
+    #   later step reads and writes `write`
 ) -> tuple[jax.Array, jax.Array]:
     """One decode iteration inside a fused window: reads the pool, writes this
     token's K/V into `staged` (not the pool — the pool stays loop-invariant so
@@ -1348,6 +1636,10 @@ def decode_window_step(
     loop-invariant history gather out of the window loop, `hists` carries the
     contiguous per-layer (hist_k, hist_v) and the pool is not touched here
     (ops/attention.py:attention_with_hist). Returns (hidden (B, h), staged')."""
+    states = None
+    if cfg.linear_layers:  # see init_staged_kv
+        staged, states = staged["kv"], dict(staged["state"])
+        state_read = jnp.where(step_k == 0, state_slots[0], state_slots[1])
     window = staged.shape[2]  # (MLA: (L, B, W, width), the same axis)
     n_stats = len(moe_stats or ())
     with jax.named_scope("embed"):
@@ -1447,17 +1739,27 @@ def decode_window_step(
                     interpret=backend == "pallas_interpret",
                 )[:, None]
 
+        if spec.holds_state:
+            recur = _StepRecurrence(
+                states[i], state_read, state_slots[1],
+                None if backend == "xla" else backend == "pallas_interpret",
+                _state_tap(i, state_slots[1], positions))
         x = _layer_body(
             cfg, lp, x, positions[:, None],
-            attend_latent if cfg.mla else attend,
+            recur if spec.holds_state
+            else attend_latent if cfg.mla else attend,
             _lora_layer_slice(lora, i), lora_idx, moe_stats, spec,
             moe_backend,
         )
+        if spec.holds_state:
+            states[i] = recur.pools
     if moe_stats is not None:
         _tap_routing(token_ids, positions, moe_stats[n_stats:])
     with jax.named_scope("final_norm"):
         x = rms_norm(x[:, 0], params["final_norm"], cfg.rms_norm_eps,
                      cfg.rms_norm_add_one, scale_f32=cfg.norm_scale_f32)
+    if states is not None:
+        staged = {"kv": staged, "state": states}
     return x, staged
 
 
@@ -1470,6 +1772,9 @@ def commit_staged_kv(
 ) -> tuple[jax.Array, ...]:
     """Scatter a whole window's staged K/V into the (donated) pool, one
     scatter per layer — the only pool write of the fused decode window."""
+    states = {}
+    if isinstance(staged, dict):  # layers that hold state: init_staged_kv
+        staged, states = staged["kv"], staged["state"]
     if staged.ndim == 4:  # MLA: (L, B, W, width), rows (b, w) as the slots
         with jax.named_scope("kv_commit"):
             return tuple(
@@ -1480,6 +1785,9 @@ def commit_staged_kv(
     new_kv: list[jax.Array] = []
     with jax.named_scope("kv_commit"):
         for i in range(L):
+            if i in states:  # advanced in place through the window
+                new_kv.append(states[i])
+                continue
             k_rows = jnp.moveaxis(staged[i, 0], 0, 1).reshape(b * w, kvh, d)
             v_rows = jnp.moveaxis(staged[i, 1], 0, 1).reshape(b * w, kvh, d)
             new_kv.append(write_kv_pages(
@@ -1498,6 +1806,10 @@ def embed_encode(
     state at each row's LAST real token, L2-normalized — the /v1/embeddings
     path (vLLM serves decoder embeddings the same way: last-token pooling).
     Returns (B, h) float32."""
+    if cfg.linear_layers:
+        raise NotImplementedError(
+            f"{cfg.model}: /v1/embeddings is not implemented for layers that "
+            "hold a recurrent state (the encode path has no state slots)")
     b, t = token_ids.shape
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None], (b, t))
     x = _embed(cfg, params, token_ids)

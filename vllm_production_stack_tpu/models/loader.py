@@ -450,3 +450,34 @@ class LagunaCheckpointView:
             return moe[parts[6].split("_")[0]][
                 j, int(parts[5]) - cfg.first_expert_held].T
         raise KeyError(name)
+
+
+class SolarOpen2CheckpointView(LagunaCheckpointView):
+    """The served tree of a solar_open2 model read back under the plain
+    reference's tensor names (testing/reference_solar_open2.py): the GQA
+    layers' `self_attn.*` and the experts as LagunaCheckpointView names
+    them, the KDA layers' `linear_attn.*` leaves (the convolution's taps a
+    projection, (channels, taps)), and the router's selection bias. As for
+    laguna, no checkpoint of this family has been read here: the names are
+    the reference's."""
+
+    _LINEAR = {"q_proj": "wq", "k_proj": "wk", "v_proj": "wv",
+               "o_proj": "wo", "f_a_proj": "wf_a", "f_b_proj": "wf_b",
+               "g_a_proj": "wg_a", "g_b_proj": "wg_b", "b_proj": "wb"}
+
+    def __getitem__(self, name: str):
+        cfg, layers = self.cfg, self.params["layers"]
+        parts = name.split(".")
+        if len(parts) > 3 and parts[3] == "linear_attn":
+            stack = layers["attn_linear"]
+            j, leaf = cfg.layer_stack_index[int(parts[2])][0], parts[4]
+            if leaf in self._LINEAR:
+                return stack[self._LINEAR[leaf]][j].T
+            if leaf.endswith("_conv1d"):
+                width = cfg.linear_num_heads * cfg.linear_head_dim
+                at = "qkv".index(leaf[0]) * width
+                return stack["conv"][j][:, at:at + width].T
+            return stack[leaf][j]  # A_log, dt_bias, o_norm
+        if name.endswith("mlp.gate.e_score_correction_bias"):
+            return layers["moe"]["bias"][int(parts[2])]
+        return super().__getitem__(name)

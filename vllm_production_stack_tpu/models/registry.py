@@ -102,13 +102,53 @@ def _laguna_preset(n_layers: int, rope: dict = _LAGUNA_ROPE,
         architecture="laguna", num_experts=256, num_experts_per_tok=10,
         moe_intermediate_size=1024, n_shared_experts=1,
         scoring_func="sigmoid", routed_scaling_factor=2.5,
-        norm_topk_prob=True, attn_output_gate=True, sliding_window=512,
+        norm_topk_prob=True, attn_output_gate="head", sliding_window=512,
     )
     kw.update(over)
     kw["layers"] = laguna_layers(
         kinds, [heads[k != "full_attention"] for k in kinds],
         ["dense"] + ["sparse"] * (n_layers - 1), rope,
         kw["sliding_window"], kw["head_dim"])
+    return kw
+
+
+def solar_open2_layers(n_layers: int, gqa_layers, num_heads: int,
+                       ) -> tuple[LayerSpec, ...]:
+    """The per-layer list of a `model_type: solar_open2` config: the layers
+    named in `gqa_layers` are softmax attention over pages WITHOUT any
+    position encoding (`use_rope` false), every other layer is a gated
+    delta-rule (KDA) layer that holds a recurrent state; every layer's MLP
+    is experts (`first_k_dense_replace` 0)."""
+    gqa = set(gqa_layers)
+    return tuple(
+        LayerSpec(num_heads=num_heads, rope=None, experts=True)
+        if i in gqa else
+        LayerSpec(num_heads=num_heads, rope=None, experts=True,
+                  mixer="linear")
+        for i in range(n_layers))
+
+
+def _solar_open2_preset(n_layers: int, **over) -> dict:
+    """upstage/Solar-Open2-250B config.json (`model_type: solar_open2`): 48
+    layers in periods of one gated NoPE GQA layer (64 query / 8 kv heads x
+    128, an elementwise output gate) and three KDA layers (64 heads x 128
+    keys and values, convolution of 4, low-rank gates of rank 128), every
+    layer 320 experts of 1 280, top-8 of sigmoid scores + a selection bias,
+    renormalised, one ungated shared SwiGLU of 1 280; vocabulary 196 608
+    untied; 1 048 576 positions published."""
+    kw = dict(
+        vocab_size=196608, hidden_size=4096, intermediate_size=10240,
+        num_layers=n_layers, num_heads=64, num_kv_heads=8, head_dim=128,
+        max_model_len=32768, rms_norm_eps=1e-5, architecture="solar_open2",
+        num_experts=320, num_experts_per_tok=8, moe_intermediate_size=1280,
+        n_shared_experts=1, scoring_func="sigmoid", moe_selection_bias=True,
+        routed_scaling_factor=1.0, norm_topk_prob=True,
+        attn_output_gate="element", linear_num_heads=64,
+        linear_head_dim=128, linear_gate_rank=128,
+    )
+    kw.update(over)
+    kw["layers"] = solar_open2_layers(
+        n_layers, range(0, n_layers, 4), kw["num_heads"])
     return kw
 
 
@@ -258,6 +298,21 @@ PRESETS: dict[str, dict] = {
                 "original_max_position_embeddings": 64,
                 "attention_factor": 1.2079441541679836},  # 0.1 ln 8 + 1
             "sliding_attention": _LAGUNA_ROPE["sliding_attention"]}),
+    "solar-open2-250b": _solar_open2_preset(48),
+    # the benchmark cell's cut: ONE chip of an eight-chip expert-parallel
+    # host that is the first pipeline stage — layers 0-7 (two whole
+    # periods G K K K G K K K), experts 0-39 of the 320 the router scores,
+    # vocabulary rows 0-24 575; every width as published, 32 768 positions
+    "solar-open2-250b-ep8-8l": _solar_open2_preset(
+        8, num_experts_held=40, first_expert_held=0, vocab_size=24576),
+    # two periods at toy widths (float32): 4 heads x 16 in both mixers, 2
+    # kv heads, gates of rank 8, 16 experts top-4 with experts 4-7 held
+    "tiny-solar-open2": _solar_open2_preset(
+        8, vocab_size=512, hidden_size=64, intermediate_size=128,
+        num_heads=4, num_kv_heads=2, head_dim=16, max_model_len=512,
+        dtype="float32", num_experts=16, num_experts_per_tok=4,
+        num_experts_held=4, first_expert_held=4, moe_intermediate_size=32,
+        linear_num_heads=4, linear_head_dim=16, linear_gate_rank=8),
 }
 
 _ARCH_MAP = {
@@ -274,6 +329,7 @@ _ARCH_MAP = {
     "Gemma2ForCausalLM": "gemma2",
     "DeepseekV3ForCausalLM": "deepseek_v3",
     "LagunaForCausalLM": "laguna",
+    "SolarOpen2ForCausalLM": "solar_open2",
 }
 
 
@@ -387,7 +443,54 @@ def _laguna_keys(hf: dict, path: str) -> dict:
         scoring_func="sigmoid",
         routed_scaling_factor=float(hf.get("moe_routed_scaling_factor", 1.0)),
         norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
-        attn_output_gate=True,
+        attn_output_gate="head",
+    )
+
+
+def _solar_open2_keys(hf: dict, path: str) -> dict:
+    """The `model_type: solar_open2` keys, and by name what of the family is
+    not served: a config that sets one is refused, not guessed at."""
+    lin = hf.get("linear_attn_config") or {}
+    refused = {
+        "use_rope": bool(hf.get("use_rope")),
+        "kda_use_full_proj": bool(hf.get("kda_use_full_proj")),
+        "linear_attn_config.num_kv_heads": lin.get("num_kv_heads")
+        not in (None, lin.get("num_heads")),
+        "first_k_dense_replace > 0": (hf.get("first_k_dense_replace") or 0)
+        > 0,
+        "linear_attn_config.short_conv_kernel_size != 4": lin.get(
+            "short_conv_kernel_size", 4) != 4,
+        "use_gqa_gate false": not hf.get("use_gqa_gate", True),
+        "kda_allow_neg_eigval false": not hf.get(
+            "kda_allow_neg_eigval", True),
+    }
+    hit = [k for k, v in refused.items() if v]
+    if hit:
+        raise ValueError(
+            f"solar_open2 config {path} sets {', '.join(hit)}: "
+            "not implemented")
+    n = hf["num_hidden_layers"]
+    gqa = hf.get("gqa_layers")
+    if gqa is None:
+        gqa = range(0, n, int(hf.get("gqa_interval", 3)) + 1)
+    return dict(
+        layers=solar_open2_layers(n, gqa, hf["num_attention_heads"]),
+        num_experts=hf["n_routed_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        n_shared_experts=int(hf.get("n_shared_experts") or 0),
+        # assumed (no key): sigmoid scores with a learned selection bias and
+        # no groups, the lineage its key names follow (n_routed_experts,
+        # routed_scaling_factor, first_k_dense_replace); softmax not taken
+        scoring_func="sigmoid", moe_selection_bias=True,
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        # assumed (no key): the gate is elementwise; a head-wise one would
+        # say so in a granularity key of its own
+        attn_output_gate="element",
+        linear_num_heads=lin["num_heads"], linear_head_dim=lin["head_dim"],
+        # assumed (no key): the low-rank pairs' rank is the head's width
+        linear_gate_rank=lin["head_dim"],
     )
 
 
@@ -439,6 +542,10 @@ def _from_hf_config(path: str) -> dict:
     if arch == "laguna":
         moe = _laguna_keys(hf, path)
         # its ropes are per layer kind (rope_parameters), read above
+        hf = {**hf, "partial_rotary_factor": None, "rope_scaling": None}
+    if arch == "solar_open2":
+        moe = _solar_open2_keys(hf, path)
+        # no layer has a rope: rope_theta and partial_rotary_factor are dead
         hf = {**hf, "partial_rotary_factor": None, "rope_scaling": None}
     gemma = (
         dict(

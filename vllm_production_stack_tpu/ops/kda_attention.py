@@ -1,0 +1,391 @@
+"""The gated delta rule with a per-channel gate (KDA, Kimi Linear,
+arXiv:2510.26692): a linear-attention layer's recurrence over a per-row,
+per-head float32 state S (D key x D value), and its two Pallas TPU kernels.
+
+One token of one head, with q and k L2-normalised (q also times D^-0.5),
+g <= 0 the log of the per-key-channel decay and beta in (0, 2):
+
+    S' = Diag(exp(g)) S            decay each key channel's row
+    u  = beta (v - S'^T k)         what the state mispredicts of v
+    S  = S' + k u^T                the delta rule's rank-one correction
+    o  = S^T q
+
+`kda_step` is that, written once (the XLA forms below and the tests' oracle
+loop over it). The state of every seat lives in a POOL `(slots, H, D, D)`
+float32 (engine/kv_cache.py:StateSlots); each op reads a row's state at
+`read[row]` and writes it at `write[row]` — the same slot, or the slot's
+other bank, which is how a rolled-back dispatch leaves the state it started
+from untouched (engine/scheduler.py).
+
+Prefill, `kda_prefill_attention` (device op of that name): the
+chunkwise-parallel form over a PACKED token axis. The axis is cut into
+blocks of 64 tokens; a WORK ITEM is the part of one row's segment that
+lies in one block (a block that holds a seam between two rows is two
+items), and the grid walks (head, item). Inside an item, with G the
+cumulative log-gates from the item's first token:
+
+    A[s, r] = sum_c k[s, c] k[r, c] exp(G[s, c] - G[r, c])      (r < s)
+    B[s, r] = sum_c q[s, c] k[r, c] exp(G[s, c] - G[r, c])      (r <= s)
+    T  = (I + Diag(beta) A)^-1     unit lower triangular: the diagonal
+                                   sub-blocks by doubling, then block merges
+    U  = T Diag(beta) (V - (K * exp(G)) S0)
+    O  = (Q * exp(G)) S0 + B U
+    S1 = Diag(exp(G_last)) S0 + (K * exp(G_last - G))^T U
+
+exp of a NEGATED cumulative gate overflows under strong decay, so no factor
+exp(-G) is ever formed: A and B are built in sub-blocks of 16 tokens. Off the
+diagonal, rows of sub-block i are scaled by exp(G[s] - G[16 i - 1]) and the
+columns before it by exp(G[16 i - 1] - G[r]) — both exponents <= 0; on the
+diagonal the difference G[s] - G[r] is formed per pair before the exp. Every
+such exponent is summed directly over the log-gates of its own range (a
+prefix or a suffix inside a sub-block plus whole sub-blocks), never taken as
+the difference of two block-long cumulative sums: behind one strongly
+decaying token those are large and their difference has lost the small
+decays after it. The
+state block of an item's row is the kernel's resident OUTPUT block: loaded
+(or zeroed, for a row whose first token the chunk holds) at the row's first
+item, carried in VMEM over its items, written back when the grid moves to
+another row — the pool is aliased in place and slots the chunk does not name
+keep their bytes.
+
+Decode, `kda_decode_attention`: one recurrent step for B rows x H heads on
+the VPU, S read once from `read[row]` and written once to `write[row]`,
+in place (`input_output_aliases`), float32.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+BLOCK = 64  # tokens of a prefill work item
+SUB = 16  # tokens of a sub-block (where gate differences are formed)
+_HI = jax.lax.Precision.HIGHEST
+
+
+def kda_step(s, q, k, v, g, beta):
+    """One token: s (..., D, D) float32; q, k, v, g (..., D); beta (...).
+    Returns (s', o (..., D))."""
+    f32 = jnp.float32
+    q, k, v, g, beta = (x.astype(f32) for x in (q, k, v, g, beta))
+    s = s * jnp.exp(g)[..., :, None]
+    pred = jnp.einsum("...kv,...k->...v", s, k, precision=_HI)
+    u = beta[..., None] * (v - pred)
+    s = s + k[..., :, None] * u[..., None, :]
+    return s, jnp.einsum("...kv,...k->...v", s, q, precision=_HI)
+
+
+def kda_decode_xla(pool, read, write, q, k, v, g, beta):
+    """One step for B rows in plain XLA: q, k, v, g (B, H, D), beta (B, H).
+    Returns (o (B, H, D) float32, pool')."""
+    s, o = kda_step(pool[read], q, k, v, g, beta)
+    return o, pool.at[write].set(s)
+
+
+def kda_prefill_xla(pool, read, write, fresh, q, k, v, g, beta, lens):
+    """A chunk in plain XLA, token by token: rows first — q, k, v, g
+    (B, T, H, D), beta (B, T, H), `lens` (B,) real tokens a row, `fresh`
+    (B,) rows that start from a zero state. Returns (o (B, T, H, D) float32,
+    pool'); a row without tokens writes back what it read."""
+    s0 = jnp.where(fresh[:, None, None, None], 0.0, pool[read])
+
+    def body(s, xs):
+        t, (qt, kt, vt, gt, bt) = xs
+        s1, o = kda_step(s, qt, kt, vt, gt, bt)
+        live = (t < lens)[:, None, None, None]
+        return jnp.where(live, s1, s), o
+
+    t = q.shape[1]
+    xs = tuple(jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta))
+    s, o = jax.lax.scan(body, s0, (jnp.arange(t), xs))
+    return jnp.moveaxis(o, 0, 1), pool.at[write].set(s)
+
+
+# -- prefill kernel ----------------------------------------------------------
+
+def work_items(row_off, chunk_lens, n: int):
+    """The work items of a packed chunk of `n` positions, from the rows'
+    offsets and lengths (B,): per item its row, its block of the packed
+    axis, the span [lo, hi) of the block that is the row's, and whether it
+    is the row's first item. `n // BLOCK + B` items, the real ones first;
+    the rest repeat the last real item with an empty span (the blocks they
+    name are already resident: nothing is copied for them)."""
+    b = chunk_lens.shape[0]
+    m = n // BLOCK + b
+    end = row_off + chunk_lens
+    first_blk = row_off // BLOCK
+    n_items = jnp.where(
+        chunk_lens > 0, (end + BLOCK - 1) // BLOCK - first_blk, 0)
+    ends = jnp.cumsum(n_items)
+    it = jnp.arange(m, dtype=jnp.int32)
+    real = it < ends[-1]
+    it_c = jnp.minimum(it, jnp.maximum(ends[-1] - 1, 0))
+    row = jnp.minimum(
+        jnp.sum(it_c[:, None] >= ends[None, :], axis=1), b - 1)
+    j = it_c - (ends - n_items)[row]
+    blk = jnp.clip(first_blk[row] + j, 0, n // BLOCK - 1)
+    lo = jnp.clip(row_off[row] - blk * BLOCK, 0, BLOCK)
+    hi = jnp.clip(end[row] - blk * BLOCK, 0, BLOCK)
+    return {
+        "row": row.astype(jnp.int32), "block": blk.astype(jnp.int32),
+        "lo": jnp.where(real, lo, 0).astype(jnp.int32),
+        "hi": jnp.where(real, hi, 0).astype(jnp.int32),
+        "first": real & (j == 0),
+    }
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    return jax.lax.dot_general(
+        a, b, dims, precision=_HI, preferred_element_type=jnp.float32)
+
+
+def _prefill_kernel(blk_ref, rd_ref, wr_ref, lo_ref, hi_ref, fl_ref,
+                    q_ref, k_ref, v_ref, g_ref, b_ref, s_in_ref,
+                    o_ref, s_ref):
+    del blk_ref, rd_ref, wr_ref
+    f32 = jnp.float32
+    h, m = pl.program_id(0), pl.program_id(1)
+    lo, hi, fl = lo_ref[m], hi_ref[m], fl_ref[m]
+
+    @pl.when((fl & 1) != 0)
+    def _load():  # the row's first item: its state, or zeros
+        s_ref[...] = jnp.where((fl & 2) != 0, 0.0, s_in_ref[...])
+
+    @pl.when(hi > lo)
+    def _item():
+        d = q_ref.shape[1]
+        tok = jax.lax.broadcasted_iota(jnp.int32, (BLOCK, 1), 0)
+        live = (tok >= lo) & (tok < hi)
+        q = jnp.where(live, q_ref[...].astype(f32), 0.0)
+        k = jnp.where(live, k_ref[...].astype(f32), 0.0)
+        v = jnp.where(live, v_ref[...].astype(f32), 0.0)
+        g = jnp.where(live, g_ref[...].astype(f32), 0.0)
+        heads = jax.lax.broadcasted_iota(jnp.int32, b_ref.shape, 1)
+        beta = jnp.sum(jnp.where(heads == h, b_ref[...].astype(f32), 0.0),
+                       axis=1, keepdims=True)
+        beta = jnp.where(live, beta, 0.0)  # (BLOCK, 1)
+        r_i = jax.lax.broadcasted_iota(jnp.int32, (BLOCK, BLOCK), 0)
+        c_i = jax.lax.broadcasted_iota(jnp.int32, (BLOCK, BLOCK), 1)
+        # log-gates summed DIRECTLY over the range each factor decays
+        # across (sums of non-positive numbers: a difference of two long
+        # cumulative sums would lose the small decays behind a strong one):
+        # within a token's own sub-block its inclusive prefix `lp` and its
+        # strict suffix `ls`, and each sub-block's total
+        same = r_i // SUB == c_i // SUB
+        lp = _dot((same & (r_i >= c_i)).astype(f32), g)  # (BLOCK, D)
+        ls = _dot((same & (r_i < c_i)).astype(f32), g)
+        n_sub = BLOCK // SUB
+        tot = [lp[(i + 1) * SUB - 1:(i + 1) * SUB] for i in range(n_sub)]
+
+        def span(lo_sub, hi_sub):  # total of sub-blocks [lo_sub, hi_sub)
+            return sum(tot[lo_sub:hi_sub], jnp.zeros_like(tot[0]))
+
+        def rows_of(fn):  # (BLOCK, D) from one (SUB, D) piece a sub-block
+            return jnp.concatenate(
+                [fn(i, slice(i * SUB, (i + 1) * SUB))
+                 for i in range(n_sub)], axis=0)
+
+        # from the item's first token to each token, and from each token to
+        # the item's last
+        gc = rows_of(lambda i, sl: lp[sl] + span(0, i))
+        g_tail = rows_of(lambda i, sl: ls[sl] + span(i + 1, n_sub))
+
+        sub_c = jax.lax.broadcasted_iota(jnp.int32, (SUB, BLOCK), 1)
+        a_rows, b_rows = [], []
+        for i in range(n_sub):
+            sl = slice(i * SUB, (i + 1) * SUB)
+            g_i, k_i, q_i = lp[sl], k[sl], q[sl]
+            a_d = jnp.zeros((SUB, BLOCK), f32)
+            b_d = jnp.zeros((SUB, BLOCK), f32)
+            for r in range(SUB):  # the diagonal sub-block, a column a turn
+                e = jnp.exp(jnp.minimum(g_i - g_i[r:r + 1], 0.0))
+                kr = k_i[r:r + 1] * e
+                col = sub_c == i * SUB + r
+                a_d = jnp.where(
+                    col, jnp.sum(k_i * kr, axis=1, keepdims=True), a_d)
+                b_d = jnp.where(
+                    col, jnp.sum(q_i * kr, axis=1, keepdims=True), b_d)
+            if i:
+                # columns before the sub-block: the row decays from the
+                # sub-block's doorstep (its own prefix), the column to it
+                # (its suffix and the sub-blocks between) — both <= 0
+                to_door = rows_of(
+                    lambda j, sj: ls[sj] + span(j + 1, max(i, j + 1)))
+                k_r = k * jnp.exp(to_door)
+                e_s = jnp.exp(g_i)
+                nt = (((1,), (1,)), ((), ()))
+                before = sub_c < i * SUB
+                a_d = jnp.where(before, _dot(k_i * e_s, k_r, nt), a_d)
+                b_d = jnp.where(before, _dot(q_i * e_s, k_r, nt), b_d)
+            a_rows.append(a_d)
+            b_rows.append(b_d)
+        a = jnp.where(r_i > c_i, jnp.concatenate(a_rows, axis=0), 0.0)
+        bm = jnp.where(r_i >= c_i, jnp.concatenate(b_rows, axis=0), 0.0)
+
+        # T = (I + L)^-1, L = Diag(beta) A strictly lower. The diagonal
+        # sub-blocks by doubling, (I - N)(I + N^2)(I + N^4)(I + N^8) with
+        # N^16 = 0, then two merges of [[A, 0], [C, B]]^-1 = [[A', 0],
+        # [-B' C A', B']] (16 -> 32 -> 64). (Doubling over the whole block
+        # would go through L^32: at 64 keys in 16 dimensions its powers grow
+        # and the result lost two digits against substitution.)
+        low = beta * a
+        eye_b = (r_i == c_i).astype(f32)
+        pair = r_i // (2 * SUB) == c_i // (2 * SUB)
+        nil = jnp.where(same, low, 0.0)
+        t = eye_b - nil
+        p = _dot(nil, nil)
+        for lvl in range(3):
+            t = t + _dot(t, p)
+            if lvl < 2:
+                p = _dot(p, p)
+        for off in (jnp.where(pair & ~same, low, 0.0),
+                    jnp.where(pair, 0.0, low)):
+            t = t - _dot(_dot(t, off), t)
+        e_g = jnp.exp(gc)
+        s0 = s_ref[0, 0]
+        u = _dot(t, beta * v) - _dot(_dot(t, beta * (k * e_g)), s0)
+        o = _dot(q * e_g, s0) + _dot(bm, u)
+        o_ref[...] = jnp.where(live, o, o_ref[...].astype(f32)).astype(
+            o_ref.dtype)
+        # Diag(exp(G_last)) S0 + (K exp(G_last - G))^T U as ONE product
+        # contracted over rows: [K'; Diag] ^T [U; S0]
+        eye = (jax.lax.broadcasted_iota(jnp.int32, (d, d), 0)
+               == jax.lax.broadcasted_iota(jnp.int32, (d, d), 1))
+        lhs = jnp.concatenate(
+            [k * jnp.exp(g_tail),
+             jnp.where(eye, jnp.exp(gc[BLOCK - 1:BLOCK]), 0.0)], axis=0)
+        s_ref[0, 0] = _dot(lhs, jnp.concatenate([u, s0], axis=0),
+                           (((0,), (0,)), ((), ())))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def kda_prefill_attention(pool, read, write, fresh, q, k, v, g, beta,
+                          row_off, chunk_lens, *, interpret: bool = False):
+    """The packed chunk through the chunked kernel: q, k, v (N, H, D) in
+    the model's dtype, g (N, H, D) float32, beta (N, H) float32; `row_off`, `chunk_lens`, `read`, `write`, `fresh`
+    (B,) a row. Returns (o (N, H, D) float32, pool')."""
+    n_tokens, heads, d = q.shape
+    pad = (-n_tokens) % BLOCK
+    if pad:  # (a bucket that is no whole number of blocks: tests)
+        q, k, v, g, beta = (
+            jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
+            for x in (q, k, v, g, beta))
+    n = n_tokens + pad
+    items = work_items(row_off, chunk_lens, n)
+    row = items["row"]
+    flags = (items["first"].astype(jnp.int32)
+             + 2 * (items["first"] & fresh[row]).astype(jnp.int32))
+    m = row.shape[0]
+
+    def tok_map(h, i, blk, *_):
+        return blk[i], h
+
+    def flat(x):
+        return x.reshape(n, heads * d)
+
+    grid = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=6,
+        grid=(heads, m),
+        in_specs=[
+            pl.BlockSpec((BLOCK, d), tok_map),
+            pl.BlockSpec((BLOCK, d), tok_map),
+            pl.BlockSpec((BLOCK, d), tok_map),
+            pl.BlockSpec((BLOCK, d), tok_map),
+            pl.BlockSpec((BLOCK, heads), lambda h, i, blk, *_: (blk[i], 0)),
+            pl.BlockSpec((1, 1, d, d),
+                         lambda h, i, blk, rd, *_: (rd[i], h, 0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((BLOCK, d), tok_map),
+            pl.BlockSpec((1, 1, d, d),
+                         lambda h, i, blk, rd, wr, *_: (wr[i], h, 0, 0)),
+        ],
+    )
+    o, pool = pl.pallas_call(
+        _prefill_kernel,
+        grid_spec=grid,
+        out_shape=[jax.ShapeDtypeStruct((n, heads * d), jnp.float32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        input_output_aliases={11: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="kda_prefill_attention",
+    )(items["block"], read[row].astype(jnp.int32),
+      write[row].astype(jnp.int32), items["lo"], items["hi"], flags,
+      flat(q), flat(k), flat(v), flat(g), beta.astype(jnp.float32), pool)
+    return o.reshape(n, heads, d)[:n_tokens], pool
+
+
+# -- decode kernel -----------------------------------------------------------
+
+def _decode_kernel(rd_ref, wr_ref, q_ref, k_ref, v_ref, g_ref, b_ref,
+                   s_in_ref, o_ref, s_ref):
+    del rd_ref, wr_ref
+    f32 = jnp.float32
+    hb, d = q_ref.shape[1], q_ref.shape[2]
+    # the heads' q, k and decay as COLUMNS (a key channel a sublane, as
+    # the state's rows are): one transpose of a (3 hb padded to D, D) tile
+    rows = jnp.concatenate(
+        [q_ref[0].astype(f32), k_ref[0].astype(f32),
+         jnp.exp(g_ref[0].astype(f32))], axis=0)
+    pad = (-3 * hb) % d
+    if pad:
+        rows = jnp.concatenate([rows, jnp.zeros((pad, d), f32)], axis=0)
+    cols = rows.T  # (D, >= 3 hb)
+    v = v_ref[0].astype(f32)
+    beta = b_ref[0].astype(f32)  # (hb, 1)
+    outs = []
+    for j in range(hb):
+        qc, kc = cols[:, j:j + 1], cols[:, hb + j:hb + j + 1]
+        s = s_in_ref[0, j] * cols[:, 2 * hb + j:2 * hb + j + 1]
+        pred = jnp.sum(s * kc, axis=0, keepdims=True)  # (1, D)
+        u = beta[j:j + 1] * (v[j:j + 1] - pred)
+        s = s + kc * u
+        s_ref[0, j] = s
+        outs.append(jnp.sum(s * qc, axis=0, keepdims=True))
+    o_ref[0] = jnp.concatenate(outs, axis=0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def kda_decode_attention(pool, read, write, q, k, v, g, beta, *,
+                         interpret: bool = False):
+    """One step for B rows through the decode kernel: q, k, v, g (B, H, D),
+    beta (B, H). Returns (o (B, H, D) float32, pool')."""
+    b, heads, d = q.shape
+    hb = 8 if heads % 8 == 0 else heads
+
+    def vec(i, j, rd, wr):
+        return i, j, 0
+
+    grid = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, heads // hb),
+        in_specs=[
+            pl.BlockSpec((1, hb, d), vec), pl.BlockSpec((1, hb, d), vec),
+            pl.BlockSpec((1, hb, d), vec), pl.BlockSpec((1, hb, d), vec),
+            pl.BlockSpec((1, hb, 1), vec),
+            pl.BlockSpec((1, hb, d, d), lambda i, j, rd, wr: (rd[i], j, 0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, hb, d), vec),
+            pl.BlockSpec((1, hb, d, d), lambda i, j, rd, wr: (wr[i], j, 0, 0)),
+        ],
+    )
+    o, pool = pl.pallas_call(
+        _decode_kernel,
+        grid_spec=grid,
+        out_shape=[jax.ShapeDtypeStruct((b, heads, d), jnp.float32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        input_output_aliases={7: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="kda_decode_attention",
+    )(read.astype(jnp.int32), write.astype(jnp.int32), q, k, v,
+      g.astype(jnp.float32), beta.astype(jnp.float32)[..., None], pool)
+    return o, pool

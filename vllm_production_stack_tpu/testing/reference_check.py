@@ -156,13 +156,21 @@ def compare(reference, cfg, params, prompts: list[list[int]],
     margins: list[float] = []
     # reference_mla.py and reference_laguna.py read checkpoint-style names
     # and orientation, and take `logit_positions` and `forced_experts`
-    by_name = cfg.mla or cfg.architecture == "laguna"
+    by_name = cfg.mla or cfg.architecture in ("laguna", "solar_open2")
     if by_name:
         from ..models import loader
 
         view = (loader.DeepseekCheckpointView if cfg.mla
+                else loader.SolarOpen2CheckpointView
+                if cfg.architecture == "solar_open2"
                 else loader.LagunaCheckpointView)
         params_ref = view(cfg, params)
+    if cfg.linear_layers and cfg.num_experts and served_routing is None:
+        # (`system_routing` drives `llama.forward` row-major on a scratch
+        # pool, which has no state slots)
+        raise ValueError(
+            f"{cfg.model}: the routing of a model with layers that hold "
+            "state comes from the served programs' tap (`served_routing`)")
     for prompt, got in zip(prompts, served):
         seq = list(prompt) + list(got["token_ids"][:-1])
         first = len(prompt) - 1  # the first compared position
@@ -171,12 +179,14 @@ def compare(reference, cfg, params, prompts: list[list[int]],
             # a long sequence's are GBs of float32)
             forced = (
                 served_routing.routing_of(
-                    seq, mark_ambiguous=cfg.architecture == "laguna")
+                    seq, mark_ambiguous=cfg.architecture in (
+                        "laguna", "solar_open2"))
                 if served_routing is not None else None)
             # (laguna: 72 heads x 16k keys of float32 scores a query —
             # 64 queries a block keep a block's scores under 0.4 GB beside
             # the served weights, which stay on the chip)
-            block = {"query_block": 64} if cfg.architecture == "laguna" else {}
+            block = ({"query_block": 64} if cfg.architecture in (
+                "laguna", "solar_open2") else {})
             logits, routing = reference.forward(
                 ref_cfg, params_ref, seq, range(first, len(seq)),
                 forced_experts=forced, **block)
@@ -219,3 +229,87 @@ def compare(reference, cfg, params, prompts: list[list[int]],
             routing_disagreement_share=differing / max(routed, 1),
             routing_margin_max_where_differing=max(margins, default=0.0))
     return out
+
+
+class StateTap:
+    """What the SERVED programs fed the recurrence of one layer that holds
+    state, token by token: set as `llama.STATE_TAP` while an engine is built
+    and driven (as `RoutingTap` is), it receives every execution's (pool
+    index each token's row wrote its state at, positions, q, k, v, g, beta)
+    and gives them back per state slot, in the order of the positions."""
+
+    def __init__(self, layer: int):
+        self.layer = layer
+        self.records: list[tuple] = []
+        self._rows: dict | None = None
+
+    def __call__(self, *fed) -> None:
+        self.records.append(tuple(np.asarray(x) for x in fed))
+
+    def __enter__(self) -> "StateTap":
+        llama.STATE_TAP = self
+        return self
+
+    def __exit__(self, *exc) -> None:
+        llama.STATE_TAP = None
+        jax.effects_barrier()  # every callback has run
+
+    def rows(self, state_slots: int) -> dict[int, tuple]:
+        """{slot: (the pool index its last token wrote, q, k, v, g, beta over
+        the row's tokens)} of every slot a row held (`state_slots` a bank;
+        slot 0, the padding rows', left out). A slot has to have been fed
+        positions 0..n-1 once each: one row, never preempted nor rolled
+        back — the check drives the engine so."""
+        if self._rows is not None:
+            return self._rows
+        slots = np.concatenate([r[0] for r in self.records])
+        positions = np.concatenate([r[1] for r in self.records])
+        fed = [np.concatenate([r[2 + i] for r in self.records])
+               for i in range(5)]
+        out = {}
+        for slot in np.unique(slots % state_slots):
+            if slot == 0:
+                continue
+            at = np.flatnonzero(slots % state_slots == slot)
+            at = at[np.argsort(positions[at], kind="stable")]
+            if not np.array_equal(positions[at], np.arange(len(at))):
+                raise ValueError(
+                    f"state slot {slot} was fed positions other than 0.."
+                    f"{len(at) - 1} once each (a second row, a preemption "
+                    "or a rollback): the check of the served state covers "
+                    "one uninterrupted row a slot")
+            out[int(slot)] = (int(slots[at[-1]]), *(x[at] for x in fed))
+        self._rows, self.records = out, []
+        return out
+
+
+def served_state(reference, cfg, tap: StateTap, pool,
+                 token_block: int = 2048) -> dict:
+    """The recurrent STATE the engine holds in its slots, where a comparison
+    of logprobs cannot see it: bf16 weights and activations through 8 layers
+    scatter logprobs by as much as a state rounded to bfloat16 at every
+    token does (PERF.md §6, PR 39), so the state has a limit of its own.
+    `pool` is layer `tap.layer`'s state pool as the engine left it after
+    serving (`runner.kv_caches[layer]["s"]`, two banks of slots); each row's
+    slot is compared with what the REFERENCE's token-by-token recurrence
+    (`reference.delta_rule`, from a zero state, in the state dtype the
+    reference's configuration states) makes of the very q, k, v, g, beta
+    the served programs fed that layer — every prefill chunk, seam, decode
+    window and bank flip of the row lies between the two. Returns the
+    largest |difference| over the reference's largest magnitude, over the
+    rows."""
+    state_dtype = jnp.dtype(
+        reference.published_keys(cfg).get("kda_state_dtype", "float32"))
+    step = jax.jit(functools.partial(
+        reference.delta_rule, state_dtype=state_dtype))
+    worst, tokens = 0.0, 0
+    rows = tap.rows(pool.shape[0] // 2)
+    for wrote, *fed in rows.values():
+        s = jnp.zeros(pool.shape[1:], jnp.float32)
+        for lo in range(0, len(fed[0]), token_block):
+            _, s = step(*(jnp.asarray(x[lo:lo + token_block]) for x in fed), s)
+        err = float(jnp.max(jnp.abs(s - jnp.asarray(pool[wrote]))))
+        worst = max(worst, err / float(jnp.max(jnp.abs(s))))
+        tokens += len(fed[0])
+    return {"layer": tap.layer, "rows": len(rows), "tokens": tokens,
+            "state_rel_err": worst}
