@@ -12,9 +12,12 @@ bf16 q, k, v, float32 state), one layer:
   rows with a seam inside a block; a continued row, a fresh row and a
   padding row) and `kda_decode_attention` at 16 rows, against
   ops/kda_attention.py's XLA forms;
-- time: the prefill kernel's ms a 2,048-token chunk and layer, the decode
-  kernel's ms a 16-row step and layer, with the share of the HBM bound of
-  what each must move (benchmarks/chip/costs_kda.py's counts).
+- time: the prefill kernel's ms a 2,048-token chunk and layer in each of the
+  three layouts, with the heads a grid step holds and the us a HEAD-ITEM
+  (one head x one row's part of one 64-token block: what the kernel's time
+  goes by), the decode kernel's ms a 16-row step and layer, each with the
+  share of the HBM bound of what it must move (benchmarks/chip/costs_kda.py's
+  counts).
 
 One JSON line per measurement, kept in chiprun_out/sweep_kda.jsonl too; the
 last line is {"ok": ...}.
@@ -120,11 +123,18 @@ def main() -> int:
         good = max(errs) <= ATOL and s_err <= ATOL
         ok &= good
         tokens = sum(lens)
+        # a row's work items: the 64-token blocks its segment touches
+        items = sum(
+            -(-(int(off[r]) + n) // kda.BLOCK) - int(off[r]) // kda.BLOCK
+            for r, n in enumerate(lens) if n)
         need = (tokens * (H * D * (4 * 2 + 4) + H * 4)
                 + sum(1 for n in lens if n) * 2 * H * D * D * 4)
         emit({"kernel": "kda_prefill_attention", "case": name, "ok": good,
               "o_err_over_tol": max(errs), "state_err_over_tol": s_err,
               "ms": secs * 1e3, "tokens": tokens,
+              "heads_per_step": kda.heads_per_step(H, D, q.dtype.itemsize),
+              "head_items": H * items,
+              "us_per_head_item": secs * 1e6 / (H * items),
               "hbm_bound_ms": need / HBM_BYTES_PER_S * 1e3,
               "share_of_hbm_bound": need / HBM_BYTES_PER_S / secs})
     rows = 16

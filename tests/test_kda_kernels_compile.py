@@ -45,6 +45,16 @@ def test_prefill_kernel_compiles_at_the_cells_shapes(topo, rows):  # noqa: F811
     # the pool is updated in place: no second copy of it among the temps
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * SLOTS * (
         HEADS * D * D * 4)
+    # the grid the compiled kernel walks: several heads a step (a fall back
+    # to one head a step fails here, not in a benchmark), the work items of
+    # a chunk of `rows` rows
+    (call,) = [e for e in jax.make_jaxpr(
+        kda.kda_prefill_attention.__wrapped__)(*args).eqns
+        if e.primitive.name == "pallas_call"]
+    per_step = kda.heads_per_step(HEADS, D, 2)
+    assert per_step > 1
+    assert tuple(call.params["grid_mapping"].grid) == (
+        HEADS // per_step, CHUNK // kda.BLOCK + rows)
 
 
 @pytest.mark.parametrize("rows", [1, 16])

@@ -121,6 +121,9 @@ def test_engine_against_the_float32_reference(cfg, backend, layers, lengths):
         len(model.page_layers), len(model.linear_layers))
     assert report["linear_backend"]["prefill"] == (
         "xla" if backend == "xla" else "kda_prefill_attention")
+    assert report["linear_backend"].get("heads_per_step") == (
+        None if backend == "xla" else kda.heads_per_step(
+            model.linear_num_heads, model.linear_head_dim, 4))
 
 
 def _faulty(**edits):
@@ -197,20 +200,24 @@ def _recurrence(pool, read, write, fresh, row_off, lens, q, k, v, g, beta):
     return o, new
 
 
-@pytest.mark.parametrize("d", [16, 128])
+# heads a grid step of the kernel holds (ops/kda_attention.heads_per_step):
+# both of two, the one of one, two groups of four, and three of six, where
+# four does not divide the heads
+@pytest.mark.parametrize("heads,d,per_step", [
+    (2, 16, 2), (1, 128, 1), (8, 128, 4), (6, 16, 3)])
 @pytest.mark.parametrize("gate_scale,beta_logit,lens", [
     (1e-3, 0.0, (70, 5, 0, 100)),   # gates near 0: nothing decays
     (30.0, 0.0, (70, 5, 0, 100)),   # the strongest decay: exp(-30 |x|)
     (1.0, 6.0, (64, 1, 63, 40)),    # beta near 2
     (1.0, 0.0, (3, 130, 2, 1))])    # lengths that are no multiple of 64
-def test_chunked_kernel_against_the_recurrence(d, gate_scale, beta_logit,
-                                               lens):
+def test_chunked_kernel_against_the_recurrence(heads, d, per_step,
+                                               gate_scale, beta_logit, lens):
     """The chunked prefill kernel (interpret mode) over a packed axis —
     rows that start inside a block, a row of no tokens, a row that starts
     from zeros — against the recurrence, reading one bank of slots and
     writing the other; slots the chunk does not name keep their bytes."""
-    heads = 2 if d == 16 else 1
-    rng = np.random.RandomState(int(gate_scale * 10) + d)
+    assert kda.heads_per_step(heads, d, 4) == per_step
+    rng = np.random.RandomState(int(gate_scale * 10) + d + heads)
     lens = np.asarray(lens, np.int32)
     n = 192
     row_off = (np.cumsum(lens) - lens).astype(np.int32)
@@ -234,6 +241,30 @@ def test_chunked_kernel_against_the_recurrence(d, gate_scale, beta_logit,
         np.asarray(o)[live], want_o[live], rtol=1e-4, atol=2e-5)
     np.testing.assert_allclose(
         np.asarray(got_pool), want_pool, rtol=1e-4, atol=5e-5)
+
+
+def test_the_report_names_the_prefill_kernels_grid_step(cfg):
+    """`/debug/programs` -> `device.mixers.linear_backend`: several heads a
+    grid step at Solar-Open2's shape (64 heads x 128, bf16) and at the tiny
+    preset's, one at a one-head shape, nothing under the XLA form."""
+    from vllm_production_stack_tpu.engine.model_runner import (
+        linear_backend_report,
+    )
+
+    solar = resolve_model_config("solar-open2-250b-ep8-8l")
+    report = linear_backend_report(solar, "pallas", "pallas")
+    assert report["prefill"] == "kda_prefill_attention"
+    assert report["block_tokens"] == kda.BLOCK == 64
+    assert 1 < report["heads_per_step"] <= solar.linear_num_heads
+    assert solar.linear_num_heads % report["heads_per_step"] == 0
+    tiny = linear_backend_report(cfg, "pallas_interpret", "pallas_interpret")
+    assert tiny["heads_per_step"] == kda.heads_per_step(
+        cfg.linear_num_heads, cfg.linear_head_dim, 4) > 1
+    one = linear_backend_report(
+        dataclasses.replace(cfg, linear_num_heads=1), "pallas", "pallas")
+    assert (one["heads_per_step"], one["block_tokens"]) == (1, 64)
+    assert linear_backend_report(solar, "xla", "xla") == {
+        "decode": "xla", "prefill": "xla"}
 
 
 @pytest.mark.parametrize("heads,d", [(4, 16), (8, 128)])

@@ -30,6 +30,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models import llama
+from ..ops import kda_attention
 from ..ops.attention import gather_pages
 from ..ops.paged_attention_pallas import _pages_fill_tiles
 from ..utils.logging import init_logger
@@ -362,6 +363,25 @@ def _collect_logprobs(logits: jax.Array, tokens: jax.Array):
     chosen = jnp.take_along_axis(lp, tokens[:, None].astype(jnp.int32), 1)[:, 0]
     top_lp, top_id = jax.lax.top_k(lp, LOGPROBS_TOPN)
     return chosen, top_lp, top_id.astype(jnp.int32)
+
+
+def linear_backend_report(cfg, attention_backend: str,
+                          prefill_backend: str) -> dict:
+    """Which form the linear-attention (KDA) layers' programs hold, and for
+    the chunked prefill kernel the shape of its grid step at this model's
+    heads: `heads_per_step` heads x one row's part of a `block_tokens`
+    block (ops/kda_attention.py reads both off the shapes)."""
+    report = {"decode": ("kda_decode_attention"
+                         if attention_backend != "xla" else "xla"),
+              "prefill": "xla"}
+    if prefill_backend.startswith("pallas"):
+        report.update(
+            prefill="kda_prefill_attention",
+            heads_per_step=kda_attention.heads_per_step(
+                cfg.linear_num_heads, cfg.linear_head_dim,
+                jnp.dtype(cfg.dtype).itemsize),
+            block_tokens=kda_attention.BLOCK)
+    return report
 
 
 class ModelRunner:
@@ -895,13 +915,8 @@ class ModelRunner:
                 "softmax": len(cfg.page_layers),
                 "linear": len(cfg.linear_layers),
                 "state_slots": self.state_slots,
-                "linear_backend": {
-                    "decode": ("kda_decode_attention"
-                               if self._attention_backend != "xla"
-                               else "xla"),
-                    "prefill": ("kda_prefill_attention"
-                                if self._prefill_backend.startswith("pallas")
-                                else "xla")},
+                "linear_backend": linear_backend_report(
+                    cfg, self._attention_backend, self._prefill_backend),
             }
         if self.config.model.num_experts:
             # the expert computation a program holds follows from its row

@@ -21,8 +21,17 @@ Prefill, `kda_prefill_attention` (device op of that name): the
 chunkwise-parallel form over a PACKED token axis. The axis is cut into
 blocks of 64 tokens; a WORK ITEM is the part of one row's segment that
 lies in one block (a block that holds a seam between two rows is two
-items), and the grid walks (head, item). Inside an item, with G the
-cumulative log-gates from the item's first token:
+items), and the grid walks (head group, item): a grid step holds
+`heads_per_step` HEADS of one item (4 of Solar-Open2's 64; read off the
+head count and the blocks' VMEM, 1 for a one-head shape) — their token
+tiles one (64, heads D) slab of the flat (N, H D) arrays, their states one
+(1, heads, D, D) block. One head's item is a chain of ~30 dependent stages
+(16 turns on the VPU, a dozen small float32 products at the highest
+precision, six MXU passes each): alone it leaves the MXUs idle behind every
+product, so the step issues a stage of each of its heads in turn, and what
+bounds it is the MXU's pushes and pops, no longer the chain (PERF.md §6,
+PR 40). Inside an item, with G the cumulative log-gates from the item's
+first token:
 
     A[s, r] = sum_c k[s, c] k[r, c] exp(G[s, c] - G[r, c])      (r < s)
     B[s, r] = sum_c q[s, c] k[r, c] exp(G[s, c] - G[r, c])      (r <= s)
@@ -31,6 +40,12 @@ cumulative log-gates from the item's first token:
     U  = T Diag(beta) (V - (K * exp(G)) S0)
     O  = (Q * exp(G)) S0 + B U
     S1 = Diag(exp(G_last)) S0 + (K * exp(G_last - G))^T U
+
+Products that share an operand go as one (both gate masks against g; A's
+and B's rows of a sub-block against the columns before it; (beta K exp G)
+and (Q exp G) against S0), block-diagonal left operands as strips of their
+blocks, and a turn holds only the rows at or under the diagonal: the same
+sums of the same float32 terms, fewer MXU pushes and VPU tiles.
 
 exp of a NEGATED cumulative gate overflows under strong decay, so no factor
 exp(-G) is ever formed: A and B are built in sub-blocks of 16 tokens. Off the
@@ -65,6 +80,12 @@ from jax.experimental.pallas import tpu as pltpu
 BLOCK = 64  # tokens of a prefill work item
 SUB = 16  # tokens of a sub-block (where gate differences are formed)
 _HI = jax.lax.Precision.HIGHEST
+# heads of one prefill grid step, at most. On a v5e at 64 heads x 128
+# (benchmarks/sweep_kda.py, PERF.md §6, PR 40): 2.77 / 1.87 / 1.54 / 1.49 us
+# a head-item at 1 / 2 / 4 / 8 — from 4 on the MXU's slots bound the step,
+# and 8 doubles what every engine start pays to trace and lower the body
+_MAX_HEADS_PER_STEP = 4
+_BLOCKS_VMEM_BYTES = 8 << 20  # of the 16 MiB a kernel may use on a v5e
 
 
 def kda_step(s, q, k, v, g, beta):
@@ -143,123 +164,217 @@ def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
         a, b, dims, precision=_HI, preferred_element_type=jnp.float32)
 
 
+def heads_per_step(heads: int, d: int, itemsize: int = 2) -> int:
+    """How many heads one grid step of the prefill kernel holds: the
+    largest divisor of `heads` not above `_MAX_HEADS_PER_STEP` whose blocks
+    (the token slabs of q, k, v, g and o and the state in and out, each
+    double-buffered by the pipeline) fit `_BLOCKS_VMEM_BYTES`."""
+    per_head = 2 * (BLOCK * d * (3 * itemsize + 2 * 4) + 2 * d * d * 4)
+    return max(hb for hb in range(1, min(heads, _MAX_HEADS_PER_STEP) + 1)
+               if heads % hb == 0
+               and (hb == 1 or hb * per_head <= _BLOCKS_VMEM_BYTES))
+
+
 def _prefill_kernel(blk_ref, rd_ref, wr_ref, lo_ref, hi_ref, fl_ref,
                     q_ref, k_ref, v_ref, g_ref, b_ref, s_in_ref,
                     o_ref, s_ref):
     del blk_ref, rd_ref, wr_ref
     f32 = jnp.float32
-    h, m = pl.program_id(0), pl.program_id(1)
+    hg, m = pl.program_id(0), pl.program_id(1)
     lo, hi, fl = lo_ref[m], hi_ref[m], fl_ref[m]
+    hb, d = s_ref.shape[1], s_ref.shape[2]
 
     @pl.when((fl & 1) != 0)
-    def _load():  # the row's first item: its state, or zeros
+    def _load():  # the row's first item: its heads' states, or zeros
         s_ref[...] = jnp.where((fl & 2) != 0, 0.0, s_in_ref[...])
 
     @pl.when(hi > lo)
     def _item():
-        d = q_ref.shape[1]
         tok = jax.lax.broadcasted_iota(jnp.int32, (BLOCK, 1), 0)
         live = (tok >= lo) & (tok < hi)
-        q = jnp.where(live, q_ref[...].astype(f32), 0.0)
-        k = jnp.where(live, k_ref[...].astype(f32), 0.0)
-        v = jnp.where(live, v_ref[...].astype(f32), 0.0)
-        g = jnp.where(live, g_ref[...].astype(f32), 0.0)
-        heads = jax.lax.broadcasted_iota(jnp.int32, b_ref.shape, 1)
-        beta = jnp.sum(jnp.where(heads == h, b_ref[...].astype(f32), 0.0),
-                       axis=1, keepdims=True)
-        beta = jnp.where(live, beta, 0.0)  # (BLOCK, 1)
         r_i = jax.lax.broadcasted_iota(jnp.int32, (BLOCK, BLOCK), 0)
         c_i = jax.lax.broadcasted_iota(jnp.int32, (BLOCK, BLOCK), 1)
-        # log-gates summed DIRECTLY over the range each factor decays
-        # across (sums of non-positive numbers: a difference of two long
-        # cumulative sums would lose the small decays behind a strong one):
-        # within a token's own sub-block its inclusive prefix `lp` and its
-        # strict suffix `ls`, and each sub-block's total
         same = r_i // SUB == c_i // SUB
-        lp = _dot((same & (r_i >= c_i)).astype(f32), g)  # (BLOCK, D)
-        ls = _dot((same & (r_i < c_i)).astype(f32), g)
-        n_sub = BLOCK // SUB
-        tot = [lp[(i + 1) * SUB - 1:(i + 1) * SUB] for i in range(n_sub)]
-
-        def span(lo_sub, hi_sub):  # total of sub-blocks [lo_sub, hi_sub)
-            return sum(tot[lo_sub:hi_sub], jnp.zeros_like(tot[0]))
+        pair = r_i // (2 * SUB) == c_i // (2 * SUB)
+        # both gate masks as one (2 BLOCK, BLOCK) operand: a token's
+        # inclusive prefix and its strict suffix inside its own sub-block
+        masks = jnp.concatenate(
+            [(same & (r_i >= c_i)).astype(f32),
+             (same & (r_i < c_i)).astype(f32)], axis=0)
+        eye = (jax.lax.broadcasted_iota(jnp.int32, (d, d), 0)
+               == jax.lax.broadcasted_iota(jnp.int32, (d, d), 1))
+        n_sub, half = BLOCK // SUB, SUB // 2
+        nt = (((1,), (1,)), ((), ()))
+        # (BLOCK // 2, BLOCK), a turn: where a row of a half faces the
+        # turn's column of its own sub-block
+        cols = [(c_i - r_i // half * SUB)[:BLOCK // 2] == r
+                for r in range(SUB)]
 
         def rows_of(fn):  # (BLOCK, D) from one (SUB, D) piece a sub-block
             return jnp.concatenate(
                 [fn(i, slice(i * SUB, (i + 1) * SUB))
                  for i in range(n_sub)], axis=0)
 
-        # from the item's first token to each token, and from each token to
-        # the item's last
-        gc = rows_of(lambda i, sl: lp[sl] + span(0, i))
-        g_tail = rows_of(lambda i, sl: ls[sl] + span(i + 1, n_sub))
+        def halves(x):  # (BLOCK, .) -> the sub-blocks' upper, lower halves
+            return [jnp.concatenate(
+                [x[i * SUB + h * half:i * SUB + (h + 1) * half]
+                 for i in range(n_sub)], axis=0) for h in range(2)]
 
-        sub_c = jax.lax.broadcasted_iota(jnp.int32, (SUB, BLOCK), 1)
-        a_rows, b_rows = [], []
-        for i in range(n_sub):
-            sl = slice(i * SUB, (i + 1) * SUB)
-            g_i, k_i, q_i = lp[sl], k[sl], q[sl]
-            a_d = jnp.zeros((SUB, BLOCK), f32)
-            b_d = jnp.zeros((SUB, BLOCK), f32)
-            for r in range(SUB):  # the diagonal sub-block, a column a turn
-                e = jnp.exp(jnp.minimum(g_i - g_i[r:r + 1], 0.0))
-                kr = k_i[r:r + 1] * e
-                col = sub_c == i * SUB + r
-                a_d = jnp.where(
-                    col, jnp.sum(k_i * kr, axis=1, keepdims=True), a_d)
-                b_d = jnp.where(
-                    col, jnp.sum(q_i * kr, axis=1, keepdims=True), b_d)
-            if i:
-                # columns before the sub-block: the row decays from the
-                # sub-block's doorstep (its own prefix), the column to it
-                # (its suffix and the sub-blocks between) — both <= 0
-                to_door = rows_of(
-                    lambda j, sj: ls[sj] + span(j + 1, max(i, j + 1)))
-                k_r = k * jnp.exp(to_door)
-                e_s = jnp.exp(g_i)
-                nt = (((1,), (1,)), ((), ()))
-                before = sub_c < i * SUB
-                a_d = jnp.where(before, _dot(k_i * e_s, k_r, nt), a_d)
-                b_d = jnp.where(before, _dot(q_i * e_s, k_r, nt), b_d)
-            a_rows.append(a_d)
-            b_rows.append(b_d)
-        a = jnp.where(r_i > c_i, jnp.concatenate(a_rows, axis=0), 0.0)
-        bm = jnp.where(r_i >= c_i, jnp.concatenate(b_rows, axis=0), 0.0)
+        def whole(x):  # ... and back
+            return jnp.concatenate(
+                [x[h][i * half:(i + 1) * half]
+                 for i in range(n_sub) for h in range(2)], axis=0)
 
-        # T = (I + L)^-1, L = Diag(beta) A strictly lower. The diagonal
-        # sub-blocks by doubling, (I - N)(I + N^2)(I + N^4)(I + N^8) with
-        # N^16 = 0, then two merges of [[A, 0], [C, B]]^-1 = [[A', 0],
-        # [-B' C A', B']] (16 -> 32 -> 64). (Doubling over the whole block
-        # would go through L^32: at 64 keys in 16 dimensions its powers grow
-        # and the result lost two digits against substitution.)
-        low = beta * a
-        eye_b = (r_i == c_i).astype(f32)
-        pair = r_i // (2 * SUB) == c_i // (2 * SUB)
-        nil = jnp.where(same, low, 0.0)
-        t = eye_b - nil
-        p = _dot(nil, nil)
-        for lvl in range(3):
-            t = t + _dot(t, p)
-            if lvl < 2:
-                p = _dot(p, p)
-        for off in (jnp.where(pair & ~same, low, 0.0),
-                    jnp.where(pair, 0.0, low)):
-            t = t - _dot(_dot(t, off), t)
-        e_g = jnp.exp(gc)
-        s0 = s_ref[0, 0]
-        u = _dot(t, beta * v) - _dot(_dot(t, beta * (k * e_g)), s0)
-        o = _dot(q * e_g, s0) + _dot(bm, u)
-        o_ref[...] = jnp.where(live, o, o_ref[...].astype(f32)).astype(
-            o_ref.dtype)
-        # Diag(exp(G_last)) S0 + (K exp(G_last - G))^T U as ONE product
-        # contracted over rows: [K'; Diag] ^T [U; S0]
-        eye = (jax.lax.broadcasted_iota(jnp.int32, (d, d), 0)
-               == jax.lax.broadcasted_iota(jnp.int32, (d, d), 1))
-        lhs = jnp.concatenate(
-            [k * jnp.exp(g_tail),
-             jnp.where(eye, jnp.exp(gc[BLOCK - 1:BLOCK]), 0.0)], axis=0)
-        s_ref[0, 0] = _dot(lhs, jnp.concatenate([u, s0], axis=0),
-                           (((0,), (0,)), ((), ())))
+        # a block-diagonal (BLOCK, BLOCK) times another is the same small
+        # products whether its blocks stand on the diagonal or side by side
+        # as an (n, BLOCK) STRIP: the left operand of such a product goes
+        # as a strip (a quarter or a half of the rows through the MXU)
+        def strip(x, n):
+            return sum(x[i:i + n] for i in range(0, BLOCK, n))
+
+        def diag(x, blocks):  # ... and back; `blocks`: `same` or `pair`
+            return jnp.where(blocks, jnp.concatenate(
+                [x] * (BLOCK // x.shape[0]), axis=0), 0.0)
+
+        eye_s = strip((r_i == c_i).astype(f32), SUB)
+
+        def head(q, k, v, g, beta, s0):
+            """One head's item, a STAGE (a product, a turn) a `yield`: q, k,
+            v, g (BLOCK, D) and beta (BLOCK, 1) zeroed outside the item's
+            span, s0 (D, D). Yields (o, s1) last."""
+            # log-gates summed DIRECTLY over the range each factor decays
+            # across (sums of non-positive numbers: a difference of two
+            # long cumulative sums would lose the small decays behind a
+            # strong one): within a token's own sub-block its inclusive
+            # prefix `lp` and its strict suffix `ls`, and each sub-block's
+            # total
+            sums = _dot(masks, g)  # (2 BLOCK, D)
+            lp, ls = sums[:BLOCK], sums[BLOCK:]
+            tot = [lp[(i + 1) * SUB - 1:(i + 1) * SUB] for i in range(n_sub)]
+
+            def span(lo_sub, hi_sub):  # total of sub-blocks [lo_sub, hi_sub)
+                return sum(tot[lo_sub:hi_sub], jnp.zeros_like(tot[0]))
+
+            # from the item's first token to each token, and from each
+            # token to the item's last
+            gc = rows_of(lambda i, sl: lp[sl] + span(0, i))
+            g_tail = rows_of(lambda i, sl: ls[sl] + span(i + 1, n_sub))
+            yield
+
+            # the diagonal sub-blocks, a column of EACH a turn: turn r
+            # holds, in every sub-block, its r-th token against the rows at
+            # or under it — both halves of the sub-block's rows while r is
+            # in the upper half, the lower half alone after that (what lies
+            # over the diagonal is never used)
+            def at_turn(x, r):  # the r-th rows, each over a half's rows
+                return jnp.concatenate(
+                    [jnp.broadcast_to(x[i * SUB + r:i * SUB + r + 1],
+                                      (half, d)) for i in range(n_sub)],
+                    axis=0)
+
+            lp_h, k_h, q_h = halves(lp), halves(k), halves(q)
+            a_h = [jnp.zeros((BLOCK // 2, BLOCK), f32)] * 2
+            b_h = list(a_h)
+            for r in range(SUB):
+                lp_r, k_r = at_turn(lp, r), at_turn(k, r)
+                for h in range(r // half, 2):
+                    kr = k_r * jnp.exp(jnp.minimum(lp_h[h] - lp_r, 0.0))
+                    a_h[h] = jnp.where(cols[r], jnp.sum(
+                        k_h[h] * kr, axis=1, keepdims=True), a_h[h])
+                    b_h[h] = jnp.where(cols[r], jnp.sum(
+                        q_h[h] * kr, axis=1, keepdims=True), b_h[h])
+                yield
+            # columns before a sub-block: the row decays from the
+            # sub-block's doorstep (its own prefix), the column to it (its
+            # suffix and the sub-blocks between) — both <= 0; A's and B's
+            # rows of the sub-block against those columns in ONE product
+            e_s = jnp.exp(lp)
+            kq = (k * e_s, q * e_s)
+            off = [jnp.zeros((2 * SUB, BLOCK), f32)]
+            for i in range(1, n_sub):
+                sl = slice(i * SUB, (i + 1) * SUB)
+                to_door = jnp.concatenate(
+                    [ls[j * SUB:(j + 1) * SUB] + span(j + 1, i)
+                     for j in range(i)], axis=0)
+                ab = _dot(jnp.concatenate([x[sl] for x in kq], axis=0),
+                          k[:i * SUB] * jnp.exp(to_door), nt)
+                off.append(jnp.concatenate(
+                    [ab, jnp.zeros((2 * SUB, BLOCK - i * SUB), f32)],
+                    axis=1))
+                yield
+            off_a = jnp.concatenate([x[:SUB] for x in off], axis=0)
+            off_b = jnp.concatenate([x[SUB:] for x in off], axis=0)
+            a = jnp.where(r_i > c_i, jnp.where(same, whole(a_h), off_a), 0.0)
+            bm = jnp.where(
+                r_i >= c_i, jnp.where(same, whole(b_h), off_b), 0.0)
+
+            # T = (I + L)^-1, L = Diag(beta) A strictly lower. The diagonal
+            # sub-blocks by doubling, (I - N)(I + N^2)(I + N^4)(I + N^8)
+            # with N^16 = 0, then two merges of [[A, 0], [C, B]]^-1 =
+            # [[A', 0], [-B' C A', B']] (16 -> 32 -> 64). (Doubling over
+            # the whole block would go through L^32: at 64 keys in 16
+            # dimensions its powers grow and the result lost two digits
+            # against substitution.)
+            low = beta * a
+            nil = strip(jnp.where(same, low, 0.0), SUB)
+            t = eye_s - nil
+            p = _dot(nil, diag(nil, same))
+            yield
+            for lvl in range(3):
+                t = t + _dot(t, diag(p, same))
+                yield
+                if lvl < 2:
+                    p = _dot(p, diag(p, same))
+                    yield
+            t = diag(t, same)
+            t_s = strip(t, 2 * SUB)
+            t_p = _dot(t_s, jnp.where(pair & ~same, low, 0.0))
+            yield
+            t = diag(t_s - _dot(t_p, t), pair)
+            yield
+            t_p = _dot(t, jnp.where(pair, 0.0, low))
+            yield
+            t = t - _dot(t_p, t)
+            yield
+            # U = T Diag(beta) (V - (K exp(G)) S0); that product with S0
+            # and O's (Q exp(G)) S0 as ONE product against S0
+            e_g = jnp.exp(gc)
+            on_s0 = _dot(jnp.concatenate(
+                [beta * (k * e_g), q * e_g], axis=0), s0)
+            yield
+            u = _dot(t, beta * v - on_s0[:BLOCK])
+            yield
+            o = on_s0[BLOCK:] + _dot(bm, u)
+            yield
+            # S1 = Diag(exp(G_last)) S0 + (K exp(G_last - G))^T U: each key
+            # channel's decay as a COLUMN (the diagonal's row sums)
+            decay = jnp.sum(
+                jnp.where(eye, jnp.exp(gc[BLOCK - 1:BLOCK]), 0.0), axis=1,
+                keepdims=True)
+            yield o, decay * s0 + _dot(k * jnp.exp(g_tail), u,
+                                       (((0,), (0,)), ((), ())))
+
+        at = [slice(j * d, (j + 1) * d) for j in range(hb)]
+
+        def tile(ref, sl):  # a head's rows of the item's span
+            return jnp.where(live, ref[:, sl].astype(f32), 0.0)
+
+        head_i = jax.lax.broadcasted_iota(jnp.int32, b_ref.shape, 1)
+        betas = jnp.where(live, b_ref[...].astype(f32), 0.0)
+        # the step's heads side by side, a stage of each in turn (`zip`
+        # draws one from every generator): the chains of an item's ~30
+        # dependent stages are independent between heads, and issued next to
+        # each other one head's waits on the MXU are another head's work
+        *_, done = zip(*(
+            head(tile(q_ref, sl), tile(k_ref, sl), tile(v_ref, sl),
+                 tile(g_ref, sl),
+                 jnp.sum(jnp.where(head_i == hg * hb + j, betas, 0.0),
+                         axis=1, keepdims=True), s_ref[0, j])
+            for j, sl in enumerate(at)))
+        for j, (sl, (o, s1)) in enumerate(zip(at, done)):
+            o_ref[:, sl] = jnp.where(
+                live, o, o_ref[:, sl].astype(f32)).astype(o_ref.dtype)
+            s_ref[0, j] = s1
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -269,6 +384,7 @@ def kda_prefill_attention(pool, read, write, fresh, q, k, v, g, beta,
     the model's dtype, g (N, H, D) float32, beta (N, H) float32; `row_off`, `chunk_lens`, `read`, `write`, `fresh`
     (B,) a row. Returns (o (N, H, D) float32, pool')."""
     n_tokens, heads, d = q.shape
+    hb = heads_per_step(heads, d, q.dtype.itemsize)
     pad = (-n_tokens) % BLOCK
     if pad:  # (a bucket that is no whole number of blocks: tests)
         q, k, v, g, beta = (
@@ -289,19 +405,19 @@ def kda_prefill_attention(pool, read, write, fresh, q, k, v, g, beta,
 
     grid = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
-        grid=(heads, m),
+        grid=(heads // hb, m),
         in_specs=[
-            pl.BlockSpec((BLOCK, d), tok_map),
-            pl.BlockSpec((BLOCK, d), tok_map),
-            pl.BlockSpec((BLOCK, d), tok_map),
-            pl.BlockSpec((BLOCK, d), tok_map),
+            pl.BlockSpec((BLOCK, hb * d), tok_map),
+            pl.BlockSpec((BLOCK, hb * d), tok_map),
+            pl.BlockSpec((BLOCK, hb * d), tok_map),
+            pl.BlockSpec((BLOCK, hb * d), tok_map),
             pl.BlockSpec((BLOCK, heads), lambda h, i, blk, *_: (blk[i], 0)),
-            pl.BlockSpec((1, 1, d, d),
+            pl.BlockSpec((1, hb, d, d),
                          lambda h, i, blk, rd, *_: (rd[i], h, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((BLOCK, d), tok_map),
-            pl.BlockSpec((1, 1, d, d),
+            pl.BlockSpec((BLOCK, hb * d), tok_map),
+            pl.BlockSpec((1, hb, d, d),
                          lambda h, i, blk, rd, wr, *_: (wr[i], h, 0, 0)),
         ],
     )
