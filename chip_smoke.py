@@ -180,6 +180,25 @@ ROUTING_MARGIN_MAX_SOLAR = 0.04
 # 1.65e-2, 1.81e-2, 2.14e-2 (PR 39, chip, PERF.md §6): the limit lies
 # between, 6 x over the one and 16 x under the other.
 KDA_STATE_RTOL = 1e-3
+# SDAR-30B-A3B (generation by diffusion over blocks, bf16 weights, 8 of 48
+# layers; benchmarks/chip/configs/sdar-30b-a3b-bf16-8l.json): the served
+# windows of block passes against reference/sdar.py's forward over the same
+# block states, teacher-forced with the engine's own order of unmasking and
+# the served programs' routing (testing/reference_check.py:compare_blocks),
+# at the logprobs of the tokens each pass unmasked. Both limits lie between
+# the served arm's readings and the faulty references' (PERF.md §6, PR 41,
+# three seeds on the chip): the largest |Δlogprob| and the mean, which a
+# fault in the mathematics shifts at every position while rounding scatters.
+# Largest: served 0.0225-0.0268; no commit pass 0.139-0.242, no q/k norm
+# 0.179-0.211, shifted logits and a causal mask 0.64-1.26.
+REFERENCE_ATOL_SDAR = 0.09
+# Mean: served 0.0063-0.0067 over 8 rows (36-44 positions); the router, the
+# softmax and the confidence in bfloat16 (the nearest precision below the
+# float32 the configuration states) 0.0199 and 0.0208 over the same rows on
+# two seeds (its largest, 0.067-0.078, is inside the limit above: the mean
+# alone tells it); no commit pass 0.028-0.036. 1.8 x over the one, 1.7 x
+# under the other.
+REFERENCE_MEAN_ATOL_SDAR = 0.012
 
 
 def engine_flags(model: str, seed: int, tp: int = 1,
@@ -830,7 +849,7 @@ def child_reference(args) -> int:
     # full layers, a per-head gate, a share of the experts) or solar_open2's
     # (KDA layers over state slots between gated NoPE GQA layers)
     family = {"deepseek_v3": "mla", "laguna": "laguna",
-              "solar_open2": "solar_open2"}.get(
+              "solar_open2": "solar_open2", "sdar_moe": "sdar"}.get(
         config.get("model_type"), "decoder")
     # (the two families whose reference follows the served routing and
     # whose cell is the long-document one)
@@ -842,6 +861,8 @@ def child_reference(args) -> int:
             REPO, "benchmarks", "chip", "reference", family + ".py"))
     reference = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(reference)
+    if family == "sdar":
+        return _child_reference_sdar(args, config, reference)
     # (max |Δlogprob|, mean |Δlogprob| or None, routing disagreement share,
     # routing margin where differing): each family's limits, set above
     atol, mean_atol, disagreement_max, margin_max = {
@@ -984,6 +1005,121 @@ def child_reference(args) -> int:
         fp8 = report["fp8_pool"] = arm(
             ["--kv-cache-dtype", "fp8", *kernels])
         report["ok"] = report["ok"] and bool(args.rehearse or not inside(fp8))
+    emit(report)
+    return 0 if report["ok"] else 1
+
+
+def _child_reference_sdar(args, config: dict, reference) -> int:
+    """`child_reference` for a model that generates by diffusion over blocks:
+    16 rows in flight, prompts of the cell's lengths, the engine's own
+    tokens, order of unmasking and logprobs against `reference.forward`
+    over the same block states (a row's first and last block: the prompt's
+    tail beside masks, and the deepest context), then the same served
+    numbers against references and procedures that are wrong in one way
+    each. Exit 0 only if the served arm is inside both limits and every
+    faulty one outside."""
+    import jax
+    import numpy as np
+
+    from vllm_production_stack_tpu.engine.engine import LLMEngine
+    from vllm_production_stack_tpu.engine.server import (
+        build_parser, engine_config_from_args,
+    )
+    from vllm_production_stack_tpu.testing import reference_check
+    from vllm_production_stack_tpu.utils.compile_cache import (
+        configure_compile_cache,
+    )
+
+    flags = (config["rehearse"] if args.rehearse else config)["engine_flags"]
+    dev = jax.devices()[0]
+    # (the rehearsal serves float32: what differs there is the order of
+    # sums, and a bfloat16 statistic is told from it only by that limit)
+    atol, mean_atol = (1e-4, 1e-5) if args.rehearse else (
+        REFERENCE_ATOL_SDAR, REFERENCE_MEAN_ATOL_SDAR)
+    report: dict = {"phase": "reference", "config": config["name"],
+                    "platform": dev.platform, "kind": dev.device_kind,
+                    "seed": args.seed, "atol": atol, "mean_atol": mean_atol}
+    if dev.platform != "tpu" and not args.rehearse:
+        report.update(ok=False, error=f"platform is {dev.platform!r}")
+        emit(report)
+        return 1
+    configure_compile_cache()
+    rng = np.random.RandomState(args.seed % (2 ** 31))
+    lo, hi, n_out, pad = (
+        (16, 64, 11, 32) if args.rehearse else (128, 1024, 18, 128))
+    lengths = np.exp(rng.uniform(np.log(lo), np.log(hi), size=16)).astype(int)
+    lengths[:2] = [hi, hi - 3]  # the longest, and one with a tail of 1
+    report["prompt_lengths"] = [int(n) for n in lengths]
+    eargs = build_parser().parse_args(
+        [*flags, "--seed", str(args.seed % (2 ** 31))])
+    ecfg = engine_config_from_args(eargs)
+    model = ecfg.model
+    # (never the mask token's id: the reference tells a mask by it)
+    prompts = [list(map(int, rng.randint(
+        1, min(model.vocab_size, model.block_diffusion.mask_token_id), n)))
+        for n in lengths]
+    t0 = time.monotonic()
+    with (reference_check.BlockTap() as tap,
+          reference_check.RoutingTap() as routing):
+        engine = LLMEngine(ecfg)
+        device = engine.runner.device_report()
+        served_rows = reference_check.served_block_logprobs(
+            engine, prompts, n_out)
+        timing = engine.flightrec.timing()
+    served = {"attention": device.get("attention"), "moe": device.get("moe"),
+              "generation": device.get("generation"),
+              "engine_s": round(time.monotonic() - t0, 1),
+              **{k: v for k, v in timing.items() if k.startswith("bd_")}}
+    # (why the passes touch the share of the experts they do: PERF.md §5)
+    served["touched_split"] = tap.touched_split(
+        model.block_diffusion.mask_token_id, model.num_experts)
+    params = engine.runner.params
+    engine.runner.shutdown()
+    engine.runner.kv_caches = None
+    del engine
+
+    def compare(rows, **kw):
+        return reference_check.compare_blocks(
+            reference, model, params, [prompts[i] for i in rows],
+            [served_rows[i] for i in rows], tap, routing, blocks="ends",
+            query_block=64, pad_to=pad, **kw)
+
+    t0 = time.monotonic()
+    wide = range(0, 16, 2)
+    served.update(compare(wide))
+    served["reference_s"] = round(time.monotonic() - t0, 1)
+    report["served"] = served
+
+    def inside(arm: dict) -> bool:
+        return (arm["max_abs_dlogprob"] <= atol
+                and arm["mean_abs_dlogprob"] <= mean_atol)
+
+    # the two shortest rows against what is wrong in ONE way each: a cache
+    # that keeps a block's last denoising pass (no commit pass), Qwen3's own
+    # causal mask, logits read one position to the left, no q/k norm. The
+    # nearest precision below the float32 the configuration states for the
+    # router, the softmax and the confidence shifts the mean by the least of
+    # them and is told by the mean alone, so it is read over the served
+    # arm's own rows: a mean over as many positions as the one it is told
+    # from.
+    keys = reference.published_keys(model)
+    short = [int(i) for i in np.argsort(lengths)[:2]]
+    t0 = time.monotonic()
+    report["faults"] = {
+        "no_commit_pass": compare(short, commit_pass=False),
+        "shifted_logits": compare(short, shift=True),
+        **{name: compare(short, ref_cfg={**keys, **wrong})
+           for name, wrong in (
+               ("causal_mask", {"causal_mask": True}),
+               ("no_qk_norm", {"qk_norm": False}))},
+        "bf16_statistics": compare(
+            wide, ref_cfg={**keys, "float32_statistics": False})}
+    report["faults_on_rows"] = {"bf16_statistics": list(wide), "others": short}
+    report["served_on_those_rows"] = compare(short)
+    report["faults_s"] = round(time.monotonic() - t0, 1)
+    report["ok"] = bool(
+        inside(served) and inside(report["served_on_those_rows"])
+        and not any(inside(f) for f in report["faults"].values()))
     emit(report)
     return 0 if report["ok"] else 1
 

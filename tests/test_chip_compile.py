@@ -646,3 +646,99 @@ def test_grouped_experts_kernel_compiles_for_v5e(topo, model, quantization,
         leaf(layers, e, it, h)).compile()
     _assert_kernel(compiled)
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+# -- generation by diffusion over blocks (SDAR, ISSUE 41) --------------------
+
+
+def _sdar(layers=2):
+    import dataclasses
+
+    from vllm_production_stack_tpu.models.registry import resolve_model_config
+
+    return dataclasses.replace(
+        resolve_model_config("sdar-30b-a3b-chat-8l"), num_layers=layers)
+
+
+def _bf16_program_args(topo, cfg, rows, window=8):
+    from vllm_production_stack_tpu.models import llama
+
+    one = SingleDeviceSharding(topo.devices[0])
+    on_chip = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one), tree)
+    params = on_chip(jax.eval_shape(
+        lambda k: llama.init_params(cfg, k), jax.random.PRNGKey(0)))
+    kv = on_chip(jax.eval_shape(lambda: llama.init_kv_cache(cfg, 4096, 32)))
+    staged = on_chip(jax.eval_shape(
+        lambda: llama.init_staged_kv(cfg, window, rows)))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+    return params, kv, staged, i32
+
+
+def test_block_pass_compiles_for_v5e(topo):
+    """One forward pass of a window of block passes at the cell's shapes (16
+    rows x a block of 4, two layers deep): the block's queries folded into
+    the GQA group axis of the decode kernel (4 kv heads x 32 query rows:
+    128 a row, inside the block's VMEM budget at 8 pages), the all-experts
+    einsum for 64 tokens of 128 experts, the transfer rule."""
+    from vllm_production_stack_tpu.models import llama
+    from vllm_production_stack_tpu.ops import paged_attention_pallas as pk
+
+    cfg = _sdar()
+    rows, t = 16, cfg.block_diffusion.block_length
+    assert llama.moe_experts_form(cfg, rows * t, kernel=True) == "all_experts"
+    assert pk._decode_pages_per_block(128, 32, 32 * t, 4, 128, 2, 2) == 8
+    params, kv, staged, i32 = _bf16_program_args(topo, cfg, rows)
+
+    def step(params, kv, staged, tokens, positions, tables, hist, slot0,
+             masked, pas):
+        stats: list = []
+        hidden, staged = llama.decode_block_step(
+            cfg, params, tokens, positions, kv, tables, staged, slot0[0],
+            hist, backend="pallas", moe_stats=stats, moe_backend="pallas")
+        logits = llama.compute_logits(
+            cfg, params, hidden.reshape(rows * t, -1)).reshape(rows, t, -1)
+        new, m_new, pick, over = llama.bd_transfer(
+            cfg.block_diffusion, logits, tokens, masked > 0, pas[0])
+        return new, m_new, staged, over, sum(s["touched"] for s in stats)
+
+    compiled = jax.jit(step).lower(
+        params, kv, staged, i32(rows, t), i32(rows, t), i32(rows, 128),
+        i32(rows), i32(1), i32(rows, t), i32(1)).compile()
+    text = compiled.as_text()
+    _assert_kernel(compiled)
+    assert "paged_decode_attention" in text
+    assert "moe_decode_experts" not in text and "ragged-dot" not in text
+    assert "bd_transfer" in text
+
+
+def test_block_mask_prefill_program_compiles_for_v5e(topo):
+    """A 1,024-token packed chunk of three rows under the block mask: the
+    prefill kernel with `block` = 4 (its query tiles are multiples of 4) and
+    the routed pairs through the fused grouped-expert kernel (1,024 > 512
+    tokens), bf16."""
+    from vllm_production_stack_tpu.models import llama
+
+    cfg = _sdar()
+    rows, n = 4, 1024
+    assert llama.moe_experts_form(cfg, n, kernel=True) == "grouped_kernel"
+    params, kv, _staged, i32 = _bf16_program_args(topo, cfg, rows)
+    nbw = (n - 1) // 32 + 2
+
+    def step(params, kv, tokens, positions, tables, lens, chunk, ids, off):
+        stats: list = []
+        hidden, kv = llama.forward(
+            cfg, params, tokens, positions, kv, tables,
+            jnp.zeros((1,), jnp.int32), lens,
+            write_blocks={"ids": ids, "start_off": off, "chunk_lens": chunk},
+            backend="pallas", moe_stats=stats, moe_backend="pallas",
+            pack=llama.pack_index(chunk, lens, n))
+        return hidden, kv, sum(s["touched"] for s in stats)
+
+    compiled = jax.jit(step, donate_argnums=1).lower(
+        params, kv, i32(n), i32(n), i32(rows, 128), i32(rows), i32(rows),
+        i32(rows, nbw), i32(rows)).compile()
+    text = compiled.as_text()
+    _assert_kernel(compiled)
+    assert "paged_prefill_attention" in text
+    assert "moe_prefill_experts" in text and "ragged-dot" not in text

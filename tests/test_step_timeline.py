@@ -260,7 +260,8 @@ def test_moe_read_share_from_its_data_file(start, end, want):
             "olmoe-1b-7b-int8.chat-saturated",
             "kanana-2-30b-a3b-bf16-8l.longdoc-saturated",
             "laguna-s21-bf16-ep4-9l.longdoc-saturated",
-            "solar-open2-bf16-ep8-8l.longdoc-saturated"]}
+            "solar-open2-bf16-ep8-8l.longdoc-saturated",
+            "sdar-30b-a3b-bf16-8l.chat-saturated"]}
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         cells = {w["name"] for w in json.load(f)["workloads"]}

@@ -15,6 +15,7 @@ import threading
 import time
 from dataclasses import dataclass, field
 
+from .. import metrics_contract as mc
 from ..qos import TenantContext
 from ..utils.tokenizer import IncrementalDetokenizer, TokenizerWrapper
 from .config import EngineConfig
@@ -56,6 +57,9 @@ class EngineStatsSnapshot:
     # state slots (kv_cache.StateSlots): bytes and slots in use, and their
     # share of the cache bytes in use
     state_bytes: int = 0
+    # generation by diffusion over blocks: the windows' device counts
+    # (metrics_contract.DEBUG_TIMING_BD_COUNTS; empty for any other model)
+    block_diffusion: dict = field(default_factory=dict)
     state_slots_in_use: int = 0
     state_share: float = 0.0
     prefix_cache_hit_rate: float = 0.0
@@ -168,6 +172,27 @@ def _window_pairs(start: int, n: int, window: int) -> int:
             + (n - short) * window)
 
 
+def _refuse_request_for_block_diffusion(model: str, sampling) -> None:
+    """What a request may not ask of a model that generates by diffusion
+    over blocks (docs/44-block-diffusion.md), by name: a ValueError is the
+    server's 400."""
+    why = {
+        "structured output (a grammar-constrained row)": (
+            sampling is not None and sampling.grammar is not None,
+            "the automaton advances one token at a time from the left, and "
+            "a block is unmasked in order of confidence"),
+        "min_tokens": (
+            sampling is not None and sampling.min_tokens > 0,
+            "the window of block passes does not suppress stop tokens on "
+            "the device"),
+    }
+    for what, (asked, reason) in why.items():
+        if asked:
+            raise ValueError(
+                f"{model}: {what} cannot be combined with generation by "
+                f"diffusion over blocks — {reason}")
+
+
 class LLMEngine:
     def __init__(
         self,
@@ -223,6 +248,17 @@ class LLMEngine:
             # no slot keeps (state snapshots: ROADMAP R-M5)
             logger.info(
                 "%s: layers that hold a recurrent state — the engine runs "
+                "WITHOUT prefix hits (--no-enable-prefix-caching says so "
+                "outright)", config.model.model)
+            config = config.replace(
+                cache=replace(config.cache, enable_prefix_caching=False))
+        if (config.model.block_diffusion is not None
+                and config.cache.enable_prefix_caching):
+            # a page is content only once every block in it is committed,
+            # and a hit over a whole prompt would leave a prefill that
+            # yields no token nothing to compute: unproven (ROADMAP R-M10)
+            logger.info(
+                "%s: generation by diffusion over blocks — the engine runs "
                 "WITHOUT prefix hits (--no-enable-prefix-caching says so "
                 "outright)", config.model.model)
             config = config.replace(
@@ -652,6 +688,9 @@ class LLMEngine:
             # races with a concurrent unload land here too — a clear 4xx-able
             # error, not a KeyError 500
             raise ValueError(f"LoRA adapter {lora_name!r} is not loaded")
+        if self.config.model.block_diffusion is not None:
+            _refuse_request_for_block_diffusion(
+                self.config.model.model, sampling)
         tenant = tenant or TenantContext()
         req = Request(
             request_id=request_id,
@@ -779,6 +818,11 @@ class LLMEngine:
         reference's engines). Returns (vectors, total prompt tokens)."""
         import numbers
 
+        if self.config.model.block_diffusion is not None:
+            raise ValueError(
+                f"{self.config.model.model}: /v1/embeddings is not built "
+                "for generation by diffusion over blocks — the encode "
+                "program masks causally, and the model attends whole blocks")
         rows: list[list[int]] = []
         vocab = self.config.model.vocab_size
         for x in inputs:
@@ -1385,7 +1429,7 @@ class LLMEngine:
         with rec.span("post", handle.seq, kind):
             with rec.wall(key + "_s"):
                 rows = handle.resolve()
-                rec.resolve(handle.seq)
+                rec.resolve(handle.seq, handle.bd_passes)
             rec.count(key + "_n")
             if kind == "prefill" and self.runner.prefill_uses_kernel:
                 rec.count("prefill_kernel_n")
@@ -2042,6 +2086,9 @@ class LLMEngine:
             kv_window_pages_given_back=(
                 pool.window.given_back_total if pool.window else 0),
             **self._state_stats(),
+            block_diffusion={
+                key: n for key, n in self.flightrec.timing().items()
+                if key in mc.DEBUG_TIMING_BD_COUNTS},
             prefix_cache_hit_rate=pool.stats.hit_rate,
             prefix_cache_hits=pool.stats.hits,
             prefix_cache_queries=pool.stats.queries,

@@ -426,10 +426,12 @@ class FlightRecorder:
         })
         return seq
 
-    def resolve(self, seq: int) -> None:
+    def resolve(self, seq: int, passes: int | None = None) -> None:
         """The dispatch's results were synced to the host — it, and every
         earlier one, is no longer a stall candidate. If nothing later is
-        enqueued the device starves from here on."""
+        enqueued the device starves from here on. `passes`: the forward
+        passes a window of block passes ran (generation by diffusion over
+        blocks: data, known only now), kept in the record."""
         now = time.monotonic_ns()
         with self._lock:
             kind = self._open.get(seq, (0.0, "start"))[1]
@@ -438,6 +440,8 @@ class FlightRecorder:
             self._done = max(self._done, seq)
             emptied = self._done >= self._seq
         fields = {"seq": seq, "phases": self._take_stamps()}
+        if passes is not None:
+            fields["passes"] = passes
         if emptied:
             # the watched step itself, nothing enqueued behind it: the host
             # knows this instant, so a dry gap hands over to a starvation gap

@@ -198,6 +198,10 @@ class EngineMetrics:
             mc.HBM_STATE_SHARE,
             "State bytes in use / (state + page bytes in use)",
         )
+        self.block_diffusion = {
+            key: counter(name, f"Generation by diffusion over blocks: {key}")
+            for key, name in mc.DEBUG_TIMING_BD_COUNTS.items()
+        }
         self.prefix_hit_rate = gauge(
             mc.PREFIX_CACHE_HIT_RATE, "Prefix cache block hit rate"
         )
@@ -689,6 +693,8 @@ class EngineMetrics:
         self.state_bytes.labels(**lb).set(s.state_bytes)
         self.state_slots_in_use.labels(**lb).set(s.state_slots_in_use)
         self.state_share.labels(**lb).set(s.state_share)
+        for key, metric in self.block_diffusion.items():
+            self._bump(metric, key, s.block_diffusion.get(key, 0))
         self.prefix_hit_rate.labels(**lb).set(s.prefix_cache_hit_rate)
         self._bump(self.prefix_hits, "hits", s.prefix_cache_hits)
         self._bump(self.prefix_queries, "queries", s.prefix_cache_queries)

@@ -17,6 +17,7 @@ rows write to the reserved null page (block 0) and their samples are dropped.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import threading
 import time
@@ -29,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import metrics_contract as mc
 from ..models import llama
 from ..ops import kda_attention
 from ..ops.attention import gather_pages
@@ -265,6 +267,71 @@ def _refuse_unbuilt_for_recurrent_state(config: EngineConfig) -> None:
                 f"recurrent state — {reason}")
 
 
+def _refuse_unbuilt_for_block_diffusion(config: EngineConfig) -> None:
+    """A model that generates by diffusion over blocks
+    (ModelConfig.block_diffusion; docs/44-block-diffusion.md) is refused, by
+    name and one sentence each, what its block passes have not been built
+    for. (Prefix caching is not refused: LLMEngine turns it off and says so.
+    Per-request: engine/engine.py refuses a grammar-constrained row and
+    `min_tokens`.)"""
+    cfg, name, sched = config.model, config.model.model, config.scheduler
+    bd = cfg.block_diffusion
+    why = {
+        **{tier: (True, "a page is content only once every block in it is "
+                  "committed, and no tier nor a PD transfer ships an open "
+                  "block")
+           for tier in config.kv_tiers_asked()},
+        f"speculative decoding (--speculative-config "
+        f"{sched.speculative_method})": (
+            sched.num_speculative_tokens > 0,
+            "both proposers draft one causal token a position and the "
+            "verify program masks causally"),
+        f"a mesh of {config.parallel.world_size} devices (tp / dp / pp / "
+        "sp / ep, context parallelism included)": (
+            config.parallel.world_size > 1,
+            "the sharded attention wrappers and the ring prefill carry no "
+            "block mask"),
+        "LoRA adapters": (
+            config.lora.max_loras > 0,
+            "the block pass feeds several positions a row and the adapter "
+            "slots are one a decode row"),
+        "multi-head latent attention, window layers or layers that hold "
+        "state": (
+            cfg.mla or cfg.any_sliding or bool(cfg.linear_layers),
+            "the block mask is built into the GQA kernels only"),
+        "softcapped attention": (
+            bool(cfg.attn_logit_softcap),
+            "the block pass has no softcap"),
+        f"--decode-window {sched.decode_window}": (
+            sched.decode_window % bd.block_length != 0,
+            f"a window holds whole blocks of {bd.block_length}"),
+        f"--block-size {config.cache.block_size}": (
+            config.cache.block_size % bd.block_length != 0,
+            f"a page holds whole blocks of {bd.block_length}"),
+        f"--max-model-len {cfg.max_model_len}": (
+            cfg.max_model_len % bd.block_length != 0,
+            f"the context holds whole blocks of {bd.block_length}"),
+        "prefill buckets that are not whole blocks": (
+            any(t % bd.block_length for t in sched.prefill_buckets),
+            "chunk seams lie on block boundaries"),
+    }
+    for what, (asked, reason) in why.items():
+        if asked:
+            raise ValueError(
+                f"{name}: {what} cannot be combined with generation by "
+                f"diffusion over blocks — {reason}")
+
+
+# may a block's kept K/V be those of a pass whose inputs still held masks?
+# Never: the commit pass is part of the mathematics. tests/test_sdar.py
+# flips this to prove the comparison with the reference catches it.
+_BD_COMMIT_PASS = True
+
+# what a window of block passes counts on the device, in this order: the
+# `bd_*_n` keys of /debug/timing (docs/44-block-diffusion.md)
+BD_COUNTS = tuple(mc.DEBUG_TIMING_BD_COUNTS)
+
+
 class StepHandle:
     """One dispatched-but-unresolved device step — the async pipeline's
     unit of in-flight work (engine/engine.py pipelined step loop).
@@ -307,6 +374,11 @@ class StepHandle:
         # prefill handles: the token positions the program computed (the
         # packed axis, padding included; rows x bucket where row-major)
         self.positions = 0
+        # a window of block passes (ModelConfig.block_diffusion): the
+        # device's (len(BD_COUNTS),) int32 counts, fetched with the tokens;
+        # `bd_passes` is what the engine's flight record reads once resolved
+        self.bd = None
+        self.bd_passes: int | None = None
 
     def resolve(self) -> list[list[int]]:
         """Sync the step's results to the host — exactly ONE jax.device_get
@@ -316,8 +388,19 @@ class StepHandle:
         if self._rows is None:
             lp_arrays = self.lp_arrays or ()
             extra = (self.moe[0],) if self.moe is not None else ()
+            if self.bd is not None:
+                extra = (self.bd, *extra)
             with self.runner.clock.span("sync", self.seq, self.kind):
                 got = jax.device_get((self.tokens, *lp_arrays, *extra))
+            if self.bd is not None:
+                # the passes are data: what follows from them (an MoE
+                # model's pairs and slots) is known only now
+                bd = [int(n) for n in got[len(lp_arrays) + 1]]
+                self.bd_passes = bd[0]
+                for key, n in zip(BD_COUNTS, bd):
+                    self.runner.clock.count(key, n)
+                if self.moe is not None:
+                    self.moe = self.moe[1](bd[0])
             mat = np.asarray(got[0])
             lp = (
                 tuple(np.asarray(x) for x in got[1:1 + len(lp_arrays)])
@@ -399,6 +482,8 @@ class ModelRunner:
             _refuse_unbuilt_for_recurrent_state(config)
         if cfg.tree_by_kind:
             _refuse_unbuilt_for_tree_by_kind(config)
+        if cfg.block_diffusion is not None:
+            _refuse_unbuilt_for_block_diffusion(config)
         pp = config.parallel.pipeline_parallel_size
         if pp > 1:
             # friendly errors beat JAX's deep 'dimension not divisible'
@@ -893,6 +978,7 @@ class ModelRunner:
         XLA — so `resolved` differs from `requested` only for 'auto'."""
         from .memory import device_report
 
+        bd = self.config.model.block_diffusion
         report = device_report(self.mesh)
         report["attention"] = {
             "decode": {
@@ -923,14 +1009,28 @@ class ModelRunner:
             # (decode) or token (prefill) bucket; `resolved`: the widest,
             # what a full batch or a full chunk runs
             sched = self.config.scheduler
+            # (a pass of a window of block passes feeds every row's block)
+            per_row = {"decode": bd.block_length if bd else 1, "prefill": 1}
             report["moe"] = {
                 phase: {
-                    "resolved": self.moe_experts_form(max(buckets)),
+                    "resolved": self.moe_experts_form(
+                        max(buckets) * per_row[phase]),
                     "by_bucket": {
-                        str(n): self.moe_experts_form(n) for n in buckets},
+                        str(n): self.moe_experts_form(n * per_row[phase])
+                        for n in buckets},
                 }
                 for phase, buckets in (("decode", sched.decode_buckets),
                                        ("prefill", sched.prefill_buckets))
+            }
+        if bd is not None:
+            # what the model's GENERATION is (docs/44-block-diffusion.md):
+            # the preset's settings, and what a decode window is made of
+            report["generation"] = {
+                "kind": "block_diffusion", **dataclasses.asdict(bd),
+                "transfer_schedule": list(bd.transfer_schedule),
+                "window_blocks": (
+                    self.config.scheduler.decode_window // bd.block_length),
+                "passes_per_block": [2, bd.denoising_steps + 1],
             }
         return report
 
@@ -1171,6 +1271,8 @@ class ModelRunner:
         far below HBM."""
         cfg = self.config.model
         block_size = self.config.cache.block_size
+        if cfg.block_diffusion is not None:
+            return self._build_bd_window_fn()
 
         @functools.partial(
             jax.jit,
@@ -1368,6 +1470,180 @@ class ModelRunner:
             return ret + tuple(moe_out)
 
         return decode_window_fn
+
+    def _build_bd_window_fn(self):
+        """The decode-window program of a model that generates by diffusion
+        over blocks (docs/44-block-diffusion.md): a window is `window`
+        positions = window / block_length blocks a row, and the forward
+        passes run ON DEVICE in one `while_loop` of identical passes —
+        "forward over each row's open block from its current tokens, stage
+        its K/V, unmask where masks remain" (llama.decode_block_step,
+        llama.bd_transfer). A block ends after the first pass in which NO
+        row's block held a mask when it began: that pass ran over every
+        row's final tokens, so it IS every row's commit pass (a row that
+        filled its block earlier has repeated an idempotent pass:
+        `repeat_row_passes`). So a window yields exactly `window` positions
+        a row whatever the confidences, `commit_staged_kv` scatters final
+        K/V once a window, and only the NUMBER of passes is data — inside
+        one program, so no pass count needs a program of its own.
+
+        Rows lock step by block: all rows work on block j of their windows
+        until the slowest has committed it. A row's first `n_given`
+        positions hold tokens it already has (the p mod block_length tail
+        of a prompt): never masked, never sampled."""
+        cfg = self.config.model
+        bd = cfg.block_diffusion
+        block_size = self.config.cache.block_size
+        blk_len = bd.block_length
+
+        @functools.partial(
+            jax.jit,
+            static_argnames=(
+                "window", "want_logprobs", "want_min_tokens", "want_grammar"
+            ),
+            donate_argnames=("kv_caches",),
+        )
+        def bd_window_fn(
+            params,
+            lora_params,  # None: refused for such a model
+            kv_caches,
+            tokens0,  # (B, W) the window's tokens: given ones, then masks
+            n_given,  # (B,) leading positions that hold given tokens
+            live,  # (B,) bool: the row is a request's (padding rows are not)
+            positions0,  # (B,) the window's first position per row
+            block_tables,  # (B, max_blocks) covering the whole window
+            temperature,  # (B,)
+            top_p,  # (B,)
+            top_k,  # (B,)
+            base_key,
+            seeds,  # (B,) uint32
+            has_seed,  # (B,) bool
+            counts0,  # (B,) output tokens generated before this window
+            window: int = 1,
+            want_logprobs: bool = False,
+            want_min_tokens: bool = False,  # (never: refused a request)
+            want_grammar: bool = False,  # (never: refused a request)
+        ):
+            b = tokens0.shape[0]
+            n_blocks = window // blk_len
+            slot = jnp.arange(window, dtype=jnp.int32)
+            masked0 = slot[None, :] >= n_given[:, None]
+            staged = llama.init_staged_kv(cfg, window, b)
+            s_ctx = block_tables.shape[1] * block_size
+            hists = None
+            if (self._attention_backend == "xla"
+                    and self._hoist_bytes(b, s_ctx) <= self._hoist_budget):
+                with jax.named_scope("gather_pages"):
+                    hists = tuple(
+                        gather_pages(kv, block_tables) for kv in kv_caches)
+            # the output index of the token at each window slot: what the
+            # seeded draw folds in (a position's own, whatever the pass)
+            out_idx = counts0[:, None] + slot[None, :] - n_given[:, None]
+
+            def body(c):
+                blk, pas = c["blk"], c["pass"]
+                slot0 = blk * blk_len
+                cur = jax.lax.dynamic_slice(c["tokens"], (0, slot0),
+                                            (b, blk_len))
+                m = jax.lax.dynamic_slice(c["masked"], (0, slot0),
+                                          (b, blk_len))
+                had_mask = jnp.any(m, axis=1)  # (B,) when the pass began
+                pos = (positions0[:, None] + slot0
+                       + jnp.arange(blk_len, dtype=jnp.int32)[None, :])
+                stats: list | None = [] if cfg.num_experts else None
+                hidden, staged = llama.decode_block_step(
+                    cfg, params, cur, pos, kv_caches, block_tables,
+                    c["staged"], slot0, positions0,
+                    backend=self._attention_backend, hists=hists,
+                    moe_stats=stats, moe_backend=self._moe_backend,
+                )
+                logits = llama.compute_logits(
+                    cfg, params, hidden.reshape(b * blk_len, -1)
+                ).reshape(b, blk_len, -1)
+                with jax.named_scope("sample"):
+                    # greedy rows take the argmax; the draw is skipped at
+                    # run time where no row samples (sampling.sample)
+                    rep = lambda a: jnp.repeat(a, blk_len)
+                    x0 = sample(
+                        logits.reshape(b * blk_len, -1), rep(temperature),
+                        rep(top_p), rep(top_k),
+                        jax.random.fold_in(base_key, c["n"][0]),
+                        rep(seeds), rep(has_seed),
+                        jax.lax.dynamic_slice(
+                            out_idx, (0, slot0), (b, blk_len)).reshape(-1),
+                    ).reshape(b, blk_len)
+                new, m_new, pick, over = llama.bd_transfer(
+                    bd, logits, cur, m, pas, x0, temperature)
+                llama.tap_block_pass(blk, pas, pos, cur, pick, new, stats)
+                out = dict(c)
+                out["staged"] = staged
+                out["tokens"] = jax.lax.dynamic_update_slice(
+                    c["tokens"], new, (0, slot0))
+                out["masked"] = jax.lax.dynamic_update_slice(
+                    c["masked"], m_new, (0, slot0))
+                if want_logprobs:
+                    # a token's logprobs are those of the pass that
+                    # unmasked it
+                    chosen, top_lp, top_id = _collect_logprobs(
+                        logits.reshape(b * blk_len, -1), new.reshape(-1))
+                    for key, val in (("lp", chosen), ("top_lp", top_lp),
+                                     ("top_id", top_id)):
+                        val = val.reshape(b, blk_len, *val.shape[1:])
+                        old = jax.lax.dynamic_slice_in_dim(
+                            c[key], slot0, blk_len, axis=1)
+                        sel = pick.reshape(pick.shape + (1,) * (val.ndim - 2))
+                        out[key] = jax.lax.dynamic_update_slice_in_dim(
+                            c[key], jnp.where(sel, val, old), slot0, axis=1)
+                n_live = jnp.sum(live, dtype=jnp.int32)
+                done_rows = live & ~had_mask
+                # the block is done once no row's held a mask going in —
+                # or, in a faulty build (tests only), once none holds one
+                # coming out: the last denoising pass's K/V are kept
+                done = ~jnp.any(had_mask if _BD_COMMIT_PASS
+                                else jnp.any(m_new, axis=1))
+                out["n"] = c["n"] + jnp.stack([
+                    jnp.int32(1), n_live,
+                    # a row's ONE pass over final tokens a block is its
+                    # commit; what it runs beyond that it repeats
+                    jnp.sum(done_rows, dtype=jnp.int32)
+                    - jnp.where(done, n_live, 0),
+                    jnp.where(done, n_live, 0),
+                    jnp.sum(jnp.where(live[:, None], pick, False),
+                            dtype=jnp.int32),
+                    jnp.sum(jnp.where(live, over, 0), dtype=jnp.int32),
+                ])
+                if cfg.num_experts:
+                    out["moe"] = c["moe"] + _moe_device_counts(cfg, stats)
+                out["blk"] = blk + done.astype(jnp.int32)
+                out["pass"] = jnp.where(done, 0, pas + 1)
+                return out
+
+            carry = {
+                "blk": jnp.int32(0), "pass": jnp.int32(0),
+                "tokens": tokens0, "masked": masked0, "staged": staged,
+                "n": jnp.zeros(len(BD_COUNTS), jnp.int32),
+            }
+            if cfg.num_experts:
+                carry["moe"] = jnp.zeros_like(_moe_device_counts(cfg, []))
+            if want_logprobs:
+                carry["lp"] = jnp.zeros((b, window), jnp.float32)
+                carry["top_lp"] = jnp.zeros(
+                    (b, window, LOGPROBS_TOPN), jnp.float32)
+                carry["top_id"] = jnp.zeros(
+                    (b, window, LOGPROBS_TOPN), jnp.int32)
+            c = jax.lax.while_loop(
+                lambda c: c["blk"] < n_blocks, body, carry)
+            pos = positions0[:, None] + slot[None, :]
+            blk = jnp.take_along_axis(block_tables, pos // block_size, axis=1)
+            slots = (blk * block_size + pos % block_size).reshape(-1)
+            kv_caches = llama.commit_staged_kv(kv_caches, c["staged"], slots)
+            ret = (kv_caches, c["tokens"])
+            if want_logprobs:
+                ret = ret + ((c["lp"], c["top_lp"], c["top_id"]),)
+            ret = ret + (c["n"],)
+            return ret + ((c["moe"],) if cfg.num_experts else ())
+
+        return bd_window_fn
 
     def _build_verify_fn(self):
         """Speculative-verification program (engine/spec_decode.py): a
@@ -1799,14 +2075,85 @@ class ModelRunner:
         ]
         return rows, lp_rows
 
+    def _pick_decode_program(self, work: DecodeWork, want_lp: bool,
+                             want_mt: bool, gkey) -> tuple:
+        """The key of the compiled decode program this window runs on:
+        never stall a decode window on a first-seen program key."""
+        sched = self.config.scheduler
+        b_pad = sched.bucket_for(len(work.requests), sched.decode_buckets)
+        nb = self._width_bucket(
+            max((len(r.block_table) for r in work.requests), default=1)
+        )
+        exact_key = ("decode", b_pad, nb, work.window, want_lp, want_mt, gkey)
+        aot_key = self._pick_decode_shape(
+            b_pad, nb, work.window, want_lp, want_mt, gkey
+        )
+        self._watch_dispatch(exact_key, aot_key)
+        return aot_key
+
+    def _decode_sampling_args(self, requests, b_pad: int):
+        """(the rng before this window's split, the sampling arguments of a
+        decode program in its order: each a host array and its sharding)."""
+        pad = b_pad - len(requests)
+        temps = [r.sampling.temperature for r in requests] + [0.0] * pad
+        top_ps = [r.sampling.top_p for r in requests] + [1.0] * pad
+        top_ks = [r.sampling.top_k for r in requests] + [0] * pad
+        seeds = [r.sampling.seed for r in requests] + [None] * pad
+        # effective output counts: tokens still in flight from the previous
+        # step count as generated (seeded-sampling fold and min_tokens
+        # suppression must see the serial-world counter); 0 on the sync path
+        counts = [
+            len(r.output_token_ids) + r.num_inflight_outputs
+            for r in requests
+        ] + [0] * pad
+        rng_before = self._rng
+        self._rng, step_key = jax.random.split(self._rng)
+        has_seed = np.asarray([s is not None for s in seeds], bool)
+        seed_vals = np.asarray([(s or 0) & 0xFFFFFFFF for s in seeds], np.uint32)
+        return rng_before, (
+            (np.asarray(temps, np.float32), self._batch1),
+            (np.asarray(top_ps, np.float32), self._batch1),
+            (np.asarray(top_ks, np.int32), self._batch1),
+            (step_key, self._rep),
+            (seed_vals, self._batch1),
+            (has_seed, self._batch1),
+            (np.asarray(counts, np.int32), self._batch1),
+        )
+
+    def _call_decode_program(self, aot_key: tuple, work: DecodeWork,
+                             lora_params, dyn_args: tuple, **static):
+        """Enqueue the window on its ahead-of-time executable, or through
+        jit with the compile noted and watched."""
+        clock = self.clock
+        aot = self._aot_exec.get(aot_key)
+        if aot is not None:
+            with clock.span("enqueue", kind="decode"):
+                return aot(
+                    self.params, lora_params, self.kv_caches, *dyn_args
+                )
+        with self._bg_lock:
+            new_program = aot_key not in self._compiled_keys
+        t0 = time.perf_counter()
+        with clock.span("enqueue", kind="decode"):
+            result = self._decode_window_fn(
+                self.params, lora_params, self.kv_caches, *dyn_args,
+                window=work.window, **static,
+            )
+        self._note_compiled(aot_key)
+        if new_program:
+            self._watch_sync_compile(
+                "decode", aot_key, time.perf_counter() - t0, work.requests,
+            )
+        return result
+
     def _dispatch_decode(
         self, work: DecodeWork, prev: StepHandle | None = None
     ) -> StepHandle:
         if self._sleeping_params_host is not None:
             raise RuntimeError("engine is sleeping; wake it before running")
-        sched = self.config.scheduler
+        if self.config.model.block_diffusion is not None:
+            return self._dispatch_bd_decode(work)
         b = len(work.requests)
-        b_pad = sched.bucket_for(b, sched.decode_buckets)
         want_lp = any(
             r.sampling.logprobs is not None for r in work.requests
         )
@@ -1838,15 +2185,7 @@ class ModelRunner:
                 self._pow2(max(g.n_states for g in grammars) + 1),
                 self._pow2(max(g.n_classes for g in grammars)),
             )
-        nb = self._width_bucket(
-            max((len(r.block_table) for r in work.requests), default=1)
-        )
-        # never stall a decode window on a first-seen program key
-        exact_key = ("decode", b_pad, nb, work.window, want_lp, want_mt, gkey)
-        aot_key = self._pick_decode_shape(
-            b_pad, nb, work.window, want_lp, want_mt, gkey
-        )
-        self._watch_dispatch(exact_key, aot_key)
+        aot_key = self._pick_decode_program(work, want_lp, want_mt, gkey)
         _, b_pad, nb, _w, _lp, use_mt, use_gkey = aot_key
 
         clock = self.clock
@@ -1870,22 +2209,8 @@ class ModelRunner:
         positions0[:b] = work.positions
         block_tables = self._group_tables(
             work.requests, pad_to=b_pad, width=nb)
-        temps = [r.sampling.temperature for r in work.requests] + [0.0] * (b_pad - b)
-        top_ps = [r.sampling.top_p for r in work.requests] + [1.0] * (b_pad - b)
-        top_ks = [r.sampling.top_k for r in work.requests] + [0] * (b_pad - b)
-        seeds = [r.sampling.seed for r in work.requests] + [None] * (b_pad - b)
-        # effective output counts: tokens still in flight from the previous
-        # step count as generated (seeded-sampling fold and min_tokens
-        # suppression must see the serial-world counter); 0 on the sync path
-        counts = [
-            len(r.output_token_ids) + r.num_inflight_outputs
-            for r in work.requests
-        ] + [0] * (b_pad - b)
-
-        rng_before = self._rng
-        self._rng, step_key = jax.random.split(self._rng)
-        has_seed = np.asarray([s is not None for s in seeds], bool)
-        seed_vals = np.asarray([(s or 0) & 0xFFFFFFFF for s in seeds], np.uint32)
+        rng_before, sampling = self._decode_sampling_args(
+            work.requests, b_pad)
         lora_idx = np.zeros(b_pad, np.int32)
         for i, req in enumerate(work.requests):
             lora_idx[i] = req.lora_index
@@ -1907,13 +2232,7 @@ class ModelRunner:
                 put(positions0, self._batch1),
                 put(block_tables, self._batch2),
                 put(lora_idx, self._batch1) if self._use_lora else None,
-                put(np.asarray(temps, np.float32), self._batch1),
-                put(np.asarray(top_ps, np.float32), self._batch1),
-                put(np.asarray(top_ks, np.int32), self._batch1),
-                put(step_key, self._rep),
-                put(seed_vals, self._batch1),
-                put(has_seed, self._batch1),
-                put(np.asarray(counts, np.int32), self._batch1),
+                *(put(a, sharding) for a, sharding in sampling),
                 put(min_toks, self._batch1),
                 put(stop_ids_arr, self._batch2),
                 self._state_slot_arrays(work.requests, b_pad),
@@ -1969,33 +2288,9 @@ class ModelRunner:
                 tc_dev, cd_dev, acc_dev, gr_idx_dev, gs_dev,
                 self._gr_eos_dev,
             )
-        aot = self._aot_exec.get(aot_key)
-        if aot is not None:
-            with clock.span("enqueue", kind="decode"):
-                result = aot(
-                    self.params, self.lora_params, self.kv_caches, *dyn_args
-                )
-        else:
-            with self._bg_lock:
-                new_program = aot_key not in self._compiled_keys
-            t0 = time.perf_counter()
-            with clock.span("enqueue", kind="decode"):
-                result = self._decode_window_fn(
-                    self.params,
-                    self.lora_params,
-                    self.kv_caches,
-                    *dyn_args,
-                    window=work.window,
-                    want_logprobs=want_lp,
-                    want_min_tokens=use_mt,
-                    want_grammar=want_gr,
-                )
-            self._note_compiled(aot_key)
-            if new_program:
-                self._watch_sync_compile(
-                    "decode", aot_key, time.perf_counter() - t0,
-                    work.requests,
-                )
+        result = self._call_decode_program(
+            aot_key, work, self.lora_params, dyn_args, want_logprobs=want_lp,
+            want_min_tokens=use_mt, want_grammar=want_gr)
         moe = None
         if self.config.model.num_experts:
             *result, touched = result
@@ -2020,9 +2315,69 @@ class ModelRunner:
         handle.moe = moe
         return handle
 
+    def _dispatch_bd_decode(self, work: DecodeWork) -> StepHandle:
+        """`_dispatch_decode` for a window of block passes
+        (`_build_bd_window_fn`): a row's input is the tokens it already has
+        in the window (`work.block_tokens`: the tail of its prompt in its
+        first window, nothing after) and masks behind them, so no row
+        chains a token from the step in flight — the windows chain on the
+        pool alone."""
+        bd = self.config.model.block_diffusion
+        b = len(work.requests)
+        want_lp = any(
+            r.sampling.logprobs is not None for r in work.requests)
+        aot_key = self._pick_decode_program(work, want_lp, False, None)
+        _, b_pad, nb, _w, _lp, _mt, _g = aot_key
+
+        if llama.BD_TAP is not None:  # (a check's tap: which rows these are)
+            llama.BD_TAP.window([r.request_id for r in work.requests])
+        clock = self.clock
+        tokens0 = np.full((b_pad, work.window), bd.mask_token_id, np.int32)
+        n_given = np.full(b_pad, work.window, np.int32)  # padding: no mask
+        for i, given in enumerate(work.block_tokens):
+            tokens0[i, :len(given)] = given
+            n_given[i] = len(given)
+        tokens0[b:] = 0
+        live = np.arange(b_pad) < b
+        positions0 = np.zeros(b_pad, np.int32)
+        positions0[:b] = work.positions
+        block_tables = self._group_tables(
+            work.requests, pad_to=b_pad, width=nb)
+        rng_before, sampling = self._decode_sampling_args(
+            work.requests, b_pad)
+        put = self._put_polled
+        with clock.span("h2d", kind="decode"):
+            dyn_args = (
+                put(tokens0, self._batch2),
+                put(n_given, self._batch1),
+                put(live, self._batch1),
+                put(positions0, self._batch1),
+                put(block_tables, self._batch2),
+                *(put(a, sharding) for a, sharding in sampling),
+            )
+        result = self._call_decode_program(
+            aot_key, work, None, dyn_args, want_logprobs=want_lp)
+        moe = None
+        if self.config.model.num_experts:
+            *result, touched = result
+            # (touched, passes -> the counts that follow from shapes)
+            moe = (touched, functools.partial(
+                self._moe_counts, touched, "decode", b_pad * bd.block_length))
+        *result, counts_dev = result
+        self.kv_caches, tokens = result[0], result[1]
+        handle = StepHandle(
+            runner=self, work=work, tokens=tokens,
+            lp_arrays=result[2] if want_lp else None, rng_before=rng_before,
+            postproc=functools.partial(self._decode_rows, work, b),
+        )
+        handle.moe, handle.bd = moe, counts_dev
+        return handle
+
     @staticmethod
     def _decode_rows(work: DecodeWork, b: int, mat, lp):
-        """Host-side row building for a resolved decode handle."""
+        """Host-side row building for a resolved decode handle. A window of
+        block passes hands a row the tokens BEHIND those it was given."""
+        skip = [len(g) for g in work.block_tokens] or [0] * b
         if lp is None:
             lp_rows = None
         else:
@@ -2036,14 +2391,14 @@ class ModelRunner:
                         (float(lp_w[i, k]),
                          list(map(int, top_id_w[i, k])),
                          list(map(float, top_lp_w[i, k])))
-                        for k in range(work.window)
+                        for k in range(skip[i], work.window)
                     ]
                     if req.sampling.logprobs is not None
                     else []
                 )
                 for i, req in enumerate(work.requests)
             ]
-        return [list(map(int, mat[i])) for i in range(b)], lp_rows
+        return [list(map(int, mat[i, skip[i]:])) for i in range(b)], lp_rows
 
     # -- helpers -----------------------------------------------------------
 
@@ -2482,7 +2837,7 @@ class ModelRunner:
             _, b, nb, window, want_lp, want_mt, gkey = key
             lowered = self._decode_window_fn.lower(
                 params_av, lora_av, kv_av,
-                *self._decode_avals(b, nb, gkey),
+                *self._decode_avals(b, nb, gkey, window),
                 window=window, want_logprobs=want_lp,
                 want_min_tokens=want_mt, want_grammar=gkey is not None,
             )
@@ -2532,7 +2887,11 @@ class ModelRunner:
             b *= 2
         top_window = 1
         w = 1
+        bd = self.config.model.block_diffusion
         while w <= sched.decode_window:
+            if bd is not None and w % bd.block_length:
+                w *= 2
+                continue  # a window of block passes holds whole blocks
             top_window = w
             for d in sorted(set(sched.decode_buckets)):
                 if d > sched.max_num_seqs:
@@ -2552,6 +2911,8 @@ class ModelRunner:
             ("prefill", b_top, t_top, top_w, False, True, False),
             ("decode", d_top, top_w, top_window, False, True, None),
         ):
+            if bd is not None:
+                break  # (min_tokens is refused a request of such a model)
             if self._compile_key_now(key, "warmup"):
                 n += 1
         logger.info("precompiled %d dominating programs", n)
@@ -2621,12 +2982,29 @@ class ModelRunner:
             (s((b,), i32, b1),) * 2 if self.state_slots else None,
         ) + gr
 
-    def _decode_avals(self, b: int, nb: int, gkey: tuple | None = None):
+    def _decode_avals(self, b: int, nb: int, gkey: tuple | None = None,
+                      window: int = 1):
         """ShapeDtypeStructs mirroring _dispatch_decode's dynamic args —
         MUST stay in lockstep with the _decode_window_fn call."""
         i32, f32 = jnp.int32, jnp.float32
         b1, b2, rep = self._batch1, self._batch2, self._rep
         s = self._sds
+        if self.config.model.block_diffusion is not None:
+            # ... and with _dispatch_bd_decode's
+            return (
+                s((b, window), i32, b2),  # tokens0
+                s((b,), i32, b1),         # n_given
+                s((b,), jnp.bool_, b1),   # live
+                s((b,), i32, b1),         # positions0
+                s((b, nb), i32, b2),      # block_tables
+                s((b,), f32, b1),         # temperature
+                s((b,), f32, b1),         # top_p
+                s((b,), i32, b1),         # top_k
+                s(self._rng.shape, self._rng.dtype, rep),  # base_key
+                s((b,), jnp.uint32, b1),  # seeds
+                s((b,), jnp.bool_, b1),   # has_seed
+                s((b,), i32, b1),         # counts0
+            )
         v = self.config.model.vocab_size
         gr = ()
         if gkey is not None:

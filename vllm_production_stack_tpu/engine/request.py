@@ -193,6 +193,13 @@ class Request:
     # output by _make_output for the trace timeline's compile_stall
     # events. None (the steady state) = never stalled.
     compile_stalls: list | None = None
+    # generation by diffusion over blocks (ModelConfig.block_diffusion): the
+    # model's block length, stamped by the scheduler at add_request; 0 for
+    # every other model. The pool holds COMMITTED blocks only: a prefill
+    # ends at the last block boundary at or before the row's tokens, yields
+    # no token, and what lies behind it (the tail of the prompt) is given
+    # to the row's next decode window
+    block_length: int = 0
 
     @property
     def num_prompt_tokens(self) -> int:
@@ -212,6 +219,8 @@ class Request:
         request that's the whole prompt; for a preempted-then-resumed request
         (which already has outputs to recompute) it's everything except the
         last token — that one is the next decode step's input."""
+        if self.block_length:
+            return self.num_tokens // self.block_length * self.block_length
         if self.output_token_ids:
             return self.num_tokens - 1
         return self.num_prompt_tokens

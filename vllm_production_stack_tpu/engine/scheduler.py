@@ -85,6 +85,13 @@ class DecodeWork:
     # sampled (device-resident) token is this row's input, or -1 when
     # token_ids[i] carries a host-known token. Empty = all host (sync path).
     chain_rows: list[int] = field(default_factory=list)
+    # generation by diffusion over blocks (the model runner's window of
+    # block passes): per row the tokens it already HAS in the window, from
+    # `positions[i]` on — the tail of its prompt in its first window,
+    # nothing after. The window yields `window - len(block_tokens[i])`
+    # tokens to the row; no row chains (`token_ids` stays empty). Empty for
+    # every other model
+    block_tokens: list[list[int]] = field(default_factory=list)
 
 
 @dataclass
@@ -145,6 +152,16 @@ class Scheduler:
         self.cache_config = cache_config
         self.config = scheduler_config
         self.block_size = cache_config.block_size
+        # generation by diffusion over blocks: the block length (0: the
+        # model yields one token a row and step, like every other)
+        bd = model_config.block_diffusion
+        self._bd_block = bd.block_length if bd is not None else 0
+        if self._bd_block and cache_config.enable_prefix_caching:
+            raise ValueError(
+                f"{model_config.model}: prefix caching is not built for "
+                "generation by diffusion over blocks (a hit that covers the "
+                "whole prompt leaves a prefill nothing to compute and no "
+                "token to yield: unproven here); LLMEngine turns it off")
         # per-token slot mappings feed only the sp>1 prefill path (row
         # scatter); the paged path commits blockwise, so skipping ~T Python
         # _slot calls per scheduled chunk keeps the host off the hot path
@@ -259,6 +276,7 @@ class Scheduler:
             )
         if req.tenant_id != "default" or req.priority != 1 or req.weight != 1.0:
             self._qos_active = True
+        req.block_length = self._bd_block
         req.status = RequestStatus.WAITING
         self.waiting.append(req)
 
@@ -502,6 +520,13 @@ class Scheduler:
         work = None
         if want_prefill and (not decode_ready or not self._last_was_prefill):
             work = self._schedule_prefill(prefilling)
+            if work is None and self._bd_block and not decode_ready:
+                # a prompt shorter than a block has nothing to prefill: the
+                # row just admitted is decode-ready as it stands
+                decode_ready = [
+                    r for r in self.running
+                    if r.num_computed_tokens + r.num_inflight_tokens
+                    >= r.prefill_target]
         if work is None and decode_ready:
             work = self._schedule_decode_or_verify(decode_ready, inflight)
         if work is not None:
@@ -821,6 +846,10 @@ class Scheduler:
         chunk = min(budget, target - req.num_computed_tokens)
         if limit is not None:
             chunk = min(chunk, limit)
+        if self._bd_block:
+            # seams on block boundaries from the row's position 0 (the
+            # target is one): a block is never split over two chunks
+            chunk -= chunk % self._bd_block
         if chunk <= 0:
             return 0
         if not self._ensure_blocks(req, req.num_computed_tokens + chunk):
@@ -841,7 +870,9 @@ class Scheduler:
             context_len=start + chunk,
             # sample only when this chunk completes a *fresh* prompt; resumed
             # requests already know their next token
-            sample=start + chunk == target and not req.output_token_ids,
+            # ... and no token comes out of a prefill of whole blocks
+            sample=(start + chunk == target and not req.output_token_ids
+                    and not self._bd_block),
         )
         return chunk
 
@@ -881,6 +912,8 @@ class Scheduler:
         ]
         if not cand:
             return None
+        if self._bd_block:
+            return self._schedule_block_windows(cand, eff_computed)
         # window bounded by model length per seq and by the batch's largest
         # remaining output budget rounded UP to a power of two: past that
         # every token of every row would be discarded, but rounding up keeps
@@ -950,6 +983,53 @@ class Scheduler:
             else:
                 batch.token_ids.append(req.token_at(pos))
                 batch.chain_rows.append(-1)
+        return batch
+
+    def _schedule_block_windows(
+        self, cand: list[Request], eff_computed
+    ) -> DecodeWork | None:
+        """`_schedule_decode` for generation by diffusion over blocks: a
+        window is whole blocks from a row's (block-aligned) computed
+        position; the tokens the row already has there — the tail of its
+        prompt, in its first window — are given to the window, which
+        yields the rest. Every row's window after its first starts on
+        masks alone, so nothing chains from the step in flight. A window
+        is `decode_window` long whatever its rows have left to generate (a
+        row's arithmetic, and so the order it unmasks in, does not depend
+        on what its neighbours want; the last window is cut at max_tokens),
+        shorter by whole blocks only where the pool's headroom or the
+        context's end says so."""
+        bl = self._bd_block
+
+        def given(r: Request) -> int:
+            # (a window in flight leaves none: its row's tokens end where
+            # its positions do)
+            return r.num_tokens + r.num_inflight_outputs - eff_computed(r)
+
+        window = min(
+            max(bl, self.config.decode_window),
+            min(self.model_config.max_model_len - eff_computed(r)
+                for r in cand))
+        while window > bl and (
+            self._extra_blocks(cand, window) > self.pool.growable_blocks
+        ):
+            window -= bl
+        picked: list[Request] = []
+        for req in cand:
+            if req not in self.running:
+                continue  # preempted while building this batch
+            if not self._ensure_blocks(req, eff_computed(req) + window):
+                continue
+            picked.append(req)
+        picked = [r for r in picked if r in self.running]
+        if not picked:
+            return None
+        batch = DecodeWork(requests=picked, window=window)
+        for req in picked:
+            pos = eff_computed(req)
+            batch.positions.append(pos)
+            batch.block_tokens.append(
+                req.tokens_between(pos, pos + given(req)))
         return batch
 
     # -- memory ------------------------------------------------------------
@@ -1466,6 +1546,10 @@ class Scheduler:
             ]
         if isinstance(work, VerifyWork):
             return [(len(t), len(t)) for t in work.token_ids]
+        if work.block_tokens:  # a window of block passes: what it was
+            # given it does not sample
+            return [(work.window, work.window - len(g))
+                    for g in work.block_tokens]
         return [(work.window, work.window)] * len(work.requests)
 
     @staticmethod
@@ -1708,6 +1792,10 @@ class Scheduler:
                     req.output_token_ids.extend(accepted)
                     start = req.num_computed_tokens
                     req.num_computed_tokens += len(accepted)
+                    if work.block_tokens:
+                        # ... and the window committed the positions of
+                        # the tokens it was given too
+                        req.num_computed_tokens += len(work.block_tokens[i])
                     self._register_full_blocks(
                         req, start, req.num_computed_tokens
                     )

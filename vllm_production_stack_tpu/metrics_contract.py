@@ -32,6 +32,21 @@ HBM_KV_WINDOW_SHARE = "tpu:hbm_kv_window_share"
 HBM_STATE_BYTES = "tpu:hbm_state_bytes"
 HBM_STATE_SLOTS_IN_USE = "tpu:hbm_state_slots_in_use"
 HBM_STATE_SHARE = "tpu:hbm_state_share"
+# generation by diffusion over blocks (ModelConfig.block_diffusion;
+# docs/44-block-diffusion.md; all 0 for every other model): what the decode
+# programs' windows of block passes counted on the device. A STEP of such a
+# model is a forward pass over each row's open block, not a token:
+# GENERATION_TOKENS stays tokens DELIVERED. Forward passes run; rows x
+# passes; those in which a row's block was already final beyond its one
+# commit pass (what running rows in lockstep costs); blocks committed;
+# tokens unmasked; and those the confidence threshold unmasked beyond the
+# schedule's count.
+BD_PASSES = "tpu:block_diffusion_passes_total"
+BD_ROW_PASSES = "tpu:block_diffusion_row_passes_total"
+BD_REPEAT_ROW_PASSES = "tpu:block_diffusion_repeat_row_passes_total"
+BD_BLOCKS = "tpu:block_diffusion_blocks_total"
+BD_TOKENS = "tpu:block_diffusion_tokens_total"
+BD_THRESHOLD_TOKENS = "tpu:block_diffusion_threshold_tokens_total"
 PREFIX_CACHE_HIT_RATE = "tpu:hbm_prefix_cache_hit_rate"
 # host-RAM offload tier (LMCache CPU-offload equivalent)
 HOST_KV_USAGE_PERC = "tpu:host_kv_usage_perc"
@@ -645,6 +660,12 @@ ALL_COUNTERS = (
     PREFIX_CACHE_QUERIES,
     NUM_PREEMPTIONS,
     HBM_KV_WINDOW_PAGES_GIVEN_BACK,
+    BD_PASSES,
+    BD_ROW_PASSES,
+    BD_REPEAT_ROW_PASSES,
+    BD_BLOCKS,
+    BD_TOKENS,
+    BD_THRESHOLD_TOKENS,
     PROMPT_TOKENS,
     GENERATION_TOKENS,
     HOST_KV_OFFLOADS,
@@ -706,6 +727,18 @@ ALL_COUNTERS = (
 # kernel, the live rows x steps of its decode windows, the state slots a
 # chunk that held a row's first token started from zeros, and the positions
 # computed a second time after a preemption took a row's slot.
+# ... and the counts a model that generates by diffusion over blocks adds
+# (engine/model_runner.py:BD_COUNTS, in its order; absent otherwise), each
+# beside the /metrics counter that serves the same number
+DEBUG_TIMING_BD_COUNTS = {
+    "bd_passes_n": BD_PASSES,
+    "bd_row_passes_n": BD_ROW_PASSES,
+    "bd_repeat_row_passes_n": BD_REPEAT_ROW_PASSES,
+    "bd_blocks_n": BD_BLOCKS,
+    "bd_tokens_n": BD_TOKENS,
+    "bd_threshold_tokens_n": BD_THRESHOLD_TOKENS,
+}
+
 DEBUG_TIMING_STATE_COUNTS = (
     "kda_prefill_tokens_n",
     "kda_prefill_segments_n",

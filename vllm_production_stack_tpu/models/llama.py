@@ -81,6 +81,27 @@ def _tap_routing(token_ids, positions, layers_routing: list) -> None:
             experts)
 
 
+# The same for the passes of a window of block passes (generation by
+# diffusion over blocks; engine/model_runner.py:_build_bd_window_fn): per
+# pass, IN ORDER, (block of the window, pass of the block, positions (B, T),
+# the block's tokens before the pass, which it unmasked, the tokens after,
+# each expert layer's chosen experts (Lm, B * T, k)); the runner tells the
+# tap which requests a window's rows are when it dispatches it.
+# testing/reference_check.py:BlockTap.
+BD_TAP = None
+
+
+def tap_block_pass(blk, pas, positions, before, picked, after,
+                   layers_routing: list | None) -> None:
+    if BD_TAP is not None:
+        experts = jnp.stack(
+            [r["experts"].reshape(-1, r["experts"].shape[-1])
+             for r in layers_routing]) if layers_routing else jnp.zeros(
+                 (0, before.size, 1), jnp.int32)
+        jax.debug.callback(BD_TAP, blk, pas, positions, before, picked,
+                           after, experts, ordered=True)
+
+
 # The same for the recurrence of ONE layer that holds state (`STATE_TAP.layer`):
 # per execution, (the pool index each token's row writes its state slot at
 # — 0, the null slot, for padding —, positions, and q, k, v, g, beta as the
@@ -1294,6 +1315,14 @@ def pack_index(chunk_lens: jax.Array, context_lens: jax.Array,
     }
 
 
+def _block_mask(cfg: ModelConfig) -> dict:
+    """The attention mask's `block` argument of a model that generates by
+    diffusion over blocks (a query sees its whole block), nothing for any
+    other model: their programs trace as they did."""
+    bd = cfg.block_diffusion
+    return {} if bd is None else {"block": bd.block_length}
+
+
 def _row_view(pack: dict, n: int, b: int) -> dict:
     """The two gather indices between a packed chunk of `n` tokens and the
     (b, n) row view attention takes: "to_rows" (b, n), the packed index of
@@ -1386,6 +1415,7 @@ def _layer(
                 pallas_prefill["context_lens"],
                 pallas_prefill["chunk_start"], scale=cfg.attn_scale,
                 interpret=pallas_prefill["interpret"],
+                **_block_mask(cfg),
             )
         return paged_attention_xla(
             q, kv_layer, block_tables, mask, scale=cfg.attn_scale,
@@ -1515,7 +1545,8 @@ def forward(
         # layer of that kind (full; a window's sliding variant)
         s_ctx = _group(block_tables, 0).shape[1] * kv_caches[0].shape[2]
         masks = {
-            w: causal_page_mask(row_positions, context_lens, s_ctx, window=w)
+            w: causal_page_mask(row_positions, context_lens, s_ctx, window=w,
+                                **_block_mask(cfg))
             for w in cfg.window_kinds
         }
         pallas_prefill = None
@@ -1761,6 +1792,142 @@ def decode_window_step(
     if states is not None:
         staged = {"kv": staged, "state": states}
     return x, staged
+
+
+def decode_block_step(
+    cfg: ModelConfig,
+    params: dict,
+    token_ids: jax.Array,  # (B, T) each row's open block, T = block_length
+    positions: jax.Array,  # (B, T) the block's positions
+    kv_caches: tuple[jax.Array, ...],  # read-only pool
+    block_tables: jax.Array,  # (B, max_blocks)
+    staged: jax.Array,  # (L, 2, W, B, kvH, D) window staging buffer
+    slot0: jax.Array,  # scalar int32: the block's first staged slot
+    hist_len: jax.Array,  # (B,): pool positions < hist_len are history
+    backend: str = "xla",  # as decode_window_step
+    hists: tuple | None = None,  # as decode_window_step
+    moe_stats: list | None = None,  # as in forward
+    moe_backend: str = "xla",  # as in forward
+) -> tuple[jax.Array, jax.Array]:
+    """`decode_window_step` for a model that generates by diffusion over
+    blocks (ModelConfig.block_diffusion): ONE forward pass over each row's
+    whole open block inside a fused window. The K/V of all T positions are
+    written to the block's staged slots [slot0, slot0 + T) at every pass —
+    overwritten by the next pass, final after the pass over the block's
+    final tokens — and every query of the block sees the same keys: the
+    pool history and the staged slots up to the END of the block. So the T
+    queries of a row fold into the GQA group axis of `paged_decode_attention`
+    (q_per_kv x T query rows a kv head, one visible range), which reads a
+    row's KV once a pass, with `step_k` = the block's last slot. Plain GQA
+    layers only (the engine refuses the rest by name). Returns (hidden
+    (B, T, h), staged')."""
+    b, t = token_ids.shape
+    window = staged.shape[2]
+    with jax.named_scope("embed"):
+        x = _embed(cfg, params, token_ids)  # (B, T, h)
+    last = slot0 + (t - 1)
+    staged_mask = jnp.arange(window, dtype=jnp.int32) <= last
+    if backend == "xla":
+        s_ctx = (
+            hists[0][0].shape[1] if hists is not None
+            else block_tables.shape[1] * kv_caches[0].shape[2])
+        hist_mask = (
+            jnp.arange(s_ctx, dtype=jnp.int32)[None, :] < hist_len[:, None])
+
+    for i, spec in enumerate(cfg.layer_specs):
+        lp = _layer_params(cfg, params, i)
+
+        def attend(q, k, v, i=i):
+            nonlocal staged
+            with jax.named_scope("kv_stage"):
+                kv = jnp.stack([k, v]).swapaxes(1, 2)  # (2, T, B, kvH, D)
+                staged = jax.lax.dynamic_update_slice(
+                    staged, kv[None].astype(staged.dtype),
+                    (i, 0, slot0, 0, 0, 0))
+            with jax.named_scope("attn"):
+                if backend == "xla":
+                    if hists is not None:
+                        return attention_with_hist(
+                            q, hists[i][0], hists[i][1], hist_mask,
+                            staged[i, 0], staged[i, 1], staged_mask,
+                            scale=cfg.attn_scale)
+                    return paged_attention_with_staged(
+                        q, kv_caches[i], block_tables, hist_mask,
+                        staged[i, 0], staged[i, 1], staged_mask,
+                        scale=cfg.attn_scale)
+                nkv, qpk, d = cfg.num_kv_heads, cfg.q_per_kv, cfg.head_dim
+                folded = q.reshape(b, t, nkv, qpk, d).swapaxes(1, 2)
+                out = paged_decode_attention(
+                    folded.reshape(b, nkv * t * qpk, d), kv_caches[i],
+                    block_tables, hist_len, staged[i, 0], staged[i, 1],
+                    last, scale=cfg.attn_scale,
+                    interpret=backend == "pallas_interpret")
+                return out.reshape(b, nkv, t, qpk, d).swapaxes(1, 2).reshape(
+                    b, t, nkv * qpk, d)
+
+        x = _layer_body(cfg, lp, x, positions, attend, None, None,
+                        moe_stats, spec, moe_backend)
+    # (a pass's routing goes to BD_TAP with the pass, not to ROUTING_TAP:
+    # a mask token at one position is fed by many passes)
+    with jax.named_scope("final_norm"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
+                     cfg.rms_norm_add_one, scale_f32=cfg.norm_scale_f32)
+    return x, staged
+
+
+def bd_transfer(
+    bd,  # engine/config.py:BlockDiffusion
+    logits: jax.Array,  # (B, T, V) float32: this pass's, at the block
+    tokens: jax.Array,  # (B, T) the block's current tokens
+    masked: jax.Array,  # (B, T) bool: positions that still hold a mask
+    pass_idx: jax.Array,  # scalar int32: the block's pass, from 0
+    x0: jax.Array | None = None,  # (B, T) candidates drawn by the caller
+    #   (temperature > 0); None: the argmax
+    temperature: jax.Array | None = None,  # (B,) beside `x0`
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """The transfer rule of generation by diffusion over blocks, a pure
+    function of one pass: at every masked position the candidate x0 (the
+    argmax of the position's OWN logits: no shift) and its confidence, that
+    distribution's probability of x0 in float32 (the softmax at the row's
+    temperature where x0 was drawn, the plain softmax under greedy);
+    then, with n = the schedule's count for this pass (at most the masks
+    left): "sequential" unmasks the leftmost n, "low_confidence_static" the
+    n of highest confidence (ties: lowest position), and
+    "low_confidence_dynamic" every masked position over the threshold if
+    those are at least the schedule's count, else as static. Returns
+    (tokens', masked', unmasked (B, T) bool, over (B,) int32: tokens the
+    threshold unmasked beyond the schedule's count)."""
+    with jax.named_scope("bd_transfer"):
+        t = tokens.shape[1]
+        scaled = logits
+        if x0 is None:
+            x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        else:
+            temp = jnp.where(temperature > 0.0, temperature, 1.0)
+            scaled = logits / temp[:, None, None]
+        conf = jnp.exp(
+            jnp.take_along_axis(scaled, x0[..., None], axis=-1)[..., 0]
+            - jax.nn.logsumexp(scaled, axis=-1))
+        schedule = jnp.asarray(bd.transfer_schedule, jnp.int32)
+        n_s = schedule[jnp.minimum(pass_idx, schedule.shape[0] - 1)]
+        n = jnp.minimum(n_s, jnp.sum(masked, axis=1, dtype=jnp.int32))
+        if bd.remasking_strategy == "sequential":
+            rank = jnp.cumsum(masked, axis=1, dtype=jnp.int32) - 1
+        else:
+            c = jnp.where(masked, conf, -jnp.inf)
+            pos = jnp.arange(t, dtype=jnp.int32)
+            # rank by confidence, ties to the lowest position
+            ahead = (c[:, None, :] > c[:, :, None]) | (
+                (c[:, None, :] == c[:, :, None])
+                & (pos[None, None, :] < pos[None, :, None]))
+            rank = jnp.sum(ahead, axis=2, dtype=jnp.int32)
+        pick = masked & (rank < n[:, None])
+        if bd.remasking_strategy == "low_confidence_dynamic":
+            high = masked & (conf > bd.confidence_threshold)
+            enough = jnp.sum(high, axis=1, dtype=jnp.int32) >= n_s
+            pick = jnp.where(enough[:, None], high, pick)
+        over = jnp.sum(pick, axis=1, dtype=jnp.int32) - n
+        return (jnp.where(pick, x0, tokens), masked & ~pick, pick, over)
 
 
 def commit_staged_kv(

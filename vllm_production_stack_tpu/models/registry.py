@@ -11,11 +11,12 @@ environments can't download)."""
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import math
 import os
 
-from ..engine.config import LayerSpec, ModelConfig, RopeSpec
+from ..engine.config import BlockDiffusion, LayerSpec, ModelConfig, RopeSpec
 
 _KANANA = dict(
     vocab_size=128256, hidden_size=2048, intermediate_size=6144,
@@ -153,6 +154,34 @@ def _solar_open2_preset(n_layers: int, **over) -> dict:
 
 
 # Architecture hyperparameters follow the public model cards.
+# SDAR's generation settings (JetLM/SDAR-30B-A3B-Chat): the config.json has
+# no key for any of them. Block length 4 and 4 passes are the -Chat
+# checkpoints' convention (the family also trains blocks of 8, 16, 32, 64:
+# other checkpoints), low_confidence_dynamic at 0.9 its published
+# generation script's choice for them, 151669 the `<|MASK|>` token its
+# tokenizer adds to Qwen3's. Every one of them is `assumed` in the
+# benchmark's configuration file.
+_SDAR_GENERATION = BlockDiffusion(
+    block_length=4, denoising_steps=4,
+    remasking_strategy="low_confidence_dynamic", confidence_threshold=0.9,
+    mask_token_id=151669)
+
+
+def _sdar_preset(n_layers: int) -> dict:
+    """JetLM/SDAR-30B-A3B-Chat config.json (`model_type: sdar_moe`): the
+    Qwen3-MoE decoder key for key — 48 layers alike, 32 x 128 query heads
+    over 4 kv heads with a per-head q/k RMSNorm, 128 experts of 768, top-8
+    of a softmax renormalised, untied head — under the block mask, with
+    generation by diffusion over blocks (`block_diffusion`)."""
+    return dict(
+        vocab_size=151936, hidden_size=2048, intermediate_size=768,
+        num_layers=n_layers, num_heads=32, num_kv_heads=4, head_dim=128,
+        max_model_len=32768, rope_theta=1000000.0, rms_norm_eps=1e-6,
+        architecture="qwen3moe", qk_norm=True, num_experts=128,
+        num_experts_per_tok=8, norm_topk_prob=True,
+        block_diffusion=_SDAR_GENERATION)
+
+
 PRESETS: dict[str, dict] = {
     "tiny-llama": dict(
         vocab_size=512, hidden_size=64, intermediate_size=128, num_layers=2,
@@ -298,6 +327,23 @@ PRESETS: dict[str, dict] = {
                 "original_max_position_embeddings": 64,
                 "attention_factor": 1.2079441541679836},  # 0.1 ln 8 + 1
             "sliding_attention": _LAGUNA_ROPE["sliding_attention"]}),
+    "sdar-30b-a3b-chat": _sdar_preset(48),
+    # the benchmark cell's cut: one pipeline stage of six, eight whole
+    # layers with all 128 experts and the whole vocabulary, every width as
+    # published
+    "sdar-30b-a3b-chat-8l": _sdar_preset(8),
+    # toy widths (float32); a threshold low enough that BOTH branches of
+    # the dynamic rule fire with random weights
+    "tiny-sdar": dict(
+        vocab_size=512, hidden_size=64, intermediate_size=32, num_layers=2,
+        num_heads=4, num_kv_heads=2, head_dim=16, max_model_len=512,
+        dtype="float32", architecture="qwen3moe", qk_norm=True,
+        rms_norm_eps=1e-6, num_experts=8, num_experts_per_tok=2,
+        norm_topk_prob=True,
+        block_diffusion=BlockDiffusion(
+            block_length=4, denoising_steps=4,
+            remasking_strategy="low_confidence_dynamic",
+            confidence_threshold=0.0031, mask_token_id=511)),
     "solar-open2-250b": _solar_open2_preset(48),
     # the benchmark cell's cut: ONE chip of an eight-chip expert-parallel
     # host that is the first pipeline stage — layers 0-7 (two whole
@@ -494,11 +540,30 @@ def _solar_open2_keys(hf: dict, path: str) -> dict:
     )
 
 
+def _refuse_other_sdar_generation(hf: dict, path: str) -> None:
+    """The generation settings of a `model_type: sdar_moe` model are the
+    preset's (`_SDAR_GENERATION`): the published config.json states none,
+    and one that states another is refused by name, not served with it."""
+    for key, ours in dataclasses.asdict(_SDAR_GENERATION).items():
+        if hf.get(key) is not None and hf[key] != ours:
+            raise ValueError(
+                f"sdar_moe with {key}={hf[key]} is not implemented "
+                f"({path}): served with {key}={ours} alone")
+
+
 def _from_hf_config(path: str) -> dict:
     with open(os.path.join(path, "config.json")) as f:
         hf = json.load(f)
     archs = hf.get("architectures", [])
     arch = next((_ARCH_MAP[a] for a in archs if a in _ARCH_MAP), None)
+    generation = {}
+    if hf.get("model_type") == "sdar_moe":
+        # keyed by model_type: the decoder is qwen3moe's key for key, and a
+        # checkpoint's tensors are taken under qwen3moe's names (neither the
+        # `architectures` string nor the names could be checked offline)
+        arch = "qwen3moe"
+        _refuse_other_sdar_generation(hf, path)
+        generation = dict(block_diffusion=_SDAR_GENERATION)
     if arch is None:
         raise ValueError(f"unsupported architecture(s) {archs} in {path}")
     heads = hf["num_attention_heads"]
@@ -630,6 +695,7 @@ def _from_hf_config(path: str) -> dict:
     else:
         inter = hf["intermediate_size"]
     return dict(
+        **generation,
         **moe,
         **gemma,
         **arch_flags,

@@ -207,18 +207,23 @@ def gather_pages(kv: jax.Array, block_tables: jax.Array) -> tuple[jax.Array, jax
 
 def causal_page_mask(
     q_positions: jax.Array, context_lens: jax.Array, s: int,
-    window: int = 0,
+    window: int = 0, block: int = 0,
 ) -> jax.Array:
     """(B, T, S) mask: gathered-context position j is attendable by the query
     at logical position p iff j < context_len and j <= p — and, when
     `window` > 0 (sliding-window attention: Mistral-v0.1 all-layer SWA,
     Gemma-2 alternating layers), additionally j > p - window. Build once
     per step per window kind and reuse across same-kind layers.
+    `block` > 0 (generation by diffusion over blocks): the query sees every
+    key up to the END of its own block of `block` positions, j < (p // block
+    + 1) * block — causal across blocks, bidirectional inside one.
 
     q_positions: (B, T); context_lens: (B,); s: gathered context length.
     """
     ctx_pos = jnp.arange(s, dtype=jnp.int32)[None, :]  # (1, S)
     valid = ctx_pos < context_lens[:, None]  # (B, S)
+    if block:
+        q_positions = (q_positions // block + 1) * block - 1
     causal = ctx_pos[:, None, :] <= q_positions[..., None]  # (B, T, S)
     mask = valid[:, None, :] & causal
     if window > 0:

@@ -444,6 +444,7 @@ def _prefill_walk_kernel(
     *,
     scale: float,
     window: int = 0,
+    block: int = 0,
 ):
     """One grid step = one query tile of one row, all heads. The tile walks
     the page blocks its row holds up to the tile's last position —
@@ -466,7 +467,13 @@ def _prefill_walk_kernel(
     the first key its FIRST query sees and a tile of Tt queries visits at
     most window + Tt keys (plus page rounding) whatever the context: the
     layer's work is O(T * window), not O(T * context). Pages before that
-    one may have been given back and are never read."""
+    one may have been given back and are never read.
+
+    `block` > 0 (generation by diffusion over blocks): a query sees the keys
+    up to the END of its block of `block` positions. Chunks start and tiles
+    are cut on multiples of `block`, so a tile still walks up to its own
+    last position and a page block still needs the mask only where it
+    straddles the tile's first query: only `last_key` changes."""
     b, qt = pl.program_id(0), pl.program_id(1)
     rows, tiles = pl.num_programs(0), pl.num_programs(1)
     nb = tables_ref.shape[1]
@@ -524,10 +531,11 @@ def _prefill_walk_kernel(
     ctx, first = ctx_ref[b], start_ref[b] + qt * tt
     page0 = tile_page0(b, qt) if window else 0
     # the last key position each query row attends, rows ordered (head, token)
-    last_key = jnp.minimum(
-        first + jax.lax.broadcasted_iota(jnp.int32, (group, 1), 0) % tt,
-        ctx - 1,
-    )
+    last_key = first + jax.lax.broadcasted_iota(
+        jnp.int32, (group, 1), 0) % tt
+    if block:
+        last_key = (last_key // block + 1) * block - 1
+    last_key = jnp.minimum(last_key, ctx - 1)
     key_offsets = jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
 
     @pl.when(n_blocks > 0)
@@ -647,6 +655,7 @@ def _paged_prefill_attention(
     *,
     scale: float,
     window: int = 0,
+    block: int = 0,
     interpret: bool = False,
 ) -> jax.Array:
     """Complete chunked-prefill attention (resident pages + the chunk's own
@@ -669,7 +678,8 @@ def _paged_prefill_attention(
     # (a window layer's kernel is a kernel of its own, and named so: the
     # device trace tells the two apart)
     out = pl.pallas_call(
-        functools.partial(_prefill_walk_kernel, scale=scale, window=window),
+        functools.partial(_prefill_walk_kernel, scale=scale, window=window,
+                          **({"block": block} if block else {})),
         **({"name": "paged_window_prefill_attention"} if window else {}),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,  # tables, context_lens, chunk_start
@@ -697,12 +707,13 @@ def _paged_prefill_attention(
     return out.reshape(b, t, nh, d)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "block"))
 def paged_prefill_attention(q, kv, block_tables, context_lens, chunk_start,
-                            *, scale: float, interpret: bool = False):
+                            *, scale: float, interpret: bool = False,
+                            block: int = 0):
     return _paged_prefill_attention(
         q, kv, block_tables, context_lens, chunk_start, scale=scale,
-        interpret=interpret)
+        interpret=interpret, block=block)
 
 
 paged_prefill_attention.__doc__ = _paged_prefill_attention.__doc__

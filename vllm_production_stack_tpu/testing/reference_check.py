@@ -313,3 +313,228 @@ def served_state(reference, cfg, tap: StateTap, pool,
         tokens += len(fed[0])
     return {"layer": tap.layer, "rows": len(rows), "tokens": tokens,
             "state_rel_err": worst}
+
+
+# -- generation by diffusion over blocks (reference_sdar.py) -----------------
+
+def served_block_logprobs(engine, prompts: list[list[int]], max_tokens: int,
+                          ) -> list[dict]:
+    """`served_logprobs`, keeping each request's id beside its tokens and
+    logprobs (what `BlockTap` keys a window's rows by)."""
+    sampling = SamplingParams(max_tokens=max_tokens, temperature=0.0,
+                              ignore_eos=True, logprobs=8)
+    ids = [engine.add_request(prompt_token_ids=p, sampling=sampling)
+           for p in prompts]
+    done = {i: {"request_id": i, "token_ids": [], "logprobs": []}
+            for i in ids}
+    while engine.has_unfinished():
+        with engine.step_lock:
+            outs = engine.step()
+        for out in outs:
+            done[out.request_id]["token_ids"].extend(out.new_token_ids)
+            done[out.request_id]["logprobs"].extend(out.new_logprobs or [])
+    return [done[i] for i in ids]
+
+
+class BlockTap:
+    """Every pass of the served windows of block passes, in order: set as
+    `llama.BD_TAP` while an engine is built and driven, it hears from the
+    runner which requests a window's rows are (`window`, at dispatch) and
+    from the program each pass's block states and routing (`__call__`; a
+    window's first pass is block 0, pass 0). `blocks_of(request id)` gives a
+    row's blocks back: {first position: [pass, ...]}, a pass = {"before",
+    "picked", "after" (T,), "experts" (Lm, T, k)}; the last pass of a block
+    is its commit pass. A window that was rolled back and dispatched again
+    is heard twice; the later hearing replaces the earlier."""
+
+    def __init__(self):
+        self.windows: list[list[str]] = []
+        self.passes: list[tuple] = []
+
+    def window(self, request_ids: list[str]) -> None:
+        self.windows.append(list(request_ids))
+
+    def __call__(self, blk, pas, positions, before, picked, after,
+                 experts) -> None:
+        self.passes.append(tuple(np.asarray(a) for a in (
+            blk, pas, positions, before, picked, after, experts)))
+
+    def __enter__(self) -> "BlockTap":
+        llama.BD_TAP = self
+        return self
+
+    def __exit__(self, *exc) -> None:
+        llama.BD_TAP = None
+        jax.effects_barrier()  # every callback has run
+
+    def blocks_of(self, request_id: str) -> dict[int, list[dict]]:
+        blocks: dict[int, list[dict]] = {}
+        w = -1
+        for blk, pas, pos, before, picked, after, experts in self.passes:
+            if blk == 0 and pas == 0:
+                w += 1
+            rows = self.windows[w]
+            if request_id not in rows:
+                continue
+            i, t = rows.index(request_id), before.shape[1]
+            first = int(pos[i, 0])
+            if pas == 0:
+                blocks[first] = []  # (a replayed block starts over)
+            blocks[first].append({
+                "before": before[i], "picked": picked[i], "after": after[i],
+                "experts": experts[:, i * t:(i + 1) * t]})
+        return blocks
+
+
+    def touched_split(self, mask_id: int, num_experts: int) -> dict:
+        """Why the passes touched the share of a layer's experts they did,
+        for the tokens of a pass's LIVE rows together (`all`; padding rows
+        feed one token at one position and choose as one), its mask tokens
+        and its other tokens apart: `tokens` a pass and layer (mean),
+        `touched` the share of the experts they chose, `independent` what
+        as many tokens touch choosing independently with the layer's own
+        expert frequencies over every tapped pass (the routers' skew alone)
+        and `balanced` with equal frequencies. Tokens that route ALIKE read
+        under `independent`; skewed routers read `independent` under
+        `balanced`."""
+        chosen, w = [], -1
+        for blk, pas, _pos, before, _picked, _after, experts in self.passes:
+            if blk == 0 and pas == 0:
+                w += 1
+            live = len(self.windows[w]) * before.shape[1]
+            chosen.append((experts[:, :live],
+                           before.reshape(-1)[:live] == mask_id))
+        k = chosen[0][0].shape[-1]
+        counts = np.zeros((chosen[0][0].shape[0], num_experts))
+        for experts, _ in chosen:
+            for layer, ids in enumerate(experts):
+                counts[layer] += np.bincount(
+                    ids.reshape(-1), minlength=num_experts)
+        freq = counts / (counts.sum(axis=1, keepdims=True) / k)  # (Lm, E)
+        split = {}
+        for name, pick in (("all", lambda m: np.ones_like(m)),
+                           ("masks", lambda m: m), ("others", lambda m: ~m)):
+            n, touched, independent, balanced = [], [], [], []
+            for experts, is_mask in chosen:
+                rows = pick(is_mask)
+                if not rows.any():
+                    continue
+                n.append(int(rows.sum()))
+                touched.append(np.mean([
+                    len(np.unique(ids[rows])) for ids in experts])
+                    / num_experts)
+                independent.append(np.mean(1 - (1 - freq) ** n[-1]))
+                balanced.append(1 - (1 - k / num_experts) ** n[-1])
+            split[name] = {
+                "tokens": float(np.mean(n)), "touched": float(np.mean(touched)),
+                "independent": float(np.mean(independent)),
+                "balanced": float(np.mean(balanced))}
+        return split
+
+
+def compare_blocks(reference, cfg, params, prompts: list[list[int]],
+                   served: list[dict], tap: BlockTap,
+                   routing: RoutingTap | None = None, *,
+                   blocks: str = "all", commit_pass: bool = True,
+                   shift: bool = False, ref_cfg: dict | None = None,
+                   query_block: int = 256, pad_to: int = 32) -> dict:
+    """The served windows of block passes against `reference.forward`
+    (reference_sdar.py), teacher-forced: for every pass that unmasked a
+    token, the reference's forward over the row's finished blocks + the
+    block AS THAT PASS SAW IT (from `tap`), and the engine's logprobs of
+    the tokens the pass unmasked against the reference's log-softmax at
+    their positions. Logits, not tokens, and the engine's own order of
+    unmasking: with random weights both the argmax and the confidence order
+    change on rounding (`order_agreement_share` says how often the
+    reference would have unmasked the same positions). With `routing` (the
+    prompt's, from `RoutingTap`) the reference follows the served programs'
+    experts: the passes' from `tap`, a finished block's from its commit
+    pass. `blocks`: "all", or "ends" (a row's first and last block).
+
+    A sequence is padded with masks to a multiple of `pad_to` (no query at or
+    before the open block sees what lies behind it): the eager reference
+    compiles each operation once a length.
+
+    Two ways to hold a WRONG procedure against the same served numbers:
+    `commit_pass` False — finished blocks stand in the reference's context
+    as their last denoising pass saw them (what a cache holds that keeps
+    that pass's K/V) — and `shift` — the token at position i is read off
+    the logits at position i - 1."""
+    ref_cfg = ref_cfg or reference.published_keys(cfg)
+    t = ref_cfg["block_length"]
+    worst = total = 0.0
+    positions = ids_compared = passes = same_order = 0
+    for prompt, got in zip(prompts, served):
+        final = list(prompt) + list(got["token_ids"])
+        p = len(prompt)
+        row_blocks = tap.blocks_of(got["request_id"])
+        starts = sorted(row_blocks)
+        # what the context holds of a finished block, and its routing
+        held = {a: (row_blocks[a][-1]["after"] if commit_pass else next(
+            x["before"] for x in reversed(row_blocks[a])
+            if x["picked"].any())) for a in starts}
+        pre = p // t * t
+        # (a (position, token) key that two rows share names neither's
+        # choice: the reference follows its own there)
+        pre_routing = (routing.routing_of(final[:pre], mark_ambiguous=True)
+                       if routing is not None and pre else None)
+        # (the blocks that hold a delivered token: what lies past
+        # max_tokens was computed and cut)
+        starts = [a for a in starts if a < len(final)]
+        check = starts if blocks == "all" else sorted({starts[0], starts[-1]})
+        for a in check:
+            context = list(final[:pre])
+            for s in starts:
+                if s < a:
+                    context += [int(x) for x in held[s]]
+            for pas, one in enumerate(row_blocks[a]):
+                if not one["picked"].any():
+                    continue
+                seq = context + [int(x) for x in one["before"]]
+                pad = -len(seq) % pad_to
+                seq += [ref_cfg["mask_token_id"]] * pad
+                forced = None
+                if routing is not None:
+                    own = np.full((pad, one["experts"].shape[-1]), -1)
+                    forced = [
+                        np.concatenate(
+                            ([pre_routing[layer]] if pre_routing else [])
+                            + [row_blocks[s][-1]["experts"][layer]
+                               for s in starts if s < a]
+                            + [one["experts"][layer], own])
+                        for layer in range(one["experts"].shape[0])]
+                at = range(a - shift, a - shift + t)
+                if at[0] < 0:
+                    continue  # (position 0 has nothing before it to shift to)
+                logits, _ = reference.forward(
+                    ref_cfg, params, seq, at, forced_experts=forced,
+                    query_block=query_block)
+                if not ref_cfg["float32_statistics"]:
+                    logits = logits.astype(jnp.bfloat16)
+                ref_lp = np.asarray(
+                    jax.nn.log_softmax(logits, axis=-1).astype(jnp.float32))
+                passes += 1
+                # would the reference have unmasked the same positions?
+                masked = [int(x) == ref_cfg["mask_token_id"] and i + a >= p
+                          for i, x in enumerate(one["before"])]
+                conf = np.exp(ref_lp.max(-1))
+                same_order += set(reference.transfer(
+                    ref_cfg, conf, masked, pas)) == set(
+                        np.flatnonzero(one["picked"]).tolist())
+                for i in np.flatnonzero(one["picked"]):
+                    k = a + int(i) - p
+                    if k >= len(got["logprobs"]):
+                        continue  # (past max_tokens: cut, not delivered)
+                    chosen_lp, top_ids, top_lps = got["logprobs"][k]
+                    diff = np.abs(
+                        ref_lp[i][np.asarray(top_ids)] - np.asarray(top_lps))
+                    worst = max(worst, float(diff.max()), abs(
+                        float(ref_lp[i][got["token_ids"][k]]) - chosen_lp))
+                    total += float(diff.sum())
+                    positions += 1
+                    ids_compared += len(top_ids)
+    return {"max_abs_dlogprob": worst,
+            "mean_abs_dlogprob": total / max(ids_compared, 1),
+            "positions": positions, "ids_compared": ids_compared,
+            "passes_compared": passes,
+            "order_agreement_share": same_order / max(passes, 1)}
