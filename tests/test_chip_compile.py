@@ -323,17 +323,23 @@ def _cell_config(model):
         resolve_model_config(model, quantization="int8"), num_layers=2)
 
 
-@pytest.mark.parametrize("rows,tokens,moe_backend", [
-    (1, CHUNK, "pallas"),  # 512 tokens: every expert on every token
-    (2, CHUNK, "pallas"),  # still 512: the token SUM chooses, not rows x bucket
-    # 1,024 tokens, the routed pairs sorted by expert: through the fused
-    # kernel on one TPU device (int8 tiles widened in VMEM), through
-    # ragged_dot where the program may hold no Pallas kernel (a mesh)
-    (2, 2 * CHUNK, "pallas"),
-    (2, 2 * CHUNK, "xla"),
+@pytest.mark.parametrize("rows,tokens,moe_backend,want", [
+    # the cell's 512-token chunk: five times the 96 tokens at which the
+    # einsum's flops overtake its int8 bytes, so the routed pairs go sorted
+    # through the fused kernel (int8 tiles widened in VMEM) on one TPU device
+    (1, CHUNK, "pallas", "grouped_kernel"),
+    # still 512: the token SUM chooses, not rows x bucket
+    (2, CHUNK, "pallas", "grouped_kernel"),
+    # where the program may hold no Pallas kernel (a mesh) the einsum stays
+    # up to 512 tokens: `ragged_dot` loses to it there
+    (1, CHUNK, "xla", "all_experts"),
+    (1, CHUNK // 8, "pallas", "all_experts"),  # 64: the bytes still bound
+    # 1,024 tokens: the kernel, or ragged_dot under a mesh
+    (2, 2 * CHUNK, "pallas", "grouped_kernel"),
+    (2, 2 * CHUNK, "xla", "grouped"),
 ])
 def test_moe_prefill_program_compiles_for_v5e(topo, rows, tokens,
-                                              moe_backend):
+                                              moe_backend, want):
     """OLMoE-1B-7B's prefill step at published widths (two layers deep),
     int8 experts, on one described v5e chip."""
     from vllm_production_stack_tpu.models import llama
@@ -342,11 +348,10 @@ def test_moe_prefill_program_compiles_for_v5e(topo, rows, tokens,
     text = _packed_prefill(
         topo, cfg, rows, tokens, moe_backend=moe_backend).as_text()
     _assert_no_widened_experts(cfg, text)
-    form = llama.moe_experts_form(cfg, tokens, kernel=moe_backend != "xla")
-    assert form == {CHUNK: "all_experts"}.get(
-        tokens, "grouped" if moe_backend == "xla" else "grouped_kernel")
-    assert ("ragged-dot" in text) == (form == "grouped")
-    assert ("moe_prefill_experts" in text) == (form == "grouped_kernel")
+    assert llama.moe_experts_form(
+        cfg, tokens, kernel=moe_backend != "xla") == want
+    assert ("ragged-dot" in text) == (want == "grouped")
+    assert ("moe_prefill_experts" in text) == (want == "grouped_kernel")
 
 
 @pytest.mark.parametrize("model", ["qwen3-8b", "qwen2-7b", "olmoe-1b-7b"])

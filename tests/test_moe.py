@@ -511,15 +511,16 @@ def _touched_case(case: str, dtype: str, quantization, n: int = 16):
     from vllm_production_stack_tpu.models.quantization import quantize_params
 
     held = case in ("held-share", "none-touched")
+    # "cell-chunk": the OLMoE cell's routing at toy widths, 64 x top-8
+    e, k = (64, 8) if case == "cell-chunk" else (8, 3)
     cfg = ModelConfig.tiny(
         model="tiny-olmoe", architecture="olmoe", intermediate_size=32,
-        num_experts=8, num_experts_per_tok=3, norm_topk_prob=False,
+        num_experts=e, num_experts_per_tok=k, norm_topk_prob=False,
         dtype=dtype, quantization=quantization,
         num_experts_held=4 if held else 0, first_expert_held=2)
     params = quantize_params(
         cfg, llama.init_params(cfg, jax.random.PRNGKey(3)))
     rng = np.random.RandomState(5)
-    e, k = cfg.num_experts, cfg.num_experts_per_tok
     xf = jnp.asarray(rng.normal(size=(n, cfg.hidden_size)), cfg.dtype)
     if case == "one-expert":  # every pair of every row on expert 5
         topi = np.full((n, k), 5)
@@ -527,6 +528,8 @@ def _touched_case(case: str, dtype: str, quantization, n: int = 16):
         topi = np.stack([(np.arange(k) + r) % e for r in range(n)])
     elif case == "none-touched":  # every pair on another chip's experts
         topi = np.stack([rng.permutation([0, 1, 6, 7])[:k] for _ in range(n)])
+    elif case == "cell-chunk":  # a random router's: every expert touched
+        topi = np.stack([rng.permutation(e)[:k] for _ in range(n)])
     else:  # "random", "held-share": some experts without a pair
         topi = np.stack([rng.permutation(e - 1)[:k] for _ in range(n)])
     topv = jnp.asarray(rng.uniform(0.05, 0.5, size=(n, k)), jnp.float32)
@@ -622,26 +625,43 @@ def test_moe_mlp_reads_a_leading_dense_models_expert_stack_in_place():
     ("laguna-s-2.1-ep4-9l", 16, None, "touched_kernel"),  # 0.47 (of 256)
     ("olmoe-1b-7b", 16, "int8", "all_experts"),  # 0.88
     ("tiny-mixtral", 16, None, "all_experts"),  # 1.00
-    ("olmoe-1b-7b", 512, "int8", "all_experts"),  # a prefill chunk
+    # the OLMoE cell's prefill chunk: 5 x the tokens at which the einsum's
+    # flops overtake its int8 bytes (ISSUE 42)
+    ("olmoe-1b-7b", 512, "int8", "grouped_kernel"),
     ("kanana-2-30b-a3b-8l", 64, None, "all_experts"),  # 0.95
     ("kanana-2-30b-a3b-8l", 32, None, "touched_kernel"),  # 0.79
     ("kanana-2-30b-a3b-8l", 2048, None, "grouped_kernel"),
     ("olmoe-1b-7b", 8, "int8", "touched_kernel"),  # 0.66: int8 leaves too
     # the long-document cells' one prefill bucket, and the token limit
     ("laguna-s-2.1-ep4-9l", 2048, None, "grouped_kernel"),
-    ("kanana-2-30b-a3b-8l", 512, None, "all_experts"),
+    ("kanana-2-30b-a3b-8l", 512, None, "grouped_kernel"),
     ("kanana-2-30b-a3b-8l", 513, None, "grouped_kernel"),
     ("olmoe-1b-7b", 513, "int8", "grouped_kernel"),  # int8 leaves too
     ("tiny-mixtral", 1024, None, "grouped_kernel"),
+    # where the einsum ends: 96 tokens a weight byte of the expert leaves
+    ("olmoe-1b-7b", 96, "int8", "all_experts"),
+    ("olmoe-1b-7b", 97, "int8", "grouped_kernel"),
+    ("kanana-2-30b-a3b-8l", 192, None, "all_experts"),  # bf16 leaves
+    ("kanana-2-30b-a3b-8l", 193, None, "grouped_kernel"),
+    ("laguna-s-2.1-ep4-9l", 192, None, "all_experts"),  # a held share too
+    ("laguna-s-2.1-ep4-9l", 193, None, "grouped_kernel"),
+    ("olmoe-1b-7b", 192, None, "all_experts"),  # the same model in bf16
+    ("tiny-olmoe", 384, None, "all_experts"),  # float32 leaves
+    ("tiny-olmoe", 385, None, "grouped_kernel"),
+    # the SDAR cell's decode pass (16 rows x a block of 4) keeps the einsum
+    ("sdar-30b-a3b-chat-8l", 64, None, "all_experts"),
+    ("sdar-30b-a3b-chat-8l", 1024, None, "grouped_kernel"),  # its chunk
 ])
 def test_the_shape_rule_picks_the_expert_form(model, tokens, quantization,
                                               want):
-    """One family, four forms, from (N, k, published E) alone."""
+    """One family, four forms, from (N, k, published E, the expert leaves'
+    itemsize) alone."""
     from vllm_production_stack_tpu.models.registry import resolve_model_config
 
     cfg = resolve_model_config(model, quantization=quantization)
     assert llama.moe_experts_form(cfg, tokens, kernel=True) == want
-    # a program that may hold no Pallas kernel keeps today's two forms
+    # a program that may hold no Pallas kernel keeps its two forms and the
+    # 512-token limit: `ragged_dot` loses to the einsum there
     assert llama.moe_experts_form(cfg, tokens, kernel=False) == (
         "grouped" if tokens > 512 else "all_experts")
 
@@ -667,18 +687,22 @@ def test_the_runner_resolves_the_einsum_under_a_mesh(mesh_size,
 @pytest.mark.parametrize("tokens,mesh_size,want", [
     (2048, 1, "grouped_kernel"),
     (2048, 2, "grouped"),  # any mesh keeps ragged_dot: no shard_map wrapper
-    (512, 1, "all_experts"),
+    (512, 1, "grouped_kernel"),  # the OLMoE cell's chunk (ISSUE 42)
+    (512, 2, "all_experts"),  # under a mesh the einsum ends at 512 as before
 ])
 def test_the_runner_reports_the_prefill_form(tokens, mesh_size, want):
     """`/debug/programs` -> `device.moe.prefill` and the form the read
-    counter is told follow the rule and the mesh (ISSUE 38)."""
+    counter is told follow the rule and the mesh (ISSUE 38), at the OLMoE
+    cell's int8 leaves (ISSUE 42)."""
     from types import SimpleNamespace
 
     from vllm_production_stack_tpu.engine.model_runner import ModelRunner
+    from vllm_production_stack_tpu.models.registry import resolve_model_config
 
     stub = SimpleNamespace(
         config=SimpleNamespace(
-            model=olmoe_cfg(), attention_backend="pallas_interpret"),
+            model=resolve_model_config("olmoe-1b-7b", quantization="int8"),
+            attention_backend="pallas_interpret"),
         mesh=SimpleNamespace(size=mesh_size))
     stub._moe_backend = ModelRunner._resolve_moe_backend(stub)
     assert ModelRunner.moe_experts_form(stub, tokens) == want
@@ -749,8 +773,11 @@ def test_visit_tables_walk_experts_then_tiles(sizes, want):
     assert (np.diff(tile) >= 0).all()
 
 
-# 90 tokens x top-3 = 270 sorted rows: three row tiles of 128, the last short
-_prefill_case = functools.partial(_touched_case, n=90)
+def _prefill_case(case: str, dtype: str, quantization):
+    """90 tokens x top-3 = 270 sorted rows: three row tiles of 128, the last
+    short; "cell-chunk": the OLMoE cell's 512 tokens x top-8 of 64."""
+    return _touched_case(
+        case, dtype, quantization, n=512 if case == "cell-chunk" else 90)
 
 
 def _grouped_forms(cfg, params, layer, xf, topv, topi):
@@ -770,21 +797,28 @@ def _grouped_forms(cfg, params, layer, xf, topv, topi):
 @pytest.mark.parametrize("dtype,quantization", [
     ("bfloat16", None), ("bfloat16", "int8"), ("float32", None)])
 @pytest.mark.parametrize("case", [
-    "random", "one-expert", "all-touched", "held-share", "none-touched"])
+    "random", "one-expert", "all-touched", "held-share", "none-touched",
+    "cell-chunk"])
 def test_grouped_kernel_equals_ragged_dot_and_all_experts(
         case, dtype, quantization):
     """The "grouped_kernel" form (interpret mode) against its two oracles
     on layer 1 of the stacked leaves: equal to the rounding of the model's
     dtype (the kernel rounds once where `ragged_dot` rounds three times),
     with an expert that got no row ("random": expert 7), one that got every
-    row, and exact zeros from pairs that land on experts held elsewhere."""
+    row, and exact zeros from pairs that land on experts held elsewhere.
+    "cell-chunk" is the OLMoE cell's 512-token chunk (ISSUE 42): 4,096 pairs
+    over 64 experts, ~64 a group, so every 128-row tile straddles two or
+    three groups and every expert is visited."""
     cfg, params, xf, topv, topi = _prefill_case(case, dtype, quantization)
     dense, ragged, kernel, sizes = _grouped_forms(
         cfg, params, 1, xf, topv, topi)
     assert int(jnp.sum(sizes)) == {
-        "none-touched": 0, "held-share": int(jnp.sum(sizes))}.get(case, 270)
+        "none-touched": 0, "held-share": int(jnp.sum(sizes)),
+        "cell-chunk": 4096}.get(case, 270)
     if case == "random":
         assert int(sizes[-1]) == 0
+    if case == "cell-chunk":
+        assert 0 < int(sizes.min()) and int(sizes.max()) < 128
     assert kernel.shape == dense.shape and kernel.dtype == dense.dtype
     if case == "none-touched":
         assert not np.asarray(kernel, np.float32).any()
@@ -843,7 +877,9 @@ def test_moe_mlp_takes_the_grouped_kernel_where_it_may_hold_one(monkeypatch):
 def test_programs_the_rule_leaves_alone_are_the_same_text(model, program):
     """A dense model, and an MoE model whose 16 rows touch nearly every
     expert, lower to the SAME StableHLO whether or not their programs may
-    hold the kernel: the rule, not the backend, decides."""
+    hold the kernel: the rule, not the backend, decides. (The prefill
+    program is 16 rows x 16 tokens: under the 384 tokens at which float32
+    leaves leave the einsum where a kernel may be held, ISSUE 42.)"""
     from vllm_production_stack_tpu.models.registry import resolve_model_config
 
     cfg = resolve_model_config(model)
@@ -862,7 +898,7 @@ def test_programs_the_rule_leaves_alone_are_the_same_text(model, program):
     def prefill(moe_backend, params, kv, tokens, tables, lens):
         return llama.forward(
             cfg, params, tokens, tokens, kv, tables,
-            jnp.zeros((rows * 32,), jnp.int32), lens,
+            jnp.zeros((rows * 16,), jnp.int32), lens,
             moe_stats=[] if cfg.num_experts else None,
             moe_backend=moe_backend)
 
@@ -871,7 +907,7 @@ def test_programs_the_rule_leaves_alone_are_the_same_text(model, program):
         args = (params, kv, staged, i32(rows), i32(rows, 4))
         fn = decode
     else:
-        args = (params, kv, i32(rows, 32), i32(rows, 8), i32(rows))
+        args = (params, kv, i32(rows, 16), i32(rows, 8), i32(rows))
         fn = prefill
     texts = {
         b: jax.jit(fn, static_argnums=0).lower(b, *args).as_text()

@@ -929,7 +929,9 @@ class ModelRunner:
     def moe_experts_form(self, tokens: int) -> str:
         """The form a program of `tokens` tokens computes its expert layers
         in: "touched_kernel" | "all_experts" | "grouped" |
-        "grouped_kernel"."""
+        "grouped_kernel" — from the token count, the model's shapes (its
+        expert leaves' itemsize among them) and whether this engine's
+        programs may hold a Pallas kernel."""
         return llama.moe_experts_form(
             self.config.model, tokens, kernel=self._moe_backend != "xla")
 

@@ -876,7 +876,7 @@ def _mlp_block(cfg: ModelConfig, lp: dict, x: jax.Array, proj,
         return res + mlp_out
 
 
-# The four forms of `_moe_mlp`'s expert computation and the two constants
+# The four forms of `_moe_mlp`'s expert computation and the three constants
 # that choose between them, measured on a v5e chip (benchmarks/sweep_moe.py;
 # ms a layer, the expert stacks as served: every layer's leaves stacked, the
 # layers run in turn).
@@ -924,7 +924,8 @@ def _mlp_block(cfg: ModelConfig, lp: dict, x: jax.Array, proj,
 #     2048        10.44              5.54           0.49 | 1.05
 #     8192     (3 GiB of temps)     18.16           0.49 | 4.19
 # XLA's grouped kernel pays for groups of two rows; from ~1k tokens the
-# einsum's E/k-fold FLOPs lose: `_ALL_EXPERTS_MAX_TOKENS`.
+# einsum's E/k-fold FLOPs lose to it: `_ALL_EXPERTS_MAX_TOKENS`, the limit
+# of a program that may hold no Pallas kernel.
 #
 # Many tokens, the fused grouped-expert kernel (`--prefill`, PERF.md §6 PR
 # 38): the sorted pairs through `moe_prefill_experts` against three
@@ -949,14 +950,58 @@ def _mlp_block(cfg: ModelConfig, lp: dict, x: jax.Array, proj,
 # / 34 / 58 experts touched at 2,048 tokens — reads 2.044 / 3.357 / 2.413
 # against ragged_dot's 3.912 / 4.771 / 5.174.) The kernel wins 1.6-2.6 x at
 # every size, int8 leaves included (widened a tile at a time in VMEM), so the
-# "grouped_kernel" form takes every leaf dtype; at 512 tokens it also beats
-# the einsum 2.0-2.6 x, which `_ALL_EXPERTS_MAX_TOKENS` does not use yet
-# (PERF.md §7). The "alone" columns include ~0.2-0.4 ms of the sweep's own
-# renormalisation of the carried rows; what the kernel leaves on the table
-# is the one-step lookahead of its pipeline (an expert's copy overlaps only
-# the last visit of the expert before it) and, from ~4k tokens, MXU passes
-# of 128 rows (78% of the peak; 256 rows reach 94%).
+# "grouped_kernel" form takes every leaf dtype. The "alone" columns include
+# ~0.2-0.4 ms of the sweep's own renormalisation of the carried rows; what
+# the kernel leaves on the table is the one-step lookahead of its pipeline
+# (an expert's copy overlaps only the last visit of the expert before it)
+# and, from ~4k tokens, MXU passes of 128 rows (78% of the peak; 256 rows
+# reach 94%).
+#
+# Where the einsum ends for a program that may hold the kernel (the same
+# sweep at `--tokens 64,128,192,256,384,512`, PERF.md §6 PR 42; balanced
+# routing, the whole form with its sort and combine, ms a layer):
+#   experts (stack)          tokens   kernel   einsum   ragged_dot
+#   64 x 2048 x 1024 int8        64    0.617    0.554     2.631
+#    (OLMoE, 16 layers)         128    0.666    0.901     2.723
+#                               192    0.703    0.930     2.756
+#                               256    0.762    1.393     2.879
+#                               384    0.853    1.974     3.041
+#                               512    0.946    2.502     3.208
+#   128 x 2048 x 768 bf16        64    1.629    1.635     2.715
+#    (Kanana-2, 7 layers)       128    1.711    1.945     3.248
+#                               192    1.750    1.698     2.944
+#                               256    1.767    2.256     4.520
+#                               384    1.843    3.177     3.449
+#                               512    1.915    3.973     4.723
+#   64 held of 256 x 3072        64    1.547    1.741     1.916
+#    x 1024 bf16 (Laguna,       128    1.722    2.199     2.549
+#    8 layers; 58 / 63 of       192    1.777    1.856     2.380
+#    the held touched at        256    1.815    2.296     4.032
+#    64 / 128 tokens)           384    1.886    3.032     2.851
+#                               512    2.013    3.982     4.156
+# The einsum does 2 N E P flops over E P itemsize bytes (P an expert's
+# parameters): 2 N / itemsize flops a byte whatever E and k are, against the
+# chip's 240 (197 TF/s over 819 GB/s), so on paper it leaves its bytes' bound
+# at 120 tokens a weight byte. Measured it leaves earlier: its matmuls reach
+# ~79% of the MXU peak (3.973 ms for 3.14 ms of flops at 512 bf16 tokens) and
+# near the crossover its reads and its products do not overlap (int8 leaves:
+# the widening too), while the kernel stays within 10% of the touched bytes'
+# time from 64 to 256 tokens. int8 leaves cross between 64 tokens (einsum by
+# 10%) and 128 (kernel by 26%); bf16 leaves tie at 192 (-3% / +4%) and the
+# kernel wins by 21-22% at 256: 0.8 x 120 = 96 tokens a weight byte,
+# `_ALL_EXPERTS_KERNEL_TOKENS_PER_WEIGHT_BYTE` (96 tokens for int8 leaves,
+# 192 for bf16; float32 leaves, which no chip serves, keep 384). Under it
+# every form reads the same bytes and the einsum needs no sort; what a kernel
+# saves there is untouched experts, `_TOUCHED_KERNEL_MAX_SHARE`'s business
+# (the Laguna rows at 64 and 128 tokens). One reading stands off the trend:
+# at exactly 128 tokens XLA's einsum is 19-38% over its own neighbours in all
+# three stacks (a tiling choice), so a bf16 program of 128 tokens would do
+# 12-22% better on the kernel; no cell runs one (PERF.md §7). `ragged_dot`
+# loses to the einsum at every one of these sizes but Laguna's 384 tokens
+# (2.851 against 3.032), so a program that may hold no kernel (any mesh, the
+# CPU, `moe_backend` "xla") keeps `_ALL_EXPERTS_MAX_TOKENS`.
 _ALL_EXPERTS_MAX_TOKENS = 512
+_ALL_EXPERTS_KERNEL_TOKENS_PER_WEIGHT_BYTE = 96
 _TOUCHED_KERNEL_MAX_SHARE = 0.8
 
 
@@ -1122,12 +1167,27 @@ def expected_touched_share(cfg: ModelConfig, n: int) -> float:
     return 1.0 - (1.0 - cfg.num_experts_per_tok / cfg.num_experts) ** n
 
 
+def all_experts_max_tokens(cfg: ModelConfig, *, kernel: bool) -> int:
+    """The most tokens a program runs through the all-experts einsum. The
+    einsum does 2 N / itemsize flops a weight byte whatever E and k are, so
+    it leaves its bytes' bound at a token count that grows with the expert
+    leaves' itemsize (int8 leaves are read as int8). Past it the fused
+    kernel's N k pairs beat the einsum's N E where the program may hold one
+    (`kernel`); `ragged_dot` loses to the einsum up to
+    `_ALL_EXPERTS_MAX_TOKENS` (the tables above)."""
+    if not kernel:
+        return _ALL_EXPERTS_MAX_TOKENS
+    itemsize = 1 if cfg.quantization else jnp.dtype(cfg.dtype).itemsize
+    return min(_ALL_EXPERTS_MAX_TOKENS,
+               _ALL_EXPERTS_KERNEL_TOKENS_PER_WEIGHT_BYTE * itemsize)
+
+
 def moe_experts_form(cfg: ModelConfig, n: int, *, kernel: bool) -> str:
     """How a program of `n` tokens computes an expert layer (_moe_mlp), from
     static shapes alone: "touched_kernel" | "all_experts" | "grouped" |
     "grouped_kernel". `kernel`: the program may hold a Pallas kernel
     (`moe_backend` is not "xla": one TPU device, no mesh)."""
-    if n > _ALL_EXPERTS_MAX_TOKENS:
+    if n > all_experts_max_tokens(cfg, kernel=kernel):
         return "grouped_kernel" if kernel else "grouped"
     if kernel and expected_touched_share(cfg, n) < _TOUCHED_KERNEL_MAX_SHARE:
         return "touched_kernel"
@@ -1159,8 +1219,11 @@ def _moe_mlp(cfg: ModelConfig, mp: dict, x: jax.Array,
 
     The expert computation is ONE family in four forms, chosen by
     `moe_experts_form` from the static token count N, the top-k, the
-    published expert count and whether the program may hold a Pallas
-    kernel (tables above; no flag, no model name):
+    published expert count, the expert leaves' itemsize and whether the
+    program may hold a Pallas kernel (tables above; no flag, no model
+    name). Where it may, "few" tokens are those under which the einsum is
+    still bound by the experts' bytes, `all_experts_max_tokens`: 96 for
+    int8 leaves, 192 for bf16; where it may not, 512:
 
     - "touched_kernel" — few tokens of a many-expert model, where the N*k
       pairs cannot cover the experts (the expected touched share under a
@@ -1178,8 +1241,8 @@ def _moe_mlp(cfg: ModelConfig, mp: dict, x: jax.Array,
       model touches 88% of its experts anyway): every expert evaluates
       every token and the routing weights zero the rest, bound by the
       expert bytes (`_experts_all`).
-    - "grouped" — more than `_ALL_EXPERTS_MAX_TOKENS` tokens (a prefill
-      program): the work follows the routed pairs (`_experts_grouped`) —
+    - "grouped" — more tokens than that (a prefill program): the work
+      follows the routed pairs (`_experts_grouped`) —
       B*T*k rows sorted by expert whatever the routing, an expert without
       rows costs nothing; the all-experts form would spend
       num_experts/top_k times the FLOPs and hold three (B*T, E, inter)
@@ -1188,7 +1251,9 @@ def _moe_mlp(cfg: ModelConfig, mp: dict, x: jax.Array,
       the CPU and under `backend` "xla" runs (no shard_map wrapper), and
       the kernel's oracle in the tests.
     - "grouped_kernel" — the same sorted pairs where the program may hold a
-      Pallas kernel: ONE fused kernel a layer
+      Pallas kernel, from the token count at which the einsum's flops
+      overtake its bytes (a 512-token chunk of 64 int8 experts: 8 x the
+      routed flops through the einsum): ONE fused kernel a layer
       (ops/moe_experts_pallas.py:moe_prefill_experts) walks the held
       experts in ascending order, brings each expert's gate, up and down
       matrices across HBM once a layer and chunk and runs its rows under
