@@ -4,15 +4,20 @@ Measures one layer's decode attention per (block_size, context, batch)
 config on the real chip — the evidence behind ModelRunner's
 `_resolve_attention_backend` policy (VERDICT r2 #7: the shipped default
 must be the measured winner at the shipped config) — and with --prefill one
-layer's chunked-prefill attention at the benchmark cells' head shapes, the
-evidence behind `resolve_auto_prefill_backend`.
+layer's chunked-prefill attention: first the long-document cells' layers (a
+2,048-token chunk behind 0-14k resident tokens, kernel only: ms and the
+share of the chip's bf16 peak), then the chat cells' head shapes against
+XLA, the evidence behind `resolve_auto_prefill_backend`. docs/22 has both
+tables.
 
-    python benchmarks/sweep_attention.py [--quick] [--fp8] [--prefill]
+    python benchmarks/sweep_attention.py [--quick] [--fp8] [--prefill
+        [--longdoc-only]]
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -51,6 +56,13 @@ def time_fn(fn, q, *args) -> float:
         run(q, *args).block_until_ready()
         best = min(best, time.perf_counter() - t0)
     return best / LOOP_ITERS * 1000.0  # ms
+
+
+def moved(qq, tb):
+    """The table as a function of time_fn's loop carry (the same numbers):
+    neither backend's reads of the pool can be hoisted out of the loop, as
+    no engine step can hoist them."""
+    return tb + (qq[0, 0, 0, 0] > 1e30).astype(tb.dtype)
 
 
 def bench_config(
@@ -142,12 +154,6 @@ def bench_prefill_config(
     ctx_lens = jnp.full((batch,), ctx, jnp.int32)
     start = jnp.full((batch,), max(ctx - chunk, 0), jnp.int32)
 
-    def moved(qq, tb):
-        """The table as a function of the loop's carry (the same numbers):
-        neither backend's reads of the pool can be hoisted out of time_fn's
-        loop, as no engine step can hoist them."""
-        return tb + (qq[0, 0, 0, 0] > 1e30).astype(tb.dtype)
-
     pallas_fn = jax.jit(
         lambda qq, pool, tb, *a: paged_prefill_attention(
             qq, pool, moved(qq, tb), *a, scale=scale)
@@ -169,6 +175,59 @@ def bench_prefill_config(
         "pallas_ms": round(pallas_ms, 3), "xla_ms": round(xla_ms, 3),
         "winner": "pallas" if pallas_ms < xla_ms else "xla",
         "ratio": round(pallas_ms / xla_ms, 2),
+    }
+
+
+# a v5e chip's bf16 peak (benchmarks/chip/peaks.json names the source)
+V5E_BF16_FLOPS = 197e12
+# the long-document cells' layers: (heads, kv heads, head_dim, window) —
+# Laguna-S-2.1's full and window layers, Solar-Open2's GQA layers
+LONGDOC_LAYERS = [(48, 8, 128, 0), (72, 8, 128, 512), (64, 8, 128, 0)]
+LONGDOC_RESIDENT = [0, 2048, 6144, 14336]
+
+
+def bench_longdoc_prefill(
+    nh: int, kvh: int, d: int, window: int, resident: int,
+    chunk: int = 2048, table_pages: int = 1024, block_size: int = 32,
+    dtype=jnp.bfloat16,
+) -> dict:
+    """One layer's prefill attention of a long-document chunk: one row, a
+    `chunk`-token chunk behind `resident` tokens in a `table_pages`-page
+    table, a window layer where `window` > 0 — ms a layer, and the flops of
+    the (query, key) pairs the chunk covers (4 x head_dim a pair and head,
+    as benchmarks/chip/costs_swa.py counts them) as a share of the chip's
+    bf16 peak. No XLA column: its (heads, chunk, table) scores do not fit."""
+    from vllm_production_stack_tpu.ops.paged_attention_pallas import (
+        paged_prefill_attention, paged_window_prefill_attention,
+    )
+
+    rng = np.random.RandomState(0)
+    num_blocks = table_pages + 2
+    scale = d ** -0.5
+    q = jnp.asarray(rng.randn(1, chunk, nh, d), dtype)
+    kv = jnp.asarray(rng.randn(2, num_blocks, block_size, kvh, d), dtype)
+    tables = jnp.asarray(
+        rng.permutation(np.arange(1, num_blocks))[None, :table_pages],
+        jnp.int32)
+    ctx_lens = jnp.full((1,), resident + chunk, jnp.int32)
+    start = jnp.full((1,), resident, jnp.int32)
+    kernel = (
+        functools.partial(paged_window_prefill_attention, window=window)
+        if window else paged_prefill_attention)
+    fn = jax.jit(
+        lambda qq, pool, tb, *a: kernel(
+            qq, pool, moved(qq, tb), *a, scale=scale)
+    )
+    ms = time_fn(fn, q, kv, tables, ctx_lens, start)
+    seen = np.arange(resident, resident + chunk, dtype=np.int64) + 1
+    pairs = int((np.minimum(seen, window) if window else seen).sum())
+    return {
+        "phase": "prefill_longdoc", "heads": f"{nh}/{kvh}x{d}",
+        "window": window, "resident": resident, "chunk": chunk,
+        "table_pages": table_pages, "pairs": pairs,
+        "pallas_ms": round(ms, 3),
+        "peak_share_pct": round(
+            100.0 * 4.0 * d * nh * pairs / V5E_BF16_FLOPS / (ms * 1e-3), 1),
     }
 
 
@@ -195,11 +254,20 @@ def main() -> None:
     p.add_argument("--prefill", action="store_true",
                    help="sweep chunked-prefill attention instead of decode "
                         "(evidence for resolve_auto_prefill_backend)")
+    p.add_argument("--longdoc-only", action="store_true",
+                   help="with --prefill: stop after the long-document rows")
     args = p.parse_args()
     # llama-1b decode head shape
     nh, kvh, d = 32, 8, 64
     kvd = jnp.dtype(ml_dtypes.float8_e4m3fn) if args.fp8 else None
     if args.prefill:
+        if kvd is None:  # the long-document cells' pools are bf16
+            for layer in LONGDOC_LAYERS[:1] if args.quick else LONGDOC_LAYERS:
+                for resident in LONGDOC_RESIDENT:
+                    print(json.dumps(
+                        bench_longdoc_prefill(*layer, resident)), flush=True)
+        if args.longdoc_only:
+            return
         for heads in PREFILL_HEADS[:1] if args.quick else PREFILL_HEADS:
             for batch, ctx, pages in PREFILL_ROWS:
                 print(json.dumps(bench_prefill_config(

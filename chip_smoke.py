@@ -7,7 +7,7 @@ quick start uses, at the full width and depth of one model the repo supports
 
   kernels   each Pallas kernel that compiles for v5e, run NON-interpreted
             against ops/attention.py's XLA path: decode at the qwen3-8b head
-            shape, prefill at the three benchmark cells' head shapes
+            shape, prefill at the chat and long-document cells' head shapes
   serve     `python -m ...engine.server` and `python -m ...router.app` as two
             OS processes; through the router: one chat completion, one
             streamed (SSE, every chunk parsed), four concurrent completions,
@@ -742,6 +742,7 @@ def child_kernels(seed: int) -> int:
     )
     from vllm_production_stack_tpu.ops.paged_attention_pallas import (
         paged_decode_attention, paged_prefill_attention,
+        paged_window_prefill_attention,
     )
     from vllm_production_stack_tpu.utils.compile_cache import (
         configure_compile_cache,
@@ -802,18 +803,30 @@ def child_kernels(seed: int) -> int:
     ctx = jnp.asarray([1536, 512, 1300, 0], jnp.int32)
     positions = start[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
     real = np.asarray(positions < ctx[:, None])  # the chunk's own tokens
-    for heads, kv_heads in ((32, 8), (28, 4), (16, 16)):
+    # ... and at the long-document cells': Laguna-S-2.1's full (48/8) and
+    # window (72/8, window 512) layers, Solar-Open2's GQA layers (64/8)
+    for heads, kv_heads, window in (
+            (32, 8, 0), (28, 4, 0), (16, 16, 0),
+            (48, 8, 0), (64, 8, 0), (72, 8, 512)):
         q, tb = arr(4, t, heads, d, gain=3.0), tables(4)
         pool = arr(2, num_blocks, bs, kv_heads, d)
-        got = paged_prefill_attention(q, pool, tb, ctx, start, scale=scale)
+        if window:
+            got = paged_window_prefill_attention(
+                q, pool, tb, ctx, start, scale=scale, window=window)
+        else:
+            got = paged_prefill_attention(
+                q, pool, tb, ctx, start, scale=scale)
         want = paged_attention_xla(
-            q, pool, tb, causal_page_mask(positions, ctx, nb * bs),
+            q, pool, tb,
+            causal_page_mask(positions, ctx, nb * bs, window=window),
             scale=scale,
         )
         got = np.asarray(got, np.float32)
         check = diff(got[real], np.asarray(want, np.float32)[real])
         check["ok"] = check["ok"] and bool(np.all(np.isfinite(got)))
-        report["kernels"][f"prefill_{heads}_{kv_heads}"] = check
+        report["kernels"][
+            f"prefill_{heads}_{kv_heads}" + (f"_w{window}" if window else "")
+        ] = check
 
     report["ok"] = all(k["ok"] for k in report["kernels"].values())
     emit(report)

@@ -157,6 +157,37 @@ def test_prefill_kernel_compiles_for_v5e(topo, model, block, kv_dtype, rows,
     ).lower(*args).compile())
 
 
+@pytest.mark.parametrize("nh,kvh,window,tiling,items", [
+    (48, 8, 0, (128, 8), 2),     # Laguna-S-2.1's full layers
+    (72, 8, 512, (128, 8), 3),   # ... and its window layers
+    (64, 8, 0, (128, 8), 2),     # Solar-Open2's GQA layers
+])
+def test_long_document_prefill_kernel_compiles_for_v5e(topo, nh, kvh, window,
+                                                       tiling, items):
+    """The long-document cells' chunk: one row of 2,048 tokens (sixteen
+    128-query tiles, a kv group's heads folded in two or three items of
+    whole heads) under a 1,024-page table of 32-token pages."""
+    d, bs, nb, t = 128, 32, 1024, 2048
+    sds = _one_chip(topo)
+    args = (
+        sds((1, t, nh, d), jnp.bfloat16, None),
+        sds((2, 1329, bs, kvh, d), jnp.bfloat16, None),
+        sds((1, nb), jnp.int32, None), sds((1,), jnp.int32, None),
+        sds((1,), jnp.int32, None),
+    )
+    assert pk._prefill_tiling(t, nb, bs, nh, kvh, d, 2, 2) == tiling
+    group = nh // kvh * tiling[0]
+    assert group // pk._prefill_sub_tile(tiling[0], nh // kvh) == items
+    fn = (
+        (lambda *a: pk.paged_window_prefill_attention(
+            *a, scale=d ** -0.5, window=window)) if window
+        else (lambda *a: pk.paged_prefill_attention(*a, scale=d ** -0.5)))
+    compiled = jax.jit(fn).lower(*args).compile()
+    _assert_kernel(compiled)
+    assert ("paged_window_prefill_attention" if window
+            else "paged_prefill_attention") in compiled.as_text()
+
+
 def test_sharded_kernels_compile_for_four_v5e_chips(topo):
     """--tensor-parallel-size 4 with explicit pallas backends: shard_map
     places one kernel per chip over its own kvh/tp heads."""
@@ -224,13 +255,18 @@ def test_decode_kernel_form_follows_the_pool_tiles(kvh, d, itemsize, want):
 
 
 @pytest.mark.parametrize("t,nb,bs,nh,kvh,d,kv_itemsize,want", [
-    (512, 64, 32, 32, 8, 128, 2, (128, 8)),   # qwen3-8b: cell 1
-    (512, 64, 32, 28, 4, 128, 2, (128, 8)),   # qwen2-7b: cell 2
+    (512, 64, 32, 32, 8, 128, 2, (256, 8)),   # qwen3-8b: cell 1
+    (512, 64, 32, 28, 4, 128, 2, (256, 8)),   # qwen2-7b: cell 2
     (512, 64, 32, 16, 16, 128, 2, (256, 8)),  # olmoe-1b-7b: the MoE cell
-    (512, 128, 32, 32, 8, 128, 2, (128, 8)),  # the table's width: no part
+    (512, 128, 32, 32, 8, 128, 2, (256, 8)),  # the table's width: no part
     (1024, 64, 32, 8, 2, 128, 2, (256, 8)),   # a tp=4 shard: the full tile
-    (512, 256, 16, 32, 8, 128, 1, (128, 16)),  # fp8, 16-token pages
-    (512, 64, 32, 64, 16, 128, 2, (64, 8)),
+    (512, 256, 16, 32, 8, 128, 1, (256, 16)),  # fp8, 16-token pages
+    (512, 64, 32, 64, 16, 128, 2, (128, 8)),
+    # the long-document cells' 2,048-token chunk under a 1,024-page table:
+    # Laguna-S-2.1's full and window layers, Solar-Open2's GQA layers
+    (2048, 1024, 32, 48, 8, 128, 2, (128, 8)),
+    (2048, 1024, 32, 72, 8, 128, 2, (128, 8)),
+    (2048, 1024, 32, 64, 8, 128, 2, (128, 8)),
     (64, 64, 32, 32, 8, 128, 2, (64, 8)),     # never longer than the bucket
     (512, 2, 32, 32, 8, 128, 2, (256, 2)),    # ... nor wider than the table
 ])

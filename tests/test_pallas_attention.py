@@ -7,6 +7,8 @@ host-side, so these tests pin the math (flash accumulation, GQA grouping,
 history masking, staged-window masking) without a chip.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -422,6 +424,11 @@ PREFILL_CASES = {
     "qwen2-7b-group7-bf16": (64, 32, 4, 7, 128, 128,
                              [(0, 128), (45, 173), (0, 0), (300, 428)],
                              jnp.bfloat16),
+    # ... and in the 256-query tile its 512-token chunks run: a group of
+    # seven heads in two items of three and a half
+    "qwen2-7b-group7-tile256-bf16": (64, 32, 4, 7, 128, 256,
+                                     [(45, 301), (0, 128), (0, 0)],
+                                     jnp.bfloat16),
     "olmoe-mha-bf16": (64, 32, 16, 1, 128, 128,
                        [(0, 128), (45, 173), (0, 0), (300, 428)],
                        jnp.bfloat16),
@@ -445,6 +452,14 @@ PREFILL_CASES = {
                np.float32),
     "table-narrower-than-a-block": (3, 32, 2, 2, 64, 32, [(0, 20), (60, 92)],
                                     np.float32),
+    # the long-document cells' head shapes (Laguna-S-2.1's full layers,
+    # Solar-Open2's GQA layers), reduced in length only: a chunk of four
+    # 128-query tiles behind several 256-key blocks that starts inside a
+    # page, a short second row (three of its tiles skipped), a padding row
+    "laguna-full-48-8-bf16": (64, 32, 8, 6, 128, 512,
+                              [(300, 812), (0, 100), (0, 0)], jnp.bfloat16),
+    "solar-gqa-64-8-bf16": (64, 32, 8, 8, 128, 512,
+                            [(557, 1069), (45, 173), (0, 0)], jnp.bfloat16),
 }
 
 
@@ -460,6 +475,83 @@ def test_prefill_walk_kernel_matches_xla_reference(name):
     real = _real_queries(case)
     np.testing.assert_allclose(out[real], ref[real], rtol=tol, atol=tol)
     assert np.all(np.isfinite(out))  # padding too: it flows on to the MLP
+
+
+@pytest.mark.parametrize("name,qpk,window,block,rows", [
+    # Laguna-S-2.1's window layers: 72 heads on 8 kv heads, window 512 — a
+    # 128-query tile's walk of three 256-key blocks crosses the window's
+    # edge of its first query, that of its last, and the diagonal; pages
+    # behind the chunk's window were given back (the null page would show)
+    ("laguna-window-72-8", 9, 512, 0, [(700, 956), (40, 140), (0, 0)]),
+    # generation by diffusion over blocks of 4 at Solar-Open2's head shape:
+    # a query sees the keys up to the end of its block
+    ("block-4-64-8", 8, 0, 4, [(300, 556), (0, 64), (0, 0)]),
+])
+def test_prefill_kernel_masks_at_the_long_document_head_shapes(
+        name, qpk, window, block, rows):
+    """The window and the block mask of the prefill kernel against the
+    masked XLA attention, bf16 at the cells' head shapes (1 bf16 ulp)."""
+    from vllm_production_stack_tpu.ops import paged_attention_pallas as pk
+    from vllm_production_stack_tpu.ops.attention import (
+        causal_page_mask, paged_attention_xla,
+    )
+
+    nb, bs, kvh, d, t = 64, 32, 8, 128, 256
+    case = _prefill_case(rows, nb, bs, kvh, qpk, d, t, seed=11,
+                         dtype=jnp.bfloat16)
+    q, kv, tables, ctx, start = case
+    if window:
+        # pages no query of the chunk sees any more: given back
+        kv = np.array(kv)
+        kv[:, 0] = 100.0
+        for r, (s0, _) in enumerate(rows):
+            tables[r, :max(0, s0 - window + 1) // bs] = 0
+    kernel = (
+        functools.partial(pk.paged_window_prefill_attention, window=window)
+        if window else
+        functools.partial(pk.paged_prefill_attention, block=block))
+    out = np.asarray(kernel(
+        jnp.asarray(q), jnp.asarray(kv), jnp.asarray(tables),
+        jnp.asarray(ctx), jnp.asarray(start), scale=d ** -0.5,
+        interpret=True).astype(jnp.float32))
+    pos = start[:, None] + np.arange(t)[None]
+    mask = causal_page_mask(
+        jnp.asarray(pos), jnp.asarray(ctx), nb * bs,
+        **({"window": window} if window else {"block": block}))
+    ref = np.asarray(paged_attention_xla(
+        jnp.asarray(q), jnp.asarray(kv), jnp.asarray(tables), mask,
+        scale=d ** -0.5).astype(jnp.float32))
+    real = _real_queries(case)
+    np.testing.assert_allclose(out[real], ref[real], rtol=2e-2, atol=2e-2)
+    assert np.all(np.isfinite(out))
+
+
+@pytest.mark.parametrize("tt,qpk,sub", [
+    (128, 6, 384),   # Laguna-S-2.1's full layers: two items of three heads
+    (128, 9, 384),   # ... its window layers: three items of three heads
+    (128, 8, 512),   # Solar-Open2: two items of four heads
+    (256, 4, 512),   # qwen3-8b: two items of two heads
+    (256, 7, 896),   # qwen2-7b: two items of three and a half heads
+    (256, 1, 128),   # olmoe-1b-7b: one head, its tokens in two items
+    (128, 1, 128),   # one head of one sub-tile: one item
+    (128, 7, 896),   # a prime group that only seven items divide: whole
+    (64, 4, 128),    # a short bucket: whole heads side by side
+    (16, 6, 48),
+    (4, 2, 4),       # the toy shapes of this file
+    (8, 1, 8),
+])
+def test_prefill_items_are_the_fewest_equal_cuts_of_a_group(tt, qpk, sub):
+    """Two items a kv group where whole sub-tiles allow it, never more than
+    PREFILL_MAX_ITEMS: every item is traced and lowered again at every
+    engine start."""
+    from vllm_production_stack_tpu.ops import paged_attention_pallas as pk
+
+    assert pk._prefill_sub_tile(tt, qpk) == sub
+    assert (qpk * tt) % sub == 0
+    assert qpk * tt // sub <= pk.PREFILL_MAX_ITEMS
+    # an item is whole sub-tiles of a head's tokens, or whole short heads
+    assert sub % (pk.PREFILL_SUB_TILE if tt % pk.PREFILL_SUB_TILE == 0
+                  else tt) == 0
 
 
 def test_prefill_dead_table_slots_and_width_do_not_reach_a_row():

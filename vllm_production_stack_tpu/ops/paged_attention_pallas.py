@@ -12,8 +12,8 @@ across blocks, and the window's staged K/V (see
 models/llama.py:decode_window_step) are folded in last, so the kernel
 computes the complete decode attention output in one op. The prefill
 kernel walks the same blocks under one row's query tile, every head of it
-resident: one dot a kv group, not one matrix with the other heads masked —
-prefill is bound by arithmetic, not by bytes.
+resident: dots a kv group (its heads' rows in a few items, scores keys by
+queries), not one matrix with the other heads masked: arithmetic, not bytes.
 
 Reference parity: the reference stack delegates this op to vLLM's CUDA
 paged-attention kernels inside its engine images (external to its repo);
@@ -352,14 +352,36 @@ def _decode_grid_kernel(
 
 # Prefill: a grid step is one row's query tile of up to PREFILL_Q_TILE
 # tokens, every head of it resident; the row's pages are visited
-# PREFILL_BLOCK_TOKENS at a time as in decode. The tile is halved until what
-# a grid step holds (_prefill_vmem) fits PREFILL_VMEM_BUDGET of the
-# PREFILL_VMEM_LIMIT the kernel asks the compiler for (a v5e core has
-# 128 MiB of VMEM; 16 MiB is only the default scope).
+# PREFILL_BLOCK_TOKENS at a time as in decode, and a block is folded into
+# the tile a kv group at a time, the group's query rows in at most
+# PREFILL_MAX_ITEMS equal ITEMS of whole PREFILL_SUB_TILEs of a head's
+# tokens (_prefill_sub_tile).
+# The tile is halved until what a grid step holds (_prefill_vmem) fits
+# PREFILL_VMEM_BUDGET of the PREFILL_VMEM_LIMIT the kernel asks the compiler
+# for (a v5e core has 128 MiB of VMEM; 16 MiB is only the default scope).
 PREFILL_Q_TILE = 256
 PREFILL_BLOCK_TOKENS = 256
+PREFILL_SUB_TILE = 128
+PREFILL_MAX_ITEMS = 4
 PREFILL_VMEM_BUDGET = 24 * 2**20
 PREFILL_VMEM_LIMIT = 32 * 2**20
+
+
+def _prefill_sub_tile(tt: int, qpk: int) -> int:
+    """Query rows one item holds, out of a kv group's qpk * tt rows ordered
+    (head, token). An item is whole UNITS — PREFILL_SUB_TILEs of a head's
+    tokens, or whole heads of a tile shorter than that — and a group is cut
+    into the FEWEST equal items, but two where it can (one item's softmax
+    runs under the next one's dot; every further item is traced and lowered
+    again at every engine start): seven heads of a 256-token tile are two
+    items of three and a half heads; a group that only many small items
+    would divide stays whole."""
+    unit = PREFILL_SUB_TILE if tt % PREFILL_SUB_TILE == 0 else tt
+    units = qpk * tt // unit
+    for items in range(2, PREFILL_MAX_ITEMS + 1):
+        if units % items == 0:
+            return qpk * tt // items
+    return qpk * tt
 
 
 def _prefill_vmem(
@@ -368,12 +390,13 @@ def _prefill_vmem(
 ) -> int:
     """Bytes of VMEM one prefill grid step holds: the double-buffered query
     and output tiles and the queries regrouped by kv group, the f32
-    accumulator with its running max and sum (one column each, lane-padded
-    to 128), two K/V slots at the pool's width, and one kv group's f32
-    scores with their exponentials in both widths."""
+    accumulator with its running max and sum (a row an item each,
+    sublane-padded to 8), two K/V slots at the pool's width, and one kv
+    group's f32 scores (every item's dot is issued before the first
+    softmax) with their exponentials in both widths."""
     lanes = -(-d // 128) * 128
     tiles = 5 * tt * nh * lanes * q_itemsize
-    state = nh * tt * (lanes + 2 * 128) * 4
+    state = nh * tt * (lanes + 2 * 8) * 4
     slots = 2 * 2 * pages * bs * kvh * lanes * kv_itemsize
     scores = (nh // kvh) * tt * pages * bs * (4 + 4 + q_itemsize)
     return tiles + state + slots + scores
@@ -437,10 +460,11 @@ def _prefill_walk_kernel(
     buf,  # (2, 2, P, bs, kvh, D) — two slots of one block's K and V pages
     sems,  # DMA semaphore per slot
     state,  # SMEM (2,) int32: slot of the next block; is it in flight
-    q_rows,  # (kvh, q_per_kv * Tt, D) — the tile's queries by kv group
-    m_ref,  # (nh * Tt, 1) f32 running max, rows ordered (head, token)
-    l_ref,  # (nh * Tt, 1) f32 running denominator
-    acc_ref,  # (nh * Tt, D) f32 running numerator
+    q_rows,  # (kvh, q_per_kv * Tt, D) — the tile's queries by kv group,
+    # and in `_finalize` its outputs
+    m_ref,  # (items, 1, Q) f32 running max, an item = Q rows of a kv group
+    l_ref,  # (items, 1, Q) f32 running denominator
+    acc_ref,  # (items, D, Q) f32 running numerator, TRANSPOSED
     *,
     scale: float,
     window: int = 0,
@@ -452,15 +476,34 @@ def _prefill_walk_kernel(
     tile past the row's tokens, whatever the table's width — each block
     copied HBM->VMEM by P async copies while the block before it is
     computed, and the tile's last block starts the first block of the next
-    tile that has any (decode's hand-over). Per block and kv group: ONE dot
-    of the group's heads' queries (q_per_kv * Tt, D) against the group's
-    keys (D, P * bs), operands as they lie in pool and activations, f32
-    scores, statistics and accumulator. Causality comes from iotas alone and
-    only where a block straddles ctx or the tile's diagonal: the scheduler
-    feeds chunks with CONTIGUOUS positions (scheduler.py: range(start,
-    start + len)), so a query's position is chunk_start + its index. Chunked
-    prefill needs no special casing — resident pages hold earlier chunks AND
-    this chunk's freshly-written KV (forward writes before attending).
+    tile that has any (decode's hand-over).
+
+    A block is folded into the tile one kv group at a time, the group's
+    qpk * Tt query rows in ITEMS of Q rows (_prefill_sub_tile), with the
+    scores TRANSPOSED: keys (P * bs, D) against an item's queries (Q, D)
+    give (keys, queries), so the running max and sum of a query reduce over
+    SUBLANES — element-wise maxima and sums of vregs, one rotate tree an
+    item — and live as one (1, Q) row an item that broadcasts over sublanes;
+    the second dot, V^T (D, keys) against the exponentials (keys, Q), leaves
+    the accumulator transposed (D, Q), turned back once a tile in
+    `_finalize`. (Scores as (queries, keys), which this kernel had, keep
+    the statistics as (rows, 1) columns: one lane of 128 in use, a lane
+    reduction and a lane broadcast each a block, and 192 vregs of scores a
+    group that every pass spilled — docs/22 has both bundle counts.) A
+    group's items sit in ONE basic block, every item's first dot issued
+    before the first softmax: the MXU takes its operations in program
+    order, so item j + 1's scores stream while item j's exponentials are
+    computed, and the second dots follow as their operands get ready.
+    Operands as they lie in pool and activations, f32 scores, statistics
+    and accumulator.
+
+    The mask comes from iotas alone and is applied to every block, without
+    a branch (two vector operations a vreg of scores, under the MXU's
+    time): the scheduler feeds chunks with CONTIGUOUS positions
+    (scheduler.py: range(start, start + len)), so a query's position is
+    chunk_start + its index. Chunked prefill needs no special casing —
+    resident pages hold earlier chunks AND this chunk's freshly-written KV
+    (forward writes before attending).
 
     `window` > 0 (a window layer): a query at position p sees keys at
     positions > p - window, so a tile's walk starts at the page that holds
@@ -472,8 +515,7 @@ def _prefill_walk_kernel(
     `block` > 0 (generation by diffusion over blocks): a query sees the keys
     up to the END of its block of `block` positions. Chunks start and tiles
     are cut on multiples of `block`, so a tile still walks up to its own
-    last position and a page block still needs the mask only where it
-    straddles the tile's first query: only `last_key` changes."""
+    last position: only `last_keys` changes."""
     b, qt = pl.program_id(0), pl.program_id(1)
     rows, tiles = pl.num_programs(0), pl.num_programs(1)
     nb = tables_ref.shape[1]
@@ -481,11 +523,9 @@ def _prefill_walk_kernel(
     tt = q_ref.shape[1]
     qpk = q_ref.shape[2] // d // kvh
     span = pages * bs  # tokens per block
-    group = qpk * tt  # query rows a kv group's dots see
-
-    # (with `window` == 0 every expression below traces to what it was
-    # before the window existed, and `tile` is not read: a full layer's
-    # kernel is unchanged)
+    group = qpk * tt  # query rows of a kv group, ordered (head, token)
+    sub = m_ref.shape[2]  # query rows an item folds at a time
+    n_sub = group // sub
 
     def tile_page0(row, tile):
         """The page a window layer's tile starts its walk at."""
@@ -530,13 +570,16 @@ def _prefill_walk_kernel(
 
     ctx, first = ctx_ref[b], start_ref[b] + qt * tt
     page0 = tile_page0(b, qt) if window else 0
-    # the last key position each query row attends, rows ordered (head, token)
-    last_key = first + jax.lax.broadcasted_iota(
-        jnp.int32, (group, 1), 0) % tt
-    if block:
-        last_key = (last_key // block + 1) * block - 1
-    last_key = jnp.minimum(last_key, ctx - 1)
-    key_offsets = jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+    key_offsets = jax.lax.broadcasted_iota(jnp.int32, (span, sub), 0)
+
+    def last_keys(j):
+        """(1, Q): the last key position each query of a group's j-th
+        sub-tile attends — a query's lane."""
+        r = j * sub + jax.lax.broadcasted_iota(jnp.int32, (1, sub), 1)
+        last = first + r % tt
+        if block:
+            last = (last // block + 1) * block - 1
+        return jnp.minimum(last, ctx - 1)
 
     @pl.when(n_blocks > 0)
     def _regroup_queries():
@@ -583,42 +626,49 @@ def _prefill_walk_kernel(
         key0 = i * span
         if window:
             key0 = key0 + page0 * bs
-        straddles = (key0 + span > ctx) | (key0 + span - 1 > first)
-        if window:
-            # ... or holds a key the tile's LAST query no longer sees
-            straddles |= key0 <= first + tt - 1 - window
 
-        def attend(g, carry):
-            q = q_rows[g]  # (group, D)
-            keys = _kv_head_rows(buf, slot, 0, g, q.dtype)
-            values = _kv_head_rows(buf, slot, 1, g, q.dtype)
+        def fold(g, j, keys, values):
+            """Item j of kv group g against the block, a generator: the
+            scores' dot, then the softmax, then the values' dot."""
+            it = g * n_sub + j
             scores = jax.lax.dot_general(
-                q, keys, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # (group, span)
-            def masked(s):
-                seen = key_offsets <= last_key - key0
-                if window:
-                    # (a padded query sees what the row's last token sees:
-                    # its row of the output is never read, but stays finite)
-                    seen &= key_offsets > last_key - window - key0
-                return jnp.where(seen, s, NEG_INF)
-
-            scores = jax.lax.cond(straddles, masked, lambda s: s, scores)
-            r = pl.ds(pl.multiple_of(g * group, group), group)
+                keys, q_rows[g, j * sub:(j + 1) * sub, :],
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            ) * scale  # (span, Q)
+            yield
+            last = last_keys(j) - key0
+            seen = key_offsets <= last
+            if window:
+                # (a padded query sees what the row's last token sees: its
+                # row of the output is never read, but stays finite)
+                seen &= key_offsets > last - window
+            scores = jnp.where(seen, scores, NEG_INF)
             # block 0 starts the statistics: the scratch is never cleared
-            m_prev = jnp.where(i == 0, NEG_INF, m_ref[r])
-            l_prev = jnp.where(i == 0, 0.0, l_ref[r])
-            acc = jnp.where(i == 0, 0.0, acc_ref[r])
-            m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+            m_prev = jnp.where(i == 0, NEG_INF, m_ref[it])
+            l_prev = jnp.where(i == 0, 0.0, l_ref[it])
+            m_new = jnp.maximum(m_prev, jnp.max(scores, axis=0, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
             p = jnp.exp(scores - m_new)  # exactly 0 on masked keys
-            m_ref[r] = m_new
-            l_ref[r] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-            acc_ref[r] = acc * alpha + jax.lax.dot(
-                p.astype(values.dtype), values,
+            m_ref[it] = m_new
+            l_ref[it] = l_prev * alpha + jnp.sum(p, axis=0, keepdims=True)
+            p = p.astype(values.dtype)
+            yield
+            acc = jnp.where(i == 0, 0.0, acc_ref[it])
+            acc_ref[it] = acc * alpha + jax.lax.dot_general(
+                values, p, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )
+            )  # V^T P^T: (D, Q)
+
+        def attend(g, carry):
+            dtype = q_rows.dtype
+            keys = _kv_head_rows(buf, slot, 0, g, dtype)
+            values = _kv_head_rows(buf, slot, 1, g, dtype)
+            items = [fold(g, j, keys, values) for j in range(n_sub)]
+            for item in items:  # every item's scores: the MXU runs ahead
+                next(item)
+            for item in items:
+                next(item)
+                next(item, None)
             return carry
 
         # the kv groups in a loop, not unrolled, where a head's rows can be
@@ -634,11 +684,21 @@ def _prefill_walk_kernel(
 
     @pl.when(n_blocks > 0)
     def _finalize():
-        # every query of a live tile attends key 0 at least: l > 0
+        # every query of a live tile attends key 0 at least: l > 0. The
+        # accumulators are turned back into `q_rows` (the queries are done
+        # with) a kv group a loop step — one traced body, not one an item —
+        # and leave head by head, as the queries came
+        def turn_back(g, carry):
+            for j in range(n_sub):
+                it = g * n_sub + j
+                q_rows[g, j * sub:(j + 1) * sub, :] = (
+                    acc_ref[it] * (1.0 / l_ref[it])).T.astype(q_rows.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, kvh, turn_back, 0)
         for h in range(kvh * qpk):
-            r = pl.ds(h * tt, tt)
             out_ref[0, :, h * d:(h + 1) * d] = (
-                acc_ref[r] / l_ref[r]).astype(out_ref.dtype)
+                q_rows[h // qpk, pl.ds(h % qpk * tt, tt), :])
 
     @pl.when(n_blocks == 0)
     def _padding():
@@ -674,6 +734,7 @@ def _paged_prefill_attention(
         t, nb, bs, nh, kvh, d, kv.dtype.itemsize, q.dtype.itemsize
     )
     assert t % tt == 0, (t, tt)  # T is a power-of-two bucket
+    sub = _prefill_sub_tile(tt, nh // kvh)
     tile = pl.BlockSpec((1, tt, nh * d), lambda i, j, *_: (i, j, 0))
     # (a window layer's kernel is a kernel of its own, and named so: the
     # device trace tells the two apart)
@@ -691,9 +752,9 @@ def _paged_prefill_attention(
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SMEM((2,), jnp.int32),
                 pltpu.VMEM((kvh, nh // kvh * tt, d), q.dtype),
-                pltpu.VMEM((nh * tt, 1), jnp.float32),
-                pltpu.VMEM((nh * tt, 1), jnp.float32),
-                pltpu.VMEM((nh * tt, d), jnp.float32),
+                pltpu.VMEM((nh * tt // sub, 1, sub), jnp.float32),
+                pltpu.VMEM((nh * tt // sub, 1, sub), jnp.float32),
+                pltpu.VMEM((nh * tt // sub, d, sub), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, t, nh * d), q.dtype),
