@@ -15,11 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from vllm_production_stack_tpu.engine.config import (
-    CacheConfig, EngineConfig, SchedulerConfig,
-)
-from vllm_production_stack_tpu.engine.engine import LLMEngine
-from vllm_production_stack_tpu.engine.request import SamplingParams
+import engine_kit as ek
 from vllm_production_stack_tpu.models import llama
 from vllm_production_stack_tpu.models.loader import LagunaCheckpointView
 from vllm_production_stack_tpu.models.registry import resolve_model_config
@@ -49,31 +45,13 @@ def params(cfg):
     return llama.init_params(cfg, jax.random.PRNGKey(3))
 
 
-def _cut(cfg, n):
-    """The first n layers of a laguna config (a whole period is 4)."""
-    return dataclasses.replace(cfg, num_layers=n, layers=cfg.layers[:n])
-
-
-def _engine_config(cfg, backend="xla", num_blocks=128, **kw):
-    # (no prefix caching: the window layers' pages live in their own group)
-    cache = kw.pop("cache", None) or CacheConfig(
-        block_size=8, num_blocks=num_blocks, enable_prefix_caching=False)
-    return EngineConfig(
-        model=cfg, cache=cache,
-        scheduler=SchedulerConfig(
-            max_num_seqs=8, max_num_batched_tokens=32, decode_buckets=(4, 8),
-            prefill_buckets=(16, 32), decode_window=4),
-        attention_backend=backend, prefill_attention_backend=backend, seed=5,
-        **kw)
-
-
 # -- the engine against the reference ---------------------------------------
 
 @pytest.mark.parametrize("backend,layers,chunk,lengths", [
     ("xla", 9, 32, (70, 120)), ("pallas_interpret", 2, 32, (40, 90)),
     ("xla", 5, 8, (70, 9, 33, 120))])
 def test_engine_against_the_float32_reference(
-        cfg, backend, layers, chunk, lengths):
+        cfg, kit, backend, layers, chunk, lengths):
     """Prefill in chunks (longer and shorter than the window of 16; packed,
     several rows a dispatch), then fused decode windows through BOTH page
     groups — prompts several windows long, so window pages are given back
@@ -82,15 +60,9 @@ def test_engine_against_the_float32_reference(
     counts, the gate, the window in kernels and masks, the held experts and
     the give-back are all under test. (The interpret-mode kernels compile
     slowly: that case runs one full and one window layer.)"""
-    model = _cut(cfg, layers)
-    econf = _engine_config(model, backend)
-    econf = econf.replace(scheduler=dataclasses.replace(
-        econf.scheduler, max_num_batched_tokens=chunk,
-        prefill_buckets=(chunk,), decode_buckets=(4,), max_num_seqs=4))
-    engine = LLMEngine(econf)
-    rng = np.random.RandomState(1)
-    prompts = [list(map(int, rng.randint(1, model.vocab_size, n)))
-               for n in lengths]
+    model = ek.cut(cfg, layers)  # (a whole period is 4)
+    engine, _ = kit.engine(ek.engine_config(model, backend, chunk=chunk))
+    prompts = ek.prompts(model, lengths)
     served = reference_check.served_logprobs(engine, prompts, 12)
     out = reference_check.compare(
         reference, model, engine.runner.params, prompts, served,
@@ -105,23 +77,18 @@ def test_engine_against_the_float32_reference(
     assert engine.scheduler.total_preemptions == 0
 
 
-def test_held_experts_through_the_touched_kernel(cfg):
+def test_held_experts_through_the_touched_kernel(cfg, kit):
     """A program that holds 4 of 16 experts and decodes two rows runs the
     touched-experts kernel (interpret mode) over the held experts only:
     what it read is what was touched, pairs on other chips' experts touch
     nothing here, and the tokens are the einsum engine's."""
-    model = _cut(cfg, 2)
-    sched = dict(decode_buckets=(2,), max_num_seqs=2)
+    model = ek.cut(cfg, 2)
     prompts = [[5, 6, 7, 8, 9], list(range(20, 45))]
-    sp = SamplingParams(max_tokens=9, temperature=0.0, ignore_eos=True)
     got = {}
     for backend in ("xla", "pallas_interpret"):
-        econf = _engine_config(model, backend)
-        engine = LLMEngine(econf.replace(scheduler=dataclasses.replace(
-            econf.scheduler, **sched)))
+        engine, before = kit.engine(ek.engine_config(model, backend, seqs=2))
         got[backend] = (
-            [o["token_ids"] for o in engine.generate(prompts, sp)],
-            engine.flightrec.timing(),
+            ek.generate(engine, prompts, 9), ek.since(engine, before),
             engine.runner.device_report()["moe"]["decode"]["resolved"])
     (tok_x, t_x, form_x), (tok_k, t_k, form_k) = got.values()
     assert (form_x, form_k) == ("all_experts", "touched_kernel")
@@ -132,22 +99,20 @@ def test_held_experts_through_the_touched_kernel(cfg):
     assert t_k["moe_decode_held_pairs_n"] < t_k["moe_decode_pairs_n"]
 
 
-def test_held_experts_through_the_grouped_kernel(cfg, monkeypatch):
+def test_held_experts_through_the_grouped_kernel(cfg, kit, monkeypatch):
     """A program that holds 4 of 16 experts and prefills chunks above the
     token limit (lowered to 8 tokens here) sorts every routed pair but
     runs only those that land on its own experts through the fused kernel
     (interpret mode): the tokens and the counters are the `ragged_dot`
     engine's, and `device.moe.prefill` says which form each holds."""
     monkeypatch.setattr(llama, "_ALL_EXPERTS_MAX_TOKENS", 8)
-    model = _cut(cfg, 2)
+    model = ek.cut(cfg, 2)
     prompts = [[5, 6, 7, 8, 9] * 4, list(range(20, 65))]
-    sp = SamplingParams(max_tokens=6, temperature=0.0, ignore_eos=True)
     got = {}
     for backend in ("xla", "pallas_interpret"):
-        engine = LLMEngine(_engine_config(model, backend))
+        engine = kit.fresh_engine(ek.engine_config(model, backend))
         got[backend] = (
-            [o["token_ids"] for o in engine.generate(prompts, sp)],
-            engine.flightrec.timing(),
+            ek.generate(engine, prompts, 6), engine.flightrec.timing(),
             engine.runner.device_report()["moe"]["prefill"]["resolved"])
     (tok_x, t_x, form_x), (tok_k, t_k, form_k) = got.values()
     assert (form_x, form_k) == ("grouped", "grouped_kernel")
@@ -189,7 +154,7 @@ def test_a_model_whose_layer_kinds_are_swapped_differs(cfg, params):
 def test_the_gate_multiplies_each_head(cfg, params):
     """Zero gate weights give g = sigmoid(0) = 1/2 on every head: the
     attention block's output is exactly half the ungated model's."""
-    model = _cut(cfg, 1)
+    model = ek.cut(cfg, 1)
     p = llama.init_params(model, jax.random.PRNGKey(0))
     p["layers"]["attn"]["wg"] = jnp.zeros_like(p["layers"]["attn"]["wg"])
     ungated = dataclasses.replace(model, attn_output_gate="")
@@ -350,11 +315,11 @@ def test_the_four_shares_add_up_to_the_whole_expert_layer(cfg):
     that quarter) plus the shared expert counted ONCE equal the uncut
     reference's whole expert layer."""
     whole = dataclasses.replace(cfg, num_experts_held=0, first_expert_held=0)
-    p_whole = llama.init_params(_cut(whole, 2), jax.random.PRNGKey(7))
+    p_whole = llama.init_params(ek.cut(whole, 2), jax.random.PRNGKey(7))
     moe = jax.tree.map(lambda a: a[0], p_whole["layers"]["moe"])
     x = jax.random.normal(jax.random.PRNGKey(8), (1, 40, cfg.hidden_size))
-    view = LagunaCheckpointView(_cut(whole, 2), p_whole)
-    ref_cfg = reference.check_config(reference.published_keys(_cut(whole, 2)))
+    view = LagunaCheckpointView(ek.cut(whole, 2), p_whole)
+    ref_cfg = reference.check_config(reference.published_keys(ek.cut(whole, 2)))
 
     def run(fn, *args):
         with jax.default_matmul_precision("highest"):
@@ -394,7 +359,7 @@ def test_logits_over_the_slice_equal_the_uncut_models_restricted(cfg):
     """A sliced vocabulary is a smaller vocabulary: with the embedding's
     rows and the head's columns [0, 128) of the uncut model's 512, the
     logits equal the uncut model's restricted to the slice."""
-    whole = _cut(cfg, 2)
+    whole = ek.cut(cfg, 2)
     sliced = dataclasses.replace(whole, vocab_size=128)
     p = llama.init_params(whole, jax.random.PRNGKey(2))
     p_slice = {**p, "embed": p["embed"][:128], "lm_head": p["lm_head"][:, :128]}
@@ -411,14 +376,3 @@ def test_logits_over_the_slice_equal_the_uncut_models_restricted(cfg):
     np.testing.assert_allclose(
         llama.compute_logits(sliced, p_slice, hidden),
         llama.compute_logits(whole, p, hidden)[:, :128], rtol=1e-6, atol=1e-6)
-
-
-def test_reference_copies_are_identical():
-    import os
-
-    here = os.path.dirname(os.path.abspath(__file__))
-    a = os.path.join(here, "..", "vllm_production_stack_tpu", "testing",
-                     "reference_laguna.py")
-    b = os.path.join(here, "..", "benchmarks", "chip", "reference",
-                     "laguna.py")
-    assert open(a).read() == open(b).read()

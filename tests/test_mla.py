@@ -13,11 +13,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import engine_kit as ek
 from vllm_production_stack_tpu.engine.config import (
-    CacheConfig, EngineConfig, ParallelConfig, SchedulerConfig,
+    CacheConfig, ParallelConfig,
 )
-from vllm_production_stack_tpu.engine.engine import LLMEngine
-from vllm_production_stack_tpu.engine.request import SamplingParams
 from vllm_production_stack_tpu.engine.memory import (
     kv_block_bytes, param_bytes,
 )
@@ -53,26 +52,20 @@ def params(cfg):
     return llama.init_params(cfg, jax.random.PRNGKey(3))
 
 
-def _engine_config(cfg, backend="xla", **kw):
-    return EngineConfig(
-        model=cfg, cache=CacheConfig(block_size=8, num_blocks=128),
-        scheduler=SchedulerConfig(
-            max_num_seqs=8, max_num_batched_tokens=32, decode_buckets=(4, 8),
-            prefill_buckets=(16, 32), decode_window=4),
-        attention_backend=backend, prefill_attention_backend=backend, seed=5,
-        **kw)
+# what this file's engines differ in from the kit's: prefix caching as the
+# flag's default has it (the latent pool is content-addressed like any other)
+POOL = dict(cache=CacheConfig(block_size=8, num_blocks=128))
 
 
 @pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
-def test_engine_against_the_float32_reference(cfg, backend):
+def test_engine_against_the_float32_reference(cfg, kit, backend):
     """Prefill in chunks (packed, several rows a dispatch), then fused decode
     windows through the latent pool, against the reference's full forward
     in its EXPANDED form on checkpoint-form weights: the absorption, the
     pool, the staged window and the rope de-interleave are all under test."""
-    engine = LLMEngine(_engine_config(cfg, backend))
-    rng = np.random.RandomState(1)
-    prompts = [list(map(int, rng.randint(1, cfg.vocab_size, n)))
-               for n in (70, 9, 33, 50)]  # 70 and 50: three and two chunks
+    engine, before = kit.engine(ek.engine_config(cfg, backend, **POOL))
+    # 70 and 50: three and two chunks
+    prompts = ek.prompts(cfg, (70, 9, 33, 50))
     served = reference_check.served_logprobs(engine, prompts, 10)
     out = reference_check.compare(
         reference, cfg, engine.runner.params, prompts, served,
@@ -81,7 +74,7 @@ def test_engine_against_the_float32_reference(cfg, backend):
     assert out["max_abs_dlogprob"] <= LOGPROB_ATOL, out
     assert out["routing_disagreement_share"] == 0.0, out
     # counters: slots count the two EXPERT layers, not the dense one
-    t = engine.flightrec.timing()
+    t = ek.since(engine, before)
     assert t["moe_prefill_slots_n"] == t["prefill_n"] * 2 * cfg.num_experts
     assert t["moe_decode_slots_n"] % (2 * cfg.num_experts) == 0
     assert 0 < t["moe_decode_touched_n"] <= t["moe_decode_slots_n"]
@@ -96,45 +89,40 @@ def test_engine_against_the_float32_reference(cfg, backend):
     ("pallas_interpret", 8, "all_experts"),  # 1 - (3/4)^8 = 0.90
 ])
 def test_read_counter_and_report_follow_the_program_form(
-        cfg, backend, rows, form):
+        cfg, kit, backend, rows, form):
     """`moe_decode_read_n` counts the experts whose bytes the decode
     programs' expert computation read — the touched ones from a program
     built with the kernel, every slot from one built with the einsum — and
     /debug/programs' `device.moe.decode.resolved` says which; the host
     knows the form from the program's shapes (ISSUE 36)."""
-    econf = _engine_config(cfg, backend)
-    econf = econf.replace(scheduler=dataclasses.replace(
-        econf.scheduler, decode_buckets=(rows,), max_num_seqs=rows))
-    engine = LLMEngine(econf)
-    engine.generate(
-        [[5, 6, 7, 8, 9], list(range(20, 50))],
-        SamplingParams(max_tokens=9, temperature=0.0, ignore_eos=True))
+    engine, before = kit.engine(
+        ek.engine_config(cfg, backend, seqs=rows, **POOL))
+    ek.generate(engine, [[5, 6, 7, 8, 9], list(range(20, 50))], 9)
     moe = engine.runner.device_report()["moe"]
     assert moe["decode"] == {"resolved": form, "by_bucket": {str(rows): form}}
-    t = engine.flightrec.timing()
+    t = ek.since(engine, before)
     assert 0 < t["moe_decode_touched_n"] < t["moe_decode_slots_n"]
     assert t["moe_decode_read_n"] == (
         t["moe_decode_touched_n"] if form == "touched_kernel"
         else t["moe_decode_slots_n"])
-    # 16- and 32-token chunks cover tiny-kanana's 8 experts: the einsum
+    # a 32-token chunk covers tiny-kanana's 8 experts: the einsum
     assert t["moe_prefill_read_n"] == t["moe_prefill_slots_n"]
 
 
 @pytest.mark.parametrize("backend,form", [
     ("xla", "grouped"), ("pallas_interpret", "grouped_kernel")])
 def test_a_long_chunk_through_the_grouped_forms_against_the_reference(
-        cfg, backend, form, monkeypatch):
-    """Above the token limit (lowered to 8 tokens here: the tiny engine's
+        cfg, kit, backend, form, monkeypatch):
+    """Above the token limit (lowered to 8 tokens here: this engine's
     chunks are 16 and 32) a prefill chunk sorts its routed pairs and runs
     them through `ragged_dot`, or through the fused kernel where the
     program may hold one (interpret mode): both within the float32
     reference's tolerance, `device.moe.prefill` says which, and the read
     counter counts the touched experts either way (ISSUE 38)."""
     monkeypatch.setattr(llama, "_ALL_EXPERTS_MAX_TOKENS", 8)
-    engine = LLMEngine(_engine_config(cfg, backend))
-    rng = np.random.RandomState(2)
-    prompts = [list(map(int, rng.randint(1, cfg.vocab_size, n)))
-               for n in (70, 9, 33)]
+    engine = kit.fresh_engine(ek.engine_config(
+        cfg, backend, sched=dict(prefill_buckets=(16, 32)), **POOL))
+    prompts = ek.prompts(cfg, (70, 9, 33), seed=2)
     served = reference_check.served_logprobs(engine, prompts, 6)
     out = reference_check.compare(
         reference, cfg, engine.runner.params, prompts, served,
@@ -403,15 +391,15 @@ def test_unbuilt_config_keys_are_refused_by_name(tmp_path, cfg, params,
      "parallelism is not implemented for multi-head latent"),
     (dict(model="int8"), "attn.wkv_a"),
 ])
-def test_unbuilt_engine_options_are_refused_at_start(cfg, kw, message):
-    base = _engine_config(cfg)
+def test_unbuilt_engine_options_are_refused_at_start(cfg, kit, kw, message):
+    base = ek.engine_config(cfg, **POOL)
     if kw.get("model") == "int8":
         kw = dict(model=dataclasses.replace(cfg, quantization="int8"))
     with pytest.raises(ValueError, match=message):
-        LLMEngine(base.replace(**kw))
+        kit.fresh_engine(base.replace(**kw))
 
 
-def test_served_over_http_and_kv_page_transfer_is_refused(cfg):
+def test_served_over_http_and_kv_page_transfer_is_refused(cfg, kit):
     """Chat and completion requests through the engine's HTTP server; the
     endpoints that ship K/V-shaped pages answer 501 with one sentence."""
     import asyncio
@@ -420,7 +408,8 @@ def test_served_over_http_and_kv_page_transfer_is_refused(cfg):
 
     from vllm_production_stack_tpu.engine.server import EngineServer
 
-    srv = EngineServer(LLMEngine(_engine_config(cfg)),
+    # (an engine of its own: the server's step thread drives it)
+    srv = EngineServer(kit.fresh_engine(ek.engine_config(cfg, **POOL)),
                        served_model_name="tiny-kanana")
 
     async def drive():
@@ -449,7 +438,7 @@ def test_served_over_http_and_kv_page_transfer_is_refused(cfg):
     asyncio.run(drive())
 
 
-def test_reference_follows_the_served_routing_in_bf16(cfg):
+def test_reference_follows_the_served_routing_in_bf16(cfg, kit):
     """In bf16 the router's hard top-k flips boundary experts, and each flip
     swaps a renormalised share of the routed output: a free-running
     comparison reads the flips. The served programs' own routing is tapped
@@ -458,11 +447,9 @@ def test_reference_follows_the_served_routing_in_bf16(cfg):
     own: logprobs then agree to bf16 rounding and every differing choice
     sits within a rounding margin."""
     bf16 = dataclasses.replace(cfg, dtype="bfloat16")
-    rng = np.random.RandomState(1)
-    prompts = [list(map(int, rng.randint(1, cfg.vocab_size, n)))
-               for n in (70, 9, 33, 50)]
+    prompts = ek.prompts(cfg, (70, 9, 33, 50))
     with reference_check.RoutingTap() as tap:
-        engine = LLMEngine(_engine_config(bf16))
+        engine = kit.fresh_engine(ek.engine_config(bf16, **POOL))
         served = reference_check.served_logprobs(engine, prompts, 10)
     assert llama.ROUTING_TAP is None
     seq = prompts[0] + served[0]["token_ids"][:-1]

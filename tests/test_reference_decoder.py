@@ -99,6 +99,9 @@ def test_reference_refuses_what_it_does_not_compute():
 @pytest.mark.parametrize("package_file,benchmark_file", [
     ("reference_decoder.py", "decoder.py"),  # the Llama lineage
     ("reference_mla.py", "mla.py"),  # the DeepSeek-V3 family (ISSUE 31)
+    ("reference_laguna.py", "laguna.py"),  # window + full layers (ISSUE 33)
+    ("reference_solar_open2.py", "solar_open2.py"),  # KDA state (ISSUE 39)
+    ("reference_sdar.py", "sdar.py"),  # diffusion over blocks (ISSUE 41)
 ])
 def test_benchmark_copy_is_byte_identical(package_file, benchmark_file):
     """benchmarks/chip/reference/<file> is the yardstick's own copy: a later

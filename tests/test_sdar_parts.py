@@ -14,14 +14,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import engine_kit as ek
 from vllm_production_stack_tpu import metrics_contract as mc
 from vllm_production_stack_tpu.engine.config import (
     BD_STRATEGIES, BlockDiffusion, CacheConfig, EngineConfig, LoRAConfig,
-    ParallelConfig, SchedulerConfig,
+    ParallelConfig,
 )
-from vllm_production_stack_tpu.engine.engine import LLMEngine
 from vllm_production_stack_tpu.engine.model_runner import ModelRunner
-from vllm_production_stack_tpu.engine.request import SamplingParams
 from vllm_production_stack_tpu.engine.scheduler import Scheduler
 from vllm_production_stack_tpu.models import llama, registry
 from vllm_production_stack_tpu.models.registry import resolve_model_config
@@ -34,45 +33,9 @@ def cfg():
     return resolve_model_config("tiny-sdar")
 
 
-def _engine_config(cfg, backend="xla", num_blocks=128, seqs=4, chunk=32,
-                   **kw):
-    return EngineConfig(
-        model=cfg,
-        cache=kw.pop("cache", None) or CacheConfig(
-            block_size=8, num_blocks=num_blocks,
-            enable_prefix_caching=False),
-        scheduler=SchedulerConfig(
-            max_num_seqs=seqs, max_num_batched_tokens=chunk,
-            decode_buckets=(seqs,), prefill_buckets=(chunk,),
-            **{"decode_window": 8, **kw.pop("sched", {})}),
-        attention_backend=backend, prefill_attention_backend=backend, seed=5,
-        **kw)
-
-
-@pytest.fixture(scope="module")
-def engines(cfg):
-    """One engine a backend, shared by the cases that only generate."""
-    built = {}
-
-    def get(backend):
-        if backend not in built:
-            built[backend] = LLMEngine(_engine_config(cfg, backend))
-        return built[backend]
-
-    return get
-
-
-def _prompts(cfg, lengths, seed=1):
-    rng = np.random.RandomState(seed)
-    # (never the mask token: a prompt may hold it, but the reference's
-    # `generate` tells a mask by its id)
-    return [list(map(int, rng.randint(1, cfg.vocab_size - 1, n)))
-            for n in lengths]
-
-
-def _greedy(max_tokens, **kw):
-    return SamplingParams(temperature=0.0, max_tokens=max_tokens,
-                          ignore_eos=True, **kw)
+# what this file's engines differ in from the kit's: a decode window of two
+# blocks of 4
+WINDOW = dict(decode_window=8)
 
 
 # -- the pieces --------------------------------------------------------------
@@ -194,42 +157,42 @@ def test_the_presets_and_the_mapper(tmp_path):
 ])
 def test_refused_by_name_at_engine_start(cfg, what, over):
     with pytest.raises(ValueError) as err:
-        ModelRunner(_engine_config(cfg, **over))
+        ModelRunner(ek.engine_config(cfg, **{**WINDOW, **over}))
     assert what in str(err.value)
     assert "generation by diffusion over blocks" in str(err.value)
 
 
-def test_refused_by_name_max_model_len_and_requests(cfg, engines):
+def test_refused_by_name_max_model_len_and_requests(cfg, kit):
     with pytest.raises(ValueError, match="--max-model-len 250"):
-        ModelRunner(_engine_config(
-            dataclasses.replace(cfg, max_model_len=250)))
-    engine = engines("xla")
+        ModelRunner(ek.engine_config(
+            dataclasses.replace(cfg, max_model_len=250), **WINDOW))
+    engine, _ = kit.engine(ek.engine_config(cfg, **WINDOW))
     with pytest.raises(ValueError, match="min_tokens"):
-        engine.add_request(prompt_token_ids=[1, 2, 3], sampling=_greedy(
+        engine.add_request(prompt_token_ids=[1, 2, 3], sampling=ek.greedy(
             4, min_tokens=2))
     with pytest.raises(ValueError, match="/v1/embeddings"):
         engine.embed([[1, 2, 3]])
     grammar = engine.grammar_cache.get({"kind": "json_object"})[0]
     with pytest.raises(ValueError, match="structured output"):
-        engine.add_request(prompt_token_ids=[1, 2, 3], sampling=_greedy(
+        engine.add_request(prompt_token_ids=[1, 2, 3], sampling=ek.greedy(
             4, grammar=grammar))
     assert not engine.has_unfinished()
 
 
-def test_prefix_caching_is_turned_off_with_one_log_line(cfg, caplog):
+def test_prefix_caching_is_turned_off_with_one_log_line(cfg, kit, caplog):
     """Asked for (the default), it is turned off by the engine, which says
     so once; the scheduler on its own refuses it by name. Two identical
     prompts therefore both compute: no hit, the same tokens."""
-    config = _engine_config(cfg, cache=CacheConfig(
+    config = ek.engine_config(cfg, **WINDOW, cache=CacheConfig(
         block_size=8, num_blocks=64, enable_prefix_caching=True))
     with caplog.at_level(logging.INFO):
-        engine = LLMEngine(config)
+        engine = kit.fresh_engine(config)
     said = [r for r in caplog.records if "WITHOUT prefix hits" in r.message]
     assert len(said) == 1
     assert not engine.config.cache.enable_prefix_caching
-    (prompt,) = _prompts(cfg, [41], seed=8)
-    (a,) = engine.generate([prompt], _greedy(6))
-    (b,) = engine.generate([prompt], _greedy(6))
+    (prompt,) = ek.prompts(cfg, [41], seed=8)
+    (a,) = engine.generate([prompt], ek.greedy(6))
+    (b,) = engine.generate([prompt], ek.greedy(6))
     assert a["token_ids"] == b["token_ids"]
     assert engine.scheduler.pool.stats.hits == 0
     with pytest.raises(ValueError, match="prefix caching is not built"):
@@ -238,7 +201,7 @@ def test_prefix_caching_is_turned_off_with_one_log_line(cfg, caplog):
 
 # -- the counters and the names ---------------------------------------------
 
-def test_counters_metrics_flight_record_and_programs(cfg):
+def test_counters_metrics_flight_record_and_programs(cfg, kit):
     """One row of 9 prompt tokens and 7 wanted: two windows (8 positions,
     then 4: one block covers what is left... the first is given 1 token
     and yields 7) — the counts by hand, the same numbers at /metrics, the
@@ -248,11 +211,11 @@ def test_counters_metrics_flight_record_and_programs(cfg):
 
     model = dataclasses.replace(cfg, block_diffusion=dataclasses.replace(
         cfg.block_diffusion, confidence_threshold=0.9))  # never fires
-    engine = LLMEngine(_engine_config(model))
-    (prompt,) = _prompts(model, [9], seed=6)
-    (out,) = engine.generate([prompt], _greedy(7))
+    engine, before = kit.engine(ek.engine_config(model, **WINDOW))
+    (prompt,) = ek.prompts(model, [9], seed=6)
+    (out,) = engine.generate([prompt], ek.greedy(7))
     assert len(out["token_ids"]) == 7
-    timing = engine.flightrec.timing()
+    timing = ek.since(engine, before)
     # one window of two blocks: 3 masks + commit, 4 masks + commit
     assert {k: timing[k] for k in mc.DEBUG_TIMING_BD_COUNTS} == {
         "bd_passes_n": 9, "bd_row_passes_n": 9, "bd_repeat_row_passes_n": 0,
@@ -281,8 +244,8 @@ def test_counters_metrics_flight_record_and_programs(cfg):
     assert engine.runner.device_report()["moe"]["decode"]["by_bucket"] == {
         "4": llama.moe_experts_form(model, 16, kernel=False)}
     # any other model serves none of it
-    plain = LLMEngine(EngineConfig.tiny())
-    plain.generate([[1, 2, 3]], _greedy(3))
+    plain, _ = kit.engine(EngineConfig.tiny())
+    plain.generate([[1, 2, 3]], ek.greedy(3))
     assert not set(mc.DEBUG_TIMING_BD_COUNTS) & set(plain.flightrec.timing())
     assert "generation" not in plain.runner.device_report()
     assert plain.stats().block_diffusion == {}
@@ -291,7 +254,7 @@ def test_counters_metrics_flight_record_and_programs(cfg):
 def test_the_passes_name_their_parts(cfg):
     """The window program's named scopes: the pass's own `attn`, `kv_stage`
     and expert scopes keep their names, and the transfer rule has one."""
-    runner = ModelRunner(_engine_config(cfg))
+    runner = ModelRunner(ek.engine_config(cfg, **WINDOW))
     lowered = runner._decode_window_fn.lower(
         runner._aval_tree(runner.params), None,
         runner._aval_tree(runner.kv_caches),
@@ -332,14 +295,7 @@ _PARENT_PROGRAMS = {
 def _program_digests(preset: str) -> tuple[str, str]:
     model = resolve_model_config(preset)
     assert model.block_diffusion is None
-    runner = ModelRunner(EngineConfig(
-        model=model,
-        cache=CacheConfig(block_size=8, num_blocks=64,
-                          enable_prefix_caching=False),
-        scheduler=SchedulerConfig(
-            max_num_seqs=4, max_num_batched_tokens=32, decode_buckets=(4,),
-            prefill_buckets=(32,), decode_window=4),
-        attention_backend="xla", prefill_attention_backend="xla", seed=5))
+    runner = ModelRunner(ek.engine_config(model, num_blocks=64))
     params = runner._aval_tree(runner.params)
     pools = runner._aval_tree(runner.kv_caches)
     flags = dict(want_logprobs=False, want_min_tokens=False,
@@ -394,15 +350,6 @@ def test_the_taps_split_of_the_touched_share(alike):
         assert split["all"]["independent"] == pytest.approx(
             1 - (7 / 8) ** 8)
         assert split["all"]["touched"] > split["all"]["independent"]
-
-
-def test_the_reference_has_its_copy_in_the_benchmark():
-    import pathlib
-
-    root = pathlib.Path(__file__).resolve().parent.parent
-    assert (root / "benchmarks/chip/reference/sdar.py").read_bytes() == (
-        root / "vllm_production_stack_tpu/testing/reference_sdar.py"
-    ).read_bytes()
 
 
 if __name__ == "__main__":

@@ -8,26 +8,11 @@ import dataclasses
 
 import pytest
 
+import engine_kit as ek
 from vllm_production_stack_tpu.engine.config import (
-    CacheConfig, EngineConfig, LoRAConfig, ModelConfig, ParallelConfig,
-    SchedulerConfig,
+    CacheConfig, LoRAConfig, ModelConfig, ParallelConfig,
 )
-from vllm_production_stack_tpu.engine.engine import LLMEngine
-from vllm_production_stack_tpu.engine.request import SamplingParams
 from vllm_production_stack_tpu.models.registry import resolve_model_config
-
-
-def _engine_config(cfg, backend="xla", **kw):
-    # (no prefix caching: the window layers' pages live in their own group)
-    cache = kw.pop("cache", None) or CacheConfig(
-        block_size=8, num_blocks=128, enable_prefix_caching=False)
-    return EngineConfig(
-        model=cfg, cache=cache,
-        scheduler=SchedulerConfig(
-            max_num_seqs=8, max_num_batched_tokens=32, decode_buckets=(4, 8),
-            prefill_buckets=(16, 32), decode_window=4),
-        attention_backend=backend, prefill_attention_backend=backend, seed=5,
-        **kw)
 
 
 def _sliding_model(name):
@@ -54,7 +39,9 @@ _ASKED = {
     "lora": dict(lora=LoRAConfig(max_loras=1, max_lora_rank=4)),
     "host_tier": dict(cache=CacheConfig(
         block_size=8, num_blocks=128, num_host_blocks=8)),
-    "draft": dict(draft=True),
+    "draft": dict(sched=dict(
+        num_speculative_tokens=2, speculative_method="draft",
+        draft_model="tiny-llama")),
 }
 
 
@@ -64,37 +51,28 @@ _ASKED = {
     # (a tree stacked by layer kind refuses a mesh and LoRA by name:
     # tests/test_window_kv.py)
     ("tiny-laguna", "host_tier"), ("tiny-laguna", "draft")])
-def test_a_sliding_model_keeps_what_it_was_served_with(name, asked):
+def test_a_sliding_model_keeps_what_it_was_served_with(kit, name, asked):
     """Gemma-2 and Mistral-v0.1 class models were served under a mesh, with
     LoRA adapters, a draft model and KV tiers before the window page group
     existed: asking for any of them keeps every layer on the one
     full-length group (the window is a mask), and the tokens are those of
     the engine that uses the window group."""
-    kw = dict(_ASKED[asked])
     prompts = [list(range(3 + i, 50 + 7 * i)) for i in range(2)]
-    sampling = SamplingParams(max_tokens=12, temperature=0.0, ignore_eos=True)
     model = _cut5(_sliding_model(name))
-    grouped = LLMEngine(_engine_config(model))
+    grouped, _ = kit.engine(ek.engine_config(model))
     assert grouped.scheduler.pool.window is not None
-    econf = _engine_config(model, **{k: v for k, v in kw.items()
-                                      if k != "draft"})
-    if kw.get("draft"):
-        econf = econf.replace(scheduler=dataclasses.replace(
-            econf.scheduler, num_speculative_tokens=2,
-            speculative_method="draft", draft_model="tiny-llama"))
-    engine = LLMEngine(econf)
+    engine, _ = kit.engine(ek.engine_config(model, **_ASKED[asked]))
     assert engine.scheduler.pool.window is None
     assert not engine.runner._window_group
-    want = grouped.generate(prompts, sampling)
-    got = engine.generate(prompts, sampling)
-    assert [o["token_ids"] for o in got] == [o["token_ids"] for o in want]
+    assert ek.generate(engine, prompts, 12) == ek.generate(
+        grouped, prompts, 12)
 
 
 @pytest.mark.parametrize("name,backend", [
     ("tiny-gemma2", "xla"), ("tiny-mistral-sliding", "xla"),
     ("tiny-mistral-sliding", "pallas_interpret"), ("tiny-laguna", "xla")])
 def test_prefix_caching_keeps_the_one_group_and_its_hits(
-        name, backend, caplog):
+        kit, name, backend, caplog):
     """Prefix caching (the default) and the window page group exclude each
     other — a hit would need pages the group gave back — so with it on a
     model with window layers keeps every layer's pages for the whole
@@ -103,21 +81,24 @@ def test_prefix_caching_keeps_the_one_group_and_its_hits(
     kernels too: they walk a full-length table from the page of the first
     visible key as they walk the group's)."""
     model = _cut5(_sliding_model(name))
-    sampling = SamplingParams(max_tokens=6, temperature=0.0, ignore_eos=True)
     prompt = list(range(1, 60))
+    # (built here: what an engine says at start is under test)
     with caplog.at_level("INFO"):
-        cached = LLMEngine(_engine_config(model, backend, cache=CacheConfig(
-            block_size=8, num_blocks=128)))
+        cached = kit.fresh_engine(ek.engine_config(
+            model, backend, cache=CacheConfig(block_size=8, num_blocks=128)))
     assert "hold the whole context like the full layers" in caplog.text
     assert "prefix caching" in caplog.text
     assert cached.scheduler.pool.window is None
-    first = cached.generate([prompt], sampling)[0]["token_ids"]
-    again = cached.generate([prompt], sampling)[0]["token_ids"]
+    first = ek.generate(cached, [prompt], 6)
+    again = ek.generate(cached, [prompt], 6)
     assert cached.scheduler.pool.stats.hits > 0
     caplog.clear()
+    config = ek.engine_config(model, backend)
     with caplog.at_level("INFO"):
-        grouped = LLMEngine(_engine_config(model, backend))
+        grouped = kit.fresh_engine(config)
     assert "keep their pages in a group of their own" in caplog.text
     assert grouped.scheduler.pool.window is not None
     assert not grouped.scheduler.pool.enable_prefix_caching
-    assert first == again == grouped.generate([prompt], sampling)[0]["token_ids"]
+    # (never driven, it compiled nothing: the module's engine of the same
+    # configuration serves the tokens)
+    assert first == again == ek.generate(kit.engine(config)[0], [prompt], 6)

@@ -15,12 +15,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import engine_kit as ek
 from vllm_production_stack_tpu import metrics_contract as mc
 from vllm_production_stack_tpu.engine import memory
 from vllm_production_stack_tpu.engine.config import (
     CacheConfig, EngineConfig, LoRAConfig, ParallelConfig, SchedulerConfig,
 )
-from vllm_production_stack_tpu.engine.engine import LLMEngine
 from vllm_production_stack_tpu.engine.request import (
     Request, SamplingParams,
 )
@@ -33,7 +33,6 @@ from vllm_production_stack_tpu.testing import reference_check
 from vllm_production_stack_tpu.testing import (
     reference_solar_open2 as reference,
 )
-from vllm_production_stack_tpu.testing.serial_loop import serial_engine
 
 # float32 on both sides. Through the XLA forms the engine runs the very
 # recurrence the reference writes, token by token: what differs is the order
@@ -56,30 +55,10 @@ def cfg():
     return resolve_model_config("tiny-solar-open2")
 
 
-def _cut(cfg, n):
-    """The first n layers (a whole period is 4: G K K K)."""
-    return dataclasses.replace(cfg, num_layers=n, layers=cfg.layers[:n])
-
-
-def _engine_config(cfg, backend="xla", num_blocks=128, seqs=4, chunk=64,
-                   **kw):
-    return EngineConfig(
-        model=cfg,
-        cache=kw.pop("cache", None) or CacheConfig(
-            block_size=8, num_blocks=num_blocks,
-            enable_prefix_caching=False),
-        scheduler=SchedulerConfig(
-            max_num_seqs=seqs, max_num_batched_tokens=chunk,
-            decode_buckets=(seqs,), prefill_buckets=(chunk,),
-            decode_window=4, **kw.pop("sched", {})),
-        attention_backend=backend, prefill_attention_backend=backend, seed=5,
-        **kw)
-
-
-def _prompts(cfg, lengths, seed=1):
-    rng = np.random.RandomState(seed)
-    return [list(map(int, rng.randint(1, cfg.vocab_size, n)))
-            for n in lengths]
+# what this file's engines differ in from the kit's: chunks of 64 tokens, a
+# block of the KDA prefill kernel (cuts of the model are whole periods of 4
+# layers, G K K K, or a stated part of one)
+CHUNK = dict(chunk=64)
 
 
 # -- the engine against the reference ---------------------------------------
@@ -87,7 +66,8 @@ def _prompts(cfg, lengths, seed=1):
 @pytest.mark.parametrize("backend,layers,lengths", [
     ("xla", 8, (70, 9, 33, 150)), ("pallas_interpret", 2, (70, 9, 33, 150)),
     ("xla", 4, (200,))])
-def test_engine_against_the_float32_reference(cfg, backend, layers, lengths):
+def test_engine_against_the_float32_reference(
+        cfg, kit, backend, layers, lengths):
     """Prefill in chunks of 64 (the 150- and 200-token prompts in three and
     four, the others packed beside them: a chunk holds up to four rows'
     segments, and a seam lies inside a kernel block), then fused decode
@@ -95,11 +75,11 @@ def test_engine_against_the_float32_reference(cfg, backend, layers, lengths):
     forward: the convolution across chunks and seams, the state carried from
     chunk to chunk and through the window, the two banks, the elementwise
     gate, no rope, the held experts with their selection bias."""
-    model = _cut(cfg, layers)
-    prompts = _prompts(model, lengths)
+    model = ek.cut(cfg, layers)
+    prompts = ek.prompts(model, lengths)
     with (reference_check.RoutingTap() as tap,
           reference_check.StateTap(model.linear_layers[-1]) as state_tap):
-        engine = LLMEngine(_engine_config(model, backend))
+        engine = kit.fresh_engine(ek.engine_config(model, backend, **CHUNK))
         served = reference_check.served_logprobs(engine, prompts, 12)
     out = reference_check.compare(
         reference, model, engine.runner.params, prompts, served,
@@ -138,22 +118,22 @@ def _faulty(**edits):
         delta_rule=reference.delta_rule)
 
 
-def _serve_tapped(model, lengths, n_out, seed):
+def _serve_tapped(kit, model, lengths, n_out, seed):
     """(params, prompts, served logprobs, routing tap, state tap, the tapped
     layer's state pool) of an XLA engine that served `lengths`."""
-    prompts = _prompts(model, lengths, seed=seed)
+    prompts = ek.prompts(model, lengths, seed=seed)
     with (reference_check.RoutingTap() as tap,
           reference_check.StateTap(model.linear_layers[-1]) as state_tap):
-        engine = LLMEngine(_engine_config(model))
+        engine = kit.fresh_engine(ek.engine_config(model, **CHUNK))
         served = reference_check.served_logprobs(engine, prompts, n_out)
     pool = np.asarray(engine.runner.kv_caches[state_tap.layer]["s"])
     return engine.runner.params, prompts, served, tap, state_tap, pool
 
 
 @pytest.fixture(scope="module")
-def served_once(cfg):
-    model = _cut(cfg, 4)
-    return model, *_serve_tapped(model, (70, 33), 8, seed=3)
+def served_once(cfg, kit):
+    model = ek.cut(cfg, 4)
+    return model, *_serve_tapped(kit, model, (70, 33), 8, seed=3)
 
 
 @pytest.mark.parametrize("edits", [
@@ -305,7 +285,7 @@ def test_the_served_states_limit_tells_a_bfloat16_reference(served_once):
 
 
 def test_an_engine_that_rounds_its_state_is_outside_the_states_limit(
-        cfg, monkeypatch):
+        cfg, kit, monkeypatch):
     """The other way round: an engine whose state is rounded to bfloat16 at
     every token (what slots or kernels kept in bfloat16 would do) against
     the float32 reference."""
@@ -316,8 +296,8 @@ def test_an_engine_that_rounds_its_state_is_outside_the_states_limit(
         return s.astype(jnp.bfloat16).astype(jnp.float32), o
 
     monkeypatch.setattr(kda, "kda_step", rounded)
-    model = _cut(cfg, 4)
-    *_, state_tap, pool = _serve_tapped(model, (70, 33), 8, seed=3)
+    model = ek.cut(cfg, 4)
+    *_, state_tap, pool = _serve_tapped(kit, model, (70, 33), 8, seed=3)
     out = reference_check.served_state(reference, model, state_tap, pool)
     assert out["state_rel_err"] > 1e-3, out
 
@@ -347,7 +327,7 @@ def test_the_shares_add_up_to_the_uncut_layer(cfg):
     as eight shares of 40 in the deployment) + the shared expert counted
     once = the uncut reference's layer, through the served `_moe_mlp` and
     through the reference alike."""
-    uncut = dataclasses.replace(_cut(cfg, 1), num_experts_held=0,
+    uncut = dataclasses.replace(ek.cut(cfg, 1), num_experts_held=0,
                                 first_expert_held=0)
     params = llama.init_params(uncut, jax.random.PRNGKey(2))
     moe = jax.tree.map(lambda a: a[0], params["layers"]["moe"])
@@ -389,7 +369,7 @@ def test_the_gate_multiplies_each_channel(cfg):
     """Zero gate weights give g = 1/2 on every head AND channel: the GQA
     block's output is exactly half the ungated model's; and the gate's leaf
     is (h, heads x D), not (h, heads)."""
-    model = _cut(cfg, 1)
+    model = ek.cut(cfg, 1)
     p = llama.init_params(model, jax.random.PRNGKey(0))
     h, d = model.hidden_size, model.head_dim
     assert p["layers"]["attn"]["wg"].shape == (1, h, 4 * d)
@@ -421,7 +401,7 @@ def test_no_position_reaches_a_layer(cfg):
     assert all(s.rope is None for s in cfg.layer_specs)
     assert [s.mixer for s in cfg.layer_specs] == (
         ["softmax"] + ["linear"] * 3) * 2
-    model = _cut(cfg, 1)
+    model = ek.cut(cfg, 1)
     p = llama.init_params(model, jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 1, model.hidden_size))
 
@@ -437,73 +417,67 @@ def test_no_position_reaches_a_layer(cfg):
 
 # -- state slots --------------------------------------------------------------
 
-def _generate(engine, prompts, max_tokens=10):
-    sp = SamplingParams(max_tokens=max_tokens, temperature=0.0,
-                        ignore_eos=True)
-    return [o["token_ids"] for o in engine.generate(prompts, sp)]
-
-
-def test_a_row_alone_among_fifteen_and_in_a_slot_just_left(cfg):
+def test_a_row_alone_among_fifteen_and_in_a_slot_just_left(cfg, kit):
     """The same prompt gives the same tokens alone, among 15 other rows
     (16 seats, every slot held), and in the slot another row has just left
     (one seat: the slot's bytes are the previous holder's until the chunk
     that holds the row's first token starts it from zeros)."""
-    model = _cut(cfg, 4)
-    prompt, others = _prompts(model, (90,))[0], _prompts(
+    model = ek.cut(cfg, 4)
+    prompt, others = ek.prompts(model, (90,))[0], ek.prompts(
         model, [20 + 7 * i for i in range(15)], seed=9)
-    alone = _generate(LLMEngine(_engine_config(model, seqs=16)), [prompt])[0]
-    crowd = LLMEngine(_engine_config(model, seqs=16, num_blocks=512))
-    assert _generate(crowd, [prompt] + others)[0] == alone
-    one = LLMEngine(_engine_config(model, seqs=1))
+    crowd, _ = kit.engine(
+        ek.engine_config(model, seqs=16, num_blocks=512, **CHUNK))
+    alone = ek.generate(crowd, [prompt])[0]
+    assert ek.generate(crowd, [prompt] + others)[0] == alone
+    one, _ = kit.engine(ek.engine_config(model, seqs=1, **CHUNK))
     assert one.scheduler.pool.state.num_usable == 1
-    first = _generate(one, [others[3]])
-    again = _generate(one, [prompt])[0]  # the one slot, just left
+    first = ek.generate(one, [others[3]])
+    again = ek.generate(one, [prompt])[0]  # the one slot, just left
     assert again == alone
-    assert first[0] == _generate(crowd, [others[3]])[0]
+    assert first[0] == ek.generate(crowd, [others[3]])[0]
 
 
-def test_preemption_gives_the_slot_back_and_the_same_tokens(cfg):
+def test_preemption_gives_the_slot_back_and_the_same_tokens(cfg, kit):
     """A pool too small for both rows' growth preempts the newer one: it
     loses its pages AND its slot, is computed again from its first token,
     and ends with the tokens of an engine that never preempted."""
-    model = _cut(cfg, 4)
-    prompts = _prompts(model, (60, 60), seed=4)
-    roomy = LLMEngine(_engine_config(model, seqs=2))
-    want = _generate(roomy, prompts, max_tokens=40)
-    tight = LLMEngine(_engine_config(model, seqs=2, num_blocks=22))
-    got = _generate(tight, prompts, max_tokens=40)
+    model = ek.cut(cfg, 4)
+    prompts = ek.prompts(model, (60, 60), seed=4)
+    roomy, before = kit.engine(ek.engine_config(model, seqs=2, **CHUNK))
+    want = ek.generate(roomy, prompts, max_tokens=40)
+    tight = kit.fresh_engine(
+        ek.engine_config(model, seqs=2, num_blocks=22, **CHUNK))
+    got = ek.generate(tight, prompts, max_tokens=40)
     assert tight.scheduler.total_preemptions > 0
     assert got == want
     timing = tight.flightrec.timing()
     assert timing["state_recomputed_tokens_n"] >= 60
     assert timing["state_slots_zeroed_n"] >= 3  # two rows, one again
-    assert roomy.flightrec.timing()["state_recomputed_tokens_n"] == 0
+    assert ek.since(roomy, before)["state_recomputed_tokens_n"] == 0
     slots = tight.scheduler.pool.state
     assert slots.num_free == slots.num_usable == 2
 
 
-def test_abort_gives_the_slot_back(cfg):
-    model = _cut(cfg, 4)
-    engine = LLMEngine(_engine_config(model, seqs=2))
-    sp = SamplingParams(max_tokens=200, temperature=0.0, ignore_eos=True)
-    rid = engine.add_request(prompt_token_ids=_prompts(model, (70,))[0],
-                             sampling=sp)
+def test_abort_gives_the_slot_back(cfg, kit):
+    model = ek.cut(cfg, 4)
+    config = ek.engine_config(model, seqs=2, **CHUNK)
+    engine, _ = kit.engine(config)
+    rid = engine.add_request(prompt_token_ids=ek.prompts(model, (70,))[0],
+                             sampling=ek.greedy(200))
     for _ in range(4):
         engine.step()
     slots = engine.scheduler.pool.state
     assert slots.num_in_use == 1
-    engine.abort_request(rid)
-    while engine.has_unfinished():
-        engine.step()
+    ek.finish(engine, rid)
     assert slots.num_in_use == 0
     # and the slot serves the next row: the same tokens as a fresh engine
-    prompt = _prompts(model, (50,), seed=6)
-    assert _generate(engine, prompt) == _generate(
-        LLMEngine(_engine_config(model, seqs=2)), prompt)
+    prompt = ek.prompts(model, (50,), seed=6)
+    assert ek.generate(engine, prompt) == ek.generate(
+        kit.fresh_engine(config), prompt)
 
 
 def test_no_admission_without_a_free_slot(cfg):
-    model = _cut(cfg, 4)
+    model = ek.cut(cfg, 4)
     sched = Scheduler(
         model, CacheConfig(block_size=8, num_blocks=64,
                            enable_prefix_caching=False),
@@ -521,22 +495,23 @@ def test_no_admission_without_a_free_slot(cfg):
 
 
 @pytest.mark.parametrize("ends", [(3, 5, 9, 12), (1, 2, 30, 7)])
-def test_a_rolled_back_window_leaves_the_state_it_read(cfg, ends):
+def test_a_rolled_back_window_leaves_the_state_it_read(cfg, kit, ends):
     """A row that meets a stop token in the middle of a decode window
     invalidates the window dispatched behind it: the device has run that
     window and advanced EVERY row's state, and the re-dispatch must start
     from the state before it — the other bank. The pipelined loop's tokens
     are the serial loop's."""
-    model = _cut(cfg, 4)
-    prompts = _prompts(model, (30, 41, 52, 23), seed=8)
-    free = _generate(LLMEngine(_engine_config(model)), prompts, 40)
+    model = ek.cut(cfg, 4)
+    prompts = ek.prompts(model, (30, 41, 52, 23), seed=8)
+    config = ek.engine_config(model, **CHUNK)
+    engine, _ = kit.engine(config)
+    free = ek.generate(engine, prompts, 40)
     # each row stops at the first appearance of the token it would emit at
     # index `end` (nothing tells the scheduler when)
     stops = [row[e] for row, e in zip(free, ends)]
     want = [row[:row.index(stop) + 1] for row, stop in zip(free, stops)]
 
-    def run(make):
-        engine = make(_engine_config(model))
+    def run(engine):
         ids = [engine.add_request(
             prompt_token_ids=p, sampling=SamplingParams(
                 max_tokens=40, temperature=0.0, stop_token_ids=(stop,)))
@@ -545,11 +520,12 @@ def test_a_rolled_back_window_leaves_the_state_it_read(cfg, ends):
         while engine.has_unfinished():
             for out in engine.step():
                 got[out.request_id].extend(out.new_token_ids)
-        return [got[i] for i in ids], engine
+        return [got[i] for i in ids]
 
-    serial, _ = run(serial_engine)
-    got, engine = run(LLMEngine)
-    assert engine.flightrec.timing().get("rollback_n", 0) > 0
+    serial = run(kit.engine(config, serial=True)[0])
+    free_run = engine.flightrec.timing()
+    got = run(engine)
+    assert ek.since(engine, free_run).get("rollback_n", 0) > 0
     assert got == serial == want
 
 
@@ -561,7 +537,7 @@ def test_param_and_cache_bytes_are_the_arrays(cfg):
 
     assert memory.param_bytes(cfg) == nbytes(
         llama.init_params(cfg, jax.random.PRNGKey(0)))
-    econf = _engine_config(cfg, seqs=4, num_blocks=40)
+    econf = ek.engine_config(cfg, num_blocks=40, **CHUNK)
     slots = memory.resolve_state_slots(econf)
     assert slots == 5
     caches = llama.init_kv_cache(cfg, 40, 8, state_slots=slots)
@@ -613,29 +589,30 @@ def test_param_and_cache_bytes_are_the_arrays(cfg):
     (dict(model=dict(quantization="int8")), "--quantization int8"),
     (dict(model=dict(checkpoint="a directory")), "a checkpoint"),
 ])
-def test_what_has_no_state_slot_is_refused_by_name(cfg, edit, message,
+def test_what_has_no_state_slot_is_refused_by_name(cfg, kit, edit, message,
                                                    tmp_path):
     edit = dict(edit)
     over = dict(edit.pop("model", {}))
     if "checkpoint" in over:  # (a weights-only directory: byte tokenizer)
         over["checkpoint"] = str(tmp_path)
-    model = dataclasses.replace(_cut(cfg, 4), **over)
+    model = dataclasses.replace(ek.cut(cfg, 4), **over)
     with pytest.raises(ValueError) as err:
-        LLMEngine(_engine_config(model, **edit))
+        kit.fresh_engine(ek.engine_config(model, **CHUNK, **edit))
     assert message in str(err.value)
     assert "cannot be combined with layers that hold a recurrent state" in str(
         err.value)
 
 
-def test_prefix_caching_is_turned_off_and_said(cfg, caplog):
+def test_prefix_caching_is_turned_off_and_said(cfg, kit, caplog):
     """With prefix caching asked for (the flag's default) the engine runs
     without prefix hits and says so; a scheduler built on its own refuses."""
     import logging
 
-    model = _cut(cfg, 4)
+    model = ek.cut(cfg, 4)
     cache = CacheConfig(block_size=8, num_blocks=64)
     with caplog.at_level(logging.INFO):
-        engine = LLMEngine(_engine_config(model, cache=cache))
+        engine = kit.fresh_engine(
+            ek.engine_config(model, cache=cache, **CHUNK))
     assert not engine.config.cache.enable_prefix_caching
     assert not engine.scheduler.pool.enable_prefix_caching
     assert any("WITHOUT prefix hits" in r.getMessage()
@@ -716,17 +693,16 @@ def test_the_mapper_refuses_by_name(tmp_path, override, message):
 
 # -- names ---------------------------------------------------------------------
 
-def test_timing_and_metrics_names_are_the_contract(cfg):
+def test_timing_and_metrics_names_are_the_contract(cfg, kit):
     from vllm_production_stack_tpu.engine.metrics import EngineMetrics
 
-    model = _cut(cfg, 4)
-    engine = LLMEngine(_engine_config(model))
-    sp = SamplingParams(max_tokens=200, temperature=0.0, ignore_eos=True)
-    engine.add_request(prompt_token_ids=_prompts(model, (70,))[0],
-                       sampling=sp)
+    model = ek.cut(cfg, 4)
+    engine, before = kit.engine(ek.engine_config(model, **CHUNK))
+    rid = engine.add_request(prompt_token_ids=ek.prompts(model, (70,))[0],
+                             sampling=ek.greedy(200))
     for _ in range(6):
         engine.step()
-    timing = engine.flightrec.timing()
+    timing = ek.since(engine, before)
     assert set(mc.DEBUG_TIMING_STATE_COUNTS) <= set(timing)
     assert timing["kda_prefill_tokens_n"] == 70
     assert timing["kda_prefill_segments_n"] == 2  # chunks of 64
@@ -744,18 +720,10 @@ def test_timing_and_metrics_names_are_the_contract(cfg):
     for name in (mc.HBM_STATE_BYTES, mc.HBM_STATE_SLOTS_IN_USE,
                  mc.HBM_STATE_SHARE):
         assert name in mc.ALL_GAUGES and f"\n{name}{{" in text
+    ek.finish(engine, rid)
     # a model without such layers serves none of the counts
-    plain = LLMEngine(EngineConfig.tiny())
-    _generate(plain, [[1, 2, 3]], 3)
+    plain, _ = kit.engine(EngineConfig.tiny())
+    ek.generate(plain, [[1, 2, 3]], 3)
     assert not set(mc.DEBUG_TIMING_STATE_COUNTS) & set(
         plain.flightrec.timing())
     assert plain.stats().state_bytes == 0
-
-
-def test_the_references_are_one_file():
-    import pathlib
-
-    root = pathlib.Path(__file__).resolve().parents[1]
-    a = root / "vllm_production_stack_tpu/testing/reference_solar_open2.py"
-    b = root / "benchmarks/chip/reference/solar_open2.py"
-    assert a.read_bytes() == b.read_bytes()

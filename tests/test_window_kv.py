@@ -11,11 +11,11 @@ import math
 import jax
 import pytest
 
+import engine_kit as ek
 from vllm_production_stack_tpu.engine.config import (
     CacheConfig, EngineConfig, LoRAConfig, ModelConfig, ParallelConfig,
     SchedulerConfig,
 )
-from vllm_production_stack_tpu.engine.engine import LLMEngine
 from vllm_production_stack_tpu.engine.kv_cache import WindowPageGroup
 from vllm_production_stack_tpu.engine.memory import (
     derive_num_blocks, kv_block_bytes, param_bytes, resolve_window_blocks,
@@ -29,24 +29,6 @@ from vllm_production_stack_tpu.models.registry import (
 @pytest.fixture(scope="module")
 def cfg():
     return resolve_model_config("tiny-laguna")
-
-
-def _cut(cfg, n):
-    """The first n layers of a laguna config (a whole period is 4)."""
-    return dataclasses.replace(cfg, num_layers=n, layers=cfg.layers[:n])
-
-
-def _engine_config(cfg, backend="xla", num_blocks=128, **kw):
-    # (no prefix caching: the window layers' pages live in their own group)
-    cache = kw.pop("cache", None) or CacheConfig(
-        block_size=8, num_blocks=num_blocks, enable_prefix_caching=False)
-    return EngineConfig(
-        model=cfg, cache=cache,
-        scheduler=SchedulerConfig(
-            max_num_seqs=8, max_num_batched_tokens=32, decode_buckets=(4, 8),
-            prefill_buckets=(16, 32), decode_window=4),
-        attention_backend=backend, prefill_attention_backend=backend, seed=5,
-        **kw)
 
 
 # -- the two page groups -----------------------------------------------------
@@ -80,31 +62,31 @@ def _drain(engine, ids):
     return [done[i] for i in ids]
 
 
-def test_a_full_group_preempts_and_the_rows_resume(cfg):
+def test_a_full_group_preempts_and_the_rows_resume(cfg, kit):
     """With a window group too small for the rows in flight, the window
     group preempts (the full group has room), the preempted rows resume
     from nothing, and every row's greedy tokens equal those of an engine
     with room for all: nothing read a page it had given back or lost."""
-    model = _cut(cfg, 5)
+    config = ek.engine_config(ek.cut(cfg, 5))
     prompts = [list(range(3 + i, 60 + 5 * i)) for i in range(4)]
-    sampling = SamplingParams(max_tokens=20, temperature=0.0, ignore_eos=True)
 
-    def run(window_pages):
-        engine = LLMEngine(_engine_config(model))
-        if window_pages:
-            # the engine sizes the group for every seat's worst case, so
-            # it never fills: hand the allocator fewer pages than the
-            # arrays hold
-            group = engine.scheduler.pool.window
-            engine.scheduler.pool.window = WindowPageGroup(
-                window_pages, group.block_size, group.window)
-        ids = [engine.add_request(prompt_token_ids=p, sampling=sampling)
+    def run(engine):
+        ids = [engine.add_request(prompt_token_ids=p, sampling=ek.greedy(20))
                for p in prompts]
-        return engine, _drain(engine, ids)
+        return _drain(engine, ids)
 
-    roomy, want = run(None)
-    tight, got = run(10)
-    assert roomy.scheduler.total_preemptions == 0
+    roomy, _ = kit.engine(config)
+    preempted = roomy.scheduler.total_preemptions
+    want = run(roomy)
+    # the engine sizes the group for every seat's worst case, so it never
+    # fills: hand an engine of its own an allocator of fewer pages than the
+    # arrays hold
+    tight = kit.fresh_engine(config)
+    group = tight.scheduler.pool.window
+    tight.scheduler.pool.window = WindowPageGroup(
+        10, group.block_size, group.window)
+    got = run(tight)
+    assert roomy.scheduler.total_preemptions == preempted
     assert tight.scheduler.total_preemptions > 0
     assert tight.scheduler.pool.num_free > 64  # the full group never filled
     assert got == want
@@ -117,15 +99,13 @@ def test_a_full_group_preempts_and_the_rows_resume(cfg):
     assert set(stats.kv_group_usage) == {"full", "window"}
 
 
-def test_kv_usage_is_the_fuller_groups_share_and_metrics_carry_both(cfg):
+def test_kv_usage_is_the_fuller_groups_share_and_metrics_carry_both(cfg, kit):
     from vllm_production_stack_tpu import metrics_contract as mc
     from vllm_production_stack_tpu.engine.metrics import EngineMetrics
 
-    engine = LLMEngine(_engine_config(_cut(cfg, 5)))
-    engine.add_request(
-        prompt_token_ids=list(range(1, 100)),
-        sampling=SamplingParams(max_tokens=40, temperature=0.0,
-                                ignore_eos=True))
+    engine, before = kit.engine(ek.engine_config(ek.cut(cfg, 5)))
+    rid = engine.add_request(
+        prompt_token_ids=list(range(1, 100)), sampling=ek.greedy(40))
     for _ in range(5):
         with engine.step_lock:
             engine.step()
@@ -140,11 +120,12 @@ def test_kv_usage_is_the_fuller_groups_share_and_metrics_carry_both(cfg):
     for group in ("full", "window"):
         assert f'group="{group}"' in text
     assert mc.HBM_KV_WINDOW_SHARE in text
-    timing = engine.timing
+    timing = ek.since(engine, before)
     assert 0 < timing["moe_prefill_held_pairs_n"] < timing[
         "moe_prefill_pairs_n"]
     # slots count HELD experts: 4 expert layers x 4 held a step
     assert timing["moe_prefill_slots_n"] % 16 == 0
+    ek.finish(engine, rid)
 
 
 @pytest.mark.parametrize("name", [
@@ -230,13 +211,13 @@ def test_published_sizes_of_the_cut():
     (dict(model_kw=dict(quantization="int8")),
      "quantization int8 is not implemented for a param tree stacked"),
 ])
-def test_unbuilt_engine_options_are_refused_by_name(cfg, kw, message):
+def test_unbuilt_engine_options_are_refused_by_name(cfg, kit, kw, message):
     """What knows the flat param tree only is refused for a tree stacked
     by layer kind, each by name."""
     kw = dict(kw)
     model = dataclasses.replace(cfg, **kw.pop("model_kw", {}))
     with pytest.raises(ValueError, match=message):
-        LLMEngine(_engine_config(model, **kw))
+        kit.fresh_engine(ek.engine_config(model, **kw))
 
 
 def _hf_config(tmp_path, **over):
@@ -309,12 +290,12 @@ def test_a_checkpoint_of_the_family_is_refused_by_name(tmp_path):
         load_checkpoint_params(model)
 
 
-def test_a_row_reads_the_same_alone_and_among_others(cfg):
+def test_a_row_reads_the_same_alone_and_among_others(cfg, kit):
     """The batch-invariance probe of the benchmark's set-up, at tiny size:
     the same prompt alone and among other seats' rows (other contexts,
     other give-back states, a wider dispatch) gives the same first-token
     distribution — a row's two tables reach no other row's pages."""
-    engine = LLMEngine(_engine_config(_cut(cfg, 5)))
+    engine, _ = kit.engine(ek.engine_config(ek.cut(cfg, 5)))
     probe = list(range(7, 7 + 45))
     sampling = SamplingParams(max_tokens=1, temperature=0.0, logprobs=8)
 
@@ -332,8 +313,7 @@ def test_a_row_reads_the_same_alone_and_among_others(cfg):
     for i in range(3):  # holders: longer rows that decode meanwhile
         engine.add_request(
             prompt_token_ids=list(range(1 + i, 80 + 9 * i)),
-            sampling=SamplingParams(max_tokens=24, temperature=0.0,
-                                    ignore_eos=True))
+            sampling=ek.greedy(24))
     for _ in range(8):
         with engine.step_lock:
             engine.step()
@@ -343,20 +323,18 @@ def test_a_row_reads_the_same_alone_and_among_others(cfg):
     assert max(abs(a - b) for a, b in zip(alone[2], crowded[2])) < 1e-5
 
 
-def test_ngram_speculation_verifies_through_both_groups(cfg):
+def test_ngram_speculation_verifies_through_both_groups(cfg, kit):
     """The speculative verify program writes and reads both page groups
     like a prefill chunk, and a rejected draft rolls both tables back: a
     repetitive prompt (drafts get proposed) decodes to the plain engine's
     greedy tokens."""
-    model = _cut(cfg, 5)
+    model = ek.cut(cfg, 5)
     prompt = [5, 6, 7, 8, 9, 10, 11, 12] * 7
-    sampling = SamplingParams(max_tokens=24, temperature=0.0, ignore_eos=True)
 
     def run(**sched):
-        econf = _engine_config(model)
-        engine = LLMEngine(econf.replace(scheduler=dataclasses.replace(
-            econf.scheduler, **sched)))
-        rid = engine.add_request(prompt_token_ids=prompt, sampling=sampling)
+        engine, _ = kit.engine(ek.engine_config(model, sched=sched))
+        rid = engine.add_request(
+            prompt_token_ids=prompt, sampling=ek.greedy(24))
         return engine, _drain(engine, [rid])[0]
 
     _, want = run()
