@@ -180,6 +180,47 @@ ROUTING_MARGIN_MAX_SOLAR = 0.04
 # 1.65e-2, 1.81e-2, 2.14e-2 (PR 39, chip, PERF.md §6): the limit lies
 # between, 6 x over the one and 16 x under the other.
 KDA_STATE_RTOL = 1e-3
+# Ling-3.0-flash's language model at 7 layers (bf16 weights, activations and
+# latent pages, float32 state slots, the KDA and the latent kernels in one
+# program; benchmarks/chip/configs/ling-hybrid-bf16-ep4-7l.json) against
+# reference/ling.py (float32, the token-by-token recurrence, the expanded
+# MLA equations), which FOLLOWS the served programs' routing (top-8 of 512
+# sigmoid scores + bias under a group limit, renormalised). Each limit lies
+# between its two readings on the chip at the published widths (PR 45,
+# PERF.md §6; prompts of 16,320, 9,184, four in 2k-16k and one of 48 tokens,
+# 32 decoded positions each, 1,792 logprobs a seed; seeds 4000000007 and
+# 3100000033): what the served configuration gave, and what has to come out
+# as not correct — the served logprobs of the SHORTEST prompt against
+# references that are wrong in one way each, by ONE of the limits and not by
+# each.
+# max |Δlogprob|: served 0.3232, 0.1155; a convolution history not zeroed
+# 0.646, 0.644, a state not zeroed 0.918, 1.883, beta with a factor 2 2.46,
+# 2.33, the unbounded gate 5.63, 4.96. The latent layer's gate left out reads
+# 0.412, 0.450 — one gated layer of seven: the MEAN and the routing tell it.
+REFERENCE_ATOL_LING = 0.50
+# mean |Δlogprob|: served 0.0251, 0.0248; the latent layer's gate left out
+# 0.1175, 0.1279 (the same faults as above 0.151-2.54): 2.4 x over the one,
+# 2 x under the other
+REFERENCE_MEAN_ATOL_LING = 0.06
+# the served choice differed from the reference's own in 20.2% of (token,
+# layer) pairs on both seeds (top-8 of 512 under a group limit: a flipped
+# GROUP moves several experts), each within 0.0544, 0.0475 of the
+# reference's 8th best choice score; the gate left out: 37.8%, 39.9% (the
+# KDA faults 85-99%)
+ROUTING_DISAGREEMENT_MAX_LING = 0.30
+ROUTING_MARGIN_MAX_LING = 0.085
+# A bfloat16 STATE (served state 3.2e-4, 8.8e-5 against 2.5e-2, 1.9e-2:
+# KDA_STATE_RTOL above) and a bfloat16 ROUTER are the nearest precisions
+# below the float32 the configuration states, and neither shows in the
+# logprobs (0.088-0.122 / mean 0.022-0.027, inside) nor in the routing the
+# reference follows (19.6-26.2%, as the served 20.2%: the served programs'
+# bf16 ACTIVATIONS already move a fifth of the choices). So the served ROUTER
+# has a limit of its own (reference_check.served_router): the reference's
+# `route` on the very inputs the last expert layer's router was fed (tapped)
+# against the experts it chose, token by token. Served: 0 of 38,528 and 0 of
+# 55,552 tokens differ; a router whose product and scores are bfloat16:
+# 12.59%, 12.64% (margins up to 0.035). The limit lies between.
+ROUTER_CHOICE_DISAGREEMENT_MAX_LING = 0.005
 # SDAR-30B-A3B (generation by diffusion over blocks, bf16 weights, 8 of 48
 # layers; benchmarks/chip/configs/sdar-30b-a3b-bf16-8l.json): the served
 # windows of block passes against reference/sdar.py's forward over the same
@@ -862,7 +903,8 @@ def child_reference(args) -> int:
     # full layers, a per-head gate, a share of the experts) or solar_open2's
     # (KDA layers over state slots between gated NoPE GQA layers)
     family = {"deepseek_v3": "mla", "laguna": "laguna",
-              "solar_open2": "solar_open2", "sdar_moe": "sdar"}.get(
+              "solar_open2": "solar_open2", "sdar_moe": "sdar",
+              "ling_flash": "ling"}.get(
         config.get("model_type"), "decoder")
     # (the two families whose reference follows the served routing and
     # whose cell is the long-document one)
@@ -889,6 +931,8 @@ def child_reference(args) -> int:
         "solar_open2": (REFERENCE_ATOL_SOLAR, REFERENCE_MEAN_ATOL_SOLAR,
                         ROUTING_DISAGREEMENT_MAX_SOLAR,
                         ROUTING_MARGIN_MAX_SOLAR),
+        "ling": (REFERENCE_ATOL_LING, REFERENCE_MEAN_ATOL_LING,
+                 ROUTING_DISAGREEMENT_MAX_LING, ROUTING_MARGIN_MAX_LING),
     }[family]
     flags = (config["rehearse"] if args.rehearse else config)["engine_flags"]
     dev = jax.devices()[0]
@@ -913,7 +957,9 @@ def child_reference(args) -> int:
     lengths = np.exp(
         rng.uniform(np.log(lo), np.log(hi), size=n_prompts)).astype(int)
     lengths[:2] = [hi, (chunk + hi) // 2]  # two prompts cross a chunk edge
-    if family == "solar_open2":
+    # (the families most of whose cache is recurrent state)
+    holds_state = family in ("solar_open2", "ling")
+    if holds_state:
         # ... and one SHORT prompt, the one the faulty references are held
         # to: what a slot's previous holder left (a state, a convolution
         # history) is forgotten a few hundred tokens into a row, so only a
@@ -935,8 +981,12 @@ def child_reference(args) -> int:
         tap = reference_check.RoutingTap() if mla else None
         linear = ecfg.model.linear_layers
         state_tap = reference_check.StateTap(linear[-1]) if linear else None
+        # (ling: and what its last expert layer's ROUTER was fed and chose)
+        router_tap = (reference_check.RouterTap(
+            ecfg.model.num_moe_layers - 1) if family == "ling" else None)
         with (tap or contextlib.nullcontext(),
-              state_tap or contextlib.nullcontext()):
+              state_tap or contextlib.nullcontext(),
+              router_tap or contextlib.nullcontext()):
             engine = LLMEngine(ecfg)
             device = engine.runner.device_report()
             out = {"attention": device.get("attention"),
@@ -948,7 +998,16 @@ def child_reference(args) -> int:
                 engine.runner.kv_caches[state_tap.layer]["s"])
             out["state"] = reference_check.served_state(
                 reference, ecfg.model, state_tap, state_pool)
+        if router_tap:
+            from vllm_production_stack_tpu.models import loader
+
+            served_view = loader.LingCheckpointView(
+                ecfg.model, engine.runner.params)
+            out["router"] = reference_check.served_router(
+                reference, ecfg.model, router_tap, served_view)
         out["engine_s"] = round(time.monotonic() - t0, 1)
+        print(f"reference check: served in {out['engine_s']} s",
+              file=sys.stderr, flush=True)
         # the reference needs the weights as served and room for one
         # layer in float32: let the pool and the programs go
         params = engine.runner.params
@@ -965,6 +1024,8 @@ def child_reference(args) -> int:
                 out["attention"]["prefill"]["resolved"] if mla else "xla"),
             served_routing=tap))
         out["reference_s"] = round(time.monotonic() - t0, 1)
+        print(f"reference check: compared in {out['reference_s']} s",
+              file=sys.stderr, flush=True)
         if faults:
             # the shortest prompt's served logprobs against references that
             # are each wrong in ONE way: the tolerance has to catch each
@@ -976,15 +1037,24 @@ def child_reference(args) -> int:
                     faulty, ecfg.model, params, [prompts[short]],
                     [served[short]], served_routing=tap)
                 out["faults"][name] = {
-                    k: got[k] for k in ("max_abs_dlogprob",
-                                        "mean_abs_dlogprob")}
+                    k: got[k] for k in (
+                        "max_abs_dlogprob", "mean_abs_dlogprob",
+                        # (ling: a router in a lower precision shows in
+                        # the routing, which the reference follows)
+                        *(("routing_disagreement_share",
+                           "routing_margin_max_where_differing")
+                          if family == "ling" else ()))}
                 if state_tap:
                     out["faults"][name]["state"] = (
                         reference_check.served_state(
                             faulty, ecfg.model, state_tap, state_pool))
+                if router_tap:
+                    out["faults"][name]["router"] = (
+                        reference_check.served_router(
+                            faulty, ecfg.model, router_tap, served_view))
         return out
 
-    served = arm([], faults=family in ("laguna", "solar_open2"))
+    served = arm([], faults=holds_state or family == "laguna")
     report["served"] = served
     routing_ok = (
         "routing_disagreement_share" not in served
@@ -997,18 +1067,29 @@ def child_reference(args) -> int:
         return (arm["max_abs_dlogprob"] <= atol
                 and (mean_atol is None
                      or arm["mean_abs_dlogprob"] <= mean_atol)
+                and (family != "ling" or (
+                    arm["routing_disagreement_share"] <= disagreement_max
+                    and arm["routing_margin_max_where_differing"]
+                    <= margin_max))
                 and ("state" not in arm
-                     or arm["state"]["state_rel_err"] <= KDA_STATE_RTOL))
+                     or arm["state"]["state_rel_err"] <= KDA_STATE_RTOL)
+                and ("router" not in arm
+                     or arm["router"]["choice_disagreement_share"]
+                     <= ROUTER_CHOICE_DISAGREEMENT_MAX_LING))
 
-    if family == "solar_open2":
+    if holds_state:
         report["state_rtol"] = KDA_STATE_RTOL
+    if family == "ling":
+        report["router_choice_disagreement_max"] = (
+            ROUTER_CHOICE_DISAGREEMENT_MAX_LING)
     report["ok"] = bool(
         inside(served) and routing_ok
         and not any(inside(f) for f in served.get("faults", {}).values()))
-    if args.fp8_arm and family != "solar_open2":
-        # (solar_open2: the precision below the configuration's is a
+    if args.fp8_arm and not holds_state:
+        # (solar_open2, ling: the precision below the configuration's is a
         # bfloat16 STATE, one of its faulty references: most of its cache
-        # is state, and an fp8 pool would touch the 2 GQA layers only)
+        # is state, and an fp8 pool would touch the 2 GQA layers — ling:
+        # the one latent layer — only)
         # (a quantized pool resolves 'auto' to XLA attention, whose
         # gathered history and scores a long-document prompt cannot
         # afford: the latent kernels widen fp8 pages themselves)
@@ -1151,8 +1232,27 @@ def _faulty_references(reference, family: str = "laguna") -> dict:
 
         return types.SimpleNamespace(
             forward=reference.forward, published_keys=published_keys,
-            delta_rule=getattr(reference, "delta_rule", None))
+            **{name: getattr(reference, name, None) for name in (
+                "delta_rule", "route", "check_config", "f32")})
 
+    if family == "ling":
+        # the per-head gate of the latent layer left out, the group limit
+        # left out of the routing weights' choice, the unbounded decay
+        # gate, beta with a factor 2, a slot's state or convolution tail
+        # trusted from the row before, a state kept in bfloat16 and a
+        # router in bfloat16 — the nearest precisions below the float32
+        # the configuration states for both
+        edits = {
+            "mla_gate_left_out": {
+                "gated_attention_proj_granularity_type": None},
+            "unbounded_gate": {"kda_safe_gate": False},
+            "beta_with_factor_2": {"kda_beta_scale": 2.0},
+            "state_not_zeroed": {"kda_state_inherited": True},
+            "conv_across_a_seam": {"kda_conv_inherited": True},
+            "bf16_state": {"kda_state_dtype": "bfloat16"},
+            "bf16_router": {"router_dtype": "bfloat16"}}
+        return {name: variant(lambda cfg, e=e: cfg.update(e))
+                for name, e in edits.items()}
     if family == "solar_open2":
         # a gate left out, beta without its factor 2, a slot's state or
         # convolution tail trusted from the row before (here: an identical

@@ -118,7 +118,8 @@ def undrained(engine) -> str:
 # what a program is traced under, as imported (see the docstring)
 _TRACED_UNDER = {(mod, name): getattr(mod, name) for mod, name in (
     (llama, "_ALL_EXPERTS_MAX_TOKENS"), (model_runner, "_BD_COMMIT_PASS"),
-    (llama, "ROUTING_TAP"), (llama, "STATE_TAP"), (llama, "BD_TAP"))}
+    (llama, "ROUTING_TAP"), (llama, "STATE_TAP"), (llama, "BD_TAP"),
+    (llama, "ROUTER_TAP"))}
 
 
 class EngineKit:

@@ -367,7 +367,9 @@ def test_checkpoint_names_round_trip_with_the_rope_de_interleave(
 
 @pytest.mark.parametrize("override,message", [
     ({"q_lora_rank": 1536}, "q_lora_rank"),
-    ({"n_group": 8, "topk_group": 4}, "n_group > 1"),
+    # (group-limited routing is served since PR 45 where the groups are
+    # of equal size: tests/test_ling_parts.py)
+    ({"n_group": 3, "topk_group": 2}, "n_group that does not divide"),
     ({"rope_scaling": {"type": "yarn", "factor": 40}}, "rope_scaling"),
 ])
 def test_unbuilt_config_keys_are_refused_by_name(tmp_path, cfg, params,

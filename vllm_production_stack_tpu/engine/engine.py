@@ -62,6 +62,7 @@ class EngineStatsSnapshot:
     block_diffusion: dict = field(default_factory=dict)
     state_slots_in_use: int = 0
     state_share: float = 0.0
+    latent_bytes: int = 0  # latent pages in use x their bytes
     prefix_cache_hit_rate: float = 0.0
     prefix_cache_hits: int = 0
     prefix_cache_queries: int = 0
@@ -235,7 +236,7 @@ class LLMEngine:
                     "own (%d pages: the window, a chunk and two decode "
                     "windows a seat), given back behind the window",
                     config.model.model, window_blocks)
-            elif not config.model.mla:
+            elif not config.model.latent_layers:
                 logger.info(
                     "%s: window layers hold the whole context like the full "
                     "layers (the window is a mask), because this "
@@ -277,7 +278,7 @@ class LLMEngine:
                     ),
                 )
             )
-        if config.model.mla:
+        if config.model.latent_layers:
             _refuse_kv_tiers_for_latent_pool(config)
         self.config = config
         self.tokenizer = tokenizer or TokenizerWrapper(
@@ -691,6 +692,15 @@ class LLMEngine:
         if self.config.model.block_diffusion is not None:
             _refuse_request_for_block_diffusion(
                 self.config.model.model, sampling)
+        refused = self.config.model.refused_token_ids
+        if refused and not set(refused).isdisjoint(prompt_token_ids):
+            # image / video placeholder tokens: the tower that would fill
+            # them in is not served (a ValueError is the server's 400)
+            raise ValueError(
+                f"{self.config.model.model}: the prompt holds image or "
+                f"video placeholder tokens "
+                f"{sorted(set(refused).intersection(prompt_token_ids))} — "
+                "only the language model is served, text alone")
         tenant = tenant or TenantContext()
         req = Request(
             request_id=request_id,
@@ -1431,6 +1441,14 @@ class LLMEngine:
                 rows = handle.resolve()
                 rec.resolve(handle.seq, handle.bd_passes)
             rec.count(key + "_n")
+            # layers of each mixer kind the dispatch ran: the model's count
+            # a pass over the layers, a decode window `window` passes
+            passes = work.window if kind == "decode" else 1
+            for name, layers in (
+                    ("mla_layers_n", self.config.model.latent_layers),
+                    ("kda_layers_n", self.config.model.linear_layers)):
+                if layers:
+                    rec.count(name, passes * len(layers))
             if kind == "prefill" and self.runner.prefill_uses_kernel:
                 rec.count("prefill_kernel_n")
             self.scheduler.end_speculative(work)
@@ -1502,17 +1520,21 @@ class LLMEngine:
         bank that is current, and the other bank's are the price of an
         exact rollback — both count."""
         pool = self.scheduler.pool
-        if pool.state is None:
+        cfg = self.config
+        if pool.state is None and not cfg.model.latent_layers:
             return {}
         from .memory import kv_block_bytes, state_slot_bytes
 
-        cfg = self.config
-        used = pool.state.num_in_use
-        state = 2 * used * state_slot_bytes(cfg.model)
         pages = (pool.num_usable - pool.num_free) * kv_block_bytes(
             cfg.model, cfg.cache.block_size,
             kv_dtype=cfg.cache.resolved_kv_dtype(cfg.model.dtype))
-        return {"state_bytes": state, "state_slots_in_use": used,
+        # (a model's pages are all latents or none is)
+        out = {"latent_bytes": pages} if cfg.model.latent_layers else {}
+        if pool.state is None:
+            return out
+        used = pool.state.num_in_use
+        state = 2 * used * state_slot_bytes(cfg.model)
+        return {**out, "state_bytes": state, "state_slots_in_use": used,
                 "state_share": state / (state + pages) if state else 0.0}
 
     def _rollback(self, nxt: _InflightStep) -> None:

@@ -249,12 +249,18 @@ def _deserialize_blocks_npz(payload: bytes):
     return hashes, blocks, fingerprint
 
 
+def _page_leaf(runner):
+    """The first layer's pool that holds pages (a layer that holds state has
+    a dict of state pools, and every KV tier is refused beside those)."""
+    return next(kv for kv in runner.kv_caches if not isinstance(kv, dict))
+
+
 def engine_block_shape(runner) -> tuple[int, ...]:
     """(L, 2, block_size, kvH, D) — the page geometry of one engine's pool.
     The ONE definition shared by import validation (KVTransfer), remote-match
     validation (KVBlockPool.expected_block_shape) and the stream receiver's
     frame-size bound: a layout change lands everywhere or nowhere."""
-    leaf = runner.kv_caches[0]
+    leaf = _page_leaf(runner)
     return (len(runner.kv_caches), 2, leaf.shape[2], *leaf.shape[3:])
 
 
@@ -264,7 +270,7 @@ def engine_block_nbytes(runner) -> int:
     n = 1
     for d in shape:
         n *= int(d)
-    return n * runner.kv_caches[0].dtype.itemsize
+    return n * _page_leaf(runner).dtype.itemsize
 
 
 class KVTransfer:

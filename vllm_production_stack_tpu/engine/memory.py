@@ -58,13 +58,7 @@ def layer_matrix_params(cfg: ModelConfig) -> dict:
     which MLP."""
     h = cfg.hidden_size
     if cfg.mla:
-        nh = cfg.num_heads
-        attn = (
-            h * nh * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)  # W_q
-            + h * cfg.latent_dim  # W_kv_a
-            + cfg.kv_lora_rank * nh * (cfg.qk_nope_head_dim + cfg.v_head_dim)
-            + nh * cfg.v_head_dim * h  # W_o
-        )
+        attn = latent_attn_params(cfg, cfg.num_heads)
     else:
         attn = (
             h * cfg.num_heads * cfg.head_dim
@@ -81,6 +75,19 @@ def layer_matrix_params(cfg: ModelConfig) -> dict:
         "dense_layers": cfg.num_layers - moe_layers,
         "moe_layers": moe_layers,
     }
+
+
+def latent_attn_params(cfg: ModelConfig, num_heads: int) -> int:
+    """Matrix parameters of ONE latent layer's attention: W_q, W_kv_a,
+    W_kv_b, W_o."""
+    h = cfg.hidden_size
+    return (
+        h * num_heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)  # W_q
+        + h * cfg.latent_dim  # W_kv_a
+        + cfg.kv_lora_rank * num_heads * (
+            cfg.qk_nope_head_dim + cfg.v_head_dim)  # W_kv_b
+        + num_heads * cfg.v_head_dim * h  # W_o
+    )
 
 
 def param_bytes(cfg: ModelConfig, tp: int = 1, pp: int = 1,
@@ -129,11 +136,18 @@ def param_bytes(cfg: ModelConfig, tp: int = 1, pp: int = 1,
             # output norm; A_log and dt_bias in float32
             lhd = cfg.linear_num_heads * cfg.linear_head_dim
             r = cfg.linear_gate_rank
-            sharded = (4 * h * lhd + 2 * (h * r + r * lhd)
+            sharded = (4 * h * lhd
+                       # (rank 0: both gates project at full rank)
+                       + 2 * ((h * r + r * lhd) if r else h * lhd)
                        + h * cfg.linear_num_heads
                        + LINEAR_CONV_TAPS * 3 * lhd
                        + cfg.linear_head_dim)
             f32_extra += 4 * (cfg.linear_num_heads + lhd)
+        elif spec.latent:
+            # the latent mixer (models/llama.py:attn_latent): its four
+            # matrices, the latent's norm, the gate a head
+            sharded = (latent_attn_params(cfg, nh) + cfg.kv_lora_rank
+                       + (h * nh if cfg.attn_output_gate else 0))
         elif cfg.attn_output_gate:
             sharded += h * nh * (
                 hd if cfg.attn_output_gate == "element" else 1)
@@ -167,11 +181,13 @@ def kv_block_bytes(cfg: ModelConfig, block_size: int, tp: int = 1,
     (engine/kv_cache.py: a model with window layers keeps two groups of
     pages, each sized on its own; 0 for a group without layers); None
     counts every layer (a model with one group)."""
-    if cfg.mla:
-        # one latent a token and layer, `latent_width` values as stored
-        # (the zero padding to whole lane tiles is real HBM, so it counts)
+    if cfg.latent_layers:
+        # one latent a token and latent layer, `latent_width` values as
+        # stored (the zero padding to whole lane tiles is real HBM, so it
+        # counts); a model's pages are all latents or none is, and a layer
+        # that holds state has none
         return max(1, (
-            cfg.num_layers * block_size * cfg.latent_width
+            len(cfg.latent_layers) * block_size * cfg.latent_width
             * dtype_bytes(kv_dtype or cfg.dtype)
         ) // pp)
     kvh = max(1, cfg.num_kv_heads // tp)
@@ -233,7 +249,8 @@ def resolve_window_blocks(config: EngineConfig) -> int | None:
     do. The ONE rule the engine, the runner's arrays and the scheduler's
     allocator follow."""
     model = config.model
-    if not model.any_sliding or model.mla or window_group_blockers(config):
+    if (not model.any_sliding or model.latent_layers
+            or window_group_blockers(config)):
         return None
     return config.scheduler.max_num_seqs * window_row_blocks(
         model, config.cache, config.scheduler) + 1

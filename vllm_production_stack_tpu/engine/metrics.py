@@ -198,6 +198,10 @@ class EngineMetrics:
             mc.HBM_STATE_SHARE,
             "State bytes in use / (state + page bytes in use)",
         )
+        self.latent_bytes = gauge(
+            mc.HBM_LATENT_BYTES,
+            "Bytes of latent pages in use (latent layers' pages held)",
+        )
         self.block_diffusion = {
             key: counter(name, f"Generation by diffusion over blocks: {key}")
             for key, name in mc.DEBUG_TIMING_BD_COUNTS.items()
@@ -693,6 +697,7 @@ class EngineMetrics:
         self.state_bytes.labels(**lb).set(s.state_bytes)
         self.state_slots_in_use.labels(**lb).set(s.state_slots_in_use)
         self.state_share.labels(**lb).set(s.state_share)
+        self.latent_bytes.labels(**lb).set(s.latent_bytes)
         for key, metric in self.block_diffusion.items():
             self._bump(metric, key, s.block_diffusion.get(key, 0))
         self.prefix_hit_rate.labels(**lb).set(s.prefix_cache_hit_rate)

@@ -147,10 +147,11 @@ def resolve_auto_prefill_backend(
 
 
 def _refuse_unbuilt_for_latent_attention(config: EngineConfig) -> None:
-    """A multi-head-latent-attention model (models/llama.py:_init_params_mla)
-    runs on ONE device, unquantized, without adapters: what has no layout
-    for its leaves or its latent pool is refused by name at start, never
-    guessed."""
+    """A model with latent layers (LayerSpec.latent: every layer,
+    models/llama.py:_init_params_mla, or some, the "attn_latent" stack of
+    _init_params_by_kind) runs on ONE device, unquantized, without
+    adapters: what has no layout for its leaves or its latent pool is
+    refused by name at start, never guessed."""
     cfg, name = config.model, config.model.model
     if config.parallel.world_size > 1:
         raise ValueError(
@@ -190,10 +191,15 @@ def _moe_device_counts(cfg, stats: list):
     (ModelConfig.num_experts_held), beside it the pairs that landed on the
     held experts, as (2,) int32."""
     touched = sum((s["touched"] for s in stats), jnp.int32(0))
-    if not cfg.num_experts_held:
+    if not cfg.num_experts_held and cfg.n_group == 1:
         return touched
-    return jnp.stack(
-        [touched, sum((s["held_pairs"] for s in stats), jnp.int32(0))])
+    counts = [touched, sum((s["held_pairs"] for s in stats), jnp.int32(0))]
+    if cfg.n_group > 1:
+        # group-limited routing: and the kept groups that hold a held
+        # expert, as (3,) int32
+        counts.append(
+            sum((s["groups_kept_held"] for s in stats), jnp.int32(0)))
+    return jnp.stack(counts)
 
 
 def _refuse_unbuilt_for_tree_by_kind(config: EngineConfig) -> None:
@@ -297,7 +303,8 @@ def _refuse_unbuilt_for_block_diffusion(config: EngineConfig) -> None:
             "slots are one a decode row"),
         "multi-head latent attention, window layers or layers that hold "
         "state": (
-            cfg.mla or cfg.any_sliding or bool(cfg.linear_layers),
+            bool(cfg.latent_layers) or cfg.any_sliding
+            or bool(cfg.linear_layers),
             "the block mask is built into the GQA kernels only"),
         "softcapped attention": (
             bool(cfg.attn_logit_softcap),
@@ -424,6 +431,16 @@ class StepHandle:
                 clock.count(
                     f"moe_{kind}_held_pairs_n",
                     int(counts[1]) if counts.size > 1 else pairs)
+                if counts.size > 2:
+                    # group-limited routing: the groups the router kept (a
+                    # token and layer-step `topk_group`), and those of them
+                    # that hold an expert this program holds
+                    model = self.runner.config.model
+                    clock.count("moe_group_kept_n", int(counts[2]))
+                    clock.count(
+                        "moe_group_slots_n",
+                        pairs // model.num_experts_per_tok
+                        * model.topk_group)
             self._rows, self.logprob_rows = self._postproc(mat, lp)
         return self._rows
 
@@ -476,7 +493,7 @@ class ModelRunner:
     ):
         self.config = config
         cfg = config.model
-        if cfg.mla:
+        if cfg.latent_layers:
             _refuse_unbuilt_for_latent_attention(config)
         if cfg.linear_layers:
             _refuse_unbuilt_for_recurrent_state(config)
@@ -734,7 +751,8 @@ class ModelRunner:
         config, cfg = self.config, self.config.model
         # (a latent pool has no kv-head axis and a state pool none to shard
         # over tp: both run on one device)
-        spec = P() if cfg.mla or cfg.linear_layers else kv_cache_spec()
+        spec = (P() if cfg.latent_layers or cfg.linear_layers
+                else kv_cache_spec())
         return jax.jit(
             lambda: llama.init_kv_cache(
                 cfg, config.cache.num_blocks, config.cache.block_size,
@@ -876,7 +894,7 @@ class ModelRunner:
         # fallback and the kernel's oracle; its pages are (block_size,
         # latent_width), one "kv head" wide
         kv_heads, head_dim = (
-            (1, model.latent_width) if model.mla
+            (1, model.latent_width) if model.latent_layers
             else (model.num_kv_heads, model.head_dim))
         if backend == "auto":
             return resolve_auto_prefill_backend(
@@ -887,7 +905,7 @@ class ModelRunner:
                 kv_quantized=self._kv_dtype != model.dtype,
                 mesh_size=self.mesh.size,
                 platform=jax.devices()[0].platform,
-                latent_pages=model.mla,
+                latent_pages=bool(model.latent_layers),
             )
         if backend not in ("xla", "pallas", "pallas_interpret"):
             raise ValueError(
@@ -900,9 +918,9 @@ class ModelRunner:
                 "num_heads and num_kv_heads divisible by tp, and a dp/tp "
                 "mesh (pp/sp/ep shard axes the kernel cannot split)"
             )
-        if backend == "pallas" and not model.mla and not _pages_fill_tiles(
-            model.num_kv_heads // tp, model.head_dim, kv_itemsize
-        ):
+        if (backend == "pallas" and not model.latent_layers
+                and not _pages_fill_tiles(
+                    model.num_kv_heads // tp, model.head_dim, kv_itemsize)):
             raise ValueError(
                 "prefill_attention_backend='pallas' needs a pool whose "
                 f"pages fill whole HBM tiles ({model.num_kv_heads // tp} kv "
@@ -1000,7 +1018,8 @@ class ModelRunner:
             # through its own)
             report["mixers"] = {
                 "by_layer": [s.mixer for s in cfg.layer_specs],
-                "softmax": len(cfg.page_layers),
+                "softmax": len(cfg.page_layers) - len(cfg.latent_layers),
+                "latent": len(cfg.latent_layers),
                 "linear": len(cfg.linear_layers),
                 "state_slots": self.state_slots,
                 "linear_backend": linear_backend_report(
@@ -1332,7 +1351,8 @@ class ModelRunner:
                      * self.config.cache.block_size)
             hoist = (
                 self._attention_backend == "xla"
-                and not cfg.mla  # (the latent fallback gathers per step)
+                and not cfg.latent_layers  # (the latent fallback gathers
+                #   per step)
                 and self._hoist_bytes(b, s_ctx) <= self._hoist_budget
             )
             hists = None

@@ -152,7 +152,7 @@ def attn_flops_per_token_ctx(cfg) -> float:
     """Attention score/value FLOPs per (token, attended position) pair,
     all layers: QK^T and PV over each head's widths (MLA, as published:
     qk_nope + qk_rope for the scores, v_head for the values)."""
-    if cfg.mla:
+    if cfg.latent_layers:
         per_head = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
                     + cfg.v_head_dim)
     else:

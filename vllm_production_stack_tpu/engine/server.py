@@ -220,7 +220,7 @@ class EngineServer:
     # -- app wiring --------------------------------------------------------
 
     def _refuse_for_latent_pool(self, handler):
-        if not self.engine.config.model.mla:
+        if not self.engine.config.model.latent_layers:
             return handler
 
         async def refused(request: web.Request) -> web.Response:

@@ -32,6 +32,12 @@ HBM_KV_WINDOW_SHARE = "tpu:hbm_kv_window_share"
 HBM_STATE_BYTES = "tpu:hbm_state_bytes"
 HBM_STATE_SLOTS_IN_USE = "tpu:hbm_state_slots_in_use"
 HBM_STATE_SHARE = "tpu:hbm_state_share"
+# ... and beside them the bytes of LATENT pages in use (pages held x the
+# bytes a page holds across the latent layers, engine/memory.py:
+# kv_block_bytes; 0 for a model no layer of which is a latent layer): with
+# HBM_STATE_BYTES the two caches of a model whose layers are latent or
+# recurrent.
+HBM_LATENT_BYTES = "tpu:hbm_latent_bytes"
 # generation by diffusion over blocks (ModelConfig.block_diffusion;
 # docs/44-block-diffusion.md; all 0 for every other model): what the decode
 # programs' windows of block passes counted on the device. A STEP of such a
@@ -622,6 +628,7 @@ ALL_GAUGES = (
     HBM_STATE_BYTES,
     HBM_STATE_SLOTS_IN_USE,
     HBM_STATE_SHARE,
+    HBM_LATENT_BYTES,
     PREFIX_CACHE_HIT_RATE,
     HOST_KV_USAGE_PERC,
     STEP_OVERLAP_FRAC,
@@ -745,4 +752,14 @@ DEBUG_TIMING_STATE_COUNTS = (
     "kda_decode_rows_n",
     "state_slots_zeroed_n",
     "state_recomputed_tokens_n",
+    # linear layers run: the model's count a prefill dispatch, times the
+    # window's steps a decode dispatch
+    "kda_layers_n",
 )
+# ... the same count of LATENT layers run, from a model that has any
+DEBUG_TIMING_LATENT_COUNTS = ("mla_layers_n",)
+# ... and what group-limited routing counts (ModelConfig.n_group > 1;
+# absent otherwise): the groups the router kept — `topk_group` a token and
+# expert layer run, padding tokens included — and those of them that hold
+# an expert this program holds (all of them without a held share)
+DEBUG_TIMING_GROUP_COUNTS = ("moe_group_kept_n", "moe_group_slots_n")

@@ -117,6 +117,13 @@ def _state_tap(layer: int, slots, positions):
         STATE_TAP, slots, positions.reshape(-1), *fed)
 
 
+# The same for the ROUTER of one expert layer (`ROUTER_TAP.moe_layer`, its
+# index among the expert layers): per execution, (the router's input (N, h)
+# as the program hands it to the router's matmul, the experts it chose
+# (N, k)). testing/reference_check.py:RouterTap.
+ROUTER_TAP = None
+
+
 def init_params(cfg: ModelConfig, rng: jax.Array) -> dict:
     """Random-init a stacked param tree (tests + benchmarks without weights)."""
     if cfg.mla:
@@ -273,7 +280,10 @@ def _init_params_by_kind(cfg: ModelConfig, rng: jax.Array) -> dict:
     given (ModelConfig.num_experts_held); "shared" is one ungated SwiGLU.
     `_layer_params` indexes each stack by the layer's own count. Layers
     whose mixer is linear (LayerSpec.holds_state) are a third stack,
-    "attn_linear" (`attn_linear` below)."""
+    "attn_linear" (`attn_linear` below), and layers whose pages hold one
+    latent a token (LayerSpec.latent) a fourth, "attn_latent"
+    (`attn_latent` below: `_init_params_mla`'s attention leaves, and the
+    per-head output gate where the model has one)."""
     unsaid = [name for name in (
         "attention_bias", "qk_norm", "qk_norm_flat", "sandwich_norms",
         "post_norms_only") if getattr(cfg, name)]
@@ -311,16 +321,20 @@ def _init_params_by_kind(cfg: ModelConfig, rng: jax.Array) -> dict:
         log U(1, 16)) and per channel the bias of its softplus (the inverse
         softplus of a step drawn log-uniform in [0.001, 0.1], both as the
         gated delta rule's public implementations initialise them: decays
-        from barely any to e^-1.6 a token at a zero gate input); the
-        output norm's weight, shared by the heads."""
+        from barely any to e^-1.6 a token at a zero gate input; under the
+        bounded gate the bias that gives the same decays); the output
+        norm's weight, shared by the heads."""
         lh, ld, r = (cfg.linear_num_heads, cfg.linear_head_dim,
                      cfg.linear_gate_rank)
         taps = LINEAR_CONV_TAPS
         leaves = {
             "wq": w(n, h, lh * ld), "wk": w(n, h, lh * ld),
             "wv": w(n, h, lh * ld), "wo": w(n, lh * ld, h),
-            "wf_a": w(n, h, r), "wf_b": w(n, r, lh * ld),
-            "wg_a": w(n, h, r), "wg_b": w(n, r, lh * ld),
+            # the decay gate's and the output gate's projections: low-rank
+            # pairs, or (linear_gate_rank 0) one full-rank matrix each
+            **({"wf_a": w(n, h, r), "wf_b": w(n, r, lh * ld),
+                "wg_a": w(n, h, r), "wg_b": w(n, r, lh * ld)} if r else
+               {"wf": w(n, h, lh * ld), "wg": w(n, h, lh * ld)}),
             "wb": w(n, h, lh),
             "conv": w(n, taps, 3 * lh * ld, scale=taps ** -0.5),
             "A_log": jnp.log(jax.random.uniform(
@@ -331,6 +345,35 @@ def _init_params_by_kind(cfg: ModelConfig, rng: jax.Array) -> dict:
             next(keys), (n, lh * ld), jnp.float32,
             jnp.log(1e-3), jnp.log(1e-1)))
         leaves["dt_bias"] = step + jnp.log(-jnp.expm1(-step))
+        if cfg.linear_gate_form == "bounded":
+            # the bounded gate, bound * sigmoid(rate (a + dt_bias)): the
+            # bias that gives the SAME decay at a zero gate input as the
+            # softplus form's, -rate x step (from barely any to e^-1.6 a
+            # token) — logit(rate step / |bound|) / rate. The softplus
+            # form's bias would leave every decay at ~0 (rate x its -2..-7)
+            rate = jnp.repeat(jnp.exp(leaves["A_log"]), ld, axis=-1)
+            share = rate * step / abs(cfg.linear_gate_lower_bound)
+            leaves["dt_bias"] = (jnp.log(share) - jnp.log1p(-share)) / rate
+        return leaves
+
+    def attn_latent(n, nh):
+        """The latent layers' stack (`_mla_attention`; the leaves of
+        `_init_params_mla`'s "attn"), and with `attn_output_gate` "head"
+        the gate's projection wg (n, h, heads)."""
+        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        rank = cfg.kv_lora_rank
+        if cfg.attn_output_gate not in ("", "head"):
+            raise ValueError(
+                f"{cfg.model}: attn_output_gate={cfg.attn_output_gate!r} is "
+                "not implemented for latent layers (a gate a head is: it "
+                "commutes with the absorbed value projection)")
+        leaves = {
+            "wq": w(n, h, nh * (dn + dr)), "wkv_a": w(n, h, rank + dr),
+            "kv_a_norm": jnp.ones((n, rank), dt),
+            "wkv_b": w(n, rank, nh * (dn + dv)), "wo": w(n, nh * dv, h)}
+        if cfg.attn_output_gate:
+            leaves["wg"] = w(n, h, nh)
         return leaves
 
     L = cfg.num_layers
@@ -345,6 +388,10 @@ def _init_params_by_kind(cfg: ModelConfig, rng: jax.Array) -> dict:
                 layers[name] = attn(len(idx), specs[idx[0]].num_heads)
         if cfg.linear_layers:
             layers["attn_linear"] = attn_linear(len(cfg.linear_layers))
+        if cfg.latent_layers:
+            layers["attn_latent"] = attn_latent(
+                len(cfg.latent_layers),
+                specs[cfg.latent_layers[0]].num_heads)
     else:
         layers["attn"] = attn(L, specs[0].num_heads)
     def w_by_layer(n, *shape):
@@ -442,7 +489,7 @@ def init_kv_cache(
     state_slots: int = 0,
 ) -> tuple[jax.Array, ...]:
     """Paged pool: a TUPLE of per-layer (2, num_blocks, block_size, kvH, D)
-    arrays (MLA: (1, num_blocks, block_size, latent_width)), NOT one stacked
+    arrays (a latent layer's: (1, num_blocks, block_size, latent_width)), NOT one stacked
     array. Per-layer leaves let jit donation alias
     each layer's pool in place; a stacked pool updated inside a scan forces
     XLA to hold a second full-pool buffer (observed +9.8 GiB on a
@@ -461,11 +508,6 @@ def init_kv_cache(
     a dispatch reads a row's slot in one bank and writes it in the other
     (engine/kv_cache.py:StateSlots)."""
     dt = jnp.dtype(dtype) if dtype is not None else _dtype(cfg)
-    if cfg.mla:
-        # ONE plane a layer: a token's latent (ops/mla_attention.py), no
-        # second copy of it as "V"
-        shape = (1, num_blocks, block_size, cfg.latent_width)
-        return tuple(jnp.zeros(shape, dt) for _ in range(cfg.num_layers))
     lh, ld = cfg.linear_num_heads, cfg.linear_head_dim
     return tuple(
         {"s": jnp.zeros((2 * state_slots, lh, ld, ld), jnp.float32),
@@ -473,12 +515,22 @@ def init_kv_cache(
              (2 * state_slots, LINEAR_CONV_TAPS - 1, 3 * lh * ld),
              _dtype(cfg))}
         if spec.holds_state else
+        # a latent layer: ONE plane, a token's latent
+        # (ops/mla_attention.py), no second copy of it as "V"
+        jnp.zeros((1, num_blocks, block_size, cfg.latent_width), dt)
+        if spec.latent else
         jnp.zeros((
             2,
             num_window_blocks if spec.window and num_window_blocks
             else num_blocks,
             block_size, cfg.num_kv_heads, cfg.head_dim), dt)
         for spec in cfg.layer_specs)
+
+
+def _page_tokens(cfg: ModelConfig, kv_caches) -> int:
+    """Tokens a page holds, read off the first layer that holds pages
+    (axis 2 of a K/V pool and of a latent pool alike)."""
+    return kv_caches[cfg.page_layers[0]].shape[2]
 
 
 def _group(x, window: int):
@@ -494,7 +546,7 @@ def lora_module_dims(cfg: ModelConfig) -> dict[str, tuple[int, int]]:
     attention projections (per-expert MLP LoRA would need per-expert deltas —
     the MoE path never consults the adapter tree, so advertising mlp modules
     there would be a silent no-op)."""
-    if cfg.mla:
+    if cfg.latent_layers:
         raise ValueError(
             "LoRA adapters are not implemented for multi-head latent "
             f"attention ({cfg.model}): its projections are wq, wkv_a, wkv_b")
@@ -593,7 +645,7 @@ def _layer_body(
 
     res = x
     ap = lp["attn"]
-    if cfg.mla:
+    if spec.latent:
         x = _mla_attention(cfg, ap, lp["input_norm"], x, positions, attend)
         return _mlp_block(cfg, lp, res + x, proj, moe_stats, moe_backend)
     if spec.holds_state:
@@ -683,19 +735,33 @@ def _kda_attention(cfg: ModelConfig, ap: dict, norm: jax.Array,
         k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
         q, k, v = (y.astype(x.dtype) for y in (q, k, v))
     with jax.named_scope("kda_gate"):
-        a = _mm(_mm(u, ap["wf_a"]), ap["wf_b"]).astype(f32)
-        # log of the decay a key channel: -exp(A_log) softplus(a + dt_bias)
-        g = (-jnp.exp(ap["A_log"].astype(f32))[None, :, None]
-             * jax.nn.softplus(a + ap["dt_bias"].astype(f32)).reshape(
-                 n, lh, ld))
-        # the step size in (0, 2): eigenvalues of I - beta k k^T in (-1, 1)
-        beta = 2.0 * jax.nn.sigmoid(_mm(u, ap["wb"]).astype(f32))
+        # (the gates' projections: low-rank pairs, or full rank)
+        a = (_mm(_mm(u, ap["wf_a"]), ap["wf_b"]) if "wf_a" in ap
+             else _mm(u, ap["wf"])).astype(f32)
+        if cfg.linear_gate_form == "bounded":
+            # log of the decay a key channel, bounded below: bound *
+            # sigmoid(exp(A_log) (a + dt_bias)), in (bound, 0) — a token
+            # decays a channel by e^bound at most
+            g = cfg.linear_gate_lower_bound * jax.nn.sigmoid(
+                jnp.exp(ap["A_log"].astype(f32))[None, :, None]
+                * (a + ap["dt_bias"].astype(f32)).reshape(n, lh, ld))
+        else:
+            # -exp(A_log) softplus(a + dt_bias), unbounded below
+            g = (-jnp.exp(ap["A_log"].astype(f32))[None, :, None]
+                 * jax.nn.softplus(a + ap["dt_bias"].astype(f32)).reshape(
+                     n, lh, ld))
+        # the step size in (0, linear_beta_scale): at 2 the eigenvalues of
+        # I - beta k k^T lie in (-1, 1), at 1 in (0, 1)
+        beta = jax.nn.sigmoid(_mm(u, ap["wb"]).astype(f32))
+        if cfg.linear_beta_scale != 1.0:
+            beta = cfg.linear_beta_scale * beta
     if recur.tap is not None:
         recur.tap(q, k, v, g, beta)
     with jax.named_scope("kda_scan"):
         o = recur.scan(q, k, v, g, beta)
     with jax.named_scope("kda_out"):
-        z = _mm(_mm(u, ap["wg_a"]), ap["wg_b"]).astype(f32)
+        z = (_mm(_mm(u, ap["wg_a"]), ap["wg_b"]) if "wg_a" in ap
+             else _mm(u, ap["wg"])).astype(f32)
         o = rms_norm(o, ap["o_norm"].astype(f32), cfg.rms_norm_eps)
         o = (o.reshape(n, lh * ld) * jax.nn.sigmoid(z)).astype(x.dtype)
         return _mm(o, ap["wo"])[:, None]
@@ -821,7 +887,13 @@ def _mla_attention(cfg: ModelConfig, ap: dict, norm: jax.Array,
     output (o_head = W_UV Σ p · c_kv): the same mathematics, with attention
     itself multi-query over the latents. `attend(q_abs (B, T, heads, width),
     latent (B, T, width), None)` stores the latents and returns
-    Σ p · c_kv, (B, T, heads, rank)."""
+    Σ p · c_kv, (B, T, heads, rank).
+
+    A layer with the per-head output gate (`attn_output_gate` "head": a leaf
+    "wg" (h, heads) beside the others) multiplies head h's output by
+    sigmoid(u W_g)[h], u the layer's normed input, before W_o — one scalar a
+    head and token, so it commutes with W_UV and is applied to the expanded
+    head outputs."""
     b, t, _ = x.shape
     nh, r = cfg.num_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -843,10 +915,18 @@ def _mla_attention(cfg: ModelConfig, ap: dict, norm: jax.Array,
             q_lat = jnp.einsum("bthd,rhd->bthr", q[..., :dn], w_kvb[..., :dn])
             q_abs = jnp.concatenate(
                 [q_lat, q_rope, jnp.zeros((b, t, nh, pad), x.dtype)], axis=-1)
+        if "wg" in ap:
+            with jax.named_scope("mla_gate"):
+                # (float32 logistic, as softmax statistics are)
+                gate = jax.nn.sigmoid(
+                    _mm(x, ap["wg"]).astype(jnp.float32)).astype(x.dtype)
     o_lat = attend(q_abs, latent, None)  # (B, T, heads, rank)
     with jax.named_scope("attn_out"):
         with jax.named_scope("mla_absorb"):
             o = jnp.einsum("bthr,rhd->bthd", o_lat, w_kvb[..., dn:])
+        if "wg" in ap:
+            with jax.named_scope("mla_gate"):
+                o = o * gate[..., None]
         return _mm(o.reshape(b, t, nh * dv), ap["wo"])
 
 
@@ -1041,9 +1121,10 @@ def _grouped_mm(xs: jax.Array, w, layer: int, group_sizes: jax.Array,
 
 
 def _moe_route(cfg: ModelConfig, router: jax.Array, xf: jax.Array,
-               bias: jax.Array | None = None):
+               bias: jax.Array | None = None, stats: dict | None = None):
     """(N, h) -> routing weights (N, k) float32, experts (N, k), their
-    one-hot (N, k, E) and the pairs each expert got (E,)."""
+    one-hot (N, k, E) and the pairs each expert got (E,). `stats`: a dict
+    that receives what group-limited routing counts (`_sigmoid_top_k`)."""
     with jax.named_scope("moe_router"):
         if cfg.scoring_func == "sigmoid":
             # the router's matmul itself in float32, as published
@@ -1055,7 +1136,7 @@ def _moe_route(cfg: ModelConfig, router: jax.Array, xf: jax.Array,
             logits = jnp.dot(
                 xf.astype(jnp.float32), router.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST)  # (N, E)
-            topv, topi = _sigmoid_top_k(cfg, logits, bias)
+            topv, topi = _sigmoid_top_k(cfg, logits, bias, stats)
         else:
             logits = (xf @ router).astype(jnp.float32)  # (N, E)
             topv, topi = _softmax_top_k(cfg, logits)
@@ -1069,16 +1150,47 @@ def _moe_route(cfg: ModelConfig, router: jax.Array, xf: jax.Array,
     return topv, topi, chosen, group_sizes
 
 
-def _sigmoid_top_k(cfg: ModelConfig, logits: jax.Array, bias: jax.Array):
-    """DeepSeek-V3 routing with one group: scores are sigmoid(logits) in
-    float32; the k experts are the top-k of scores + the selection bias
+def _sigmoid_top_k(cfg: ModelConfig, logits: jax.Array, bias: jax.Array,
+                   stats: dict | None = None):
+    """DeepSeek-V3 routing: scores are sigmoid(logits) in float32; the k
+    experts are the top-k of the CHOICE scores, scores + the selection bias
     (ties to the lower index, jax.lax.top_k); their weights are the SCORES
     (the bias moves the choice, never the weight), divided by their sum
-    under norm_topk_prob, times routed_scaling_factor."""
+    under norm_topk_prob, times routed_scaling_factor.
+
+    Group-limited (`n_group` > 1): the E router outputs — ALL of them,
+    whatever share of the experts this program holds — are n_group groups
+    of E / n_group consecutive experts; a group's score is the sum of its
+    two best choice scores; the `topk_group` best groups are kept (ties to
+    the lower group index, jax.lax.top_k again) and the top-k is taken
+    among their experts alone: an expert of another group is never chosen
+    (its choice score counts as -inf; the published zero fill chooses the
+    same experts while the kept groups' choice scores are positive, which
+    sigmoid scores + a small bias are). All of it in float32. `stats`
+    receives "groups_kept_held": how many of the N x topk_group kept groups
+    hold an expert this program holds."""
     scores = jax.nn.sigmoid(logits)
     # (a family without the selection bias has no such leaf: laguna)
-    _, topi = jax.lax.top_k(
-        scores if bias is None else scores + bias, cfg.num_experts_per_tok)
+    choice = scores if bias is None else scores + bias
+    if cfg.n_group > 1:
+        with jax.named_scope("moe_route_groups"):
+            n, e = choice.shape
+            per = e // cfg.n_group
+            best2, _ = jax.lax.top_k(
+                choice.reshape(n, cfg.n_group, per), min(2, per))
+            _, kept = jax.lax.top_k(
+                jnp.sum(best2, axis=-1), cfg.topk_group)  # (N, topk_group)
+            keep = jnp.any(
+                kept[..., None] == jnp.arange(cfg.n_group, dtype=kept.dtype),
+                axis=1)  # (N, n_group)
+            choice = jnp.where(
+                jnp.repeat(keep, per, axis=1), choice, -jnp.inf)
+            if stats is not None:
+                lo = cfg.first_expert_held // per
+                hi = (cfg.first_expert_held + cfg.held_experts - 1) // per
+                stats["groups_kept_held"] = jnp.sum(
+                    ((kept >= lo) & (kept <= hi)).astype(jnp.int32))
+    _, topi = jax.lax.top_k(choice, cfg.num_experts_per_tok)
     topv = jnp.take_along_axis(scores, topi, axis=-1)
     if cfg.norm_topk_prob:
         topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
@@ -1282,12 +1394,18 @@ def _moe_mlp(cfg: ModelConfig, mp: dict, x: jax.Array,
     `moe_*_touched_n`), `held_pairs`, the pairs that landed on them (only
     from a program that holds a share: `moe_*_held_pairs_n`), and
     `experts` (B, T, k), each token's choice (read by the comparison with
-    the reference, testing/reference_decoder.py)."""
+    the reference, testing/reference_decoder.py); under group-limited
+    routing also `groups_kept_held` (`_sigmoid_top_k`:
+    `moe_group_kept_n`)."""
     b, t, h = x.shape
     n = b * t
     xf = x.reshape(n, h)
+    group_stats: dict = {}
     topv, topi, chosen, group_sizes = _moe_route(
-        cfg, mp["router"], xf, mp.get("bias"))
+        cfg, mp["router"], xf, mp.get("bias"), group_stats)
+    if (ROUTER_TAP is not None and stacked is not None
+            and stacked[1] == ROUTER_TAP.moe_layer):
+        jax.debug.callback(ROUTER_TAP, xf, topi)
     form = moe_experts_form(cfg, n, kernel=backend != "xla")
     if form == "all_experts":
         y = _experts_all(cfg, mp, xf, topv, chosen)
@@ -1310,8 +1428,10 @@ def _moe_mlp(cfg: ModelConfig, mp: dict, x: jax.Array,
                         * _mm(xf, sp["up"]), sp["down"])
     touched = jnp.sum((group_sizes > 0).astype(jnp.int32))
     routing = {"touched": touched, "experts": topi.reshape(b, t, -1)}
-    if cfg.num_experts_held:
+    if cfg.num_experts_held or cfg.n_group > 1:
         routing["held_pairs"] = jnp.sum(group_sizes)
+    if cfg.n_group > 1:
+        routing.update(group_stats)
     return y.reshape(b, t, h), routing
 
 
@@ -1329,11 +1449,11 @@ def _layer_params(cfg: ModelConfig, params: dict, i: int) -> dict:
     mlp_kind = "moe" if spec.experts else "mlp"
     # name in the layer's dict -> (stack in the tree, the layer's index there)
     picks = {name: (name, i) for name in layers
-             if name not in ("attn", "attn_window", "attn_linear", "mlp",
-                             "moe")}
+             if name not in ("attn", "attn_window", "attn_linear",
+                             "attn_latent", "mlp", "moe")}
     picks["attn"] = (
-        ({"full": "attn", "window": "attn_window",
-          "linear": "attn_linear"}[spec.attn_kind], ai)
+        ({"full": "attn", "window": "attn_window", "linear": "attn_linear",
+          "latent": "attn_latent"}[spec.attn_kind], ai)
         if cfg.attn_stacks_by_kind else ("attn", i))
     picks[mlp_kind] = (mlp_kind, j := mi if two_mlps else i)
     # (slices are traced in the tree's own sorted order, a kind-stacked MLP
@@ -1444,7 +1564,7 @@ def _layer(
             **write_blocks, "ids": _group(write_blocks["ids"], spec.window)}
 
     def _attention(q):
-        if cfg.mla:
+        if spec.latent:
             # q is the absorbed queries (B, T, heads, width)
             if pallas_prefill is not None:
                 return mla_prefill_attention(
@@ -1493,14 +1613,14 @@ def _layer(
             q, k = q[:, 0], k[:, 0]  # (N, heads, D)
             v = None if v is None else v[:, 0]
         with jax.named_scope("kv_stage"):
-            if cfg.mla and write_blocks is not None:
+            if spec.latent and write_blocks is not None:
                 # k is the token's latent (models/llama.py:_mla_attention)
                 kv_layer = write_latent_pages_blockwise(
                     kv_layer, k, write_blocks["ids"],
                     write_blocks["start_off"], write_blocks["chunk_lens"],
                     row_off=None if rows is None else rows["row_off"],
                 )
-            elif cfg.mla:
+            elif spec.latent:
                 kv_layer = write_latent_pages(
                     kv_layer, k.reshape(b * t, -1), slot_mapping)
             elif write_blocks is not None:
@@ -1608,7 +1728,8 @@ def forward(
     else:
         # attention masks, built once per WINDOW KIND and reused by every
         # layer of that kind (full; a window's sliding variant)
-        s_ctx = _group(block_tables, 0).shape[1] * kv_caches[0].shape[2]
+        s_ctx = (_group(block_tables, 0).shape[1]
+                 * _page_tokens(cfg, kv_caches))
         masks = {
             w: causal_page_mask(row_positions, context_lens, s_ctx, window=w,
                                 **_block_mask(cfg))
@@ -1664,7 +1785,7 @@ def forward(
 
 
 def _refuse_mla(cfg: ModelConfig, what: str) -> None:
-    if cfg.mla:
+    if cfg.latent_layers:
         raise NotImplementedError(
             f"{what} is not implemented for multi-head latent attention "
             f"({cfg.model}): its pool holds one latent a token, not K and V")
@@ -1675,7 +1796,7 @@ def init_staged_kv(
     kv_caches: tuple | None = None,
 ) -> jax.Array:
     """Staging buffer for one fused decode window: (L, 2, W, B, kvH, D)
-    (MLA: (L, B, W, latent_width)).
+    (a model whose pages hold latents: (L, B, W, latent_width)).
     Small (MBs), so carrying it through the window loop is cheap — unlike the
     pool itself (see paged_attention_with_staged).
 
@@ -1685,22 +1806,21 @@ def init_staged_kv(
     pools}}: the state advances in place at every step and is handed back
     by `commit_staged_kv`, as the staged K/V are."""
     dt = jnp.dtype(dtype) if dtype is not None else _dtype(cfg)
-    if cfg.linear_layers:
-        # (the buffer keeps a plane for every layer, so a layer's index is
-        # its plane; the state layers' planes, 0.5 MiB each, stay unused)
-        return {
-            "kv": jnp.zeros((cfg.num_layers, 2, window, batch,
-                             cfg.num_kv_heads, cfg.head_dim), dt),
-            "state": {i: kv_caches[i] for i in cfg.linear_layers}}
-    if cfg.mla:
+    if cfg.latent_layers:
         # one latent a token, rows first: (L, B, W, width) — a row's window
         # is one block of the decode kernel, and (b, w) row-major is the
         # commit's slot order
-        return jnp.zeros(
+        buf = jnp.zeros(
             (cfg.num_layers, batch, window, cfg.latent_width), dt)
-    return jnp.zeros(
-        (cfg.num_layers, 2, window, batch, cfg.num_kv_heads, cfg.head_dim), dt
-    )
+    else:
+        buf = jnp.zeros((cfg.num_layers, 2, window, batch,
+                         cfg.num_kv_heads, cfg.head_dim), dt)
+    if cfg.linear_layers:
+        # (the buffer keeps a plane for every layer, so a layer's index is
+        # its plane; the state layers' planes, 0.5 MiB each, stay unused)
+        return {"kv": buf,
+                "state": {i: kv_caches[i] for i in cfg.linear_layers}}
+    return buf
 
 
 def decode_window_step(
@@ -1736,7 +1856,7 @@ def decode_window_step(
     if cfg.linear_layers:  # see init_staged_kv
         staged, states = staged["kv"], dict(staged["state"])
         state_read = jnp.where(step_k == 0, state_slots[0], state_slots[1])
-    window = staged.shape[2]  # (MLA: (L, B, W, width), the same axis)
+    window = staged.shape[2]  # (latents: (L, B, W, width), the same axis)
     n_stats = len(moe_stats or ())
     with jax.named_scope("embed"):
         x = _embed(cfg, params, token_ids)[:, None]  # (B, 1, h)
@@ -1746,7 +1866,8 @@ def decode_window_step(
         s_ctx = (
             hists[0][0].shape[1]
             if hists is not None
-            else _group(block_tables, 0).shape[1] * kv_caches[0].shape[2]
+            else _group(block_tables, 0).shape[1]
+            * _page_tokens(cfg, kv_caches)
         )
         arange = jnp.arange(s_ctx, dtype=jnp.int32)[None, :]
         hist_mask = arange < hist_len[:, None]
@@ -1843,7 +1964,7 @@ def decode_window_step(
         x = _layer_body(
             cfg, lp, x, positions[:, None],
             recur if spec.holds_state
-            else attend_latent if cfg.mla else attend,
+            else attend_latent if spec.latent else attend,
             _lora_layer_slice(lora, i), lora_idx, moe_stats, spec,
             moe_backend,
         )
@@ -2007,10 +2128,10 @@ def commit_staged_kv(
     states = {}
     if isinstance(staged, dict):  # layers that hold state: init_staged_kv
         staged, states = staged["kv"], staged["state"]
-    if staged.ndim == 4:  # MLA: (L, B, W, width), rows (b, w) as the slots
+    if staged.ndim == 4:  # latents: (L, B, W, width), rows (b, w) as slots
         with jax.named_scope("kv_commit"):
             return tuple(
-                write_latent_pages(
+                states[i] if i in states else write_latent_pages(
                     kv, staged[i].reshape(-1, staged.shape[-1]), slot_mapping)
                 for i, kv in enumerate(kv_caches))
     L, _, w, b, kvh, d = staged.shape
@@ -2054,8 +2175,8 @@ def embed_encode(
         lp = _layer_params(cfg, params, i)
         m = masks[spec.window]
 
-        def attend(q, k, v, m=m):
-            if cfg.mla:  # absorbed queries against the chunk's own latents
+        def attend(q, k, v, m=m, spec=spec):
+            if spec.latent:  # absorbed queries against the chunk's latents
                 k = k[:, :, None]
                 return masked_attention(
                     q, k, k, m, scale=cfg.attn_scale)[..., :cfg.kv_lora_rank]

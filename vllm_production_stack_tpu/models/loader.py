@@ -481,3 +481,34 @@ class SolarOpen2CheckpointView(LagunaCheckpointView):
         if name.endswith("mlp.gate.e_score_correction_bias"):
             return layers["moe"]["bias"][int(parts[2])]
         return super().__getitem__(name)
+
+
+class LingCheckpointView(SolarOpen2CheckpointView):
+    """The served tree of a ling_flash model read back under the plain
+    reference's tensor names (testing/reference_ling.py): the KDA layers'
+    `linear_attn.*` leaves with their FULL-RANK gate projections (`f_proj`,
+    `g_proj`), the latent layers' `self_attn.*` (the "attn_latent" stack:
+    rope columns in rotate-half order, as the reference ropes them), a
+    leading dense layer's `mlp.*`, the experts by GLOBAL index and the
+    router's selection bias at the layer's index among the expert layers.
+    No checkpoint of this family has been read here: the names are the
+    reference's."""
+
+    _LINEAR = {**SolarOpen2CheckpointView._LINEAR,
+               "f_proj": "wf", "g_proj": "wg"}
+    _LATENT = {"q_proj": "wq", "kv_a_proj_with_mqa": "wkv_a",
+               "kv_b_proj": "wkv_b", "o_proj": "wo", "g_proj": "wg"}
+
+    def __getitem__(self, name: str):
+        cfg, layers = self.cfg, self.params["layers"]
+        parts = name.split(".")
+        if len(parts) > 3 and parts[3] == "self_attn":
+            stack = layers["attn_latent"]
+            j = cfg.layer_stack_index[int(parts[2])][0]
+            if parts[4] == "kv_a_layernorm":
+                return stack["kv_a_norm"][j]
+            return stack[self._LATENT[parts[4]]][j].T
+        if name.endswith("mlp.gate.e_score_correction_bias"):
+            return layers["moe"]["bias"][
+                cfg.layer_stack_index[int(parts[2])][1]]
+        return super().__getitem__(name)

@@ -153,6 +153,60 @@ def _solar_open2_preset(n_layers: int, **over) -> dict:
     return kw
 
 
+def ling_flash_layers(n_layers: int, layer_group_size: int,
+                      first_k_dense: int, num_heads: int, rope_theta: float,
+                      ) -> tuple[LayerSpec, ...]:
+    """The per-layer list of a Ling-3.0-flash config: layer i is a LATENT
+    layer (multi-head latent attention, rope on its rope key) where
+    (i + 1) % layer_group_size == 0, else a gated delta-rule (KDA) layer
+    that holds a recurrent state and has no position encoding; the first
+    `first_k_dense` layers carry a dense SwiGLU, the rest experts."""
+    rope = RopeSpec(theta=rope_theta)
+    return tuple(
+        LayerSpec(num_heads=num_heads, rope=rope, mixer="latent",
+                  experts=i >= first_k_dense)
+        if (i + 1) % layer_group_size == 0 else
+        LayerSpec(num_heads=num_heads, rope=None, mixer="linear",
+                  experts=i >= first_k_dense)
+        for i in range(n_layers))
+
+
+def _ling_flash_preset(n_layers: int, layer_group_size: int = 6,
+                       **over) -> dict:
+    """inclusionAI/Ling-3.0-flash-VL config.json, the language model: 42
+    layers in periods of five KDA layers (32 heads x 128 keys and values,
+    convolution of 4, full-rank gates, the bounded decay gate, beta in
+    (0, 1)) and one latent layer (MLA: a 512-value latent and one 64-value
+    rope key a token, 32 heads of 128 + 64 (q) and 128 (v), no query latent,
+    a per-head output gate); two leading dense SwiGLUs of 6 144, then 512
+    experts of 768, top-8 of sigmoid scores + a selection bias under a group
+    limit (8 groups, 4 kept), renormalised x 2.5, one ungated shared SwiGLU
+    of 768; vocabulary 157 184 untied; 131 072 positions published. The
+    vision tower, multi-token prediction and the SwiGLU clamp are not
+    served (docs/45-latent-beside-state.md)."""
+    kw = dict(
+        vocab_size=157184, hidden_size=2560, intermediate_size=6144,
+        num_layers=n_layers, num_heads=32, num_kv_heads=32, head_dim=128,
+        max_model_len=32768, rope_theta=6000000.0, rms_norm_eps=1e-6,
+        architecture="ling_flash", kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, first_k_dense_replace=2,
+        num_experts=512, num_experts_per_tok=8, moe_intermediate_size=768,
+        n_shared_experts=1, scoring_func="sigmoid", moe_selection_bias=True,
+        routed_scaling_factor=2.5, norm_topk_prob=True, n_group=8,
+        topk_group=4, attn_output_gate="head", linear_num_heads=32,
+        linear_head_dim=128, linear_gate_rank=0, linear_gate_form="bounded",
+        linear_gate_lower_bound=-5.0, linear_beta_scale=1.0,
+        # image_patch_token, video_patch_token, image_start_token,
+        # video_start_token
+        refused_token_ids=(157157, 156909, 157158, 157160),
+    )
+    kw.update(over)
+    kw["layers"] = ling_flash_layers(
+        n_layers, layer_group_size, kw["first_k_dense_replace"],
+        kw["num_heads"], kw["rope_theta"])
+    return kw
+
+
 # Architecture hyperparameters follow the public model cards.
 # SDAR's generation settings (JetLM/SDAR-30B-A3B-Chat): the config.json has
 # no key for any of them. Block length 4 and 4 passes are the -Chat
@@ -359,6 +413,28 @@ PRESETS: dict[str, dict] = {
         dtype="float32", num_experts=16, num_experts_per_tok=4,
         num_experts_held=4, first_expert_held=4, moe_intermediate_size=32,
         linear_num_heads=4, linear_head_dim=16, linear_gate_rank=8),
+    "ling-3.0-flash": _ling_flash_preset(42),
+    # the benchmark cell's cut: ONE chip of a four-chip expert-parallel
+    # host that is the first pipeline stage — layers 0-6 with ONE leading
+    # dense layer (K(dense) K K K K M K: one whole period of expert
+    # layers, 5 KDA : 1 MLA), experts 0-127 of the 512 the router scores
+    # (groups 0 and 1 whole), vocabulary rows 0-39 295; every width as
+    # published, 32 768 positions served
+    "ling-3.0-flash-ep4-7l": _ling_flash_preset(
+        7, first_k_dense_replace=1, num_experts_held=128,
+        first_expert_held=0, vocab_size=39296),
+    # one period at toy widths (float32): 4 heads x 16 in both mixers, a
+    # 32-value latent + an 8-value rope key, a dense layer 0 and 6 expert
+    # layers, 16 experts in 4 groups of 4 (2 groups kept, top-4) with groups
+    # 1 and 2 (experts 4-11) held
+    "tiny-ling-flash": _ling_flash_preset(
+        7, vocab_size=512, hidden_size=64, intermediate_size=128,
+        num_heads=4, num_kv_heads=4, head_dim=16, max_model_len=512,
+        dtype="float32", kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, first_k_dense_replace=1,
+        num_experts=16, num_experts_per_tok=4, n_group=4, topk_group=2,
+        num_experts_held=8, first_expert_held=4, moe_intermediate_size=32,
+        linear_num_heads=4, linear_head_dim=16, refused_token_ids=()),
 }
 
 _ARCH_MAP = {
@@ -410,10 +486,13 @@ def _deepseek_v3_keys(hf: dict, path: str) -> dict:
     """The DeepSeek-V3-family keys (Kanana-2), and by name what of the
     family is not implemented: a config that sets one is refused, not
     guessed at."""
+    n_group = hf.get("n_group") or 1
     refused = {
         "q_lora_rank": hf.get("q_lora_rank") is not None,
-        "n_group > 1 (group-limited routing)": (hf.get("n_group") or 1) > 1,
-        "topk_group > 1": (hf.get("topk_group") or 1) > 1,
+        "n_group that does not divide n_routed_experts": bool(
+            hf["n_routed_experts"] % n_group),
+        "topk_group outside 1..n_group": not (
+            1 <= (hf.get("topk_group") or 1) <= n_group),
         "rope_scaling (yarn and its mscale)": bool(hf.get("rope_scaling")),
         "moe_layer_freq != 1": (hf.get("moe_layer_freq") or 1) != 1,
         "scoring_func other than sigmoid": hf.get(
@@ -425,7 +504,7 @@ def _deepseek_v3_keys(hf: dict, path: str) -> dict:
         raise ValueError(
             f"deepseek_v3 config {path} sets {', '.join(hit)}: not "
             "implemented (multi-head latent attention is served with a "
-            "direct query projection, one routing group and plain rope)"
+            "direct query projection, groups of equal size and plain rope)"
         )
     return dict(
         kv_lora_rank=hf["kv_lora_rank"],
@@ -441,6 +520,7 @@ def _deepseek_v3_keys(hf: dict, path: str) -> dict:
         routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
         norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
         rope_interleave=bool(hf.get("rope_interleave", True)),
+        n_group=n_group, topk_group=hf.get("topk_group") or 1,
     )
 
 
@@ -540,6 +620,96 @@ def _solar_open2_keys(hf: dict, path: str) -> dict:
     )
 
 
+def _is_ling_flash(hf: dict) -> bool:
+    """Is this a Ling-3.0-flash config? Told by its own keys (latent layers
+    every `layer_group_size`-th layer beside KDA layers): neither its
+    `model_type` nor its `architectures` string could be checked offline."""
+    return all(k in hf for k in (
+        "layer_group_size", "kda_safe_gate", "kv_lora_rank"))
+
+
+def _ling_flash_keys(hf: dict, path: str) -> dict:
+    """The Ling-3.0-flash keys (the language model's), and by name what of
+    the family is not served: a config that sets one is refused, not
+    guessed at."""
+    n, heads = hf["num_hidden_layers"], hf["num_attention_heads"]
+    n_group = hf.get("n_group") or 1
+    refused = {
+        "use_nGPT": bool(hf.get("use_nGPT")),
+        "value_norm": bool(hf.get("value_norm")),
+        "up_proj_norm": bool(hf.get("up_proj_norm")),
+        "use_kda_lora": bool(hf.get("use_kda_lora")),
+        "no_kda_lora false": not hf.get("no_kda_lora", True),
+        "mtp_use_kda": bool(hf.get("mtp_use_kda")),
+        "scale_router_input": bool(hf.get("scale_router_input")),
+        "q_lora_rank": hf.get("q_lora_rank") is not None,
+        "use_mla_nope": bool(hf.get("use_mla_nope")),
+        "kda_safe_gate false": not hf.get("kda_safe_gate", True),
+        "linear_silu false": not hf.get("linear_silu", True),
+        "short_conv_kernel_size != 4": hf.get(
+            "short_conv_kernel_size", 4) != 4,
+        "num_kv_heads_for_linear_attn": (
+            hf.get("num_kv_heads_for_linear_attn") or 0) not in (0, heads),
+        "group_norm_size != 1": (hf.get("group_norm_size") or 1) != 1,
+        "rotary_dim other than qk_rope_head_dim": hf.get(
+            "rotary_dim", hf["qk_rope_head_dim"]) != hf["qk_rope_head_dim"],
+        "score_function other than sigmoid": hf.get(
+            "score_function", "sigmoid") != "sigmoid",
+        "gated_attention_proj_granularity_type other than head_wise": hf.get(
+            "gated_attention_proj_granularity_type",
+            "head_wise") != "head_wise",
+        "n_group that does not divide num_experts": bool(
+            hf["num_experts"] % n_group),
+        "topk_group outside 1..n_group": not (
+            1 <= (hf.get("topk_group") or 1) <= n_group),
+        "rope_scaling": bool(hf.get("rope_scaling")),
+        # the clamp of the SwiGLU's arguments: off (0) in every layer kept
+        "a non-zero expert_swiglu_limit_list entry in a kept layer": any(
+            (hf.get("expert_swiglu_limit_list") or [])[:n]),
+        "a non-zero share_expert_swiglu_limit_list entry in a kept layer":
+            any((hf.get("share_expert_swiglu_limit_list") or [])[:n]),
+    }
+    hit = [k for k, v in refused.items() if v]
+    if hit:
+        raise ValueError(
+            f"ling_flash config {path} sets {', '.join(hit)}: "
+            "not implemented")
+    first_k = hf.get("first_k_dense_replace") or 0
+    return dict(
+        layers=ling_flash_layers(
+            n, hf["layer_group_size"], first_k, heads,
+            float(hf.get("rope_theta", 10000.0))),
+        kv_lora_rank=hf["kv_lora_rank"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"],
+        qk_rope_head_dim=hf["qk_rope_head_dim"],
+        v_head_dim=hf["v_head_dim"],
+        first_k_dense_replace=first_k,
+        num_experts=hf["num_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        # one ungated shared SwiGLU of moe_shared_expert_intermediate_size
+        n_shared_experts=(hf.get("moe_shared_expert_intermediate_size") or 0)
+        // hf["moe_intermediate_size"],
+        scoring_func="sigmoid",
+        moe_selection_bias=bool(hf.get("moe_router_enable_expert_bias")),
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        n_group=n_group, topk_group=hf.get("topk_group") or 1,
+        attn_output_gate="head",
+        linear_num_heads=heads,
+        # (keys and values alike: num_kv_heads_for_linear_attn 0)
+        linear_head_dim=hf.get("head_dim") or hf["hidden_size"] // heads,
+        linear_gate_rank=0, linear_gate_form="bounded",
+        linear_gate_lower_bound=float(hf.get("kda_lower_bound", -5)),
+        # assumed (no key): beta = sigmoid, in (0, 1)
+        linear_beta_scale=1.0,
+        refused_token_ids=tuple(
+            hf[k] for k in ("image_patch_token", "video_patch_token",
+                            "image_start_token", "video_start_token")
+            if hf.get(k) is not None),
+    )
+
+
 def _refuse_other_sdar_generation(hf: dict, path: str) -> None:
     """The generation settings of a `model_type: sdar_moe` model are the
     preset's (`_SDAR_GENERATION`): the published config.json states none,
@@ -564,6 +734,8 @@ def _from_hf_config(path: str) -> dict:
         arch = "qwen3moe"
         _refuse_other_sdar_generation(hf, path)
         generation = dict(block_diffusion=_SDAR_GENERATION)
+    if _is_ling_flash(hf):
+        arch = "ling_flash"
     if arch is None:
         raise ValueError(f"unsupported architecture(s) {archs} in {path}")
     heads = hf["num_attention_heads"]
@@ -611,6 +783,11 @@ def _from_hf_config(path: str) -> dict:
     if arch == "solar_open2":
         moe = _solar_open2_keys(hf, path)
         # no layer has a rope: rope_theta and partial_rotary_factor are dead
+        hf = {**hf, "partial_rotary_factor": None, "rope_scaling": None}
+    if arch == "ling_flash":
+        moe = _ling_flash_keys(hf, path)
+        # the latent layers rope their 64-value rope key whole (rotary_dim
+        # = qk_rope_head_dim): partial_rotary_factor says that of head_dim
         hf = {**hf, "partial_rotary_factor": None, "rope_scaling": None}
     gemma = (
         dict(

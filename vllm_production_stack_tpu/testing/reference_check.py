@@ -131,6 +131,14 @@ def system_routing(cfg, params, tokens: list[int], chunk: int | None = None,
     return [np.concatenate(layer) for layer in zip(*parts)]
 
 
+# the architectures whose tree is stacked by layer kind: the view
+# (models/loader.py) that hands the plain reference the served weights
+# under its own tensor names
+_BY_KIND_VIEWS = {"laguna": "LagunaCheckpointView",
+                  "solar_open2": "SolarOpen2CheckpointView",
+                  "ling_flash": "LingCheckpointView"}
+
+
 def compare(reference, cfg, params, prompts: list[list[int]],
             served: list[dict], routing_chunk: int | None = None,
             routing_backend: str = "xla",
@@ -156,14 +164,12 @@ def compare(reference, cfg, params, prompts: list[list[int]],
     margins: list[float] = []
     # reference_mla.py and reference_laguna.py read checkpoint-style names
     # and orientation, and take `logit_positions` and `forced_experts`
-    by_name = cfg.mla or cfg.architecture in ("laguna", "solar_open2")
+    by_name = cfg.mla or cfg.architecture in _BY_KIND_VIEWS
     if by_name:
         from ..models import loader
 
         view = (loader.DeepseekCheckpointView if cfg.mla
-                else loader.SolarOpen2CheckpointView
-                if cfg.architecture == "solar_open2"
-                else loader.LagunaCheckpointView)
+                else getattr(loader, _BY_KIND_VIEWS[cfg.architecture]))
         params_ref = view(cfg, params)
     if cfg.linear_layers and cfg.num_experts and served_routing is None:
         # (`system_routing` drives `llama.forward` row-major on a scratch
@@ -179,14 +185,13 @@ def compare(reference, cfg, params, prompts: list[list[int]],
             # a long sequence's are GBs of float32)
             forced = (
                 served_routing.routing_of(
-                    seq, mark_ambiguous=cfg.architecture in (
-                        "laguna", "solar_open2"))
+                    seq, mark_ambiguous=cfg.architecture in _BY_KIND_VIEWS)
                 if served_routing is not None else None)
             # (laguna: 72 heads x 16k keys of float32 scores a query —
             # 64 queries a block keep a block's scores under 0.4 GB beside
             # the served weights, which stay on the chip)
-            block = ({"query_block": 64} if cfg.architecture in (
-                "laguna", "solar_open2") else {})
+            block = ({"query_block": 64}
+                     if cfg.architecture in _BY_KIND_VIEWS else {})
             logits, routing = reference.forward(
                 ref_cfg, params_ref, seq, range(first, len(seq)),
                 forced_experts=forced, **block)
@@ -313,6 +318,70 @@ def served_state(reference, cfg, tap: StateTap, pool,
         tokens += len(fed[0])
     return {"layer": tap.layer, "rows": len(rows), "tokens": tokens,
             "state_rel_err": worst}
+
+
+class RouterTap:
+    """What the SERVED programs fed the router of one expert layer, and what
+    it chose: set as `llama.ROUTER_TAP` while an engine is built and driven
+    (as `RoutingTap` is), it receives every execution's (router input (N, h),
+    chosen experts (N, k)) of expert layer `moe_layer` (its index among the
+    expert layers), padding rows and positions included — they are routed by
+    the same arithmetic."""
+
+    def __init__(self, moe_layer: int):
+        self.moe_layer = moe_layer
+        self.records: list[tuple] = []
+
+    def __call__(self, x, experts) -> None:
+        self.records.append((np.asarray(x), np.asarray(experts)))
+
+    def __enter__(self) -> "RouterTap":
+        llama.ROUTER_TAP = self
+        return self
+
+    def __exit__(self, *exc) -> None:
+        llama.ROUTER_TAP = None
+        jax.effects_barrier()  # every callback has run
+
+
+def served_router(reference, cfg, tap: RouterTap, params_ref,
+                  max_tokens: int = 65536) -> dict:
+    """The ROUTER the engine serves, where a comparison of logprobs cannot
+    see it and the routing the reference follows cannot either: the served
+    programs' bf16 activations move a fifth of the (token, layer) choices
+    off the float32 reference's own (PERF.md §6, PR 45), so a router
+    computed in a lower precision hides among them. Here the reference's
+    `route` is given the VERY inputs the served router was given (tapped,
+    widened to float32) and its choice is held against the served one: what
+    is left between the two is the router's own arithmetic — its product's
+    precision, its scores, the group limit, the ties. Returns the share of
+    tokens whose expert SETS differ and, where they do, how far the worst
+    served expert's choice score lies under the reference's k-th best."""
+    ref_cfg = reference.published_keys(cfg)
+    layer = [i for i, s in enumerate(cfg.layer_specs) if s.experts][
+        tap.moe_layer]
+    prefix = f"model.layers.{layer}."
+
+    def get(name):
+        return reference.f32(params_ref[name])
+
+    tokens = differing = 0
+    margin = 0.0
+    for x, served in tap.records:
+        if tokens >= max_tokens:
+            break
+        with jax.default_matmul_precision("highest"):
+            _, own, gap = reference.route(
+                reference.check_config(ref_cfg), get, prefix,
+                reference.f32(x), served)
+        same = (np.sort(served, -1) == np.sort(np.asarray(own), -1)).all(-1)
+        tokens += same.size
+        differing += int((~same).sum())
+        margin = max(margin, float(np.max(
+            np.where(same, 0.0, np.asarray(gap)), initial=0.0)))
+    return {"moe_layer": tap.moe_layer, "tokens": tokens,
+            "choice_disagreement_share": differing / max(tokens, 1),
+            "choice_margin_max_where_differing": margin}
 
 
 # -- generation by diffusion over blocks (reference_sdar.py) -----------------
