@@ -7,7 +7,8 @@ quick start uses, at the full width and depth of one model the repo supports
 
   kernels   each Pallas kernel that compiles for v5e, run NON-interpreted
             against ops/attention.py's XLA path: decode at the qwen3-8b head
-            shape, prefill at the chat and long-document cells' head shapes
+            shape, prefill at the chat and long-document cells' head shapes,
+            the chunk write kernel bit for bit over the whole pool
   serve     `python -m ...engine.server` and `python -m ...router.app` as two
             OS processes; through the router: one chat completion, one
             streamed (SSE, every chunk parsed), four concurrent completions,
@@ -780,9 +781,10 @@ def child_kernels(seed: int) -> int:
     sys.path.insert(0, REPO)
     from vllm_production_stack_tpu.ops.attention import (
         causal_page_mask, paged_attention_with_staged, paged_attention_xla,
+        write_kv_pages_blockwise,
     )
     from vllm_production_stack_tpu.ops.paged_attention_pallas import (
-        paged_decode_attention, paged_prefill_attention,
+        paged_decode_attention, paged_kv_write, paged_prefill_attention,
         paged_window_prefill_attention,
     )
     from vllm_production_stack_tpu.utils.compile_cache import (
@@ -868,6 +870,32 @@ def child_kernels(seed: int) -> int:
         report["kernels"][
             f"prefill_{heads}_{kv_heads}" + (f"_w{window}" if window else "")
         ] = check
+
+    # the chunk write kernel against the XLA form, the WHOLE pool bit for
+    # bit: four rows packed on a 512-token axis (a continued row that starts
+    # and ends mid-page, a one-token row, a first chunk, a padding row), at
+    # the cells' kv head counts and an fp8 pool
+    ids = np.zeros((4, (t - 1) // bs + 2), np.int32)
+    hist, lens = np.array([1000, 95, 0, 0]), np.array([301, 1, 210, 0])
+    pages = iter(rng.permutation(np.arange(1, num_blocks)).tolist())
+    for i in range(3):
+        span = (hist[i] + lens[i] - 1) // bs - hist[i] // bs + 1
+        ids[i, :span] = [next(pages) for _ in range(span)]
+    write = tuple(jnp.asarray(a, jnp.int32) for a in (
+        ids, hist % bs, lens, np.cumsum(lens) - lens))
+    for kv_heads, dtype in ((4, jnp.bfloat16), (8, jnp.bfloat16),
+                            (16, jnp.bfloat16), (4, jnp.float8_e4m3fn)):
+        pool = arr(2, num_blocks, bs, kv_heads, d).astype(dtype)
+        k, v = arr(t, kv_heads, d), arr(t, kv_heads, d)
+        got = np.asarray(paged_kv_write(pool, k, v, *write)).view(np.uint8)
+        want = np.asarray(write_kv_pages_blockwise(
+            pool, k, v, *write[:3], row_off=write[3])).view(np.uint8)
+        report["kernels"][f"kv_write_{kv_heads}_{jnp.dtype(dtype).name}"] = {
+            "bytes_that_differ": int(np.sum(got != want)),
+            "bytes_written": int(np.sum(
+                want != np.asarray(pool).view(np.uint8))),
+            "ok": bool(np.array_equal(got, want)),
+        }
 
     report["ok"] = all(k["ok"] for k in report["kernels"].values())
     emit(report)

@@ -157,6 +157,36 @@ def test_prefill_kernel_compiles_for_v5e(topo, model, block, kv_dtype, rows,
     ).lower(*args).compile())
 
 
+@pytest.mark.parametrize("kvh,kv_dtype,rows,tokens,blocks", [
+    (4, "bfloat16", 4, CHUNK, 2048),   # qwen2-7b's cell: the pool XLA copied
+    (8, "bfloat16", 1, CHUNK, 768),    # qwen3-8b's
+    (16, "bfloat16", 2, CHUNK, 1280),  # olmoe-1b-7b's
+    (4, "bfloat16", 2, 1024, 4096),    # SDAR's 1,024-token bucket
+    (8, "bfloat16", 2, 2048, 1329),    # Laguna's window group, a long chunk
+    (2, "bfloat16", 4, CHUNK, 1024),   # a tp=4 shard that still fills tiles
+    (4, "float8_e4m3fn", 2, CHUNK, 1024),  # rows converted before the call
+])
+def test_kv_write_kernel_compiles_for_v5e(topo, kvh, kv_dtype, rows, tokens,
+                                          blocks):
+    """The chunk write kernel at the cells' pools: token spans copied HBM
+    to HBM at a dynamic token offset of a page (whole (kvh, D) tiles under
+    it), the pool aliased to the result — donated, the program holds no
+    copy of it and no temps."""
+    sds, d, bs = _one_chip(topo), 128, 32
+    new = sds((tokens, kvh, d), jnp.bfloat16, None)
+    i32 = lambda *shape: sds(shape, jnp.int32, None)
+    compiled = jax.jit(pk.paged_kv_write, donate_argnums=0).lower(
+        sds((2, blocks, bs, kvh, d), jnp.dtype(kv_dtype), None), new, new,
+        i32(rows, (tokens - 1) // bs + 2), i32(rows), i32(rows), i32(rows),
+    ).compile()
+    _assert_kernel(compiled)
+    text = compiled.as_text()
+    assert "paged_kv_write" in text
+    assert f"[2,{blocks},{bs},{kvh},{d}]" not in "".join(
+        line for line in text.splitlines() if " copy(" in line)
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
 @pytest.mark.parametrize("nh,kvh,window,tiling,items", [
     (48, 8, 0, (128, 8), 2),     # Laguna-S-2.1's full layers
     (72, 8, 512, (128, 8), 3),   # ... and its window layers
@@ -409,6 +439,13 @@ def test_prefill_program_of_a_cell_holds_no_score_temp(topo, model):
     assert "paged_prefill_attention" in text
     assert "gather_pages" not in text
     nh, kvh, d = HEADS[model]
+    # the chunk's K/V go in through the write kernel, and no operation but
+    # the kernels holds a layer's pool: nothing converts its layout (the
+    # XLA scatter had each layer's whole pool copied there and back)
+    assert "paged_kv_write" in text
+    pool = rf"= \S+\[2,{POOL_BLOCKS},32,{kvh},{d}\]\S* (copy|scatter|fusion)\("
+    held = [line[:200] for line in text.splitlines() if re.search(pool, line)]
+    assert not held, held
     scores = rf"\[{rows},{nh},{CHUNK},{s_ctx}\]|\[{rows},{kvh},\d+,{CHUNK},{s_ctx}\]"
     history = rf"\[{rows},{s_ctx},{kvh},{d}\]"
     held = [line[:160] for line in text.splitlines()

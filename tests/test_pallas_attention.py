@@ -593,6 +593,193 @@ def test_prefill_kernel_walks_only_the_blocks_a_tile_needs():
         _prefill_kernel_out((q, poisoned, tables, ctx, start)), clean)
 
 
+# The chunk write kernel's cases: (kv heads, pool dtype, page size, rows as
+# (resident tokens before the chunk, chunk tokens) — (h, 0) a row of zero
+# tokens, None a padding row: every id on the null page —, the packed axis).
+KV_WRITE_CASES = {
+    # a row that starts mid-page and ends mid-page, a page-aligned row
+    "mid_page_start_and_end": (4, "bfloat16", 8, [(13, 22), (16, 8)], 32),
+    # a chunk that ends mid-page from a page boundary; one token on a page
+    "ends_mid_page": (4, "bfloat16", 8, [(0, 19), (31, 1)], 32),
+    "row_of_zero_tokens": (4, "bfloat16", 8, [(5, 9), (12, 0), (0, 7)], 16),
+    "padding_row_on_the_null_page": (
+        4, "bfloat16", 8, [(3, 12), None, None, None], 16),
+    # two rows end to end: the second's tokens start inside a block of the
+    # packed axis (17 is no multiple of 8), and both run to the axis' end
+    "seam_inside_a_block": (4, "bfloat16", 8, [(6, 17), (40, 47)], 64),
+    "two_kv_heads": (2, "bfloat16", 8, [(13, 22), (0, 10)], 32),
+    "eight_kv_heads": (8, "bfloat16", 8, [(13, 22), (0, 10)], 32),
+    "float32_pool_32_token_pages": (
+        4, "float32", 32, [(45, 70), (0, 33), (64, 25)], 128),
+    "int8_pool": (4, "int8", 8, [(13, 22), (16, 8)], 32),
+    "fp8_pool": (4, "float8_e4m3fn", 8, [(13, 22), (16, 8)], 32),
+    # the chunk is the whole bucket and starts on a page's last slot: the
+    # widest span a (B, NBW) table holds
+    "widest_span": (4, "bfloat16", 8, [(7, 32)], 32),
+}
+
+
+def _kv_write_case(kvh, dtype, bs, rows, n, seed=0, num_blocks=48, d=128):
+    """(pool, k, v, write ids, start_off, chunk_lens, row_off): rows hold
+    distinct pages, none the null page; the new rows come in float32 (the
+    activations' dtype) whatever the pool's."""
+    rng = np.random.RandomState(seed)
+    pool = jnp.asarray(
+        rng.randn(2, num_blocks, bs, kvh, d) * 3).astype(dtype)
+    k, v = (jnp.asarray(rng.randn(n, kvh, d) * 3, jnp.float32)
+            for _ in range(2))
+    nbw = (n - 1) // bs + 2
+    pages = iter(rng.permutation(np.arange(1, num_blocks)))
+    ids = np.zeros((len(rows), nbw), np.int32)
+    off, lens, row_off = (np.zeros(len(rows), np.int32) for _ in range(3))
+    at = 0
+    for i, row in enumerate(rows):
+        row_off[i] = at
+        if row is None:
+            continue
+        hist, lens[i] = row
+        off[i] = hist % bs
+        if lens[i]:
+            span = (hist + lens[i] - 1) // bs - hist // bs + 1
+            ids[i, :span] = [next(pages) for _ in range(span)]
+        at += lens[i]
+    assert at <= n
+    return pool, k, v, *map(jnp.asarray, (ids, off, lens, row_off))
+
+
+def _same_bits(a, b):
+    np.testing.assert_array_equal(
+        np.asarray(a).view(np.uint8), np.asarray(b).view(np.uint8))
+
+
+@pytest.mark.parametrize("name", [
+    *KV_WRITE_CASES, "window_page_group", "row_major_chunk"])
+def test_kv_write_kernel_matches_the_xla_form_bit_for_bit(name):
+    """`paged_kv_write` (interpret mode) against its oracle,
+    `write_kv_pages_blockwise`, over the WHOLE pool: the chunk's tokens on
+    their pages and every other byte as it was, the null page included."""
+    from vllm_production_stack_tpu.models import llama
+    from vllm_production_stack_tpu.ops.attention import (
+        write_kv_pages_blockwise,
+    )
+    from vllm_production_stack_tpu.ops.paged_attention_pallas import (
+        paged_kv_write,
+    )
+
+    if name == "window_page_group":
+        # a window layer writes the same span through the window group's
+        # ids into the window group's (smaller) pool: llama._group picks
+        # the table, the call is the same
+        pool, k, v, ids, off, lens, row_off = _kv_write_case(
+            4, "bfloat16", 8, [(13, 22), (16, 8)], 32, num_blocks=48)
+        small, _, _, ids_w, *_ = _kv_write_case(
+            4, "bfloat16", 8, [(13, 22), (16, 8)], 32, seed=1, num_blocks=12)
+        for window, kv in ((0, pool), (16, small)):
+            table = llama._group((ids, ids_w), window)
+            assert table is (ids_w if window else ids)
+            want = write_kv_pages_blockwise(
+                kv, k, v, table, off, lens, row_off=row_off)
+            _same_bits(paged_kv_write(
+                kv, k, v, table, off, lens, row_off, interpret=True), want)
+        return
+    if name == "row_major_chunk":
+        # (B, T) rows, as a dp > 1 prefill or a bare llama.forward has
+        # them: the kernel takes them end to end, each row at i * T
+        pool, k, v, ids, off, lens, _ = _kv_write_case(
+            4, "bfloat16", 8, [(13, 9), (0, 16), (7, 0)], 48)
+        k, v = k.reshape(3, 16, 4, 128), v.reshape(3, 16, 4, 128)
+        ids = ids[:, :3]  # (T - 1) // bs + 2 pages a row
+        want = write_kv_pages_blockwise(pool, k, v, ids, off, lens)
+        got = paged_kv_write(
+            pool, k.reshape(-1, 4, 128), v.reshape(-1, 4, 128), ids, off,
+            lens, jnp.arange(3, dtype=jnp.int32) * 16, interpret=True)
+        _same_bits(got, want)
+        return
+    pool, k, v, ids, off, lens, row_off = _kv_write_case(
+        *KV_WRITE_CASES[name])
+    want = write_kv_pages_blockwise(
+        pool, k, v, ids, off, lens, row_off=row_off)
+    got = paged_kv_write(pool, k, v, ids, off, lens, row_off, interpret=True)
+    assert got.dtype == pool.dtype and got.shape == pool.shape
+    _same_bits(got, want)
+    # something was written, and only inside the rows' spans
+    changed = np.asarray(
+        (got.astype(jnp.float32) != pool.astype(jnp.float32))).sum()
+    assert 0 < changed <= 2 * int(lens.sum()) * pool.shape[3] * pool.shape[4]
+
+
+def test_kv_write_kernel_reads_no_page_and_spares_the_null_page():
+    """Pages a chunk does not touch are never read or written — poisoned
+    with NaN they stay NaN and nothing else turns NaN — and padding rows
+    leave the null page alone."""
+    from vllm_production_stack_tpu.ops.paged_attention_pallas import (
+        paged_kv_write,
+    )
+
+    pool, k, v, ids, off, lens, row_off = _kv_write_case(
+        2, "float32", 8, [(3, 12), None, (8, 8)], 32)
+    used = sorted(set(np.asarray(ids).ravel().tolist()) - {0})
+    spare = [b for b in range(pool.shape[1]) if b not in used]
+    poisoned = pool.at[:, jnp.asarray(spare)].set(jnp.nan)
+    got = np.asarray(paged_kv_write(
+        poisoned, k, v, ids, off, lens, row_off, interpret=True))
+    assert np.isnan(got[:, spare]).all()
+    assert not np.isnan(got[:, used]).any()
+    # the first row's first page keeps its three resident tokens
+    first = int(ids[0, 0])
+    np.testing.assert_array_equal(
+        got[:, first, :3], np.asarray(pool)[:, first, :3])
+
+
+@pytest.mark.parametrize("model", ["tiny-llama", "tiny-laguna"])
+def test_engine_chunk_write_kernel_serves_the_xla_forms_tokens(model):
+    """A chunked prefill, then decode, of a tiny preset: the Pallas backend
+    (write kernel + prefill kernel, interpret mode) gives the tokens the XLA
+    backend gives, and /debug/timing says which write ran: the kernel wrote
+    the pages the rows' spans hold and read none back, the XLA form read
+    and rewrote every slot of its (rows, pages) table. tiny-laguna writes
+    through both page groups' tables."""
+    from vllm_production_stack_tpu.engine.config import (
+        CacheConfig, EngineConfig, SchedulerConfig,
+    )
+    from vllm_production_stack_tpu.engine.engine import LLMEngine
+    from vllm_production_stack_tpu.engine.request import SamplingParams
+    from vllm_production_stack_tpu.models.registry import (
+        resolve_model_config,
+    )
+
+    def make(prefill_backend):
+        return LLMEngine(EngineConfig(
+            model=resolve_model_config(model),
+            cache=CacheConfig(block_size=8, num_blocks=128,
+                              enable_prefix_caching=False),
+            scheduler=SchedulerConfig(
+                max_num_seqs=2, max_num_batched_tokens=32,
+                prefill_buckets=(32,), decode_buckets=(2,), decode_window=4,
+            ),
+            attention_backend="xla",
+            prefill_attention_backend=prefill_backend,
+        ))
+
+    rng = np.random.RandomState(3)
+    prompts = [list(rng.randint(1, 200, size=n)) for n in (45, 70)]
+    sp = SamplingParams(max_tokens=6, temperature=0.0, ignore_eos=True)
+    kernel, xla = make("pallas_interpret"), make("xla")
+    assert kernel.runner.prefill_writes_by_kernel
+    assert not xla.runner.prefill_writes_by_kernel
+    out_k, out_x = kernel.generate(prompts, sp), xla.generate(prompts, sp)
+    for a, b in zip(out_k, out_x):
+        assert a["token_ids"] == b["token_ids"]
+    tk, tx = kernel.flightrec.timing(), xla.flightrec.timing()
+    assert tk["prefill_n"] == tx["prefill_n"] >= 4
+    # 45 and 70 tokens lie on 6 and 9 pages of 8; a chunk that starts
+    # mid-page touches that page again
+    assert 15 <= tk["kv_write_pages_n"] <= 15 + tk["prefill_n"]
+    assert tk["kv_write_read_pages_n"] == 0
+    assert tx["kv_write_pages_n"] == tx["kv_write_read_pages_n"] > (
+        tk["kv_write_pages_n"])
+
+
 def test_prefill_sharded_matches_unsharded_tp2_dp2():
     """shard_map placement of the prefill kernel over (dp=2, tp=2): pure
     placement, no collective — must match the single-instance kernel."""

@@ -1463,6 +1463,10 @@ class LLMEngine:
                 # packed axis with its padding (rows x bucket where the
                 # chunk is row-major); tokens / positions = the fill share
                 rec.count("prefill_positions", handle.positions)
+                # pages a layer's chunk write wrote, and those it read back
+                # to merge first (none where the write kernel runs)
+                rec.count("kv_write_pages_n", handle.kv_write_pages[0])
+                rec.count("kv_write_read_pages_n", handle.kv_write_pages[1])
                 # (query, key) pairs its attention covers: a chunk's query
                 # at position p sees keys 0..p (chunks are contiguous)
                 rec.count("attn_prefill_pairs_n", sum(

@@ -225,6 +225,7 @@ class FlightRecorder:
             *(p + "_s" for p in PHASES),
             "prefill_s", "prefill_n", "prefill_chained_n",
             "prefill_kernel_n", "prefill_tokens", "prefill_positions",
+            "kv_write_pages_n", "kv_write_read_pages_n",
             "decode_s", "decode_n", "decode_tokens",
             "overlap_s", "step_wall_s", "rollback_n", "steps",
             "dispatch_n", "starved_n",

@@ -381,6 +381,10 @@ class StepHandle:
         # prefill handles: the token positions the program computed (the
         # packed axis, padding included; rows x bucket where row-major)
         self.positions = 0
+        # ... and (pages a layer's chunk write wrote, pages it read back
+        # first): the rows' spans and none through the write kernel, every
+        # slot of the (B, NBW) table twice through the XLA form
+        self.kv_write_pages = (0, 0)
         # a window of block passes (ModelConfig.block_diffusion): the
         # device's (len(BD_COUNTS),) int32 counts, fetched with the tokens;
         # `bd_passes` is what the engine's flight record reads once resolved
@@ -989,6 +993,12 @@ class ModelRunner:
     def prefill_uses_kernel(self) -> bool:
         """Whether the prefill programs hold the Pallas kernel."""
         return self._prefill_backend.startswith("pallas")
+
+    @property
+    def prefill_writes_by_kernel(self) -> bool:
+        """Whether a chunk's K and V reach the pool through the write kernel
+        (llama.chunk_write_by_kernel), not the XLA read-merge-scatter."""
+        return llama.chunk_write_by_kernel(self._prefill_backend, self.mesh)
 
     def device_report(self) -> dict:
         """The "device" section of GET /debug/programs: what the engine
@@ -1982,7 +1992,7 @@ class ModelRunner:
         top_ks = np.zeros(b_pad, np.int32)
         seeds: list[int | None] = [None] * b_pad
         counts = np.zeros(b_pad, np.int32)
-        row_off = 0
+        row_off = span_pages = 0
         for i, req in enumerate(work.requests):
             row = work.token_ids[i]
             # row i's tokens: the next len(row) of the packed axis, or the
@@ -2001,6 +2011,7 @@ class ModelRunner:
             first_blk = hist // bs
             n_span = (work.context_lens[i] - 1) // bs - first_blk + 1
             write_ids[i, :n_span] = req.block_table[first_blk : first_blk + n_span]
+            span_pages += n_span
             start_off[i] = hist % bs
             sample_rows[i] = (row_off if packed else i * t_pad) + len(row) - 1
             row_off += len(row)
@@ -2072,6 +2083,11 @@ class ModelRunner:
         )
         handle.moe = moe
         handle.positions = token_ids.size
+        cfg = self.config.model
+        if self._sp == 1 and len(cfg.page_layers) > len(cfg.latent_layers):
+            handle.kv_write_pages = (
+                (span_pages, 0) if self.prefill_writes_by_kernel
+                else (b_pad * nbw,) * 2)
         handle.chain_vec = tokens_dev
         return handle
 

@@ -51,6 +51,7 @@ from ..ops.moe_experts_pallas import moe_decode_experts, moe_prefill_experts
 from ..ops.paged_attention_pallas import (
     paged_decode_attention,
     paged_decode_attention_sharded,
+    paged_kv_write,
     paged_prefill_attention,
     paged_prefill_attention_sharded,
     paged_window_decode_attention,
@@ -1500,6 +1501,18 @@ def pack_index(chunk_lens: jax.Array, context_lens: jax.Array,
     }
 
 
+def chunk_write_by_kernel(backend: str, mesh) -> bool:
+    """Whether a prefill chunk's K/V reach the pool through the write kernel
+    (ops/paged_attention_pallas.py:paged_kv_write): wherever the prefill
+    kernel attends on one device. A Pallas backend on a chip means pages
+    that fill whole HBM tiles (ModelRunner._resolve_prefill_backend refuses
+    it for a head dim of 64 or a one-head tp shard), which is all the write
+    kernel needs; the XLA backend and a mesh of several devices (pallas_call
+    has no GSPMD rule, and the pool is sharded over tp there) keep
+    write_kv_pages_blockwise."""
+    return backend.startswith("pallas") and (mesh is None or mesh.size == 1)
+
+
 def _block_mask(cfg: ModelConfig) -> dict:
     """The attention mask's `block` argument of a model that generates by
     diffusion over blocks (a query sees its whole block), nothing for any
@@ -1538,8 +1551,9 @@ def _layer(
     lora_idx: jax.Array | None = None,
     write_blocks: dict | None = None,  # blockwise-write inputs (see forward)
     pallas_prefill: dict | None = None,  # {"context_lens", "chunk_start",
-    #   "interpret", "mesh"} — route attention through the paged
-    #   flash-prefill kernel instead of the XLA gather (mask is None then)
+    #   "interpret", "mesh", "write_kernel"} — route attention through the
+    #   paged flash-prefill kernel instead of the XLA gather (mask is None
+    #   then), and the chunk's K/V write through its kernel
     moe_stats: list | None = None,  # see _layer_body
     rows: dict | None = None,  # _row_view(...) of a packed chunk: x is
     #   (N, 1, h) then
@@ -1623,6 +1637,19 @@ def _layer(
             elif spec.latent:
                 kv_layer = write_latent_pages(
                     kv_layer, k.reshape(b * t, -1), slot_mapping)
+            elif write_blocks is not None and pallas_prefill is not None and (
+                    pallas_prefill["write_kernel"]):
+                # beside the prefill kernel: the pool keeps its layout
+                # through the program (a row-major chunk is its rows end to
+                # end, each at a multiple of T)
+                kv_layer = paged_kv_write(
+                    kv_layer, k.reshape(-1, nkv, hd), v.reshape(-1, nkv, hd),
+                    write_blocks["ids"], write_blocks["start_off"],
+                    write_blocks["chunk_lens"],
+                    (jnp.arange(b, dtype=jnp.int32) * t if rows is None
+                     else rows["row_off"]),
+                    interpret=pallas_prefill["interpret"],
+                )
             elif write_blocks is not None:
                 kv_layer = write_kv_pages_blockwise(
                     kv_layer, k, v, write_blocks["ids"],
@@ -1724,6 +1751,7 @@ def forward(
                 positions[:, 0] if chunk_start is None else chunk_start),
             "interpret": backend == "pallas_interpret",
             "mesh": mesh,
+            "write_kernel": chunk_write_by_kernel(backend, mesh),
         }
     else:
         # attention masks, built once per WINDOW KIND and reused by every

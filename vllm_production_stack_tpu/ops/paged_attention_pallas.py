@@ -987,3 +987,112 @@ def paged_window_decode_attention(
     return _paged_decode_attention(
         q, kv, block_tables, hist_len, staged_k, staged_v, step_k,
         scale=scale, window=window, interpret=interpret)
+
+
+def _kv_write_kernel(
+    # scalar prefetch
+    ids_ref,  # (B, NBW) int32 — pool page per (row, page of its written span)
+    off_ref,  # (B,) int32 — the chunk's first token's offset in its 1st page
+    len_ref,  # (B,) int32 — the row's real chunk tokens (0: a padding row)
+    row_ref,  # (B,) int32 — the row's first token on the packed axis
+    # inputs, all left in HBM
+    k_hbm,  # (N, kvh, D) — the packed chunk's new K rows, in the pool's dtype
+    v_hbm,  # (N, kvh, D)
+    kv_in,  # (2, num_blocks, bs, kvh, D) — the pool; aliased to kv_out
+    # output
+    kv_out,  # the same buffer
+    # scratch
+    sem,  # one DMA semaphore: every copy of the call
+):
+    """The chunk's new K and V rows into the pages its rows hold, by async
+    copies HBM -> HBM of token spans only: for each page a row's chunk
+    touches, the tokens of the packed axis that fall on it go to
+    `pool[0|1, page, first : first + n]`. A copy's extent is static, so a
+    span of n tokens (1 .. bs: a chunk that starts or ends mid-page) leaves
+    as the powers of two of n's bits, end to end, largest first — a whole
+    page is ONE copy a plane. Nothing is read from the pool and nothing
+    outside [start_off, start_off + chunk_len) is written: pages past a
+    row's span and padding rows issue no copy (the null page is left as it
+    is). Every copy is started before the first is waited for; the walk is
+    two loops over (row, page), no unrolling over pages, so the body stays
+    a few dozen lines of Mosaic however wide the table."""
+    del kv_in
+    bs = kv_out.shape[2]
+    pieces = [1 << i for i in reversed(range(bs.bit_length()))]
+
+    def page_copies(b, j):
+        """[(is it issued, K copy, V copy)] of page j of row b's span."""
+        off, page = off_ref[b], ids_ref[b, j]
+        lo = jnp.maximum(off - j * bs, 0)
+        n = jnp.minimum(off + len_ref[b] - j * bs, bs) - lo
+        src = row_ref[b] + j * bs + lo - off
+        out = []
+        for size in pieces:
+            at = n - (n & (2 * size - 1))  # the larger pieces lie before it
+            out.append(((n & size) != 0, *(
+                pltpu.make_async_copy(
+                    new.at[pl.ds(src + at, size)],
+                    kv_out.at[which, page, pl.ds(lo + at, size)],
+                    sem,
+                ) for which, new in enumerate((k_hbm, v_hbm)))))
+        return out
+
+    def over_pages(do):
+        def row(b, carry):
+            def page(j, carry):
+                for issued, *copies in page_copies(b, j):
+                    @pl.when(issued)
+                    def _():
+                        for copy in copies:
+                            do(copy)
+                return carry
+
+            span = jnp.where(
+                len_ref[b] > 0, pl.cdiv(off_ref[b] + len_ref[b], bs), 0)
+            return jax.lax.fori_loop(0, span, page, carry)
+
+        jax.lax.fori_loop(0, ids_ref.shape[0], row, 0)
+
+    over_pages(lambda copy: copy.start())
+    over_pages(lambda copy: copy.wait())
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def paged_kv_write(
+    kv: jax.Array,  # (2, num_blocks, bs, kvh, D) — the pool, updated in place
+    k: jax.Array,  # (N, kvh, D) — the packed chunk's new K rows
+    v: jax.Array,  # (N, kvh, D)
+    write_block_ids: jax.Array,  # (B, NBW) int32 — the pages of each row's
+    #   written span, in order (padding -> 0, the null page)
+    start_off: jax.Array,  # (B,) int32 — first token's offset in page 0
+    chunk_lens: jax.Array,  # (B,) int32 — real tokens a row
+    row_off: jax.Array,  # (B,) int32 — each row's first token on the axis
+    *,
+    interpret: bool = False,
+) -> jax.Array:
+    """A prefill chunk's K/V write as ONE kernel a layer that takes the pool
+    as the attention kernels do — in HBM, aliased input to output — so the
+    pool keeps one layout through a prefill program and XLA has no scatter
+    to lay it out for (ops/attention.py:write_kv_pages_blockwise is the XLA
+    form and this kernel's oracle: the same values on the same pages, the
+    rest of the pool bit for bit). A quantized pool's rows are converted
+    before the call, as the XLA form's `.astype` does. On a chip the pool's
+    pages have to fill whole HBM tiles (_pages_fill_tiles), as for the
+    prefill kernel beside which this runs."""
+    return pl.pallas_call(
+        _kv_write_kernel,
+        name="paged_kv_write",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,  # ids, start_off, chunk_lens, row_off
+            grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+        ),
+        out_shape=jax.ShapeDtypeStruct(kv.shape, kv.dtype),
+        input_output_aliases={6: 0},  # the pool, behind 4 scalars + k + v
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(write_block_ids, start_off, chunk_lens, row_off,
+      k.astype(kv.dtype), v.astype(kv.dtype), kv)
