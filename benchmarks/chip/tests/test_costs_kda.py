@@ -211,13 +211,13 @@ def test_the_manifest_names_what_was_added(solar):
     assert os.path.exists(
         os.path.join(HERE, "traffic", cell["traffic"] + ".json"))
     by_name = {m["name"]: m for m in manifest["per_layer"]}
-    tail = [m["name"] for m in manifest["per_layer"][-6:]]
-    assert tail == ["kda_prefill_roofline", "kda_decode_roofline",
-                    "kda_decode_step_roofline", "kda_state_share",
-                    "gqa_prefill_attn_roofline", "gqa_decode_attn_roofline"]
-    for name in tail:
+    # looked up by name: later PRs append entries behind these, and cells
+    # behind this one on a list
+    for name in ("kda_prefill_roofline", "kda_decode_roofline",
+                 "kda_decode_step_roofline", "kda_state_share",
+                 "gqa_prefill_attn_roofline", "gqa_decode_attn_roofline"):
         m = by_name[name]
-        assert m["workloads"] == [CELL] and m["moves"] == "out_tok_per_s"
+        assert CELL in m["workloads"] and m["moves"] == "out_tok_per_s"
         with open(os.path.join(HERE, "metrics", name + ".json")) as f:
             spec = json.load(f)
         assert os.path.exists(
@@ -226,7 +226,7 @@ def test_the_manifest_names_what_was_added(solar):
     # they stand (counters; hidden_size, moe_intermediate_size, weight_dtype)
     for name in ("moe_touched_share", "moe_read_share",
                  "moe_held_pairs_per_expert", "moe_prefill_experts_roofline"):
-        assert by_name[name]["workloads"][-1] == CELL
+        assert CELL in by_name[name]["workloads"]
     # every width as published; the cut's keys beside their published values
     assert (solar["hidden_size"], solar["moe_intermediate_size"],
             solar["num_experts_per_tok"], solar["head_dim"]) == (

@@ -48,7 +48,6 @@ def window(start, end):
 
 @pytest.mark.parametrize("name, want", [
     ("device_starved_share", 6.0),
-    ("starved_gap_ms", 30.0),
     ("dispatch_host_ms", 20.0),
 ])
 def test_ratio_metrics_from_their_files(name, want):
