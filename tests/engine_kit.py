@@ -83,6 +83,40 @@ def generate(engine, prompts, max_tokens=10):
     return [o["token_ids"] for o in engine.generate(prompts, greedy(max_tokens))]
 
 
+def squeeze(pool):
+    """Take every free page from under the seated rows, as a draft model's
+    scratch pages would (give them back with `pool.free_scratch`): admission
+    reserves a row's whole budget (`Scheduler._can_admit`), so a pool that
+    shrinks under seated rows is the one way left to a preemption by
+    growth."""
+    held = []
+    while (blk := pool.allocate_scratch()) is not None:
+        held.append(blk)
+    return held
+
+
+def generate_squeezed(engine, prompts, max_tokens=10):
+    """`generate`, with the pool squeezed once all the rows decode: their
+    growth finds no page and the newest is preempted; the pages come back at
+    the first preemption."""
+    sched = engine.scheduler
+    ids = [engine.add_request(prompt_token_ids=p, sampling=greedy(max_tokens))
+           for p in prompts]
+    got = {i: [] for i in ids}
+    held, squeezed, before = [], False, sched.total_preemptions
+    while engine.has_unfinished():
+        if not squeezed and len(sched.running) == len(ids) and all(
+                r.output_token_ids for r in sched.running):
+            held, squeezed = squeeze(sched.pool), True
+        for out in engine.step():
+            got[out.request_id].extend(out.new_token_ids)
+        if held and sched.total_preemptions > before:
+            while held:
+                sched.pool.free_scratch(held.pop())
+    assert squeezed and not held
+    return [got[i] for i in ids]
+
+
 def finish(engine, *request_ids):
     """Abort what a test would leave running, and step until drained."""
     for rid in request_ids:

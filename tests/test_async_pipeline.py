@@ -10,6 +10,7 @@ import jax
 import numpy as np
 import pytest
 
+import engine_kit as ek
 from vllm_production_stack_tpu.engine.config import EngineConfig
 from vllm_production_stack_tpu.engine.engine import LLMEngine
 from vllm_production_stack_tpu.engine.request import SamplingParams
@@ -309,9 +310,9 @@ def _mix(tag):
 def test_prefills_arriving_while_rows_decode_equal_the_serial_loop(
         pipe, serial, tag):
     """Prompts arrive while other rows decode: every chunk is enqueued
-    behind a window and a window behind every chunk, the new rows' first
-    tokens chained on the device — streams and dispatch order are the
-    serial loop's, and no window is thrown away for it."""
+    behind a window or a chunk and something behind every chunk, the new
+    rows' first tokens chained on the device — streams and dispatch order
+    are the serial loop's, and no window is thrown away for it."""
     sp, tenants = _mix(tag)
     calls = (0, 0, 3, 5, 6, 9, 14)
     out, order = {}, {}
@@ -363,11 +364,15 @@ def test_first_token_that_finishes_its_row_rolls_the_window_back(
     """The one thing the window behind a chunk assumes is that no row
     finishes ON its first token. Where one does, the reconcile of the chunk
     discards the window (rollback_n + 1) and the re-dispatch takes the
-    serial loop's turn: a decode window, not the chunk that waits."""
+    serial loop's turn: the round stands where the discarded window's
+    schedule() found it, one chunk in with one seat that does not decode,
+    so a decode window, not the chunk that waits."""
     prompt, sp = _first_token_finisher(serial, how)
     long = SamplingParams(max_tokens=40, temperature=0.7, ignore_eos=True)
     arrivals = {
-        0: [(f"{how}-keeper", prompt_ids(61, 6), long)],
+        # seven of the eight seats decode: the round is one chunk long
+        0: [(f"{how}-keeper{n}", prompt_ids(61 + 10 * n, 6), long)
+            for n in range(7)],
         # the finisher's chunk, then a prompt that waits behind it
         4: [(f"{how}-once", prompt, sp)],
         5: [(f"{how}-late", prompt_ids(62, 9), long)],
@@ -524,10 +529,11 @@ def test_an_unresolved_chunk_is_protected_like_a_window(pipe):
     assert _pool_is_whole(pipe) and not pipe.has_unfinished()
 
 
-def test_nothing_decode_ready_resolves_the_chunk_first(pipe, serial):
+def test_a_lone_prompts_chunks_follow_each_other_in_flight(pipe, serial):
     """A lone prompt of several chunks: there is no row to decode beside a
-    chunk, so each is resolved before the next is planned — never a second
-    chunk over rows whose positions an unresolved one still owes."""
+    chunk, and the next chunk is planned over the one in flight, from the
+    position that one ends at — the device's queue does not empty between
+    two chunks of a prompt."""
     sp = SamplingParams(max_tokens=9, temperature=0.0, ignore_eos=True)
     budget = pipe.config.scheduler.max_num_batched_tokens
     prompt = prompt_ids(69, 2 * budget + 7)  # three chunks
@@ -542,13 +548,153 @@ def test_nothing_decode_ready_resolves_the_chunk_first(pipe, serial):
     before = pipe.timing
     got = drive(pipe, {0: [("lone", prompt, sp)]}, hook=hook)
     assert got == drive(serial, {0: [("lone", prompt, sp)]})
-    # one chunk in flight at a time, each planned from resolved positions
+    # after each call: the chunk before resolved, the next one in flight
     assert states == [(0, budget), (budget, budget), (2 * budget, 7)]
     after = pipe.timing
     assert after["prefill_n"] - before["prefill_n"] == 3
-    # only the last chunk has something decode-ready behind it: its own row
-    assert after["prefill_chained_n"] - before["prefill_chained_n"] == 1
+    # every chunk had the next step enqueued behind it: a chunk twice, then
+    # the window of its own row
+    assert after["prefill_chained_n"] - before["prefill_chained_n"] == 3
+    # (nothing was decode-ready: pure prefill, no round)
+    assert after["prefill_follow_n"] == before["prefill_follow_n"]
     assert _pool_is_whole(pipe)
+
+
+# -- the scheduler's round through the pipelined loop -------------------------
+#
+# With rows that decode and seats that do not, a chunk follows a chunk: the
+# second is planned over the first in flight, at the position that one ends
+# at, and reads the pages (latents, state slot) it wrote.
+
+
+def _round_engines(kind, kit, pipe, serial):
+    """(pipelined engine, serial engine, chunk) of a model kind: dense is the
+    module's pair; latent attention and state slots are the kit's presets."""
+    from vllm_production_stack_tpu.models.registry import (
+        resolve_model_config,
+    )
+
+    if kind == "dense":
+        return pipe, serial, pipe.config.scheduler.max_num_batched_tokens
+    model, chunk = {
+        "latent": (resolve_model_config("tiny-kanana"), 32),
+        # (G K K K: one period; a chunk is a block of the KDA prefill form)
+        "state_slot": (ek.cut(resolve_model_config("tiny-solar-open2"), 4),
+                       64),
+    }[kind]
+    config = ek.engine_config(model, chunk=chunk)
+    return kit.engine(config)[0], kit.engine(config, serial=True)[0], chunk
+
+
+@pytest.mark.parametrize("kind", ["dense", "latent", "state_slot"])
+def test_chunks_follow_chunks_beside_decoding_rows_like_the_serial_loop(
+        kind, kit, pipe, serial):
+    """Two rows decode, long prompts of several chunks arrive: every round
+    runs more than one chunk, each planned over the one in flight — token
+    streams (unseeded sampling: they hang on the dispatch ORDER) and the
+    order are the serial loop's, every chunk had its successor enqueued
+    behind it, and nothing is thrown away but the tail."""
+    fast, slow, chunk = _round_engines(kind, kit, pipe, serial)
+    vocab = fast.config.model.vocab_size
+
+    def prompt(seed, n):
+        return list(map(int, np.random.RandomState(seed).randint(
+            1, min(vocab, 500), n)))
+
+    short = SamplingParams(max_tokens=40, temperature=0.8, ignore_eos=True)
+    long = SamplingParams(max_tokens=20, temperature=0.8, ignore_eos=True)
+    arrivals = {
+        0: [(f"{kind}-a", prompt(81, 9), short),
+            (f"{kind}-b", prompt(82, 12), short)],
+        3: [(f"{kind}-long", prompt(83, 2 * chunk + 22), long),
+            (f"{kind}-longer", prompt(84, 3 * chunk + 7), long)],
+    }
+    out, order, counted = {}, {}, {}
+    for eng in (fast, slow):
+        eng.runner._rng = jax.random.PRNGKey(13)
+        seq0, before = _last_seq(eng), eng.timing
+        out[eng is fast] = drive(eng, arrivals)
+        order[eng is fast] = dispatch_order(eng, seq0)
+        counted[eng is fast] = {
+            k: eng.timing[k] - before[k]
+            for k in ("prefill_n", "prefill_chained_n", "prefill_follow_n",
+                      "rollback_n")}
+        assert eng.timing["round_chunks_max"] >= 2
+        assert eng.scheduler.total_preemptions == 0
+        assert _pool_is_whole(eng) and not ek.undrained(eng)
+    assert out[True] == out[False]
+    assert [len(out[True][f"{kind}-{n}"]) for n in
+            ("a", "b", "long", "longer")] == [40, 40, 20, 20]
+    assert order[True] == order[False]
+    # two rows decode: the prompts' chunks run two and more a round
+    kinds = "".join(k[0] for k, _ in order[True])
+    assert "pp" in kinds and "ppd" in kinds
+    got = counted[True]
+    assert got["prefill_follow_n"] > 0
+    assert got["prefill_follow_n"] == counted[False]["prefill_follow_n"]
+    assert got["prefill_chained_n"] == got["prefill_n"] >= 6
+    assert got["rollback_n"] <= 1  # the tail only
+
+
+@pytest.mark.parametrize("kind", ["dense", "latent", "state_slot"])
+def test_a_queue_behind_full_seats_is_served_like_the_serial_loop(
+        kind, kit, pipe, serial):
+    """More requests than seats, long prompts among them: the round keeps
+    the seats full (a chunk follows a chunk while seats stand empty) and
+    every greedy stream is the serial loop's — a row's tokens hang on its
+    own prompt alone, whatever the order its chunks ran in."""
+    fast, slow, chunk = _round_engines(kind, kit, pipe, serial)
+    seats = fast.config.scheduler.max_num_seqs
+    lengths = [9, 2 * chunk + 5, 14, 3 * chunk + 1, 21, chunk + 9, 7,
+               2 * chunk + 30, 11, 17][: seats + 4]
+    prompts = ek.prompts(fast.config.model, lengths, seed=9)
+    before = fast.timing
+    want = ek.generate(slow, prompts, max_tokens=18)
+    assert ek.generate(fast, prompts, max_tokens=18) == want
+    after = fast.timing
+    assert after["prefill_follow_n"] > before["prefill_follow_n"]
+    assert fast.scheduler.total_preemptions == 0
+    assert _pool_is_whole(fast)
+
+
+def test_the_three_counters_of_the_round_and_of_admission(pipe, serial):
+    """`/debug/timing` says what the scheduler did about the seats: chunks
+    that followed chunks beside decoding rows (`prefill_follow_n`), the
+    longest round (`round_chunks_max`, a maximum: it is read, not
+    subtracted) and the turns in which the pool, not the seats, kept a
+    request waiting (`seat_held_by_pool_n`). Eight requests of fifteen
+    blocks' budget each over a pool of sixty-three: four sit, four wait with
+    four seats empty, nobody is preempted, and the streams are the serial
+    loop's."""
+    sp = SamplingParams(max_tokens=40, temperature=0.0, ignore_eos=True)
+    arrivals = {0: [(f"c{n}", prompt_ids(90 + n, 70), sp) for n in range(8)]}
+    seats, out, counted = {}, {}, {}
+
+    def hook(eng, i):
+        seats[eng] = max(seats.get(eng, 0), len(eng.scheduler.running))
+
+    for eng in (pipe, serial):
+        before = eng.timing
+        out[eng] = drive(eng, arrivals, hook=hook)
+        after = eng.timing
+        counted[eng] = {k: after[k] - before[k] for k in (
+            "prefill_follow_n", "seat_held_by_pool_n", "prefill_n")}
+        assert after["round_chunks_max"] >= 2
+        assert eng.scheduler.total_preemptions == 0 and _pool_is_whole(eng)
+    assert out[pipe] == out[serial]
+    assert all(len(v) == 40 for v in out[pipe].values())
+    assert seats == {pipe: 4, serial: 4}  # 4 x 15 blocks of 63
+    for eng in (pipe, serial):
+        assert counted[eng]["seat_held_by_pool_n"] > 0
+        assert 0 < counted[eng]["prefill_follow_n"] < counted[eng]["prefill_n"]
+    # (the pipelined loop plans some turns twice: only the chunks agree)
+    assert counted[pipe]["prefill_n"] == counted[serial]["prefill_n"]
+    # the counters are part of every snapshot, at zero too
+    from vllm_production_stack_tpu.engine.flightrec import FlightRecorder
+
+    fresh = FlightRecorder().timing()
+    assert (fresh["prefill_follow_n"], fresh["round_chunks_max"],
+            fresh["seat_held_by_pool_n"]) == (0, 0, 0)
 
 
 def test_grammar_row_sits_out_the_window_behind_its_chunk(pipe, serial):

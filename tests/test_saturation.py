@@ -17,6 +17,7 @@ import time
 
 import pytest
 
+import engine_kit as ek
 from vllm_production_stack_tpu.engine.config import (
     CacheConfig,
     EngineConfig,
@@ -273,13 +274,18 @@ def test_sched_overshoot_and_delivery():
 
 
 def test_sched_preemption_keeps_pending_then_charges_recompute():
-    s = make_scheduler(num_blocks=8, block_size=4, max_seqs=2, window=2)
+    s = make_scheduler(num_blocks=20, block_size=4, max_seqs=2, window=2)
     a, b = req("a", 8, max_tokens=20, ignore_eos=True), req(
         "b", 8, max_tokens=20, ignore_eos=True
     )
     s.add_request(a)
     s.add_request(b)
-    for _ in range(12):
+    held = []
+    for _ in range(20):
+        if not held and len(b.output_token_ids) >= 5:
+            # admission reserved both rows' whole budgets: the pool goes
+            # from under them
+            held = ek.squeeze(s.pool)
         work = s.schedule()
         if work is None:
             break
@@ -287,6 +293,8 @@ def test_sched_preemption_keeps_pending_then_charges_recompute():
         if s.total_preemptions:
             break
     assert s.total_preemptions >= 1
+    for blk in held:
+        s.pool.free_scratch(blk)
     victim = next(r for r in (a, b) if r.num_preemptions > 0)
     # pending SURVIVES preemption — the token fate is still open
     assert victim.ledger_pending > 0

@@ -297,16 +297,17 @@ def test_preemption_with_a_block_open(cfg, kit, when):
 
 
 def test_preemption_by_a_pool_that_runs_out(cfg, kit):
-    """Four rows that outgrow a pool of 14 pages: the newest row is
-    preempted as the others grow, through the pipelined loop, and every
-    answer is still the reference's."""
-    engine, _ = kit.engine(ek.engine_config(cfg, num_blocks=15, **WINDOW))
+    """Four rows whose pool runs out under them
+    (`engine_kit.generate_squeezed`): the newest row is preempted as the
+    others grow, through the pipelined loop, and every answer is still the
+    reference's."""
+    engine, _ = kit.engine(ek.engine_config(cfg, **WINDOW))
+    preempted = engine.scheduler.total_preemptions
     prompts = ek.prompts(cfg, (21, 18, 11, 14), seed=9)
-    outs = engine.generate(prompts, ek.greedy(20))
-    assert engine.scheduler.total_preemptions > 0
+    outs = ek.generate_squeezed(engine, prompts, max_tokens=20)
+    assert engine.scheduler.total_preemptions > preempted
     for prompt, out in zip(prompts, outs):
-        assert out["token_ids"] == _reference_tokens(
-            cfg, engine, prompt, 20)["tokens"]
+        assert out == _reference_tokens(cfg, engine, prompt, 20)["tokens"]
 
 
 def test_sampling_with_a_temperature(cfg, kit):

@@ -438,23 +438,24 @@ def test_a_row_alone_among_fifteen_and_in_a_slot_just_left(cfg, kit):
 
 
 def test_preemption_gives_the_slot_back_and_the_same_tokens(cfg, kit):
-    """A pool too small for both rows' growth preempts the newer one: it
-    loses its pages AND its slot, is computed again from its first token,
-    and ends with the tokens of an engine that never preempted."""
+    """A pool that runs out under both rows' growth preempts the newer one
+    (`engine_kit.generate_squeezed`): it loses its pages AND its slot, is
+    computed again from its first token, and ends with the tokens of a run
+    that never preempted."""
     model = ek.cut(cfg, 4)
     prompts = ek.prompts(model, (60, 60), seed=4)
-    roomy, before = kit.engine(ek.engine_config(model, seqs=2, **CHUNK))
-    want = ek.generate(roomy, prompts, max_tokens=40)
-    tight = kit.fresh_engine(
-        ek.engine_config(model, seqs=2, num_blocks=22, **CHUNK))
-    got = ek.generate(tight, prompts, max_tokens=40)
-    assert tight.scheduler.total_preemptions > 0
+    engine, before = kit.engine(ek.engine_config(model, seqs=2, **CHUNK))
+    want = ek.generate(engine, prompts, max_tokens=40)
+    assert ek.since(engine, before)["state_recomputed_tokens_n"] == 0
+    preempted = engine.scheduler.total_preemptions
+    before = engine.flightrec.timing()
+    got = ek.generate_squeezed(engine, prompts, max_tokens=40)
+    assert engine.scheduler.total_preemptions > preempted
     assert got == want
-    timing = tight.flightrec.timing()
+    timing = ek.since(engine, before)
     assert timing["state_recomputed_tokens_n"] >= 60
     assert timing["state_slots_zeroed_n"] >= 3  # two rows, one again
-    assert ek.since(roomy, before)["state_recomputed_tokens_n"] == 0
-    slots = tight.scheduler.pool.state
+    slots = engine.scheduler.pool.state
     assert slots.num_free == slots.num_usable == 2
 
 

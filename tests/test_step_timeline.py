@@ -442,38 +442,53 @@ def test_a_chunk_row_is_sliced_not_walked():
 
 def test_slow_device_program_reads_no_dry_gap(monkeypatch):
     """A program that outlasts the host's turn: the next one is enqueued
-    while it still runs, at every dispatch."""
-    import time
-
-    import jax
-
-    @jax.jit
-    def slowed(tokens):
-        def wait(x):
-            time.sleep(0.12)
-            return x
-        return jax.pure_callback(
-            wait, jax.ShapeDtypeStruct(tokens.shape, tokens.dtype), tokens)
-
+    while it still runs, at every dispatch, and the polls in between open
+    no dry gap. No clock and no device decide how long a program runs here:
+    the recorder is told that a program is complete once the host's wait
+    for it has returned (or the host has thrown it away), and not before. (A
+    sleep in a host callback behind the program did not say that: under
+    load the tiny program had ended before the callback was enqueued, the
+    CPU client then ran the callback on the step thread itself, and the
+    program before the next dispatch WAS complete — one run in three under
+    six test workers.)"""
     eng = _engine()
     execute_async = eng.runner.execute_async
+    rec_dispatch = eng.flightrec.dispatch
+    waited_for = []  # one flag a program, the newest last
+
+    def ending(flag, method):
+        def call():
+            got = method()
+            flag.append(True)
+            return got
+        return call
 
     def slow_program(work, prev=None):
         handle = execute_async(work, prev=prev)
-        handle.tokens = slowed(handle.tokens)  # the program's end, later
-        if handle.chain_vec is not None:
-            handle.chain_vec = handle.tokens
+        flag = []
+        waited_for.append(flag)
+        handle.resolve = ending(flag, handle.resolve)
+        handle.discard = ending(flag, handle.discard)
         return handle
 
+    def dispatch(*args, ready, **kw):
+        flag = waited_for[-1]  # of the program `execute_async` just made
+        return rec_dispatch(
+            *args, ready=lambda: bool(flag) and ready(), **kw)
+
     monkeypatch.setattr(eng.runner, "execute_async", slow_program)
-    eng.generate([[1, 2, 3, 4, 5]], _greedy(24))  # compiled, `slowed` too
+    monkeypatch.setattr(eng.flightrec, "dispatch", dispatch)
+    eng.generate([[1, 2, 3, 4, 5]], _greedy(24))  # compiled
     behind = _count_dispatches_behind_a_step(eng, monkeypatch)
     before = eng.timing
     out = eng.generate([[5, 4, 3, 2, 1, 7]], _greedy(40))
     d = _delta(eng, before)
     assert len(out[0]["token_ids"]) == 40
-    assert len(behind) >= 4 and d["dry_poll_n"] > len(behind)
+    # every dispatch but the first had a running step to be enqueued behind
+    assert len(behind) >= 4 and len(behind) == d["dispatch_n"] - 1
+    assert d["dry_poll_n"] > len(behind)
     assert d["dry_n"] == 0 == d["dry_s"] == d["dry_slack_s"]
+    assert all(waited_for)
 
 
 def test_rolled_back_dispatch_does_not_empty_the_queue():

@@ -641,7 +641,11 @@ class LLMEngine:
         build + h2d + enqueue, overlap_s = host time that ran while a device
         step was in flight, step_wall_s = total step() wall, rollback_n =
         speculative steps discarded, prefill_chained_n = prefill chunks
-        that had the next step enqueued behind them before their resolve)."""
+        that had the next step enqueued behind them before their resolve,
+        prefill_follow_n = chunks that followed a chunk while rows were
+        decode-ready, round_chunks_max = the most chunks of one round,
+        seat_held_by_pool_n = schedule() calls that left a seat empty with
+        a request waiting because the pool could not hold it to its end)."""
         return self.flightrec.timing()
 
     @property
@@ -1378,10 +1382,12 @@ class LLMEngine:
         finish, stop-string hit, abort, or a first token that finished its
         row), the just-dispatched step is discarded and rolled back, so the
         emitted token streams are bitwise identical to the serial reference
-        loop's (testing/serial_loop.py). What cannot be enqueued behind the
-        in-flight step (a second prefill chunk over an unresolved one, a
-        chunk with nothing to decode beside it, a priority stall) waits for
-        its resolve and is dispatched in the same call. Outputs returned by
+        loop's (testing/serial_loop.py). A chunk follows a chunk in flight
+        the same way (the scheduler's round: a row starts its next chunk
+        where the one in flight ends). Where nothing can be enqueued behind
+        the in-flight step (a priority stall, a row parked at a fetch, rows
+        that sit a step out), the next step waits for its resolve and is
+        dispatched in the same call. Outputs returned by
         one call therefore belong to the step dispatched on the PREVIOUS
         call (one step of latency)."""
         outputs: list[RequestOutput] = []
@@ -1406,6 +1412,10 @@ class LLMEngine:
         clients would hang forever."""
         with self.flightrec.span("sched"):
             work = self.scheduler.schedule(inflight=inflight)
+            if self.scheduler.seat_held_by_pool:
+                # a seat stays empty although a request waits for it: the
+                # pool could not carry that request to its end
+                self.flightrec.count("seat_held_by_pool_n")
             for req in self.scheduler.take_finished_externally():
                 outputs.append(
                     self._make_output(
@@ -1455,6 +1465,11 @@ class LLMEngine:
             results = self.scheduler.postprocess(work, rows)
             rec.poll()  # `post` of a chunk is long: see FlightRecorder.poll
             if kind == "prefill":
+                if work.round_chunk > 1:
+                    # a chunk that followed a chunk although rows were
+                    # decode-ready (the scheduler's round); the longest round
+                    rec.count("prefill_follow_n")
+                    rec.peak("round_chunks_max", work.round_chunk)
                 # chunk tokens PROCESSED (mid-prompt chunks emit none)
                 rec.count(
                     "prefill_tokens", sum(len(t) for t in work.token_ids)
@@ -1592,12 +1607,13 @@ class LLMEngine:
                 nxt = None
             elif work is None:
                 # nothing could be enqueued behind the in-flight step: a
-                # prefill chunk with nothing to decode beside it (the
-                # scheduler plans no second chunk over the rows an
-                # unresolved one still owes), or a priority stall (a
-                # higher-priority waiter blocked by in-flight victims). It
-                # is resolved now — re-schedule IN THIS CALL, so the next
-                # chunk, or the preempt-and-admit, happens a full loop
+                # priority stall (a higher-priority waiter blocked by
+                # in-flight victims), or rows that must see it resolved (a
+                # hydration plan's, a constrained row behind its chunk; a
+                # chunk FOLLOWS a chunk in flight without this: the
+                # scheduler plans it at the speculative position). It is
+                # resolved now — re-schedule IN THIS CALL, so the
+                # preempt-and-admit, or the next step, happens a full loop
                 # round-trip sooner.
                 work = self._schedule(outputs)
                 if work is not None:

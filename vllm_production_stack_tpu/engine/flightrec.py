@@ -224,6 +224,7 @@ class FlightRecorder:
         self._tot: dict[str, int] = dict.fromkeys((
             *(p + "_s" for p in PHASES),
             "prefill_s", "prefill_n", "prefill_chained_n",
+            "prefill_follow_n", "round_chunks_max", "seat_held_by_pool_n",
             "prefill_kernel_n", "prefill_tokens", "prefill_positions",
             "kv_write_pages_n", "kv_write_read_pages_n",
             "decode_s", "decode_n", "decode_tokens",
@@ -274,6 +275,12 @@ class FlightRecorder:
         model's `moe_*_n`) appears in timing() once it has been counted: a
         dense model serves none of them."""
         self._tot[key] = self._tot.get(key, 0) + n
+
+    def peak(self, key: str, n: int) -> None:
+        """Keep the largest `n` seen under `key` (a running maximum: the
+        one kind of total a reader does not subtract)."""
+        if n > self._tot.get(key, 0):
+            self._tot[key] = n
 
     @property
     def done_seq(self) -> int:

@@ -207,7 +207,11 @@ def window_row_blocks(model: ModelConfig, cache: CacheConfig,
     its resolved position, one prefill chunk in flight (the give-back runs
     on resolved positions only: a rolled-back step must find its pages),
     two decode windows (one in flight, one enqueued behind it), and the
-    page rounding at both ends."""
+    page rounding at both ends. (A second chunk planned over the one in
+    flight spends another seat's share: two token budgets are in flight
+    over ALL rows at most, and the group holds `max_num_seqs` of them; with
+    ONE seat the second chunk may find no page, is then not planned, and
+    the step loop resolves the first: `Scheduler._ensure_blocks`.)"""
     tokens = (model.max_window + sched.max_num_batched_tokens
               + 2 * sched.decode_window)
     return math.ceil(tokens / cache.block_size) + 2

@@ -4,9 +4,11 @@ The reference's engines run vLLM's scheduler (external, controlled via flags
 like --enable-chunked-prefill, helm deployment-vllm-multi.yaml:140-146); this
 is the TPU engine's own: it emits fixed-*logical* work items (one prefill
 chunk, or one decode batch) which the model runner pads into bucketed device
-shapes. Policy: decode and prefill alternate when both are pending, so a long
-prompt can't stall token generation (the point of chunked prefill) and decode
-can't starve admissions.
+shapes. Policy: a ROUND gives every seat one turn of the device — the rows
+that decode take theirs together (one window), every other seat may take one
+prefill chunk — so a long prompt can't stall token generation (the point of
+chunked prefill), decode can't starve admissions, and seats do not stand
+empty behind a queue (`Scheduler.schedule`).
 """
 
 from __future__ import annotations
@@ -51,6 +53,9 @@ class PrefillWork:
     slot_mappings: list[list[int]] = field(default_factory=list)
     context_lens: list[int] = field(default_factory=list)
     sample: list[bool] = field(default_factory=list)
+    # the chunk's place in its round (`Scheduler.schedule`): 1 = the first
+    # after a decode window; 0 where no row was decode-ready (pure prefill)
+    round_chunk: int = 0
 
     def add_row(
         self,
@@ -198,12 +203,17 @@ class Scheduler:
         self.waiting: deque[Request] = deque()
         self.running: list[Request] = []
         self._hash_chains: dict[str, list[int]] = {}  # req id -> per-block hashes
-        self._last_was_prefill = False
+        # prefill chunks dispatched since the last decode window: where the
+        # round stands (schedule())
+        self._round_chunks = 0
         self._last_was_verify = False
-        # the two alternation flags as the last schedule() that returned
-        # work found them: a rollback of that work puts them back, so the
-        # serial re-dispatch takes the turn the serial loop would
-        self._turn_before = (False, False)
+        # the round's count and the verify flag as the last schedule() that
+        # returned work found them: a rollback of that work puts them back,
+        # so the serial re-dispatch takes the turn the serial loop would
+        self._turn_before = (0, False)
+        # the last schedule() left a seat empty with a request waiting
+        # because the pool could not carry it to its end (`_can_admit`)
+        self.seat_held_by_pool = False
         self.total_preemptions = 0
         # speculative-decoding counters (vLLM metric parity:
         # spec_decode_num_draft_tokens / num_accepted_tokens), plus the
@@ -465,12 +475,26 @@ class Scheduler:
         two-deep pipeline. A PREFILL chunk in flight is planned the same
         way: a row whose prompt it ends is decode-ready at its prompt
         length and takes its first token from the chunk's device output,
-        so the next window is enqueued behind the chunk. No second prefill
-        is scheduled over an unresolved one (its rows' num_computed_tokens
-        are still owed): with nothing to decode beside it this returns
-        None, and the step loop resolves the chunk first."""
+        so the next window is enqueued behind the chunk; a row it leaves
+        mid-prompt starts its next chunk where the chunk in flight ends
+        (`_try_add_chunk`), and the device runs the two in order, so the
+        second reads the pages and the state slot the first wrote.
+
+        What is picked is the next turn of a ROUND, in which every seat gets
+        one turn of the device: the rows that are decode-ready take theirs
+        TOGETHER (one decode window), and every other seat — mid-prompt, or
+        empty with a request waiting for it — may take one prefill chunk.
+        So with something to decode and something to prefill, a chunk is
+        chosen while the chunks dispatched since the last window are fewer
+        than the seats that do not decode (and one at least), else the
+        window: the more rows would wait for chunks, the fewer chunks they
+        wait for. One seat that does not decode gives chunk, window, chunk,
+        window; nothing to decode gives chunks only; every seat decoding
+        gives windows only. The only inputs are `max_num_seqs` and what the
+        seats hold: there is nothing to set."""
         self.expire_deadlines()
         self.apply_evictions()
+        self.seat_held_by_pool = False
         if (
             self._qos_active
             and inflight is not None
@@ -504,6 +528,9 @@ class Scheduler:
                 >= r.prefill_target
             )
             (decode_ready if done else prefilling).append(r)
+        # the rows whose turn the window is: one that the step in flight
+        # ends (`_exhausted`) is no longer there once it has resolved
+        deciding = sum(1 for r in decode_ready if not self._exhausted(r))
         can_admit = bool(self.waiting) and (
             len(self.running) < self.config.max_num_seqs
             or (
@@ -513,12 +540,14 @@ class Scheduler:
             )
         )
 
-        want_prefill = (
-            bool(prefilling) or can_admit
-        ) and not isinstance(inflight, PrefillWork)
-        turn = (self._last_was_prefill, self._last_was_verify)
+        want_prefill = bool(prefilling) or can_admit
+        turn = (self._round_chunks, self._last_was_verify)
         work = None
-        if want_prefill and (not decode_ready or not self._last_was_prefill):
+        if want_prefill and (
+            not decode_ready
+            or self._round_chunks
+            < max(1, self.config.max_num_seqs - deciding)
+        ):
             work = self._schedule_prefill(prefilling)
             if work is None and self._bd_block and not decode_ready:
                 # a prompt shorter than a block has nothing to prefill: the
@@ -530,9 +559,25 @@ class Scheduler:
         if work is None and decode_ready:
             work = self._schedule_decode_or_verify(decode_ready, inflight)
         if work is not None:
-            self._last_was_prefill = isinstance(work, PrefillWork)
+            if isinstance(work, PrefillWork):
+                self._round_chunks += 1
+                work.round_chunk = self._round_chunks if decode_ready else 0
+            else:
+                self._round_chunks = 0
             self._turn_before = turn
         return work
+
+    def _exhausted(self, r: Request) -> bool:
+        """The row's speculative state has used up `max_tokens` or the
+        model's length: in the resolved world it has FINISHED (on the sync
+        path it finishes in postprocess and leaves `running`, so this is
+        never true there)."""
+        return (
+            r.sampling.max_tokens
+            <= len(r.output_token_ids) + r.num_inflight_outputs
+            or self.model_config.max_model_len
+            <= r.num_computed_tokens + r.num_inflight_tokens
+        )
 
     def _schedule_decode_or_verify(
         self, ready: list[Request], inflight=None
@@ -779,7 +824,7 @@ class Scheduler:
                 if need > self.pool.num_usable:
                     self._can_admit(req)  # aborts the impossible fit
                     continue
-                if self.pool.num_free + len(victim.block_table) < need:
+                if not self._fits(req, victim):
                     # the memory watermark would still block the admission
                     # even with the victim's blocks back — don't pay a
                     # preemption (full recompute for the victim) for
@@ -836,14 +881,22 @@ class Scheduler:
         boundary (free tokens — adopted, not computed) and then bounds
         the prefill chunk at the next unresolved load boundary; a request
         parked exactly at a pending fetch contributes no row this step
-        (decode and other prefills proceed around it)."""
-        limit = (
-            self._consume_hydrated(req)
-            if req.hydration_plan is not None
-            else None
-        )
+        (decode and other prefills proceed around it).
+
+        A row with a chunk still in flight (async pipeline) starts this one
+        where that one ends, `num_computed_tokens + num_inflight_tokens`,
+        like a decode window planned over a window: the device runs the two
+        in order. A hydration plan is consumed at RESOLVED positions only
+        (an adoption moves `num_computed_tokens`), so its row sits out while
+        a chunk of it is in flight."""
+        limit = None
+        if req.hydration_plan is not None:
+            if req.num_inflight_tokens:
+                return 0
+            limit = self._consume_hydrated(req)
         target = req.prefill_target
-        chunk = min(budget, target - req.num_computed_tokens)
+        start = req.num_computed_tokens + req.num_inflight_tokens
+        chunk = min(budget, target - start)
         if limit is not None:
             chunk = min(chunk, limit)
         if self._bd_block:
@@ -852,11 +905,10 @@ class Scheduler:
             chunk -= chunk % self._bd_block
         if chunk <= 0:
             return 0
-        if not self._ensure_blocks(req, req.num_computed_tokens + chunk):
+        if not self._ensure_blocks(req, start + chunk):
             return 0  # req preempted itself; it's back in waiting
         if req not in self.running:
             return 0
-        start = req.num_computed_tokens
         idxs = range(start, start + chunk)
         work.add_row(
             request=req,
@@ -898,8 +950,7 @@ class Scheduler:
         # sync path: exhausted rows finish in postprocess and leave running)
         cand = [
             r for r in ready[: self.config.max_num_seqs]
-            if r.sampling.max_tokens - eff_outputs(r) > 0
-            and self.model_config.max_model_len - eff_computed(r) > 0
+            if not self._exhausted(r)
             # constrained rows chain decode-on-decode (the window program
             # hands the next window its post-window automaton states on
             # device) but cannot chain onto a verify step or a prefill
@@ -1051,10 +1102,43 @@ class Scheduler:
             for r in reqs
         )
 
+    def _budget_blocks(self, req: Request) -> int:
+        """Blocks `req` may come to hold: its prompt, the answer it declared
+        (`max_tokens`) and the tail of the decode window that samples its
+        last token (a window's blocks are allocated whole), within the
+        model's length — and within the pool: a request that declares more
+        than the pool holds is seated once it has the pool to itself."""
+        tokens = (
+            req.num_prompt_tokens + req.sampling.max_tokens
+            + max(self._bd_block, self.config.decode_window, 1) - 1
+        )
+        return min(
+            self._blocks_needed(
+                min(tokens, self.model_config.max_model_len)),
+            self.pool.num_usable,
+        )
+
+    def _fits(self, req: Request, victim: Request | None = None) -> bool:
+        """Do the free blocks, less what every running row may still grow by
+        (`_budget_blocks` beyond its table), hold `req`'s own budget? With
+        `victim`: once that row's seat and blocks were taken for it."""
+        free = self.pool.num_free
+        if victim is not None:
+            free += len(victim.block_table)
+        owed = sum(
+            max(0, self._budget_blocks(r) - len(r.block_table))
+            for r in self.running if r is not victim
+        )
+        return free - owed >= self._budget_blocks(req)
+
     def _can_admit(self, req: Request) -> bool:
-        """Admission watermark: only admit when the pool can hold the whole
-        recompute target plus one decode token — prevents admission/preemption
-        thrash (the oldest running request must always be able to finish)."""
+        """Admission keeps its promise: a request is seated only when the
+        pool can hold it to its end — its whole recompute target AND the
+        answer it declared — beside what the rows already seated may still
+        grow by. Every running request can then finish; the pool, and not a
+        preemption (a whole prompt recomputed), is what says "no more
+        seats". A request reserves what it declares: a very large
+        `max_tokens` keeps others out (docs/21-engine-configuration.md)."""
         need = self._blocks_needed(req.prefill_target + 1)
         # the window group holds at most a window and what is in flight of
         # a row, whatever its length
@@ -1078,7 +1162,10 @@ class Scheduler:
                 return False
         if self.pool.state is not None and not self.pool.state.num_free:
             return False  # no admission without a free state slot
-        return self.pool.num_free >= need
+        if not self._fits(req):
+            self.seat_held_by_pool = True
+            return False
+        return True
 
     def window_share(self) -> float:
         """Window-group pages resident / the pages the same live rows would
@@ -1601,16 +1688,16 @@ class Scheduler:
         writes land only at positions >= the speculative base — beyond
         every registered prefix-cache block, and fully overwritten (in
         device order) by whichever dispatch next owns those slots. The
-        prefill / decode and verify / decode turns go back to where the
-        discarded work's schedule() found them (nothing is scheduled
-        between a dispatch and its rollback), so the re-dispatch follows the
-        serial loop's order. Goodput ledger: what the step samples (a
-        window x its rows, a verify row's fed positions, a chunk's first
-        tokens) nobody will consume — sampled AND wasted in one motion
-        (they never reach postprocess)."""
+        round's count of chunks and the verify / decode turn go back to
+        where the discarded work's schedule() found them (nothing is
+        scheduled between a dispatch and its rollback), so the re-dispatch
+        follows the serial loop's order. Goodput ledger: what the step
+        samples (a window x its rows, a verify row's fed positions, a
+        chunk's first tokens) nobody will consume — sampled AND wasted in
+        one motion (they never reach postprocess)."""
         self.ledger.rollback(sum(out for _, out in self._row_advance(work)))
         self.end_speculative(work)
-        self._last_was_prefill, self._last_was_verify = self._turn_before
+        self._round_chunks, self._last_was_verify = self._turn_before
         for req in work.requests:
             if req.status.finished or req not in self.running:
                 continue  # blocks already released by its finish
