@@ -166,7 +166,7 @@ REFERENCE_MEAN_ATOL_SOLAR = 0.09
 # 0.0174-0.0210 of the reference's 8th best biased score
 ROUTING_DISAGREEMENT_MAX_SOLAR = 0.30
 ROUTING_MARGIN_MAX_SOLAR = 0.04
-# The nearest precision below the configuration's is a bfloat16 STATE, and
+# A precision below the configuration's is a bfloat16 STATE, and
 # logprobs cannot tell it: on the shortest prompt it reads 0.121-0.141 /
 # mean 0.0345-0.0392 beside the served 0.124-0.140 / 0.0287-0.0299 — bf16
 # weights and activations through 8 layers scatter as much. So the SERVED
@@ -222,6 +222,69 @@ ROUTING_MARGIN_MAX_LING = 0.085
 # 55,552 tokens differ; a router whose product and scores are bfloat16:
 # 12.59%, 12.64% (margins up to 0.035). The limit lies between.
 ROUTER_CHOICE_DISAGREEMENT_MAX_LING = 0.005
+# Falcon-H1-34B at 6 layers (bf16 weights, activations and K/V pages, float32
+# state slots, the SSD and the paged GQA kernels of the SAME layer in one
+# program; benchmarks/chip/configs/falcon-h1-34b-bf16-6l.json) against
+# reference/falcon_h1.py (float32, the token-by-token recurrence, plain
+# softmax attention, the multipliers literally as published; nothing routes).
+# Rows of the cell's own lengths — 16,320, 9,184 and thirteen log-uniform in
+# 2k-16k, all 16 seats in flight with one of 48 tokens — and 32 decoded
+# positions each (4,096 logprobs, ~100k tokens a seed; seeds 4000000007 and
+# 3100000033; PR 50, PERF.md §6). Each limit lies between what the served
+# configuration gave and what has to come out as not correct: the served
+# logprobs of the SHORTEST prompt against the reference's fault arms
+# (reference/falcon_h1.py: a branch zeroed, a multiplier left out, the norm
+# before the gate, the groups' B and C exchanged, D or the convolution's bias
+# left out).
+# max |Δlogprob|: served 0.0933, 0.0853; the gentlest arm, the embedding's
+# multiplier left out (every norm rescales it away: only the branches'
+# weight beside the residual moves), 0.846, 0.855; attention zeroed 3.78,
+# 4.21, the groups exchanged 3.96, 4.80, the norm before the gate 4.64, 4.32,
+# the convolution's bias left out 5.02, 5.56, D left out 5.67, 5.39, a
+# multiplier of a branch or of the MLP left out 6.6-10.6, the state-space
+# branch zeroed 9.29, 9.42, the head's multiplier left out 230, 226.
+REFERENCE_ATOL_FALCON_H1 = 0.30
+# mean |Δlogprob|: served 0.0189, 0.0184; the same arms 0.251, 0.233 (the
+# embedding's multiplier), 1.41-5.59, 65.5, 68.8: 2.6 x over the one, 4.7 x
+# under the other
+REFERENCE_MEAN_ATOL_FALCON_H1 = 0.05
+# A precision below the configuration's is a bfloat16 STATE, and
+# logprobs cannot tell it (0.0924 / mean 0.0201 and 0.0831 / 0.0203 beside
+# the served 0.0933 / 0.0189 and 0.0853 / 0.0184: bf16 weights and
+# activations scatter as much): the SERVED state
+# has a limit of its own (reference_check.served_state), each row's slot of
+# the last layer's pool against the reference's recurrence over the x, B, C,
+# dt the served programs fed that layer (tapped). Served 6.7e-5, 1.5e-5 (16
+# rows, 101,122 and 105,506 tokens), the reference with its state rounded to
+# bfloat16 at every token 1.48e-2, 1.23e-2: the limit lies between, 15 x
+# over the one and 12 x under the other, and that arm fails by it ALONE.
+SSD_STATE_RTOL = 1e-3
+# A float8 K/V pool (`--kv-cache-dtype fp8`; the configuration states
+# bfloat16 pages, which every layer holds: ~1.3 GiB live beside 0.4 GiB of
+# state) is the other precision below, and logprobs cannot tell it either:
+# over thousands of diffuse keys the pages' rounding averages away. The fp8
+# arm read 0.1378 / mean 0.0194 and 0.1154 / 0.0196 on the two seeds, INSIDE
+# the limits above, beside the served 0.0933 / 0.0189 and 0.0853 / 0.0184:
+# the mean does not move, and between 0.0933 and 0.1154 no limit on a
+# maximum over 4,096 values has room (the seeds alone move it by 0.008 and
+# 0.022). So the served PAGES have a limit of their own
+# (reference_check.served_pages): each row's pages of the FIRST layer, found
+# through the block table the row last ran with, against the reference's K
+# (times `key_multiplier`, rotated) and V of the tokens it was fed — one
+# norm and two projections from the embedding. Served 3.51e-3, 3.51e-3 (16
+# rows, 101,106 and 105,490 tokens: bfloat16's 2^-9 twice over), the fp8 arm
+# 2.69e-2, 2.69e-2: the limit lies between, 2.8 x over the one and 2.7 x
+# under the other, and that arm fails by it ALONE (its state reads 5.6e-5,
+# 1.1e-5: float32 whatever the pages are).
+KV_PAGES_RTOL = 1e-2
+FALCON_H1_ARMS = (
+    {"zero_branch": "attention"}, {"zero_branch": "ssm"},
+    {"norm_before_gate": True}, {"swap_groups": True}, {"no_d": True},
+    {"no_conv_bias": True},
+    *({"skip_multiplier": k} for k in (
+        "embedding_multiplier", "lm_head_multiplier",
+        "attention_out_multiplier", "key_multiplier", "ssm_in_multiplier",
+        "ssm_out_multiplier", "ssm_multipliers", "mlp_multipliers")))
 # SDAR-30B-A3B (generation by diffusion over blocks, bf16 weights, 8 of 48
 # layers; benchmarks/chip/configs/sdar-30b-a3b-bf16-8l.json): the served
 # windows of block passes against reference/sdar.py's forward over the same
@@ -905,9 +968,10 @@ def child_kernels(seed: int) -> int:
 def child_reference(args) -> int:
     """The engine against the plain float32 reference, in this process (it
     holds the chip). One JSON line; exit 0 only if the served arm is inside
-    its family's limits (the largest |Δlogprob|, for laguna the mean too),
-    its routing inside its bounds, and the fp8-pool arm — for laguna also
-    each reference that is wrong in one way — outside them."""
+    its family's limits (the largest |Δlogprob|, for laguna the mean too;
+    where the model holds state, the served state; for falcon_h1 the served
+    pages), its routing inside its bounds, and the fp8-pool arm — for laguna
+    also each reference that is wrong in one way — outside them."""
     import contextlib
     import importlib.util
 
@@ -919,6 +983,7 @@ def child_reference(args) -> int:
     from vllm_production_stack_tpu.engine.server import (
         build_parser, engine_config_from_args,
     )
+    from vllm_production_stack_tpu.models import llama
     from vllm_production_stack_tpu.testing import reference_check
     from vllm_production_stack_tpu.utils.compile_cache import (
         configure_compile_cache,
@@ -932,7 +997,7 @@ def child_reference(args) -> int:
     # (KDA layers over state slots between gated NoPE GQA layers)
     family = {"deepseek_v3": "mla", "laguna": "laguna",
               "solar_open2": "solar_open2", "sdar_moe": "sdar",
-              "ling_flash": "ling"}.get(
+              "ling_flash": "ling", "falcon_h1": "falcon_h1"}.get(
         config.get("model_type"), "decoder")
     # (the two families whose reference follows the served routing and
     # whose cell is the long-document one)
@@ -961,6 +1026,8 @@ def child_reference(args) -> int:
                         ROUTING_MARGIN_MAX_SOLAR),
         "ling": (REFERENCE_ATOL_LING, REFERENCE_MEAN_ATOL_LING,
                  ROUTING_DISAGREEMENT_MAX_LING, ROUTING_MARGIN_MAX_LING),
+        "falcon_h1": (REFERENCE_ATOL_FALCON_H1, REFERENCE_MEAN_ATOL_FALCON_H1,
+                      None, None),
     }[family]
     flags = (config["rehearse"] if args.rehearse else config)["engine_flags"]
     dev = jax.devices()[0]
@@ -985,8 +1052,18 @@ def child_reference(args) -> int:
     lengths = np.exp(
         rng.uniform(np.log(lo), np.log(hi), size=n_prompts)).astype(int)
     lengths[:2] = [hi, (chunk + hi) // 2]  # two prompts cross a chunk edge
-    # (the families most of whose cache is recurrent state)
-    holds_state = family in ("solar_open2", "ling")
+    # (the families with layers that hold a recurrent state)
+    holds_state = family in ("solar_open2", "ling", "falcon_h1")
+    # (whether an fp8 pool reaches most of the model: K/V or latent pages in
+    # more than half of its layers)
+    model = engine_config_from_args(build_parser().parse_args(flags)).model
+    pages_in_most_layers = 2 * len(model.page_layers) > model.num_layers
+    state_rtol = SSD_STATE_RTOL if family == "falcon_h1" else KDA_STATE_RTOL
+    if family == "falcon_h1" and not args.rehearse:
+        # every seat in flight: fifteen rows of the cell's lengths (and the
+        # short one below)
+        lengths = np.concatenate([lengths, np.exp(rng.uniform(
+            np.log(lo), np.log(hi), size=9)).astype(int)])
     if holds_state:
         # ... and one SHORT prompt, the one the faulty references are held
         # to: what a slot's previous holder left (a state, a convolution
@@ -1006,8 +1083,9 @@ def child_reference(args) -> int:
         # (MLA: the served programs' own routing is tapped, and the
         # reference follows it — reference_check.compare says why; a model
         # with state slots: what its last such layer's recurrence was fed)
-        tap = reference_check.RoutingTap() if mla else None
-        linear = ecfg.model.linear_layers
+        tap = (reference_check.RoutingTap()
+               if mla and family != "falcon_h1" else None)
+        linear = ecfg.model.state_layers
         state_tap = reference_check.StateTap(linear[-1]) if linear else None
         # (ling: and what its last expert layer's ROUTER was fed and chose)
         router_tap = (reference_check.RouterTap(
@@ -1025,7 +1103,8 @@ def child_reference(args) -> int:
             state_pool = np.asarray(
                 engine.runner.kv_caches[state_tap.layer]["s"])
             out["state"] = reference_check.served_state(
-                reference, ecfg.model, state_tap, state_pool)
+                reference, ecfg.model, state_tap, state_pool,
+                params=engine.runner.params)
         if router_tap:
             from vllm_production_stack_tpu.models import loader
 
@@ -1033,6 +1112,9 @@ def child_reference(args) -> int:
                 ecfg.model, engine.runner.params)
             out["router"] = reference_check.served_router(
                 reference, ecfg.model, router_tap, served_view)
+        if family == "falcon_h1":
+            first_pages = np.asarray(
+                llama.page_pool(engine.runner.kv_caches[0]))
         out["engine_s"] = round(time.monotonic() - t0, 1)
         print(f"reference check: served in {out['engine_s']} s",
               file=sys.stderr, flush=True)
@@ -1051,10 +1133,35 @@ def child_reference(args) -> int:
             routing_backend=(
                 out["attention"]["prefill"]["resolved"] if mla else "xla"),
             served_routing=tap))
+        if family == "falcon_h1":
+            out["pages"] = reference_check.served_pages(
+                reference, ecfg.model, params, first_pages, prompts, served)
         out["reference_s"] = round(time.monotonic() - t0, 1)
         print(f"reference check: compared in {out['reference_s']} s",
               file=sys.stderr, flush=True)
-        if faults:
+        if faults and family == "falcon_h1":
+            # (its faulty references are its own fault arms; the bfloat16
+            # state is the state's limit's to tell, on every row)
+            short = int(np.argmin(lengths))
+            out["faults"] = {}
+            for arms in FALCON_H1_ARMS:
+                got = reference_check.compare(
+                    reference, ecfg.model, params, [prompts[short]],
+                    [served[short]], reference_arms=arms)
+                out["faults"]["-".join(
+                    f"{k}={v}" for k, v in arms.items())] = {
+                    k: got[k] for k in (
+                        "max_abs_dlogprob", "mean_abs_dlogprob")}
+            got = reference_check.compare(
+                reference, ecfg.model, params, [prompts[short]],
+                [served[short]], reference_arms={"state_dtype": "bfloat16"})
+            out["faults"]["state_bfloat16"] = {
+                **{k: got[k] for k in (
+                    "max_abs_dlogprob", "mean_abs_dlogprob")},
+                "state": reference_check.served_state(
+                    reference, ecfg.model, state_tap, state_pool,
+                    params=params, state_dtype="bfloat16")}
+        elif faults:
             # the shortest prompt's served logprobs against references that
             # are each wrong in ONE way: the tolerance has to catch each
             short = int(np.argmin(lengths))
@@ -1091,7 +1198,8 @@ def child_reference(args) -> int:
             <= margin_max))
     def inside(arm: dict) -> bool:
         """Within the limits: the largest |Δlogprob|, where the family has
-        one the mean, and where the model holds state the served state."""
+        one the mean, where the model holds state the served state, and
+        where the arm read them the served pages."""
         return (arm["max_abs_dlogprob"] <= atol
                 and (mean_atol is None
                      or arm["mean_abs_dlogprob"] <= mean_atol)
@@ -1100,24 +1208,29 @@ def child_reference(args) -> int:
                     and arm["routing_margin_max_where_differing"]
                     <= margin_max))
                 and ("state" not in arm
-                     or arm["state"]["state_rel_err"] <= KDA_STATE_RTOL)
+                     or arm["state"]["state_rel_err"] <= state_rtol)
+                and ("pages" not in arm
+                     or arm["pages"]["pages_rel_err"] <= KV_PAGES_RTOL)
                 and ("router" not in arm
                      or arm["router"]["choice_disagreement_share"]
                      <= ROUTER_CHOICE_DISAGREEMENT_MAX_LING))
 
     if holds_state:
-        report["state_rtol"] = KDA_STATE_RTOL
+        report["state_rtol"] = state_rtol
+    if family == "falcon_h1":
+        report["pages_rtol"] = KV_PAGES_RTOL
     if family == "ling":
         report["router_choice_disagreement_max"] = (
             ROUTER_CHOICE_DISAGREEMENT_MAX_LING)
     report["ok"] = bool(
         inside(served) and routing_ok
         and not any(inside(f) for f in served.get("faults", {}).values()))
-    if args.fp8_arm and not holds_state:
+    if args.fp8_arm and pages_in_most_layers:
         # (solar_open2, ling: the precision below the configuration's is a
         # bfloat16 STATE, one of its faulty references: most of its cache
         # is state, and an fp8 pool would touch the 2 GQA layers — ling:
-        # the one latent layer — only)
+        # the one latent layer — only. falcon_h1 has BOTH arms: every layer
+        # holds K/V pages beside its state)
         # (a quantized pool resolves 'auto' to XLA attention, whose
         # gathered history and scores a long-document prompt cannot
         # afford: the latent kernels widen fp8 pages themselves)

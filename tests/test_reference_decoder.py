@@ -103,6 +103,8 @@ def test_reference_refuses_what_it_does_not_compute():
     ("reference_solar_open2.py", "solar_open2.py"),  # KDA state (ISSUE 39)
     ("reference_sdar.py", "sdar.py"),  # diffusion over blocks (ISSUE 41)
     ("reference_ling.py", "ling.py"),  # latents beside state (ISSUE 45)
+    ("reference_falcon_h1.py", "falcon_h1.py"),  # pages AND state in one
+    #                                              layer (ISSUE 50)
 ])
 def test_benchmark_copy_is_byte_identical(package_file, benchmark_file):
     """benchmarks/chip/reference/<file> is the yardstick's own copy: a later

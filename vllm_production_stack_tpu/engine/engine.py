@@ -244,7 +244,7 @@ class LLMEngine:
                     "in a group of their own that holds the window only",
                     config.model.model,
                     "; ".join(window_group_blockers(config)))
-        if config.model.linear_layers and config.cache.enable_prefix_caching:
+        if config.model.state_layers and config.cache.enable_prefix_caching:
             # a hit would need the recurrent state at its boundary, which
             # no slot keeps (state snapshots: ROADMAP R-M5)
             logger.info(
@@ -1456,7 +1456,11 @@ class LLMEngine:
             passes = work.window if kind == "decode" else 1
             for name, layers in (
                     ("mla_layers_n", self.config.model.latent_layers),
-                    ("kda_layers_n", self.config.model.linear_layers)):
+                    ("kda_layers_n", self.config.model.linear_layers),
+                    ("ssd_layers_n", self.config.model.ssm_layers),
+                    ("page_and_state_layers_n", tuple(
+                        i for i in self.config.model.state_layers
+                        if self.config.model.layer_specs[i].holds_pages))):
                 if layers:
                     rec.count(name, passes * len(layers))
             if kind == "prefill" and self.runner.prefill_uses_kernel:
@@ -1503,11 +1507,17 @@ class LLMEngine:
                     rec.count("attn_prefill_window_keys_n", sum(
                         min(int(p[-1]) + 1, len(p) + window - 1)
                         for p in work.positions if len(p)))
-                if self.config.model.linear_layers:
-                    rec.count("kda_prefill_tokens_n",
-                              sum(len(t) for t in work.token_ids))
-                    rec.count("kda_prefill_segments_n",
-                              sum(1 for t in work.token_ids if len(t)))
+                # (a recurrence's counters: what its kernels' work is
+                # computed from, benchmarks/chip/costs_kda.py, costs_ssd.py)
+                for name, layers in (
+                        ("kda", self.config.model.linear_layers),
+                        ("ssd", self.config.model.ssm_layers)):
+                    if layers:
+                        rec.count(name + "_prefill_tokens_n",
+                                  sum(len(t) for t in work.token_ids))
+                        rec.count(name + "_prefill_segments_n",
+                                  sum(1 for t in work.token_ids if len(t)))
+                if self.config.model.state_layers:
                     rec.count("state_slots_zeroed_n", sum(
                         1 for p in work.positions
                         if len(p) and int(p[0]) == 0))
@@ -1526,9 +1536,12 @@ class LLMEngine:
                 # spec-decode verify (1..k+1 accepted per row)
                 accepted = sum(len(t) for _, t in results)
                 rec.count("decode_tokens", accepted)
-                if self.config.model.linear_layers and kind == "decode":
-                    rec.count("kda_decode_rows_n",
-                              len(work.requests) * work.window)
+                for name, layers in (
+                        ("kda", self.config.model.linear_layers),
+                        ("ssd", self.config.model.ssm_layers)):
+                    if layers and kind == "decode":
+                        rec.count(name + "_decode_rows_n",
+                                  len(work.requests) * work.window)
                 self._meter_decode(work, accepted)
             rec.poll()
             self._emit_results(results, handle.logprob_rows, outputs)

@@ -151,10 +151,13 @@ class WindowPageGroup:
 
 
 class StateSlots:
-    """Host-side accounting for the STATE SLOTS of a model whose
-    linear-attention layers hold a recurrent state and no pages
-    (LayerSpec.holds_state; models/llama.py:init_kv_cache gives each such
-    layer {"s", "conv"} pools of two banks x `num_slots` slots). The second
+    """Host-side accounting for the STATE SLOTS of a model with layers that
+    hold a recurrent state (LayerSpec.holds_state) — without pages (a gated
+    delta-rule layer) or BESIDE them (state-space heads next to attention
+    heads); models/llama.py:init_kv_cache gives each such layer {"s",
+    "conv"} pools of two banks x `num_slots` slots, of the geometry of the
+    layer's recurrence (ModelConfig.state_geometry: this class counts
+    slots and knows no shape). The second
     kind of cache beside the pages, in the same manager: a row takes ONE
     slot with its first pages and gives it back with them — when it ends, is
     aborted or is preempted — and holds it whatever its context length.

@@ -251,8 +251,11 @@ def _deserialize_blocks_npz(payload: bytes):
 
 def _page_leaf(runner):
     """The first layer's pool that holds pages (a layer that holds state has
-    a dict of state pools, and every KV tier is refused beside those)."""
-    return next(kv for kv in runner.kv_caches if not isinstance(kv, dict))
+    a dict of state pools — with its page array under "kv" where it holds
+    pages too — and every KV tier is refused beside those)."""
+    return next(kv["kv"] if isinstance(kv, dict) else kv
+                for kv in runner.kv_caches
+                if not isinstance(kv, dict) or "kv" in kv)
 
 
 def engine_block_shape(runner) -> tuple[int, ...]:

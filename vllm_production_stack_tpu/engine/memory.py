@@ -14,7 +14,7 @@ import jax
 
 from ..utils.logging import init_logger
 from .config import (
-    LINEAR_CONV_TAPS, CacheConfig, EngineConfig, ModelConfig, ParallelConfig,
+    STATE_CONV_TAPS, CacheConfig, EngineConfig, ModelConfig, ParallelConfig,
     SchedulerConfig,
 )
 
@@ -130,7 +130,19 @@ def param_bytes(cfg: ModelConfig, tp: int = 1, pp: int = 1,
         norms = (0 if cfg.post_norms_only else 2) + (
             2 if cfg.sandwich_norms or cfg.post_norms_only else 0)
         replicated = norms * h + qk_norm_params(cfg)
-        if spec.holds_state:
+        if spec.recurrence == "ssd":
+            # the state-space branch BESIDE the attention heads
+            # (models/llama.py:attn_ssm): the input and output projections,
+            # the taps and their bias, the gated norm; A_log, D and dt_bias
+            # in float32
+            sharded += (h * (2 * cfg.ssm_inner
+                             + 2 * cfg.ssm_num_groups * cfg.ssm_state_dim
+                             + cfg.ssm_num_heads)
+                        + cfg.ssm_inner * h
+                        + (STATE_CONV_TAPS + 1) * cfg.ssm_conv_dim
+                        + cfg.ssm_inner)
+            f32_extra += 4 * 3 * cfg.ssm_num_heads
+        elif spec.holds_state:
             # the KDA mixer (models/llama.py:attn_linear): q, k, v, o; the
             # two low-rank pairs; the step size's projection; the taps; the
             # output norm; A_log and dt_bias in float32
@@ -140,7 +152,7 @@ def param_bytes(cfg: ModelConfig, tp: int = 1, pp: int = 1,
                        # (rank 0: both gates project at full rank)
                        + 2 * ((h * r + r * lhd) if r else h * lhd)
                        + h * cfg.linear_num_heads
-                       + LINEAR_CONV_TAPS * 3 * lhd
+                       + STATE_CONV_TAPS * 3 * lhd
                        + cfg.linear_head_dim)
             f32_extra += 4 * (cfg.linear_num_heads + lhd)
         elif spec.latent:
@@ -261,22 +273,27 @@ def resolve_window_blocks(config: EngineConfig) -> int | None:
 
 
 def state_slot_bytes(model: ModelConfig) -> int:
-    """Bytes ONE state slot holds across the layers that hold state: a
-    float32 (heads, D, D) state and the convolution's tail — the last
-    taps - 1 rows of the q | k | v projections in the model's dtype — a
-    layer, whatever the context length. 0 for a model without such
-    layers."""
-    lhd = model.linear_num_heads * model.linear_head_dim
-    return len(model.linear_layers) * (
-        lhd * model.linear_head_dim * 4
-        + (LINEAR_CONV_TAPS - 1) * 3 * lhd * dtype_bytes(model.dtype))
+    """Bytes ONE state slot holds across the layers that hold state,
+    whatever the context length: a layer's float32 state and its
+    convolution's tail — the last taps - 1 rows of the convolved channels
+    in the model's dtype —, both of the geometry of the layer's RECURRENCE
+    (ModelConfig.state_geometry: (heads, D, D) and q | k | v for the gated
+    delta rule, (heads, P, N) and x | B | C for the state-space duality).
+    0 for a model without such layers."""
+    total = 0
+    for i in model.state_layers:
+        shape, channels = model.state_geometry(
+            model.layer_specs[i].recurrence)
+        total += (math.prod(shape) * 4 + (STATE_CONV_TAPS - 1) * channels
+                  * dtype_bytes(model.dtype))
+    return total
 
 
 def resolve_state_slots(config: EngineConfig) -> int:
     """State slots a BANK of the state pools holds: one a seat and the null
     slot 0 that padding rows write to; 0 for a model no layer of which
     holds state. The arrays hold two banks (kv_cache.StateSlots)."""
-    if not config.model.linear_layers:
+    if not config.model.state_layers:
         return 0
     return config.scheduler.max_num_seqs + 1
 

@@ -32,7 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import metrics_contract as mc
 from ..models import llama
-from ..ops import kda_attention
+from ..ops import kda_attention, ssd_scan
 from ..ops.attention import gather_pages
 from ..ops.paged_attention_pallas import _pages_fill_tiles
 from ..utils.logging import init_logger
@@ -250,21 +250,22 @@ def _refuse_unbuilt_for_recurrent_state(config: EngineConfig) -> None:
         f"a mesh of {config.parallel.world_size} devices (tp / dp / pp / "
         "sp / ep, context parallelism included)": (
             config.parallel.world_size > 1,
-            "parallel/sharding.py has no spec for the attn_linear leaves "
-            "nor for the state pools, and the ring prefill carries no state"),
+            "parallel/sharding.py has no spec for the attn_linear and "
+            "attn_ssm leaves nor for the state pools, and the ring prefill "
+            "carries no state"),
         "LoRA adapters": (
             config.lora.max_loras > 0,
             "the adapter buffers know the q/k/v/o projections of softmax "
-            "layers only"),
+            "layers in the flat tree only"),
         f"--quantization {cfg.quantization}": (
             bool(cfg.quantization),
             "models/quantization.py names no int8 form for the attn_linear "
-            "leaves"),
+            "and attn_ssm leaves"),
         "a checkpoint": (
             bool(cfg.checkpoint),
-            "the tensor names of the linear-attention leaves were never "
-            "checked against a published checkpoint offline; the presets "
-            "serve random weights"),
+            "the tensor names of the linear-attention and state-space "
+            "leaves were never checked against a published checkpoint "
+            "offline; the presets serve random weights"),
     }
     for what, (asked, reason) in why.items():
         if asked:
@@ -304,7 +305,7 @@ def _refuse_unbuilt_for_block_diffusion(config: EngineConfig) -> None:
         "multi-head latent attention, window layers or layers that hold "
         "state": (
             bool(cfg.latent_layers) or cfg.any_sliding
-            or bool(cfg.linear_layers),
+            or bool(cfg.state_layers),
             "the block mask is built into the GQA kernels only"),
         "softcapped attention": (
             bool(cfg.attn_logit_softcap),
@@ -488,6 +489,21 @@ def linear_backend_report(cfg, attention_backend: str,
     return report
 
 
+def ssd_backend_report(cfg, attention_backend: str,
+                       prefill_backend: str) -> dict:
+    """The same for the state-space heads (ops/ssd_scan.py)."""
+    report = {"decode": ("ssd_decode_step"
+                         if attention_backend != "xla" else "xla"),
+              "prefill": "xla"}
+    if prefill_backend.startswith("pallas"):
+        report.update(
+            prefill="ssd_prefill_scan",
+            heads_per_step=ssd_scan.heads_per_step(
+                cfg.ssm_num_heads, cfg.ssm_num_groups),
+            block_tokens=ssd_scan.BLOCK)
+    return report
+
+
 class ModelRunner:
     def __init__(
         self,
@@ -499,7 +515,7 @@ class ModelRunner:
         cfg = config.model
         if cfg.latent_layers:
             _refuse_unbuilt_for_latent_attention(config)
-        if cfg.linear_layers:
+        if cfg.state_layers:
             _refuse_unbuilt_for_recurrent_state(config)
         if cfg.tree_by_kind:
             _refuse_unbuilt_for_tree_by_kind(config)
@@ -755,7 +771,7 @@ class ModelRunner:
         config, cfg = self.config, self.config.model
         # (a latent pool has no kv-head axis and a state pool none to shard
         # over tp: both run on one device)
-        spec = (P() if cfg.latent_layers or cfg.linear_layers
+        spec = (P() if cfg.latent_layers or cfg.state_layers
                 else kv_cache_spec())
         return jax.jit(
             lambda: llama.init_kv_cache(
@@ -1021,20 +1037,27 @@ class ModelRunner:
             },
         }
         cfg = self.config.model
-        if cfg.linear_layers:
+        if cfg.state_layers:
             # the mixer kinds of every program: layers that hold pages
             # (softmax attention) and layers that hold state (the gated
-            # delta rule, through its kernels wherever attention runs
-            # through its own)
+            # delta rule or state-space heads beside the attention heads,
+            # through their kernels wherever attention runs through its
+            # own)
             report["mixers"] = {
-                "by_layer": [s.mixer for s in cfg.layer_specs],
+                "by_layer": [s.mixer + ("+ssd" if s.recurrence == "ssd"
+                                        else "") for s in cfg.layer_specs],
                 "softmax": len(cfg.page_layers) - len(cfg.latent_layers),
                 "latent": len(cfg.latent_layers),
                 "linear": len(cfg.linear_layers),
+                "ssd": len(cfg.ssm_layers),
                 "state_slots": self.state_slots,
-                "linear_backend": linear_backend_report(
-                    cfg, self._attention_backend, self._prefill_backend),
             }
+            if cfg.linear_layers:
+                report["mixers"]["linear_backend"] = linear_backend_report(
+                    cfg, self._attention_backend, self._prefill_backend)
+            if cfg.ssm_layers:
+                report["mixers"]["ssd_backend"] = ssd_backend_report(
+                    cfg, self._attention_backend, self._prefill_backend)
         if self.config.model.num_experts:
             # the expert computation a program holds follows from its row
             # (decode) or token (prefill) bucket; `resolved`: the widest,
@@ -1370,7 +1393,8 @@ class ModelRunner:
                 with jax.named_scope("gather_pages"):
                     hists = tuple(
                         gather_pages(
-                            kv_caches[i], llama._group(block_tables, w))
+                            llama.page_pool(kv_caches[i]),
+                            llama._group(block_tables, w))
                         if cfg.layer_specs[i].holds_pages else None
                         for i, w in enumerate(windows)
                     )

@@ -192,7 +192,7 @@ class Scheduler:
             self.pool.window = WindowPageGroup(
                 window_blocks, cache_config.block_size,
                 model_config.max_window)
-        if model_config.linear_layers:
+        if model_config.state_layers:
             # layers that hold a recurrent state: a slot a seat beside the
             # pages (kv_cache.StateSlots)
             if cache_config.enable_prefix_caching:

@@ -756,6 +756,19 @@ DEBUG_TIMING_STATE_COUNTS = (
     # window's steps a decode dispatch
     "kda_layers_n",
 )
+# ... what a model with state-space heads beside its attention heads counts
+# in the KDA counts' place (LayerSpec.recurrence "ssd"; absent otherwise;
+# `state_slots_zeroed_n` and `state_recomputed_tokens_n` above are any
+# recurrence's): the tokens and row segments of its prefill chunks through
+# `ssd_prefill_scan`, the live rows x steps through `ssd_decode_step`, the
+# layers with such heads run, and of those the ones that hold pages too
+DEBUG_TIMING_SSD_COUNTS = (
+    "ssd_prefill_tokens_n",
+    "ssd_prefill_segments_n",
+    "ssd_decode_rows_n",
+    "ssd_layers_n",
+    "page_and_state_layers_n",
+)
 # ... the same count of LATENT layers run, from a model that has any
 DEBUG_TIMING_LATENT_COUNTS = ("mla_layers_n",)
 # ... and what group-limited routing counts (ModelConfig.n_group > 1;

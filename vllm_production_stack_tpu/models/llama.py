@@ -19,7 +19,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..engine.config import LINEAR_CONV_TAPS, LayerSpec, ModelConfig
+from ..engine.config import (
+    STATE_CONV_TAPS, LayerSpec, ModelConfig, MupSpec)
 from ..ops.attention import (
     apply_rope,
     attention_with_hist,
@@ -48,6 +49,12 @@ from ..ops.mla_attention_pallas import (
     mla_prefill_attention,
 )
 from ..ops.moe_experts_pallas import moe_decode_experts, moe_prefill_experts
+from ..ops.ssd_scan import (
+    ssd_decode_step,
+    ssd_decode_xla,
+    ssd_prefill_scan,
+    ssd_prefill_xla,
+)
 from ..ops.paged_attention_pallas import (
     paged_decode_attention,
     paged_decode_attention_sharded,
@@ -284,7 +291,10 @@ def _init_params_by_kind(cfg: ModelConfig, rng: jax.Array) -> dict:
     "attn_linear" (`attn_linear` below), and layers whose pages hold one
     latent a token (LayerSpec.latent) a fourth, "attn_latent"
     (`attn_latent` below: `_init_params_mla`'s attention leaves, and the
-    per-head output gate where the model has one)."""
+    per-head output gate where the model has one). A layer with
+    state-space heads BESIDE its attention heads (LayerSpec.recurrence
+    "ssd") owns leaves in its attention stack AND in a fifth, "attn_ssm"
+    (`attn_ssm` below)."""
     unsaid = [name for name in (
         "attention_bias", "qk_norm", "qk_norm_flat", "sandwich_norms",
         "post_norms_only") if getattr(cfg, name)]
@@ -299,14 +309,25 @@ def _init_params_by_kind(cfg: ModelConfig, rng: jax.Array) -> dict:
     dt = _dtype(cfg)
     keys = iter(jax.random.split(rng, 32))
 
-    def w(*shape, scale=None):
-        scale = scale if scale is not None else shape[-2] ** -0.5
+    mup = cfg.mup or MupSpec()
+
+    def w(*shape, scale=None, over=1.0):
+        # `over`: the multiplier the forward pass scales this matrix's
+        # output by (MupSpec) — the draw is that much wider, so that the
+        # SCALED output is what the standard init's would be (a trained
+        # maximal-update checkpoint's weights have grown likewise; with the
+        # plain init every branch would vanish beside the residual and no
+        # check could tell a branch left out)
+        scale = (scale if scale is not None else shape[-2] ** -0.5) / over
         return (jax.random.normal(next(keys), shape, jnp.float32)
                 * scale).astype(dt)
 
     def attn(n, nh):
-        leaves = {"wq": w(n, h, nh * hd), "wk": w(n, h, nkv * hd),
-                  "wv": w(n, h, nkv * hd), "wo": w(n, nh * hd, h)}
+        leaves = {"wq": w(n, h, nh * hd, over=mup.attention_in),
+                  "wk": w(n, h, nkv * hd,
+                          over=mup.attention_in * mup.key),
+                  "wv": w(n, h, nkv * hd, over=mup.attention_in),
+                  "wo": w(n, nh * hd, h, over=mup.attention_out)}
         if cfg.attn_output_gate:
             # one gate a head, or one a head and channel
             leaves["wg"] = w(
@@ -327,7 +348,7 @@ def _init_params_by_kind(cfg: ModelConfig, rng: jax.Array) -> dict:
         norm's weight, shared by the heads."""
         lh, ld, r = (cfg.linear_num_heads, cfg.linear_head_dim,
                      cfg.linear_gate_rank)
-        taps = LINEAR_CONV_TAPS
+        taps = STATE_CONV_TAPS
         leaves = {
             "wq": w(n, h, lh * ld), "wk": w(n, h, lh * ld),
             "wv": w(n, h, lh * ld), "wo": w(n, lh * ld, h),
@@ -356,6 +377,37 @@ def _init_params_by_kind(cfg: ModelConfig, rng: jax.Array) -> dict:
             share = rate * step / abs(cfg.linear_gate_lower_bound)
             leaves["dt_bias"] = (jnp.log(share) - jnp.log1p(-share)) / rate
         return leaves
+
+    def attn_ssm(n):
+        """The state-space branches' stack (`_ssm_mixer`): the input
+        projection over z | x | B | C | dt (each span drawn wider by its
+        multiplier, see `w`); the depthwise convolution's taps over x | B |
+        C (tap 3 multiplies the current token) and its bias (uniform in
+        +-taps^-0.5, a convolution's usual init); per head the log of the
+        rate (log U(1, 16)), the skip weight D (ones) and the bias of the
+        step's softplus (the inverse softplus of a step drawn log-uniform
+        in [0.001, 0.1]) as Mamba-2's public implementation initialises
+        them, in float32; the gated norm's weight; the output projection."""
+        hs, gn = cfg.ssm_num_heads, cfg.ssm_num_groups * cfg.ssm_state_dim
+        inner, taps = cfg.ssm_inner, STATE_CONV_TAPS
+        spans = (inner, inner, gn, gn, hs)
+        step = jnp.exp(jax.random.uniform(
+            next(keys), (n, hs), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+        return {
+            "w_in": jnp.concatenate(
+                [w(n, h, width, over=mup.ssm_in * m)
+                 for width, m in zip(spans, mup.ssm)], axis=-1),
+            "conv": w(n, taps, cfg.ssm_conv_dim, scale=taps ** -0.5),
+            "conv_bias": jax.random.uniform(
+                next(keys), (n, cfg.ssm_conv_dim), jnp.float32,
+                -taps ** -0.5, taps ** -0.5).astype(dt),
+            "A_log": jnp.log(jax.random.uniform(
+                next(keys), (n, hs), jnp.float32, 1.0, 16.0)),
+            "D": jnp.ones((n, hs), jnp.float32),
+            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "norm": jnp.ones((n, inner), dt),
+            "w_out": w(n, inner, h, over=mup.ssm_out),
+        }
 
     def attn_latent(n, nh):
         """The latent layers' stack (`_mla_attention`; the leaves of
@@ -395,6 +447,9 @@ def _init_params_by_kind(cfg: ModelConfig, rng: jax.Array) -> dict:
                 specs[cfg.latent_layers[0]].num_heads)
     else:
         layers["attn"] = attn(L, specs[0].num_heads)
+    if cfg.ssm_layers:
+        layers["attn_ssm"] = attn_ssm(len(cfg.ssm_layers))
+
     def w_by_layer(n, *shape):
         """A stacked leaf drawn one layer at a time into its buffer: the
         float32 draw that is live is one layer's (0.8 GB at 64 experts of
@@ -412,8 +467,9 @@ def _init_params_by_kind(cfg: ModelConfig, rng: jax.Array) -> dict:
 
     lm = cfg.num_moe_layers
     if L - lm:
-        layers["mlp"] = {"gate": w(L - lm, h, it), "up": w(L - lm, h, it),
-                         "down": w(L - lm, it, h)}
+        layers["mlp"] = {"gate": w(L - lm, h, it, over=mup.mlp_gate),
+                         "up": w(L - lm, h, it),
+                         "down": w(L - lm, it, h, over=mup.mlp_down)}
     if lm:
         layers["moe"] = {
             "router": w(lm, h, e),
@@ -429,7 +485,8 @@ def _init_params_by_kind(cfg: ModelConfig, rng: jax.Array) -> dict:
     params = {"embed": w(cfg.vocab_size, h, scale=0.02), "layers": layers,
               "final_norm": jnp.ones((h,), dt)}
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = w(h, cfg.vocab_size, scale=0.02)
+        params["lm_head"] = w(h, cfg.vocab_size, scale=0.02,
+                              over=mup.lm_head)
     if lm and cfg.moe_selection_bias:
         # the selection bias, non-zero and small (see _init_params_mla;
         # drawn last: the older trees' draws stay what they were)
@@ -481,6 +538,8 @@ def _embed(cfg: ModelConfig, params: dict, token_ids: jax.Array) -> jax.Array:
     x = params["embed"][token_ids].astype(_dtype(cfg))
     if cfg.scale_embeddings:  # Gemma: sqrt(h) in the embedding dtype
         x = x * jnp.asarray(cfg.hidden_size**0.5, _dtype(cfg))
+    if cfg.mup is not None:
+        x = x * jnp.asarray(cfg.mup.embedding, _dtype(cfg))
     return x
 
 
@@ -507,31 +566,55 @@ def init_kv_cache(
     taps - 1, 3 * H * D)} — the recurrent state and the convolution's tail
     of every seat (slot 0: the null slot padding rows use), in two BANKS:
     a dispatch reads a row's slot in one bank and writes it in the other
-    (engine/kv_cache.py:StateSlots)."""
+    (engine/kv_cache.py:StateSlots). The geometry is the RECURRENCE's
+    (`ModelConfig.state_geometry`): a state-space branch's is (H, P, N) and x | B | C
+    channels. A layer that holds pages AND state (LayerSpec.recurrence
+    "ssd") has both: its leaf is {"kv": the page array, "s": ..., "conv":
+    ...}."""
     dt = jnp.dtype(dtype) if dtype is not None else _dtype(cfg)
-    lh, ld = cfg.linear_num_heads, cfg.linear_head_dim
-    return tuple(
-        {"s": jnp.zeros((2 * state_slots, lh, ld, ld), jnp.float32),
-         "conv": jnp.zeros(
-             (2 * state_slots, LINEAR_CONV_TAPS - 1, 3 * lh * ld),
-             _dtype(cfg))}
-        if spec.holds_state else
-        # a latent layer: ONE plane, a token's latent
-        # (ops/mla_attention.py), no second copy of it as "V"
-        jnp.zeros((1, num_blocks, block_size, cfg.latent_width), dt)
-        if spec.latent else
-        jnp.zeros((
+
+    def pages(spec):
+        if spec.latent:
+            # a latent layer: ONE plane, a token's latent
+            # (ops/mla_attention.py), no second copy of it as "V"
+            return jnp.zeros(
+                (1, num_blocks, block_size, cfg.latent_width), dt)
+        return jnp.zeros((
             2,
             num_window_blocks if spec.window and num_window_blocks
             else num_blocks,
             block_size, cfg.num_kv_heads, cfg.head_dim), dt)
+
+    def state(spec):
+        shape, channels = cfg.state_geometry(spec.recurrence)
+        return {"s": jnp.zeros((2 * state_slots, *shape), jnp.float32),
+                "conv": jnp.zeros(
+                    (2 * state_slots, STATE_CONV_TAPS - 1, channels),
+                    _dtype(cfg))}
+
+    return tuple(
+        pages(spec) if not spec.holds_state else
+        state(spec) if not spec.holds_pages else
+        {"kv": pages(spec), **state(spec)}
         for spec in cfg.layer_specs)
+
+
+def page_pool(kv_layer):
+    """The page array of a layer's cache leaf: the leaf itself, or the
+    "kv" of a layer that holds state beside its pages (None: the layer
+    holds state alone)."""
+    return kv_layer.get("kv") if isinstance(kv_layer, dict) else kv_layer
+
+
+def _state_pools(kv_layer: dict) -> dict:
+    """The state pools of a layer's cache leaf."""
+    return {"s": kv_layer["s"], "conv": kv_layer["conv"]}
 
 
 def _page_tokens(cfg: ModelConfig, kv_caches) -> int:
     """Tokens a page holds, read off the first layer that holds pages
     (axis 2 of a K/V pool and of a latent pool alike)."""
-    return kv_caches[cfg.page_layers[0]].shape[2]
+    return page_pool(kv_caches[cfg.page_layers[0]]).shape[2]
 
 
 def _group(x, window: int):
@@ -621,11 +704,16 @@ def _layer_body(
     spec: LayerSpec | None = None,  # this layer's entry of the per-layer
     #   list (its head count and rope); None: layer 0's
     moe_backend: str = "xla",  # see _moe_mlp
+    recur=None,  # a layer that holds state: its _Recurrence (`attend` is
+    #   None where the layer holds no pages)
 ) -> jax.Array:
     """The Llama layer math shared by every execution mode — prefill and the
-    fused decode window differ ONLY in how attention consumes/stores KV, so
-    that strategy is injected as `attend` and everything else (projections,
-    bias, RoPE, residuals, MLP, LoRA deltas) exists exactly once."""
+    fused decode window differ ONLY in how attention consumes/stores KV (and
+    a recurrence its state), so those strategies are injected as `attend`
+    and `recur` and everything else (projections, bias, RoPE, residuals,
+    MLP, LoRA deltas) exists exactly once. The multipliers are MupSpec's,
+    applied here and never folded into a weight; each is 1 but for
+    Falcon-H1."""
     b, t, h = x.shape
     spec = spec or cfg.layer_specs[0]
     hd, nh, nkv = cfg.head_dim, spec.num_heads, cfg.num_kv_heads
@@ -646,21 +734,24 @@ def _layer_body(
 
     res = x
     ap = lp["attn"]
+    mup = cfg.mup or MupSpec()
     if spec.latent:
         x = _mla_attention(cfg, ap, lp["input_norm"], x, positions, attend)
         return _mlp_block(cfg, lp, res + x, proj, moe_stats, moe_backend)
-    if spec.holds_state:
-        x = _kda_attention(cfg, ap, lp["input_norm"], x, attend)
+    if spec.holds_state and not spec.holds_pages:
+        x = _kda_attention(cfg, ap, lp["input_norm"], x, recur)
         return _mlp_block(cfg, lp, res + x, proj, moe_stats, moe_backend)
     with jax.named_scope("attn_qkv"):
         if not cfg.post_norms_only:
             x = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps,
                          cfg.rms_norm_add_one)
-        q = proj(x, ap["wq"], "q_proj")
-        k = proj(x, ap["wk"], "k_proj")
-        v = proj(x, ap["wv"], "v_proj")
+        xa = _scaled(x, mup.attention_in)
+        q = proj(xa, ap["wq"], "q_proj")
+        k = proj(xa, ap["wk"], "k_proj")
+        v = proj(xa, ap["wv"], "v_proj")
         if cfg.attention_bias:
             q, k, v = q + ap["bq"], k + ap["bk"], v + ap["bv"]
+        k = _scaled(k, mup.key)
         if cfg.qk_norm_flat:
             # olmo2: RMSNorm over the whole flat projection, pre-reshape
             q = rms_norm(q, ap["q_norm"], cfg.rms_norm_eps,
@@ -705,6 +796,16 @@ def _layer_body(
             attn_out = rms_norm(attn_out, lp["attn_out_norm"],
                                 cfg.rms_norm_eps, cfg.rms_norm_add_one,
                      scale_f32=cfg.norm_scale_f32)
+    if spec.recurrence == "ssd":
+        # Falcon-H1: state-space heads read the SAME normed input, and the
+        # two scaled branches add to ONE residual
+        #   a = Attn(u * attention_in) * attention_out
+        #   m = SSM (u * ssm_in)       * ssm_out
+        m = _ssm_mixer(cfg, lp["attn_ssm"], _scaled(x, mup.ssm_in), recur)
+        with jax.named_scope("h1_branches"):
+            attn_out = (_scaled(attn_out, mup.attention_out)
+                        + _scaled(m.reshape(attn_out.shape), mup.ssm_out))
+    with jax.named_scope("attn_out"):
         x = res + attn_out
     return _mlp_block(cfg, lp, x, proj, moe_stats, moe_backend)
 
@@ -768,9 +869,61 @@ def _kda_attention(cfg: ModelConfig, ap: dict, norm: jax.Array,
         return _mm(o, ap["wo"])[:, None]
 
 
+def _scaled(y: jax.Array, m: float) -> jax.Array:
+    """y times a MupSpec multiplier, in y's dtype; y itself at 1."""
+    return y if m == 1.0 else y * jnp.asarray(m, y.dtype)
+
+
+def _ssm_mixer(cfg: ModelConfig, sp: dict, u: jax.Array, recur) -> jax.Array:
+    """A state-space branch (Mamba-2; ops/ssd_scan.py has the recurrence),
+    its scaled normed input (N, 1, h) -> (N, 1, h). The tokens lie on the
+    first axis (a packed chunk, or a decode step's rows); what a token
+    needs of the tokens before it `recur` brings, as `_kda_attention`'s:
+    `recur.conv(x | B | C, taps)` and `recur.scan_ssd(x, B, C, dt, A, D)`
+    -> y (N, H, P) float32."""
+    n, dt_model = u.shape[0], u.dtype
+    hs, hp = cfg.ssm_num_heads, cfg.ssm_head_dim
+    g, ns, inner = cfg.ssm_num_groups, cfg.ssm_state_dim, cfg.ssm_inner
+    f32 = jnp.float32
+    mup = cfg.mup or MupSpec()
+    with jax.named_scope("ssm_in_proj"):
+        p = _mm(u[:, 0], sp["w_in"])  # z | x | B | C | dt
+        if any(m != 1.0 for m in mup.ssm):
+            # `mup_vector`: one multiplier a span
+            p = p * jnp.concatenate([
+                jnp.full((width,), m, p.dtype) for width, m in zip(
+                    (inner, inner, g * ns, g * ns, hs), mup.ssm)])
+        z, xbc, dt = jnp.split(p, [inner, inner + cfg.ssm_conv_dim], axis=-1)
+    with jax.named_scope("ssm_conv"):
+        xbc = jax.nn.silu(
+            recur.conv(xbc, sp["conv"]) + sp["conv_bias"].astype(f32)
+        ).astype(dt_model)
+        x, bm, cm = jnp.split(xbc, [inner, inner + g * ns], axis=-1)
+        # the step, float32, no clamp (the limits are 0 and infinity)
+        dt = jax.nn.softplus(dt.astype(f32) + sp["dt_bias"].astype(f32))
+        rate = -jnp.exp(sp["A_log"].astype(f32))
+    x, bm, cm = (x.reshape(n, hs, hp), bm.reshape(n, g, ns),
+                 cm.reshape(n, g, ns))
+    if recur.tap is not None:
+        recur.tap(x, bm, cm, dt)
+    with jax.named_scope("ssm_scan"):
+        y = recur.scan_ssd(x, bm, cm, dt, rate, sp["D"])
+    with jax.named_scope("ssm_gate_norm"):
+        # the gate FIRST (`mamba_norm_before_gate` false), then RMSNorm over
+        # each group's channels
+        y = y.reshape(n, inner) * jax.nn.silu(z.astype(f32))
+        y = rms_norm(y.reshape(n, g, inner // g),
+                     sp["norm"].astype(f32).reshape(g, inner // g),
+                     cfg.rms_norm_eps)
+        y = y.reshape(n, inner).astype(dt_model)
+    with jax.named_scope("ssm_out"):
+        return _mm(y, sp["w_out"])[:, None]
+
+
 class _Recurrence:
-    """What `_kda_attention` asks of the tokens before its own: the
-    convolution and the scan of ONE linear layer, reading a row's state
+    """What `_kda_attention` and `_ssm_mixer` ask of the tokens before
+    their own: the convolution and the scan of ONE layer, reading a row's
+    state
     slot at `read[row]` and writing it at `write[row]` of the layer's pools
     (`self.pools`, replaced by what the ops return). `tap`: see STATE_TAP."""
 
@@ -831,16 +984,35 @@ class _PackedRecurrence(_Recurrence):
                 q, k, v, g, beta, st["row_off"], st["chunk_lens"],
                 interpret=st["interpret"])
         else:
-            # the XLA form takes rows first, as attention does
-            view = ((lambda y: y[None]) if rows["to_rows"] is None
-                    else (lambda y: y[rows["to_rows"]]))
-            o, s = kda_prefill_xla(
-                self.pools["s"], self.read, self.write, st["fresh"],
-                *(view(y) for y in (q, k, v, g, beta)), st["chunk_lens"])
-            o = (o[0] if rows["to_rows"] is None
-                 else o.reshape(-1, *o.shape[2:])[rows["to_packed"]])
+            o, s = self._by_rows(kda_prefill_xla, (q, k, v, g, beta))
         self.pools = {**self.pools, "s": s}
         return o
+
+    def _by_rows(self, scan_xla, per_token: tuple, *per_head):
+        """A recurrence's XLA form, which takes rows first as attention
+        does: the packed per-token arrays through the row view, the result
+        gathered back."""
+        st, rows = self.st, self.rows
+        view = ((lambda y: y[None]) if rows["to_rows"] is None
+                else (lambda y: y[rows["to_rows"]]))
+        o, s = scan_xla(
+            self.pools["s"], self.read, self.write, st["fresh"],
+            *(view(y) for y in per_token), *per_head, st["chunk_lens"])
+        o = (o[0] if rows["to_rows"] is None
+             else o.reshape(-1, *o.shape[2:])[rows["to_packed"]])
+        return o, s
+
+    def scan_ssd(self, x, b, c, dt, a, d):
+        st = self.st
+        if st["interpret"] is not None:
+            y, s = ssd_prefill_scan(
+                self.pools["s"], self.read, self.write, st["fresh"],
+                x, b, c, dt, a, d, st["row_off"], st["chunk_lens"],
+                interpret=st["interpret"])
+        else:
+            y, s = self._by_rows(ssd_prefill_xla, (x, b, c, dt), a, d)
+        self.pools = {**self.pools, "s": s}
+        return y
 
 
 class _StepRecurrence(_Recurrence):
@@ -871,6 +1043,17 @@ class _StepRecurrence(_Recurrence):
                 interpret=self.interpret)
         self.pools = {**self.pools, "s": s}
         return o
+
+    def scan_ssd(self, x, b, c, dt, a, d):
+        if self.interpret is None:
+            y, s = ssd_decode_xla(
+                self.pools["s"], self.read, self.write, x, b, c, dt, a, d)
+        else:
+            y, s = ssd_decode_step(
+                self.pools["s"], self.read, self.write, x, b, c, dt, a, d,
+                interpret=self.interpret)
+        self.pools = {**self.pools, "s": s}
+        return y
 
 
 def _mla_attention(cfg: ModelConfig, ap: dict, norm: jax.Array,
@@ -945,11 +1128,12 @@ def _mlp_block(cfg: ModelConfig, lp: dict, x: jax.Array, proj,
             if moe_stats is not None:
                 moe_stats.append(routing)
             return res + mlp_out
-        mp = lp["mlp"]
-        inner = _activation(cfg)(proj(x, mp["gate"], "gate_proj")) * proj(
-            x, mp["up"], "up_proj"
-        )
-        mlp_out = proj(inner, mp["down"], "down_proj")
+        mp, mup = lp["mlp"], cfg.mup or MupSpec()
+        # W_down(W_up f * act(W_gate f * mlp_gate)) * mlp_down
+        inner = _activation(cfg)(_scaled(
+            proj(x, mp["gate"], "gate_proj"), mup.mlp_gate)) * proj(
+                x, mp["up"], "up_proj")
+        mlp_out = _scaled(proj(inner, mp["down"], "down_proj"), mup.mlp_down)
         if cfg.sandwich_norms or cfg.post_norms_only:
             mlp_out = rms_norm(mlp_out, lp["ffw_out_norm"],
                                cfg.rms_norm_eps, cfg.rms_norm_add_one,
@@ -1451,11 +1635,13 @@ def _layer_params(cfg: ModelConfig, params: dict, i: int) -> dict:
     # name in the layer's dict -> (stack in the tree, the layer's index there)
     picks = {name: (name, i) for name in layers
              if name not in ("attn", "attn_window", "attn_linear",
-                             "attn_latent", "mlp", "moe")}
+                             "attn_latent", "attn_ssm", "mlp", "moe")}
     picks["attn"] = (
         ({"full": "attn", "window": "attn_window", "linear": "attn_linear",
           "latent": "attn_latent"}[spec.attn_kind], ai)
         if cfg.attn_stacks_by_kind else ("attn", i))
+    if spec.recurrence == "ssd":
+        picks["attn_ssm"] = ("attn_ssm", cfg.ssm_layers.index(i))
     picks[mlp_kind] = (mlp_kind, j := mi if two_mlps else i)
     # (slices are traced in the tree's own sorted order, a kind-stacked MLP
     # last: the order the programs of every older model were traced in, so
@@ -1565,11 +1751,14 @@ def _layer(
     b, t = x.shape[0], x.shape[1]
     hd, nkv = cfg.head_dim, cfg.num_kv_heads
     spec = spec or cfg.layer_specs[0]
+    recur = None
     if spec.holds_state:
-        recur = _PackedRecurrence(kv_layer, state, rows)
-        x = _layer_body(cfg, lp, x, positions, recur, lora, lora_idx,
-                        moe_stats, spec, moe_backend)
-        return x, recur.pools
+        recur = _PackedRecurrence(_state_pools(kv_layer), state, rows)
+        if not spec.holds_pages:
+            x = _layer_body(cfg, lp, x, positions, None, lora, lora_idx,
+                            moe_stats, spec, moe_backend, recur)
+            return x, recur.pools
+        kv_layer = kv_layer["kv"]  # pages AND state: both strategies
     # the layer kind's page group: its block tables, written span, slots
     block_tables = _group(block_tables, spec.window)
     slot_mapping = _group(slot_mapping, spec.window)
@@ -1673,8 +1862,8 @@ def _layer(
             return out.reshape(-1, *out.shape[2:])[rows["to_packed"]][:, None]
 
     x = _layer_body(cfg, lp, x, positions, attend, lora, lora_idx,
-                    moe_stats, spec, moe_backend)
-    return x, kv_layer
+                    moe_stats, spec, moe_backend, recur)
+    return x, kv_layer if recur is None else {"kv": kv_layer, **recur.pools}
 
 
 def forward(
@@ -1765,7 +1954,7 @@ def forward(
         }
         pallas_prefill = None
     state = None
-    if cfg.linear_layers:
+    if cfg.state_layers:
         if pack is None or state_slots is None:
             raise NotImplementedError(
                 f"{cfg.model}: layers that hold a recurrent state prefill "
@@ -1843,11 +2032,12 @@ def init_staged_kv(
     else:
         buf = jnp.zeros((cfg.num_layers, 2, window, batch,
                          cfg.num_kv_heads, cfg.head_dim), dt)
-    if cfg.linear_layers:
+    if cfg.state_layers:
         # (the buffer keeps a plane for every layer, so a layer's index is
-        # its plane; the state layers' planes, 0.5 MiB each, stay unused)
-        return {"kv": buf,
-                "state": {i: kv_caches[i] for i in cfg.linear_layers}}
+        # its plane; the planes of layers without pages, 0.5 MiB each, stay
+        # unused)
+        return {"kv": buf, "state": {
+            i: _state_pools(kv_caches[i]) for i in cfg.state_layers}}
     return buf
 
 
@@ -1881,7 +2071,7 @@ def decode_window_step(
     contiguous per-layer (hist_k, hist_v) and the pool is not touched here
     (ops/attention.py:attention_with_hist). Returns (hidden (B, h), staged')."""
     states = None
-    if cfg.linear_layers:  # see init_staged_kv
+    if cfg.state_layers:  # see init_staged_kv
         staged, states = staged["kv"], dict(staged["state"])
         state_read = jnp.where(step_k == 0, state_slots[0], state_slots[1])
     window = staged.shape[2]  # (latents: (L, B, W, width), the same axis)
@@ -1932,7 +2122,8 @@ def decode_window_step(
                     interpret=backend == "pallas_interpret",
                 )[:, None]
 
-        def attend(q, k, v, i=i, spec=spec, tables=tables):
+        def attend(q, k, v, i=i, spec=spec, tables=tables,
+                   pool=page_pool(kv_caches[i])):
             nonlocal staged
             with jax.named_scope("kv_stage"):
                 staged = staged.at[i, 0, step_k].set(
@@ -1950,7 +2141,7 @@ def decode_window_step(
                             softcap=cfg.attn_logit_softcap,
                         )
                     return paged_attention_with_staged(
-                        q, kv_caches[i], tables, h_mask,
+                        q, pool, tables, h_mask,
                         staged[i, 0], staged[i, 1], staged_mask,
                         scale=cfg.attn_scale,
                         softcap=cfg.attn_logit_softcap,
@@ -1966,20 +2157,20 @@ def decode_window_step(
                     # pallas_call has no GSPMD partition rule — shard_map
                     # over (dp, tp) places one kernel instance per device
                     return paged_decode_attention_sharded(
-                        mesh, q[:, 0], kv_caches[i], tables, hist_len,
+                        mesh, q[:, 0], pool, tables, hist_len,
                         staged[i, 0], staged[i, 1], step_k,
                         scale=cfg.attn_scale,
                         interpret=backend == "pallas_interpret",
                     )[:, None]
                 if spec.window:
                     return paged_window_decode_attention(
-                        q[:, 0], kv_caches[i], tables, hist_len,
+                        q[:, 0], pool, tables, hist_len,
                         staged[i, 0], staged[i, 1], step_k,
                         scale=cfg.attn_scale, window=spec.window,
                         interpret=backend == "pallas_interpret",
                     )[:, None]
                 return paged_decode_attention(
-                    q[:, 0], kv_caches[i], tables, hist_len,
+                    q[:, 0], pool, tables, hist_len,
                     staged[i, 0], staged[i, 1], step_k, scale=cfg.attn_scale,
                     interpret=backend == "pallas_interpret",
                 )[:, None]
@@ -1991,10 +2182,9 @@ def decode_window_step(
                 _state_tap(i, state_slots[1], positions))
         x = _layer_body(
             cfg, lp, x, positions[:, None],
-            recur if spec.holds_state
-            else attend_latent if spec.latent else attend,
+            attend_latent if spec.latent else attend,
             _lora_layer_slice(lora, i), lora_idx, moe_stats, spec,
-            moe_backend,
+            moe_backend, recur if spec.holds_state else None,
         )
         if spec.holds_state:
             states[i] = recur.pools
@@ -2166,14 +2356,17 @@ def commit_staged_kv(
     new_kv: list[jax.Array] = []
     with jax.named_scope("kv_commit"):
         for i in range(L):
-            if i in states:  # advanced in place through the window
+            # (a state is advanced in place through the window)
+            both = i in states and "kv" in kv_caches[i]
+            if i in states and not both:
                 new_kv.append(states[i])
                 continue
             k_rows = jnp.moveaxis(staged[i, 0], 0, 1).reshape(b * w, kvh, d)
             v_rows = jnp.moveaxis(staged[i, 1], 0, 1).reshape(b * w, kvh, d)
-            new_kv.append(write_kv_pages(
-                kv_caches[i], k_rows, v_rows,
-                _group(slot_mapping, i in window_layers)))
+            pages = write_kv_pages(
+                page_pool(kv_caches[i]), k_rows, v_rows,
+                _group(slot_mapping, i in window_layers))
+            new_kv.append({"kv": pages, **states[i]} if both else pages)
     return tuple(new_kv)
 
 
@@ -2187,7 +2380,7 @@ def embed_encode(
     state at each row's LAST real token, L2-normalized — the /v1/embeddings
     path (vLLM serves decoder embeddings the same way: last-token pooling).
     Returns (B, h) float32."""
-    if cfg.linear_layers:
+    if cfg.state_layers:
         raise NotImplementedError(
             f"{cfg.model}: /v1/embeddings is not implemented for layers that "
             "hold a recurrent state (the encode path has no state slots)")
@@ -2342,4 +2535,6 @@ def compute_logits(cfg: ModelConfig, params: dict, hidden: jax.Array) -> jax.Arr
             logits = (hidden @ params["embed"].T).astype(jnp.float32)
         else:
             logits = _mm(hidden, params["lm_head"]).astype(jnp.float32)
+        if cfg.mup is not None:
+            logits = logits * cfg.mup.lm_head
         return _softcap(logits, cfg.final_logit_softcap)

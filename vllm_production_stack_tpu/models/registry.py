@@ -16,7 +16,9 @@ import json
 import math
 import os
 
-from ..engine.config import BlockDiffusion, LayerSpec, ModelConfig, RopeSpec
+from ..engine.config import (
+    STATE_CONV_TAPS, BlockDiffusion, LayerSpec, ModelConfig, MupSpec,
+    RopeSpec)
 
 _KANANA = dict(
     vocab_size=128256, hidden_size=2048, intermediate_size=6144,
@@ -215,6 +217,106 @@ def _ling_flash_preset(n_layers: int, layer_group_size: int = 6,
 # generation script's choice for them, 151669 the `<|MASK|>` token its
 # tokenizer adds to Qwen3's. Every one of them is `assumed` in the
 # benchmark's configuration file.
+def _falcon_h1_keys(hf: dict, path: str) -> dict:
+    """The `model_type: falcon_h1` keys — the per-layer list (every layer
+    holds pages AND a state-space state), the Mamba-2 sizes and the
+    maximal-update multipliers — and by name what of the family is not
+    built: a config that sets one is refused, not guessed at."""
+    n_heads, d_head = hf["mamba_n_heads"], hf["mamba_d_head"]
+    refused = {
+        "attn_layer_indices": hf.get("attn_layer_indices") is not None,
+        "mamba_use_mlp false": not hf.get("mamba_use_mlp", True),
+        "rope_scaling": bool(hf.get("rope_scaling")),
+        **{f"{k} true": bool(hf.get(k)) for k in (
+            "attention_bias", "mlp_bias", "projectors_bias",
+            "mamba_proj_bias")},
+        "mamba_conv_bias false": not hf.get("mamba_conv_bias", True),
+        "mamba_norm_before_gate true": bool(
+            hf.get("mamba_norm_before_gate")),
+        "mamba_rms_norm false": not hf.get("mamba_rms_norm", True),
+        f"mamba_d_conv != {STATE_CONV_TAPS}": hf.get(
+            "mamba_d_conv", STATE_CONV_TAPS) != STATE_CONV_TAPS,
+        "mamba_n_heads x mamba_d_head != mamba_d_ssm":
+        n_heads * d_head != hf["mamba_d_ssm"],
+        "mamba_n_heads not a multiple of mamba_n_groups":
+        n_heads % hf["mamba_n_groups"] != 0,
+        "hidden_act != silu": hf.get("hidden_act", "silu") != "silu",
+    }
+    hit = [k for k, v in refused.items() if v]
+    if hit:
+        raise ValueError(
+            f"falcon_h1 config {path} sets {', '.join(hit)}: "
+            "not implemented")
+    # assumed (no key): rope over the whole head in the half-rotation form
+    # (rotate-half), as the family's other models; the interleaved form is
+    # not taken
+    rope = RopeSpec(theta=float(hf.get("rope_theta", 10000.0)))
+    mlp = hf.get("mlp_multipliers") or (1.0, 1.0)
+    return dict(
+        layers=(LayerSpec(num_heads=hf["num_attention_heads"], rope=rope,
+                          recurrence="ssd"),) * hf["num_hidden_layers"],
+        ssm_num_heads=n_heads, ssm_head_dim=d_head,
+        ssm_state_dim=hf["mamba_d_state"],
+        ssm_num_groups=hf["mamba_n_groups"],
+        mup=MupSpec(
+            embedding=float(hf.get("embedding_multiplier", 1.0)),
+            lm_head=float(hf.get("lm_head_multiplier", 1.0)),
+            attention_in=float(hf.get("attention_in_multiplier", 1.0)),
+            attention_out=float(hf.get("attention_out_multiplier", 1.0)),
+            key=float(hf.get("key_multiplier", 1.0)),
+            ssm_in=float(hf.get("ssm_in_multiplier", 1.0)),
+            ssm_out=float(hf.get("ssm_out_multiplier", 1.0)),
+            ssm=tuple(float(m) for m in (
+                hf.get("ssm_multipliers") or (1.0,) * 5)),
+            mlp_gate=float(mlp[0]), mlp_down=float(mlp[1])),
+    )
+
+
+# tiiuae/Falcon-H1-34B-Instruct config.json (`model_type: falcon_h1`), the
+# keys `_falcon_h1_keys` reads, as published
+_FALCON_H1_34B = {
+    "model_type": "falcon_h1", "vocab_size": 261120, "hidden_size": 5120,
+    "intermediate_size": 21504, "num_hidden_layers": 72,
+    "num_attention_heads": 20, "num_key_value_heads": 4, "head_dim": 128,
+    "rope_theta": 100000000000, "rope_scaling": None, "rms_norm_eps": 1e-05,
+    "max_position_embeddings": 262144, "tie_word_embeddings": False,
+    "attention_bias": False, "mlp_bias": False, "projectors_bias": False,
+    "attn_layer_indices": None, "hidden_act": "silu",
+    "mamba_chunk_size": 128, "mamba_conv_bias": True, "mamba_d_conv": 4,
+    "mamba_d_head": 128, "mamba_d_ssm": 4096, "mamba_d_state": 256,
+    "mamba_expand": 2, "mamba_n_groups": 2, "mamba_n_heads": 32,
+    "mamba_norm_before_gate": False, "mamba_proj_bias": False,
+    "mamba_rms_norm": True, "mamba_use_mlp": True,
+    "attention_in_multiplier": 1, "attention_out_multiplier": 0.0375,
+    "embedding_multiplier": 5.656854249492381,
+    "key_multiplier": 0.011048543456039804,
+    "lm_head_multiplier": 0.0078125,
+    "mlp_multipliers": [0.1767766952966369, 0.011160714285714284],
+    "ssm_in_multiplier": 0.25,
+    "ssm_multipliers": [0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                        0.3535533905932738],
+    "ssm_out_multiplier": 0.08838834764831845,
+}
+
+
+def _falcon_h1_preset(n_layers: int, **over) -> dict:
+    """A Falcon-H1 preset of `n_layers` at the 34B's published keys (every
+    layer alike: Mamba-2 heads beside attention heads, one residual), 32 768
+    positions served; `over` replaces published keys (the CPU's toy
+    widths)."""
+    hf = {**_FALCON_H1_34B, "num_hidden_layers": n_layers,
+          "max_position_embeddings": 32768, **over}
+    heads = hf["num_attention_heads"]
+    return dict(
+        _falcon_h1_keys(hf, "preset"), architecture="falcon_h1",
+        vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"], num_layers=n_layers,
+        num_heads=heads, num_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf["head_dim"], rope_theta=float(hf["rope_theta"]),
+        rms_norm_eps=hf["rms_norm_eps"],
+        max_model_len=hf["max_position_embeddings"])
+
+
 _SDAR_GENERATION = BlockDiffusion(
     block_length=4, denoising_steps=4,
     remasking_strategy="low_confidence_dynamic", confidence_threshold=0.9,
@@ -413,6 +515,20 @@ PRESETS: dict[str, dict] = {
         dtype="float32", num_experts=16, num_experts_per_tok=4,
         num_experts_held=4, first_expert_held=4, moe_intermediate_size=32,
         linear_num_heads=4, linear_head_dim=16, linear_gate_rank=8),
+    "falcon-h1-34b": _falcon_h1_preset(72),
+    # the benchmark cell's cut: ONE pipeline stage of twelve — layers 0-5
+    # (every layer is alike: the pattern's period is one), every width, all
+    # heads and the whole vocabulary as published; this stage also carries
+    # the final norm and the head
+    "falcon-h1-34b-6l": _falcon_h1_preset(6),
+    # three layers at toy widths (float32), every ratio kept: GQA group 2,
+    # 2 groups of B / C over 4 state-space heads, a state dim (16) that is
+    # not the head dim (8), 4 taps, every multiplier as published
+    "tiny-falcon-h1": dict(_falcon_h1_preset(
+        3, vocab_size=512, hidden_size=64, intermediate_size=128,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        mamba_n_heads=4, mamba_d_head=8, mamba_d_ssm=32, mamba_d_state=16,
+        max_position_embeddings=512), dtype="float32"),
     "ling-3.0-flash": _ling_flash_preset(42),
     # the benchmark cell's cut: ONE chip of a four-chip expert-parallel
     # host that is the first pipeline stage — layers 0-6 with ONE leading
@@ -736,6 +852,8 @@ def _from_hf_config(path: str) -> dict:
         generation = dict(block_diffusion=_SDAR_GENERATION)
     if _is_ling_flash(hf):
         arch = "ling_flash"
+    if hf.get("model_type") == "falcon_h1":
+        arch = "falcon_h1"
     if arch is None:
         raise ValueError(f"unsupported architecture(s) {archs} in {path}")
     heads = hf["num_attention_heads"]
@@ -783,6 +901,10 @@ def _from_hf_config(path: str) -> dict:
     if arch == "solar_open2":
         moe = _solar_open2_keys(hf, path)
         # no layer has a rope: rope_theta and partial_rotary_factor are dead
+        hf = {**hf, "partial_rotary_factor": None, "rope_scaling": None}
+    if arch == "falcon_h1":
+        moe = _falcon_h1_keys(hf, path)
+        # its one rope is the per-layer list's (rope_scaling is refused)
         hf = {**hf, "partial_rotary_factor": None, "rope_scaling": None}
     if arch == "ling_flash":
         moe = _ling_flash_keys(hf, path)

@@ -128,13 +128,14 @@ def kda_prefill_xla(pool, read, write, fresh, q, k, v, g, beta, lens):
 
 # -- prefill kernel ----------------------------------------------------------
 
-def work_items(row_off, chunk_lens, n: int):
+def work_items(row_off, chunk_lens, n: int, BLOCK: int = BLOCK):
     """The work items of a packed chunk of `n` positions, from the rows'
     offsets and lengths (B,): per item its row, its block of the packed
     axis, the span [lo, hi) of the block that is the row's, and whether it
     is the row's first item. `n // BLOCK + B` items, the real ones first;
     the rest repeat the last real item with an empty span (the blocks they
-    name are already resident: nothing is copied for them)."""
+    name are already resident: nothing is copied for them). `BLOCK`: the
+    tokens of a block (this module's; ops/ssd_scan.py cuts its own)."""
     b = chunk_lens.shape[0]
     m = n // BLOCK + b
     end = row_off + chunk_lens

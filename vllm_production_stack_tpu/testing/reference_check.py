@@ -26,18 +26,24 @@ def served_logprobs(engine, prompts: list[list[int]], max_tokens: int,
                     ) -> list[dict]:
     """Greedy generation through the engine's own step loop, keeping what
     `generate()` drops: per produced token its (chosen logprob, top ids,
-    top logprobs). All prompts are in flight together, as served."""
+    top logprobs), and the row's block table as the last step it was still
+    running left it (`served_pages` reads the pool through it). All prompts
+    are in flight together, as served."""
     sampling = SamplingParams(max_tokens=max_tokens, temperature=0.0,
                               ignore_eos=True, logprobs=8)
     ids = [engine.add_request(prompt_token_ids=p, sampling=sampling)
            for p in prompts]
-    done = {i: {"token_ids": [], "logprobs": []} for i in ids}
+    done = {i: {"token_ids": [], "logprobs": [], "block_table": []}
+            for i in ids}
     while engine.has_unfinished():
         with engine.step_lock:
             outs = engine.step()
         for out in outs:
             done[out.request_id]["token_ids"].extend(out.new_token_ids)
             done[out.request_id]["logprobs"].extend(out.new_logprobs or [])
+        for req in engine.scheduler.running:
+            if req.request_id in done:
+                done[req.request_id]["block_table"] = list(req.block_table)
     return [done[i] for i in ids]
 
 
@@ -142,7 +148,8 @@ _BY_KIND_VIEWS = {"laguna": "LagunaCheckpointView",
 def compare(reference, cfg, params, prompts: list[list[int]],
             served: list[dict], routing_chunk: int | None = None,
             routing_backend: str = "xla",
-            served_routing: RoutingTap | None = None) -> dict:
+            served_routing: RoutingTap | None = None,
+            reference_arms: dict | None = None) -> dict:
     """`reference` is the reference module (the package's, or the
     benchmark's byte-identical copy). Returns the worst |Δlogprob| over
     every compared (position, id), how many were compared, and for an MoE
@@ -156,7 +163,10 @@ def compare(reference, cfg, params, prompts: list[list[int]],
     experts the served programs chose and reports how far each choice lay
     from its own: for renormalised top-6-of-128 weights in bf16 a
     free-running comparison measures the flips, amplified, and nothing
-    else (3.25 nats at 8 layers on the chip, PERF.md §6 PR 31)."""
+    else (3.25 nats at 8 layers on the chip, PERF.md §6 PR 31).
+    `reference_arms`: keywords the reference's `forward` takes to compute
+    something ELSE (reference_falcon_h1.py's fault arms: the check shows
+    each lands outside a limit)."""
     ref_cfg = reference.published_keys(cfg)
     worst, positions, ids_compared, total = 0.0, 0, 0, 0.0
     chosen_worst = 0.0
@@ -195,6 +205,13 @@ def compare(reference, cfg, params, prompts: list[list[int]],
             logits, routing = reference.forward(
                 ref_cfg, params_ref, seq, range(first, len(seq)),
                 forced_experts=forced, **block)
+            first = 0
+        elif cfg.ssm_layers:
+            # reference_falcon_h1.py reads the served tree's own leaves and
+            # takes `logit_positions` and its fault arms
+            logits, routing = reference.forward(
+                ref_cfg, params, seq, range(first, len(seq)),
+                **(reference_arms or {}))
             first = 0
         else:
             logits, routing = reference.forward(ref_cfg, params, seq)
@@ -241,7 +258,8 @@ class StateTap:
     state, token by token: set as `llama.STATE_TAP` while an engine is built
     and driven (as `RoutingTap` is), it receives every execution's (pool
     index each token's row wrote its state at, positions, q, k, v, g, beta)
-    and gives them back per state slot, in the order of the positions."""
+    and gives them back per state slot, in the order of the positions (a
+    state-space layer's scan is fed x, B, C, dt)."""
 
     def __init__(self, layer: int):
         self.layer = layer
@@ -270,7 +288,7 @@ class StateTap:
         slots = np.concatenate([r[0] for r in self.records])
         positions = np.concatenate([r[1] for r in self.records])
         fed = [np.concatenate([r[2 + i] for r in self.records])
-               for i in range(5)]
+               for i in range(len(self.records[0]) - 2)]
         out = {}
         for slot in np.unique(slots % state_slots):
             if slot == 0:
@@ -289,7 +307,8 @@ class StateTap:
 
 
 def served_state(reference, cfg, tap: StateTap, pool,
-                 token_block: int = 2048) -> dict:
+                 token_block: int = 2048, params: dict | None = None,
+                 state_dtype: str | None = None) -> dict:
     """The recurrent STATE the engine holds in its slots, where a comparison
     of logprobs cannot see it: bf16 weights and activations through 8 layers
     scatter logprobs by as much as a state rounded to bfloat16 at every
@@ -302,11 +321,25 @@ def served_state(reference, cfg, tap: StateTap, pool,
     the served programs fed that layer — every prefill chunk, seam, decode
     window and bank flip of the row lies between the two. Returns the
     largest |difference| over the reference's largest magnitude, over the
-    rows."""
-    state_dtype = jnp.dtype(
-        reference.published_keys(cfg).get("kda_state_dtype", "float32"))
-    step = jax.jit(functools.partial(
-        reference.delta_rule, state_dtype=state_dtype))
+    rows. A state-space layer's recurrence (`reference.ssd_recurrence`)
+    also reads the layer's A_log and D, from `params`; `state_dtype`
+    replaces the configuration's (the arm that has to fail)."""
+    keys = reference.published_keys(cfg)
+    if cfg.layer_specs[tap.layer].recurrence == "ssd":
+        sp = jax.tree.map(lambda a: a[cfg.ssm_layers.index(tap.layer)],
+                          params["layers"]["attn_ssm"])
+        recurrence = functools.partial(
+            reference.ssd_recurrence, state_dtype=jnp.dtype(
+                state_dtype or keys["ssm_state_dtype"]))
+
+        def step(x, b, c, dt, s):
+            return recurrence(x, b, c, dt, s, sp["A_log"], sp["D"])
+
+        step = jax.jit(step)
+    else:
+        step = jax.jit(functools.partial(
+            reference.delta_rule, state_dtype=jnp.dtype(
+                state_dtype or keys.get("kda_state_dtype", "float32"))))
     worst, tokens = 0.0, 0
     rows = tap.rows(pool.shape[0] // 2)
     for wrote, *fed in rows.values():
@@ -318,6 +351,41 @@ def served_state(reference, cfg, tap: StateTap, pool,
         tokens += len(fed[0])
     return {"layer": tap.layer, "rows": len(rows), "tokens": tokens,
             "state_rel_err": worst}
+
+
+def served_pages(reference, cfg, params, pool, prompts: list[list[int]],
+                 served: list[dict]) -> dict:
+    """The K/V PAGES the engine holds, where a comparison of logprobs cannot
+    see them: over thousands of keys the rounding of a float8 pool averages
+    away (Falcon-H1 at published widths: the largest |Δlogprob| 0.12–0.14
+    over float8 pages beside 0.085–0.093 over bfloat16 ones, the mean
+    unmoved; PERF.md §6, PR 50), so the pages have a limit of their own.
+    `pool` is the FIRST layer's page array as the engine left it after
+    `served_logprobs` (2, blocks, block size, kv heads, head dim): each
+    row's pages, found through the block table it had when it last ran,
+    against `reference.first_layer_kv` of the tokens it was fed. The rows
+    were in flight together and nothing was admitted behind them, so a page
+    given back still holds what its row wrote. Returns the largest relative
+    error (root of the summed squares, K and V each) over the rows."""
+    keys = reference.published_keys(cfg)
+    pool = np.asarray(pool)
+    block = pool.shape[2]
+    worst, tokens = 0.0, 0
+    for prompt, row in zip(prompts, served):
+        # (the last produced token was never fed; a row's last step may have
+        # been given pages the table kept here does not list yet)
+        fed = (list(prompt) + list(row["token_ids"]))[:-1]
+        fed = fed[:len(row["block_table"]) * block]
+        held = pool[:, row["block_table"]].reshape(
+            2, -1, *pool.shape[3:])[:, :len(fed)]
+        for want, got in zip(
+                reference.first_layer_kv(keys, params, fed), held):
+            got = jnp.asarray(got).astype(jnp.float32)
+            worst = max(worst, float(
+                jnp.linalg.norm(got - want) / jnp.linalg.norm(want)))
+        tokens += len(fed)
+    return {"layer": 0, "rows": len(served), "tokens": tokens,
+            "pages_rel_err": worst}
 
 
 class RouterTap:
