@@ -8,6 +8,7 @@ quick start uses, at the full width and depth of one model the repo supports
   kernels   each Pallas kernel that compiles for v5e, run NON-interpreted
             against ops/attention.py's XLA path: decode at the qwen3-8b head
             shape, prefill at the chat and long-document cells' head shapes,
+            the latent prefill kernel at Kanana-2's (ops/mla_attention.py),
             the chunk write kernel bit for bit over the whole pool
   serve     `python -m ...engine.server` and `python -m ...router.app` as two
             OS processes; through the router: one chat completion, one
@@ -846,6 +847,12 @@ def child_kernels(seed: int) -> int:
         causal_page_mask, paged_attention_with_staged, paged_attention_xla,
         write_kv_pages_blockwise,
     )
+    from vllm_production_stack_tpu.ops.mla_attention import (
+        latent_attention_xla,
+    )
+    from vllm_production_stack_tpu.ops.mla_attention_pallas import (
+        mla_prefill_attention,
+    )
     from vllm_production_stack_tpu.ops.paged_attention_pallas import (
         paged_decode_attention, paged_kv_write, paged_prefill_attention,
         paged_window_prefill_attention,
@@ -933,6 +940,21 @@ def child_kernels(seed: int) -> int:
         report["kernels"][
             f"prefill_{heads}_{kv_heads}" + (f"_w{window}" if window else "")
         ] = check
+
+    # the latent prefill kernel at Kanana-2's and Ling's shapes (32 heads,
+    # rank 512 + rope 64 stored 640 wide): the same four rows, both pools
+    lat = arr(1, num_blocks, bs, 640).at[..., 576:].set(0)
+    qa, tb = arr(4, t, 32, 640, gain=3.0).at[..., 576:].set(0), tables(4)
+    mask = causal_page_mask(positions, ctx, nb * bs)
+    for name, pool in (("mla_prefill", lat),
+                       ("mla_prefill_fp8_pool", lat.astype(jnp.float8_e4m3fn))):
+        got = np.asarray(mla_prefill_attention(
+            qa, pool, tb, ctx, start, scale=192 ** -0.5, rank=512), np.float32)
+        want = latent_attention_xla(qa, pool, tb, mask, scale=192 ** -0.5,
+                                    rank=512)
+        check = diff(got[real], np.asarray(want, np.float32)[real])
+        check["ok"] = check["ok"] and bool(np.all(np.isfinite(got)))
+        report["kernels"][name] = check
 
     # the chunk write kernel against the XLA form, the WHOLE pool bit for
     # bit: four rows packed on a 512-token axis (a continued row that starts

@@ -15,6 +15,7 @@ import pytest
 from vllm_production_stack_tpu.models import llama
 from vllm_production_stack_tpu.models.registry import resolve_model_config
 from vllm_production_stack_tpu.ops import kda_attention as kda
+from vllm_production_stack_tpu.ops import mla_attention_pallas as mk
 
 from test_chip_compile import topo  # noqa: F401
 from test_kda_kernels_compile import CHUNK, SLOTS, _program_args
@@ -37,6 +38,10 @@ def test_prefill_program_compiles_for_v5e(topo, rows):  # noqa: F811
         "linear", "latent", "linear"]
     assert kda.heads_per_step(cfg.linear_num_heads, cfg.linear_head_dim,
                               2) > 1
+    # the latent layer's tile is the Kanana cell's: 128 tokens x 32 heads
+    # (tests/test_chip_compile.py compiles that kernel alone)
+    assert mk._prefill_tile(n, 8, 32, cfg.num_heads, cfg.latent_width,
+                            cfg.kv_lora_rank, 2, 2) == 128
     params, kv, i32 = _program_args(topo, cfg)
     nbw = (n - 1) // 32 + 2
 

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 import engine_kit as ek
 from vllm_production_stack_tpu.engine.config import (
@@ -30,6 +31,7 @@ from vllm_production_stack_tpu.ops.attention import causal_page_mask
 from vllm_production_stack_tpu.ops.mla_attention import (
     latent_attention_with_staged, latent_attention_xla,
 )
+from vllm_production_stack_tpu.ops import mla_attention_pallas as mk
 from vllm_production_stack_tpu.ops.mla_attention_pallas import (
     mla_decode_attention, mla_prefill_attention,
 )
@@ -183,16 +185,54 @@ def test_latent_decode_kernel_against_xla():
             np.asarray(got), np.asarray(want), atol=2e-6)
 
 
-def test_latent_prefill_kernel_against_xla():
-    """A padding row, a first chunk shorter than its tile, a continued
-    chunk that ends past a 256-token block edge, a chunk from mid-page."""
+# name: (heads, rank, width, page tokens, pool pages, table pages, chunk
+# bucket, dtypes (queries, pool), per row (resident tokens, chunk tokens),
+# tolerance)
+_F32, _BF16, _FP8 = jnp.float32, jnp.bfloat16, jnp.float8_e4m3fn
+_TOL_F32 = dict(atol=2e-6)
+_TOL_BF16 = dict(rtol=2e-2, atol=2e-2)  # tests/test_pallas_attention.py's
+PREFILL_CASES = {
+    # a padding row, a first chunk shorter than its tile, a continued chunk
+    # that ends past a 256-token block edge, a chunk from mid-page
+    "four_heads": (4, 32, 128, 8, 80, 40, 32, (_F32, _F32),
+                   [(0, 0), (0, 20), (268, 32), (33, 7)], _TOL_F32),
+    # many heads a token, and a count that is no power of two: the rows'
+    # (token, head) order and the diagonal's `iota // heads`
+    "128_heads": (128, 32, 128, 8, 80, 40, 32, (_F32, _F32),
+                  [(268, 32), (0, 20)], _TOL_F32),
+    "24_heads": (24, 32, 128, 8, 80, 40, 32, (_F32, _F32),
+                 [(33, 7), (268, 32)], _TOL_F32),
+    # four 128-token tiles a row. Row 0 starts inside a page behind 303
+    # resident tokens, which end inside the 256-key block [256, 512): its
+    # first tile visits block 0 (neither edge) and block 1 (the diagonal),
+    # its last tile ends the row inside block 3 (ctx AND the diagonal);
+    # beside it a short row, a padding row and a row whose last two tiles
+    # lie past its tokens, its second one straddling ctx alone
+    "several_tiles": (4, 32, 128, 8, 160, 128, 512, (_F32, _F32),
+                      [(303, 512), (33, 7), (0, 0), (100, 200)], _TOL_F32),
+    "fp8_pool": (4, 32, 128, 8, 80, 40, 32, (_BF16, _FP8),
+                 [(0, 0), (0, 20), (268, 32), (33, 7)], _TOL_BF16),
+    # the cell's: 32 heads x 640 / 512, 32-token pages, 128-token tiles of
+    # 4,096 rows, behind 300 resident tokens
+    "kanana_bf16": (32, 512, 640, 32, 40, 32, 256, (_BF16, _BF16),
+                    [(300, 256), (0, 130)], _TOL_BF16),
+}
+
+
+@pytest.mark.parametrize("case", list(PREFILL_CASES))
+def test_latent_prefill_kernel_against_xla(case):
+    """Interpret mode against `latent_attention_xla`, every token a row
+    holds; what a padded position reads is finite."""
+    (nh, rank, width, bs, blocks, nb, t, (q_dtype, kv_dtype), rows,
+     tol) = PREFILL_CASES[case]
     rng = np.random.RandomState(1)
-    nh, rank, bs, blocks, nb, t = 4, 32, 8, 80, 40, 32
-    kv = _latents(rng, 1, blocks, bs)
-    tables = jnp.asarray(rng.randint(1, blocks, (4, nb)), jnp.int32)
-    ctx = jnp.asarray([0, 20, 300, 40], jnp.int32)
-    start = jnp.asarray([0, 0, 300 - 32, 40 - 7], jnp.int32)
-    q = _latents(rng, 4, t, nh)
+    kv = _latents(rng, 1, blocks, bs, width=width,
+                  dim=rank + 8).astype(kv_dtype)
+    tables = jnp.asarray(rng.randint(1, blocks, (len(rows), nb)), jnp.int32)
+    start = jnp.asarray([r for r, _ in rows], jnp.int32)
+    ctx = start + jnp.asarray([n for _, n in rows], jnp.int32)
+    q = _latents(rng, len(rows), t, nh, width=width,
+                 dim=rank + 8).astype(q_dtype)
     got = mla_prefill_attention(q, kv, tables, ctx, start, scale=0.2,
                                 rank=rank, interpret=True)
     pos = start[:, None] + jnp.arange(t)[None]
@@ -200,9 +240,26 @@ def test_latent_prefill_kernel_against_xla():
         q, kv, tables, causal_page_mask(pos, ctx, nb * bs), scale=0.2,
         rank=rank)
     real = np.asarray(pos < ctx[:, None])
-    assert np.isfinite(np.asarray(got)).all()
+    got = np.asarray(got, np.float32)
+    assert got.shape == (len(rows), t, nh, rank) and np.isfinite(got).all()
     np.testing.assert_allclose(
-        np.asarray(got)[real], np.asarray(want)[real], atol=2e-6)
+        got[real], np.asarray(want, np.float32)[real], **tol)
+
+
+@pytest.mark.parametrize("n", [16, 128, 256, 512])
+def test_a_statistic_in_every_lane_meets_any_width(n):
+    """`_lanes`: what the kernel's (rows, STAT_LANES) running max and sum
+    become beside (rows, n) scores or accumulator columns."""
+    stat = jnp.broadcast_to(jnp.arange(8.0)[:, None], (8, mk.STAT_LANES))
+
+    def kernel(stat_ref, out_ref):
+        out_ref[...] = mk._lanes(stat_ref[...], n)
+
+    got = pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((8, n), jnp.float32),
+        interpret=True)(stat)
+    np.testing.assert_array_equal(
+        got, np.broadcast_to(np.arange(8.0)[:, None], (8, n)))
 
 
 def test_routing_bias_moves_the_choice_and_never_the_weight(cfg):

@@ -9,7 +9,7 @@ flash-style online softmax across blocks in float32 — for a pool that holds
 ONE latent a token (ops/mla_attention.py): a block is one (tokens, width)
 matrix that is the keys whole and, in its first `rank` columns, the values.
 There is one key head, so no per-head column mask and no regrouping: every
-query head of a row (decode) or every (head, token) of a query tile
+query head of a row (decode) or every (token, head) of a query tile
 (prefill) is a row of ONE `(rows, width) @ (width, 256)` dot and one
 `(rows, 256) @ (256, rank)` dot a block.
 
@@ -234,12 +234,13 @@ def mla_decode_attention(
 def _prefill_vmem(tt: int, pages: int, bs: int, nh: int, width: int,
                   rank: int, kv_itemsize: int, q_itemsize: int) -> int:
     """Bytes of VMEM one prefill grid step holds: the double-buffered query
-    and output tiles, the f32 accumulator with its running max and sum (one
-    column each, lane-padded to 128), two slots of a block, and the block's
-    f32 scores with their exponentials in both widths."""
+    and output tiles, the f32 accumulator with its running max and sum
+    (STAT_LANES lanes each, a row's value in every lane), two slots of a
+    block, and the block's f32 scores with their exponentials in both
+    widths."""
     rows = nh * tt
     tiles = 2 * rows * (width + rank) * q_itemsize
-    state = rows * (rank + 2 * 128) * 4
+    state = rows * (rank + 2 * STAT_LANES) * 4
     slots = 2 * pages * bs * width * kv_itemsize
     scores = rows * pages * bs * (4 + 4 + q_itemsize)
     return tiles + state + slots + scores
@@ -255,38 +256,73 @@ def _prefill_tile(t: int, pages: int, bs: int, nh: int, width: int,
     return tt
 
 
+# Prefill: the running max and sum of a tile's rows are kept STAT_LANES wide,
+# a row's value in every lane (a (rows, 1) column holds as many vregs with one
+# lane of each in use, and meets the scores and the accumulator only through
+# a lane broadcast a vreg).
+STAT_LANES = 128
+
+
+def _lanes(stat, n: int):
+    """A lane-replicated (rows, STAT_LANES) statistic as wide as the (rows,
+    n) array it meets: whole copies side by side, or its first n lanes."""
+    if n % STAT_LANES:
+        assert n < STAT_LANES, n
+        return stat[:, :n]
+    return stat if n == STAT_LANES else pltpu.repeat(stat, n // STAT_LANES, 1)
+
+
 def _mla_prefill_kernel(
     # scalar prefetch
     tables_ref,  # (B, nb) int32
     ctx_ref,  # (B,) int32 — resident tokens AFTER this chunk (incl. chunk)
     start_ref,  # (B,) int32 — logical position of the chunk's first token
     # inputs
-    q_ref,  # (1, heads, Tt, width) — this tile's absorbed queries
+    q_ref,  # (1, Tt * heads, width) — this tile's absorbed queries, rows
+    # ordered (token, head): the activations' own order
     kv_hbm,  # (1, num_blocks, bs, width)
     # output
-    out_ref,  # (1, heads, Tt, rank)
+    out_ref,  # (1, Tt * heads, rank)
     # scratch
     buf,  # (2, P, bs, width)
     sems,
     state,  # SMEM (2,) int32
-    m_ref,  # (heads * Tt, 1) f32 running max, rows ordered (head, token)
-    l_ref,  # (heads * Tt, 1) f32 running denominator
-    acc_ref,  # (heads * Tt, rank) f32 running numerator
+    m_ref,  # (Tt * heads, STAT_LANES) f32 running max, in every lane
+    l_ref,  # (Tt * heads, STAT_LANES) f32 running denominator, likewise
+    acc_ref,  # (Tt * heads, rank) f32 running numerator
     *,
     scale: float,
+    nh: int,
 ):
     """One grid step = one query tile of one row, all heads as rows of the
     same two dots. The tile walks the blocks its row holds up to the tile's
     last position — none for a padding row or a tile past the row's tokens —
-    and hands the next live tile its first block in flight. Causality comes
-    from iotas alone and only where a block straddles ctx or the tile's
-    diagonal: the scheduler feeds chunks with CONTIGUOUS positions, so a
-    query's position is chunk_start + its index; resident pages hold earlier
-    chunks AND this chunk's latents (the model writes before attending)."""
+    and hands the next live tile its first block in flight.
+
+    A block is folded into the tile in ONE basic block — the scores' dot,
+    the mask, the softmax, the values' dot, nothing under a branch — so the
+    compiler's scheduler runs the vector units' work on one part of the rows
+    under the MXU's on another: by its own count the MXU is busy in 97% of
+    the body's bundles, and cutting the rows into items by hand (what
+    ops/paged_attention_pallas.py does, whose body has twice the vector
+    work a flop) only adds to it (docs/22 has the counts and the chip's
+    readings). The block is the MXU's stationary operand in both dots:
+    transposed, as that kernel folds, the queries would be — a weight tile
+    for 256 streamed rows where here one serves the tile's thousands. The
+    running max and sum live STAT_LANES wide with a row's value in every
+    lane, so neither is broadcast along lanes to meet the scores or the
+    accumulator.
+
+    Causality comes from iotas alone, on every block and without a branch
+    (two vector operations a vreg of scores, under the MXU's time): the
+    scheduler feeds chunks with CONTIGUOUS positions, so a query's position
+    is chunk_start + its index; resident pages hold earlier chunks AND this
+    chunk's latents (the model writes before attending)."""
     b, qt = pl.program_id(0), pl.program_id(1)
     rows, tiles = pl.num_programs(0), pl.num_programs(1)
     pages, bs, width = buf.shape[1:]
-    nh, tt, rank = out_ref.shape[1:]
+    tile_rows, rank = out_ref.shape[1:]
+    tt = tile_rows // nh
     span = pages * bs
     copies = functools.partial(_block_copies, kv_hbm, tables_ref, buf, sems)
 
@@ -312,11 +348,6 @@ def _mla_prefill_kernel(
         start_block(b, 0, slot0)
 
     ctx, first = ctx_ref[b], start_ref[b] + qt * tt
-    # the last key position each query row attends, rows ordered (head, token)
-    last_key = jnp.minimum(
-        first + jax.lax.broadcasted_iota(jnp.int32, (nh * tt, 1), 0) % tt,
-        ctx - 1,
-    )
     key_offsets = jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
 
     def visit(i, carry):
@@ -351,31 +382,32 @@ def _mla_prefill_kernel(
         for copy in copies(b, i, slot):
             copy.wait()
         key0 = i * span
-        straddles = (key0 + span > ctx) | (key0 + span - 1 > first)
-        q = q_ref[0].reshape(nh * tt, width)
         block = buf[slot].reshape(span, width)
-        if block.dtype != q.dtype:
-            block = block.astype(jnp.float32).astype(q.dtype)
+        if block.dtype != q_ref.dtype:
+            block = block.astype(jnp.float32).astype(q_ref.dtype)
         scores = jax.lax.dot_general(
-            q, block, (((1,), (1,)), ((), ())),
+            q_ref[0], block, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale  # (heads * Tt, span)
-        scores = jax.lax.cond(
-            straddles,
-            lambda s: jnp.where(key_offsets <= last_key - key0, s, NEG_INF),
-            lambda s: s,
-            scores,
-        )
+        ) * scale  # (Tt * heads, span)
+        # the last key each row attends, from the block's first (built here:
+        # hoisted, its 512 vregs are spilled a tile and filled a visit); a
+        # token's heads attend alike
+        last_key = jnp.minimum(
+            first + jax.lax.broadcasted_iota(
+                jnp.int32, (tile_rows, 1), 0) // nh,
+            ctx - 1,
+        ) - key0
+        scores = jnp.where(key_offsets <= last_key, scores, NEG_INF)
         # block 0 starts the statistics: the scratch is never cleared
         m_prev = jnp.where(i == 0, NEG_INF, m_ref[...])
         l_prev = jnp.where(i == 0, 0.0, l_ref[...])
         acc = jnp.where(i == 0, 0.0, acc_ref[...])
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(scores - m_new)  # exactly 0 on masked keys
+        p = jnp.exp(scores - _lanes(m_new, span))  # exactly 0 on masked keys
         m_ref[...] = m_new
         l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc * alpha + jax.lax.dot(
+        acc_ref[...] = acc * _lanes(alpha, rank) + jax.lax.dot(
             p.astype(block.dtype), block[:, :rank],
             preferred_element_type=jnp.float32,
         )
@@ -386,8 +418,8 @@ def _mla_prefill_kernel(
     @pl.when(n_blocks > 0)
     def _finalize():
         # every query of a live tile attends key 0 at least: l > 0
-        out_ref[0] = (acc_ref[...] / l_ref[...]).reshape(
-            nh, tt, rank).astype(out_ref.dtype)
+        out_ref[0] = (acc_ref[...] * _lanes(1.0 / l_ref[...], rank)).astype(
+            out_ref.dtype)
 
     @pl.when(n_blocks == 0)
     def _padding():
@@ -416,28 +448,28 @@ def mla_prefill_attention(
     tt = _prefill_tile(t, pages, bs, nh, width, rank, kv.dtype.itemsize,
                        q_abs.dtype.itemsize)
     assert t % tt == 0, (t, tt)  # T is a power-of-two bucket
+    rows = tt * nh  # a tile's (token, head) pairs: q_abs's own order
     out = pl.pallas_call(
-        functools.partial(_mla_prefill_kernel, scale=scale),
+        functools.partial(_mla_prefill_kernel, scale=scale, nh=nh),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,  # tables, context_lens, chunk_start
             grid=(b, t // tt),
             in_specs=[
-                pl.BlockSpec((1, nh, tt, width),
-                             lambda i, j, *_: (i, 0, j, 0)),
+                pl.BlockSpec((1, rows, width), lambda i, j, *_: (i, j, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((1, nh, tt, rank),
-                                   lambda i, j, *_: (i, 0, j, 0)),
+            out_specs=pl.BlockSpec((1, rows, rank),
+                                   lambda i, j, *_: (i, j, 0)),
             scratch_shapes=[
                 pltpu.VMEM((2, pages, bs, width), kv.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SMEM((2,), jnp.int32),
-                pltpu.VMEM((nh * tt, 1), jnp.float32),
-                pltpu.VMEM((nh * tt, 1), jnp.float32),
-                pltpu.VMEM((nh * tt, rank), jnp.float32),
+                pltpu.VMEM((rows, STAT_LANES), jnp.float32),
+                pltpu.VMEM((rows, STAT_LANES), jnp.float32),
+                pltpu.VMEM((rows, rank), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, nh, t, rank), q_abs.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, t * nh, rank), q_abs.dtype),
         # tiles run in order: each hands the next its first block in flight
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
@@ -445,5 +477,5 @@ def mla_prefill_attention(
         ),
         interpret=interpret,
     )(block_tables, context_lens, chunk_start,
-      jnp.transpose(q_abs, (0, 2, 1, 3)), kv)
-    return jnp.transpose(out, (0, 2, 1, 3))
+      q_abs.reshape(b, t * nh, width), kv)
+    return out.reshape(b, t, nh, rank)
