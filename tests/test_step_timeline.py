@@ -23,6 +23,7 @@ from vllm_production_stack_tpu.engine.flightrec import (
     ThreadRegistry,
 )
 from vllm_production_stack_tpu.engine.request import SamplingParams
+from vllm_production_stack_tpu.metrics_contract import DEVICE_SCOPES
 from vllm_production_stack_tpu.testing.serial_loop import serial_engine
 
 # every key /debug/timing's `engine` group served before this timeline
@@ -33,10 +34,13 @@ PARENT_KEYS = {
     "overlap_s", "step_wall_s", "rollback_n",
 }
 STEP_PHASES = ("sched", "build", "h2d", "enqueue", "sync", "post")
-# the scopes a captured program's HLO metadata names (docs/14-tracing.md)
-LAYER_SCOPES = {"attn_qkv", "kv_stage", "attn", "attn_out", "mlp"}
-PREFILL_SCOPES = LAYER_SCOPES | {"embed", "final_norm", "lm_head", "sample"}
-DECODE_SCOPES = PREFILL_SCOPES | {"kv_commit", "gather_pages"}
+# the scopes a dense model's programs name, out of the one registry
+# (metrics_contract.DEVICE_SCOPES): all but the other mixers', the experts',
+# the output gate's and block diffusion's; a decode window alone commits its
+# staged K/V and (XLA attention) gathers its pages
+_NOT_DENSE = ("mla_", "kda_", "ssm_", "moe_", "h1_", "bd_", "attn_gate")
+DECODE_SCOPES = {s for s in DEVICE_SCOPES if not s.startswith(_NOT_DENSE)}
+PREFILL_SCOPES = DECODE_SCOPES - {"kv_commit", "gather_pages"}
 
 
 def _engine() -> LLMEngine:
@@ -681,16 +685,11 @@ def test_capture_holds_dev_dry_beside_step_spans(tmp_path, monkeypatch):
 
 
 def test_xplane_timeline_rebuilds_both_accounts_from_the_capture():
-    """tools/xplane_timeline.py: starvation gaps and dry gaps over a capture
-    from `step:*` spans and `dev:dry` events alone."""
-    import importlib.util
-    import os
+    """engine/capture_summary.py (where tools/xplane_timeline.py's reduction
+    lives now): starvation gaps and dry gaps over a capture from `step:*`
+    spans and `dev:dry` events alone."""
+    from vllm_production_stack_tpu.engine import capture_summary as tool
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    mod = importlib.util.spec_from_file_location(
-        "xplane_timeline", os.path.join(root, "tools", "xplane_timeline.py"))
-    tool = importlib.util.module_from_spec(mod)
-    mod.loader.exec_module(tool)
     ms = 1e6
     steps = [  # (phase, start, end, seq, kind), ns
         ("build", 0 * ms, 10 * ms, 1, "prefill"),

@@ -15,6 +15,8 @@ from __future__ import annotations
 import argparse
 import asyncio
 import json
+import os
+import sys
 import time
 
 import numpy as np
@@ -143,6 +145,10 @@ class EngineServer:
         # on-demand xprof capture (/debug/profile/start|stop): the dir of
         # the live jax.profiler trace, None when not capturing
         self._profile_dir: str | None = None
+        # ... and what the last capture came to (engine/capture_summary.py),
+        # or {"error", "dir"}: POST /debug/profile/stop's reply, GET
+        # /debug/profile/summary, `device.last_capture` of /debug/programs
+        self._last_capture: dict | None = None
         self._session = None  # lazy outbound ClientSession (kv_pull)
         self.kv_event_publisher = None  # started when KV_CONTROLLER_URL set
         self._tok_repr_cache: dict[int, tuple[str, list[int]]] = {}
@@ -257,6 +263,7 @@ class EngineServer:
         r.add_post("/debug/postmortem", self.debug_postmortem)
         r.add_post("/debug/profile/start", self.debug_profile_start)
         r.add_post("/debug/profile/stop", self.debug_profile_stop)
+        r.add_get("/debug/profile/summary", self.debug_profile_summary)
         r.add_post("/sleep", self.sleep)
         r.add_post("/wake_up", self.wake_up)
         r.add_get("/is_sleeping", self.is_sleeping)
@@ -1712,7 +1719,11 @@ class EngineServer:
                                   "JSON black box now (docs/37)",
         "POST /debug/profile/start": "start an xprof device capture "
                                      "({\"dir\": ...})",
-        "POST /debug/profile/stop": "stop the capture and flush the dump",
+        "POST /debug/profile/stop": "stop the capture, flush the dump, and "
+                                    "answer with its summary: the chip's "
+                                    "seconds by program and by named scope, "
+                                    "idle gaps by host phase (docs/14)",
+        "GET /debug/profile/summary": "the last capture's summary again",
     }
 
     async def debug_index(self, request: web.Request) -> web.Response:
@@ -1805,6 +1816,9 @@ class EngineServer:
                 None, kvhash_backend
             ),
         }
+        if self._last_capture is not None:
+            # where the chip's time went in the last xprof capture
+            body["device"]["last_capture"] = self._last_capture
         return web.json_response(body)
 
     async def debug_postmortem(self, request: web.Request) -> web.Response:
@@ -1858,7 +1872,20 @@ class EngineServer:
             {"status": "profiling", "dir": log_dir, "clocks": clocks}
         )
 
+    # the reduction of a capture is bounded: a child that has not answered
+    # by then is killed, and the capture stays where it was written
+    CAPTURE_SUMMARY_TIMEOUT_S = 180.0
+
     async def debug_profile_stop(self, request: web.Request) -> web.Response:
+        """Stop the capture, flush the dump, and say where the chip's time
+        went in it: the capture is reduced where it lies by a child process
+        (engine/capture_summary.py: no JAX, no device, niced) that this
+        handler awaits and the step thread never does. The summary is the
+        reply's `summary`, `GET /debug/profile/summary` and
+        `device.last_capture` of `GET /debug/programs`. A child that fails
+        or runs out of time leaves `{"error", "dir"}` there and the reply is
+        still 200 with the dump's directory: the capture is never lost to
+        its summary."""
         if self._profile_dir is None:
             return error(409, "no profile capture in progress", "conflict")
         log_dir, self._profile_dir = self._profile_dir, None
@@ -1872,7 +1899,57 @@ class EngineServer:
         except Exception as e:
             return error(500, f"profiler stop failed: {e}", "internal_error")
         logger.info("xprof capture stopped (%s)", log_dir)
-        return web.json_response({"status": "stopped", "dir": log_dir})
+        self._last_capture = await self._summarize_capture(log_dir)
+        return web.json_response({
+            "status": "stopped", "dir": log_dir,
+            "summary": self._last_capture,
+        })
+
+    async def _summarize_capture(self, log_dir: str) -> dict:
+        out_path = os.path.join(log_dir, "capture_summary.json")
+        package_root = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        env["PYTHONPATH"] = os.pathsep.join(
+            p for p in (package_root, env.get("PYTHONPATH")) if p)
+        t0 = time.monotonic()
+        proc = None
+        try:
+            proc = await asyncio.create_subprocess_exec(
+                sys.executable, "-m",
+                "vllm_production_stack_tpu.engine.capture_summary",
+                log_dir, out_path, env=env,
+                stdout=asyncio.subprocess.PIPE,
+                stderr=asyncio.subprocess.PIPE,
+            )
+            out, err = await asyncio.wait_for(
+                proc.communicate(), self.CAPTURE_SUMMARY_TIMEOUT_S)
+            if proc.returncode != 0:
+                raise RuntimeError(
+                    f"exit code {proc.returncode}: "
+                    f"{err.decode(errors='replace')[-400:].strip()}")
+            # (the child also writes it beside the dump)
+            doc = await asyncio.get_running_loop().run_in_executor(
+                None, json.loads, out)
+        except (OSError, RuntimeError, ValueError, asyncio.TimeoutError) as e:
+            logger.warning("capture summary of %s failed: %r", log_dir, e)
+            doc = {"error": f"{type(e).__name__}: {e}"[:600], "dir": log_dir}
+        finally:
+            if proc is not None and proc.returncode is None:
+                proc.kill()
+                await proc.wait()
+        doc["child_s"] = round(time.monotonic() - t0, 3)
+        return doc
+
+    async def debug_profile_summary(
+        self, request: web.Request
+    ) -> web.Response:
+        """GET /debug/profile/summary: what POST /debug/profile/stop last
+        answered with."""
+        if self._last_capture is None:
+            return error(404, "no capture has been stopped yet",
+                         "not_found_error")
+        return web.json_response(self._last_capture)
 
     async def debug_timing(self, request: web.Request) -> web.Response:
         """Served-stack profiling: where the step thread's wall time goes

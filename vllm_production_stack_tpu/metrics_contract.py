@@ -776,3 +776,75 @@ DEBUG_TIMING_LATENT_COUNTS = ("mla_layers_n",)
 # expert layer run, padding tokens included — and those of them that hold
 # an expert this program holds (all of them without a held share)
 DEBUG_TIMING_GROUP_COUNTS = ("moe_group_kept_n", "moe_group_slots_n")
+
+# The device scopes: every `jax.named_scope` name of the package, each in ONE
+# group — the lever that would move its seconds. They are metadata only (the
+# compiled operations are the same) and land in each device operation's
+# `tf_op` stat (`jit(decode_window_fn)/while/body/attn_qkv/dot_general`);
+# engine/capture_summary.py books an operation to the INNERMOST name of its
+# path that is a key here (`attn_gate` inside `attn_qkv` is `glue`), and to
+# `other` where the path holds none. This is the one list: the docs point
+# here, tests/test_capture_summary.py holds it equal to the literals in the
+# source, and a scope added to a model is added here with its group.
+DEVICE_SCOPES = {
+    # proj — the mixers' matmuls (fused projections, weight layouts)
+    "attn_qkv": "proj",     # input norm, Q/K/V projections, qk_norm, RoPE
+    "attn_out": "proj",     # output projection, its norm, the residual
+    "mla_q": "proj",        # a latent layer's query projection and its RoPE
+    "mla_latent": "proj",   # ... the down-projection to the cached latent
+    "mla_absorb": "proj",   # ... W_kv_b folded into the query / the output
+    "kda_qkv": "proj",      # a gated-delta layer's norm and q|k|v matmuls
+    "kda_out": "proj",      # ... output gate, norm and projection
+    "ssm_in_proj": "proj",  # state-space heads: z|x|B|C|dt in one matmul
+    "ssm_out": "proj",      # ... their output projection
+    # glue — convolutions, gates and norms around the mixers
+    "attn_gate": "glue",    # the attention output gate (logistic, multiply)
+    "mla_gate": "glue",     # the same of a latent layer
+    "kda_conv": "glue",     # short convolution, SiLU, q/k L2 norms
+    "kda_gate": "glue",     # decay and beta gates (low-rank pairs, softplus)
+    "ssm_conv": "glue",     # short convolution over x|B|C, SiLU
+    "ssm_gate_norm": "glue",  # gate by z, grouped RMSNorm
+    "h1_branches": "glue",  # attention and state-space branches summed
+    # mixer — the attention / recurrence kernels and what XLA leaves beside
+    "attn": "mixer",        # paged attention: the Pallas kernel, or XLA's
+    "kda_scan": "mixer",    # the gated delta rule over a chunk or a step
+    "ssm_scan": "mixer",    # the state-space scan
+    # mlp / experts / moe_route — the second half of a layer
+    "mlp": "mlp",           # post-attention norm, gate/up/down, residual
+    "moe_shared": "mlp",    # the shared experts of an expert layer
+    "moe_experts": "experts",  # the routed experts' matmuls
+    "moe_router": "moe_route",  # router matmul, softmax / sigmoid, top-k
+    "moe_route_groups": "moe_route",  # group-limited routing's group top-k
+    "moe_dispatch": "moe_route",  # sort by expert, the row gather
+    "moe_combine": "moe_route",  # un-sort, weight and sum
+    # ends — before the first layer and after the last
+    "embed": "ends",        # token embedding lookup
+    "final_norm": "ends",   # last norm
+    "lm_head": "ends",      # logits
+    "sample": "ends",       # masks, the draw, logprobs, the automaton step
+    "bd_transfer": "ends",  # block diffusion: which masked tokens to keep
+    # weights — the layer loop's slices of the stacked leaves: what XLA
+    # copies (or prefetches) of a layer's weights before its matmuls
+    "layer_weights": "weights",
+    # kv — moving K/V and pages
+    "kv_stage": "kv",       # this step's K/V into the pool or the staging
+    "kv_commit": "kv",      # a decode window's one scatter into the pool
+    "gather_pages": "kv",   # the hoisted history gather (XLA attention)
+}
+# ... the groups in the order a report prints them; the last is no scope's
+DEVICE_SCOPE_GROUPS = ("proj", "glue", "mixer", "mlp", "experts",
+                       "moe_route", "ends", "weights", "kv", "other")
+# ... and the KIND of a device program, by the family of its module name
+# (`jit_<function>`), for the functions engine/model_runner.py jits as step
+# programs in its `_build_*_fn` methods: `step_fn` and `sp_step_fn` (a
+# prefill chunk), `decode_window_fn` and `bd_window_fn` (a decode window),
+# `verify_fn` (a speculation's verify). A program under a millisecond (an
+# RNG split, a chain gather) is `glue_program` whatever its name; a longer
+# one of another name `other_program`.
+DEVICE_PROGRAM_KINDS = {
+    "jit_step_fn": "prefill",
+    "jit_sp_step_fn": "prefill",
+    "jit_decode_window_fn": "window",
+    "jit_bd_window_fn": "window",
+    "jit_verify_fn": "verify",
+}
