@@ -19,6 +19,17 @@ bf16 q, k, v, float32 state), one layer:
   share of the HBM bound of what it must move (benchmarks/chip/costs_kda.py's
   counts).
 
+The pass that makes q, k and v (`kda_short_conv`, ops/kda_short_conv.py;
+PERF.md §6, PR 53), at Solar-Open2's and Ling's widths (64 and 32 heads x
+128; `--conv-only` stops after it):
+
+- check: against the XLA form it stands in for (`models/llama.py:
+  _Recurrence.qkv` over `_PackedRecurrence.conv`) in the same three layouts,
+  bf16 in and out: the new tails bit for bit, q, k, v within one bf16 step
+  and the share of values that differ at all;
+- time: both forms' ms a 2,048-token chunk and layer, each with its share of
+  the bytes' bound (the projections read once, q, k, v written once).
+
 One JSON line per measurement, kept in chiprun_out/sweep_kda.jsonl too; the
 last line is {"ok": ...}.
 """
@@ -37,7 +48,9 @@ import numpy as np
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
+from vllm_production_stack_tpu.models import llama  # noqa: E402
 from vllm_production_stack_tpu.ops import kda_attention as kda  # noqa: E402
+from vllm_production_stack_tpu.ops import kda_short_conv as ksc  # noqa: E402
 
 H, D, N, SLOTS = 64, 128, 2048, 34
 HBM_BYTES_PER_S = 819e9  # benchmarks/chip/peaks.json, TPU v5 lite
@@ -45,6 +58,7 @@ OUT = os.path.join(REPO, "chiprun_out", "sweep_kda.jsonl")
 # float32 state on both sides; q, k, v are the same bf16 values; what
 # differs is a 64-token block solved at once against 64 updates in turn
 ATOL, RTOL = 2e-3, 2e-3
+TIMES = 8  # applications of the q, k, v pass in one timed program
 
 
 def emit(obj: dict) -> None:
@@ -83,17 +97,89 @@ def timed(fn, *args, reps=5):
     return (time.perf_counter() - t0) / reps, out
 
 
+LAYOUTS = (
+    ("one_row", (N, 0), (True, False)),
+    ("seam_inside_a_block", (1000, 1048), (False, True)),
+    ("continued_fresh_padding", (700, 900, 0), (False, True, False)))
+
+
+def short_conv(rng, heads: int) -> bool:
+    """The q, k, v pass against the XLA form at `heads` x D, then both
+    timed."""
+    c, taps_n, bf = 3 * heads * D, 4, jnp.bfloat16
+    proj = tuple(jnp.asarray(rng.randn(N, c // 3), bf) for _ in range(3))
+    taps = jnp.asarray(rng.randn(taps_n, c) * 0.5, bf)
+    pool = jnp.asarray(rng.randn(SLOTS, taps_n - 1, c), bf)
+    need = 2 * N * c * 2
+    ok = True
+    for name, lens, fresh in LAYOUTS:
+        b = len(lens)
+        lens_a = jnp.asarray(lens, jnp.int32)
+        pack = llama.pack_index(lens_a, lens_a, N)
+        st = {"read": jnp.arange(1, b + 1, dtype=jnp.int32),
+              "write": jnp.arange(18, b + 18, dtype=jnp.int32),
+              "row_off": pack["row_off"], "tok_row": pack["tok_row"],
+              "chunk_lens": lens_a, "fresh": jnp.asarray(fresh), "tap": None}
+
+        def form(interpret, times=1):
+            def run(pool, proj):
+                # `times` applications in ONE program, each on the one
+                # before's q, k, v (a call of its own is ~0.5 ms of host
+                # dispatch: more than the pass); flat, as the recurrence's
+                # kernel takes them (a relayout to (N, H, D) on the way out
+                # would be timed with the form)
+                for _ in range(times):
+                    recur = llama._PackedRecurrence(
+                        {"conv": pool}, {**st, "interpret": interpret}, None)
+                    proj = tuple(z.reshape(N, heads * D) for z in recur.qkv(
+                        proj, taps, heads, D))
+                    pool = recur.pools["conv"]
+                return proj, pool
+            return jax.jit(run)
+
+        _, (want, want_pool) = timed(form(None), pool, proj, reps=1)
+        _, (got, got_pool) = timed(form(False), pool, proj, reps=1)
+        xla_s = timed(form(None, TIMES), pool, proj)[0] / TIMES
+        secs = timed(form(False, TIMES), pool, proj)[0] / TIMES
+        tokens = sum(lens)
+        w = np.concatenate(
+            [np.asarray(z[:tokens], np.float32).ravel() for z in want])
+        g = np.concatenate(
+            [np.asarray(z[:tokens], np.float32).ravel() for z in got])
+        # one step of bfloat16 at the value's size
+        steps = float(np.max(np.abs(g - w) / np.maximum(
+            2.0 ** (np.floor(np.log2(np.maximum(np.abs(w), 1e-30))) - 7),
+            1e-30)))
+        differ = float(np.mean(g != w))
+        tails = bool(jnp.all(got_pool == want_pool))
+        good = tails and steps <= 1.0 and differ < 1e-2
+        ok &= good
+        emit({"kernel": "kda_short_conv", "case": name, "heads": heads,
+              "ok": good, "tails_equal": tails, "max_bf16_steps": steps,
+              "share_that_differ": differ, "ms": secs * 1e3,
+              "xla_form_ms": xla_s * 1e3,
+              "token_tile": ksc.token_tile(N),
+              "heads_per_tile": ksc.heads_per_tile(heads, D),
+              "bytes_bound_ms": need / HBM_BYTES_PER_S * 1e3,
+              "share_of_bytes_bound": need / HBM_BYTES_PER_S / secs,
+              "xla_form_share_of_bytes_bound":
+                  need / HBM_BYTES_PER_S / xla_s})
+    return ok
+
+
 def main() -> int:
     dev = jax.devices()[0]
     emit({"platform": dev.platform, "kind": dev.device_kind})
     rng = np.random.RandomState(0)
     ok = True
+    for heads in (H, 32):  # Solar-Open2's and Ling's KDA layers
+        ok &= short_conv(rng, heads)
+    if "--conv-only" in sys.argv[1:]:
+        emit({"ok": bool(ok)})
+        return 0 if ok else 1
     pool = jnp.asarray(rng.randn(SLOTS, H, D, D) * 0.1, jnp.float32)
     q, k, v, g, beta = inputs(rng, N)
-    for name, lens, fresh in (
-            ("one_row", (N, 0), (True, False)),
-            ("seam_inside_a_block", (1000, 1048), (False, True)),
-            ("continued_fresh_padding", (700, 900, 0), (False, True, False))):
+    for name, lens, fresh in LAYOUTS:
         b = len(lens)
         lens_a = jnp.asarray(lens, jnp.int32)
         off = jnp.cumsum(lens_a) - lens_a

@@ -982,6 +982,40 @@ def child_kernels(seed: int) -> int:
             "ok": bool(np.array_equal(got, want)),
         }
 
+    # the pass that makes a KDA layer's q, k, v (ops/kda_short_conv.py)
+    # against the XLA form it stands in for, bf16 in and out: a continued
+    # row, a fresh row whose slot held garbage and a padding row on a
+    # 512-token axis, at Solar-Open2's and Ling's head counts — the new
+    # tails bit for bit, q, k, v within one bf16 step
+    from vllm_production_stack_tpu.models import llama
+
+    lens = jnp.asarray([210, 290, 0], jnp.int32)
+    pack = llama.pack_index(lens, lens, 512)
+    for heads in (64, 32):
+        width = 3 * heads * 128
+        x, taps, tails = arr(512, width), arr(4, width), arr(8, 3, width)
+        out = {}
+        for interpret in (None, False):
+            recur = llama._PackedRecurrence({"conv": tails}, {
+                "read": jnp.asarray([1, 2, 3]), "write": jnp.asarray([4, 5, 6]),
+                "row_off": pack["row_off"], "tok_row": pack["tok_row"],
+                "chunk_lens": lens, "fresh": jnp.asarray([False, True, False]),
+                "interpret": interpret, "tap": None}, None)
+            qkv = recur.qkv(tuple(jnp.split(x, 3, axis=-1)), taps, heads, 128)
+            out[interpret] = (np.concatenate([np.asarray(
+                y[:500], np.float32).ravel() for y in qkv]),
+                np.asarray(recur.pools["conv"], np.float32))
+        (want, want_tails), (got, got_tails) = out[None], out[False]
+        steps = float(np.max(np.abs(got - want) / 2.0 ** (np.floor(
+            np.log2(np.maximum(np.abs(want), 1e-30))) - 7)))
+        report["kernels"][f"kda_short_conv_{heads}"] = {
+            "max_bf16_steps": steps,
+            "share_that_differ": float(np.mean(got != want)),
+            "tails_equal": bool(np.array_equal(got_tails, want_tails)),
+            "ok": bool(steps <= 1.0
+                       and np.array_equal(got_tails, want_tails)),
+        }
+
     report["ok"] = all(k["ok"] for k in report["kernels"].values())
     emit(report)
     return 0 if report["ok"] else 1

@@ -7,6 +7,7 @@ widths two layers deep (one GQA and one KDA layer) with the kernels of both
 mixers and of the experts."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 from vllm_production_stack_tpu.models import llama
 from vllm_production_stack_tpu.models.registry import resolve_model_config
 from vllm_production_stack_tpu.ops import kda_attention as kda
+from vllm_production_stack_tpu.ops import kda_short_conv as ksc
 
 from test_chip_compile import _assert_kernel, topo  # noqa: F401
 
@@ -71,6 +73,25 @@ def test_decode_kernel_compiles_at_the_cells_shapes(topo, rows):  # noqa: F811
     ).lower(*args).compile()
     _assert_kernel(compiled)
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+@pytest.mark.parametrize("heads", [64, 32])  # Solar-Open2's, Ling's
+def test_qkv_pass_compiles_at_the_cells_shapes(topo, heads):  # noqa: F811
+    """2,048 x 24,576 and 2,048 x 12,288 of projections (a third each),
+    16 rows' tails: one head's lanes x the whole chunk a grid step, nothing
+    beside the kernel (no pad, no slice, no copy of the projections)."""
+    s = _sds(topo)
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    width, rows = 3 * heads * D, 16
+    args = (*(s((CHUNK, width // 3), bf),) * 3, s((4, width), bf),
+            s((3 * rows, width), f32), s((3 * rows,), i32), s((rows,), i32))
+    fn = functools.partial(ksc.kda_short_conv.__wrapped__, heads=heads)
+    compiled = jax.jit(fn).lower(*args).compile()
+    _assert_kernel(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+    (call,) = [e for e in jax.make_jaxpr(fn)(*args).eqns
+               if e.primitive.name == "pallas_call"]
+    assert tuple(call.params["grid_mapping"].grid) == (1, heads)
 
 
 def _cut(layers=2):

@@ -152,10 +152,12 @@ def test_a_bias_flips_a_group_and_never_a_weight(cfg):
 
 # -- the KDA variants and the head gate -----------------------------------------
 
-class _Capture:
-    """A recurrence that keeps what `_kda_attention` hands the scan."""
+class _Capture(llama._Recurrence):
+    """A recurrence that keeps what `_kda_attention` hands the scan (q, k,
+    v by the plain `qkv` over a convolution that passes x through)."""
 
-    tap = None
+    def __init__(self):
+        super().__init__({}, None, None)
 
     def conv(self, x, taps):
         return x.astype(jnp.float32)

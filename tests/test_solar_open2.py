@@ -29,6 +29,7 @@ from vllm_production_stack_tpu.models import llama
 from vllm_production_stack_tpu.models.loader import SolarOpen2CheckpointView
 from vllm_production_stack_tpu.models.registry import resolve_model_config
 from vllm_production_stack_tpu.ops import kda_attention as kda
+from vllm_production_stack_tpu.ops import kda_short_conv as ksc
 from vllm_production_stack_tpu.testing import reference_check
 from vllm_production_stack_tpu.testing import (
     reference_solar_open2 as reference,
@@ -237,12 +238,19 @@ def test_the_report_names_the_prefill_kernels_grid_step(cfg):
     assert report["block_tokens"] == kda.BLOCK == 64
     assert 1 < report["heads_per_step"] <= solar.linear_num_heads
     assert solar.linear_num_heads % report["heads_per_step"] == 0
+    # the pass that makes q, k, v beside it: one head's lanes x the chunk
+    assert report["qkv"] == {
+        "op": "kda_short_conv", "heads_per_tile": 1, "token_tile": 2048}
+    assert linear_backend_report(solar, "pallas", "pallas", 8192)[
+        "qkv"]["token_tile"] == 2048
     tiny = linear_backend_report(cfg, "pallas_interpret", "pallas_interpret")
     assert tiny["heads_per_step"] == kda.heads_per_step(
         cfg.linear_num_heads, cfg.linear_head_dim, 4) > 1
     one = linear_backend_report(
         dataclasses.replace(cfg, linear_num_heads=1), "pallas", "pallas")
     assert (one["heads_per_step"], one["block_tokens"]) == (1, 64)
+    assert tiny["qkv"]["heads_per_tile"] == ksc.heads_per_tile(
+        cfg.linear_num_heads, cfg.linear_head_dim)
     assert linear_backend_report(solar, "xla", "xla") == {
         "decode": "xla", "prefill": "xla"}
 

@@ -32,7 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import metrics_contract as mc
 from ..models import llama
-from ..ops import kda_attention, ssd_scan
+from ..ops import kda_attention, kda_short_conv, ssd_scan
 from ..ops.attention import gather_pages
 from ..ops.paged_attention_pallas import _pages_fill_tiles
 from ..utils.logging import init_logger
@@ -470,12 +470,15 @@ def _collect_logprobs(logits: jax.Array, tokens: jax.Array):
     return chosen, top_lp, top_id.astype(jnp.int32)
 
 
-def linear_backend_report(cfg, attention_backend: str,
-                          prefill_backend: str) -> dict:
+def linear_backend_report(cfg, attention_backend: str, prefill_backend: str,
+                          max_tokens: int = 2048) -> dict:
     """Which form the linear-attention (KDA) layers' programs hold, and for
     the chunked prefill kernel the shape of its grid step at this model's
     heads: `heads_per_step` heads x one row's part of a `block_tokens`
-    block (ops/kda_attention.py reads both off the shapes)."""
+    block (ops/kda_attention.py reads both off the shapes); `qkv`: the
+    pass that makes q, k and v beside it (ops/kda_short_conv.py) and a grid
+    step's tile, heads x tokens of a chunk of `max_tokens` positions (the
+    largest prefill bucket)."""
     report = {"decode": ("kda_decode_attention"
                          if attention_backend != "xla" else "xla"),
               "prefill": "xla"}
@@ -485,7 +488,11 @@ def linear_backend_report(cfg, attention_backend: str,
             heads_per_step=kda_attention.heads_per_step(
                 cfg.linear_num_heads, cfg.linear_head_dim,
                 jnp.dtype(cfg.dtype).itemsize),
-            block_tokens=kda_attention.BLOCK)
+            block_tokens=kda_attention.BLOCK,
+            qkv={"op": "kda_short_conv",
+                 "heads_per_tile": kda_short_conv.heads_per_tile(
+                     cfg.linear_num_heads, cfg.linear_head_dim),
+                 "token_tile": kda_short_conv.token_tile(max_tokens)})
     return report
 
 
@@ -1054,7 +1061,8 @@ class ModelRunner:
             }
             if cfg.linear_layers:
                 report["mixers"]["linear_backend"] = linear_backend_report(
-                    cfg, self._attention_backend, self._prefill_backend)
+                    cfg, self._attention_backend, self._prefill_backend,
+                    max(self.config.scheduler.prefill_buckets))
             if cfg.ssm_layers:
                 report["mixers"]["ssd_backend"] = ssd_backend_report(
                     cfg, self._attention_backend, self._prefill_backend)

@@ -38,6 +38,7 @@ from ..ops.kda_attention import (
     kda_prefill_attention,
     kda_prefill_xla,
 )
+from ..ops.kda_short_conv import kda_short_conv
 from ..ops.mla_attention import (
     latent_attention_with_staged,
     latent_attention_xla,
@@ -817,25 +818,22 @@ def _kda_attention(cfg: ModelConfig, ap: dict, norm: jax.Array,
     (N, 1, h) -> the block's output before the residual add. The tokens lie
     on the first axis (a packed chunk, or a decode step's rows); what a
     token needs of the tokens before it — the convolution's tail and the
-    state — `recur` brings: `recur.conv(x (N, 3 H D), taps)` returns the
-    causal depthwise convolution over each row's own tokens (float32), and
-    `recur.scan(q, k, v, g, beta)` runs the recurrence and returns o
-    (N, H, D) float32."""
+    state — `recur` brings: `recur.qkv((xq, xk, xv) (N, H D) each, taps, H,
+    D)` returns q, k and v (N, H, D) in the model's dtype — the causal
+    depthwise convolution of the three projections over each row's own
+    tokens, SiLU and the q / k L2 norms, in float32: XLA's, over
+    `recur.conv` of their concatenation, for a decode step and for a chunk
+    whose recurrence is XLA's; ONE Pallas pass (ops/kda_short_conv.py) for
+    a chunk that runs the Pallas recurrence — and `recur.scan(q, k, v, g,
+    beta)` runs the recurrence and returns o (N, H, D) float32."""
     n = x.shape[0]
     lh, ld = cfg.linear_num_heads, cfg.linear_head_dim
     f32 = jnp.float32
     with jax.named_scope("kda_qkv"):
         u = rms_norm(x[:, 0], norm, cfg.rms_norm_eps)
-        qkv = jnp.concatenate(
-            [_mm(u, ap["wq"]), _mm(u, ap["wk"]), _mm(u, ap["wv"])], axis=-1)
+        proj = (_mm(u, ap["wq"]), _mm(u, ap["wk"]), _mm(u, ap["wv"]))
     with jax.named_scope("kda_conv"):
-        qkv = jax.nn.silu(recur.conv(qkv, ap["conv"]))
-        q, k, v = (y.reshape(n, lh, ld) for y in jnp.split(qkv, 3, axis=-1))
-        # L2 norm over a head's dims (float32), q also times D^-0.5
-        q = q * jax.lax.rsqrt(
-            jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6) * ld ** -0.5
-        k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
-        q, k, v = (y.astype(x.dtype) for y in (q, k, v))
+        q, k, v = recur.qkv(proj, ap["conv"], lh, ld)
     with jax.named_scope("kda_gate"):
         # (the gates' projections: low-rank pairs, or full rank)
         a = (_mm(_mm(u, ap["wf_a"]), ap["wf_b"]) if "wf_a" in ap
@@ -930,6 +928,21 @@ class _Recurrence:
     def __init__(self, pools: dict, read, write, tap=None):
         self.pools, self.read, self.write, self.tap = pools, read, write, tap
 
+    def qkv(self, proj: tuple, taps, heads: int, d: int):
+        """A KDA layer's q, k, v (N, H, D) in the projections' dtype from
+        its three projections (N, H D) each: `self.conv` of their
+        concatenation, SiLU, the split and the L2 norm over a head's dims,
+        in float32 — the plain form."""
+        x = jnp.concatenate(proj, axis=-1)
+        n = x.shape[0]
+        y = jax.nn.silu(self.conv(x, taps))
+        q, k, v = (z.reshape(n, heads, d) for z in jnp.split(y, 3, axis=-1))
+        # q also times D^-0.5
+        q = q * jax.lax.rsqrt(
+            jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6) * d ** -0.5
+        k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
+        return tuple(z.astype(x.dtype) for z in (q, k, v))
+
 
 class _PackedRecurrence(_Recurrence):
     """A packed prefill chunk: row i's tokens at [row_off[i], row_off[i] +
@@ -941,13 +954,50 @@ class _PackedRecurrence(_Recurrence):
         super().__init__(pools, st["read"], st["write"], st["tap"])
         self.st, self.rows = st, rows
 
+    def _old_tail(self):
+        """The rows' tails as the chunk finds them, (B, K - 1, C): zeros
+        for a fresh row, whatever its slot held."""
+        return jnp.where(self.st["fresh"][:, None, None], 0,
+                         self.pools["conv"][self.read])
+
+    def _tail_terms(self, tail, taps, n: int):
+        """What the tails add to their rows' first K - 1 tokens, a token
+        row each (B (K - 1), C) float32, and the packed position of each
+        (`n`: the row has no such token), then the index K - 1 long that
+        `_keep_tail` goes on with. `taps` (K, C) float32."""
+        kk = taps.shape[0]
+        off, lens = self.st["row_off"], self.st["chunk_lens"]
+        tail_f = tail.astype(jnp.float32)
+        corr = jnp.stack([
+            sum(tail_f[:, kk - 1 + jj - d] * taps[kk - 1 - d]
+                for d in range(jj + 1, kk))
+            for jj in range(kk - 1)], axis=1)  # (B, kk - 1, C)
+        jj = jnp.arange(kk - 1, dtype=jnp.int32)[None]
+        at = jnp.where(jj < lens[:, None], off[:, None] + jj, n)
+        at = at.reshape(-1)
+        return corr.reshape(-1, corr.shape[-1]), at, jj
+
+    def _keep_tail(self, rows_of_x, n: int, tail, jj):
+        """The layer's `conv` pool takes the rows' new tails: the last
+        K - 1 of [old tail | the row's tokens]; `rows_of_x(index)`: those
+        token rows of the packed (n, C) input."""
+        km = tail.shape[1]
+        off, lens = self.st["row_off"], self.st["chunk_lens"]
+        back = lens[:, None] - km + jj  # in-row index, < 0: old tail
+        new = jnp.where(
+            (back >= 0)[..., None],
+            rows_of_x(jnp.clip(off[:, None] + back, 0, n - 1)),
+            jnp.take_along_axis(
+                tail, jnp.clip(km + back, 0, km - 1)[..., None], axis=1))
+        self.pools = {**self.pools, "conv": self.pools["conv"].at[
+            self.write].set(new.astype(self.pools["conv"].dtype))}
+
     def conv(self, x, taps):
         st = self.st
         n, kk = x.shape[0], taps.shape[0]
-        off, lens = st["row_off"], st["chunk_lens"]
-        tail = jnp.where(st["fresh"][:, None, None], 0,
-                         self.pools["conv"][self.read])  # (B, kk - 1, C)
-        j = (jnp.arange(n, dtype=jnp.int32) - off[st["tok_row"]])[:, None]
+        tail = self._old_tail()
+        j = (jnp.arange(n, dtype=jnp.int32)
+             - st["row_off"][st["tok_row"]])[:, None]
         xf, taps = x.astype(jnp.float32), taps.astype(jnp.float32)
         y = xf * taps[kk - 1]
         for d in range(1, kk):
@@ -956,25 +1006,27 @@ class _PackedRecurrence(_Recurrence):
             prev = jnp.concatenate([jnp.zeros_like(xf[:d]), xf[:-d]], axis=0)
             y = y + jnp.where(j >= d, prev, 0) * taps[kk - 1 - d]
         # a row's first kk - 1 tokens also read its tail
-        tail_f = tail.astype(jnp.float32)
-        corr = jnp.stack([
-            sum(tail_f[:, kk - 1 + jj - d] * taps[kk - 1 - d]
-                for d in range(jj + 1, kk))
-            for jj in range(kk - 1)], axis=1)  # (B, kk - 1, C)
-        jj = jnp.arange(kk - 1, dtype=jnp.int32)[None]
-        at = jnp.where(jj < lens[:, None], off[:, None] + jj, n)
-        y = y.at[at.reshape(-1)].add(
-            corr.reshape(-1, corr.shape[-1]), mode="drop")
-        # the new tail: the last kk - 1 of [old tail | the row's tokens]
-        back = lens[:, None] - (kk - 1) + jj  # in-row index, < 0: old tail
-        new = jnp.where(
-            (back >= 0)[..., None],
-            x[jnp.clip(off[:, None] + back, 0, n - 1)],
-            jnp.take_along_axis(
-                tail, jnp.clip(kk - 1 + back, 0, kk - 2)[..., None], axis=1))
-        self.pools = {**self.pools, "conv": self.pools["conv"].at[
-            self.write].set(new.astype(self.pools["conv"].dtype))}
+        corr, at, jj = self._tail_terms(tail, taps, n)
+        y = y.at[at].add(corr, mode="drop")
+        self._keep_tail(lambda at: x[at], n, tail, jj)
         return y
+
+    def qkv(self, proj, taps, heads, d):
+        st = self.st
+        if st["interpret"] is None:
+            return super().qkv(proj, taps, heads, d)
+        # beside the Pallas recurrence: the same sums in ONE pass over the
+        # projections as the matmuls wrote them (nothing concatenates them)
+        n = proj[0].shape[0]
+        tail = self._old_tail()
+        corr, at, jj = self._tail_terms(tail, taps.astype(jnp.float32), n)
+        self._keep_tail(
+            lambda at: jnp.concatenate([x[at] for x in proj], axis=-1),
+            n, tail, jj)
+        return tuple(
+            z.reshape(n, heads, d) for z in kda_short_conv(
+                *proj, taps, corr, at, st["row_off"], heads=heads,
+                interpret=st["interpret"]))
 
     def scan(self, q, k, v, g, beta):
         st, rows = self.st, self.rows
