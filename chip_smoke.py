@@ -29,6 +29,10 @@ engine's logprobs against its log-softmax. A second arm with an fp8 KV pool
 has to FAIL the same tolerance (`--fp8-arm 0` leaves it out). `--rehearse`
 runs it at the configuration's tiny size on the CPU.
 
+`--child batch_probe --config benchmarks/chip/configs/<name>.json` runs ONLY
+the batch-invariance probe of a model that selects its keys, with a prompt
+of twice its top-k (`child_batch_probe`).
+
 `--chips 4` runs ONLY the four-chip comparison: the same engine at tp=1 on
 the first device, then, after it has exited, at --tensor-parallel-size 4 —
 same prompts, greedy, logprobs on.
@@ -305,6 +309,68 @@ REFERENCE_ATOL_SDAR = 0.09
 # alone tells it); no commit pass 0.028-0.036. 1.8 x over the one, 1.7 x
 # under the other.
 REFERENCE_MEAN_ATOL_SDAR = 0.012
+
+
+# GLM-5.2 at 7 layers (bf16 weights, activations, latents and index keys,
+# ops/dsa_attention.py's selection) against reference/glm_dsa.py (float32,
+# expanded form), which FOLLOWS the served programs' routing as the MLA
+# comparison does AND the key positions they selected
+# (reference_check.SelectionTap): a bf16 index score flips the 2,048th and
+# the 2,049th key as a bf16 router flips a boundary expert, and a
+# free-running comparison would measure the flips. Each limit lies between
+# its readings on the chip at the published widths (PR 55, PERF.md §6): what
+# the served configuration gave over its seeds, and what has to come out as
+# not correct — the reference wrong in one way each (GLM_DSA_ARMS), on the
+# prompt of 2.5 x the top-k. The readings below are this child's own, as
+# committed, on seeds 3100000019 and 3100000073 (both exit 0; five prompts
+# of 4.2k-16.3k tokens in flight together, 160 positions, 1,280 logprobs a
+# seed); two earlier runs of six prompts (3100000019, 3100000033) and a
+# scratch run of one 5,200-token prompt (3100000047) read alike.
+# Served: max |dlogprob| 0.0875, 0.1078 (earlier 0.0847, 0.1001, 0.0740);
+# mean 0.0213, 0.0219 — the Kanana arm's rounding (0.066 at 8 layers of 32
+# heads) at 64 heads and hidden 6,144. What has to come out as not correct
+# by these two: a borrowing layer handed the selection of the full layer
+# before the right one — 0.226 / 0.056 and 0.270 / 0.057 (0.2035 / 0.0512 on
+# the scratch prompt). On the SHORTEST prompt of the earlier runs, 2.1-2.4k
+# tokens, where nearly every key is selected, it read 0.108 / 0.030 and
+# 0.096 / 0.024: no limit can tell it there, which is why the arms are held
+# to a prompt of 2.5 x the top-k.
+REFERENCE_ATOL_GLM_DSA = 0.15
+REFERENCE_MEAN_ATOL_GLM_DSA = 0.035
+# the served expert choice differed from the reference's own in 11.9% /
+# 11.6% of (token, layer) pairs (top-8 of 256: more boundary pairs than
+# Kanana's top-6 of 128), the served set's worst expert within 0.0097 /
+# 0.0099 of the reference's 8th best biased score (0.0102 earlier); the
+# wrong lender 0.030 / 0.023
+ROUTING_DISAGREEMENT_MAX_GLM_DSA = 0.20
+ROUTING_MARGIN_MAX_GLM_DSA = 0.02
+# the served (token, slot) choices outside the reference's own top-2,048:
+# 0.67% / 0.59% of 187 M / 147 M slots (bf16 scores AND the bf16 hidden state
+# under them flip keys near the 2,048th), 0.33% on the arms' prompt. There
+# the ReLU left out reads 10.3% / 10.5%, the heads' weights left out 29.5% /
+# 29.8%, the last 2,048 keys 29.5% / 29.2%, top-1,024 43.8% / 43.8%, the key
+# un-normed 3.7% / 3.6%. How far the worst served slot lies under the
+# reference's 2,048th best score, in standard deviations of the scores:
+# 0.27 / 0.25 (0.92 / 0.63 in the earlier six-prompt runs: a MAXIMUM over
+# 2e8 slots of what four layers of bf16 activations move a score by, so a
+# wide limit); the ReLU 3.0 / 3.1, the weights 3.8 / 4.1, top-1,024 5.3 /
+# 5.4; the key un-normed 1.42 / 1.19 is inside: the share tells it.
+# Index keys rounded to float8 read 0.73% / 0.73% and 0.45 / 0.44: INSIDE
+# both — the pages' limit below tells that arm. (The FIRST layer's share
+# alone, reported beside the whole and not limited yet: served 0.23% / 0.20%,
+# 0.12% on the arms' prompt, where float8 keys read 0.63% / 0.62%.)
+SELECTION_DISAGREEMENT_MAX_GLM_DSA = 0.02
+SELECTION_MARGIN_SPREADS_MAX_GLM_DSA = 1.5
+# the index keys the first layer holds (bf16) against the reference's
+# float32 ones: 0.0031 on every seed (a bf16 key's rounding); against the
+# keys a float8 cache would hold — the nearest precision below the
+# configuration's, the `key_dtype` arm — 0.0267 / 0.0267 (a float8 key's
+# rounding; 0.0256 in float32 on the CPU at tiny size)
+INDEX_PAGES_RTOL_GLM_DSA = 1e-2
+GLM_DSA_ARMS = (
+    {"relu": False}, {"head_weights": False}, {"last_k": True},
+    {"topk_share": 0.5}, {"key_norm": False},
+    {"key_dtype": "float8_e4m3fn"}, {"borrow_lag": True})
 
 
 def engine_flags(model: str, seed: int, tp: int = 1,
@@ -1053,7 +1119,8 @@ def child_reference(args) -> int:
     # (KDA layers over state slots between gated NoPE GQA layers)
     family = {"deepseek_v3": "mla", "laguna": "laguna",
               "solar_open2": "solar_open2", "sdar_moe": "sdar",
-              "ling_flash": "ling", "falcon_h1": "falcon_h1"}.get(
+              "ling_flash": "ling", "falcon_h1": "falcon_h1",
+              "glm_moe_dsa": "glm_dsa"}.get(
         config.get("model_type"), "decoder")
     # (the two families whose reference follows the served routing and
     # whose cell is the long-document one)
@@ -1067,6 +1134,8 @@ def child_reference(args) -> int:
     spec.loader.exec_module(reference)
     if family == "sdar":
         return _child_reference_sdar(args, config, reference)
+    if family == "glm_dsa":
+        return _child_reference_glm_dsa(args, config, reference)
     # (max |Δlogprob|, mean |Δlogprob| or None, routing disagreement share,
     # routing margin where differing): each family's limits, set above
     atol, mean_atol, disagreement_max, margin_max = {
@@ -1300,6 +1369,233 @@ def child_reference(args) -> int:
     return 0 if report["ok"] else 1
 
 
+def _child_reference_glm_dsa(args, config: dict, reference) -> int:
+    """`child_reference` for a model whose latent layers attend over
+    SELECTED keys: prompts of the long-document cell's lengths (every one
+    longer than the top-k) in flight together, the engine's logprobs against
+    `reference.forward` FOLLOWING the served routing and selection, the
+    selection's own two limits, the first layer's index keys, and the served
+    numbers held against references that are wrong in one way each
+    (GLM_DSA_ARMS, on ONE prompt of 2.5 x the top-k): exit 0 only with the
+    served arm inside every limit and each faulty one outside at least one."""
+    import jax
+    import numpy as np
+
+    from vllm_production_stack_tpu.engine.engine import LLMEngine
+    from vllm_production_stack_tpu.engine.server import (
+        build_parser, engine_config_from_args,
+    )
+    from vllm_production_stack_tpu.testing import reference_check
+    from vllm_production_stack_tpu.utils.compile_cache import (
+        configure_compile_cache,
+    )
+
+    flags = (config["rehearse"] if args.rehearse else config)["engine_flags"]
+    dev = jax.devices()[0]
+    limits = {
+        "atol": REFERENCE_ATOL_GLM_DSA,
+        "mean_atol": REFERENCE_MEAN_ATOL_GLM_DSA,
+        "routing_disagreement_max": ROUTING_DISAGREEMENT_MAX_GLM_DSA,
+        "routing_margin_max": ROUTING_MARGIN_MAX_GLM_DSA,
+        "selection_disagreement_max": SELECTION_DISAGREEMENT_MAX_GLM_DSA,
+        "selection_margin_spreads_max": SELECTION_MARGIN_SPREADS_MAX_GLM_DSA,
+        "index_pages_rtol": INDEX_PAGES_RTOL_GLM_DSA}
+    report: dict = {"phase": "reference", "config": config["name"],
+                    "platform": dev.platform, "kind": dev.device_kind,
+                    "seed": args.seed, **limits}
+    if dev.platform != "tpu" and not args.rehearse:
+        report.update(ok=False, error=f"platform is {dev.platform!r}")
+        emit(report)
+        return 1
+    configure_compile_cache()
+    rng = np.random.RandomState(args.seed % (2 ** 31))
+    ecfg = engine_config_from_args(build_parser().parse_args(
+        [*flags, "--seed", str(args.seed % (2 ** 31))]))
+    model = ecfg.model
+    # the long-document cell's range (the tiny preset: its rehearse mix's),
+    # every prompt longer than the top-k; one at the top, one past a chunk
+    # edge, two log-uniform
+    lo, hi, n_out, n_prompts = ((48, 320, 8, 5) if args.rehearse
+                                else (2049, 16320, 32, 5))
+    assert lo > model.index_topk
+    chunk = ecfg.scheduler.max_num_batched_tokens
+    lengths = np.exp(
+        rng.uniform(np.log(lo), np.log(hi), size=n_prompts)).astype(int)
+    # ... and one of 2.5 x the top-k, which the faulty references are held
+    # to: three fifths of its queries select among more keys than they keep.
+    # NOT the shortest, as the other families' arms: at 2.1-2.4k tokens
+    # nearly every key is selected and no indexer fault can show (the first
+    # two chip runs of PR 55 read 0.0005-0.019 of slots there)
+    held = 2
+    lengths[:3] = [hi, (chunk + hi) // 2, max(lo, int(2.5 * model.index_topk))]
+    report["prompt_lengths"] = [int(n) for n in lengths]
+    prompts = [list(map(int, rng.randint(1, model.vocab_size, size=n)))
+               for n in lengths]
+    t0 = time.monotonic()
+    with (reference_check.RoutingTap() as routing,
+          reference_check.SelectionTap() as selection):
+        engine = LLMEngine(ecfg)
+        device = engine.runner.device_report()
+        served_rows = reference_check.served_logprobs(engine, prompts, n_out)
+    index_pool = np.asarray(engine.runner.kv_caches[0]["idx"])
+    served: dict = {"attention": device.get("attention"),
+                    "moe": device.get("moe"), "mixers": device.get("mixers"),
+                    "engine_s": round(time.monotonic() - t0, 1)}
+    print(f"reference check: served in {served['engine_s']} s",
+          file=sys.stderr, flush=True)
+    # the reference needs the weights as served and room for one layer in
+    # float32: let the pool and the programs go
+    params = engine.runner.params
+    engine.runner.shutdown()
+    engine.runner.kv_caches = None
+    del engine
+    t0 = time.monotonic()
+
+    def compare(rows_p, rows_s, arms=None):
+        return reference_check.compare(
+            reference, model, params, rows_p, rows_s, served_routing=routing,
+            served_selection=selection, reference_arms=arms)
+
+    served.update(compare(prompts, served_rows))
+    served["index_pages"] = reference_check.served_index_pages(
+        reference, model, params, index_pool, prompts, served_rows)
+    served["reference_s"] = round(time.monotonic() - t0, 1)
+    print(f"reference check: compared in {served['reference_s']} s",
+          file=sys.stderr, flush=True)
+    report["served"] = served
+
+    def outside(arm: dict) -> list[str]:
+        """The limits an arm's readings lie outside."""
+        held = {
+            "max_abs_dlogprob": limits["atol"],
+            "mean_abs_dlogprob": limits["mean_atol"],
+            "routing_disagreement_share": limits["routing_disagreement_max"],
+            "routing_margin_max_where_differing": limits[
+                "routing_margin_max"],
+            "selection_disagreement_share": limits[
+                "selection_disagreement_max"],
+            "selection_margin_max_in_spreads": limits[
+                "selection_margin_spreads_max"]}
+        out = [k for k, lim in held.items() if arm[k] > lim]
+        if "index_pages" in arm and (arm["index_pages"]["index_pages_rel_err"]
+                                     > limits["index_pages_rtol"]):
+            out.append("index_pages_rel_err")
+        return out
+
+    t0 = time.monotonic()
+    report["faults_on_prompt"] = int(lengths[held])
+    report["faults"] = {}
+    for arms in GLM_DSA_ARMS:
+        arms = dict(arms)
+        if "topk_share" in arms:  # (top-1,024 at the published top-2,048)
+            arms = {"topk": int(model.index_topk * arms["topk_share"])}
+        got = compare([prompts[held]], [served_rows[held]], arms)
+        keep = {k: got[k] for k in (
+            "max_abs_dlogprob", "mean_abs_dlogprob",
+            "routing_disagreement_share",
+            "routing_margin_max_where_differing",
+            "selection_disagreement_share", "selection_margin_max_in_spreads",
+            "selection_first_layer_disagreement_share")}
+        if "key_dtype" in arms:
+            # (a cache of a lower precision: the served pages against the
+            # keys such a cache would hold)
+            keep["index_pages"] = reference_check.served_index_pages(
+                reference, model, params, index_pool, [prompts[held]],
+                [served_rows[held]], key_dtype=arms["key_dtype"])
+        keep["outside"] = outside(keep)
+        report["faults"]["-".join(f"{k}={v}" for k, v in arms.items())] = keep
+    report["faults_s"] = round(time.monotonic() - t0, 1)
+    report["served"]["outside"] = outside(served)
+    # on the CPU the limits are the chip's bf16 ones: float32 lies inside
+    # them by orders, and so may a fault (`borrow_lag` at tiny size)
+    report["ok"] = bool(not report["served"]["outside"] and all(
+        f["outside"] for f in report["faults"].values()))
+    emit(report)
+    return 0 if report["ok"] else 1
+
+
+def child_batch_probe(args) -> int:
+    """Batch invariance with a prompt LONGER than the top-k of a model that
+    selects its keys (`--config`; benchmarks/chip/run.py's own probe is 600
+    tokens: under GLM-5.2's top-2,048 every key is selected there, so a
+    selection that moves with the batch would pass it). The configuration's
+    engine as ONE OS process, prefix caching OFF so that the second pass
+    computes every chunk again: a prompt of twice the top-k alone, then with
+    fifteen long requests holding the other seats; the first token's
+    top-8 logprobs are held as the four-chip comparison's are. This process
+    imports no JAX."""
+    with open(os.path.join(REPO, args.config)) as f:
+        config = json.load(f)
+    at = config["rehearse"] if args.rehearse else config
+    # (tokens = characters under the presets' byte tokenizer; the tiny
+    # preset's top-k is 16 and its contexts end at 512)
+    n_probe, hold, step, n_out = ((128, 160, 8, 200) if args.rehearse
+                                  else (4096, 6000, 400, 1024))
+    port = free_port()
+    url = f"http://127.0.0.1:{port}"
+    fails = Failures()
+    report: dict = {"phase": "batch_probe", "config": config["name"],
+                    "seed": args.seed, "probe_tokens": n_probe,
+                    "logprob_atol": LOGPROB_ATOL,
+                    "min_shared_top8": MIN_SHARED_TOP8}
+    engine = Child("engine_batch_probe", [
+        "-m", "vllm_production_stack_tpu.engine.server", "--port", str(port),
+        "--host", "127.0.0.1", *at["engine_flags"], "--seed",
+        str(args.seed % (2 ** 31)), "--no-enable-prefix-caching"])
+    try:
+        engine.wait_healthy(url, 1500)
+        report["platform"] = http_json(
+            "GET", url + "/debug/programs")["device"]["platform"]
+        probe = filler(n_probe, salt=7)
+
+        def first_top8() -> dict:
+            out = completion(url, at["model"], probe, 1, logprobs=8)
+            return out["choices"][0]["logprobs"]["top_logprobs"][0]
+
+        alone = first_top8()
+        holders = [threading.Thread(
+            target=completion, daemon=True, args=(
+                url, at["model"], filler(hold + step * i, salt=100 + i),
+                n_out)) for i in range(15)]
+        for t in holders:
+            t.start()
+
+        def in_flight() -> float:
+            seen = scrape(url)
+            return sum(metric_sum(seen, "tpu:num_requests_" + state)
+                       for state in ("running", "waiting"))
+
+        # the probe arrives behind all fifteen and is prefilled and decoded
+        # while they hold their seats (their answers outlast its prompt)
+        deadline = time.monotonic() + 300
+        while in_flight() < 15:
+            if time.monotonic() > deadline:
+                raise TimeoutError("fifteen requests never were in flight")
+            time.sleep(0.02)
+        crowded = first_top8()
+        report["in_flight_after_probe"] = in_flight()
+        for t in holders:
+            t.join(timeout=600)
+        common = set(alone) & set(crowded)
+        worst = max((abs(alone[t] - crowded[t]) for t in common),
+                    default=float("inf"))
+        report.update(shared_top8=len(common), max_abs_diff=worst)
+        fails.check(report["in_flight_after_probe"] >= 1,
+                    "the other seats had emptied before the probe returned")
+        fails.check(len(common) >= MIN_SHARED_TOP8 and worst <= LOGPROB_ATOL,
+                    f"the first token moves with the batch: {len(common)}/8 "
+                    f"shared, max |dlogprob| {worst}")
+        fails.check(report["platform"] == "tpu" or args.rehearse,
+                    f"platform is {report['platform']!r}")
+    except Exception as e:
+        fails.add(f"{type(e).__name__}: {e}"[:1500])
+    finally:
+        engine.stop(fails)
+    report.update(ok=not fails.items, failures=fails.items)
+    emit(report)
+    return 0 if report["ok"] else 1
+
+
 def _child_reference_sdar(args, config: dict, reference) -> int:
     """`child_reference` for a model that generates by diffusion over blocks:
     16 rows in flight, prompts of the cell's lengths, the engine's own
@@ -1489,12 +1785,15 @@ def main() -> int:
     p.add_argument("--model", default="qwen3-8b",
                    help="registry preset (tests pass tiny-llama)")
     p.add_argument("--seed", type=int, default=0)
-    p.add_argument("--child", default=None, choices=["kernels", "reference"],
+    p.add_argument("--child", default=None,
+                   choices=["kernels", "reference", "batch_probe"],
                    help=argparse.SUPPRESS)
     p.add_argument("--config", default=REFERENCE_CONFIG,
-                   help="--child reference: the benchmark configuration")
+                   help="--child reference, batch_probe: the benchmark "
+                        "configuration")
     p.add_argument("--rehearse", action="store_true",
-                   help="--child reference: tiny size, any platform")
+                   help="--child reference, batch_probe: tiny size, any "
+                        "platform")
     p.add_argument("--fp8-arm", type=int, default=1, choices=[0, 1],
                    help="--child reference: 0 skips the fp8-pool arm (more "
                         "seeds of the served arm alone)")
@@ -1503,6 +1802,8 @@ def main() -> int:
         return child_kernels(args.seed)
     if args.child == "reference":
         return child_reference(args)
+    if args.child == "batch_probe":
+        return child_batch_probe(args)
 
     fails = Failures()
     device = None

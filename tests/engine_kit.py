@@ -15,7 +15,7 @@ hand over the parameters the module's first engine of that (model, seed)
 drew, and the first reuse draws its own and is held to the same bytes.
 
 What may NOT share, and builds with `fresh_engine`: a test that traces under
-a tap (llama.ROUTING_TAP, STATE_TAP, BD_TAP) or under a `monkeypatch`ed
+a tap (llama.ROUTING_TAP, STATE_TAP, BD_TAP, SELECTION_TAP) or under a `monkeypatch`ed
 constant or function (llama._ALL_EXPERTS_MAX_TOKENS,
 model_runner._BD_COMMIT_PASS, a faulty `bd_transfer`): a shared engine traces
 its programs lazily, and one traced there would carry the edit to every later
@@ -153,7 +153,7 @@ def undrained(engine) -> str:
 _TRACED_UNDER = {(mod, name): getattr(mod, name) for mod, name in (
     (llama, "_ALL_EXPERTS_MAX_TOKENS"), (model_runner, "_BD_COMMIT_PASS"),
     (llama, "ROUTING_TAP"), (llama, "STATE_TAP"), (llama, "BD_TAP"),
-    (llama, "ROUTER_TAP"))}
+    (llama, "ROUTER_TAP"), (llama, "SELECTION_TAP"))}
 
 
 class EngineKit:

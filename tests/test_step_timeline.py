@@ -38,7 +38,8 @@ STEP_PHASES = ("sched", "build", "h2d", "enqueue", "sync", "post")
 # (metrics_contract.DEVICE_SCOPES): all but the other mixers', the experts',
 # the output gate's and block diffusion's; a decode window alone commits its
 # staged K/V and (XLA attention) gathers its pages
-_NOT_DENSE = ("mla_", "kda_", "ssm_", "moe_", "h1_", "bd_", "attn_gate")
+_NOT_DENSE = ("mla_", "kda_", "ssm_", "moe_", "h1_", "bd_", "dsa_",
+              "attn_gate")
 DECODE_SCOPES = {s for s in DEVICE_SCOPES if not s.startswith(_NOT_DENSE)}
 PREFILL_SCOPES = DECODE_SCOPES - {"kv_commit", "gather_pages"}
 
@@ -265,7 +266,8 @@ def test_moe_read_share_from_its_data_file(start, end, want):
             "kanana-2-30b-a3b-bf16-8l.longdoc-saturated",
             "laguna-s21-bf16-ep4-9l.longdoc-saturated",
             "solar-open2-bf16-ep8-8l.longdoc-saturated",
-            "sdar-30b-a3b-bf16-8l.chat-saturated"]}
+            "sdar-30b-a3b-bf16-8l.chat-saturated",
+            "glm-5.2-bf16-ep16-7l.longdoc-saturated"]}
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         cells = {w["name"] for w in json.load(f)["workloads"]}

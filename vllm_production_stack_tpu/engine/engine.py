@@ -63,6 +63,7 @@ class EngineStatsSnapshot:
     state_slots_in_use: int = 0
     state_share: float = 0.0
     latent_bytes: int = 0  # latent pages in use x their bytes
+    index_bytes: int = 0  # ... x their index keys' bytes (learned selection)
     prefix_cache_hit_rate: float = 0.0
     prefix_cache_hits: int = 0
     prefix_cache_queries: int = 0
@@ -1463,6 +1464,7 @@ class LLMEngine:
                         if self.config.model.layer_specs[i].holds_pages))):
                 if layers:
                     rec.count(name, passes * len(layers))
+            self._count_selected(work, kind, passes)
             if kind == "prefill" and self.runner.prefill_uses_kernel:
                 rec.count("prefill_kernel_n")
             self.scheduler.end_speculative(work)
@@ -1546,6 +1548,42 @@ class LLMEngine:
             rec.poll()
             self._emit_results(results, handle.logprob_rows, outputs)
 
+    def _count_selected(self, work, kind: str, passes: int) -> None:
+        """metrics_contract.DEBUG_TIMING_DSA_COUNTS of one dispatch, from
+        the lengths the host knows (a model without learned sparse
+        attention counts none): a query at position p sees p + 1 keys, an
+        indexer scores them all, and every layer that attends under a
+        selection attends min(p + 1, index_topk) of them."""
+        model = self.config.model
+        if not model.selecting_layers:
+            return
+        rec, k = self.flightrec, model.index_topk
+        full, every = len(model.index_layers), len(model.selecting_layers)
+        rec.count("dsa_full_layers_n", passes * full)
+        rec.count("dsa_shared_layers_n", passes * (every - full))
+        if kind == "prefill":
+            spans = [(int(p[0]), len(p)) for p in work.positions if len(p)]
+        else:
+            spans = [(int(p), work.window) for p in work.positions]
+            rec.count("dsa_decode_rows_n", len(spans) * work.window)
+        def keys_seen(first, n):
+            return n * first + n * (n + 1) // 2
+
+        visible = sum(keys_seen(first, n) for first, n in spans)
+        # (the first `under` queries of a span see no more than k keys)
+        selected = sum(
+            keys_seen(first, under) + (n - under) * k
+            for first, n in spans
+            for under in (max(0, min(n, k - first)),))
+        rec.count("dsa_index_pairs_n", full * visible)
+        rec.count("dsa_visible_keys_n", every * visible)
+        rec.count("dsa_selected_keys_n", every * selected)
+        if kind != "prefill":
+            # (the decode windows' part of the two, which the benchmark's
+            # rooflines set against the windows' device time)
+            rec.count("dsa_decode_index_pairs_n", full * visible)
+            rec.count("dsa_decode_selected_keys_n", every * selected)
+
     def _state_stats(self) -> dict:
         """The state slots' part of a stats snapshot (empty for a model no
         layer of which holds state): a slot in use holds its bytes in the
@@ -1555,13 +1593,19 @@ class LLMEngine:
         cfg = self.config
         if pool.state is None and not cfg.model.latent_layers:
             return {}
-        from .memory import kv_block_bytes, state_slot_bytes
+        from .memory import (
+            index_block_bytes, kv_block_bytes, state_slot_bytes)
 
-        pages = (pool.num_usable - pool.num_free) * kv_block_bytes(
-            cfg.model, cfg.cache.block_size,
-            kv_dtype=cfg.cache.resolved_kv_dtype(cfg.model.dtype))
-        # (a model's pages are all latents or none is)
-        out = {"latent_bytes": pages} if cfg.model.latent_layers else {}
+        held = pool.num_usable - pool.num_free
+        kv_dtype = cfg.cache.resolved_kv_dtype(cfg.model.dtype)
+        pages = held * kv_block_bytes(
+            cfg.model, cfg.cache.block_size, kv_dtype=kv_dtype)
+        # (a model's pages are all latents or none is; the index keys of
+        # the layers that own an indexer are counted apart)
+        index = held * index_block_bytes(
+            cfg.model, cfg.cache.block_size, kv_dtype)
+        out = ({"latent_bytes": pages - index, "index_bytes": index}
+               if cfg.model.latent_layers else {})
         if pool.state is None:
             return out
         used = pool.state.num_in_use

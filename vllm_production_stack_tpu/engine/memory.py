@@ -77,12 +77,32 @@ def layer_matrix_params(cfg: ModelConfig) -> dict:
     }
 
 
+def indexer_params(cfg: ModelConfig) -> int:
+    """Parameters of ONE indexer (a layer with LayerSpec.indexer "full";
+    models/llama.py "attn_index"): W_q_b from the query latent, W_k and its
+    LayerNorm's weight and bias, the heads' weight projection."""
+    hi, di = cfg.index_n_heads, cfg.index_head_dim
+    return (cfg.q_lora_rank * hi * di + cfg.hidden_size * di + 2 * di
+            + cfg.hidden_size * hi)
+
+
+def index_block_bytes(cfg: ModelConfig, block_size: int,
+                      kv_dtype: str | None = None) -> int:
+    """Bytes of ONE pool block's index keys: one key of `index_head_dim`
+    values a token and layer that owns an indexer (the pool's second leaf;
+    0 for a model without)."""
+    return (len(cfg.index_layers) * block_size * cfg.index_head_dim
+            * dtype_bytes(kv_dtype or cfg.dtype))
+
+
 def latent_attn_params(cfg: ModelConfig, num_heads: int) -> int:
-    """Matrix parameters of ONE latent layer's attention: W_q, W_kv_a,
-    W_kv_b, W_o."""
+    """Matrix parameters of ONE latent layer's attention: W_q (or, with a
+    query latent, W_q_a, its norm and W_q_b), W_kv_a, W_kv_b, W_o."""
     h = cfg.hidden_size
+    qk = num_heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    rq = cfg.q_lora_rank
     return (
-        h * num_heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)  # W_q
+        (h * rq + rq + rq * qk if rq else h * qk)  # W_q
         + h * cfg.latent_dim  # W_kv_a
         + cfg.kv_lora_rank * num_heads * (
             cfg.qk_nope_head_dim + cfg.v_head_dim)  # W_kv_b
@@ -109,9 +129,10 @@ def param_bytes(cfg: ModelConfig, tp: int = 1, pp: int = 1,
         total = (
             cfg.vocab_size * h * (1 if cfg.tie_word_embeddings else 2) + h
             + L * (m["attn"] + 2 * h + cfg.kv_lora_rank)
+            + len(cfg.index_layers) * indexer_params(cfg)
             + m["dense_layers"] * m["dense_mlp"]
             + m["moe_layers"] * (
-                cfg.num_experts * m["expert"] + m["shared"] + m["router"])
+                cfg.held_experts * m["expert"] + m["shared"] + m["router"])
         ) * dtype_bytes(cfg.dtype)
         # the selection bias is float32 whatever the model's dtype
         return total + m["moe_layers"] * cfg.num_experts * 4
@@ -198,9 +219,11 @@ def kv_block_bytes(cfg: ModelConfig, block_size: int, tp: int = 1,
         # stored (the zero padding to whole lane tiles is real HBM, so it
         # counts); a model's pages are all latents or none is, and a layer
         # that holds state has none
+        # ... and one index key a token and layer that owns an indexer
         return max(1, (
             len(cfg.latent_layers) * block_size * cfg.latent_width
             * dtype_bytes(kv_dtype or cfg.dtype)
+            + index_block_bytes(cfg, block_size, kv_dtype)
         ) // pp)
     kvh = max(1, cfg.num_kv_heads // tp)
     # (a layer that holds state has no pages: LayerSpec.holds_pages)

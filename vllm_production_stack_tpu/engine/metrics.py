@@ -202,6 +202,11 @@ class EngineMetrics:
             mc.HBM_LATENT_BYTES,
             "Bytes of latent pages in use (latent layers' pages held)",
         )
+        self.index_bytes = gauge(
+            mc.HBM_INDEX_BYTES,
+            "Bytes of index keys in use (beside the latents of the layers "
+            "that own an indexer)",
+        )
         self.block_diffusion = {
             key: counter(name, f"Generation by diffusion over blocks: {key}")
             for key, name in mc.DEBUG_TIMING_BD_COUNTS.items()
@@ -698,6 +703,7 @@ class EngineMetrics:
         self.state_slots_in_use.labels(**lb).set(s.state_slots_in_use)
         self.state_share.labels(**lb).set(s.state_share)
         self.latent_bytes.labels(**lb).set(s.latent_bytes)
+        self.index_bytes.labels(**lb).set(s.index_bytes)
         for key, metric in self.block_diffusion.items():
             self._bump(metric, key, s.block_diffusion.get(key, 0))
         self.prefix_hit_rate.labels(**lb).set(s.prefix_cache_hit_rate)

@@ -150,8 +150,10 @@ def _refuse_unbuilt_for_latent_attention(config: EngineConfig) -> None:
     """A model with latent layers (LayerSpec.latent: every layer,
     models/llama.py:_init_params_mla, or some, the "attn_latent" stack of
     _init_params_by_kind) runs on ONE device, unquantized, without
-    adapters: what has no layout for its leaves or its latent pool is
-    refused by name at start, never guessed."""
+    adapters: what has no layout for its leaves (attn.wq — or, with a
+    query latent, attn.wq_a, q_a_norm, wq_b — wkv_a, kv_a_norm, wkv_b, wo;
+    the attn_index stack of the layers that own an indexer) or its latent
+    pool is refused by name at start, never guessed."""
     cfg, name = config.model, config.model.model
     if config.parallel.world_size > 1:
         raise ValueError(
@@ -182,6 +184,44 @@ def _refuse_unbuilt_for_latent_attention(config: EngineConfig) -> None:
             "(the draft's K/V pages have another shape); use the n-gram "
             "proposer"
         )
+
+
+def _refuse_unbuilt_for_selected_keys(config: EngineConfig) -> None:
+    """A model whose latent layers attend over SELECTED keys
+    (LayerSpec.indexer; docs/55-selected-keys.md) is refused, by name and
+    one sentence each, what its second cache leaf and its selection have not
+    been built for. (A mesh, int8, LoRA and a draft model are refused as
+    for every latent model: `_refuse_unbuilt_for_latent_attention`, whose
+    leaves here are attn.wq_a, q_a_norm, wq_b in wq's place and the
+    attn_index stack.)"""
+    cfg, name, sched = config.model, config.model.model, config.scheduler
+    why = {
+        **{tier: (True, "a page of a layer that owns an indexer is a latent "
+                  "page AND an index-key page, and engine/kv_transfer.py "
+                  "ships the first leaf only")
+           for tier in config.kv_tiers_asked()},
+        f"speculative decoding (--speculative-config "
+        f"{sched.speculative_method})": (
+            sched.num_speculative_tokens > 0,
+            "the verify program is row-major, and ops/dsa_attention.py "
+            "selects for packed chunks and single decode steps only (the "
+            "model's own multi-token-prediction layer is not served)"),
+        f"--kv-cache-dtype {config.cache.kv_cache_dtype}": (
+            config.cache.kv_cache_dtype != "auto",
+            "the index keys are stored in the model's dtype: a float8 index "
+            "cache selects other keys, and which scale it would take is not "
+            "published"),
+        "a checkpoint": (
+            bool(cfg.checkpoint),
+            "the tensor names of a glm_moe_dsa checkpoint (the query "
+            "latent's, the indexer's) were never checked against a "
+            "published one offline; the presets serve random weights"),
+    }
+    for what, (asked, reason) in why.items():
+        if asked:
+            raise ValueError(
+                f"{name}: {what} cannot be combined with attention over "
+                f"selected keys — {reason}")
 
 
 def _moe_device_counts(cfg, stats: list):
@@ -522,6 +562,8 @@ class ModelRunner:
         cfg = config.model
         if cfg.latent_layers:
             _refuse_unbuilt_for_latent_attention(config)
+        if cfg.selecting_layers:
+            _refuse_unbuilt_for_selected_keys(config)
         if cfg.state_layers:
             _refuse_unbuilt_for_recurrent_state(config)
         if cfg.tree_by_kind:
@@ -1044,6 +1086,26 @@ class ModelRunner:
             },
         }
         cfg = self.config.model
+        if cfg.selecting_layers:
+            # learned sparse attention (docs/55-selected-keys.md): who
+            # selects, who borrows, and what computes it
+            report["mixers"] = {
+                "by_layer": [f"latent+{s.indexer}" for s in cfg.layer_specs],
+                "latent": len(cfg.latent_layers),
+                "full": len(cfg.index_layers),
+                "shared": len(cfg.selecting_layers) - len(cfg.index_layers),
+                "index_topk": cfg.index_topk,
+                "indexer_backend": {
+                    "prefill": "xla: dsa_index_score (blocked einsum) + "
+                               "dsa_select (k-th value by bisection)",
+                    "decode": "xla: dsa_index_score + dsa_select "
+                              "(jax.lax.top_k)"},
+                "attention_backend": {
+                    "prefill": "xla: dsa_attn (masked pass over the row's "
+                               "latents)",
+                    "decode": "xla: dsa_gather + dsa_attn (the selected "
+                              "latents by slot)"},
+            }
         if cfg.state_layers:
             # the mixer kinds of every program: layers that hold pages
             # (softmax attention) and layers that hold state (the gated

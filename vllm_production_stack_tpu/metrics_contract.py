@@ -38,6 +38,12 @@ HBM_STATE_SHARE = "tpu:hbm_state_share"
 # HBM_STATE_BYTES the two caches of a model whose layers are latent or
 # recurrent.
 HBM_LATENT_BYTES = "tpu:hbm_latent_bytes"
+# ... and of INDEX KEYS in use beside them (pages held x the bytes a page's
+# index keys take across the layers that own an indexer, engine/memory.py:
+# index_block_bytes; 0 for a model without learned sparse attention). The
+# latent gauge counts the latents alone: the two add up to the pool's bytes
+# in use.
+HBM_INDEX_BYTES = "tpu:hbm_index_bytes"
 # generation by diffusion over blocks (ModelConfig.block_diffusion;
 # docs/44-block-diffusion.md; all 0 for every other model): what the decode
 # programs' windows of block passes counted on the device. A STEP of such a
@@ -629,6 +635,7 @@ ALL_GAUGES = (
     HBM_STATE_SLOTS_IN_USE,
     HBM_STATE_SHARE,
     HBM_LATENT_BYTES,
+    HBM_INDEX_BYTES,
     PREFIX_CACHE_HIT_RATE,
     HOST_KV_USAGE_PERC,
     STEP_OVERLAP_FRAC,
@@ -771,6 +778,25 @@ DEBUG_TIMING_SSD_COUNTS = (
 )
 # ... the same count of LATENT layers run, from a model that has any
 DEBUG_TIMING_LATENT_COUNTS = ("mla_layers_n",)
+# ... what a model whose latent layers attend over SELECTED keys counts
+# (LayerSpec.indexer; docs/55-selected-keys.md; absent otherwise), on the
+# host at the resolve from lengths it knows: the layers that own an indexer
+# and those that borrow a selection, run (the model's count a prefill
+# dispatch, times the window's steps a decode dispatch); the (query, key)
+# pairs the indexers scored; the keys the queries could see and those they
+# attended — min(visible, index_topk) — summed over queries and over every
+# layer that attends under a selection; the live rows x steps of the decode
+# windows, and the decode windows' part of the pairs and of the attended keys
+DEBUG_TIMING_DSA_COUNTS = (
+    "dsa_full_layers_n",
+    "dsa_shared_layers_n",
+    "dsa_index_pairs_n",
+    "dsa_visible_keys_n",
+    "dsa_selected_keys_n",
+    "dsa_decode_rows_n",
+    "dsa_decode_index_pairs_n",
+    "dsa_decode_selected_keys_n",
+)
 # ... and what group-limited routing counts (ModelConfig.n_group > 1;
 # absent otherwise): the groups the router kept — `topk_group` a token and
 # expert layer run, padding tokens included — and those of them that hold
@@ -805,8 +831,14 @@ DEVICE_SCOPES = {
     "ssm_conv": "glue",     # short convolution over x|B|C, SiLU
     "ssm_gate_norm": "glue",  # gate by z, grouped RMSNorm
     "h1_branches": "glue",  # attention and state-space branches summed
+    "dsa_index_q": "proj",  # an indexer's queries and head weights
+    "dsa_index_k": "proj",  # ... its one key a token: matmul, LayerNorm, RoPE
     # mixer — the attention / recurrence kernels and what XLA leaves beside
     "attn": "mixer",        # paged attention: the Pallas kernel, or XLA's
+    "dsa_index_score": "mixer",  # an indexer's scores over the visible keys
+    "dsa_select": "mixer",  # the exact top-k of them (threshold / top_k)
+    "dsa_gather": "mixer",  # a decode step's gather of the selected latents
+    "dsa_attn": "mixer",    # latent attention over the selected keys
     "kda_scan": "mixer",    # the gated delta rule over a chunk or a step
     "ssm_scan": "mixer",    # the state-space scan
     # mlp / experts / moe_route — the second half of a layer

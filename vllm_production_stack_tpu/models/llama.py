@@ -39,6 +39,12 @@ from ..ops.kda_attention import (
     kda_prefill_xla,
 )
 from ..ops.kda_short_conv import kda_short_conv
+from ..ops.dsa_attention import (
+    dsa_decode_attention,
+    dsa_index_decode,
+    dsa_prefill_attention,
+    selection_indices,
+)
 from ..ops.mla_attention import (
     latent_attention_with_staged,
     latent_attention_xla,
@@ -124,6 +130,35 @@ def _state_tap(layer: int, slots, positions):
         return None
     return lambda *fed: jax.debug.callback(
         STATE_TAP, slots, positions.reshape(-1), *fed)
+
+
+# The same for the SELECTION of a model whose latent layers attend over
+# selected keys (LayerSpec.indexer; docs/55-selected-keys.md): per execution
+# and layer that owns an indexer, (the layer's index among those, token ids,
+# positions, the selected key positions (tokens, K) in ascending order, -1
+# past a query's count). testing/reference_check.py:SelectionTap.
+SELECTION_TAP = None
+
+
+def _tap_selection(layer: int, token_ids, positions, chosen) -> None:
+    if SELECTION_TAP is not None:
+        jax.debug.callback(
+            SELECTION_TAP, jnp.int32(layer), token_ids.reshape(-1),
+            positions.reshape(-1), chosen)
+
+
+def _tap_step_selection(layer: int, token_ids, positions, chosen: tuple,
+                        s_ctx: int, hist_len) -> None:
+    """A decode step's selection (`dsa_index_decode`'s indices and which are
+    real) as the tap takes it: positions — pool index s is position s, staged
+    slot w position hist_len + w — ascending, -1 last."""
+    if SELECTION_TAP is None:
+        return
+    at, real = chosen
+    last = jnp.iinfo(jnp.int32).max
+    at = jnp.where(at < s_ctx, at, hist_len[:, None] + at - s_ctx)
+    at = jnp.sort(jnp.where(real, at, last), axis=1)
+    _tap_selection(layer, token_ids, positions, jnp.where(at == last, -1, at))
 
 
 # The same for the ROUTER of one expert layer (`ROUTER_TAP.moe_layer`, its
@@ -233,15 +268,40 @@ def _init_params_mla(cfg: ModelConfig, rng: jax.Array) -> dict:
     lm, it, mit = L - k_dense, cfg.intermediate_size, cfg.expert_intermediate_size
     dt = _dtype(cfg)
     keys = iter(jax.random.split(rng, 24))
+    # (the leaves this docstring's tree did not have are drawn from keys of
+    # their own: the older models' draws stay what they were)
+    more = iter(jax.random.split(jax.random.fold_in(rng, 55), 16))
 
-    def w(*shape, scale=None):
+    def w(*shape, scale=None, keys=keys):
         scale = scale if scale is not None else shape[-2] ** -0.5
         return (jax.random.normal(next(keys), shape, jnp.float32)
                 * scale).astype(dt)
 
+    def experts(n, *shape):
+        """A stack of expert leaves: a held share (`num_experts_held`) is
+        drawn one layer at a time — the float32 draw that is live is one
+        layer's (0.8 GB at 16 experts of 6144 x 2048), not the leaf's."""
+        if not cfg.num_experts_held:
+            return w(n, e, *shape)
+        layer_keys = jax.random.split(next(more), n)
+        shape = (cfg.held_experts, *shape)
+
+        def draw(i, buf):
+            piece = jax.random.normal(layer_keys[i], shape, jnp.float32)
+            return jax.lax.dynamic_update_index_in_dim(
+                buf, (piece * shape[-2] ** -0.5).astype(dt), i, 0)
+
+        return jax.lax.fori_loop(0, n, draw, jnp.zeros((n, *shape), dt))
+
+    # a query latent (`q_lora_rank`): wq_a (L, h, rank), q_a_norm (L, rank),
+    # wq_b (L, rank, heads * (nope + rope)) in wq's place
+    rq = cfg.q_lora_rank
     layers: dict[str, Any] = {
         "attn": {
-            "wq": w(L, h, nh * (dn + dr)),
+            **({"wq_a": w(L, h, rq, keys=more),
+                "q_a_norm": jnp.ones((L, rq), dt),
+                "wq_b": w(L, rq, nh * (dn + dr))} if rq else
+               {"wq": w(L, h, nh * (dn + dr))}),
             "wkv_a": w(L, h, r + dr),
             "kv_a_norm": jnp.ones((L, r), dt),
             "wkv_b": w(L, r, nh * (dn + dv)),
@@ -250,6 +310,24 @@ def _init_params_mla(cfg: ModelConfig, rng: jax.Array) -> dict:
         "input_norm": jnp.ones((L, h), dt),
         "post_attn_norm": jnp.ones((L, h), dt),
     }
+    if cfg.index_layers:
+        # the indexers, a stack over the layers that own one
+        # (LayerSpec.indexer "full"): wq_b (Lf, q rank, Hi * D) from the
+        # query latent, wk (Lf, h, D) and the LayerNorm's weight and bias
+        # over the key, w_proj (Lf, h, Hi) the weight a head and token. The
+        # first `qk_rope_head_dim` of D are roped (rotate-half order)
+        lf, hi, di = (len(cfg.index_layers), cfg.index_n_heads,
+                      cfg.index_head_dim)
+        layers["attn_index"] = {
+            "wq_b": w(lf, rq, hi * di, keys=more),
+            "wk": w(lf, h, di, keys=more),
+            # (not ones and zeros: a norm left out, or its bias, has to show)
+            "k_norm": (1.0 + 0.1 * jax.random.normal(
+                next(more), (lf, di), jnp.float32)).astype(dt),
+            "k_norm_bias": (0.1 * jax.random.normal(
+                next(more), (lf, di), jnp.float32)).astype(dt),
+            "w_proj": w(lf, h, hi, keys=more),
+        }
     if k_dense:
         layers["mlp"] = {"gate": w(k_dense, h, it), "up": w(k_dense, h, it),
                          "down": w(k_dense, it, h)}
@@ -258,8 +336,8 @@ def _init_params_mla(cfg: ModelConfig, rng: jax.Array) -> dict:
         layers["moe"] = {
             "router": w(lm, h, e),
             "bias": 0.02 * jax.random.normal(next(keys), (lm, e), jnp.float32),
-            "gate": w(lm, e, h, mit), "up": w(lm, e, h, mit),
-            "down": w(lm, e, mit, h),
+            "gate": experts(lm, h, mit), "up": experts(lm, h, mit),
+            "down": experts(lm, mit, h),
         }
         if sit:
             layers["moe"]["shared"] = {
@@ -550,7 +628,9 @@ def init_kv_cache(
     state_slots: int = 0,
 ) -> tuple[jax.Array, ...]:
     """Paged pool: a TUPLE of per-layer (2, num_blocks, block_size, kvH, D)
-    arrays (a latent layer's: (1, num_blocks, block_size, latent_width)), NOT one stacked
+    arrays (a latent layer's: (1, num_blocks, block_size, latent_width); one
+    that owns an indexer: {"kv": that, "idx": (1, num_blocks, block_size,
+    index_head_dim)}, an index key a token beside the latent), NOT one stacked
     array. Per-layer leaves let jit donation alias
     each layer's pool in place; a stacked pool updated inside a scan forces
     XLA to hold a second full-pool buffer (observed +9.8 GiB on a
@@ -578,8 +658,15 @@ def init_kv_cache(
         if spec.latent:
             # a latent layer: ONE plane, a token's latent
             # (ops/mla_attention.py), no second copy of it as "V"
-            return jnp.zeros(
+            latents = jnp.zeros(
                 (1, num_blocks, block_size, cfg.latent_width), dt)
+            if spec.indexer == "full":
+                # ... and a layer that owns an indexer one index key a
+                # token beside it, under the same block table
+                # (ops/dsa_attention.py)
+                return {"kv": latents, "idx": jnp.zeros(
+                    (1, num_blocks, block_size, cfg.index_head_dim), dt)}
+            return latents
         return jnp.zeros((
             2,
             num_window_blocks if spec.window and num_window_blocks
@@ -737,7 +824,8 @@ def _layer_body(
     ap = lp["attn"]
     mup = cfg.mup or MupSpec()
     if spec.latent:
-        x = _mla_attention(cfg, ap, lp["input_norm"], x, positions, attend)
+        x = _mla_attention(cfg, ap, lp["input_norm"], x, positions, attend,
+                           lp.get("attn_index"))
         return _mlp_block(cfg, lp, res + x, proj, moe_stats, moe_backend)
     if spec.holds_state and not spec.holds_pages:
         x = _kda_attention(cfg, ap, lp["input_norm"], x, recur)
@@ -1109,7 +1197,8 @@ class _StepRecurrence(_Recurrence):
 
 
 def _mla_attention(cfg: ModelConfig, ap: dict, norm: jax.Array,
-                   x: jax.Array, positions: jax.Array, attend) -> jax.Array:
+                   x: jax.Array, positions: jax.Array, attend,
+                   index: dict | None = None) -> jax.Array:
     """Multi-head latent attention in the absorbed form, (B, T, h) -> the
     attention block's output before the residual add.
 
@@ -1129,7 +1218,18 @@ def _mla_attention(cfg: ModelConfig, ap: dict, norm: jax.Array,
     "wg" (h, heads) beside the others) multiplies head h's output by
     sigmoid(u W_g)[h], u the layer's normed input, before W_o — one scalar a
     head and token, so it commutes with W_UV and is applied to the expanded
-    head outputs."""
+    head outputs.
+
+    A model with a QUERY latent (`q_lora_rank`: leaves wq_a, q_a_norm, wq_b
+    in wq's place) makes its queries from c_q = RMSNorm(W_qa u). A layer
+    that owns an INDEXER (`index`: its "attn_index" leaves; LayerSpec.indexer
+    "full") also makes, per token, the indexer's queries (Hi heads x D, from
+    c_q), ONE index key (LayerNorm of a projection of u) — rope on the first
+    `qk_rope_head_dim` of D of both — and a weight a head (from u, the two
+    scales Hi^-1/2 D^-1/2 folded in, float32), and hands `attend` the three
+    in the values' place: `attend` stores the key beside the latent, selects
+    and attends over the selection (ops/dsa_attention.py). A layer that
+    borrows hands it None, as every plain latent layer does."""
     b, t, _ = x.shape
     nh, r = cfg.num_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -1138,7 +1238,12 @@ def _mla_attention(cfg: ModelConfig, ap: dict, norm: jax.Array,
     with jax.named_scope("attn_qkv"):
         x = rms_norm(x, norm, cfg.rms_norm_eps)
         with jax.named_scope("mla_q"):
-            q = _mm(x, ap["wq"]).reshape(b, t, nh, dn + dr)
+            if "wq_a" in ap:
+                c_q = rms_norm(_mm(x, ap["wq_a"]), ap["q_a_norm"],
+                               cfg.rms_norm_eps)
+                q = _mm(c_q, ap["wq_b"]).reshape(b, t, nh, dn + dr)
+            else:
+                q = _mm(x, ap["wq"]).reshape(b, t, nh, dn + dr)
             q_rope = apply_rope(q[..., dn:], positions, cfg.rope_theta)
         with jax.named_scope("mla_latent"):
             ckr = _mm(x, ap["wkv_a"])  # (B, T, rank + rope)
@@ -1156,7 +1261,22 @@ def _mla_attention(cfg: ModelConfig, ap: dict, norm: jax.Array,
                 # (float32 logistic, as softmax statistics are)
                 gate = jax.nn.sigmoid(
                     _mm(x, ap["wg"]).astype(jnp.float32)).astype(x.dtype)
-    o_lat = attend(q_abs, latent, None)  # (B, T, heads, rank)
+        selecting = None
+        if index is not None:
+            hi, di = cfg.index_n_heads, cfg.index_head_dim
+            with jax.named_scope("dsa_index_q"):
+                q_idx = apply_rope(
+                    _mm(c_q, index["wq_b"]).reshape(b, t, hi, di), positions,
+                    cfg.rope_theta, rotary_dim=dr)
+                w_idx = _mm(x, index["w_proj"]).astype(jnp.float32) * (
+                    hi ** -0.5 * di ** -0.5)
+            with jax.named_scope("dsa_index_k"):
+                k_idx = apply_rope(
+                    _layer_norm(_mm(x, index["wk"]), index["k_norm"],
+                                index["k_norm_bias"])[:, :, None, :],
+                    positions, cfg.rope_theta, rotary_dim=dr)[:, :, 0]
+            selecting = {"q": q_idx, "k": k_idx, "w": w_idx}
+    o_lat = attend(q_abs, latent, selecting)  # (B, T, heads, rank)
     with jax.named_scope("attn_out"):
         with jax.named_scope("mla_absorb"):
             o = jnp.einsum("bthr,rhd->bthd", o_lat, w_kvb[..., dn:])
@@ -1164,6 +1284,20 @@ def _mla_attention(cfg: ModelConfig, ap: dict, norm: jax.Array,
             with jax.named_scope("mla_gate"):
                 o = o * gate[..., None]
         return _mm(o.reshape(b, t, nh * dv), ap["wo"])
+
+
+INDEX_KEY_NORM_EPS = 1e-6
+
+
+def _layer_norm(x: jax.Array, weight: jax.Array, bias: jax.Array) -> jax.Array:
+    """LayerNorm with weight and bias (the indexer's key), statistics in
+    float32."""
+    xf = x.astype(jnp.float32)
+    mean = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
+    normed = (xf - mean) * jax.lax.rsqrt(var + INDEX_KEY_NORM_EPS)
+    return (normed * weight.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(x.dtype)
 
 
 def _mlp_block(cfg: ModelConfig, lp: dict, x: jax.Array, proj,
@@ -1687,13 +1821,16 @@ def _layer_params(cfg: ModelConfig, params: dict, i: int) -> dict:
     # name in the layer's dict -> (stack in the tree, the layer's index there)
     picks = {name: (name, i) for name in layers
              if name not in ("attn", "attn_window", "attn_linear",
-                             "attn_latent", "attn_ssm", "mlp", "moe")}
+                             "attn_latent", "attn_ssm", "attn_index",
+                             "mlp", "moe")}
     picks["attn"] = (
         ({"full": "attn", "window": "attn_window", "linear": "attn_linear",
           "latent": "attn_latent"}[spec.attn_kind], ai)
         if cfg.attn_stacks_by_kind else ("attn", i))
     if spec.recurrence == "ssd":
         picks["attn_ssm"] = ("attn_ssm", cfg.ssm_layers.index(i))
+    if spec.indexer == "full":
+        picks["attn_index"] = ("attn_index", cfg.index_layers.index(i))
     picks[mlp_kind] = (mlp_kind, j := mi if two_mlps else i)
     # (slices are traced in the tree's own sorted order, a kind-stacked MLP
     # last: the order the programs of every older model were traced in, so
@@ -1804,6 +1941,9 @@ def _layer(
     moe_backend: str = "xla",  # see _moe_mlp
     state: dict | None = None,  # a layer that holds state: the rows' slots
     #   and the pack (see forward); `kv_layer` is its pools then
+    selected: dict | None = None,  # a layer that attends over selected keys
+    #   (LayerSpec.indexer): {"context_lens", "chunk_start", "carry"} — see
+    #   forward; `kv_layer` is {"kv", "idx"} where the layer owns an indexer
 ) -> tuple[jax.Array, jax.Array]:
     b, t = x.shape[0], x.shape[1]
     hd, nkv = cfg.head_dim, cfg.num_kv_heads
@@ -1867,8 +2007,40 @@ def _layer(
             softcap=cfg.attn_logit_softcap,
         )
 
+    def attend_selected(q_abs, latent, selecting):
+        """A packed chunk of a layer that attends over selected keys: the
+        latents (and the index keys of a layer that selects) go to their
+        pages, then ops/dsa_attention.py selects — or takes the selection
+        the last selecting layer left in `selected["carry"]` — and
+        attends."""
+        nonlocal kv_layer
+        span = (write_blocks["ids"], write_blocks["start_off"],
+                write_blocks["chunk_lens"])
+        with jax.named_scope("kv_stage"):
+            pools = {"kv": write_latent_pages_blockwise(
+                page_pool(kv_layer), latent[:, 0], *span,
+                row_off=rows["row_off"])}
+            index = None
+            if selecting is not None:
+                pools["idx"] = write_latent_pages_blockwise(
+                    kv_layer["idx"], selecting["k"][:, 0], *span,
+                    row_off=rows["row_off"])
+                index = {"q": selecting["q"][:, 0], "w": selecting["w"][:, 0],
+                         "pool": pools["idx"]}
+        with jax.named_scope("attn"):
+            out, selected["carry"] = dsa_prefill_attention(
+                q_abs[:, 0], pools["kv"], block_tables,
+                selected["context_lens"], selected["chunk_start"],
+                rows["row_off"], index=index, selection=selected["carry"],
+                scale=cfg.attn_scale, rank=cfg.kv_lora_rank,
+                topk=cfg.index_topk)
+        kv_layer = pools if selecting is not None else pools["kv"]
+        return out[:, None]
+
     def attend(q, k, v):
         nonlocal kv_layer
+        if spec.indexer:
+            return attend_selected(q, k, v)
         if rows is not None:
             q, k = q[:, 0], k[:, 0]  # (N, heads, D)
             v = None if v is None else v[:, 0]
@@ -1974,6 +2146,11 @@ def forward(
             "pallas prefill does not support softcapped models (the runner "
             "gates these to the XLA backend)"
         )
+    if cfg.selecting_layers and (pack is None or write_blocks is None):
+        raise NotImplementedError(
+            f"{cfg.model}: layers that attend over selected keys prefill "
+            "packed chunks only (ops/dsa_attention.py computes a row from "
+            "its slice of the packed axis)")
     rows, row_positions, chunk_start = None, positions, None
     if pack is not None:
         rows = _row_view(
@@ -1985,6 +2162,12 @@ def forward(
         token_ids, positions = token_ids[:, None], positions[:, None]
     with jax.named_scope("embed"):
         x = _embed(cfg, params, token_ids)
+    selected = None
+    if cfg.selecting_layers:
+        # the SELECTION is carried down the layer loop: a layer that owns an
+        # indexer leaves it in "carry", the layers behind it read it there
+        selected = {"context_lens": context_lens,
+                    "chunk_start": pack["chunk_start"], "carry": None}
     if backend.startswith("pallas"):
         # the kernel masks from scalars alone — the scheduler feeds chunks
         # with contiguous positions (scheduler.py: range(start, start+len)),
@@ -1999,6 +2182,9 @@ def forward(
             "mesh": mesh,
             "write_kernel": chunk_write_by_kernel(backend, mesh),
         }
+    elif selected is not None:
+        # (ops/dsa_attention.py masks from the same scalars)
+        masks, pallas_prefill = {}, None
     else:
         # attention masks, built once per WINDOW KIND and reused by every
         # layer of that kind (full; a window's sliding variant)
@@ -2048,8 +2234,14 @@ def forward(
             pallas_prefill, moe_stats, rows, spec, moe_backend,
             *(({**state, "tap": _state_tap(i, tok_slot, positions)},)
               if spec.holds_state else ()),
+            **({"selected": selected} if spec.indexer else {}),
         )
         new_kv.append(layer_kv)
+        if spec.indexer == "full" and SELECTION_TAP is not None:
+            _tap_selection(
+                cfg.index_layers.index(i), token_ids, positions,
+                selection_indices(selected["carry"][:token_ids.shape[0]],
+                                  cfg.index_topk))
     if moe_stats is not None:
         _tap_routing(token_ids, positions, moe_stats[n_stats:])
     with jax.named_scope("final_norm"):
@@ -2089,6 +2281,11 @@ def init_staged_kv(
     else:
         buf = jnp.zeros((cfg.num_layers, 2, window, batch,
                          cfg.num_kv_heads, cfg.head_dim), dt)
+    if cfg.index_layers:
+        # ... and the window's index keys of the layers that own an
+        # indexer, (Lf, B, W, D), committed beside the latents
+        return {"kv": buf, "idx": jnp.zeros(
+            (len(cfg.index_layers), batch, window, cfg.index_head_dim), dt)}
     if cfg.state_layers:
         # (the buffer keeps a plane for every layer, so a layer's index is
         # its plane; the planes of layers without pages, 0.5 MiB each, stay
@@ -2127,7 +2324,9 @@ def decode_window_step(
     loop-invariant history gather out of the window loop, `hists` carries the
     contiguous per-layer (hist_k, hist_v) and the pool is not touched here
     (ops/attention.py:attention_with_hist). Returns (hidden (B, h), staged')."""
-    states = None
+    states = staged_idx = chosen = None
+    if cfg.index_layers:  # see init_staged_kv
+        staged, staged_idx = staged["kv"], staged["idx"]
     if cfg.state_layers:  # see init_staged_kv
         staged, states = staged["kv"], dict(staged["state"])
         state_read = jnp.where(step_k == 0, state_slots[0], state_slots[1])
@@ -2178,6 +2377,35 @@ def decode_window_step(
                     rank=cfg.kv_lora_rank,
                     interpret=backend == "pallas_interpret",
                 )[:, None]
+
+        def attend_selected(q_abs, latent, selecting, i=i):
+            """A step of a layer that attends over selected keys: a layer
+            that owns an indexer stages its index key beside the latent and
+            selects (`chosen`: what the layers behind it read), every such
+            layer gathers the selected latents and attends
+            (ops/dsa_attention.py)."""
+            nonlocal staged, staged_idx, chosen
+            with jax.named_scope("kv_stage"):
+                staged = staged.at[i, :, step_k].set(
+                    latent[:, 0].astype(staged.dtype))
+                if selecting is not None:
+                    j = cfg.index_layers.index(i)
+                    staged_idx = staged_idx.at[j, :, step_k].set(
+                        selecting["k"][:, 0].astype(staged_idx.dtype))
+            with jax.named_scope("attn"):
+                if selecting is not None:
+                    chosen = dsa_index_decode(
+                        selecting["q"][:, 0], selecting["w"][:, 0],
+                        kv_caches[i]["idx"], block_tables, hist_len,
+                        staged_idx[j], step_k, topk=cfg.index_topk)
+                    _tap_step_selection(
+                        j, token_ids, positions, chosen,
+                        block_tables.shape[1] * _page_tokens(cfg, kv_caches),
+                        hist_len)
+                return dsa_decode_attention(
+                    q_abs[:, 0], page_pool(kv_caches[i]), block_tables,
+                    staged[i], *chosen, scale=cfg.attn_scale,
+                    rank=cfg.kv_lora_rank)[:, None]
 
         def attend(q, k, v, i=i, spec=spec, tables=tables,
                    pool=page_pool(kv_caches[i])):
@@ -2239,7 +2467,8 @@ def decode_window_step(
                 _state_tap(i, state_slots[1], positions))
         x = _layer_body(
             cfg, lp, x, positions[:, None],
-            attend_latent if spec.latent else attend,
+            attend_selected if spec.indexer
+            else attend_latent if spec.latent else attend,
             _lora_layer_slice(lora, i), lora_idx, moe_stats, spec,
             moe_backend, recur if spec.holds_state else None,
         )
@@ -2252,6 +2481,8 @@ def decode_window_step(
                      cfg.rms_norm_add_one, scale_f32=cfg.norm_scale_f32)
     if states is not None:
         staged = {"kv": staged, "state": states}
+    if staged_idx is not None:
+        staged = {"kv": staged, "idx": staged_idx}
     return x, staged
 
 
@@ -2400,15 +2631,30 @@ def commit_staged_kv(
 ) -> tuple[jax.Array, ...]:
     """Scatter a whole window's staged K/V into the (donated) pool, one
     scatter per layer — the only pool write of the fused decode window."""
-    states = {}
-    if isinstance(staged, dict):  # layers that hold state: init_staged_kv
-        staged, states = staged["kv"], staged["state"]
+    states, staged_idx = {}, None
+    if isinstance(staged, dict):  # layers that hold state, or that own an
+        # indexer: init_staged_kv
+        states, staged_idx = staged.get("state", {}), staged.get("idx")
+        staged = staged["kv"]
     if staged.ndim == 4:  # latents: (L, B, W, width), rows (b, w) as slots
+        def rows_of(buf):
+            return buf.reshape(-1, buf.shape[-1])
+
+        new_kv, owners = [], 0
         with jax.named_scope("kv_commit"):
-            return tuple(
-                states[i] if i in states else write_latent_pages(
-                    kv, staged[i].reshape(-1, staged.shape[-1]), slot_mapping)
-                for i, kv in enumerate(kv_caches))
+            for i, kv in enumerate(kv_caches):
+                if i in states:
+                    new_kv.append(states[i])
+                    continue
+                pages = write_latent_pages(
+                    page_pool(kv), rows_of(staged[i]), slot_mapping)
+                if isinstance(kv, dict):
+                    # a layer that owns an indexer: its index keys beside
+                    pages = {"kv": pages, "idx": write_latent_pages(
+                        kv["idx"], rows_of(staged_idx[owners]), slot_mapping)}
+                    owners += 1
+                new_kv.append(pages)
+        return tuple(new_kv)
     L, _, w, b, kvh, d = staged.shape
     new_kv: list[jax.Array] = []
     with jax.named_scope("kv_commit"):

@@ -209,6 +209,66 @@ def _ling_flash_preset(n_layers: int, layer_group_size: int = 6,
     return kw
 
 
+def glm_dsa_layers(indexer_types, mlp_layer_types, num_heads: int,
+                   rope_theta: float) -> tuple[LayerSpec, ...]:
+    """The per-layer list of a `model_type: glm_moe_dsa` config: every layer
+    a LATENT layer that attends over a SELECTION of its keys — its own
+    where `indexer_types` says "full" (the layer owns an indexer and an
+    index key a token), the nearest full layer's before it where it says
+    "shared" — with a dense SwiGLU where `mlp_layer_types` says "dense",
+    experts where it says "sparse"."""
+    if len(indexer_types) != len(mlp_layer_types):
+        raise ValueError(
+            f"{len(indexer_types)} indexer_types beside "
+            f"{len(mlp_layer_types)} mlp_layer_types")
+    rope = RopeSpec(theta=rope_theta)
+    return tuple(
+        LayerSpec(num_heads=num_heads, rope=rope, mixer="latent",
+                  indexer=kind, experts=mlp == "sparse")
+        for kind, mlp in zip(indexer_types, mlp_layer_types))
+
+
+# zai-org/GLM-5.2 config.json: which layers own an indexer — 0-2, then
+# every fourth (index_topk_freq 4 behind index_skip_topk_offset 3)
+_GLM_52_INDEXER_TYPES = ("full",) * 3 + tuple(
+    "full" if (i - 3) % 4 == 3 else "shared" for i in range(3, 78))
+
+
+def _glm_dsa_preset(indexer_types=_GLM_52_INDEXER_TYPES,
+                    first_k_dense: int = 3, **over) -> dict:
+    """zai-org/GLM-5.2 config.json (`model_type: glm_moe_dsa`): 78 layers of
+    multi-head latent attention (64 heads of 192 + 64 (q) and 256 (v), a
+    512-value latent and one 64-value rope key a token, a QUERY latent of
+    2 048) over a learned SELECTION of keys — an indexer of 32 heads x 128
+    scores every visible key and the best 2 048 are attended; the layers
+    `indexer_types` marks "full" own one, the "shared" ones borrow the
+    selection of the full layer before them; three leading dense SwiGLUs of
+    12 288, then 256 experts of 2 048, top-8 of sigmoid scores + a selection
+    bias, renormalised x 2.5, one ungated shared SwiGLU; vocabulary 154 880
+    untied; 1 048 576 positions published. The multi-token-prediction layer
+    is not served (docs/55-selected-keys.md)."""
+    n = len(indexer_types)
+    kw = dict(
+        vocab_size=154880, hidden_size=6144, intermediate_size=12288,
+        num_layers=n, num_heads=64, num_kv_heads=64, head_dim=192,
+        max_model_len=32768, rope_theta=8000000.0, rms_norm_eps=1e-5,
+        architecture="glm_moe_dsa", kv_lora_rank=512, q_lora_rank=2048,
+        qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+        index_n_heads=32, index_head_dim=128, index_topk=2048,
+        first_k_dense_replace=first_k_dense, num_experts=256,
+        num_experts_per_tok=8, moe_intermediate_size=2048,
+        n_shared_experts=1, scoring_func="sigmoid",
+        routed_scaling_factor=2.5, norm_topk_prob=True,
+        rope_interleave=True,
+    )
+    kw.update(over)
+    kw["layers"] = glm_dsa_layers(
+        indexer_types,
+        ("dense",) * first_k_dense + ("sparse",) * (n - first_k_dense),
+        kw["num_heads"], kw["rope_theta"])
+    return kw
+
+
 # Architecture hyperparameters follow the public model cards.
 # SDAR's generation settings (JetLM/SDAR-30B-A3B-Chat): the config.json has
 # no key for any of them. Block length 4 and 4 passes are the -Chat
@@ -551,6 +611,30 @@ PRESETS: dict[str, dict] = {
         num_experts=16, num_experts_per_tok=4, n_group=4, topk_group=2,
         num_experts_held=8, first_expert_held=4, moe_intermediate_size=32,
         linear_num_heads=4, linear_head_dim=16, refused_token_ids=()),
+    "glm-5.2": _glm_dsa_preset(),
+    # the benchmark cell's cut: ONE chip (shard 0 of sixteen that share each
+    # layer's experts) of the pipeline stage that holds published layers 2-8
+    # — the last leading dense layer (full), then shared shared shared full
+    # shared shared: a whole indexer period and more — experts 0-15 of the
+    # 256 the router scores, vocabulary rows 0-19 359; every width as
+    # published, 32 768 positions served
+    "glm-5.2-ep16-7l": _glm_dsa_preset(
+        ("full", "shared", "shared", "shared", "full", "shared", "shared"),
+        first_k_dense=1, num_experts_held=16, first_expert_held=0,
+        vocab_size=19360),
+    # toy widths (float32) whose top-k (16) lies UNDER every test's context:
+    # 4 heads of 16 + 8 (q) and 16 (v), a 32-value latent, a query latent of
+    # 48, an indexer of 2 heads x 16 (rope on 8), a dense layer 0 (full),
+    # then shared shared full shared; 8 experts, top-2, experts 2-5 held
+    "tiny-glm-dsa": _glm_dsa_preset(
+        ("full", "shared", "shared", "full", "shared"), first_k_dense=1,
+        vocab_size=512, hidden_size=64, intermediate_size=128, num_heads=4,
+        num_kv_heads=4, head_dim=16, max_model_len=512, dtype="float32",
+        kv_lora_rank=32, q_lora_rank=48, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, index_n_heads=2,
+        index_head_dim=16, index_topk=16, num_experts=8,
+        num_experts_per_tok=2, moe_intermediate_size=32,
+        num_experts_held=4, first_expert_held=2),
 }
 
 _ARCH_MAP = {
@@ -604,7 +688,10 @@ def _deepseek_v3_keys(hf: dict, path: str) -> dict:
     guessed at."""
     n_group = hf.get("n_group") or 1
     refused = {
-        "q_lora_rank": hf.get("q_lora_rank") is not None,
+        "q_lora_rank (a query latent is served for `model_type: "
+        "glm_moe_dsa`, models/registry.py:_glm_moe_dsa_keys, whose tree has "
+        "attn.wq_a / q_a_norm / wq_b; a deepseek_v3 checkpoint's q_a_proj "
+        "has no leaf here)": hf.get("q_lora_rank") is not None,
         "n_group that does not divide n_routed_experts": bool(
             hf["n_routed_experts"] % n_group),
         "topk_group outside 1..n_group": not (
@@ -637,6 +724,69 @@ def _deepseek_v3_keys(hf: dict, path: str) -> dict:
         norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
         rope_interleave=bool(hf.get("rope_interleave", True)),
         n_group=n_group, topk_group=hf.get("topk_group") or 1,
+    )
+
+
+def _glm_moe_dsa_keys(hf: dict, path: str) -> dict:
+    """The `model_type: glm_moe_dsa` keys (GLM-5.2): DeepSeek-V3's latent
+    attention WITH a query latent, an indexer a "full" layer, and the
+    per-layer lists that say who selects and who borrows; and by name what
+    of the family is not served: a config that sets one is refused, not
+    guessed at. (The engine refuses the CHECKPOINT beside such a config at
+    start — engine/model_runner.py — its tensor names were never checked
+    against a published one.)"""
+    rope = hf.get("rope_parameters") or {}
+    n = hf["num_hidden_layers"]
+    kinds = hf.get("indexer_types") or []
+    mlps = hf.get("mlp_layer_types") or (
+        ["dense"] * hf.get("first_k_dense_replace", 0)
+        + ["sparse"] * (n - hf.get("first_k_dense_replace", 0)))
+    dense = [m == "dense" for m in mlps]
+    k_dense = sum(dense)
+    refused = {
+        "index_topk_pattern (a top-k that differs by layer)": hf.get(
+            "index_topk_pattern") is not None,
+        "rope_scaling": bool(hf.get("rope_scaling")) or rope.get(
+            "rope_type", "default") != "default",
+        "index_kpool keys (an indexer over pooled keys)": any(
+            k.startswith("index_kpool") for k in hf),
+        "indexer_types that is not one entry a layer": len(kinds) != n,
+        "indexer_types that does not start with 'full'": bool(
+            kinds) and kinds[0] != "full",
+        "dense layers behind a sparse one": dense != sorted(
+            dense, reverse=True),
+        "q_lora_rank null": not hf.get("q_lora_rank"),
+        "n_group != 1": (hf.get("n_group") or 1) != 1,
+        "topk_group != 1": (hf.get("topk_group") or 1) != 1,
+        "moe_layer_freq != 1": (hf.get("moe_layer_freq") or 1) != 1,
+        "scoring_func other than sigmoid": hf.get(
+            "scoring_func", "sigmoid") != "sigmoid",
+        "attention_bias": bool(hf.get("attention_bias")),
+    }
+    hit = [k for k, v in refused.items() if v]
+    if hit:
+        raise ValueError(
+            f"glm_moe_dsa config {path} sets {', '.join(hit)}: not "
+            "implemented (docs/55-selected-keys.md says what is served)")
+    theta = float(rope.get("rope_theta", hf.get("rope_theta", 10000.0)))
+    return dict(
+        layers=glm_dsa_layers(kinds, mlps, hf["num_attention_heads"], theta),
+        rope_theta=theta,
+        kv_lora_rank=hf["kv_lora_rank"], q_lora_rank=hf["q_lora_rank"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"],
+        qk_rope_head_dim=hf["qk_rope_head_dim"],
+        v_head_dim=hf["v_head_dim"],
+        index_n_heads=hf["index_n_heads"],
+        index_head_dim=hf["index_head_dim"], index_topk=hf["index_topk"],
+        first_k_dense_replace=k_dense,
+        num_experts=hf["n_routed_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        n_shared_experts=hf.get("n_shared_experts") or 0,
+        scoring_func="sigmoid",
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        rope_interleave=bool(hf.get("rope_interleave", True)),
     )
 
 
@@ -854,6 +1004,8 @@ def _from_hf_config(path: str) -> dict:
         arch = "ling_flash"
     if hf.get("model_type") == "falcon_h1":
         arch = "falcon_h1"
+    if hf.get("model_type") == "glm_moe_dsa":
+        arch = "glm_moe_dsa"
     if arch is None:
         raise ValueError(f"unsupported architecture(s) {archs} in {path}")
     heads = hf["num_attention_heads"]
@@ -906,6 +1058,11 @@ def _from_hf_config(path: str) -> dict:
         moe = _falcon_h1_keys(hf, path)
         # its one rope is the per-layer list's (rope_scaling is refused)
         hf = {**hf, "partial_rotary_factor": None, "rope_scaling": None}
+    if arch == "glm_moe_dsa":
+        moe = _glm_moe_dsa_keys(hf, path)
+        # its rope is `rope_parameters` (read there; scaling is refused)
+        hf = {**hf, "partial_rotary_factor": None, "rope_scaling": None,
+              "rope_theta": moe.pop("rope_theta")}
     if arch == "ling_flash":
         moe = _ling_flash_keys(hf, path)
         # the latent layers rope their 64-value rope key whole (rotary_dim

@@ -99,6 +99,67 @@ class RoutingTap:
         return list(np.stack(rows, axis=1))  # Lm x (T, k)
 
 
+class SelectionTap:
+    """The key positions the SERVED programs selected, token by token, in a
+    model whose latent layers attend over selected keys (LayerSpec.indexer;
+    docs/55-selected-keys.md): set as `llama.SELECTION_TAP` while an engine
+    is built and driven (as `RoutingTap` is), it receives every execution's
+    (index of the layer among those that own an indexer, token ids,
+    positions, selected positions (tokens, K) ascending, -1 padded) and
+    gives them back per sequence, keyed by (position, token id)."""
+
+    def __init__(self):
+        self.records: list[tuple] = []
+        self._by_key: dict | None = None
+        # (layer, position, token id) keys under which two executions
+        # recorded DIFFERENT selections: two sequences in flight with the
+        # same token at the same position (`RoutingTap.ambiguous`). Such a
+        # token comes back as a row of -2: the reference selects for it
+        self.ambiguous: set = set()
+
+    def __call__(self, layer, token_ids, positions, chosen) -> None:
+        self.records.append((
+            int(layer), np.asarray(token_ids), np.asarray(positions),
+            np.asarray(chosen).astype(np.int32)))
+
+    def __enter__(self) -> "SelectionTap":
+        llama.SELECTION_TAP = self
+        return self
+
+    def __exit__(self, *exc) -> None:
+        llama.SELECTION_TAP = None
+        jax.effects_barrier()  # every callback has run
+
+    def selection_of(self, tokens: list[int]) -> list[np.ndarray]:
+        """Per layer that owns an indexer the (T, K) positions selected for
+        `tokens` fed at positions 0..T-1 (whichever program fed each; a
+        token fed twice with the same selection — a preemption — is one),
+        -1 past a query's count; a row of -2 where the key names two
+        selections."""
+        if self._by_key is None:
+            self._by_key = {}
+            for layer, toks, pos, chosen in self.records:
+                for i, key in enumerate(zip(pos.tolist(), toks.tolist())):
+                    key = (layer, *key)
+                    seen = self._by_key.get(key)
+                    if seen is not None and not np.array_equal(
+                            seen, chosen[i]):
+                        self.ambiguous.add(key)
+                    self._by_key[key] = chosen[i]
+            self.records = []
+        layers = 1 + max(k[0] for k in self._by_key)
+        out = []
+        for layer in range(layers):
+            rows = [self._by_key[(layer, p, t)] for p, t in enumerate(tokens)]
+            width = max(len(r) for r in rows)
+            out.append(np.stack([
+                np.full(width, -2, np.int32)
+                if (layer, p, t) in self.ambiguous else
+                np.pad(r, (0, width - len(r)), constant_values=-1)
+                for (p, t), r in zip(enumerate(tokens), rows)]))
+        return out
+
+
 def system_routing(cfg, params, tokens: list[int], chunk: int | None = None,
                    backend: str = "xla") -> list[np.ndarray]:
     """Each MoE layer's chosen experts (T, k) as the system's own model code
@@ -149,7 +210,8 @@ def compare(reference, cfg, params, prompts: list[list[int]],
             served: list[dict], routing_chunk: int | None = None,
             routing_backend: str = "xla",
             served_routing: RoutingTap | None = None,
-            reference_arms: dict | None = None) -> dict:
+            reference_arms: dict | None = None,
+            served_selection: SelectionTap | None = None) -> dict:
     """`reference` is the reference module (the package's, or the
     benchmark's byte-identical copy). Returns the worst |Δlogprob| over
     every compared (position, id), how many were compared, and for an MoE
@@ -166,7 +228,11 @@ def compare(reference, cfg, params, prompts: list[list[int]],
     else (3.25 nats at 8 layers on the chip, PERF.md §6 PR 31).
     `reference_arms`: keywords the reference's `forward` takes to compute
     something ELSE (reference_falcon_h1.py's fault arms: the check shows
-    each lands outside a limit)."""
+    each lands outside a limit). `served_selection` (`reference_glm_dsa`
+    only): the reference also FOLLOWS the key positions the served programs
+    selected and reports how far they lay from its own top-k
+    (`selection_*`; reference_glm_dsa.py says why); without it the
+    reference selects for itself."""
     ref_cfg = reference.published_keys(cfg)
     worst, positions, ids_compared, total = 0.0, 0, 0, 0.0
     chosen_worst = 0.0
@@ -174,19 +240,24 @@ def compare(reference, cfg, params, prompts: list[list[int]],
     margins: list[float] = []
     # reference_mla.py and reference_laguna.py read checkpoint-style names
     # and orientation, and take `logit_positions` and `forced_experts`
-    by_name = cfg.mla or cfg.architecture in _BY_KIND_VIEWS
+    selects = bool(cfg.selecting_layers)  # (reads the served tree itself)
+    by_name = (cfg.mla and not selects) or (
+        cfg.architecture in _BY_KIND_VIEWS)
     if by_name:
         from ..models import loader
 
         view = (loader.DeepseekCheckpointView if cfg.mla
                 else getattr(loader, _BY_KIND_VIEWS[cfg.architecture]))
         params_ref = view(cfg, params)
-    if cfg.linear_layers and cfg.num_experts and served_routing is None:
+    selection_reports: list = []
+    if (cfg.linear_layers or selects) and cfg.num_experts and (
+            served_routing is None):
         # (`system_routing` drives `llama.forward` row-major on a scratch
         # pool, which has no state slots)
         raise ValueError(
             f"{cfg.model}: the routing of a model with layers that hold "
-            "state comes from the served programs' tap (`served_routing`)")
+            "state, or that select their keys, comes from the served "
+            "programs' tap (`served_routing`)")
     for prompt, got in zip(prompts, served):
         seq = list(prompt) + list(got["token_ids"][:-1])
         first = len(prompt) - 1  # the first compared position
@@ -205,6 +276,19 @@ def compare(reference, cfg, params, prompts: list[list[int]],
             logits, routing = reference.forward(
                 ref_cfg, params_ref, seq, range(first, len(seq)),
                 forced_experts=forced, **block)
+            first = 0
+        elif selects:
+            # reference_glm_dsa.py reads the served tree's own leaves and
+            # takes `logit_positions`, the served routing and selection,
+            # and its fault arms
+            forced = served_routing.routing_of(seq, mark_ambiguous=True)
+            logits, routing, reports = reference.forward(
+                ref_cfg, params, seq, range(first, len(seq)),
+                forced_experts=forced, forced_selection=(
+                    served_selection.selection_of(seq)
+                    if served_selection is not None else None),
+                **(reference_arms or {}))
+            selection_reports.extend(reports)
             first = 0
         elif cfg.ssm_layers:
             # reference_falcon_h1.py reads the served tree's own leaves and
@@ -226,7 +310,8 @@ def compare(reference, cfg, params, prompts: list[list[int]],
             positions += 1
             ids_compared += len(top_ids)
         if cfg.num_experts:
-            ours_all = forced if by_name and forced is not None else (
+            ours_all = forced if (by_name or selects) and (
+                forced is not None) else (
                 system_routing(cfg, params, seq, routing_chunk,
                                routing_backend))
             # (a leading dense layer routes nothing: the reference says None)
@@ -250,7 +335,41 @@ def compare(reference, cfg, params, prompts: list[list[int]],
             routing_pairs=routed,
             routing_disagreement_share=differing / max(routed, 1),
             routing_margin_max_where_differing=max(margins, default=0.0))
+    if served_selection is not None:
+        out.update(reference.selection_summary(selection_reports))
+        out["selection_keys_ambiguous"] = len(served_selection.ambiguous)
     return out
+
+
+def served_index_pages(reference, cfg, params, pool,
+                       prompts: list[list[int]], served: list[dict],
+                       key_dtype: str | None = None) -> dict:
+    """The INDEX KEYS the engine holds beside its latents, where neither
+    the logprobs nor the selection the reference follows can see them: a
+    key that is stored wrong moves the selection of every LATER query, which
+    the reference then follows. `pool` is the first layer's second cache
+    leaf as the engine left it after `served_logprobs` (1, blocks, block
+    size, index dim): each row's pages, found through the block table it had
+    when it last ran (`served_pages` says which rows those are), against
+    `reference.first_index_keys` of the tokens it was fed (`key_dtype`: the
+    reference's arm that holds its keys as a cache of that dtype would).
+    Returns the largest relative error (root of the summed squares) over the
+    rows."""
+    keys = reference.published_keys(cfg)
+    pool = np.asarray(pool)
+    block = pool.shape[2]
+    worst, tokens = 0.0, 0
+    for prompt, row in zip(prompts, served):
+        fed = (list(prompt) + list(row["token_ids"]))[:-1]
+        fed = fed[:len(row["block_table"]) * block]
+        got = jnp.asarray(pool[0, row["block_table"]].reshape(
+            -1, pool.shape[3])[:len(fed)]).astype(jnp.float32)
+        want = reference.first_index_keys(keys, params, fed, key_dtype)
+        worst = max(worst, float(
+            jnp.linalg.norm(got - want) / jnp.linalg.norm(want)))
+        tokens += len(fed)
+    return {"layer": 0, "rows": len(served), "tokens": tokens,
+            "index_pages_rel_err": worst}
 
 
 class StateTap:
