@@ -1095,6 +1095,7 @@ def child_reference(args) -> int:
     pages), its routing inside its bounds, and the fp8-pool arm — for laguna
     also each reference that is wrong in one way — outside them."""
     import contextlib
+    import gc
     import importlib.util
 
     import jax
@@ -1362,6 +1363,14 @@ def child_reference(args) -> int:
         kernels = (["--attention-backend", "pallas",
                     "--prefill-attention-backend", "pallas"]
                    if mla and not args.rehearse else [])
+        # (the served arm's reading first, should the second engine fail;
+        # and its programs and the reference's — an eager op a sequence
+        # length — unloaded: the init program of an 8B int8 model needs the
+        # chip all but empty, model_runner._init_device_params)
+        print("reference check: served arm " + json.dumps(served),
+              file=sys.stderr, flush=True)
+        gc.collect()
+        jax.clear_caches()
         fp8 = report["fp8_pool"] = arm(
             ["--kv-cache-dtype", "fp8", *kernels])
         report["ok"] = report["ok"] and bool(args.rehearse or not inside(fp8))

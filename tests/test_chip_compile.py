@@ -465,6 +465,78 @@ def test_prefill_program_of_a_cell_holds_no_score_temp(topo, model):
     assert temps - lone <= 2 * row_view + 4 * written, (temps, lone)
 
 
+@pytest.mark.parametrize("program", ["window", "chunk"])
+@pytest.mark.parametrize("model,quantization", [
+    ("qwen3-8b", "int8"), ("qwen2-7b", "int8"),
+    ("kanana-2-30b-a3b-8l", None)])
+def test_window_and_chunk_of_a_cell_read_their_weights_in_place(
+        topo, model, quantization, program):
+    """The eight-step decode window at 16 rows and the packed 2,048-token
+    chunk of a cell's model, three layers deep: outside the dots' own
+    fusions no operation of ENTRY or of the loop's body reads weights alone
+    and writes a buffer with the element count of a weight leaf, of its
+    slice, or of a stack — no copy, slice or re-layout of a weight
+    (tools/weight_copies.py is the scan). A leaf stored (in, heads *
+    head_dim) whose product is reshaped to heads was sliced a layer a STEP
+    and its stack re-laid a window: 0.9 GB a step at qwen3-8b (PERF.md §6
+    PR 56)."""
+    from tools import weight_copies as wc
+
+    cfg = wc.cell_config(model, quantization, 3)
+    latent = bool(cfg.latent_layers)
+    # (a latent cell's table is max_model_len / 32 wide over an 8k pool)
+    sizes = dict(table=1024, blocks=8192) if latent else {}
+    if program == "window":
+        compiled, params = wc.compile_window(topo, cfg, **sizes)
+    else:
+        compiled, params = wc.compile_chunk(
+            topo, cfg, rows=2 if latent else 4, **sizes)
+    text = compiled.as_text()
+    _assert_kernel(compiled)
+    assert wc.writes_outside_dots(text)  # the scan reads this text
+    copies = wc.weight_copies(text, params)
+    assert not copies, [(c["where"], c["name"], c["bytes"], c["scope"])
+                        for c in copies]
+
+
+@pytest.mark.parametrize("model,quantization,layers,blocks,table", [
+    ("qwen3-8b", "int8", 36, 768, 128),  # the cell: 50 MB of K, of V a layer
+    ("qwen3-8b", "int8", 36, 256, 128),  # (a stated 16 MiB lets 512 pages in)
+    ("laguna-s-2.1-ep4-9l", None, 9, 4096, 1024),  # the window kernel too
+    ("kanana-2-30b-a3b-8l", None, 8, 1024, 1024),  # latent: 42 MB a layer
+])
+def test_runner_window_of_a_cell_parks_no_pool_in_vmem(
+        topo, model, quantization, layers, blocks, table):
+    """The window program the RUNNER builds (the qwen3 cell's: 36 layers, 16
+    rows, the 128-page table, the 768-page pool, which fits a v5e's 128 MiB
+    of VMEM; a pool a third of it; the Laguna cell's two page groups; a
+    latent pool of 1,024 pages): the compiler prefetches no pool into VMEM
+    — with the weights' copies gone from it, its memory-space assignment
+    parked every layer's WHOLE pool there a step (`slice-done` + `copy-done`
+    1.5 s of an 8-s slice on the chip) until the decode kernels claimed the
+    VMEM, `DECODE_VMEM_LIMIT` — and no weight is copied here either
+    (PERF.md §6 PR 56)."""
+    import re
+
+    from tools import weight_copies as wc
+
+    cfg = wc.cell_config(model, quantization, layers)
+    compiled, params = wc.compile_runner_window(
+        topo, cfg, blocks=blocks, table=table)
+    text = compiled.as_text()
+    assert "decode_attention" in text
+    # a page of any pool here: (32 tokens, 8 kv heads, 128) or (32, 640)
+    parked = [line for line in wc.vmem_prefetches(text)
+              if re.search(r"\[[\d,]*,32,(8,128|640)\]\S*S\(1\)", line)]
+    assert not parked, parked[:4]
+    assert not wc.weight_copies(text, params)
+    if blocks == 768:
+        # nothing of a megabyte at all, and the stacks re-laid in ENTRY
+        # were 0.86 GB of 1.33 GB of temps
+        assert not wc.vmem_prefetches(text)
+        assert compiled.memory_analysis().temp_size_in_bytes < 2**27
+
+
 def test_moe_decode_step_compiles_for_v5e(topo):
     """One decode step of OLMoE-1B-7B at 16 rows: the Pallas decode kernel
     at its third head shape (16 KV heads x 128) and the all-experts einsum

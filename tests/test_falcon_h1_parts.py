@@ -264,7 +264,11 @@ def test_the_multiplier_vector_scales_its_five_spans(cfg):
 def test_a_multiplier_of_one_is_no_operation_and_another_is_told(cfg):
     """`attention_in_multiplier` is published as 1 (the engine test's arms
     cannot tell it left out); at 2 the reference tells it."""
-    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    from vllm_production_stack_tpu.testing.reference_check import (
+        reference_tree)
+
+    # (the reference reads (in, out) matrices: a view of the tree)
+    params = reference_tree(llama.init_params(cfg, jax.random.PRNGKey(0)))
     keys = reference.published_keys(cfg)
     tokens = list(range(3, 30))
     base, _ = reference.forward(keys, params, tokens, [26])

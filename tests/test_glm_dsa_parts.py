@@ -191,9 +191,17 @@ def test_param_and_cache_bytes_are_the_arrays(cfg):
     assert memory.param_bytes(cfg) == nbytes(params)
     # the query latent's leaves in W_q's place, the indexers' over 2 layers
     assert set(params["layers"]["attn"]) == {
-        "wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_a_norm", "wkv_b", "wo"}
+        "wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_a_norm", "wk_b", "wv_b",
+        "wo"}
+    # (a projection split into heads lies (heads, head_dim, in))
+    nh, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    assert {k: params["layers"]["attn"][k].shape[1:]
+            for k in ("wq_b", "wk_b", "wv_b")} == {
+        "wq_b": (nh, dn + dr, cfg.q_lora_rank),
+        "wk_b": (nh, dn, cfg.kv_lora_rank),
+        "wv_b": (nh, cfg.v_head_dim, cfg.kv_lora_rank)}
     assert {k: v.shape for k, v in params["layers"]["attn_index"].items()} == {
-        "wq_b": (2, 48, 32), "wk": (2, 64, 16), "k_norm": (2, 16),
+        "wq_b": (2, 2, 16, 48), "wk": (2, 64, 16), "k_norm": (2, 16),
         "k_norm_bias": (2, 16), "w_proj": (2, 64, 2)}
     assert params["layers"]["moe"]["gate"].shape == (4, 4, 64, 32)  # held
     econf = ek.engine_config(cfg, num_blocks=40)

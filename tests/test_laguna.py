@@ -130,8 +130,8 @@ def test_a_model_whose_layer_kinds_are_swapped_differs(cfg, params):
     stacks have the kinds' own shapes."""
     layers = params["layers"]
     h, d = cfg.hidden_size, cfg.head_dim
-    assert layers["attn"]["wq"].shape == (3, h, 4 * d)
-    assert layers["attn_window"]["wq"].shape == (6, h, 6 * d)
+    assert layers["attn"]["wq"].shape == (3, 4, d, h)
+    assert layers["attn_window"]["wq"].shape == (6, 6, d, h)
     assert layers["attn"]["wg"].shape == (3, h, 4)
     assert layers["attn_window"]["wo"].shape == (6, 6 * d, h)
     assert layers["moe"]["router"].shape == (8, h, 16)

@@ -414,7 +414,9 @@ def test_checkpoint_names_round_trip_with_the_rope_de_interleave(
                                       np.asarray(b, np.float32))
     # the checkpoint's rope columns ARE interleaved: column 2i of the
     # checkpoint is the program's column i, column 2i+1 its column i + d/2
-    wq = np.asarray(params["layers"]["attn"]["wq"][1]).reshape(64, 4, 24)
+    # (the tree holds (heads, nope ‖ rope, h), the checkpoint's rows)
+    wq = np.moveaxis(np.asarray(params["layers"]["attn"]["wq"][1]), -1, 0)
+    assert wq.shape == (64, 4, 24)
     ck = tensors[p + "self_attn.q_proj.weight"].T.reshape(64, 4, 24)
     np.testing.assert_array_equal(ck[..., :16], wq[..., :16])  # nope: as is
     np.testing.assert_array_equal(ck[..., 16::2], wq[..., 16:20])

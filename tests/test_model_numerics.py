@@ -55,6 +55,10 @@ def hf_model_from_params(cfg: ModelConfig, params):
     def t(x):  # jax (in, out) -> torch (out, in)
         return torch.from_numpy(np.asarray(x, dtype=np.float32).T.copy())
 
+    def th(x):  # jax (heads, head_dim, in) -> torch (out, in): as it lies
+        x = np.asarray(x, dtype=np.float32)
+        return torch.from_numpy(x.reshape(-1, x.shape[-1]).copy())
+
     def v(x):
         return torch.from_numpy(np.asarray(x, dtype=np.float32).copy())
 
@@ -63,9 +67,9 @@ def hf_model_from_params(cfg: ModelConfig, params):
     lp = params["layers"]
     for i in range(cfg.num_layers):
         p = f"model.layers.{i}."
-        sd[p + "self_attn.q_proj.weight"] = t(lp["attn"]["wq"][i])
-        sd[p + "self_attn.k_proj.weight"] = t(lp["attn"]["wk"][i])
-        sd[p + "self_attn.v_proj.weight"] = t(lp["attn"]["wv"][i])
+        sd[p + "self_attn.q_proj.weight"] = th(lp["attn"]["wq"][i])
+        sd[p + "self_attn.k_proj.weight"] = th(lp["attn"]["wk"][i])
+        sd[p + "self_attn.v_proj.weight"] = th(lp["attn"]["wv"][i])
         sd[p + "self_attn.o_proj.weight"] = t(lp["attn"]["wo"][i])
         sd[p + "mlp.gate_proj.weight"] = t(lp["mlp"]["gate"][i])
         sd[p + "mlp.up_proj.weight"] = t(lp["mlp"]["up"][i])

@@ -52,6 +52,10 @@ def hf_model_from_params(cfg: ModelConfig, params):
     def t(x):  # jax (in, out) -> torch (out, in)
         return torch.from_numpy(np.asarray(x, dtype=np.float32).T.copy())
 
+    def th(x):  # jax (heads, head_dim, in) -> torch (out, in): as it lies
+        x = np.asarray(x, dtype=np.float32)
+        return torch.from_numpy(x.reshape(-1, x.shape[-1]).copy())
+
     def v(x):
         return torch.from_numpy(np.asarray(x, dtype=np.float32).copy())
 
@@ -60,9 +64,9 @@ def hf_model_from_params(cfg: ModelConfig, params):
     lp = params["layers"]
     for i in range(cfg.num_layers):
         p = f"model.layers.{i}."
-        sd[p + "self_attn.q_proj.weight"] = t(lp["attn"]["wq"][i])
-        sd[p + "self_attn.k_proj.weight"] = t(lp["attn"]["wk"][i])
-        sd[p + "self_attn.v_proj.weight"] = t(lp["attn"]["wv"][i])
+        sd[p + "self_attn.q_proj.weight"] = th(lp["attn"]["wq"][i])
+        sd[p + "self_attn.k_proj.weight"] = th(lp["attn"]["wk"][i])
+        sd[p + "self_attn.v_proj.weight"] = th(lp["attn"]["wv"][i])
         sd[p + "self_attn.o_proj.weight"] = t(lp["attn"]["wo"][i])
         sd[p + "block_sparse_moe.gate.weight"] = t(lp["moe"]["router"][i])
         for j in range(cfg.num_experts):
@@ -376,6 +380,10 @@ def hf_olmoe_from_params(cfg: ModelConfig, params):
     def t(x):  # jax (in, out) -> torch (out, in)
         return torch.from_numpy(np.asarray(x, dtype=np.float32).T.copy())
 
+    def th(x):  # jax (heads, head_dim, in) -> torch (out, in): as it lies
+        x = np.asarray(x, dtype=np.float32)
+        return torch.from_numpy(x.reshape(-1, x.shape[-1]).copy())
+
     def v(x):
         return torch.from_numpy(np.asarray(x, dtype=np.float32).copy())
 
@@ -387,7 +395,8 @@ def hf_olmoe_from_params(cfg: ModelConfig, params):
         p = f"model.layers.{i}."
         for ours, theirs in (("wq", "q_proj"), ("wk", "k_proj"),
                              ("wv", "v_proj"), ("wo", "o_proj")):
-            sd[p + f"self_attn.{theirs}.weight"] = t(lp["attn"][ours][i])
+            sd[p + f"self_attn.{theirs}.weight"] = (
+                t if ours == "wo" else th)(lp["attn"][ours][i])
         sd[p + "self_attn.q_norm.weight"] = v(lp["attn"]["q_norm"][i])
         sd[p + "self_attn.k_norm.weight"] = v(lp["attn"]["k_norm"][i])
         sd[p + "mlp.gate.weight"] = t(lp["moe"]["router"][i])

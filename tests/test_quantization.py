@@ -34,13 +34,41 @@ def test_dequantized_weight_within_rounding_bound():
         leaf = qp["layers"]["attn"][name]
         assert is_quantized_leaf(leaf)
         w = np.asarray(params["layers"]["attn"][name], np.float32)
-        deq = np.asarray(leaf["q"], np.float32) * np.asarray(leaf["s"])
+        scale = np.asarray(leaf["s"])
+        if name != "wo":
+            # stored (L, heads, head_dim, in): one scale an output channel,
+            # (L, heads, head_dim)
+            assert scale.shape == w.shape[:-1], name
+            scale = scale[..., None]
+        deq = np.asarray(leaf["q"], np.float32) * scale
         # symmetric rounding: |W - deq| <= scale/2 elementwise
-        bound = np.broadcast_to(np.asarray(leaf["s"]) / 2 + 1e-8, w.shape)
+        bound = np.broadcast_to(scale / 2 + 1e-8, w.shape)
         assert np.all(np.abs(w - deq) <= bound), name
     # embed and norms stay unquantized
     assert not is_quantized_leaf(qp["embed"])
     assert not is_quantized_leaf(qp["layers"]["input_norm"])
+
+
+@pytest.mark.parametrize("name,heads", [("wq", "num_heads"),
+                                        ("wk", "num_kv_heads"),
+                                        ("wv", "num_kv_heads")])
+def test_heads_major_leaf_quantizes_as_its_matrix_does(name, heads):
+    """A leaf stored (L, heads, head_dim, in) holds the values and the scales
+    the (L, in, heads * head_dim) matrix has, moved: the reduction runs over
+    the contraction axis wherever it lies."""
+    from vllm_production_stack_tpu.models.quantization import _quantize
+
+    cfg = _cfg()
+    n = getattr(cfg, heads)
+    leaf = llama.init_params(cfg, jax.random.PRNGKey(0))["layers"]["attn"][name]
+    assert leaf.shape == (cfg.num_layers, n, cfg.head_dim, cfg.hidden_size)
+    matrix = jnp.swapaxes(leaf.reshape(cfg.num_layers, -1, cfg.hidden_size),
+                          1, 2)  # (L, in, out)
+    np.testing.assert_array_equal(llama.heads_major(matrix, n), leaf)
+    got, want = _quantize(leaf, axis=-1), _quantize(matrix)
+    np.testing.assert_array_equal(got["q"], llama.heads_major(want["q"], n))
+    np.testing.assert_array_equal(
+        got["s"], want["s"].reshape(cfg.num_layers, n, cfg.head_dim))
 
 
 def test_spec_tree_mirrors_param_tree():

@@ -43,7 +43,10 @@ WINDOW = dict(decode_window=8)
 
 def _reference_tokens(cfg, engine, prompt, g, **keys):
     ref_cfg = {**reference.published_keys(cfg), **keys}
-    return reference.generate(ref_cfg, engine.runner.params, prompt, g)
+    # (the reference reads (in, out) matrices: a view of the served tree)
+    return reference.generate(
+        ref_cfg, reference_check.reference_tree(engine.runner.params),
+        prompt, g)
 
 
 def _drive(engine, arrivals):

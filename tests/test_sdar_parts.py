@@ -268,27 +268,29 @@ def test_the_passes_name_their_parts(cfg):
 
 # -- every other model's programs are what they were -------------------------
 
-# sha256 of the StableHLO the PARENT commit (PR 40, fcbbba0) lowers for each
-# tiny preset's prefill and decode-window program (`_program_digests`). A
+# sha256 of the StableHLO the PARENT commit (PR 40, fcbbba0) lowered for each
+# tiny preset's prefill and decode-window program (`_program_digests`) — as
+# replaced by PR 56, which changes EVERY program on purpose (the q, k and v
+# leaves lie (heads, head_dim, in): models/llama.py:_mm_heads). A
 # later PR that changes one of these programs on purpose replaces its pins:
 # `PYTHONPATH=. JAX_PLATFORMS=cpu python tests/test_sdar_parts.py` prints this
 # table for the tree it runs in.
 _PARENT_PROGRAMS = {
     "tiny-llama": (
-        "e9ad55c86272efd0124eed07fda55b11fafbcad04eddc516450d4bad666e77f5",
-        "f4a650088d7f3380cd5d0929beaeaf00948a4901632e8360abadbfe7123290fc"),
+        "a6250089dc5c35e95b5bc2fec2ce159dcfeedb751d3e43176df201f4af0354a5",
+        "9d6c74b69adf8883a96c980aa668f87f2a2886e501acf5f6654e62a9d414c4b0"),
     "tiny-olmoe": (
-        "e5c71dd871e2328cf70f005619a853b31e826c16818114202e3fe309c2505373",
-        "b27b5979c81a8002148b5179ee858bd3deab3e4353f4a5a13db771c13cd1622d"),
+        "ea972abe9c513e9822d7e3cee55f95bcbd07a2c8153aa5917cd75de5f9ef743a",
+        "29b0b9e6a9cf3190d87ed9780e14066cd46f3213aac19055d6d4ac4ec124dd13"),
     "tiny-kanana": (
-        "1caad6a47ad4ae9c22c1e4fdf8ec9dc5e0f51d754edde723127553c8150a6f8b",
-        "d606aec47aefa1970a3c50ee9de80692ce3f66ff49945dd2e8fea730af6007fd"),
+        "835756d307acb047b15102431d6ee23bbf7e30eac71d3a3dce8f4f086f9ecf94",
+        "1da1977a0cb1bfc4c488c1f427e5c68b0f78e454da84288e3934c65ff3151a28"),
     "tiny-laguna": (
-        "2eddf71a7281da2c6f437a9843e3d878bea72d3a14bcff98a5daa6a2e185bdd2",
-        "ba57227f19864e03d8c3e1f33f30343502af65672a99664dbb510400d4d38e04"),
+        "84c5060c104db6a190498008aa633a649db6cd402c34af5cabe4342b9d0b9b5f",
+        "39ac9f0d6ecd6186d94b640298f81664e7dd0a7d3ae47721175c30b94e85ad27"),
     "tiny-solar-open2": (
-        "59a3f3dd24fb6212fce130d3ee0f173aa59b932bf3d659a40f998eb06330dde0",
-        "7915a1a37be91eb86a44323b927ef34276ab372bdd77768aa252358ccd71f709"),
+        "a727984aaff51ec817be0ea698e51c2966be3a60d65a115899843ba9a2a9a884",
+        "b439e853cf18dadccde6caa9b2040cf99538fb38ebca3d07314d1c9a37a49c7d"),
 }
 
 

@@ -151,7 +151,7 @@ def _refuse_unbuilt_for_latent_attention(config: EngineConfig) -> None:
     models/llama.py:_init_params_mla, or some, the "attn_latent" stack of
     _init_params_by_kind) runs on ONE device, unquantized, without
     adapters: what has no layout for its leaves (attn.wq — or, with a
-    query latent, attn.wq_a, q_a_norm, wq_b — wkv_a, kv_a_norm, wkv_b, wo;
+    query latent, attn.wq_a, q_a_norm, wq_b — wkv_a, kv_a_norm, wk_b, wv_b, wo;
     the attn_index stack of the layers that own an indexer) or its latent
     pool is refused by name at start, never guessed."""
     cfg, name = config.model, config.model.model
@@ -160,7 +160,7 @@ def _refuse_unbuilt_for_latent_attention(config: EngineConfig) -> None:
             f"{name}: tensor/data/pipeline/sequence/expert parallelism is not "
             "implemented for multi-head latent attention — parallel/"
             "sharding.py has no spec for its leaves (attn.wq, wkv_a, "
-            "kv_a_norm, wkv_b; moe.bias, moe.shared; the mlp/moe stacks of "
+            "kv_a_norm, wk_b, wv_b; moe.bias, moe.shared; the mlp/moe stacks of "
             "unequal depth) nor for the one-latent-per-token pool, which has "
             "no kv-head axis to shard"
         )
@@ -168,13 +168,13 @@ def _refuse_unbuilt_for_latent_attention(config: EngineConfig) -> None:
         raise ValueError(
             f"{name}: --quantization {cfg.quantization} is not implemented "
             "for multi-head latent attention — models/quantization.py names "
-            "no int8 form for attn.wkv_a / attn.wkv_b (the absorbed "
-            "projections read W_kv_b per head) or moe.shared"
+            "no int8 form for attn.wkv_a / attn.wk_b / attn.wv_b (the "
+            "absorbed projections read W_kv_b per head) or moe.shared"
         )
     if config.lora.max_loras > 0:
         raise ValueError(
             f"{name}: LoRA adapters are not implemented for multi-head "
-            "latent attention (its projections are wq, wkv_a, wkv_b)"
+            "latent attention (its projections are wq, wkv_a, wk_b, wv_b)"
         )
     if config.scheduler.num_speculative_tokens and (
         config.scheduler.speculative_method == "draft"
@@ -696,10 +696,11 @@ class ModelRunner:
                 f"requires an MoE model, but {cfg.model} has no experts — "
                 "the ep axis would only replicate dense compute"
             )
-        self._attention_backend = self._resolve_attention_backend()
-        self._prefill_backend = self._resolve_prefill_backend()
-        self._moe_backend = self._resolve_moe_backend()
-        self._hoist_budget = self._compute_hoist_budget()
+        self._bind_program_inputs(
+            self._resolve_attention_backend(),
+            self._resolve_prefill_backend(),
+            self._resolve_moe_backend(),
+            self._compute_hoist_budget())
         self._step_fn = (
             self._build_sp_step_fn() if self._sp > 1 else self._build_step_fn()
         )
@@ -847,6 +848,31 @@ class ModelRunner:
             read[i] = req.state_bank * self.state_slots + req.state_slot
             write[i] = (req.state_bank ^ 1) * self.state_slots + req.state_slot
         return (self._put(read, self._batch1), self._put(write, self._batch1))
+
+    def _bind_program_inputs(self, attention: str, prefill: str, moe: str,
+                             hoist_budget: int) -> None:
+        """What the `_build_*_fn` program builders read of a runner beside
+        `config` and `mesh` — set here and nowhere else, so that
+        `decode_window_program` builds what `__init__` builds."""
+        self._attention_backend = attention
+        self._prefill_backend = prefill
+        self._moe_backend = moe
+        self._hoist_budget = hoist_budget
+
+    @classmethod
+    def decode_window_program(cls, config: EngineConfig,
+                              mesh: jax.sharding.Mesh):
+        """The jitted decode-window function a runner over `mesh` builds with
+        the Pallas backends, WITHOUT a runner's device state: no weights, no
+        pools, nothing probed — `mesh` may hold a described topology's
+        devices. For `.lower(...)` against abstract arguments, the way
+        `_call_decode_program` calls it (tools/weight_copies.py reads the
+        compiled text: a cell's window as the installed TPU compiler lays
+        it out, with no chip)."""
+        self = object.__new__(cls)
+        self.config, self.mesh = config, mesh
+        self._bind_program_inputs("pallas", "pallas", "pallas", 0)
+        return self._build_decode_window_fn()
 
     def _resolve_attention_backend(self) -> str:
         """'auto' → the measured winner for the pool's block size.

@@ -7,9 +7,16 @@ body (fast compiles, fixed shapes — XLA-friendly control flow instead of a
 Python loop over 32 layers).
 
 Every weight is an (in, out)-oriented matrix so the forward pass is plain
-`x @ w` feeding the MXU; tensor parallelism is expressed entirely by the
-PartitionSpecs in parallel/sharding.py — no collective appears in this file
-(XLA/GSPMD inserts them).
+`x @ w` feeding the MXU — but for a projection whose result is split into
+heads: its leaf is stored (heads, head_dim, in), the checkpoint's own (out,
+in) with the head axis split, and the product contracts against it as stored
+(`_mm_heads`). Stored (in, heads * head_dim), the reshape to heads rides on
+the product and XLA slices every layer's leaf into a buffer of its own a
+decode STEP and re-lays the whole stack a window — 0.9 GB a step at qwen3-8b
+(PERF.md §6 PR 56; tools/weight_copies.py is the scan that shows it). Tensor
+parallelism is expressed entirely by the PartitionSpecs in
+parallel/sharding.py — no collective appears in this file (XLA/GSPMD inserts
+them).
 """
 
 from __future__ import annotations
@@ -75,6 +82,31 @@ from ..ops.paged_attention_pallas import (
 
 def _dtype(cfg: ModelConfig):
     return jnp.dtype(cfg.dtype)
+
+
+# The leaves stored (L, heads, head_dim, in), by stack of the tree — what
+# `_mm_heads` contracts against; every other matrix is (L, in, out).
+HEADS_MAJOR = {"attn": ("wq", "wk", "wv", "wq_b", "wk_b", "wv_b"),
+               "attn_window": ("wq", "wk", "wv"),
+               "attn_latent": ("wq", "wk_b", "wv_b"),
+               "attn_index": ("wq_b",)}
+
+
+def contraction_axis(stack: str, name: str) -> int:
+    """The axis of leaf `name` in stack `stack` of the tree that its product
+    contracts: the last of a `HEADS_MAJOR` leaf, the one before the last of
+    an (in, out) matrix. What follows a leaf's layout without naming its
+    axes — the int8 scale's reduction and its spec (models/quantization.py)
+    — asks here."""
+    return -1 if name in HEADS_MAJOR.get(stack, ()) else -2
+
+
+def heads_major(w: jax.Array, heads: int) -> jax.Array:
+    """A stack of (in, heads * head_dim) matrices (..., in, out) as the leaf
+    a head-split projection is stored as, (..., heads, head_dim, in): the
+    same values, moved (`init_params` draws the matrix it always drew)."""
+    *lead, n_in, out = w.shape
+    return jnp.swapaxes(w, -1, -2).reshape(*lead, heads, out // heads, n_in)
 
 
 # A test's tap on the routing of an MoE model's programs: when set (before a
@@ -205,9 +237,10 @@ def init_params(cfg: ModelConfig, rng: jax.Array) -> dict:
         "embed": w(next(keys), cfg.vocab_size, h, scale=0.02),
         "layers": {
             "attn": {
-                "wq": w(next(keys), L, h, nh * hd),
-                "wk": w(next(keys), L, h, nkv * hd),
-                "wv": w(next(keys), L, h, nkv * hd),
+                # (heads, head_dim, in): see the module docstring
+                "wq": heads_major(w(next(keys), L, h, nh * hd), nh),
+                "wk": heads_major(w(next(keys), L, h, nkv * hd), nkv),
+                "wv": heads_major(w(next(keys), L, h, nkv * hd), nkv),
                 "wo": w(next(keys), L, nh * hd, h),
             },
             mlp_key: mlp,
@@ -239,6 +272,16 @@ def init_params(cfg: ModelConfig, rng: jax.Array) -> dict:
     return params
 
 
+def _split_kv_b(w: jax.Array, heads: int, nope: int) -> dict:
+    """W_kv_b drawn (..., rank, heads * (nope + v)), each head k_nope ‖ v, as
+    the two leaves `_mla_attention` reads — "wk_b" (..., heads, nope, rank),
+    W_UK, and "wv_b" (..., heads, v, rank), W_UV: the checkpoint's (out, in)
+    rows, a head's split where its two products part. One leaf sliced for
+    both is copied, a layer's slice a step (PERF.md §6 PR 56)."""
+    w = heads_major(w, heads)
+    return {"wk_b": w[..., :nope, :], "wv_b": w[..., nope:, :]}
+
+
 def _init_params_mla(cfg: ModelConfig, rng: jax.Array) -> dict:
     """The tree of a DeepSeek-V3-family model (Kanana-2): latent attention
     in every layer; a dense SwiGLU in the first `first_k_dense_replace`
@@ -247,11 +290,12 @@ def _init_params_mla(cfg: ModelConfig, rng: jax.Array) -> dict:
     indexes each by its own count; `_grouped_mm`'s L*E groups are the expert
     layers') — beside the attention leaves and norms stacked over all L.
 
-    attn: wq (L, h, heads*(nope+rope)), head-major, each head nope ‖ rope;
+    attn: wq (L, heads, nope+rope, h), each head nope ‖ rope (`HEADS_MAJOR`);
     wkv_a (L, h, rank+rope): the kv latent ‖ the shared rope key; kv_a_norm
-    (L, rank); wkv_b (L, rank, heads*(nope+v)): each head k_nope ‖ v; wo
-    (L, heads*v, h). Rope columns are in rotate-half order (a checkpoint's
-    interleaved pairs are de-interleaved at load, models/loader.py).
+    (L, rank); W_kv_b, each head k_nope ‖ v, as wk_b (L, heads, nope, rank)
+    and wv_b (L, heads, v, rank) (`_split_kv_b`); wo (L, heads*v, h). Rope
+    rows are in rotate-half order (a checkpoint's interleaved pairs are
+    de-interleaved at load, models/loader.py).
     moe: router (Lm, h, E), bias (Lm, E) float32 — the selection bias, drawn
     NON-zero here: a zero bias would leave the rule that it moves the choice
     and never the weight untested; small (std 0.02 beside sigmoid scores
@@ -294,17 +338,17 @@ def _init_params_mla(cfg: ModelConfig, rng: jax.Array) -> dict:
         return jax.lax.fori_loop(0, n, draw, jnp.zeros((n, *shape), dt))
 
     # a query latent (`q_lora_rank`): wq_a (L, h, rank), q_a_norm (L, rank),
-    # wq_b (L, rank, heads * (nope + rope)) in wq's place
+    # wq_b (L, heads, nope + rope, rank) in wq's place
     rq = cfg.q_lora_rank
     layers: dict[str, Any] = {
         "attn": {
             **({"wq_a": w(L, h, rq, keys=more),
                 "q_a_norm": jnp.ones((L, rq), dt),
-                "wq_b": w(L, rq, nh * (dn + dr))} if rq else
-               {"wq": w(L, h, nh * (dn + dr))}),
+                "wq_b": heads_major(w(L, rq, nh * (dn + dr)), nh)} if rq else
+               {"wq": heads_major(w(L, h, nh * (dn + dr)), nh)}),
             "wkv_a": w(L, h, r + dr),
             "kv_a_norm": jnp.ones((L, r), dt),
-            "wkv_b": w(L, r, nh * (dn + dv)),
+            **_split_kv_b(w(L, r, nh * (dn + dv)), nh, dn),
             "wo": w(L, nh * dv, h),
         },
         "input_norm": jnp.ones((L, h), dt),
@@ -312,14 +356,14 @@ def _init_params_mla(cfg: ModelConfig, rng: jax.Array) -> dict:
     }
     if cfg.index_layers:
         # the indexers, a stack over the layers that own one
-        # (LayerSpec.indexer "full"): wq_b (Lf, q rank, Hi * D) from the
+        # (LayerSpec.indexer "full"): wq_b (Lf, Hi, D, q rank) from the
         # query latent, wk (Lf, h, D) and the LayerNorm's weight and bias
         # over the key, w_proj (Lf, h, Hi) the weight a head and token. The
         # first `qk_rope_head_dim` of D are roped (rotate-half order)
         lf, hi, di = (len(cfg.index_layers), cfg.index_n_heads,
                       cfg.index_head_dim)
         layers["attn_index"] = {
-            "wq_b": w(lf, rq, hi * di, keys=more),
+            "wq_b": heads_major(w(lf, rq, hi * di, keys=more), hi),
             "wk": w(lf, h, di, keys=more),
             # (not ones and zeros: a norm left out, or its bias, has to show)
             "k_norm": (1.0 + 0.1 * jax.random.normal(
@@ -358,7 +402,7 @@ def _init_params_by_kind(cfg: ModelConfig, rng: jax.Array) -> dict:
 
     The attention leaves are TWO stacks where the kinds' head counts differ
     — "attn" over the full layers, "attn_window" over the window layers:
-    wq (L_kind, h, heads_kind * D), wk, wv (L_kind, h, kvH * D), wo
+    wq (L_kind, heads_kind, D, h), wk, wv (L_kind, kvH, D, h), wo
     (L_kind, heads_kind * D, h), wg (L_kind, h, heads_kind), the gate — and
     one "attn" stack over all L where they agree. The MLP leaves are two
     stacks as `_init_params_mla`'s: "mlp" over the dense layers, "moe" over
@@ -402,10 +446,13 @@ def _init_params_by_kind(cfg: ModelConfig, rng: jax.Array) -> dict:
                 * scale).astype(dt)
 
     def attn(n, nh):
-        leaves = {"wq": w(n, h, nh * hd, over=mup.attention_in),
-                  "wk": w(n, h, nkv * hd,
-                          over=mup.attention_in * mup.key),
-                  "wv": w(n, h, nkv * hd, over=mup.attention_in),
+        leaves = {"wq": heads_major(
+                      w(n, h, nh * hd, over=mup.attention_in), nh),
+                  "wk": heads_major(
+                      w(n, h, nkv * hd, over=mup.attention_in * mup.key),
+                      nkv),
+                  "wv": heads_major(
+                      w(n, h, nkv * hd, over=mup.attention_in), nkv),
                   "wo": w(n, nh * hd, h, over=mup.attention_out)}
         if cfg.attn_output_gate:
             # one gate a head, or one a head and channel
@@ -501,9 +548,11 @@ def _init_params_by_kind(cfg: ModelConfig, rng: jax.Array) -> dict:
                 "not implemented for latent layers (a gate a head is: it "
                 "commutes with the absorbed value projection)")
         leaves = {
-            "wq": w(n, h, nh * (dn + dr)), "wkv_a": w(n, h, rank + dr),
+            "wq": heads_major(w(n, h, nh * (dn + dr)), nh),
+            "wkv_a": w(n, h, rank + dr),
             "kv_a_norm": jnp.ones((n, rank), dt),
-            "wkv_b": w(n, rank, nh * (dn + dv)), "wo": w(n, nh * dv, h)}
+            **_split_kv_b(w(n, rank, nh * (dn + dv)), nh, dn),
+            "wo": w(n, nh * dv, h)}
         if cfg.attn_output_gate:
             leaves["wg"] = w(n, h, nh)
         return leaves
@@ -603,6 +652,17 @@ def _mm(x: jax.Array, w) -> jax.Array:
     if isinstance(w, dict):
         return (x @ w["q"].astype(x.dtype)) * w["s"].astype(x.dtype)
     return x @ w
+
+
+def _mm_heads(x: jax.Array, w) -> jax.Array:
+    """(..., in) times a leaf stored (heads, head_dim, in) -> (..., heads,
+    head_dim): the product contracts against the leaf as it lies, so its
+    fusion reads the stacked leaf in place. An int8 leaf's "s" is (heads,
+    head_dim), one scale an output channel as `_mm`'s."""
+    if isinstance(w, dict):
+        return (jnp.einsum("...h,ndh->...nd", x, w["q"].astype(x.dtype))
+                * w["s"].astype(x.dtype))
+    return jnp.einsum("...h,ndh->...nd", x, w)
 
 
 def _activation(cfg: ModelConfig):
@@ -721,7 +781,8 @@ def lora_module_dims(cfg: ModelConfig) -> dict[str, tuple[int, int]]:
     if cfg.latent_layers:
         raise ValueError(
             "LoRA adapters are not implemented for multi-head latent "
-            f"attention ({cfg.model}): its projections are wq, wkv_a, wkv_b")
+            f"attention ({cfg.model}): its projections are wq, wkv_a, wk_b, "
+            "wv_b")
     h, hd = cfg.hidden_size, cfg.head_dim
     nh, nkv, it = cfg.num_heads, cfg.num_kv_heads, cfg.intermediate_size
     dims = {
@@ -810,15 +871,16 @@ def _layer_body(
     if lora is not None:
         lscale = lora["scale"][lora_idx]
 
-        def proj(xin, w, name):
-            out = _mm(xin, w)
+        def proj(xin, w, name, mm=_mm):
+            out = mm(xin, w)
             if name in lora:
-                out += _lora_delta(xin, lora[name], lora_idx, lscale)
+                out += _lora_delta(
+                    xin, lora[name], lora_idx, lscale).reshape(out.shape)
             return out
     else:
 
-        def proj(xin, w, name):
-            return _mm(xin, w)
+        def proj(xin, w, name, mm=_mm):
+            return mm(xin, w)
 
     res = x
     ap = lp["attn"]
@@ -835,20 +897,23 @@ def _layer_body(
             x = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps,
                          cfg.rms_norm_add_one)
         xa = _scaled(x, mup.attention_in)
-        q = proj(xa, ap["wq"], "q_proj")
-        k = proj(xa, ap["wk"], "k_proj")
-        v = proj(xa, ap["wv"], "v_proj")
+        # (b, t, heads, head_dim) as the products leave them
+        q = proj(xa, ap["wq"], "q_proj", _mm_heads)
+        k = proj(xa, ap["wk"], "k_proj", _mm_heads)
+        v = proj(xa, ap["wv"], "v_proj", _mm_heads)
         if cfg.attention_bias:
-            q, k, v = q + ap["bq"], k + ap["bk"], v + ap["bv"]
+            q, k, v = (q + ap["bq"].reshape(nh, hd),
+                       k + ap["bk"].reshape(nkv, hd),
+                       v + ap["bv"].reshape(nkv, hd))
         k = _scaled(k, mup.key)
         if cfg.qk_norm_flat:
-            # olmo2: RMSNorm over the whole flat projection, pre-reshape
-            q = rms_norm(q, ap["q_norm"], cfg.rms_norm_eps,
-                         scale_f32=cfg.norm_scale_f32)
-            k = rms_norm(k, ap["k_norm"], cfg.rms_norm_eps,
-                         scale_f32=cfg.norm_scale_f32)
-        q = q.reshape(b, t, nh, hd)
-        k = k.reshape(b, t, nkv, hd)
+            # olmo2: RMSNorm over the whole flat projection
+            q = rms_norm(q.reshape(b, t, nh * hd), ap["q_norm"],
+                         cfg.rms_norm_eps, scale_f32=cfg.norm_scale_f32
+                         ).reshape(b, t, nh, hd)
+            k = rms_norm(k.reshape(b, t, nkv * hd), ap["k_norm"],
+                         cfg.rms_norm_eps, scale_f32=cfg.norm_scale_f32
+                         ).reshape(b, t, nkv, hd)
         if cfg.qk_norm:
             # qwen3: per-head RMSNorm on q/k BEFORE rope (HF Qwen3Attention)
             q = rms_norm(q, ap["q_norm"], cfg.rms_norm_eps)
@@ -860,7 +925,6 @@ def _layer_body(
             k = apply_rope(k, positions, rope.theta, scaling=rope.scaling,
                            rotary_dim=rope.rotary_dim,
                            attention_factor=rope.attention_factor)
-        v = v.reshape(b, t, nkv, hd)
         if cfg.attn_output_gate:
             with jax.named_scope("attn_gate"):
                 # one scalar a head (or a head and channel) and token, from
@@ -1234,16 +1298,15 @@ def _mla_attention(cfg: ModelConfig, ap: dict, norm: jax.Array,
     nh, r = cfg.num_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     pad = cfg.latent_width - cfg.latent_dim
-    w_kvb = ap["wkv_b"].reshape(r, nh, dn + dv)
     with jax.named_scope("attn_qkv"):
         x = rms_norm(x, norm, cfg.rms_norm_eps)
         with jax.named_scope("mla_q"):
             if "wq_a" in ap:
                 c_q = rms_norm(_mm(x, ap["wq_a"]), ap["q_a_norm"],
                                cfg.rms_norm_eps)
-                q = _mm(c_q, ap["wq_b"]).reshape(b, t, nh, dn + dr)
+                q = _mm_heads(c_q, ap["wq_b"])  # (b, t, heads, nope + rope)
             else:
-                q = _mm(x, ap["wq"]).reshape(b, t, nh, dn + dr)
+                q = _mm_heads(x, ap["wq"])
             q_rope = apply_rope(q[..., dn:], positions, cfg.rope_theta)
         with jax.named_scope("mla_latent"):
             ckr = _mm(x, ap["wkv_a"])  # (B, T, rank + rope)
@@ -1253,7 +1316,7 @@ def _mla_attention(cfg: ModelConfig, ap: dict, norm: jax.Array,
             latent = jnp.concatenate(
                 [c_kv, k_rope, jnp.zeros((b, t, pad), x.dtype)], axis=-1)
         with jax.named_scope("mla_absorb"):
-            q_lat = jnp.einsum("bthd,rhd->bthr", q[..., :dn], w_kvb[..., :dn])
+            q_lat = jnp.einsum("bthd,hdr->bthr", q[..., :dn], ap["wk_b"])
             q_abs = jnp.concatenate(
                 [q_lat, q_rope, jnp.zeros((b, t, nh, pad), x.dtype)], axis=-1)
         if "wg" in ap:
@@ -1266,7 +1329,7 @@ def _mla_attention(cfg: ModelConfig, ap: dict, norm: jax.Array,
             hi, di = cfg.index_n_heads, cfg.index_head_dim
             with jax.named_scope("dsa_index_q"):
                 q_idx = apply_rope(
-                    _mm(c_q, index["wq_b"]).reshape(b, t, hi, di), positions,
+                    _mm_heads(c_q, index["wq_b"]), positions,
                     cfg.rope_theta, rotary_dim=dr)
                 w_idx = _mm(x, index["w_proj"]).astype(jnp.float32) * (
                     hi ** -0.5 * di ** -0.5)
@@ -1279,7 +1342,7 @@ def _mla_attention(cfg: ModelConfig, ap: dict, norm: jax.Array,
     o_lat = attend(q_abs, latent, selecting)  # (B, T, heads, rank)
     with jax.named_scope("attn_out"):
         with jax.named_scope("mla_absorb"):
-            o = jnp.einsum("bthr,rhd->bthd", o_lat, w_kvb[..., dn:])
+            o = jnp.einsum("bthr,hdr->bthd", o_lat, ap["wv_b"])
         if "wg" in ap:
             with jax.named_scope("mla_gate"):
                 o = o * gate[..., None]

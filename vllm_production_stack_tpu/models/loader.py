@@ -9,7 +9,10 @@ engine's equivalent: a local HF checkpoint dir (config.json +
 models/llama.py:init_params:
 
 - HF stores projection weights (out, in); ours are (in, out) so the forward
-  pass is plain ``x @ w`` — every matrix transposes on load.
+  pass is plain ``x @ w`` — every matrix transposes on load, but the GQA
+  layers' q, k and v projections: those stay (out, in) with the head axis
+  split, (heads, head_dim, in), the orientation their product reads
+  (models/llama.py:_mm_heads).
 - Per-layer weights stack along a leading L axis (one traced layer body).
 
 Weights land on device via the caller's NamedShardings (ModelRunner
@@ -83,6 +86,15 @@ def load_checkpoint_params(cfg: ModelConfig) -> dict:
         # HF (out, in) -> ours (in, out)
         return np.ascontiguousarray(ckpt.get(name).T).astype(dt)
 
+    def rows(name: str) -> np.ndarray:
+        # HF (out, in), as it lies
+        return ckpt.get(name).astype(dt)
+
+    def heads(name: str) -> np.ndarray:
+        # HF (heads * head_dim, in) -> ours (heads, head_dim, in)
+        w = rows(name)
+        return w.reshape(-1, cfg.head_dim, w.shape[-1])
+
     def vec(name: str) -> np.ndarray:
         return ckpt.get(name).astype(dt)
 
@@ -90,7 +102,7 @@ def load_checkpoint_params(cfg: ModelConfig) -> dict:
         return np.stack([kind(fmt.format(i)) for i in range(cfg.num_layers)])
 
     if cfg.mla:
-        params = _deepseek_v3_tree(cfg, mat, vec)
+        params = _deepseek_v3_tree(cfg, mat, vec, rows)
         logger.info(
             "loaded checkpoint %s (%d tensors, dtype %s)",
             cfg.checkpoint, len(list(ckpt.keys())), cfg.dtype,
@@ -147,20 +159,19 @@ def load_checkpoint_params(cfg: ModelConfig) -> dict:
     if cfg.architecture == "phi3":
         # Phi-3 fuses q/k/v into qkv_proj (row-stacked q, k, v) and
         # gate/up into gate_up_proj — split on the HF OUT axis (rows)
-        # before the (out, in) -> (in, out) transpose. Each fused tensor
-        # is read from disk ONCE per layer and sliced in memory.
+        # (gate/up: before the (out, in) -> (in, out) transpose). Each
+        # fused tensor is read from disk ONCE per layer and sliced in memory.
         nh_rows = cfg.num_heads * cfg.head_dim
         nkv_rows = cfg.num_kv_heads * cfg.head_dim
         it = cfg.intermediate_size
         q_l, k_l, v_l, g_l, u_l = [], [], [], [], []
         for i in range(cfg.num_layers):
             qkv = ckpt.get(p.format(i) + "self_attn.qkv_proj.weight")
-            q_l.append(np.ascontiguousarray(qkv[:nh_rows].T).astype(dt))
-            k_l.append(np.ascontiguousarray(
-                qkv[nh_rows:nh_rows + nkv_rows].T).astype(dt))
-            v_l.append(np.ascontiguousarray(
-                qkv[nh_rows + nkv_rows:nh_rows + 2 * nkv_rows].T
-            ).astype(dt))
+            for out, at in ((q_l, 0), (k_l, nh_rows),
+                            (v_l, nh_rows + nkv_rows)):
+                part = qkv[at:at + (nkv_rows if at else nh_rows)]
+                out.append(part.reshape(
+                    -1, cfg.head_dim, part.shape[-1]).astype(dt))
             gu_w = ckpt.get(p.format(i) + "mlp.gate_up_proj.weight")
             g_l.append(np.ascontiguousarray(gu_w[:it].T).astype(dt))
             u_l.append(np.ascontiguousarray(gu_w[it:2 * it].T).astype(dt))
@@ -175,9 +186,9 @@ def load_checkpoint_params(cfg: ModelConfig) -> dict:
         mlp_key = "mlp"
     else:
         attn_tree = {
-            "wq": stack(p + "self_attn.q_proj.weight", mat),
-            "wk": stack(p + "self_attn.k_proj.weight", mat),
-            "wv": stack(p + "self_attn.v_proj.weight", mat),
+            "wq": stack(p + "self_attn.q_proj.weight", heads),
+            "wk": stack(p + "self_attn.k_proj.weight", heads),
+            "wv": stack(p + "self_attn.v_proj.weight", heads),
             "wo": stack(p + "self_attn.o_proj.weight", mat),
         }
     params: dict = {
@@ -249,8 +260,9 @@ def _rope_columns(cfg: ModelConfig, w, to_checkpoint: bool):
     return np.concatenate([w[..., 0::2], w[..., 1::2]], axis=-1)
 
 
-def _deepseek_v3_tree(cfg: ModelConfig, mat, vec) -> dict:
-    """Checkpoint names -> models/llama.py:_init_params_mla's tree."""
+def _deepseek_v3_tree(cfg: ModelConfig, mat, vec, rows) -> dict:
+    """Checkpoint names -> models/llama.py:_init_params_mla's tree (`mat`: a
+    tensor (in, out), `rows`: (out, in) as the file holds it, `vec`)."""
     nh, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     r, k_dense = cfg.kv_lora_rank, cfg.first_k_dense_replace
     p = "model.layers.{}."
@@ -259,9 +271,15 @@ def _deepseek_v3_tree(cfg: ModelConfig, mat, vec) -> dict:
         return np.stack([kind(fmt.format(i)) for i in layers])
 
     def wq(name):
-        w = mat(name).reshape(-1, nh, dn + dr)  # (h, heads, nope ‖ rope)
-        w[..., dn:] = _rope_columns(cfg, w[..., dn:], to_checkpoint=False)
-        return w.reshape(w.shape[0], -1)
+        # (heads, nope ‖ rope, h): the file's rows, the head axis split
+        w = rows(name).reshape(nh, dn + dr, -1)
+        w[:, dn:] = np.swapaxes(_rope_columns(
+            cfg, np.swapaxes(w[:, dn:], -1, -2), to_checkpoint=False), -1, -2)
+        return w
+
+    def kv_b(name):
+        # (heads, k_nope ‖ v, rank), split where the two products part
+        return rows(name).reshape(nh, -1, r)
 
     def wkv_a(name):
         w = mat(name)  # (h, rank ‖ rope)
@@ -277,13 +295,15 @@ def _deepseek_v3_tree(cfg: ModelConfig, mat, vec) -> dict:
             "wq": stack(a + "q_proj.weight", wq, every),
             "wkv_a": stack(a + "kv_a_proj_with_mqa.weight", wkv_a, every),
             "kv_a_norm": stack(a + "kv_a_layernorm.weight", vec, every),
-            "wkv_b": stack(a + "kv_b_proj.weight", mat, every),
             "wo": stack(a + "o_proj.weight", mat, every),
         },
         "input_norm": stack(p + "input_layernorm.weight", vec, every),
         "post_attn_norm": stack(
             p + "post_attention_layernorm.weight", vec, every),
     }
+    w_kv_b = stack(a + "kv_b_proj.weight", kv_b, every)
+    layers["attn"] |= {"wk_b": np.ascontiguousarray(w_kv_b[:, :, :dn]),
+                       "wv_b": np.ascontiguousarray(w_kv_b[:, :, dn:])}
     if len(dense):
         layers["mlp"] = {
             key: stack(p + f"mlp.{key}_proj.weight", mat, dense)
@@ -318,6 +338,14 @@ def _deepseek_v3_tree(cfg: ModelConfig, mat, vec) -> dict:
     return params
 
 
+def _kv_b_rows(attn: dict, i: int):
+    """Layer i's W_kv_b as the checkpoint holds it, (heads * (nope + v),
+    rank), from the tree's two leaves (models/llama.py:_split_kv_b)."""
+    w = np.concatenate(
+        [np.asarray(attn["wk_b"][i]), np.asarray(attn["wv_b"][i])], axis=1)
+    return w.reshape(-1, w.shape[-1])
+
+
 class DeepseekCheckpointView:
     """The served tree read back under the checkpoint's tensor names and
     orientation ((out, in) matrices, interleaved rope columns): the inverse
@@ -348,9 +376,11 @@ class DeepseekCheckpointView:
         if rest == "post_attention_layernorm.weight":
             return layers["post_attn_norm"][i]
         if rest == "self_attn.q_proj.weight":
-            w = np.array(attn["wq"][i]).reshape(-1, nh, dn + dr)
-            w[..., dn:] = _rope_columns(cfg, w[..., dn:], to_checkpoint=True)
-            return w.reshape(w.shape[0], -1).T
+            w = np.array(attn["wq"][i])  # (heads, nope ‖ rope, h)
+            w[:, dn:] = np.swapaxes(_rope_columns(
+                cfg, np.swapaxes(w[:, dn:], -1, -2), to_checkpoint=True),
+                -1, -2)
+            return w.reshape(-1, w.shape[-1])
         if rest == "self_attn.kv_a_proj_with_mqa.weight":
             w = np.array(attn["wkv_a"][i])
             w[:, cfg.kv_lora_rank:] = _rope_columns(
@@ -359,7 +389,7 @@ class DeepseekCheckpointView:
         if rest == "self_attn.kv_a_layernorm.weight":
             return attn["kv_a_norm"][i]
         if rest == "self_attn.kv_b_proj.weight":
-            return attn["wkv_b"][i].T
+            return _kv_b_rows(attn, i)
         if rest == "self_attn.o_proj.weight":
             return attn["wo"][i].T
         if i < k_dense:
@@ -436,7 +466,9 @@ class LagunaCheckpointView:
                     "attn_window" if spec.window else "attn"], ai
             else:
                 attn, j = layers["attn"], i
-            return attn["w" + parts[4][0]][j].T  # q_proj -> wq, g_proj -> wg
+            w = attn["w" + parts[4][0]][j]  # q_proj -> wq, g_proj -> wg
+            # (q, k, v lie (heads, head_dim, in): (out, in) already)
+            return w.reshape(-1, w.shape[-1]) if w.ndim == 3 else w.T
         both = "mlp" in layers and "moe" in layers
         j = mi if both else i
         if not spec.experts:
@@ -496,8 +528,7 @@ class LingCheckpointView(SolarOpen2CheckpointView):
 
     _LINEAR = {**SolarOpen2CheckpointView._LINEAR,
                "f_proj": "wf", "g_proj": "wg"}
-    _LATENT = {"q_proj": "wq", "kv_a_proj_with_mqa": "wkv_a",
-               "kv_b_proj": "wkv_b", "o_proj": "wo", "g_proj": "wg"}
+    _LATENT = {"kv_a_proj_with_mqa": "wkv_a", "o_proj": "wo", "g_proj": "wg"}
 
     def __getitem__(self, name: str):
         cfg, layers = self.cfg, self.params["layers"]
@@ -507,6 +538,10 @@ class LingCheckpointView(SolarOpen2CheckpointView):
             j = cfg.layer_stack_index[int(parts[2])][0]
             if parts[4] == "kv_a_layernorm":
                 return stack["kv_a_norm"][j]
+            if parts[4] == "kv_b_proj":
+                return _kv_b_rows(stack, j)
+            if parts[4] == "q_proj":  # (heads, nope ‖ rope, h): its rows
+                return stack["wq"][j].reshape(-1, stack["wq"].shape[-1])
             return stack[self._LATENT[parts[4]]][j].T
         if name.endswith("mlp.gate.e_score_correction_bias"):
             return layers["moe"]["bias"][

@@ -4,7 +4,8 @@ The reference serves 8B-class models on 16-24 GiB GPUs in bf16; one v5e chip
 has 16 GiB HBM, so Llama-3-8B bf16 weights (~16 GiB) cannot fit next to a KV
 pool. int8 weight-only quantization (the vLLM `--quantization` family's
 simplest member) halves the weight bytes: every linear weight W (…, in, out)
-is stored as int8 with one float32 scale per output channel
+— or (…, heads, head_dim, in), `llama.contraction_axis` — is stored as int8 with one
+float32 scale per output channel
 (scale = max|W|/127 over the contraction axis), and the matmul dequantizes
 on the fly — `(x @ q.astype(bf16)) * s` — which XLA fuses into the matmul
 epilogue. The HBM read of the weight is the int8 tensor, so bandwidth-bound
@@ -28,6 +29,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from .llama import contraction_axis
+
 QUANTIZED_ATTN = ("wq", "wk", "wv", "wo")
 QUANTIZED_MLP = ("gate", "up", "down")
 
@@ -36,13 +39,17 @@ def is_quantized_leaf(w) -> bool:
     return isinstance(w, dict) and "q" in w and "s" in w
 
 
-def _quantize(w):
-    """(…, in, out) -> {"q": int8, "s": float32 (…, 1, out)}. Works on
-    numpy (host-side checkpoint path) and jax arrays (jitted init path).
-    A stacked expert leaf (L, E, in, out) goes one layer at a time, so the
-    float32 working copy is one layer's experts (0.5 GiB at OLMoE-1B-7B),
-    not the leaf's (8 GiB)."""
-    if w.ndim >= 4:
+def _quantize(w, axis: int = -2):
+    """(…, in, out) -> {"q": int8, "s": float32 (…, 1, out)}: one scale an
+    output channel, the maximum over the contraction `axis`
+    (`llama.contraction_axis`). A leaf stored (…, heads, head_dim, in)
+    contracts its LAST axis and its "s" is (…, heads, head_dim) — the same
+    scale a channel, where the channel lies. Works on numpy (host-side
+    checkpoint path) and jax arrays (jitted init path). A stacked expert
+    leaf (L, E, in, out) goes one layer at a time, so the float32 working
+    copy is one layer's experts (0.5 GiB at OLMoE-1B-7B), not the leaf's
+    (8 GiB)."""
+    if w.ndim >= 4 and axis == -2:
         if isinstance(w, np.ndarray):
             parts = [_quantize(layer) for layer in w]
             return {k: np.stack([p[k] for p in parts]) for k in ("q", "s")}
@@ -51,10 +58,11 @@ def _quantize(w):
         return jax.lax.map(_quantize, w)
     xp = np if isinstance(w, np.ndarray) else _jnp()
     wf = w.astype(xp.float32)
-    amax = xp.max(xp.abs(wf), axis=-2, keepdims=True)
+    amax = xp.max(xp.abs(wf), axis=axis, keepdims=True)
     scale = xp.maximum(amax, 1e-8) / 127.0
     q = xp.clip(xp.round(wf / scale), -127, 127).astype(xp.int8)
-    return {"q": q, "s": scale.astype(xp.float32)}
+    scale = scale.astype(xp.float32)
+    return {"q": q, "s": scale if axis == -2 else scale[..., 0]}
 
 
 def _jnp():
@@ -81,7 +89,7 @@ def quantize_params(cfg, params: dict) -> dict:
     layers = dict(params["layers"])
     attn = dict(layers["attn"])
     for name in QUANTIZED_ATTN:
-        attn[name] = _quantize(attn[name])
+        attn[name] = _quantize(attn[name], contraction_axis("attn", name))
     layers["attn"] = attn
     if "mlp" in layers:
         mlp = dict(layers["mlp"])
@@ -102,25 +110,25 @@ def quantize_params(cfg, params: dict) -> dict:
 def quantize_specs(cfg, specs: dict) -> dict:
     """Mirror quantize_params on a llama_param_specs tree: each quantized
     leaf's spec becomes {"q": <w spec>, "s": <w spec with the contraction
-    axis unsharded>} — the scale's axis -2 has size 1."""
+    axis unsharded>} — the scale's axis -2 has size 1; a leaf that contracts
+    its last axis (`llama.contraction_axis`) has a scale without it."""
     if cfg.quantization is None:
         return specs
     from jax.sharding import PartitionSpec as P
 
-    def scale_spec(spec: P) -> P:
+    def q(spec: P, axis: int = -2) -> dict:
         parts = list(spec)
-        if len(parts) >= 2:
+        if axis == -1:
+            parts = parts[:-1]
+        elif len(parts) >= 2:
             parts[-2] = None
-        return P(*parts)
-
-    def q(spec: P) -> dict:
-        return {"q": spec, "s": scale_spec(spec)}
+        return {"q": spec, "s": P(*parts)}
 
     out = dict(specs)
     layers = dict(specs["layers"])
     attn = dict(layers["attn"])
     for name in QUANTIZED_ATTN:
-        attn[name] = q(attn[name])
+        attn[name] = q(attn[name], contraction_axis("attn", name))
     layers["attn"] = attn
     if "mlp" in layers:
         mlp = dict(layers["mlp"])

@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .paged_attention_pallas import DECODE_VMEM_LIMIT
+
 NEG_INF = -1e30
 
 # tokens per page block, both kernels (8 pages of 32)
@@ -225,8 +227,10 @@ def mla_decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((b, nh, rank), q_abs.dtype),
         # rows run in order: each hands the next its first block in flight
+        # (the limit keeps a small pool out of VMEM: paged_attention_pallas)
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=DECODE_VMEM_LIMIT),
         interpret=interpret,
     )(block_tables, hist_len, jnp.reshape(step_k, (1,)), q_abs, kv, staged)
 

@@ -365,6 +365,28 @@ PREFILL_SUB_TILE = 128
 PREFILL_MAX_ITEMS = 4
 PREFILL_VMEM_BUDGET = 24 * 2**20
 PREFILL_VMEM_LIMIT = 32 * 2**20
+# The decode kernels (this file's two and ops/mla_attention_pallas.py's)
+# STATE a scoped-VMEM limit far above their own buffers (two page blocks in
+# flight, inside the 16 MiB a kernel gets unasked) — to keep XLA from parking
+# the kernel's POOL operand in VMEM. XLA reserves a custom call's stated
+# limit for the call's duration, and its memory-space assignment prefetches
+# an operand across the call only into what is left of a v5e's 128 MiB. The
+# pool is `pl.ANY`: where a layer's pool fitted (768 pages of 32 x 8 x 128
+# bf16 K and V, 50 MB each) the compiler prefetched every layer's WHOLE pool
+# a decode step, 3.5 GB a qwen3 step of which the rows' pages are a few
+# percent — `slice-done` 0.78 s and `copy-done` 0.72 s of an 8-s slice (my
+# chip run, PR 56, PERF.md §6); before, the copies of the q, k and v weights
+# stood in that VMEM. Neither an HBM memory space on the operand's BlockSpec
+# nor `pltpu.with_memory_space_constraint(kv, pltpu.HBM)` stops it (libtpu
+# 0.0.34: the custom call then carries `input_memory_space_colors` and the
+# prefetch stays). So the value is a capacity: compiled for a described v5e
+# (tests/test_chip_compile.py), a stated 16 MiB keeps the 768-page pool out
+# but lets a 512-page one back in; 96 MiB leaves 32 MiB, which takes no GQA
+# pool down to 256 pages nor a latent pool (32 x 640 bf16 a page) of 1,024
+# pages — a 512-page latent pool, 21 MB and 16k tokens, is still parked, and
+# 128 MiB would stop that too at the price of everything XLA itself keeps
+# there across the call (the window's temps 40 -> 66 MB). 96 is what ran.
+DECODE_VMEM_LIMIT = 96 * 2**20
 
 
 def _prefill_sub_tile(tt: int, qpk: int) -> int:
@@ -955,7 +977,8 @@ def _paged_decode_attention(
         out_shape=jax.ShapeDtypeStruct((b, nh, d), q.dtype),
         # rows run in order: each hands the next its first block in flight
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",) * len(grid)
+            dimension_semantics=("arbitrary",) * len(grid),
+            vmem_limit_bytes=DECODE_VMEM_LIMIT,
         ),
         interpret=interpret,
     )(

@@ -48,10 +48,10 @@ def llama_param_specs(cfg: ModelConfig) -> dict:
             lambda _: P(),
             jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
     attn = {
-        # [L, hidden, heads*head_dim] — L over pp stages, head axis over tp
-        "wq": P(PP_AXIS, None, TP_AXIS),
-        "wk": P(PP_AXIS, None, TP_AXIS),
-        "wv": P(PP_AXIS, None, TP_AXIS),
+        # [L, heads, head_dim, hidden] — L over pp stages, head axis over tp
+        "wq": P(PP_AXIS, TP_AXIS, None, None),
+        "wk": P(PP_AXIS, TP_AXIS, None, None),
+        "wv": P(PP_AXIS, TP_AXIS, None, None),
         # [L, heads*head_dim, hidden] — shard input (head) axis; psum after
         "wo": P(PP_AXIS, TP_AXIS, None),
     }

@@ -198,6 +198,57 @@ def system_routing(cfg, params, tokens: list[int], chunk: int | None = None,
     return [np.concatenate(layer) for layer in zip(*parts)]
 
 
+class _Matrices:
+    """A leaf the served tree stores (L, heads, head_dim, in) as the stack
+    of (in, heads * head_dim) matrices a reference reads — a layer at a
+    time, `view[i]`: no second copy of the stack is held."""
+
+    def __init__(self, leaf):
+        self.leaf = leaf
+
+    def __getitem__(self, i):
+        w = self.leaf[i]
+        return w.reshape(-1, w.shape[-1]).T
+
+
+class _KvB:
+    """W_kv_b, which the served tree holds as two leaves (llama._split_kv_b),
+    as the stack of (rank, heads * (nope + v)) matrices a reference reads."""
+
+    def __init__(self, wk_b, wv_b):
+        self.halves = (wk_b, wv_b)
+
+    def __getitem__(self, i):
+        w = jnp.concatenate([half[i] for half in self.halves], axis=1)
+        return w.reshape(-1, w.shape[-1]).T
+
+
+def reference_tree(params: dict) -> dict:
+    """The served tree as the references that read it directly take it
+    (reference_decoder.py, reference_sdar.py, reference_falcon_h1.py,
+    reference_glm_dsa.py): every matrix (in, out). The leaves of
+    `llama.HEADS_MAJOR` are viewed layer by layer (`_Matrices`; a latent
+    stack's two halves of W_kv_b as one "wkv_b", `_KvB`), an int8 leaf's
+    scale as the (L, 1, out) it is for every other matrix; the rest of the
+    tree is the served arrays themselves."""
+    layers = dict(params["layers"])
+    for stack, names in llama.HEADS_MAJOR.items():
+        if stack not in layers:
+            continue
+        attn = layers[stack] = dict(layers[stack])
+        if "wk_b" in attn:
+            attn["wkv_b"] = _KvB(attn.pop("wk_b"), attn.pop("wv_b"))
+        for name in names:
+            leaf = attn.get(name)
+            if isinstance(leaf, dict):
+                s = leaf["s"]
+                attn[name] = {"q": _Matrices(leaf["q"]),
+                              "s": s.reshape(s.shape[0], 1, -1)}
+            elif leaf is not None:
+                attn[name] = _Matrices(leaf)
+    return {**params, "layers": layers}
+
+
 # the architectures whose tree is stacked by layer kind: the view
 # (models/loader.py) that hands the plain reference the served weights
 # under its own tensor names
@@ -243,12 +294,15 @@ def compare(reference, cfg, params, prompts: list[list[int]],
     selects = bool(cfg.selecting_layers)  # (reads the served tree itself)
     by_name = (cfg.mla and not selects) or (
         cfg.architecture in _BY_KIND_VIEWS)
+    served_params = params
     if by_name:
         from ..models import loader
 
         view = (loader.DeepseekCheckpointView if cfg.mla
                 else getattr(loader, _BY_KIND_VIEWS[cfg.architecture]))
         params_ref = view(cfg, params)
+    else:
+        params = reference_tree(params)
     selection_reports: list = []
     if (cfg.linear_layers or selects) and cfg.num_experts and (
             served_routing is None):
@@ -312,7 +366,7 @@ def compare(reference, cfg, params, prompts: list[list[int]],
         if cfg.num_experts:
             ours_all = forced if (by_name or selects) and (
                 forced is not None) else (
-                system_routing(cfg, params, seq, routing_chunk,
+                system_routing(cfg, served_params, seq, routing_chunk,
                                routing_backend))
             # (a leading dense layer routes nothing: the reference says None)
             for ours, ref in zip(ours_all, [r for r in routing if r]):
@@ -364,7 +418,8 @@ def served_index_pages(reference, cfg, params, pool,
         fed = fed[:len(row["block_table"]) * block]
         got = jnp.asarray(pool[0, row["block_table"]].reshape(
             -1, pool.shape[3])[:len(fed)]).astype(jnp.float32)
-        want = reference.first_index_keys(keys, params, fed, key_dtype)
+        want = reference.first_index_keys(
+            keys, reference_tree(params), fed, key_dtype)
         worst = max(worst, float(
             jnp.linalg.norm(got - want) / jnp.linalg.norm(want)))
         tokens += len(fed)
@@ -487,6 +542,7 @@ def served_pages(reference, cfg, params, pool, prompts: list[list[int]],
     given back still holds what its row wrote. Returns the largest relative
     error (root of the summed squares, K and V each) over the rows."""
     keys = reference.published_keys(cfg)
+    params = reference_tree(params)
     pool = np.asarray(pool)
     block = pool.shape[2]
     worst, tokens = 0.0, 0
@@ -717,6 +773,7 @@ def compare_blocks(reference, cfg, params, prompts: list[list[int]],
     that pass's K/V) — and `shift` — the token at position i is read off
     the logits at position i - 1."""
     ref_cfg = ref_cfg or reference.published_keys(cfg)
+    params = reference_tree(params)
     t = ref_cfg["block_length"]
     worst = total = 0.0
     positions = ids_compared = passes = same_order = 0
