@@ -1,10 +1,19 @@
 """A selected-keys op's share of its roofline (`costs_dsa`): the least work
 an exact form does — from the program's `dsa_*` counters over the traced
 slice — over the peaks, divided by the device seconds the engine's own
-summary of the capture books to the op's named scopes in the programs of
-its kind (`ctx["device"]["last_capture"]["scopes"]`: rows [kind, group,
-scope, seconds, ops]; selection included: the indexer's op is its scores AND
-its top-k, the attention's its gather AND its softmax).
+summary of the capture books to the op in the programs of its kind
+(`ctx["device"]["last_capture"]`): XLA's own operations under the op's named
+scopes (`scopes` rows [kind, group, scope, seconds, ops]; selection included:
+the indexer's op is its scores AND its top-k, the attention's its gather AND
+its softmax) PLUS the custom calls of the op's kernel family (`kernels` rows
+[kind, family, seconds, n, group]: the summary books a Pallas kernel there
+and never to `scopes`, whatever scope it runs under). The day an op becomes
+a kernel its seconds move from the one list to the other and the share reads
+on. The kernel that takes an op over is NAMED for it (`KERNELS`, the repo's
+`kda_prefill_attention` / `mla_decode_attention` pattern):
+`dsa_index_prefill`, `dsa_prefill_attention`, `dsa_index_decode`,
+`dsa_decode_attention`; a kernel under another name is not counted, and the
+share then reads too high.
 
 `op`: `index_prefill` | `prefill_attn` (a chunk: compute-bound, its keys'
 bytes shared by a tile's queries — the indexer's context keys once are
@@ -25,6 +34,12 @@ SCOPES = {
     "decode_attn": ("window", ("dsa_gather", "dsa_attn")),
     "decode_step": ("window", None),
 }
+KERNELS = {
+    "index_prefill": ("dsa_index_prefill",),
+    "prefill_attn": ("dsa_prefill_attention",),
+    "index_decode": ("dsa_index_decode",),
+    "decode_attn": ("dsa_decode_attention",),
+}
 NEEDS = ("dsa_index_pairs_n", "dsa_selected_keys_n",
          "dsa_decode_index_pairs_n", "dsa_decode_selected_keys_n")
 
@@ -44,8 +59,13 @@ def read(ctx, op):
     prog = cap["programs"].get(kind)
     if not prog or prog["n"] <= 0:
         return None
-    seconds = prog["device_s"] if scopes is None else sum(
-        r[3] for r in cap["scopes"] if r[0] == kind and r[2] in scopes)
+    if scopes is None:
+        seconds = prog["device_s"]
+    else:
+        seconds = sum(r[3] for r in cap["scopes"]
+                      if r[0] == kind and r[2] in scopes)
+        seconds += sum(r[2] for r in cap["kernels"]
+                       if r[0] == kind and r[1] in KERNELS[op])
     if seconds <= 0:
         return None
 

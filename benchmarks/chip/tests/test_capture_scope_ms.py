@@ -120,12 +120,15 @@ def test_kernels_are_left_out_of_a_groups_sum_and_in_the_share():
 
 
 def test_the_thirteen_are_in_the_manifest_with_cells_that_exist():
+    """Found by NAME, together and in `WANT`'s order: what stands behind
+    them, and how many cells a list has grown to, is a later PR's to add."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         manifest = json.load(f)
     cells = {w["name"] for w in manifest["workloads"]}
-    mine = [m for m in manifest["per_layer"] if m["name"] in WANT]
-    assert [m["name"] for m in manifest["per_layer"][-13:]] == \
-        [m["name"] for m in mine] and len(mine) == 13
+    names = [m["name"] for m in manifest["per_layer"]]
+    at = names.index(next(iter(WANT)))
+    assert names[at:at + len(WANT)] == list(WANT) and len(WANT) == 13
+    mine = manifest["per_layer"][at:at + len(WANT)]
     for m in mine:
         assert set(m.get("workloads", cells)) <= cells, m["name"]
         assert m["source"] == "device_trace" and m["better"] == "lower"
@@ -133,5 +136,6 @@ def test_the_thirteen_are_in_the_manifest_with_cells_that_exist():
         assert m["unit"] == ("%" if m["name"].endswith("_share") else "ms")
     glue = {m["name"]: m["workloads"] for m in mine if "glue" in m["name"]}
     moe = {m["name"]: m["workloads"] for m in mine if "moe_route" in m["name"]}
-    assert len(glue) == 2 and all(len(w) == 4 for w in glue.values())
-    assert len(moe) == 2 and all(len(w) == 6 for w in moe.values())
+    # PR 52's four and six cells; a cell with such scopes joins at the end
+    assert len(glue) == 2 and all(len(w) >= 4 for w in glue.values())
+    assert len(moe) == 2 and all(len(w) >= 6 for w in moe.values())

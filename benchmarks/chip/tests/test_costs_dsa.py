@@ -129,38 +129,74 @@ def test_each_op_reads_its_scopes_and_its_counters():
         assert 0 < dsa_roofline.read(c, op) < 100, op
 
 
+FULL = {"dsa_index_pairs_n": 10**9, "dsa_selected_keys_n": 10**9,
+        "dsa_decode_index_pairs_n": 10**8,
+        "dsa_decode_selected_keys_n": 10**8,
+        "attn_prefill_keys_n": 10**5, "moe_decode_touched_n": 100,
+        "moe_decode_held_pairs_n": 100}
+
+
+@pytest.mark.parametrize("op, moved", [
+    ("index_prefill", ("dsa_index_score", "dsa_select")),
+    ("prefill_attn", ("dsa_attn",)),
+    ("index_decode", ("dsa_index_score",)),  # the top-k stays XLA's
+    ("decode_attn", ("dsa_attn",)),  # ... and so does the gather
+])
+def test_an_ops_roofline_follows_it_into_a_kernel(op, moved):
+    """The engine's summary books a custom call to `kernels` and never to
+    `scopes`: the same capture with an op's seconds moved from its scope rows
+    to a row of its kernel family reads the same share, the families are the
+    names the docstring gives, and a kernel of another family, or of the same
+    family in programs of the other kind, adds nothing."""
+    kind = dsa_roofline.SCOPES[op][0]
+    (family,) = dsa_roofline.KERNELS[op]
+    assert family in dsa_roofline.__doc__
+    was = capture()
+    rows = [r for r in was["scopes"] if r[0] == kind and r[2] in moved]
+    assert len(rows) == len(moved)
+    now = {**was,
+           "scopes": [r for r in was["scopes"] if r not in rows],
+           "kernels": [[kind, family, sum(r[3] for r in rows), 7.0, "mixer"]]}
+    share = dsa_roofline.read(ctx(FULL, was), op)
+    assert dsa_roofline.read(ctx(FULL, now), op) == pytest.approx(share)
+    other = "window" if kind == "prefill" else "prefill"
+    stray = {**was, "kernels": [[kind, "mla_decode_attention", 0.3, 7.0,
+                                 "mixer"], [other, family, 0.3, 7.0, "mixer"]]}
+    assert dsa_roofline.read(ctx(FULL, stray), op) == pytest.approx(share)
+    # the whole step's share is the program's seconds: it has both already
+    assert dsa_roofline.read(ctx(FULL, now), "decode_step") == pytest.approx(
+        dsa_roofline.read(ctx(FULL, was), "decode_step"))
+    assert set(dsa_roofline.KERNELS) == set(dsa_roofline.SCOPES) - {
+        "decode_step"}
+
+
 @pytest.mark.parametrize("op", sorted(dsa_roofline.SCOPES))
 def test_what_leaves_a_metric_out(op):
-    full = {"dsa_index_pairs_n": 10**9, "dsa_selected_keys_n": 10**9,
-            "dsa_decode_index_pairs_n": 10**8,
-            "dsa_decode_selected_keys_n": 10**8,
-            "attn_prefill_keys_n": 10**5, "moe_decode_touched_n": 100,
-            "moe_decode_held_pairs_n": 100}
-    assert dsa_roofline.read(ctx(full), op) is not None
+    assert dsa_roofline.read(ctx(FULL), op) is not None
     # a parent commit: no summary, or a failed reduction, or a CPU engine
-    assert dsa_roofline.read({**ctx(full), "device": None}, op) is None
+    assert dsa_roofline.read({**ctx(FULL), "device": None}, op) is None
     assert dsa_roofline.read(
-        ctx(full, {"error": "TimeoutError", "dir": "/x"}), op) is None
-    assert dsa_roofline.read(ctx(full, {**capture(), "chips": 0}), op) is None
+        ctx(FULL, {"error": "TimeoutError", "dir": "/x"}), op) is None
+    assert dsa_roofline.read(ctx(FULL, {**capture(), "chips": 0}), op) is None
     # ... or a program without the counters
-    bare = ctx(full)
+    bare = ctx(FULL)
     for doc in bare["trace_span"].values():
         for key in dsa_roofline.NEEDS:
             del doc["engine"][key]
     assert dsa_roofline.read(bare, op) is None
-    assert dsa_roofline.read({**ctx(full), "trace_span": {}}, op) is None
+    assert dsa_roofline.read({**ctx(FULL), "trace_span": {}}, op) is None
     # another model's configuration
     other = load(os.path.join(
         HERE, "configs", "kanana-2-30b-a3b-bf16-8l.json"))
-    assert dsa_roofline.read(ctx(full, cfg=other), op) is None
+    assert dsa_roofline.read(ctx(FULL, cfg=other), op) is None
     # no program of the kind in the slice, or none of the scopes
     kind = dsa_roofline.SCOPES[op][0]
     none = capture()
     del none["programs"][kind]
-    assert dsa_roofline.read(ctx(full, none), op) is None
+    assert dsa_roofline.read(ctx(FULL, none), op) is None
     if dsa_roofline.SCOPES[op][1]:
         assert dsa_roofline.read(
-            ctx(full, {**capture(), "scopes": []}), op) is None
+            ctx(FULL, {**capture(), "scopes": []}), op) is None
 
 
 def test_the_accepted_expert_readers_count_this_configuration_right():
@@ -183,19 +219,23 @@ def test_the_accepted_expert_readers_count_this_configuration_right():
 
 
 def test_the_manifest_entries():
+    """Every entry is found by its NAME: the driver holds a later PR to the
+    end of each list, so nothing here may hold "last" or a list's length."""
     manifest = load(os.path.join(REPO, "BENCHMARK.json"))
-    entry = manifest["configs"][-1]
-    assert entry["name"] == CFG["name"] == "glm-5.2-bf16-ep16-7l"
+    assert CFG["name"] == "glm-5.2-bf16-ep16-7l"
+    entry = next(c for c in manifest["configs"] if c["name"] == CFG["name"])
+    assert entry["file"].endswith("configs/glm-5.2-bf16-ep16-7l.json")
     assert entry["source"] == CFG["source"]
     assert sorted(entry["reduced"]) == sorted(CFG["reduced"])
-    cell = manifest["workloads"][-1]
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
-        CELL, CFG["name"], "longdoc-closed", 1)
+    cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        CFG["name"], "longdoc-closed", 1)
     names = [m["name"] for m in manifest["per_layer"]]
     by_name = {m["name"]: m for m in manifest["per_layer"]}
-    # the seven new metrics, together, at the end of the list (the check
-    # reads an entry put in the middle as a change to the one it displaced)
-    assert tuple(names[-7:]) == NEW
+    # the seven metrics of PR 55, together and in this order, wherever the
+    # list has grown to since
+    at = names.index(NEW[0])
+    assert tuple(names[at:at + len(NEW)]) == NEW
     for name in NEW:
         m = by_name[name]
         assert m["workloads"] == [CELL] and m["moves"] == "out_tok_per_s"
@@ -206,18 +246,15 @@ def test_the_manifest_entries():
     assert [n for n in names if n.endswith("decode_step_roofline")
             and CELL in by_name[n]["workloads"]] == [
         "dsa_decode_step_roofline"]
-    # of the accepted metrics whose readers count this configuration right
-    # (the tests above), the one whose list may grow at its end
-    assert by_name["moe_read_share"]["workloads"][-1] == CELL
-    # ... and not the three whose last entry test_costs_ling.py holds to be
-    # Ling's cell, nor those whose counts are dense over the latents, nor
-    # the four lists an accepted test pins to their length
-    for name in ("moe_touched_share", "moe_held_pairs_per_expert",
-                 "moe_prefill_experts_roofline",
-                 "mla_prefill_attn_roofline", "mla_decode_attn_roofline",
-                 "mla_decode_step_roofline", "chunk_glue_ms_per_ktok",
-                 "chunk_moe_route_ms_per_ktok", "window_glue_ms",
-                 "window_moe_route_ms"):
+    # the accepted metrics whose readers count this configuration right (the
+    # test above): ratios of the program's counters over HELD experts, and
+    # `hidden_size` x `moe_intermediate_size` a touched expert
+    for name in ("moe_read_share", "moe_touched_share",
+                 "moe_held_pairs_per_expert", "moe_prefill_experts_roofline"):
+        assert CELL in by_name[name]["workloads"]
+    # ... and not those whose counts are dense over the latents
+    for name in ("mla_prefill_attn_roofline", "mla_decode_attn_roofline",
+                 "mla_decode_step_roofline"):
         assert CELL not in by_name[name]["workloads"]
     # every width as published; the cut's keys beside their published values
     assert (CFG["hidden_size"], CFG["moe_intermediate_size"],
