@@ -290,6 +290,62 @@ FALCON_H1_ARMS = (
         "embedding_multiplier", "lm_head_multiplier",
         "attention_out_multiplier", "key_multiplier", "ssm_in_multiplier",
         "ssm_out_multiplier", "ssm_multipliers", "mlp_multipliers")))
+# Olmo-Hybrid-7B at 12 layers (bf16 weights, activations and K/V pages,
+# float32 state slots of 30 x 96 x 192; the Gated DeltaNet kernels in 9
+# layers and the paged kernels in 3, in one program;
+# benchmarks/chip/configs/olmo-hybrid-7b-bf16-12l.json) against
+# reference/olmo_hybrid.py (float32, the token-by-token recurrence, plain
+# softmax attention; nothing routes). Rows as Falcon-H1's: the cell's own
+# lengths, all 16 seats in flight with one of 48 tokens, 32 decoded positions
+# each. Each limit lies between what the served configuration gave and what
+# has to come out as not correct (PR 58, PERF.md §6; the readings are beside
+# each limit): the served logprobs of the SHORTEST prompt against the
+# reference's fault arms — every `assumed` reading's alternative
+# (OLMO_HYBRID_ARMS).
+# max |Δlogprob| (my chip runs, PR 58; seeds 5800000101 and 3300000217, 16
+# rows, 512 positions, 4,096 logprobs a seed; under these limits again at
+# seeds 5800000501 and 3300000607 from the committed files: served 0.1608,
+# 0.1460 / mean 0.0314, 0.0314, the gentlest arm 0.3173, 0.4043 / 0.0859,
+# 0.0869, pages 1.57e-2, 1.59e-2 against the fp8 arm's 3.09e-2, 3.08e-2,
+# exit 0 both): served 0.1593, 0.1651; the
+# gentlest arm, a QK-norm a head instead of the flat one, 0.4030, 0.3797; a
+# rope of base 500,000 1.73, 1.59, of 10,000 1.77, 1.94; a sigmoid output
+# gate 8.21, 7.46; a pre-norm linear block 7.79, 8.08. The limit lies
+# between: 1.6 x over the one, 1.5 x under the other.
+REFERENCE_ATOL_OLMO_HYBRID = 0.26
+# mean |Δlogprob|: served 0.0307, 0.0314; the same arms 0.0880, 0.0972 (the
+# QK-norm a head), 0.46-0.62 (a rope), 4.28-4.50 (the gate, the pre-norm
+# block): 1.75 x over the one, 1.6 x under the other
+REFERENCE_MEAN_ATOL_OLMO_HYBRID = 0.055
+# A precision below the configuration's is a bfloat16 STATE, and logprobs
+# cannot tell it (0.1993 / mean 0.0509 and 0.2200 / 0.0470 on the shortest
+# prompt, inside both limits above): the SERVED state's limit does
+# (KDA_STATE_RTOL, 1e-3: each row's slot of the last linear layer against the
+# reference's recurrence over the q, k, v, g, beta the served programs fed
+# it): served 4.7e-6, 1.7e-6 (16 rows, 138,920 and 102,268 tokens), the
+# reference with its state rounded to bfloat16 at every token 9.2e-3,
+# 1.2e-2 — that arm fails by the state's limit ALONE.
+# A float8 K/V pool is the other precision below (the 3 full layers hold 48
+# KiB a token: most of the cache's bytes), and logprobs cannot tell it
+# either: the fp8 arm read 0.1494 / mean 0.0309 and 0.2036 / 0.0322 beside
+# the served 0.1593 / 0.0307 and 0.1651 / 0.0314. The served PAGES have a
+# limit of their own (reference_check.served_pages): each row's pages of the
+# first FULL layer (layer 3: behind three Gated DeltaNet layers and their
+# SwiGLUs, all bf16 — not one norm and two projections from the embedding as
+# Falcon-H1's first layer, so the served reading is what those layers'
+# activations scatter by, not bfloat16's 2^-9) against the reference's K
+# (after the flat norm) and V. Served 1.54e-2 (16 rows, 102,252 tokens), the
+# fp8 arm 3.10e-2 (1.54e-2 and float8's 2^-4 / sqrt 3 in quadrature): the
+# limit lies between, 1.4 x over the one and 1.4 x under the other, and the
+# fp8 arm fails by it ALONE (its state reads 5.3e-6). (The first seed's rows
+# summed to 138,920 tokens, more than the pool's 131,072: two rows waited,
+# pages were given back and written again, and both arms read 0.98 — the
+# rows now fit the pool: `_fit_pool`.)
+KV_PAGES_RTOL_OLMO_HYBRID = 2.2e-2
+OLMO_HYBRID_ARMS = (
+    {"pre_norm_linear": True}, {"output_gate": "sigmoid"},
+    {"rope_theta": 10000.0}, {"rope_theta": 500000.0},
+    {"qk_norm_per_head": True})
 # SDAR-30B-A3B (generation by diffusion over blocks, bf16 weights, 8 of 48
 # layers; benchmarks/chip/configs/sdar-30b-a3b-bf16-8l.json): the served
 # windows of block passes against reference/sdar.py's forward over the same
@@ -1121,7 +1177,8 @@ def child_reference(args) -> int:
     family = {"deepseek_v3": "mla", "laguna": "laguna",
               "solar_open2": "solar_open2", "sdar_moe": "sdar",
               "ling_flash": "ling", "falcon_h1": "falcon_h1",
-              "glm_moe_dsa": "glm_dsa"}.get(
+              "glm_moe_dsa": "glm_dsa",
+              "olmo_hybrid": "olmo_hybrid"}.get(
         config.get("model_type"), "decoder")
     # (the two families whose reference follows the served routing and
     # whose cell is the long-document one)
@@ -1154,6 +1211,8 @@ def child_reference(args) -> int:
                  ROUTING_DISAGREEMENT_MAX_LING, ROUTING_MARGIN_MAX_LING),
         "falcon_h1": (REFERENCE_ATOL_FALCON_H1, REFERENCE_MEAN_ATOL_FALCON_H1,
                       None, None),
+        "olmo_hybrid": (REFERENCE_ATOL_OLMO_HYBRID,
+                        REFERENCE_MEAN_ATOL_OLMO_HYBRID, None, None),
     }[family]
     flags = (config["rehearse"] if args.rehearse else config)["engine_flags"]
     dev = jax.devices()[0]
@@ -1179,17 +1238,31 @@ def child_reference(args) -> int:
         rng.uniform(np.log(lo), np.log(hi), size=n_prompts)).astype(int)
     lengths[:2] = [hi, (chunk + hi) // 2]  # two prompts cross a chunk edge
     # (the families with layers that hold a recurrent state)
-    holds_state = family in ("solar_open2", "ling", "falcon_h1")
+    holds_state = family in (
+        "solar_open2", "ling", "falcon_h1", "olmo_hybrid")
+    # (the families whose reference reads the served tree itself and takes
+    # its fault arms as keywords; every seat in flight; the served pages of
+    # the first layer that holds any have a limit of their own)
+    own_arms = {"falcon_h1": FALCON_H1_ARMS,
+                "olmo_hybrid": OLMO_HYBRID_ARMS}.get(family)
     # (whether an fp8 pool reaches most of the model: K/V or latent pages in
     # more than half of its layers)
     model = engine_config_from_args(build_parser().parse_args(flags)).model
     pages_in_most_layers = 2 * len(model.page_layers) > model.num_layers
     state_rtol = SSD_STATE_RTOL if family == "falcon_h1" else KDA_STATE_RTOL
-    if family == "falcon_h1" and not args.rehearse:
+    pages_rtol = (KV_PAGES_RTOL_OLMO_HYBRID if family == "olmo_hybrid"
+                  else KV_PAGES_RTOL)
+    if own_arms and not args.rehearse:
         # every seat in flight: fifteen rows of the cell's lengths (and the
         # short one below)
         lengths = np.concatenate([lengths, np.exp(rng.uniform(
             np.log(lo), np.log(hi), size=9)).astype(int)])
+    if own_arms:
+        # (every row in flight at once, or a page given back is written
+        # again before `served_pages` reads it)
+        lengths = _fit_pool(
+            lengths, n_out, int(flags[flags.index("--num-blocks") + 1])
+            * int(flags[flags.index("--block-size") + 1]), lo)
     if holds_state:
         # ... and one SHORT prompt, the one the faulty references are held
         # to: what a slot's previous holder left (a state, a convolution
@@ -1210,7 +1283,7 @@ def child_reference(args) -> int:
         # reference follows it — reference_check.compare says why; a model
         # with state slots: what its last such layer's recurrence was fed)
         tap = (reference_check.RoutingTap()
-               if mla and family != "falcon_h1" else None)
+               if mla and not own_arms else None)
         linear = ecfg.model.state_layers
         state_tap = reference_check.StateTap(linear[-1]) if linear else None
         # (ling: and what its last expert layer's ROUTER was fed and chose)
@@ -1238,9 +1311,9 @@ def child_reference(args) -> int:
                 ecfg.model, engine.runner.params)
             out["router"] = reference_check.served_router(
                 reference, ecfg.model, router_tap, served_view)
-        if family == "falcon_h1":
-            first_pages = np.asarray(
-                llama.page_pool(engine.runner.kv_caches[0]))
+        if own_arms:
+            first_pages = np.asarray(llama.page_pool(
+                engine.runner.kv_caches[ecfg.model.page_layers[0]]))
         out["engine_s"] = round(time.monotonic() - t0, 1)
         print(f"reference check: served in {out['engine_s']} s",
               file=sys.stderr, flush=True)
@@ -1259,18 +1332,18 @@ def child_reference(args) -> int:
             routing_backend=(
                 out["attention"]["prefill"]["resolved"] if mla else "xla"),
             served_routing=tap))
-        if family == "falcon_h1":
+        if own_arms:
             out["pages"] = reference_check.served_pages(
                 reference, ecfg.model, params, first_pages, prompts, served)
         out["reference_s"] = round(time.monotonic() - t0, 1)
         print(f"reference check: compared in {out['reference_s']} s",
               file=sys.stderr, flush=True)
-        if faults and family == "falcon_h1":
+        if faults and own_arms:
             # (its faulty references are its own fault arms; the bfloat16
             # state is the state's limit's to tell, on every row)
             short = int(np.argmin(lengths))
             out["faults"] = {}
-            for arms in FALCON_H1_ARMS:
+            for arms in own_arms:
                 got = reference_check.compare(
                     reference, ecfg.model, params, [prompts[short]],
                     [served[short]], reference_arms=arms)
@@ -1336,27 +1409,28 @@ def child_reference(args) -> int:
                 and ("state" not in arm
                      or arm["state"]["state_rel_err"] <= state_rtol)
                 and ("pages" not in arm
-                     or arm["pages"]["pages_rel_err"] <= KV_PAGES_RTOL)
+                     or arm["pages"]["pages_rel_err"] <= pages_rtol)
                 and ("router" not in arm
                      or arm["router"]["choice_disagreement_share"]
                      <= ROUTER_CHOICE_DISAGREEMENT_MAX_LING))
 
     if holds_state:
         report["state_rtol"] = state_rtol
-    if family == "falcon_h1":
-        report["pages_rtol"] = KV_PAGES_RTOL
+    if own_arms:
+        report["pages_rtol"] = pages_rtol
     if family == "ling":
         report["router_choice_disagreement_max"] = (
             ROUTER_CHOICE_DISAGREEMENT_MAX_LING)
     report["ok"] = bool(
         inside(served) and routing_ok
         and not any(inside(f) for f in served.get("faults", {}).values()))
-    if args.fp8_arm and pages_in_most_layers:
+    if args.fp8_arm and (pages_in_most_layers or family == "olmo_hybrid"):
         # (solar_open2, ling: the precision below the configuration's is a
         # bfloat16 STATE, one of its faulty references: most of its cache
         # is state, and an fp8 pool would touch the 2 GQA layers — ling:
         # the one latent layer — only. falcon_h1 has BOTH arms: every layer
-        # holds K/V pages beside its state)
+        # holds K/V pages beside its state; olmo_hybrid as well: its 3 full
+        # layers hold 45 KiB a token, most of the cache's bytes)
         # (a quantized pool resolves 'auto' to XLA attention, whose
         # gathered history and scores a long-document prompt cannot
         # afford: the latent kernels widen fp8 pages themselves)
@@ -1718,6 +1792,19 @@ def _child_reference_sdar(args, config: dict, reference) -> int:
         and not any(inside(f) for f in report["faults"].values()))
     emit(report)
     return 0 if report["ok"] else 1
+
+
+def _fit_pool(lengths, n_out: int, pool_tokens: int, lo: int):
+    """The rows' lengths, the longest of the drawn ones (not the two that
+    cross a chunk edge) halved until all rows, their answers and a page of
+    slack each fit a pool of `pool_tokens` at once."""
+    lengths = lengths.copy()
+    while int(lengths.sum()) + len(lengths) * (n_out + 64) > pool_tokens:
+        longest = 2 + int(lengths[2:].argmax())
+        if lengths[longest] // 2 < lo:
+            break
+        lengths[longest] //= 2
+    return lengths
 
 
 def _faulty_references(reference, family: str = "laguna") -> dict:

@@ -518,33 +518,27 @@ def test_timing_and_metrics_names_are_the_contract(cfg, kit):
     assert not set(mc.DEBUG_TIMING_SSD_COUNTS) & set(solar.flightrec.timing())
 
 
-# PR 52's pin: its thirteen metrics are `per_layer`'s LAST thirteen. The
-# driver's check takes an entry put anywhere but at the END of a list as a
-# change to the entry it displaced (it refused PR 55 for exactly that), so a
-# PR that adds a per-layer metric cannot keep this one case; a `benchmark` PR
-# has to relax it (PERF.md §7). Everything else the case holds is held below.
-_PINNED_LAST = ("benchmarks/chip/tests/test_capture_scope_ms.py::"
-                "test_the_thirteen_are_in_the_manifest_with_cells_that_exist")
-
-
 def test_the_benchmarks_own_tests_pass():
     """Nothing else in tier-1 runs benchmarks/chip/tests: the manifest, the
     data files' cross-references and the cost functions this PR extends
-    (PERF.md §7, PR 48's open item)."""
+    (PERF.md §7, PR 48's open item). Every case runs: since PR 57 they find
+    their entries by name, so a PR that appends a metric deselects none."""
     done = subprocess.run(
         [sys.executable, "-m", "pytest", "benchmarks/chip/tests", "-q",
-         "-p", "no:cacheprovider", "--deselect", _PINNED_LAST],
+         "-p", "no:cacheprovider"],
         cwd=REPO, env={**os.environ, "JAX_PLATFORMS": "cpu"},
         capture_output=True, text=True, timeout=60)
     assert done.returncode == 0, done.stdout[-2000:] + done.stderr[-2000:]
-    assert "1 deselected" in done.stdout, done.stdout[-500:]
+    assert "deselected" not in done.stdout, done.stdout[-500:]
 
 
 def test_the_captures_thirteen_stand_together_and_only_dsa_follows():
-    """What the deselected case holds, but for "last": the thirteen are in
-    the manifest, together and in their order, with cells that exist, their
-    four pinned lists at 4 / 6 cells; and the ONLY entries behind them are
-    PR 55's seven, by name, so the next one to append edits this line."""
+    """PR 52's thirteen by name: in the manifest, together and in their
+    order, with cells that exist, their fields, the glue lists at the four
+    cells of the state and window models or more, the moe_route lists at
+    the six MoE cells or more. (Nothing about what follows them: a PR
+    appends at the END of `per_layer`, and
+    benchmarks/chip/tests/test_append_safe.py guards that property.)"""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         manifest = json.load(f)
     thirteen = (
@@ -557,11 +551,6 @@ def test_the_captures_thirteen_stand_together_and_only_dsa_follows():
     names = [m["name"] for m in manifest["per_layer"]]
     at = names.index(thirteen[0])
     assert tuple(names[at:at + 13]) == thirteen
-    assert names[at + 13:] == [
-        "dsa_index_prefill_roofline", "dsa_prefill_attn_roofline",
-        "dsa_index_decode_roofline", "dsa_decode_attn_roofline",
-        "dsa_decode_step_roofline", "dsa_selected_share",
-        "dsa_index_cache_share"]
     cells = {w["name"] for w in manifest["workloads"]}
     mine = manifest["per_layer"][at:at + 13]
     for m in mine:
@@ -571,5 +560,6 @@ def test_the_captures_thirteen_stand_together_and_only_dsa_follows():
         assert m["unit"] == ("%" if m["name"].endswith("_share") else "ms")
     glue = [m["workloads"] for m in mine if "glue" in m["name"]]
     moe = [m["workloads"] for m in mine if "moe_route" in m["name"]]
-    assert [len(w) for w in glue] == [4, 4]
-    assert [len(w) for w in moe] == [6, 6]
+    assert len(glue) == len(moe) == 2
+    assert all(len(w) >= 4 for w in glue)
+    assert all(len(w) >= 6 for w in moe)

@@ -106,6 +106,8 @@ def test_reference_refuses_what_it_does_not_compute():
     ("reference_falcon_h1.py", "falcon_h1.py"),  # pages AND state in one
     #                                              layer (ISSUE 50)
     ("reference_glm_dsa.py", "glm_dsa.py"),  # selected keys (ISSUE 55)
+    ("reference_olmo_hybrid.py", "olmo_hybrid.py"),  # a gate a head, a
+    #                                      96 x 192 state (ISSUE 58)
 ])
 def test_benchmark_copy_is_byte_identical(package_file, benchmark_file):
     """benchmarks/chip/reference/<file> is the yardstick's own copy: a later

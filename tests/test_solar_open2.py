@@ -251,8 +251,13 @@ def test_the_report_names_the_prefill_kernels_grid_step(cfg):
     assert (one["heads_per_step"], one["block_tokens"]) == (1, 64)
     assert tiny["qkv"]["heads_per_tile"] == ksc.heads_per_tile(
         cfg.linear_num_heads, cfg.linear_head_dim)
+    # (beside what a slot holds of one layer, whatever runs the recurrence:
+    # the state's geometry, the gate's granularity — ISSUE 58)
     assert linear_backend_report(solar, "xla", "xla") == {
-        "decode": "xla", "prefill": "xla"}
+        "decode": "xla", "prefill": "xla",
+        "state_geometry": [64, 128, 128], "gate_per": "channel",
+        "output_gate": "sigmoid",
+        "slot_bytes_a_layer": 4 * 2**20 + 144 * 2**10}
 
 
 @pytest.mark.parametrize("heads,d", [(4, 16), (8, 128)])

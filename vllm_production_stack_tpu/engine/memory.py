@@ -167,15 +167,21 @@ def param_bytes(cfg: ModelConfig, tp: int = 1, pp: int = 1,
             # the KDA mixer (models/llama.py:attn_linear): q, k, v, o; the
             # two low-rank pairs; the step size's projection; the taps; the
             # output norm; A_log and dt_bias in float32
-            lhd = cfg.linear_num_heads * cfg.linear_head_dim
+            lh = cfg.linear_num_heads
+            replicated = norms * h  # (a flat QK-norm is the page layers')
+            kw, vw = lh * cfg.linear_head_dim, lh * cfg.linear_value_dim
             r = cfg.linear_gate_rank
-            sharded = (4 * h * lhd
-                       # (rank 0: both gates project at full rank)
-                       + 2 * ((h * r + r * lhd) if r else h * lhd)
-                       + h * cfg.linear_num_heads
-                       + STATE_CONV_TAPS * 3 * lhd
-                       + cfg.linear_head_dim)
-            f32_extra += 4 * (cfg.linear_num_heads + lhd)
+            # the decay gate projects to a key channel or to a head
+            # (`linear_gate_per`), the output gate to the values
+            gw = kw if cfg.linear_gate_per == "channel" else lh
+
+            def gate(width):  # (rank 0: a gate projects at full rank)
+                return (h * r + r * width) if r else h * width
+
+            sharded = (2 * h * kw + 2 * h * vw + gate(gw) + gate(vw)
+                       + h * lh + STATE_CONV_TAPS * (2 * kw + vw)
+                       + cfg.linear_value_dim)
+            f32_extra += 4 * (lh + gw)
         elif spec.latent:
             # the latent mixer (models/llama.py:attn_latent): its four
             # matrices, the latent's norm, the gate a head
@@ -225,7 +231,8 @@ def kv_block_bytes(cfg: ModelConfig, block_size: int, tp: int = 1,
             * dtype_bytes(kv_dtype or cfg.dtype)
             + index_block_bytes(cfg, block_size, kv_dtype)
         ) // pp)
-    kvh = max(1, cfg.num_kv_heads // tp)
+    # (the heads a PAGE holds: ModelConfig.page_kv_heads)
+    kvh = max(1, cfg.pool_kv_heads // tp)
     # (a layer that holds state has no pages: LayerSpec.holds_pages)
     layers = {None: len(cfg.page_layers), "full": len(cfg.full_layers),
               "window": len(cfg.window_layers)}[group]
@@ -300,7 +307,7 @@ def state_slot_bytes(model: ModelConfig) -> int:
     whatever the context length: a layer's float32 state and its
     convolution's tail — the last taps - 1 rows of the convolved channels
     in the model's dtype —, both of the geometry of the layer's RECURRENCE
-    (ModelConfig.state_geometry: (heads, D, D) and q | k | v for the gated
+    (ModelConfig.state_geometry: (heads, Dk, Dv) and q | k | v for the gated
     delta rule, (heads, P, N) and x | B | C for the state-space duality).
     0 for a model without such layers."""
     total = 0

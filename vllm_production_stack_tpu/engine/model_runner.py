@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -44,7 +45,7 @@ from ..parallel.sharding import (
 )
 from .compile_watch import CompileWatch, program_memory_bytes
 from .flightrec import FlightRecorder
-from .config import EngineConfig
+from .config import STATE_CONV_TAPS, EngineConfig
 from .sampling import (
     SUPPRESS_IDS, apply_grammar_mask, greedy_argmax, sample,
     suppress_stop_tokens,
@@ -512,27 +513,47 @@ def _collect_logprobs(logits: jax.Array, tokens: jax.Array):
 
 def linear_backend_report(cfg, attention_backend: str, prefill_backend: str,
                           max_tokens: int = 2048) -> dict:
-    """Which form the linear-attention (KDA) layers' programs hold, and for
-    the chunked prefill kernel the shape of its grid step at this model's
+    """Which form the linear-attention (delta-rule) layers' programs hold —
+    by the gate's granularity the KDA kernels (a gate a key channel) or the
+    Gated DeltaNet ones (a gate a head) of ops/kda_attention.py — beside
+    what a state slot holds of ONE layer (`state_geometry` (heads, Dk, Dv)
+    float32 and the convolution's tail: `slot_bytes_a_layer`), and for the
+    chunked prefill kernel the shape of its grid step at this model's
     heads: `heads_per_step` heads x one row's part of a `block_tokens`
-    block (ops/kda_attention.py reads both off the shapes); `qkv`: the
-    pass that makes q, k and v beside it (ops/kda_short_conv.py) and a grid
-    step's tile, heads x tokens of a chunk of `max_tokens` positions (the
-    largest prefill bucket)."""
-    report = {"decode": ("kda_decode_attention"
+    block; `qkv`: the pass that makes q, k and v beside it — ONE Pallas
+    pass (ops/kda_short_conv.py; a grid step's tile, heads x tokens of a
+    chunk of `max_tokens` positions, the largest prefill bucket) where the
+    three projections are equally wide, else XLA's."""
+    from .memory import dtype_bytes
+
+    per_head = cfg.linear_gate_per == "head"
+    family = "gdn" if per_head else "kda"
+    shape, channels = cfg.state_geometry("delta")
+    lh, dk, dv = shape
+    report = {"decode": (f"{family}_decode_attention"
                          if attention_backend != "xla" else "xla"),
-              "prefill": "xla"}
+              "prefill": "xla",
+              "state_geometry": list(shape), "gate_per": cfg.linear_gate_per,
+              "output_gate": cfg.linear_output_gate,
+              "slot_bytes_a_layer": math.prod(shape) * 4 + (
+                  STATE_CONV_TAPS - 1) * channels * dtype_bytes(cfg.dtype)}
     if prefill_backend.startswith("pallas"):
         report.update(
-            prefill="kda_prefill_attention",
-            heads_per_step=kda_attention.heads_per_step(
-                cfg.linear_num_heads, cfg.linear_head_dim,
-                jnp.dtype(cfg.dtype).itemsize),
+            prefill=f"{family}_prefill_attention",
+            heads_per_step=(
+                kda_attention.gdn_heads_per_step(lh) if per_head
+                else kda_attention.heads_per_step(
+                    lh, dk, jnp.dtype(cfg.dtype).itemsize)),
             block_tokens=kda_attention.BLOCK,
-            qkv={"op": "kda_short_conv",
-                 "heads_per_tile": kda_short_conv.heads_per_tile(
-                     cfg.linear_num_heads, cfg.linear_head_dim),
-                 "token_tile": kda_short_conv.token_tile(max_tokens)})
+            qkv=({"op": "kda_short_conv",
+                  "heads_per_tile": kda_short_conv.heads_per_tile(lh, dk),
+                  "token_tile": kda_short_conv.token_tile(max_tokens)}
+                 if dk == dv else {"op": "xla"}))
+        if per_head:
+            # what the kernels pad a head's keys and values to, in the
+            # chunk's activations only (ops/kda_attention.py "THE LANES")
+            report["lanes"] = [kda_attention.whole_lanes(dk),
+                               kda_attention.whole_lanes(dv)]
     return report
 
 
@@ -627,7 +648,8 @@ class ModelRunner:
         # own (engine/kv_cache.py:WindowPageGroup): a second block table,
         # written span and slot vector ride beside the full group's, as
         # (full, window) pairs (models/llama.py:forward)
-        from .memory import resolve_state_slots, resolve_window_blocks
+        from .memory import (
+            resolve_state_slots, resolve_window_blocks, state_slot_bytes)
 
         self.num_window_blocks = resolve_window_blocks(config)
         self._window_group = self.num_window_blocks is not None
@@ -635,6 +657,16 @@ class ModelRunner:
         # slots (engine/kv_cache.py:StateSlots): `state_slots` a bank, two
         # banks; each program takes its rows' (read, write) slot indices
         self.state_slots = resolve_state_slots(config)
+        if cfg.linear_layers:
+            logger.info(
+                "%s: %d delta-rule layers hold a float32 state of (heads, "
+                "keys, values) = %s a row, one decay gate a %s, output gate "
+                "%s: %d slots a bank x 2 banks x %d bytes a slot over the "
+                "layers that hold state",
+                cfg.model, len(cfg.linear_layers),
+                cfg.state_geometry("delta")[0], cfg.linear_gate_per,
+                cfg.linear_output_gate, self.state_slots,
+                state_slot_bytes(cfg))
         self.kv_caches = self._new_kv_caches()
         self._use_lora = config.lora.max_loras > 0
         if self._use_lora:
@@ -990,7 +1022,7 @@ class ModelRunner:
         # latent_width), one "kv head" wide
         kv_heads, head_dim = (
             (1, model.latent_width) if model.latent_layers
-            else (model.num_kv_heads, model.head_dim))
+            else (model.pool_kv_heads, model.head_dim))
         if backend == "auto":
             return resolve_auto_prefill_backend(
                 block_size=self.config.cache.block_size,
@@ -1015,7 +1047,7 @@ class ModelRunner:
             )
         if (backend == "pallas" and not model.latent_layers
                 and not _pages_fill_tiles(
-                    model.num_kv_heads // tp, model.head_dim, kv_itemsize)):
+                    model.pool_kv_heads // tp, model.head_dim, kv_itemsize)):
             raise ValueError(
                 "prefill_attention_backend='pallas' needs a pool whose "
                 f"pages fill whole HBM tiles ({model.num_kv_heads // tp} kv "

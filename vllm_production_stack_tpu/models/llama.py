@@ -40,6 +40,8 @@ from ..ops.attention import (
     write_kv_pages_blockwise,
 )
 from ..ops.kda_attention import (
+    gdn_decode_attention,
+    gdn_prefill_attention,
     kda_decode_attention,
     kda_decode_xla,
     kda_prefill_attention,
@@ -419,8 +421,8 @@ def _init_params_by_kind(cfg: ModelConfig, rng: jax.Array) -> dict:
     "ssd") owns leaves in its attention stack AND in a fifth, "attn_ssm"
     (`attn_ssm` below)."""
     unsaid = [name for name in (
-        "attention_bias", "qk_norm", "qk_norm_flat", "sandwich_norms",
-        "post_norms_only") if getattr(cfg, name)]
+        "attention_bias", "qk_norm", "sandwich_norms")
+        if getattr(cfg, name)]
     if unsaid:
         raise ValueError(
             f"{cfg.model}: a param tree stacked by layer kind has no leaves "
@@ -458,39 +460,47 @@ def _init_params_by_kind(cfg: ModelConfig, rng: jax.Array) -> dict:
             # one gate a head, or one a head and channel
             leaves["wg"] = w(
                 n, h, nh * (hd if cfg.attn_output_gate == "element" else 1))
+        if cfg.qk_norm_flat:
+            # OLMo-2's: one weight a channel of the FLAT projection
+            leaves["q_norm"] = jnp.ones((n, nh * hd), dt)
+            leaves["k_norm"] = jnp.ones((n, nkv * hd), dt)
         return leaves
 
     def attn_linear(n):
-        """The KDA layers' stack (`_kda_attention`): q, k, v and o
-        projections over heads x D keys and values alike; the decay gate's
-        and the output gate's low-rank pairs; the step size's projection;
-        the depthwise convolution's taps over q | k | v (tap 3 multiplies
-        the current token); per head the log of the decay's rate (drawn as
-        log U(1, 16)) and per channel the bias of its softplus (the inverse
-        softplus of a step drawn log-uniform in [0.001, 0.1], both as the
-        gated delta rule's public implementations initialise them: decays
-        from barely any to e^-1.6 a token at a zero gate input; under the
-        bounded gate the bias that gives the same decays); the output
-        norm's weight, shared by the heads."""
-        lh, ld, r = (cfg.linear_num_heads, cfg.linear_head_dim,
-                     cfg.linear_gate_rank)
+        """The delta-rule layers' stack (`_kda_attention`): q and k
+        projections over heads x Dk keys, v and o over heads x Dv values;
+        the decay gate's (to a key channel, or to a head:
+        `linear_gate_per`) and the output gate's (to the values)
+        projections, low-rank pairs or full rank; the step size's
+        projection; the depthwise convolution's taps over q | k | v (tap 3
+        multiplies the current token); per head the log of the decay's
+        rate (drawn as log U(1, 16)) and per gate the bias of its softplus
+        (the inverse softplus of a step drawn log-uniform in [0.001, 0.1],
+        both as the gated delta rule's public implementations initialise
+        them: decays from barely any to e^-1.6 a token at a zero gate
+        input; under the bounded gate the bias that gives the same
+        decays); the output norm's weight, shared by the heads."""
+        lh, dk, dv, r = (cfg.linear_num_heads, cfg.linear_head_dim,
+                         cfg.linear_value_dim, cfg.linear_gate_rank)
         taps = STATE_CONV_TAPS
+        # gates a token: one a key channel, or one a head
+        gw = lh * dk if cfg.linear_gate_per == "channel" else lh
         leaves = {
-            "wq": w(n, h, lh * ld), "wk": w(n, h, lh * ld),
-            "wv": w(n, h, lh * ld), "wo": w(n, lh * ld, h),
+            "wq": w(n, h, lh * dk), "wk": w(n, h, lh * dk),
+            "wv": w(n, h, lh * dv), "wo": w(n, lh * dv, h),
             # the decay gate's and the output gate's projections: low-rank
             # pairs, or (linear_gate_rank 0) one full-rank matrix each
-            **({"wf_a": w(n, h, r), "wf_b": w(n, r, lh * ld),
-                "wg_a": w(n, h, r), "wg_b": w(n, r, lh * ld)} if r else
-               {"wf": w(n, h, lh * ld), "wg": w(n, h, lh * ld)}),
+            **({"wf_a": w(n, h, r), "wf_b": w(n, r, gw),
+                "wg_a": w(n, h, r), "wg_b": w(n, r, lh * dv)} if r else
+               {"wf": w(n, h, gw), "wg": w(n, h, lh * dv)}),
             "wb": w(n, h, lh),
-            "conv": w(n, taps, 3 * lh * ld, scale=taps ** -0.5),
+            "conv": w(n, taps, lh * (2 * dk + dv), scale=taps ** -0.5),
             "A_log": jnp.log(jax.random.uniform(
                 next(keys), (n, lh), jnp.float32, 1.0, 16.0)),
-            "o_norm": jnp.ones((n, ld), dt),
+            "o_norm": jnp.ones((n, dv), dt),
         }
         step = jnp.exp(jax.random.uniform(
-            next(keys), (n, lh * ld), jnp.float32,
+            next(keys), (n, gw), jnp.float32,
             jnp.log(1e-3), jnp.log(1e-1)))
         leaves["dt_bias"] = step + jnp.log(-jnp.expm1(-step))
         if cfg.linear_gate_form == "bounded":
@@ -499,7 +509,7 @@ def _init_params_by_kind(cfg: ModelConfig, rng: jax.Array) -> dict:
             # softplus form's, -rate x step (from barely any to e^-1.6 a
             # token) — logit(rate step / |bound|) / rate. The softplus
             # form's bias would leave every decay at ~0 (rate x its -2..-7)
-            rate = jnp.repeat(jnp.exp(leaves["A_log"]), ld, axis=-1)
+            rate = jnp.repeat(jnp.exp(leaves["A_log"]), gw // lh, axis=-1)
             share = rate * step / abs(cfg.linear_gate_lower_bound)
             leaves["dt_bias"] = (jnp.log(share) - jnp.log1p(-share)) / rate
         return leaves
@@ -558,10 +568,11 @@ def _init_params_by_kind(cfg: ModelConfig, rng: jax.Array) -> dict:
         return leaves
 
     L = cfg.num_layers
+    # OLMo-2's block norms the sublayers' OUTPUTS and nothing else
     layers: dict[str, Any] = {
-        "input_norm": jnp.ones((L, h), dt),
-        "post_attn_norm": jnp.ones((L, h), dt),
-    }
+        name: jnp.ones((L, h), dt) for name in (
+            ("attn_out_norm", "ffw_out_norm") if cfg.post_norms_only
+            else ("input_norm", "post_attn_norm"))}
     if cfg.attn_stacks_by_kind:
         for name, idx in (("attn", cfg.full_layers),
                           ("attn_window", cfg.window_layers)):
@@ -731,7 +742,7 @@ def init_kv_cache(
             2,
             num_window_blocks if spec.window and num_window_blocks
             else num_blocks,
-            block_size, cfg.num_kv_heads, cfg.head_dim), dt)
+            block_size, cfg.pool_kv_heads, cfg.head_dim), dt)
 
     def state(spec):
         shape, channels = cfg.state_geometry(spec.recurrence)
@@ -890,7 +901,8 @@ def _layer_body(
                            lp.get("attn_index"))
         return _mlp_block(cfg, lp, res + x, proj, moe_stats, moe_backend)
     if spec.holds_state and not spec.holds_pages:
-        x = _kda_attention(cfg, ap, lp["input_norm"], x, recur)
+        x = _kda_attention(cfg, ap, lp.get("input_norm"), x, recur,
+                           lp.get("attn_out_norm"))
         return _mlp_block(cfg, lp, res + x, proj, moe_stats, moe_backend)
     with jax.named_scope("attn_qkv"):
         if not cfg.post_norms_only:
@@ -935,7 +947,19 @@ def _layer_body(
 
     # `attend` names its own parts: kv_stage (this step's K/V into the
     # pool or the window's staging buffer) and attn
-    attn = attend(q, k, v)
+    if cfg.pool_kv_heads != nkv:
+        # a page holds more kv heads than the model has (whole HBM tiles:
+        # ModelConfig.page_kv_heads): zero heads behind the model's own,
+        # in k, v and — a group each — q; only the model's come back
+        def more_heads(y, n):
+            return jnp.pad(y, ((0, 0), (0, 0), (0, n - y.shape[2]), (0, 0)))
+
+        attn = attend(
+            more_heads(q, cfg.pool_kv_heads * (nh // nkv)),
+            more_heads(k, cfg.pool_kv_heads),
+            more_heads(v, cfg.pool_kv_heads))[:, :, :nh]
+    else:
+        attn = attend(q, k, v)
     if cfg.attn_output_gate:
         with jax.named_scope("attn_gate"):
             attn = attn * (gate.reshape(attn.shape)
@@ -963,26 +987,33 @@ def _layer_body(
     return _mlp_block(cfg, lp, x, proj, moe_stats, moe_backend)
 
 
-def _kda_attention(cfg: ModelConfig, ap: dict, norm: jax.Array,
-                   x: jax.Array, recur) -> jax.Array:
-    """A linear-attention layer's mixer (the gated delta rule with a
-    per-channel gate, KDA; ops/kda_attention.py has the recurrence),
-    (N, 1, h) -> the block's output before the residual add. The tokens lie
-    on the first axis (a packed chunk, or a decode step's rows); what a
-    token needs of the tokens before it — the convolution's tail and the
-    state — `recur` brings: `recur.qkv((xq, xk, xv) (N, H D) each, taps, H,
-    D)` returns q, k and v (N, H, D) in the model's dtype — the causal
-    depthwise convolution of the three projections over each row's own
-    tokens, SiLU and the q / k L2 norms, in float32: XLA's, over
-    `recur.conv` of their concatenation, for a decode step and for a chunk
-    whose recurrence is XLA's; ONE Pallas pass (ops/kda_short_conv.py) for
-    a chunk that runs the Pallas recurrence — and `recur.scan(q, k, v, g,
-    beta)` runs the recurrence and returns o (N, H, D) float32."""
+def _kda_attention(cfg: ModelConfig, ap: dict, norm: jax.Array | None,
+                   x: jax.Array, recur,
+                   out_norm: jax.Array | None = None) -> jax.Array:
+    """A linear-attention layer's mixer — the gated delta rule, its gate one
+    a key channel (KDA) or one a head (Gated DeltaNet:
+    `ModelConfig.linear_gate_per`), its state Dk x Dv a head;
+    ops/kda_attention.py has the recurrence — (N, 1, h) -> the block's
+    output before the residual add. The tokens lie on the first axis (a
+    packed chunk, or a decode step's rows); what a token needs of the
+    tokens before it — the convolution's tail and the state — `recur`
+    brings: `recur.qkv((xq, xk, xv), taps, H, Dk)` returns q, k (N, H, Dk)
+    and v (N, H, Dv) in the model's dtype — the causal depthwise
+    convolution of the three projections over each row's own tokens, SiLU
+    and the q / k L2 norms, in float32: XLA's, over `recur.conv` of their
+    concatenation, for a decode step and for a chunk whose recurrence is
+    XLA's; ONE Pallas pass (ops/kda_short_conv.py) for a chunk that runs
+    the Pallas recurrence — and `recur.scan(q, k, v, g, beta)` runs the
+    recurrence, g (N, H, Dk) or (N, H), and returns o (N, H, Dv) float32.
+    `norm`: the block's pre-norm weight (None: OLMo-2's block has none);
+    `out_norm`: the weight of its norm on the mixer's OUTPUT."""
     n = x.shape[0]
-    lh, ld = cfg.linear_num_heads, cfg.linear_head_dim
+    lh, ld, dv = (cfg.linear_num_heads, cfg.linear_head_dim,
+                  cfg.linear_value_dim)
     f32 = jnp.float32
     with jax.named_scope("kda_qkv"):
-        u = rms_norm(x[:, 0], norm, cfg.rms_norm_eps)
+        u = (x[:, 0] if norm is None
+             else rms_norm(x[:, 0], norm, cfg.rms_norm_eps))
         proj = (_mm(u, ap["wq"]), _mm(u, ap["wk"]), _mm(u, ap["wv"]))
     with jax.named_scope("kda_conv"):
         q, k, v = recur.qkv(proj, ap["conv"], lh, ld)
@@ -990,7 +1021,12 @@ def _kda_attention(cfg: ModelConfig, ap: dict, norm: jax.Array,
         # (the gates' projections: low-rank pairs, or full rank)
         a = (_mm(_mm(u, ap["wf_a"]), ap["wf_b"]) if "wf_a" in ap
              else _mm(u, ap["wf"])).astype(f32)
-        if cfg.linear_gate_form == "bounded":
+        if cfg.linear_gate_per == "head":
+            # ONE log-decay a head: -exp(A_log) softplus(a + dt_bias), a
+            # and dt_bias a head — (N, H), never broadcast over the keys
+            g = (-jnp.exp(ap["A_log"].astype(f32))[None]
+                 * jax.nn.softplus(a + ap["dt_bias"].astype(f32)))
+        elif cfg.linear_gate_form == "bounded":
             # log of the decay a key channel, bounded below: bound *
             # sigmoid(exp(A_log) (a + dt_bias)), in (bound, 0) — a token
             # decays a channel by e^bound at most
@@ -1015,8 +1051,14 @@ def _kda_attention(cfg: ModelConfig, ap: dict, norm: jax.Array,
         z = (_mm(_mm(u, ap["wg_a"]), ap["wg_b"]) if "wg_a" in ap
              else _mm(u, ap["wg"])).astype(f32)
         o = rms_norm(o, ap["o_norm"].astype(f32), cfg.rms_norm_eps)
-        o = (o.reshape(n, lh * ld) * jax.nn.sigmoid(z)).astype(x.dtype)
-        return _mm(o, ap["wo"])[:, None]
+        gate = (jax.nn.silu if cfg.linear_output_gate == "silu"
+                else jax.nn.sigmoid)
+        o = (o.reshape(n, lh * dv) * gate(z)).astype(x.dtype)
+        out = _mm(o, ap["wo"])
+        if out_norm is not None:
+            out = rms_norm(out, out_norm, cfg.rms_norm_eps,
+                           scale_f32=cfg.norm_scale_f32)
+        return out[:, None]
 
 
 def _scaled(y: jax.Array, m: float) -> jax.Array:
@@ -1081,14 +1123,17 @@ class _Recurrence:
         self.pools, self.read, self.write, self.tap = pools, read, write, tap
 
     def qkv(self, proj: tuple, taps, heads: int, d: int):
-        """A KDA layer's q, k, v (N, H, D) in the projections' dtype from
-        its three projections (N, H D) each: `self.conv` of their
+        """A delta-rule layer's q, k (N, H, D) and v (N, H, Dv) in the
+        projections' dtype from its three projections (N, H D | H Dv):
+        `self.conv` of their
         concatenation, SiLU, the split and the L2 norm over a head's dims,
         in float32 — the plain form."""
         x = jnp.concatenate(proj, axis=-1)
         n = x.shape[0]
         y = jax.nn.silu(self.conv(x, taps))
-        q, k, v = (z.reshape(n, heads, d) for z in jnp.split(y, 3, axis=-1))
+        # (v at its own width: what the two key widths leave of the axis)
+        q, k, v = (z.reshape(n, heads, -1) for z in jnp.split(
+            y, (heads * d, 2 * heads * d), axis=-1))
         # q also times D^-0.5
         q = q * jax.lax.rsqrt(
             jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6) * d ** -0.5
@@ -1165,7 +1210,9 @@ class _PackedRecurrence(_Recurrence):
 
     def qkv(self, proj, taps, heads, d):
         st = self.st
-        if st["interpret"] is None:
+        if st["interpret"] is None or proj[2].shape[-1] != heads * d:
+            # (the one pass tiles three thirds of ONE width: a value width
+            # of its own takes the plain form)
             return super().qkv(proj, taps, heads, d)
         # beside the Pallas recurrence: the same sums in ONE pass over the
         # projections as the matmuls wrote them (nothing concatenates them)
@@ -1183,7 +1230,10 @@ class _PackedRecurrence(_Recurrence):
     def scan(self, q, k, v, g, beta):
         st, rows = self.st, self.rows
         if st["interpret"] is not None:
-            o, s = kda_prefill_attention(
+            # (g a head and key channel, or — one rank less — a head)
+            kernel = (kda_prefill_attention if g.ndim == q.ndim
+                      else gdn_prefill_attention)
+            o, s = kernel(
                 self.pools["s"], self.read, self.write, st["fresh"],
                 q, k, v, g, beta, st["row_off"], st["chunk_lens"],
                 interpret=st["interpret"])
@@ -1242,7 +1292,9 @@ class _StepRecurrence(_Recurrence):
             o, s = kda_decode_xla(
                 self.pools["s"], self.read, self.write, q, k, v, g, beta)
         else:
-            o, s = kda_decode_attention(
+            kernel = (kda_decode_attention if g.ndim == q.ndim
+                      else gdn_decode_attention)
+            o, s = kernel(
                 self.pools["s"], self.read, self.write, q, k, v, g, beta,
                 interpret=self.interpret)
         self.pools = {**self.pools, "s": s}
@@ -2009,7 +2061,7 @@ def _layer(
     #   forward; `kv_layer` is {"kv", "idx"} where the layer owns an indexer
 ) -> tuple[jax.Array, jax.Array]:
     b, t = x.shape[0], x.shape[1]
-    hd, nkv = cfg.head_dim, cfg.num_kv_heads
+    hd, nkv = cfg.head_dim, cfg.pool_kv_heads  # (k, v as `attend` gets them)
     spec = spec or cfg.layer_specs[0]
     recur = None
     if spec.holds_state:
@@ -2343,7 +2395,7 @@ def init_staged_kv(
             (cfg.num_layers, batch, window, cfg.latent_width), dt)
     else:
         buf = jnp.zeros((cfg.num_layers, 2, window, batch,
-                         cfg.num_kv_heads, cfg.head_dim), dt)
+                         cfg.pool_kv_heads, cfg.head_dim), dt)
     if cfg.index_layers:
         # ... and the window's index keys of the layers that own an
         # indexer, (Lf, B, W, D), committed beside the latents
@@ -2401,7 +2453,7 @@ def decode_window_step(
     staged_mask = jnp.arange(window, dtype=jnp.int32) <= step_k
     if backend == "xla":
         s_ctx = (
-            hists[0][0].shape[1]
+            hists[cfg.page_layers[0]][0].shape[1]  # (layer 0 may hold none)
             if hists is not None
             else _group(block_tables, 0).shape[1]
             * _page_tokens(cfg, kv_caches)

@@ -147,7 +147,8 @@ def _solar_open2_preset(n_layers: int, **over) -> dict:
         n_shared_experts=1, scoring_func="sigmoid", moe_selection_bias=True,
         routed_scaling_factor=1.0, norm_topk_prob=True,
         attn_output_gate="element", linear_num_heads=64,
-        linear_head_dim=128, linear_gate_rank=128,
+        linear_head_dim=128, linear_value_head_dim=128,
+        linear_gate_rank=128,
     )
     kw.update(over)
     kw["layers"] = solar_open2_layers(
@@ -196,7 +197,8 @@ def _ling_flash_preset(n_layers: int, layer_group_size: int = 6,
         n_shared_experts=1, scoring_func="sigmoid", moe_selection_bias=True,
         routed_scaling_factor=2.5, norm_topk_prob=True, n_group=8,
         topk_group=4, attn_output_gate="head", linear_num_heads=32,
-        linear_head_dim=128, linear_gate_rank=0, linear_gate_form="bounded",
+        linear_head_dim=128, linear_value_head_dim=128, linear_gate_rank=0,
+        linear_gate_form="bounded",
         linear_gate_lower_bound=-5.0, linear_beta_scale=1.0,
         # image_patch_token, video_patch_token, image_start_token,
         # video_start_token
@@ -373,6 +375,106 @@ def _falcon_h1_preset(n_layers: int, **over) -> dict:
         intermediate_size=hf["intermediate_size"], num_layers=n_layers,
         num_heads=heads, num_kv_heads=hf["num_key_value_heads"],
         head_dim=hf["head_dim"], rope_theta=float(hf["rope_theta"]),
+        rms_norm_eps=hf["rms_norm_eps"],
+        max_model_len=hf["max_position_embeddings"])
+
+
+def _olmo_hybrid_keys(hf: dict, path: str) -> dict:
+    """The `model_type: olmo_hybrid` keys — `layer_types`, the five
+    `linear_*` keys, `rope_parameters` — as the per-layer list and the delta
+    rule's geometry, and by name what of the family is not built: a config
+    that sets one is refused, not guessed at. `linear_attention`: Gated
+    DeltaNet (arXiv:2412.06464), ONE decay gate a head, keys and values of
+    their own widths, a SiLU output gate; `full_attention`: as many
+    key-value heads as the config says, a flat QK-norm; both in OLMo-2's
+    post-norm block."""
+    kinds = tuple(hf["layer_types"])
+    heads = hf["linear_num_key_heads"]
+    rope = (hf.get("rope_parameters") or {})
+    refused = {
+        "layer_types other than linear_attention / full_attention": bool(
+            set(kinds) - {"linear_attention", "full_attention"}),
+        "layer_types of another length than num_hidden_layers":
+        len(kinds) != hf["num_hidden_layers"],
+        "linear_num_value_heads != linear_num_key_heads":
+        hf["linear_num_value_heads"] != heads,
+        f"linear_conv_kernel_dim != {STATE_CONV_TAPS}": hf.get(
+            "linear_conv_kernel_dim", STATE_CONV_TAPS) != STATE_CONV_TAPS,
+        "linear_allow_neg_eigval false": not hf.get(
+            "linear_allow_neg_eigval", True),
+        # assumed: `rope_theta: null` says NO position encoding; a theta is
+        # a rope in the full layers, which is not the published model
+        "rope_parameters.rope_theta": rope.get("rope_theta") is not None,
+        "rope_scaling": bool(hf.get("rope_scaling")),
+        "attention_bias true": bool(hf.get("attention_bias")),
+        "hidden_act != silu": hf.get("hidden_act", "silu") != "silu",
+    }
+    hit = [k for k, v in refused.items() if v]
+    if hit:
+        raise ValueError(
+            f"olmo_hybrid config {path} sets {', '.join(hit)}: "
+            "not implemented")
+    return dict(
+        layers=tuple(
+            LayerSpec(num_heads=hf["num_attention_heads"], rope=None,
+                      mixer="linear" if kind == "linear_attention"
+                      else "softmax")
+            for kind in kinds),
+        linear_num_heads=heads, linear_head_dim=hf["linear_key_head_dim"],
+        linear_value_head_dim=hf["linear_value_head_dim"],
+        # assumed (no key): the gates project at full rank, the decay gate
+        # is unbounded (-exp(A_log) softplus), the output gate goes through
+        # SiLU — the gated delta rule's public implementations' defaults
+        linear_gate_rank=0, linear_gate_per="head",
+        linear_output_gate="silu", linear_beta_scale=2.0,
+        # OLMo-2's block and norms
+        qk_norm_flat=True, post_norms_only=True, norm_scale_f32=True,
+        # a page holds whole sublane tiles of kv heads (30 -> 32; the toy
+        # preset's 6 -> 8): ModelConfig.page_kv_heads
+        page_kv_heads=_whole_tiles(hf["num_key_value_heads"]),
+    )
+
+
+def _whole_tiles(kv_heads: int) -> int:
+    """The kv heads a page holds so that its (kv heads, head_dim) rows fill
+    whole HBM tiles at every pool dtype the engine serves: the power of two
+    that holds them up to 8, multiples of 32 above."""
+    if kv_heads <= 8:
+        return 1 << (kv_heads - 1).bit_length()
+    return kv_heads + (-kv_heads) % 32
+
+
+# allenai/Olmo-Hybrid-7B config.json (`model_type: olmo_hybrid`), the keys
+# `_olmo_hybrid_keys` reads, as published
+_OLMO_HYBRID_7B = {
+    "model_type": "olmo_hybrid", "vocab_size": 100352, "hidden_size": 3840,
+    "intermediate_size": 11008, "num_hidden_layers": 32,
+    "num_attention_heads": 30, "num_key_value_heads": 30, "head_dim": None,
+    "hidden_act": "silu", "max_position_embeddings": 65536,
+    "attention_bias": False, "rms_norm_eps": 1e-06,
+    "tie_word_embeddings": False,
+    "layer_types": (("linear_attention",) * 3 + ("full_attention",)) * 8,
+    "linear_num_key_heads": 30, "linear_num_value_heads": 30,
+    "linear_key_head_dim": 96, "linear_value_head_dim": 192,
+    "linear_conv_kernel_dim": 4, "linear_allow_neg_eigval": True,
+    "rope_parameters": {"rope_theta": None},
+}
+
+
+def _olmo_hybrid_preset(n_layers: int, **over) -> dict:
+    """An Olmo-Hybrid preset of the first `n_layers` (periods L L L F) at
+    the 7B's published keys, 32 768 positions served; `over` replaces published
+    keys (the CPU's toy widths)."""
+    hf = {**_OLMO_HYBRID_7B, "num_hidden_layers": n_layers,
+          "max_position_embeddings": 32768, **over}
+    hf["layer_types"] = tuple(hf["layer_types"])[:n_layers]
+    heads = hf["num_attention_heads"]
+    return dict(
+        _olmo_hybrid_keys(hf, "preset"), architecture="olmo_hybrid",
+        vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"], num_layers=n_layers,
+        num_heads=heads, num_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf["head_dim"] or hf["hidden_size"] // heads,
         rms_norm_eps=hf["rms_norm_eps"],
         max_model_len=hf["max_position_embeddings"])
 
@@ -574,7 +676,8 @@ PRESETS: dict[str, dict] = {
         num_heads=4, num_kv_heads=2, head_dim=16, max_model_len=512,
         dtype="float32", num_experts=16, num_experts_per_tok=4,
         num_experts_held=4, first_expert_held=4, moe_intermediate_size=32,
-        linear_num_heads=4, linear_head_dim=16, linear_gate_rank=8),
+        linear_num_heads=4, linear_head_dim=16, linear_value_head_dim=16,
+        linear_gate_rank=8),
     "falcon-h1-34b": _falcon_h1_preset(72),
     # the benchmark cell's cut: ONE pipeline stage of twelve — layers 0-5
     # (every layer is alike: the pattern's period is one), every width, all
@@ -610,7 +713,25 @@ PRESETS: dict[str, dict] = {
         qk_rope_head_dim=8, v_head_dim=16, first_k_dense_replace=1,
         num_experts=16, num_experts_per_tok=4, n_group=4, topk_group=2,
         num_experts_held=8, first_expert_held=4, moe_intermediate_size=32,
-        linear_num_heads=4, linear_head_dim=16, refused_token_ids=()),
+        linear_num_heads=4, linear_head_dim=16, linear_value_head_dim=16,
+        refused_token_ids=()),
+    "olmo-hybrid-7b": _olmo_hybrid_preset(32),
+    # the benchmark cell's cut: ONE pipeline stage — layers 0-11 (three
+    # whole periods L L L F: 9 Gated DeltaNet layers of 30 heads x 96 keys
+    # x 192 values, 3 full-attention layers of 30 query and 30 key-value
+    # heads x 128), every head, every width and the whole vocabulary as
+    # published; this stage also carries the final norm and the head
+    "olmo-hybrid-7b-12l": _olmo_hybrid_preset(12),
+    # two periods at toy widths (float32) that keep the lane problem's
+    # SHAPE: 6 heads (no multiple of 4) x 12 keys x 24 values (a key width
+    # that is not the value width, neither a whole number of lanes), 6
+    # key-value heads x 16 in the full layers
+    "tiny-olmo-hybrid": dict(_olmo_hybrid_preset(
+        8, vocab_size=512, hidden_size=96, intermediate_size=128,
+        num_attention_heads=6, num_key_value_heads=6,
+        linear_num_key_heads=6, linear_num_value_heads=6,
+        linear_key_head_dim=12, linear_value_head_dim=24,
+        max_position_embeddings=512), dtype="float32"),
     "glm-5.2": _glm_dsa_preset(),
     # the benchmark cell's cut: ONE chip (shard 0 of sixteen that share each
     # layer's experts) of the pipeline stage that holds published layers 2-8
@@ -1006,6 +1127,8 @@ def _from_hf_config(path: str) -> dict:
         arch = "falcon_h1"
     if hf.get("model_type") == "glm_moe_dsa":
         arch = "glm_moe_dsa"
+    if hf.get("model_type") == "olmo_hybrid":
+        arch = "olmo_hybrid"
     if arch is None:
         raise ValueError(f"unsupported architecture(s) {archs} in {path}")
     heads = hf["num_attention_heads"]
@@ -1057,6 +1180,10 @@ def _from_hf_config(path: str) -> dict:
     if arch == "falcon_h1":
         moe = _falcon_h1_keys(hf, path)
         # its one rope is the per-layer list's (rope_scaling is refused)
+        hf = {**hf, "partial_rotary_factor": None, "rope_scaling": None}
+    if arch == "olmo_hybrid":
+        moe = _olmo_hybrid_keys(hf, path)
+        # no layer has a rope (`rope_parameters.rope_theta` null)
         hf = {**hf, "partial_rotary_factor": None, "rope_scaling": None}
     if arch == "glm_moe_dsa":
         moe = _glm_moe_dsa_keys(hf, path)

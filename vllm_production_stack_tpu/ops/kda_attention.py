@@ -89,11 +89,11 @@ _BLOCKS_VMEM_BYTES = 8 << 20  # of the 16 MiB a kernel may use on a v5e
 
 
 def kda_step(s, q, k, v, g, beta):
-    """One token: s (..., D, D) float32; q, k, v, g (..., D); beta (...).
-    Returns (s', o (..., D))."""
+    """One token: s (..., Dk, Dv) float32; q, k (..., Dk); v (..., Dv); beta
+    (...); g (..., Dk), or (...): a gate a head. Returns (s', o (..., Dv))."""
     f32 = jnp.float32
     q, k, v, g, beta = (x.astype(f32) for x in (q, k, v, g, beta))
-    s = s * jnp.exp(g)[..., :, None]
+    s = s * jnp.exp(g if g.ndim == q.ndim else g[..., None])[..., :, None]
     pred = jnp.einsum("...kv,...k->...v", s, k, precision=_HI)
     u = beta[..., None] * (v - pred)
     s = s + k[..., :, None] * u[..., None, :]
@@ -505,4 +505,331 @@ def kda_decode_attention(pool, read, write, q, k, v, g, beta, *,
         name="kda_decode_attention",
     )(read.astype(jnp.int32), write.astype(jnp.int32), q, k, v,
       g.astype(jnp.float32), beta.astype(jnp.float32)[..., None], pool)
+    return o, pool
+
+
+# -- one gate a head, key width != value width (Gated DeltaNet) --------------
+#
+# The same recurrence with ONE log-decay a head and token (arXiv:2412.06464)
+# and a state of Dk x Dv a head (96 x 192 at Olmo-Hybrid's widths). With a
+# scalar gate the chunk's pair products need no per-channel mask:
+#
+#     A[s, r] = (k_s . k_r) exp(G_s - G_r)        (r < s)
+#     B[s, r] = (q_s . k_r) exp(G_s - G_r)        (r <= s)
+#
+# is ONE product [K; Q] K^T under a (64, 64) decay mask, where the
+# per-channel form builds it in 16-token sub-blocks. The exponent of a pair
+# is summed DIRECTLY over the pair's own range, D[s, r] = sum_{r < t <= s}
+# g_t, as one product of two 0/1 masks around Diag(g) — never a difference
+# of two block-long cumulative sums (the module docstring says why). No
+# (N, H, Dk) gate array exists anywhere: the kernels take g (N, H) as they
+# take beta.
+#
+# THE LANES. 30 heads x 96 key channels fill no slab of whole 128-lane
+# tiles (h x 96 % 128 == 0 needs h % 4 == 0; 30's divisors are 1, 2, 3, 5,
+# 6, 10, 15, 30), and neither 96 nor 192 is a whole number of lanes. The
+# kernels therefore take q and k with each head's keys padded with zeros to
+# `whole_lanes(Dk)` (128) and v with its values padded to `whole_lanes(Dv)`
+# (256): a head's tile is whole lanes, any divisor of the head count is a
+# slab, and
+# every product inside is tile-aligned. What is padded is the chunk's
+# ACTIVATIONS on their way into the kernel (XLA writes them padded in the
+# fusion that makes them; the kernel's output comes back padded and its
+# consumer reads the first Dv lanes) — never the pools: a slot holds
+# (H, Dk, Dv) float32 as published, the kernel pads a head's state into a
+# VMEM scratch tile and stores the published part back. The padding is a
+# third more MXU columns than the published geometry needs; the benchmark's
+# costs count the published geometry, so it reads as lost share.
+
+_GDN_MAX_HEADS_PER_STEP = 3
+
+
+def whole_lanes(d: int) -> int:
+    return d + (-d) % 128
+
+
+def gdn_heads_per_step(heads: int) -> int:
+    """Heads of one grid step of `gdn_prefill_attention`: the largest
+    divisor of `heads` not above `_GDN_MAX_HEADS_PER_STEP`."""
+    return max(hb for hb in range(1, min(heads, _GDN_MAX_HEADS_PER_STEP) + 1)
+               if heads % hb == 0)
+
+
+def _gdn_prefill_kernel(blk_ref, rd_ref, wr_ref, lo_ref, hi_ref, fl_ref,
+                        q_ref, k_ref, v_ref, g_ref, b_ref, s_in_ref,
+                        o_ref, s_ref, pad_ref):
+    del blk_ref, rd_ref, wr_ref
+    f32 = jnp.float32
+    hg, m = pl.program_id(0), pl.program_id(1)
+    lo, hi, fl = lo_ref[m], hi_ref[m], fl_ref[m]
+    hb, dk, dv = s_ref.shape[1], s_ref.shape[2], s_ref.shape[3]
+    dkp, dvp = pad_ref.shape[1], pad_ref.shape[2]
+
+    @pl.when((fl & 1) != 0)
+    def _load():  # the row's first item: its heads' states, or zeros
+        s_ref[...] = jnp.where((fl & 2) != 0, 0.0, s_in_ref[...])
+
+    @pl.when(hi > lo)
+    def _item():
+        tok = jax.lax.broadcasted_iota(jnp.int32, (BLOCK, 1), 0)
+        live = (tok >= lo) & (tok < hi)
+        r_i = jax.lax.broadcasted_iota(jnp.int32, (BLOCK, BLOCK), 0)
+        c_i = jax.lax.broadcasted_iota(jnp.int32, (BLOCK, BLOCK), 1)
+        same = r_i // SUB == c_i // SUB
+        pair = r_i // (2 * SUB) == c_i // (2 * SUB)
+        # a token's inclusive prefix and its strict suffix over the item
+        sums_of = jnp.concatenate(
+            [(r_i >= c_i).astype(f32), (r_i < c_i).astype(f32)], axis=0)
+        after = (r_i > c_i).astype(f32)
+        nt = (((1,), (1,)), ((), ()))
+
+        def strip(x, n):  # see `_prefill_kernel`
+            return sum(x[i:i + n] for i in range(0, BLOCK, n))
+
+        def diag(x, blocks):
+            return jnp.where(blocks, jnp.concatenate(
+                [x] * (BLOCK // x.shape[0]), axis=0), 0.0)
+
+        eye_s = strip((r_i == c_i).astype(f32), SUB)
+
+        def head(q, k, v, g, beta, s0):
+            """One head's item, a stage a `yield`: q, k (BLOCK, dkp), v
+            (BLOCK, dvp) and g, beta (BLOCK, 1), zeroed outside the item's
+            span; s0 (dkp, dvp). Yields (o, s1) last."""
+            # the log-gates a lane: every sum below comes out of the MXU
+            # with the value in every lane, as the tiles it scales want it
+            g_l = jnp.broadcast_to(g, (BLOCK, dkp))
+            sums = _dot(sums_of, g_l)  # (2 BLOCK, dkp)
+            gc, g_tail = sums[:BLOCK], sums[BLOCK:]
+            # D[s, r] = sum of g over (r, s]: <= 0, 0 on and over the
+            # diagonal
+            decay = jnp.exp(_dot(sums_of[:BLOCK], g_l[:, :BLOCK] * after))
+            yield
+            ab = _dot(jnp.concatenate([k, q], axis=0), k, nt)
+            ab = ab * jnp.concatenate([decay, decay], axis=0)
+            a = jnp.where(r_i > c_i, ab[:BLOCK], 0.0)
+            bm = jnp.where(r_i >= c_i, ab[BLOCK:], 0.0)
+            yield
+            # T = (I + Diag(beta) A)^-1 as `_prefill_kernel` inverts it
+            low = beta * a
+            nil = strip(jnp.where(same, low, 0.0), SUB)
+            t = eye_s - nil
+            p = _dot(nil, diag(nil, same))
+            yield
+            for lvl in range(3):
+                t = t + _dot(t, diag(p, same))
+                yield
+                if lvl < 2:
+                    p = _dot(p, diag(p, same))
+                    yield
+            t = diag(t, same)
+            t_s = strip(t, 2 * SUB)
+            t_p = _dot(t_s, jnp.where(pair & ~same, low, 0.0))
+            yield
+            t = diag(t_s - _dot(t_p, t), pair)
+            yield
+            t_p = _dot(t, jnp.where(pair, 0.0, low))
+            yield
+            t = t - _dot(t_p, t)
+            yield
+            e_g = jnp.exp(gc)
+            on_s0 = _dot(jnp.concatenate(
+                [beta * (k * e_g), q * e_g], axis=0), s0)
+            yield
+            u = _dot(t, beta * v - on_s0[:BLOCK])
+            yield
+            o = on_s0[BLOCK:] + _dot(bm, u)
+            yield
+            # S1 = exp(G_last) S0 + (K exp(G_last - G))^T U
+            # (the block's decay in every lane of a row: down the
+            # sublanes, then lane tile by lane tile)
+            last = jnp.broadcast_to(
+                jnp.exp(gc[BLOCK - 1:BLOCK, :128]), (dkp, 128))
+            last = jnp.concatenate([last] * (dvp // 128), axis=1)
+            yield o, last * s0 + _dot(k * jnp.exp(g_tail), u,
+                                      (((0,), (0,)), ((), ())))
+
+        def tile(ref, j, w):  # a head's rows of the item's span
+            return jnp.where(
+                live, ref[:, j * w:(j + 1) * w].astype(f32), 0.0)
+
+        head_i = jax.lax.broadcasted_iota(jnp.int32, b_ref.shape, 1)
+        betas = jnp.where(live, b_ref[...].astype(f32), 0.0)
+        gates = jnp.where(live, g_ref[...].astype(f32), 0.0)
+
+        def of_head(x, j):
+            return jnp.sum(jnp.where(head_i == hg * hb + j, x, 0.0),
+                           axis=1, keepdims=True)
+
+        # a head's published (dk, dv) state in a zeroed (dkp, dvp) tile
+        pad_ref[...] = jnp.zeros(pad_ref.shape, f32)
+        for j in range(hb):
+            pad_ref[j, :dk, :dv] = s_ref[0, j]
+        *_, done = zip(*(
+            head(tile(q_ref, j, dkp), tile(k_ref, j, dkp),
+                 tile(v_ref, j, dvp), of_head(gates, j), of_head(betas, j),
+                 pad_ref[j])
+            for j in range(hb)))
+        for j, (o, s1) in enumerate(done):
+            sl = slice(j * dvp, (j + 1) * dvp)
+            o_ref[:, sl] = jnp.where(
+                live, o, o_ref[:, sl].astype(f32)).astype(o_ref.dtype)
+            s_ref[0, j] = s1[:dk, :dv]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def gdn_prefill_attention(pool, read, write, fresh, q, k, v, g, beta,
+                          row_off, chunk_lens, *, interpret: bool = False):
+    """The packed chunk through the chunked kernel, ONE gate a head: q, k
+    (N, H, Dk) and v (N, H, Dv) in the model's dtype, g and beta (N, H)
+    float32, pool (slots, H, Dk, Dv) float32; `row_off`, `chunk_lens`,
+    `read`, `write`, `fresh` (B,) a row. Returns (o (N, H, Dv) float32,
+    pool')."""
+    n_tokens, heads, dk = q.shape
+    dv = v.shape[-1]
+    dkp, dvp = whole_lanes(dk), whole_lanes(dv)
+    hb = gdn_heads_per_step(heads)
+    pad = (-n_tokens) % BLOCK
+    n = n_tokens + pad
+
+    def flat(x, width):  # tokens to whole blocks, a head to whole lanes
+        x = jnp.pad(x, ((0, pad), (0, 0), (0, width - x.shape[-1])))
+        return x.reshape(n, heads * width)
+
+    g, beta = (jnp.pad(x.astype(jnp.float32), ((0, pad), (0, 0)))
+               for x in (g, beta))
+    items = work_items(row_off, chunk_lens, n)
+    row = items["row"]
+    flags = (items["first"].astype(jnp.int32)
+             + 2 * (items["first"] & fresh[row]).astype(jnp.int32))
+    m = row.shape[0]
+
+    def tok_map(h, i, blk, *_):
+        return blk[i], h
+
+    def head_map(h, i, blk, *_):
+        return blk[i], 0
+
+    grid = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=6,
+        grid=(heads // hb, m),
+        in_specs=[
+            pl.BlockSpec((BLOCK, hb * dkp), tok_map),
+            pl.BlockSpec((BLOCK, hb * dkp), tok_map),
+            pl.BlockSpec((BLOCK, hb * dvp), tok_map),
+            pl.BlockSpec((BLOCK, heads), head_map),
+            pl.BlockSpec((BLOCK, heads), head_map),
+            pl.BlockSpec((1, hb, dk, dv),
+                         lambda h, i, blk, rd, *_: (rd[i], h, 0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((BLOCK, hb * dvp), tok_map),
+            pl.BlockSpec((1, hb, dk, dv),
+                         lambda h, i, blk, rd, wr, *_: (wr[i], h, 0, 0)),
+        ],
+        scratch_shapes=[pltpu.VMEM((hb, dkp, dvp), jnp.float32)],
+    )
+    o, pool = pl.pallas_call(
+        _gdn_prefill_kernel,
+        grid_spec=grid,
+        out_shape=[jax.ShapeDtypeStruct((n, heads * dvp), jnp.float32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        input_output_aliases={11: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="gdn_prefill_attention",
+    )(items["block"], read[row].astype(jnp.int32),
+      write[row].astype(jnp.int32), items["lo"], items["hi"], flags,
+      flat(q, dkp), flat(k, dkp), flat(v, dvp), g, beta, pool)
+    return o.reshape(n, heads, dvp)[:n_tokens, :, :dv], pool
+
+
+def _gdn_decode_kernel(rd_ref, wr_ref, q_ref, k_ref, v_ref, g_ref, b_ref,
+                       s_in_ref, o_ref, s_ref):
+    del rd_ref, wr_ref
+    f32 = jnp.float32
+    hb, dkp = q_ref.shape[1], q_ref.shape[2]
+    dk = s_ref.shape[2]
+    # the heads' q, k and decay as COLUMNS (a key channel a sublane, as the
+    # state's rows are): one transpose of a (dkp, dkp) tile whose rows are
+    # q | k | the head's decay in every lane
+    rows = jnp.concatenate(
+        [q_ref[0].astype(f32), k_ref[0].astype(f32),
+         jnp.broadcast_to(jnp.exp(g_ref[0].astype(f32)), (hb, dkp)),
+         jnp.zeros((dkp - 3 * hb, dkp), f32)], axis=0)
+    cols = rows.T  # (dkp, dkp)
+    v = v_ref[0].astype(f32)
+    beta = b_ref[0].astype(f32)  # (hb, 1)
+    outs = []
+    for j in range(hb):
+        qc, kc = cols[:dk, j:j + 1], cols[:dk, hb + j:hb + j + 1]
+        s = s_in_ref[0, j] * cols[:dk, 2 * hb + j:2 * hb + j + 1]
+        pred = jnp.sum(s * kc, axis=0, keepdims=True)  # (1, Dv)
+        u = beta[j:j + 1] * (v[j:j + 1] - pred)
+        s = s + kc * u
+        s_ref[0, j] = s
+        outs.append(jnp.sum(s * qc, axis=0, keepdims=True))
+    o_ref[0] = jnp.concatenate(outs, axis=0)
+
+
+def gdn_decode_heads_per_step(heads: int, dk: int) -> int:
+    """Heads of one grid step of `gdn_decode_attention`: 8 where they
+    divide the head count (whole sublane tiles), else all of them (a block
+    as tall as its array is always allowed) — whose q, k and decay rows
+    have to fit the one (lanes, lanes) tile the step transposes."""
+    hb = 8 if heads % 8 == 0 else heads
+    if 3 * hb > whole_lanes(dk):
+        raise ValueError(
+            f"gdn_decode_attention: {heads} heads of {dk} keys — 3 x {hb} "
+            f"rows do not fit the {whole_lanes(dk)}-row tile a step "
+            "transposes")
+    return hb
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def gdn_decode_attention(pool, read, write, q, k, v, g, beta, *,
+                         interpret: bool = False):
+    """One step for B rows, ONE gate a head: q, k (B, H, Dk), v (B, H,
+    Dv), g and beta (B, H), pool (slots, H, Dk, Dv) float32. Returns
+    (o (B, H, Dv) float32, pool')."""
+    b, heads, dk = q.shape
+    dv = v.shape[-1]
+    dkp = whole_lanes(dk)
+    hb = gdn_decode_heads_per_step(heads, dk)
+    q, k = (jnp.pad(x, ((0, 0), (0, 0), (0, dkp - dk))) for x in (q, k))
+
+    def vec(i, j, rd, wr):
+        return i, j, 0
+
+    grid = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, heads // hb),
+        in_specs=[
+            pl.BlockSpec((1, hb, dkp), vec), pl.BlockSpec((1, hb, dkp), vec),
+            pl.BlockSpec((1, hb, dv), vec), pl.BlockSpec((1, hb, 1), vec),
+            pl.BlockSpec((1, hb, 1), vec),
+            pl.BlockSpec((1, hb, dk, dv),
+                         lambda i, j, rd, wr: (rd[i], j, 0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, hb, dv), vec),
+            pl.BlockSpec((1, hb, dk, dv),
+                         lambda i, j, rd, wr: (wr[i], j, 0, 0)),
+        ],
+    )
+    o, pool = pl.pallas_call(
+        _gdn_decode_kernel,
+        grid_spec=grid,
+        out_shape=[jax.ShapeDtypeStruct((b, heads, dv), jnp.float32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        input_output_aliases={7: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=48 << 20),
+        interpret=interpret,
+        name="gdn_decode_attention",
+    )(read.astype(jnp.int32), write.astype(jnp.int32), q, k, v,
+      g.astype(jnp.float32)[..., None], beta.astype(jnp.float32)[..., None],
+      pool)
     return o, pool

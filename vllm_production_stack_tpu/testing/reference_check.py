@@ -226,6 +226,7 @@ class _KvB:
 def reference_tree(params: dict) -> dict:
     """The served tree as the references that read it directly take it
     (reference_decoder.py, reference_sdar.py, reference_falcon_h1.py,
+    reference_olmo_hybrid.py,
     reference_glm_dsa.py): every matrix (in, out). The leaves of
     `llama.HEADS_MAJOR` are viewed layer by layer (`_Matrices`; a latent
     stack's two halves of W_kv_b as one "wkv_b", `_KvB`), an int8 leaf's
@@ -344,9 +345,10 @@ def compare(reference, cfg, params, prompts: list[list[int]],
                 **(reference_arms or {}))
             selection_reports.extend(reports)
             first = 0
-        elif cfg.ssm_layers:
-            # reference_falcon_h1.py reads the served tree's own leaves and
-            # takes `logit_positions` and its fault arms
+        elif cfg.ssm_layers or cfg.architecture == "olmo_hybrid":
+            # reference_falcon_h1.py and reference_olmo_hybrid.py read the
+            # served tree's own leaves and take `logit_positions` and their
+            # fault arms
             logits, routing = reference.forward(
                 ref_cfg, params, seq, range(first, len(seq)),
                 **(reference_arms or {}))
@@ -555,7 +557,9 @@ def served_pages(reference, cfg, params, pool, prompts: list[list[int]],
             2, -1, *pool.shape[3:])[:, :len(fed)]
         for want, got in zip(
                 reference.first_layer_kv(keys, params, fed), held):
-            got = jnp.asarray(got).astype(jnp.float32)
+            # (a page may hold more kv heads than the model has, zeros:
+            # ModelConfig.page_kv_heads)
+            got = jnp.asarray(got[:, :want.shape[1]]).astype(jnp.float32)
             worst = max(worst, float(
                 jnp.linalg.norm(got - want) / jnp.linalg.norm(want)))
         tokens += len(fed)
